@@ -55,9 +55,10 @@ class TpuModel:
     # set by to_mesh(): params are sharded over this jax.sharding.Mesh and
     # every generate/serving entry point runs SPMD under it
     mesh: Optional[Any] = None
-    # set by to_mesh(comm_qtype=...): parallel/qcollectives.CommConfig —
-    # routes the TP row-parallel epilogues through the block-quantized
-    # ring all-reduce; None keeps GSPMD's implicit fp32 psum
+    # set by to_mesh(): parallel/qcollectives.CommConfig — under tp > 1
+    # the forward runs the per-layer projections per shard and reduces
+    # the row-parallel pair through its all-reduce (exact, or the
+    # block-quantized ring for comm_qtype=...)
     comm: Optional[Any] = None
 
     @property
@@ -79,20 +80,24 @@ class TpuModel:
         call shape so callers don't branch."""
         if self.pp_size <= 1:
             fwd = self.family.forward
-            if self.comm is not None and self.comm.enabled:
+            if self.comm is not None and self.comm.axis_size > 1:
                 if getattr(self, "_comm_fwd", None) is None:
                     import functools
                     import inspect
 
-                    if "comm" not in inspect.signature(fwd).parameters:
+                    if "comm" in inspect.signature(fwd).parameters:
+                        # cached: a stable callable identity keeps the
+                        # jit caches in generate/serving warm across calls
+                        self._comm_fwd = functools.partial(
+                            fwd, comm=self.comm)
+                    elif self.comm.enabled:
                         raise NotImplementedError(
                             f"{self.config.model_type}'s forward does not "
                             "take comm= — quantized TP collectives are "
                             "wired for the llama family only"
                         )
-                    # cached: a stable callable identity keeps the jit
-                    # caches in generate/serving warm across calls
-                    self._comm_fwd = functools.partial(fwd, comm=self.comm)
+                    else:  # GSPMD partitions this family's forward
+                        self._comm_fwd = fwd
                 return self._comm_fwd
             return fwd
         if getattr(self, "_pp_step", None) is None:
@@ -246,13 +251,15 @@ class TpuModel:
             else getattr(self, "default_comm_qtype", None)
         )
         self.comm = None
-        if cq != "none":
-            if self.pp_size > 1:
-                raise NotImplementedError(
-                    "comm_qtype is wired for the tp epilogues of the "
-                    "single-stage forward; pipeline stages keep fp32 "
-                    "collectives (pp=1 to quantize comms)"
-                )
+        if cq != "none" and self.pp_size > 1:
+            raise NotImplementedError(
+                "comm_qtype is wired for the tp epilogues of the "
+                "single-stage forward; pipeline stages keep fp32 "
+                "collectives (pp=1 to quantize comms)"
+            )
+        if self.pp_size == 1:
+            # tp > 1: the forward runs its projections per shard and
+            # reduces through this (exact psum for "none")
             self.comm = CommConfig(mesh=mesh, axis_name="tp", qtype=cq)
         return self
 
@@ -261,9 +268,7 @@ class TpuModel:
 
         if self.mesh is None:
             return contextlib.nullcontext()
-        from bigdl_tpu.parallel._compat import set_mesh
-
-        return set_mesh(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def save_low_bit(self, path: str, *, faults=None) -> None:
         """Atomic, digest-manifested save (convert/low_bit.py): a kill
@@ -528,11 +533,12 @@ class TpuModel:
 
         if draft_params is None:
             draft_params = self.self_draft_params()
-        return speculative_generate(
-            self.config, self.params, draft_params, prompts,
-            self.family.forward, max_new_tokens=max_new_tokens,
-            draft_k=draft_k, **kw,
-        )
+        with self._mesh_ctx():
+            return speculative_generate(
+                self.config, self.params, draft_params, prompts,
+                self.family.forward, max_new_tokens=max_new_tokens,
+                draft_k=draft_k, **kw,
+            )
 
 
 def _merged_model(config, params, qtype, merge_fused: bool = True) -> TpuModel:

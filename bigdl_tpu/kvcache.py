@@ -271,7 +271,7 @@ def read_layer_raw(
     blocks in-kernel (the paged path's fp8 story) — going through
     read_layer instead would materialize the full dense bf16 cache in
     HBM each step, forfeiting exactly the bytes fp8 KV saves (the dense
-    `sdp_fp8` caveat, VERDICT §2.1)."""
+    `sdp_fp8` caveat)."""
     k = jax.lax.dynamic_index_in_dim(cache.k, layer, axis=0, keepdims=False)
     v = jax.lax.dynamic_index_in_dim(cache.v, layer, axis=0, keepdims=False)
     if not cache.quantized:

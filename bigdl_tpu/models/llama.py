@@ -36,6 +36,7 @@ from bigdl_tpu import kvcache
 from bigdl_tpu.kvcache import KVCache
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.ops import apply_rotary_emb, attention, linear, rms_norm, rope_cos_sin
+from bigdl_tpu.ops.linear import col_parallel_linear, row_parallel_linear
 from bigdl_tpu.ops.norms import layer_norm
 from bigdl_tpu.ops.rope import alibi_slopes, make_inv_freq_scaled
 from bigdl_tpu.quant import QTensor, quantize
@@ -44,6 +45,16 @@ from bigdl_tpu.quant.qtypes import resolve_qtype
 Params = dict[str, Any]
 
 _NEG_INF = -1e30
+
+# weight name -> its per-shard linear under tensor parallelism, matching
+# parallel/sharding.layer_specs (to_mesh splits the merged wqkv/w_gateup
+# back before sharding, so only the split names occur)
+_TP_PARALLEL = {
+    "wq": col_parallel_linear, "wk": col_parallel_linear,
+    "wv": col_parallel_linear, "w_gate": col_parallel_linear,
+    "w_up": col_parallel_linear,
+    "wo": row_parallel_linear, "w_down": row_parallel_linear,
+}
 
 
 # ---------------------------------------------------------------------------
@@ -532,10 +543,11 @@ def forward(
     remat: bool = False,  # static: jax.checkpoint each scan layer —
     # backward recomputes the layer instead of saving its activations
     # (long-context training memory lever; make_train_step(remat=True))
-    comm=None,  # static: parallel/qcollectives.CommConfig — routes the
-    # row-parallel epilogues (wo, w_down) through the explicit
-    # block-quantized ring all-reduce instead of GSPMD's implicit fp32
-    # psum. None or comm_qtype="none" keeps today's path bit-identical.
+    comm=None,  # static: parallel/qcollectives.CommConfig, set by
+    # TpuModel.to_mesh for tp > 1 — the per-layer projections run per
+    # shard under shard_map, and the row-parallel pair (wo, w_down)
+    # reduces through its all-reduce: exact for comm_qtype="none", the
+    # block-quantized ring otherwise. None is the single-device path.
 ) -> tuple[jax.Array, Optional[KVCache]]:
     """Returns (logits [B, T, V] float32, updated cache with pos advanced).
 
@@ -639,7 +651,7 @@ def forward(
     # score matrix in HBM); decode and the differentiable cache-free
     # training path use the fused XLA attention. Mirrors the reference's
     # sdp_causal vs sdp dispatch (models/common.py:222-258).
-    from bigdl_tpu.ops.pallas import use_pallas
+    from bigdl_tpu.ops.pallas import use_pallas, why_not_pallas
 
     uniform_window = (config.sliding_window_pattern is None
                       and config.sliding_layers is None)
@@ -695,6 +707,23 @@ def forward(
         and attention_override is None
     )
 
+    from bigdl_tpu.ops import routes
+
+    att_detail = f"mode={mode} B{B} T{T}"
+    if use_paged_kernel:
+        routes.note("attention", "pallas:paged", att_detail)
+    elif use_flash_train:
+        routes.note("attention", "pallas:flash_train", att_detail)
+    elif use_flash:
+        routes.note("attention", "pallas:flash", att_detail)
+    else:
+        why = why_not_pallas() or (
+            "dense-cache decode: no kernel, fused XLA attention" if T == 1
+            else "per-row cache.pos: flash takes a scalar q_offset"
+            if cache is not None and cache.pos.ndim != 0
+            else "alibi, mixed window layers, softcap or an override")
+        routes.note("attention", "xla", f"{att_detail} ({why})")
+
     if use_flash or use_paged_kernel or use_flash_train:
         mask_global = mask_sliding = None
         alibi_bias = None
@@ -717,20 +746,21 @@ def forward(
 
     lora_scale = lora["scale"] if lora is not None else None
 
-    quantize_comm = comm is not None and comm.enabled
+    tp_sharded = comm is not None and comm.axis_size > 1
 
     def proj(x, p, lp, wname, bname=None):
         b = p.get(bname) if bname else None
         pair = lp[wname] if lp is not None and wname in lp else None
-        if quantize_comm and wname in ("wo", "w_down"):
-            # the two per-layer row-parallel epilogues whose implicit TP
-            # psum the quantized ring replaces (the lm_head's single
-            # vocab-shard reduce and MoE experts stay on GSPMD's); the
-            # LoRA delta below still reduces implicitly — rank-r traffic
-            # is negligible next to the hidden-size epilogue
-            from bigdl_tpu.ops.linear import row_parallel_linear
-
-            y = row_parallel_linear(x, p[wname], comm, b, compute_dtype)
+        if tp_sharded and wname in _TP_PARALLEL:
+            # under tensor parallelism the per-layer projections run
+            # per shard (ops/linear.py: a K-sharded packed weight left
+            # to GSPMD is gathered whole every call, and a Mosaic call
+            # cannot be partitioned); the row-parallel pair reduces
+            # through comm's all-reduce, exact or quantized. The
+            # lm_head's vocab-shard product and MoE experts stay on
+            # GSPMD's; the LoRA delta below reduces implicitly — rank-r
+            # traffic is negligible next to the hidden-size epilogue
+            y = _TP_PARALLEL[wname](x, p[wname], comm, b, compute_dtype)
             if pair is not None:
                 y = y + _lora_delta(x, pair, lora_scale, compute_dtype)
         else:

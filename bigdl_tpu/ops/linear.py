@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bigdl_tpu.ops import routes
 from bigdl_tpu.quant import QTensor
 
 # Decode GEMV threshold, same role as the reference's `use_batch_forward`
@@ -225,18 +226,21 @@ for _name, _e in _QGEMV_QTYPES.items():
     )
 
 
-def _fused_kernel(x: jax.Array, w: QTensor) -> Optional[Callable]:
-    """The fused kernel this (x, w) pair dispatches to, or None for the
-    XLA dequant path. Shape guards are shared by both shape classes."""
-    from bigdl_tpu.ops.pallas import use_pallas
+def _fused_route(x: jax.Array, w: QTensor) -> tuple[Optional[Callable], str]:
+    """(kernel, why): the fused kernel this (x, w) pair dispatches to,
+    or None with the guard that sent it to the XLA dequant route. Shape
+    guards are shared by both shape classes."""
+    from bigdl_tpu.ops.pallas import why_not_pallas
     from bigdl_tpu.ops.pallas.tiling import VMEM_BUDGET
 
     entry = _QGEMV_QTYPES.get(w.qtype)
-    if entry is None or w.data.ndim != 2:
-        return None
+    if entry is None:
+        return None, "no fused kernel registered for this qtype"
+    if w.data.ndim != 2:
+        return None, f"weight is rank {w.data.ndim}, kernels take rank 2"
     out, kw_ = w.data.shape
     if out % 128 != 0:
-        return None
+        return None, "O not a multiple of 128 lanes"
     # the kernels tile O at >= 128 rows (Mosaic lane rule forbids
     # smaller output tiles); if even a 128-row tile's persistent weight
     # block cannot fit half the scoped-VMEM budget (the other half is
@@ -244,14 +248,21 @@ def _fused_kernel(x: jax.Array, w: QTensor) -> Optional[Callable]:
     # compile a kernel that overflows vmem
     row_bytes = kw_ * w.data.dtype.itemsize
     if 128 * row_bytes > VMEM_BUDGET // 2:
-        return None
+        return None, "a 128-row weight tile exceeds half the VMEM budget"
     if w.shape[-1] % entry.k_multiple != 0:
-        return None
-    if not use_pallas():
-        return None
+        return None, f"K not a multiple of {entry.k_multiple}"
+    off = why_not_pallas()
+    if off is not None:
+        return None, off
     if _rows(x.shape) <= _GEMV_MAX_ROWS:
-        return entry.run
-    return entry.gemm  # None for gemm_exempt formats
+        return entry.run, "gemv"
+    if entry.gemm is None:
+        return None, f"gemm_exempt: {entry.gemm_exempt}"
+    return entry.gemm, "gemm"
+
+
+def _fused_kernel(x: jax.Array, w: QTensor) -> Optional[Callable]:
+    return _fused_route(x, w)[0]
 
 
 def _use_qgemv(x: jax.Array, w: QTensor) -> bool:
@@ -481,7 +492,13 @@ def linear(
     doubles as the fused path's parity oracle.
     """
     if isinstance(w, QTensor):
-        if _fused_kernel(x, w) is not None:
+        kernel, why = _fused_route(x, w)
+        routes.note(
+            "linear", f"pallas:{why}" if kernel is not None else "xla",
+            f"{w.qtype} M{_rows(x.shape)} K{w.shape[-1]} "
+            f"O{w.data.shape[-2]}" + ("" if kernel is not None
+                                      else f" ({why})"))
+        if kernel is not None:
             block_o = 256 if w.data.shape[0] % 256 == 0 else 128
             xc = x.astype(compute_dtype)
             if lora is not None:
@@ -535,16 +552,20 @@ def row_parallel_linear(
     bias: Optional[jax.Array] = None,
     compute_dtype=jnp.bfloat16,
 ) -> jax.Array:
-    """`linear` for a row-parallel (contraction-sharded) weight with an
-    EXPLICIT quantized all-reduce epilogue (parallel/qcollectives.py).
+    """`linear` for a row-parallel (contraction-sharded) weight, run per
+    shard under shard_map with an EXPLICIT all-reduce epilogue
+    (parallel/qcollectives.py): ``jax.lax.psum`` for comm qtype "none",
+    the block-scaled ring all-reduce with error feedback for a
+    quantized `comm_qtype`. A 1-wide axis (or no comm) is plain
+    `linear`.
 
-    Under plain GSPMD the psum behind wo / w_down is implicit — XLA
-    inserts it from the shardings, fp32/bf16 on the wire. A
-    `CommConfig` with a quantized `comm_qtype` replaces that one
-    epilogue with a shard_map partial matmul + block-scaled ring
-    all-reduce with error feedback; ``comm.enabled == False`` (qtype
-    "none" or a 1-wide axis) falls straight back to `linear`, leaving
-    the implicit-psum path bit-identical to today's.
+    Left to GSPMD, the XLA dequant of a K-sharded packed weight
+    all-gathers the whole packed weight to every device on every call
+    (the half-split nibble layout below is not a block sharding of K,
+    so the partitioner gives up: seen in the compiled decode step, PR
+    21), and a Mosaic call cannot be partitioned at all. Per shard,
+    each device decodes its own bytes, with the fused kernel where the
+    shard's shape is eligible.
 
     The shard_map's in_specs shard only `comm.axis_name` (x's
     contraction dim, W's K dim); other mesh axes see the operands
@@ -563,13 +584,11 @@ def row_parallel_linear(
     Layouts that cannot be sliced consistently (bit planes, k-quant
     superblocks, shards that straddle a scale block) dequantize once and
     take the dense partial-matmul path instead."""
-    if comm is None or not comm.enabled:
+    if comm is None or comm.axis_size <= 1:
         return linear(x, w, bias, compute_dtype)
     import dataclasses
 
     from bigdl_tpu.parallel import qcollectives as qc
-    from bigdl_tpu.parallel._compat import shard_map
-
     from jax.sharding import PartitionSpec as P
 
     ax = comm.axis_name
@@ -607,8 +626,37 @@ def row_parallel_linear(
             error_feedback=comm.error_feedback,
         )
 
-    f = shard_map(part, mesh=comm.mesh, in_specs=(xspec, wspec),
+    f = jax.shard_map(part, mesh=comm.mesh, in_specs=(xspec, wspec),
                   out_specs=P(), check_vma=False)
+    y = f(x, w)
+    if bias is not None:
+        y = y + bias.astype(y.dtype)
+    return y
+
+
+def col_parallel_linear(
+    x: jax.Array,
+    w: Union[QTensor, jax.Array],
+    comm,
+    bias: Optional[jax.Array] = None,
+    compute_dtype=jnp.bfloat16,
+) -> jax.Array:
+    """`linear` for a column-parallel (output-sharded) weight, run per
+    shard under shard_map: x is replicated over `comm.axis_name`, each
+    device multiplies by its own rows of W (no layout care needed: every
+    QTensor field is row-leading) and the output stays sharded on its
+    last axis. XLA cannot partition a Mosaic call, so this is how the
+    fused kernels run under tensor parallelism. A 1-wide axis (or no
+    comm) is plain `linear`."""
+    if comm is None or comm.axis_size <= 1:
+        return linear(x, w, bias, compute_dtype)
+    from jax.sharding import PartitionSpec as P
+
+    ax = comm.axis_name
+    f = jax.shard_map(
+        lambda xs, ws: linear(xs, ws, None, compute_dtype),
+        mesh=comm.mesh, in_specs=(P(), P(ax, None)),
+        out_specs=P(*([None] * (x.ndim - 1) + [ax])), check_vma=False)
     y = f(x, w)
     if bias is not None:
         y = y + bias.astype(y.dtype)

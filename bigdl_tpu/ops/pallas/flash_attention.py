@@ -43,7 +43,6 @@ _NEG_INF = -1e30
 # analytic attention roofline evaluates at the kernel's REAL tiles
 _LANES = MOSAIC_LANES
 
-from bigdl_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
 
 
 def _kernel(
@@ -197,6 +196,7 @@ def _flash(
         args += [k_scale, v_scale]
     return pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
@@ -209,7 +209,7 @@ def _flash(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -22,8 +22,8 @@ the training shapes (llama-family QLoRA/LoRA/full finetune). Softcap
 (gemma2) stays on the XLA path. The forward math duplicates
 flash_attention._kernel deliberately: that kernel is silicon-validated
 for inference and is not touched; this one adds the lse output (written
-as an [.., 8]-lane block to satisfy the Mosaic lane rule,
-BENCH_NOTES.md r05 finding #4).
+as an [.., 8]-lane block to satisfy the Mosaic lane rule: a block's
+last dim is a multiple of 128 or the whole array dim).
 
 Layouts follow the inference kernel: kernels run on [B, H, T, D] with
 T/S/D padded to block multiples; the public wrapper takes/returns the
@@ -42,7 +42,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bigdl_tpu.ops.pallas import qdecode
-from bigdl_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
 from bigdl_tpu.ops.pallas.tiling import MOSAIC_LANES
 from bigdl_tpu.utils import round_up
 
@@ -244,6 +243,7 @@ def _fwd(q, k, v, start, qoff, scale, block_q, block_k, causal, window,
     )
     return pl.pallas_call(
         kernel,
+        name="flash_train_fwd",
         grid=(B, Hq, n_q, n_k),
         in_specs=[
             _smem((B,)), _smem((1,)),
@@ -267,7 +267,7 @@ def _fwd(q, k, v, start, qoff, scale, block_q, block_k, causal, window,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -288,6 +288,7 @@ def _bwd(q, k, v, do, lse, delta, start, qoff, scale, block_q, block_k,
     )
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_train_dq",
         grid=(B, Hq, n_q, n_k),
         in_specs=[
             _smem((B,)), _smem((1,)),
@@ -306,7 +307,7 @@ def _bwd(q, k, v, do, lse, delta, start, qoff, scale, block_q, block_k,
                                lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Tp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -320,6 +321,7 @@ def _bwd(q, k, v, do, lse, delta, start, qoff, scale, block_q, block_k,
     h_of = lambda h, gi: h * group + gi // n_q
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_train_dkv",
         grid=(B, Hkv, n_k, group * n_q),
         in_specs=[
             _smem((B,)), _smem((1,)),
@@ -346,7 +348,7 @@ def _bwd(q, k, v, do, lse, delta, start, qoff, scale, block_q, block_k,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

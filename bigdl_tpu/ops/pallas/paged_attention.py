@@ -34,7 +34,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bigdl_tpu.ops.pallas import qdecode
-from bigdl_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
 
 _NEG_INF = -1e30
 
@@ -179,9 +178,10 @@ def paged_decode_attention(
             _kernel, n_kv=Hkv, group=G, page=page, n_batch=B,
             softcap=softcap, quantized=quantized,
         ),
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

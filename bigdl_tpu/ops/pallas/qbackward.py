@@ -49,16 +49,17 @@ from jax.experimental.pallas import tpu as pltpu
 from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
 from bigdl_tpu.ops.pallas.tiling import (
-    DX_ACC_BPE, chunk_target_dx, finest_split, pick_block_m,
+    DX_ACC_BPE, VMEM_LIMIT_BYTES, chunk_target_dx, finest_split, pick_block_m,
     pick_block_m_dx, pick_block_o, pick_block_o_dw, round_up,
 )
-from bigdl_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
 
 
 def _params_reduce():
     # the innermost grid axis is a sequential reduction into VMEM
     # scratch — it must not be parallelized/reordered
-    return _CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 # ---------------------------------------------------------------------------
@@ -119,6 +120,7 @@ def _dxmm(spec, out_dtype, block_m: int, block_o: int, ck: int, K: int,
     # pattern benchmark/roofline.bwd_dx_cost prices)
     return pl.pallas_call(
         functools.partial(_dx_kernel, K=K, ck=ck, spec=spec),
+        name="qmatmul_dx",
         grid=(Mp // block_m, O // block_o),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
@@ -218,6 +220,7 @@ def _dwmm(out_dtype, block_m: int, block_o: int, interpret: bool, g2, x2):
     K = x2.shape[1]
     return pl.pallas_call(
         _dw_kernel,
+        name="dw_matmul",
         grid=(Op // block_o, Mp // block_m),
         in_specs=[
             pl.BlockSpec((block_m, block_o), lambda o, m: (m, o),

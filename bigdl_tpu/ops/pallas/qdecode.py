@@ -257,13 +257,18 @@ def load_side(spec: DecodeSpec, refs):
     """Load + bit-decode the scale-side refs once per kernel invocation
     (persistent across the chunk loop). Returns the in-VMEM f32 arrays
     `decode_chunk` slices per chunk."""
+    def as_f32(ref):
+        # integer sub-scales go through int32: Mosaic has no
+        # uint8 -> float32 cast (PR 21 chip run: q2_k / q4_k / q5_k)
+        return ref[:].astype(jnp.int32).astype(jnp.float32)
+
     if spec.super_block:
         if spec.mins:
             d, dmin, sc, mn = refs
             return (f16_bits_to_f32(d[:]), f16_bits_to_f32(dmin[:]),
-                    sc[:].astype(jnp.float32), mn[:].astype(jnp.float32))
+                    as_f32(sc), as_f32(mn))
         d, sc = refs
-        return (f16_bits_to_f32(d[:]), sc[:].astype(jnp.float32))
+        return (f16_bits_to_f32(d[:]), as_f32(sc))
     if spec.mins:
         s, m = refs
         return (f16_bits_to_f32(s[:]), f16_bits_to_f32(m[:]))

@@ -10,8 +10,8 @@ ONE kernel body serves every registered qtype and every shape class:
 * decode GEMV (rows <= 32): HBM-bandwidth-bound — the win over the XLA
   fallback (dequantize to bf16, then matmul) is that W crosses HBM
   packed, e.g. 0.5 byte/weight + one f16 scale per 32 for nibble
-  formats, up to ~6x less weight traffic than bf16 (measured 2.7x
-  end-to-end on v5e, BENCH_NOTES r03);
+  formats, up to ~6x less weight traffic than bf16 (end-to-end gain on
+  the chip: not measured);
 * prefill / batched / QLoRA GEMM (rows > 32): the same weight tiles are
   dequantized ONCE per [block_m, block_o] tile in VMEM and fed straight
   to the MXU — no in-graph bf16 weight materialization, no HBM round
@@ -29,8 +29,8 @@ Layout contract (quant/numerics.py pack_nibbles / pack_planes): the
 m-th split of a b-bit plane is a *contiguous* byte range unpacked with
 one static shift — chunks walk logical elements within the finest plane
 split, so every chunk reads one contiguous, lane-aligned slice per
-plane and one slice of x (never a strided deinterleave: ~40us of XLA
-prologue per call on the old interleaved layout, v5e round 3).
+plane and one slice of x (never a strided deinterleave, which costs an
+XLA prologue per call).
 
 Mosaic constraints found on real TPU (the CPU interpreter accepts all of
 these, silently):
@@ -63,17 +63,17 @@ from jax.experimental.pallas import tpu as pltpu
 from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
 from bigdl_tpu.ops.pallas.tiling import (
-    chunk_target, finest_split, lora_operand_bytes, pick_block_m,
-    pick_block_o, round_up,
+    VMEM_LIMIT_BYTES, chunk_target, finest_split, lora_operand_bytes,
+    pick_block_m, pick_block_o, round_up,
 )
 
 BLOCK = 32  # quant block (elements per scale) for sym_int4; nf4/fp4 use 64
 
-from bigdl_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
-
 
 def _params_parallel():
-    return _CompilerParams(dimension_semantics=("parallel", "parallel"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _f16_bits(a: jax.Array) -> jax.Array:
@@ -176,6 +176,7 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
     # assumes exactly this fetch pattern)
     return pl.pallas_call(
         functools.partial(_kernel, K=K, ck=ck, spec=spec, lora=lora),
+        name="qmatmul_lora" if lora else "qmatmul",
         grid=(Mp // block_m, O // block_o),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(

@@ -19,7 +19,16 @@ from __future__ import annotations
 
 from bigdl_tpu.utils import round_up  # noqa: F401  (re-exported policy dep)
 
-VMEM_BUDGET = 10 * 1024 * 1024  # leave scoped-VMEM headroom under 16 MiB
+VMEM_BUDGET = 10 * 1024 * 1024  # what the tile policy prices, see below
+
+# Scoped-VMEM limit the matmul family asks Mosaic for. VMEM_BUDGET
+# prices every operand block ONCE; the pipeline double-buffers each
+# input and output block and the kernel body holds loaded copies
+# besides, so the compiler's stack is about twice the priced figure: on
+# a v5e the forward at block_m=128, K=14336 allocated 18.15 MiB and the
+# dx kernel 17.73 MiB against the 16 MiB default and were refused (PR
+# 21 chip run). A v5e core has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 # x row-tile slab cap: the [block_m, K] activation block must leave room
 # for the weight tile + per-chunk dequant temporaries in the budget
@@ -147,10 +156,14 @@ def pick_block_o_dw(O: int, K: int) -> int:
     op8 = round_up(max(O, 1), 8)
     if op8 <= 256 and op8 * K * DX_ACC_BPE <= _DX_SLAB_BYTES:
         return op8
-    for bo in (256, 128, 64, 32, 16):
-        if bo < op8 and bo * K * DX_ACC_BPE <= _DX_SLAB_BYTES:
-            return bo
-    return 8
+    # block_o is the LANE dim of g's [block_m, block_o] block: a tile
+    # that does not cover all of O must be a multiple of 128 (Mosaic
+    # refused the former 64/32/16 tiles at K=14336, PR 21 chip run);
+    # the 128 floor may exceed the slab allowance, which
+    # VMEM_LIMIT_BYTES absorbs
+    if 256 < op8 and 256 * K * DX_ACC_BPE <= _DX_SLAB_BYTES:
+        return 256
+    return 128
 
 
 # ---------------------------------------------------------------------------

@@ -38,7 +38,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.quant import QTensor
-from bigdl_tpu.parallel._compat import shard_map as _shard_map
 
 
 def pipeline_param_specs(params: dict, axis: str = "pp") -> dict:
@@ -134,13 +133,6 @@ def make_pipeline_step(
     def step(params, tokens, cache, mode="decode", last_logits_only=False,
              collect_obs: int = 0):
         def stage_step(params, tokens, cache):
-            # NOTE pinned-jax limitation: when the mesh composes pp with a
-            # real (size>1) tp/dp axis, 0.4.37's partial-manual shard_map
-            # cannot lower this program (axis_index -> PartitionId
-            # UNIMPLEMENTED; feeding the stage id as a pp-sharded operand
-            # instead trades that for a partitioner CHECK-fail crash, so
-            # the clean exception is the better failure). pp-only meshes
-            # (every axis but pp size 1) run fully manual and work.
             s = jax.lax.axis_index(axis)
             h0 = embed_tokens(config, params, tokens, compute_dtype)
             B, T = tokens.shape
@@ -209,7 +201,7 @@ def make_pipeline_step(
         if collect_obs:
             # obs stacks stage-local layer blocks -> global [L, B, W, Hq, D]
             out_specs = out_specs + (P(axis),)
-        return _shard_map(
+        return jax.shard_map(
             stage_step,
             mesh=mesh,
             in_specs=(pspecs, P(), pp_cache_specs(cache, axis)),
@@ -289,7 +281,7 @@ def make_pipeline_forward(
     def fn(params, tokens, start=None):
         if start is None:
             start = jnp.zeros((tokens.shape[0],), jnp.int32)
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             stage_fn,
             mesh=mesh,
             in_specs=(pipeline_param_specs(params, axis), P(), P()),

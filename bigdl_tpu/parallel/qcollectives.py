@@ -35,8 +35,8 @@ Algorithm — reduce-scatter ring + all-gather ring, both on
 ``qtype="none"`` bypasses all of this and calls ``jax.lax.psum`` /
 ``jax.lax.all_gather`` — bit-identical to the unquantized path.
 
-Everything here is device-local (runs inside `_compat.shard_map`, the
-jax-0.4.37-portable shim) and CPU-testable on the dryrun meshes.
+Everything here is device-local (runs inside `jax.shard_map`, the
+kernels' Manual-axis context) and CPU-testable on virtual devices.
 """
 
 from __future__ import annotations
@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from bigdl_tpu.parallel._compat import shard_map as _shard_map
 # the per-block symmetric codec primitives (quant/numerics.py): blocked
 # views, safe reciprocal, fp8 format ranges/dtypes
 from bigdl_tpu.quant.numerics import _FP8_DTYPE, _FP8_MAX, _safe_inv
@@ -167,7 +166,7 @@ def decode_array(data: jax.Array, scales: jax.Array, shape, dtype,
 
 
 # ---------------------------------------------------------------------------
-# device-local collectives (call inside _compat.shard_map)
+# device-local collectives (call inside jax.shard_map)
 # ---------------------------------------------------------------------------
 
 
@@ -341,7 +340,7 @@ def mesh_all_reduce(xs: jax.Array, mesh: Mesh, axis_name: str = "tp",
         )
         return red[None]
 
-    f = _shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
+    f = jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
                    check_vma=False)
     return f(xs)
 
@@ -368,7 +367,7 @@ def mesh_reduce_scatter(xs: jax.Array, mesh: Mesh, axis_name: str = "tp",
         )
         return own[None]
 
-    f = _shard_map(body, mesh=mesh, in_specs=(spec,),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(spec,),
                    out_specs=P(axis_name, None), check_vma=False)
     return f(xs).reshape(-1)
 
@@ -393,6 +392,6 @@ def mesh_all_gather(x: jax.Array, mesh: Mesh, axis_name: str = "tp",
             block_size=block_size, tiled=True,
         )
 
-    f = _shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=P(),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=P(),
                    check_vma=False)
     return f(x)

@@ -25,7 +25,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from bigdl_tpu.parallel._compat import shard_map as _shard_map
 
 _NEG_INF = -1e30
 
@@ -148,7 +147,7 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "sp", causal: bool = True,
     seq_spec = P(None, axis_name, None, None)
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec),
         out_specs=seq_spec,

@@ -5,8 +5,8 @@ llama.cpp's k-quant byte layouts interleave codes, packed sub-scales
 and fp16 super-scales inside 84..210-byte super-blocks — a CPU-SIMD
 artifact. A Pallas kernel cannot slice those byte offsets (Mosaic lane
 alignment), and XLA's in-graph byte decode materializes bf16 weights in
-HBM, measured 2.7x slower end-to-end (BENCH_NOTES r03). So on TPU a
-k-quant QTensor stores PLANES:
+HBM (its cost on the chip: not measured). So on TPU a k-quant QTensor
+stores PLANES:
 
   q2_k: data      [.., K/4]   uint8  quarter-split packed 2-bit codes
         scales    [.., K/256] f16    super-scale d

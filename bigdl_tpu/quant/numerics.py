@@ -58,12 +58,11 @@ def pack_nibbles(codes: jax.Array) -> jax.Array:
 def unpack_nibbles(packed: jax.Array) -> jax.Array:
     """[..., K//2] packed uint8 -> [..., K] uint8 codes (element order).
 
-    Written as broadcast-shift + reshape rather than
-    ``concatenate([lo, hi], -1)``: the pinned jaxlib's SPMD partitioner
-    miscompiles concatenate along a sharded axis whenever the mesh has a
-    second non-trivial axis (partial replication), which silently
-    corrupted every packed-weight dequant on dp>1 inference meshes.
-    The two spellings are bit-identical on unsharded inputs.
+    Written as broadcast-shift + reshape: one expression for any
+    number of splits (unpack_planes shares it), bit-identical to
+    ``concatenate([lo, hi], -1)``. An older jaxlib miscompiled that
+    concatenate along a sharded axis on multi-axis meshes; under jax
+    0.9.0 both spellings are right (re-tested on dp x tp meshes, PR 21).
     """
     shifts = jnp.asarray([0, 4], jnp.uint8)[:, None]
     out = (packed[..., None, :] >> shifts) & 0xF
@@ -100,9 +99,7 @@ def pack_planes(codes: jax.Array, planes: tuple) -> jax.Array:
 def unpack_planes(data: jax.Array, planes: tuple, k: int) -> jax.Array:
     """Inverse of pack_planes: concatenated planes -> [..., K] uint8.
 
-    Same broadcast-shift + reshape spelling as unpack_nibbles (instead of
-    a concatenate over the per-byte sub-element splits) — see the
-    sharded-concatenate note there.
+    Same broadcast-shift + reshape spelling as unpack_nibbles.
     """
     off = 0
     shift = 0

@@ -1,11 +1,12 @@
-"""Synthetic QTensor fields for kernel smokes and benchmarks.
+"""Synthetic quantized weights, made host-side from a seed.
 
-The fused-GEMV kernels only see packed fields; running the real
-host-side quantizer at benchmark shapes costs minutes (the k-quant
-numpy pass on a 4096x14336 weight measured ~90 s on the bench host,
-r05) while random-but-valid fields cost milliseconds and exercise the
-identical compiled program. Used by bench.py's compile-smoke stage and
-scripts/tpu_smoke.py."""
+The fused kernels only see packed fields, and a machine with a chip may
+have no network and no checkpoint. Running the real host-side quantizer
+at 7B shapes costs minutes; random-but-valid fields cost seconds and
+exercise the identical compiled program. `synth_qtensor` makes one
+weight in any registered format (the kernel matrix of
+`chip_smoke.py --kernels`); `synth_params` makes a whole llama-family
+parameter tree (`chip_smoke.py`'s model)."""
 
 from __future__ import annotations
 
@@ -89,3 +90,47 @@ def synth_qtensor(qtype: str, O: int, K: int,
             scales=scales(nb),
         )
     return QTensor(qtype=qtype, **fields)
+
+
+def synth_params(config, seed: int = 0) -> dict:
+    """Host-numpy sym_int4 parameter tree for a llama-family config, in
+    exactly the layout `models/llama.forward` expects after
+    `optimize_model(...)`: the tree's structure comes from
+    `jax.eval_shape` over the real init + quantize + merge path, so no
+    device op and no compilation runs. Packed codes are random
+    nibbles with the one unpaired code (0, value -8) moved to 8 (value
+    0), so weights have zero mean: a common offset in every weight
+    would make a rank-one term dominate the forward and every logit
+    vector look alike, whatever the prompt. Scales are sized so that a
+    dequantized weight has the init's standard deviation of about 0.02
+    and the forward stays in range through any depth; norm weights are
+    1."""
+    import jax
+
+    from bigdl_tpu.models import llama
+
+    shapes = jax.eval_shape(
+        lambda k: llama.merge_fused_params(
+            llama.quantize_params(llama.init_params(config, k), "sym_int4"),
+            config),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+    )
+    rng = np.random.default_rng(seed)
+    # code values: -7..7 uniform plus a double share of 0: std 4.18
+    scale = np.float32(0.02 / 4.18)
+
+    def leaf(path, x):
+        dt = np.dtype(x.dtype)
+        if dt == np.uint8:  # packed nibbles, code 0 -> code 8
+            b = rng.integers(0, 256, x.shape, np.uint8)
+            b |= ((b & 0x0F) == 0).astype(np.uint8) << 3
+            b |= ((b & 0xF0) == 0).astype(np.uint8) << 7
+            return b
+        if dt == np.float16:  # per-block scales
+            return (scale * rng.uniform(0.5, 1.5, x.shape).astype(np.float32)
+                    ).astype(dt)
+        if "norm" in jax.tree_util.keystr(path):
+            return np.ones(x.shape, dt)
+        return (0.02 * rng.standard_normal(x.shape, np.float32)).astype(dt)
+
+    return jax.tree_util.tree_map_with_path(leaf, shapes)

@@ -28,6 +28,7 @@ import json
 import queue
 import threading
 import time
+import traceback
 import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Optional
@@ -114,7 +115,12 @@ class _EngineThread(threading.Thread):
                 busy = self.engine.step()
             except Exception as e:  # noqa: BLE001
                 # fail everything in flight so clients unblock, then keep
-                # serving (a poisoned request must not kill the server)
+                # serving (a poisoned request must not kill the server);
+                # counted and printed, so that a server whose every step
+                # fails cannot pass for a healthy one
+                traceback.print_exc()
+                self.engine.step_errors += 1
+                self.engine.last_step_error = f"{type(e).__name__}: {e}"
                 self.engine.fail_all(f"engine error: {e}")
                 busy = False
             if not busy:

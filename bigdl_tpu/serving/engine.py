@@ -45,6 +45,15 @@ from bigdl_tpu.serving.metrics import Histogram
 from bigdl_tpu.utils import round_up
 
 
+def _named(name: str, fn, *bound):
+    """functools.partial with a name: JAX labels its compile log, its
+    monitoring events and the profiler's trace with it, so each engine
+    program is told apart (an unnamed partial is `jit(<unknown>)`)."""
+    p = functools.partial(fn, *bound)
+    p.__name__ = name
+    return p
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -472,18 +481,18 @@ class InferenceEngine:
                     "forward"
                 )
         self._decode = self._with_mesh(jax.jit(
-            functools.partial(self._decode_impl, fwd),
+            _named("engine_decode", self._decode_impl, fwd),
             donate_argnames=("cache", "seen"),
         ))
         self._prefill = self._with_mesh(jax.jit(
-            functools.partial(self._prefill_impl, fwd),
+            _named("engine_prefill", self._prefill_impl, fwd),
             static_argnames=("bucket",),
         ))
         self._insert = self._with_mesh(jax.jit(
             self._insert_impl, donate_argnames=("cache",)
         ))
         self._paged_prefill = self._with_mesh(jax.jit(
-            functools.partial(self._paged_prefill_impl, fwd),
+            _named("engine_paged_prefill", self._paged_prefill_impl, fwd),
             donate_argnames=("k", "v", "ks", "vs"),
         ))
         self._copy_page = self._with_mesh(jax.jit(
@@ -527,7 +536,7 @@ class InferenceEngine:
             # rollback a per-row pos subtraction in both pools
             self.dcache = self._make_pool(force_dense=True)
             spec_jit = jax.jit(
-                functools.partial(self._spec_decode_impl, fwd),
+                _named("engine_spec_decode", self._spec_decode_impl, fwd),
                 static_argnums=(0,),  # k_draft: ladder of compiled programs
                 donate_argnames=("cache", "dcache", "seen"),
             )
@@ -556,9 +565,7 @@ class InferenceEngine:
                 # stay valid across _reset_state (same shapes).
                 import contextlib
 
-                from bigdl_tpu.parallel._compat import set_mesh
-
-                ctx = (set_mesh(self._mesh) if self._mesh is not None
+                ctx = (jax.set_mesh(self._mesh) if self._mesh is not None
                        else contextlib.nullcontext())
                 args = (self.model.params, self._draft_params, self.cur,
                         self.cache, self.dcache, jax.random.PRNGKey(0),
@@ -639,6 +646,11 @@ class InferenceEngine:
         self.requests_shed = 0  # guarded-by: _stat_lock
         self.request_timeouts = 0  # guarded-by: _stat_lock
         self.requests_completed = 0
+        # exceptions out of step() that the caller's loop survived
+        # (api_server._EngineThread counts them here; only that thread
+        # writes) and the newest one's text
+        self.step_errors = 0
+        self.last_step_error: Optional[str] = None
         self.journal_corrupt_lines = 0  # set at journal attach below
         self.queue_wait = Histogram()
         # phase-latency histograms (docs/observability.md): observed
@@ -713,10 +725,9 @@ class InferenceEngine:
         if self._mesh is None:
             return fn
 
+        @functools.wraps(fn)  # keeps the jit reachable (__wrapped__)
         def wrapped(*a, **k):
-            from bigdl_tpu.parallel._compat import set_mesh
-
-            with set_mesh(self._mesh):
+            with jax.set_mesh(self._mesh):
                 return fn(*a, **k)
 
         return wrapped
@@ -737,32 +748,35 @@ class InferenceEngine:
         if self.paged and not force_dense:
             from bigdl_tpu import kvpaged
 
-            return kvpaged.init_paged(
+            cache = kvpaged.init_paged(
                 cfg.num_hidden_layers, self.n_pages, self.page_size,
                 cfg.num_key_value_heads, cfg.head_dim_, self.n_slots,
                 self.max_pages_per_row, quantize_kv=self.quantize_kv,
             )
-        cache = kvcache.init_cache(
-            cfg.num_hidden_layers, self.n_slots, self.max_len + self._reserve,
-            cfg.num_key_value_heads, cfg.head_dim_,
-            quantize_kv=self.quantize_kv,
-        )
-        cache = dataclasses.replace(
-            cache, pos=jnp.zeros((self.n_slots,), jnp.int32)
-        )
+        else:
+            cache = kvcache.init_cache(
+                cfg.num_hidden_layers, self.n_slots,
+                self.max_len + self._reserve,
+                cfg.num_key_value_heads, cfg.head_dim_,
+                quantize_kv=self.quantize_kv,
+            )
+            cache = dataclasses.replace(
+                cache, pos=jnp.zeros((self.n_slots,), jnp.int32)
+            )
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            # layer axis over pp stages (when present), kv heads over tp
+            # layer axis over pp stages (when present), kv heads over
+            # tp: axis 3 in both layouts (dense [L, B, S, Hkv, D], paged
+            # [L, n_pages, page, Hkv, D]); everything else replicates
             pp = "pp" if "pp" in self._mesh.axis_names else None
             kv_sh = NamedSharding(self._mesh, P(pp, None, None, "tp", None))
+            sc_sh = NamedSharding(self._mesh, P(pp, None, None, "tp"))
             rep = NamedSharding(self._mesh, P())
-            cache = dataclasses.replace(
+            cache = jax.tree.map(
+                lambda a: jax.device_put(
+                    a, {5: kv_sh, 4: sc_sh}.get(a.ndim, rep)),
                 cache,
-                k=jax.device_put(cache.k, kv_sh),
-                v=jax.device_put(cache.v, kv_sh),
-                pos=jax.device_put(cache.pos, rep),
-                start=jax.device_put(cache.start, rep),
             )
         return cache
 

@@ -265,6 +265,13 @@ class Metrics:
                 "# TYPE bigdl_tpu_request_timeouts_total counter",
                 f"bigdl_tpu_request_timeouts_total "
                 f"{self.engine.request_timeouts}",
+                "# HELP bigdl_tpu_engine_step_errors_total exceptions "
+                "raised by engine.step() and survived by the server's "
+                "worker thread (a refused kernel or a device fault looks "
+                "like a healthy server without this)",
+                "# TYPE bigdl_tpu_engine_step_errors_total counter",
+                f"bigdl_tpu_engine_step_errors_total "
+                f"{self.engine.step_errors}",
                 "# HELP bigdl_tpu_preempted_waiting preempted requests "
                 "parked in host RAM awaiting resume",
                 "# TYPE bigdl_tpu_preempted_waiting gauge",
@@ -466,6 +473,7 @@ _ENGINE_FAMILIES = (
     "bigdl_tpu_preemption_resumes_total",
     "bigdl_tpu_requests_shed_total",
     "bigdl_tpu_request_timeouts_total",
+    "bigdl_tpu_engine_step_errors_total",
     "bigdl_tpu_preempted_waiting",
     "bigdl_tpu_journal_corrupt_lines_total",
     "bigdl_tpu_queue_wait_seconds",

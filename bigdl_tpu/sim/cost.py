@@ -6,8 +6,8 @@ The modeled hardware/model pair is INDEPENDENT of the tiny model that
 produces token dynamics on CPU: the engine executes tiny-llama to keep
 every cache/scheduler path real, while each jitted call's duration is
 priced as if it were `config` (default llama2-7b) at `qtype` on an
-HBM with `hbm_gbps` — the calibration knob the next live-TPU window
-tunes against measured GB/s (BENCH_NOTES r03 discipline).
+HBM with `hbm_gbps` — a model input (the chip's published peak by
+default), not a measurement.
 
 Pricing follows the roofline: a phase costs
 ``max(bytes / HBM_BW, flops / peak)`` plus a fixed per-dispatch host
@@ -34,9 +34,9 @@ from bigdl_tpu.quant.qtypes import resolve_qtype
 class CostModel:
     config: ModelConfig
     qtype: Optional[str] = "sym_int4"  # None = dense bf16 weights
-    #: the calibration knob (docs/benchmarking.md): achievable HBM GB/s
-    #: of the modeled chip; default is v5e-class. The next live-TPU
-    #: window sets this from measured kernel GB/s (bench.py gemv_timed).
+    #: HBM GB/s of the modeled chip (docs/benchmarking.md); the default
+    #: is the v5e's published peak. What the kernels achieve on the
+    #: chip is not measured.
     hbm_gbps: float = 819.0
     #: bf16 MXU peak — the compute-bound floor of every phase
     peak_tflops: float = 197.0

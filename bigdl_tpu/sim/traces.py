@@ -248,7 +248,7 @@ def assign_adapters(trace: Trace, n_adapters: int, seed: int = 0,
 
 
 # ---------------------------------------------------------------------------
-# named mixes: the CLI / bench.py vocabulary. Sizes are chosen so every
+# named mixes: the CLI's vocabulary. Sizes are chosen so every
 # mix completes on CPU (tiny-llama token dynamics) in seconds while
 # still exercising its target path; "overload" offers ~4x the modeled
 # capacity so admission bounds, queue deadlines, preemption and shed

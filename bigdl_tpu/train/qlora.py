@@ -210,7 +210,7 @@ def make_train_step(
     ring_mesh: pass the Mesh to replace those all-gathers with ring
     attention (parallel/ring.py) — each device keeps 1/sp of the KV and
     shards rotate over ICI, making attention memory O(T/sp) for
-    long-context training. Requires an enclosing mesh context (parallel._compat.set_mesh) and
+    long-context training. Requires an enclosing mesh context (jax.set_mesh) and
     sliding_window/softcap-free attention (llama-family default).
 
     fused_backward=False traces the step with the XLA
@@ -224,7 +224,6 @@ def make_train_step(
     if ring_mesh is not None:
         from jax.sharding import PartitionSpec as P
 
-        from bigdl_tpu.parallel._compat import shard_map as _shard_map
         from bigdl_tpu.parallel.ring import ring_attention
 
         # features the ring path does not implement — fail loudly instead
@@ -250,7 +249,7 @@ def make_train_step(
                 scale=config.attn_scale, start=start,
             )
 
-        attention_override = _shard_map(
+        attention_override = jax.shard_map(
             _local,
             mesh=ring_mesh,
             in_specs=(qspec, qspec, qspec, P(batch_axis)),

@@ -63,7 +63,3 @@ def cache_slot_quantum() -> int:
 
 def native_disabled() -> bool:
     return _bool("BIGDL_TPU_DISABLE_NATIVE")
-
-
-def pallas_disabled() -> bool:
-    return _bool("BIGDL_TPU_DISABLE_PALLAS")

@@ -31,20 +31,25 @@ _CHIPS = {
 
 
 def chip_specs(device=None) -> Optional[tuple[float, float]]:
-    """(peak_flops, hbm_bytes_per_s) for the given (default: first) device,
-    or None when unknown (CPU test runs)."""
+    """(peak_flops, hbm_bytes_per_s) for the given (default: first)
+    device. None on the CPU (tests: there is no peak to compare with);
+    an accelerator whose `device_kind` is not in the table is an error,
+    not a default."""
     import jax
 
     if device is None:
-        devices = jax.devices()
-        if not devices:
-            return None
-        device = devices[0]
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
     kind = getattr(device, "device_kind", "") or ""
     for prefix, specs in _CHIPS.items():
         if kind.startswith(prefix):
             return specs
-    return None
+    raise ValueError(
+        f"no peak FLOP/s / HBM bandwidth on record for device_kind "
+        f"{kind!r} (platform {device.platform!r}); add it to "
+        "utils/flops._CHIPS with its source"
+    )
 
 
 def matmul_params(config) -> dict:

@@ -104,12 +104,6 @@ def main(argv=None) -> int:
 
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        # make the env var authoritative even where a sitecustomize
-        # force-registers another platform (CI runs this entrypoint on
-        # the virtual CPU mesh; TPU VMs leave it unset -> default tpu)
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from bigdl_tpu.parallel.health import init_multihost_with_retry
     from bigdl_tpu.parallel.multihost import host_aware_mesh
 
@@ -173,10 +167,8 @@ def main(argv=None) -> int:
     # price of an untouched optimizer after a NaN)
     step_j = jax.jit(step_fn)
 
-    from bigdl_tpu.parallel._compat import set_mesh
-
     def supervised_step(lora_t, opt_t, tokens, mask):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return step_j(params, lora_t, opt_t, tokens, mask)
 
     # hung-step detection rides the supervisor's watchdog: a lost peer

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_platforms", "cpu")
-
 from bigdl_tpu import kvcache
 from bigdl_tpu.models import llama, minicpmo, qwen2_audio
 from bigdl_tpu.models import whisper as whisper_mod

@@ -8,8 +8,6 @@ streaming for unbounded conversations.
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-
 from bigdl_tpu import ChatSession
 from bigdl_tpu.api import TpuModel, optimize_model
 from bigdl_tpu.models import llama

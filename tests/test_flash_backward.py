@@ -1,6 +1,6 @@
 """Trainable flash attention: forward and gradients vs the XLA
 attention oracle, interpret mode (the CPU stand-in for Mosaic; the
-silicon compile is covered by scripts/tpu_smoke.py)."""
+compile on the chip is covered by `chip_smoke.py --train`)."""
 
 import jax
 import jax.numpy as jnp

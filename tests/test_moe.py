@@ -114,9 +114,8 @@ def test_ragged_under_expert_parallel_mesh():
     )
     sharded = shard_params(params, param_specs(cfg), mesh)
     tokens = jnp.ones((2, 8), jnp.int32)
-    from bigdl_tpu.parallel._compat import set_mesh
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits = jax.jit(
             lambda p, t: llama.forward(cfg, p, t, None, mode="prefill")[0]
         )(sharded, tokens)

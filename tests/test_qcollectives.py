@@ -216,9 +216,9 @@ def test_tp_generate_comm_qtype_routing():
     ref = _tiny_model().generate(prompts, max_new_tokens=12)
     mesh = _tp_mesh(2)
 
-    # "none" keeps the implicit-psum path: byte-identical tokens
+    # "none" reduces through an exact psum: byte-identical tokens
     exact = _tiny_model().to_mesh(mesh, comm_qtype="none")
-    assert exact.comm is None
+    assert exact.comm is not None and not exact.comm.enabled
     out = exact.generate(prompts, max_new_tokens=12)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
 

@@ -3,8 +3,8 @@
 The fused kernels run through the Pallas interpreter on CPU and are
 diffed against the XLA dequant reference, straddling `_GEMV_MAX_ROWS`
 (the old cliff: shapes above it fell back to materializing the
-dequantized weights in-graph, the 2.7x class measured in BENCH_NOTES
-r03 for decode). All core-marked: scripts/ci.sh --core runs them.
+dequantized weights in-graph; its cost on the chip: not measured). All
+core-marked: scripts/ci.sh --core runs them.
 """
 
 import jax

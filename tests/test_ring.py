@@ -88,9 +88,8 @@ def test_train_step_with_ring_matches_dense(rng):
     tokens = jax.device_put(tokens, NamedSharding(mesh, P("dp", None)))
     mask = jnp.ones((B, T), jnp.float32)
 
-    from bigdl_tpu.parallel._compat import set_mesh
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         plain = make_train_step(config, llama.forward, optimizer, P("dp", "sp"))
         ringd = make_train_step(
             config, llama.forward, optimizer, P("dp", "sp"), ring_mesh=mesh
@@ -121,9 +120,8 @@ def test_ring_with_left_padding(rng, sp_mesh):
     ring_fn = partial(
         ring_attention, axis_name="sp", axis_size=8, causal=True, start=start
     )
-    from bigdl_tpu.parallel._compat import shard_map
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda a, b, c: ring_fn(a, b, c),
         mesh=sp_mesh, in_specs=(seq, seq, seq), out_specs=seq,
         check_vma=False,

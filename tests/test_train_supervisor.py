@@ -571,7 +571,6 @@ def test_supervised_qlora_on_dryrun_multihost_mesh(tmp_path):
     from its rotating checkpoint afterwards."""
     from bigdl_tpu.models import llama
     from bigdl_tpu.models.config import PRESETS
-    from bigdl_tpu.parallel._compat import set_mesh
     from bigdl_tpu.parallel.multihost import host_aware_mesh
     from bigdl_tpu.parallel.sharding import (
         expand_specs_for_params, lora_specs, param_specs, shard_params,
@@ -596,7 +595,7 @@ def test_supervised_qlora_on_dryrun_multihost_mesh(tmp_path):
                                      return_grad_norm=True))
 
     def supervised_step(lora_t, opt_t, tokens, mask):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return step_j(params, lora_t, opt_t, tokens, mask)
 
     rng = np.random.default_rng(0)
