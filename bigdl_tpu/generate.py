@@ -264,11 +264,12 @@ def generate_tokens(
 
     key, k0 = jax.random.split(key)
     first_logits = logits[:, -1]
-    if use_rep:
-        first_logits = apply_repetition_penalty(
-            first_logits, seen, gen.repetition_penalty
-        )
-    first = sample_token(first_logits, k0, gen)
+    with jax.named_scope("sample"):
+        if use_rep:
+            first_logits = apply_repetition_penalty(
+                first_logits, seen, gen.repetition_penalty
+            )
+        first = sample_token(first_logits, k0, gen)
     if use_rep:
         seen = seen.at[jnp.arange(B), first].set(True)
 
@@ -292,11 +293,12 @@ def generate_tokens(
         )
         key, k = jax.random.split(key)
         step_logits = logits[:, -1]
-        if use_rep:
-            step_logits = apply_repetition_penalty(
-                step_logits, seen, gen.repetition_penalty
-            )
-        nxt = sample_token(step_logits, k, gen)
+        with jax.named_scope("sample"):
+            if use_rep:
+                step_logits = apply_repetition_penalty(
+                    step_logits, seen, gen.repetition_penalty
+                )
+            nxt = sample_token(step_logits, k, gen)
         if eos is not None:
             nxt = jnp.where(done, gen.pad_token_id, nxt)
             done = done | (nxt == eos)

@@ -783,134 +783,140 @@ def forward(
         hidden, c, idx = carry
         p, lp = xs if lora is not None else (xs, None)
 
-        x = norm(hidden, p["attn_norm"], p.get("attn_norm_b"))
-        if "wqkv" in p:  # merged layout (merge_fused_params)
-            QD, KD = Hq * D, Hkv * D
-            qkv = linear(x, p["wqkv"], p.get("bqkv"), compute_dtype)
-            q, k, v = (qkv[..., :QD], qkv[..., QD:QD + KD],
-                       qkv[..., QD + KD:])
-            if lp is not None:  # lora stays keyed by the unmerged names
-                if "wq" in lp:
-                    q = q + _lora_delta(x, lp["wq"], lora_scale, compute_dtype)
-                if "wk" in lp:
-                    k = k + _lora_delta(x, lp["wk"], lora_scale, compute_dtype)
-                if "wv" in lp:
-                    v = v + _lora_delta(x, lp["wv"], lora_scale, compute_dtype)
-            q = q.reshape(B, T, Hq, D)
-            k = k.reshape(B, T, Hkv, D)
-            v = v.reshape(B, T, Hkv, D)
-        else:
-            q = proj(x, p, lp, "wq", "bq").reshape(B, T, Hq, D)
-            k = proj(x, p, lp, "wk", "bk").reshape(B, T, Hkv, D)
-            v = proj(x, p, lp, "wv", "bv").reshape(B, T, Hkv, D)
-        if config.qk_norm:
-            q = rms_norm(q, p["q_norm"], eps, offset=config.rms_norm_offset)
-            k = rms_norm(k, p["k_norm"], eps, offset=config.rms_norm_offset)
-        if use_rope:
-            if cos_local is not None:
-                is_sliding_l = sliding_flags[layer_offset + idx]
-                cos_l = jnp.where(is_sliding_l, cos_local, cos)
-                sin_l = jnp.where(is_sliding_l, sin_local, sin)
+        with jax.named_scope("norm_rope"):
+            x = norm(hidden, p["attn_norm"], p.get("attn_norm_b"))
+        with jax.named_scope("attn"):
+            if "wqkv" in p:  # merged layout (merge_fused_params)
+                QD, KD = Hq * D, Hkv * D
+                qkv = linear(x, p["wqkv"], p.get("bqkv"), compute_dtype)
+                q, k, v = (qkv[..., :QD], qkv[..., QD:QD + KD],
+                           qkv[..., QD + KD:])
+                if lp is not None:  # lora stays keyed by the unmerged names
+                    if "wq" in lp:
+                        q = q + _lora_delta(x, lp["wq"], lora_scale, compute_dtype)
+                    if "wk" in lp:
+                        k = k + _lora_delta(x, lp["wk"], lora_scale, compute_dtype)
+                    if "wv" in lp:
+                        v = v + _lora_delta(x, lp["wv"], lora_scale, compute_dtype)
+                q = q.reshape(B, T, Hq, D)
+                k = k.reshape(B, T, Hkv, D)
+                v = v.reshape(B, T, Hkv, D)
             else:
-                cos_l, sin_l = cos, sin
-            q, k = apply_rotary_emb(q, k, cos_l, sin_l, config.rope_interleaved)
-        if logn_col is not None:
-            q = q * logn_col
+                q = proj(x, p, lp, "wq", "bq").reshape(B, T, Hq, D)
+                k = proj(x, p, lp, "wk", "bk").reshape(B, T, Hkv, D)
+                v = proj(x, p, lp, "wv", "bv").reshape(B, T, Hkv, D)
+        with jax.named_scope("norm_rope"):
+            if config.qk_norm:
+                q = rms_norm(q, p["q_norm"], eps, offset=config.rms_norm_offset)
+                k = rms_norm(k, p["k_norm"], eps, offset=config.rms_norm_offset)
+            if use_rope:
+                if cos_local is not None:
+                    is_sliding_l = sliding_flags[layer_offset + idx]
+                    cos_l = jnp.where(is_sliding_l, cos_local, cos)
+                    sin_l = jnp.where(is_sliding_l, sin_local, sin)
+                else:
+                    cos_l, sin_l = cos, sin
+                q, k = apply_rotary_emb(q, k, cos_l, sin_l, config.rope_interleaved)
+            if logn_col is not None:
+                q = q * logn_col
 
-        k_scale_att = v_scale_att = None
-        if c is not None:
-            c = kvcache.update_layer(c, idx, k, v)
-            if use_flash and c.quantized:
-                # fp8 codes + scales go straight to the flash kernel,
-                # which dequantizes per block in-kernel — never a dense
-                # bf16 copy of the cache in HBM (kvcache.read_layer_raw)
-                k_att, v_att, k_scale_att, v_scale_att = \
-                    kvcache.read_layer_raw(c, idx)
-            elif not use_paged_kernel:
-                k_att, v_att = kvcache.read_layer(c, idx, compute_dtype)
-        else:
-            k_att = k.astype(compute_dtype)
-            v_att = v.astype(compute_dtype)
+        with jax.named_scope("attn"):
+            k_scale_att = v_scale_att = None
+            if c is not None:
+                c = kvcache.update_layer(c, idx, k, v)
+                if use_flash and c.quantized:
+                    # fp8 codes + scales go straight to the flash kernel,
+                    # which dequantizes per block in-kernel — never a dense
+                    # bf16 copy of the cache in HBM (kvcache.read_layer_raw)
+                    k_att, v_att, k_scale_att, v_scale_att = \
+                        kvcache.read_layer_raw(c, idx)
+                elif not use_paged_kernel:
+                    k_att, v_att = kvcache.read_layer(c, idx, compute_dtype)
+            else:
+                k_att = k.astype(compute_dtype)
+                v_att = v.astype(compute_dtype)
 
-        if use_paged_kernel:
-            from bigdl_tpu.ops.pallas import paged_decode_attention
+            if use_paged_kernel:
+                from bigdl_tpu.ops.pallas import paged_decode_attention
 
-            if config.sliding_window is None:
-                win_l = None
-            else:  # traced: sliding layers alternate within the scan
-                win_l = jnp.where(
-                    sliding_flags[layer_offset + idx],
-                    config.sliding_window, 2 ** 30,
-                ).astype(jnp.int32)
-            attn = paged_decode_attention(
-                q[:, 0], c.k, c.v, c.block_tables, idx, c.pos, c.start,
-                k_scale=c.k_scale, v_scale=c.v_scale,
-                scale=config.attn_scale,
-                softcap=config.attn_logit_softcap, window=win_l,
-            )[:, None]
-        elif attention_override is not None and c is None:
-            attn = attention_override(q, k_att, v_att, row_start)
-        elif use_flash_train:
-            from bigdl_tpu.ops.pallas import flash_attention_trainable
+                if config.sliding_window is None:
+                    win_l = None
+                else:  # traced: sliding layers alternate within the scan
+                    win_l = jnp.where(
+                        sliding_flags[layer_offset + idx],
+                        config.sliding_window, 2 ** 30,
+                    ).astype(jnp.int32)
+                attn = paged_decode_attention(
+                    q[:, 0], c.k, c.v, c.block_tables, idx, c.pos, c.start,
+                    k_scale=c.k_scale, v_scale=c.v_scale,
+                    scale=config.attn_scale,
+                    softcap=config.attn_logit_softcap, window=win_l,
+                )[:, None]
+            elif attention_override is not None and c is None:
+                attn = attention_override(q, k_att, v_att, row_start)
+            elif use_flash_train:
+                from bigdl_tpu.ops.pallas import flash_attention_trainable
 
-            attn = flash_attention_trainable(
-                q, k_att, v_att, row_start,
-                window=config.sliding_window, scale=config.attn_scale,
-            )
-        elif use_flash:
-            from bigdl_tpu.ops.pallas import flash_attention
+                attn = flash_attention_trainable(
+                    q, k_att, v_att, row_start,
+                    window=config.sliding_window, scale=config.attn_scale,
+                )
+            elif use_flash:
+                from bigdl_tpu.ops.pallas import flash_attention
 
-            attn = flash_attention(
-                q, k_att, v_att, start=row_start, q_offset=pos0,
-                window=config.sliding_window, softcap=config.attn_logit_softcap,
-                scale=config.attn_scale,
-                k_scale=k_scale_att, v_scale=v_scale_att,
-            )
-        else:
-            is_sliding = sliding_flags[layer_offset + idx]
-            mask = jnp.where(is_sliding, mask_sliding, mask_global)
-            if alibi_bias is not None:
-                mask = jnp.where(mask, alibi_bias, _NEG_INF)
-            attn = attention(
-                q, k_att, v_att, mask,
-                scale=config.attn_scale, softcap=config.attn_logit_softcap,
-            )
-        out = proj(attn.reshape(B, T, Hq * D), p, lp, "wo", "bo")
-        if config.post_attn_norm:
-            out = norm(out, p["post_attn_norm"])
-        rs = config.residual_scale
-        if config.parallel_residual:
-            # gptneox: attention and MLP both read the SAME layer input;
-            # residual adds both at once
-            mlp_in = norm(hidden, p["mlp_norm"], p.get("mlp_norm_b"))
-        else:
-            hidden = hidden + (out * rs if rs else out)
-            mlp_in = norm(hidden, p["mlp_norm"], p.get("mlp_norm_b"))
+                attn = flash_attention(
+                    q, k_att, v_att, start=row_start, q_offset=pos0,
+                    window=config.sliding_window, softcap=config.attn_logit_softcap,
+                    scale=config.attn_scale,
+                    k_scale=k_scale_att, v_scale=v_scale_att,
+                )
+            else:
+                is_sliding = sliding_flags[layer_offset + idx]
+                mask = jnp.where(is_sliding, mask_sliding, mask_global)
+                if alibi_bias is not None:
+                    mask = jnp.where(mask, alibi_bias, _NEG_INF)
+                attn = attention(
+                    q, k_att, v_att, mask,
+                    scale=config.attn_scale, softcap=config.attn_logit_softcap,
+                )
+            out = proj(attn.reshape(B, T, Hq * D), p, lp, "wo", "bo")
+        with jax.named_scope("norm_rope"):
+            if config.post_attn_norm:
+                out = norm(out, p["post_attn_norm"])
+            rs = config.residual_scale
+            if config.parallel_residual:
+                # gptneox: attention and MLP both read the SAME layer input;
+                # residual adds both at once
+                mlp_in = norm(hidden, p["mlp_norm"], p.get("mlp_norm_b"))
+            else:
+                hidden = hidden + (out * rs if rs else out)
+                mlp_in = norm(hidden, p["mlp_norm"], p.get("mlp_norm_b"))
 
-        x = mlp_in
-        if config.is_moe:
-            down = _moe_mlp(config, x, p, compute_dtype)
-        elif "w_gateup" in p:  # merged layout (merge_fused_params)
-            gu = linear(x, p["w_gateup"], p.get("b_gateup"), compute_dtype)
-            I2 = gu.shape[-1] // 2
-            gate, up = gu[..., :I2], gu[..., I2:]
-            if lp is not None:
-                if "w_gate" in lp:
-                    gate = gate + _lora_delta(x, lp["w_gate"], lora_scale,
+        with jax.named_scope("ffn"):
+            x = mlp_in
+            if config.is_moe:
+                down = _moe_mlp(config, x, p, compute_dtype)
+            elif "w_gateup" in p:  # merged layout (merge_fused_params)
+                gu = linear(x, p["w_gateup"], p.get("b_gateup"), compute_dtype)
+                I2 = gu.shape[-1] // 2
+                gate, up = gu[..., :I2], gu[..., I2:]
+                if lp is not None:
+                    if "w_gate" in lp:
+                        gate = gate + _lora_delta(x, lp["w_gate"], lora_scale,
+                                                  compute_dtype)
+                    if "w_up" in lp:
+                        up = up + _lora_delta(x, lp["w_up"], lora_scale,
                                               compute_dtype)
-                if "w_up" in lp:
-                    up = up + _lora_delta(x, lp["w_up"], lora_scale,
-                                          compute_dtype)
-            down = proj(_act(config.hidden_act, gate) * up, p, lp, "w_down", "b_down")
-        elif config.gated_mlp:
-            gate = proj(x, p, lp, "w_gate", "b_gate")
-            up = proj(x, p, lp, "w_up", "b_up")
-            down = proj(_act(config.hidden_act, gate) * up, p, lp, "w_down", "b_down")
-        else:
-            up = proj(x, p, lp, "w_up", "b_up")
-            down = proj(_act(config.hidden_act, up), p, lp, "w_down", "b_down")
-        if config.post_attn_norm:
-            down = norm(down, p["post_mlp_norm"])
+                down = proj(_act(config.hidden_act, gate) * up, p, lp, "w_down", "b_down")
+            elif config.gated_mlp:
+                gate = proj(x, p, lp, "w_gate", "b_gate")
+                up = proj(x, p, lp, "w_up", "b_up")
+                down = proj(_act(config.hidden_act, gate) * up, p, lp, "w_down", "b_down")
+            else:
+                up = proj(x, p, lp, "w_up", "b_up")
+                down = proj(_act(config.hidden_act, up), p, lp, "w_down", "b_down")
+            if config.post_attn_norm:
+                down = norm(down, p["post_mlp_norm"])
         if config.parallel_residual:
             hidden = hidden + out + down
         else:
@@ -935,7 +941,8 @@ def forward(
     else:
         if last_logits_only:
             h = h[:, -1:]
-        logits = lm_head_logits(config, params, h, compute_dtype)
+        with jax.named_scope("lm_head"):
+            logits = lm_head_logits(config, params, h, compute_dtype)
     if cache is not None:
         cache = kvcache.advance(cache, T)
     if collect_obs:

@@ -2,7 +2,10 @@
 
 - tracing.py  — bounded ring-buffer span recorder (Chrome trace-event /
   Perfetto export) + crc-suffixed per-request JSONL log + trace summary
-- profiler.py — guarded on-demand ``jax.profiler`` windows
+- profiler.py — guarded on-demand ``jax.profiler`` windows, their clock
+  tied to the recorder's
+- retrace.py  — what JAX's tracing, lowering and compiling cost the
+  thread that pays (one ``jax.monitoring`` listener per process)
 
 The serving engine (serving/engine.py) and training supervisor
 (train/supervisor.py) both record into the same :class:`TraceRecorder`

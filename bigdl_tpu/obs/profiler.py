@@ -17,9 +17,14 @@ waited, the XLA trace says *which op* the device ran meanwhile.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Callable, Optional
+
+#: the host-plane event of the profile whose start is the instant the
+#: recorder's `profiler.sync` instant was stamped
+SYNC_ANNOTATION = "bigdl_tpu.clock_sync"
 
 
 class ProfilerBusy(RuntimeError):
@@ -45,6 +50,7 @@ class ProfilerWindow:
         self._clock = clock  # window-age timestamps (WCT001: injectable)
         self.logdir: Optional[str] = None
         self.started_at: Optional[float] = None
+        self._recorder = None  # the open window's, for `profiler.stop`
 
     def _fns(self):
         if self._start_fn is not None:
@@ -53,7 +59,14 @@ class ProfilerWindow:
 
         return jp.start_trace, jp.stop_trace
 
-    def start(self, logdir: str) -> dict:
+    def start(self, logdir: str, recorder=None) -> dict:
+        """Open the window. With a `recorder` (obs.tracing.TraceRecorder)
+        the two traces get one clock: right after the profiler starts,
+        inside a `TraceAnnotation` named :data:`SYNC_ANNOTATION`, the
+        recorder's clock is read and an instant `profiler.sync` recorded
+        there. The annotation is an event of the profile's host plane,
+        so `instant.ts - annotation.start` is what to add to a profile
+        timestamp to place it among the spans."""
         if not logdir:
             raise ValueError("profiler window needs a logdir")
         with self._lock:
@@ -66,7 +79,20 @@ class ProfilerWindow:
             start(logdir)  # raises before any state flips on failure
             self.logdir = logdir
             self.started_at = self._clock()
+            self._recorder = recorder
+            if recorder is not None:
+                with self._annotation():
+                    recorder.instant("profiler.sync", logdir=logdir)
             return self.status()
+
+    def _annotation(self):
+        """The profile-side half of the clock tie; a window on stub
+        functions has no profile to annotate."""
+        if self._start_fn is not None:
+            return contextlib.nullcontext()
+        import jax.profiler as jp
+
+        return jp.TraceAnnotation(SYNC_ANNOTATION)
 
     def stop(self) -> dict:
         with self._lock:
@@ -74,6 +100,7 @@ class ProfilerWindow:
                 raise ProfilerIdle("no profiler window is open")
             _, stop = self._fns()
             logdir, t0 = self.logdir, self.started_at
+            recorder, self._recorder = self._recorder, None
             try:
                 stop()
             finally:
@@ -81,6 +108,8 @@ class ProfilerWindow:
                 # wedge every later start behind ProfilerBusy
                 self.logdir = None
                 self.started_at = None
+            if recorder is not None:
+                recorder.instant("profiler.stop", logdir=logdir)
             return {"active": False, "logdir": logdir,
                     "seconds": round(self._clock() - (t0 or 0.0), 3)}
 

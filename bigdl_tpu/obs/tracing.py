@@ -34,7 +34,10 @@ Track model: ``tid`` 0 is the engine/trainer track (``decode_step``,
 track at ``tid = rid`` with strictly sequential spans — ``queued`` →
 ``prefill`` → ``decode`` windows → ``preempted`` → more ``decode``
 windows — so nesting is trivially monotonic per track (the golden test
-asserts it).
+asserts it). A span's phases are child spans on its own track that
+partition it (``complete_parts``): ``prefill`` into ``prefill.dispatch``
+| ``first_token.sample`` | ``first_token.arm``, ``decode_step`` into
+``decode.dispatch`` | ``decode.fetch``.
 """
 
 from __future__ import annotations
@@ -110,6 +113,31 @@ class TraceRecorder:
             "tid": int(tid), "ts": int(ts * 1e6),
             "dur": max(int(dur * 1e6), 0), "args": args,
         })
+
+    def complete_parts(self, ts: float, dur: float, cuts, parts,
+                       tid: int = 0, cat: str = "engine") -> None:
+        """Child spans that partition the span `complete(_, ts, dur)`
+        recorded: `cuts` are the instants (seconds, ascending) where one
+        child ends and the next begins, `parts` one `(name, args)` more
+        than there are cuts. The edges are taken in whole microseconds
+        from the parent's own rounded start and end, so the children
+        abut, nest in the parent (`validate_nesting`) and their
+        durations sum to its duration exactly; rounding each child's
+        start and length on its own would let the last one stick out a
+        microsecond."""
+        if not self.enabled:
+            return
+        lo = int(ts * 1e6)
+        hi = lo + max(int(dur * 1e6), 0)
+        edges = [lo]
+        for c in cuts:
+            edges.append(min(max(int(c * 1e6), edges[-1]), hi))
+        edges.append(hi)
+        for (name, args), a, b in zip(parts, edges, edges[1:]):
+            self._append({
+                "name": name, "ph": "X", "cat": cat, "pid": self._pid,
+                "tid": int(tid), "ts": a, "dur": b - a, "args": args,
+            })
 
     def instant(self, name: str, ts: Optional[float] = None, tid: int = 0,
                 cat: str = "engine", **args: Any) -> None:

@@ -330,7 +330,8 @@ class ApiServer:
                             return self._json(
                                 400, {"error": "profiler start needs "
                                       "a logdir"})
-                        return self._json(200, PROFILER.start(logdir))
+                        return self._json(200, PROFILER.start(
+                            logdir, recorder=outer.tracer))
                     if action == "stop":
                         return self._json(200, PROFILER.stop())
                 except (ProfilerBusy, ProfilerIdle) as e:

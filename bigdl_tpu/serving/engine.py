@@ -40,9 +40,15 @@ import numpy as np
 from bigdl_tpu import kvcache
 from bigdl_tpu.generate import GenerationConfig, sample_token_per_row
 from bigdl_tpu.models.config import ModelConfig
+from bigdl_tpu.obs import retrace
 from bigdl_tpu.serving.faults import NULL_INJECTOR, FaultError
 from bigdl_tpu.serving.metrics import Histogram
 from bigdl_tpu.utils import round_up
+
+#: where the stepping thread can pay for a retrace: the three child spans
+#: of an admission, a decode step, and everything between them
+RETRACE_PHASES = ("prefill.dispatch", "first_token.sample",
+                  "first_token.arm", "decode_step", "other")
 
 
 def _named(name: str, fn, *bound):
@@ -665,6 +671,18 @@ class InferenceEngine:
         # folding it into queue_wait would hide preemption stalls inside
         # the admission-wait signal operators alert on
         self.resume_wait = Histogram()
+        # what JAX's tracing, lowering and compiling-or-loading cost the
+        # stepping thread, by the phase that paid (obs/retrace.py;
+        # always on, like every counter). Only the stepping thread
+        # writes; a scrape reads whole values.
+        retrace.install()
+        self.retrace_seconds = dict.fromkeys(RETRACE_PHASES, 0.0)
+        self.retraces = dict.fromkeys(RETRACE_PHASES, 0)
+        self._rt_acc: Optional[retrace.Accumulator] = None
+        self._rt_seconds = 0.0
+        self._rt_programs = 0
+        self._chunk_retrace_s = 0.0  # paid by earlier chunks of the one
+        # chunked prefill in flight; its `prefill.dispatch` span reports it
         # swap-in programs (swap-OUT is a plain device_get, no jit). The
         # donated cache makes the restore an in-place scatter. Family
         # caches (nested pools / property pos) have no row-swap story:
@@ -883,12 +901,14 @@ class InferenceEngine:
         last = logits[:, -1]
         # all-default batches (every penalty 1.0) skip the O(slots x V)
         # rewrite, mirroring sample_token_per_row's all-greedy guard
-        step = jax.lax.cond(
-            jnp.any(penalty != 1.0),
-            lambda: apply_repetition_penalty(last, seen, penalty),
-            lambda: last,
-        )
-        nxt = sample_token_per_row(step, key, temp, topk, topp, dosample)
+        with jax.named_scope("sample"):
+            step = jax.lax.cond(
+                jnp.any(penalty != 1.0),
+                lambda: apply_repetition_penalty(last, seen, penalty),
+                lambda: last,
+            )
+            nxt = sample_token_per_row(step, key, temp, topk, topp,
+                                       dosample)
         # chosen-token logprob without materializing [B, V] log-softmax:
         # gather the logit, subtract the row's logsumexp
         step32 = step.astype(jnp.float32)
@@ -1435,6 +1455,7 @@ class InferenceEngine:
         st = self._prefilling
         if st is None:
             return
+        self._retrace_mark("other")  # this step's sweeps and admissions
         prompt = st.req.prompt
         rem = len(prompt) - st.written
         n = min(st.chunk, rem)
@@ -1455,6 +1476,7 @@ class InferenceEngine:
         )
         st.written += n
         if not last:
+            self._chunk_retrace_s += self._retrace_mark("prefill.dispatch")
             return
         slot = st.slot
         self._prefilling = None
@@ -2096,12 +2118,30 @@ class InferenceEngine:
             return "queue_deadline_s"
         return None
 
+    def _retrace_mark(self, phase: str) -> float:
+        """Book to `phase` what JAX traced, lowered and compiled or
+        loaded on this thread since the last mark; returns those
+        seconds. No clock and, where nothing was traced, no write."""
+        acc = retrace.thread_accumulator()
+        if acc is not self._rt_acc:  # the first mark, or another thread
+            # took over the stepping: what it paid before is not ours
+            self._rt_acc = acc
+            self._rt_seconds, self._rt_programs = acc.seconds, acc.programs
+            return 0.0
+        paid = acc.seconds - self._rt_seconds
+        if paid:
+            self.retrace_seconds[phase] += paid
+            self.retraces[phase] += acc.programs - self._rt_programs
+            self._rt_seconds, self._rt_programs = acc.seconds, acc.programs
+        return paid
+
     def _mark_admitted(self, req: Request) -> None:
         """Stamp the request's (first) admission: the moment it left the
         queue and prefill work began. queue_wait therefore measures pure
         waiting — prefill time is its own phase (prefill_seconds and the
         "prefill" span) — and the "queued" span ends exactly where the
         prefill span starts."""
+        self._retrace_mark("other")  # the admission's phases start here
         if req.admit_ts is not None:
             return
         req.admit_ts = self._clock()
@@ -2115,6 +2155,13 @@ class InferenceEngine:
     def _activate(self, slot: int, req: Request, logits_last) -> None:
         """Shared post-prefill bookkeeping: sample the first token, arm
         the slot's sampling params, emit."""
+        tr = self.tracer
+        rt_dispatch = self._chunk_retrace_s + self._retrace_mark(
+            "prefill.dispatch")
+        self._chunk_retrace_s = 0.0
+        t_enter = t_sampled = None
+        if tr is not None and tr.enabled:
+            t_enter = self._clock()
         temp, topk, topp, dosample = self._slot_sampling(req)
         penalty = (req.repetition_penalty
                    if req.repetition_penalty is not None
@@ -2141,6 +2188,10 @@ class InferenceEngine:
             jnp.asarray([topp], jnp.float32),
             jnp.asarray([dosample], jnp.bool_),
         )[0])
+        # the first host sync: the prefill program has run by now
+        rt_sample = self._retrace_mark("first_token.sample")
+        if t_enter is not None:
+            t_sampled = self._clock()
         self.cur = self.cur.at[slot].set(first)
         eos = (req.eos_token_id if req.eos_token_id is not None
                else self.gen.eos_token_id)
@@ -2168,13 +2219,28 @@ class InferenceEngine:
         # first emit — the request track stays monotonically nested:
         # queued | prefill | decode windows ...
         now = self._clock()
+        rt_arm = self._retrace_mark("first_token.arm")
         if req.admit_ts is not None:
             self.prefill_seconds.observe(now - req.admit_ts)
-            tr = self.tracer
             if tr is not None and tr.enabled:
                 tr.complete("prefill", req.admit_ts, now - req.admit_ts,
                             tid=req.rid, cat="request", rid=req.rid,
-                            prompt_tokens=len(req.prompt))
+                            prompt_tokens=len(req.prompt),
+                            # the streams this admission stalled
+                            occupancy=int(self.active.sum()) - 1,
+                            queue_depth=self._queue.qsize())
+                if t_sampled is not None:
+                    tr.complete_parts(
+                        req.admit_ts, now - req.admit_ts,
+                        (t_enter, t_sampled),
+                        (("prefill.dispatch",
+                          {"rid": req.rid, "prompt_tokens": len(req.prompt),
+                           "retrace_s": rt_dispatch}),
+                         ("first_token.sample",
+                          {"rid": req.rid, "retrace_s": rt_sample}),
+                         ("first_token.arm",
+                          {"rid": req.rid, "retrace_s": rt_arm})),
+                        tid=req.rid, cat="request")
         self._emit(slot, first, first_lp, first_top)
 
     def _admit_dense(self, req: Request, slot: int) -> None:
@@ -2622,6 +2688,7 @@ class InferenceEngine:
         self._rng, k = jax.random.split(self._rng)
         if self.speculative:
             return self._step_speculative(k)
+        self._retrace_mark("other")
         t0 = self._clock()
         try:
             nxt, lps, top, self.cache, self.seen = self._decode(
@@ -2637,6 +2704,7 @@ class InferenceEngine:
             self.fail_all("decode step failed")
             self._reset_state()
             raise
+        dispatched = self._stamp_dispatched()
         self.cur = nxt
         toks = np.asarray(nxt)
         lps_h = self._inject_nan(np.asarray(lps))
@@ -2645,7 +2713,7 @@ class InferenceEngine:
             tops_h = (np.asarray(top[0]), np.asarray(top[1]))
         # the np.asarray fetches above are the host sync: the step's
         # device work is really done here, so the duration is honest
-        self._note_decode_step(t0)
+        self._note_decode_step(t0, dispatched)
         for i in np.nonzero(self.active)[0]:
             i = int(i)
             s = self._slots[i]
@@ -2670,18 +2738,35 @@ class InferenceEngine:
             self._emit(i, int(toks[i]), float(lps_h[i]), alt)
         return True
 
-    def _note_decode_step(self, t0: float) -> None:
+    def _stamp_dispatched(self) -> Optional[tuple]:
+        """While tracing, (clock, retrace seconds so far in the step) at
+        the instant the step's program was enqueued: where the
+        `decode.dispatch` span ends and `decode.fetch` begins."""
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            return self._clock(), self._retrace_mark("decode_step")
+        return None
+
+    def _note_decode_step(self, t0: float,
+                          dispatched: Optional[tuple] = None) -> None:
         """Per-step phase accounting: duration histogram + the engine
         track's span/occupancy counter (tid 0 — batch-level, not
         per-request)."""
         t1 = self._clock()
         self.decode_step_seconds.observe(t1 - t0)
+        rt_rest = self._retrace_mark("decode_step")
         tr = self.tracer
         if tr is not None and tr.enabled:
             busy = int(self.active.sum())
             tr.complete("decode_step", t0, t1 - t0, tid=0, cat="engine",
                         occupancy=busy, slots=self.n_slots,
                         queue_depth=self._queue.qsize())
+            if dispatched is not None:
+                tr.complete_parts(
+                    t0, t1 - t0, (dispatched[0],),
+                    (("decode.dispatch", {"retrace_s": dispatched[1]}),
+                     ("decode.fetch", {"retrace_s": rt_rest})),
+                    tid=0, cat="engine")
             tr.counter("batch", ts=t1, occupancy=busy,
                        queued=self._queue.qsize(),
                        preempted=len(self._preempted))
@@ -2700,6 +2785,7 @@ class InferenceEngine:
             # slot, but adapter engines never build them (_spec_exec
             # stays None — the jit path retraces per tree structure)
             kw["lora"] = self._gather_blora()
+        self._retrace_mark("other")
         t0 = self._clock()
         try:
             (choice, lp_all, n_acc, cur2, self.cache, self.dcache,
@@ -2715,11 +2801,12 @@ class InferenceEngine:
             self.fail_all("speculative decode step failed")
             self._reset_state()
             raise
+        dispatched = self._stamp_dispatched()
         self.cur = cur2
         choice_h = np.asarray(choice)
         lp_h = self._inject_nan(np.asarray(lp_all))
         n_acc_h = np.asarray(n_acc)
-        self._note_decode_step(t0)
+        self._note_decode_step(t0, dispatched)
         self.spec_rounds += 1
         if self.adaptive_draft:
             self._adapt_draft_k(n_acc_h[self.active])

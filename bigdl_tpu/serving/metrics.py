@@ -342,6 +342,29 @@ class Metrics:
                 "preempted requests' host-RAM parked time until resume "
                 "(not folded into queue_wait)",
             )
+            # what un-jitted JAX calls cost the step loop (obs/retrace.py,
+            # docs/observability.md §3): a phase whose seconds grow with
+            # every request is retraced per request
+            lines += [
+                "# HELP bigdl_tpu_retrace_seconds_total seconds the step "
+                "loop's thread spent in JAX tracing, lowering and "
+                "compiling or loading programs, by the phase that paid",
+                "# TYPE bigdl_tpu_retrace_seconds_total counter",
+            ]
+            lines += [
+                f'bigdl_tpu_retrace_seconds_total{{phase="{phase}"}} '
+                f"{secs:.6f}"
+                for phase, secs in self.engine.retrace_seconds.items()
+            ]
+            lines += [
+                "# HELP bigdl_tpu_retraces_total programs the step loop's "
+                "thread built or loaded from the compile cache, by phase",
+                "# TYPE bigdl_tpu_retraces_total counter",
+            ]
+            lines += [
+                f'bigdl_tpu_retraces_total{{phase="{phase}"}} {n}'
+                for phase, n in self.engine.retraces.items()
+            ]
             lines += [
                 # chunked prefill (docs/serving.md §6): one count per
                 # prefill dispatch — a monolithic prefill is 1 chunk
@@ -486,6 +509,8 @@ _ENGINE_FAMILIES = (
     "bigdl_tpu_prefill_seconds",
     "bigdl_tpu_decode_step_seconds",
     "bigdl_tpu_resume_wait_seconds",
+    "bigdl_tpu_retrace_seconds_total",
+    "bigdl_tpu_retraces_total",
     "bigdl_tpu_prefill_chunks_total",
 )
 

@@ -106,7 +106,8 @@ def test_trace_export_golden(model, tmp_path):
     # tid 0 is RESERVED for the engine track: rids start at 1, so no
     # request's lifecycle spans can interleave with decode_step spans
     assert min(r.rid for r in reqs) >= 1
-    assert all(e["name"] in ("decode_step", "batch")
+    assert all(e["name"] in ("decode_step", "decode.dispatch",
+                             "decode.fetch", "batch")
                for e in events if e["tid"] == 0 and e["ph"] != "M")
     # every request has its own track with a queued->prefill sequence
     for r in reqs:
@@ -281,6 +282,201 @@ def test_ttft_itl_under_injected_slow_step(model):
 
 
 # ---------------------------------------------------------------------------
+# phase spans: an admission and a decode step, partitioned (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+ADMISSION_PARTS = ["prefill.dispatch", "first_token.sample",
+                   "first_token.arm"]
+STEP_PARTS = ["decode.dispatch", "decode.fetch"]
+
+ENGINES = {
+    "dense": {},
+    "paged": {"paged": True, "page_size": 8},
+    # every prompt below takes three chunks, with decode steps between
+    "paged-chunked": {"paged": True, "page_size": 8,
+                      "prefill_chunk_tokens": 8},
+    "speculative": {"paged": True, "page_size": 8, "speculative": True,
+                    "draft_k": 3},
+}
+
+
+def _engine(model, kind, **kw):
+    opts = dict(ENGINES[kind])
+    if opts.get("speculative"):
+        opts["draft_params"] = model.params
+    return InferenceEngine(model, n_slots=2, max_len=128, **opts, **kw)
+
+
+def _serve(eng, n=3):
+    reqs = [eng.submit(list(range(1 + i, 21 + i)), max_new_tokens=5)
+            for i in range(n)]
+    eng.run_until_idle()
+    assert all(r.done for r in reqs)
+    return reqs
+
+
+def _children(events, parent):
+    """The spans on `parent`'s track that lie inside it, in time order."""
+    lo, hi = parent["ts"], parent["ts"] + parent["dur"]
+    return sorted((e for e in events if e.get("ph") == "X"
+                   and e is not parent and e["tid"] == parent["tid"]
+                   and lo <= e["ts"] and e["ts"] + e["dur"] <= hi),
+                  key=lambda e: (e["ts"], e["ts"] + e["dur"]))
+
+
+def _abut_and_fill(parent, kids):
+    assert kids[0]["ts"] == parent["ts"]
+    for a, b in zip(kids, kids[1:]):
+        assert a["ts"] + a["dur"] == b["ts"]
+    assert sum(k["dur"] for k in kids) == parent["dur"]  # within 3 us: 0
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_phase_spans_partition_admission_and_step(model, kind):
+    """Every `prefill` span holds exactly `prefill.dispatch`,
+    `first_token.sample`, `first_token.arm`, every `decode_step` holds
+    `decode.dispatch` then `decode.fetch`; the children abut, sum to
+    their parent and carry the retrace seconds paid inside them. The
+    parents keep what they had (bench/ reads them) and gain the
+    admission's occupancy and queue depth."""
+    tr = TraceRecorder(enabled=True)
+    eng = _engine(model, kind, tracer=tr)
+    reqs = _serve(eng)
+    eng.close()
+    events = tr.events()
+    assert validate_nesting(events) == []
+    spans = [e for e in events if e.get("ph") == "X"]
+
+    prefills = [e for e in spans if e["name"] == "prefill"]
+    assert sorted(e["tid"] for e in prefills) == sorted(r.rid for r in reqs)
+    for p in prefills:
+        kids = [k for k in _children(events, p)
+                if k["name"] in ADMISSION_PARTS]
+        assert [k["name"] for k in kids] == ADMISSION_PARTS
+        _abut_and_fill(p, kids)
+        assert all(k["cat"] == "request" and k["args"]["rid"] == p["tid"]
+                   and k["args"]["retrace_s"] >= 0 for k in kids)
+        assert kids[0]["args"]["prompt_tokens"] == 20
+        # the un-jitted first-token sampling is retraced for every request
+        assert kids[1]["args"]["retrace_s"] > 0
+        assert set(p["args"]) == {"rid", "prompt_tokens", "occupancy",
+                                  "queue_depth"}
+        assert 0 <= p["args"]["occupancy"] < eng.n_slots
+    # the third request was admitted while the first two were decoding
+    assert max(p["args"]["occupancy"] for p in prefills) >= 1
+    assert max(p["args"]["queue_depth"] for p in prefills) >= 1
+
+    steps = [e for e in spans if e["name"] == "decode_step"]
+    assert steps
+    for s in steps:
+        kids = _children(events, s)
+        assert [k["name"] for k in kids] == STEP_PARTS
+        _abut_and_fill(s, kids)
+        assert set(s["args"]) == {"occupancy", "slots", "queue_depth"}
+    if kind == "paged-chunked":  # `prefill.dispatch` covers every chunk,
+        # and the steps that ran between them
+        first = min(prefills, key=lambda e: e["ts"])
+        later = [p for p in prefills if p is not first]
+        assert any(p["ts"] <= s["ts"] < p["ts"] + p["dur"]
+                   for p in later for s in steps)
+
+
+class _CountingClock:
+    def __init__(self):
+        self.calls = 0
+
+    def __call__(self):
+        self.calls += 1
+        return time.time()
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged-chunked", "speculative"])
+def test_tracing_off_costs_no_clock_call(model, kind):
+    """The off-cost, counted: with no recorder and with a disabled one
+    the engine asks its clock equally often over the same requests, and
+    nothing is recorded; turned on, it asks exactly twice more per
+    admission and once more per decode step (the children's inner
+    edges; every other edge is a stamp the engine already took)."""
+    calls = {}
+    for name, tracer in (("none", None),
+                         ("disabled", TraceRecorder(enabled=False)),
+                         ("enabled", TraceRecorder(enabled=True))):
+        clock = _CountingClock()
+        eng = _engine(model, kind, tracer=tracer, clock=clock)
+        _serve(eng)
+        eng.close()
+        calls[name] = clock.calls
+        if name == "disabled":
+            assert tracer.events() == []
+    assert calls["none"] == calls["disabled"]
+    names = [e["name"] for e in tracer.events() if e.get("ph") == "X"]
+    assert calls["enabled"] - calls["none"] == \
+        2 * names.count("prefill") + names.count("decode_step")
+
+
+def test_retrace_counter_books_to_the_phase_that_paid(model):
+    """A program traced inside the decode call lands in `decode_step`
+    and nowhere else; the per-request retrace of the first-token sampling
+    lands in `first_token.sample`; a second engine in the process does
+    not register a second listener (the seconds are not doubled); and
+    /metrics renders both families with no drift."""
+    import jax.monitoring as mon
+    import jax.numpy as jnp
+
+    from bigdl_tpu.obs import retrace
+
+    eng = InferenceEngine(model, n_slots=2, max_len=128)
+    other = InferenceEngine(model, n_slots=1, max_len=64)  # installs again
+    seen = []
+
+    def listener(event, secs, **kw):
+        if event in (retrace.TRACE, retrace.LOWER, retrace.COMPILE):
+            seen.append(secs)
+
+    mon.register_event_duration_secs_listener(listener)
+    try:
+        eng.submit([5, 4, 3, 2, 1], max_new_tokens=4)
+        eng.step()  # admission and the first decode step
+        assert eng.retrace_seconds["first_token.sample"] > 0
+        assert eng.retraces["first_token.sample"] >= 1
+        before = dict(eng.retrace_seconds)
+        n_before = dict(eng.retraces)
+
+        decode, x = eng._decode, jnp.arange(7)
+
+        def retracing_decode(*a, **k):
+            jax.jit(lambda v: v * 3 + 1)(x)  # one fresh program
+            return decode(*a, **k)
+
+        eng._decode = retracing_decode
+        del seen[:]
+        eng.step()
+        eng._decode = decode
+        paid = eng.retrace_seconds["decode_step"] - before["decode_step"]
+        assert paid > 0
+        assert paid == pytest.approx(sum(seen), rel=1e-6)  # once, not twice
+        assert eng.retraces["decode_step"] == n_before["decode_step"] + 1
+        for phase in ADMISSION_PARTS:
+            assert eng.retrace_seconds[phase] == before[phase]
+    finally:
+        mon.unregister_event_duration_listener(listener)
+    eng.run_until_idle()
+    assert other.retrace_seconds == dict.fromkeys(other.retrace_seconds, 0.0)
+
+    text = Metrics(eng).render()
+    assert metric_drift(text, eng) == ([], [])
+    for phase in eng.retrace_seconds:
+        assert _metric_value(
+            text, f'bigdl_tpu_retrace_seconds_total{{phase="{phase}"}}'
+        ) == pytest.approx(eng.retrace_seconds[phase], abs=1e-6)
+        assert _metric_value(
+            text, f'bigdl_tpu_retraces_total{{phase="{phase}"}}'
+        ) == eng.retraces[phase]
+    eng.close()
+    other.close()
+
+
+# ---------------------------------------------------------------------------
 # tracing-disabled overhead guard (< 2% on a synthetic step loop)
 # ---------------------------------------------------------------------------
 
@@ -384,6 +580,35 @@ def test_profiler_window_guards():
     with pytest.raises(RuntimeError, match="xla said no"):
         win2.stop()
     assert not win2.status()["active"]
+
+
+def test_profiler_window_ties_its_clock_to_the_recorder():
+    """`start(..., recorder=r)` leaves ONE `profiler.sync` instant on
+    the recorder's clock (the profile-side annotation is entered only
+    with the real profiler), `stop` a `profiler.stop`; a disabled
+    recorder records neither, and no recorder is the old behaviour."""
+    t = {"now": 500.0}
+
+    def clock():
+        t["now"] += 1.0
+        return t["now"]
+
+    tr = TraceRecorder(enabled=True, clock=clock)
+    win = ProfilerWindow(start_fn=lambda d: None, stop_fn=lambda: None)
+    win.start("/tmp/prof-sync", recorder=tr)
+    sync = [e for e in tr.events() if e["name"] == "profiler.sync"]
+    assert len(sync) == 1 and sync[0]["ph"] == "i"
+    assert sync[0]["args"] == {"logdir": "/tmp/prof-sync"}
+    assert sync[0]["ts"] == 501 * 10**6  # the recorder's clock, read once
+    win.stop()
+    names = [e["name"] for e in tr.events()]
+    assert names == ["profiler.sync", "profiler.stop"]
+    win.start("/tmp/prof-sync")  # no recorder: nothing more is recorded
+    win.stop()
+    off = TraceRecorder(enabled=False)
+    win.start("/tmp/prof-sync", recorder=off)
+    win.stop()
+    assert len(tr.events()) == 2 and off.events() == []
 
 
 def test_profiler_start_failure_leaves_idle():
