@@ -295,9 +295,9 @@ def decode_attention_cost(pos, page: int, Hq: int, Hkv: int, D: int,
     ints — the engine's cache.pos for the active slots).
 
     Paged (ops/pallas/paged_attention): grid (B, max_pages), one
-    (page, Hkv, D) k and v tile per live page — pages past
-    ceil(pos/page) all map to the scratch sink page 0, whose single tile
-    stays HBM-resident, so the traffic model counts live pages only.
+    (page, Hkv, D) k and v tile per live page — on a step outside a
+    row's live range the index maps name the block already held and the
+    body is skipped, so the traffic model counts live pages only.
     Dense: each row streams its [max_len] cache rows (the dense decode
     path has no page table to skip dead slots by block). fp8 KV halves
     code bytes and adds the f32 per-(slot, head) scale planes."""

@@ -98,6 +98,15 @@ def init_paged(
     )
 
 
+def live_rows(cache: PagedKVCache) -> jax.Array:
+    """[B] bool: rows whose block table maps a page at all. Physical
+    page 0 is the scratch sink (`PagePool` never hands it out) and a
+    released slot's row points EVERY entry there while its `pos` keeps
+    advancing, so `pos` alone would call ever more of the sink live. The
+    paged decode kernel spends nothing on a row this says is idle."""
+    return jnp.any(cache.block_tables != 0, axis=1)
+
+
 # ---------------------------------------------------------------------------
 # Host-side page accounting (serving/engine.py + serving/radix.py)
 # ---------------------------------------------------------------------------

@@ -699,7 +699,7 @@ def forward(
     # Paged decode reads KV pages in place via the Pallas paged-attention
     # kernel — the XLA path would gather every page into a dense [B, S]
     # copy per step (3x the HBM traffic; kvpaged.py docstring).
-    from bigdl_tpu.kvpaged import PagedKVCache
+    from bigdl_tpu.kvpaged import PagedKVCache, live_rows
 
     use_paged_kernel = (
         isinstance(cache, PagedKVCache) and mode == "decode" and T == 1
@@ -712,6 +712,7 @@ def forward(
     att_detail = f"mode={mode} B{B} T{T}"
     if use_paged_kernel:
         routes.note("attention", "pallas:paged", att_detail)
+        row_live = live_rows(cache)  # the table does not change in here
     elif use_flash_train:
         routes.note("attention", "pallas:flash_train", att_detail)
     elif use_flash:
@@ -851,6 +852,7 @@ def forward(
                     k_scale=c.k_scale, v_scale=c.v_scale,
                     scale=config.attn_scale,
                     softcap=config.attn_logit_softcap, window=win_l,
+                    live=row_live,
                 )[:, None]
             elif attention_override is not None and c is None:
                 attn = attention_override(q, k_att, v_att, row_start)

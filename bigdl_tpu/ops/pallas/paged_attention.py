@@ -19,9 +19,14 @@ each row's pages straight from the pool:
 - GQA: q reshapes to [Hkv, G, D] and both dots batch over the kv head
   axis, so all query heads of a row are served by one page DMA.
 
-Stale pages (entries past the row's allocation point at physical page 0,
-the engine's scratch sink) are read but fully masked; a fully-masked
-page contributes exp-weights of exactly 0, not a poisoned max.
+Only LIVE pages cost anything. Row b's valid slots are
+max(start_b, pos_b - window + 1) .. pos_b, so its live pages are one range
+first_b .. last_b (`live_page_range`), and a row the caller marks idle
+has none. On a grid step outside the range the body is skipped (m / l /
+acc stand) and the K / V / scale index maps clamp p into the range
+(`clamped_page`), so the step asks for the block the pipeline already
+holds and no DMA is issued: a dead page is neither loaded nor used,
+whatever it holds. A row with no live page writes zeros (l == 0).
 """
 
 from __future__ import annotations
@@ -36,6 +41,30 @@ from jax.experimental.pallas import tpu as pltpu
 from bigdl_tpu.ops.pallas import qdecode
 
 _NEG_INF = -1e30
+_NO_WINDOW = 2 ** 30
+
+
+def live_page_range(pos, start, window, page: int, max_pages: int,
+                    live=None):
+    """(first, last) logical pages of each row that hold a valid slot,
+    both inside 0 .. max_pages - 1. The valid slots are
+    max(start, pos - window + 1) .. pos; `first > last` says the row has
+    none (`start > pos`, or `live` false: then (1, 0), which
+    `clamped_page` still turns into the in-range page 0)."""
+    lo = jnp.maximum(start, pos - window + 1)
+    first = jnp.clip(lo // page, 0, max_pages - 1)
+    last = jnp.clip(pos // page, 0, max_pages - 1)
+    if live is not None:
+        first = jnp.where(live, first, 1)
+        last = jnp.where(live, last, 0)
+    return first, last
+
+
+def clamped_page(p, first, last):
+    """The logical page grid step `p` points its DMA at: `p` inside
+    first .. last, the nearer end outside it, so that consecutive dead
+    steps name the block already held."""
+    return jnp.minimum(jnp.maximum(p, first), last)
 
 
 def _kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, *refs,
@@ -55,49 +84,54 @@ def _kernel(bt_ref, meta_ref, q_ref, k_ref, v_ref, *refs,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].reshape(n_kv, group, -1).astype(jnp.float32)
-    # shared KV decode body (qdecode.decode_kv): pages stay TYPED fp8
-    # here — bitcasting the [L, n_pages, ...] pool per decode step would
-    # copy it in HBM — so decode_kv takes its typed-fp8 arm, exact and
-    # bit-identical to the uint8 bit-decode arm the flash wrapper uses
-    k = qdecode.decode_kv(
-        k_ref[0, 0], ks_ref[0, 0][..., None] if quantized else None
-    )  # [page, Hkv, D]
-    v = qdecode.decode_kv(
-        v_ref[0, 0], vs_ref[0, 0][..., None] if quantized else None
-    )
+    first_b = meta_ref[2 + 2 * n_batch + b]
+    last_b = meta_ref[2 + 3 * n_batch + b]
 
-    # scores [Hkv, G, page], both dots batched over the kv-head axis
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32,
-    )
-    if softcap is not None:
-        s = jnp.tanh(s / softcap) * softcap
+    @pl.when((p >= first_b) & (p <= last_b))
+    def _live_page():
+        pos_b = meta_ref[2 + b]
+        start_b = meta_ref[2 + n_batch + b]
+        win = meta_ref[1]  # traced per-layer sliding window (2**30 = none)
+        q = q_ref[0].reshape(n_kv, group, -1).astype(jnp.float32)
+        # shared KV decode body (qdecode.decode_kv): pages stay TYPED fp8
+        # here — bitcasting the [L, n_pages, ...] pool per decode step would
+        # copy it in HBM — so decode_kv takes its typed-fp8 arm, exact and
+        # bit-identical to the uint8 bit-decode arm the flash wrapper uses
+        k = qdecode.decode_kv(
+            k_ref[0, 0], ks_ref[0, 0][..., None] if quantized else None
+        )  # [page, Hkv, D]
+        v = qdecode.decode_kv(
+            v_ref[0, 0], vs_ref[0, 0][..., None] if quantized else None
+        )
 
-    # validity of this page's slots for row b: start <= slot <= pos
-    # (pos is the slot the current token was just written to)
-    pos_b = meta_ref[2 + b]
-    start_b = meta_ref[2 + n_batch + b]
-    win = meta_ref[1]  # traced per-layer sliding window (2**30 = none)
-    slot = p * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    valid = (slot >= start_b) & (slot <= pos_b) & (slot > pos_b - win)
-    s = jnp.where(valid, s, _NEG_INF)
+        # scores [Hkv, G, page], both dots batched over the kv-head axis
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (1,))),
+            preferred_element_type=jnp.float32,
+        )
+        if softcap is not None:
+            s = jnp.tanh(s / softcap) * softcap
 
-    m_prev = m_ref[:]  # [Hkv, G, 1-padded lanes]
-    m_cur = jnp.max(s, axis=2, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    # exp-weights of masked slots are exactly 0 (a fully-masked page
-    # must contribute nothing, even while m is still -inf)
-    pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
+        # validity of this page's slots for row b: start <= slot <= pos
+        # (pos is the slot the current token was just written to)
+        slot = p * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        valid = (slot >= start_b) & (slot <= pos_b) & (slot > pos_b - win)
+        s = jnp.where(valid, s, _NEG_INF)
 
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(pexp, axis=2, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        pexp, v, (((2,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[:] = m_new
+        m_prev = m_ref[:]  # [Hkv, G, 1-padded lanes]
+        m_cur = jnp.max(s, axis=2, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        # exp-weights of masked slots are exactly 0 (a fully-masked page
+        # must contribute nothing, even while m is still -inf)
+        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(pexp, axis=2, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            pexp, v, (((2,), (0,)), ((0,), (1,))),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[:] = m_new
 
     @pl.when(p == mp - 1)
     def _finish():
@@ -123,9 +157,12 @@ def paged_decode_attention(
     scale: float | None = None,
     softcap: float | None = None,
     window=None,  # traced per-layer sliding window; None = unbounded
+    live: jax.Array | None = None,  # [B] bool; None = every row is live
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Returns [B, Hq, D] attention over each row's pages, in place."""
+    """Returns [B, Hq, D] attention over each row's pages, in place.
+    A row that `live` marks idle costs neither DMA nor compute and
+    comes back as zeros."""
     from bigdl_tpu.ops.pallas import interpret_mode
 
     if interpret is None:
@@ -138,16 +175,23 @@ def paged_decode_attention(
     sc = scale if scale is not None else D ** -0.5
     q = q.astype(jnp.float32) * sc  # q block is tiny; keep full precision
 
-    win = jnp.asarray(2 ** 30 if window is None else window, jnp.int32)
+    win = jnp.asarray(_NO_WINDOW if window is None else window, jnp.int32)
+    pos = pos.astype(jnp.int32)
+    start = start.astype(jnp.int32)
+    first, last = live_page_range(pos, start, win, page, mp, live)
     meta = jnp.concatenate([
         jnp.reshape(layer, (1,)).astype(jnp.int32), win[None],
-        pos.astype(jnp.int32), start.astype(jnp.int32),
+        pos, start, first, last,
     ])
+
+    def phys(b, p, bt, meta):  # the physical page step (b, p) holds
+        return bt[b, clamped_page(p, meta[2 + 2 * B + b],
+                                  meta[2 + 3 * B + b])]
 
     quantized = k_scale is not None
     kv_spec = pl.BlockSpec(
         (1, 1, page, Hkv, D),
-        lambda b, p, bt, meta: (meta[0], bt[b, p], 0, 0, 0),
+        lambda b, p, bt, meta: (meta[0], phys(b, p, bt, meta), 0, 0, 0),
     )
     in_specs = [
         pl.BlockSpec((1, Hq, D), lambda b, p, bt, meta: (b, 0, 0)),
@@ -157,7 +201,7 @@ def paged_decode_attention(
     if quantized:
         sc_spec = pl.BlockSpec(
             (1, 1, page, Hkv),
-            lambda b, p, bt, meta: (meta[0], bt[b, p], 0, 0),
+            lambda b, p, bt, meta: (meta[0], phys(b, p, bt, meta), 0, 0),
         )
         in_specs += [sc_spec, sc_spec]
         args += [k_scale, v_scale]

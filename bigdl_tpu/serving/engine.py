@@ -2758,9 +2758,13 @@ class InferenceEngine:
         tr = self.tracer
         if tr is not None and tr.enabled:
             busy = int(self.active.sum())
+            pages = {}
+            if self.paged:  # _slot_pos still holds the step's own pos
+                pages["live_pages"], pages["grid_pages"] = \
+                    self.paged_grid_pages()
             tr.complete("decode_step", t0, t1 - t0, tid=0, cat="engine",
                         occupancy=busy, slots=self.n_slots,
-                        queue_depth=self._queue.qsize())
+                        queue_depth=self._queue.qsize(), **pages)
             if dispatched is not None:
                 tr.complete_parts(
                     t0, t1 - t0, (dispatched[0],),
@@ -2976,6 +2980,16 @@ class InferenceEngine:
                 held[pg] += 1
         return sum(1 for pg in range(1, self.n_pages)
                    if self._page_ref[pg] != held[pg])
+
+    def paged_grid_pages(self) -> tuple[int, int]:
+        """(live, grid) pages of the paged decode kernel's next step, from
+        the host's mirror (no device read): the pages up to each active
+        row's `pos`, and the kernel's whole grid of slots x pages per row.
+        Only the live ones cost the kernel a DMA and a softmax update."""
+        mp = self.max_pages_per_row
+        live = sum(min(self._slot_pos[int(i)] // self.page_size + 1, mp)
+                   for i in np.nonzero(self.active)[0])
+        return live, self.n_slots * mp
 
     def kv_utilization(self) -> float:
         """Fraction of the KV pool holding live state: allocated pages

@@ -375,10 +375,17 @@ class Metrics:
                 f"{self.engine.prefill_chunks}",
             ]
             if self.engine.paged:
+                live, grid = self.engine.paged_grid_pages()
                 lines += [
                     "# HELP bigdl_tpu_free_pages allocatable KV pages",
                     "# TYPE bigdl_tpu_free_pages gauge",
                     f"bigdl_tpu_free_pages {len(self.engine._free_pages)}",
+                    "# HELP bigdl_tpu_paged_live_page_share fraction of "
+                    "the paged decode kernel's grid (slots x pages per "
+                    "row) that holds live KV; the rest is skipped",
+                    "# TYPE bigdl_tpu_paged_live_page_share gauge",
+                    f"bigdl_tpu_paged_live_page_share "
+                    f"{live / max(grid, 1):.4f}",
                     "# HELP bigdl_tpu_prefix_hits_total full-page prefix "
                     "cache hits",
                     "# TYPE bigdl_tpu_prefix_hits_total counter",
@@ -516,6 +523,7 @@ _ENGINE_FAMILIES = (
 
 _PAGED_FAMILIES = (
     "bigdl_tpu_free_pages",
+    "bigdl_tpu_paged_live_page_share",
     "bigdl_tpu_prefix_hits_total",
     "bigdl_tpu_prefix_partial_hits_total",
     "bigdl_tpu_prefix_tokens_reused_total",

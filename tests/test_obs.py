@@ -372,13 +372,44 @@ def test_phase_spans_partition_admission_and_step(model, kind):
         kids = _children(events, s)
         assert [k["name"] for k in kids] == STEP_PARTS
         _abut_and_fill(s, kids)
-        assert set(s["args"]) == {"occupancy", "slots", "queue_depth"}
+        assert set(s["args"]) == {"occupancy", "slots", "queue_depth"} | (
+            {"live_pages", "grid_pages"} if eng.paged else set())
     if kind == "paged-chunked":  # `prefill.dispatch` covers every chunk,
         # and the steps that ran between them
         first = min(prefills, key=lambda e: e["ts"])
         later = [p for p in prefills if p is not first]
         assert any(p["ts"] <= s["ts"] < p["ts"] + p["dur"]
                    for p in later for s in steps)
+
+
+@pytest.mark.parametrize("kind", ["paged", "paged-chunked"])
+def test_decode_step_span_counts_live_pages(model, kind):
+    """`decode_step` spans of a paged engine carry the kernel's grid
+    (`grid_pages` = slots x pages per row) and the part of it that holds
+    live KV, counted on the host: it equals sum(pos // page + 1) over the
+    active rows of the DEVICE cache the step was given."""
+    tr = TraceRecorder(enabled=True)
+    eng = _engine(model, kind, tracer=tr)
+    seen, decode = [], eng._decode
+
+    def spy(params, cur, cache, *a, **kw):
+        pos = np.asarray(cache.pos)[eng.active]
+        seen.append(int((pos // eng.page_size + 1).sum()))
+        return decode(params, cur, cache, *a, **kw)
+
+    eng._decode = spy
+    _serve(eng)
+    eng.close()
+    steps = [e["args"] for e in tr.events()
+             if e.get("ph") == "X" and e["name"] == "decode_step"]
+    assert [a["live_pages"] for a in steps] == seen and min(seen) >= 1
+    grid = eng.n_slots * eng.max_pages_per_row
+    assert all(a["live_pages"] <= a["grid_pages"] == grid for a in steps)
+    # prompts of 20 tokens on pages of 8: a row starts on its third page
+    assert max(seen) >= 2 * 3
+    # the operator's gauge is the same ratio; every slot is released now
+    assert _metric_value(Metrics(eng).render(),
+                         "bigdl_tpu_paged_live_page_share") == 0.0
 
 
 class _CountingClock:

@@ -191,11 +191,27 @@ def test_paged_kernel_decode_matches_gather(model, monkeypatch):
     assert [o[:6] for o in out] == [r[:6] for r in ref], (out, ref)
 
 
-def test_paged_kernel_attention_unit(rng=None):
+def _gather_reference(q, cache, layer, pos, start, window=None,
+                      softcap=None):
+    """Masked dense attention over the gathered view of `cache`."""
+    from bigdl_tpu.ops.attention import attention
+
+    kd, vd = kvpaged.read_layer(cache, jnp.asarray(layer), jnp.float32)
+    sj = jnp.arange(kd.shape[1])
+    mask = (sj[None, :] <= pos[:, None]) & (sj[None, :] >= start[:, None])
+    if window is not None:
+        mask = mask & (sj[None, :] > (pos - window)[:, None])
+    return attention(q[:, None], kd, vd, mask[:, None, None, None],
+                     softcap=softcap)[:, 0]
+
+
+@pytest.mark.parametrize("window", [None, 7])
+@pytest.mark.parametrize("layer", [0, 1])
+def test_paged_kernel_attention_unit(layer, window):
     """paged_decode_attention == masked dense attention over the
     gathered view, including GQA, sliding window and non-contiguous
-    pages."""
-    from bigdl_tpu.ops.attention import attention
+    pages. No `live` argument: every row is live, physical page 0
+    included (row 2's last page)."""
     from bigdl_tpu.ops.pallas import paged_decode_attention
 
     rng = np.random.default_rng(0)
@@ -208,26 +224,164 @@ def test_paged_kernel_attention_unit(rng=None):
     start = jnp.asarray([2, 0, 5], jnp.int32)
     q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
 
-    for layer in (0, 1):
-        for window in (None, 7):
-            out = paged_decode_attention(
-                q, k_pages, v_pages, bt, jnp.asarray(layer), pos, start,
-                window=window, interpret=True,
-            )
-            # reference: gather + masked attention
-            cache = kvpaged.PagedKVCache(
-                k=k_pages, v=v_pages, block_tables=bt, pos=pos, start=start,
-            )
-            kd, vd = kvpaged.read_layer(cache, jnp.asarray(layer), jnp.float32)
-            S = kd.shape[1]
-            sj = jnp.arange(S)
-            mask = (sj[None, :] <= pos[:, None]) & (sj[None, :] >= start[:, None])
-            if window is not None:
-                mask = mask & (sj[None, :] > (pos - window)[:, None])
-            ref = attention(q[:, None], kd, vd, mask[:, None, None, None])
-            np.testing.assert_allclose(
-                np.asarray(out), np.asarray(ref[:, 0]), atol=2e-2, rtol=2e-2,
-            )
+    out = paged_decode_attention(
+        q, k_pages, v_pages, bt, jnp.asarray(layer), pos, start,
+        window=window, interpret=True,
+    )
+    cache = kvpaged.PagedKVCache(
+        k=k_pages, v=v_pages, block_tables=bt, pos=pos, start=start,
+    )
+    ref = _gather_reference(q, cache, layer, pos, start, window)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), atol=2e-2, rtol=2e-2,
+    )
+
+
+# Pages of 8 slots, 4 to a row, rows 0..2 on physical pages 1..12 (0 is the
+# scratch sink). `live` None means no argument: every row live.
+_KP, _KMP, _KB = 8, 4, 3
+_KBT = np.asarray([[5, 2, 9, 1], [3, 7, 11, 4], [10, 6, 8, 12]], np.int32)
+KERNEL_CASES = {
+    "gqa7": dict(G=7, pos=[17, 9, 30], start=[2, 0, 5]),
+    "pos_last_slot_of_page": dict(pos=[7, 15, 23]),  # k * page - 1
+    "pos_first_slot_of_page": dict(pos=[8, 16, 24]),  # k * page
+    "dead_leading_pages": dict(pos=[30, 20, 27], start=[17, 8, 26]),
+    "window_drops_pages": dict(pos=[30, 25, 12], start=[0, 3, 0], window=7),
+    "fp8_pages": dict(pos=[17, 9, 30], start=[2, 0, 5], fp8=True),
+    "softcap": dict(pos=[17, 9, 30], softcap=5.0),
+    "idle_row": dict(pos=[17, 29, 12], live=[True, False, True]),
+}
+
+
+def _kernel_case(name, poison):
+    """(kernel output, gather reference on the clean pool, live) for one
+    of KERNEL_CASES. `poison` writes NaN into K (and the fp8 scales) and
+    inf into V of every physical page outside each live row's
+    first .. last, scratch page included."""
+    from bigdl_tpu.ops.pallas import paged_decode_attention
+    from bigdl_tpu.ops.pallas.paged_attention import live_page_range
+
+    c = dict(G=3, start=[0, 0, 0], window=None, softcap=None, fp8=False,
+             live=None)
+    c.update(KERNEL_CASES[name])
+    rng = np.random.default_rng(1)
+    L, Hkv, D = 2, 2, 16
+    NP, n0 = _KBT.max() + 1, _KP * _KMP
+    pos = jnp.asarray(c["pos"], jnp.int32)
+    start = jnp.asarray(c["start"], jnp.int32)
+    live = None if c["live"] is None else jnp.asarray(c["live"])
+    bt = _KBT if live is None else np.where(
+        np.asarray(c["live"])[:, None], _KBT, 0)  # as the engine parks it
+
+    cache = kvpaged.init_paged(L, NP, _KP, Hkv, D, _KB, _KMP,
+                               dtype=jnp.float32, quantize_kv=c["fp8"])
+    cache = dataclasses.replace(cache, block_tables=jnp.asarray(_KBT),
+                                start=start)
+    for layer in range(L):  # fill every row's pages, slots past pos too
+        kk = jnp.asarray(rng.standard_normal((_KB, n0, Hkv, D)), jnp.float32)
+        vv = jnp.asarray(rng.standard_normal((_KB, n0, Hkv, D)), jnp.float32)
+        cache = kvpaged.update_layer(cache, jnp.asarray(layer), kk, vv)
+    cache = dataclasses.replace(cache, block_tables=jnp.asarray(bt), pos=pos)
+    if live is not None:
+        np.testing.assert_array_equal(kvpaged.live_rows(cache), c["live"])
+    q = jnp.asarray(rng.standard_normal((_KB, Hkv * c["G"], D)), jnp.float32)
+    layer = 1
+    ref = _gather_reference(q, cache, layer, pos, start, c["window"],
+                            c["softcap"])
+
+    if poison:
+        win = 2 ** 30 if c["window"] is None else c["window"]
+        first, last = (np.asarray(a) for a in live_page_range(
+            pos, start, win, _KP, _KMP, live))
+        dead = np.ones(NP, bool)
+        for b in range(_KB):
+            dead[bt[b, first[b]:last[b] + 1]] = False
+        assert dead[0] and dead.sum() >= 3
+        dead = jnp.asarray(dead)
+
+        def spoil(a, bad):
+            return jnp.where(dead.reshape((1, NP) + (1,) * (a.ndim - 2)),
+                             jnp.asarray(bad, a.dtype), a)
+
+        cache = dataclasses.replace(
+            cache, k=spoil(cache.k, np.nan), v=spoil(cache.v, np.inf),
+            **({"k_scale": spoil(cache.k_scale, np.nan),
+                "v_scale": spoil(cache.v_scale, np.nan)} if c["fp8"] else {}))
+    out = paged_decode_attention(
+        q, cache.k, cache.v, cache.block_tables, jnp.asarray(layer), pos,
+        start, k_scale=cache.k_scale, v_scale=cache.v_scale,
+        softcap=c["softcap"],
+        window=None if c["window"] is None else jnp.asarray(c["window"]),
+        live=live, interpret=True,
+    )
+    return np.asarray(out, np.float32), np.asarray(ref), c["live"]
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CASES))
+def test_paged_kernel_live_pages_match_gather(name):
+    """The kernel against the gather on the shapes and positions where a
+    live range can go wrong: 7 query heads to a KV head, `pos` on either
+    side of a page boundary, dead LEADING pages (`start`, a binding
+    window), fp8 pages, softcap, and an idle row (zeros, finite) beside
+    live ones."""
+    out, ref, live = _kernel_case(name, poison=False)
+    rows = [b for b in range(_KB) if live is None or live[b]]
+    np.testing.assert_allclose(out[rows], ref[rows], atol=2e-2, rtol=2e-2)
+    if live is not None:
+        idle = [b for b in range(_KB) if not live[b]]
+        assert idle and not out[idle].any()
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CASES))
+def test_paged_kernel_ignores_poisoned_dead_pages(name):
+    """NaN / inf in every page outside first .. last (V included) leaves
+    the output bit-equal to the clean run: dead pages are neither loaded
+    nor used. A kernel that only masks them computes 0 * NaN."""
+    clean, _, _ = _kernel_case(name, poison=False)
+    dirty, _, _ = _kernel_case(name, poison=True)
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+
+
+@pytest.mark.parametrize("page", [1, 4, 16, 64])
+def test_live_page_range_and_clamped_map_properties(page):
+    """Over random pos / start / window: every slot the mask admits lies
+    in first .. last, both end pages hold an admitted slot, the clamped
+    index map names page p inside the range and the nearest live page's
+    physical page outside it; an idle row has no live page and a map that
+    stays in the table."""
+    from bigdl_tpu.ops.pallas.paged_attention import (
+        clamped_page, live_page_range)
+
+    rng = np.random.default_rng(page)
+    n, mp = 200, 12
+    pos = rng.integers(0, mp * page, n)
+    start = np.minimum(rng.integers(0, mp * page, n), pos)
+    win = np.where(rng.random(n) < 0.3, 2 ** 30,
+                   rng.integers(1, 3 * page + 2, n))
+    first, last = (np.asarray(a) for a in live_page_range(
+        jnp.asarray(pos), jnp.asarray(start), jnp.asarray(win), page, mp))
+    bt = rng.permutation(n * mp).reshape(n, mp) + 1
+    slot = np.arange(mp * page)
+    pages = np.arange(mp)
+    for i in range(n):
+        ok = (slot >= start[i]) & (slot <= pos[i]) & (slot > pos[i] - win[i])
+        holds = np.unique(slot[ok] // page)  # pages with an admitted slot
+        assert holds.min() == first[i] and holds.max() == last[i]
+        assert 0 <= first[i] <= last[i] < mp
+        got = bt[i, np.asarray(clamped_page(pages, first[i], last[i]))]
+        want = bt[i, np.where(pages < first[i], first[i],
+                              np.where(pages > last[i], last[i], pages))]
+        np.testing.assert_array_equal(got, want)
+
+    live = rng.random(n) < 0.5
+    first, last = (np.asarray(a) for a in live_page_range(
+        jnp.asarray(pos), jnp.asarray(start), jnp.asarray(win), page, mp,
+        jnp.asarray(live)))
+    assert (first[~live] > last[~live]).all()  # no p is first <= p <= last
+    idx = np.asarray(clamped_page(pages[None, :], first[:, None],
+                                  last[:, None]))
+    assert ((0 <= idx) & (idx < mp)).all()
 
 
 def test_paged_fp8_pages(model):
