@@ -85,8 +85,10 @@ class ModelConfig:
     moe_intermediate_size: Optional[int] = None
     shared_expert_intermediate_size: Optional[int] = None  # qwen2_moe
     norm_topk_prob: bool = False  # renormalize top-k router weights
-    # dispatch formulation: None = auto (dense for E<=8, ragged above),
-    # or force "dense" / "ragged" (models/llama.py _moe_mlp)
+    # the XLA dispatch formulation for where the grouped kernel cannot
+    # run (training, a mesh, dense weights): None = auto (dense for E<=8,
+    # ragged above), or force "dense" / "ragged" (models/llama.py
+    # _moe_dispatch; packed experts at inference take the kernel)
     moe_dispatch: Optional[str] = None
     moe_capacity_factor: float = 1.25  # ragged: slots per expert vs even load
     # mllama (llama-3.2 vision): indices of the tanh-gated cross-attention
@@ -935,11 +937,14 @@ PRESETS: dict[str, ModelConfig] = {
         num_attention_heads=32, num_key_value_heads=32,
         max_position_embeddings=4096,
     ),
+    # Mixtral-8x7B-v0.1's config.json; tests hold it to the `published`
+    # block of bench/configs/mixtral-8x7b-int4.json
     "mixtral-8x7b": ModelConfig(
         model_type="mixtral", vocab_size=32000, hidden_size=4096,
         intermediate_size=14336, num_hidden_layers=32,
         num_attention_heads=32, num_key_value_heads=8,
-        rope_theta=1000000.0, num_experts=8, num_experts_per_tok=2,
-        norm_topk_prob=True,
+        rope_theta=1000000.0, rms_norm_eps=1e-05,
+        max_position_embeddings=32768, sliding_window=None,
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=True,
     ),
 }

@@ -293,30 +293,31 @@ def _router(config: ModelConfig, xc, p):
 
 
 def _moe_mlp(config: ModelConfig, x, p, compute_dtype):
-    """Routed experts (llama's dense/ragged dispatch over our router) +
-    ungated shared experts (DeepseekV2MoE.forward)."""
+    """Routed experts (llama's `_moe_dispatch` over our router: grouped
+    kernel on packed stacks at inference, else dense / ragged) + ungated
+    shared experts (DeepseekV2MoE.forward)."""
     B, T, hid = x.shape
     xc = x.astype(compute_dtype)
     topv, topi = _router(config, xc.reshape(-1, hid), p)
     topv = topv.reshape(B, T, -1)
     topi = topi.reshape(B, T, -1)
 
-    if llama.resolve_moe_dispatch(config) == "ragged":
-        rcfg = config
-        if (config.topk_method or "greedy") != "greedy" and config.n_group:
-            # group-limited routing concentrates every token's k experts
-            # into topk_group of n_group groups, so per-expert load can
-            # exceed the uniform-load capacity by G/topk_group — scale
-            # the capacity factor accordingly or hot experts silently
-            # drop tokens (GShard overflow) where HF computes the full sum
-            rcfg = dataclasses.replace(
-                config,
-                moe_capacity_factor=config.moe_capacity_factor
-                * config.n_group / max(config.topk_group or 1, 1),
-            )
-        out = llama._moe_dispatch_ragged(rcfg, xc, p, compute_dtype, topv, topi)
-    else:
-        out = llama._moe_dispatch_dense(config, xc, p, compute_dtype, topv, topi)
+    rcfg = config
+    if (config.topk_method or "greedy") != "greedy" and config.n_group:
+        # group-limited routing concentrates every token's k experts
+        # into topk_group of n_group groups, so per-expert load can
+        # exceed the uniform-load capacity by G/topk_group — scale
+        # the ragged formulation's capacity factor accordingly or hot
+        # experts silently drop tokens (GShard overflow) where HF
+        # computes the full sum. The grouped kernel (packed stacks at
+        # inference) drops nothing and needs no capacity.
+        rcfg = dataclasses.replace(
+            config,
+            moe_capacity_factor=config.moe_capacity_factor
+            * config.n_group / max(config.topk_group or 1, 1),
+        )
+    out = llama._moe_dispatch(config, xc, p, compute_dtype, topv, topi,
+                              ragged_config=rcfg)
 
     if config.n_shared_experts:
         g = linear(xc, p["w_gate_s"], None, compute_dtype)

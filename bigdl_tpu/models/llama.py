@@ -27,6 +27,8 @@ Batch rows are left-padded (see bigdl_tpu/kvcache.py).
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -359,8 +361,14 @@ def _deq(w, compute_dtype):
 
 
 def resolve_moe_dispatch(config: ModelConfig) -> str:
-    """Auto policy: dense combine is cheaper below ~8 experts (all-matmul,
-    no gather/scatter); capacity dispatch above (FLOPs ∝ k/E)."""
+    """The XLA formulation for where the grouped kernel cannot run
+    (`_moe_dispatch`): `config.moe_dispatch` if set, else "ragged" above
+    8 experts (FLOPs go with k/E, assignments past capacity are DROPPED)
+    and "dense" up to 8. Dense is exact and differentiable but not cheap:
+    on a v5e Mixtral-8x7B's dense combine took 227 ms a decode step on
+    ten layers, 92% of it writing every expert's bf16 weights (PERF.md,
+    PR 23), so it is the training and mesh formulation, not a serving
+    path."""
     if config.moe_dispatch is not None:
         return config.moe_dispatch
     return "ragged" if config.num_experts > 8 else "dense"
@@ -369,11 +377,18 @@ def resolve_moe_dispatch(config: ModelConfig) -> str:
 def _moe_router(config: ModelConfig, xc: jax.Array, p: Params):
     """Top-k routing with softmax weights. Returns (topv [B,T,k] f32,
     topi [B,T,k] i32). Mixtral renormalizes the top-k weights
-    (norm_topk_prob=True via config), qwen2_moe per its flag."""
+    (norm_topk_prob=True via config), qwen2_moe per its flag.
+
+    The logits are a float32 product at full precision (E x H weights:
+    it costs nothing): a TPU's default matmul rounds float32 operands to
+    bf16, and a top-k over logits that coarse picks another expert
+    wherever the k-th and the next lie within the rounding."""
     router_logits = jnp.einsum(
-        "bth,eh->bte", xc, p["router"].astype(xc.dtype),
+        "bth,eh->bte", xc.astype(jnp.float32),
+        p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
-    ).astype(jnp.float32)
+    )
     probs_all = jax.nn.softmax(router_logits, axis=-1)
     topv, topi = jax.lax.top_k(probs_all, config.num_experts_per_tok)
     if config.norm_topk_prob:
@@ -479,26 +494,121 @@ def _moe_dispatch_dense(
     return jnp.einsum("bteh,bte->bth", d, combine.astype(compute_dtype))
 
 
-def _moe_mlp(config: ModelConfig, x: jax.Array, p: Params, compute_dtype) -> jax.Array:
-    """Mixture-of-experts MLP (reference models/mixtral.py, qwen2_moe.py +
-    `xe_linear.get_moe_indexes`): top-k routing with softmax weights.
+_EXPERT_STACKS = ("w_gate_e", "w_up_e", "w_down_e")
 
-    Two formulations, chosen by `config.moe_dispatch` (auto = by expert
-    count):
-    - "dense": every expert computes every token, router weights (zero
-      for unrouted) combine them — all-matmul, no gather/scatter,
-      MXU-friendly, exactly differentiable. Best at mixtral scale (E=8).
-    - "ragged": capacity-based dispatch, FLOPs ∝ k/E — required for
-      qwen2-moe scale (E=60, k=4). See _moe_dispatch_ragged.
-    """
+
+def _moe_dispatch_grouped(
+    config: ModelConfig, xc: jax.Array, p: Params, compute_dtype,
+    topv: jax.Array, topi: jax.Array, layer=None,
+) -> jax.Array:
+    """Dropless dispatch on packed weights: the assignments are sorted
+    by expert, each expert's rows padded to a whole row tile, and
+    gate/up/down run through the grouped fused dequant kernel
+    (`ops/pallas/moe_qmatmul.py`) with float32 accumulation. Every
+    assignment is computed (the layout has room for all N*k, however
+    they fall), an expert nobody chose is never read, and no expert is
+    ever dequantized into HBM. Rows are independent from the gather to
+    the combine, so a padded or idle row (NaN included) cannot reach a
+    live one. `layer` says the stacks' packed codes still carry the layer
+    axis (forward keeps them out of the scan's slices)."""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+
+    B, T, H = xc.shape
+    E, k, N = config.num_experts, config.num_experts_per_tok, B * T
+    wu, wd = p["w_up_e"], p["w_down_e"]
+    block_m = mq.moe_block_m(N, max(H, wu.data.shape[-2]))
+    n_tiles = mq.moe_n_tiles(N, k, E, block_m)
+    call = functools.partial(mq.moe_qmatmul, block_m=block_m, layer=layer)
+
+    with jax.named_scope("moe.dispatch"):
+        dest, src, tile_expert, n_used = mq.moe_layout(
+            topi.reshape(N, k), E, block_m, n_tiles)
+        xs = xc.reshape(N, H)[src]  # [n_tiles * block_m, H]
+        row_expert = jnp.repeat(tile_expert, block_m)
+    with jax.named_scope("moe.experts"):
+        if not config.gated_mlp:  # phixtral: biased fc1 -> act -> fc2
+            u = call(xs, wu, tile_expert, n_used, out_dtype=jnp.float32)
+            if "b_up_e" in p:
+                u = u + p["b_up_e"].astype(jnp.float32)[row_expert]
+            z = _act(config.hidden_act, u)
+        elif config.hidden_act in mq.FUSED_ACTS:
+            z = call(xs, (p["w_gate_e"], wu), tile_expert, n_used,
+                     act=config.hidden_act)
+        else:
+            g, u = (call(xs, w, tile_expert, n_used, out_dtype=jnp.float32)
+                    for w in (p["w_gate_e"], wu))
+            z = _act(config.hidden_act, g) * u
+        y = call(z.astype(compute_dtype), wd, tile_expert, n_used,
+                 out_dtype=jnp.float32)
+        if not config.gated_mlp and "b_down_e" in p:
+            y = y + p["b_down_e"].astype(jnp.float32)[row_expert]
+    with jax.named_scope("moe.combine"):
+        out = jnp.sum(y[dest] * topv.reshape(N, k, 1), axis=1)
+    return out.astype(compute_dtype).reshape(B, T, H)
+
+
+def moe_grouped_why_not(p: Params, differentiable: bool) -> Optional[str]:
+    """None when a layer's experts take `_moe_dispatch_grouped`: packed
+    stacks the kernels can tile, the kernels in use (a TPU, or the
+    interpreter; not under a mesh axis XLA partitions), and nothing to
+    differentiate (the grouped kernel has no backward)."""
+    from bigdl_tpu.ops.linear import grouped_route
+
+    if differentiable:
+        return "adapters are being trained: the XLA formulations differentiate"
+    return grouped_route(*(p[n] for n in _EXPERT_STACKS if n in p))
+
+
+def _moe_dispatch(
+    config: ModelConfig, xc: jax.Array, p: Params, compute_dtype,
+    topv: jax.Array, topi: jax.Array, ragged_config=None,
+    differentiable: bool = False, layer=None,
+) -> jax.Array:
+    """Routed experts of one layer, by the one rule every MoE family
+    goes through: packed expert stacks at inference take the dropless
+    grouped kernel; everything else (dense weights, training, a mesh
+    axis XLA partitions, an ineligible shape, the CPU without the
+    interpreter) takes the XLA formulation `resolve_moe_dispatch` names.
+    `ragged_config` carries a family's capacity adjustment for the
+    ragged formulation only (DeepSeek's group-limited routing)."""
+    from bigdl_tpu.ops import routes
+
+    B, T, H = xc.shape
+    detail = (f"N{B * T} k{config.num_experts_per_tok} "
+              f"E{config.num_experts} H{H}")
+    why = moe_grouped_why_not(p, differentiable)
+    if why is None:
+        routes.note("moe", "pallas:grouped",
+                    f"{p['w_up_e'].qtype} {detail} dropless")
+        return _moe_dispatch_grouped(config, xc, p, compute_dtype, topv,
+                                     topi, layer)
+    assert layer is None, "unsliced expert codes are for the grouped path"
+    kind = resolve_moe_dispatch(config)
+    routes.note("moe", f"xla:{kind}", f"{detail} ({why})")
+    if kind == "ragged":
+        return _moe_dispatch_ragged(ragged_config or config, xc, p,
+                                    compute_dtype, topv, topi)
+    return _moe_dispatch_dense(config, xc, p, compute_dtype, topv, topi)
+
+
+def _moe_mlp(config: ModelConfig, x: jax.Array, p: Params, compute_dtype
+             ) -> jax.Array:
+    """`_moe_block`'s output alone."""
+    return _moe_block(config, x, p, compute_dtype)[0]
+
+
+def _moe_block(config: ModelConfig, x: jax.Array, p: Params, compute_dtype,
+               differentiable: bool = False, layer=None):
+    """Mixture-of-experts MLP (reference models/mixtral.py, qwen2_moe.py +
+    `xe_linear.get_moe_indexes`): top-k routing with softmax weights in
+    float32, then the routed experts by `_moe_dispatch` (docs/kernels.md
+    says which path runs when). Returns (out [B,T,H], topi [B,T,k])."""
     B, T, H = x.shape
     xc = x.astype(compute_dtype)
-    topv, topi = _moe_router(config, xc, p)
-
-    if resolve_moe_dispatch(config) == "ragged":
-        out = _moe_dispatch_ragged(config, xc, p, compute_dtype, topv, topi)
-    else:
-        out = _moe_dispatch_dense(config, xc, p, compute_dtype, topv, topi)
+    with jax.named_scope("moe.router"):
+        topv, topi = _moe_router(config, xc, p)
+    out = _moe_dispatch(config, xc, p, compute_dtype, topv, topi,
+                        differentiable=differentiable, layer=layer)
 
     if config.shared_expert_intermediate_size:
         # qwen2_moe shared expert, sigmoid-gated (models/qwen2_moe.py)
@@ -512,7 +622,7 @@ def _moe_mlp(config: ModelConfig, x: jax.Array, p: Params, compute_dtype) -> jax
             jnp.einsum("bth,oh->bto", xc, p["shared_gate"].astype(compute_dtype))
         )
         out = out + sd * gate
-    return out
+    return out, topi
 
 
 def forward(
@@ -548,6 +658,9 @@ def forward(
     # shard under shard_map, and the row-parallel pair (wo, w_down)
     # reduces through its all-reduce: exact for comm_qtype="none", the
     # block-quantized ring otherwise. None is the single-device path.
+    moe_routing: bool = False,  # static: also return every layer's top-k
+    # expert ids [L, B, T, k] int32, last (the serving engine counts expert
+    # load from them, with the tokens a step already fetches)
 ) -> tuple[jax.Array, Optional[KVCache]]:
     """Returns (logits [B, T, V] float32, updated cache with pos advanced).
 
@@ -747,6 +860,20 @@ def forward(
 
     lora_scale = lora["scale"] if lora is not None else None
 
+    # The packed codes of expert stacks that take the grouped kernel stay
+    # OUT of the scan's per-layer slices: the kernel reads its blocks from
+    # the whole [L, E, O, C] array by layer index, where a slice handed to
+    # a Mosaic call is first copied whole (every expert, hit or not, every
+    # step). Scales stay sliced, and fp8 codes, which reach the kernel
+    # through a bitcast that would copy the whole stack instead.
+    layers = params["layers"]
+    moe_codes = {}
+    if config.is_moe and moe_grouped_why_not(layers, lora is not None) is None:
+        moe_codes = {n: layers[n].data for n in _EXPERT_STACKS if n in layers
+                     and not layers[n].spec.storage.startswith("fp8")}
+        layers = {n: dataclasses.replace(w, data=None) if n in moe_codes
+                  else w for n, w in layers.items()}
+
     tp_sharded = comm is not None and comm.axis_size > 1
 
     def proj(x, p, lp, wname, bname=None):
@@ -896,8 +1023,14 @@ def forward(
 
         with jax.named_scope("ffn"):
             x = mlp_in
+            routed = None
             if config.is_moe:
-                down = _moe_mlp(config, x, p, compute_dtype)
+                codes = {n: dataclasses.replace(p[n], data=d)
+                         for n, d in moe_codes.items()}
+                down, routed = _moe_block(
+                    config, x, {**p, **codes}, compute_dtype,
+                    differentiable=lora is not None,
+                    layer=idx if codes else None)
             elif "w_gateup" in p:  # merged layout (merge_fused_params)
                 gu = linear(x, p["w_gateup"], p.get("b_gateup"), compute_dtype)
                 I2 = gu.shape[-1] // 2
@@ -924,17 +1057,18 @@ def forward(
         else:
             hidden = hidden + (down * rs if rs else down)
 
-        ys = q[:, T - collect_obs:] if collect_obs else None
+        ys = (q[:, T - collect_obs:] if collect_obs else None,
+              routed if moe_routing else None)
         return (hidden, c, idx + 1), ys
 
-    xs = (params["layers"], lora["layers"]) if lora is not None else params["layers"]
+    xs = (layers, lora["layers"]) if lora is not None else layers
     scan_body = body
     if remat:
         # recompute the layer in the backward instead of saving its
         # activations; prevent_cse is the documented setting for remat
         # inside scan (jax.checkpoint docs)
         scan_body = jax.checkpoint(body, prevent_cse=False)
-    (h, cache, _), obs = jax.lax.scan(
+    (h, cache, _), (obs, routing) = jax.lax.scan(
         scan_body, (h, cache, jnp.zeros((), jnp.int32)), xs
     )
 
@@ -947,6 +1081,5 @@ def forward(
             logits = lm_head_logits(config, params, h, compute_dtype)
     if cache is not None:
         cache = kvcache.advance(cache, T)
-    if collect_obs:
-        return logits, cache, obs
-    return logits, cache
+    out = (logits, cache) + ((obs,) if collect_obs else ())
+    return out + ((routing,) if moe_routing else ())

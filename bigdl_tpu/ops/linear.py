@@ -226,19 +226,17 @@ for _name, _e in _QGEMV_QTYPES.items():
     )
 
 
-def _fused_route(x: jax.Array, w: QTensor) -> tuple[Optional[Callable], str]:
-    """(kernel, why): the fused kernel this (x, w) pair dispatches to,
-    or None with the guard that sent it to the XLA dequant route. Shape
-    guards are shared by both shape classes."""
+def _shape_guard(w: QTensor) -> tuple[Optional[_GemvEntry], str]:
+    """(entry, "") when the kernels can tile this weight's last two dims
+    [O, K] (a rank-2 weight or each expert of a stack), else (None, the
+    guard that refuses it)."""
     from bigdl_tpu.ops.pallas import why_not_pallas
     from bigdl_tpu.ops.pallas.tiling import VMEM_BUDGET
 
     entry = _QGEMV_QTYPES.get(w.qtype)
     if entry is None:
         return None, "no fused kernel registered for this qtype"
-    if w.data.ndim != 2:
-        return None, f"weight is rank {w.data.ndim}, kernels take rank 2"
-    out, kw_ = w.data.shape
+    out, kw_ = w.data.shape[-2:]
     if out % 128 != 0:
         return None, "O not a multiple of 128 lanes"
     # the kernels tile O at >= 128 rows (Mosaic lane rule forbids
@@ -254,11 +252,42 @@ def _fused_route(x: jax.Array, w: QTensor) -> tuple[Optional[Callable], str]:
     off = why_not_pallas()
     if off is not None:
         return None, off
+    return entry, ""
+
+
+def _fused_route(x: jax.Array, w: QTensor) -> tuple[Optional[Callable], str]:
+    """(kernel, why): the fused kernel this (x, w) pair dispatches to,
+    or None with the guard that sent it to the XLA dequant route. Shape
+    guards are shared by both shape classes."""
+    if w.data.ndim != 2:
+        return None, f"weight is rank {w.data.ndim}, kernels take rank 2"
+    entry, why = _shape_guard(w)
+    if entry is None:
+        return None, why
     if _rows(x.shape) <= _GEMV_MAX_ROWS:
         return entry.run, "gemv"
     if entry.gemm is None:
         return None, f"gemm_exempt: {entry.gemm_exempt}"
     return entry.gemm, "gemm"
+
+
+def grouped_route(*stacks) -> Optional[str]:
+    """None when every expert stack ([.., E, O, K] QTensors of one MoE
+    layer) can take the grouped kernel (`ops/pallas/moe_qmatmul.py`: rows
+    sorted by expert, each tile's weights read packed from its expert),
+    else the guard that refuses: the same format and shape rules as
+    `linear`'s kernels, applied to one expert."""
+    for w in stacks:
+        if not isinstance(w, QTensor):
+            return "expert weights are dense, not packed"
+        if w.data.ndim < 3:
+            return f"weight is rank {w.data.ndim}, not a stack of experts"
+        entry, why = _shape_guard(w)
+        if entry is None:
+            return why
+        if entry.gemm is None:
+            return f"gemm_exempt: {entry.gemm_exempt}"
+    return None
 
 
 def _fused_kernel(x: jax.Array, w: QTensor) -> Optional[Callable]:

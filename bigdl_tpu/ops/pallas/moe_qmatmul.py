@@ -1,0 +1,232 @@
+"""Pallas fused dequant matmul over a STACK of experts, rows grouped by
+expert: the sparse-expert counterpart of `ops/pallas/qmatmul.py`.
+
+    y[r] = x[r] @ dequant(W[expert_of(r)])^T        for every row r
+
+`x` holds the token assignments of one MoE layer sorted by expert
+(`moe_layout`): expert e's rows are contiguous and start at a multiple of
+`block_m`, so every `block_m`-row tile belongs to exactly one expert and
+the kernel is `qmatmul`'s body with one more index: the grid is
+(row tiles, O tiles), and the weight block of tile m comes from expert
+`tile_expert[m]` of the packed stack `[E, O, K*bits/8]`, read through a
+scalar-prefetched table. Nothing here depends on how many rows an expert
+got: group sizes live on the device, 0 is allowed, and no assignment is
+ever dropped (the layout has room for all of them).
+
+What a step pays for:
+
+* the tiles in use come first (`n_used` of the static `n_tiles`); a tile
+  past them computes nothing and its block indices name the blocks the
+  last live step already holds, so it costs no DMA either (the rule
+  `paged_attention.py` uses for dead pages);
+* an expert with no rows has no tile, so its weights are never read; an
+  expert whose rows fit one tile (every decode batch: `block_m` covers
+  the whole batch) is read once, packed;
+* the same per-chunk decode as `qmatmul` (`qdecode.decode_chunk`): bf16
+  weight chunks in VMEM, float32 accumulation, no bf16 or float32 copy
+  of an expert in HBM.
+
+With two stacks (`w_gate`, `w_up`) the tile computes both products from
+the one x tile and writes `act(gate) * up`, in float32, before the
+cast: the gated FFN's first half in one call.
+
+The packed codes may keep their leading layer axis (`[L, E, O, C]` with
+a traced `layer`): the kernel then reads its blocks straight out of the
+scanned-over array, where a `[E, O, C]` slice handed to a Mosaic call
+would first be copied whole, every expert of it, hit or not. The float16
+scales (an eighth of the bytes) cannot: Mosaic takes no float16 argument,
+the uint16 view of them is a copy XLA materializes, and one layer's copy
+at a time is what fits.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from bigdl_tpu.ops.pallas import qdecode
+from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
+from bigdl_tpu.ops.pallas.qmatmul import _side_arrays, _validate
+from bigdl_tpu.ops.pallas.tiling import (
+    VMEM_LIMIT_BYTES, chunk_target, finest_split, pick_block_m, pick_block_o,
+)
+
+#: activations the gated call applies in-kernel (float32, before the
+#: cast); any other gated activation runs as two plain calls + XLA
+FUSED_ACTS = {"silu": jax.nn.silu}
+
+
+def moe_block_m(n_tokens: int, k_max: int) -> int:
+    """Row tile of one MoE layer: `qmatmul`'s policy at the LARGER of
+    the FFN's two contraction dims, since gate/up and down share one row
+    layout. Up to 256 tokens it covers the whole batch, so no expert
+    needs a second tile and each hit expert is read once."""
+    return pick_block_m(n_tokens, k_max)
+
+
+def moe_n_tiles(n_tokens: int, k: int, n_experts: int, block_m: int) -> int:
+    """Static bound on the row tiles the sorted layout can need: every
+    expert pads its group to a multiple of `block_m`, and no expert gets
+    more than `n_tokens` rows (top-k picks distinct experts)."""
+    per_expert = -(-n_tokens // block_m)
+    padded = (n_tokens * k + n_experts * (block_m - 1)) // block_m
+    return max(1, min(n_experts * per_expert, padded))
+
+
+def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int):
+    """Where each assignment goes. `topi [N, k]` int32 expert ids ->
+
+    * `dest [N, k]`: row of the sorted buffer that holds assignment
+      (n, j); rows of one expert are contiguous, in token order, from a
+      multiple of `block_m`;
+    * `src [n_tiles * block_m]`: the token each row reads (padding rows
+      read token 0; nothing reads their output);
+    * `tile_expert [n_tiles]`: the expert whose weights tile m uses (a
+      tile past `n_used` repeats the last live tile's);
+    * `n_used`: scalar, tiles that hold at least one assignment.
+    """
+    N, k = topi.shape
+    e_flat = topi.reshape(N * k).astype(jnp.int32)
+    onehot = (e_flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None]
+              ).astype(jnp.int32)  # [N*k, E]
+    seen = jnp.cumsum(onehot, axis=0)
+    rank = jnp.sum((seen - onehot) * onehot, axis=-1)  # earlier same-expert
+    counts = seen[-1]
+    tiles = (counts + block_m - 1) // block_m
+    tile_end = jnp.cumsum(tiles)
+    n_used = tile_end[-1]
+    dest = ((tile_end - tiles)[e_flat] * block_m + rank).astype(jnp.int32)
+    tok = jnp.arange(N * k, dtype=jnp.int32) // k
+    src = jnp.zeros((n_tiles * block_m,), jnp.int32).at[dest].set(
+        tok, unique_indices=True)
+    m = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), n_used - 1)
+    tile_expert = jnp.sum(
+        (tile_end[None, :] <= m[:, None]).astype(jnp.int32), axis=-1)
+    return dest.reshape(N, k), src, tile_expert, n_used
+
+
+def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
+            spec: DecodeSpec, n_w: int, act):
+    """One [block_m, block_o] tile of one expert: `qmatmul._kernel`'s
+    chunk loop over each of the `n_w` weight stacks, skipped whole when
+    the tile holds no assignment."""
+    del te_ref  # read by the index maps
+    o_ref = refs[-1]
+    per = 1 + spec.n_side
+
+    @pl.when(pl.program_id(0) < meta_ref[0])
+    def _live_tile():
+        x = x_ref[:].astype(jnp.bfloat16)  # [block_m, K]
+        accs = []
+        for i in range(n_w):
+            w = refs[i * per][:]  # packed codes [block_o, row_bytes]
+            side = qdecode.load_side(spec, refs[i * per + 1:(i + 1) * per])
+            acc = jnp.zeros((x.shape[0], w.shape[0]), jnp.float32)
+            for e0, c in qdecode.walk(K, spec.planes, ck):
+                wd = qdecode.decode_chunk(spec, K, w, side, e0, c)
+                acc += jax.lax.dot_general(
+                    qdecode.slc(x, e0, c), wd, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            accs.append(acc)
+        y = accs[0] if n_w == 1 else FUSED_ACTS[act](accs[0]) * accs[1]
+        o_ref[:] = y.astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("spec", "out_dtype", "block_m", "block_o",
+                              "ck", "n_w", "act", "layered", "interpret"))
+def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
+             act, layered: tuple, interpret: bool, tile_expert, meta, x,
+             *arrays):
+    Mp, K = x.shape
+    O = arrays[0].shape[-2]
+    n_o = O // block_o
+
+    def x_map(m, o, te, meta):
+        return (jnp.minimum(m, meta[0] - 1), 0)
+
+    def w_map(has_layer):  # a dead tile names the block already held
+        return lambda m, o, te, meta: (
+            meta[1] if has_layer else 0, te[m],
+            jnp.where(m < meta[0], o, n_o - 1), 0)
+
+    in_specs = [pl.BlockSpec((block_m, K), x_map)] + [
+        pl.BlockSpec((None, None, block_o, a.shape[-1]), w_map(has_layer))
+        for a, has_layer in zip(arrays, layered)
+    ]
+    return pl.pallas_call(
+        functools.partial(_kernel, K=K, ck=ck, spec=spec, n_w=n_w, act=act),
+        name="moe_qmatmul",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(Mp // block_m, n_o),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((block_m, block_o),
+                                   lambda m, o, te, meta: (m, o)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((Mp, O), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(tile_expert, meta, x, *arrays)
+
+
+def moe_qmatmul(
+    x: jax.Array,  # [n_tiles * block_m, K] rows sorted by expert
+    ws,  # one QTensor stack [E, O, K], or the (gate, up) pair of a gated
+    # FFN; with `layer`, any field may be rank 4 and is indexed by it
+    tile_expert: jax.Array,  # [n_tiles] int32 (moe_layout)
+    n_used: jax.Array,  # scalar int32
+    block_m: int,
+    act: str | None = None,  # with a pair: y = act(x @ Wg^T) * (x @ Wu^T)
+    layer=None,  # traced index into the stacks' leading layer axis
+    out_dtype=jnp.bfloat16,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """[n_tiles * block_m, O]; rows of tiles past `n_used` are not
+    written and hold no meaning."""
+    from bigdl_tpu.ops.pallas import interpret_mode
+
+    if interpret is None:
+        interpret = interpret_mode()
+    ws = tuple(ws) if isinstance(ws, (tuple, list)) else (ws,)
+    assert len(ws) in (1, 2) and (len(ws) == 2) == (act is not None), (
+        len(ws), act)
+    w0 = ws[0]
+    assert all(w.qtype == w0.qtype and w.data.shape == w0.data.shape
+               for w in ws)
+    spec = qdecode.spec_for(w0.spec)
+    K = x.shape[-1]
+    assert x.shape[0] == tile_expert.shape[0] * block_m, (x.shape, block_m)
+
+    arrays, layered = [], []
+    for w in ws:
+        data = w.data
+        if w.spec.storage.startswith("fp8"):
+            data = jax.lax.bitcast_convert_type(data, jnp.uint8)
+        _validate(spec, K, data)
+        for a in (data, *_side_arrays(spec, w.scales, w.mins, w.sub_scales,
+                                      w.sub_mins)):
+            layered.append(a.ndim == 4)
+            arrays.append(a if a.ndim == 4 else a[None])
+    O = arrays[0].shape[-2]
+    n_w = len(ws)
+    per = 1 + spec.n_side
+    persist_row = sum(a.shape[-1] * a.dtype.itemsize for a in arrays[:per])
+    block_o = pick_block_o(O, persist_row * n_w)
+    persist = (n_w * block_o * persist_row + block_m * K * 2
+               + n_w * block_m * block_o * 4)
+    ck = chunk_target(block_o * n_w, persist, finest_split(K, spec.planes),
+                      temp_bpe=20 if spec.mins else 14)
+    meta = jnp.stack([jnp.asarray(n_used, jnp.int32),
+                      jnp.asarray(0 if layer is None else layer, jnp.int32)])
+    return _moe_qmm(spec, jnp.dtype(out_dtype), block_m, block_o, ck, n_w,
+                    act, tuple(layered), bool(interpret),
+                    tile_expert.astype(jnp.int32),
+                    meta, x.astype(jnp.bfloat16), *arrays)

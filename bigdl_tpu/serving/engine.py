@@ -31,6 +31,7 @@ import itertools
 import queue
 import threading
 import time
+import weakref
 from typing import Any, Callable, Optional
 
 import jax
@@ -60,6 +61,39 @@ def _named(name: str, fn, *bound):
     return p
 
 
+def _moe_load(choices: "np.ndarray", n_experts: int) -> dict:
+    """Span arguments from the expert choices [L, n, k] of a step's live
+    rows (or an admission's prompt tokens): how many assignments there
+    were, how many (layer, expert) pairs got any (each is one expert's
+    packed weights read), the busiest pair's, and how many pairs there
+    are."""
+    L = choices.shape[0]
+    flat = (np.arange(L)[:, None] * n_experts
+            + choices.reshape(L, -1).astype(np.int64)).ravel()
+    c = np.bincount(flat, minlength=L * n_experts)
+    return {"moe_assignments": int(c.sum()),
+            "moe_experts_hit": int(np.count_nonzero(c)),
+            "moe_max_expert_load": int(c.max(initial=0)),
+            "moe_experts": int(c.size)}
+
+
+# The newest finished request that carries its expert choices, held WEAKLY:
+# nothing is copied and nothing is kept alive, so once its caller lets go
+# of the Request this is empty again. It is how a caller that holds only a
+# token sequence (not the Request) finds the choices made on it. Its one
+# reader is bench/reference/mixtral.py, whose caller does not hand it the
+# request; when the benchmark's entry does (PERF.md section 7), this and
+# `last_routed_request` go.
+_last_routed: Optional["weakref.ref"] = None
+
+
+def last_routed_request() -> Optional["Request"]:
+    """The newest request a sparse-expert engine of this process finished
+    with a whole record of its expert choices (`Request.expert_ids`), if
+    its caller still holds it; None otherwise."""
+    return None if _last_routed is None else _last_routed()
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -87,6 +121,12 @@ class Request:
     # when the engine runs with logprobs_top_k=N: per emitted token, the
     # N most likely {token_id: logprob} alternatives
     out_top_logprobs: list[dict] = dataclasses.field(default_factory=list)
+    # sparse-expert models: the top-k expert ids chosen at every prompt
+    # position [L, T, k] int8 (None when part of the prompt came from the
+    # prefix cache and was not computed here) and at each decode step's
+    # input position ([L, k] per step); read them with expert_ids()
+    prompt_experts: Optional["np.ndarray"] = None
+    out_experts: list = dataclasses.field(default_factory=list)
     done: bool = False
     finish_reason: str = ""  # "stop" (EOS) | "length" (budget) |
     # "invalid" (rejected at submit — over-long prompt) | "error" |
@@ -110,6 +150,20 @@ class Request:
     last_token_ts: Optional[float] = None
     preempt_ts: Optional[float] = None  # set while parked in host RAM
     preempted_s: float = 0.0  # total seconds spent parked (all swaps)
+
+    def expert_ids(self, n_positions: int) -> Optional["np.ndarray"]:
+        """The top-k expert ids [L, n_positions, k] chosen at the first
+        `n_positions` positions of `prompt + out_tokens` (the serving
+        counterpart of HF's `output_router_logits`), or None: a dense
+        model, a prompt served partly from the prefix cache, or positions
+        not decoded yet. The last emitted token was never an input, so
+        there are len(prompt) + len(out_tokens) - 1 positions at most."""
+        n_out = n_positions - len(self.prompt)
+        if (self.prompt_experts is None
+                or not 0 <= n_out <= len(self.out_experts)):
+            return None
+        steps = [e[:, None] for e in self.out_experts[:n_out]]
+        return np.concatenate([self.prompt_experts] + steps, axis=1)
 
 
 @dataclasses.dataclass
@@ -170,6 +224,9 @@ class _PrefillState:
     # final chunk registers under them without re-walking the tree;
     # they cannot be evicted meanwhile (the slot holds their pages)
     chunk: int  # token budget per chunk
+    moe: list = dataclasses.field(default_factory=list)  # sparse-expert
+    # models: (expert choices on the device, tokens) of the chunks so far
+    start: int = 0  # `written` at the first chunk (0: the whole prompt)
 
 
 class InferenceEngine:
@@ -486,6 +543,29 @@ class InferenceEngine:
                     "epilogue path; adapter serving needs a llama-family "
                     "forward"
                 )
+        # sparse-expert models: a step also returns the top-k expert ids
+        # of every row ([L, B, k] int8), fetched with the tokens: the
+        # expert-load span arguments and gauges (docs/observability.md)
+        # and each request's record of its choices (Request.expert_ids).
+        # A family forward says that it reports its routing by taking
+        # moe_routing= (models/llama.forward does; deepseek.forward does
+        # not yet, and docs/observability.md says so). Dense models, and
+        # forwards that do not report, pay nothing.
+        self._moe_routing = False
+        if getattr(self.config, "is_moe", False):
+            import inspect
+
+            try:
+                self._moe_routing = ("moe_routing"
+                                     in inspect.signature(fwd).parameters)
+            except (TypeError, ValueError):  # pragma: no cover - exotic
+                pass
+        self._moe_last: Optional[tuple] = None  # newest decode step's
+        # expert ids [L, B, k] and its live rows; see moe_load
+        # (choices on the device, tokens) per chunk of the prefill being
+        # activated, and the prompt position its first chunk started at
+        self._admit_moe: list = []
+        self._admit_moe_start = 0
         self._decode = self._with_mesh(jax.jit(
             _named("engine_decode", self._decode_impl, fwd),
             donate_argnames=("cache", "seen"),
@@ -878,11 +958,23 @@ class InferenceEngine:
             start=jnp.zeros((1,), jnp.int32),
         )
         kw = {} if lora is None else {"lora": lora}
-        logits, cache = forward(
-            self.config, params, tokens, cache, mode="prefill", **kw
-        )
+        logits, cache, experts = self._forward_routing(
+            forward, params, tokens, cache, "prefill", kw)
         return (logits[0, last_idx], cache.k, cache.v, cache.k_scale,
-                cache.v_scale)
+                cache.v_scale, None if experts is None else experts[:, 0])
+
+    def _forward_routing(self, forward, params, tokens, cache, mode, kw):
+        """`forward`, and for a sparse-expert model every position's top-k
+        expert ids [L, B, T, k] as small integers (None for a dense
+        model: nothing is traced for it)."""
+        if not self._moe_routing:
+            return forward(self.config, params, tokens, cache, mode=mode,
+                           **kw) + (None,)
+        logits, cache, routing = forward(
+            self.config, params, tokens, cache, mode=mode, moe_routing=True,
+            **kw)
+        small = jnp.int8 if self.config.num_experts <= 127 else jnp.int16
+        return logits, cache, routing.astype(small)
 
     def _decode_impl(self, forward, params, cur, cache, key,
                      temp, topk, topp, dosample, seen, penalty,
@@ -895,9 +987,8 @@ class InferenceEngine:
         # output (ops/linear.lora_epilogue) — adapter-less slots carry
         # zero-padded rows and a 0 scale, contributing exactly nothing
         kw = {} if lora is None else {"lora": lora}
-        logits, cache = forward(
-            self.config, params, cur[:, None], cache, mode="decode", **kw
-        )
+        logits, cache, experts = self._forward_routing(
+            forward, params, cur[:, None], cache, "decode", kw)
         last = logits[:, -1]
         # all-default batches (every penalty 1.0) skip the O(slots x V)
         # rewrite, mirroring sample_token_per_row's all-greedy guard
@@ -920,7 +1011,8 @@ class InferenceEngine:
             tv, ti = jax.lax.top_k(step32, self.logprobs_top_k)
             top = (ti, tv - lse[:, None])
         seen = seen.at[jnp.arange(seen.shape[0]), nxt].set(True)
-        return nxt, lp, top, cache, seen
+        return (nxt, lp, top, cache, seen,
+                None if experts is None else experts[:, :, 0])
 
     def _spec_decode_impl(self, forward, k_draft, params, dparams, cur, cache,
                           dcache, key, temp, topk, topp, dosample, seen,
@@ -1395,7 +1487,7 @@ class InferenceEngine:
             self._slots[slot] = _Slot(req=req, seq=next(self._seq))
             self._prefilling = _PrefillState(
                 req=req, slot=slot, row=row, written=lp_eff,
-                path=path, chunk=chunk,
+                path=path, chunk=chunk, start=lp_eff,
             )
             return True
 
@@ -1405,13 +1497,16 @@ class InferenceEngine:
         toks = np.full((1, bucket), self.gen.pad_token_id, np.int32)
         toks[0, : len(tail2)] = tail2  # RIGHT pad: writes past pos get
         # overwritten by decode and are masked meanwhile
-        logits_last, k, v, ks, vs = self._paged_prefill(
+        logits_last, k, v, ks, vs, experts = self._paged_prefill(
             self.model.params, self.cache.k, self.cache.v,
             self.cache.k_scale, self.cache.v_scale,
             jnp.asarray(row[None]), jnp.asarray([lp_eff], jnp.int32),
             jnp.asarray(toks), jnp.asarray(len(tail2) - 1),
             lora=self._prefill_lora(req),
         )
+        if experts is not None:
+            self._admit_moe = [(experts, len(tail2))]
+            self._admit_moe_start = lp_eff
         self.cache = dataclasses.replace(
             self.cache, k=k, v=v, k_scale=ks, v_scale=vs,
             pos=self.cache.pos.at[slot].set(len(prompt)),
@@ -1464,13 +1559,15 @@ class InferenceEngine:
         toks = np.full((1, bucket), self.gen.pad_token_id, np.int32)
         toks[0, :n] = prompt[st.written: st.written + n]
         self.prefill_chunks += 1
-        logits_last, k, v, ks, vs = self._paged_prefill(
+        logits_last, k, v, ks, vs, moe = self._paged_prefill(
             self.model.params, self.cache.k, self.cache.v,
             self.cache.k_scale, self.cache.v_scale,
             jnp.asarray(st.row[None]), jnp.asarray([st.written], jnp.int32),
             jnp.asarray(toks), jnp.asarray(n - 1),
             lora=self._prefill_lora(st.req),
         )
+        if moe is not None:
+            st.moe.append((moe, n))
         self.cache = dataclasses.replace(
             self.cache, k=k, v=v, k_scale=ks, v_scale=vs,
         )
@@ -1490,6 +1587,7 @@ class InferenceEngine:
         self._slot_pos[slot] = len(prompt)
         self._register_prefix(prompt, st.path, self._slot_pages[slot],
                               ns=st.req.adapter)
+        self._admit_moe, self._admit_moe_start = st.moe, st.start
         self._activate(slot, st.req, logits_last[None])
 
     def _admit_draft(self, slot: int, prompt: list[int], limit: int) -> None:
@@ -2029,6 +2127,9 @@ class InferenceEngine:
         reason = req.finish_reason or "?"
         with self._stat_lock:
             self.finish_reasons[reason] += 1
+        if req.prompt_experts is not None:
+            global _last_routed
+            _last_routed = weakref.ref(req)
         entry = self._adapter_refs.pop(req.rid, None)
         if entry is not None:
             # the request's one adapter hold releases exactly at its
@@ -2220,6 +2321,15 @@ class InferenceEngine:
         # queued | prefill | decode windows ...
         now = self._clock()
         rt_arm = self._retrace_mark("first_token.arm")
+        moe_args = {}
+        if self._admit_moe:  # the prefill has run (first-token sync above)
+            chosen = np.concatenate(
+                [np.asarray(a)[:, :n] for a, n in self._admit_moe], axis=1)
+            if self._admit_moe_start == 0:  # nothing came from the cache
+                req.prompt_experts = chosen
+            if tr is not None and tr.enabled:
+                moe_args = _moe_load(chosen, self.config.num_experts)
+            self._admit_moe = []
         if req.admit_ts is not None:
             self.prefill_seconds.observe(now - req.admit_ts)
             if tr is not None and tr.enabled:
@@ -2228,7 +2338,7 @@ class InferenceEngine:
                             prompt_tokens=len(req.prompt),
                             # the streams this admission stalled
                             occupancy=int(self.active.sum()) - 1,
-                            queue_depth=self._queue.qsize())
+                            queue_depth=self._queue.qsize(), **moe_args)
                 if t_sampled is not None:
                     tr.complete_parts(
                         req.admit_ts, now - req.admit_ts,
@@ -2691,7 +2801,7 @@ class InferenceEngine:
         self._retrace_mark("other")
         t0 = self._clock()
         try:
-            nxt, lps, top, self.cache, self.seen = self._decode(
+            nxt, lps, top, self.cache, self.seen, moe = self._decode(
                 self.model.params, self.cur, self.cache, k,
                 jnp.asarray(self._temp), jnp.asarray(self._topk),
                 jnp.asarray(self._topp), jnp.asarray(self._dosample),
@@ -2711,6 +2821,11 @@ class InferenceEngine:
         tops_h = None
         if top is not None:
             tops_h = (np.asarray(top[0]), np.asarray(top[1]))
+        experts_h = None
+        if moe is not None:
+            experts_h = np.asarray(moe)  # [L, B, k]
+            # counted only when a span or a gauge reads it (moe_load)
+            self._moe_last = (experts_h, self.active.copy())
         # the np.asarray fetches above are the host sync: the step's
         # device work is really done here, so the duration is honest
         self._note_decode_step(t0, dispatched)
@@ -2731,6 +2846,8 @@ class InferenceEngine:
             s.remaining -= 1
             if self.paged:
                 self._slot_pos[i] += 1
+            if experts_h is not None:
+                s.req.out_experts.append(experts_h[:, i])
             alt = None
             if tops_h is not None:
                 alt = {int(t): float(l)
@@ -2764,7 +2881,8 @@ class InferenceEngine:
                     self.paged_grid_pages()
             tr.complete("decode_step", t0, t1 - t0, tid=0, cat="engine",
                         occupancy=busy, slots=self.n_slots,
-                        queue_depth=self._queue.qsize(), **pages)
+                        queue_depth=self._queue.qsize(), **pages,
+                        **self.moe_load())
             if dispatched is not None:
                 tr.complete_parts(
                     t0, t1 - t0, (dispatched[0],),
@@ -2990,6 +3108,15 @@ class InferenceEngine:
         live = sum(min(self._slot_pos[int(i)] // self.page_size + 1, mp)
                    for i in np.nonzero(self.active)[0])
         return live, self.n_slots * mp
+
+    def moe_load(self) -> dict:
+        """The newest decode step's expert load (the `moe_*` arguments of
+        its `decode_step` span, and the `/metrics` gauges): {} for a dense
+        model or before the first step."""
+        if self._moe_last is None:
+            return {}
+        experts, live = self._moe_last
+        return _moe_load(experts[:, live], self.config.num_experts)
 
     def kv_utilization(self) -> float:
         """Fraction of the KV pool holding live state: allocated pages

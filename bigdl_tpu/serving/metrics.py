@@ -411,6 +411,28 @@ class Metrics:
                     "# TYPE bigdl_tpu_radix_nodes gauge",
                     f"bigdl_tpu_radix_nodes {self.engine.radix.n_nodes}",
                 ]
+            if getattr(self.engine, "_moe_routing", False):
+                # sparse-expert models: the newest decode step's expert
+                # load (the `moe_*` arguments of its `decode_step` span)
+                load = self.engine.moe_load()
+                n, pairs = (load.get("moe_assignments", 0),
+                            load.get("moe_experts", 0))
+                lines += [
+                    "# HELP bigdl_tpu_moe_expert_load_imbalance busiest "
+                    "(layer, expert) pair's assignments over the mean per "
+                    "pair in the newest decode step (1 = even; 0 before "
+                    "the first step)",
+                    "# TYPE bigdl_tpu_moe_expert_load_imbalance gauge",
+                    f"bigdl_tpu_moe_expert_load_imbalance "
+                    f"{load.get('moe_max_expert_load', 0) * pairs / max(n, 1):.4f}",
+                    "# HELP bigdl_tpu_moe_experts_hit_share fraction of "
+                    "the (layer, expert) pairs that got an assignment in "
+                    "the newest decode step; each is one expert's packed "
+                    "weights read, the rest are skipped",
+                    "# TYPE bigdl_tpu_moe_experts_hit_share gauge",
+                    f"bigdl_tpu_moe_experts_hit_share "
+                    f"{load.get('moe_experts_hit', 0) / max(pairs, 1):.4f}",
+                ]
             if getattr(self.engine, "adapters", None) is not None:
                 # multi-tenant LoRA registry (serving/adapters.py §7)
                 st = self.engine.adapters.stats()
@@ -531,6 +553,11 @@ _PAGED_FAMILIES = (
     "bigdl_tpu_radix_nodes",
 )
 
+_MOE_FAMILIES = (
+    "bigdl_tpu_moe_expert_load_imbalance",
+    "bigdl_tpu_moe_experts_hit_share",
+)
+
 _SPEC_FAMILIES = (
     "bigdl_tpu_spec_rounds_total",
     "bigdl_tpu_spec_emitted_total",
@@ -555,6 +582,8 @@ def expected_families(engine=None) -> list:
         names += _ENGINE_FAMILIES
         if getattr(engine, "paged", False):
             names += _PAGED_FAMILIES
+        if getattr(engine, "_moe_routing", False):
+            names += _MOE_FAMILIES
         if getattr(engine, "adapters", None) is not None:
             names += _ADAPTER_FAMILIES
         if getattr(engine, "speculative", False):

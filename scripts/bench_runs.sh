@@ -1,0 +1,29 @@
+#!/usr/bin/env bash
+# Runs of benchmark cells in ONE chip call, each run's whole output kept
+# under chiprun_out/ and its result line printed (PERF.md quotes them).
+#
+#   scripts/bench_runs.sh TAG DIR TRACE CELL SEED [CELL SEED ...]
+#
+# DIR is a checkout to run from: `.` for the tree, or a directory that
+# .gitignore lists holding another commit (`git archive <commit> | tar -x -C
+# .bench_checkout/parent`), with this PR's benchmark files laid over it where
+# the comparison asks for that. A parent-against-change reading is four of
+# these in one `chiprun -- bash -c '...'`, in the order parent, change,
+# change, parent, each pair of sides at the same seeds. Never stops at a
+# failing run: the exit code of each is printed.
+set -u
+tag=$1 dir=$2 trace=$3
+shift 3
+root=$(cd "$(dirname "$0")/.." && pwd)
+seconds=$(python3 -c "import json; print(json.load(open('$root/BENCHMARK.json'))['run_seconds'])")
+mkdir -p "$root/chiprun_out"
+while [ $# -ge 2 ]; do
+    cell=$1 seed=$2
+    shift 2
+    log="$root/chiprun_out/${tag}_${cell}_${seed}_t${trace}.log"
+    (cd "$root/$dir" && python3 bench/run.py --workload "$cell" --seed "$seed" \
+        --seconds "$seconds" --trace "$trace") >"$log" 2>&1
+    echo "$tag $cell $seed trace$trace exit $?"
+    grep -a "reference check" "$log" | cut -c1-400
+    tail -n 1 "$log" | cut -c1-3000
+done
