@@ -3,8 +3,10 @@
 An un-jitted JAX call (`lax.cond` over fresh closures, `.at[].set`, a
 `jnp` function on the host path) is traced, lowered and compiled — or
 fetched from the persistent cache — again on every call, on the calling
-thread, while the device may sit idle. The engine's admission pays that
-per request (PERF.md). `jax.monitoring` reports each such duration; ONE
+thread, while the device may sit idle. The engine's admission paid that
+per request until its first-token work became one program built once per
+engine (PERF.md, PR 27); the counter is what says it stays so.
+`jax.monitoring` reports each such duration; ONE
 listener per process adds them to an accumulator of the thread they ran
 on, and whoever owns that thread (the engine's step loop) reads it at
 its phase boundaries: the difference between two reads is what was paid

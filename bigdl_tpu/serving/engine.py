@@ -61,6 +61,19 @@ def _named(name: str, fn, *bound):
     return p
 
 
+def _read_first_token(out, n_top: int) -> tuple:
+    """Unpack what `_first_token_impl` packs for the host, int32
+    [2 + 2 * n_top]: the token, its logprob's bits, then the `n_top` most
+    likely ids and their logprobs' bits. Returns (token, logprob, {id:
+    logprob} or None)."""
+    out = np.asarray(out)  # the admission's one fetch
+    top = None
+    if n_top:
+        top = {int(t): float(l) for t, l in zip(
+            out[2:2 + n_top], out[2 + n_top:].view(np.float32))}
+    return int(out[0]), float(out[1:2].view(np.float32)[0]), top
+
+
 def _moe_load(choices: "np.ndarray", n_experts: int) -> dict:
     """Span arguments from the expert choices [L, n, k] of a step's live
     rows (or an admission's prompt tokens): how many assignments there
@@ -484,6 +497,8 @@ class InferenceEngine:
         # the all-1.0 common case skips the rewrite via a lax.cond in
         # _decode_impl
         self.seen = jnp.zeros((n_slots, self.config.vocab_size), jnp.bool_)
+        # the `seen` row of a request with no penalty, made once
+        self._no_seen_row = jnp.zeros((self.config.vocab_size,), jnp.bool_)
 
         # ---- multi-tenant LoRA adapters (serving/adapters.py) ----
         self.adapters = adapters
@@ -569,6 +584,12 @@ class InferenceEngine:
         self._decode = self._with_mesh(jax.jit(
             _named("engine_decode", self._decode_impl, fwd),
             donate_argnames=("cache", "seen"),
+        ))
+        # the admission's own program (_first_token_impl): like the decode
+        # step's, built once per engine, so an admission compiles nothing
+        self._first_token = self._with_mesh(jax.jit(
+            _named("engine_first_token", self._first_token_impl),
+            donate_argnames=("cur", "seen"),
         ))
         self._prefill = self._with_mesh(jax.jit(
             _named("engine_prefill", self._prefill_impl, fwd),
@@ -975,6 +996,41 @@ class InferenceEngine:
             **kw)
         small = jnp.int8 if self.config.num_experts <= 127 else jnp.int16
         return logits, cache, routing.astype(small)
+
+    def _first_token_impl(self, logits, rng, temp, topk, topp, dosample,
+                          penalty, row, slot, cur, seen):
+        """An admission's device work as ONE program: split the engine's
+        key, penalise and sample the first token from the prefill's
+        last-row logits, arm the slot's `cur` and `seen` (both donated),
+        and pack what the host reads into one int32 vector
+        (_read_first_token). Everything a request chooses is a traced input, so
+        one executable serves greedy, sampled and penalised requests;
+        sample_token_per_row's cond keeps the all-greedy case off the
+        full-vocab sort. The key stream is the eager one's: the engine's
+        key is split once per admission, as once per decode step, so an
+        engine seeded alike and fed alike samples alike."""
+        from bigdl_tpu.generate import apply_repetition_penalty
+
+        logits = logits.reshape(1, -1)
+        rng, key = jax.random.split(rng)
+        # no penalty arrives as 1.0 with an all-False row: the identity
+        logits = apply_repetition_penalty(logits, row[None], penalty)
+        with jax.named_scope("sample"):
+            first = sample_token_per_row(
+                logits, key, temp[None], topk[None], topp[None],
+                dosample[None])[0]
+        row_lp = jax.nn.log_softmax(logits.astype(jnp.float32).reshape(-1))
+
+        def bits(x):
+            return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+        out = [first[None], bits(row_lp[first])[None]]
+        if self.logprobs_top_k:  # static: an engine constant
+            tv, ti = jax.lax.top_k(row_lp, self.logprobs_top_k)
+            out += [ti.astype(jnp.int32), bits(tv)]
+        cur = cur.at[slot].set(first)
+        seen = seen.at[slot].set(row).at[slot, first].set(True)
+        return cur, seen, rng, jnp.concatenate(out)
 
     def _decode_impl(self, forward, params, cur, cache, key,
                      temp, topk, topp, dosample, seen, penalty,
@@ -1521,7 +1577,7 @@ class InferenceEngine:
             # always prefills its full context into the dense draft pool
             self._admit_draft(slot, prompt, limit)
 
-        self._activate(slot, req, logits_last[None])
+        self._activate(slot, req, logits_last)
         return True
 
     def _register_prefix(self, prompt: list[int], path: list,
@@ -1588,7 +1644,7 @@ class InferenceEngine:
         self._register_prefix(prompt, st.path, self._slot_pages[slot],
                               ns=st.req.adapter)
         self._admit_moe, self._admit_moe_start = st.moe, st.start
-        self._activate(slot, st.req, logits_last[None])
+        self._activate(slot, st.req, logits_last)
 
     def _admit_draft(self, slot: int, prompt: list[int], limit: int) -> None:
         """Left-pad-prefill the speculative draft pool's row for a newly
@@ -2267,33 +2323,25 @@ class InferenceEngine:
         penalty = (req.repetition_penalty
                    if req.repetition_penalty is not None
                    else self.gen.repetition_penalty)
+        row = self._no_seen_row
         if penalty != 1.0:
-            from bigdl_tpu.generate import apply_repetition_penalty, \
-                seen_from_prompt
-
-            prompt_arr = np.asarray([req.prompt], np.int32)
-            row = seen_from_prompt(
-                jnp.asarray(prompt_arr), jnp.zeros((1,), jnp.int32),
-                self.config.vocab_size,
-            )[0]
-            logits_last = apply_repetition_penalty(
-                logits_last, row[None], jnp.asarray(penalty, jnp.float32)
-            )
-        else:
-            row = jnp.zeros((self.config.vocab_size,), jnp.bool_)
-        self._rng, k = jax.random.split(self._rng)
-        first = int(sample_token_per_row(
-            logits_last, k,
-            jnp.asarray([temp], jnp.float32),
-            jnp.asarray([topk], jnp.int32),
-            jnp.asarray([topp], jnp.float32),
-            jnp.asarray([dosample], jnp.bool_),
-        )[0])
-        # the first host sync: the prefill program has run by now
+            # the prompt's presence mask, built here: a program over the
+            # prompt would be one per distinct length
+            row = np.zeros((self.config.vocab_size,), bool)
+            ids = np.asarray(req.prompt, np.int64)
+            row[ids[(ids >= 0) & (ids < row.size)]] = True
+        self.cur, self.seen, self._rng, out = self._first_token(
+            logits_last, self._rng, np.float32(temp), np.int32(topk),
+            np.float32(topp), np.bool_(dosample), np.float32(penalty),
+            row, np.int32(slot), cur=self.cur, seen=self.seen,
+        )
+        # the admission's one host sync: the prefill program has run
+        # by now
+        first, first_lp, first_top = _read_first_token(
+            out, self.logprobs_top_k)
         rt_sample = self._retrace_mark("first_token.sample")
         if t_enter is not None:
             t_sampled = self._clock()
-        self.cur = self.cur.at[slot].set(first)
         eos = (req.eos_token_id if req.eos_token_id is not None
                else self.gen.eos_token_id)
         self._slots[slot] = _Slot(
@@ -2303,18 +2351,8 @@ class InferenceEngine:
         self._temp[slot], self._topk[slot] = temp, topk
         self._topp[slot], self._dosample[slot] = topp, dosample
         self._penalty[slot] = penalty
-        self.seen = self.seen.at[slot].set(row).at[slot, first].set(True)
         self._set_slot_adapter(slot, req)
         self.active[slot] = True
-        row_lp = jax.nn.log_softmax(
-            jnp.asarray(logits_last, jnp.float32).reshape(-1)
-        )
-        first_lp = float(row_lp[first])
-        first_top = None
-        if self.logprobs_top_k:
-            tv, ti = jax.lax.top_k(row_lp, self.logprobs_top_k)
-            first_top = {int(t): float(l)
-                         for t, l in zip(np.asarray(ti), np.asarray(tv))}
         # prefill phase closes HERE (the first-token sample above was a
         # host sync, so the span covers real work), strictly before the
         # first emit — the request track stays monotonically nested:

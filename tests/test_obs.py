@@ -349,6 +349,7 @@ def test_phase_spans_partition_admission_and_step(model, kind):
 
     prefills = [e for e in spans if e["name"] == "prefill"]
     assert sorted(e["tid"] for e in prefills) == sorted(r.rid for r in reqs)
+    first_admitted = min(prefills, key=lambda e: e["ts"])
     for p in prefills:
         kids = [k for k in _children(events, p)
                 if k["name"] in ADMISSION_PARTS]
@@ -357,8 +358,11 @@ def test_phase_spans_partition_admission_and_step(model, kind):
         assert all(k["cat"] == "request" and k["args"]["rid"] == p["tid"]
                    and k["args"]["retrace_s"] >= 0 for k in kids)
         assert kids[0]["args"]["prompt_tokens"] == 20
-        # the un-jitted first-token sampling is retraced for every request
-        assert kids[1]["args"]["retrace_s"] > 0
+        # the first-token program is built by the engine's first
+        # admission; no later one traces, lowers or compiles anything
+        if p is not first_admitted:
+            assert kids[1]["args"]["retrace_s"] == 0
+            assert kids[2]["args"]["retrace_s"] == 0
         assert set(p["args"]) == {"rid", "prompt_tokens", "occupancy",
                                   "queue_depth"}
         assert 0 <= p["args"]["occupancy"] < eng.n_slots
@@ -447,10 +451,11 @@ def test_tracing_off_costs_no_clock_call(model, kind):
 
 def test_retrace_counter_books_to_the_phase_that_paid(model):
     """A program traced inside the decode call lands in `decode_step`
-    and nowhere else; the per-request retrace of the first-token sampling
-    lands in `first_token.sample`; a second engine in the process does
-    not register a second listener (the seconds are not doubled); and
-    /metrics renders both families with no drift."""
+    and nowhere else; the first-token program, built by the engine's
+    first admission, lands in `first_token.sample`, and from the second
+    admission on neither `first_token` phase grows; a second engine in
+    the process does not register a second listener (the seconds are not
+    doubled); and /metrics renders both families with no drift."""
     import jax.monitoring as mon
     import jax.numpy as jnp
 
@@ -492,6 +497,12 @@ def test_retrace_counter_books_to_the_phase_that_paid(model):
     finally:
         mon.unregister_event_duration_listener(listener)
     eng.run_until_idle()
+    for prompt in ([9, 8, 7], [1, 2, 3, 4, 5, 6, 7]):  # further admissions
+        eng.submit(prompt, max_new_tokens=2)
+        eng.run_until_idle()
+    for phase in ("first_token.sample", "first_token.arm"):
+        assert eng.retraces[phase] == n_before[phase]
+        assert eng.retrace_seconds[phase] == before[phase]
     assert other.retrace_seconds == dict.fromkeys(other.retrace_seconds, 0.0)
 
     text = Metrics(eng).render()

@@ -720,3 +720,161 @@ def test_completions_top_logprobs_honors_requested_count(model):
         assert len(out0["choices"][0]["logprobs"]["token_logprobs"]) == 3
     finally:
         srv.shutdown()
+
+
+# ---- an admission compiles nothing (the engine's first-token program) ----
+
+ADMISSION_PATHS = {
+    "dense": {},
+    "paged": {"paged": True, "page_size": 16},
+    "chunked": {"paged": True, "page_size": 16, "prefill_chunk_tokens": 16},
+}
+# per request kind: the engine's options and those of request i
+REQUEST_KINDS = {
+    "greedy": ({}, lambda i: {}),
+    "sampled": ({}, lambda i: {"do_sample": True,
+                               "temperature": 0.6 + 0.1 * i,
+                               "top_k": (0, 5, 40)[i % 3],
+                               "top_p": (1.0, 0.9, 0.5)[i % 3]}),
+    "penalty": ({}, lambda i: {"repetition_penalty": 1.3}),
+    "top-logprobs": ({"logprobs_top_k": 3}, lambda i: {}),
+}
+PROMPT_LENGTHS = (5, 23, 70)  # three lengths, three dense prefill buckets
+
+
+@pytest.mark.parametrize("kind", list(REQUEST_KINDS))
+@pytest.mark.parametrize("path", list(ADMISSION_PATHS))
+def test_admission_compiles_nothing_after_warmup(model, path, kind,
+                                                 monkeypatch):
+    """After one request of each prompt length, six further admissions
+    trace, lower and compile nothing between the end of
+    `prefill.dispatch` and the emit (all of `_activate`), whatever the
+    request asks for, and each reads the device once."""
+    from jax._src import array as jax_array
+
+    from bigdl_tpu.obs import retrace
+
+    eng_kw, req_kw = REQUEST_KINDS[kind]
+    eng = InferenceEngine(model, n_slots=2, max_len=128,
+                          **ADMISSION_PATHS[path], **eng_kw)
+    acc = retrace.thread_accumulator()
+    paid, fetches = [], [0]
+    # a device array reaches the host through np.asarray (the buffer
+    # protocol on the CPU) or through int() / float() / .item(), which
+    # read ArrayImpl._value
+    value, asarray = jax_array.ArrayImpl._value, np.asarray
+
+    def counting_value(self):
+        fetches[0] += 1
+        return value.fget(self)
+
+    def counting_asarray(a, *args, **kw):
+        fetches[0] += isinstance(a, jax.Array)
+        return asarray(a, *args, **kw)
+
+    activate = eng._activate
+
+    def counted(*a, **k):
+        n0, s0, f0 = acc.programs, acc.seconds, fetches[0]
+        activate(*a, **k)
+        paid.append((acc.programs - n0, acc.seconds - s0, fetches[0] - f0))
+
+    eng._activate = counted
+    monkeypatch.setattr(jax_array.ArrayImpl, "_value",
+                        property(counting_value))
+    monkeypatch.setattr(np, "asarray", counting_asarray)
+
+    def serve(i):
+        n = PROMPT_LENGTHS[i % 3]
+        r = eng.submit([(7 * i + j) % CFG.vocab_size for j in range(n)],
+                       max_new_tokens=3, **req_kw(i))
+        eng.run_until_idle(max_steps=100)
+        assert r.done and len(r.out_tokens) == 3
+
+    for i in range(3):  # warm-up: one of each length
+        serve(i)
+    assert eng.retraces["first_token.sample"] == 1  # built once
+    del paid[:]
+    counts = dict(eng.retraces)
+    for i in range(3, 9):
+        serve(i)
+    eng.close()
+    assert paid == [(0, 0.0, 1)] * 6
+    for phase in ("first_token.sample", "first_token.arm"):
+        assert eng.retraces[phase] == counts[phase]
+
+
+def _eager_first_token(logits, rng, temp, topk, topp, dosample, penalty,
+                       row, slot, cur, seen, n_top):
+    """What `_activate` ran op by op before the engine owned a program:
+    the formulation `_first_token_impl` has to reproduce."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.generate import (apply_repetition_penalty,
+                                    sample_token_per_row)
+
+    rng, k = jax.random.split(rng)
+    if penalty != 1.0:
+        logits = apply_repetition_penalty(
+            logits, row[None], jnp.asarray(penalty, jnp.float32))
+    first = int(sample_token_per_row(
+        logits, k,
+        jnp.asarray([temp], jnp.float32), jnp.asarray([topk], jnp.int32),
+        jnp.asarray([topp], jnp.float32), jnp.asarray([dosample], jnp.bool_),
+    )[0])
+    cur = cur.at[slot].set(first)
+    seen = seen.at[slot].set(row).at[slot, first].set(True)
+    row_lp = jax.nn.log_softmax(jnp.asarray(logits, jnp.float32).reshape(-1))
+    tv, ti = jax.lax.top_k(row_lp, n_top)
+    top = {int(t): float(l) for t, l in zip(np.asarray(ti), np.asarray(tv))}
+    return (first, float(row_lp[first]), top, np.asarray(cur),
+            np.asarray(seen), np.asarray(rng))
+
+
+@pytest.mark.parametrize("penalty", [1.0, 1.3], ids=["plain", "penalised"])
+@pytest.mark.parametrize("dosample", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("vocab", [32000, 1003])
+def test_first_token_program_agrees_with_eager(model, vocab, dosample,
+                                               penalty):
+    """The engine's first-token program against the eager formulation it
+    replaced: token, logprob, top-k logprobs, `cur` and the slot's `seen`
+    row bit for bit on greedy rows, with and without a penalty; the same
+    token for the same key when sampling, and the same key left behind.
+    The program's shapes are its inputs', so any vocabulary runs through
+    a tiny engine's."""
+    import jax.numpy as jnp
+
+    from bigdl_tpu.serving.engine import _read_first_token
+
+    n_top, slots, slot = 4, 3, 1
+    eng = InferenceEngine(model, n_slots=2, max_len=64, logprobs_top_k=n_top)
+    rs = np.random.default_rng(vocab + 2 * dosample)
+    for trial in range(3):
+        logits = jnp.asarray(
+            rs.normal(0.0, 3.0, (1, vocab)).astype(np.float32))
+        row = np.zeros((vocab,), bool)
+        if penalty != 1.0:
+            row[rs.integers(0, vocab, 50)] = True
+            row[int(np.argmax(logits))] = True  # the penalty moves the best
+        rng = jax.random.PRNGKey(11 + trial)
+        cur = jnp.asarray(rs.integers(0, vocab, slots), jnp.int32)
+        seen = jnp.asarray(rs.random((slots, vocab)) < 0.01)
+        temp, topk, topp = 0.8, (0, 50, 7)[trial], (1.0, 0.9, 0.6)[trial]
+        want = _eager_first_token(
+            logits, rng, temp, topk, topp, dosample, penalty,
+            jnp.asarray(row), slot, cur, seen, n_top)
+        cur2, seen2, rng2, out = eng._first_token(
+            logits, rng, np.float32(temp), np.int32(topk), np.float32(topp),
+            np.bool_(dosample), np.float32(penalty), row, np.int32(slot),
+            cur=cur, seen=seen)
+        assert out.dtype == np.int32 and out.shape == (2 + 2 * n_top,)
+        first, first_lp, top = _read_first_token(out, n_top)
+        assert first == want[0]
+        np.testing.assert_array_equal(np.asarray(cur2), want[3])
+        np.testing.assert_array_equal(np.asarray(seen2), want[4])
+        np.testing.assert_array_equal(np.asarray(rng2), want[5])
+        if dosample:  # the filter's sort is not held to the bit
+            assert first_lp == pytest.approx(want[1], rel=1e-6)
+        else:  # floats compare exactly: bit for bit (no NaN here)
+            assert (first_lp, top) == (want[1], want[2])
+    eng.close()
