@@ -84,8 +84,9 @@ def _is_self_attr(node: ast.AST) -> Optional[str]:
 
 
 def _is_alloc_name(attr: Optional[str]) -> bool:
-    """Page-allocator naming convention: ``pool.alloc()`` and the
-    ``self._alloc_page*`` / injected ``self._alloc`` wrappers around it.
+    """Page-allocator naming convention: ``pool.alloc()``, the ladder
+    around it (``PageTable.alloc``, serving/pages.py) and the
+    ``self._alloc_page*`` / injected ``self._alloc`` wrappers around that.
     Name-based so callable attributes (AdapterPager's ``_alloc`` is a
     constructor-injected closure) count even when unresolvable."""
     return attr is not None and (attr == "alloc" or attr.startswith("_alloc"))
@@ -503,7 +504,7 @@ class Project:
         """Whether *fi* returns a freshly-acquired page ref to its caller.
 
         Fixpoint over "returns a var assigned from ``.alloc()`` or from
-        a returns_ref callee" (covers Engine._alloc_page and the
+        a returns_ref callee" (covers PageTable.alloc and the engine's
         preempting wrapper around it without hand-listing either).
         """
         self._compute_returns_ref()
@@ -880,7 +881,7 @@ class _PageInterp:
                         # Moves into a local list: list now owns it.
                         s.live[rname] = min(acq, s.live.get(rname, acq))
                     else:
-                        # self._slot_pages[slot].append(pg): transferred.
+                        # self.slot_pages[slot].append(pg): transferred.
                         s.escaped.add(nm)
             else:
                 # Passing a name to a callee that captures it transfers

@@ -108,7 +108,7 @@ def live_rows(cache: PagedKVCache) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Host-side page accounting (serving/engine.py + serving/radix.py)
+# Host-side page accounting (serving/pages.py + serving/radix.py)
 # ---------------------------------------------------------------------------
 
 

@@ -50,7 +50,11 @@ def _load() -> Optional[ctypes.CDLL]:
                 tag = hashlib.sha256(f.read()).hexdigest()[:16]
             so = os.path.join(_build_dir(), f"quant_kernels_{tag}.so")
             if not os.path.exists(so):
-                tmp = so + ".tmp"
+                # a name of this process's own: several processes that
+                # start with no build yet (test workers in a fresh HOME)
+                # each compile and each rename a whole file; with one
+                # shared name all but the first lost theirs half-written
+                tmp = f"{so}.{os.getpid()}.tmp"
                 subprocess.run(
                     [
                         "g++", "-O3", "-march=native", "-fopenmp", "-shared",

@@ -595,7 +595,7 @@ class AdapterPager:
       drops its device pages (decref -> free list). The host copy in
       the AdapterRegistry survives, so "page-out to host" is a free
       drop, and the next request naming the tenant pages back in.
-    * eviction order under page pressure (engine._alloc_page): radix
+    * eviction order under page pressure (PageTable.alloc): radix
       leaf -> refcount-0 adapter page-out -> preemption.
 
     ``scale`` stays host-side registry metadata (f32) — only the bf16
@@ -719,10 +719,11 @@ class AdapterPager:
         del self._res[victim.name]
         return True
 
-    def reset(self, pool) -> None:
-        """Post-crash rebuild (engine._reset_state): the old PagePool
+    def reset(self, pool, alloc: Callable[[], Optional[int]]) -> None:
+        """Post-crash rebuild (PageTable.rebuilt): the old PagePool
         died with the cache, so residency is simply forgotten — no
         decrefs against a pool that no longer exists. Counters survive
         (engine totals, not cache state)."""
         self._pool = pool
+        self._alloc = alloc
         self._res.clear()

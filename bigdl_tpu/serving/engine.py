@@ -38,18 +38,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu import kvcache
+from bigdl_tpu import kvcache, kvpaged
 from bigdl_tpu.generate import GenerationConfig, sample_token_per_row
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.obs import retrace
 from bigdl_tpu.serving.faults import NULL_INJECTOR, FaultError
 from bigdl_tpu.serving.metrics import Histogram
+from bigdl_tpu.serving.pages import NeverFits, PageTable, prefill_bucket
 from bigdl_tpu.utils import round_up
 
 #: where the stepping thread can pay for a retrace: the three child spans
 #: of an admission, a decode step, and everything between them
 RETRACE_PHASES = ("prefill.dispatch", "first_token.sample",
                   "first_token.arm", "decode_step", "other")
+
+#: decode tokens coalesced into one `decode` span of a request's track
+TRACE_DECODE_EVERY = 8
 
 
 def _named(name: str, fn, *bound):
@@ -220,8 +224,9 @@ class _Preempted:
 
 @dataclasses.dataclass
 class _PrefillState:
-    """A request mid-chunked-prefill: it owns its slot and its fully
-    allocated page table, but `active` stays False (no decode) and the
+    """A request's prefill, in chunks (a monolithic prefill is one,
+    run at once): mid-plan it owns its slot and its fully allocated
+    page table, but `active` stays False (no decode) and the
     engine's block-table row stays pointed at the scratch page until
     the last chunk lands — idle-slot garbage decode writes must never
     reach the half-filled (possibly shared) real pages. Chunks write
@@ -285,8 +290,6 @@ class InferenceEngine:
         preemption: bool = True,  # page-pool exhaustion mid-decode swaps
         # a victim's KV to host RAM and requeues it instead of silently
         # truncating its output with "length"
-        preemption_policy: str = "youngest",  # victim choice: "youngest"
-        # (least progress lost, default) or "oldest"
         faults: Optional[Any] = None,  # FaultInjector (serving/faults.py);
         # None = the shared inert injector (zero-cost hooks)
         adapters: Optional[Any] = None,  # AdapterRegistry
@@ -300,7 +303,6 @@ class InferenceEngine:
         # recorded only while tracer.enabled (off = one attr check)
         request_log: Optional[str] = None,  # JSONL path: one derived-
         # timings record per finished request (crc-suffixed lines)
-        trace_decode_every: int = 8,  # decode tokens coalesced per span
         clock: Callable[[], float] = time.time,  # every lifecycle
         # timestamp (deadlines, spans, histograms) flows through this —
         # the simulated-clock benchmark drives the engine with a fake one
@@ -310,7 +312,6 @@ class InferenceEngine:
         # the end of __init__ already stamp timestamps and record finishes
         self._clock = clock
         self.tracer = tracer
-        self.trace_decode_every = max(int(trace_decode_every), 1)
         self._request_log = None
         if request_log is not None:
             from bigdl_tpu.obs.tracing import RequestLog
@@ -434,39 +435,14 @@ class InferenceEngine:
         # holds its slot and pages but is NOT decoded (active stays
         # False) until its last chunk lands. Engine-thread only.
         self._prefilling: Optional[_PrefillState] = None
-        if paged:
-            # physical page 0 is the scratch sink: idle slots still run
-            # the decode step (static-shape price) and their masked
-            # garbage writes go through their block tables — released
-            # slots point every entry at page 0 so they can never corrupt
-            # pages reallocated to live requests
-            from bigdl_tpu import kvpaged
-            from bigdl_tpu.serving.radix import RadixPrefixCache
-
-            # refcounted page accounting: one hold per slot block-table
-            # entry + one per cached radix node (kvpaged.PagePool);
-            # _free_pages/_page_ref stay as live views of the pool's
-            # lists (metrics.py and the sim driver read them)
-            self._pool = kvpaged.PagePool(self.n_pages)
-            self._free_pages = self._pool.free
-            self._page_ref = self._pool.ref
-            # radix-tree prefix cache (serving/radix.py): full-page
-            # descent + mid-page divergence match + leaf-first LRU
-            # eviction; replaced the flat tuple(prefix)-hash cache
-            self.radix = RadixPrefixCache(page_size, self._pool)
-            self._slot_pages: list[list[int]] = [[] for _ in range(n_slots)]
-            self._slot_written: list[int] = [0] * n_slots  # logical slots covered
-            self.prefix_hits = 0
-            # sub-page sharing: cached-page KV copied instead of
-            # re-prefilled when a prefix diverges mid-page
-            self.prefix_partial_hits = 0
-            self.prefix_tokens_reused = 0
-            self.prefix_evictions = 0  # radix leaves dropped for pages
-            self._bt_host = np.zeros(
-                (n_slots, self.max_pages_per_row), np.int32
-            )
-            self._bt_dirty = True
-            self._slot_pos = [0] * n_slots  # host mirror of cache.pos
+        self._faults = faults if faults is not None else NULL_INJECTOR
+        # the paged cache's host bookkeeping (serving/pages.py): page
+        # lists, refcounts, the radix prefix cache, the block table's
+        # mirror. _reset_state rebuilds it through the same constructor
+        self.pages = PageTable(
+            n_slots, self.n_pages, page_size, self.max_pages_per_row,
+            max_len, faults=self._faults,
+        ) if paged else None
         self._rng = jax.random.PRNGKey(seed)
         # queue.Queue (not SimpleQueue): the queue-deadline sweep filters
         # the backing deque in place under .mutex
@@ -521,18 +497,17 @@ class InferenceEngine:
         # (A, B) leaves live in pages drawn from the SAME PagePool as
         # KV — one device budget. Under page pressure the allocator's
         # escalation is radix leaf -> holder-free adapter page-out ->
-        # preemption (_alloc_page); _gather_blora reads the pages
+        # preemption (PageTable.alloc); _gather_blora reads the pages
         # instead of re-transferring host weights per assignment change
         self._pager = None
         if adapters is not None and paged and self._family_pool is None:
-            from bigdl_tpu import kvpaged
             from bigdl_tpu.serving.adapters import AdapterPager
 
             self._adapter_store = kvpaged.AdapterPageStore(
                 self.n_pages, kvpaged.kv_page_nbytes(self.cache)
             )
-            self._pager = AdapterPager(
-                self._adapter_store, self._pool, self._alloc_page,
+            self._pager = self.pages.pager = AdapterPager(
+                self._adapter_store, self.pages.pool, self.pages.alloc,
                 faults=faults,
             )
 
@@ -704,17 +679,10 @@ class InferenceEngine:
         self._cancelled: dict[int, Request] = {}
 
         # ---- overload protection state ----
-        if preemption_policy not in ("youngest", "oldest"):
-            raise ValueError(
-                f"preemption_policy must be 'youngest' or 'oldest', "
-                f"got {preemption_policy!r}"
-            )
         self.max_queue = max_queue
         self.queue_deadline_s = queue_deadline_s
         self.deadline_s = deadline_s
         self.preemption = preemption
-        self.preemption_policy = preemption_policy
-        self._faults = faults if faults is not None else NULL_INJECTOR
         # graceful-shutdown latch (begin_drain): new submits shed with
         # kind "draining" (503 + Retry-After) while in-flight work runs
         # to completion. Plain bool store/read across threads — a submit
@@ -791,8 +759,6 @@ class InferenceEngine:
         if self._family_cache is not None:
             self.preemption = False
         elif paged:
-            from bigdl_tpu import kvpaged
-
             self._swap_in = self._with_mesh(jax.jit(
                 kvpaged.swap_in_pages, donate_argnames=("cache",)
             ))
@@ -865,8 +831,6 @@ class InferenceEngine:
                 cache, pos=jnp.zeros((self.n_slots,), jnp.int32)
             )
         if self.paged and not force_dense:
-            from bigdl_tpu import kvpaged
-
             cache = kvpaged.init_paged(
                 cfg.num_hidden_layers, self.n_pages, self.page_size,
                 cfg.num_key_value_heads, cfg.head_dim_, self.n_slots,
@@ -972,8 +936,6 @@ class InferenceEngine:
         last token's logits (pad writes land at slots >= pos and are
         overwritten by decode). `lora` = the request's rank-bucketed
         adapter tree (every chunk of a chunked prefill carries it)."""
-        from bigdl_tpu import kvpaged
-
         cache = kvpaged.PagedKVCache(
             k=k, v=v, k_scale=ks, v_scale=vs, block_tables=row_bt, pos=pos0,
             start=jnp.zeros((1,), jnp.int32),
@@ -1381,159 +1343,41 @@ class InferenceEngine:
 
     # ---- paged page management -------------------------------------------
 
-    def _alloc_page(self) -> Optional[int]:
-        """A free page, evicting LRU radix leaves (serving/radix.py)
-        while the free list is dry, then paging out holder-free
-        adapters (serving/adapters.AdapterPager) — adapters share this
-        pool's budget, and their host copies make page-out free to
-        undo. Eviction only ever drops pages no slot holds, so it
-        composes with preemption: the escalation order is free list ->
-        cache eviction -> adapter page-out -> host-RAM swap-out
-        (_alloc_page_preempting)."""
-        if self._faults.fire("alloc_page") is not None:
-            return None  # injected pool exhaustion (serving/faults.py)
-        pg = self._pool.alloc()
-        while pg is None and self.radix.evict_one():
-            self.prefix_evictions += 1
-            pg = self._pool.alloc()
-        while pg is None and self._pager is not None \
-                and self._pager.evict_one():
-            pg = self._pool.alloc()
-        return pg
-
-    def _release_slot_pages(self, slot: int) -> None:
-        for pg in self._slot_pages[slot]:
-            self._pool.decref(pg)  # frees on 0; cached nodes keep theirs
-        self._slot_pages[slot] = []
-        self._slot_written[slot] = 0
-        self._slot_pos[slot] = 0
-        # retarget the idle slot's garbage decode writes at the scratch
-        # page and park its position (see __init__)
-        self._bt_host[slot, :] = 0
-        self._bt_dirty = True
-        self.cache = dataclasses.replace(
-            self.cache, pos=self.cache.pos.at[slot].set(0)
-        )
-
     def _admit_paged(self, req: Request, slot: int) -> bool:
-        """Tail-truncate, reuse the longest cached prompt prefix from
-        the radix tree (storage AND prefill compute, at any split
-        point: full pages by descent, a mid-page divergence via the
-        page-copy path), allocate fresh pages for the whole remainder,
-        then prefill — monolithically, or as a chunk plan the step loop
-        advances one chunk at a time (prefill_chunk_tokens). False =
-        not enough pages; retry later."""
-        page = self.page_size
+        """Tail-truncate, book the slot's pages (PageTable.reserve: the
+        longest cached prefix shared, fresh pages for the remainder),
+        copy the page a mid-page divergence shares, then prefill —
+        monolithically, or as a chunk plan the step loop advances one
+        chunk at a time (prefill_chunk_tokens). False = not enough
+        pages; retry later."""
         limit = self.max_len - req.max_new_tokens
         if len(req.prompt) > limit:
             req.prompt = req.prompt[-limit:]
         prompt = req.prompt
-
-        # longest cached full-page run (O(prompt) incremental keys;
-        # matched nodes are LRU-refreshed in O(1) each), in the
-        # request's adapter namespace: pages prefilled under a LoRA
-        # adapter carry its shifted K/V, so tenants never share pages
-        # with each other or with the base (radix.root_for)
-        path = self.radix.match(prompt, ns=req.adapter)
-        shared = [nd.page for nd in path]
-        n_hit = len(shared)
-        lp = n_hit * page
-        tail = prompt[lp:]
-        head_node = path[-1] if path else self.radix.root_for(req.adapter)
-
-        # sub-page sharing: the deepest matched node's child whose page
-        # agrees with our tail for t_copy tokens lets us COPY those KV
-        # slots instead of re-prefilling them. Capped at len(tail)-1 so
-        # the last real token always prefills (its logits seed
-        # generation).
-        t_copy, src_node = 0, None
-        if len(tail) > 1:
-            m, child = self.radix.match_partial(head_node, tail)
-            t_copy = min(m, len(tail) - 1)
-            src_node = child if t_copy > 0 else None
-            if src_node is None:
-                t_copy = 0
-        src_page = src_node.page if src_node is not None else None
-
-        def plan(cut):
-            # 16-token bucket quantum (was 32): post-hit tails are
-            # short, and halving the pad floor halves the wasted
-            # prefill width a mid-page split pays — this is what makes
-            # sub-page reuse actually engage (the copy is skipped
-            # unless it shrinks the plan)
-            b = min(round_up(max(len(prompt) - lp - cut, 16), 16),
-                    self.max_len - lp - cut)
-            return b, -(-(lp + cut + b) // page) - n_hit
-
-        bucket0, need0 = plan(0)
-        if src_page is not None:
-            bucket, need = plan(t_copy)
-            # prefill cost is quantized to the bucket/page plan: a copy
-            # that doesn't shrink either is pure added latency (the
-            # page-copy dispatch + LRU bookkeeping) — skip it
-            if bucket >= bucket0 and need >= need0:
-                t_copy, src_page, src_node = 0, None, None
-                bucket, need = bucket0, need0
-        else:
-            t_copy = 0
-            bucket, need = bucket0, need0
-        lp_eff = lp + t_copy
-        tail2 = prompt[lp_eff:]
-        if need > self.n_pages - 1:  # can NEVER be satisfied (page 0 is
-            # scratch): fail now instead of head-of-line blocking forever
-            self._fail_request(req, (
-                f"prompt needs {need} pages but the pool only has "
-                f"{self.n_pages - 1}; raise n_pages or shorten the prompt"
-            ))
+        try:
+            plan = self.pages.reserve(slot, prompt, ns=req.adapter)
+        except NeverFits as e:
+            self._fail_request(req, str(e))
             return True  # consumed (failed), keep admitting others
-        # incref shared pages (and the sub-page copy source) BEFORE
-        # allocating fresh ones — _alloc_page's radix eviction must not
-        # evict a page out of this very request's prefix (cache-only
-        # holds are fair eviction game)
-        for pg in shared:
-            self._pool.incref(pg)
-        if src_page is not None:
-            self._pool.incref(src_page)
-        fresh: list[int] = []
-        for _ in range(need):
-            pg = self._alloc_page()
-            if pg is None:  # out of pages: roll back, retry next step
-                for q in fresh:
-                    self._pool.decref(q)
-                for q in shared:
-                    self._pool.decref(q)
-                if src_page is not None:
-                    self._pool.decref(src_page)
-                return False
-            fresh.append(pg)
+        if plan is None:
+            return False
         # admission is committed from here on (every later path prefills
         # and activates) — stamp it so queue_wait/queued exclude prefill
         self._mark_admitted(req)
-        if n_hit:
-            self.prefix_hits += 1
-
-        table = shared + fresh
-        self._slot_pages[slot] = table
-        # page-ALIGNED coverage: _ensure_decode_pages extends in whole
-        # pages, so a non-aligned start would drift the page index
-        self._slot_written[slot] = len(table) * page
-        row = np.zeros((self.max_pages_per_row,), np.int32)
-        row[: len(table)] = table
-
-        if src_page is not None:
-            # copy the WHOLE source page (one static-shape program;
-            # slots past t_copy are overwritten by the tail prefill or
-            # masked by pos), then release the copy hold
+        if plan.copy is not None:
             self.cache = self._copy_page(
-                self.cache, jnp.asarray(src_page), jnp.asarray(fresh[0])
+                self.cache, jnp.asarray(plan.copy[0]),
+                jnp.asarray(plan.copy[1]),
             )
-            self._pool.decref(src_page)
-            self.prefix_partial_hits += 1
-            self.prefix_tokens_reused += t_copy
-            self.radix.touch(src_node)  # it just proved hot
-
+        rest = len(prompt) - plan.covered
         chunk = self.prefill_chunk_tokens
-        if chunk is not None and len(tail2) > chunk:
+        chunked = chunk is not None and rest > chunk
+        st = _PrefillState(
+            req=req, slot=slot, row=plan.row, written=plan.covered,
+            path=plan.path, chunk=chunk if chunked else rest,
+            start=plan.covered,
+        )
+        if chunked:
             # chunk plan: the slot is HELD (req set, active False, its
             # engine block-table row left at the scratch page) and
             # step() advances one chunk per iteration via
@@ -1541,79 +1385,32 @@ class InferenceEngine:
             # between chunks, so this prompt cannot stall it by more
             # than one chunk
             self._slots[slot] = _Slot(req=req, seq=next(self._seq))
-            self._prefilling = _PrefillState(
-                req=req, slot=slot, row=row, written=lp_eff,
-                path=path, chunk=chunk, start=lp_eff,
-            )
-            return True
-
-        self._bt_host[slot] = row
-        self._bt_dirty = True
-        self.prefill_chunks += 1
-        toks = np.full((1, bucket), self.gen.pad_token_id, np.int32)
-        toks[0, : len(tail2)] = tail2  # RIGHT pad: writes past pos get
-        # overwritten by decode and are masked meanwhile
-        logits_last, k, v, ks, vs, experts = self._paged_prefill(
-            self.model.params, self.cache.k, self.cache.v,
-            self.cache.k_scale, self.cache.v_scale,
-            jnp.asarray(row[None]), jnp.asarray([lp_eff], jnp.int32),
-            jnp.asarray(toks), jnp.asarray(len(tail2) - 1),
-            lora=self._prefill_lora(req),
-        )
-        if experts is not None:
-            self._admit_moe = [(experts, len(tail2))]
-            self._admit_moe_start = lp_eff
-        self.cache = dataclasses.replace(
-            self.cache, k=k, v=v, k_scale=ks, v_scale=vs,
-            pos=self.cache.pos.at[slot].set(len(prompt)),
-            start=self.cache.start.at[slot].set(0),
-        )
-        self._slot_pos[slot] = len(prompt)
-
-        self._register_prefix(prompt, path, table, ns=req.adapter)
-
-        if self.speculative:
-            # prefix-cache hits only save TARGET prefill; the draft
-            # always prefills its full context into the dense draft pool
-            self._admit_draft(slot, prompt, limit)
-
-        self._activate(slot, req, logits_last)
+            self._prefilling = st
+        else:
+            self._prefill_chunk(st)  # monolithic: the rest is one chunk
         return True
-
-    def _register_prefix(self, prompt: list[int], path: list,
-                         table: list[int], ns=None) -> None:
-        """Register the prompt's fully-covered pages past the matched
-        run as radix nodes (the cache takes its own page reference).
-        An existing edge keeps its canonical page — our duplicate stays
-        slot-only and frees at release. `ns` = the request's adapter
-        name: adapter-prefilled pages register under that tenant's own
-        radix root, never the shared base tree."""
-        page = self.page_size
-        node = path[-1] if path else self.radix.root_for(ns)
-        for i in range(len(path), len(prompt) // page):
-            key = tuple(prompt[i * page: (i + 1) * page])
-            nxt = node.children.get(key)
-            if nxt is None:
-                nxt = self.radix.insert(node, key, table[i])
-            node = nxt
 
     def _advance_prefill(self) -> None:
         """Run AT MOST ONE chunk of the at-most-one in-flight chunked
         prefill: the per-step decode stall a new prompt can inflict is
-        bounded by one chunk. The final chunk installs the real block
-        table, registers radix nodes, and activates the slot (first
-        token emits — TTFT closes here)."""
-        st = self._prefilling
-        if st is None:
-            return
-        self._retrace_mark("other")  # this step's sweeps and admissions
+        bounded by one chunk."""
+        if self._prefilling is not None:
+            self._retrace_mark("other")  # this step's sweeps and admissions
+            self._prefill_chunk(self._prefilling)
+
+    def _prefill_chunk(self, st: _PrefillState) -> None:
+        """Prefill the next `st.chunk` tokens of the prompt through the
+        slot's own row. The final chunk (of a monolithic prefill, the
+        only one) installs the real block table, registers radix nodes,
+        and activates the slot (first token emits — TTFT closes here)."""
         prompt = st.req.prompt
         rem = len(prompt) - st.written
         n = min(st.chunk, rem)
         last = n == rem
-        bucket = min(round_up(max(n, 16), 16), self.max_len - st.written)
+        bucket = prefill_bucket(n, self.max_len - st.written)
         toks = np.full((1, bucket), self.gen.pad_token_id, np.int32)
-        toks[0, :n] = prompt[st.written: st.written + n]
+        toks[0, :n] = prompt[st.written: st.written + n]  # RIGHT pad:
+        # writes past pos get overwritten by decode, masked meanwhile
         self.prefill_chunks += 1
         logits_last, k, v, ks, vs, moe = self._paged_prefill(
             self.model.params, self.cache.k, self.cache.v,
@@ -1633,17 +1430,20 @@ class InferenceEngine:
             return
         slot = st.slot
         self._prefilling = None
-        self._bt_host[slot] = st.row
-        self._bt_dirty = True
+        self.pages.install(slot, st.row, len(prompt))
         self.cache = dataclasses.replace(
             self.cache,
             pos=self.cache.pos.at[slot].set(len(prompt)),
             start=self.cache.start.at[slot].set(0),
         )
-        self._slot_pos[slot] = len(prompt)
-        self._register_prefix(prompt, st.path, self._slot_pages[slot],
-                              ns=st.req.adapter)
+        self.pages.register_prefix(slot, prompt, st.path,
+                                   ns=st.req.adapter)
         self._admit_moe, self._admit_moe_start = st.moe, st.start
+        if self.speculative:
+            # prefix-cache hits only save TARGET prefill; the draft
+            # always prefills its full context into the dense draft pool
+            self._admit_draft(slot, prompt,
+                              self.max_len - st.req.max_new_tokens)
         self._activate(slot, st.req, logits_last)
 
     def _admit_draft(self, slot: int, prompt: list[int], limit: int) -> None:
@@ -1674,11 +1474,8 @@ class InferenceEngine:
         or a pool that provably cannot support the request at all."""
         for i in np.nonzero(self.active)[0]:
             slot = int(i)
-            while (self.active[slot]
-                   and self._slot_pos[slot] + need_tokens
-                   > self._slot_written[slot]):
-                idx = len(self._slot_pages[slot])
-                if idx >= self.max_pages_per_row:  # logical capacity hit
+            while self.active[slot] and self.pages.short(slot, need_tokens):
+                if self.pages.row_full(slot):  # logical capacity hit
                     self._finish(slot, "length")
                     break
                 pg = self._alloc_page_preempting(slot)
@@ -1686,22 +1483,19 @@ class InferenceEngine:
                     if self.active[slot]:  # not self-preempted: stuck
                         self._finish(slot, "length")
                     break
-                self._slot_pages[slot].append(pg)
-                self._slot_written[slot] += self.page_size
-                self._bt_host[slot, idx] = pg
-                self._bt_dirty = True
+                self.pages.extend(slot, pg)
 
     # ---- preemption (host-RAM KV swap) ------------------------------------
 
     def _alloc_page_preempting(self, slot: int) -> Optional[int]:
-        """_alloc_page, escalating to preemption under pool pressure:
-        swap victims out (policy order) until a page frees. With no other
+        """PageTable.alloc, escalating to preemption under pool pressure:
+        swap victims out (youngest first) until a page frees. With no other
         victim, the requesting slot preempts ITSELF — but only if it has
         made progress since its last resume; a no-progress self-preempt
         proves the pool cannot support the request (swap-in would need
         the very pages that are missing) and would livelock."""
         while True:
-            pg = self._alloc_page()
+            pg = self.pages.alloc()
             if pg is not None or not self.preemption:
                 return pg
             victim = self._pick_victim(exclude=slot)
@@ -1711,7 +1505,7 @@ class InferenceEngine:
             if self._abort_prefill_for_pages():
                 continue  # the chunk plan yielded its pages
             s = self._slots[slot]
-            if s.resumed_pos < 0 or self._slot_pos[slot] > s.resumed_pos:
+            if s.resumed_pos < 0 or self.pages.pos[slot] > s.resumed_pos:
                 self._preempt_slot(slot)  # caller sees the slot inactive
             return None
 
@@ -1735,7 +1529,7 @@ class InferenceEngine:
         return True
 
     def _pick_victim(self, exclude: int) -> Optional[int]:
-        """Victim slot per policy. youngest = most recently (re)admitted:
+        """The youngest slot, the one most recently (re)admitted:
         it loses the least progress and, being FIFO-resumed behind older
         preempted work, cannot starve the oldest request — the oldest is
         never chosen while anyone else is active, so it always completes
@@ -1746,9 +1540,7 @@ class InferenceEngine:
         # no resumable decode state to swap; it is never a victim
         if not cands:
             return None
-        pick = max(cands) if self.preemption_policy == "youngest" \
-            else min(cands)
-        return pick[1]
+        return max(cands)[1]
 
     def _preempt_slot(self, slot: int) -> None:
         """Swap a slot's KV to host RAM and requeue its request with the
@@ -1761,13 +1553,10 @@ class InferenceEngine:
         now = self._clock()
         self._flush_decode_window(slot, now)
         if self.paged:
-            pos = self._slot_pos[slot]
-            n_keep = -(-pos // self.page_size)  # pages holding real KV
-            from bigdl_tpu import kvpaged
-
-            blob = kvpaged.swap_out_pages(
-                self.cache, self._slot_pages[slot][:n_keep]
-            )
+            pos = self.pages.pos[slot]
+            keep = self.pages.kv_pages(slot)
+            n_keep = len(keep)
+            blob = kvpaged.swap_out_pages(self.cache, keep)
             start = 0
         else:
             pos = int(np.asarray(self.cache.pos[slot]))
@@ -1810,20 +1599,9 @@ class InferenceEngine:
         the entry stays queued and newer admissions wait behind it."""
         req = entry.req
         if self.paged:
-            fresh: list[int] = []
-            for _ in range(entry.n_pages):
-                pg = self._alloc_page()
-                if pg is None:  # roll back; retry when pages free up
-                    for q in fresh:
-                        self._pool.decref(q)
-                    return False
-                fresh.append(pg)
-            self._slot_pages[slot] = fresh
-            self._slot_written[slot] = entry.n_pages * self.page_size
-            row = np.zeros((self.max_pages_per_row,), np.int32)
-            row[: entry.n_pages] = fresh
-            self._bt_host[slot] = row
-            self._bt_dirty = True
+            fresh = self.pages.restore(slot, entry.n_pages, entry.pos)
+            if fresh is None:  # retry when pages free up
+                return False
             b = entry.blob
             self.cache = self._swap_in(
                 self.cache, b.k, b.v, b.k_scale, b.v_scale,
@@ -1834,7 +1612,6 @@ class InferenceEngine:
                 pos=self.cache.pos.at[slot].set(entry.pos),
                 start=self.cache.start.at[slot].set(0),
             )
-            self._slot_pos[slot] = entry.pos
         else:
             k, v, ks, vs = entry.blob
             self.cache = self._dense_swap_in(
@@ -2130,12 +1907,12 @@ class InferenceEngine:
             q = self._queue.queue
             if not q:
                 return None
-            if len(q) > 1 and self.radix.n_nodes:
+            if len(q) > 1 and self.pages.radix.n_nodes:
                 n = min(len(q), self._ADMIT_SCAN_WINDOW)
-                best_i, best_d = 0, self.radix.match_len(
+                best_i, best_d = 0, self.pages.cached_len(
                     q[0].prompt, ns=q[0].adapter)
                 for i in range(1, n):
-                    d = self.radix.match_len(q[i].prompt, ns=q[i].adapter)
+                    d = self.pages.cached_len(q[i].prompt, ns=q[i].adapter)
                     if d > best_d:
                         best_i, best_d = i, d
                 if best_i:
@@ -2194,7 +1971,7 @@ class InferenceEngine:
             self.adapters.release(entry)
             if self._pager is not None:
                 # the device pages mirror the hold: holder-free pages
-                # become page-out candidates for _alloc_page
+                # become page-out candidates for PageTable.alloc
                 self._pager.drop_holder(req.rid)
         tr = self.tracer
         if req.preempt_ts is not None:
@@ -2502,13 +2279,13 @@ class InferenceEngine:
         req.last_token_ts = now
         tr = self.tracer
         if tr is not None and tr.enabled:
-            # coalesce decode into one span per trace_decode_every
+            # coalesce decode into one span per TRACE_DECODE_EVERY
             # tokens; each window opens where the previous span closed,
             # keeping the request track monotonically nested
             if s.n_win == 0:
                 s.t_win = prev
             s.n_win += 1
-            if s.n_win >= self.trace_decode_every:
+            if s.n_win >= TRACE_DECODE_EVERY:
                 tr.complete("decode", s.t_win, now - s.t_win,
                             tid=req.rid, cat="request", rid=req.rid,
                             tokens=s.n_win)
@@ -2593,7 +2370,10 @@ class InferenceEngine:
         self._penalty[slot] = 1.0
         self.seen = self.seen.at[slot].set(False)
         if self.paged:
-            self._release_slot_pages(slot)
+            self.pages.release(slot)
+            self.cache = dataclasses.replace(
+                self.cache, pos=self.cache.pos.at[slot].set(0)
+            )
 
     def _reset_state(self) -> None:
         """Rebuild the (possibly donated-away) cache after a failed decode
@@ -2612,26 +2392,9 @@ class InferenceEngine:
         self._slot_adapter = [None] * self.n_slots
         self._blora, self._blora_dirty = None, True
         if self.paged:
-            from bigdl_tpu import kvpaged
-            from bigdl_tpu.serving.radix import RadixPrefixCache
-
-            # rebuild pool + radix together (cached nodes reference the
-            # old pool's pages); hit/eviction counters survive — they
-            # are engine totals, not cache state
-            self._pool = kvpaged.PagePool(self.n_pages)
-            self._free_pages = self._pool.free
-            self._page_ref = self._pool.ref
-            self.radix = RadixPrefixCache(self.page_size, self._pool)
-            if self._pager is not None:
-                # resident adapters referenced the dead pool's pages;
-                # drop residency (host copies in the registry survive —
-                # the next admission re-pages-in) and retarget the pool
-                self._pager.reset(self._pool)
-            self._slot_pages = [[] for _ in range(self.n_slots)]
-            self._slot_written = [0] * self.n_slots
-            self._slot_pos = [0] * self.n_slots
-            self._bt_host[:] = 0
-            self._bt_dirty = True
+            # cached nodes and resident adapters reference the old
+            # pool's pages; the hit and eviction totals survive
+            self.pages = self.pages.rebuilt()
 
     def cancel(self, req: Request) -> None:
         """Thread-safe: stop generating for a request whose consumer is
@@ -2824,11 +2587,11 @@ class InferenceEngine:
             self._ensure_decode_pages(
                 self._cur_k if self.speculative else 1
             )
-            if self._bt_dirty:
+            bt = self.pages.block_table()
+            if bt is not None:
                 self.cache = dataclasses.replace(
-                    self.cache, block_tables=jnp.asarray(self._bt_host)
+                    self.cache, block_tables=jnp.asarray(bt)
                 )
-                self._bt_dirty = False
         if not self.active.any():
             return (not self._queue.empty() or self._waiting is not None
                     or bool(self._preempted)
@@ -2883,7 +2646,7 @@ class InferenceEngine:
                 continue
             s.remaining -= 1
             if self.paged:
-                self._slot_pos[i] += 1
+                self.pages.advance(i)
             if experts_h is not None:
                 s.req.out_experts.append(experts_h[:, i])
             alt = None
@@ -2914,9 +2677,9 @@ class InferenceEngine:
         if tr is not None and tr.enabled:
             busy = int(self.active.sum())
             pages = {}
-            if self.paged:  # _slot_pos still holds the step's own pos
+            if self.paged:  # its pos still holds the step's own
                 pages["live_pages"], pages["grid_pages"] = \
-                    self.paged_grid_pages()
+                    self.pages.grid_pages(self.active)
             tr.complete("decode_step", t0, t1 - t0, tid=0, cat="engine",
                         occupancy=busy, slots=self.n_slots,
                         queue_depth=self._queue.qsize(), **pages,
@@ -2983,7 +2746,7 @@ class InferenceEngine:
                 self._finish(i, "error")
                 continue
             if self.paged:  # mirror the post-rollback pool position
-                self._slot_pos[i] += int(n_acc_h[i]) + 1
+                self.pages.advance(i, int(n_acc_h[i]) + 1)
             for t in range(int(n_acc_h[i]) + 1):
                 s.remaining -= 1
                 self.spec_emitted += 1
@@ -3119,33 +2882,9 @@ class InferenceEngine:
         return max(self._clock() - self._t_start, 0.0)
 
     def page_leaks(self) -> int:
-        """Pages whose refcount disagrees with their accounted holders
-        (slot block tables + radix cache nodes) plus any page neither
-        free nor held at all. 0 is the invariant; the sim report and
-        the chaos tests gate on it at drain."""
-        if not self.paged:
-            return 0
-        held = [0] * self.n_pages
-        for pages in self._slot_pages:
-            for pg in pages:
-                held[pg] += 1
-        for node in self.radix.nodes():
-            held[node.page] += 1
-        if self._pager is not None:
-            for pg in self._pager.held_pages():
-                held[pg] += 1
-        return sum(1 for pg in range(1, self.n_pages)
-                   if self._page_ref[pg] != held[pg])
-
-    def paged_grid_pages(self) -> tuple[int, int]:
-        """(live, grid) pages of the paged decode kernel's next step, from
-        the host's mirror (no device read): the pages up to each active
-        row's `pos`, and the kernel's whole grid of slots x pages per row.
-        Only the live ones cost the kernel a DMA and a softmax update."""
-        mp = self.max_pages_per_row
-        live = sum(min(self._slot_pos[int(i)] // self.page_size + 1, mp)
-                   for i in np.nonzero(self.active)[0])
-        return live, self.n_slots * mp
+        """PageTable.page_leaks of a paged engine (0 is the invariant at
+        drain); a dense engine has no pages to leak."""
+        return self.pages.page_leaks() if self.paged else 0
 
     def moe_load(self) -> dict:
         """The newest decode step's expert load (the `moe_*` arguments of
@@ -3162,8 +2901,7 @@ class InferenceEngine:
         positions over total row capacity (dense). Family caches without
         a standard pos vector report 0 rather than guessing."""
         if self.paged:
-            cap = self.n_pages - 1
-            return (cap - len(self._free_pages)) / max(cap, 1)
+            return self.pages.utilization()
         # HOST-side estimate only: reading cache.pos here would race the
         # decode jit's cache donation (the buffers are deleted for most
         # of every step, and /metrics scrapes from a handler thread).

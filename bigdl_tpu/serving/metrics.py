@@ -375,11 +375,12 @@ class Metrics:
                 f"{self.engine.prefill_chunks}",
             ]
             if self.engine.paged:
-                live, grid = self.engine.paged_grid_pages()
+                pages = self.engine.pages  # serving/pages.PageTable
+                live, grid = pages.grid_pages(self.engine.active)
                 lines += [
                     "# HELP bigdl_tpu_free_pages allocatable KV pages",
                     "# TYPE bigdl_tpu_free_pages gauge",
-                    f"bigdl_tpu_free_pages {len(self.engine._free_pages)}",
+                    f"bigdl_tpu_free_pages {pages.pool.n_free}",
                     "# HELP bigdl_tpu_paged_live_page_share fraction of "
                     "the paged decode kernel's grid (slots x pages per "
                     "row) that holds live KV; the rest is skipped",
@@ -389,27 +390,27 @@ class Metrics:
                     "# HELP bigdl_tpu_prefix_hits_total full-page prefix "
                     "cache hits",
                     "# TYPE bigdl_tpu_prefix_hits_total counter",
-                    f"bigdl_tpu_prefix_hits_total {self.engine.prefix_hits}",
+                    f"bigdl_tpu_prefix_hits_total {pages.prefix_hits}",
                     "# HELP bigdl_tpu_prefix_partial_hits_total sub-page "
                     "prefix copies",
                     "# TYPE bigdl_tpu_prefix_partial_hits_total counter",
                     f"bigdl_tpu_prefix_partial_hits_total "
-                    f"{self.engine.prefix_partial_hits}",
+                    f"{pages.prefix_partial_hits}",
                     "# HELP bigdl_tpu_prefix_tokens_reused_total prompt "
                     "tokens served from copied KV instead of prefill",
                     "# TYPE bigdl_tpu_prefix_tokens_reused_total counter",
                     f"bigdl_tpu_prefix_tokens_reused_total "
-                    f"{self.engine.prefix_tokens_reused}",
+                    f"{pages.prefix_tokens_reused}",
                     # radix prefix cache (serving/radix.py)
                     "# HELP bigdl_tpu_prefix_evictions_total radix "
                     "cache leaves evicted for page pressure",
                     "# TYPE bigdl_tpu_prefix_evictions_total counter",
                     f"bigdl_tpu_prefix_evictions_total "
-                    f"{self.engine.prefix_evictions}",
+                    f"{pages.prefix_evictions}",
                     "# HELP bigdl_tpu_radix_nodes cached prefix pages "
                     "(radix tree nodes)",
                     "# TYPE bigdl_tpu_radix_nodes gauge",
-                    f"bigdl_tpu_radix_nodes {self.engine.radix.n_nodes}",
+                    f"bigdl_tpu_radix_nodes {pages.radix.n_nodes}",
                 ]
             if getattr(self.engine, "_moe_routing", False):
                 # sparse-expert models: the newest decode step's expert

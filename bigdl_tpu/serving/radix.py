@@ -32,7 +32,7 @@ Composition (docs/serving.md §6): eviction only ever touches pages
 whose sole reference is the cache's own, so it can never steal a page
 from a live slot or from a host-RAM-parked request's future swap-in —
 preemption (PR 6) and journal replay (PR 7) see cached pages exactly
-like any other allocation. The engine escalates allocation pressure as
+like any other allocation. `PageTable.alloc` escalates pressure as
 free list -> radix eviction -> preemption.
 
 This module is pure host-side bookkeeping: no jax, no clock reads.
@@ -60,8 +60,9 @@ class RadixNode:
 
 
 class RadixPrefixCache:
-    """The tree + its LRU. The engine owns the hit/eviction counters
-    (they must survive `_reset_state`, which rebuilds this object);
+    """The tree + its LRU, held by serving/pages.PageTable. The table
+    owns the hit/eviction counters (they survive `PageTable.rebuilt`,
+    which builds a new cache);
     the cache owns structure and page references only."""
 
     def __init__(self, page_size: int, pool):
@@ -204,8 +205,7 @@ class RadixPrefixCache:
         return True
 
     def clear(self) -> None:
-        """Release every cached page (engine `_reset_state`: the pool
-        is rebuilt alongside, so holds must not linger)."""
+        """Release every cached page back to the pool."""
         for node in self._lru:
             self.pool.decref(node.page)
             node.parent = None

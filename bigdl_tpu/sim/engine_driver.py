@@ -271,7 +271,7 @@ class SimDriver:
         for i in np.nonzero(eng.active)[0]:
             s = eng._slots[int(i)]
             if eng.paged:
-                out.append(int(eng._slot_pos[int(i)]))
+                out.append(int(eng.pages.pos[int(i)]))
             elif s.req is not None:
                 out.append(len(s.req.prompt) + len(s.req.out_tokens))
         return out
@@ -470,12 +470,12 @@ class SimDriver:
             # hold the radix's own reference at drain
             page_leak = eng.page_leaks()
             kv_extra = {
-                "free_pages_at_drain": len(eng._free_pages),
-                "cached_prefix_pages": eng.radix.n_nodes,
-                "prefix_hits": eng.prefix_hits,
-                "prefix_partial_hits": eng.prefix_partial_hits,
-                "prefix_tokens_reused": eng.prefix_tokens_reused,
-                "prefix_evictions": eng.prefix_evictions,
+                "free_pages_at_drain": eng.pages.pool.n_free,
+                "cached_prefix_pages": eng.pages.radix.n_nodes,
+                "prefix_hits": eng.pages.prefix_hits,
+                "prefix_partial_hits": eng.pages.prefix_partial_hits,
+                "prefix_tokens_reused": eng.pages.prefix_tokens_reused,
+                "prefix_evictions": eng.pages.prefix_evictions,
             }
         adapter_extra: dict = {}
         if self.adapters is not None:

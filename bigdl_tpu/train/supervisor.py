@@ -457,6 +457,10 @@ class TrainSupervisor:
         loss_h, gnorm_h = self._inject_anomalies(loss_h, gnorm_h)
         reasons = self._anomaly_reasons(loss_h, gnorm_h)
         dt = self._clock() - t0
+        # the span's end in the clock that stamped its start: a length on
+        # another clock (dt) next to a simulated tracer's stamps can make
+        # one step's span run into the next
+        tw1 = self.tracer.now() if tracing else 0.0
         TRAIN_STEP_SECONDS.observe(dt)
         anomaly, preempt = self._consensus(
             bool(reasons), self._preempt_flag.is_set())
@@ -488,7 +492,7 @@ class TrainSupervisor:
             # engine-track complete span per step, anomalies visible as
             # skipped=True plus the EventLog-mirrored "anomaly" instant
             self.tracer.complete(
-                "train.step", tw0, dt, tid=0, cat="train", step=step,
+                "train.step", tw0, tw1 - tw0, tid=0, cat="train", step=step,
                 loss=report.loss, skipped=report.skipped,
             )
         if (self.config.heartbeat_every

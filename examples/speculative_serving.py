@@ -46,9 +46,9 @@ def main():
     per_round = engine.spec_emitted / max(engine.spec_rounds, 1)
     print(f"speculative: {engine.spec_rounds} verify rounds, "
           f"{per_round:.2f} tokens/round")
-    print(f"prefix cache: {engine.prefix_hits} full-page hits, "
-          f"{engine.prefix_partial_hits} sub-page copies "
-          f"({engine.prefix_tokens_reused} tokens reused)")
+    print(f"prefix cache: {engine.pages.prefix_hits} full-page hits, "
+          f"{engine.pages.prefix_partial_hits} sub-page copies "
+          f"({engine.pages.prefix_tokens_reused} tokens reused)")
 
 
 if __name__ == "__main__":

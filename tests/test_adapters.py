@@ -363,7 +363,7 @@ def test_shared_prefix_never_leaks_across_tenants(model, adapter_dir):
     first = eng.submit(prompt, max_new_tokens=8, adapter="t-r2")
     eng.run_until_idle(max_steps=500)
     assert first.out_tokens == refs["t-r2"]
-    assert eng.radix.n_nodes == 2, "scenario must register shared pages"
+    assert eng.pages.radix.n_nodes == 2, "scenario must register shared pages"
     # same tokens through the base and a second tenant: A's pages are
     # unreachable from their namespaces, so both re-prefill correctly
     # (and a repeat of A itself HITS its own namespace, staying parity)
@@ -371,7 +371,7 @@ def test_shared_prefix_never_leaks_across_tenants(model, adapter_dir):
         req = eng.submit(prompt, max_new_tokens=8, adapter=name)
         eng.run_until_idle(max_steps=500)
         assert req.out_tokens == refs[name], name
-    assert eng.prefix_hits > 0, "tenant A's repeat must hit its own ns"
+    assert eng.pages.prefix_hits > 0, "tenant A's repeat must hit its own ns"
     assert eng.page_leaks() == 0
 
 
@@ -637,17 +637,17 @@ def test_unified_paging_shares_kv_pool(model, adapter_dir, merged_oracle):
             (name, prompt)
     # every resident page carries a real pool reference (one each)
     for pg in pager.held_pages():
-        assert eng._pool.ref[pg] >= 1
+        assert eng.pages.pool.ref[pg] >= 1
     # holder-free residency is evictable: drain the pool and the
     # allocator's escalation (radix -> adapter page-out) frees them
     grabbed = []
-    pg = eng._alloc_page()
+    pg = eng.pages.alloc()
     while pg is not None:
         grabbed.append(pg)
-        pg = eng._alloc_page()
+        pg = eng.pages.alloc()
     assert pager.pages_resident == 0 and pager.page_outs >= 3
     for pg in grabbed:
-        eng._pool.decref(pg)
+        eng.pages.pool.decref(pg)
     assert eng.page_leaks() == 0
     # next admission pages back in from the surviving host copy
     r = eng.submit(PROMPTS[1], max_new_tokens=4, adapter="t-r2")

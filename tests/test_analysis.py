@@ -660,6 +660,7 @@ def test_page_real_adapter_and_engine_paths_are_clean():
     paths = [os.path.join(REPO, p) for p in (
         "bigdl_tpu/serving/adapters.py",
         "bigdl_tpu/serving/engine.py",
+        "bigdl_tpu/serving/pages.py",
         "bigdl_tpu/serving/radix.py",
         "bigdl_tpu/kvpaged.py",
     )]

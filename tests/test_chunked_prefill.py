@@ -78,9 +78,9 @@ def test_chunked_prefill_composes_with_radix_hits(model):
     p1 = list(range(10, 34))  # 3 full pages
     p2 = list(range(10, 26)) + [90, 91, 92, 93, 94, 95, 96, 97]
     r1 = _run(eng, [p1], maxnt=6)[0]
-    hits0 = eng.prefix_hits
+    hits0 = eng.pages.prefix_hits
     r2 = _run(eng, [p2], maxnt=6)[0]
-    assert eng.prefix_hits == hits0 + 1
+    assert eng.pages.prefix_hits == hits0 + 1
     dense = InferenceEngine(model, n_slots=2, max_len=128)
     d1, d2 = _run(dense, [p1, p2], maxnt=6)
     assert r1.out_tokens == d1.out_tokens
@@ -134,13 +134,13 @@ def _start_chunked(eng, prompt, **kw):
 def test_cancel_between_chunks_frees_pages(model):
     eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
                           page_size=16, prefill_chunk_tokens=16)
-    free0 = len(eng._free_pages)
+    free0 = eng.pages.pool.n_free
     req = _start_chunked(eng, list(range(1, 129)), max_new_tokens=4)
     eng.cancel(req)
     eng.run_until_idle()
     assert req.done and req.finish_reason == "stop"
     assert eng._prefilling is None
-    assert len(eng._free_pages) + eng.radix.n_nodes == free0
+    assert eng.pages.pool.n_free + eng.pages.radix.n_nodes == free0
     assert eng.page_leaks() == 0
     # the engine still serves
     nxt = _run(eng, [[5, 6, 7]], maxnt=4)[0]
@@ -152,14 +152,14 @@ def test_deadline_between_chunks_times_out_cleanly(model):
     eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
                           page_size=16, prefill_chunk_tokens=16,
                           clock=lambda: fake[0])
-    free0 = len(eng._free_pages)
+    free0 = eng.pages.pool.n_free
     req = _start_chunked(eng, list(range(1, 129)), max_new_tokens=4,
                          deadline_s=5.0)
     fake[0] = 10.0  # expire while mid-prefill
     eng.run_until_idle()
     assert req.done and req.finish_reason == "timeout"
     assert eng._prefilling is None
-    assert len(eng._free_pages) + eng.radix.n_nodes == free0
+    assert eng.pages.pool.n_free + eng.pages.radix.n_nodes == free0
     assert eng.page_leaks() == 0
     assert eng.request_timeouts == 1
 
@@ -205,12 +205,12 @@ def test_journal_replay_after_death_mid_chunk(model, tmp_path):
 def test_fail_all_mid_chunk_releases_everything(model):
     eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
                           page_size=16, prefill_chunk_tokens=16)
-    free0 = len(eng._free_pages)
+    free0 = eng.pages.pool.n_free
     req = _start_chunked(eng, list(range(1, 129)), max_new_tokens=4)
     eng.fail_all("injected crash")
     assert req.done and req.finish_reason == "error"
     assert eng._prefilling is None
-    assert len(eng._free_pages) + eng.radix.n_nodes == free0
+    assert eng.pages.pool.n_free + eng.pages.radix.n_nodes == free0
     assert eng.page_leaks() == 0
 
 
@@ -279,11 +279,11 @@ def test_evict_then_readmit_leaves_zero_dead_nodes(model):
         # ...then the shared prefix is readmitted
         r = _run(eng, [shared + [30 + round_i]], maxnt=4)[0]
         assert not r.error
-        eng.radix.check()  # no dead/unreachable nodes, refs consistent
+        eng.pages.radix.check()  # no dead/unreachable nodes, refs consistent
         assert eng.page_leaks() == 0
-    assert eng.prefix_evictions > 0
+    assert eng.pages.prefix_evictions > 0
     # drain invariant: every page free or cache-held
-    assert len(eng._free_pages) + eng.radix.n_nodes == 6
+    assert eng.pages.pool.n_free + eng.pages.radix.n_nodes == 6
 
 
 def test_eviction_composes_with_preemption(model):

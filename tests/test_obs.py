@@ -35,6 +35,7 @@ from bigdl_tpu.obs.tracing import (
     summarize_trace,
     validate_nesting,
 )
+from bigdl_tpu.serving import engine as engine_mod
 from bigdl_tpu.serving.engine import InferenceEngine
 from bigdl_tpu.serving.faults import FaultInjector
 from bigdl_tpu.serving.metrics import Metrics, metric_drift
@@ -73,14 +74,15 @@ def _metric_value(text, prefix):
 # trace export: golden structure
 # ---------------------------------------------------------------------------
 
-def test_trace_export_golden(model, tmp_path):
+def test_trace_export_golden(model, tmp_path, monkeypatch):
     """A traced serving run exports valid Chrome trace JSON with the
     full request-lifecycle span vocabulary, monotonically nested spans
     per track, and a crc-clean derived-timings request log."""
+    monkeypatch.setattr(engine_mod, "TRACE_DECODE_EVERY", 3)
     tr = TraceRecorder(enabled=True)
     log_path = str(tmp_path / "requests.jsonl")
     eng = InferenceEngine(model, n_slots=2, max_len=128, tracer=tr,
-                          request_log=log_path, trace_decode_every=3)
+                          request_log=log_path)
     reqs = [eng.submit([3, 1, 4, 1, 5], max_new_tokens=8)
             for _ in range(3)]
     eng.run_until_idle()
@@ -159,16 +161,16 @@ def test_trace_export_sanitizes_non_finite_args(tmp_path):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.chaos
-def test_preempted_request_trace_and_metric_consistency(model):
+def test_preempted_request_trace_and_metric_consistency(model, monkeypatch):
     """Chaos-suite run with tracing: injected pool exhaustion preempts
     and resumes a request; the trace carries its queued/prefill/decode/
     preempted spans, and the TTFT/ITL/phase histograms on /metrics agree
     with the spans of the same run within 10%."""
+    monkeypatch.setattr(engine_mod, "TRACE_DECODE_EVERY", 4)
     tr = TraceRecorder(enabled=True)
     inj = FaultInjector(seed=0)
     eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8, faults=inj, tracer=tr,
-                          trace_decode_every=4)
+                          page_size=8, faults=inj, tracer=tr)
     r = eng.submit([3, 1, 4, 1, 5], max_new_tokens=40)
     eng.step()  # admit; next page allocation is the decode extension
     inj.arm("alloc_page", times=1)

@@ -98,10 +98,10 @@ def test_prefix_cache_hits_and_reuses_compute(model):
     p2 = prefix + [30, 31, 32]
     r1 = eng.submit(p1, max_new_tokens=6)
     eng.run_until_idle()
-    assert eng.prefix_hits == 0
+    assert eng.pages.prefix_hits == 0
     r2 = eng.submit(p2, max_new_tokens=6)
     eng.run_until_idle()
-    assert eng.prefix_hits == 1
+    assert eng.pages.prefix_hits == 1
     assert r1.done and r2.done
 
     # same prompts through a dense engine agree token for token
@@ -121,8 +121,8 @@ def test_pages_released_and_reused(model):
         assert len(out[0]) == 6
     # after the last finish, non-cached pages returned to the free list
     # (page 0 is the reserved scratch sink, so 5 allocatable)
-    in_cache = eng.radix.n_nodes
-    assert len(eng._free_pages) + in_cache == 5
+    in_cache = eng.pages.radix.n_nodes
+    assert eng.pages.pool.n_free + in_cache == 5
     assert eng.page_leaks() == 0
 
 
@@ -443,8 +443,8 @@ def test_speculative_paged_page_accounting(model):
     for i in range(3):  # reuse the pool across rounds
         out = _run(eng, [[1 + i, 2, 3, 4, 5]], maxnt=10)
         assert len(out[0]) == 10
-    in_cache = eng.radix.n_nodes
-    assert len(eng._free_pages) + in_cache == 7  # page 0 = scratch
+    in_cache = eng.pages.radix.n_nodes
+    assert eng.pages.pool.n_free + in_cache == 7  # page 0 = scratch
     assert eng.page_leaks() == 0
 
 
@@ -461,7 +461,7 @@ def test_speculative_paged_prefix_cache_composes(model):
     eng.run_until_idle()
     r2 = eng.submit(p2, max_new_tokens=6)
     eng.run_until_idle()
-    assert eng.prefix_hits == 1
+    assert eng.pages.prefix_hits == 1
     dense = InferenceEngine(model, n_slots=2, max_len=128)
     d1 = dense.submit(p1, max_new_tokens=6)
     d2 = dense.submit(p2, max_new_tokens=6)
@@ -503,23 +503,23 @@ def test_subpage_prefix_sharing_skips_prefill(model):
     p2 = p1[:13] + [99 + i for i in range(29)]
     r2 = eng.submit(p2, max_new_tokens=6)
     eng.run_until_idle()
-    assert eng.prefix_hits == 1            # full page 0
-    assert eng.prefix_partial_hits == 1    # partial page 1
-    assert eng.prefix_tokens_reused == 5
+    assert eng.pages.prefix_hits == 1            # full page 0
+    assert eng.pages.prefix_partial_hits == 1    # partial page 1
+    assert eng.pages.prefix_tokens_reused == 5
 
     # no full page shared: 6/8 of page 0 only, same bucket shrink
     p3 = p1[:6] + [77 + i for i in range(28)]
     r3 = eng.submit(p3, max_new_tokens=6)
     eng.run_until_idle()
-    assert eng.prefix_partial_hits == 2
-    assert eng.prefix_tokens_reused == 5 + 6
+    assert eng.pages.prefix_partial_hits == 2
+    assert eng.pages.prefix_tokens_reused == 5 + 6
 
     # sharing so little that the bucket plan is unchanged: no copy
-    before = eng.prefix_partial_hits
+    before = eng.pages.prefix_partial_hits
     p4 = p1[:13] + [200, 201]
     r4 = eng.submit(p4, max_new_tokens=6)
     eng.run_until_idle()
-    assert eng.prefix_partial_hits == before
+    assert eng.pages.prefix_partial_hits == before
 
     dense = InferenceEngine(model, n_slots=2, max_len=128)
     outs = []
@@ -544,8 +544,8 @@ def test_subpage_sharing_source_page_protected_from_eviction(model):
     eng.submit(p1, max_new_tokens=4)
     eng.run_until_idle()
 
-    saved = list(eng._free_pages)
-    eng._free_pages.clear()  # only the 2 cached prefix pages remain
+    saved = list(eng.pages.pool.free)
+    eng.pages.pool.free.clear()  # only the 2 cached prefix pages remain
     # long tail so the copy plan engages (bucket 64 -> 32)
     p2 = p1[:13] + [99 + i for i in range(29)]
     r2 = eng.submit(p2, max_new_tokens=4)
@@ -553,7 +553,7 @@ def test_subpage_sharing_source_page_protected_from_eviction(model):
     assert not r2.done  # deferred: page 0 is shared, page 1 is the src
     assert eng._waiting is not None
 
-    eng._free_pages.extend(saved)
+    eng.pages.pool.free.extend(saved)
     eng.run_until_idle()
     assert r2.done and not r2.error
     dense = InferenceEngine(model, n_slots=1, max_len=64)
@@ -612,7 +612,7 @@ def test_no_page_leak_under_cancel_rounds(model):
     non-cached page to the free list with no negative refcounts."""
     eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
                           page_size=8, n_pages=12)
-    free0 = len(eng._free_pages)
+    free0 = eng.pages.pool.n_free
     for round_i in range(3):
         rs = [eng.submit([round_i * 17 + j, 5, 6, 7, 8], max_new_tokens=40)
               for j in range(2)]
@@ -621,6 +621,6 @@ def test_no_page_leak_under_cancel_rounds(model):
         for r in rs:
             eng.cancel(r)
         eng.run_until_idle()
-        assert len(eng._free_pages) + eng.radix.n_nodes == free0
+        assert eng.pages.pool.n_free + eng.pages.radix.n_nodes == free0
         assert eng.page_leaks() == 0
-        assert not [r for r in eng._page_ref[1:] if r < 0]
+        assert not [r for r in eng.pages.pool.ref[1:] if r < 0]

@@ -213,7 +213,8 @@ def _tiny_model(seed=0):
 
 def test_tp_generate_comm_qtype_routing():
     prompts = [[3, 1, 4, 1, 5, 9, 2, 6]]
-    ref = _tiny_model().generate(prompts, max_new_tokens=12)
+    base = _tiny_model()
+    ref = base.generate(prompts, max_new_tokens=12)
     mesh = _tp_mesh(2)
 
     # "none" reduces through an exact psum: byte-identical tokens
@@ -222,11 +223,29 @@ def test_tp_generate_comm_qtype_routing():
     out = exact.generate(prompts, max_new_tokens=12)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
 
-    # int8 comm: greedy decode survives the quantized epilogues
+    # int8 comm moves every logit by up to the format's declared
+    # tolerance, so a near-tie may flip and greedy TOKENS are no
+    # invariant (this model's top-2 margins sit inside it). Held to the
+    # reference on the context int8 itself generated, where both see the
+    # same inputs: logits within TOLERANCE, and every token generate()
+    # picked through the quantized ring no further below the reference's
+    # best than the two logits can have moved
     q = _tiny_model().to_mesh(mesh, comm_qtype="int8")
     assert q.comm is not None and q.comm.enabled
-    outq = q.generate(prompts, max_new_tokens=12)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(outq))
+    outq = np.asarray(q.generate(prompts, max_new_tokens=12))
+    assert outq.shape == np.asarray(ref).shape
+    ctx = jnp.asarray(np.concatenate([prompts, outq], axis=1)[:, :-1])
+    cfg = base.config
+    want = np.asarray(
+        base.family.forward(cfg, base.params, ctx, None)[0], np.float32)[0]
+    with q._mesh_ctx():
+        got = jax.jit(lambda p, t: q.forward_fn(cfg, p, t, None)[0])(
+            q.params, ctx)
+    bound = qc.TOLERANCE["int8"] * np.abs(want).max()
+    assert np.abs(np.asarray(got, np.float32)[0] - want).max() <= bound
+    at = want[len(prompts[0]) - 1:]  # the rows that chose outq
+    picked = at[np.arange(outq.shape[1]), outq[0]]
+    assert (at.max(-1) - picked <= 2 * bound).all(), at.max(-1) - picked
 
 
 def test_default_comm_qtype_attribute():

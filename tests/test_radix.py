@@ -249,7 +249,7 @@ def test_pop_deepest_match_orders_and_keeps_fifo_ties():
     pre = list(range(1, 33))  # 2 full pages at page_size 16
     seed = eng.submit(pre + [40, 41], max_new_tokens=2)
     eng.run_until_idle(max_steps=100)
-    assert seed.done and eng.radix.n_nodes == 2  # cache primed
+    assert seed.done and eng.pages.radix.n_nodes == 2  # cache primed
     # queue: miss A, 1-page match B, 2-page match C, miss D
     a = eng.submit([9] * 8, max_new_tokens=2)
     b = eng.submit(pre[:16] + [7, 7], max_new_tokens=2)

@@ -117,13 +117,13 @@ def test_nan_logits_paged_releases_pages(model):
     inj = FaultInjector(seed=0)
     eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
                           page_size=8, faults=inj)
-    free0 = len(eng._free_pages)
+    free0 = eng.pages.pool.n_free
     r = eng.submit([3, 1, 4, 1, 5], max_new_tokens=20)
     eng.step()
     inj.arm("nan_logits", times=1)
     eng.run_until_idle()
     assert r.done and r.finish_reason == "error"
-    assert len(eng._free_pages) + eng.radix.n_nodes == free0
+    assert eng.pages.pool.n_free + eng.pages.radix.n_nodes == free0
     assert eng.page_leaks() == 0
 
 
@@ -361,7 +361,7 @@ def test_chaos_sweep_survives_every_fault_class(model, tmp_path):
     eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
                           page_size=8, n_pages=10, faults=inj,
                           journal=str(tmp_path / "sweep.jsonl"))
-    free0 = len(eng._free_pages)
+    free0 = eng.pages.pool.n_free
     reqs = [eng.submit([2 + i, 7, 9, 11], max_new_tokens=30)
             for i in range(4)]
     eng.step()
@@ -377,7 +377,7 @@ def test_chaos_sweep_survives_every_fault_class(model, tmp_path):
             assert len(r.out_tokens) == 30, (
                 f"'{r.finish_reason}' after {len(r.out_tokens)} tokens"
             )
-    assert len(eng._free_pages) + eng.radix.n_nodes == free0
+    assert eng.pages.pool.n_free + eng.pages.radix.n_nodes == free0
     assert eng.page_leaks() == 0
     assert not eng._preempted and not eng.active.any()
     # still serving after the sweep

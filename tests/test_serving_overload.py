@@ -37,7 +37,7 @@ def model():
 def _pages_balanced(eng) -> bool:
     """Every page is either free, radix-cached, or the scratch page,
     and every refcount matches its accounted holders."""
-    ok = (len(eng._free_pages) + eng.radix.n_nodes
+    ok = (eng.pages.pool.n_free + eng.pages.radix.n_nodes
           == eng.n_pages - 1)
     return ok and eng.page_leaks() == 0
 

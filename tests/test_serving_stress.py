@@ -199,7 +199,7 @@ def test_full_feature_composition_torture(server, tmp_path):
         speculative=True, draft_params=model.params, draft_k=4,
         adaptive_draft=True, quantize_kv=True, journal=jpath,
     )
-    free0 = len(eng._free_pages)
+    free0 = eng.pages.pool.n_free
     reqs = [eng.submit([2 + i, 7, 9, 11], max_new_tokens=12,
                        do_sample=(i % 2 == 0), temperature=0.8)
             for i in range(5)]
@@ -209,7 +209,7 @@ def test_full_feature_composition_torture(server, tmp_path):
     eng.run_until_idle()
     assert all(r.done for r in reqs)
     assert not [r.error for r in reqs if r.error]
-    assert len(eng._free_pages) + eng.radix.n_nodes == free0
+    assert eng.pages.pool.n_free + eng.pages.radix.n_nodes == free0
     assert eng.page_leaks() == 0
     eng2 = InferenceEngine(model, n_slots=2, max_len=96, paged=True,
                            page_size=8, journal=jpath)
