@@ -496,6 +496,11 @@ def _moe_dispatch_dense(
 
 _EXPERT_STACKS = ("w_gate_e", "w_up_e", "w_down_e")
 
+# the per-layer weights that go through `linear` (the shared expert's go
+# through the XLA dequant)
+_LINEAR_STACKS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                  "wqkv", "w_gateup")
+
 
 def _moe_dispatch_grouped(
     config: ModelConfig, xc: jax.Array, p: Params, compute_dtype,
@@ -859,24 +864,39 @@ def forward(
         mask_sliding = mask_sliding[:, None, None]
 
     lora_scale = lora["scale"] if lora is not None else None
-
-    # The packed codes of expert stacks that take the grouped kernel stay
-    # OUT of the scan's per-layer slices: the kernel reads its blocks from
-    # the whole [L, E, O, C] array by layer index, where a slice handed to
-    # a Mosaic call is first copied whole (every expert, hit or not, every
-    # step). Scales stay sliced, and fp8 codes, which reach the kernel
-    # through a bitcast that would copy the whole stack instead.
-    layers = params["layers"]
-    moe_codes = {}
-    if config.is_moe and moe_grouped_why_not(layers, lora is not None) is None:
-        moe_codes = {n: layers[n].data for n in _EXPERT_STACKS if n in layers
-                     and not layers[n].spec.storage.startswith("fp8")}
-        layers = {n: dataclasses.replace(w, data=None) if n in moe_codes
-                  else w for n, w in layers.items()}
-
     tp_sharded = comm is not None and comm.axis_size > 1
 
-    def proj(x, p, lp, wname, bname=None):
+    # The packed codes of every per-layer weight that goes to a kernel
+    # stay OUT of the scan's per-layer slices: the kernel reads its blocks
+    # from the whole stack by layer index ([L, O, C] through `linear`'s
+    # `layer`, the experts' [L, E, O, C] through the grouped kernel's),
+    # where a slice handed to a Mosaic call is first copied whole, every
+    # layer of every step (and every expert, hit or not). Who keeps today's
+    # slices, each by what `forward` sees in its inputs: adapters (the
+    # backward's dx kernel takes one layer's weight, and the XLA expert
+    # formulations differentiate), projections that run per shard under
+    # tensor parallelism, a weight the kernels' shape guard refuses (the
+    # XLA dequant fuses its own slice), and fp8 codes, which reach a kernel
+    # through a bitcast that would copy the whole stack instead. Scales
+    # stay sliced: a sixteenth of the bytes, and the uint16 view of a whole
+    # float16 stack is itself a copy XLA materialises.
+    layers = params["layers"]
+    stack_codes = {}
+    if lora is None:
+        from bigdl_tpu.ops.linear import grouped_route
+
+        names = [n for n in _LINEAR_STACKS if n in layers
+                 and not (tp_sharded and n in _TP_PARALLEL)
+                 and grouped_route(layers[n]) is None]
+        if config.is_moe and moe_grouped_why_not(layers, False) is None:
+            names += [n for n in _EXPERT_STACKS if n in layers]
+        stack_codes = {n: layers[n].data for n in names
+                       if not layers[n].spec.storage.startswith("fp8")}
+        layers = {n: dataclasses.replace(w, data=None) if n in stack_codes
+                  else w for n, w in layers.items()}
+    moe_stacked = any(n in stack_codes for n in _EXPERT_STACKS)
+
+    def layer_proj(x, p, lp, wname, bname=None, idx=None):
         b = p.get(bname) if bname else None
         pair = lp[wname] if lp is not None and wname in lp else None
         if tp_sharded and wname in _TP_PARALLEL:
@@ -898,7 +918,8 @@ def forward(
             # applies the same lora_epilogue einsums as before
             lo = ((pair["a"], pair["b"], lora_scale)
                   if pair is not None else None)
-            y = linear(x, p[wname], b, compute_dtype, lora=lo)
+            y = linear(x, p[wname], b, compute_dtype, lora=lo,
+                       layer=idx if wname in stack_codes else None)
         return y
 
     # per-layer static sliding flags, as a traced vector for the scan body
@@ -910,13 +931,18 @@ def forward(
     def body(carry, xs):
         hidden, c, idx = carry
         p, lp = xs if lora is not None else (xs, None)
+        # the unsliced codes go back in, with the index that finds this
+        # layer in them
+        p = {**p, **{n: dataclasses.replace(p[n], data=d)
+                     for n, d in stack_codes.items()}}
+        proj = functools.partial(layer_proj, idx=idx)
 
         with jax.named_scope("norm_rope"):
             x = norm(hidden, p["attn_norm"], p.get("attn_norm_b"))
         with jax.named_scope("attn"):
             if "wqkv" in p:  # merged layout (merge_fused_params)
                 QD, KD = Hq * D, Hkv * D
-                qkv = linear(x, p["wqkv"], p.get("bqkv"), compute_dtype)
+                qkv = proj(x, p, None, "wqkv", "bqkv")
                 q, k, v = (qkv[..., :QD], qkv[..., QD:QD + KD],
                            qkv[..., QD + KD:])
                 if lp is not None:  # lora stays keyed by the unmerged names
@@ -1025,14 +1051,12 @@ def forward(
             x = mlp_in
             routed = None
             if config.is_moe:
-                codes = {n: dataclasses.replace(p[n], data=d)
-                         for n, d in moe_codes.items()}
                 down, routed = _moe_block(
-                    config, x, {**p, **codes}, compute_dtype,
+                    config, x, p, compute_dtype,
                     differentiable=lora is not None,
-                    layer=idx if codes else None)
+                    layer=idx if moe_stacked else None)
             elif "w_gateup" in p:  # merged layout (merge_fused_params)
-                gu = linear(x, p["w_gateup"], p.get("b_gateup"), compute_dtype)
+                gu = proj(x, p, None, "w_gateup", "b_gateup")
                 I2 = gu.shape[-1] // 2
                 gate, up = gu[..., :I2], gu[..., I2:]
                 if lp is not None:

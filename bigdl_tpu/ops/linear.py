@@ -28,6 +28,7 @@ oracle.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 from typing import Callable, NamedTuple, Optional, Union
 
@@ -53,49 +54,51 @@ def _rows(shape) -> int:
     return n
 
 
-def _run_sym_int4(x, w, bo):
+def _run_sym_int4(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_int4
 
-    return qmatmul_int4(x, w.data, w.scales, out_dtype=x.dtype, block_o=bo)
+    return qmatmul_int4(x, w.data, w.scales, out_dtype=x.dtype, block_o=bo,
+                        layer=layer)
 
 
-def _run_asym_int4(x, w, bo):
+def _run_asym_int4(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_asym_int4
 
     return qmatmul_asym_int4(x, w.data, w.scales, w.mins, out_dtype=x.dtype,
-                             block_o=bo)
+                             block_o=bo, layer=layer)
 
 
-def _run_codebook(x, w, bo):
+def _run_codebook(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_codebook
 
     return qmatmul_codebook(x, w.data, w.scales, codebook=w.spec.codebook,
                             block=w.spec.block_size, out_dtype=x.dtype,
-                            block_o=bo)
+                            block_o=bo, layer=layer)
 
 
-def _run_int8(x, w, bo):
+def _run_int8(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_int8
 
-    return qmatmul_int8(x, w.data, w.scales, out_dtype=x.dtype, block_o=bo)
+    return qmatmul_int8(x, w.data, w.scales, out_dtype=x.dtype, block_o=bo,
+                        layer=layer)
 
 
-def _run_asym_int5(x, w, bo):
+def _run_asym_int5(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_bytes
 
     return qmatmul_bytes(x, w.data, w.scales, w.mins, decode="i8",
                          block=w.spec.block_size, out_dtype=x.dtype,
-                         block_o=bo)
+                         block_o=bo, layer=layer)
 
 
-def _run_fp8(x, w, bo):
+def _run_fp8(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_fp8
 
     return qmatmul_fp8(x, w.data, w.scales, block=w.spec.block_size,
-                       out_dtype=x.dtype, block_o=bo)
+                       out_dtype=x.dtype, block_o=bo, layer=layer)
 
 
-def _run_planes(x, w, bo):
+def _run_planes(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_planes
 
     spec = w.spec
@@ -106,28 +109,32 @@ def _run_planes(x, w, bo):
     else:  # sym_int5: v - 16
         decode = ("offset", 16)
     return qmatmul_planes(x, w.data, w.scales, spec.planes, decode,
-                          spec.block_size, out_dtype=x.dtype, block_o=bo)
+                          spec.block_size, out_dtype=x.dtype, block_o=bo,
+                          layer=layer)
 
 
-def _run_q4k(x, w, bo):
+def _run_q4k(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_q4k
 
     return qmatmul_q4k(x, w.data, w.scales, w.mins, w.sub_scales,
-                       w.sub_mins, out_dtype=x.dtype, block_o=bo)
+                       w.sub_mins, out_dtype=x.dtype, block_o=bo,
+                       layer=layer)
 
 
-def _run_q5k(x, w, bo):
+def _run_q5k(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_q5k
 
     return qmatmul_q5k(x, w.data, w.scales, w.mins, w.sub_scales,
-                       w.sub_mins, out_dtype=x.dtype, block_o=bo)
+                       w.sub_mins, out_dtype=x.dtype, block_o=bo,
+                       layer=layer)
 
 
-def _run_q2k(x, w, bo):
+def _run_q2k(x, w, bo, layer=None):
     from bigdl_tpu.ops.pallas import qmatmul_q2k
 
     return qmatmul_q2k(x, w.data, w.scales, w.mins, w.sub_scales,
-                       w.sub_mins, out_dtype=x.dtype, block_o=bo)
+                       w.sub_mins, out_dtype=x.dtype, block_o=bo,
+                       layer=layer)
 
 
 def _run_dx(g, w, bo):
@@ -138,13 +145,13 @@ def _run_dx(g, w, bo):
     return qmatmul_dx(g, w, out_dtype=g.dtype, block_o=bo)
 
 
-def _run_q6k(x, w, bo):
+def _run_q6k(x, w, bo, layer=None):
     # planar q3_k is structurally identical to q6_k (int8 centered
     # codes, int8 sub-scales per 16, f16 d per 256) and shares its kernel
     from bigdl_tpu.ops.pallas import qmatmul_q6k
 
     return qmatmul_q6k(x, w.data, w.scales, w.sub_scales, out_dtype=x.dtype,
-                       block_o=bo)
+                       block_o=bo, layer=layer)
 
 
 class _GemvEntry(NamedTuple):
@@ -255,12 +262,15 @@ def _shape_guard(w: QTensor) -> tuple[Optional[_GemvEntry], str]:
     return entry, ""
 
 
-def _fused_route(x: jax.Array, w: QTensor) -> tuple[Optional[Callable], str]:
+def _fused_route(x: jax.Array, w: QTensor, stacked: bool = False
+                 ) -> tuple[Optional[Callable], str]:
     """(kernel, why): the fused kernel this (x, w) pair dispatches to,
     or None with the guard that sent it to the XLA dequant route. Shape
-    guards are shared by both shape classes."""
-    if w.data.ndim != 2:
-        return None, f"weight is rank {w.data.ndim}, kernels take rank 2"
+    guards are shared by both shape classes. `stacked`: the codes carry
+    a leading layer axis the caller indexes (`linear`'s `layer`)."""
+    rank = w.data.ndim - int(stacked)
+    if rank != 2:
+        return None, f"weight is rank {rank}, kernels take rank 2"
     entry, why = _shape_guard(w)
     if entry is None:
         return None, why
@@ -272,16 +282,18 @@ def _fused_route(x: jax.Array, w: QTensor) -> tuple[Optional[Callable], str]:
 
 
 def grouped_route(*stacks) -> Optional[str]:
-    """None when every expert stack ([.., E, O, K] QTensors of one MoE
-    layer) can take the grouped kernel (`ops/pallas/moe_qmatmul.py`: rows
-    sorted by expert, each tile's weights read packed from its expert),
-    else the guard that refuses: the same format and shape rules as
-    `linear`'s kernels, applied to one expert."""
+    """None when every [O, K] weight of every stack takes a kernel
+    whatever the row count, else the guard that refuses: `linear`'s
+    format and shape rules applied to one weight of the stack. The
+    stacks are a model's layers `[L, O, K]` (`qmatmul`, reading layer
+    `layer` in place) or one MoE layer's experts `[.., E, O, K]` (the
+    grouped kernel, `ops/pallas/moe_qmatmul.py`: rows sorted by expert,
+    each tile's weights read packed from its expert)."""
     for w in stacks:
         if not isinstance(w, QTensor):
-            return "expert weights are dense, not packed"
+            return "weights are dense, not packed"
         if w.data.ndim < 3:
-            return f"weight is rank {w.data.ndim}, not a stack of experts"
+            return f"weight is rank {w.data.ndim}, not a stack"
         entry, why = _shape_guard(w)
         if entry is None:
             return why
@@ -362,22 +374,34 @@ def _zero_cotangent(w: QTensor) -> QTensor:
     return w.map_arrays(z)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _fused_matmul(x: jax.Array, w: QTensor, qtype: str, block_o: int):
+def _layer_of(w: QTensor, layer) -> QTensor:
+    """One layer's weight, for the paths that take rank 2: `w` as it is,
+    or with its stacked codes sliced at `layer` (a copy of them)."""
+    if layer is None:
+        return w
+    return dataclasses.replace(w, data=jax.lax.dynamic_index_in_dim(
+        w.data, layer, keepdims=False))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _fused_matmul(x: jax.Array, w: QTensor, layer, qtype: str, block_o: int):
     entry = _QGEMV_QTYPES[qtype]
     run = entry.run if _rows(x.shape) <= _GEMV_MAX_ROWS else entry.gemm
-    return run(x, w, block_o)
+    return run(x, w, block_o, layer=layer)
 
 
-def _fused_fwd(x, w, qtype, block_o):
-    return _fused_matmul(x, w, qtype, block_o), w
+def _fused_fwd(x, w, layer, qtype, block_o):
+    return _fused_matmul(x, w, layer, qtype, block_o), (w, layer)
 
 
-def _fused_bwd(qtype, block_o, w, g):
+def _fused_bwd(qtype, block_o, res, g):
     # dx = g @ dequant(W) through the fused Pallas kernel (or the XLA
-    # remat oracle under fused_backward_scope(False)); W itself is
-    # frozen, so its cotangent is a symbolic zero
-    return _fused_dx(g, w, qtype, block_o), _zero_cotangent(w)
+    # remat oracle under fused_backward_scope(False)), which takes one
+    # layer's weight; W itself is frozen, so its cotangent is a symbolic
+    # zero
+    w, layer = res
+    dx = _fused_dx(g, _layer_of(w, layer), qtype, block_o)
+    return dx, _zero_cotangent(w), None
 
 
 _fused_matmul.defvjp(_fused_fwd, _fused_bwd)
@@ -502,9 +526,17 @@ def linear(
     bias: Optional[jax.Array] = None,
     compute_dtype=jnp.bfloat16,
     lora=None,
+    layer=None,
 ) -> jax.Array:
     """y = x @ W^T (+ bias) (+ LoRA delta). W has logical shape
     [out_features, in_features].
+
+    With ``layer`` (a traced index) the packed codes ``w.data`` are those
+    of a whole stack of layers ``[L, O, C]`` and the kernel reads layer
+    ``layer`` of them in place; every other field of ``w`` is that
+    layer's own. A caller inside a layer scan hands the codes over this
+    way because a per-layer slice given to a Mosaic call is copied whole
+    first (`models/llama.forward` says which weights it does this for).
 
     QTensor weights route to the fused Pallas dequant kernels whenever
     the shape is eligible (GEMV below `_GEMV_MAX_ROWS` rows, tiled GEMM
@@ -521,14 +553,18 @@ def linear(
     doubles as the fused path's parity oracle.
     """
     if isinstance(w, QTensor):
-        kernel, why = _fused_route(x, w)
+        stacked = layer is not None
+        assert not (stacked and lora is not None), (
+            "an adapter's base weight comes sliced: the backward's dx "
+            "kernel takes one layer")
+        kernel, why = _fused_route(x, w, stacked)
         routes.note(
             "linear", f"pallas:{why}" if kernel is not None else "xla",
             f"{w.qtype} M{_rows(x.shape)} K{w.shape[-1]} "
-            f"O{w.data.shape[-2]}" + ("" if kernel is not None
-                                      else f" ({why})"))
+            f"O{w.data.shape[-2]} " + ("stack" if stacked else "slice")
+            + ("" if kernel is not None else f" ({why})"))
         if kernel is not None:
-            block_o = 256 if w.data.shape[0] % 256 == 0 else 128
+            block_o = 256 if w.data.shape[-2] % 256 == 0 else 128
             xc = x.astype(compute_dtype)
             if lora is not None:
                 ops = _lora_cat_operands(x, lora, compute_dtype)
@@ -537,13 +573,13 @@ def linear(
                     if bias is not None:
                         y = y + bias.astype(compute_dtype)
                     return y
-            y = _fused_matmul(xc, w, w.qtype, block_o)
+            y = _fused_matmul(xc, w, layer, w.qtype, block_o)
             if lora is not None:
                 y = y + lora_epilogue(x, *lora, compute_dtype)
             if bias is not None:
                 y = y + bias.astype(compute_dtype)
             return y
-        wd = w.dequantize(compute_dtype)
+        wd = _layer_of(w, layer).dequantize(compute_dtype)
     else:
         wd = w.astype(compute_dtype)
     y = jnp.einsum(

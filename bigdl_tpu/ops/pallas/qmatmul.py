@@ -87,10 +87,12 @@ def _f16_bits(a: jax.Array) -> jax.Array:
 # the unified kernel: one O x M tile, any DecodeSpec
 # ---------------------------------------------------------------------------
 
-def _kernel(x_ref, w_ref, *rest, K: int, ck: int, spec: DecodeSpec,
-            lora: bool = False):
+def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
+            spec: DecodeSpec, lora: bool = False):
     """One [block_m, block_o] output tile: acc += x_chunk @ dq(W_chunk)^T
     over statically-unrolled chunks of the logical contraction axis.
+    `layer_ref` is read by the weight's index map alone: the tile arrives
+    as `[block_o, row_bytes]` whichever layer of the stack it came from.
     The weight tile is loaded packed and upcast PER CHUNK inside
     qdecode.decode_chunk — a hoisted full-row int32 copy would keep
     4 B/packed-byte live across the whole unrolled loop and defeat the
@@ -105,6 +107,7 @@ def _kernel(x_ref, w_ref, *rest, K: int, ck: int, spec: DecodeSpec,
     per-row adapter selection AND scale: row m holds scale_m in its own
     adapter group's rank-bucket columns and 0 elsewhere, which is how
     one dot pair serves a heterogeneous multi-tenant batch."""
+    del layer_ref
     o_ref = rest[-1]
     if lora:
         a_ref, b_ref, g_ref = rest[-4:-1]
@@ -140,18 +143,25 @@ def _kernel(x_ref, w_ref, *rest, K: int, ck: int, spec: DecodeSpec,
                               "ck", "interpret", "lora")
 )
 def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
-         interpret: bool, lora: bool, x2, w, *rest):
+         interpret: bool, lora: bool, layer, x2, w, *rest):
+    """`w` is the packed codes of a STACK of weights `[L, O, row_bytes]`
+    and `layer [1]` int32 the one to multiply by, scalar-prefetched so
+    the index map can name it: the tile's DMA reads layer `layer[0]` out
+    of the whole array. A slice `w[layer]` handed to a Mosaic call would
+    first be copied whole, every call. Everything else (`rest`: scales,
+    LoRA operands) is one layer's own rank-2 array."""
     Mp, K = x2.shape
-    O = w.shape[0]
+    O = w.shape[1]
     if lora:
         *side, la, lb, lg = rest
     else:
         side = rest
-    row = lambda m, o: (o, 0)  # weight-side blocks follow the O grid dim
+    row = lambda m, o, l: (o, 0)  # weight-side blocks follow the O grid dim
     in_specs = [
-        pl.BlockSpec((block_m, K), lambda m, o: (m, 0),
+        pl.BlockSpec((block_m, K), lambda m, o, l: (m, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((block_o, w.shape[1]), row, memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, block_o, w.shape[2]),
+                     lambda m, o, l: (l[0], o, 0), memory_space=pltpu.VMEM),
     ] + [
         pl.BlockSpec((block_o, a.shape[1]), row, memory_space=pltpu.VMEM)
         for a in side
@@ -163,11 +173,11 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
         # every spec legal at any rank bucket (R need not be
         # lane/sublane aligned when the block covers the whole dim).
         in_specs += [
-            pl.BlockSpec((la.shape[0], K), lambda m, o: (0, 0),
+            pl.BlockSpec((la.shape[0], K), lambda m, o, l: (0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((block_o, lb.shape[1]), row,
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_m, lg.shape[1]), lambda m, o: (m, 0),
+            pl.BlockSpec((block_m, lg.shape[1]), lambda m, o, l: (m, 0),
                          memory_space=pltpu.VMEM),
         ]
     # grid order (m, o): o innermost, so the x tile stays resident across
@@ -177,15 +187,19 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
     return pl.pallas_call(
         functools.partial(_kernel, K=K, ck=ck, spec=spec, lora=lora),
         name="qmatmul_lora" if lora else "qmatmul",
-        grid=(Mp // block_m, O // block_o),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (block_m, block_o), lambda m, o: (m, o), memory_space=pltpu.VMEM
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Mp // block_m, O // block_o),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (block_m, block_o), lambda m, o, l: (m, o),
+                memory_space=pltpu.VMEM
+            ),
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, O), out_dtype),
         compiler_params=_params_parallel(),
         interpret=interpret,
-    )(x2, w, *rest)
+    )(layer, x2, w, *rest)
 
 
 def _validate(spec: DecodeSpec, K: int, data) -> None:
@@ -217,8 +231,15 @@ def _side_arrays(spec: DecodeSpec, scales, mins, sub_scales, sub_mins):
 
 
 def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
-           lora=None):
+           lora=None, layer=None):
     """Shared wrapper: flatten/pad rows, pick tiles, run the kernel.
+
+    With ``layer`` (a traced index) ``data`` is the codes of a stack of
+    same-shaped weights ``[L, O, row_bytes]`` and the kernel reads layer
+    ``layer`` of it in place; ``side`` is that layer's own. A weight of
+    its own (``[O, row_bytes]``: the LM head, anything outside a layer
+    scan) is the stack of one read at 0, a reshape and no copy. Tiles are
+    picked from one layer's ``[O, row_bytes]`` either way.
 
     ``lora`` (optional) is the fused-epilogue operand triple
     ``(a_cat [R, K], b_cat [O, R], gate [M, R])`` — see _kernel; the
@@ -228,7 +249,10 @@ def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
     if interpret is None:
         interpret = interpret_mode()
     *lead, K = x.shape
-    O = data.shape[0]
+    if layer is None:
+        data, layer = data[None], 0
+    assert data.ndim == 3, data.shape
+    O = data.shape[1]
     _validate(spec, K, data)
 
     M = 1
@@ -257,7 +281,7 @@ def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
                  gate2)
         lora_bytes = lora_operand_bytes(R, K, 256, block_m)
 
-    persist_row = data.shape[1] * data.dtype.itemsize + sum(
+    persist_row = data.shape[2] * data.dtype.itemsize + sum(
         a.shape[1] * a.dtype.itemsize for a in side)
     block_o = pick_block_o(O, persist_row, cap=block_o)
     persist = (block_o * persist_row + block_m * K * 2
@@ -265,7 +289,8 @@ def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
     ck = chunk_target(block_o, persist, finest_split(K, spec.planes),
                       temp_bpe=20 if spec.mins else 14)
     y = _qmm(spec, jnp.dtype(out_dtype), block_m, block_o, ck,
-             bool(interpret), lora is not None, x2, data, *side, *extra)
+             bool(interpret), lora is not None,
+             jnp.asarray(layer, jnp.int32).reshape(1), x2, data, *side, *extra)
     return y[:M].reshape(*lead, O)
 
 
@@ -279,6 +304,7 @@ def qmatmul(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `w.data` is then a stack [L, O, *]
 ) -> jax.Array:
     """y[..., O] = x @ dequant(W)^T, fused, for any QTensor — GEMV and
     tiled GEMM shapes alike. The decode recipe comes straight from the
@@ -291,7 +317,8 @@ def qmatmul(
         # byte codebook arithmetically from the bit fields
         data = jax.lax.bitcast_convert_type(data, jnp.uint8)
     side = _side_arrays(spec, w.scales, w.mins, w.sub_scales, w.sub_mins)
-    return _fused(x, data, spec, side, out_dtype, block_o, interpret)
+    return _fused(x, data, spec, side, out_dtype, block_o, interpret,
+                  layer=layer)
 
 
 def qmatmul_lora(
@@ -335,11 +362,12 @@ def qmatmul_int4(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """y[..., O] = x @ dequant(W)^T for a sym_int4 QTensor's fields."""
     spec = DecodeSpec(planes=(4,), value=("offset", 8), block=BLOCK)
     return _fused(x, data, spec, (_f16_bits(scales),), out_dtype, block_o,
-                  interpret)
+                  interpret, layer=layer)
 
 
 def qmatmul_codebook(
@@ -351,6 +379,7 @@ def qmatmul_codebook(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """Fused dequant matmul for LUT nibble formats (nf4 / fp4).
 
@@ -364,7 +393,7 @@ def qmatmul_codebook(
         block=block,
     )
     return _fused(x, data, spec, (_f16_bits(scales),), out_dtype, block_o,
-                  interpret)
+                  interpret, layer=layer)
 
 
 def qmatmul_int8(
@@ -374,11 +403,12 @@ def qmatmul_int8(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """y[..., O] = x @ dequant(W)^T for a sym_int8 QTensor's fields:
     weights cross HBM as int8 — half the traffic of bf16."""
     return qmatmul_bytes(x, data, scales, None, "i8", BLOCK, out_dtype,
-                         block_o, interpret)
+                         block_o, interpret, layer)
 
 
 def qmatmul_asym_int4(
@@ -389,6 +419,7 @@ def qmatmul_asym_int4(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """Fused dequant matmul for asym_int4: the per-block min adds one
     rank-1-per-block term, folded into the bf16 weight expansion before
@@ -396,7 +427,7 @@ def qmatmul_asym_int4(
     spec = DecodeSpec(planes=(4,), value=("offset", 0), block=BLOCK,
                       mins=True)
     return _fused(x, data, spec, (_f16_bits(scales), _f16_bits(mins)),
-                  out_dtype, block_o, interpret)
+                  out_dtype, block_o, interpret, layer=layer)
 
 
 def qmatmul_q4k(
@@ -409,6 +440,7 @@ def qmatmul_q4k(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """Fused dequant matmul for planar q4_k (quant/kq_planar.py):
     w = (d*sc)*q - (dmin*mn). Weights cross HBM at 4.625 bits/weight —
@@ -419,7 +451,7 @@ def qmatmul_q4k(
     return _fused(
         x, data, spec,
         (_f16_bits(scales), _f16_bits(mins), sub_scales, sub_mins),
-        out_dtype, block_o, interpret)
+        out_dtype, block_o, interpret, layer=layer)
 
 
 def qmatmul_q6k(
@@ -430,6 +462,7 @@ def qmatmul_q6k(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """Fused matmul for planar q6_k: w = (d*sc)*q per 16-element
     sub-block. Planar q3_k is structurally identical (int8 centered
@@ -437,7 +470,7 @@ def qmatmul_q6k(
     spec = DecodeSpec(planes=(), value=("offset", 0), block=16,
                       super_block=256)
     return _fused(x, data, spec, (_f16_bits(scales), sub_scales),
-                  out_dtype, block_o, interpret)
+                  out_dtype, block_o, interpret, layer=layer)
 
 
 _BYTE_VALUES = {"i8": ("offset", 0), "e4m3": ("e4m3",), "e5m2": ("e5m2",)}
@@ -453,6 +486,7 @@ def qmatmul_bytes(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """Fused dequant matmul for byte-per-element formats: sym_int8,
     asym_int5 (decode="i8" + mins) and fp8_e4m3/fp8_e5m2 (pass data
@@ -463,7 +497,8 @@ def qmatmul_bytes(
                       mins=mins is not None)
     side = ((_f16_bits(scales), _f16_bits(mins)) if mins is not None
             else (_f16_bits(scales),))
-    return _fused(x, data, spec, side, out_dtype, block_o, interpret)
+    return _fused(x, data, spec, side, out_dtype, block_o, interpret,
+                  layer=layer)
 
 
 def qmatmul_fp8(
@@ -474,6 +509,7 @@ def qmatmul_fp8(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """Fused dequant matmul for fp8 weights: bytes cross HBM as stored
     (half the traffic of the bf16 dequant fallback) and decode in-kernel
@@ -481,7 +517,7 @@ def qmatmul_fp8(
     decode = "e4m3" if data.dtype == jnp.float8_e4m3fn else "e5m2"
     bits = jax.lax.bitcast_convert_type(data, jnp.uint8)
     return qmatmul_bytes(x, bits, scales, None, decode, block, out_dtype,
-                         block_o, interpret)
+                         block_o, interpret, layer)
 
 
 def qmatmul_planes(
@@ -494,13 +530,14 @@ def qmatmul_planes(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """Fused dequant matmul for packed multi-plane formats (fp6 at 6,
     sym_int5 at 5, nf3 at 3 bits/weight of HBM traffic vs 16 for the
     dequant fallback). `decode` is the qdecode value tag as-is."""
     spec = DecodeSpec(planes=tuple(planes), value=tuple(decode), block=block)
     return _fused(x, data, spec, (_f16_bits(scales),), out_dtype, block_o,
-                  interpret)
+                  interpret, layer=layer)
 
 
 def qmatmul_q2k(
@@ -513,6 +550,7 @@ def qmatmul_q2k(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """Fused matmul for planar q2_k: w = (d*sc)*q - (dmin*mn) per
     16-element sub-block, 2.625 bits/weight of HBM traffic."""
@@ -521,7 +559,7 @@ def qmatmul_q2k(
     return _fused(
         x, data, spec,
         (_f16_bits(scales), _f16_bits(mins), sub_scales, sub_mins),
-        out_dtype, block_o, interpret)
+        out_dtype, block_o, interpret, layer=layer)
 
 
 def qmatmul_q5k(
@@ -534,6 +572,7 @@ def qmatmul_q5k(
     out_dtype=jnp.bfloat16,
     block_o: int = 256,
     interpret: bool | None = None,
+    layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
     """Fused matmul for planar q5_k: q4_k's two-level math with the 5th
     code bit read from an extra packed plane (5.625 bits/weight)."""
@@ -542,4 +581,4 @@ def qmatmul_q5k(
     return _fused(
         x, data, spec,
         (_f16_bits(scales), _f16_bits(mins), sub_scales, sub_mins),
-        out_dtype, block_o, interpret)
+        out_dtype, block_o, interpret, layer=layer)

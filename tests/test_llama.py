@@ -319,3 +319,164 @@ def test_hf_equivalence(qtype):
     err = np.abs(np.asarray(qlogits) - hf_logits).mean()
     scale = np.abs(hf_logits).mean() + 1e-6
     assert err / scale < 0.35, err / scale
+
+
+# ---------------------------------------------------------------------------
+# the layer scan hands the kernels whole stacks of packed codes and its
+# index (PR 30), where a per-layer slice given to a Mosaic call is copied
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def interpret(monkeypatch):
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+
+
+STACK_CFG = ModelConfig(
+    vocab_size=256, hidden_size=256, intermediate_size=512,
+    num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+    max_position_embeddings=128)
+
+
+def _stack_params(qtype="sym_int4"):
+    from bigdl_tpu.api import optimize_model
+
+    return optimize_model(
+        llama.init_params(STACK_CFG, jax.random.PRNGKey(5)), STACK_CFG, qtype)
+
+
+@pytest.mark.parametrize("qtype", ["sym_int4", "q4_k"])
+def test_forward_over_stacked_codes_equals_chained_one_layer_forwards(
+        interpret, qtype):
+    """Three DIFFERENT layers through one scan, against three one-layer
+    `forward` calls chained by the pipeline's hooks, each on its own slice
+    of the parameters and its own one-layer cache: bit-equal in prefill and
+    in decode, so a layer index that is wrong or stale cannot pass."""
+    cfg, params = STACK_CFG, _stack_params(qtype)
+    L, B, T = cfg.num_hidden_layers, 2, 40
+    toks = jax.random.randint(jax.random.PRNGKey(6), (B, T), 0, 256)
+
+    def cache(n):
+        return kvcache.init_cache(n, B, 64, cfg.num_key_value_heads,
+                                  cfg.head_dim_)
+
+    def whole(p, t, c, mode):
+        return llama.forward(cfg, p, t, c, mode=mode)
+
+    def chained(p, t, cs, mode):
+        h, out = t, []
+        for l in range(L):
+            one = {**p, "layers": jax.tree.map(lambda a: a[l:l + 1],
+                                               p["layers"])}
+            h, c = llama.forward(
+                cfg, one, h, cs[l], mode=mode, input_is_hidden=l > 0,
+                return_hidden=l < L - 1, layer_offset=l)
+            out.append(c)
+        return h, out
+
+    run = jax.jit(whole, static_argnames="mode")
+    run_chain = jax.jit(chained, static_argnames="mode")
+    lg, c = run(params, toks, cache(L), mode="prefill")
+    lg1, cs = run_chain(params, toks, [cache(1) for _ in range(L)],
+                        mode="prefill")
+    np.testing.assert_array_equal(np.asarray(lg), np.asarray(lg1))
+    nxt = jnp.argmax(lg[:, -1], -1)[:, None].astype(jnp.int32)
+    ld, c = run(params, nxt, c, mode="decode")
+    ld1, cs = run_chain(params, nxt, cs, mode="decode")
+    np.testing.assert_array_equal(np.asarray(ld), np.asarray(ld1))
+    assert np.isfinite(np.asarray(ld)).all()
+    # the layers differ: the same token through layer 0 three times is
+    # another network
+    same = {**params, "layers": jax.tree.map(
+        lambda a: jnp.repeat(a[:1], L, 0), params["layers"])}
+    assert not np.array_equal(
+        np.asarray(run(same, toks, cache(L), mode="prefill")[0]),
+        np.asarray(lg))
+
+
+def _scan_xs(jaxpr):
+    """The avals of the layer scan's per-iteration inputs."""
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"
+             and e.params["length"] == STACK_CFG.num_hidden_layers]
+    assert len(scans) == 1, [e.primitive.name for e in jaxpr.eqns]
+    e = scans[0]
+    skip = e.params["num_consts"] + e.params["num_carry"]
+    return [v.aval for v in e.invars[skip:]]
+
+
+def test_packed_codes_stay_out_of_the_scan_slices_unless_adapters(interpret):
+    """No rank-3 uint8 leaf (a stack of packed codes [L, O, C]) among the
+    scan's sliced inputs when the kernels run and nothing is
+    differentiated; all four of them back under an adapter tree, and on
+    the XLA route, which fuses its own slice."""
+    from bigdl_tpu.train.qlora import init_lora
+
+    params = _stack_params()
+    toks = jnp.zeros((1, 8), jnp.int32)
+    lora = init_lora(STACK_CFG, jax.random.PRNGKey(0), rank=4)
+
+    def codes(lora_tree):
+        jaxpr = jax.make_jaxpr(lambda p, lo: llama.forward(
+            STACK_CFG, p, toks, None, lora=lo))(params, lora_tree).jaxpr
+        return [a for a in _scan_xs(jaxpr)
+                if a.dtype == jnp.uint8 and a.ndim == 3]
+
+    assert codes(None) == []
+    assert len(codes(lora)) == 4  # wqkv, wo, w_gateup, w_down
+
+
+def test_packed_codes_stay_sliced_on_the_xla_route():
+    params = _stack_params()
+    toks = jnp.zeros((1, 8), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p: llama.forward(
+        STACK_CFG, p, toks, None))(params).jaxpr
+    assert len([a for a in _scan_xs(jaxpr)
+                if a.dtype == jnp.uint8 and a.ndim == 3]) == 4
+
+
+@pytest.mark.parametrize("name,stacked", [
+    ("mistral-7b-int4", 4), ("qwen2-7b-int4", 4), ("mixtral-8x7b-int4", 2)])
+def test_bench_configurations_read_every_projection_from_the_stack(
+        interpret, name, stacked):
+    """The benchmark's three configurations at their published widths and
+    two layers, traced with abstract weights: a decode step and a prefill
+    note `stack` for all four projections of a layer (Mixtral: the two of
+    its attention; its experts go through the grouped kernel by the same
+    index), `slice` for the LM head alone, and `stack` for none under an
+    adapter tree."""
+    import os
+
+    from bench import cells, weights
+    from bigdl_tpu.ops.routes import record_routes
+    from bigdl_tpu.train.qlora import init_lora
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = cells.load_json(root, "bench", "configs", name + ".json")
+    hf = cells.as_run(config)
+    hf["num_hidden_layers"] = 2
+    cfg = ModelConfig.from_hf_config(hf)
+    qtype, slots = config["bench"]["qtype"], config["bench"]["engine"]["n_slots"]
+    params = weights.param_shapes(cfg, qtype)
+
+    def notes(B, T, mode, lora=None):
+        cache = jax.eval_shape(lambda: kvcache.init_cache(
+            2, B, 2048, cfg.num_key_value_heads, cfg.head_dim_))
+        with record_routes() as routes:
+            jax.eval_shape(
+                lambda p, c, lo: llama.forward(
+                    cfg, p, jnp.zeros((B, T), jnp.int32), c, mode=mode,
+                    lora=lo),
+                params, cache, lora)
+        lin = {k: n for k, n in routes.items() if k[0] == "linear"}
+        assert all(k[1].startswith("pallas:") for k in lin), lin
+        return (sum(n for k, n in lin.items() if k[2].endswith(" stack")),
+                sum(n for k, n in lin.items() if k[2].endswith(" slice")),
+                routes)
+
+    for B, T, mode in ((slots, 1, "decode"), (1, 1024, "prefill")):
+        n_stack, n_slice, routes = notes(B, T, mode)
+        assert (n_stack, n_slice) == (stacked, 1), routes
+        if cfg.is_moe:
+            assert any(k[:2] == ("moe", "pallas:grouped") for k in routes)
+    lora = jax.eval_shape(lambda: init_lora(cfg, jax.random.PRNGKey(0), 4))
+    n_stack, n_slice, routes = notes(1, 64, "prefill", lora)
+    assert n_stack == 0 and n_slice == stacked + 1, routes

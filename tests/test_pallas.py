@@ -6,6 +6,7 @@ Pallas interpreter on CPU and are diffed against the plain-jnp ops, so
 kernel logic is covered in CI without a chip.
 """
 
+import dataclasses
 import math
 
 import jax
@@ -509,3 +510,111 @@ def test_llama_fp8_kv_prefill_flash_matches_xla(rng, monkeypatch):
         return np.asarray(logits, np.float32)
 
     np.testing.assert_allclose(run("interpret"), run("0"), atol=5e-2)
+
+
+# ---------------------------------------------------------------------------
+# qmatmul on a stack of layers at a traced index (PR 30): the layer scan of
+# models/llama.forward hands the kernel the whole [L, O, C] codes and its
+# index, where a per-layer slice given to a Mosaic call is copied first
+# ---------------------------------------------------------------------------
+
+def _stack_of(rng, qtype, L, O, K):
+    """L DIFFERENT weights quantized as one stack [L, O, K], and each
+    layer's own QTensor."""
+    w = jnp.asarray(rng.normal(size=(L, O, K)) * 0.1, jnp.float32)
+    stack = quantize(w, qtype)
+    assert stack.qtype == qtype and stack.data.ndim == 3
+    return stack, [stack.map_arrays(lambda a, l=l: a[l]) for l in range(L)]
+
+
+def _at_layer(stack, own):
+    """What the scan body hands `linear`: the whole stack's codes, every
+    other field one layer's."""
+    return dataclasses.replace(own, data=stack.data)
+
+
+_STACK_CASES = (
+    [("sym_int4", 128, m) for m in (1, 16, 32, 256)]  # GEMV rows and a GEMM
+    + [("sym_int5", 1024, 16), ("q4_k", 256, 16)]  # two planes; a k-quant
+    + [(q, k, 4) for q, k in (  # and the layer reaches every format's kernel
+        ("asym_int4", 128), ("nf4", 128), ("fp4", 128), ("sym_int8", 128),
+        ("asym_int5", 128), ("fp8_e4m3", 128), ("fp8_e5m2", 128),
+        ("fp6", 512), ("nf3", 1024), ("q2_k", 512), ("q3_k", 256),
+        ("q5_k", 1024), ("q6_k", 256))]
+)
+
+
+@pytest.mark.core
+@pytest.mark.parametrize("qtype,K,m", _STACK_CASES)
+def test_linear_on_a_stack_is_bit_equal_to_the_slice(rng, monkeypatch, qtype,
+                                                     K, m):
+    """`linear(x, w, layer=l)` with `w.data` the codes of three different
+    layers, l traced, against `linear` on layer l's own weight: the same
+    tiles, the same chunks, the same bits. Neighbouring layers differ, so
+    a wrong index cannot pass."""
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+    from bigdl_tpu.ops.linear import _QGEMV_QTYPES, linear
+    from bigdl_tpu.ops.routes import record_routes
+
+    assert set(q for q, _, _ in _STACK_CASES) == set(_QGEMV_QTYPES)
+    L, O = 3, 256
+    stack, own = _stack_of(rng, qtype, L, O, K)
+    x = jnp.asarray(rng.normal(size=(m, K)), jnp.float32).astype(jnp.bfloat16)
+    with record_routes() as routes:
+        at = jax.jit(lambda x, w, l: linear(x, w, layer=l))
+        got = [np.asarray(at(x, _at_layer(stack, own[l]), jnp.int32(l)),
+                          np.float32) for l in range(L)]
+        want = [np.asarray(jax.jit(linear)(x, own[l]), np.float32)
+                for l in range(L)]
+    kind = "gemv" if m <= 32 else "gemm"
+    assert routes == {
+        ("linear", f"pallas:{kind}", f"{qtype} M{m} K{K} O{O} stack"): 1,
+        ("linear", f"pallas:{kind}", f"{qtype} M{m} K{K} O{O} slice"): 1,
+    }
+    for l in range(L):
+        assert np.isfinite(want[l]).all()
+        np.testing.assert_array_equal(got[l], want[l])
+        assert not np.array_equal(want[l], want[(l + 1) % L])
+
+
+@pytest.mark.core
+def test_qmatmul_stack_index_is_traced_and_rank2_is_the_stack_of_one(rng):
+    """One compiled program serves every layer (the index is data, not a
+    constant), and a weight of its own goes through the same call as the
+    stack of one read at 0."""
+    from bigdl_tpu.ops.pallas.qmatmul import qmatmul
+
+    L, O, K = 3, 128, 128
+    stack, own = _stack_of(rng, "sym_int4", L, O, K)
+    x = jnp.asarray(rng.normal(size=(2, K)), jnp.float32).astype(jnp.bfloat16)
+    f = jax.jit(lambda x, w, l: qmatmul(x, w, interpret=True, layer=l))
+    ys = [np.asarray(f(x, _at_layer(stack, own[l]), jnp.int32(l)), np.float32)
+          for l in range(L)]
+    assert f._cache_size() == 1
+    for l in range(L):
+        np.testing.assert_array_equal(
+            ys[l], np.asarray(qmatmul(x, own[l], interpret=True), np.float32))
+    text = str(jax.make_jaxpr(
+        lambda x, w: qmatmul(x, w, interpret=False))(x, own[0]))
+    assert text.count("pallas_call") == 1 and "name=qmatmul" in text
+    assert "u8[1,128,64]" in text  # a reshape in front of the one call
+
+
+@pytest.mark.core
+def test_linear_on_a_stack_differentiates_like_the_slice(rng, monkeypatch):
+    """The backward's dx kernel takes one layer's weight: a stacked call
+    slices its codes there, and dx is the slice's dx."""
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+    from bigdl_tpu.ops.linear import linear
+
+    stack, own = _stack_of(rng, "sym_int4", 3, 128, 128)
+    x = jnp.asarray(rng.normal(size=(4, 128)), jnp.float32)
+    loss = lambda x, w, l: jnp.sum(linear(x, w, layer=l).astype(
+        jnp.float32) ** 2)
+    for l in (0, 2):
+        got = jax.grad(loss)(x, _at_layer(stack, own[l]), jnp.int32(l))
+        want = jax.grad(loss)(x, own[l], None)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(AssertionError, match="adapter"):
+        linear(x, _at_layer(stack, own[0]), layer=jnp.int32(0),
+               lora=(jnp.zeros((2, 128)), jnp.zeros((128, 2)), 1.0))
