@@ -139,6 +139,14 @@ from bigdl_tpu.models import rwkv  # noqa: E402  (attention-free recurrence)
 _FAMILIES["rwkv"] = rwkv
 _FAMILIES["rwkv5"] = rwkv
 
+from bigdl_tpu.models import brumby  # noqa: E402  (llama block, state cache)
+
+# brumby is the llama block with power-retention attention
+# (config.attention_kind): llama.forward runs it, and the family's
+# `init_cache` hands generate a recurrent state where the others get a KV
+# cache (bigdl_tpu/kvstate.py)
+_FAMILIES["brumby"] = brumby
+
 # whisper (models/whisper.py) is an encoder-decoder family with its own
 # WhisperConfig and (params, mel, prompt) call shape — deliberately NOT in
 # _FAMILIES, whose consumers (optimize_model, TpuModel.generate) assume

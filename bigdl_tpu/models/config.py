@@ -57,6 +57,14 @@ class ModelConfig:
     rms_norm_offset: bool = False  # gemma (1+w) rmsnorm weights
     post_attn_norm: bool = False  # gemma2 extra norms after attn/mlp blocks
     qk_norm: bool = False  # per-head RMSNorm on q/k (qwen3-style)
+    # what the attention block computes from q, k, v: "softmax", or
+    # "power_retention" (brumby; bigdl_tpu/kvstate.py has the equations):
+    # weights (q . k / sqrt(D)) ** retention_degree decayed by a learned
+    # per-token gate of each KV head, normalised by their sum +
+    # retention_eps, held as a recurrent state and no keys
+    attention_kind: str = "softmax"
+    retention_degree: int = 2
+    retention_eps: float = 1e-6
     # gemma-style embedding scale
     scale_embeddings: bool = False  # multiply embed output by sqrt(hidden)
     embedding_scale: Optional[float] = None  # minicpm scale_emb multiplier
@@ -124,6 +132,17 @@ class ModelConfig:
     audio_pool_step: Optional[int] = None  # minicpmo post-projection pool
 
     def __post_init__(self):
+        if self.attention_kind not in ("softmax", "power_retention"):
+            raise ValueError(
+                f"attention_kind must be 'softmax' or 'power_retention'; "
+                f"got {self.attention_kind!r}"
+            )
+        if (self.attention_kind == "power_retention"
+                and self.retention_degree != 2):
+            raise NotImplementedError(
+                f"power retention of degree {self.retention_degree}: the "
+                "state's feature map is written for degree 2"
+            )
         if self.moe_dispatch not in (None, "dense", "ragged"):
             raise ValueError(
                 f"moe_dispatch must be None, 'dense' or 'ragged'; "
@@ -547,6 +566,17 @@ def _hf_qwen3(hf, kw):
     kw.setdefault("head_dim", hf.get("head_dim"))
 
 
+def _hf_brumby(hf, kw):
+    """Brumby (Manifest AI): Qwen3's block with the softmax attention
+    replaced by power retention. The source's config.json names none of
+    the retention layer's own sizes; the defaults are the published
+    description's (degree 2, a sigmoid gate per KV head and token)."""
+    _hf_qwen3(hf, kw)
+    kw["attention_kind"] = "power_retention"
+    kw["retention_degree"] = hf.get("retention_degree", 2)
+    kw["retention_eps"] = hf.get("retention_eps", 1e-6)
+
+
 def _hf_qwen3_moe(hf, kw):
     _hf_qwen3(hf, kw)
     kw["num_experts"] = hf.get("num_experts", 128)
@@ -880,6 +910,7 @@ _HF_BUILDERS = {
     "janus": _hf_janus,
     "multi_modality": _hf_janus,  # janus checkpoints' original model_type
     "qwen3": _hf_qwen3,
+    "brumby": _hf_brumby,
     "qwen3_moe": _hf_qwen3_moe,
     "phi": _hf_phi,
     "cohere": _hf_cohere,

@@ -130,6 +130,11 @@ def init_params(
         D = config.head_dim_
         layers["q_norm"] = jnp.ones((L, D), dtype)
         layers["k_norm"] = jnp.ones((L, D), dtype)
+    if config.attention_kind == "power_retention":
+        # the retention gate: one logit per KV head and token. Dense and
+        # outside the fused wqkv on purpose (80 KB a layer at Brumby's
+        # sizes; it is read in float32)
+        layers["w_g"] = w((L, config.num_key_value_heads, H))
     params: Params = {
         "embed": w((V, H)),
         "layers": layers,
@@ -828,7 +833,20 @@ def forward(
     from bigdl_tpu.ops import routes
 
     att_detail = f"mode={mode} B{B} T{T}"
-    if use_paged_kernel:
+    # the second attention kind: no keys are kept and nothing is masked;
+    # what the cache holds is a recurrent state (bigdl_tpu/kvstate.py)
+    retention = config.attention_kind == "power_retention"
+    if retention:
+        from bigdl_tpu import kvstate
+
+        use_flash = use_flash_train = use_paged_kernel = False
+        ret_valid = kvstate.valid_positions(cache, slots, row_start, T)
+        why = (kvstate.why_not_kernel(D) if mode == "decode" and T == 1
+               and cache is not None else "the chunked form is XLA's")
+        routes.note("attention",
+                    "pallas:retention" if why is None else "xla:retention",
+                    att_detail + (f" ({why})" if why else ""))
+    elif use_paged_kernel:
         routes.note("attention", "pallas:paged", att_detail)
         row_live = live_rows(cache)  # the table does not change in here
     elif use_flash_train:
@@ -843,7 +861,7 @@ def forward(
             else "alibi, mixed window layers, softcap or an override")
         routes.note("attention", "xla", f"{att_detail} ({why})")
 
-    if use_flash or use_paged_kernel or use_flash_train:
+    if use_flash or use_paged_kernel or use_flash_train or retention:
         mask_global = mask_sliding = None
         alibi_bias = None
     else:
@@ -976,7 +994,19 @@ def forward(
 
         with jax.named_scope("attn"):
             k_scale_att = v_scale_att = None
-            if c is not None:
+            if retention:
+                # log-gates in float32 at full precision, like a router's
+                # logits: 8 x H weights, and a gate sums over the context
+                gate = jnp.einsum(
+                    "bth,jh->btj", x.astype(jnp.float32),
+                    p["w_g"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+                attn, c = kvstate.attend(
+                    c, idx, q, k, v, jax.nn.log_sigmoid(gate), ret_valid,
+                    config.retention_eps, decode=mode == "decode")
+                attn = attn.astype(compute_dtype)
+            elif c is not None:
                 c = kvcache.update_layer(c, idx, k, v)
                 if use_flash and c.quantized:
                     # fp8 codes + scales go straight to the flash kernel,
@@ -990,7 +1020,9 @@ def forward(
                 k_att = k.astype(compute_dtype)
                 v_att = v.astype(compute_dtype)
 
-            if use_paged_kernel:
+            if retention:
+                pass  # done above, on the state
+            elif use_paged_kernel:
                 from bigdl_tpu.ops.pallas import paged_decode_attention
 
                 if config.sliding_window is None:
@@ -1103,7 +1135,9 @@ def forward(
             h = h[:, -1:]
         with jax.named_scope("lm_head"):
             logits = lm_head_logits(config, params, h, compute_dtype)
-    if cache is not None:
+    if retention and cache is not None:
+        cache = kvstate.advance(cache, T)
+    elif cache is not None:
         cache = kvcache.advance(cache, T)
     out = (logits, cache) + ((obs,) if collect_obs else ())
     return out + ((routing,) if moe_routing else ())

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu import kvcache, kvpaged
+from bigdl_tpu import kvcache, kvpaged, kvstate
 from bigdl_tpu.generate import GenerationConfig, sample_token_per_row
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.obs import retrace
@@ -245,6 +245,8 @@ class _PrefillState:
     moe: list = dataclasses.field(default_factory=list)  # sparse-expert
     # models: (expert choices on the device, tokens) of the chunks so far
     start: int = 0  # `written` at the first chunk (0: the whole prompt)
+    state_chunks: int = 0  # a recurrent-state model: chunks of the
+    # prefill form run so far (the `prefill` span's `state_chunks`)
 
 
 class InferenceEngine:
@@ -348,6 +350,26 @@ class InferenceEngine:
         # fp8 KV storage for the shared pool (dense or paged): halves KV
         # HBM capacity + traffic, the reference's fp8 kv-cache lever
         self.quantize_kv = quantize_kv
+        # a model whose attention keeps a recurrent STATE and no keys
+        # (bigdl_tpu/kvstate.py): a slot then holds one state row where
+        # the others hold pages, and the page table owns the rows
+        self._state_rows = self.config.attention_kind == kvstate.KIND
+        if self._state_rows:
+            kind = f"{kvstate.KIND} ({model.config.model_type})"
+            if not paged:
+                raise NotImplementedError(
+                    f"{kind} is served with paged=True: a slot's recurrent "
+                    "state is a row the page table owns, and there is no "
+                    "dense pool of keys to fall back on")
+            if quantize_kv:
+                raise NotImplementedError(
+                    f"quantize_kv is not available for {kind}: the cache "
+                    "is a float32 recurrent state, not keys and values")
+            if speculative:
+                raise NotImplementedError(
+                    f"speculative serving is not available for {kind}: a "
+                    "rejected draft cannot be taken back out of a "
+                    "recurrent state by moving `pos`")
         # families with their own cache serve through either (a) the
         # generic dataclass insert path when they declare SERVABLE_CACHE
         # (MLA's latent — flat [L, B, S, ...] fields with real pos/start;
@@ -367,7 +389,7 @@ class InferenceEngine:
                 f"{model.config.model_type}: engine_pool and engine_insert "
                 "must be defined together"
             )
-        if hasattr(fam, "init_cache"):
+        if hasattr(fam, "init_cache") and not self._state_rows:
             custom = (self._family_pool is not None
                       and self._family_insert is not None)
             if not custom and not getattr(fam, "SERVABLE_CACHE", False):
@@ -391,6 +413,12 @@ class InferenceEngine:
                 f"{model.config.model_type}'s family cache; use "
                 "quantize_kv=False"
             )
+        if self._state_rows:
+            # what a page is here: the slot's state row, whole. It never
+            # grows, so one page spans `max_len` tokens and a row of the
+            # table has one entry; `page_size` and `n_pages` size nothing
+            # (a caller that builds every engine alike may pass them)
+            page_size, n_pages = max_len, n_slots + 1
         self.page_size = page_size
         # physical reserve past max_len: a speculative verify round writes
         # draft_k tokens at pos..pos+K-1 before rolling back; a request
@@ -442,6 +470,7 @@ class InferenceEngine:
         self.pages = PageTable(
             n_slots, self.n_pages, page_size, self.max_pages_per_row,
             max_len, faults=self._faults,
+            share_prefixes=not self._state_rows,
         ) if paged else None
         self._rng = jax.random.PRNGKey(seed)
         # queue.Queue (not SimpleQueue): the queue-deadline sweep filters
@@ -458,6 +487,10 @@ class InferenceEngine:
         self._mesh = getattr(model, "mesh", None)
 
         self.cache = self._make_pool()
+        # bytes of one slot's recurrent state over all layers (0 for a
+        # model that keeps keys): a decode step moves twice that a live row
+        self.state_row_bytes = (
+            kvstate.row_nbytes(self.cache) if self._state_rows else 0)
         self.cur = jnp.zeros((n_slots,), jnp.int32)  # last token per slot
         self.active = np.zeros((n_slots,), bool)  # host-side mask
         # per-slot sampling params (host mirrors, shipped traced each step)
@@ -556,6 +589,9 @@ class InferenceEngine:
         # activated, and the prompt position its first chunk started at
         self._admit_moe: list = []
         self._admit_moe_start = 0
+        # a recurrent-state model: chunks of the prefill form that the
+        # admission being activated ran (its `prefill` span's argument)
+        self._admit_state_chunks = 0
         self._decode = self._with_mesh(jax.jit(
             _named("engine_decode", self._decode_impl, fwd),
             donate_argnames=("cache", "seen"),
@@ -576,6 +612,9 @@ class InferenceEngine:
         self._paged_prefill = self._with_mesh(jax.jit(
             _named("engine_paged_prefill", self._paged_prefill_impl, fwd),
             donate_argnames=("k", "v", "ks", "vs"),
+        ) if not self._state_rows else jax.jit(
+            _named("engine_paged_prefill", self._state_prefill_impl, fwd),
+            donate_argnames=("S", "z"),
         ))
         self._copy_page = self._with_mesh(jax.jit(
             self._copy_page_impl, donate_argnames=("cache",)
@@ -758,6 +797,10 @@ class InferenceEngine:
         # preemption is gated off for them.
         if self._family_cache is not None:
             self.preemption = False
+        elif self._state_rows:
+            self._swap_in = self._with_mesh(jax.jit(
+                kvstate.swap_in_rows, donate_argnames=("state",)
+            ))
         elif paged:
             self._swap_in = self._with_mesh(jax.jit(
                 kvpaged.swap_in_pages, donate_argnames=("cache",)
@@ -830,6 +873,14 @@ class InferenceEngine:
             return dataclasses.replace(
                 cache, pos=jnp.zeros((self.n_slots,), jnp.int32)
             )
+        if self._state_rows:
+            cache = kvstate.init_state(
+                cfg.num_hidden_layers, self.n_slots,
+                cfg.num_key_value_heads, cfg.head_dim_, max_len=self.max_len)
+            # per-row positions, and the table: no slot holds a row yet
+            return dataclasses.replace(
+                cache, pos=jnp.zeros((self.n_slots,), jnp.int32),
+                block_tables=jnp.zeros((self.n_slots, 1), jnp.int32))
         if self.paged and not force_dense:
             cache = kvpaged.init_paged(
                 cfg.num_hidden_layers, self.n_pages, self.page_size,
@@ -945,6 +996,23 @@ class InferenceEngine:
             forward, params, tokens, cache, "prefill", kw)
         return (logits[0, last_idx], cache.k, cache.v, cache.k_scale,
                 cache.v_scale, None if experts is None else experts[:, 0])
+
+    def _state_prefill_impl(self, forward, params, S, z, row_bt, pos0,
+                            tokens, last_idx, lora=None):
+        """`_paged_prefill_impl` for a model that keeps a recurrent state:
+        ONE slot's prompt (or the next chunk of it) through the chunked
+        form, from nothing when `pos0` is 0 and else from the row's state,
+        written into the slot's row of the shared pool (donated S, z).
+        tokens are RIGHT-padded to a bucket; the positions past `last_idx`
+        leave the state as it was (kvstate: gate 1, no update)."""
+        cache = kvstate.RetentionState(
+            S=S, z=z, block_tables=row_bt, pos=pos0,
+            start=jnp.zeros((1,), jnp.int32), valid_len=last_idx[None] + 1,
+            max_len=self.max_len)
+        kw = {} if lora is None else {"lora": lora}
+        logits, cache = forward(self.config, params, tokens, cache,
+                                mode="prefill", **kw)
+        return logits[0, last_idx], cache.S, cache.z
 
     def _forward_routing(self, forward, params, tokens, cache, mode, kw):
         """`forward`, and for a sparse-expert model every position's top-k
@@ -1412,18 +1480,26 @@ class InferenceEngine:
         toks[0, :n] = prompt[st.written: st.written + n]  # RIGHT pad:
         # writes past pos get overwritten by decode, masked meanwhile
         self.prefill_chunks += 1
-        logits_last, k, v, ks, vs, moe = self._paged_prefill(
-            self.model.params, self.cache.k, self.cache.v,
-            self.cache.k_scale, self.cache.v_scale,
-            jnp.asarray(st.row[None]), jnp.asarray([st.written], jnp.int32),
-            jnp.asarray(toks), jnp.asarray(n - 1),
-            lora=self._prefill_lora(st.req),
-        )
-        if moe is not None:
-            st.moe.append((moe, n))
-        self.cache = dataclasses.replace(
-            self.cache, k=k, v=v, k_scale=ks, v_scale=vs,
-        )
+        where = (jnp.asarray(st.row[None]),
+                 jnp.asarray([st.written], jnp.int32), jnp.asarray(toks),
+                 jnp.asarray(n - 1))
+        if self._state_rows:
+            logits_last, S, z = self._paged_prefill(
+                self.model.params, self.cache.S, self.cache.z, *where,
+                lora=self._prefill_lora(st.req))
+            self.cache = dataclasses.replace(self.cache, S=S, z=z)
+            st.state_chunks += kvstate.prefill_chunks(bucket)
+        else:
+            logits_last, k, v, ks, vs, moe = self._paged_prefill(
+                self.model.params, self.cache.k, self.cache.v,
+                self.cache.k_scale, self.cache.v_scale, *where,
+                lora=self._prefill_lora(st.req),
+            )
+            if moe is not None:
+                st.moe.append((moe, n))
+            self.cache = dataclasses.replace(
+                self.cache, k=k, v=v, k_scale=ks, v_scale=vs,
+            )
         st.written += n
         if not last:
             self._chunk_retrace_s += self._retrace_mark("prefill.dispatch")
@@ -1439,6 +1515,7 @@ class InferenceEngine:
         self.pages.register_prefix(slot, prompt, st.path,
                                    ns=st.req.adapter)
         self._admit_moe, self._admit_moe_start = st.moe, st.start
+        self._admit_state_chunks = st.state_chunks
         if self.speculative:
             # prefix-cache hits only save TARGET prefill; the draft
             # always prefills its full context into the dense draft pool
@@ -1556,7 +1633,8 @@ class InferenceEngine:
             pos = self.pages.pos[slot]
             keep = self.pages.kv_pages(slot)
             n_keep = len(keep)
-            blob = kvpaged.swap_out_pages(self.cache, keep)
+            blob = (kvstate.swap_out_rows if self._state_rows
+                    else kvpaged.swap_out_pages)(self.cache, keep)
             start = 0
         else:
             pos = int(np.asarray(self.cache.pos[slot]))
@@ -1603,10 +1681,10 @@ class InferenceEngine:
             if fresh is None:  # retry when pages free up
                 return False
             b = entry.blob
+            parked = ((b.S, b.z) if self._state_rows
+                      else (b.k, b.v, b.k_scale, b.v_scale))
             self.cache = self._swap_in(
-                self.cache, b.k, b.v, b.k_scale, b.v_scale,
-                jnp.asarray(fresh, jnp.int32),
-            )
+                self.cache, *parked, jnp.asarray(fresh, jnp.int32))
             self.cache = dataclasses.replace(
                 self.cache,
                 pos=self.cache.pos.at[slot].set(entry.pos),
@@ -2145,6 +2223,8 @@ class InferenceEngine:
             if tr is not None and tr.enabled:
                 moe_args = _moe_load(chosen, self.config.num_experts)
             self._admit_moe = []
+        if self._state_rows:
+            moe_args["state_chunks"] = self._admit_state_chunks
         if req.admit_ts is not None:
             self.prefill_seconds.observe(now - req.admit_ts)
             if tr is not None and tr.enabled:
@@ -2677,7 +2757,10 @@ class InferenceEngine:
         if tr is not None and tr.enabled:
             busy = int(self.active.sum())
             pages = {}
-            if self.paged:  # its pos still holds the step's own
+            if self._state_rows:  # what the step read and wrote again
+                pages["state_rows_live"] = busy
+                pages["state_bytes_moved"] = 2 * busy * self.state_row_bytes
+            elif self.paged:  # its pos still holds the step's own
                 pages["live_pages"], pages["grid_pages"] = \
                     self.pages.grid_pages(self.active)
             tr.complete("decode_step", t0, t1 - t0, tid=0, cat="engine",

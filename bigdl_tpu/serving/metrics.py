@@ -412,6 +412,22 @@ class Metrics:
                     "# TYPE bigdl_tpu_radix_nodes gauge",
                     f"bigdl_tpu_radix_nodes {pages.radix.n_nodes}",
                 ]
+            if getattr(self.engine, "_state_rows", False):
+                # a model whose slots hold recurrent state rows
+                # (bigdl_tpu/kvstate.py): what a decode step reads and
+                # writes again is the live rows, whatever the contexts
+                lines += [
+                    "# HELP bigdl_tpu_state_rows_live slots whose state "
+                    "row the next decode step reads and writes",
+                    "# TYPE bigdl_tpu_state_rows_live gauge",
+                    f"bigdl_tpu_state_rows_live "
+                    f"{int(self.engine.active.sum())}",
+                    "# HELP bigdl_tpu_state_pool_bytes device bytes of the "
+                    "recurrent-state pool (every slot's row, all layers)",
+                    "# TYPE bigdl_tpu_state_pool_bytes gauge",
+                    f"bigdl_tpu_state_pool_bytes "
+                    f"{self.engine.state_row_bytes * self.engine.n_slots}",
+                ]
             if getattr(self.engine, "_moe_routing", False):
                 # sparse-expert models: the newest decode step's expert
                 # load (the `moe_*` arguments of its `decode_step` span)
@@ -554,6 +570,11 @@ _PAGED_FAMILIES = (
     "bigdl_tpu_radix_nodes",
 )
 
+_STATE_FAMILIES = (
+    "bigdl_tpu_state_rows_live",
+    "bigdl_tpu_state_pool_bytes",
+)
+
 _MOE_FAMILIES = (
     "bigdl_tpu_moe_expert_load_imbalance",
     "bigdl_tpu_moe_experts_hit_share",
@@ -583,6 +604,8 @@ def expected_families(engine=None) -> list:
         names += _ENGINE_FAMILIES
         if getattr(engine, "paged", False):
             names += _PAGED_FAMILIES
+        if getattr(engine, "_state_rows", False):
+            names += _STATE_FAMILIES
         if getattr(engine, "_moe_routing", False):
             names += _MOE_FAMILIES
         if getattr(engine, "adapters", None) is not None:

@@ -72,8 +72,17 @@ class PageTable:
 
     def __init__(self, n_slots: int, n_pages: int, page_size: int,
                  max_pages_per_row: int, max_len: int,
-                 faults=NULL_INJECTOR):
+                 faults=NULL_INJECTOR, share_prefixes: bool = True):
         self.n_slots = n_slots
+        # False for a model whose page is a recurrent state ROW
+        # (bigdl_tpu/kvstate.py: one page of `max_len` tokens a slot, page
+        # p is state row p - 1): the row is booked, parked, restored,
+        # released and counted like any page, but it holds the whole
+        # context folded together, so a prompt's prefix cannot be shared
+        # out of it. A prefix hit would need a snapshot of the state at
+        # the prefix's end; none is kept, so `register_prefix` registers
+        # nothing, the radix tree stays empty and no admission matches
+        self.share_prefixes = share_prefixes
         self.page_size = page_size
         self.max_pages_per_row = max_pages_per_row
         self.max_len = max_len
@@ -105,7 +114,8 @@ class PageTable:
         (host copies in the registry survive, the next admission pages
         them in again)."""
         new = PageTable(self.n_slots, self.pool.n_pages, self.page_size,
-                        self.max_pages_per_row, self.max_len, self._faults)
+                        self.max_pages_per_row, self.max_len, self._faults,
+                        self.share_prefixes)
         for name in self.TOTALS:
             setattr(new, name, getattr(self, name))
         if self.pager is not None:
@@ -259,6 +269,8 @@ class PageTable:
         slot-only and frees at release. `ns` = the request's adapter
         name: adapter-prefilled pages register under that tenant's own
         radix root, never the shared base tree."""
+        if not self.share_prefixes:
+            return
         page = self.page_size
         table = self.slot_pages[slot]
         node = path[-1] if path else self.radix.root_for(ns)
