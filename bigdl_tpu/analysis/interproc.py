@@ -708,6 +708,18 @@ class TilingBudgetInvariants(Check):
          "_DX_SLAB_BYTES does not fit inside VMEM_BUDGET — the dx "
          "accumulator slab would leave no room for the chunk loop",
          "shrink the backward accumulator slab or raise the budget"),
+        (("WORDS_VMEM_BYTES", "VMEM_LIMIT_BYTES"),
+         lambda e: e["WORDS_VMEM_BYTES"] <= e["VMEM_LIMIT_BYTES"] * 3 // 4,
+         "WORDS_VMEM_BYTES leaves the word path's chunk loop under a "
+         "quarter of the scoped-VMEM limit — its 6 to 8 MiB of "
+         "temporaries and the x / output blocks would not fit",
+         "keep WORDS_VMEM_BYTES <= 3/4 of VMEM_LIMIT_BYTES"),
+        (("WORD_BLOCK_O", "WORD_ROWS", "MOSAIC_LANES"),
+         lambda e: e["WORD_BLOCK_O"] % (e["WORD_ROWS"] * e["MOSAIC_LANES"])
+         == 0,
+         "WORD_BLOCK_O / WORD_ROWS is not a multiple of MOSAIC_LANES — "
+         "the word tile would not transpose in whole 128-lane pieces",
+         "the word path's O tile must be WORD_ROWS x 128 x n rows"),
         (("DX_ACC_BPE",),
          lambda e: e["DX_ACC_BPE"] >= 6,
          "DX_ACC_BPE under-prices the dx row tile (f32 accumulator + "

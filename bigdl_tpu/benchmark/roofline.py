@@ -31,16 +31,21 @@ _X_BPE = 2  # activations cross as bf16 (the kernels' compute dtype)
 _OUT_BPE = 2
 
 
+def code_bytes_per_row(qtype: str, K: int) -> int:
+    """Bytes of packed codes per output row (the `data` field alone)."""
+    spec = resolve_qtype(qtype)
+    if spec.storage == "packed_u8":
+        return K // 2
+    if spec.storage == "packed_planes":
+        return K * sum(spec.planes) // 8
+    return K  # int8 / fp8: one code byte per element
+
+
 def weight_bytes_per_row(qtype: str, K: int) -> int:
     """Stored bytes per output row: packed codes + every scale field —
     exactly what the kernel's weight-side BlockSpecs fetch."""
     spec = resolve_qtype(qtype)
-    if spec.storage == "packed_u8":
-        data = K // 2
-    elif spec.storage == "packed_planes":
-        data = K * sum(spec.planes) // 8
-    else:  # int8 / fp8: one code byte per element
-        data = K
+    data = code_bytes_per_row(qtype, K)
     if spec.superblock:
         nsuper = K // spec.superblock
         nsub = K // spec.block_size
@@ -68,7 +73,8 @@ def qmatmul_cost(qtype: str, M: int, K: int, O: int) -> dict:
 
     block_m = pick_block_m(M, K)
     mp = round_up(max(M, 1), block_m)
-    block_o = pick_block_o(O, row_bytes, cap=256)
+    block_o = pick_block_o(O, row_bytes,
+                           row_bytes=code_bytes_per_row(qtype, K))
     grid_m = mp // block_m
 
     fused_bytes = w_total * grid_m + mp * K * _X_BPE + mp * O * _OUT_BPE

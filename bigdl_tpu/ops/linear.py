@@ -564,7 +564,9 @@ def linear(
             f"O{w.data.shape[-2]} " + ("stack" if stacked else "slice")
             + ("" if kernel is not None else f" ({why})"))
         if kernel is not None:
-            block_o = 256 if w.data.shape[-2] % 256 == 0 else 128
+            from bigdl_tpu.ops.pallas.tiling import WORD_BLOCK_O
+
+            block_o = WORD_BLOCK_O  # a cap: `tiling.pick_block_o` picks
             xc = x.astype(compute_dtype)
             if lora is not None:
                 ops = _lora_cat_operands(x, lora, compute_dtype)

@@ -52,7 +52,8 @@ from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
 from bigdl_tpu.ops.pallas.qmatmul import _side_arrays, _validate
 from bigdl_tpu.ops.pallas.tiling import (
-    VMEM_LIMIT_BYTES, chunk_target, finest_split, pick_block_m, pick_block_o,
+    VMEM_LIMIT_BYTES, finest_split, forward_chunk, pick_block_m, pick_block_o,
+    words_ok,
 )
 
 #: activations the gated call applies in-kernel (float32, before the
@@ -110,30 +111,28 @@ def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int):
 
 
 def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
-            spec: DecodeSpec, n_w: int, act):
+            spec: DecodeSpec, n_w: int, act, words: bool):
     """One [block_m, block_o] tile of one expert: `qmatmul._kernel`'s
-    chunk loop over each of the `n_w` weight stacks, skipped whole when
-    the tile holds no assignment."""
+    chunk loop (`qdecode.tile_product`) over each of the `n_w` weight
+    stacks, skipped whole when the tile holds no assignment. With
+    ``words`` three scratch refs per stack follow the output."""
     del te_ref  # read by the index maps
-    o_ref = refs[-1]
     per = 1 + spec.n_side
+    o_ref = refs[n_w * per]
+    scratch = refs[n_w * per + 1:]
 
     @pl.when(pl.program_id(0) < meta_ref[0])
     def _live_tile():
-        x = x_ref[:].astype(jnp.bfloat16)  # [block_m, K]
-        accs = []
-        for i in range(n_w):
-            w = refs[i * per][:]  # packed codes [block_o, row_bytes]
-            side = qdecode.load_side(spec, refs[i * per + 1:(i + 1) * per])
-            acc = jnp.zeros((x.shape[0], w.shape[0]), jnp.float32)
-            for e0, c in qdecode.walk(K, spec.planes, ck):
-                wd = qdecode.decode_chunk(spec, K, w, side, e0, c)
-                acc += jax.lax.dot_general(
-                    qdecode.slc(x, e0, c), wd, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            accs.append(acc)
+        accs = [
+            qdecode.tile_product(
+                spec, K, ck, x_ref, refs[i * per],
+                refs[i * per + 1:(i + 1) * per],
+                scratch[3 * i:3 * i + 3] if words else None)
+            for i in range(n_w)
+        ]
         y = accs[0] if n_w == 1 else FUSED_ACTS[act](accs[0]) * accs[1]
+        if words:
+            y = qdecode.natural_columns(y)
         o_ref[:] = y.astype(o_ref.dtype)
 
 
@@ -159,8 +158,14 @@ def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
         pl.BlockSpec((None, None, block_o, a.shape[-1]), w_map(has_layer))
         for a, has_layer in zip(arrays, layered)
     ]
+    per = 1 + spec.n_side
+    row_bytes = arrays[0].shape[-1]
+    words = words_ok(block_o, row_bytes)
+    scratch = qdecode.word_scratch(
+        spec, block_o, row_bytes, arrays[per - 1].shape[-1]) * n_w
     return pl.pallas_call(
-        functools.partial(_kernel, K=K, ck=ck, spec=spec, n_w=n_w, act=act),
+        functools.partial(_kernel, K=K, ck=ck, spec=spec, n_w=n_w, act=act,
+                          words=words),
         name="moe_qmatmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -168,6 +173,7 @@ def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((block_m, block_o),
                                    lambda m, o, te, meta: (m, o)),
+            scratch_shapes=scratch if words else [],
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, O), out_dtype),
         compiler_params=pltpu.CompilerParams(
@@ -219,11 +225,13 @@ def moe_qmatmul(
     n_w = len(ws)
     per = 1 + spec.n_side
     persist_row = sum(a.shape[-1] * a.dtype.itemsize for a in arrays[:per])
-    block_o = pick_block_o(O, persist_row * n_w)
+    block_o = pick_block_o(O, persist_row * n_w,
+                           row_bytes=arrays[0].shape[-1] * n_w)
     persist = (n_w * block_o * persist_row + block_m * K * 2
                + n_w * block_m * block_o * 4)
-    ck = chunk_target(block_o * n_w, persist, finest_split(K, spec.planes),
-                      temp_bpe=20 if spec.mins else 14)
+    ck = forward_chunk(words_ok(block_o, arrays[0].shape[-1]), block_o * n_w,
+                       persist, finest_split(K, spec.planes), spec.block,
+                       spec.mins)
     meta = jnp.stack([jnp.asarray(n_used, jnp.int32),
                       jnp.asarray(0 if layer is None else layer, jnp.int32)])
     return _moe_qmm(spec, jnp.dtype(out_dtype), block_m, block_o, ck, n_w,

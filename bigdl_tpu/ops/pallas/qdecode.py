@@ -4,7 +4,11 @@ One implementation of the per-format bit decode, consumed by BOTH the
 fused dequant-GEMV and the tiled dequant-GEMM kernels in
 `ops/pallas/qmatmul.py` (and, later, by flash-attention epilogues) — the
 format decode lives here exactly once, the matmul kernels are tiling +
-epilogue.
+epilogue. `tile_product` is the forward kernels' chunk loop; it decodes
+a tile either in the stored [o, k] layout (`decode_chunk`, which
+`qbackward` also calls) or, where the tile's shape allows, as 32-bit
+words transposed once per grid step (`decode_chunk_words`, bottom of
+this file), the same values bit for bit.
 
 A format is described by a static, hashable `DecodeSpec`:
 
@@ -29,9 +33,11 @@ measurement history):
 * no f16 vector type -> f16 scales cross as uint16 bits, decoded to f32
   with integer ops (`f16_bits_to_f32`); subnormals decode exactly — NOT
   flushed (k-quant super-scales routinely land below 6.1e-5);
-* no lane-collapsing reshape -> per-block scales expand to per-element
-  via a one-hot matmul (iota compare + MXU dot), not broadcast+reshape;
-* no vector gather -> codebooks are compare/select trees, fp8/fp6 decode
+* no lane-collapsing reshape -> in the stored layout per-block scales
+  expand to per-element via a one-hot matmul (iota compare + MXU dot),
+  not broadcast+reshape; the word path has them on sublanes, where
+  broadcast + reshape IS a layout no-op;
+* no vector gather of a table -> codebooks are compare/select trees, fp8/fp6 decode
   arithmetically from their bit fields.
 """
 
@@ -41,8 +47,12 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.ops.pallas.tiling import chunk_spans, finest_split
+from bigdl_tpu.ops.pallas.tiling import (
+    WORD_ROWS, chunk_spans, finest_split, round_up, words_chunk_loops,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,15 +141,28 @@ def fp8_bits_to_f32(b, exp_bits: int, mant_bits: int, bias: int):
     return jnp.where(exp == 0, sub, val)
 
 
-def expand_scales(s, ck: int, block: int):
+def expand_scales(s, ck: int, block: int, from_f16: bool = False):
     """[rows, nbc] per-block scales -> [rows, ck] per-element for one
     chunk whose start is block-aligned: element j belongs to local block
-    j // block. One-hot matmul: iota/compare/dot only."""
+    j // block. One-hot matmul: iota/compare/dot only.
+
+    The MXU's default-precision float32 matmul rounds its operands (one
+    scale in seven came out a bf16 step off on a v5e, PR 32; the CPU
+    interpreter's dot is exact). With ``from_f16`` the scales are float16
+    values, 11 significant bits: their bf16 rounding and the remainder
+    (3 bits) ride ONE bf16 pass side by side, contraction 2 * nbc, and the
+    float32 accumulator adds the two back exactly, at the old cost. The
+    k-quants' 24-bit products keep the float32 dot and its rounding."""
     nbc = s.shape[-1]
+    if from_f16:
+        hi = s.astype(jnp.bfloat16)
+        lo = (s - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        s = jnp.concatenate([hi, lo], axis=1)
+    n = s.shape[-1]
     sel = (
-        jax.lax.broadcasted_iota(jnp.int32, (nbc, ck), 1) // block
-        == jax.lax.broadcasted_iota(jnp.int32, (nbc, ck), 0)
-    ).astype(jnp.float32)
+        jax.lax.broadcasted_iota(jnp.int32, (n, ck), 1) // block
+        == jax.lax.broadcasted_iota(jnp.int32, (n, ck), 0) % nbc
+    ).astype(s.dtype)
     return jax.lax.dot_general(
         s, sel, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -343,8 +366,247 @@ def decode_chunk(spec: DecodeSpec, K: int, w, side, e0: int, c: int):
         s, m = side
         exp = expand_scales(
             jnp.concatenate([slc(s, sb0, nsc), slc(m, sb0, nsc)], axis=0),
-            c, spec.block)
+            c, spec.block, from_f16=True)
         return (vals * exp[:bo] + exp[bo:]).astype(jnp.bfloat16)
     (s,) = side
-    return (vals * expand_scales(slc(s, sb0, nsc), c, spec.block)
-            ).astype(jnp.bfloat16)
+    return (vals * expand_scales(slc(s, sb0, nsc), c, spec.block,
+                                 from_f16=True)).astype(jnp.bfloat16)
+
+
+# ---------------------------------------------------------------------------
+# the forward kernels' decode: the tile read as 32-bit words, transposed
+# ---------------------------------------------------------------------------
+#
+# What bounded the old loop (scripts/qmatmul_kernel_bench.py, PR 32, on the
+# chip): not the code decode and not HBM, but the per-element scales. In the
+# stored [o, k] layout a block's scale is constant over `block` LANES, and
+# every way to spread it there is dear: the one-hot matmul streams one MXU
+# row and pops one result vreg per 8 rows x 128 lanes of scales (a third to
+# two fifths of the kernel's time at every shape), a lane gather is slower
+# still. With k on SUBLANES a block's scale is a sublane broadcast of one
+# row, shared by block / 8 vregs.
+#
+# So the tile is turned once per grid step, as 32-bit words: a byte tile
+# [bo, row_bytes] viewed as int32 holds WORD_ROWS = 4 consecutive O rows in
+# one lane (`pltpu.bitcast`, free), and the XLU transposes [bo / 4,
+# row_bytes] words, an eighth of the vregs the decoded float32 tile has.
+# Pack p (bits 8p .. 8p+7 of a word) then decodes to rows 4i + p of the
+# tile with no uint8 -> int32 widening at all, so the result's columns come
+# out pack-major: `tile_product` returns them so, and the kernels put them
+# back once per tile before the store (`natural_columns`).
+
+def word_scratch(spec: DecodeSpec, block_o: int, row_bytes: int, nb: int):
+    """Scratch shapes of one weight stack's word path: the transposed
+    words, the effective scales (and mins) staged as 128-lane groups for
+    the strided row reads, and their transposes, the four packs side by
+    side on lanes."""
+    nbp = round_up(nb, 128)
+    return [
+        pltpu.VMEM((row_bytes, block_o // WORD_ROWS), jnp.int32),
+        pltpu.VMEM((nbp // 128, block_o, 128), jnp.float32),
+        pltpu.VMEM((2 if spec.mins else 1, nbp, block_o), jnp.float32),
+    ]
+
+
+def effective_side(spec: DecodeSpec, side):
+    """`load_side`'s arrays -> the per-(sub-)block float32 factors the
+    decode multiplies and adds, in the stored [bo, nb] layout:
+    w = v * scale (+ offset). Two-level formats fold their super-scales in
+    here, once per tile (d * sc, and -(dmin * mn))."""
+    if spec.super_block:
+        per_super = spec.super_block // spec.block
+        n_sub = side[-1].shape[-1]
+        if spec.mins:
+            d32, dmin32, scf, mnf = side
+            return (expand_super(d32, n_sub, 0, per_super) * scf,
+                    -(expand_super(dmin32, n_sub, 0, per_super) * mnf))
+        d32, scf = side
+        return (expand_super(d32, n_sub, 0, per_super) * scf,)
+    return tuple(side)
+
+
+def _pad_lanes(a, mult: int = 128):
+    n = a.shape[-1]
+    if n % mult == 0:
+        return a
+    return jnp.concatenate(
+        [a, jnp.zeros((a.shape[0], mult - n % mult), a.dtype)], axis=1)
+
+
+def stage_words(spec: DecodeSpec, w_ref, side_refs, scratch,
+                piece: int = 2048):
+    """Once per grid step: the tile's words and its effective scales,
+    transposed into `scratch` (see `word_scratch`)."""
+    wT_ref, s32_ref, sT_ref = scratch
+    bo, row_bytes = w_ref.shape
+    q = bo // WORD_ROWS
+    for j0 in range(0, row_bytes, piece):
+        cw = min(piece, row_bytes - j0)
+        wT_ref[j0:j0 + cw, :] = pltpu.bitcast(
+            w_ref[:, j0:j0 + cw], jnp.int32).T
+    eff = effective_side(spec, load_side(spec, side_refs))
+    for i, a in enumerate(eff):
+        a = _pad_lanes(a)
+        for g in range(a.shape[-1] // 128):
+            s32_ref[g] = slc(a, g * 128, 128)
+            # rows 4i + p of the tile are pack p: a strided sublane read
+            # (the staging ref's last dim is 128, the only width Mosaic
+            # strides)
+            for p in range(WORD_ROWS):
+                sT_ref[i, g * 128:(g + 1) * 128, p * q:(p + 1) * q] = s32_ref[
+                    g, pl.ds(p, q, stride=WORD_ROWS), :].T
+
+
+def _packs(words, shift: int, bits: int, signed: bool = False):
+    """[c, bo/4] words -> [c, bo] int32: the `bits`-wide field at
+    `shift` of each of the four bytes, pack p on lanes p*bo/4 .. (a
+    128-aligned lane concatenation places vregs, it moves nothing)."""
+    if signed:  # int8 codes: sign-extend the byte
+        return jnp.concatenate(
+            [(words << (24 - 8 * p)) >> 24 for p in range(WORD_ROWS)], axis=1)
+    return jnp.concatenate(
+        [(words >> (8 * p + shift)) & ((1 << bits) - 1)
+         for p in range(WORD_ROWS)], axis=1)
+
+
+def word_codes(spec: DecodeSpec, K: int, wT_ref, signed: bool, seg: int,
+               off, c: int):
+    """int32 codes [c, bo] (pack-major lanes) of the `c` logical elements
+    at `off` (a Python int or a traced index, a multiple of 8) within
+    segment `seg` of the finest plane split: `plane_chunk_code` with the
+    byte axis on sublanes. Within a segment every plane's split index is
+    static, so the shifts are."""
+    if not spec.planes:
+        return _packs(wT_ref[pl.ds(off, c), :], 0, 8, signed)
+    e_seg = seg * finest_split(K, spec.planes)
+    code = None
+    shift = 0
+    for r_plane, bits, _s, qel in plane_layout(K, spec.planes):
+        mp = e_seg // qel
+        words = wT_ref[pl.ds(r_plane + e_seg - mp * qel + off, c), :]
+        piece = _packs(words, bits * mp, bits)
+        code = piece if code is None else code | (piece << shift)
+        shift += bits
+    return code
+
+
+def _rows_repeat(a, block: int):
+    """[n, q] -> [n * block, q], each row `block` times: a sublane
+    broadcast (block is a multiple of 8)."""
+    n, q = a.shape
+    return jnp.broadcast_to(a[:, None, :], (n, block, q)).reshape(n * block, q)
+
+
+def decode_chunk_words(spec: DecodeSpec, K: int, wT_ref, sT_ref, signed: bool,
+                       seg: int, off, c: int):
+    """bf16 weights [c, bo] (k on sublanes, the tile's rows on lanes,
+    pack-major) of the `c` elements at `off` within segment `seg`: the
+    values `decode_chunk` gives, bit for bit."""
+    vals = decode_values(word_codes(spec, K, wT_ref, signed, seg, off, c),
+                         spec.value)
+    e_seg = seg * finest_split(K, spec.planes)
+    nsc = c // spec.block
+    if isinstance(off, int):
+        sb0 = (e_seg + off) // spec.block
+    else:  # chunk off / c of a loop: c covers 8 whole blocks a row
+        sb0 = pl.multiple_of(
+            e_seg // spec.block + jax.lax.div(off, c) * nsc, 8)
+    w = vals * _rows_repeat(sT_ref[0, pl.ds(sb0, nsc), :], spec.block)
+    if spec.mins:
+        w = w + _rows_repeat(sT_ref[1, pl.ds(sb0, nsc), :], spec.block)
+    return w.astype(jnp.bfloat16)
+
+
+def tile_product(spec: DecodeSpec, K: int, ck: int, x_ref, w_ref, side_refs,
+                 scratch=None):
+    """float32 [block_m, block_o] = x @ dq(W tile)^T, the chunk loop both
+    forward kernels run: chunks of the logical contraction axis, each
+    decoded to bf16 and fed to the MXU, so live dequant temporaries stay
+    O(block_o * ck) whatever K is.
+
+    Without `scratch` the loop is statically unrolled in the stored
+    layout. With it (the word path, `words_ok`) the columns come out
+    pack-major (see `natural_columns`), and the chunks of one segment of
+    the finest plane split are ONE `fori_loop` body wherever `ck` divides
+    the segment into chunks whose scale rows start on a sublane tile
+    (`tiling.words_chunk`), unrolled when it is LOWERED
+    (`unroll=True`): Python traces the body once per segment whatever K
+    is, which is what keeps a program's set-up (an end-to-end metric:
+    every program is traced anew in every process) near the old loop's,
+    and Mosaic still sees straight-line code. Left rolled, the loop read
+    33% slower on the chip (38.4 -> 51.5 us at 4096 -> 6144, PR 32): one
+    chunk's decode does not overlap the next one's product across a
+    loop's back edge."""
+    bm = x_ref.shape[0]
+    bo = w_ref.shape[0]
+    if scratch is None:
+        x = x_ref[:].astype(jnp.bfloat16)
+        side = load_side(spec, side_refs)
+        w = w_ref[:]  # packed codes [block_o, row_bytes]
+        acc = jnp.zeros((bm, bo), jnp.float32)
+        for e0, c in walk(K, spec.planes, ck):
+            wd = decode_chunk(spec, K, w, side, e0, c)  # bf16 [bo, c]
+            acc += jax.lax.dot_general(
+                slc(x, e0, c), wd, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return acc
+    stage_words(spec, w_ref, side_refs, scratch)
+    wT_ref, _, sT_ref = scratch
+    signed = jnp.issubdtype(w_ref.dtype, jnp.signedinteger)
+    qmin = finest_split(K, spec.planes)
+
+    def chunk(acc, seg, off, c):
+        wd = decode_chunk_words(spec, K, wT_ref, sT_ref, signed, seg, off, c)
+        xs = x_ref[:, pl.ds(pl.multiple_of(seg * qmin + off, 128), c)]
+        return acc + jax.lax.dot_general(
+            xs.astype(jnp.bfloat16), wd, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    acc = jnp.zeros((bm, bo), jnp.float32)
+    one_body = words_chunk_loops(qmin, ck, spec.block)
+    for seg in range(K // qmin):
+        if one_body:
+            acc = jax.lax.fori_loop(
+                0, qmin // ck,
+                lambda i, acc, seg=seg: chunk(
+                    acc, seg, pl.multiple_of(i * ck, ck), ck),
+                acc, unroll=True)
+        else:
+            for c0, c in chunk_spans(qmin, ck):
+                acc = chunk(acc, seg, c0, c)
+    return acc
+
+
+# out[r, l] = x[r, idx[r, l, 0]] on one 128-lane group: the gather
+# `jnp.take_along_axis(x, idx, axis=1)` traces to (Mosaic's `dynamic_gather`)
+# without the dozen equations of index bookkeeping around it
+_LANE_GATHER = jax.lax.GatherDimensionNumbers(
+    offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+    operand_batching_dims=(0,), start_indices_batching_dims=(0,))
+
+
+def natural_columns(y):
+    """[block_m, 512] with pack-major columns (column 128 p + i holds row
+    4i + p of the tile) -> natural order, once per tile before the store:
+    output lane l of the j-th 128-lane group is pack l % 4, column
+    32j + l // 4: a lane gather per pack and a select. 16 gathers for
+    every 8 rows of the tile; a transpose of the [M, O] result in XLA
+    instead costs less at M <= 32 (1.7 us a call, chip, PR 32) and a
+    quarter of the GEMM itself at M >= 256."""
+    bm, bo = y.shape
+    assert bo == WORD_ROWS * 128, bo  # one 128-lane group a pack
+    packs = [slc(y, p * 128, 128) for p in range(WORD_ROWS)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bm, 128), 1)
+    is_pack = [(lane & (WORD_ROWS - 1)) == p for p in range(1, WORD_ROWS)]
+    col = jax.lax.shift_right_logical(lane, 2)  # l // WORD_ROWS
+    out = []
+    for j in range(WORD_ROWS):
+        idx = (col + j * (128 // WORD_ROWS))[..., None]
+        o = None
+        for p, pack in enumerate(packs):
+            t = jax.lax.gather(
+                pack, idx, _LANE_GATHER, slice_sizes=(1, 1),
+                mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+            o = t if o is None else jax.lax.select(is_pack[p - 1], t, o)
+        out.append(o)
+    return jnp.concatenate(out, axis=1)

@@ -10,8 +10,10 @@ ONE kernel body serves every registered qtype and every shape class:
 * decode GEMV (rows <= 32): HBM-bandwidth-bound — the win over the XLA
   fallback (dequantize to bf16, then matmul) is that W crosses HBM
   packed, e.g. 0.5 byte/weight + one f16 scale per 32 for nibble
-  formats, up to ~6x less weight traffic than bf16 (end-to-end gain on
-  the chip: not measured);
+  formats, up to ~6x less weight traffic than bf16. On a v5e the kernel
+  reads 43 to 50% of its HBM time where the word path runs (the code
+  decode and the product bound it, not HBM: PERF.md section 6, PR 32)
+  and a third where it cannot;
 * prefill / batched / QLoRA GEMM (rows > 32): the same weight tiles are
   dequantized ONCE per [block_m, block_o] tile in VMEM and fed straight
   to the MXU — no in-graph bf16 weight materialization, no HBM round
@@ -22,8 +24,11 @@ decoder for GEMV, GEMM and, later, flash epilogues — a format is a
 static `DecodeSpec`); tile/chunk policy lives in `ops/pallas/tiling.py`
 (pure Python, shared with `benchmark/roofline.py`'s analytic cost
 model). This module is tiling + epilogue: grid over (M tiles, O tiles),
-an in-kernel statically-unrolled chunk loop over K bounds live dequant
-temporaries to O(block_o * chunk) regardless of K.
+and `qdecode.tile_product`'s chunk loop over K, which bounds live
+dequant temporaries to O(block_o * chunk) regardless of K. Where a 512-row tile fits, that loop runs on the tile read as
+32-bit words and transposed once (k on sublanes, a block's scale a
+sublane broadcast, docs/kernels.md#word-path); elsewhere in the stored
+layout.
 
 Layout contract (quant/numerics.py pack_nibbles / pack_planes): the
 m-th split of a b-bit plane is a *contiguous* byte range unpacked with
@@ -37,9 +42,11 @@ these, silently):
 
 * no f16 vector type -> scales cross as uint16 bits and are decoded to
   f32 with integer ops in-kernel (r03);
-* no lane-collapsing reshape -> per-block scales expand to per-element
-  via a one-hot matmul (iota compare + MXU dot), not broadcast+reshape
-  (r03);
+* no lane-collapsing reshape -> in the stored layout per-block scales
+  expand to per-element via a one-hot matmul (iota compare + MXU dot),
+  not broadcast+reshape (r03); that matmul cost a third of the kernel's
+  time and rounds the scales (PR 32), which is why the word path spreads
+  them along sublanes instead;
 * the last two dims of every BlockSpec must be (sublane, 128)-aligned
   UNLESS the block covers the whole array dim (r05). This outlaws any
   lane-tiling of the skinny scale arrays (K/32 columns: tiles of
@@ -63,8 +70,8 @@ from jax.experimental.pallas import tpu as pltpu
 from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
 from bigdl_tpu.ops.pallas.tiling import (
-    VMEM_LIMIT_BYTES, chunk_target, finest_split, lora_operand_bytes,
-    pick_block_m, pick_block_o, round_up,
+    VMEM_LIMIT_BYTES, WORD_BLOCK_O, finest_split, forward_chunk,
+    lora_operand_bytes, pick_block_m, pick_block_o, round_up, words_ok,
 )
 
 BLOCK = 32  # quant block (elements per scale) for sym_int4; nf4/fp4 use 64
@@ -88,15 +95,13 @@ def _f16_bits(a: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
-            spec: DecodeSpec, lora: bool = False):
-    """One [block_m, block_o] output tile: acc += x_chunk @ dq(W_chunk)^T
-    over statically-unrolled chunks of the logical contraction axis.
-    `layer_ref` is read by the weight's index map alone: the tile arrives
-    as `[block_o, row_bytes]` whichever layer of the stack it came from.
-    The weight tile is loaded packed and upcast PER CHUNK inside
-    qdecode.decode_chunk — a hoisted full-row int32 copy would keep
-    4 B/packed-byte live across the whole unrolled loop and defeat the
-    O(block_o * ck) VMEM bound.
+            spec: DecodeSpec, lora: bool = False, words: bool = False):
+    """One [block_m, block_o] output tile: `qdecode.tile_product`'s chunk
+    loop, acc += x_chunk @ dq(W_chunk)^T over chunks of the logical
+    contraction axis. `layer_ref` is read by the weight's
+    index map alone: the tile arrives as `[block_o, row_bytes]` whichever
+    layer of the stack it came from. With ``words`` the last three refs
+    are the word path's scratch.
 
     With ``lora`` the multi-tenant LoRA epilogue folds into the same
     tile before writeback (the S-LoRA/Punica batched-adapter GEMM,
@@ -108,26 +113,21 @@ def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
     adapter group's rank-bucket columns and 0 elsewhere, which is how
     one dot pair serves a heterogeneous multi-tenant batch."""
     del layer_ref
+    scratch = None
+    if words:
+        rest, scratch = rest[:-3], rest[-3:]
     o_ref = rest[-1]
     if lora:
         a_ref, b_ref, g_ref = rest[-4:-1]
         side_refs = rest[:-4]
     else:
         side_refs = rest[:-1]
-    side = qdecode.load_side(spec, side_refs)
-    w = w_ref[:]  # packed codes [block_o, row_bytes]
-    x = x_ref[:].astype(jnp.bfloat16)  # [block_m, K]
-
-    acc = jnp.zeros((x_ref.shape[0], w_ref.shape[0]), jnp.float32)
-    for e0, c in qdecode.walk(K, spec.planes, ck):
-        wd = qdecode.decode_chunk(spec, K, w, side, e0, c)  # bf16 [bo, c]
-        acc += jax.lax.dot_general(
-            qdecode.slc(x, e0, c), wd, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    acc = qdecode.tile_product(spec, K, ck, x_ref, w_ref, side_refs, scratch)
+    if words:
+        acc = qdecode.natural_columns(acc)
     if lora:
         xa = jax.lax.dot_general(  # [block_m, R]
-            x, a_ref[:], (((1,), (1,)), ((), ())),
+            x_ref[:].astype(jnp.bfloat16), a_ref[:], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         xa = xa * g_ref[:].astype(jnp.float32)
@@ -180,12 +180,18 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
             pl.BlockSpec((block_m, lg.shape[1]), lambda m, o, l: (m, 0),
                          memory_space=pltpu.VMEM),
         ]
+    # the word path: not with LoRA operands (their VMEM beside the
+    # transposed tile was never compiled for the chip)
+    words = not lora and words_ok(block_o, w.shape[2])
+    scratch = qdecode.word_scratch(
+        spec, block_o, w.shape[2], side[-1].shape[1]) if words else []
     # grid order (m, o): o innermost, so the x tile stays resident across
     # a full sweep of weight tiles and packed weights are re-fetched only
     # once per M tile (the roofline model in benchmark/roofline.py
     # assumes exactly this fetch pattern)
     return pl.pallas_call(
-        functools.partial(_kernel, K=K, ck=ck, spec=spec, lora=lora),
+        functools.partial(_kernel, K=K, ck=ck, spec=spec, lora=lora,
+                          words=words),
         name="qmatmul_lora" if lora else "qmatmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -195,6 +201,7 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
                 (block_m, block_o), lambda m, o, l: (m, o),
                 memory_space=pltpu.VMEM
             ),
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, O), out_dtype),
         compiler_params=_params_parallel(),
@@ -283,11 +290,13 @@ def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
 
     persist_row = data.shape[2] * data.dtype.itemsize + sum(
         a.shape[1] * a.dtype.itemsize for a in side)
-    block_o = pick_block_o(O, persist_row, cap=block_o)
+    block_o = pick_block_o(O, persist_row, cap=block_o,
+                           row_bytes=0 if lora is not None else data.shape[2])
     persist = (block_o * persist_row + block_m * K * 2
                + block_m * block_o * 4 + lora_bytes)
-    ck = chunk_target(block_o, persist, finest_split(K, spec.planes),
-                      temp_bpe=20 if spec.mins else 14)
+    ck = forward_chunk(lora is None and words_ok(block_o, data.shape[2]),
+                       block_o, persist, finest_split(K, spec.planes),
+                       spec.block, spec.mins)
     y = _qmm(spec, jnp.dtype(out_dtype), block_m, block_o, ck,
              bool(interpret), lora is not None,
              jnp.asarray(layer, jnp.int32).reshape(1), x2, data, *side, *extra)
@@ -302,7 +311,7 @@ def qmatmul(
     x: jax.Array,  # [..., K]
     w,  # QTensor (any registered non-dense qtype)
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `w.data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -328,7 +337,7 @@ def qmatmul_lora(
     b_cat: jax.Array,  # [O, R] concatenated adapter B columns
     gate: jax.Array,  # [M, R] per-row scale-in-own-group selection mask
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
 ) -> jax.Array:
     """``qmatmul`` with the multi-tenant LoRA epilogue fused into the
@@ -360,7 +369,7 @@ def qmatmul_int4(
     data: jax.Array,  # [O, K // 2] packed uint8 (sym_int4, half-split)
     scales: jax.Array,  # [O, K // 32] f16 (or bf16)
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -377,7 +386,7 @@ def qmatmul_codebook(
     codebook,  # 16 static floats: value = codebook[code] * scale
     block: int = 64,
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -385,9 +394,9 @@ def qmatmul_codebook(
 
     Same HBM story as qmatmul_int4 (weights cross as packed nibbles,
     ~4x less traffic than bf16); the in-kernel decode is a 16-way
-    compare/select tree over the static codebook instead of (v - 8) —
-    Mosaic has no vector gather, and at GEMV arithmetic intensity the
-    extra VPU selects stay under the HBM bound."""
+    compare/select tree over the static codebook instead of (v - 8):
+    Mosaic has no vector gather of a table. What the selects cost on the
+    chip: not measured (the cells are sym_int4)."""
     spec = DecodeSpec(
         planes=(4,), value=("lut", tuple(float(c) for c in codebook)),
         block=block,
@@ -401,7 +410,7 @@ def qmatmul_int8(
     data: jax.Array,  # [O, K] int8 (sym_int8 / imported q8_0)
     scales: jax.Array,  # [O, K // 32] f16 (or bf16)
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -417,7 +426,7 @@ def qmatmul_asym_int4(
     scales: jax.Array,  # [O, K // 32] f16
     mins: jax.Array,  # [O, K // 32] f16 (raw block minimum; w = q*d + m)
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -438,7 +447,7 @@ def qmatmul_q4k(
     sub_scales: jax.Array,  # [O, K // 32] uint8 6-bit sc
     sub_mins: jax.Array,  # [O, K // 32] uint8 6-bit mn
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -460,7 +469,7 @@ def qmatmul_q6k(
     scales: jax.Array,  # [O, K // 256] f16 super-scale d
     sub_scales: jax.Array,  # [O, K // 16] int8 sc
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -484,7 +493,7 @@ def qmatmul_bytes(
     decode: str = "i8",  # i8 | e4m3 | e5m2
     block: int = BLOCK,
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -507,7 +516,7 @@ def qmatmul_fp8(
     scales: jax.Array,  # [O, K // block] f16
     block: int = 128,
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -528,7 +537,7 @@ def qmatmul_planes(
     decode: tuple,  # ("offset", o) | ("lut", codebook) | ("e2m3",)
     block: int,
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -548,7 +557,7 @@ def qmatmul_q2k(
     sub_scales: jax.Array,  # [O, K // 16] uint8 4-bit sc
     sub_mins: jax.Array,  # [O, K // 16] uint8 4-bit mn
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:
@@ -570,7 +579,7 @@ def qmatmul_q5k(
     sub_scales: jax.Array,  # [O, K // 32] uint8 6-bit sc
     sub_mins: jax.Array,  # [O, K // 32] uint8 6-bit mn
     out_dtype=jnp.bfloat16,
-    block_o: int = 256,
+    block_o: int = WORD_BLOCK_O,
     interpret: bool | None = None,
     layer=None,  # traced index: `data` is then a stack [L, O, *]
 ) -> jax.Array:

@@ -12,7 +12,10 @@ Shared by two consumers that must never disagree:
 The policy encodes the Mosaic rules the kernels were built around
 (module docstring of qmatmul.py): output tiles never below 128 lanes,
 full-lane operand blocks, and live VMEM bounded by an in-kernel
-statically-unrolled chunk loop over K.
+statically-unrolled chunk loop over K. Where a 512-row tile fits
+(`words_ok`), the forward kernels run that loop on the tile read as
+32-bit words and transposed (docs/kernels.md#word-path), and
+`pick_block_o` picks the tile for it.
 """
 
 from __future__ import annotations
@@ -29,6 +32,12 @@ VMEM_BUDGET = 10 * 1024 * 1024  # what the tile policy prices, see below
 # dx kernel 17.73 MiB against the 16 MiB default and were refused (PR
 # 21 chip run). A v5e core has 128 MiB of VMEM.
 VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+# What a word-path tile (`words_tile_bytes`) may hold of that limit; the
+# rest is the x and output blocks (twice) and the chunk loop's
+# temporaries. The widest row the cells have, Qwen2's K = 18944, holds
+# 19.7 MiB and compiles (tests/test_tpu_lowering.py).
+WORDS_VMEM_BYTES = 20 * 1024 * 1024
 
 # x row-tile slab cap: the [block_m, K] activation block must leave room
 # for the weight tile + per-chunk dequant temporaries in the budget
@@ -60,10 +69,45 @@ def chunk_spans(total: int, target: int):
     return spans
 
 
-def pick_block_o(O: int, persist_per_row: int, cap: int = 256) -> int:
-    """Largest lane-legal O tile: a multiple of 128 dividing O (256
-    preferred, 128 if the per-row persistent footprint is large or the
-    caller caps it), else the full dim (always legal — Mosaic pads)."""
+#: O rows that share one 32-bit lane when an 8-bit code tile is read as
+#: words (`qdecode`'s word path): the tile's rows 4i .. 4i+3
+WORD_ROWS = 4
+
+#: the word path's O tile: bo / WORD_ROWS lanes must fill a 128-lane
+#: transpose, so 512 rows (1024 would only add VMEM)
+WORD_BLOCK_O = 512
+
+def words_tile_bytes(row_bytes: int, persist_per_row: int) -> int:
+    """VMEM a word-path tile holds across its chunk loop: the packed
+    blocks as the pipeline keeps them (twice), the transposed words (the
+    code bytes again) and the scales staged and transposed in float32
+    (8 bytes for every stored side byte covers the f16 and the 8-bit
+    sub-scale formats, lane padding included at real widths)."""
+    side = persist_per_row - row_bytes
+    return WORD_BLOCK_O * (2 * persist_per_row + row_bytes + 8 * side)
+
+
+def words_ok(block_o: int, row_bytes: int) -> bool:
+    """Can a [block_o, row_bytes] code tile take `qdecode`'s word path?
+    Its words [block_o / 4, row_bytes] must transpose in whole 128 x 128
+    pieces. Static, from the tile's shape alone."""
+    return block_o == WORD_BLOCK_O and row_bytes % 128 == 0
+
+
+def pick_block_o(O: int, persist_per_row: int, cap: int = WORD_BLOCK_O,
+                 row_bytes: int = 0) -> int:
+    """The O tile. `WORD_BLOCK_O` rows where the word path can run (O a
+    multiple of it, `row_bytes` given and lane-aligned, the tile and its
+    transposed copy within `WORDS_VMEM_BYTES`); else the largest
+    lane-legal tile for the stored-layout loop: a multiple of 128
+    dividing O (256 preferred, 128 if the per-row persistent footprint is
+    large or the caller caps it), else the full dim (always legal —
+    Mosaic pads)."""
+    if (cap >= WORD_BLOCK_O and O % WORD_BLOCK_O == 0
+            and row_bytes and words_ok(WORD_BLOCK_O, row_bytes)
+            and words_tile_bytes(row_bytes, persist_per_row)
+            <= WORDS_VMEM_BYTES):
+        return WORD_BLOCK_O
     for bo in (256, 128):
         if bo <= cap and O % bo == 0 and (
             bo * persist_per_row <= VMEM_BUDGET // 2
@@ -250,12 +294,51 @@ def flash_live_blocks(T: int, S: int, block_q: int, block_k: int,
     return live
 
 
+#: the word path's chunk: [512, block_o] of codes, values, repeated
+#: scales, products (float32) and the bf16 result are 6 MiB of
+#: temporaries (8 with mins); smaller chunks only add loop turns, larger
+#: ones VMEM (both read the same on the chip, PR 32)
+WORDS_CHUNK = 512
+
+
+def words_chunk_loops(qmin: int, ck: int, block: int) -> bool:
+    """Can the chunks of a `qmin`-element segment be one loop body with a
+    traced chunk index? They must tile the segment, and each must cover 8
+    whole blocks, so that its scale rows start on a sublane tile."""
+    return qmin % ck == 0 and (ck // block) % 8 == 0
+
+
+def words_chunk(qmin: int, block: int) -> int:
+    """The word path's chunk for segments of `qmin` elements (the finest
+    plane split) and `block` elements a scale: the largest multiple of
+    128 up to `WORDS_CHUNK` that divides the segment and lets
+    `qdecode.tile_product` trace the segment's chunks as one loop body
+    (`words_chunk_loops`); where there is none (a 64-element block at
+    K = 3584), the largest that divides it, and the loop is Python's."""
+    fits = [ck for ck in range(128, WORDS_CHUNK + 1, 128) if qmin % ck == 0]
+    loops = [ck for ck in fits if words_chunk_loops(qmin, ck, block)]
+    return max(loops or fits or [128])
+
+
+def forward_chunk(words: bool, rows: int, persist_bytes: int, qmin: int,
+                  block: int, mins: bool) -> int:
+    """The forward kernels' chunk over segments of `qmin` elements:
+    `words_chunk` on the word path, else `chunk_target` for `rows` rows
+    of stored-layout temporaries (14 B an element, 20 with mins)."""
+    if words:
+        return words_chunk(qmin, block)
+    return chunk_target(rows, persist_bytes, qmin,
+                        temp_bpe=20 if mins else 14)
+
+
 def chunk_target(block_o: int, persist_bytes: int, kh: int,
                  temp_bpe: int = 12) -> int:
-    """Largest chunk whose per-chunk temporaries (temp_bpe B/element of
-    dequant intermediates — decoded codes + expanded scales in f32 plus
-    the bf16 weight tile — plus the one-hot sel) fit beside the
-    persistent blocks in the scoped-VMEM budget."""
+    """The stored-layout loop's chunk: the largest whose per-chunk
+    temporaries (`block_o` rows at temp_bpe B/element of dequant
+    intermediates: widened codes, float32 values and expanded scales,
+    the bf16 result; plus the one-hot sel) fit beside the persistent
+    blocks in the scoped-VMEM budget. (`words_chunk` is the word
+    path's.)"""
     for ck in (2048, 1024, 512, 256, 128):
         if ck > kh:
             continue

@@ -1,0 +1,442 @@
+#!/usr/bin/env python3
+"""`qmatmul` alone on the chip, at the benchmark cells' own (K, O) and M,
+whole and with parts of its body taken out, IN THIS SCRIPT'S OWN COPIES of
+the kernel bodies (nothing in `bigdl_tpu/` has a switch for it). By hand,
+through the chip tool:
+
+    python scripts/qmatmul_kernel_bench.py [--plan cells|mistral|quick]
+    python scripts/qmatmul_kernel_bench.py --lower   # compile only, no chip
+
+Each line is one (body, variant, K, O, M): 64 dependent calls inside one
+jit (the next call's layer index is computed from the last one's output),
+host clock at 16, 32 and 64 calls, least squares for the time of one; the
+bytes that must move (packed codes, scales, x, y) over 819 GB/s is
+`hbm_us`, and `share` their quotient.
+
+Bodies:
+
+* `tree`: `bigdl_tpu.ops.pallas.qmatmul._qmm` as it stands (variant d);
+* `words`: this script's copy of the word path (`qdecode.tile_product`
+  with scratch): the tile read as 32-bit words, transposed once, decoded
+  with k on sublanes. Variants: d whole, a scales not spread (one
+  broadcast row), b codes not decoded (the raw byte of the word), c
+  nothing computed (tiles fetched, output zero);
+* `rows`: the copy of the loop as it was before PR 32 (and still is where
+  no 512-row tile fits, and in `qbackward`): stored [o, k] layout, scales
+  spread over lanes by a float32 one-hot matmul per chunk. Same variants,
+  and `ab`: both taken out (widen, convert, cast and the product alone).
+
+It also checks, on the device it runs on, that what each body feeds the
+MXU is the dequantizer's weights bit for bit. The CPU interpreter cannot
+say: its float32 dot is exact, the MXU's default-precision one is not.
+
+Not part of the benchmark: the cells measure the kernel inside their
+programs (`kernel.decode.qmatmul_roofline`, `generate.decode_mbu`).
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import importlib
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from bigdl_tpu.ops.pallas import qdecode
+from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
+from bigdl_tpu.ops.pallas.tiling import (
+    VMEM_LIMIT_BYTES, finest_split, forward_chunk, pick_block_m, pick_block_o,
+    round_up, words_ok,
+)
+
+SPEC = DecodeSpec(planes=(4,), value=("offset", 8), block=32)
+HBM_BYTES_PER_S = 819e9  # TPU v5e, Google Cloud documentation
+
+
+# ----------------------------------------------------------- the two bodies
+
+def rows_body(x, w_ref, s_ref, *, K, ck, variant):
+    """The stored-layout loop (`tile_product` without scratch), sym_int4."""
+    bo, kh = w_ref.shape[0], K // 2
+    s = qdecode.f16_bits_to_f32(s_ref[:])
+    w = w_ref[:]
+    acc = jnp.zeros((x.shape[0], bo), jnp.float32)
+    for e0, c in qdecode.walk(K, SPEC.planes, ck):
+        mp = e0 // kh
+        wb = qdecode.slc(w, e0 - mp * kh, c).astype(jnp.int32)
+        if "b" in variant:
+            v = wb.astype(jnp.float32)
+        else:
+            v = (((wb >> (4 * mp)) & 15) - 8).astype(jnp.float32)
+        if "a" in variant:
+            sx = jnp.broadcast_to(qdecode.slc(s, e0 // 32, 1), (bo, c))
+        else:
+            sx = qdecode.expand_scales(qdecode.slc(s, e0 // 32, c // 32), c, 32,
+                                       from_f16=True)
+        acc += jax.lax.dot_general(
+            qdecode.slc(x, e0, c), (v * sx).astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    return acc
+
+
+def words_body(x_ref, w_ref, s_ref, scratch, *, K, ck, variant):
+    """The word path (`tile_product` with scratch), sym_int4: the chunks of
+    each nibble half one loop body, unrolled when it is lowered; the four
+    packs side by side on lanes. (`split` in the variant: a chain and a
+    dot per pack instead; `roll`: the loop left rolled.)"""
+    qdecode.stage_words(SPEC, w_ref, (s_ref,), scratch)
+    wT_ref, _, sT_ref = scratch
+    bo, kh = w_ref.shape[0], K // 2
+    q = bo // 4
+    split = "split" in variant
+
+    def weights(words, sx, h, lanes):
+        if "b" in variant:
+            v = ((words >> (8 * lanes)) & 0xFF if split
+                 else qdecode._packs(words, 0, 8)).astype(jnp.float32)
+        else:
+            code = ((words >> (8 * lanes + 4 * h)) & 15 if split
+                    else qdecode._packs(words, 4 * h, 4))
+            v = (code - 8).astype(jnp.float32)
+        return (v * sx).astype(jnp.bfloat16)
+
+    acc = tuple(jnp.zeros((x_ref.shape[0], q), jnp.float32) for _ in range(4)
+                ) if split else jnp.zeros((x_ref.shape[0], bo), jnp.float32)
+    for h in range(2):
+        def chunk(i, acc, h=h):
+            off = pl.multiple_of(i * ck, ck)
+            words = wT_ref[pl.ds(off, ck), :]
+            sb0 = pl.multiple_of((h * kh) // 32 + off // 32, 8)
+            rows = pl.ds(0, 1) if "a" in variant else pl.ds(sb0, ck // 32)
+            xs = x_ref[:, pl.ds(pl.multiple_of(h * kh + off, 128), ck)
+                       ].astype(jnp.bfloat16)
+            dot = lambda wd: jax.lax.dot_general(
+                xs, wd, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            spread = lambda s: (jnp.broadcast_to(s, (ck, s.shape[1]))
+                                if "a" in variant
+                                else qdecode._rows_repeat(s, 32))
+            if split:
+                return tuple(
+                    acc[p] + dot(weights(
+                        words, spread(sT_ref[0, rows, p * q:(p + 1) * q]),
+                        h, p)) for p in range(4))
+            return acc + dot(weights(words, spread(sT_ref[0, rows, :]), h, 0))
+        acc = jax.lax.fori_loop(0, kh // ck, chunk, acc,
+                                unroll="roll" not in variant)
+    return jnp.concatenate(acc, axis=1) if split else acc
+
+
+def _kernel(layer_ref, x_ref, w_ref, s_ref, o_ref, *scratch, K, ck, body,
+            variant):
+    del layer_ref
+    if variant == "c":
+        o_ref[:] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        return
+    if body == "words":
+        acc = qdecode.natural_columns(words_body(
+            x_ref, w_ref, s_ref, scratch, K=K, ck=ck, variant=variant))
+    else:
+        acc = rows_body(x_ref[:].astype(jnp.bfloat16), w_ref, s_ref, K=K,
+                        ck=ck, variant=variant)
+    o_ref[:] = acc.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "block_o", "ck",
+                                             "body", "variant"))
+def qmm_copy(layer, x2, w, s, *, block_m, block_o, ck, body, variant):
+    """`qmatmul._qmm` with this script's body (the word body puts its
+    columns back in order as the tree's does, `natural_columns`)."""
+    Mp, K = x2.shape
+    O = w.shape[1]
+    scratch = (qdecode.word_scratch(SPEC, block_o, w.shape[2], s.shape[1])
+               if body == "words" else [])
+    return pl.pallas_call(
+        functools.partial(_kernel, K=K, ck=ck, body=body, variant=variant),
+        name=f"qmatmul_{body}_{variant}",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Mp // block_m, O // block_o),
+            in_specs=[
+                pl.BlockSpec((block_m, K), lambda m, o, l: (m, 0)),
+                pl.BlockSpec((None, block_o, w.shape[2]),
+                             lambda m, o, l: (l[0], o, 0)),
+                pl.BlockSpec((block_o, s.shape[1]), lambda m, o, l: (o, 0)),
+            ],
+            out_specs=pl.BlockSpec((block_m, block_o), lambda m, o, l: (m, o)),
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct((Mp, O), jnp.bfloat16),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+    )(layer, x2, w, s)
+
+
+def tiles(body, M, K, O):
+    """The policy's tiles: the tree's for `tree` and `words`, the 256-row
+    ones the stored-layout loop had for `rows`."""
+    block_m = pick_block_m(M, K)
+    persist_row = K // 2 + (K // 32) * 2
+    block_o = pick_block_o(O, persist_row, cap=256 if body == "rows" else 512,
+                           row_bytes=K // 2)
+    persist = block_o * persist_row + block_m * K * 2 + block_m * block_o * 4
+    ck = forward_chunk(words_ok(block_o, K // 2), block_o, persist,
+                       finest_split(K, SPEC.planes), SPEC.block, False)
+    return block_m, block_o, ck
+
+
+def build(body, variant, M, K, O):
+    """-> (run(n, x, w, s): n dependent calls, call(layer, x, w, s), tiles)."""
+    block_m, block_o, ck = tiles(body, M, K, O)
+    if body == "words" and not words_ok(block_o, K // 2):
+        return None
+    if body == "tree":
+        qm = importlib.import_module("bigdl_tpu.ops.pallas.qmatmul")
+
+        def call(layer, x, w, s):
+            return qm._qmm(SPEC, jnp.dtype(jnp.bfloat16), block_m, block_o,
+                           ck, False, False, layer, x, w, s)
+    else:
+        def call(layer, x, w, s):
+            return qmm_copy(layer, x, w, s, block_m=block_m, block_o=block_o,
+                            ck=ck, body=body, variant=variant)
+
+    @jax.jit
+    def run(n, x, w, s):
+        def one(i, carry):
+            layer, acc = carry
+            y = call(layer, x, w, s)
+            flag = (y[0, 0] != y[0, 0]).astype(jnp.int32)  # 0; needs y
+            return (jnp.reshape((i + 1) % w.shape[0] + flag, (1,)),
+                    acc + y[0, 0].astype(jnp.float32))
+        return jax.lax.fori_loop(
+            0, n, one, (jnp.zeros((1,), jnp.int32), jnp.float32(0)))[1]
+
+    return run, call, (block_m, block_o, ck)
+
+
+def operands(M, K, O, block_m, key, sharding=None):
+    Mp = round_up(M, block_m)
+    if sharding is not None:  # shapes alone, for a described device
+        sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=sharding)
+        return (sds((Mp, K), jnp.bfloat16), sds((2, O, K // 2), jnp.uint8),
+                sds((O, K // 32), jnp.uint16))
+    k1, k2, k3 = jax.random.split(key, 3)
+    x = jax.random.normal(k1, (Mp, K), jnp.float32).astype(jnp.bfloat16)
+    w = jax.random.randint(k2, (2, O, K // 2), 0, 256, jnp.int32
+                           ).astype(jnp.uint8)
+    s = (jax.random.uniform(k3, (O, K // 32)) * 0.01 + 0.001
+         ).astype(jnp.float16)
+    return x, w, jax.lax.bitcast_convert_type(s, jnp.uint16)
+
+
+def measure(body, variant, M, K, O, key, ns=(16, 32, 64), reps=3):
+    built = build(body, variant, M, K, O)
+    if built is None:
+        return None
+    run, _, (block_m, block_o, ck) = built
+    x, w, s = operands(M, K, O, block_m, key)
+    jax.block_until_ready(run(2, x, w, s))
+    ts = []
+    for n in ns:
+        best = float("inf")
+        for _ in range(reps):
+            t = time.perf_counter()
+            jax.block_until_ready(run(n, x, w, s))
+            best = min(best, time.perf_counter() - t)
+        ts.append(best)
+    per_call = float(np.polyfit(np.asarray(ns, float), np.asarray(ts), 1)[0])
+    nbytes = O * (K // 2 + K // 32 * 2) + x.size * 2 + x.shape[0] * O * 2
+    return dict(body=body, variant=variant, M=M, K=K, O=O, block_m=block_m,
+                block_o=block_o, ck=ck, us=per_call * 1e6,
+                hbm_us=nbytes / HBM_BYTES_PER_S * 1e6,
+                share=100 * nbytes / HBM_BYTES_PER_S / per_call)
+
+
+# ------------------------------------------- what the MXU is fed, on device
+
+def fed_weights_check():
+    """Each body's decoded bf16 tile [512, 4096] against
+    round_bf16(float32(code - 8) * float32(scale)), computed on the host."""
+    K, bo = 4096, 512
+
+    def rows_kern(w_ref, s_ref, o_ref):
+        side = qdecode.load_side(SPEC, (s_ref,))
+        w = w_ref[:]
+        for e0, c in qdecode.walk(K, SPEC.planes, 2048):
+            o_ref[:, e0:e0 + c] = qdecode.decode_chunk(SPEC, K, w, side, e0, c)
+
+    def words_kern(w_ref, s_ref, o_ref, *scratch):
+        qdecode.stage_words(SPEC, w_ref, (s_ref,), scratch)
+        for seg in range(2):
+            for c0 in range(0, K // 2, 512):
+                o_ref[seg * (K // 2) + c0:seg * (K // 2) + c0 + 512, :] = \
+                    qdecode.decode_chunk_words(
+                        SPEC, K, scratch[0], scratch[2], False, seg, c0, 512)
+
+    k2, k3 = jax.random.split(jax.random.key(1))
+    w = jax.random.randint(k2, (bo, K // 2), 0, 256, jnp.int32
+                           ).astype(jnp.uint8)
+    sc = (jax.random.uniform(k3, (bo, K // 32)) * 0.01 + 0.001
+          ).astype(jnp.float16)
+    bits = jax.lax.bitcast_convert_type(sc, jnp.uint16)
+    params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+    rows = pl.pallas_call(
+        rows_kern, out_shape=jax.ShapeDtypeStruct((bo, K), jnp.bfloat16),
+        compiler_params=params)(w, bits)
+    words = pl.pallas_call(
+        words_kern,
+        out_shape=jax.ShapeDtypeStruct((K, bo), jnp.bfloat16),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0, grid=(1,),
+            in_specs=[pl.BlockSpec((bo, K // 2), lambda i: (0, 0)),
+                      pl.BlockSpec((bo, K // 32), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((K, bo), lambda i: (0, 0)),
+            scratch_shapes=qdecode.word_scratch(SPEC, bo, K // 2, K // 32)),
+        compiler_params=params)(w, bits)
+    # lane p * bo/4 + i of the tile is its row 4i + p
+    words = jnp.transpose(words.reshape(K, 4, bo // 4), (2, 1, 0)
+                          ).reshape(bo, K)
+    wn = np.asarray(w).astype(np.int32)
+    codes = np.concatenate([wn & 15, wn >> 4], axis=1) - 8
+    sf = np.repeat(np.asarray(sc).astype(np.float32), 32, axis=1)
+    want = np.asarray(jnp.asarray(codes.astype(np.float32) * sf
+                                  ).astype(jnp.bfloat16).astype(jnp.float32))
+    for name, got in (("rows", rows), ("words", words)):
+        g = np.asarray(got.astype(jnp.float32))
+        bad = g != want
+        rel = (np.abs(g - want)[bad] / np.abs(want)[bad]).max() if bad.any() \
+            else 0.0
+        yield dict(check="fed_weights_vs_dequantizer", body=name,
+                   mismatched=int(bad.sum()), of=int(bad.size),
+                   worst_rel=float(rel))
+
+
+def product_check():
+    """The tree's kernel against XLA on the same device: y in float32 from
+    the dequantizer's bf16 weights at HIGHEST precision. What may differ is
+    float32 summation order: a few 1e-6 of |y|."""
+    qm = importlib.import_module("bigdl_tpu.ops.pallas.qmatmul")
+    for M, K, O in ((1, 4096, 6144), (32, 14336, 4096), (256, 4096, 6144),
+                    (32, 4096, 32000)):
+        x, w, s = operands(M, K, O, pick_block_m(M, K), jax.random.key(M))
+        x = x[:M]
+        scales = jax.lax.bitcast_convert_type(s, jnp.float16)
+        y = qm.qmatmul_int4(x, w, scales, out_dtype=jnp.float32,
+                            layer=jnp.int32(1))
+        codes = jnp.concatenate([w[1] & 15, w[1] >> 4], axis=1
+                                ).astype(jnp.float32) - 8
+        wd = (codes * jnp.repeat(scales.astype(jnp.float32), 32, axis=1)
+              ).astype(jnp.bfloat16).astype(jnp.float32)
+        want = jnp.dot(x.astype(jnp.float32), wd.T,
+                       precision=jax.lax.Precision.HIGHEST)
+        yield dict(check="product_vs_xla", M=M, K=K, O=O,
+                   worst=float(jnp.abs(y - want).max()),
+                   of=float(jnp.abs(want).max()))
+
+
+# ----------------------------------------------------------------- the plans
+
+def cell_shapes():
+    """{configuration: ((K, O) of its decode step's distinct projections,
+    the rows its cell's decode step has)}."""
+    from bench import costs
+
+    rows = {"mistral-7b-int4": 32, "qwen2-7b-int4": 16,
+            "mixtral-8x7b-int4": 16, "brumby-14b-int4": 8}
+    out = {}
+    for name, M in rows.items():
+        with open(os.path.join(ROOT, "bench", "configs", name + ".json")) as f:
+            hf = json.load(f)
+        out[name] = (sorted(set(costs.decode_linears(hf))), M)
+    return out
+
+
+def plan_of(name):
+    plan = []
+    if name == "quick":
+        for K, O in ((4096, 6144), (14336, 4096)):
+            for M in (1, 32, 256):
+                plan += [("tree", "d", M, K, O), ("rows", "d", M, K, O)]
+        return plan
+    if name == "forms":  # the word body's loop forms against each other
+        for K, O in ((4096, 6144), (14336, 4096)):
+            for M in (1, 32, 256):
+                plan += [("words", v, M, K, O)
+                         for v in ("d", "d-split", "d-roll", "d-split-roll")]
+        return plan
+    shapes = cell_shapes()
+    if name == "mistral":  # every variant, every M
+        for K, O in shapes["mistral-7b-int4"][0]:
+            for M in (1, 8, 16, 32):
+                plan += [("tree", "d", M, K, O)]
+                plan += [("words", v, M, K, O) for v in "abc"]
+                plan += [("rows", v, M, K, O) for v in ("d", "a", "b", "ab")]
+        return plan
+    for cfg, (kos, M) in shapes.items():  # the before / after table
+        for K, O in kos:
+            for m in sorted({1, M} if cfg.startswith("mistral") else {M}):
+                plan += [("rows", "d", m, K, O), ("tree", "d", m, K, O),
+                         ("words", "c", m, K, O)]
+    plan += [(b, "d", 256, K, O) for b in ("rows", "tree")
+             for K, O in ((4096, 6144), (14336, 4096))]
+    return plan
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--plan", default="cells",
+                    choices=("cells", "mistral", "quick", "forms"))
+    ap.add_argument("--lower", action="store_true",
+                    help="compile the plan for a described v5e; no chip")
+    ap.add_argument("--out", default="chiprun_out/qmatmul_kernel_bench.jsonl")
+    args = ap.parse_args()
+    plan = plan_of(args.plan)
+
+    if args.lower:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        one = SingleDeviceSharding(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0])
+        for body, v, M, K, O in plan:
+            built = build(body, v, M, K, O)
+            if built is None:
+                print(f"skip {body} {v} M={M} K={K} O={O}: no 512-row tile")
+                continue
+            run, _, (block_m, _, _) = built
+            run.lower(jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
+                      *operands(M, K, O, block_m, None, one)).compile()
+            print(f"ok {body} {v} M={M} K={K} O={O}", flush=True)
+        return 0
+
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind}", flush=True)
+    if dev.platform != "tpu":
+        print("no TPU: a kernel's time comes only from the chip")
+        return 2
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    key = jax.random.key(0)
+    with open(args.out, "a") as f:
+        results = (measure(*case, key) for case in plan)
+        for r in (*fed_weights_check(), *product_check(), *results):
+            if r is not None:
+                print(json.dumps(r), flush=True)
+                f.write(json.dumps(r) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1049,6 +1049,23 @@ def test_dsp005_vmem_ceiling():
     assert len(fs) == 1 and "16 MiB" in fs[0].message
 
 
+@pytest.mark.parametrize("src,word", [
+    ("VMEM_LIMIT_BYTES = 32 * 1024 * 1024\n"
+     "WORDS_VMEM_BYTES = 28 * 1024 * 1024", "WORDS_VMEM_BYTES"),
+    ("MOSAIC_LANES = 128\nWORD_ROWS = 4\nWORD_BLOCK_O = 256",
+     "WORD_BLOCK_O"),
+], ids=["vmem", "tile"])
+def test_dsp005_word_path_policy(src, word):
+    """The word path's constants (ISSUE 32): its tile transposes in whole
+    128-lane pieces and leaves the chunk loop a quarter of the limit."""
+    fs = lint(src, "bigdl_tpu/ops/pallas/tiling.py", "DSP005")
+    assert len(fs) == 1 and word in fs[0].message
+    assert fs[0].code.startswith(word)
+    good = {"WORDS_VMEM_BYTES": src.replace("28", "20"),
+            "WORD_BLOCK_O": src.replace("256", "512")}[word]
+    assert lint(good, "bigdl_tpu/ops/pallas/tiling.py", "DSP005") == []
+
+
 def test_dsp_suppression_comment_works():
     assert lint("""
         # graftlint: disable=DSP004

@@ -1,0 +1,129 @@
+"""The packed matmuls compile for a v5e at the benchmark cells' own widths.
+
+No chip is attached: the TPU compiler that is installed here compiles for a
+DESCRIBED v5e (`jax.experimental.topologies`), which raises what the chip's
+compiler would raise: a slice Mosaic cannot tile, a transpose or strided read
+it does not lower, more scoped VMEM than the kernel may use. The Pallas
+interpreter accepts all of these in silence. Nothing runs, so this says
+nothing of results or times.
+
+One file, and the topology is described inside a fixture: only one process
+at a time may load the TPU library, so only the xdist worker that is given
+this file does, and every worker collects the same tests.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+pytestmark = pytest.mark.core
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# (K, O) of the cells' projections (bench/costs.decode_linears of the four
+# configurations) with the rows the cell's decode step has, and a prefill's
+# row tile at each configuration's widest contraction
+_DENSE = [
+    # Mistral-7B: wqkv, wo, w_gateup, w_down, head (32000: no 512 tile)
+    (4096, 6144, 1), (4096, 6144, 32), (4096, 4096, 32), (4096, 28672, 32),
+    (14336, 4096, 32), (4096, 32000, 32), (14336, 4096, 256),
+    # Qwen2-7B
+    (3584, 4608, 16), (3584, 3584, 16), (3584, 37888, 16), (18944, 3584, 16),
+    (3584, 152064, 16), (18944, 3584, 256),
+    # Brumby-14B
+    (5120, 7168, 8), (5120, 5120, 8), (5120, 34816, 8), (17408, 5120, 8),
+    (5120, 151936, 8), (17408, 5120, 256),
+]
+
+
+@pytest.mark.parametrize("K,O,M", _DENSE)
+def test_qmatmul_compiles_at_the_cells_shapes(one_chip, K, O, M):
+    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_int4
+
+    L = 2
+
+    def f(x, data, scales, layer):
+        return qmatmul_int4(x, data, scales, interpret=False, layer=layer)
+
+    jax.jit(f).lower(
+        _sds((M, K), jnp.bfloat16, one_chip),
+        _sds((L, O, K // 2), jnp.uint8, one_chip),
+        _sds((O, K // 32), jnp.float16, one_chip),
+        _sds((), jnp.int32, one_chip),
+    ).compile()
+
+
+def _format_names():
+    from bigdl_tpu.ops.linear import _QGEMV_QTYPES
+    return sorted(_QGEMV_QTYPES)
+
+
+@pytest.mark.parametrize("O", (1024, 768), ids=("words", "rows"))
+@pytest.mark.parametrize("qtype", _format_names())
+def test_every_format_compiles_on_both_loops(one_chip, qtype, O):
+    """GEMV rows and a GEMM row tile, K = 2048, on the word path (two
+    512-row tiles) and on the stored-layout loop (O = 768 has no 512-row
+    tile): each format's planes, value decode and scale levels through
+    Mosaic. (The k-quants encode on the host, so the fields' shapes come
+    from a real, small quantization and not from `eval_shape`.)"""
+    from bigdl_tpu.ops.pallas.qmatmul import qmatmul
+    from bigdl_tpu.ops.pallas.tiling import WORD_BLOCK_O, pick_block_o
+    from bigdl_tpu.quant import quantize
+
+    K = 2048
+    qt = quantize(jnp.zeros((O, K), jnp.float32), qtype)
+    rb = qt.data.shape[1] * qt.data.dtype.itemsize
+    assert (pick_block_o(O, 2 * rb, row_bytes=rb) == WORD_BLOCK_O) \
+        == (O == 1024)
+    assert qt.qtype == qtype
+    qt = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), qt)
+    for M in (8, 256):
+        jax.jit(lambda x, w: qmatmul(x, w, interpret=False)).lower(
+            _sds((M, K), jnp.bfloat16, one_chip), qt).compile()
+
+
+@pytest.mark.parametrize("K,O,gated", [(4096, 14336, True),
+                                       (14336, 4096, False)])
+def test_moe_qmatmul_compiles_at_mixtrals_shapes(one_chip, K, O, gated):
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    L, E, N, k = 2, 8, 16, 2
+    bm = mq.moe_block_m(N, 14336)
+    n_tiles = mq.moe_n_tiles(N, k, E, bm)
+
+    def f(x, te, n_used, layer, *fields):
+        ws = [QTensor(qtype="sym_int4", data=fields[2 * i],
+                      scales=fields[2 * i + 1]) for i in range(len(fields) // 2)]
+        return mq.moe_qmatmul(x, ws if gated else ws[0], te, n_used, bm,
+                              act="silu" if gated else None, layer=layer,
+                              interpret=False)
+
+    fields = []
+    for _ in range(2 if gated else 1):
+        fields += [_sds((L, E, O, K // 2), jnp.uint8, one_chip),
+                   _sds((E, O, K // 32), jnp.float16, one_chip)]
+    jax.jit(f).lower(
+        _sds((n_tiles * bm, K), jnp.bfloat16, one_chip),
+        _sds((n_tiles,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        *fields,
+    ).compile()
