@@ -1,0 +1,13 @@
+"""Device time of the `paged_latent_decode_attention` kernel per decode step
+(all layers), from the trace. None for a program without the kernel."""
+
+ENTRIES = ("engine",)
+
+
+def read(run):
+    dev = run.device
+    if dev is None:
+        return None
+    n_steps, secs = dev.kernel_in_program("paged_latent_decode_attention",
+                                          "engine_decode")
+    return secs / n_steps * 1e3 if n_steps and secs else None

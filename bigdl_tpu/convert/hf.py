@@ -1268,7 +1268,12 @@ def params_from_state_dict(
             params[k] = maybe_quant(k, v)
         return params
 
-    if config.model_type in ("deepseek_v2", "deepseek_v3", "minicpm3"):
+    if config.model_type in ("deepseek_v2", "deepseek_v3", "minicpm3",
+                             "glm4_moe_lite"):
+        # glm4_moe_lite's next-token-prediction layer
+        # (model.layers.<num_hidden_layers>.*) is never asked for: the
+        # tree reads layers 0 .. num_hidden_layers - 1 by name, which is
+        # how HF's Glm4MoeLiteForCausalLM drops it at load
         dense_dicts, moe_dicts, top = _deepseek_tree(
             config, get_tensor, maybe_quant
         )

@@ -98,13 +98,85 @@ def init_paged(
     )
 
 
-def live_rows(cache: PagedKVCache) -> jax.Array:
+def live_rows(cache) -> jax.Array:
     """[B] bool: rows whose block table maps a page at all. Physical
     page 0 is the scratch sink (`PagePool` never hands it out) and a
     released slot's row points EVERY entry there while its `pos` keeps
     advancing, so `pos` alone would call ever more of the sink live. The
     paged decode kernel spends nothing on a row this says is idle."""
     return jnp.any(cache.block_tables != 0, axis=1)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PagedLatentCache:
+    """The paged cache of a latent-attention (MLA) model
+    (models/deepseek.py): a page holds, for each of its tokens and each
+    layer, ONE row of `kv_lora_rank + qk_rope_head_dim` values (the
+    compressed kv, then the shared rope key) and no heads. One array, so a
+    page is one DMA for the decode kernel
+    (ops/pallas/paged_attention.paged_latent_decode_attention) and one
+    scatter for a write. The row is padded with zeros to whole tiles of
+    128 lanes (576 -> 640): a TPU pads it to that in HBM whatever the shape
+    says, and for a width that is NOT whole tiles the compiler prefers a
+    layout with the PAGE axis minor-most, which every program would then
+    copy whole before the kernel could read a page (bench/tools/fit_latent.py
+    showed a 4.2 GB temporary in every step at GLM-4.7-Flash's sizes).
+    Booked, shared, parked and restored by
+    `serving/pages.PageTable` exactly as a KV page is: the table sees page
+    numbers and `page_nbytes`, nothing else."""
+
+    lat: jax.Array  # [L, n_pages, page_size, round_up(r + dr, 128)] bf16
+    block_tables: jax.Array  # [B, max_pages] int32 physical page ids
+    pos: jax.Array  # [B] int32 next logical slot per row
+    start: jax.Array  # [B] int32 first valid slot (left padding)
+
+    @property
+    def page_size(self) -> int:
+        return self.lat.shape[2]
+
+    @property
+    def max_len(self) -> int:  # logical capacity per row
+        return self.block_tables.shape[1] * self.page_size
+
+    def next_positions(self, t: int) -> jax.Array:
+        step = jnp.arange(t, dtype=jnp.int32)[None, :]
+        return jnp.maximum(self.pos[:, None] + step - self.start[:, None], 0)
+
+
+def init_latent(n_layers: int, n_pages: int, page_size: int, rank: int,
+                rope_dim: int, batch: int, max_pages_per_row: int,
+                dtype=jnp.bfloat16) -> PagedLatentCache:
+    width = -(-(rank + rope_dim) // 128) * 128
+    return PagedLatentCache(
+        lat=jnp.zeros((n_layers, n_pages, page_size, width), dtype),
+        block_tables=jnp.zeros((batch, max_pages_per_row), jnp.int32),
+        pos=jnp.zeros((batch,), jnp.int32),
+        start=jnp.zeros((batch,), jnp.int32),
+    )
+
+
+def update_latent_layer(cache: PagedLatentCache, layer: jax.Array,
+                        lat_new: jax.Array) -> PagedLatentCache:
+    """Write lat_new [B, T, r + dr] (zero-padded to the pool's width) at
+    each row's pos through the block table. Does NOT advance pos (the
+    forward advances once)."""
+    T = lat_new.shape[1]
+    lat_new = jnp.pad(lat_new, ((0, 0), (0, 0),
+                                (0, cache.lat.shape[-1] - lat_new.shape[-1])))
+    page = cache.page_size
+    s = cache.pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    phys = jnp.take_along_axis(cache.block_tables, s // page, axis=1)
+    return dataclasses.replace(cache, lat=cache.lat.at[
+        layer, phys, s % page].set(lat_new.astype(cache.lat.dtype)))
+
+
+def read_latent_layer(cache: PagedLatentCache, layer: jax.Array) -> jax.Array:
+    """One layer's pages of every row gathered into [B, S, width]."""
+    lat_l = jax.lax.dynamic_index_in_dim(cache.lat, layer, 0, keepdims=False)
+    B, mp = cache.block_tables.shape
+    return lat_l[cache.block_tables].reshape(
+        B, mp * cache.page_size, lat_l.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -158,10 +230,13 @@ class PagePool:
         return len(self.free)
 
 
-def kv_page_nbytes(cache: PagedKVCache) -> int:
+def kv_page_nbytes(cache) -> int:
     """Bytes of ONE physical page across every layer (K + V + fp8
-    scales) — the unit the unified KV/adapter device budget is
-    denominated in (serving/adapters.AdapterPager)."""
+    scales, or the latents) — the unit the unified KV/adapter device
+    budget is denominated in (serving/adapters.AdapterPager)."""
+    if isinstance(cache, PagedLatentCache):
+        L, _, page, width = cache.lat.shape
+        return L * page * width * cache.lat.dtype.itemsize
     L, _, page, Hkv, D = cache.k.shape
     n = 2 * L * page * Hkv * D * cache.k.dtype.itemsize
     if cache.quantized:
@@ -275,6 +350,44 @@ def swap_in_pages(cache: PagedKVCache, k, v, k_scale, v_scale,
         upd["v_scale"] = cache.v_scale.at[:, pages].set(
             jnp.asarray(v_scale, cache.v_scale.dtype))
     return dataclasses.replace(cache, **upd)
+
+
+@dataclasses.dataclass
+class HostLatentPages:
+    """`HostKVPages` for a `PagedLatentCache`: the parked pages' latents,
+    every layer, byte for byte."""
+
+    lat: "object"  # np.ndarray [L, n, page, r + dr] in the pool dtype
+
+    @property
+    def n_pages(self) -> int:
+        return self.lat.shape[1]
+
+    @property
+    def nbytes(self) -> int:
+        return self.lat.nbytes
+
+
+def swap_out_latent(cache: PagedLatentCache, pages) -> HostLatentPages:
+    import numpy as np
+
+    ids = jnp.asarray(list(pages), jnp.int32)
+    return HostLatentPages(lat=np.asarray(jax.device_get(cache.lat[:, ids])))
+
+
+def swap_in_latent(cache: PagedLatentCache, lat,
+                   pages: jax.Array) -> PagedLatentCache:
+    """`swap_in_pages` for latent pages (jitted by the engine with the
+    cache donated; one program per distinct page count)."""
+    return dataclasses.replace(cache, lat=cache.lat.at[:, pages].set(
+        jnp.asarray(lat, cache.lat.dtype)))
+
+
+def copy_latent_page(cache: PagedLatentCache, src, dst) -> PagedLatentCache:
+    """One physical page's latents (all layers) into another: the sub-page
+    prefix-sharing copy."""
+    return dataclasses.replace(
+        cache, lat=cache.lat.at[:, dst].set(cache.lat[:, src]))
 
 
 def update_layer(

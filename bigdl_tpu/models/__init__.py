@@ -118,6 +118,7 @@ from bigdl_tpu.models import deepseek  # noqa: E402  (MLA latent-KV cache)
 _FAMILIES["deepseek_v2"] = deepseek
 _FAMILIES["deepseek_v3"] = deepseek
 _FAMILIES["minicpm3"] = deepseek
+_FAMILIES["glm4_moe_lite"] = deepseek  # GLM-4.7-Flash: DeepSeek-V3's layers
 
 from bigdl_tpu.models import yuan  # noqa: E402  (LFA conv-filtered attention)
 

@@ -553,6 +553,24 @@ def _hf_deepseek_v3(hf, kw):
     kw["norm_topk_prob"] = hf.get("norm_topk_prob", True)
 
 
+def _hf_glm4_moe_lite(hf, kw):
+    """GLM-4.7-Flash (HF modeling_glm4_moe_lite: DeepseekV3Attention and
+    Glm4MoeTopkRouter, so every layer's equations are DeepSeek-V3's). What
+    the source's config.json has no key for is set here for the model
+    type, as `norm_topk_prob` is for mixtral: sigmoid scores (HF
+    hard-codes them for this family) and the pair-interleaved rope (HF's
+    `rope_interleave` default). `rope_scaling` is null there, so the
+    softmax scale is (192 + 64)^-0.5 with no mscale term.
+    `num_nextn_predict_layers` is read and the layer is not run: HF's
+    Glm4MoeLiteForCausalLM drops `model.layers.<num_hidden_layers>.*` at
+    load, and so does convert/hf.py."""
+    _hf_deepseek_v3(hf, kw)
+    kw["scoring_func"] = "sigmoid"
+    kw["first_k_dense_replace"] = hf.get("first_k_dense_replace", 1)
+    kw["n_group"] = hf.get("n_group") or 1
+    kw["topk_group"] = hf.get("topk_group") or 1
+
+
 def _hf_minicpm3(hf, kw):
     """MiniCPM3 (reference models/minicpm3.py): MLA attention + the
     minicpm residual/embedding/logit scalings, dense MLP."""
@@ -905,6 +923,7 @@ _HF_BUILDERS = {
     "deepseek_v2": _hf_deepseek_v2,
     "deepseek_v3": _hf_deepseek_v3,
     "minicpm3": _hf_minicpm3,
+    "glm4_moe_lite": _hf_glm4_moe_lite,
     "internvl": _hf_internvl,
     "internvl_chat": _hf_internvl,
     "janus": _hf_janus,

@@ -230,3 +230,138 @@ def paged_decode_attention(
         ),
         interpret=interpret,
     )(*args)
+
+
+# ---------------------------------------------------------------------------
+# Latent pages (MLA, models/deepseek.py): the absorbed decode form
+# ---------------------------------------------------------------------------
+
+#: logical pages one grid step of the latent kernel reads. Each is its own
+#: block (its own DMA through the block table), and the step joins them in
+#: VMEM and makes ONE score dot and ONE context dot over all of them: a
+#: 64-token page alone is a dot of 64 columns, and the grid step's fixed
+#: cost would be paid per page.
+LATENT_PAGES_PER_STEP = 8
+
+
+def _latent_kernel(bt_ref, meta_ref, q_ref, *refs, rank: int, page: int,
+                   group: int, n_batch: int, scale: float):
+    lat_refs, (o_ref, acc_ref, m_ref, l_ref) = refs[:group], refs[group:]
+    b = pl.program_id(0)
+    p = pl.program_id(1)
+
+    @pl.when(p == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    first_b = meta_ref[1 + 2 * n_batch + b]
+    last_b = meta_ref[1 + 3 * n_batch + b]
+
+    # a step with a live page among its `group`: the others' blocks are
+    # clamped onto live pages of the same row (finite, and masked by slot)
+    @pl.when((first_b <= last_b) & (p * group <= last_b)
+             & (p * group + group - 1 >= first_b))
+    def _live_step():
+        pos_b = meta_ref[1 + b]
+        start_b = meta_ref[1 + n_batch + b]
+        lat = jnp.concatenate([r[0, 0] for r in lat_refs], axis=0) \
+            if group > 1 else lat_refs[0][0, 0]  # [group * page, r + dr]
+        # every head is a row of the dot: one read of the page serves all
+        s = jax.lax.dot_general(
+            q_ref[0], lat, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [Hp, G * page]
+        slot = p * (group * page) + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        valid = (slot >= start_b) & (slot <= pos_b)
+        s = jnp.where(valid, s, _NEG_INF)
+
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            pexp.astype(lat.dtype), lat[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    @pl.when(p == pl.num_programs(1) - 1)
+    def _finish():
+        l = l_ref[:]
+        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "pages_per_step"))
+def paged_latent_decode_attention(
+    q_eff: jax.Array,  # [B, H, r]: W_uk^T q_nope, the absorbed query
+    q_pe: jax.Array,  # [B, H, dr] rotated
+    lat_pages: jax.Array,  # [L, n_pages, page, width >= r + dr] the FULL
+    # pool, rows zero-padded to whole lane tiles (kvpaged.PagedLatentCache)
+    block_tables: jax.Array,  # [B, max_pages] int32
+    layer: jax.Array,  # scalar int32
+    pos: jax.Array,  # [B] slot holding the current token
+    start: jax.Array,  # [B]
+    scale: float,
+    live: jax.Array | None = None,  # [B] bool; None = every row is live
+    interpret: bool | None = None,
+    pages_per_step: int = LATENT_PAGES_PER_STEP,
+) -> jax.Array:
+    """Absorbed MLA decode over latent pages, in place: returns the
+    context [B, H, r] (softmax-weighted sum of the compressed kv), to be
+    up-projected by W_uv outside. bf16 dots accumulated in float32,
+    float32 softmax state; pages outside `live_page_range` cost neither
+    DMA nor compute, and a row `live` marks idle comes back as zeros."""
+    from bigdl_tpu.ops.pallas import interpret_mode
+
+    if interpret is None:
+        interpret = interpret_mode()
+    B, H, r = q_eff.shape
+    L, NP, page, width = lat_pages.shape
+    mp = block_tables.shape[1]
+    G = min(pages_per_step, mp)
+    steps = -(-mp // G)
+    Hp = -(-H // 16) * 16  # whole bf16 sublane tiles for the dots' rows
+
+    q = jnp.concatenate([q_eff, q_pe], axis=-1).astype(lat_pages.dtype)
+    q = jnp.pad(q, ((0, 0), (0, Hp - H), (0, width - q.shape[-1])))
+    pos = pos.astype(jnp.int32)
+    start = start.astype(jnp.int32)
+    first, last = live_page_range(pos, start, _NO_WINDOW, page, mp, live)
+    meta = jnp.concatenate([
+        jnp.reshape(layer, (1,)).astype(jnp.int32), pos, start, first, last])
+
+    def lat_spec(j):
+        def index(b, p, bt, meta):
+            pg = clamped_page(p * G + j, meta[1 + 2 * B + b],
+                              meta[1 + 3 * B + b])
+            return meta[0], bt[b, pg], 0, 0
+        return pl.BlockSpec((1, 1, page, width), index)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, steps),
+        in_specs=[pl.BlockSpec((1, Hp, width), lambda b, p, bt, meta: (b, 0, 0))]
+        + [lat_spec(j) for j in range(G)],
+        out_specs=pl.BlockSpec((1, Hp, r), lambda b, p, bt, meta: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((Hp, r), jnp.float32),
+            pltpu.VMEM((Hp, 1), jnp.float32),
+            pltpu.VMEM((Hp, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, rank=r, page=page, group=G,
+                          n_batch=B, scale=scale),
+        name="paged_latent_decode_attention",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hp, r), jnp.bfloat16),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(block_tables, meta, q, *([lat_pages] * G))
+    return out[:, :H]

@@ -247,6 +247,8 @@ class _PrefillState:
     start: int = 0  # `written` at the first chunk (0: the whole prompt)
     state_chunks: int = 0  # a recurrent-state model: chunks of the
     # prefill form run so far (the `prefill` span's `state_chunks`)
+    upprojected: int = 0  # a latent-page model: tokens whose K and V the
+    # chunks so far expanded from latents (`latent_tokens_upprojected`)
 
 
 class InferenceEngine:
@@ -370,14 +372,30 @@ class InferenceEngine:
                     f"speculative serving is not available for {kind}: a "
                     "rejected draft cannot be taken back out of a "
                     "recurrent state by moving `pos`")
-        # families with their own cache serve through either (a) the
-        # generic dataclass insert path when they declare SERVABLE_CACHE
-        # (MLA's latent — flat [L, B, S, ...] fields with real pos/start;
-        # models/deepseek.py), or (b) their own engine_pool/engine_insert
-        # adapter when the cache has nested pools or property pos
-        # (rwkv recurrent state, yuan localized-filter hiddens, mllama
-        # cross-attention; the generic path would silently corrupt them).
+        # families with their own cache serve through (a) the generic
+        # dataclass insert path when they declare SERVABLE_CACHE (MLA's
+        # latent as a dense pool — flat [L, B, S, ...] fields with real
+        # pos/start; models/deepseek.py), (b) their own engine_pool /
+        # engine_insert adapter when the cache has nested pools or property
+        # pos (rwkv recurrent state, yuan localized-filter hiddens, mllama
+        # cross-attention; the generic path would silently corrupt them),
+        # or (c), paged, through a page pool of their own kind when they
+        # offer `init_paged_cache` (MLA's latent pages,
+        # kvpaged.PagedLatentCache): the page table books, shares, parks
+        # and restores such a page as it does a KV page, and the kind is
+        # chosen here, once, as `kvstate.KIND` is.
         fam = model.family
+        self._latent_pool = (getattr(fam, "init_paged_cache", None)
+                             if paged else None)
+        self._latent = self._latent_pool is not None
+        if self._latent:
+            kind = f"latent pages ({model.config.model_type})"
+            for asked, what in ((quantize_kv, "quantize_kv"),
+                                (speculative, "speculative serving"),
+                                (adapters is not None, "adapter serving")):
+                if asked:
+                    raise NotImplementedError(
+                        f"{what} is not wired for {kind} yet (ROADMAP R1)")
         self._family_cache = None
         self._family_pool = getattr(fam, "engine_pool", None)
         self._family_insert = getattr(fam, "engine_insert", None)
@@ -389,7 +407,8 @@ class InferenceEngine:
                 f"{model.config.model_type}: engine_pool and engine_insert "
                 "must be defined together"
             )
-        if hasattr(fam, "init_cache") and not self._state_rows:
+        if (hasattr(fam, "init_cache") and not self._state_rows
+                and not self._latent):
             custom = (self._family_pool is not None
                       and self._family_insert is not None)
             if not custom and not getattr(fam, "SERVABLE_CACHE", False):
@@ -491,6 +510,10 @@ class InferenceEngine:
         # model that keeps keys): a decode step moves twice that a live row
         self.state_row_bytes = (
             kvstate.row_nbytes(self.cache) if self._state_rows else 0)
+        # bytes of one token's latents over all layers (0 for a model that
+        # keeps keys and values): what a decode step must read a live token
+        self.latent_token_bytes = (
+            fam.latent_token_nbytes(self.config) if self._latent else 0)
         self.cur = jnp.zeros((n_slots,), jnp.int32)  # last token per slot
         self.active = np.zeros((n_slots,), bool)  # host-side mask
         # per-slot sampling params (host mirrors, shipped traced each step)
@@ -571,9 +594,8 @@ class InferenceEngine:
         # expert-load span arguments and gauges (docs/observability.md)
         # and each request's record of its choices (Request.expert_ids).
         # A family forward says that it reports its routing by taking
-        # moe_routing= (models/llama.forward does; deepseek.forward does
-        # not yet, and docs/observability.md says so). Dense models, and
-        # forwards that do not report, pay nothing.
+        # moe_routing= (models/llama.forward and deepseek.forward do).
+        # Dense models, and forwards that do not report, pay nothing.
         self._moe_routing = False
         if getattr(self.config, "is_moe", False):
             import inspect
@@ -592,6 +614,9 @@ class InferenceEngine:
         # a recurrent-state model: chunks of the prefill form that the
         # admission being activated ran (its `prefill` span's argument)
         self._admit_state_chunks = 0
+        # a latent-page model: tokens whose K and V the admission's
+        # prefill up-projected from latents (its `prefill` span's argument)
+        self._admit_upprojected = 0
         self._decode = self._with_mesh(jax.jit(
             _named("engine_decode", self._decode_impl, fwd),
             donate_argnames=("cache", "seen"),
@@ -609,15 +634,22 @@ class InferenceEngine:
         self._insert = self._with_mesh(jax.jit(
             self._insert_impl, donate_argnames=("cache",)
         ))
-        self._paged_prefill = self._with_mesh(jax.jit(
-            _named("engine_paged_prefill", self._paged_prefill_impl, fwd),
-            donate_argnames=("k", "v", "ks", "vs"),
-        ) if not self._state_rows else jax.jit(
-            _named("engine_paged_prefill", self._state_prefill_impl, fwd),
-            donate_argnames=("S", "z"),
-        ))
+        if self._state_rows:
+            paged_prefill = jax.jit(
+                _named("engine_paged_prefill", self._state_prefill_impl, fwd),
+                donate_argnames=("S", "z"))
+        elif self._latent:
+            paged_prefill = jax.jit(
+                _named("engine_paged_prefill", self._latent_prefill_impl, fwd),
+                donate_argnames=("lat",))
+        else:
+            paged_prefill = jax.jit(
+                _named("engine_paged_prefill", self._paged_prefill_impl, fwd),
+                donate_argnames=("k", "v", "ks", "vs"))
+        self._paged_prefill = self._with_mesh(paged_prefill)
         self._copy_page = self._with_mesh(jax.jit(
-            self._copy_page_impl, donate_argnames=("cache",)
+            kvpaged.copy_latent_page if self._latent
+            else self._copy_page_impl, donate_argnames=("cache",)
         ))
         # --- in-engine speculative decoding (reference serves it through
         # ipex_llm_worker.py:72-99; SURVEY §7 names "continuous batching +
@@ -801,6 +833,10 @@ class InferenceEngine:
             self._swap_in = self._with_mesh(jax.jit(
                 kvstate.swap_in_rows, donate_argnames=("state",)
             ))
+        elif self._latent:
+            self._swap_in = self._with_mesh(jax.jit(
+                kvpaged.swap_in_latent, donate_argnames=("cache",)
+            ))
         elif paged:
             self._swap_in = self._with_mesh(jax.jit(
                 kvpaged.swap_in_pages, donate_argnames=("cache",)
@@ -873,6 +909,9 @@ class InferenceEngine:
             return dataclasses.replace(
                 cache, pos=jnp.zeros((self.n_slots,), jnp.int32)
             )
+        if self._latent and not force_dense:
+            return self._latent_pool(cfg, self.n_pages, self.page_size,
+                                     self.n_slots, self.max_pages_per_row)
         if self._state_rows:
             cache = kvstate.init_state(
                 cfg.num_hidden_layers, self.n_slots,
@@ -1013,6 +1052,19 @@ class InferenceEngine:
         logits, cache = forward(self.config, params, tokens, cache,
                                 mode="prefill", **kw)
         return logits[0, last_idx], cache.S, cache.z
+
+    def _latent_prefill_impl(self, forward, params, lat, row_bt, pos0,
+                             tokens, last_idx, lora=None):
+        """`_paged_prefill_impl` for a model that keeps latent pages: ONE
+        slot's tail prefill through the family forward's expanded form,
+        its latents written straight into the shared pool (donated)."""
+        cache = kvpaged.PagedLatentCache(
+            lat=lat, block_tables=row_bt, pos=pos0,
+            start=jnp.zeros((1,), jnp.int32))
+        logits, cache, experts = self._forward_routing(
+            forward, params, tokens, cache, "prefill", {})
+        return (logits[0, last_idx], cache.lat,
+                None if experts is None else experts[:, 0])
 
     def _forward_routing(self, forward, params, tokens, cache, mode, kw):
         """`forward`, and for a sparse-expert model every position's top-k
@@ -1489,6 +1541,14 @@ class InferenceEngine:
                 lora=self._prefill_lora(st.req))
             self.cache = dataclasses.replace(self.cache, S=S, z=z)
             st.state_chunks += kvstate.prefill_chunks(bucket)
+        elif self._latent:
+            logits_last, lat, moe = self._paged_prefill(
+                self.model.params, self.cache.lat, *where)
+            if moe is not None:
+                st.moe.append((moe, n))
+            self.cache = dataclasses.replace(self.cache, lat=lat)
+            # the expanded form up-projects the row's whole capacity
+            st.upprojected += self.cache.max_len
         else:
             logits_last, k, v, ks, vs, moe = self._paged_prefill(
                 self.model.params, self.cache.k, self.cache.v,
@@ -1516,6 +1576,7 @@ class InferenceEngine:
                                    ns=st.req.adapter)
         self._admit_moe, self._admit_moe_start = st.moe, st.start
         self._admit_state_chunks = st.state_chunks
+        self._admit_upprojected = st.upprojected
         if self.speculative:
             # prefix-cache hits only save TARGET prefill; the draft
             # always prefills its full context into the dense draft pool
@@ -1634,6 +1695,7 @@ class InferenceEngine:
             keep = self.pages.kv_pages(slot)
             n_keep = len(keep)
             blob = (kvstate.swap_out_rows if self._state_rows
+                    else kvpaged.swap_out_latent if self._latent
                     else kvpaged.swap_out_pages)(self.cache, keep)
             start = 0
         else:
@@ -1682,6 +1744,7 @@ class InferenceEngine:
                 return False
             b = entry.blob
             parked = ((b.S, b.z) if self._state_rows
+                      else (b.lat,) if self._latent
                       else (b.k, b.v, b.k_scale, b.v_scale))
             self.cache = self._swap_in(
                 self.cache, *parked, jnp.asarray(fresh, jnp.int32))
@@ -2225,6 +2288,8 @@ class InferenceEngine:
             self._admit_moe = []
         if self._state_rows:
             moe_args["state_chunks"] = self._admit_state_chunks
+        if self._latent:
+            moe_args["latent_tokens_upprojected"] = self._admit_upprojected
         if req.admit_ts is not None:
             self.prefill_seconds.observe(now - req.admit_ts)
             if tr is not None and tr.enabled:
@@ -2763,6 +2828,12 @@ class InferenceEngine:
             elif self.paged:  # its pos still holds the step's own
                 pages["live_pages"], pages["grid_pages"] = \
                     self.pages.grid_pages(self.active)
+                if self._latent:  # slots 0 .. pos of every live row
+                    live = sum(self.pages.pos[i] + 1
+                               for i in np.nonzero(self.active)[0])
+                    pages["latent_live_tokens"] = int(live)
+                    pages["latent_bytes_read"] = int(
+                        live * self.latent_token_bytes)
             tr.complete("decode_step", t0, t1 - t0, tid=0, cat="engine",
                         occupancy=busy, slots=self.n_slots,
                         queue_depth=self._queue.qsize(), **pages,

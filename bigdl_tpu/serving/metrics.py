@@ -428,6 +428,22 @@ class Metrics:
                     f"bigdl_tpu_state_pool_bytes "
                     f"{self.engine.state_row_bytes * self.engine.n_slots}",
                 ]
+            if getattr(self.engine, "_latent", False):
+                # a model whose pages hold latents (kvpaged.
+                # PagedLatentCache): pages in use, and what they hold
+                pool = self.engine.pages.pool
+                lines += [
+                    "# HELP bigdl_tpu_latent_pages_in_use latent pages "
+                    "held by slots or the prefix cache (of n_pages - 1)",
+                    "# TYPE bigdl_tpu_latent_pages_in_use gauge",
+                    f"bigdl_tpu_latent_pages_in_use "
+                    f"{pool.n_pages - 1 - pool.n_free}",
+                    "# HELP bigdl_tpu_latent_token_bytes bytes of one "
+                    "token's latents over all layers",
+                    "# TYPE bigdl_tpu_latent_token_bytes gauge",
+                    f"bigdl_tpu_latent_token_bytes "
+                    f"{self.engine.latent_token_bytes}",
+                ]
             if getattr(self.engine, "_moe_routing", False):
                 # sparse-expert models: the newest decode step's expert
                 # load (the `moe_*` arguments of its `decode_step` span)
@@ -575,6 +591,11 @@ _STATE_FAMILIES = (
     "bigdl_tpu_state_pool_bytes",
 )
 
+_LATENT_FAMILIES = (
+    "bigdl_tpu_latent_pages_in_use",
+    "bigdl_tpu_latent_token_bytes",
+)
+
 _MOE_FAMILIES = (
     "bigdl_tpu_moe_expert_load_imbalance",
     "bigdl_tpu_moe_experts_hit_share",
@@ -606,6 +627,8 @@ def expected_families(engine=None) -> list:
             names += _PAGED_FAMILIES
         if getattr(engine, "_state_rows", False):
             names += _STATE_FAMILIES
+        if getattr(engine, "_latent", False):
+            names += _LATENT_FAMILIES
         if getattr(engine, "_moe_routing", False):
             names += _MOE_FAMILIES
         if getattr(engine, "adapters", None) is not None:

@@ -291,7 +291,27 @@ def test_engine_serves_mla_family():
     for r, ref in zip(reqs, refs):
         assert r.out_tokens == ref, (r.out_tokens, ref)
 
-    # paged mode is a KV-pool concept — family caches refuse clearly
+    # paged, the family serves from latent pages (PR 34) and says the same
+    paged = InferenceEngine(m, n_slots=2, max_len=64, paged=True,
+                            page_size=16, n_pages=9)
+    reqs = [paged.submit(p, max_new_tokens=6) for p in prompts]
+    paged.run_until_idle()
+    for r, ref in zip(reqs, refs):
+        assert r.out_tokens == ref, (r.out_tokens, ref)
+    assert paged.page_leaks() == 0
+
+
+def test_paged_refuses_a_family_cache_that_is_not_a_pool():
+    """Paged mode is a page-pool concept: a family whose cache is neither
+    KV pages, a state row nor latent pages refuses clearly."""
+    from bigdl_tpu.models import rwkv
+    from bigdl_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig.from_hf_config(dict(
+        model_type="rwkv", vocab_size=96, hidden_size=64,
+        num_hidden_layers=2, attention_hidden_size=64,
+        intermediate_size=128))
+    m = TpuModel(cfg, rwkv.init_params(cfg, jax.random.PRNGKey(0)), "bf16")
     with pytest.raises(NotImplementedError, match="paged"):
         InferenceEngine(m, n_slots=2, max_len=64, paged=True)
 

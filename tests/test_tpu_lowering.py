@@ -127,3 +127,104 @@ def test_moe_qmatmul_compiles_at_mixtrals_shapes(one_chip, K, O, gated):
         _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
         *fields,
     ).compile()
+
+
+# ---- GLM-4.7-Flash: latent pages, 64 experts (ISSUE 34) ---------------------
+
+def test_latent_decode_kernel_compiles_at_the_cells_shapes(one_chip):
+    """32 slots x 80 pages of 64 tokens, 20 heads on rows of 640 lanes, a
+    pool of 2561 pages over 20 layers: Mosaic takes the eight page blocks of
+    a grid step and the two dots over them, and XLA hands the pool over as
+    it lies (no copy of 4.2 GB in front of the call)."""
+    from bigdl_tpu.ops.pallas.paged_attention import (
+        paged_latent_decode_attention)
+
+    B, H, r, dr, page, mp, L, NP = 32, 20, 512, 64, 64, 80, 20, 2561
+
+    def f(qe, qp, lat, bt, layer, pos, start, live):
+        return paged_latent_decode_attention(
+            qe, qp, lat, bt, layer, pos, start, scale=0.0625, live=live,
+            interpret=False)
+
+    c = jax.jit(f).lower(
+        _sds((B, H, r), jnp.bfloat16, one_chip),
+        _sds((B, H, dr), jnp.bfloat16, one_chip),
+        _sds((L, NP, page, 640), jnp.bfloat16, one_chip),
+        _sds((B, mp), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip)).compile()
+    assert "paged_latent_decode_attention" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("K,O,gated", [(2048, 1536, True),
+                                       (1536, 2048, False)])
+def test_moe_qmatmul_compiles_at_glms_shapes(one_chip, K, O, gated):
+    """64 experts of width 1536 under a 32-slot step's 128 assignments."""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    L, E, N, k = 2, 64, 32, 4
+    bm = mq.moe_block_m(N, 2048)
+    n_tiles = mq.moe_n_tiles(N, k, E, bm)
+
+    def f(x, te, n_used, layer, *fields):
+        ws = [QTensor(qtype="sym_int4", data=fields[2 * i],
+                      scales=fields[2 * i + 1]) for i in range(len(fields) // 2)]
+        return mq.moe_qmatmul(x, ws if gated else ws[0], te, n_used, bm,
+                              act="silu" if gated else None, layer=layer,
+                              interpret=False)
+
+    fields = []
+    for _ in range(2 if gated else 1):
+        fields += [_sds((L, E, O, K // 2), jnp.uint8, one_chip),
+                   _sds((E, O, K // 32), jnp.float16, one_chip)]
+    jax.jit(f).lower(
+        _sds((n_tiles * bm, K), jnp.bfloat16, one_chip),
+        _sds((n_tiles,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        *fields,
+    ).compile()
+
+
+def test_glms_decode_step_slices_no_packed_stack(one_chip, monkeypatch):
+    """`deepseek.forward`'s decode step at published widths (one dense and
+    two expert layers, 32 slots) compiled for the chip: no expert stack
+    [64, O, C] and no packed projection of a layer is sliced out of its
+    stack in the optimized HLO: the kernels read them by layer index."""
+    import json
+    import os
+    import re
+
+    from bench import weights
+    from bigdl_tpu.models import deepseek
+    from bigdl_tpu.models.config import ModelConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs",
+                           "glm-4.7-flash-int4.json")) as f:
+        hf = dict(json.load(f)["published"], num_hidden_layers=3)
+    cfg = ModelConfig.from_hf_config(hf)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the target
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(weights.param_shapes(cfg, "sym_int4"))
+    cache = on_chip(jax.eval_shape(
+        lambda: deepseek.init_paged_cache(cfg, 161, 64, 32, 80)))
+
+    def step(p, toks, c):
+        return deepseek.forward(cfg, p, toks, c, mode="decode",
+                                moe_routing=True)
+
+    text = jax.jit(step, donate_argnums=(2,)).lower(
+        params, _sds((32, 1), jnp.int32, one_chip), cache).compile().as_text()
+    assert "paged_latent_decode_attention" in text and "moe_qmatmul" in text
+    # uint8 results of a dynamic-slice: packed codes cut out of a stack.
+    # One is left, by `ops/linear`'s shape guard: w_dkv's 576 rows are not
+    # whole lane tiles, so it takes the XLA dequant, which fuses its slice
+    sliced = set(re.findall(r"= (u8\[[\d,]+\])\S* dynamic-slice\(", text))
+    big = {s for s in sliced
+           if sum(int(d) >= 256 for d in s[3:-1].split(",")) >= 2}
+    assert big <= {"u8[1,576,1024]"}, big
