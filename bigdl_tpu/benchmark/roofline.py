@@ -300,10 +300,11 @@ def decode_attention_cost(pos, page: int, Hq: int, Hkv: int, D: int,
     live KV. `pos` is the per-row written position (int or list of
     ints — the engine's cache.pos for the active slots).
 
-    Paged (ops/pallas/paged_attention): grid (B, max_pages), one
-    (page, Hkv, D) k and v tile per live page — on a step outside a
-    row's live range the index maps name the block already held and the
-    body is skipped, so the traffic model counts live pages only.
+    Paged (ops/pallas/paged_attention): grid (B,), and per row a loop
+    over groups of its LIVE pages, one DMA of a (page, Hkv, D) k and v
+    tile per live page through the block table — a page outside a row's
+    live range is never fetched and an idle row runs no loop at all, so
+    the traffic model counts live pages only; q goes in as the bf16 it is.
     Dense: each row streams its [max_len] cache rows (the dense decode
     path has no page table to skip dead slots by block). fp8 KV halves
     code bytes and adds the f32 per-(slot, head) scale planes."""
@@ -319,7 +320,7 @@ def decode_attention_cost(pos, page: int, Hq: int, Hkv: int, D: int,
         pages = 0
     slot_bytes = Hkv * D * kv_bpe + (Hkv * 4 if quantize_kv else 0)
     kv_bytes = 2 * slots * slot_bytes  # k AND v
-    q_bytes = len(rows) * Hq * D * 4  # the kernel lifts q to f32
+    q_bytes = len(rows) * Hq * D * _X_BPE
     o_bytes = len(rows) * Hq * D * _OUT_BPE
     flops = 4 * sum(max(p, 1) for p in rows) * Hq * D
     total = layers * (kv_bytes + q_bytes + o_bytes)

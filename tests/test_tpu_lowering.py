@@ -129,6 +129,63 @@ def test_moe_qmatmul_compiles_at_mixtrals_shapes(one_chip, K, O, gated):
     ).compile()
 
 
+# ---- paged decode attention over groups of live pages (ISSUE 35) -----------
+
+# (slots, KV heads, query heads a KV head, head size, layers, pages in the
+# pool): the three configurations that serve from KV pages (pages of 64
+# tokens, 32 a row), Mistral's with an fp8 pool, and shapes whose [Hkv, D]
+# tiles XLA pads in HBM, which no DMA of the kernel's own can slice: those
+# pages come through Pallas's pipeline, one a grid step, to the same body
+_PAGED = {
+    "mistral-7b": (32, 8, 4, 128, 32, 1025),
+    "qwen2-7b": (16, 4, 7, 128, 28, 1025),
+    "mixtral-8x7b": (16, 8, 4, 128, 10, 1025),
+    "mistral-7b-fp8": (32, 8, 4, 128, 32, 1025),
+    "piped-head-64": (8, 8, 4, 64, 16, 257),  # Llama-3.2-1B's attention
+    "piped-one-kv-head": (8, 1, 8, 256, 18, 257),  # Gemma-2B's (MQA)
+    "piped-six-kv-heads": (8, 6, 4, 128, 2, 257),
+    "piped-head-64-fp8": (8, 2, 7, 64, 24, 257),  # Qwen2-0.5B's, fp8 pool
+}
+
+
+@pytest.mark.parametrize("name", list(_PAGED))
+def test_paged_decode_kernel_compiles_at_the_cells_shapes(one_chip, name):
+    """Mosaic takes the in-kernel loop over a row's live groups: a DMA per
+    live page out of the pool in HBM, the pages joined into one operand
+    without a relayout ([pages, page, Hkv, D] read as [columns, D]), the two
+    dots over all KV heads at once and the masked softmax between them; and
+    XLA hands the pool over as it lies (no copy in front of the call). The
+    fp8 pool brings its scales by group and column. The `piped` shapes
+    compile the other way pages reach the same body."""
+    from bigdl_tpu.ops.pallas import paged_attention as pa
+
+    B, Hkv, G, D, L, NP = _PAGED[name]
+    page, mp = 64, 32
+    fp8 = name.endswith("fp8")
+    assert pa.pool_tiles_whole(Hkv, D, 1 if fp8 else 2) \
+        != name.startswith("piped")
+    kv = _sds((L, NP, page, Hkv, D),
+              jnp.float8_e5m2 if fp8 else jnp.bfloat16, one_chip)
+    scales = [_sds((L, NP, page, Hkv), jnp.float32, one_chip)] * 2 if fp8 \
+        else []
+
+    def f(q, k, v, bt, layer, pos, start, win, live, *scales):
+        return pa.paged_decode_attention(
+            q, k, v, bt, layer, pos, start, *scales, window=win, live=live,
+            softcap=30.0 if fp8 else None, interpret=False)
+
+    c = jax.jit(f).lower(
+        _sds((B, Hkv * G, D), jnp.bfloat16, one_chip), kv, kv,
+        _sds((B, mp), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((B,), jnp.bool_, one_chip),
+        *scales).compile()
+    assert "paged_decode_attention" in c.as_text()
+    if not name.startswith("piped"):  # a pool of padded tiles is re-laid in
+        # front of a call that stands alone, on the parent's kernel as here
+        assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 # ---- GLM-4.7-Flash: latent pages, 64 experts (ISSUE 34) ---------------------
 
 def test_latent_decode_kernel_compiles_at_the_cells_shapes(one_chip):
