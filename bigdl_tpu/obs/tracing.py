@@ -36,8 +36,10 @@ track at ``tid = rid`` with strictly sequential spans — ``queued`` →
 windows — so nesting is trivially monotonic per track (the golden test
 asserts it). A span's phases are child spans on its own track that
 partition it (``complete_parts``): ``prefill`` into ``prefill.dispatch``
-| ``first_token.sample`` | ``first_token.arm``, ``decode_step`` into
-``decode.dispatch`` | ``decode.fetch``.
+| ``first_token.sample`` | ``first_token.arm``; ``engine.step`` into
+``step.reap`` | ``step.admit`` | ``step.pages`` | ``decode_step`` |
+``step.emit``, and ``decode_step`` into ``decode.dispatch`` (``decode.args``
+| ``decode.call``) | ``decode.fetch`` (``decode.wait`` | ``decode.read``).
 """
 
 from __future__ import annotations
@@ -124,20 +126,27 @@ class TraceRecorder:
         abut, nest in the parent (`validate_nesting`) and their
         durations sum to its duration exactly; rounding each child's
         start and length on its own would let the last one stick out a
-        microsecond."""
+        microsecond. A part `(name, args, cuts, parts)` is partitioned
+        in turn, between the edges it was given."""
         if not self.enabled:
             return
         lo = int(ts * 1e6)
-        hi = lo + max(int(dur * 1e6), 0)
+        self._parts(lo, lo + max(int(dur * 1e6), 0), cuts, parts,
+                    int(tid), cat)
+
+    def _parts(self, lo: int, hi: int, cuts, parts, tid: int,
+               cat: str) -> None:
         edges = [lo]
         for c in cuts:
             edges.append(min(max(int(c * 1e6), edges[-1]), hi))
         edges.append(hi)
-        for (name, args), a, b in zip(parts, edges, edges[1:]):
+        for part, a, b in zip(parts, edges, edges[1:]):
             self._append({
-                "name": name, "ph": "X", "cat": cat, "pid": self._pid,
-                "tid": int(tid), "ts": a, "dur": b - a, "args": args,
+                "name": part[0], "ph": "X", "cat": cat, "pid": self._pid,
+                "tid": tid, "ts": a, "dur": b - a, "args": part[1],
             })
+            if len(part) > 2:
+                self._parts(a, b, part[2], part[3], tid, cat)
 
     def instant(self, name: str, ts: Optional[float] = None, tid: int = 0,
                 cat: str = "engine", **args: Any) -> None:

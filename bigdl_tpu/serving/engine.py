@@ -25,6 +25,7 @@ directly (serving/api_server.py).
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import itertools
@@ -54,6 +55,10 @@ RETRACE_PHASES = ("prefill.dispatch", "first_token.sample",
 
 #: decode tokens coalesced into one `decode` span of a request's track
 TRACE_DECODE_EVERY = 8
+
+#: what a phase runs under while tracing is off: one shared context that
+#: does nothing, so an untraced step builds no annotation
+_NO_PHASE = contextlib.nullcontext()
 
 
 def _named(name: str, fn, *bound):
@@ -197,6 +202,31 @@ class _Slot:
     # span and that window's start timestamp (obs/tracing.py)
     t_win: float = 0.0
     n_win: int = 0
+
+
+class _StepTrace:
+    """One `step()` while tracing is on: the parts of its `engine.step`
+    span closed so far, each `(name, args)` or, partitioned in turn,
+    `(name, args, cuts, parts)` with the instant it ended in `cuts`
+    (`TraceRecorder.complete_parts` takes them as they stand), and the
+    decode step's number, which its spans and its annotations carry."""
+
+    __slots__ = ("t_in", "cuts", "parts", "chunks", "admitted", "seq",
+                 "t_args", "t_wait", "arrays")
+
+    def __init__(self, t_in: float, chunks: int):
+        self.t_in = t_in
+        self.cuts: list = []
+        self.parts: list = []
+        self.chunks = chunks  # `prefill_chunks` at entry
+        self.admitted = 0  # admissions completed in this step
+        self.seq: Optional[int] = None  # set once the step decodes
+        self.t_args = self.t_wait = t_in  # inner edges of `decode_step`
+        self.arrays = 0  # arrays `decode.read` fetched
+
+    def close(self, t: float, *part) -> None:
+        self.cuts.append(t)
+        self.parts.append(part)
 
 
 @dataclasses.dataclass
@@ -823,6 +853,11 @@ class InferenceEngine:
         self._rt_programs = 0
         self._chunk_retrace_s = 0.0  # paid by earlier chunks of the one
         # chunked prefill in flight; its `prefill.dispatch` span reports it
+        self._step_trace: Optional[_StepTrace] = None  # the step under
+        # way, while tracing: None is what every traced-only site checks
+        self._step_seq = 0  # decode steps traced so far
+        self._annotation = None  # jax.profiler.TraceAnnotation, imported
+        # by the first traced step
         # swap-in programs (swap-OUT is a plain device_get, no jit). The
         # donated cache makes the restore an in-place scatter. Family
         # caches (nested pools / property pos) have no row-swap story:
@@ -1523,6 +1558,14 @@ class InferenceEngine:
         slot's own row. The final chunk (of a monolithic prefill, the
         only one) installs the real block table, registers radix nodes,
         and activates the slot (first token emits — TTFT closes here)."""
+        with self._phase("prefill.dispatch", st.req.rid):
+            logits_last = self._dispatch_chunk(st)
+        if logits_last is not None:
+            self._activate(st.slot, st.req, logits_last)
+
+    def _dispatch_chunk(self, st: _PrefillState):
+        """The chunk's host work up to its activation; returns the last
+        chunk's logits, None after an earlier one."""
         prompt = st.req.prompt
         rem = len(prompt) - st.written
         n = min(st.chunk, rem)
@@ -1563,7 +1606,7 @@ class InferenceEngine:
         st.written += n
         if not last:
             self._chunk_retrace_s += self._retrace_mark("prefill.dispatch")
-            return
+            return None
         slot = st.slot
         self._prefilling = None
         self.pages.install(slot, st.row, len(prompt))
@@ -1582,7 +1625,7 @@ class InferenceEngine:
             # always prefills its full context into the dense draft pool
             self._admit_draft(slot, prompt,
                               self.max_len - st.req.max_new_tokens)
-        self._activate(slot, st.req, logits_last)
+        return logits_last
 
     def _admit_draft(self, slot: int, prompt: list[int], limit: int) -> None:
         """Left-pad-prefill the speculative draft pool's row for a newly
@@ -2237,6 +2280,8 @@ class InferenceEngine:
         t_enter = t_sampled = None
         if tr is not None and tr.enabled:
             t_enter = self._clock()
+        if self._step_trace is not None:
+            self._step_trace.admitted += 1
         temp, topk, topp, dosample = self._slot_sampling(req)
         penalty = (req.repetition_penalty
                    if req.repetition_penalty is not None
@@ -2248,15 +2293,16 @@ class InferenceEngine:
             row = np.zeros((self.config.vocab_size,), bool)
             ids = np.asarray(req.prompt, np.int64)
             row[ids[(ids >= 0) & (ids < row.size)]] = True
-        self.cur, self.seen, self._rng, out = self._first_token(
-            logits_last, self._rng, np.float32(temp), np.int32(topk),
-            np.float32(topp), np.bool_(dosample), np.float32(penalty),
-            row, np.int32(slot), cur=self.cur, seen=self.seen,
-        )
-        # the admission's one host sync: the prefill program has run
-        # by now
-        first, first_lp, first_top = _read_first_token(
-            out, self.logprobs_top_k)
+        with self._phase("first_token.sample", req.rid):
+            self.cur, self.seen, self._rng, out = self._first_token(
+                logits_last, self._rng, np.float32(temp), np.int32(topk),
+                np.float32(topp), np.bool_(dosample), np.float32(penalty),
+                row, np.int32(slot), cur=self.cur, seen=self.seen,
+            )
+            # the admission's one host sync: the prefill program has run
+            # by now
+            first, first_lp, first_top = _read_first_token(
+                out, self.logprobs_top_k)
         rt_sample = self._retrace_mark("first_token.sample")
         if t_enter is not None:
             t_sampled = self._clock()
@@ -2325,16 +2371,17 @@ class InferenceEngine:
         tokens[0, bucket - len(req.prompt):] = req.prompt
         pad = bucket - len(req.prompt)
         self.prefill_chunks += 1  # a monolithic prefill is one chunk
-        logits_last, pcache = self._prefill(
-            self.model.params, jnp.asarray(tokens),
-            jnp.asarray([pad], jnp.int32), bucket=bucket,
-            lora=self._prefill_lora(req),
-        )
-        self.cache = self._insert(
-            self.cache, pcache, jnp.asarray(slot), jnp.asarray(pad)
-        )
-        if self.speculative:
-            self._admit_draft(slot, req.prompt, limit)
+        with self._phase("prefill.dispatch", req.rid):
+            logits_last, pcache = self._prefill(
+                self.model.params, jnp.asarray(tokens),
+                jnp.asarray([pad], jnp.int32), bucket=bucket,
+                lora=self._prefill_lora(req),
+            )
+            self.cache = self._insert(
+                self.cache, pcache, jnp.asarray(slot), jnp.asarray(pad)
+            )
+            if self.speculative:
+                self._admit_draft(slot, req.prompt, limit)
         self._activate(slot, req, logits_last)
 
     def _admit(self) -> None:
@@ -2714,7 +2761,57 @@ class InferenceEngine:
 
     def step(self) -> bool:
         """Admit queued requests, advance every active slot one token.
-        Returns True if any work remains."""
+        Returns True if any work remains. While tracing, the step's
+        phases are stamped as they pass and recorded when it ends
+        (`_note_step`); off, this is `_step` and one check."""
+        tr = self.tracer
+        if tr is None or not tr.enabled:
+            return self._step()
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
+        st = self._step_trace = _StepTrace(self._clock(),
+                                           self.prefill_chunks)
+        ok = False
+        try:
+            more = self._step()
+            ok = True
+            return more
+        finally:
+            self._step_trace = None
+            self._note_step(tr, st, ok)
+
+    def _phase(self, name: str, rid: Optional[int] = None):
+        """What a phase of the step runs under. While tracing, a
+        `TraceAnnotation` of the span's name with the decode step's `seq`
+        (an admission's: its `rid`): an event of an open profile's host
+        plane, a flag check when none is open. Off, nothing is built."""
+        st = self._step_trace
+        if st is None:
+            return _NO_PHASE
+        if rid is None:
+            return self._annotation(name, seq=st.seq)
+        return self._annotation(name, rid=rid)
+
+    def _note_step(self, tr, st: _StepTrace, ok: bool) -> None:
+        """The engine track's `engine.step` span and the parts that
+        partition it, for a step that admitted, advanced a prefill chunk
+        or decoded (the idle loop's polls record nothing). A step that
+        raised keeps the parts it had closed."""
+        if st.seq is None and self.prefill_chunks == st.chunks:
+            return
+        cuts = st.cuts  # a step that raised: the part left open is bare
+        end = cuts[-1] if ok else self._clock()
+        tr.complete("engine.step", st.t_in, end - st.t_in, tid=0,
+                    cat="engine", seq=st.seq, admitted=st.admitted,
+                    occupancy=int(self.active.sum()))
+        if st.parts:
+            tr.complete_parts(st.t_in, cuts[-1] - st.t_in, cuts[:-1],
+                              st.parts, tid=0, cat="engine")
+
+    def _step(self) -> bool:
+        st = self._step_trace  # None unless tracing
         f = self._faults.fire("slow_step")
         if f is not None:  # injected device stall (serving/faults.py)
             time.sleep(float(f.get("seconds", 0.05)))
@@ -2723,8 +2820,13 @@ class InferenceEngine:
         self._reap_deadlines()
         self._sweep_preempted()
         self._sweep_queue()
+        if st is not None:
+            st.close(self._clock(), "step.reap", {})
         self._admit()
         self._advance_prefill()  # at most one chunk per step
+        if st is not None:
+            st.close(self._clock(), "step.admit", {})
+        bt = None
         if self.paged:
             # reserve for the CURRENT ladder K (== draft_k when not
             # adaptive): after a downshift the round writes at most
@@ -2738,22 +2840,28 @@ class InferenceEngine:
                     self.cache, block_tables=jnp.asarray(bt)
                 )
         if not self.active.any():
+            if st is not None:
+                st.close(self._clock(), "step.pages",
+                         {"bt_uploaded": bt is not None})
             return (not self._queue.empty() or self._waiting is not None
                     or bool(self._preempted)
                     or self._prefilling is not None)
         self._rng, k = jax.random.split(self._rng)
         if self.speculative:
-            return self._step_speculative(k)
+            return self._step_speculative(k, bt is not None)
         self._retrace_mark("other")
-        t0 = self._clock()
+        t0 = self._start_decode(bt is not None)
         try:
-            nxt, lps, top, self.cache, self.seen, moe = self._decode(
-                self.model.params, self.cur, self.cache, k,
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp), jnp.asarray(self._dosample),
-                self.seen, jnp.asarray(self._penalty),
-                lora=self._gather_blora(),
-            )
+            with self._phase("decode.args"):
+                sampling, penalty = self._upload_sampling()
+                lora = self._gather_blora()
+            if st is not None:
+                st.t_args = self._clock()
+            with self._phase("decode.call"):
+                nxt, lps, top, self.cache, self.seen, moe = self._decode(
+                    self.model.params, self.cur, self.cache, k, *sampling,
+                    self.seen, penalty, lora=lora,
+                )
         except Exception:
             # the donated cache buffer is gone — rebuild before re-raising
             # (the server's guard fails the in-flight requests)
@@ -2762,51 +2870,80 @@ class InferenceEngine:
             raise
         dispatched = self._stamp_dispatched()
         self.cur = nxt
-        toks = np.asarray(nxt)
-        lps_h = self._inject_nan(np.asarray(lps))
-        tops_h = None
-        if top is not None:
-            tops_h = (np.asarray(top[0]), np.asarray(top[1]))
-        experts_h = None
-        if moe is not None:
-            experts_h = np.asarray(moe)  # [L, B, k]
-            # counted only when a span or a gauge reads it (moe_load)
-            self._moe_last = (experts_h, self.active.copy())
+        with self._phase("decode.wait"):
+            toks = np.asarray(nxt)  # returns when the program has run
+        if st is not None:
+            st.t_wait = self._clock()
+        with self._phase("decode.read"):
+            lps_h = self._inject_nan(np.asarray(lps))
+            tops_h = None
+            if top is not None:
+                tops_h = (np.asarray(top[0]), np.asarray(top[1]))
+            experts_h = None
+            if moe is not None:
+                experts_h = np.asarray(moe)  # [L, B, k]
+                # counted only when a span or a gauge reads it (moe_load)
+                self._moe_last = (experts_h, self.active.copy())
+        if st is not None:
+            st.arrays = 1 + 2 * (top is not None) + (moe is not None)
         # the np.asarray fetches above are the host sync: the step's
         # device work is really done here, so the duration is honest
         self._note_decode_step(t0, dispatched)
-        for i in np.nonzero(self.active)[0]:
-            i = int(i)
-            s = self._slots[i]
-            if not np.isfinite(lps_h[i]):
-                # non-finite logits guard: quarantine the ONE poisoned
-                # slot (its sampled token/logprob are garbage) instead of
-                # letting the exception path fail_all the whole batch —
-                # per-row decode means other slots' math is untouched
-                s.req.error = (
-                    "non-finite logits in decode step; request "
-                    "quarantined (other slots unaffected)"
-                )
-                self._finish(i, "error")
-                continue
-            s.remaining -= 1
-            if self.paged:
-                self.pages.advance(i)
-            if experts_h is not None:
-                s.req.out_experts.append(experts_h[:, i])
-            alt = None
-            if tops_h is not None:
-                alt = {int(t): float(l)
-                       for t, l in zip(tops_h[0][i], tops_h[1][i])}
-            self._emit(i, int(toks[i]), float(lps_h[i]), alt)
+        with self._phase("step.emit"):
+            for i in np.nonzero(self.active)[0]:
+                i = int(i)
+                s = self._slots[i]
+                if not np.isfinite(lps_h[i]):
+                    # non-finite logits guard: quarantine the ONE
+                    # poisoned slot (its sampled token/logprob are
+                    # garbage) instead of letting the exception path
+                    # fail_all the whole batch — per-row decode means
+                    # other slots' math is untouched
+                    s.req.error = (
+                        "non-finite logits in decode step; request "
+                        "quarantined (other slots unaffected)"
+                    )
+                    self._finish(i, "error")
+                    continue
+                s.remaining -= 1
+                if self.paged:
+                    self.pages.advance(i)
+                if experts_h is not None:
+                    s.req.out_experts.append(experts_h[:, i])
+                alt = None
+                if tops_h is not None:
+                    alt = {int(t): float(l)
+                           for t, l in zip(tops_h[0][i], tops_h[1][i])}
+                self._emit(i, int(toks[i]), float(lps_h[i]), alt)
+        if st is not None:
+            st.close(self._clock(), "step.emit", {})
         return True
+
+    def _upload_sampling(self) -> tuple:
+        """The per-slot sampling vectors as the step's program takes them,
+        sent from their host mirrors: (temperature, top-k, top-p,
+        do-sample) and the repetition penalty."""
+        return (jnp.asarray(self._temp), jnp.asarray(self._topk),
+                jnp.asarray(self._topp), jnp.asarray(self._dosample)), \
+            jnp.asarray(self._penalty)
+
+    def _start_decode(self, bt_uploaded: bool) -> float:
+        """The clock at the start of the `decode_step` span; while
+        tracing, also where `step.pages` ends and the step gets the `seq`
+        its spans and annotations carry."""
+        t0 = self._clock()
+        st = self._step_trace
+        if st is not None:
+            self._step_seq += 1
+            st.seq = self._step_seq
+            st.close(t0, "step.pages", {"bt_uploaded": bt_uploaded})
+        return t0
 
     def _stamp_dispatched(self) -> Optional[tuple]:
         """While tracing, (clock, retrace seconds so far in the step) at
         the instant the step's program was enqueued: where the
         `decode.dispatch` span ends and `decode.fetch` begins."""
-        tr = self.tracer
-        if tr is not None and tr.enabled:
+        if self._step_trace is not None:
             return self._clock(), self._retrace_mark("decode_step")
         return None
 
@@ -2814,12 +2951,13 @@ class InferenceEngine:
                           dispatched: Optional[tuple] = None) -> None:
         """Per-step phase accounting: duration histogram + the engine
         track's span/occupancy counter (tid 0 — batch-level, not
-        per-request)."""
+        per-request). The span and the children that partition it close
+        here and are recorded with the step's (`_note_step`)."""
         t1 = self._clock()
         self.decode_step_seconds.observe(t1 - t0)
         rt_rest = self._retrace_mark("decode_step")
-        tr = self.tracer
-        if tr is not None and tr.enabled:
+        st = self._step_trace
+        if st is not None:
             busy = int(self.active.sum())
             pages = {}
             if self._state_rows:  # what the step read and wrote again
@@ -2834,23 +2972,25 @@ class InferenceEngine:
                     pages["latent_live_tokens"] = int(live)
                     pages["latent_bytes_read"] = int(
                         live * self.latent_token_bytes)
-            tr.complete("decode_step", t0, t1 - t0, tid=0, cat="engine",
-                        occupancy=busy, slots=self.n_slots,
-                        queue_depth=self._queue.qsize(), **pages,
-                        **self.moe_load())
-            if dispatched is not None:
-                tr.complete_parts(
-                    t0, t1 - t0, (dispatched[0],),
-                    (("decode.dispatch", {"retrace_s": dispatched[1]}),
-                     ("decode.fetch", {"retrace_s": rt_rest})),
-                    tid=0, cat="engine")
-            tr.counter("batch", ts=t1, occupancy=busy,
-                       queued=self._queue.qsize(),
-                       preempted=len(self._preempted))
+            st.close(
+                t1, "decode_step",
+                dict(seq=st.seq, occupancy=busy, slots=self.n_slots,
+                     queue_depth=self._queue.qsize(), **pages,
+                     **self.moe_load()),
+                (dispatched[0],),
+                (("decode.dispatch", {"retrace_s": dispatched[1]},
+                  (st.t_args,), (("decode.args", {}), ("decode.call", {}))),
+                 ("decode.fetch", {"retrace_s": rt_rest},
+                  (st.t_wait,), (("decode.wait", {}),
+                                 ("decode.read", {"arrays": st.arrays})))))
+            self.tracer.counter("batch", ts=t1, occupancy=busy,
+                                queued=self._queue.qsize(),
+                                preempted=len(self._preempted))
 
-    def _step_speculative(self, k) -> bool:
+    def _step_speculative(self, k, bt_uploaded: bool) -> bool:
         """Draft-K-then-verify round: each live slot emits 1..draft_k
         tokens (its accepted prefix + the target's bonus token)."""
+        st = self._step_trace
         if self._spec_exec is not None:  # pre-compiled ladder program
             fn = self._spec_exec[self._cur_k]
         else:
@@ -2863,50 +3003,60 @@ class InferenceEngine:
             # stays None — the jit path retraces per tree structure)
             kw["lora"] = self._gather_blora()
         self._retrace_mark("other")
-        t0 = self._clock()
+        t0 = self._start_decode(bt_uploaded)
         try:
-            (choice, lp_all, n_acc, cur2, self.cache, self.dcache,
-             self.seen) = fn(
-                self.model.params, self._draft_params, self.cur,
-                self.cache, self.dcache, k,
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp), jnp.asarray(self._dosample),
-                self.seen, jnp.asarray(self._penalty),
-                **kw,
-            )
+            with self._phase("decode.args"):
+                sampling, penalty = self._upload_sampling()
+            if st is not None:
+                st.t_args = self._clock()
+            with self._phase("decode.call"):
+                (choice, lp_all, n_acc, cur2, self.cache, self.dcache,
+                 self.seen) = fn(
+                    self.model.params, self._draft_params, self.cur,
+                    self.cache, self.dcache, k, *sampling,
+                    self.seen, penalty, **kw,
+                )
         except Exception:
             self.fail_all("speculative decode step failed")
             self._reset_state()
             raise
         dispatched = self._stamp_dispatched()
         self.cur = cur2
-        choice_h = np.asarray(choice)
-        lp_h = self._inject_nan(np.asarray(lp_all))
-        n_acc_h = np.asarray(n_acc)
+        with self._phase("decode.wait"):
+            choice_h = np.asarray(choice)
+        if st is not None:
+            st.t_wait = self._clock()
+            st.arrays = 2
+        with self._phase("decode.read"):
+            lp_h = self._inject_nan(np.asarray(lp_all))
+            n_acc_h = np.asarray(n_acc)
         self._note_decode_step(t0, dispatched)
         self.spec_rounds += 1
         if self.adaptive_draft:
             self._adapt_draft_k(n_acc_h[self.active])
-        for i in np.nonzero(self.active)[0]:
-            i = int(i)
-            s = self._slots[i]
-            if not np.all(np.isfinite(lp_h[i, : int(n_acc_h[i]) + 1])):
-                # same quarantine as the plain path: one poisoned row
-                # must not take the batch down
-                s.req.error = (
-                    "non-finite logits in speculative verify; request "
-                    "quarantined (other slots unaffected)"
-                )
-                self._finish(i, "error")
-                continue
-            if self.paged:  # mirror the post-rollback pool position
-                self.pages.advance(i, int(n_acc_h[i]) + 1)
-            for t in range(int(n_acc_h[i]) + 1):
-                s.remaining -= 1
-                self.spec_emitted += 1
-                self._emit(i, int(choice_h[i, t]), float(lp_h[i, t]))
-                if not self.active[i]:  # EOS or budget hit mid-round
-                    break
+        with self._phase("step.emit"):
+            for i in np.nonzero(self.active)[0]:
+                i = int(i)
+                s = self._slots[i]
+                if not np.all(np.isfinite(lp_h[i, : int(n_acc_h[i]) + 1])):
+                    # same quarantine as the plain path: one poisoned row
+                    # must not take the batch down
+                    s.req.error = (
+                        "non-finite logits in speculative verify; request "
+                        "quarantined (other slots unaffected)"
+                    )
+                    self._finish(i, "error")
+                    continue
+                if self.paged:  # mirror the post-rollback pool position
+                    self.pages.advance(i, int(n_acc_h[i]) + 1)
+                for t in range(int(n_acc_h[i]) + 1):
+                    s.remaining -= 1
+                    self.spec_emitted += 1
+                    self._emit(i, int(choice_h[i, t]), float(lp_h[i, t]))
+                    if not self.active[i]:  # EOS or budget hit mid-round
+                        break
+        if st is not None:
+            st.close(self._clock(), "step.emit", {})
         return True
 
     def _adapt_draft_k(self, n_acc: np.ndarray) -> None:

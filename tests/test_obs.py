@@ -13,6 +13,7 @@ Acceptance invariants:
   and vice versa (drift check, both directions).
 """
 
+import contextlib
 import json
 import time
 
@@ -108,8 +109,7 @@ def test_trace_export_golden(model, tmp_path, monkeypatch):
     # tid 0 is RESERVED for the engine track: rids start at 1, so no
     # request's lifecycle spans can interleave with decode_step spans
     assert min(r.rid for r in reqs) >= 1
-    assert all(e["name"] in ("decode_step", "decode.dispatch",
-                             "decode.fetch", "batch")
+    assert all(e["name"] in ENGINE_TRACK
                for e in events if e["tid"] == 0 and e["ph"] != "M")
     # every request has its own track with a queued->prefill sequence
     for r in reqs:
@@ -290,6 +290,18 @@ def test_ttft_itl_under_injected_slow_step(model):
 ADMISSION_PARTS = ["prefill.dispatch", "first_token.sample",
                    "first_token.arm"]
 STEP_PARTS = ["decode.dispatch", "decode.fetch"]
+DISPATCH_PARTS = ["decode.args", "decode.call"]
+FETCH_PARTS = ["decode.wait", "decode.read"]
+#: `engine.step`'s parts, in order; a step that decoded has all five, one
+#: that admitted and left no slot active the first three
+ENGINE_STEP_PARTS = ["step.reap", "step.admit", "step.pages", "decode_step",
+                     "step.emit"]
+#: every event name of the engine track (tid 0)
+ENGINE_TRACK = set(ENGINE_STEP_PARTS + STEP_PARTS + DISPATCH_PARTS
+                   + FETCH_PARTS + ["engine.step", "batch"])
+#: the phases mirrored onto a profile's host plane, by what they carry
+STEP_ANNOTATIONS = DISPATCH_PARTS + FETCH_PARTS + ["step.emit"]
+ADMISSION_ANNOTATIONS = ["prefill.dispatch", "first_token.sample"]
 
 ENGINES = {
     "dense": {},
@@ -317,12 +329,14 @@ def _serve(eng, n=3):
     return reqs
 
 
-def _children(events, parent):
-    """The spans on `parent`'s track that lie inside it, in time order."""
+def _children(events, parent, names=None):
+    """The spans on `parent`'s track that lie inside it (those called one
+    of `names`, if given), in time order."""
     lo, hi = parent["ts"], parent["ts"] + parent["dur"]
     return sorted((e for e in events if e.get("ph") == "X"
                    and e is not parent and e["tid"] == parent["tid"]
-                   and lo <= e["ts"] and e["ts"] + e["dur"] <= hi),
+                   and lo <= e["ts"] and e["ts"] + e["dur"] <= hi
+                   and (names is None or e["name"] in names)),
                   key=lambda e: (e["ts"], e["ts"] + e["dur"]))
 
 
@@ -337,10 +351,13 @@ def _abut_and_fill(parent, kids):
 def test_phase_spans_partition_admission_and_step(model, kind):
     """Every `prefill` span holds exactly `prefill.dispatch`,
     `first_token.sample`, `first_token.arm`, every `decode_step` holds
-    `decode.dispatch` then `decode.fetch`; the children abut, sum to
-    their parent and carry the retrace seconds paid inside them. The
-    parents keep what they had (bench/ reads them) and gain the
-    admission's occupancy and queue depth."""
+    `decode.dispatch` (`decode.args`, `decode.call`) then `decode.fetch`
+    (`decode.wait`, `decode.read`), every `engine.step` holds `step.reap`,
+    `step.admit`, `step.pages` and, when it decoded, its `decode_step`
+    and `step.emit`; at each level the children abut, sum to their
+    parent to the microsecond, and carry the retrace seconds paid inside
+    them. The parents keep what they had (bench/ reads them) and gain
+    the admission's occupancy and queue depth, and the step's `seq`."""
     tr = TraceRecorder(enabled=True)
     eng = _engine(model, kind, tracer=tr)
     reqs = _serve(eng)
@@ -375,17 +392,108 @@ def test_phase_spans_partition_admission_and_step(model, kind):
     steps = [e for e in spans if e["name"] == "decode_step"]
     assert steps
     for s in steps:
-        kids = _children(events, s)
+        kids = _children(events, s, STEP_PARTS)
         assert [k["name"] for k in kids] == STEP_PARTS
         _abut_and_fill(s, kids)
-        assert set(s["args"]) == {"occupancy", "slots", "queue_depth"} | (
+        for kid, names in zip(kids, (DISPATCH_PARTS, FETCH_PARTS)):
+            grand = _children(events, kid)
+            assert [g["name"] for g in grand] == names
+            _abut_and_fill(kid, grand)
+        assert grand[1]["args"] == {"arrays": 2 if eng.speculative else 1}
+        assert set(s["args"]) == {"seq", "occupancy", "slots",
+                                  "queue_depth"} | (
             {"live_pages", "grid_pages"} if eng.paged else set())
+    # one counter of decode steps, in the order they ran
+    assert [s["args"]["seq"] for s in sorted(steps, key=lambda e: e["ts"])] \
+        == list(range(1, len(steps) + 1))
+
+    whole = [e for e in spans if e["name"] == "engine.step"]
+    for w in whole:
+        kids = _children(events, w, ENGINE_STEP_PARTS)
+        names = [k["name"] for k in kids]
+        assert set(w["args"]) == {"seq", "admitted", "occupancy"}
+        if w["args"]["seq"] is None:  # admitted or advanced a chunk, and
+            # no slot was active yet (a chunked prefill's first chunks)
+            assert names == ENGINE_STEP_PARTS[:3]
+        else:
+            assert names == ENGINE_STEP_PARTS
+            assert kids[3]["args"]["seq"] == w["args"]["seq"]
+        assert kids[2]["args"] == {"bt_uploaded": kids[2]["args"][
+            "bt_uploaded"]} and (eng.paged or not kids[2]["args"][
+                "bt_uploaded"])
+        _abut_and_fill(w, kids)
+    # every decode step lies in an `engine.step`, every admission in a
+    # `step.admit`; the idle loop's polls record nothing
+    assert sorted(w["args"]["seq"] for w in whole
+                  if w["args"]["seq"] is not None) == \
+        sorted(s["args"]["seq"] for s in steps)
+    assert sum(w["args"]["admitted"] for w in whole) == len(prefills)
+    admits = [e for e in spans if e["name"] == "step.admit"]
+    for p in prefills:
+        assert any(a["ts"] <= p["ts"] + p["dur"] <= a["ts"] + a["dur"] + 1
+                   for a in admits)
+    n = len(events)
+    assert eng.step() is False and len(tr.events()) == n
     if kind == "paged-chunked":  # `prefill.dispatch` covers every chunk,
         # and the steps that ran between them
         first = min(prefills, key=lambda e: e["ts"])
         later = [p for p in prefills if p is not first]
         assert any(p["ts"] <= s["ts"] < p["ts"] + p["dur"]
                    for p in later for s in steps)
+
+
+def test_complete_parts_partitions_a_part_in_turn():
+    """A part given as `(name, args, cuts, parts)` is cut again between
+    the edges it got: at both levels the children abut and sum to their
+    parent in whole microseconds, whatever the stamps' fractions."""
+    tr = TraceRecorder(enabled=True)
+    t = [10.0000004, 10.0010006, 10.0020009, 10.0030001, 10.0040007,
+         10.0050003]
+    tr.complete("whole", t[0], t[5] - t[0])
+    tr.complete_parts(t[0], t[5] - t[0], (t[1], t[4]), (
+        ("a", {}),
+        ("b", {"k": 1}, (t[2], t[3]), (("b1", {}), ("b2", {}), ("b3", {}))),
+        ("c", {})))
+    events = tr.events()
+    assert validate_nesting(events) == []
+    by = {e["name"]: e for e in events}
+    _abut_and_fill(by["whole"], [by["a"], by["b"], by["c"]])
+    _abut_and_fill(by["b"], [by["b1"], by["b2"], by["b3"]])
+    assert by["b"]["args"] == {"k": 1}
+    # a cut outside its parent is held to the parent's edges
+    tr.clear()
+    tr.complete_parts(1.0, 0.001, (0.5,), (
+        ("x", {}, (2.0,), (("x1", {}), ("x2", {}))), ("y", {})))
+    by = {e["name"]: e for e in tr.events()}
+    assert by["x"]["dur"] == by["x1"]["dur"] == by["x2"]["dur"] == 0
+    assert by["y"]["dur"] == 1000
+
+
+def test_a_step_that_raises_keeps_the_parts_it_closed(model):
+    """The decode call fails: `engine.step` is recorded to the instant
+    the step gave up, with the three parts that had closed before it and
+    no `decode_step`; nothing is left over for the next step."""
+    tr = TraceRecorder(enabled=True)
+    eng = _engine(model, "paged", tracer=tr)
+    eng.submit(list(range(1, 21)), max_new_tokens=5)
+
+    def boom(*a, **kw):
+        raise RuntimeError("device said no")
+
+    eng._decode = boom
+    with pytest.raises(RuntimeError, match="device said no"):
+        eng.step()
+    eng.close()
+    events = tr.events()
+    assert validate_nesting(events) == []
+    whole = [e for e in events if e["name"] == "engine.step"]
+    assert len(whole) == 1 and whole[0]["args"]["admitted"] == 1
+    kids = _children(events, whole[0], ENGINE_STEP_PARTS)
+    assert [k["name"] for k in kids] == ENGINE_STEP_PARTS[:3]
+    assert kids[0]["ts"] == whole[0]["ts"]
+    assert kids[-1]["ts"] + kids[-1]["dur"] <= \
+        whole[0]["ts"] + whole[0]["dur"]
+    assert eng._step_trace is None
 
 
 @pytest.mark.parametrize("kind", ["paged", "paged-chunked"])
@@ -427,28 +535,89 @@ class _CountingClock:
         return time.time()
 
 
+def _count_annotations(monkeypatch):
+    """Stand in for `jax.profiler.TraceAnnotation`, which the engine
+    imports at its first traced step: every one built is listed."""
+    built = []
+
+    class Annotation(contextlib.nullcontext):
+        def __init__(self, name, **ids):
+            super().__init__()
+            built.append((name, ids))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    return built
+
+
 @pytest.mark.parametrize("kind", ["dense", "paged-chunked", "speculative"])
-def test_tracing_off_costs_no_clock_call(model, kind):
+def test_tracing_off_costs_no_clock_call(model, kind, monkeypatch):
     """The off-cost, counted: with no recorder and with a disabled one
-    the engine asks its clock equally often over the same requests, and
-    nothing is recorded; turned on, it asks exactly twice more per
-    admission and once more per decode step (the children's inner
-    edges; every other edge is a stamp the engine already took)."""
-    calls = {}
+    the engine asks its clock equally often over the same requests,
+    builds no annotation, and nothing is recorded; turned on, it asks
+    exactly twice more per admission (the children's inner edges), three
+    times more per call of `step()` (its entry, the ends of `step.reap`
+    and `step.admit`), once more where the step ends (`step.pages` of a
+    step that left no slot active, `step.emit` of one that decoded) and
+    three times more per decode step (the ends of `decode.args`,
+    `decode.dispatch` and `decode.wait`); every other edge is a stamp
+    the engine already took."""
+    built = _count_annotations(monkeypatch)
+    calls, stepped = {}, {}
     for name, tracer in (("none", None),
                          ("disabled", TraceRecorder(enabled=False)),
                          ("enabled", TraceRecorder(enabled=True))):
         clock = _CountingClock()
         eng = _engine(model, kind, tracer=tracer, clock=clock)
+        step, stepped[name] = eng.step, 0
+
+        def counted():
+            stepped[name] += 1
+            return step()
+
+        eng.step = counted
         _serve(eng)
         eng.close()
         calls[name] = clock.calls
         if name == "disabled":
-            assert tracer.events() == []
+            assert tracer.events() == [] and built == []
     assert calls["none"] == calls["disabled"]
+    assert stepped["none"] == stepped["disabled"] == stepped["enabled"]
     names = [e["name"] for e in tracer.events() if e.get("ph") == "X"]
     assert calls["enabled"] - calls["none"] == \
-        2 * names.count("prefill") + names.count("decode_step")
+        2 * names.count("prefill") + 4 * stepped["enabled"] \
+        + 3 * names.count("decode_step")
+    # one annotation per mirrored phase, none for a phase with no span
+    made = [n for n, _ in built]
+    for phase in STEP_ANNOTATIONS:
+        assert made.count(phase) == names.count("decode_step")
+    assert made.count("first_token.sample") == names.count("prefill")
+    assert made.count("prefill.dispatch") == eng.prefill_chunks
+    assert set(made) == set(STEP_ANNOTATIONS + ADMISSION_ANNOTATIONS)
+
+
+@pytest.mark.parametrize("kind", ["paged", "speculative"])
+def test_annotations_carry_the_spans_seq(model, kind, monkeypatch):
+    """What mirrors a phase onto a profile's host plane is named as the
+    span and carries the `seq` of its `decode_step` (an admission's
+    phases: the request's `rid`), in the order the spans were cut."""
+    built = _count_annotations(monkeypatch)
+    tr = TraceRecorder(enabled=True)
+    eng = _engine(model, kind, tracer=tr)
+    reqs = _serve(eng)
+    eng.close()
+    spans = [e for e in tr.events() if e.get("ph") == "X"]
+    for phase in STEP_ANNOTATIONS:
+        assert [ids for n, ids in built if n == phase] == [
+            {"seq": e["args"]["seq"]} for e in sorted(
+                (e for e in spans if e["name"] == "decode_step"),
+                key=lambda e: e["ts"])]
+    for phase in ADMISSION_ANNOTATIONS:
+        assert [ids for n, ids in built if n == phase] == [
+            {"rid": e["args"]["rid"]} for e in sorted(
+                (e for e in spans if e["name"] == phase),
+                key=lambda e: e["ts"] + e["dur"])]
+    assert {ids["rid"] for n, ids in built
+            if n == "first_token.sample"} == {r.rid for r in reqs}
 
 
 def test_retrace_counter_books_to_the_phase_that_paid(model):
