@@ -29,17 +29,19 @@ Design constraints (docs/observability.md):
   drive the engine and this recorder from the same fake clock, so the
   traces it exports are in simulated seconds, not wall time.
 
-Track model: ``tid`` 0 is the engine/trainer track (``decode_step``,
-``train.step`` spans, occupancy counters); each request gets its own
+Track model: ``tid`` 0 is the engine/trainer track (``engine.step``,
+``train.step`` spans, occupancy counters); ``decode_step`` spans have a
+track of their own (``DECODE_TID``); each request gets its own
 track at ``tid = rid`` with strictly sequential spans — ``queued`` →
 ``prefill`` → ``decode`` windows → ``preempted`` → more ``decode``
 windows — so nesting is trivially monotonic per track (the golden test
 asserts it). A span's phases are child spans on its own track that
 partition it (``complete_parts``): ``prefill`` into ``prefill.dispatch``
 | ``first_token.sample`` | ``first_token.arm``; ``engine.step`` into
-``step.reap`` | ``step.admit`` | ``step.pages`` | ``decode_step`` |
-``step.emit``, and ``decode_step`` into ``decode.dispatch`` (``decode.args``
-| ``decode.call``) | ``decode.fetch`` (``decode.wait`` | ``decode.read``).
+``step.reap`` | ``step.admit`` | ``step.pages`` | ``decode.dispatch``
+(``decode.args`` | ``decode.call``) of the step it enqueues |
+``decode.fetch`` (``decode.wait`` | ``decode.read``) | ``step.emit`` of
+the step it reads.
 """
 
 from __future__ import annotations
@@ -55,6 +57,11 @@ from typing import Any, Callable, Optional
 # unboundedly many rids, and the *name* table (unlike the ring) is not
 # otherwise bounded
 _MAX_NAMED_TRACKS = 4096
+
+#: the decode track: `decode_step` spans, one a step the engine read. With a
+#: step in flight a span runs from one `engine.step` into the next, so it
+#: cannot nest on the engine track. Request tracks are `tid = rid` >= 1.
+DECODE_TID = -1
 
 
 class TraceRecorder:
@@ -101,7 +108,9 @@ class TraceRecorder:
         self._named.add(tid)
         self._append({
             "name": "thread_name", "ph": "M", "pid": self._pid,
-            "tid": int(tid), "args": {"name": f"req {tid}"},
+            "tid": int(tid),
+            "args": {"name": ("decode steps" if tid == DECODE_TID
+                              else f"req {tid}")},
         })
 
     def complete(self, name: str, ts: float, dur: float, tid: int = 0,

@@ -43,6 +43,7 @@ from bigdl_tpu import kvcache, kvpaged, kvstate
 from bigdl_tpu.generate import GenerationConfig, sample_token_per_row
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.obs import retrace
+from bigdl_tpu.obs.tracing import DECODE_TID
 from bigdl_tpu.serving.faults import NULL_INJECTOR, FaultError
 from bigdl_tpu.serving.metrics import Histogram
 from bigdl_tpu.serving.pages import NeverFits, PageTable, prefill_bucket
@@ -68,6 +69,17 @@ def _named(name: str, fn, *bound):
     p = functools.partial(fn, *bound)
     p.__name__ = name
     return p
+
+
+def _bits(x):
+    """A float32 array's bits as int32: how a logprob rides in the one
+    int32 vector a program packs for the host."""
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _expert_id_dtype(n_experts: int):
+    """The small integer type a step's expert ids are kept in."""
+    return np.int8 if n_experts <= 127 else np.int16
 
 
 def _read_first_token(out, n_top: int) -> tuple:
@@ -209,10 +221,10 @@ class _StepTrace:
     span closed so far, each `(name, args)` or, partitioned in turn,
     `(name, args, cuts, parts)` with the instant it ended in `cuts`
     (`TraceRecorder.complete_parts` takes them as they stand), and the
-    decode step's number, which its spans and its annotations carry."""
+    number of the decode step it read, which its span carries."""
 
     __slots__ = ("t_in", "cuts", "parts", "chunks", "admitted", "seq",
-                 "t_args", "t_wait", "arrays")
+                 "decoded")
 
     def __init__(self, t_in: float, chunks: int):
         self.t_in = t_in
@@ -220,13 +232,29 @@ class _StepTrace:
         self.parts: list = []
         self.chunks = chunks  # `prefill_chunks` at entry
         self.admitted = 0  # admissions completed in this step
-        self.seq: Optional[int] = None  # set once the step decodes
-        self.t_args = self.t_wait = t_in  # inner edges of `decode_step`
-        self.arrays = 0  # arrays `decode.read` fetched
+        self.seq: Optional[int] = None  # of the decode step it read
+        self.decoded = False  # it dispatched or read a decode step
 
     def close(self, t: float, *part) -> None:
         self.cuts.append(t)
         self.parts.append(part)
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A decode step that was dispatched and whose tokens the host has not
+    read yet. Plain decode keeps one in flight: the device runs it (and
+    has the next one queued behind it) while the host emits the step
+    before, reaps, admits and books pages."""
+
+    out: Any  # what `_decode_impl` packs for the host, on the device
+    reqs: list  # per slot, the request its row was computed for; None for
+    # an idle row and for a slot whose LAST step, by its count of tokens,
+    # was still unread at dispatch. A slot that holds another request (or
+    # none) when the step is read finished in between: its row is dropped
+    t0: float  # where `step.pages` of the dispatching call ended
+    ahead: bool  # dispatched with its predecessor unread
+    seq: Optional[int] = None  # its number, while tracing
 
 
 @dataclasses.dataclass
@@ -856,6 +884,15 @@ class InferenceEngine:
         self._step_trace: Optional[_StepTrace] = None  # the step under
         # way, while tracing: None is what every traced-only site checks
         self._step_seq = 0  # decode steps traced so far
+        # the decode step in flight (plain decode keeps one, `_Flight`),
+        # when the newest step read returned its tokens, and what
+        # /metrics counts: steps read, by whether each was dispatched
+        # ahead of its predecessor's read, and rows computed for a slot
+        # that had finished by the time its step was read
+        self._flight: Optional[_Flight] = None
+        self._t_read = 0.0
+        self.decode_steps = [0, 0]
+        self.decode_rows_discarded = 0
         self._annotation = None  # jax.profiler.TraceAnnotation, imported
         # by the first traced step
         # swap-in programs (swap-OUT is a plain device_get, no jit). The
@@ -1111,8 +1148,8 @@ class InferenceEngine:
         logits, cache, routing = forward(
             self.config, params, tokens, cache, mode=mode, moe_routing=True,
             **kw)
-        small = jnp.int8 if self.config.num_experts <= 127 else jnp.int16
-        return logits, cache, routing.astype(small)
+        return logits, cache, routing.astype(
+            _expert_id_dtype(self.config.num_experts))
 
     def _first_token_impl(self, logits, rng, temp, topk, topp, dosample,
                           penalty, row, slot, cur, seen):
@@ -1138,13 +1175,10 @@ class InferenceEngine:
                 dosample[None])[0]
         row_lp = jax.nn.log_softmax(logits.astype(jnp.float32).reshape(-1))
 
-        def bits(x):
-            return jax.lax.bitcast_convert_type(x, jnp.int32)
-
-        out = [first[None], bits(row_lp[first])[None]]
+        out = [first[None], _bits(row_lp[first])[None]]
         if self.logprobs_top_k:  # static: an engine constant
             tv, ti = jax.lax.top_k(row_lp, self.logprobs_top_k)
-            out += [ti.astype(jnp.int32), bits(tv)]
+            out += [ti.astype(jnp.int32), _bits(tv)]
         cur = cur.at[slot].set(first)
         seen = seen.at[slot].set(row).at[slot, first].set(True)
         return cur, seen, rng, jnp.concatenate(out)
@@ -1179,13 +1213,18 @@ class InferenceEngine:
         lse = jax.scipy.special.logsumexp(step32, axis=-1)
         lp = (jnp.take_along_axis(step32, nxt[:, None], axis=-1)[:, 0]
               - lse)
-        top = None
+        seen = seen.at[jnp.arange(seen.shape[0]), nxt].set(True)
+
+        # what the host reads, one int32 row a slot (_read_step): `nxt` is
+        # the next step's input and never leaves the device
+        out = [nxt[:, None], _bits(lp)[:, None]]
         if self.logprobs_top_k:  # static: compiles only when opted in
             tv, ti = jax.lax.top_k(step32, self.logprobs_top_k)
-            top = (ti, tv - lse[:, None])
-        seen = seen.at[jnp.arange(seen.shape[0]), nxt].set(True)
-        return (nxt, lp, top, cache, seen,
-                None if experts is None else experts[:, :, 0])
+            out += [ti.astype(jnp.int32), _bits(tv - lse[:, None])]
+        if experts is not None:  # [L, B, 1, k] -> [B, L * k]
+            out.append(jnp.swapaxes(experts[:, :, 0], 0, 1).reshape(
+                nxt.shape[0], -1).astype(jnp.int32))
+        return nxt, jnp.concatenate(out, axis=1), cache, seen
 
     def _spec_decode_impl(self, forward, k_draft, params, dparams, cur, cache,
                           dcache, key, temp, topk, topp, dosample, seen,
@@ -1729,8 +1768,13 @@ class InferenceEngine:
         request (its stream sees a pause, never a sentinel). Decode after
         the matching swap-in is bit-exact: the blob preserves the cache
         bytes and the resume restores cur/seen/sampling state untouched."""
+        req = self._slots[slot].req
+        # the copy needs the pool at rest and exact positions: read the
+        # step in flight first. It may have been this request's last
+        self._drain()
         s = self._slots[slot]
-        req = s.req
+        if s.req is not req or not self.active[slot]:
+            return
         now = self._clock()
         self._flush_decode_window(slot, now)
         if self.paged:
@@ -2570,6 +2614,7 @@ class InferenceEngine:
     def _reset_state(self) -> None:
         """Rebuild the (possibly donated-away) cache after a failed decode
         so the engine can keep serving new requests."""
+        self._drop_flight()
         self.cache = self._make_pool()
         if self.speculative:
             self.dcache = self._make_pool(force_dense=True)
@@ -2610,18 +2655,20 @@ class InferenceEngine:
                 self._cancelled.pop(s.req.rid, None)
                 self._finish(i, "stop", counted=False)
 
-    def _inject_nan(self, lps: "np.ndarray") -> "np.ndarray":
+    def _inject_nan(self, lps: "np.ndarray",
+                    live: "np.ndarray") -> "np.ndarray":
         """Chaos hook shared by the plain and speculative decode paths:
         when nan_logits is armed, poison the victim rows' host-side
         logprobs as if the model had produced non-finite values for
-        them (the quarantine guard downstream must catch it)."""
+        them (the quarantine guard downstream must catch it). `live`:
+        the rows of the step being read."""
         f = self._faults.fire("nan_logits")
         if f is None:
             return lps
         lps = lps.copy()
         victims = f.get("slots")
         if victims is None:
-            act = np.nonzero(self.active)[0]
+            act = np.nonzero(live)[0]
             victims = [int(act[0])] if act.size else []
         for v in victims:
             lps[v] = np.nan
@@ -2782,16 +2829,16 @@ class InferenceEngine:
             self._step_trace = None
             self._note_step(tr, st, ok)
 
-    def _phase(self, name: str, rid: Optional[int] = None):
+    def _phase(self, name: str, rid: Optional[int] = None,
+               seq: Optional[int] = None):
         """What a phase of the step runs under. While tracing, a
-        `TraceAnnotation` of the span's name with the decode step's `seq`
+        `TraceAnnotation` of the span's name with its decode step's `seq`
         (an admission's: its `rid`): an event of an open profile's host
         plane, a flag check when none is open. Off, nothing is built."""
-        st = self._step_trace
-        if st is None:
+        if self._step_trace is None:
             return _NO_PHASE
         if rid is None:
-            return self._annotation(name, seq=st.seq)
+            return self._annotation(name, seq=seq)
         return self._annotation(name, rid=rid)
 
     def _note_step(self, tr, st: _StepTrace, ok: bool) -> None:
@@ -2799,7 +2846,7 @@ class InferenceEngine:
         partition it, for a step that admitted, advanced a prefill chunk
         or decoded (the idle loop's polls record nothing). A step that
         raised keeps the parts it had closed."""
-        if st.seq is None and self.prefill_chunks == st.chunks:
+        if not st.decoded and self.prefill_chunks == st.chunks:
             return
         cuts = st.cuts  # a step that raised: the part left open is bare
         end = cuts[-1] if ok else self._clock()
@@ -2811,10 +2858,19 @@ class InferenceEngine:
                               st.parts, tid=0, cat="engine")
 
     def _step(self) -> bool:
+        """Reap, admit, book pages; then, for plain decode, dispatch the
+        step AFTER the one in flight and only then fetch and emit the one
+        in flight, so that the device always has its next program queued
+        and everything the host does for a step runs beside the device's
+        work on the step before. One step in flight and no more: the
+        host's work a step is shorter than any device step, so a second
+        would buy nothing and cost a step of admission latency and a
+        dropped row more per finish nobody could foresee."""
         st = self._step_trace  # None unless tracing
         f = self._faults.fire("slow_step")
         if f is not None:  # injected device stall (serving/faults.py)
             time.sleep(float(f.get("seconds", 0.05)))
+        unread = self._flight
         self._reap_cancelled()
         self._reap_preempt_requests()
         self._reap_deadlines()
@@ -2826,40 +2882,160 @@ class InferenceEngine:
         self._advance_prefill()  # at most one chunk per step
         if st is not None:
             st.close(self._clock(), "step.admit", {})
-        bt = None
-        if self.paged:
-            # reserve for the CURRENT ladder K (== draft_k when not
-            # adaptive): after a downshift the round writes at most
-            # _cur_k tokens before rollback, so tighter is still safe
-            self._ensure_decode_pages(
-                self._cur_k if self.speculative else 1
-            )
-            bt = self.pages.block_table()
-            if bt is not None:
-                self.cache = dataclasses.replace(
-                    self.cache, block_tables=jnp.asarray(bt)
-                )
-        if not self.active.any():
+        if self.speculative:
+            return self._step_speculative()
+        # a call reads one step: where a preemption already drained the
+        # one in flight (`_preempt_slot`), the next call dispatches again
+        drained = unread is not None and self._flight is None
+        plan = [] if drained else self._plan_decode()
+        bt_uploaded = self._upload_block_table()
+        first = newest = self._flight
+        if first is None and not plan:
             if st is not None:
                 st.close(self._clock(), "step.pages",
-                         {"bt_uploaded": bt is not None})
-            return (not self._queue.empty() or self._waiting is not None
-                    or bool(self._preempted)
-                    or self._prefilling is not None)
-        self._rng, k = jax.random.split(self._rng)
-        if self.speculative:
-            return self._step_speculative(k, bt is not None)
+                         {"bt_uploaded": bt_uploaded})
+            return self._more_work()
+        keys = []
+        for _ in plan:  # one split a dispatched step, in dispatch order
+            self._rng, k = jax.random.split(self._rng)
+            keys.append(k)
         self._retrace_mark("other")
-        t0 = self._start_decode(bt is not None)
+        t0 = self._start_decode(bt_uploaded)
+        for reqs, k in zip(plan, keys):
+            newest = self._dispatch(reqs, k, t0, ahead=newest is not None)
+            if first is None:  # after an idle stretch: the step to read
+                first = newest
+        self._flight = newest if newest is not first else None
+        self._read_step(first, parts=True)
+        return True
+
+    def _more_work(self) -> bool:
+        """What `step()` returns: a caller that stops at False leaves no
+        request waiting and no decode step unread."""
+        return bool(self.active.any() or self._flight is not None
+                    or not self._queue.empty() or self._waiting is not None
+                    or self._preempted or self._prefilling is not None)
+
+    def _plan_decode(self) -> list:
+        """The decode steps this call dispatches, each as the requests its
+        rows are computed for (`_Flight.reqs`), with their pages booked.
+        With a step in flight: the one after it, where some slot outlives
+        the one in flight and the pool gives its pages without a victim
+        (`_book_ahead`). With none (the engine was idle, or the last call
+        could not run ahead): the next step, its pages booked as ever
+        (`_ensure_decode_pages`, which may preempt), and the one after
+        it on the same terms as above."""
+        plan: list = []
+        unread = None if self._flight is None else self._flight.reqs
+        if unread is None and self.active.any():
+            if self.paged:
+                self._ensure_decode_pages()
+            if self.active.any():
+                unread = [s.req if a else None
+                          for s, a in zip(self._slots, self.active)]
+                plan.append(unread)
+        if unread is not None:
+            rows = self._book_ahead(unread)
+            if rows is not None:
+                plan.append(rows)
+        return plan
+
+    def _book_ahead(self, unread: list) -> Optional[list]:
+        """The rows of the step after the one whose rows are `unread`
+        (dispatched, its tokens not read yet), or None where that step is
+        not to be dispatched before `unread` is read. A slot whose unread
+        step is its last by its count of tokens gets no row, no page and
+        no `"length"`: its row of the next step writes where the block
+        table's unbooked entries point, the scratch page, or inside the
+        slot's own last page. With no row at all nothing is dispatched.
+        A row's next write must lie inside pages the slot holds at
+        dispatch, one token further than the host's mirror says for a
+        slot with a token unread; such a page is taken where the pool
+        has one free or a cached prefix to evict, never from a victim:
+        under that pressure the caller reads the step in flight first and
+        the next call books with exact positions."""
+        rows: list = [None] * self.n_slots
+        need = []
+        for i in np.nonzero(self.active)[0]:
+            s = self._slots[int(i)]
+            behind = unread[i] is s.req
+            if behind and s.remaining <= 1:
+                continue
+            rows[i] = s.req
+            need.append((int(i), 1 + behind))
+        if not need:
+            return None
+        if self.paged:
+            for slot, n in need:
+                while self.pages.short(slot, n):
+                    pg = (None if self.pages.row_full(slot)
+                          else self.pages.alloc())
+                    if pg is None:
+                        return None
+                    self.pages.extend(slot, pg)
+        return rows
+
+    def _upload_block_table(self) -> bool:
+        """Send the block table where the host's mirror has changed."""
+        bt = self.pages.block_table() if self.paged else None
+        if bt is not None:
+            self.cache = dataclasses.replace(
+                self.cache, block_tables=jnp.asarray(bt))
+        return bt is not None
+
+    def _upload_sampling(self) -> tuple:
+        """The per-slot sampling vectors as the step's program takes them,
+        sent from their host mirrors: (temperature, top-k, top-p,
+        do-sample) and the repetition penalty."""
+        return (jnp.asarray(self._temp), jnp.asarray(self._topk),
+                jnp.asarray(self._topp), jnp.asarray(self._dosample)), \
+            jnp.asarray(self._penalty)
+
+    def _start_decode(self, bt_uploaded: bool) -> float:
+        """The clock where the call starts to dispatch and read decode
+        steps: a step dispatched with none unread has its `decode_step`
+        span start here. While tracing, also where `step.pages` ends."""
+        t0 = self._clock()
+        st = self._step_trace
+        if st is not None:
+            st.decoded = True
+            st.close(t0, "step.pages", {"bt_uploaded": bt_uploaded})
+        return t0
+
+    def _next_seq(self) -> Optional[int]:
+        """While tracing, the number of the decode step being dispatched:
+        its span, its phases' spans and their annotations carry it."""
+        if self._step_trace is None:
+            return None
+        self._step_seq += 1
+        return self._step_seq
+
+    def _note_dispatched(self, seq: Optional[int], t_args) -> None:
+        """While tracing, close `decode.dispatch` of step `seq` where its
+        program has been enqueued."""
+        st = self._step_trace
+        if st is not None:
+            st.close(self._clock(), "decode.dispatch",
+                     {"seq": seq,
+                      "retrace_s": self._retrace_mark("decode_step")},
+                     (t_args,), (("decode.args", {}), ("decode.call", {})))
+
+    def _dispatch(self, reqs: list, key, t0: float, ahead: bool) -> _Flight:
+        """Enqueue one decode step over the slot pool; `reqs` are the
+        requests whose rows it computes. What it returns for the host is
+        read later (`_read_step`); `cur`, the cache and `seen` are the
+        next step's inputs and stay on the device."""
+        st = self._step_trace
+        seq, t_args = self._next_seq(), None
         try:
-            with self._phase("decode.args"):
+            with self._phase("decode.args", seq=seq):
                 sampling, penalty = self._upload_sampling()
                 lora = self._gather_blora()
             if st is not None:
-                st.t_args = self._clock()
-            with self._phase("decode.call"):
-                nxt, lps, top, self.cache, self.seen, moe = self._decode(
-                    self.model.params, self.cur, self.cache, k, *sampling,
+                t_args = self._clock()
+            with self._phase("decode.call", seq=seq):
+                self.cur, out, self.cache, self.seen = self._decode(
+                    self.model.params, self.cur, self.cache, key, *sampling,
                     self.seen, penalty, lora=lora,
                 )
         except Exception:
@@ -2868,32 +3044,68 @@ class InferenceEngine:
             self.fail_all("decode step failed")
             self._reset_state()
             raise
-        dispatched = self._stamp_dispatched()
-        self.cur = nxt
-        with self._phase("decode.wait"):
-            toks = np.asarray(nxt)  # returns when the program has run
-        if st is not None:
-            st.t_wait = self._clock()
-        with self._phase("decode.read"):
-            lps_h = self._inject_nan(np.asarray(lps))
-            tops_h = None
-            if top is not None:
-                tops_h = (np.asarray(top[0]), np.asarray(top[1]))
-            experts_h = None
-            if moe is not None:
-                experts_h = np.asarray(moe)  # [L, B, k]
-                # counted only when a span or a gauge reads it (moe_load)
-                self._moe_last = (experts_h, self.active.copy())
-        if st is not None:
-            st.arrays = 1 + 2 * (top is not None) + (moe is not None)
-        # the np.asarray fetches above are the host sync: the step's
-        # device work is really done here, so the duration is honest
-        self._note_decode_step(t0, dispatched)
-        with self._phase("step.emit"):
-            for i in np.nonzero(self.active)[0]:
+        self._note_dispatched(seq, t_args)
+        return _Flight(out, reqs, t0, ahead, seq)
+
+    def _drain(self) -> None:
+        """Read the step in flight now, through the path every read takes:
+        for what needs the pool at rest and the host's mirrors exact (a
+        preemption's copy to host RAM, a speculative round, shutdown)."""
+        fl, self._flight = self._flight, None
+        if fl is not None:
+            self._read_step(fl)
+
+    def _read_step(self, fl: _Flight, parts: bool = False) -> None:
+        """Fetch what step `fl` left for the host and apply it: advance,
+        emit and finish its live rows. A row whose slot no longer holds
+        the request it was computed for (EOS, a stop, a cancel, a
+        deadline, a quarantine seen after dispatch) is dropped. `parts`:
+        the call's own read, whose phases are parts of `engine.step`."""
+        st = self._step_trace if parts else None
+        t_wait = None
+        try:
+            with self._phase("decode.wait", seq=fl.seq):
+                fl.out.block_until_ready()  # the program has run
+            if st is not None:
+                t_wait = self._clock()
+            with self._phase("decode.read", seq=fl.seq):
+                host = np.asarray(fl.out)  # the step's one fetch
+        except Exception:
+            # a device failure surfaces here, with the next step queued
+            # on what this one left: both are lost with the pool
+            self.fail_all("decode step failed")
+            self._reset_state()
+            raise
+        live = np.zeros((self.n_slots,), bool)
+        for i, r in enumerate(fl.reqs):
+            live[i] = (r is not None and self._slots[i].req is r
+                       and self.active[i])
+        self.decode_rows_discarded += (
+            sum(r is not None for r in fl.reqs) - int(live.sum()))
+        self.decode_steps[fl.ahead] += 1
+        n_top = self.logprobs_top_k
+        toks = host[:, 0]
+        lps = self._inject_nan(
+            np.ascontiguousarray(host[:, 1]).view(np.float32), live)
+        tops_h = experts_h = None
+        if n_top:
+            tops_h = (host[:, 2:2 + n_top], np.ascontiguousarray(
+                host[:, 2 + n_top:2 + 2 * n_top]).view(np.float32))
+        if self._moe_routing:  # [B, L * k] -> [L, B, k]
+            experts_h = host[:, 2 + 2 * n_top:].reshape(
+                self.n_slots, -1, self.config.num_experts_per_tok
+            ).transpose(1, 0, 2).astype(
+                _expert_id_dtype(self.config.num_experts))
+            # counted only when a span or a gauge reads it (moe_load)
+            self._moe_last = (experts_h, live)
+        # the fetch above is the host sync: the step's device work is
+        # really done here, so the duration is honest
+        self._note_decode_step(fl, live, t_wait)
+        with self._phase("step.emit", seq=fl.seq):
+            for i in np.nonzero(live)[0]:
                 i = int(i)
                 s = self._slots[i]
-                if not np.isfinite(lps_h[i]):
+                if not np.isfinite(lps[i]):
                     # non-finite logits guard: quarantine the ONE
                     # poisoned slot (its sampled token/logprob are
                     # garbage) instead of letting the exception path
@@ -2914,83 +3126,78 @@ class InferenceEngine:
                 if tops_h is not None:
                     alt = {int(t): float(l)
                            for t, l in zip(tops_h[0][i], tops_h[1][i])}
-                self._emit(i, int(toks[i]), float(lps_h[i]), alt)
+                self._emit(i, int(toks[i]), float(lps[i]), alt)
         if st is not None:
-            st.close(self._clock(), "step.emit", {})
-        return True
+            st.seq = fl.seq
+            st.close(self._clock(), "step.emit", {"seq": fl.seq})
 
-    def _upload_sampling(self) -> tuple:
-        """The per-slot sampling vectors as the step's program takes them,
-        sent from their host mirrors: (temperature, top-k, top-p,
-        do-sample) and the repetition penalty."""
-        return (jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp), jnp.asarray(self._dosample)), \
-            jnp.asarray(self._penalty)
-
-    def _start_decode(self, bt_uploaded: bool) -> float:
-        """The clock at the start of the `decode_step` span; while
-        tracing, also where `step.pages` ends and the step gets the `seq`
-        its spans and annotations carry."""
-        t0 = self._clock()
-        st = self._step_trace
-        if st is not None:
-            self._step_seq += 1
-            st.seq = self._step_seq
-            st.close(t0, "step.pages", {"bt_uploaded": bt_uploaded})
-        return t0
-
-    def _stamp_dispatched(self) -> Optional[tuple]:
-        """While tracing, (clock, retrace seconds so far in the step) at
-        the instant the step's program was enqueued: where the
-        `decode.dispatch` span ends and `decode.fetch` begins."""
-        if self._step_trace is not None:
-            return self._clock(), self._retrace_mark("decode_step")
-        return None
-
-    def _note_decode_step(self, t0: float,
-                          dispatched: Optional[tuple] = None) -> None:
-        """Per-step phase accounting: duration histogram + the engine
-        track's span/occupancy counter (tid 0 — batch-level, not
-        per-request). The span and the children that partition it close
-        here and are recorded with the step's (`_note_step`)."""
+    def _note_decode_step(self, fl: _Flight, live: "np.ndarray",
+                          t_wait: Optional[float] = None,
+                          arrays: int = 1) -> None:
+        """Per-step accounting where step `fl` has been fetched: the
+        duration histogram and, on the decode track, its `decode_step`
+        span, from the later of its dispatch and the fetch before it to
+        this fetch, so that consecutive spans never overlap and, with a
+        step in flight, its length is what the step cost the device. The
+        span's arguments describe `fl`: they are taken before the host's
+        mirrors advance. `t_wait`: where `decode.wait` ended, for a read
+        whose phases are parts of `engine.step`."""
         t1 = self._clock()
-        self.decode_step_seconds.observe(t1 - t0)
+        t_start = max(fl.t0, self._t_read)
+        self._t_read = t1
+        self.decode_step_seconds.observe(t1 - t_start)
         rt_rest = self._retrace_mark("decode_step")
+        tr = self.tracer
+        if fl.seq is None or tr is None or not tr.enabled:
+            return
         st = self._step_trace
         if st is not None:
-            busy = int(self.active.sum())
-            pages = {}
-            if self._state_rows:  # what the step read and wrote again
-                pages["state_rows_live"] = busy
-                pages["state_bytes_moved"] = 2 * busy * self.state_row_bytes
-            elif self.paged:  # its pos still holds the step's own
-                pages["live_pages"], pages["grid_pages"] = \
-                    self.pages.grid_pages(self.active)
-                if self._latent:  # slots 0 .. pos of every live row
-                    live = sum(self.pages.pos[i] + 1
-                               for i in np.nonzero(self.active)[0])
-                    pages["latent_live_tokens"] = int(live)
-                    pages["latent_bytes_read"] = int(
-                        live * self.latent_token_bytes)
-            st.close(
-                t1, "decode_step",
-                dict(seq=st.seq, occupancy=busy, slots=self.n_slots,
-                     queue_depth=self._queue.qsize(), **pages,
-                     **self.moe_load()),
-                (dispatched[0],),
-                (("decode.dispatch", {"retrace_s": dispatched[1]},
-                  (st.t_args,), (("decode.args", {}), ("decode.call", {}))),
-                 ("decode.fetch", {"retrace_s": rt_rest},
-                  (st.t_wait,), (("decode.wait", {}),
-                                 ("decode.read", {"arrays": st.arrays})))))
-            self.tracer.counter("batch", ts=t1, occupancy=busy,
-                                queued=self._queue.qsize(),
-                                preempted=len(self._preempted))
+            st.decoded = True
+        busy = int(live.sum())
+        pages = {}
+        if self._state_rows:  # what the step read and wrote again
+            pages["state_rows_live"] = busy
+            pages["state_bytes_moved"] = 2 * busy * self.state_row_bytes
+        elif self.paged:  # its pos still holds the step's own
+            pages["live_pages"], pages["grid_pages"] = \
+                self.pages.grid_pages(live)
+            if self._latent:  # slots 0 .. pos of every live row
+                n = sum(self.pages.pos[i] + 1 for i in np.nonzero(live)[0])
+                pages["latent_live_tokens"] = int(n)
+                pages["latent_bytes_read"] = int(
+                    n * self.latent_token_bytes)
+        tr.complete(
+            "decode_step", t_start, t1 - t_start, tid=DECODE_TID,
+            cat="engine", seq=fl.seq, ahead=fl.ahead, occupancy=busy,
+            slots=self.n_slots, queue_depth=self._queue.qsize(), **pages,
+            **self.moe_load())
+        tr.counter("batch", ts=t1, occupancy=busy,
+                   queued=self._queue.qsize(),
+                   preempted=len(self._preempted))
+        if st is not None and t_wait is not None:
+            st.close(t1, "decode.fetch",
+                     {"seq": fl.seq, "retrace_s": rt_rest}, (t_wait,),
+                     (("decode.wait", {}), ("decode.read", {"arrays": arrays})))
 
-    def _step_speculative(self, k, bt_uploaded: bool) -> bool:
+    def _step_speculative(self) -> bool:
         """Draft-K-then-verify round: each live slot emits 1..draft_k
-        tokens (its accepted prefix + the target's bonus token)."""
+        tokens (its accepted prefix + the target's bonus token). A
+        round's accepted counts decide the next positions, so a round is
+        dispatched and read in one call, with nothing in flight."""
         st = self._step_trace
+        self._drain()
+        if self.paged:
+            # reserve for the CURRENT ladder K (== draft_k when not
+            # adaptive): after a downshift the round writes at most
+            # _cur_k tokens before rollback, so tighter is still safe
+            self._ensure_decode_pages(self._cur_k)
+        bt_uploaded = self._upload_block_table()
+        if not self.active.any():
+            if st is not None:
+                st.close(self._clock(), "step.pages",
+                         {"bt_uploaded": bt_uploaded})
+            return self._more_work()
+        self._rng, k = jax.random.split(self._rng)
         if self._spec_exec is not None:  # pre-compiled ladder program
             fn = self._spec_exec[self._cur_k]
         else:
@@ -3004,12 +3211,13 @@ class InferenceEngine:
             kw["lora"] = self._gather_blora()
         self._retrace_mark("other")
         t0 = self._start_decode(bt_uploaded)
+        seq, t_args, t_wait = self._next_seq(), None, None
         try:
-            with self._phase("decode.args"):
+            with self._phase("decode.args", seq=seq):
                 sampling, penalty = self._upload_sampling()
             if st is not None:
-                st.t_args = self._clock()
-            with self._phase("decode.call"):
+                t_args = self._clock()
+            with self._phase("decode.call", seq=seq):
                 (choice, lp_all, n_acc, cur2, self.cache, self.dcache,
                  self.seen) = fn(
                     self.model.params, self._draft_params, self.cur,
@@ -3020,21 +3228,23 @@ class InferenceEngine:
             self.fail_all("speculative decode step failed")
             self._reset_state()
             raise
-        dispatched = self._stamp_dispatched()
+        self._note_dispatched(seq, t_args)
         self.cur = cur2
-        with self._phase("decode.wait"):
+        with self._phase("decode.wait", seq=seq):
             choice_h = np.asarray(choice)
         if st is not None:
-            st.t_wait = self._clock()
-            st.arrays = 2
-        with self._phase("decode.read"):
-            lp_h = self._inject_nan(np.asarray(lp_all))
+            t_wait = self._clock()
+        live = self.active.copy()
+        with self._phase("decode.read", seq=seq):
+            lp_h = self._inject_nan(np.asarray(lp_all), live)
             n_acc_h = np.asarray(n_acc)
-        self._note_decode_step(t0, dispatched)
+        self.decode_steps[0] += 1
+        self._note_decode_step(
+            _Flight(None, [], t0, False, seq), live, t_wait, arrays=2)
         self.spec_rounds += 1
         if self.adaptive_draft:
             self._adapt_draft_k(n_acc_h[self.active])
-        with self._phase("step.emit"):
+        with self._phase("step.emit", seq=seq):
             for i in np.nonzero(self.active)[0]:
                 i = int(i)
                 s = self._slots[i]
@@ -3056,7 +3266,8 @@ class InferenceEngine:
                     if not self.active[i]:  # EOS or budget hit mid-round
                         break
         if st is not None:
-            st.close(self._clock(), "step.emit", {})
+            st.seq = seq
+            st.close(self._clock(), "step.emit", {"seq": seq})
         return True
 
     def _adapt_draft_k(self, n_acc: np.ndarray) -> None:
@@ -3090,6 +3301,7 @@ class InferenceEngine:
         after a crash must not itself crash (an armed crash_before_done
         with charges left would otherwise kill the engine thread)."""
         self._cleanup = True
+        self._drop_flight()
         try:
             for i, s in enumerate(self._slots):
                 if s.req is None:
@@ -3121,6 +3333,17 @@ class InferenceEngine:
             self.active[:] = False
         finally:
             self._cleanup = False
+
+    def _drop_flight(self) -> None:
+        """Forget the step in flight, its tokens unread: what failed may
+        have left some slots a token behind the others (a crash inside
+        one slot's finish), so nothing computed on top of it is emitted.
+        Its writes lie inside pages its slots held at dispatch, and
+        whatever uses those pages next is enqueued behind it."""
+        fl, self._flight = self._flight, None
+        if fl is not None:
+            self.decode_rows_discarded += sum(
+                r is not None for r in fl.reqs)
 
     def run_until_idle(self, max_steps: int = 100000) -> None:
         for _ in range(max_steps):
@@ -3158,6 +3381,7 @@ class InferenceEngine:
             if deadline is not None and self._clock() > deadline:
                 return False
             self.step()
+        self._drain()  # a step whose every row finished meanwhile
         return True
 
     def close(self) -> None:
@@ -3167,6 +3391,7 @@ class InferenceEngine:
         After a clean drain the rewrite holds zero entries — the next
         start replays nothing; after a timed-out drain it holds exactly
         the unfinished tail. Idempotent."""
+        self._drain()
         if self._request_log is not None:
             self._request_log.close()
         if self._journal is None:

@@ -365,6 +365,23 @@ class Metrics:
                 f'bigdl_tpu_retraces_total{{phase="{phase}"}} {n}'
                 for phase, n in self.engine.retraces.items()
             ]
+            ahead = self.engine.decode_steps  # [read late, read ahead]
+            lines += [
+                # plain decode keeps one step in flight (engine._step):
+                # how often it does, and what a late-seen finish costs
+                "# HELP bigdl_tpu_decode_steps_total decode steps read, by "
+                "whether the step was dispatched with its predecessor "
+                "still unread (one step in flight)",
+                "# TYPE bigdl_tpu_decode_steps_total counter",
+                f'bigdl_tpu_decode_steps_total{{ahead="0"}} {ahead[0]}',
+                f'bigdl_tpu_decode_steps_total{{ahead="1"}} {ahead[1]}',
+                "# HELP bigdl_tpu_decode_rows_discarded_total rows of a "
+                "decode step computed for a slot that had finished by the "
+                "time the step was read",
+                "# TYPE bigdl_tpu_decode_rows_discarded_total counter",
+                f"bigdl_tpu_decode_rows_discarded_total "
+                f"{self.engine.decode_rows_discarded}",
+            ]
             lines += [
                 # chunked prefill (docs/serving.md §6): one count per
                 # prefill dispatch — a monolithic prefill is 1 chunk
@@ -574,6 +591,8 @@ _ENGINE_FAMILIES = (
     "bigdl_tpu_retrace_seconds_total",
     "bigdl_tpu_retraces_total",
     "bigdl_tpu_prefill_chunks_total",
+    "bigdl_tpu_decode_steps_total",
+    "bigdl_tpu_decode_rows_discarded_total",
 )
 
 _PAGED_FAMILIES = (

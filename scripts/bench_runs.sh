@@ -10,7 +10,9 @@
 # the comparison asks for that. A parent-against-change reading is four of
 # these in one `chiprun -- bash -c '...'`, in the order parent, change,
 # change, parent, each pair of sides at the same seeds. Never stops at a
-# failing run: the exit code of each is printed.
+# failing run: the exit code of each is printed. BENCH_RUNNER names another
+# script with bench/run.py's arguments (scripts/bench_engine_counters.py,
+# which adds the engine's step-in-flight counters to the log).
 set -u
 tag=$1 dir=$2 trace=$3
 shift 3
@@ -21,9 +23,9 @@ while [ $# -ge 2 ]; do
     cell=$1 seed=$2
     shift 2
     log="$root/chiprun_out/${tag}_${cell}_${seed}_t${trace}.log"
-    (cd "$root/$dir" && python3 bench/run.py --workload "$cell" --seed "$seed" \
+    (cd "$root/$dir" && python3 "${BENCH_RUNNER:-bench/run.py}" --workload "$cell" --seed "$seed" \
         --seconds "$seconds" --trace "$trace") >"$log" 2>&1
     echo "$tag $cell $seed trace$trace exit $?"
-    grep -a "reference check" "$log" | cut -c1-400
+    grep -a "reference check\|^engine counters" "$log" | cut -c1-400
     tail -n 1 "$log" | cut -c1-3000
 done

@@ -30,6 +30,7 @@ from bigdl_tpu.obs.profiler import (
     ProfilerWindow,
 )
 from bigdl_tpu.obs.tracing import (
+    DECODE_TID,
     RequestLog,
     TraceRecorder,
     format_summary,
@@ -173,7 +174,9 @@ def test_preempted_request_trace_and_metric_consistency(model, monkeypatch):
                           page_size=8, faults=inj, tracer=tr)
     r = eng.submit([3, 1, 4, 1, 5], max_new_tokens=40)
     eng.step()  # admit; next page allocation is the decode extension
-    inj.arm("alloc_page", times=1)
+    # twice: the page a step AHEAD of the one in flight (dry there, the
+    # engine only reads first), then the page the next step needs
+    inj.arm("alloc_page", times=2)
     eng.run_until_idle()
     assert r.done and not r.error and r.preemptions == 1
     assert eng.preemptions == 1 and eng.preemption_resumes == 1
@@ -289,16 +292,18 @@ def test_ttft_itl_under_injected_slow_step(model):
 
 ADMISSION_PARTS = ["prefill.dispatch", "first_token.sample",
                    "first_token.arm"]
-STEP_PARTS = ["decode.dispatch", "decode.fetch"]
 DISPATCH_PARTS = ["decode.args", "decode.call"]
 FETCH_PARTS = ["decode.wait", "decode.read"]
-#: `engine.step`'s parts, in order; a step that decoded has all five, one
-#: that admitted and left no slot active the first three
-ENGINE_STEP_PARTS = ["step.reap", "step.admit", "step.pages", "decode_step",
-                     "step.emit"]
-#: every event name of the engine track (tid 0)
-ENGINE_TRACK = set(ENGINE_STEP_PARTS + STEP_PARTS + DISPATCH_PARTS
-                   + FETCH_PARTS + ["engine.step", "batch"])
+#: `engine.step`'s parts, in order: one that admitted and left no slot
+#: active has the first three; one that decoded then dispatches the step
+#: AFTER the one in flight (after an idle stretch two steps, and none
+#: where no slot outlives the one in flight) and reads the one in flight
+ENGINE_STEP_PARTS = ["step.reap", "step.admit", "step.pages",
+                     "decode.dispatch", "decode.fetch", "step.emit"]
+#: every event name of the engine track (tid 0); `decode_step` spans run
+#: from one call of `step()` into the next and have a track of their own
+ENGINE_TRACK = set(ENGINE_STEP_PARTS + DISPATCH_PARTS + FETCH_PARTS
+                   + ["engine.step", "batch"])
 #: the phases mirrored onto a profile's host plane, by what they carry
 STEP_ANNOTATIONS = DISPATCH_PARTS + FETCH_PARTS + ["step.emit"]
 ADMISSION_ANNOTATIONS = ["prefill.dispatch", "first_token.sample"]
@@ -350,14 +355,15 @@ def _abut_and_fill(parent, kids):
 @pytest.mark.parametrize("kind", list(ENGINES))
 def test_phase_spans_partition_admission_and_step(model, kind):
     """Every `prefill` span holds exactly `prefill.dispatch`,
-    `first_token.sample`, `first_token.arm`, every `decode_step` holds
-    `decode.dispatch` (`decode.args`, `decode.call`) then `decode.fetch`
-    (`decode.wait`, `decode.read`), every `engine.step` holds `step.reap`,
-    `step.admit`, `step.pages` and, when it decoded, its `decode_step`
-    and `step.emit`; at each level the children abut, sum to their
-    parent to the microsecond, and carry the retrace seconds paid inside
-    them. The parents keep what they had (bench/ reads them) and gain
-    the admission's occupancy and queue depth, and the step's `seq`."""
+    `first_token.sample`, `first_token.arm`; every `engine.step` holds
+    `step.reap`, `step.admit`, `step.pages` and, when it decoded, the
+    `decode.dispatch` (`decode.args`, `decode.call`) of the steps it
+    enqueued, then the `decode.fetch` (`decode.wait`, `decode.read`) and
+    the `step.emit` of the step it read; at each level the children abut,
+    sum to their parent to the microsecond, and carry the retrace seconds
+    paid inside them. `decode_step` spans, on their own track, follow one
+    another without overlap, and `seq` ties a step's span to its phases.
+    The parents keep what they had (bench/ reads them)."""
     tr = TraceRecorder(enabled=True)
     eng = _engine(model, kind, tracer=tr)
     reqs = _serve(eng)
@@ -389,35 +395,62 @@ def test_phase_spans_partition_admission_and_step(model, kind):
     assert max(p["args"]["occupancy"] for p in prefills) >= 1
     assert max(p["args"]["queue_depth"] for p in prefills) >= 1
 
-    steps = [e for e in spans if e["name"] == "decode_step"]
-    assert steps
+    steps = sorted((e for e in spans if e["name"] == "decode_step"),
+                   key=lambda e: e["ts"])
+    assert steps and all(e["tid"] == DECODE_TID for e in steps)
     for s in steps:
-        kids = _children(events, s, STEP_PARTS)
-        assert [k["name"] for k in kids] == STEP_PARTS
-        _abut_and_fill(s, kids)
-        for kid, names in zip(kids, (DISPATCH_PARTS, FETCH_PARTS)):
+        assert set(s["args"]) == {"seq", "ahead", "occupancy", "slots",
+                                  "queue_depth"} | (
+            {"live_pages", "grid_pages"} if eng.paged else set())
+    # one counter of decode steps, in the order they ran, and no step's
+    # span starts before the one before it has ended
+    assert [s["args"]["seq"] for s in steps] == \
+        list(range(1, len(steps) + 1))
+    for a, b in zip(steps, steps[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+    # `seq` pairs a step's phases: dispatched once (its uploads, its call)
+    # and fetched once (the wait, the read), then emitted
+    by_seq = {}
+    for e in spans:
+        if e["name"] in ("decode.dispatch", "decode.fetch", "step.emit"):
+            by_seq.setdefault(e["args"]["seq"], []).append(e)
+    assert sorted(by_seq) == [s["args"]["seq"] for s in steps]
+    for s in steps:
+        mine = sorted(by_seq[s["args"]["seq"]], key=lambda e: e["ts"])
+        assert [e["name"] for e in mine] == ENGINE_STEP_PARTS[3:]
+        for kid, names in zip(mine, (DISPATCH_PARTS, FETCH_PARTS)):
             grand = _children(events, kid)
             assert [g["name"] for g in grand] == names
             _abut_and_fill(kid, grand)
+            assert kid["args"]["retrace_s"] >= 0
         assert grand[1]["args"] == {"arrays": 2 if eng.speculative else 1}
-        assert set(s["args"]) == {"seq", "occupancy", "slots",
-                                  "queue_depth"} | (
-            {"live_pages", "grid_pages"} if eng.paged else set())
-    # one counter of decode steps, in the order they ran
-    assert [s["args"]["seq"] for s in sorted(steps, key=lambda e: e["ts"])] \
-        == list(range(1, len(steps) + 1))
+        # the span ends where its fetch does, and a step dispatched with
+        # its predecessor unread says so
+        assert abs(mine[1]["ts"] + mine[1]["dur"]
+                   - s["ts"] - s["dur"]) <= 1  # us: each rounds on its own
+        before = by_seq.get(s["args"]["seq"] - 1)
+        assert s["args"]["ahead"] == (before is not None and mine[0]["ts"]
+                                      < min(e["ts"] for e in before
+                                            if e["name"] == "decode.fetch"))
+    # plain decode keeps a step in flight; a speculative round is read in
+    # the call that dispatched it
+    assert any(s["args"]["ahead"] for s in steps) != bool(eng.speculative)
 
     whole = [e for e in spans if e["name"] == "engine.step"]
     for w in whole:
         kids = _children(events, w, ENGINE_STEP_PARTS)
         names = [k["name"] for k in kids]
         assert set(w["args"]) == {"seq", "admitted", "occupancy"}
+        assert names[:3] == ENGINE_STEP_PARTS[:3]
         if w["args"]["seq"] is None:  # admitted or advanced a chunk, and
             # no slot was active yet (a chunked prefill's first chunks)
             assert names == ENGINE_STEP_PARTS[:3]
-        else:
-            assert names == ENGINE_STEP_PARTS
-            assert kids[3]["args"]["seq"] == w["args"]["seq"]
+        else:  # read that step, after dispatching up to two
+            assert names[-2:] == ENGINE_STEP_PARTS[-2:]
+            assert names[3:-2] in ([], ["decode.dispatch"],
+                                   ["decode.dispatch"] * 2)
+            assert kids[-1]["args"]["seq"] == kids[-2]["args"]["seq"] \
+                == w["args"]["seq"]
         assert kids[2]["args"] == {"bt_uploaded": kids[2]["args"][
             "bt_uploaded"]} and (eng.paged or not kids[2]["args"][
                 "bt_uploaded"])
@@ -504,14 +537,14 @@ def test_decode_step_span_counts_live_pages(model, kind):
     active rows of the DEVICE cache the step was given."""
     tr = TraceRecorder(enabled=True)
     eng = _engine(model, kind, tracer=tr)
-    seen, decode = [], eng._decode
+    seen, dispatch = [], eng._dispatch
 
-    def spy(params, cur, cache, *a, **kw):
-        pos = np.asarray(cache.pos)[eng.active]
+    def spy(reqs, *a, **kw):  # the rows this step computes for a request
+        pos = np.asarray(eng.cache.pos)[[r is not None for r in reqs]]
         seen.append(int((pos // eng.page_size + 1).sum()))
-        return decode(params, cur, cache, *a, **kw)
+        return dispatch(reqs, *a, **kw)
 
-    eng._decode = spy
+    eng._dispatch = spy
     _serve(eng)
     eng.close()
     steps = [e["args"] for e in tr.events()

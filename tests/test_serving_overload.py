@@ -59,7 +59,9 @@ def test_preemption_parity_paged_under_injected_exhaustion(model):
                           page_size=8, faults=inj)
     r = eng.submit(prompt, max_new_tokens=40)
     eng.step()  # admit; the next page allocation is the decode extension
-    inj.arm("alloc_page", times=1)
+    # twice: dry for the page a step AHEAD of the one in flight (the engine
+    # only reads first), and dry for the page the next step needs
+    inj.arm("alloc_page", times=2)
     eng.run_until_idle()
     assert r.done and not r.error
     assert eng.preemptions == 1 and eng.preemption_resumes == 1
@@ -177,7 +179,9 @@ def test_preemption_disabled_restores_length_finish(model):
                           page_size=8, faults=inj, preemption=False)
     r = eng.submit([3, 1, 4, 1, 5], max_new_tokens=40)
     eng.step()
-    inj.arm("alloc_page", times=1)
+    # twice: dry for the page a step AHEAD of the one in flight (the engine
+    # only reads first), and dry for the page the next step needs
+    inj.arm("alloc_page", times=2)
     eng.run_until_idle()
     assert r.done and r.finish_reason == "length"
     assert len(r.out_tokens) < 40
