@@ -44,6 +44,8 @@ _QUANT_TARGETS = {
     # MLA projections (models/deepseek.py; the per-head w_uk/w_uv factors
     # stay dense — they are absorbed into f32 attention math)
     "w_dq", "w_uq", "w_dkv",
+    # the Mamba-2 mixer's projections (models/granitemoehybrid.py)
+    "w_in", "w_out",
 }
 
 Get = Callable[[str], np.ndarray]
@@ -1186,6 +1188,66 @@ def _deepseek_tree(config: ModelConfig, get: Get, quant) -> tuple[list, list, di
     return dense_dicts, moe_dicts, top
 
 
+def _granitemoehybrid_tree(config: ModelConfig, get: Get, quant
+                           ) -> tuple[list, dict]:
+    """Granite 4.0-H (HF modeling_granitemoehybrid). Returns (one list of
+    per-layer dicts for each RUN of layers of one kind, top), `quant`
+    applied as tensors stream in. `mamba.in_proj` is [z | xBC | dt] as it
+    stands; `conv1d.weight [C, 1, K]` becomes `conv_w [K, C]`; `A_log`
+    becomes the decay rate `a = exp(A_log)` in float16; `dt_bias`, `D` and
+    the convolution stay float32. An expert's and the shared MLP's
+    `input_linear` [.., 2 x width, H] is [gate | up]: it splits into the
+    `w_gate` / `w_up` stacks the grouped kernel takes. With tied embeddings
+    the head is a packed copy of the table."""
+    from bigdl_tpu.models.granitemoehybrid import layer_runs
+
+    def f32(x):
+        return jnp.asarray(np.asarray(x, np.float32))
+
+    def one(i: int, kind: str) -> dict:
+        p = f"model.layers.{i}."
+        d = {"attn_norm": get(p + "input_layernorm.weight"),
+             "mlp_norm": get(p + "post_attention_layernorm.weight")}
+        if kind == "mamba":
+            m = p + "mamba."
+            d.update(w_in=get(m + "in_proj.weight"),
+                     w_out=get(m + "out_proj.weight"),
+                     mixer_norm=get(m + "norm.weight"))
+            exact = {
+                "conv_w": f32(np.asarray(get(m + "conv1d.weight"))[:, 0].T),
+                "conv_b": f32(get(m + "conv1d.bias")),
+                "dt_bias": f32(get(m + "dt_bias")), "D": f32(get(m + "D")),
+                "a": jnp.exp(f32(get(m + "A_log"))).astype(jnp.float16)}
+        else:
+            a = p + "self_attn."
+            d.update(wq=get(a + "q_proj.weight"), wk=get(a + "k_proj.weight"),
+                     wv=get(a + "v_proj.weight"), wo=get(a + "o_proj.weight"))
+            exact = {}
+        if config.is_moe:
+            e = p + "block_sparse_moe."
+            w_in = np.asarray(get(e + "input_linear.weight"))  # [E, 2I, H]
+            half = w_in.shape[1] // 2
+            d.update(router=get(e + "router.layer.weight"),
+                     w_gate_e=w_in[:, :half], w_up_e=w_in[:, half:],
+                     w_down_e=get(e + "output_linear.weight"))
+        if config.shared_intermediate_size:
+            w_in = np.asarray(get(p + "shared_mlp.input_linear.weight"))
+            half = w_in.shape[0] // 2
+            d.update(w_gate_s=w_in[:half], w_up_s=w_in[half:],
+                     w_down_s=get(p + "shared_mlp.output_linear.weight"))
+        return {**{k: quant(k, v) for k, v in d.items()}, **exact}
+
+    runs, i = [], 0
+    for kind, _, n in layer_runs(config):
+        runs.append([one(i + j, kind) for j in range(n)])
+        i += n
+    top = {"embed": get("model.embed_tokens.weight"),
+           "final_norm": get("model.norm.weight")}
+    top["lm_head"] = (top["embed"] if config.tie_word_embeddings
+                      else get("lm_head.weight"))
+    return runs, top
+
+
 def layer_tensors(config: ModelConfig, i: int, get: Get) -> dict[str, np.ndarray]:
     fn = _FAMILY_LAYER.get(config.model_type, _llama_layer)
     return fn(config, i, get)
@@ -1282,6 +1344,14 @@ def params_from_state_dict(
             params["layers"] = stack_dicts(dense_dicts)
         if moe_dicts:
             params["moe_layers"] = stack_dicts(moe_dicts)
+        for k, v in top.items():
+            params[k] = maybe_quant(k, v)
+        return params
+
+    if config.model_type == "granitemoehybrid":
+        runs, top = _granitemoehybrid_tree(config, get_tensor, maybe_quant)
+        params = {"runs": {f"{r:02d}": stack_dicts(run)
+                           for r, run in enumerate(runs)}}
         for k, v in top.items():
             params[k] = maybe_quant(k, v)
         return params

@@ -1,0 +1,314 @@
+"""State beside pages: the cache of a model whose layers are of two kinds.
+
+Beside `kvcache.py` (a dense pool of keys and values), `kvpaged.py` (pages of
+them, or of latents) and `kvstate.py` (a recurrent state in EVERY layer and
+no keys): a hybrid such as Granite 4.0-H (`models/granitemoehybrid.py`) runs
+a few softmax-attention layers between many Mamba-2 layers. An attention
+layer keeps keys and values, which grow with the context; a Mamba-2 layer
+keeps, per sequence, the last `d_conv - 1` inputs of its causal convolution
+and a state `h [heads, head size, d_state]` in float32, which do not. So one
+slot of the serving engine holds BOTH: pages, booked by
+`serving/pages.PageTable` exactly as a dense model's are, and one STATE ROW,
+which is the slot's own index (row b of the pool belongs to batch row b of a
+decode step; a prefill names its row in `rows`). Nothing books the rows: a
+slot has one whether it is used or not, a row that is idle is neither read
+nor written by a step, and an admission's prefill starts its row from zero
+(`pos == 0`) whatever the last holder left.
+
+    conv [Lm, d_conv - 1, R, C]   float32, C = inner + 2 * groups * d_state
+    ssm  [Lm, R, heads * head size, d_state]   float32, d_state on lanes
+    k, v [La, n_pages, page, Hkv, D]           as kvpaged.PagedKVCache's
+
+The Mamba-2 recurrence of one head, with a_t = dt_t * A <= 0:
+
+    h_t = exp(a_t) h_{t-1} + dt_t x_t (x) B_t        y_t = h_t C_t + D x_t
+
+A decode step runs it once, through the Pallas kernel `mamba2_decode`
+(ops/pallas/mamba2.py: the live rows' state read and written in place) where
+the kernels are in use, else in `jnp`. A prefill runs the CHUNKED form
+(`ssd_chunked`: inside a chunk the sum over s <= t of exp(sum a) C_t . B_s
+dt_s x_s, across chunks the state) on the XLA route under
+`jax.named_scope("mamba2_prefill")`. Decay sums and the state are float32.
+A position that is no token (left padding before `start`, the right padding
+of a bucket past `valid_len`) carries dt = 0 and a zero convolution input:
+no decay, no update, and the convolution's tail is taken at the last real
+token.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from bigdl_tpu import kvpaged
+
+KIND = "state_beside_pages"
+_HI = jax.lax.Precision.HIGHEST  # float32 operands stay float32 on the MXU
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class HybridCache:
+    k: jax.Array  # [La, n_pages, page, Hkv, D]
+    v: jax.Array
+    conv: jax.Array  # [Lm, d_conv - 1, R, C] float32
+    ssm: jax.Array  # [Lm, R, heads * head size, d_state] float32
+    block_tables: jax.Array  # [B, max_pages] int32, 0 = nobody's page
+    pos: jax.Array  # [B] int32 next slot per row
+    start: jax.Array  # [B] int32 first valid slot (left padding)
+    # [B] int32 state row of each batch row; None = batch row b holds row b
+    rows: Optional[jax.Array] = None
+    # [B] int32: how many of the NEXT forward's T positions are tokens
+    # (the engine's prefill pads a bucket on the right); None = all
+    valid_len: Optional[jax.Array] = None
+
+    @property
+    def page_size(self) -> int:
+        return self.k.shape[2]
+
+    @property
+    def max_len(self) -> int:  # logical capacity per row
+        return self.block_tables.shape[1] * self.page_size
+
+    @property
+    def n_rows(self) -> int:
+        return self.ssm.shape[1]
+
+    @property
+    def kv(self) -> kvpaged.PagedKVCache:
+        """The attention layers' pages as `kvpaged` reads and writes them."""
+        return kvpaged.PagedKVCache(
+            k=self.k, v=self.v, block_tables=self.block_tables, pos=self.pos,
+            start=self.start)
+
+    def state_rows(self) -> tuple[jax.Array, jax.Array]:
+        """(state row of each batch row, which batch rows are live): a row
+        whose block table maps no page is an idle slot of the engine."""
+        B = self.block_tables.shape[0]
+        rows = (jnp.arange(B, dtype=jnp.int32) if self.rows is None
+                else self.rows.astype(jnp.int32))
+        return rows, kvpaged.live_rows(self)
+
+
+def init_hybrid(n_attn: int, n_mamba: int, n_pages: int, page_size: int,
+                n_kv_heads: int, head_dim: int, rows: int,
+                max_pages_per_row: int, conv_dim: int, d_conv: int,
+                inner: int, d_state: int, batch: Optional[int] = None,
+                dtype=jnp.bfloat16) -> HybridCache:
+    """Zeros: pages nobody holds and `rows` state rows."""
+    b = rows if batch is None else batch
+    kv = (n_attn, n_pages, page_size, n_kv_heads, head_dim)
+    return HybridCache(
+        k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
+        conv=jnp.zeros((n_mamba, d_conv - 1, rows, conv_dim), jnp.float32),
+        ssm=jnp.zeros((n_mamba, rows, inner, d_state), jnp.float32),
+        block_tables=jnp.zeros((b, max_pages_per_row), jnp.int32),
+        pos=jnp.zeros((b,), jnp.int32), start=jnp.zeros((b,), jnp.int32))
+
+
+def row_nbytes(cache: HybridCache) -> int:
+    """Bytes of ONE state row over all Mamba layers: what a decode step
+    reads, and writes again, for each live slot."""
+    return (cache.conv.size + cache.ssm.size) // cache.n_rows * 4
+
+
+def valid_positions(cache: HybridCache, T: int) -> jax.Array:
+    """[B, T] bool: the positions of this forward that are tokens."""
+    slots = cache.pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    ok = slots >= cache.start[:, None]
+    if cache.valid_len is not None:
+        ok = ok & (jnp.arange(T)[None, :] < cache.valid_len[:, None])
+    return ok
+
+
+def advance(cache: HybridCache, n: int) -> HybridCache:
+    """`n` positions went through; those past `valid_len` were padding."""
+    step = n if cache.valid_len is None else cache.valid_len
+    return dataclasses.replace(cache, pos=cache.pos + step, valid_len=None)
+
+
+# ---------------------------------------------------------------------------
+# the Mamba-2 mixer's state arithmetic
+# ---------------------------------------------------------------------------
+
+def causal_conv(tail, xbc, w, b, end):
+    """Depthwise causal convolution over `tail [B, K-1, C]` (the inputs
+    before this forward) followed by `xbc [B, T, C]`, all float32; `w [K,
+    C]` (w[k] weighs the input K-1-k positions back), `b [C]`. Returns
+    (out [B, T, C], the tail after position `end [B]` - 1: the last K-1
+    inputs of the real tokens)."""
+    K, T = w.shape[0], xbc.shape[1]
+    window = jnp.concatenate([tail, xbc], axis=1)  # [B, T + K - 1, C]
+    out = b + sum(window[:, k:k + T] * w[k] for k in range(K))
+    at = end[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+    return out, jnp.take_along_axis(window, at[..., None], axis=1)
+
+
+def ssm_step(x, dt, A, Bm, Cm, h):
+    """One token in `jnp`. x [B, H, P], dt [B, H], A [H], Bm, Cm [B, N],
+    h [B, H, P, N], all float32. Returns (y [B, H, P] without the D term,
+    h)."""
+    dec = jnp.exp(dt * A)
+    h = dec[..., None, None] * h + (
+        (dt[..., None] * x)[..., None] * Bm[:, None, None, :])
+    return jnp.einsum("bhpn,bn->bhp", h, Cm, precision=_HI), h
+
+
+def ssd_chunked(x, dt, A, Bm, Cm, h, chunk: int):
+    """The chunked (SSD) form over T tokens from the state `h`. x [B, T, H,
+    P], dt [B, T, H] (0 where the position is no token), A [H], Bm, Cm [B,
+    T, N] (one group), h [B, H, P, N], all float32. Returns (y [B, T, H, P]
+    without the D term, h after the T tokens)."""
+    B, T, H, P = x.shape
+    Q = min(chunk, T)
+    pad = -T % Q
+    if pad:  # dt = 0: a padded position neither decays nor updates
+        x, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
+                                 (a.ndim - 2)) for a in (x, dt, Bm, Cm))
+    n = (T + pad) // Q
+
+    def chunks(a):  # [B, n * Q, ...] -> [n, B, Q, ...]
+        return jnp.moveaxis(a.reshape(B, n, Q, *a.shape[2:]), 1, 0)
+
+    causal = jnp.tril(jnp.ones((Q, Q), jnp.bool_))
+
+    def one(h, xs):
+        xc, dtc, bc, cc = xs
+        cum = jnp.cumsum(dtc * A, axis=1)  # [B, Q, H] decay sums, inclusive
+        ch = jnp.moveaxis(cum, 1, 2)  # [B, H, Q]
+        u = dtc[..., None] * xc  # [B, Q, H, P]
+        # inside the chunk: token t sees s <= t through C_t . B_s
+        cb = jnp.einsum("btn,bsn->bts", cc, bc, precision=_HI)
+        decay = jnp.exp(jnp.where(
+            causal, ch[..., :, None] - ch[..., None, :], -jnp.inf))
+        y = jnp.einsum("bhts,bshp->bthp", cb[:, None] * decay, u,
+                       precision=_HI)
+        # what came before the chunk: the state
+        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+            "btn,bhpn->bthp", cc, h, precision=_HI)
+        # the state after the chunk
+        tail = jnp.exp(cum[:, -1:] - cum)  # [B, Q, H] decay to the end
+        h = jnp.exp(cum[:, -1])[..., None, None] * h + jnp.einsum(
+            "bshp,bsn->bhpn", u * tail[..., None], bc, precision=_HI)
+        return h, y
+
+    h, y = jax.lax.scan(one, h, tuple(map(chunks, (x, dt, Bm, Cm))))
+    return jnp.moveaxis(y, 0, 1).reshape(B, n * Q, H, P)[:, :T], h
+
+
+def why_not_kernel(d_state: int, inner: int) -> Optional[str]:
+    """None when a decode step takes `mamba2_decode`."""
+    from bigdl_tpu.ops.pallas import interpret_mode, why_not_pallas
+    from bigdl_tpu.ops.pallas.mamba2 import CHUNK
+
+    why = why_not_pallas()
+    if why is None and inner % CHUNK:
+        why = f"inner width {inner} is not whole chunks of {CHUNK} rows"
+    if why is None and d_state % 128 and not interpret_mode():
+        why = f"d_state {d_state} is not whole lanes"
+    return why
+
+
+def mix(cache: HybridCache, layer, xbc, dt, A, D, conv_w, conv_b, *,
+        n_heads: int, d_head: int, d_state: int, chunk: int, decode: bool):
+    """The state part of Mamba layer `layer` (index among the Mamba layers)
+    over this forward's T positions: the causal convolution and silu over
+    `xbc [B, T, C]`, then the recurrence. `dt [B, T, H]` is the step AFTER
+    the softplus; A [H] < 0, D [H]; everything float32. Returns
+    (y [B, T, H, P] float32 with the D term, the cache with the layer's
+    rows updated). One group of B and C."""
+    from bigdl_tpu.ops import routes
+
+    B, T, C = xbc.shape
+    H, P, N = n_heads, d_head, d_state
+    inner = H * P
+    valid = valid_positions(cache, T)
+    xbc = jnp.where(valid[..., None], xbc.astype(jnp.float32), 0.0)
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    end = (jnp.full((B,), T, jnp.int32) if cache.valid_len is None
+           else cache.valid_len.astype(jnp.int32))
+    rows, live = cache.state_rows()
+    at = jnp.clip(rows, 0, cache.n_rows - 1)
+    to = jnp.where(live, at, cache.n_rows)  # an idle row writes nowhere
+    # a row at position 0 starts from nothing, whatever its last holder left
+    fresh = (cache.pos == 0)[:, None, None]
+    tail = jnp.where(fresh, 0.0, jnp.moveaxis(cache.conv[layer][:, at], 0, 1))
+    out, tail = causal_conv(tail, xbc, conv_w, conv_b, end)
+    out = jax.nn.silu(out)
+    x = out[..., :inner].reshape(B, T, H, P)
+    Bm, Cm = out[..., inner:inner + N], out[..., inner + N:]
+    # (the two advanced indices are apart: their axis comes first)
+    conv = cache.conv.at[layer, :, to].set(tail, mode="drop")
+    detail = f"B{B} T{T} H{H} P{P} N{N}"
+    why = why_not_kernel(N, inner)
+    if decode and T == 1 and why is None:
+        from bigdl_tpu.ops.pallas.mamba2 import mamba2_decode
+
+        routes.note("mamba2", "pallas", detail)
+        y, ssm = mamba2_decode(cache.ssm, layer, rows, live, x[:, 0],
+                               dt[:, 0], A, Bm[:, 0], Cm[:, 0])
+        y = y[:, None]
+    else:
+        routes.note("mamba2", "xla", detail + (
+            f" ({why})" if decode and T == 1 else " chunked prefill"))
+        h = jnp.where(fresh[..., None], 0.0,
+                      cache.ssm[layer, at].reshape(B, H, P, N))
+        if decode and T == 1:
+            y, h = ssm_step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], h)
+            y = y[:, None]
+        else:
+            with jax.named_scope("mamba2_prefill"):
+                y, h = ssd_chunked(x, dt, A, Bm, Cm, h, chunk)
+        ssm = cache.ssm.at[layer, to].set(h.reshape(B, inner, N),
+                                          mode="drop")
+    y = y + D[:, None] * x
+    return y, dataclasses.replace(cache, conv=conv, ssm=ssm)
+
+
+def prefill_chunks(n_tokens: int, chunk: int) -> int:
+    """Chunks of the prefill form over `n_tokens` (a span's argument)."""
+    return -(-n_tokens // min(chunk, max(n_tokens, 1)))
+
+
+# ---------------------------------------------------------------------------
+# a slot to host RAM and back (the engine's preemption)
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass
+class HostHybrid:
+    """One slot parked in host RAM: its pages and its state row, numpy
+    copies, bit for bit."""
+
+    k: np.ndarray  # [La, n, page, Hkv, D]
+    v: np.ndarray
+    conv: np.ndarray  # [Lm, d_conv - 1, C]
+    ssm: np.ndarray  # [Lm, heads * head size, d_state]
+
+    @property
+    def nbytes(self) -> int:
+        return (self.k.nbytes + self.v.nbytes + self.conv.nbytes
+                + self.ssm.nbytes)
+
+
+def swap_out(cache: HybridCache, pages, row: int) -> HostHybrid:
+    """Copy the pages `pages` and state row `row` to the host."""
+    at = jnp.asarray(pages, jnp.int32)
+    return HostHybrid(k=np.asarray(cache.k[:, at]),
+                      v=np.asarray(cache.v[:, at]),
+                      conv=np.asarray(cache.conv[:, :, row]),
+                      ssm=np.asarray(cache.ssm[:, row]))
+
+
+def swap_in(cache: HybridCache, k, v, conv, ssm, row, pages) -> HybridCache:
+    """Write a parked slot into the pages `pages` and state row `row`; jit
+    with the cache donated, the write is in place."""
+    at = pages.astype(jnp.int32)
+    return dataclasses.replace(
+        cache, k=cache.k.at[:, at].set(k), v=cache.v.at[:, at].set(v),
+        conv=cache.conv.at[:, :, row].set(conv),
+        ssm=cache.ssm.at[:, row].set(ssm))
+

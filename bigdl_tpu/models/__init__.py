@@ -160,10 +160,23 @@ _FAMILIES["brumby"] = brumby
 # directly (params_from_state_dict ingests a diffusers UNet checkpoint)
 
 
+# families imported on first use, so that `import bigdl_tpu` and the other
+# models' programs never load (or trace through) their modules
+_LAZY_FAMILIES = {
+    # Mamba-2 and NoPE attention layers mixed by index, a state row beside
+    # KV pages in one slot (bigdl_tpu/kvhybrid.py)
+    "granitemoehybrid": "bigdl_tpu.models.granitemoehybrid",
+}
+
+
 def get_family(model_type: str):
+    if model_type in _LAZY_FAMILIES:
+        import importlib
+
+        return importlib.import_module(_LAZY_FAMILIES[model_type])
     if model_type not in _FAMILIES:
         raise NotImplementedError(
-            f"model_type {model_type!r} not yet supported; have {sorted(_FAMILIES)}"
+            f"model_type {model_type!r} not yet supported; have {sorted([*_FAMILIES, *_LAZY_FAMILIES])}"
         )
     return _FAMILIES[model_type]
 

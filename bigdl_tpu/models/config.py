@@ -130,6 +130,20 @@ class ModelConfig:
     vision_start_token_id: Optional[int] = None
     audio_token_id: Optional[int] = None  # minicpmo audio placeholders
     audio_pool_step: Optional[int] = None  # minicpmo post-projection pool
+    # granitemoehybrid (models/granitemoehybrid.py): each layer's mixer,
+    # "mamba" | "attention", by index; the Mamba-2 mixer's sizes (heads x
+    # head size = the inner width; one conv over inner + 2 * groups *
+    # state channels); attention without any position encoding; the
+    # always-on shared MLP beside the routed experts
+    layer_types: Optional[tuple] = None
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    position_embedding_type: str = "rope"  # "nope": no rope call at all
+    shared_intermediate_size: Optional[int] = None
 
     def __post_init__(self):
         if self.attention_kind not in ("softmax", "power_retention"):
@@ -161,7 +175,7 @@ class ModelConfig:
         # (save_low_bit -> load_low_bit) and must re-become tuples or the
         # config stops hashing as a static jit argument
         for f in ("sliding_layers", "cross_attention_layers",
-                  "mrope_section"):
+                  "mrope_section", "layer_types"):
             v = getattr(self, f)
             if isinstance(v, list):
                 object.__setattr__(self, f, tuple(v))
@@ -595,6 +609,57 @@ def _hf_brumby(hf, kw):
     kw["retention_eps"] = hf.get("retention_eps", 1e-6)
 
 
+def _hf_granitemoehybrid(hf, kw):
+    """Granite 4.0-H (HF modeling_granitemoehybrid): Mamba-2 and attention
+    layers by `layer_types`, every layer followed by top-k routed experts
+    plus an always-on shared MLP, Granite's four multipliers, and no
+    position encoding in the attention layers. `intermediate_size` is the
+    width of ONE expert. What config.json has no key for (the router's
+    softmax over the chosen logits, the gate before the mixer's norm) is
+    the model type's, written in models/granitemoehybrid.py."""
+    L = hf["num_hidden_layers"]
+    kinds = tuple(hf.get("layer_types") or ("attention",) * L)
+    if len(kinds) != L or set(kinds) - {"mamba", "attention"}:
+        raise ValueError(
+            f"layer_types must name {L} layers as 'mamba' or 'attention'; "
+            f"got {len(kinds)}: {sorted(set(kinds))}")
+    kw["layer_types"] = kinds
+    pe = hf.get("position_embedding_type", "nope")
+    if pe != "nope":
+        raise NotImplementedError(
+            f"granitemoehybrid with position_embedding_type {pe!r}: the "
+            "attention layers are written without a position encoding")
+    kw["position_embedding_type"] = pe
+    if hf.get("mamba_proj_bias"):
+        raise NotImplementedError("granitemoehybrid with mamba_proj_bias")
+    if hf.get("mamba_n_groups", 1) != 1:
+        raise NotImplementedError(
+            f"granitemoehybrid with mamba_n_groups "
+            f"{hf['mamba_n_groups']}: the mixer is written for one group "
+            "of B and C")
+    kw["mamba_n_heads"] = hf.get("mamba_n_heads", 128)
+    kw["mamba_d_head"] = hf.get("mamba_d_head", 64)
+    kw["mamba_d_state"] = hf.get("mamba_d_state", 128)
+    kw["mamba_d_conv"] = hf.get("mamba_d_conv", 4)
+    kw["mamba_n_groups"] = hf.get("mamba_n_groups", 1)
+    kw["mamba_chunk_size"] = hf.get("mamba_chunk_size", 256)
+    inner = hf.get("mamba_expand", 2) * hf["hidden_size"]
+    if kw["mamba_n_heads"] * kw["mamba_d_head"] != inner:
+        raise ValueError(
+            f"mamba_n_heads x mamba_d_head = "
+            f"{kw['mamba_n_heads'] * kw['mamba_d_head']} is not "
+            f"mamba_expand x hidden_size = {inner}")
+    kw["num_experts"] = hf.get("num_local_experts", 0)
+    kw["num_experts_per_tok"] = hf.get("num_experts_per_tok", 2)
+    kw["moe_intermediate_size"] = hf["intermediate_size"]
+    kw["shared_intermediate_size"] = hf.get("shared_intermediate_size")
+    kw["embedding_scale"] = hf.get("embedding_multiplier", 1.0)
+    kw["residual_scale"] = hf.get("residual_multiplier", 1.0)
+    kw["attn_scale"] = hf.get("attention_multiplier")
+    kw["logit_scale"] = 1.0 / hf.get("logits_scaling", 1.0)
+    kw.setdefault("tie_word_embeddings", True)
+
+
 def _hf_qwen3_moe(hf, kw):
     _hf_qwen3(hf, kw)
     kw["num_experts"] = hf.get("num_experts", 128)
@@ -930,6 +995,7 @@ _HF_BUILDERS = {
     "multi_modality": _hf_janus,  # janus checkpoints' original model_type
     "qwen3": _hf_qwen3,
     "brumby": _hf_brumby,
+    "granitemoehybrid": _hf_granitemoehybrid,
     "qwen3_moe": _hf_qwen3_moe,
     "phi": _hf_phi,
     "cohere": _hf_cohere,
@@ -996,5 +1062,21 @@ PRESETS: dict[str, ModelConfig] = {
         rope_theta=1000000.0, rms_norm_eps=1e-05,
         max_position_embeddings=32768, sliding_window=None,
         num_experts=8, num_experts_per_tok=2, norm_topk_prob=True,
+    ),
+    # granite-4.0-h's shape at toy sizes: two runs of Mamba-2 layers around
+    # one NoPE attention layer, 8 experts top-3 and a shared MLP
+    # (tests/test_granitemoehybrid.py holds it to its config.json form)
+    "tiny-granite-hybrid": ModelConfig(
+        model_type="granitemoehybrid", vocab_size=256, hidden_size=64,
+        intermediate_size=32, num_hidden_layers=5,
+        num_attention_heads=4, num_key_value_heads=2,
+        tie_word_embeddings=True, rms_norm_eps=1e-5,
+        layer_types=("mamba", "mamba", "attention", "mamba", "mamba"),
+        mamba_n_heads=4, mamba_d_head=32, mamba_d_state=16, mamba_d_conv=4,
+        mamba_n_groups=1, mamba_chunk_size=8,
+        position_embedding_type="nope", num_experts=8,
+        num_experts_per_tok=3, moe_intermediate_size=32,
+        shared_intermediate_size=64, embedding_scale=12,
+        residual_scale=0.22, attn_scale=0.0625, logit_scale=0.25,
     ),
 }

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu import kvcache, kvpaged, kvstate
+from bigdl_tpu import kvcache, kvhybrid, kvpaged, kvstate
 from bigdl_tpu.generate import GenerationConfig, sample_token_per_row
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.obs import retrace
@@ -439,21 +439,33 @@ class InferenceEngine:
         # cross-attention; the generic path would silently corrupt them),
         # or (c), paged, through a page pool of their own kind when they
         # offer `init_paged_cache` (MLA's latent pages,
-        # kvpaged.PagedLatentCache): the page table books, shares, parks
-        # and restores such a page as it does a KV page, and the kind is
-        # chosen here, once, as `kvstate.KIND` is.
+        # kvpaged.PagedLatentCache; a hybrid's KV pages with a state row
+        # beside them in every slot, kvhybrid.HybridCache): the page table
+        # books, parks and restores such a page as it does a KV page, and
+        # the kind is chosen here, once, as `kvstate.KIND` is.
         fam = model.family
-        self._latent_pool = (getattr(fam, "init_paged_cache", None)
-                             if paged else None)
-        self._latent = self._latent_pool is not None
-        if self._latent:
-            kind = f"latent pages ({model.config.model_type})"
+        self._paged_pool = (getattr(fam, "init_paged_cache", None)
+                            if paged else None)
+        own_kind = getattr(fam, "PAGED_CACHE_KIND", None)
+        self._hybrid = (self._paged_pool is not None
+                        and own_kind == kvhybrid.KIND)
+        self._latent = self._paged_pool is not None and not self._hybrid
+        if own_kind == kvhybrid.KIND and not paged:
+            raise NotImplementedError(
+                f"{kvhybrid.KIND} ({model.config.model_type}) is served "
+                "with paged=True: a slot holds KV pages for the attention "
+                "layers and a state row for the others")
+        if self._paged_pool is not None:
+            kind = (f"{kvhybrid.KIND} ({model.config.model_type})"
+                    if self._hybrid
+                    else f"latent pages ({model.config.model_type})")
             for asked, what in ((quantize_kv, "quantize_kv"),
                                 (speculative, "speculative serving"),
                                 (adapters is not None, "adapter serving")):
                 if asked:
                     raise NotImplementedError(
-                        f"{what} is not wired for {kind} yet (ROADMAP R1)")
+                        f"{what} is not wired for {kind} yet (ROADMAP "
+                        f"{'R4' if self._hybrid else 'R1'})")
         self._family_cache = None
         self._family_pool = getattr(fam, "engine_pool", None)
         self._family_insert = getattr(fam, "engine_insert", None)
@@ -466,7 +478,7 @@ class InferenceEngine:
                 "must be defined together"
             )
         if (hasattr(fam, "init_cache") and not self._state_rows
-                and not self._latent):
+                and self._paged_pool is None):
             custom = (self._family_pool is not None
                       and self._family_insert is not None)
             if not custom and not getattr(fam, "SERVABLE_CACHE", False):
@@ -547,7 +559,8 @@ class InferenceEngine:
         self.pages = PageTable(
             n_slots, self.n_pages, page_size, self.max_pages_per_row,
             max_len, faults=self._faults,
-            share_prefixes=not self._state_rows,
+            # a prefix hit would need the state at the prefix's end
+            share_prefixes=not (self._state_rows or self._hybrid),
         ) if paged else None
         self._rng = jax.random.PRNGKey(seed)
         # queue.Queue (not SimpleQueue): the queue-deadline sweep filters
@@ -567,11 +580,14 @@ class InferenceEngine:
         # bytes of one slot's recurrent state over all layers (0 for a
         # model that keeps keys): a decode step moves twice that a live row
         self.state_row_bytes = (
-            kvstate.row_nbytes(self.cache) if self._state_rows else 0)
+            kvstate.row_nbytes(self.cache) if self._state_rows
+            else kvhybrid.row_nbytes(self.cache) if self._hybrid else 0)
         # bytes of one token's latents over all layers (0 for a model that
         # keeps keys and values): what a decode step must read a live token
         self.latent_token_bytes = (
             fam.latent_token_nbytes(self.config) if self._latent else 0)
+        # bytes of state rows that decode steps read and wrote again
+        self.state_bytes_moved = 0
         self.cur = jnp.zeros((n_slots,), jnp.int32)  # last token per slot
         self.active = np.zeros((n_slots,), bool)  # host-side mask
         # per-slot sampling params (host mirrors, shipped traced each step)
@@ -700,6 +716,10 @@ class InferenceEngine:
             paged_prefill = jax.jit(
                 _named("engine_paged_prefill", self._latent_prefill_impl, fwd),
                 donate_argnames=("lat",))
+        elif self._hybrid:
+            paged_prefill = jax.jit(
+                _named("engine_paged_prefill", self._hybrid_prefill_impl, fwd),
+                donate_argnames=("k", "v", "conv", "ssm"))
         else:
             paged_prefill = jax.jit(
                 _named("engine_paged_prefill", self._paged_prefill_impl, fwd),
@@ -909,6 +929,10 @@ class InferenceEngine:
             self._swap_in = self._with_mesh(jax.jit(
                 kvpaged.swap_in_latent, donate_argnames=("cache",)
             ))
+        elif self._hybrid:
+            self._swap_in = self._with_mesh(jax.jit(
+                kvhybrid.swap_in, donate_argnames=("cache",)
+            ))
         elif paged:
             self._swap_in = self._with_mesh(jax.jit(
                 kvpaged.swap_in_pages, donate_argnames=("cache",)
@@ -981,9 +1005,9 @@ class InferenceEngine:
             return dataclasses.replace(
                 cache, pos=jnp.zeros((self.n_slots,), jnp.int32)
             )
-        if self._latent and not force_dense:
-            return self._latent_pool(cfg, self.n_pages, self.page_size,
-                                     self.n_slots, self.max_pages_per_row)
+        if self._paged_pool is not None and not force_dense:
+            return self._paged_pool(cfg, self.n_pages, self.page_size,
+                                    self.n_slots, self.max_pages_per_row)
         if self._state_rows:
             cache = kvstate.init_state(
                 cfg.num_hidden_layers, self.n_slots,
@@ -1136,6 +1160,23 @@ class InferenceEngine:
         logits, cache, experts = self._forward_routing(
             forward, params, tokens, cache, "prefill", {})
         return (logits[0, last_idx], cache.lat,
+                None if experts is None else experts[:, 0])
+
+    def _hybrid_prefill_impl(self, forward, params, k, v, conv, ssm, row_bt,
+                             pos0, tokens, last_idx, slot):
+        """`_paged_prefill_impl` for a model whose slot holds a state row
+        beside its pages: ONE slot's prompt (or the next chunk of it), its
+        keys and values written into the row's pages and its state, from
+        nothing when `pos0` is 0 and else from the row's own, into state row
+        `slot` (all four donated). The positions past `last_idx` leave the
+        state and the convolution's tail as they were."""
+        cache = kvhybrid.HybridCache(
+            k=k, v=v, conv=conv, ssm=ssm, block_tables=row_bt, pos=pos0,
+            start=jnp.zeros((1,), jnp.int32), rows=slot,
+            valid_len=last_idx[None] + 1)
+        logits, cache, experts = self._forward_routing(
+            forward, params, tokens, cache, "prefill", {})
+        return (logits[0, last_idx], cache.k, cache.v, cache.conv, cache.ssm,
                 None if experts is None else experts[:, 0])
 
     def _forward_routing(self, forward, params, tokens, cache, mode, kw):
@@ -1631,6 +1672,16 @@ class InferenceEngine:
             self.cache = dataclasses.replace(self.cache, lat=lat)
             # the expanded form up-projects the row's whole capacity
             st.upprojected += self.cache.max_len
+        elif self._hybrid:
+            c = self.cache
+            logits_last, k, v, conv, ssm, moe = self._paged_prefill(
+                self.model.params, c.k, c.v, c.conv, c.ssm, *where,
+                jnp.asarray([st.slot], jnp.int32))
+            if moe is not None:
+                st.moe.append((moe, n))
+            self.cache = dataclasses.replace(c, k=k, v=v, conv=conv, ssm=ssm)
+            st.state_chunks += kvhybrid.prefill_chunks(
+                bucket, self.config.mamba_chunk_size)
         else:
             logits_last, k, v, ks, vs, moe = self._paged_prefill(
                 self.model.params, self.cache.k, self.cache.v,
@@ -1781,9 +1832,12 @@ class InferenceEngine:
             pos = self.pages.pos[slot]
             keep = self.pages.kv_pages(slot)
             n_keep = len(keep)
-            blob = (kvstate.swap_out_rows if self._state_rows
-                    else kvpaged.swap_out_latent if self._latent
-                    else kvpaged.swap_out_pages)(self.cache, keep)
+            if self._hybrid:  # the pages and the slot's state row
+                blob = kvhybrid.swap_out(self.cache, keep, slot)
+            else:
+                blob = (kvstate.swap_out_rows if self._state_rows
+                        else kvpaged.swap_out_latent if self._latent
+                        else kvpaged.swap_out_pages)(self.cache, keep)
             start = 0
         else:
             pos = int(np.asarray(self.cache.pos[slot]))
@@ -1832,6 +1886,8 @@ class InferenceEngine:
             b = entry.blob
             parked = ((b.S, b.z) if self._state_rows
                       else (b.lat,) if self._latent
+                      else (b.k, b.v, b.conv, b.ssm, jnp.asarray(slot))
+                      if self._hybrid
                       else (b.k, b.v, b.k_scale, b.v_scale))
             self.cache = self._swap_in(
                 self.cache, *parked, jnp.asarray(fresh, jnp.int32))
@@ -2376,7 +2432,7 @@ class InferenceEngine:
             if tr is not None and tr.enabled:
                 moe_args = _moe_load(chosen, self.config.num_experts)
             self._admit_moe = []
-        if self._state_rows:
+        if self.state_row_bytes:
             moe_args["state_chunks"] = self._admit_state_chunks
         if self._latent:
             moe_args["latent_tokens_upprojected"] = self._admit_upprojected
@@ -3147,18 +3203,22 @@ class InferenceEngine:
         self._t_read = t1
         self.decode_step_seconds.observe(t1 - t_start)
         rt_rest = self._retrace_mark("decode_step")
+        busy = int(live.sum())
+        # what the step read and wrote again of state rows (0 without them)
+        moved = 2 * busy * self.state_row_bytes
+        self.state_bytes_moved += moved
         tr = self.tracer
         if fl.seq is None or tr is None or not tr.enabled:
             return
         st = self._step_trace
         if st is not None:
             st.decoded = True
-        busy = int(live.sum())
         pages = {}
-        if self._state_rows:  # what the step read and wrote again
+        if self.state_row_bytes:  # in place of pages, or beside them
             pages["state_rows_live"] = busy
-            pages["state_bytes_moved"] = 2 * busy * self.state_row_bytes
-        elif self.paged:  # its pos still holds the step's own
+            pages["state_bytes_moved"] = moved
+        if self.paged and not self._state_rows:
+            # the table's pos still holds the step's own
             pages["live_pages"], pages["grid_pages"] = \
                 self.pages.grid_pages(live)
             if self._latent:  # slots 0 .. pos of every live row

@@ -429,9 +429,10 @@ class Metrics:
                     "# TYPE bigdl_tpu_radix_nodes gauge",
                     f"bigdl_tpu_radix_nodes {pages.radix.n_nodes}",
                 ]
-            if getattr(self.engine, "_state_rows", False):
-                # a model whose slots hold recurrent state rows
-                # (bigdl_tpu/kvstate.py): what a decode step reads and
+            if getattr(self.engine, "state_row_bytes", 0):
+                # a model whose slots hold recurrent state rows (in every
+                # layer, bigdl_tpu/kvstate.py, or beside KV pages,
+                # bigdl_tpu/kvhybrid.py): what a decode step reads and
                 # writes again is the live rows, whatever the contexts
                 lines += [
                     "# HELP bigdl_tpu_state_rows_live slots whose state "
@@ -444,6 +445,11 @@ class Metrics:
                     "# TYPE bigdl_tpu_state_pool_bytes gauge",
                     f"bigdl_tpu_state_pool_bytes "
                     f"{self.engine.state_row_bytes * self.engine.n_slots}",
+                    "# HELP bigdl_tpu_state_bytes_moved_total bytes of "
+                    "state rows that decode steps read and wrote again",
+                    "# TYPE bigdl_tpu_state_bytes_moved_total counter",
+                    f"bigdl_tpu_state_bytes_moved_total "
+                    f"{self.engine.state_bytes_moved}",
                 ]
             if getattr(self.engine, "_latent", False):
                 # a model whose pages hold latents (kvpaged.
@@ -608,6 +614,7 @@ _PAGED_FAMILIES = (
 _STATE_FAMILIES = (
     "bigdl_tpu_state_rows_live",
     "bigdl_tpu_state_pool_bytes",
+    "bigdl_tpu_state_bytes_moved_total",
 )
 
 _LATENT_FAMILIES = (
@@ -644,7 +651,7 @@ def expected_families(engine=None) -> list:
         names += _ENGINE_FAMILIES
         if getattr(engine, "paged", False):
             names += _PAGED_FAMILIES
-        if getattr(engine, "_state_rows", False):
+        if getattr(engine, "state_row_bytes", 0):
             names += _STATE_FAMILIES
         if getattr(engine, "_latent", False):
             names += _LATENT_FAMILIES

@@ -1,0 +1,109 @@
+"""A model PR keeps its code off the other models' trace path.
+
+PR 33 was refused over warm `setup_s` alone (`qwen2-7b.chat-closed` 36.28 ->
+40.72 s): a warm set-up is mostly TRACING the shared forward and the engine's
+programs, and a new mechanism on that path is paid by every cell. So the
+programs the benchmark's other configurations trace are counted here, at toy
+sizes: the equations of `jax.make_jaxpr` of `engine_decode` and
+`engine_paged_prefill` (nested jaxprs counted in) for a tiny Mistral, Qwen2,
+Mixtral, Brumby and GLM-4.7-Flash, pinned to what the tree BEFORE PR 38
+counted (this file run as a script from that tree's root prints them). A
+count, not a time: a change that moves one has to say why and measure warm
+`setup_s` on both sides before it re-pins.
+
+    JAX_PLATFORMS=cpu python tests/test_engine_jaxpr_guard.py
+"""
+
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_DENSE = dict(hidden_size=128, intermediate_size=256, num_hidden_layers=2,
+              num_attention_heads=4, num_key_value_heads=2, vocab_size=512,
+              rms_norm_eps=1e-5, rope_theta=1e4, max_position_embeddings=2048,
+              tie_word_embeddings=False)
+MODELS = {
+    "mistral": dict(_DENSE, model_type="mistral"),
+    "qwen2": dict(_DENSE, model_type="qwen2"),
+    "mixtral": dict(_DENSE, model_type="mixtral", num_local_experts=4,
+                    num_experts_per_tok=2),
+    "brumby": dict(_DENSE, model_type="brumby", head_dim=32),
+    "glm4_moe_lite": dict(
+        _DENSE, model_type="glm4_moe_lite", num_hidden_layers=3,
+        num_key_value_heads=4, moe_intermediate_size=128, n_routed_experts=8,
+        num_experts_per_tok=2, n_shared_experts=1, first_k_dense_replace=1,
+        n_group=1, topk_group=1, topk_method="noaux_tc", norm_topk_prob=True,
+        routed_scaling_factor=1.8, q_lora_rank=128, kv_lora_rank=96,
+        qk_nope_head_dim=32, qk_rope_head_dim=32, v_head_dim=64,
+        rope_scaling=None),
+}
+
+# (engine_decode, engine_paged_prefill) on the parent of PR 38
+PINNED = {
+    "brumby": (577, 488),
+    "glm4_moe_lite": (921, 803),
+    "mistral": (460, 314),
+    "mixtral": (505, 356),
+    "qwen2": (462, 316),
+}
+
+
+def n_eqns(jaxpr) -> int:
+    n = 0
+    for e in jaxpr.eqns:
+        n += 1
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)  # a closed one's own
+                if hasattr(sub, "eqns"):
+                    n += n_eqns(sub)
+    return n
+
+
+def counts(name: str) -> tuple:
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.api import TpuModel, optimize_model
+    from bigdl_tpu.models import get_family
+    from bigdl_tpu.models.config import ModelConfig
+    from bigdl_tpu.serving.engine import InferenceEngine
+
+    cfg = ModelConfig.from_hf_config(MODELS[name])
+    params = optimize_model(
+        get_family(cfg.model_type).init_params(cfg, jax.random.PRNGKey(0)),
+        cfg, "sym_int4")
+    eng = InferenceEngine(TpuModel(cfg, params, "sym_int4"), n_slots=4,
+                          max_len=256, paged=True, page_size=16, n_pages=65)
+    B, c, z = 4, eng.cache, jnp.zeros
+    if hasattr(c, "S"):  # a state row a slot
+        pool = (c.S, c.z)
+    elif hasattr(c, "lat"):  # latent pages
+        pool = (c.lat,)
+    else:
+        pool = (c.k, c.v, c.k_scale, c.v_scale)
+    programs = (
+        (eng._decode, (
+            params, z((B,), jnp.int32), c, jax.random.PRNGKey(0), z((B,)),
+            z((B,), jnp.int32), z((B,)), z((B,), bool), eng.seen, z((B,)))),
+        (eng._paged_prefill, (
+            params, *pool, z((1, eng.max_pages_per_row), jnp.int32),
+            z((1,), jnp.int32), z((1, 64), jnp.int32), z((), jnp.int32))))
+    return tuple(
+        n_eqns(jax.make_jaxpr(getattr(fn, "__wrapped__", fn))(*args).jaxpr)
+        for fn, args in programs)
+
+
+@pytest.mark.core
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_the_other_models_programs_count_what_they_counted(name):
+    assert counts(name) == PINNED[name]
+
+
+if __name__ == "__main__":
+    sys.path.insert(0, os.getcwd())
+    for name in sorted(MODELS):
+        print(f"    {name!r}: {counts(name)},", flush=True)
