@@ -213,8 +213,10 @@ def update_layer(
         return kvpaged.update_layer(cache, layer, k_new, v_new)
     per_row = cache.pos.ndim == 1
     if cache.quantized:
-        kq, ks = _quantize_heads(k_new)
-        vq, vs = _quantize_heads(v_new)
+        # scales in the cache's own type: float16 here, float32 in a row
+        # gathered from fp8 pages (kvpaged.gather_row)
+        kq, ks = _quantize_heads(k_new, cache.k_scale.dtype)
+        vq, vs = _quantize_heads(v_new, cache.v_scale.dtype)
         if per_row:
             k = _scatter_rows(cache.k, layer, cache.pos, kq)
             v = _scatter_rows(cache.v, layer, cache.pos, vq)

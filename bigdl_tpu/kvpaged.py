@@ -10,10 +10,25 @@ prefixes. Here KV lives in pages of `page_size` tokens:
   physical page (unallocated entries may hold anything: reads beyond
   `pos` are masked by attention, and the engine allocates before
   writes);
-- writes scatter through the table; reads gather the row's pages back
-  into the dense [B, S, Hkv, D] view the attention ops consume (the
-  gather moves the same bytes attention reads — a dedicated Pallas
-  paged-attention kernel that indexes pages in place is the follow-up).
+- a decode step scatters its one token a row through the table
+  (`update_layer`) and attends over the pages where they lie: the Pallas
+  kernel `ops/pallas/paged_attention.paged_decode_attention` fetches a
+  row's live pages out of the pool by its own DMA, so no dense view of
+  the cache is ever built;
+- an admission's prefill never handles the pool in its layer loop: it
+  gathers ONE row's pages once for every layer (`gather_row`) into a
+  dense one-row `kvcache.KVCache` at a scalar position, prefills that
+  (a contiguous write, flash attention), and writes back only the pages
+  it wrote, a page a `dynamic_update_slice` (`scatter_row_pages`). The
+  pool is a few GB: as the carry of a scan that scattered into it and
+  sliced a layer out of it, XLA re-laid all of it (a pool whose KV heads
+  do not fill a tile, Qwen2's four) or copied a layer of it (any pool)
+  on every layer of every admission (PERF.md section 6, PR 39);
+- `read_layer`, the gather of every row's pages into the dense
+  [B, S, Hkv, D] view, is what is left for the routes without a kernel:
+  decode on the XLA route (a CPU, alibi, an attention override), a
+  speculative round's verify forward, and the attention layers of
+  `kvhybrid.py`'s prefill.
 
 Pages are allocated on demand and refcounted (`PagePool`), so identical
 prompt prefixes share both storage and prefill compute — the serving
@@ -388,6 +403,70 @@ def copy_latent_page(cache: PagedLatentCache, src, dst) -> PagedLatentCache:
     prefix-sharing copy."""
     return dataclasses.replace(
         cache, lat=cache.lat.at[:, dst].set(cache.lat[:, src]))
+
+
+def gather_row(cache: PagedKVCache):
+    """ONE row's pages (block table [1, max_pages]) of every layer, out of
+    the pool in one gather, as a dense one-row `kvcache.KVCache`
+    [L, 1, max_pages * page, Hkv, D] at the row's scalar position: what an
+    admission's prefill works on, in place of the pool (the engine's
+    `_paged_prefill_impl`). fp8 pages bring codes and scales as they lie."""
+    from bigdl_tpu.kvcache import KVCache
+
+    bt = cache.block_tables[0]
+
+    def row(a):  # [L, n_pages, page, ...] -> [L, 1, max_pages * page, ...]
+        if a is None:
+            return None
+        return a[:, bt].reshape(a.shape[0], 1, -1, *a.shape[3:])
+
+    return KVCache(
+        k=row(cache.k), v=row(cache.v), k_scale=row(cache.k_scale),
+        v_scale=row(cache.v_scale), pos=cache.pos[0], start=cache.start)
+
+
+def pages_spanned(pos: int, n_tokens: int, page: int, max_pages: int) -> int:
+    """Logical pages that `n_tokens` written from slot `pos` touch: what
+    `scatter_row_pages` writes back (the host's count, for the admission's
+    `prefill` span)."""
+    return min((pos + n_tokens - 1) // page, max_pages - 1) - pos // page + 1
+
+
+def scatter_row_pages(cache: PagedKVCache, row, n_tokens: int) -> PagedKVCache:
+    """Write back, through the block table, the pages of `row` (a
+    `gather_row` cache after a prefill of `n_tokens` from `cache.pos[0]`)
+    that the prefill wrote: logical pages `pos // page .. (pos + n_tokens -
+    1) // page`. Their number is static (the most `n_tokens` can span), the
+    first is not; what the count has over the span goes to physical page 0,
+    the scratch sink, where a table's entries past the row's allocation
+    point already. No other page of the pool is written."""
+    page = cache.page_size
+    mp = cache.block_tables.shape[1]
+    pos = cache.pos[0]
+    n = min((n_tokens + page - 2) // page + 1, mp)
+    logical = pos // page + jnp.arange(n, dtype=jnp.int32)
+    written = logical <= jnp.minimum((pos + n_tokens - 1) // page, mp - 1)
+    logical = jnp.minimum(logical, mp - 1)
+    phys = jnp.where(written, cache.block_tables[0, logical], 0)
+
+    fields = [f for f in ("k", "v", "k_scale", "v_scale")
+              if getattr(cache, f) is not None]
+    # the row as its pages [L, max_pages, page, ...], a page an update in
+    # place: a scatter into a pool whose KV heads do not fill a tile has
+    # XLA re-lay the whole pool around it, there and back
+    rows = [r.reshape(r.shape[0], mp, page, *r.shape[3:])
+            for r in (getattr(row, f) for f in fields)]
+
+    def put(i, pools):
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                pool, jax.lax.dynamic_slice_in_dim(r, logical[i], 1, axis=1),
+                (0, phys[i]) + (0,) * (pool.ndim - 2))
+            for pool, r in zip(pools, rows))
+
+    pools = jax.lax.fori_loop(
+        0, n, put, tuple(getattr(cache, f) for f in fields))
+    return dataclasses.replace(cache, **dict(zip(fields, pools)))
 
 
 def update_layer(

@@ -307,6 +307,8 @@ class _PrefillState:
     # prefill form run so far (the `prefill` span's `state_chunks`)
     upprojected: int = 0  # a latent-page model: tokens whose K and V the
     # chunks so far expanded from latents (`latent_tokens_upprojected`)
+    row_pages: int = 0  # KV pages: pool pages the chunks so far gathered
+    pages_written: int = 0  # ... and pool pages they wrote back
 
 
 class InferenceEngine:
@@ -691,6 +693,9 @@ class InferenceEngine:
         # a latent-page model: tokens whose K and V the admission's
         # prefill up-projected from latents (its `prefill` span's argument)
         self._admit_upprojected = 0
+        # KV pages: pool pages the admission's prefill gathered and wrote
+        # back (its `prefill` span's `row_pages` and `pages_written`)
+        self._admit_row_pages = self._admit_pages_written = 0
         self._decode = self._with_mesh(jax.jit(
             _named("engine_decode", self._decode_impl, fwd),
             donate_argnames=("cache", "seen"),
@@ -1116,21 +1121,29 @@ class InferenceEngine:
 
     def _paged_prefill_impl(self, forward, params, k, v, ks, vs, row_bt,
                             pos0, tokens, last_idx, lora=None):
-        """Tail prefill for ONE slot, writing straight into the shared
-        page pool (donated k/v): no dense mini-cache, no insert copy.
+        """Tail prefill for ONE slot on the row's OWN pages: gathered once
+        for every layer into a dense one-row cache at the row's scalar
+        position (what a radix hit shares and what earlier chunks wrote
+        come with them), prefilled as `_prefill_impl` prefills a dense
+        engine's row (a contiguous write and the flash kernel, where
+        `forward` takes it), and only the pages this call wrote scattered
+        back into the donated pool. The pool is the operand of that gather
+        and of that scatter and of nothing else: never the layer loop's
+        carry, never sliced by layer.
         tokens are RIGHT-padded to a bucket; last_idx selects the real
         last token's logits (pad writes land at slots >= pos and are
         overwritten by decode). `lora` = the request's rank-bucketed
         adapter tree (every chunk of a chunked prefill carries it)."""
-        cache = kvpaged.PagedKVCache(
+        pool = kvpaged.PagedKVCache(
             k=k, v=v, k_scale=ks, v_scale=vs, block_tables=row_bt, pos=pos0,
             start=jnp.zeros((1,), jnp.int32),
         )
         kw = {} if lora is None else {"lora": lora}
-        logits, cache, experts = self._forward_routing(
-            forward, params, tokens, cache, "prefill", kw)
-        return (logits[0, last_idx], cache.k, cache.v, cache.k_scale,
-                cache.v_scale, None if experts is None else experts[:, 0])
+        logits, row, experts = self._forward_routing(
+            forward, params, tokens, kvpaged.gather_row(pool), "prefill", kw)
+        pool = kvpaged.scatter_row_pages(pool, row, tokens.shape[1])
+        return (logits[0, last_idx], pool.k, pool.v, pool.k_scale,
+                pool.v_scale, None if experts is None else experts[:, 0])
 
     def _state_prefill_impl(self, forward, params, S, z, row_bt, pos0,
                             tokens, last_idx, lora=None):
@@ -1693,6 +1706,9 @@ class InferenceEngine:
             self.cache = dataclasses.replace(
                 self.cache, k=k, v=v, k_scale=ks, v_scale=vs,
             )
+            st.row_pages += self.max_pages_per_row
+            st.pages_written += kvpaged.pages_spanned(
+                st.written, bucket, self.page_size, self.max_pages_per_row)
         st.written += n
         if not last:
             self._chunk_retrace_s += self._retrace_mark("prefill.dispatch")
@@ -1710,6 +1726,8 @@ class InferenceEngine:
         self._admit_moe, self._admit_moe_start = st.moe, st.start
         self._admit_state_chunks = st.state_chunks
         self._admit_upprojected = st.upprojected
+        self._admit_row_pages = st.row_pages
+        self._admit_pages_written = st.pages_written
         if self.speculative:
             # prefix-cache hits only save TARGET prefill; the draft
             # always prefills its full context into the dense draft pool
@@ -2436,6 +2454,9 @@ class InferenceEngine:
             moe_args["state_chunks"] = self._admit_state_chunks
         if self._latent:
             moe_args["latent_tokens_upprojected"] = self._admit_upprojected
+        if self._admit_row_pages:  # with `page_nbytes`: pool bytes touched
+            moe_args["row_pages"] = self._admit_row_pages
+            moe_args["pages_written"] = self._admit_pages_written
         if req.admit_ts is not None:
             self.prefill_seconds.observe(now - req.admit_ts)
             if tr is not None and tr.enabled:

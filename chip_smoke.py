@@ -614,16 +614,16 @@ def lowered_engine_programs(server, sz: Sizes, tp: int) -> None:
         p_found = kernels_in(prefill.as_text())
         say(f"lowered engine decode step: Mosaic calls {dict(d_found)}")
         say(f"lowered engine prefill (bucket {sz.buckets[1]}): Mosaic "
-            f"calls {dict(p_found)}; attention on the XLA route by "
-            "design (the engine's cache.pos is per row, flash takes a "
-            "scalar q_offset)")
+            f"calls {dict(p_found)}; it works on the row's own pages at a "
+            "scalar position, so its attention is flash")
         if tp == 1:
             if not sz.mosaic:
                 return
             check(d_found["qmatmul"] == 5
                   and d_found["paged_decode_attention"] == 1,
                   f"decode step lowered kernels: {dict(d_found)}")
-            check(p_found["qmatmul"] == 5 and len(p_found) == 1,
+            check(p_found["qmatmul"] == 5 and p_found["flash_attention"] == 1
+                  and len(p_found) == 2,
                   f"prefill lowered kernels: {dict(p_found)}")
             return
         # tp > 1: the projections run per shard under shard_map (so

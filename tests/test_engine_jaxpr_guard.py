@@ -9,7 +9,14 @@ sizes: the equations of `jax.make_jaxpr` of `engine_decode` and
 Mixtral, Brumby and GLM-4.7-Flash, pinned to what the tree BEFORE PR 38
 counted (this file run as a script from that tree's root prints them). A
 count, not a time: a change that moves one has to say why and measure warm
-`setup_s` on both sides before it re-pins.
+`setup_s` on both sides before it re-pins. PR 39 did, for the three models that
+prefill from KV pages: their `engine_paged_prefill` gathers the row's pages,
+prefills a dense row and writes pages back in a loop (314 -> 357 equations for
+the tiny Mistral, on the CPU's mask route), and warm `setup_s` read 35.3 / 36.2
+s against the parent's 37.3 / 37.1 in `qwen2-7b.chat-closed`, 35.1 against 36.5
+in `mistral-7b.chat-steady` (PERF.md section 6, PR 39). The same write-back
+UNROLLED, a `dynamic_update_slice` a page, counted 395 here and cost 5.7 s of
+warm set-up in chat-steady: the count saw it before the chip did.
 
     JAX_PLATFORMS=cpu python tests/test_engine_jaxpr_guard.py
 """
@@ -41,13 +48,14 @@ MODELS = {
         rope_scaling=None),
 }
 
-# (engine_decode, engine_paged_prefill) on the parent of PR 38
+# (engine_decode, engine_paged_prefill) on the parent of PR 38; the paged
+# prefill of mistral, mixtral and qwen2 as PR 39 left it (314, 356, 316 before)
 PINNED = {
     "brumby": (577, 488),
     "glm4_moe_lite": (921, 803),
-    "mistral": (460, 314),
-    "mixtral": (505, 356),
-    "qwen2": (462, 316),
+    "mistral": (460, 357),
+    "mixtral": (505, 399),
+    "qwen2": (462, 359),
 }
 
 

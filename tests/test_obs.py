@@ -388,8 +388,14 @@ def test_phase_spans_partition_admission_and_step(model, kind):
         if p is not first_admitted:
             assert kids[1]["args"]["retrace_s"] == 0
             assert kids[2]["args"]["retrace_s"] == 0
+        # over KV pages also the pool pages the admission gathered (its
+        # row's table, once a chunk) and those it wrote back
+        pool = {"row_pages", "pages_written"} if eng.paged else set()
         assert set(p["args"]) == {"rid", "prompt_tokens", "occupancy",
-                                  "queue_depth"}
+                                  "queue_depth"} | pool
+        if pool:
+            assert 0 < p["args"]["pages_written"] <= p["args"]["row_pages"]
+            assert p["args"]["row_pages"] % eng.max_pages_per_row == 0
         assert 0 <= p["args"]["occupancy"] < eng.n_slots
     # the third request was admitted while the first two were decoding
     assert max(p["args"]["occupancy"] for p in prefills) >= 1

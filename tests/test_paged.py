@@ -103,6 +103,121 @@ def test_paged_engine_matches_dense_engine(model):
     assert out == ref
 
 
+# ---- an admission's prefill works on its row's own pages (ISSUE 39) ---------
+
+def _watch_prefills(eng):
+    """Record, for every call of the admission's program from here on, the
+    row's block table, its position, the bucket, and the pool before and
+    after the call."""
+    calls, inner = [], eng._paged_prefill
+
+    def spy(params, k, v, ks, vs, row_bt, pos0, tokens, last_idx, **kw):
+        pools = [a for a in (k, v, ks, vs) if a is not None]
+        before = [np.asarray(a).view(np.uint8) for a in pools]  # donated next
+        out = inner(params, k, v, ks, vs, row_bt, pos0, tokens, last_idx,
+                    **kw)
+        after = [np.asarray(a).view(np.uint8) for a in out[1:5]
+                 if a is not None]
+        calls.append((np.asarray(row_bt)[0], int(pos0[0]), tokens.shape[1],
+                      before, after))
+        return out
+
+    eng._paged_prefill = spy
+    return calls
+
+
+_ROW_PREFILL_CASES = {
+    # name: (engine options, prompts served BEFORE the watched admission,
+    #        the watched prompt, shared whole pages, calls of the program)
+    "plain": ({}, [], list(range(3, 16)), 0, 1),
+    "radix_hit": ({}, [list(range(10, 26)) + [40, 41]],
+                  list(range(10, 26)) + [50, 51, 52], 2, 1),
+    # 5/8 of page 1 comes by the page copy: the prefill starts mid-page
+    "subpage_hit": ({}, [list(range(10, 26))],
+                    list(range(10, 23)) + [99 + i for i in range(29)], 1, 1),
+    "second_chunk": ({"prefill_chunk_tokens": 16}, [],
+                     [int(t) for t in np.random.default_rng(3).integers(
+                         1, 250, 45)], 0, 3),
+    "fp8": ({"quantize_kv": True}, [], list(range(3, 16)), 0, 1),
+}
+
+
+@pytest.mark.parametrize("route", ["xla", "pallas:flash"])
+@pytest.mark.parametrize("case", list(_ROW_PREFILL_CASES))
+def test_admission_writes_only_its_rows_own_pages(model, case, route,
+                                                  monkeypatch):
+    """The pool holds a sentinel in every page; one admission then leaves
+    every page but those its call wrote (logical pages `pos // page ..
+    (pos + bucket - 1) // page` of its row, and the scratch page 0) bit
+    for bit as it was: the pages a radix hit shares, the pages an earlier
+    chunk wrote, everybody else's. Its tokens and first-token logprob are
+    the dense engine's. On the mask route and with the kernels interpreted:
+    the row's position is a scalar, so the prefill's attention is flash."""
+    from bigdl_tpu.obs.tracing import TraceRecorder
+    from bigdl_tpu.ops import routes
+
+    monkeypatch.setenv("BIGDL_TPU_PALLAS",
+                       "0" if route == "xla" else "interpret")
+    opts, earlier, prompt, shared, n_calls = _ROW_PREFILL_CASES[case]
+    page = 8
+    tr = TraceRecorder(capacity=4096)
+    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
+                          page_size=page, tracer=tr, **opts)
+    c = eng.cache
+    fill = {f: jnp.full_like(getattr(c, f), 0.5)
+            for f in ("k", "v", "k_scale", "v_scale")
+            if getattr(c, f) is not None}
+    eng.cache = dataclasses.replace(c, **fill)
+    for p in earlier:
+        _run(eng, [p], maxnt=4)
+    calls = _watch_prefills(eng)
+    with routes.record_routes() as took:
+        req = eng.submit(prompt, max_new_tokens=6)
+        eng.run_until_idle()
+    assert req.done and not req.error
+    assert len(calls) == n_calls
+    assert {r for op, r, d in took
+            if op == "attention" and "mode=prefill" in d} == {route}
+    assert eng.pages.prefix_hits == (shared > 0)
+
+    first_row, n_written = calls[0][0], 0
+    for row, pos, bucket, before, after in calls:
+        lo, hi = pos // page, (pos + bucket - 1) // page
+        assert hi < len(row)
+        n_written += hi - lo + 1
+        written = {int(row[i]) for i in range(lo, hi + 1)} | {0}
+        kept = [pg for pg in range(eng.n_pages) if pg not in written]
+        assert set(int(pg) for pg in row[:lo]).isdisjoint(written - {0})
+        for b, a in zip(before, after):
+            np.testing.assert_array_equal(a[:, kept], b[:, kept])
+        # and it did write: the first page of the call changed
+        assert any((a[:, row[lo]] != b[:, row[lo]]).any()
+                   for b, a in zip(before, after))
+    # the admission's span says how many pool pages it touched
+    span = [e["args"] for e in tr.events() if e["name"] == "prefill"][-1]
+    assert span["row_pages"] == n_calls * eng.max_pages_per_row
+    assert span["pages_written"] == n_written
+    if shared:  # whole pages of the earlier request's, mapped and kept
+        assert calls[0][1] >= shared * page
+    if case == "subpage_hit":
+        assert eng.pages.prefix_partial_hits == 1 and calls[0][1] % page
+    if case == "second_chunk":
+        assert [c[1] for c in calls] == [0, 16, 32]
+        assert all((c[0] == first_row).all() for c in calls)
+
+    dense = InferenceEngine(model, n_slots=2, max_len=128,
+                            **{k: v for k, v in opts.items()
+                               if k == "quantize_kv"})
+    ref = dense.submit(prompt, max_new_tokens=6)
+    dense.run_until_idle()
+    if case == "fp8":  # the dense pool rounds its scales to float16
+        assert req.out_tokens[:1] == ref.out_tokens[:1]
+    else:
+        assert req.out_tokens == ref.out_tokens
+    assert req.out_logprobs[0] == pytest.approx(ref.out_logprobs[0],
+                                                abs=2e-2)
+
+
 def test_paged_pool_smaller_than_dense_worstcase(model):
     """The pool can be much smaller than slots*max_len and still serve
     (on-demand allocation): 4 slots x 256 logical but only 24 pages x 16

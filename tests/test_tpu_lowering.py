@@ -285,3 +285,65 @@ def test_glms_decode_step_slices_no_packed_stack(one_chip, monkeypatch):
     big = {s for s in sliced
            if sum(int(d) >= 256 for d in s[3:-1].split(",")) >= 2}
     assert big <= {"u8[1,576,1024]"}, big
+
+
+# ---- an admission's prefill on its row's own pages (ISSUE 39) ---------------
+
+@pytest.mark.parametrize("name,bucket", [("qwen2-7b-int4", 256),
+                                         ("mistral-7b-int4", 1024)])
+def test_paged_prefill_handles_no_whole_pool(one_chip, monkeypatch, name,
+                                             bucket):
+    """`engine_paged_prefill` at a cell's own pool (1025 pages of 64 tokens;
+    Qwen2's four KV heads do not fill a tile, Mistral's eight do), two
+    layers, compiled for the chip. In the optimized HLO no `copy` is as
+    large as one layer of the pool (XLA re-laid the whole four-head pool,
+    there and back, around a scatter into it), no `dynamic-slice` cuts a
+    layer's whole pool out for a page gather, the pool comes back aliased,
+    and the attention is the flash kernel."""
+    import math
+    import os
+    import re
+
+    from bench import cells, weights
+    from bigdl_tpu import kvpaged
+    from bigdl_tpu.api import TpuModel
+    from bigdl_tpu.models.config import ModelConfig
+    from bigdl_tpu.serving.engine import InferenceEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = cells.load_json(root, "bench", "configs", name + ".json")
+    cfg = ModelConfig.from_hf_config(
+        dict(cells.as_run(config), num_hidden_layers=2))
+    e, qtype = config["bench"]["engine"], config["bench"]["qtype"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the target
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    # the engine lends its program; its own pool is tiny
+    eng = InferenceEngine(TpuModel(cfg, None, qtype), n_slots=e["n_slots"],
+                          max_len=e["max_len"], paged=True,
+                          page_size=e["page_size"], n_pages=e["n_slots"] + 1)
+    pool = on_chip(jax.eval_shape(lambda: kvpaged.init_paged(
+        2, e["n_pages"], e["page_size"], cfg.num_key_value_heads,
+        cfg.head_dim_, 1, eng.max_pages_per_row)))
+    c = eng._paged_prefill.lower(
+        on_chip(weights.param_shapes(cfg, qtype)), pool.k, pool.v, None,
+        None, _sds((1, eng.max_pages_per_row), jnp.int32, one_chip),
+        _sds((1,), jnp.int32, one_chip), _sds((1, bucket), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), lora=None).compile()
+    text = c.as_text()
+    assert "flash_attention" in text
+
+    layer = pool.k.shape[1:]  # [n_pages, page, Hkv, D]
+    results = re.findall(r"= \w+\[([\d,]+)\]\S* (copy|dynamic-slice)\(", text)
+    assert results
+    for dims, op in results:
+        dims = [int(d) for d in dims.split(",")]
+        if op == "copy":
+            assert math.prod(dims) < math.prod(layer), dims
+        else:
+            assert tuple(d for d in dims if d != 1) != layer, dims
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * pool.k.size * pool.k.dtype.itemsize
+    assert m.temp_size_in_bytes < math.prod(layer) * pool.k.dtype.itemsize
