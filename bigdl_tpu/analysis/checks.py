@@ -373,6 +373,7 @@ class MetricsDrift(Check):
     TARGET = "bigdl_tpu/serving/metrics.py"
     REGISTRY_NAMES = ("_PROCESS_FAMILIES", "_ENGINE_FAMILIES",
                       "_PAGED_FAMILIES", "_STATE_FAMILIES", "_LATENT_FAMILIES",
+                      "_WINDOW_FAMILIES",
                       "_MOE_FAMILIES", "_SPEC_FAMILIES", "_ADAPTER_FAMILIES")
     _TYPE_RE = re.compile(r"# TYPE (bigdl_tpu_\w+) ")
     _FAMILY_RE = re.compile(r"^(bigdl_tpu_\w+)(?:$|[\s{])")

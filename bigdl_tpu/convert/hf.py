@@ -1248,6 +1248,42 @@ def _granitemoehybrid_tree(config: ModelConfig, get: Get, quant
     return runs, top
 
 
+def _smallthinker_tree(config: ModelConfig, get: Get, quant
+                       ) -> tuple[list, dict]:
+    """SmallThinker (PowerInfer's modeling_smallthinker). Returns (one list
+    of per-layer dicts for each POSITION of the layouts' period, top),
+    `quant` applied as tensors stream in: layer `l` is entry `l // P` of
+    position `l % P` (models/smallthinker.py scans over the periods). The
+    router is `block_sparse_moe.primary_router` [E, H], kept unpacked; an
+    expert is three bias-free linears `experts.<e>.{gate,up,down}`, stacked
+    into the `w_gate_e` / `w_up_e` / `w_down_e` the grouped kernel takes."""
+    from bigdl_tpu.models.smallthinker import period
+
+    def one(i: int) -> dict:
+        p = f"model.layers.{i}."
+        a, e = p + "self_attn.", p + "block_sparse_moe."
+        d = {"attn_norm": get(p + "input_layernorm.weight"),
+             "mlp_norm": get(p + "post_attention_layernorm.weight"),
+             "wq": get(a + "q_proj.weight"), "wk": get(a + "k_proj.weight"),
+             "wv": get(a + "v_proj.weight"), "wo": get(a + "o_proj.weight"),
+             "router": get(e + "primary_router.weight")}
+        for ours, theirs in (("w_gate_e", "gate"), ("w_up_e", "up"),
+                             ("w_down_e", "down")):
+            d[ours] = np.stack([
+                np.asarray(get(f"{e}experts.{x}.{theirs}.weight"))
+                for x in range(config.num_experts)])
+        return {k: quant(k, v) for k, v in d.items()}
+
+    P = period(config)
+    positions = [[one(i) for i in range(j, config.num_hidden_layers, P)]
+                 for j in range(P)]
+    top = {"embed": get("model.embed_tokens.weight"),
+           "final_norm": get("model.norm.weight")}
+    if not config.tie_word_embeddings:
+        top["lm_head"] = get("lm_head.weight")
+    return positions, top
+
+
 def layer_tensors(config: ModelConfig, i: int, get: Get) -> dict[str, np.ndarray]:
     fn = _FAMILY_LAYER.get(config.model_type, _llama_layer)
     return fn(config, i, get)
@@ -1352,6 +1388,14 @@ def params_from_state_dict(
         runs, top = _granitemoehybrid_tree(config, get_tensor, maybe_quant)
         params = {"runs": {f"{r:02d}": stack_dicts(run)
                            for r, run in enumerate(runs)}}
+        for k, v in top.items():
+            params[k] = maybe_quant(k, v)
+        return params
+
+    if config.model_type == "smallthinker":
+        positions, top = _smallthinker_tree(config, get_tensor, maybe_quant)
+        params = {"period": {str(j): stack_dicts(layers)
+                             for j, layers in enumerate(positions)}}
         for k, v in top.items():
             params[k] = maybe_quant(k, v)
         return params

@@ -432,19 +432,26 @@ def pages_spanned(pos: int, n_tokens: int, page: int, max_pages: int) -> int:
     return min((pos + n_tokens - 1) // page, max_pages - 1) - pos // page + 1
 
 
-def scatter_row_pages(cache: PagedKVCache, row, n_tokens: int) -> PagedKVCache:
+def scatter_row_pages(cache: PagedKVCache, row, n_tokens: int, first=None,
+                      most: Optional[int] = None) -> PagedKVCache:
     """Write back, through the block table, the pages of `row` (a
     `gather_row` cache after a prefill of `n_tokens` from `cache.pos[0]`)
     that the prefill wrote: logical pages `pos // page .. (pos + n_tokens -
     1) // page`. Their number is static (the most `n_tokens` can span), the
     first is not; what the count has over the span goes to physical page 0,
     the scratch sink, where a table's entries past the row's allocation
-    point already. No other page of the pool is written."""
+    point already. No other page of the pool is written. `first` (traced)
+    with `most` (static, a bound on the pages from `first` to the span's
+    end) writes back the span's END only: a window layer's pages that a
+    later query still reads (kvwindow.scatter_rows)."""
     page = cache.page_size
     mp = cache.block_tables.shape[1]
     pos = cache.pos[0]
     n = min((n_tokens + page - 2) // page + 1, mp)
-    logical = pos // page + jnp.arange(n, dtype=jnp.int32)
+    if most is not None:
+        n = min(n, most)
+    logical = (pos // page if first is None else first) + jnp.arange(
+        n, dtype=jnp.int32)
     written = logical <= jnp.minimum((pos + n_tokens - 1) // page, mp - 1)
     logical = jnp.minimum(logical, mp - 1)
     phys = jnp.where(written, cache.block_tables[0, logical], 0)

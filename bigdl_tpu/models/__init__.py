@@ -166,6 +166,9 @@ _LAZY_FAMILIES = {
     # Mamba-2 and NoPE attention layers mixed by index, a state row beside
     # KV pages in one slot (bigdl_tpu/kvhybrid.py)
     "granitemoehybrid": "bigdl_tpu.models.granitemoehybrid",
+    # window and full attention layers mixed by index, a rope in the window
+    # layers only, two groups of pages in one slot (bigdl_tpu/kvwindow.py)
+    "smallthinker": "bigdl_tpu.models.smallthinker",
 }
 
 

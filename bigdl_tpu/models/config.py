@@ -144,6 +144,10 @@ class ModelConfig:
     mamba_chunk_size: int = 256
     position_embedding_type: str = "rope"  # "nope": no rope call at all
     shared_intermediate_size: Optional[int] = None
+    # smallthinker (models/smallthinker.py): which layers take a rope, by
+    # index (beside `sliding_layers`, which layers take the window); a
+    # layer with 0 has no position encoding at all
+    rope_layers: Optional[tuple] = None
 
     def __post_init__(self):
         if self.attention_kind not in ("softmax", "power_retention"):
@@ -175,7 +179,7 @@ class ModelConfig:
         # (save_low_bit -> load_low_bit) and must re-become tuples or the
         # config stops hashing as a static jit argument
         for f in ("sliding_layers", "cross_attention_layers",
-                  "mrope_section", "layer_types"):
+                  "mrope_section", "layer_types", "rope_layers"):
             v = getattr(self, f)
             if isinstance(v, list):
                 object.__setattr__(self, f, tuple(v))
@@ -222,6 +226,10 @@ class ModelConfig:
         """Build from a HuggingFace config.json dict (the ingest path the
         reference drives through transformers AutoConfig, model.py:111)."""
         model_type = hf.get("model_type", "llama")
+        if "model_type" not in hf and "moe_num_primary_experts" in hf:
+            # PowerInfer's SmallThinker names itself in `model_name` and its
+            # experts "primary": such a dict is no llama
+            model_type = "smallthinker"
         if model_type == "chatglm" and isinstance(hf.get("vision_config"),
                                                   dict):
             # THUDM glm-4v-9b ships model_type "chatglm" + a vision_config
@@ -607,6 +615,43 @@ def _hf_brumby(hf, kw):
     kw["attention_kind"] = "power_retention"
     kw["retention_degree"] = hf.get("retention_degree", 2)
     kw["retention_eps"] = hf.get("retention_eps", 1e-6)
+
+
+def _hf_smallthinker(hf, kw):
+    """SmallThinker (PowerInfer/SmallThinker-21BA3B-Instruct): window and
+    full attention mixed by `sliding_window_layout`, a rope only where
+    `rope_layout` says so, every layer's FFN `moe_num_primary_experts`
+    ReLU-gated experts of `moe_ffn_hidden_size`, top
+    `moe_num_active_primary_experts`, routed from the layer's INPUT. What
+    config.json has no key for (where the router reads, that every layer is
+    sparse, the rope's half-split convention) is the model type's, written
+    in models/smallthinker.py."""
+    L = hf["num_hidden_layers"]
+    window = hf.get("sliding_window_size")
+    sliding = tuple(int(x) for x in hf.get("sliding_window_layout")
+                    or (0,) * L)
+    rope = tuple(int(x) for x in hf.get("rope_layout") or (1,) * L)
+    if len(sliding) != L or len(rope) != L:
+        raise ValueError(
+            f"sliding_window_layout and rope_layout must have "
+            f"num_hidden_layers = {L} entries; got {len(sliding)} and "
+            f"{len(rope)}")
+    if any(sliding) and not window:
+        raise ValueError("sliding_window_layout names window layers but "
+                         "sliding_window_size is not set")
+    if not hf.get("moe_primary_router_apply_softmax", True):
+        raise NotImplementedError(
+            "smallthinker with moe_primary_router_apply_softmax false (a "
+            "sigmoid router): the router is written as a softmax")
+    kw["sliding_window"] = window if any(sliding) else None
+    kw["sliding_layers"], kw["rope_layers"] = sliding, rope
+    kw["num_experts"] = hf["moe_num_primary_experts"]
+    kw["num_experts_per_tok"] = hf["moe_num_active_primary_experts"]
+    kw["moe_intermediate_size"] = hf["moe_ffn_hidden_size"]
+    kw["norm_topk_prob"] = bool(hf.get("norm_topk_prob", True))
+    kw["hidden_act"] = "relu"
+    kw["gated_mlp"] = True
+    kw.setdefault("tie_word_embeddings", False)
 
 
 def _hf_granitemoehybrid(hf, kw):
@@ -996,6 +1041,7 @@ _HF_BUILDERS = {
     "qwen3": _hf_qwen3,
     "brumby": _hf_brumby,
     "granitemoehybrid": _hf_granitemoehybrid,
+    "smallthinker": _hf_smallthinker,
     "qwen3_moe": _hf_qwen3_moe,
     "phi": _hf_phi,
     "cohere": _hf_cohere,
@@ -1078,5 +1124,18 @@ PRESETS: dict[str, ModelConfig] = {
         num_experts_per_tok=3, moe_intermediate_size=32,
         shared_intermediate_size=64, embedding_scale=12,
         residual_scale=0.22, attn_scale=0.0625, logit_scale=0.25,
+    ),
+    # SmallThinker's shape at toy sizes: two periods of one full NoPE layer
+    # and three window layers with a rope, 8 ReLU-gated experts top-3
+    # routed from the layer's input, a window shorter than the tests'
+    # sequences (tests/test_smallthinker.py)
+    "tiny-smallthinker": ModelConfig(
+        model_type="smallthinker", vocab_size=256, hidden_size=64,
+        num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=32, rms_norm_eps=1e-6, rope_theta=1.5e6,
+        max_position_embeddings=256, sliding_window=32,
+        sliding_layers=(0, 1, 1, 1) * 2, rope_layers=(0, 1, 1, 1) * 2,
+        num_experts=8, num_experts_per_tok=3, moe_intermediate_size=32,
+        norm_topk_prob=True, hidden_act="relu",
     ),
 }

@@ -58,7 +58,8 @@ from bigdl_tpu.ops.pallas.tiling import (
 
 #: activations the gated call applies in-kernel (float32, before the
 #: cast); any other gated activation runs as two plain calls + XLA
-FUSED_ACTS = {"silu": jax.nn.silu}
+FUSED_ACTS = {"silu": jax.nn.silu,
+              "relu": lambda g: jnp.maximum(g, 0.0)}
 
 
 def moe_block_m(n_tokens: int, k_max: int) -> int:

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu import kvcache, kvhybrid, kvpaged, kvstate
+from bigdl_tpu import kvcache, kvhybrid, kvpaged, kvstate, kvwindow
 from bigdl_tpu.generate import GenerationConfig, sample_token_per_row
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.obs import retrace
@@ -309,6 +309,10 @@ class _PrefillState:
     # chunks so far expanded from latents (`latent_tokens_upprojected`)
     row_pages: int = 0  # KV pages: pool pages the chunks so far gathered
     pages_written: int = 0  # ... and pool pages they wrote back
+    wrow: Optional[Any] = None  # two groups of pages: the slot's row of
+    # the window group's table, and that group's pages written back
+    # (`pages_written` is then the global group's)
+    window_pages_written: int = 0
 
 
 class InferenceEngine:
@@ -442,32 +446,45 @@ class InferenceEngine:
         # or (c), paged, through a page pool of their own kind when they
         # offer `init_paged_cache` (MLA's latent pages,
         # kvpaged.PagedLatentCache; a hybrid's KV pages with a state row
-        # beside them in every slot, kvhybrid.HybridCache): the page table
-        # books, parks and restores such a page as it does a KV page, and
-        # the kind is chosen here, once, as `kvstate.KIND` is.
+        # beside them in every slot, kvhybrid.HybridCache; a window group
+        # of pages beside the global one, kvwindow.PageGroups): the page
+        # table books, parks and restores such a page as it does a KV page,
+        # and the kind is chosen here, once, as `kvstate.KIND` is.
         fam = model.family
         self._paged_pool = (getattr(fam, "init_paged_cache", None)
                             if paged else None)
         own_kind = getattr(fam, "PAGED_CACHE_KIND", None)
         self._hybrid = (self._paged_pool is not None
                         and own_kind == kvhybrid.KIND)
-        self._latent = self._paged_pool is not None and not self._hybrid
-        if own_kind == kvhybrid.KIND and not paged:
+        self._groups = (self._paged_pool is not None
+                        and own_kind == kvwindow.KIND)
+        self._latent = (self._paged_pool is not None and not self._hybrid
+                        and not self._groups)
+        if own_kind in (kvhybrid.KIND, kvwindow.KIND) and not paged:
             raise NotImplementedError(
-                f"{kvhybrid.KIND} ({model.config.model_type}) is served "
-                "with paged=True: a slot holds KV pages for the attention "
-                "layers and a state row for the others")
+                f"{own_kind} ({model.config.model_type}) is served with "
+                "paged=True: a slot holds KV pages for the attention layers "
+                + ("and a state row for the others"
+                   if own_kind == kvhybrid.KIND else
+                   "in two groups, and frees the window group's behind the "
+                   "window"))
         if self._paged_pool is not None:
-            kind = (f"{kvhybrid.KIND} ({model.config.model_type})"
-                    if self._hybrid
-                    else f"latent pages ({model.config.model_type})")
+            kind, where = (
+                (f"{kvhybrid.KIND} ({model.config.model_type})", "R4")
+                if self._hybrid else
+                (f"{kvwindow.KIND} ({model.config.model_type})", "R3")
+                if self._groups else
+                (f"latent pages ({model.config.model_type})", "R1"))
             for asked, what in ((quantize_kv, "quantize_kv"),
                                 (speculative, "speculative serving"),
-                                (adapters is not None, "adapter serving")):
+                                (adapters is not None, "adapter serving"),
+                                (self._groups
+                                 and prefill_chunk_tokens is not None,
+                                 "prefill_chunk_tokens")):
                 if asked:
                     raise NotImplementedError(
                         f"{what} is not wired for {kind} yet (ROADMAP "
-                        f"{'R4' if self._hybrid else 'R1'})")
+                        f"{where})")
         self._family_cache = None
         self._family_pool = getattr(fam, "engine_pool", None)
         self._family_insert = getattr(fam, "engine_insert", None)
@@ -561,8 +578,12 @@ class InferenceEngine:
         self.pages = PageTable(
             n_slots, self.n_pages, page_size, self.max_pages_per_row,
             max_len, faults=self._faults,
-            # a prefix hit would need the state at the prefix's end
-            share_prefixes=not (self._state_rows or self._hybrid),
+            # a prefix hit would need the state at the prefix's end, or
+            # the window pages of the prefix's last `window` tokens, which
+            # the request that wrote them has freed by then
+            share_prefixes=not (self._state_rows or self._hybrid
+                                or self._groups),
+            window=self.config.sliding_window if self._groups else None,
         ) if paged else None
         self._rng = jax.random.PRNGKey(seed)
         # queue.Queue (not SimpleQueue): the queue-deadline sweep filters
@@ -696,6 +717,10 @@ class InferenceEngine:
         # KV pages: pool pages the admission's prefill gathered and wrote
         # back (its `prefill` span's `row_pages` and `pages_written`)
         self._admit_row_pages = self._admit_pages_written = 0
+        # two groups of pages: the window group's share of those written,
+        # and the table's count of freed pages at the last decode span
+        self._admit_window_pages_written = 0
+        self._window_freed_noted = 0
         self._decode = self._with_mesh(jax.jit(
             _named("engine_decode", self._decode_impl, fwd),
             donate_argnames=("cache", "seen"),
@@ -725,6 +750,10 @@ class InferenceEngine:
             paged_prefill = jax.jit(
                 _named("engine_paged_prefill", self._hybrid_prefill_impl, fwd),
                 donate_argnames=("k", "v", "conv", "ssm"))
+        elif self._groups:
+            paged_prefill = jax.jit(
+                _named("engine_paged_prefill", self._groups_prefill_impl, fwd),
+                donate_argnames=("k", "v", "kw", "vw"))
         else:
             paged_prefill = jax.jit(
                 _named("engine_paged_prefill", self._paged_prefill_impl, fwd),
@@ -937,6 +966,10 @@ class InferenceEngine:
         elif self._hybrid:
             self._swap_in = self._with_mesh(jax.jit(
                 kvhybrid.swap_in, donate_argnames=("cache",)
+            ))
+        elif self._groups:
+            self._swap_in = self._with_mesh(jax.jit(
+                kvwindow.swap_in, donate_argnames=("cache",)
             ))
         elif paged:
             self._swap_in = self._with_mesh(jax.jit(
@@ -1190,6 +1223,26 @@ class InferenceEngine:
         logits, cache, experts = self._forward_routing(
             forward, params, tokens, cache, "prefill", {})
         return (logits[0, last_idx], cache.k, cache.v, cache.conv, cache.ssm,
+                None if experts is None else experts[:, 0])
+
+    def _groups_prefill_impl(self, forward, params, k, v, kw, vw, row_bt,
+                             row_wbt, pos0, tokens, last_idx):
+        """`_paged_prefill_impl` for a model whose slot holds two groups of
+        pages: ONE slot's prompt on the row's own pages of both groups,
+        gathered once into the dense form at the row's scalar position, and
+        written back a page at a time (all four pools donated): the global
+        group's pages whole, the window group's only from the first page a
+        query at the prompt's end (`pos0 + last_idx + 1`) still reads."""
+        pool = kvwindow.PageGroups(
+            k=k, v=v, kw=kw, vw=vw, block_tables=row_bt,
+            window_tables=row_wbt, pos=pos0,
+            start=jnp.zeros((1,), jnp.int32))
+        logits, row, experts = self._forward_routing(
+            forward, params, tokens, kvwindow.gather_rows(pool), "prefill",
+            {"logits_at": last_idx})  # the head on the last token alone
+        pool = kvwindow.scatter_rows(pool, row, tokens.shape[1],
+                                     last_idx + 1, self.config.sliding_window)
+        return (logits[0, 0], pool.k, pool.v, pool.kw, pool.vw,
                 None if experts is None else experts[:, 0])
 
     def _forward_routing(self, forward, params, tokens, cache, mode, kw):
@@ -1623,7 +1676,7 @@ class InferenceEngine:
         st = _PrefillState(
             req=req, slot=slot, row=plan.row, written=plan.covered,
             path=plan.path, chunk=chunk if chunked else rest,
-            start=plan.covered,
+            start=plan.covered, wrow=plan.wrow,
         )
         if chunked:
             # chunk plan: the slot is HELD (req set, active False, its
@@ -1695,6 +1748,20 @@ class InferenceEngine:
             self.cache = dataclasses.replace(c, k=k, v=v, conv=conv, ssm=ssm)
             st.state_chunks += kvhybrid.prefill_chunks(
                 bucket, self.config.mamba_chunk_size)
+        elif self._groups:
+            c = self.cache
+            logits_last, k, v, kw, vw, moe = self._paged_prefill(
+                self.model.params, c.k, c.v, c.kw, c.vw, where[0],
+                jnp.asarray(st.wrow[None]), *where[1:])
+            if moe is not None:
+                st.moe.append((moe, n))
+            self.cache = dataclasses.replace(c, k=k, v=v, kw=kw, vw=vw)
+            st.row_pages += 2 * self.max_pages_per_row
+            st.window_pages_written += kvwindow.window_pages_spanned(
+                st.written, bucket, n, self.config.sliding_window,
+                self.page_size, self.max_pages_per_row)
+            st.pages_written += kvpaged.pages_spanned(
+                st.written, bucket, self.page_size, self.max_pages_per_row)
         else:
             logits_last, k, v, ks, vs, moe = self._paged_prefill(
                 self.model.params, self.cache.k, self.cache.v,
@@ -1715,7 +1782,7 @@ class InferenceEngine:
             return None
         slot = st.slot
         self._prefilling = None
-        self.pages.install(slot, st.row, len(prompt))
+        self.pages.install(slot, st.row, len(prompt), st.wrow)
         self.cache = dataclasses.replace(
             self.cache,
             pos=self.cache.pos.at[slot].set(len(prompt)),
@@ -1728,6 +1795,7 @@ class InferenceEngine:
         self._admit_upprojected = st.upprojected
         self._admit_row_pages = st.row_pages
         self._admit_pages_written = st.pages_written
+        self._admit_window_pages_written = st.window_pages_written
         if self.speculative:
             # prefix-cache hits only save TARGET prefill; the draft
             # always prefills its full context into the dense draft pool
@@ -1852,6 +1920,9 @@ class InferenceEngine:
             n_keep = len(keep)
             if self._hybrid:  # the pages and the slot's state row
                 blob = kvhybrid.swap_out(self.cache, keep, slot)
+            elif self._groups:  # the pages of both groups
+                blob = kvwindow.swap_out(self.cache, keep,
+                                         self.pages.window_kv_pages(slot))
             else:
                 blob = (kvstate.swap_out_rows if self._state_rows
                         else kvpaged.swap_out_latent if self._latent
@@ -1906,9 +1977,12 @@ class InferenceEngine:
                       else (b.lat,) if self._latent
                       else (b.k, b.v, b.conv, b.ssm, jnp.asarray(slot))
                       if self._hybrid
+                      else (b.k, b.v, b.kw, b.vw) if self._groups
                       else (b.k, b.v, b.k_scale, b.v_scale))
-            self.cache = self._swap_in(
-                self.cache, *parked, jnp.asarray(fresh, jnp.int32))
+            into = (jnp.asarray(fresh, jnp.int32),)
+            if self._groups:
+                into += (jnp.asarray(self.pages.win_pages[slot], jnp.int32),)
+            self.cache = self._swap_in(self.cache, *parked, *into)
             self.cache = dataclasses.replace(
                 self.cache,
                 pos=self.cache.pos.at[slot].set(entry.pos),
@@ -2454,7 +2528,12 @@ class InferenceEngine:
             moe_args["state_chunks"] = self._admit_state_chunks
         if self._latent:
             moe_args["latent_tokens_upprojected"] = self._admit_upprojected
-        if self._admit_row_pages:  # with `page_nbytes`: pool bytes touched
+        if self._groups:  # the pages written back, by group
+            moe_args["row_pages"] = self._admit_row_pages
+            moe_args["pages_written_global"] = self._admit_pages_written
+            moe_args["pages_written_window"] = \
+                self._admit_window_pages_written
+        elif self._admit_row_pages:  # with `page_nbytes`: pool bytes touched
             moe_args["row_pages"] = self._admit_row_pages
             moe_args["pages_written"] = self._admit_pages_written
         if req.admit_ts is not None:
@@ -3056,8 +3135,10 @@ class InferenceEngine:
         """Send the block table where the host's mirror has changed."""
         bt = self.pages.block_table() if self.paged else None
         if bt is not None:
+            both = ({"window_tables": jnp.asarray(self.pages.window_table)}
+                    if self._groups else {})
             self.cache = dataclasses.replace(
-                self.cache, block_tables=jnp.asarray(bt))
+                self.cache, block_tables=jnp.asarray(bt), **both)
         return bt is not None
 
     def _upload_sampling(self) -> tuple:
@@ -3238,7 +3319,15 @@ class InferenceEngine:
         if self.state_row_bytes:  # in place of pages, or beside them
             pages["state_rows_live"] = busy
             pages["state_bytes_moved"] = moved
-        if self.paged and not self._state_rows:
+        if self._groups:
+            # by group, and no one-pool count: a window layer loads fewer
+            # pages than `pos` spans. The table's pos still holds the
+            # step's own; freed = since the step before
+            pages.update(self.pages.group_pages(live))
+            freed = self.pages.window_pages_freed
+            pages["window_pages_freed"] = freed - self._window_freed_noted
+            self._window_freed_noted = freed
+        elif self.paged and not self._state_rows:
             # the table's pos still holds the step's own
             pages["live_pages"], pages["grid_pages"] = \
                 self.pages.grid_pages(live)

@@ -451,6 +451,26 @@ class Metrics:
                     f"bigdl_tpu_state_bytes_moved_total "
                     f"{self.engine.state_bytes_moved}",
                 ]
+            if getattr(self.engine, "_groups", False):
+                # a model whose slots hold two groups of pages
+                # (bigdl_tpu/kvwindow.py): the window group's go back to
+                # their pool behind the window, while the request decodes
+                in_use = self.engine.pages.pages_in_use()
+                lines += [
+                    "# HELP bigdl_tpu_global_pages_in_use pages of the "
+                    "global group (full-attention layers) some slot holds",
+                    "# TYPE bigdl_tpu_global_pages_in_use gauge",
+                    f"bigdl_tpu_global_pages_in_use {in_use[0]}",
+                    "# HELP bigdl_tpu_window_pages_in_use pages of the "
+                    "window group (window layers) some slot holds",
+                    "# TYPE bigdl_tpu_window_pages_in_use gauge",
+                    f"bigdl_tpu_window_pages_in_use {in_use[1]}",
+                    "# HELP bigdl_tpu_window_pages_freed_total window pages "
+                    "given back behind the window by requests still decoding",
+                    "# TYPE bigdl_tpu_window_pages_freed_total counter",
+                    f"bigdl_tpu_window_pages_freed_total "
+                    f"{self.engine.pages.window_pages_freed}",
+                ]
             if getattr(self.engine, "_latent", False):
                 # a model whose pages hold latents (kvpaged.
                 # PagedLatentCache): pages in use, and what they hold
@@ -617,6 +637,12 @@ _STATE_FAMILIES = (
     "bigdl_tpu_state_bytes_moved_total",
 )
 
+_WINDOW_FAMILIES = (
+    "bigdl_tpu_global_pages_in_use",
+    "bigdl_tpu_window_pages_in_use",
+    "bigdl_tpu_window_pages_freed_total",
+)
+
 _LATENT_FAMILIES = (
     "bigdl_tpu_latent_pages_in_use",
     "bigdl_tpu_latent_token_bytes",
@@ -653,6 +679,8 @@ def expected_families(engine=None) -> list:
             names += _PAGED_FAMILIES
         if getattr(engine, "state_row_bytes", 0):
             names += _STATE_FAMILIES
+        if getattr(engine, "_groups", False):
+            names += _WINDOW_FAMILIES
         if getattr(engine, "_latent", False):
             names += _LATENT_FAMILIES
         if getattr(engine, "_moe_routing", False):

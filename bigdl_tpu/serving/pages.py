@@ -26,6 +26,7 @@ from typing import Optional
 import numpy as np
 
 from bigdl_tpu.kvpaged import PagePool
+from bigdl_tpu.kvwindow import first_live_page, window_pool_pages
 from bigdl_tpu.serving.faults import NULL_INJECTOR
 from bigdl_tpu.serving.radix import RadixPrefixCache
 from bigdl_tpu.utils import round_up
@@ -55,6 +56,8 @@ class Reservation:
     copy: Optional[tuple[int, int]]  # (source, destination) page to copy
     # on the device before the prefill: a prefix that diverges mid-page
     path: list  # the matched radix nodes, for `register_prefix`
+    wrow: Optional[np.ndarray] = None  # two groups of pages: the slot's
+    # row of the WINDOW group's table, `install`ed with `row`
 
 
 class PageTable:
@@ -64,7 +67,15 @@ class PageTable:
     Physical page 0 is the scratch sink: idle slots still run the decode
     step (static-shape price) and their masked garbage writes go through
     their block tables, so a released slot's row points every entry at
-    page 0 and can never corrupt pages reallocated to live requests."""
+    page 0 and can never corrupt pages reallocated to live requests.
+
+    With `window` set the slot holds TWO lists of pages (bigdl_tpu/
+    kvwindow.py): the global group's, everything above, and the window
+    group's, out of a pool of its own (`wpool`, its own scratch page 0).
+    The two are booked and extended together to the same logical end; a
+    window page goes back to its pool in the step whose `advance` carries
+    `pos - window` past its last position (`_free_behind`), and its entry
+    of the window table goes back to the scratch page."""
 
     #: what `rebuilt` carries over: engine totals, not cache state
     TOTALS = ("prefix_hits", "prefix_partial_hits", "prefix_tokens_reused",
@@ -72,7 +83,8 @@ class PageTable:
 
     def __init__(self, n_slots: int, n_pages: int, page_size: int,
                  max_pages_per_row: int, max_len: int,
-                 faults=NULL_INJECTOR, share_prefixes: bool = True):
+                 faults=NULL_INJECTOR, share_prefixes: bool = True,
+                 window: Optional[int] = None):
         self.n_slots = n_slots
         # False for a model whose page is a recurrent state ROW
         # (bigdl_tpu/kvstate.py: one page of `max_len` tokens a slot, page
@@ -105,6 +117,18 @@ class PageTable:
         self.prefix_partial_hits = 0
         self.prefix_tokens_reused = 0
         self.prefix_evictions = 0  # radix leaves dropped for pages
+        # two groups of pages: the window group's pool, each slot's pages
+        # (logical page `win_first[slot] + i` is `win_pages[slot][i]`) and
+        # the window table's mirror, uploaded with the block table's
+        self.window = window
+        self.wpool = None
+        if window is not None:
+            self.wpool = PagePool(window_pool_pages(n_slots, window,
+                                                    page_size))
+            self.win_pages: list[list[int]] = [[] for _ in range(n_slots)]
+            self.win_first = [0] * n_slots
+            self.window_table = np.zeros_like(self._bt)
+            self.window_pages_freed = 0  # behind the window, ever
 
     def rebuilt(self) -> "PageTable":
         """The table as the constructor makes it, for a device pool that
@@ -115,7 +139,9 @@ class PageTable:
         them in again)."""
         new = PageTable(self.n_slots, self.pool.n_pages, self.page_size,
                         self.max_pages_per_row, self.max_len, self._faults,
-                        self.share_prefixes)
+                        self.share_prefixes, self.window)
+        if self.window is not None:
+            new.window_pages_freed = self.window_pages_freed
         for name in self.TOTALS:
             setattr(new, name, getattr(self, name))
         if self.pager is not None:
@@ -135,6 +161,8 @@ class PageTable:
         engine's, which chooses a victim: _alloc_page_preempting)."""
         if self._faults.fire("alloc_page") is not None:
             return None  # injected pool exhaustion (serving/faults.py)
+        if self.wpool is not None and not self.wpool.n_free:
+            return None  # the groups grow together: neither without the other
         pg = self.pool.alloc()
         while pg is None and self.radix.evict_one():
             self.prefix_evictions += 1
@@ -208,6 +236,15 @@ class PageTable:
                 f"{self.pool.n_pages - 1}; raise n_pages or shorten the "
                 "prompt"
             )
+        w_first = 0
+        if self.window is not None:
+            # the window group: the pages a query at the prompt's end still
+            # reads, up to the global group's end
+            w_first = first_live_page(len(prompt), self.window, page)
+            if n_hit + need - w_first > self.wpool.n_pages - 1:
+                raise NeverFits(
+                    f"prompt needs {n_hit + need - w_first} window pages "
+                    f"but that pool only has {self.wpool.n_pages - 1}")
         # incref shared pages (and the sub-page copy source) BEFORE
         # allocating fresh ones — alloc's radix eviction must not evict
         # a page out of this very request's prefix (cache-only holds
@@ -228,6 +265,18 @@ class PageTable:
                     self.pool.decref(src_page)
                 return None
             fresh.append(pg)
+        wrow = None
+        if self.window is not None:
+            held = self._window_pages(n_hit + need - w_first)
+            if held is None:  # roll back both groups, retry next step
+                for q in fresh:
+                    self.pool.decref(q)
+                for q in shared:
+                    self.pool.decref(q)
+                if src_page is not None:
+                    self.pool.decref(src_page)
+                return None
+            wrow = self._seat_window(slot, w_first, held)
         if n_hit:
             self.prefix_hits += 1
         row = self._seat(slot, shared + fresh)
@@ -242,7 +291,7 @@ class PageTable:
             self.prefix_tokens_reused += t_copy
             self.radix.touch(src_node)  # it just proved hot
             copy = (src_page, fresh[0])
-        return Reservation(row, lp + t_copy, copy, path)
+        return Reservation(row, lp + t_copy, copy, path, wrow)
 
     def _seat(self, slot: int, table: list[int]) -> np.ndarray:
         """`table` becomes the slot's pages; returns its block-table row."""
@@ -254,9 +303,33 @@ class PageTable:
         row[: len(table)] = table
         return row
 
-    def install(self, slot: int, row: np.ndarray, pos: int) -> None:
+    def _window_pages(self, n: int) -> Optional[list[int]]:
+        """`n` fresh pages of the window group, or None with nothing held."""
+        held: list[int] = []
+        for _ in range(n):
+            pg = self.wpool.alloc()
+            if pg is None:
+                for q in held:
+                    self.wpool.decref(q)
+                return None
+            held.append(pg)
+        return held
+
+    def _seat_window(self, slot: int, first: int,
+                     held: list[int]) -> np.ndarray:
+        """`held` become the slot's window pages from logical page `first`;
+        returns its row of the window table."""
+        self.win_first[slot], self.win_pages[slot] = first, held
+        wrow = np.zeros((self.max_pages_per_row,), np.int32)
+        wrow[first: first + len(held)] = held
+        return wrow
+
+    def install(self, slot: int, row: np.ndarray, pos: int,
+                wrow: Optional[np.ndarray] = None) -> None:
         """The slot's KV is written up to `pos` and the decode step may
         go through its pages: until now its row pointed at scratch."""
+        if wrow is not None:
+            self.window_table[slot] = wrow
         self._bt[slot] = row
         self._bt_dirty = True
         self.pos[slot] = pos
@@ -290,7 +363,12 @@ class PageTable:
         return len(self.slot_pages[slot]) >= self.max_pages_per_row
 
     def extend(self, slot: int, pg: int) -> None:
-        """One more whole page at the end of the slot's row."""
+        """One more whole page at the end of the slot's row (and of its
+        window group's: `alloc` gave `pg` only with a window page free)."""
+        if self.window is not None:
+            wpg = self.wpool.alloc()
+            self.window_table[slot, len(self.slot_pages[slot])] = wpg
+            self.win_pages[slot].append(wpg)
         self._bt[slot, len(self.slot_pages[slot])] = pg
         self._bt_dirty = True
         self.slot_pages[slot].append(pg)
@@ -299,28 +377,69 @@ class PageTable:
     def advance(self, slot: int, n: int = 1) -> None:
         """The decode step wrote `n` more tokens of this slot."""
         self.pos[slot] += n
+        if self.window is not None:
+            self._free_behind(slot)
+
+    def _free_behind(self, slot: int) -> None:
+        """Give back the slot's window pages whose last position the window
+        has passed: the next query, at `pos`, reads none of them."""
+        live = first_live_page(self.pos[slot], self.window, self.page_size)
+        pages = self.win_pages[slot]
+        n = min(live - self.win_first[slot], len(pages))
+        if n <= 0:
+            return
+        for pg in pages[:n]:
+            self.wpool.decref(pg)
+        del pages[:n]
+        first = self.win_first[slot]
+        self.window_table[slot, first: first + n] = 0
+        self.win_first[slot] = first + n
+        self.window_pages_freed += n
+        self._bt_dirty = True
 
     def kv_pages(self, slot: int) -> list[int]:
         """The slot's pages that hold real KV, in order: what a swap-out
         to host RAM must carry."""
         return self.slot_pages[slot][: -(-self.pos[slot] // self.page_size)]
 
+    def window_kv_pages(self, slot: int) -> list[int]:
+        """The slot's window pages that hold real KV, in order: from the
+        window's first page to the one before `pos`."""
+        n = -(-self.pos[slot] // self.page_size) - self.win_first[slot]
+        return self.win_pages[slot][: max(n, 0)]
+
     def restore(self, slot: int, n_pages: int,
                 pos: int) -> Optional[list[int]]:
         """Fresh pages for a parked request's swap-in (physical placement
         is irrelevant, the block table re-maps it), installed as the
         slot's row. None = the pool cannot hold the restore yet, and
-        nothing is held."""
+        nothing is held. With two groups the window's pages at `pos` come
+        with them (`win_pages[slot]`, as many as `window_kv_pages` gave)."""
+        wrow = None
+        if self.window is not None:
+            first = first_live_page(pos, self.window, self.page_size)
+            held = self._window_pages(n_pages - first)
+            if held is None:
+                return None
+            wrow = self._seat_window(slot, first, held)
         fresh: list[int] = []
         for _ in range(n_pages):
             pg = self.alloc()
             if pg is None:  # roll back; retry when pages free up
                 for q in fresh:
                     self.pool.decref(q)
+                if wrow is not None:
+                    self._drop_window(slot)
                 return None
             fresh.append(pg)
-        self.install(slot, self._seat(slot, fresh), pos)
+        self.install(slot, self._seat(slot, fresh), pos, wrow)
         return fresh
+
+    def _drop_window(self, slot: int) -> None:
+        for pg in self.win_pages[slot]:
+            self.wpool.decref(pg)
+        self.win_pages[slot], self.win_first[slot] = [], 0
+        self.window_table[slot] = 0
 
     def release(self, slot: int) -> None:
         """Drop the slot's holds (a count reaching 0 frees the page;
@@ -328,6 +447,8 @@ class PageTable:
         the scratch page and park its position."""
         for pg in self.slot_pages[slot]:
             self.pool.decref(pg)
+        if self.window is not None:
+            self._drop_window(slot)
         self.slot_pages[slot] = []
         self.written[slot] = 0
         self.pos[slot] = 0
@@ -345,9 +466,41 @@ class PageTable:
     # ---- what /metrics, the sim report and the tests read ------------------
 
     def utilization(self) -> float:
-        """Allocated pages over the allocatable pool (page 0 is scratch)."""
+        """Allocated pages over the allocatable pool (page 0 is scratch);
+        of both pools where there are two."""
         cap = self.pool.n_pages - 1
-        return (cap - self.pool.n_free) / max(cap, 1)
+        free = self.pool.n_free
+        if self.wpool is not None:
+            cap += self.wpool.n_pages - 1
+            free += self.wpool.n_free
+        return (cap - free) / max(cap, 1)
+
+    def pages_in_use(self) -> tuple[int, int]:
+        """(global, window) pages some holder has, of two groups."""
+        return (self.pool.n_pages - 1 - self.pool.n_free,
+                self.wpool.n_pages - 1 - self.wpool.n_free)
+
+    def group_pages(self, active: np.ndarray) -> dict:
+        """`grid_pages` of two groups, the decode step's span arguments:
+        live = the pages the paged kernel must load for a layer of that
+        group (the global group's up to each active row's `pos`, the window
+        group's from `max(0, pos - window + 1)` on), grid = slots x pages a
+        row; the window pages the active rows hold, and what they would
+        hold had none been freed (the global group's, booked alike)."""
+        mp, page = self.max_pages_per_row, self.page_size
+        rows = [int(i) for i in np.nonzero(active)[0]]
+        last = [min(self.pos[i] // page, mp - 1) for i in rows]
+        return {
+            "live_pages_global": sum(p + 1 for p in last),
+            "grid_pages_global": self.n_slots * mp,
+            "live_pages_window": sum(
+                p - first_live_page(self.pos[i], self.window, page) + 1
+                for i, p in zip(rows, last)),
+            "grid_pages_window": self.n_slots * mp,
+            "window_pages_held": sum(len(self.win_pages[i]) for i in rows),
+            "window_pages_unfreed": sum(len(self.slot_pages[i])
+                                        for i in rows),
+        }
 
     def grid_pages(self, active: np.ndarray) -> tuple[int, int]:
         """(live, grid) pages of the paged decode kernel's next step, from
@@ -373,5 +526,13 @@ class PageTable:
         if self.pager is not None:
             for pg in self.pager.held_pages():
                 held[pg] += 1
-        return sum(1 for pg in range(1, self.pool.n_pages)
-                   if self.pool.ref[pg] != held[pg])
+        leaks = sum(1 for pg in range(1, self.pool.n_pages)
+                    if self.pool.ref[pg] != held[pg])
+        if self.wpool is not None:  # one holder a window page: its slot
+            wheld = [0] * self.wpool.n_pages
+            for pages in self.win_pages:
+                for pg in pages:
+                    wheld[pg] += 1
+            leaks += sum(1 for pg in range(1, self.wpool.n_pages)
+                         if self.wpool.ref[pg] != wheld[pg])
+        return leaks

@@ -17,6 +17,11 @@ s against the parent's 37.3 / 37.1 in `qwen2-7b.chat-closed`, 35.1 against 36.5
 in `mistral-7b.chat-steady` (PERF.md section 6, PR 39). The same write-back
 UNROLLED, a `dynamic_update_slice` a page, counted 395 here and cost 5.7 s of
 warm set-up in chat-steady: the count saw it before the chip did.
+PR 41 adds a tiny SmallThinker with a pin of its own: a family of its own
+(models/smallthinker.py, loaded on first use), so the five above count what
+they counted; its own count is a scan over the periods of its layouts with
+the period's four layers as the body, so it grows with the PERIOD and not
+with the depth (two periods here count what six would).
 
     JAX_PLATFORMS=cpu python tests/test_engine_jaxpr_guard.py
 """
@@ -46,6 +51,15 @@ MODELS = {
         routed_scaling_factor=1.8, q_lora_rank=128, kv_lora_rank=96,
         qk_nope_head_dim=32, qk_rope_head_dim=32, v_head_dim=64,
         rope_scaling=None),
+    # no `model_type`, as the source's config.json keys have none
+    "smallthinker": dict(
+        hidden_size=128, head_dim=32, num_attention_heads=4,
+        num_key_value_heads=2, num_hidden_layers=8, vocab_size=512,
+        rms_norm_eps=1e-6, rope_theta=1.5e6, max_position_embeddings=2048,
+        tie_word_embeddings=False, moe_ffn_hidden_size=128,
+        moe_num_primary_experts=8, moe_num_active_primary_experts=3,
+        sliding_window_size=32, sliding_window_layout=[0, 1, 1, 1] * 2,
+        rope_layout=[0, 1, 1, 1] * 2),
 }
 
 # (engine_decode, engine_paged_prefill) on the parent of PR 38; the paged
@@ -56,6 +70,7 @@ PINNED = {
     "mistral": (460, 357),
     "mixtral": (505, 399),
     "qwen2": (462, 359),
+    "smallthinker": (1404, 1244),  # PR 41's own: a four-layer scan body
 }
 
 
@@ -91,6 +106,9 @@ def counts(name: str) -> tuple:
         pool = (c.S, c.z)
     elif hasattr(c, "lat"):  # latent pages
         pool = (c.lat,)
+    elif hasattr(c, "kw"):  # two groups of pages, a table each
+        pool = (c.k, c.v, c.kw, c.vw,
+                z((1, eng.max_pages_per_row), jnp.int32))
     else:
         pool = (c.k, c.v, c.k_scale, c.v_scale)
     programs = (

@@ -260,3 +260,150 @@ def test_grid_pages_counts_live_rows_only():
     active = np.array([True, False, True])
     assert t.grid_pages(active) == (2 + 1, 3 * 6)
     assert t.utilization() == 8 / 8
+
+
+# ---------------------------------------------------------------------------
+# two groups of pages in one slot (bigdl_tpu/kvwindow.py): `window=`
+# ---------------------------------------------------------------------------
+
+WINDOW = 8  # two pages of PAGE = 4
+
+
+def groups(n_pages=33, n_slots=2, rows=16, max_len=64, window=WINDOW):
+    return PageTable(n_slots, n_pages, PAGE, rows, max_len,
+                     share_prefixes=False, window=window)
+
+
+def admit2(t, slot, prompt):
+    plan = t.reserve(slot, prompt)
+    if plan is not None:
+        t.install(slot, plan.row, len(prompt), plan.wrow)
+    return plan
+
+
+def test_window_pool_is_derived_from_the_slots_and_the_window():
+    t = groups(n_slots=3)
+    assert t.wpool.n_pages == 3 * (WINDOW // PAGE + 2) + 1
+    assert t.pages_in_use() == (0, 0) and t.utilization() == 0.0
+
+
+@pytest.mark.parametrize("n_prompt,first,held", [
+    (3, 0, 4),  # 3 tokens pad to 16 positions: pages 0 .. 3, none dead
+    (16, 2, 2),  # a query at 16 reads from 9 on: pages 2, 3 of 0 .. 3
+    (21, 3, 5),  # pads to 32: pages 3 .. 7
+])
+def test_reserve_books_the_windows_pages_only(n_prompt, first, held):
+    t = groups()
+    plan = admit2(t, 0, seq(n_prompt))
+    n = len(t.slot_pages[0])
+    assert t.win_first[0] == first and len(t.win_pages[0]) == held == n - first
+    assert list(plan.wrow[:first]) == [0] * first
+    assert list(plan.wrow[first:n]) == t.win_pages[0]
+    assert (t.window_table[0] == plan.wrow).all()
+    assert all(t.wpool.ref[pg] == 1 for pg in t.win_pages[0])  # one holder
+    t.release(0)
+    assert t.pages_in_use() == (0, 0) and t.page_leaks() == 0
+    assert not t.window_table.any() and not t.block_table()[0].any()
+
+
+def test_a_window_page_is_freed_in_the_step_that_passes_it():
+    """Decoding one token a step: the slot never holds more than
+    W // P + 2 window pages, a page goes back the step `pos - window` passes
+    its last position, and its table entry goes back to the scratch page."""
+    t = groups()
+    admit2(t, 0, seq(5))  # pads to 16: pages 0 .. 3 in both groups
+    most, freed_at = 0, []
+    for _ in range(40):
+        while t.short(0, 2):  # the engine books one step ahead
+            t.extend(0, t.alloc())
+        before = t.window_pages_freed
+        t.advance(0)
+        if t.window_pages_freed > before:
+            freed_at.append(t.pos[0])
+            assert t.block_table() is not None  # an upload is due
+        most = max(most, len(t.win_pages[0]))
+        first = max(t.pos[0] - WINDOW + 1, 0) // PAGE
+        assert t.win_first[0] == first
+        assert not t.window_table[0, :first].any()
+        assert t.window_table[0, first:len(t.slot_pages[0])].all()
+        assert len(t.slot_pages[0]) == first + len(t.win_pages[0])
+        assert t.page_leaks() == 0
+    assert most <= WINDOW // PAGE + 2
+    # page j's last position is 4j + 3: dead for a query at 4j + 3 + WINDOW
+    assert freed_at == [11, 15, 19, 23, 27, 31, 35, 39, 43]
+    assert t.window_pages_freed == 9
+    held = t.pages_in_use()
+    assert held[0] == len(t.slot_pages[0]) and held[1] == len(t.win_pages[0])
+    t.release(0)
+    assert t.page_leaks() == 0 and t.pages_in_use() == (0, 0)
+
+
+def test_freed_pages_return_to_the_pool_and_serve_another_slot():
+    t = groups(n_slots=2)
+    cap = t.wpool.n_pages - 1
+    admit2(t, 0, seq(5))
+    t.advance(0, 20)  # position 25 reads from 18 on: page 4 is the first
+    assert t.win_pages[0] == [] and t.win_first[0] == 4  # all four were dead
+    assert t.wpool.n_free == cap
+    admit2(t, 1, seq(30))
+    assert t.page_leaks() == 0
+
+
+@pytest.mark.parametrize("room", [True, False])
+def test_restore_brings_both_groups_or_nothing(room):
+    t = groups(n_pages=9 if room else 7)
+    admit2(t, 0, seq(14))  # pads to 16: four pages; window from page 1
+    t.advance(0, 2)  # 16: window from page 2
+    keep, wkeep = t.kv_pages(0), t.window_kv_pages(0)
+    assert len(keep) == 4 and len(wkeep) == 2 and t.win_first[0] == 2
+    if room:
+        t.release(0)
+    free = (t.pool.n_free, t.wpool.n_free)
+    fresh = t.restore(1, len(keep), 16)
+    if room:
+        assert len(fresh) == 4 and t.win_first[1] == 2
+        assert len(t.win_pages[1]) == len(wkeep)
+        assert list(t.window_table[1, :4]) == [0, 0] + t.win_pages[1]
+        assert t.pos[1] == 16 and t.written[1] == 16
+    else:  # two of six global pages are free: nothing is held of either
+        assert fresh is None and t.win_pages[1] == []
+        assert (t.pool.n_free, t.wpool.n_free) == free
+    assert t.page_leaks() == 0
+
+
+def test_a_dry_window_pool_refuses_and_rolls_back():
+    # a window that is no whole number of pages, and a prompt whose padding
+    # spans one page more than `window // page + 2`: refused, nothing held
+    t = groups(n_slots=1, n_pages=65, rows=32, max_len=128, window=7)
+    assert t.wpool.n_pages - 1 == 3
+    with pytest.raises(NeverFits, match="window pages"):
+        t.reserve(0, seq(9))  # pads to 16: pages 0 .. 3, all four live
+    assert t.pool.n_free == 64 and t.wpool.n_free == 3
+    admit2(t, 0, seq(16))  # pages 0 .. 3; position 16 reads from page 2
+    t.extend(0, t.alloc())
+    assert len(t.win_pages[0]) == 3 and t.wpool.n_free == 0
+    assert t.alloc() is None  # neither group grows without the other
+    assert t.page_leaks() == 0
+
+
+def test_group_pages_counts_what_each_kernel_loads():
+    t = groups()
+    admit2(t, 0, seq(21))  # position 21: pages 0 .. 5 live; window 3 .. 5
+    admit2(t, 1, seq(2))
+    got = t.group_pages(np.array([True, False]))
+    assert got == {"live_pages_global": 6, "grid_pages_global": 2 * 16,
+                   "live_pages_window": 3, "grid_pages_window": 2 * 16,
+                   "window_pages_held": 5, "window_pages_unfreed": 8}
+    both = t.group_pages(np.array([True, True]))
+    assert both["live_pages_global"] == 6 + 1
+    assert both["live_pages_window"] == 3 + 1
+
+
+def test_rebuilt_keeps_the_window_and_the_freed_total():
+    t = groups()
+    admit2(t, 0, seq(5))
+    t.advance(0, 20)
+    new = t.rebuilt()
+    assert new.window == WINDOW and new.window_pages_freed == 4
+    assert new.wpool.n_free == new.wpool.n_pages - 1
+    assert vars(new).keys() == vars(t).keys()
