@@ -557,6 +557,22 @@ def _moe_dispatch_grouped(
     return out.astype(compute_dtype).reshape(B, T, H)
 
 
+def _grouped_plan(config: ModelConfig, p: Params) -> str:
+    """The tile plan of each call `_moe_dispatch_grouped` makes for this
+    layer (`moe_qmatmul.call_plan`: the loop, and the grid steps an
+    expert), for the route note: `gate_up words:paired x1 of 3 tiles, down
+    words x1 of 8 tiles`."""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+
+    if not config.gated_mlp:
+        first = f"up {mq.call_plan(p['w_up_e'])}"
+    elif config.hidden_act in mq.FUSED_ACTS:
+        first = f"gate_up {mq.call_plan((p['w_gate_e'], p['w_up_e']))}"
+    else:  # two plain calls and the activation in XLA
+        first = f"gate, up {mq.call_plan(p['w_up_e'])}"
+    return f"{first}, down {mq.call_plan(p['w_down_e'])}"
+
+
 def moe_grouped_why_not(p: Params, differentiable: bool) -> Optional[str]:
     """None when a layer's experts take `_moe_dispatch_grouped`: packed
     stacks the kernels can tile, the kernels in use (a TPU, or the
@@ -589,7 +605,8 @@ def _moe_dispatch(
     why = moe_grouped_why_not(p, differentiable)
     if why is None:
         routes.note("moe", "pallas:grouped",
-                    f"{p['w_up_e'].qtype} {detail} dropless")
+                    f"{p['w_up_e'].qtype} {detail} dropless: "
+                    f"{_grouped_plan(config, p)}")
         return _moe_dispatch_grouped(config, xc, p, compute_dtype, topv,
                                      topi, layer)
     assert layer is None, "unsliced expert codes are for the grouped path"

@@ -30,6 +30,35 @@ With two stacks (`w_gate`, `w_up`) the tile computes both products from
 the one x tile and writes `act(gate) * up`, in float32, before the
 cast: the gated FFN's first half in one call.
 
+What one grid step holds is `tiling.grouped_tile`'s, from O, the row bytes
+and the number of stacks (`call_plan` says it; the models put it in their
+route note):
+
+* `words`: a 512-row word tile of each stack (docs/kernels.md#word-path),
+  wherever O is a multiple of 512: every down projection, Mixtral's and
+  GLM's gate / up;
+* `words:paired`: a gated call whose O is a multiple of 256 and not of
+  512 (granite's and SmallThinker's 768-wide experts) holds 256 rows of
+  `w_gate` and 256 of `w_up` a step and decodes them as ONE 512-row word
+  tile: the two blocks' words stacked on sublanes and turned together,
+  ONE product `[block_m, 512]` where `words` runs two, columns 0..255
+  gate and 256..511 up once `natural_columns` has put them back. Same
+  index maps, same dead-tile rule;
+* on either word form a step holds SEVERAL word tiles where they are
+  small (`tiling.GROUPED_STEP_BYTES` of codes: a whole 768-wide expert,
+  GLM's too; Mixtral's 2 and 3.5 MiB tiles stay one a step): a grid step
+  costs 0.8 to 1.1 us beside the bytes it brings (chip, PR 44), so the
+  body walks the tiles it holds, one product and one store each, in a
+  loop that is traced ONCE and unrolled when it is lowered: straight-line
+  code lets one tile's stores overlap the next one's staging (left
+  rolled it gains nothing over a tile a step), and a body written out in
+  Python cost granite's cell 45 s of warm set-up in tracing;
+* `loop`: the stored-layout loop at 256- or 128-row tiles. Left to an
+  UNGATED call at such a width (phixtral's `fc1`, or the two plain calls
+  of a gated FFN whose activation is not in `FUSED_ACTS`) and to rows of
+  codes that are not whole 128-byte lanes: no benchmark cell runs one,
+  so there is no third form for them.
+
 The packed codes may keep their leading layer axis (`[L, E, O, C]` with
 a traced `layer`): the kernel then reads its blocks straight out of the
 scanned-over array, where a `[E, O, C]` slice handed to a Mosaic call
@@ -52,8 +81,8 @@ from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
 from bigdl_tpu.ops.pallas.qmatmul import _side_arrays, _validate
 from bigdl_tpu.ops.pallas.tiling import (
-    VMEM_LIMIT_BYTES, finest_split, forward_chunk, pick_block_m, pick_block_o,
-    words_ok,
+    VMEM_LIMIT_BYTES, WORD_BLOCK_O, finest_split, forward_chunk, grouped_tile,
+    pick_block_m,
 )
 
 #: activations the gated call applies in-kernel (float32, before the
@@ -112,37 +141,72 @@ def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int):
 
 
 def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
-            spec: DecodeSpec, n_w: int, act, words: bool):
+            spec: DecodeSpec, n_w: int, act, form: str, rows: int):
     """One [block_m, block_o] tile of one expert: `qmatmul._kernel`'s
     chunk loop (`qdecode.tile_product`) over each of the `n_w` weight
-    stacks, skipped whole when the tile holds no assignment. With
-    ``words`` three scratch refs per stack follow the output."""
+    stacks, skipped whole when the tile holds no assignment. On the word
+    path three scratch refs per word tile follow the output; the paired
+    form (`tiling.grouped_tile`) has one tile for both stacks. A step
+    that holds several tiles of `rows` rows walks them, one product and
+    one store each, through the same scratch (a `fori_loop` unrolled at
+    lowering: see the module docstring)."""
     del te_ref  # read by the index maps
     per = 1 + spec.n_side
     o_ref = refs[n_w * per]
     scratch = refs[n_w * per + 1:]
+    held = o_ref.shape[1] // rows  # tiles this step holds
 
-    @pl.when(pl.program_id(0) < meta_ref[0])
-    def _live_tile():
+    def product(blocks, sides):
+        """float32 [block_m, rows]: one word tile (or the loop's tile)."""
+        if form == "words:paired":
+            qdecode.stage_words(spec, blocks, sides, scratch)
+            y = qdecode.natural_columns(qdecode.staged_product(
+                spec, K, ck, x_ref, scratch,
+                jnp.issubdtype(blocks[0].dtype, jnp.signedinteger)))
+            return FUSED_ACTS[act](y[:, :rows]) * y[:, rows:]
+        words = form == "words"
         accs = [
             qdecode.tile_product(
-                spec, K, ck, x_ref, refs[i * per],
-                refs[i * per + 1:(i + 1) * per],
+                spec, K, ck, x_ref, blocks[i], sides[i],
                 scratch[3 * i:3 * i + 3] if words else None)
             for i in range(n_w)
         ]
         y = accs[0] if n_w == 1 else FUSED_ACTS[act](accs[0]) * accs[1]
-        if words:
-            y = qdecode.natural_columns(y)
-        o_ref[:] = y.astype(o_ref.dtype)
+        return qdecode.natural_columns(y) if words else y
+
+    @pl.when(pl.program_id(0) < meta_ref[0])
+    def _live_tile():
+        blocks = [refs[i * per] for i in range(n_w)]
+        sides = [refs[i * per + 1:(i + 1) * per] for i in range(n_w)]
+        if held == 1:
+            o_ref[:] = product(blocks, sides).astype(o_ref.dtype)
+            return
+
+        def tile(j, carry):
+            at = pl.ds(pl.multiple_of(j * rows, rows), rows)
+            y = product([b.at[at, :] for b in blocks],
+                        # (loaded here: a ref view narrower than 128
+                        # lanes does not lower)
+                        [[r[at, :] for r in side] for side in sides]
+                        ).astype(o_ref.dtype)
+            # (a store takes no dynamic lane offset; unrolled, `j` is a
+            # constant and the branches fold away)
+            for t in range(held):
+                @pl.when(j == t)
+                def _store():
+                    o_ref[:, t * rows:(t + 1) * rows] = y
+            return carry
+
+        jax.lax.fori_loop(0, held, tile, 0, unroll=held)
 
 
 @functools.partial(
     jax.jit, static_argnames=("spec", "out_dtype", "block_m", "block_o",
-                              "ck", "n_w", "act", "layered", "interpret"))
+                              "ck", "n_w", "act", "form", "rows",
+                              "layered", "interpret"))
 def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
-             act, layered: tuple, interpret: bool, tile_expert, meta, x,
-             *arrays):
+             act, form: str, rows: int, layered: tuple, interpret: bool,
+             tile_expert, meta, x, *arrays):
     Mp, K = x.shape
     O = arrays[0].shape[-2]
     n_o = O // block_o
@@ -160,13 +224,14 @@ def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
         for a, has_layer in zip(arrays, layered)
     ]
     per = 1 + spec.n_side
-    row_bytes = arrays[0].shape[-1]
-    words = words_ok(block_o, row_bytes)
-    scratch = qdecode.word_scratch(
-        spec, block_o, row_bytes, arrays[per - 1].shape[-1]) * n_w
+    scratch = []
+    if form != "loop":  # a word tile of each stack, or the pair's one
+        scratch = qdecode.word_scratch(
+            spec, WORD_BLOCK_O, arrays[0].shape[-1], arrays[per - 1].shape[-1]
+        ) * (1 if form == "words:paired" else n_w)
     return pl.pallas_call(
         functools.partial(_kernel, K=K, ck=ck, spec=spec, n_w=n_w, act=act,
-                          words=words),
+                          form=form, rows=rows),
         name="moe_qmatmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -174,7 +239,7 @@ def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((block_m, block_o),
                                    lambda m, o, te, meta: (m, o)),
-            scratch_shapes=scratch if words else [],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, O), out_dtype),
         compiler_params=pltpu.CompilerParams(
@@ -222,20 +287,39 @@ def moe_qmatmul(
                                       w.sub_mins)):
             layered.append(a.ndim == 4)
             arrays.append(a if a.ndim == 4 else a[None])
-    O = arrays[0].shape[-2]
     n_w = len(ws)
-    per = 1 + spec.n_side
-    persist_row = sum(a.shape[-1] * a.dtype.itemsize for a in arrays[:per])
-    block_o = pick_block_o(O, persist_row * n_w,
-                           row_bytes=arrays[0].shape[-1] * n_w)
+    form, rows, held, persist_row = _plan(ws)
+    block_o = rows * held
     persist = (n_w * block_o * persist_row + block_m * K * 2
                + n_w * block_m * block_o * 4)
-    ck = forward_chunk(words_ok(block_o, arrays[0].shape[-1]), block_o * n_w,
-                       persist, finest_split(K, spec.planes), spec.block,
-                       spec.mins)
+    ck = forward_chunk(form != "loop", block_o * n_w, persist,
+                       finest_split(K, spec.planes), spec.block, spec.mins)
     meta = jnp.stack([jnp.asarray(n_used, jnp.int32),
                       jnp.asarray(0 if layer is None else layer, jnp.int32)])
     return _moe_qmm(spec, jnp.dtype(out_dtype), block_m, block_o, ck, n_w,
-                    act, tuple(layered), bool(interpret),
+                    act, form, rows, tuple(layered), bool(interpret),
                     tile_expert.astype(jnp.int32),
                     meta, x.astype(jnp.bfloat16), *arrays)
+
+
+def _plan(ws) -> tuple:
+    """`tiling.grouped_tile` of one call's stacks and the bytes a row of
+    one stack holds, from their fields' static shapes (the side arrays
+    cross with the bytes they are stored in)."""
+    w0 = ws[0]
+    fields = [f for f in (w0.data, w0.scales, w0.mins, w0.sub_scales,
+                          w0.sub_mins) if f is not None]
+    persist_row = sum(f.shape[-1] * f.dtype.itemsize for f in fields)
+    row_bytes = w0.data.shape[-1] * w0.data.dtype.itemsize
+    return (*grouped_tile(w0.data.shape[-2], persist_row, row_bytes,
+                          len(ws)), persist_row)
+
+
+def call_plan(ws) -> str:
+    """What `moe_qmatmul` will run for these stacks, for a route note: the
+    loop, the grid steps an expert and the word tiles a step where it
+    holds several, `words:paired x1 of 3 tiles`."""
+    ws = tuple(ws) if isinstance(ws, (tuple, list)) else (ws,)
+    form, rows, held, _ = _plan(ws)
+    note = f"{form} x{ws[0].data.shape[-2] // (rows * held)}"
+    return note if held == 1 else f"{note} of {held} tiles"

@@ -433,20 +433,26 @@ def _pad_lanes(a, mult: int = 128):
         [a, jnp.zeros((a.shape[0], mult - n % mult), a.dtype)], axis=1)
 
 
-def stage_words(spec: DecodeSpec, w_ref, side_refs, scratch,
+def stage_words(spec: DecodeSpec, w_refs, side_refs, scratch,
                 piece: int = 2048):
     """Once per grid step: the tile's words and its effective scales,
-    transposed into `scratch` (see `word_scratch`)."""
+    transposed into `scratch` (see `word_scratch`). `w_refs` holds the
+    tile's code blocks and `side_refs` each block's side refs: one block of
+    512 rows, or the 256-row gate and up blocks of a gated expert call
+    (`tiling.grouped_tile`), whose 64 + 64 word rows are stacked on
+    sublanes and turned as one, so that the tile's rows 0..255 are gate
+    and 256..511 up."""
     wT_ref, s32_ref, sT_ref = scratch
-    bo, row_bytes = w_ref.shape
-    q = bo // WORD_ROWS
+    row_bytes = w_refs[0].shape[1]
+    q = sum(r.shape[0] for r in w_refs) // WORD_ROWS
     for j0 in range(0, row_bytes, piece):
         cw = min(piece, row_bytes - j0)
-        wT_ref[j0:j0 + cw, :] = pltpu.bitcast(
-            w_ref[:, j0:j0 + cw], jnp.int32).T
-    eff = effective_side(spec, load_side(spec, side_refs))
-    for i, a in enumerate(eff):
-        a = _pad_lanes(a)
+        words = [pltpu.bitcast(r[:, j0:j0 + cw], jnp.int32) for r in w_refs]
+        wT_ref[j0:j0 + cw, :] = (
+            words[0] if len(words) == 1 else jnp.concatenate(words, axis=0)).T
+    effs = [effective_side(spec, load_side(spec, refs)) for refs in side_refs]
+    for i, a in enumerate(zip(*effs)):
+        a = _pad_lanes(a[0] if len(a) == 1 else jnp.concatenate(a, axis=0))
         for g in range(a.shape[-1] // 128):
             s32_ref[g] = slc(a, g * 128, 128)
             # rows 4i + p of the tile are pack p: a strided sublane read
@@ -537,9 +543,8 @@ def tile_product(spec: DecodeSpec, K: int, ck: int, x_ref, w_ref, side_refs,
     33% slower on the chip (38.4 -> 51.5 us at 4096 -> 6144, PR 32): one
     chunk's decode does not overlap the next one's product across a
     loop's back edge."""
-    bm = x_ref.shape[0]
-    bo = w_ref.shape[0]
     if scratch is None:
+        bm, bo = x_ref.shape[0], w_ref.shape[0]
         x = x_ref[:].astype(jnp.bfloat16)
         side = load_side(spec, side_refs)
         w = w_ref[:]  # packed codes [block_o, row_bytes]
@@ -550,9 +555,17 @@ def tile_product(spec: DecodeSpec, K: int, ck: int, x_ref, w_ref, side_refs,
                 slc(x, e0, c), wd, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
         return acc
-    stage_words(spec, w_ref, side_refs, scratch)
+    stage_words(spec, (w_ref,), (side_refs,), scratch)
+    return staged_product(
+        spec, K, ck, x_ref, scratch,
+        jnp.issubdtype(w_ref.dtype, jnp.signedinteger))
+
+
+def staged_product(spec: DecodeSpec, K: int, ck: int, x_ref, scratch,
+                   signed: bool):
+    """`tile_product`'s chunk loop over the word tile `stage_words` left
+    in `scratch`: float32 [block_m, 512], columns pack-major."""
     wT_ref, _, sT_ref = scratch
-    signed = jnp.issubdtype(w_ref.dtype, jnp.signedinteger)
     qmin = finest_split(K, spec.planes)
 
     def chunk(acc, seg, off, c):
@@ -562,7 +575,8 @@ def tile_product(spec: DecodeSpec, K: int, ck: int, x_ref, w_ref, side_refs,
             xs.astype(jnp.bfloat16), wd, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    acc = jnp.zeros((bm, bo), jnp.float32)
+    acc = jnp.zeros((x_ref.shape[0], wT_ref.shape[1] * WORD_ROWS),
+                    jnp.float32)
     one_body = words_chunk_loops(qmin, ck, spec.block)
     for seg in range(K // qmin):
         if one_body:

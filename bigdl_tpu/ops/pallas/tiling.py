@@ -118,6 +118,50 @@ def pick_block_o(O: int, persist_per_row: int, cap: int = WORD_BLOCK_O,
     return O
 
 
+#: packed codes one grid step of the grouped expert kernel may hold (all
+#: stacks): a step costs 0.8 to 1.1 us over its bytes' time (chip, PR 44),
+#: so an expert whose word tiles are small takes several a step (a whole
+#: 768-wide expert: 3 MiB gate / up, 1.5 MiB down). Mixtral's tiles are
+#: 2 MiB (gate / up) and 3.5 MiB and stay one a step.
+GROUPED_STEP_BYTES = 3 * 1024 * 1024
+
+
+def grouped_tile(O: int, persist_per_row: int, row_bytes: int,
+                 stacks: int) -> tuple:
+    """The grouped expert kernel's plan of one call, from O, one stack's
+    row bytes and the number of stacks alone: (the loop that decodes a
+    tile, the tile's rows of EACH stack, the tiles a grid step holds).
+
+    * ``"words"``: `pick_block_o`'s 512-row word tile of each stack;
+    * ``"words:paired"``: a gated call (two stacks) whose O is a multiple
+      of 256 and not of 512 (768-wide experts) takes 256 rows of each
+      stack, decoded as ONE 512-row word tile: 64 + 64 word rows side by
+      side (`qdecode.stage_words`), one product, gate in columns 0..255
+      and up in 256..511;
+    * ``"loop"``: the stored-layout loop at `pick_block_o`'s 256- or
+      128-row tile (an ungated call at such a width, a row of codes that
+      is not whole 128-byte lanes).
+
+    On the word path a step holds as many word tiles as divide the call
+    within `GROUPED_STEP_BYTES` of codes, and the kernel's body walks
+    them. `moe_qmatmul` alone calls this; the dense kernels keep
+    `pick_block_o`."""
+    rows, form = WORD_BLOCK_O // 2, "words:paired"
+    if not (stacks == 2 and O % WORD_BLOCK_O == rows
+            and words_ok(WORD_BLOCK_O, row_bytes)
+            and words_tile_bytes(row_bytes, persist_per_row)
+            <= WORDS_VMEM_BYTES):
+        rows = pick_block_o(O, persist_per_row * stacks,
+                            row_bytes=row_bytes * stacks)
+        if not words_ok(rows, row_bytes):
+            return "loop", rows, 1
+        form = "words"
+    n = O // rows
+    held = max(t for t in range(1, n + 1) if n % t == 0 and (
+        t == 1 or t * rows * row_bytes * stacks <= GROUPED_STEP_BYTES))
+    return form, rows, held
+
+
 def pick_block_m(M: int, K: int, x_bpe: int = 2) -> int:
     """Row tile for the M grid dimension.
 

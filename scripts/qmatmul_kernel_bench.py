@@ -5,6 +5,7 @@ the kernel bodies (nothing in `bigdl_tpu/` has a switch for it). By hand,
 through the chip tool:
 
     python scripts/qmatmul_kernel_bench.py [--plan cells|mistral|quick]
+    python scripts/qmatmul_kernel_bench.py --plan experts   # the grouped kernel
     python scripts/qmatmul_kernel_bench.py --lower   # compile only, no chip
 
 Each line is one (body, variant, K, O, M): 64 dependent calls inside one
@@ -25,6 +26,17 @@ Bodies:
   no 512-row tile fits, and in `qbackward`): stored [o, k] layout, scales
   spread over lanes by a float32 one-hot matmul per chunk. Same variants,
   and `ab`: both taken out (widen, convert, cast and the product alone).
+
+`--plan experts` (PR 44) does the same for the grouped expert kernel
+`moe_qmatmul` at the four MoE cells' expert shapes, one line a (shape,
+variant): `hit` experts of E each with one row tile, as a decode step has
+them. It prints us a hit expert, the share of HBM time, and what a grid
+step costs over its bytes' time (`step_over_us`). Variants, in
+`experts_kernel` below: `tree` the kernel as it stands; `loop` the
+stored-layout loop at 256-row tiles (what a 768-wide gated call ran before
+PR 44); `fetch` nothing computed; `paired` the gated call's two 256-row
+blocks decoded as ONE 512-row word tile; `mb` / `one` several word tiles a
+grid step (about 1 MB of codes; a whole expert).
 
 It also checks, on the device it runs on, that what each body feeds the
 MXU is the dequantizer's weights bit for bit. The CPU interpreter cannot
@@ -95,7 +107,7 @@ def words_body(x_ref, w_ref, s_ref, scratch, *, K, ck, variant):
     each nibble half one loop body, unrolled when it is lowered; the four
     packs side by side on lanes. (`split` in the variant: a chain and a
     dot per pack instead; `roll`: the loop left rolled.)"""
-    qdecode.stage_words(SPEC, w_ref, (s_ref,), scratch)
+    qdecode.stage_words(SPEC, (w_ref,), ((s_ref,),), scratch)
     wT_ref, _, sT_ref = scratch
     bo, kh = w_ref.shape[0], K // 2
     q = bo // 4
@@ -279,7 +291,7 @@ def fed_weights_check():
             o_ref[:, e0:e0 + c] = qdecode.decode_chunk(SPEC, K, w, side, e0, c)
 
     def words_kern(w_ref, s_ref, o_ref, *scratch):
-        qdecode.stage_words(SPEC, w_ref, (s_ref,), scratch)
+        qdecode.stage_words(SPEC, (w_ref,), ((s_ref,),), scratch)
         for seg in range(2):
             for c0 in range(0, K // 2, 512):
                 o_ref[seg * (K // 2) + c0:seg * (K // 2) + c0 + 512, :] = \
@@ -347,6 +359,281 @@ def product_check():
                    of=float(jnp.abs(want).max()))
 
 
+# ------------------------------------------------- the grouped expert kernel
+
+# (E, k, rows of a decode step, experts hit a layer (the traced decode_step
+# spans' `moe_experts_hit` a MoE layer: ledger and PERF.md, PR 41; GLM's
+# from its roofline's bytes), ((K, O, gated), ...)) of the four MoE cells
+EXPERT_SHAPES = {
+    "granite": (72, 10, 32, 72, ((4096, 768, True), (768, 4096, False))),
+    "smallthinker": (64, 6, 16, 50, ((2560, 768, True), (768, 2560, False))),
+    "glm": (64, 4, 32, 55, ((2048, 1536, True), (1536, 2048, False))),
+    "mixtral": (8, 2, 16, 8, ((4096, 14336, True), (14336, 4096, False))),
+}
+STEP_BYTES = 1 << 20  # `mb`: codes a grid step may hold, all stacks
+
+
+def experts_kernel(te_ref, meta_ref, x_ref, *refs, K, ck, n_w, variant, tiles,
+                   paired):
+    """One grid step of the grouped kernel: `tiles` word tiles of one
+    expert (each 512 rows of a stack, or 256 + 256 of the gated pair)."""
+    del te_ref
+    o_ref, scratch = refs[2 * n_w], refs[2 * n_w + 1:]
+    silu = jax.nn.silu
+
+    @pl.when(pl.program_id(0) < meta_ref[0])
+    def _live_tile():
+        if variant == "fetch":
+            o_ref[:] = jnp.zeros(o_ref.shape, o_ref.dtype)
+            return
+        if variant == "loop":
+            accs = [qdecode.tile_product(SPEC, K, ck, x_ref, refs[2 * i],
+                                         (refs[2 * i + 1],))
+                    for i in range(n_w)]
+            y = accs[0] if n_w == 1 else silu(accs[0]) * accs[1]
+            o_ref[:] = y.astype(o_ref.dtype)
+            return
+        rows = 256 if paired else 512  # of a stack, a word tile
+        for j in range(tiles):
+            def cut(r):
+                return r if tiles == 1 else r.at[pl.ds(j * rows, rows), :]
+            ws = [cut(refs[2 * i]) for i in range(n_w)]
+            # (a row slice of a scale REF 24 lanes wide does not lower)
+            ss = [(refs[2 * i + 1][:][j * rows:(j + 1) * rows],)
+                  for i in range(n_w)]
+            if paired:
+                qdecode.stage_words(SPEC, ws, ss, scratch[:3])
+                y = qdecode.natural_columns(qdecode.staged_product(
+                    SPEC, K, ck, x_ref, scratch[:3], False))
+                y = silu(y[:, :256]) * y[:, 256:]
+            else:
+                accs = []
+                for i in range(n_w):
+                    sc = scratch[3 * i:3 * i + 3]
+                    qdecode.stage_words(SPEC, ws[i:i + 1], ss[i:i + 1], sc)
+                    accs.append(qdecode.staged_product(
+                        SPEC, K, ck, x_ref, sc, False))
+                y = qdecode.natural_columns(
+                    accs[0] if n_w == 1 else silu(accs[0]) * accs[1])
+            o_ref[:, j * rows:(j + 1) * rows] = y.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "block_o", "ck",
+                                             "variant", "tiles"))
+def experts_copy(te, meta, x, *arrays, block_m, block_o, ck, variant, tiles):
+    """`moe_qmatmul._moe_qmm` with this script's body. `arrays`: (codes
+    [L, E, O, C], scale bits [E, O, nb]) a stack; `block_o` rows of each
+    stack a grid step."""
+    Mp, K = x.shape
+    n_w = len(arrays) // 2
+    O = arrays[0].shape[2]
+    n_o = O // block_o
+
+    def o_of(m, o, meta):  # a dead tile names the block already held
+        return jnp.where(m < meta[0], o, n_o - 1)
+
+    in_specs = [pl.BlockSpec((block_m, K), lambda m, o, te, meta: (
+        jnp.minimum(m, meta[0] - 1), 0))]
+    for i in range(n_w):
+        in_specs += [
+            pl.BlockSpec((None, None, block_o, arrays[2 * i].shape[3]),
+                         lambda m, o, te, meta: (meta[1], te[m],
+                                                 o_of(m, o, meta), 0)),
+            pl.BlockSpec((None, block_o, arrays[2 * i + 1].shape[2]),
+                         lambda m, o, te, meta: (te[m], o_of(m, o, meta), 0)),
+        ]
+    paired = n_w == 2 and O % 512 == 256
+    sets = 0 if variant in ("loop", "fetch") else 1 if paired else n_w
+    scratch = qdecode.word_scratch(SPEC, 512, K // 2, K // 32) * sets
+    return pl.pallas_call(
+        functools.partial(experts_kernel, K=K, ck=ck, n_w=n_w,
+                          variant=variant, tiles=tiles, paired=paired),
+        name=f"moe_qmatmul_{variant}",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(Mp // block_m, n_o),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((block_m, block_o),
+                                   lambda m, o, te, meta: (m, o)),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((Mp, O), jnp.bfloat16),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+    )(te, meta, x, *arrays)
+
+
+def experts_tiles(variant, K, O, n_w):
+    """(block_o a stack, word tiles a step, chunk) of a variant, or None
+    where the shape has no such form."""
+    from bigdl_tpu.ops.pallas.tiling import words_chunk
+
+    row = K // 2 + (K // 32) * 2
+    qmin = finest_split(K, SPEC.planes)
+    if variant in ("loop", "fetch"):
+        bo = pick_block_o(O, row * n_w, cap=256 if variant == "loop" else 512,
+                          row_bytes=K // 2 * n_w)
+        if variant == "fetch" and n_w == 2 and O % 512:
+            bo = 256
+        return bo, 1, forward_chunk(False, bo * n_w, 0, qmin, SPEC.block,
+                                    False)
+    paired = n_w == 2 and O % 512 == 256
+    if variant == "paired" and not paired:
+        return None
+    rows = 256 if paired else 512  # of a stack, a word tile
+    if O % rows:
+        return None
+    n = O // rows
+    if variant == "paired":
+        t = 1
+    elif variant == "mb":
+        t = max(d for d in range(1, n + 1) if n % d == 0
+                and (d == 1 or d * rows * (K // 2) * n_w <= STEP_BYTES))
+    else:  # one: a whole expert, where its blocks fit twice
+        t = n
+        if 2 * n_w * O * row > 12 * 1024 * 1024:
+            return None
+    if variant in ("mb", "one") and t == 1:
+        return None  # the tree's own plan, or `paired`
+    if variant == "one" and experts_tiles("mb", K, O, n_w) == (
+            rows * t, t, words_chunk(qmin, SPEC.block)):
+        return None  # `mb` holds the whole expert already
+    return rows * t, t, words_chunk(qmin, SPEC.block)
+
+
+def experts_operands(name, K, O, gated, key, sharding=None):
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+
+    E, k, N, hit, shapes = EXPERT_SHAPES[name]
+    block_m = mq.moe_block_m(N, max(max(s[0], s[1]) for s in shapes))
+    n_tiles = mq.moe_n_tiles(N, k, E, block_m)
+    n_w = 2 if gated else 1
+    L = 2
+    shapes_ = ([((n_tiles,), jnp.int32), ((2,), jnp.int32),
+                ((n_tiles * block_m, K), jnp.bfloat16)]
+               + [((L, E, O, K // 2), jnp.uint8),
+                  ((E, O, K // 32), jnp.uint16)] * n_w)
+    if sharding is not None:
+        return block_m, hit, [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+                              for s, d in shapes_]
+    te = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), hit - 1)
+    keys = jax.random.split(key, 1 + 2 * n_w)
+    x = jax.random.normal(keys[0], shapes_[2][0], jnp.float32
+                          ).astype(jnp.bfloat16)
+    arrays = []
+    for i in range(n_w):
+        arrays.append(jax.random.randint(
+            keys[1 + 2 * i], (L, E, O, K // 2), 0, 256, jnp.int32
+        ).astype(jnp.uint8))
+        s = (jax.random.uniform(keys[2 + 2 * i], (E, O, K // 32)) * 0.01
+             + 0.001).astype(jnp.float16)
+        arrays.append(jax.lax.bitcast_convert_type(s, jnp.uint16))
+    return block_m, hit, [te, jnp.asarray([hit, 0], jnp.int32), x, *arrays]
+
+
+def experts_build(variant, name, K, O, gated):
+    """-> (run(n, te, meta, x, *arrays): n dependent calls, one call, plan)."""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    n_w = 2 if gated else 1
+    E, k, N, hit, shapes = EXPERT_SHAPES[name]
+    block_m = mq.moe_block_m(N, max(max(s[0], s[1]) for s in shapes))
+    if variant == "tree":
+        plan = None
+
+        def call(te, meta, x, *arrays):
+            ws = [QTensor(qtype="sym_int4", data=arrays[2 * i],
+                          scales=jax.lax.bitcast_convert_type(
+                              arrays[2 * i + 1], jnp.float16))
+                  for i in range(n_w)]
+            return mq.moe_qmatmul(x, ws if gated else ws[0], te, meta[0],
+                                  block_m, act="silu" if gated else None,
+                                  layer=meta[1], interpret=False)
+    else:
+        plan = experts_tiles(variant, K, O, n_w)
+        if plan is None:
+            return None
+        block_o, tiles, ck = plan
+
+        def call(te, meta, x, *arrays):
+            return experts_copy(te, meta, x, *arrays, block_m=block_m,
+                                block_o=block_o, ck=ck, variant=variant,
+                                tiles=tiles)
+
+    @jax.jit
+    def run(n, te, meta, x, *arrays):
+        def one(i, carry):
+            layer, acc = carry
+            y = call(te, jnp.stack([meta[0], layer]), x, *arrays)
+            flag = (y[0, 0] != y[0, 0]).astype(jnp.int32)  # 0; needs y
+            return ((i + 1) % arrays[0].shape[0] + flag,
+                    acc + y[0, 0].astype(jnp.float32))
+        return jax.lax.fori_loop(0, n, one, (jnp.int32(0), jnp.float32(0)))[1]
+
+    return run, call, plan
+
+
+def experts_measure(variant, name, K, O, gated, key, ns=(16, 32, 64), reps=3):
+    built = experts_build(variant, name, K, O, gated)
+    if built is None:
+        return None
+    run, _, plan = built
+    block_m, hit, args = experts_operands(name, K, O, gated, key)
+    jax.block_until_ready(run(2, *args))
+    ts = []
+    for n in ns:
+        best = float("inf")
+        for _ in range(reps):
+            t = time.perf_counter()
+            jax.block_until_ready(run(n, *args))
+            best = min(best, time.perf_counter() - t)
+        ts.append(best)
+    per_call = float(np.polyfit(np.asarray(ns, float), np.asarray(ts), 1)[0])
+    n_w = 2 if gated else 1
+    nbytes = (n_w * O * (K // 2 + K // 32 * 2) + block_m * K * 2
+              + block_m * O * 2)  # a hit expert
+    us = per_call * 1e6 / hit
+    hbm_us = nbytes / HBM_BYTES_PER_S * 1e6
+    out = dict(plan="experts", cell=name, variant=variant, K=K, O=O,
+               gated=gated, hit=hit, block_m=block_m, call_us=per_call * 1e6,
+               us_expert=us, hbm_us=hbm_us, share=100 * hbm_us / us)
+    if plan is not None:
+        steps = O // plan[0]
+        out.update(block_o=plan[0], tiles=plan[1], ck=plan[2], steps=steps,
+                   step_over_us=(us - hbm_us) / steps)
+    return out
+
+
+def experts_check():
+    """Each variant against the tree's kernel on the same operands, on the
+    device (granite's two shapes, rows of live tiles): the same bf16
+    weights into the same float32 sums."""
+    for K, O, gated in EXPERT_SHAPES["granite"][4]:
+        _, hit, args = experts_operands("granite", K, O, gated,
+                                        jax.random.key(3))
+        block_m = args[2].shape[0] // args[0].shape[0]
+        want = experts_build("tree", "granite", K, O, gated)[1](*args)
+        want = np.asarray(want[:hit * block_m].astype(jnp.float32))
+        for v in ("loop", "paired", "mb", "one"):
+            built = experts_build(v, "granite", K, O, gated)
+            if built is None:
+                continue
+            got = np.asarray(built[1](*args)[:hit * block_m
+                                             ].astype(jnp.float32))
+            yield dict(check="experts_vs_tree", variant=v, K=K, O=O,
+                       worst=float(np.abs(got - want).max()),
+                       of=float(np.abs(want).max()))
+
+
+def experts_plan():
+    plan = []
+    for name, (_, _, _, _, shapes) in EXPERT_SHAPES.items():
+        for K, O, gated in shapes:
+            plan += [(v, name, K, O, gated)
+                     for v in ("tree", "loop", "fetch", "paired", "mb", "one")]
+    return plan
+
+
 # ----------------------------------------------------------------- the plans
 
 def cell_shapes():
@@ -398,12 +685,13 @@ def plan_of(name):
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--plan", default="cells",
-                    choices=("cells", "mistral", "quick", "forms"))
-    ap.add_argument("--lower", action="store_true",
+                    choices=("cells", "mistral", "quick", "forms", "experts"))
+    ap.add_argument("--lower", "--fit", action="store_true",
                     help="compile the plan for a described v5e; no chip")
     ap.add_argument("--out", default="chiprun_out/qmatmul_kernel_bench.jsonl")
     args = ap.parse_args()
-    plan = plan_of(args.plan)
+    experts = args.plan == "experts"
+    plan = experts_plan() if experts else plan_of(args.plan)
 
     if args.lower:
         from jax.experimental import topologies
@@ -411,7 +699,16 @@ def main() -> int:
 
         one = SingleDeviceSharding(topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2").devices[0])
-        for body, v, M, K, O in plan:
+        for v, name, K, O, gated in plan if experts else ():
+            built = experts_build(v, name, K, O, gated)
+            if built is None:
+                print(f"skip {v} {name} K={K} O={O}: no such form")
+                continue
+            _, _, args_ = experts_operands(name, K, O, gated, None, one)
+            built[0].lower(jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
+                           *args_).compile()
+            print(f"ok {v} {name} K={K} O={O} {built[2]}", flush=True)
+        for body, v, M, K, O in () if experts else plan:
             built = build(body, v, M, K, O)
             if built is None:
                 print(f"skip {body} {v} M={M} K={K} O={O}: no 512-row tile")
@@ -430,8 +727,13 @@ def main() -> int:
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     key = jax.random.key(0)
     with open(args.out, "a") as f:
-        results = (measure(*case, key) for case in plan)
-        for r in (*fed_weights_check(), *product_check(), *results):
+        if experts:
+            checks = experts_check()
+            results = (experts_measure(*case, key) for case in plan)
+        else:
+            checks = (*fed_weights_check(), *product_check())
+            results = (measure(*case, key) for case in plan)
+        for r in (*checks, *results):
             if r is not None:
                 print(json.dumps(r), flush=True)
                 f.write(json.dumps(r) + "\n")

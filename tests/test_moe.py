@@ -298,6 +298,35 @@ def test_grouped_dispatch_drops_nothing(interpret, tiny_mixtral, routing,
                                rtol=0, atol=1e-3)
 
 
+@pytest.mark.parametrize("act,gated,want", [
+    ("silu", True, "gate_up words:paired x1 of 3 tiles, "
+                   "down words x1 of 8 tiles"),
+    ("relu", True, "gate_up words:paired x1 of 3 tiles, "
+                   "down words x1 of 8 tiles"),
+    # not fused: two plain 768-wide calls, which stay on the stored-layout
+    # loop (ISSUE 44: no third form), as phixtral's fc1 does
+    ("gelu_pytorch_tanh", True, "gate, up loop x3, down words x1 of 8 tiles"),
+    ("gelu_new", False, "up loop x3, down words x1 of 8 tiles"),
+])
+def test_grouped_route_note_names_each_calls_tile_plan(act, gated, want):
+    """The route note of a grouped MoE layer says which loop each of its
+    calls takes and the grid steps an expert (granite's 4096 x 768 experts:
+    nothing computed, the plan is static)."""
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    def stack(O, K):
+        return QTensor(qtype="sym_int4",
+                       data=jax.ShapeDtypeStruct((2, O, K // 2), jnp.uint8),
+                       scales=jax.ShapeDtypeStruct((2, O, K // 32),
+                                                   jnp.float16))
+
+    cfg = dataclasses.replace(ModelConfig.from_hf_config(TINY_MIXTRAL),
+                              hidden_act=act, gated_mlp=gated)
+    p = {"w_gate_e": stack(768, 4096), "w_up_e": stack(768, 4096),
+         "w_down_e": stack(4096, 768)}
+    assert llama._grouped_plan(cfg, p) == want
+
+
 def test_ragged_dispatch_drops_where_grouped_does_not(tiny_mixtral):
     """The point of the case: capacity 1.25 holds 15 of the 48 assignments
     each of the two experts gets (ceil(48 * 2 * 1.25 / 8)), and the other

@@ -12,7 +12,9 @@ Three things are held:
   (`tests/test_qmatmul_cells.py` does the same at the benchmark cells' own
   K and M);
 * the grouped expert kernel does the same with an empty group, one stack
-  and the gated pair.
+  and the gated pair, on 512-row word tiles and on the PAIRED tile of a
+  768-wide gated call (ISSUE 44: 256 rows of `w_gate` beside 256 of `w_up`
+  decoded as one 512-row word tile).
 
 TOLERANCE of the products: both sides multiply the same bf16 operands into
 float32, so they differ by float32 summation order alone. Weights are drawn
@@ -31,13 +33,14 @@ from bigdl_tpu.ops.linear import _QGEMV_QTYPES
 from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qmatmul import _side_arrays, qmatmul
 from bigdl_tpu.ops.pallas.tiling import (
-    WORD_BLOCK_O, WORD_ROWS, chunk_spans, finest_split, words_chunk, words_ok,
+    WORD_BLOCK_O, WORD_ROWS, chunk_spans, finest_split, grouped_tile,
+    words_chunk, words_ok,
 )
 from bigdl_tpu.quant import quantize
 
 pytestmark = pytest.mark.core
 
-CELL_KS = (3584, 4096, 5120, 14336, 17408, 18944)
+CELL_KS = (2560, 3584, 4096, 5120, 14336, 17408, 18944)
 
 
 @pytest.fixture
@@ -61,21 +64,27 @@ def _kernel_data(qt):
                                     qt.sub_mins)
 
 
-def _decoded_by_words(qt, K, ck=None):
+def _decoded_by_words(qts, K, ck=None):
     """[O, K] bf16: what the word path feeds the MXU for one tile, its
-    chunks rolled or unrolled as the kernel would have them."""
-    spec, data, side = _kernel_data(qt)
-    O = data.shape[0]
+    chunks rolled or unrolled as the kernel would have them. `qts`: the
+    tile's one block of 512 rows, or the gated pair's two of 256."""
+    qts = qts if isinstance(qts, (tuple, list)) else (qts,)
+    blocks = [_kernel_data(qt) for qt in qts]
+    spec, data, side = blocks[0]
+    O = sum(b[1].shape[0] for b in blocks)
     assert words_ok(O, data.shape[1])
     q = O // WORD_ROWS
     qmin = finest_split(K, spec.planes)
     ck = ck or words_chunk(qmin, spec.block)
+    per = 1 + spec.n_side
 
-    def kern(w_ref, *refs):
-        side_refs, o_ref, scratch = refs[:spec.n_side], refs[spec.n_side], \
-            refs[spec.n_side + 1:]
-        qdecode.stage_words(spec, w_ref, side_refs, scratch)
-        signed = jnp.issubdtype(w_ref.dtype, jnp.signedinteger)
+    def kern(*refs):
+        o_ref, scratch = refs[len(blocks) * per], refs[len(blocks) * per + 1:]
+        qdecode.stage_words(
+            spec, [refs[i * per] for i in range(len(blocks))],
+            [refs[i * per + 1:(i + 1) * per] for i in range(len(blocks))],
+            scratch)
+        signed = jnp.issubdtype(refs[0].dtype, jnp.signedinteger)
         for seg in range(K // qmin):
             for c0, c in chunk_spans(qmin, ck):
                 o_ref[seg * qmin + c0:seg * qmin + c0 + c, :] = \
@@ -87,7 +96,7 @@ def _decoded_by_words(qt, K, ck=None):
         scratch_shapes=qdecode.word_scratch(spec, O, data.shape[1],
                                             side[-1].shape[1]),
         interpret=True,
-    )(data, *side)
+    )(*(a for _, d, sd in blocks for a in (d, *sd)))
     # lane p * q + i of the tile is its row 4i + p
     return jnp.transpose(out.reshape(K, WORD_ROWS, q), (2, 1, 0)).reshape(O, K)
 
@@ -127,15 +136,34 @@ def _reference(x, qt):
                    preferred_element_type=jnp.float32)
 
 
+@pytest.mark.parametrize("blocks", (1, 2), ids=("one-block", "paired"))
 @pytest.mark.parametrize("K", CELL_KS)
-def test_decoded_words_at_the_cells_widths(K):
+def test_decoded_words_at_the_cells_widths(K, blocks):
     """sym_int4, the cells' format, at each cell's contraction width: odd
-    chunk tails, scale columns that do not fill 128 lanes (K / 32 = 112,
-    160, 448, 544, 592)."""
-    qt = _weights("sym_int4", WORD_BLOCK_O, K, seed=K)
-    want = np.asarray(qt.dequantize(jnp.bfloat16).astype(jnp.float32))
-    got = np.asarray(_decoded_by_words(qt, K).astype(jnp.float32))
+    chunk tails, scale columns that do not fill 128 lanes (K / 32 = 80,
+    112, 160, 448, 544, 592). `paired`: two blocks of 256 rows (a gated
+    768-wide expert call's `w_gate` and `w_up` blocks) staged as one tile:
+    its rows 0..255 are the first block's weights, 256..511 the second's,
+    the dequantizer's bit for bit."""
+    qts = [_weights("sym_int4", WORD_BLOCK_O // blocks, K, seed=K + i)
+           for i in range(blocks)]
+    want = np.concatenate([np.asarray(
+        qt.dequantize(jnp.bfloat16).astype(jnp.float32)) for qt in qts])
+    got = np.asarray(_decoded_by_words(qts, K).astype(jnp.float32))
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("qtype", sorted(_QGEMV_QTYPES))
+def test_paired_tile_decodes_every_format_bit_for_bit(qtype):
+    """The paired tile's staging for every packed format (mins, two-level
+    scales, byte codes): the dequantizer's weights of both blocks."""
+    K = 1024
+    qts = [_weights(qtype, WORD_BLOCK_O // 2, K, seed=i) for i in range(2)]
+    want = np.concatenate([np.asarray(
+        qt.dequantize(jnp.bfloat16).astype(jnp.float32)) for qt in qts])
+    got = np.asarray(_decoded_by_words(qts, K).astype(jnp.float32))
+    bad = np.argwhere(got != want)
+    assert bad.size == 0, (qtype, len(bad), bad[:4])
 
 
 @pytest.mark.parametrize("qtype", sorted(_QGEMV_QTYPES))
@@ -158,29 +186,77 @@ def test_every_format_takes_the_word_path(interpret, qtype):
                                    atol=5e-5, err_msg=f"{qtype} M={M}")
 
 
-@pytest.mark.parametrize("gated", (False, True), ids=("one-stack", "gated"))
-@pytest.mark.parametrize("K", (4096, 14336))
-def test_grouped_kernel_on_the_word_path(interpret, K, gated):
-    """Mixtral's two contractions, a group of size 0, one stack and the
-    (gate, up) pair: rows of expert e are x @ dq(W[e])^T."""
+# (K, O, act, block_m): Mixtral's two contractions on 512-row word tiles,
+# then the PAIRED tile of granite's (K 4096) and SmallThinker's (K 2560)
+# 768-wide gated calls at a decode step's row tiles and a prefill's
+_GROUPED = [(K, WORD_BLOCK_O, act, 8) for K in (4096, 14336)
+            for act in (None, "silu")] + [
+    (4096, 768, "silu", 32), (4096, 768, "relu", 16), (4096, 768, "silu", 256),
+    (2560, 768, "relu", 16), (2560, 768, "silu", 32), (2560, 768, "relu", 256),
+]
+
+
+@pytest.mark.parametrize("K,O,act,bm", _GROUPED)
+def test_grouped_kernel_on_the_word_path(interpret, K, O, act, bm):
+    """A group of size 0 (an expert with no rows), a dead tile past the
+    tiles in use, one stack and the (gate, up) pair: rows of expert e are
+    x @ dq(W[e])^T. A gated 768-wide call takes the paired tile, and
+    agrees with two ungated calls (the stored-layout loop) + XLA."""
     from bigdl_tpu.ops.pallas import moe_qmatmul as mq
 
-    E, O, bm = 4, WORD_BLOCK_O, 8
+    E, gated = 4, act is not None
     groups = [5, 0, 9, 2]
     ws = [quantize(jax.random.normal(jax.random.PRNGKey(i), (E, O, K))
                    * K ** -0.5, "sym_int4") for i in range(2 if gated else 1)]
+    paired = O == 768
+    assert mq.call_plan(ws if gated else ws[0]) == (
+        "words:paired x1 of 3 tiles" if paired
+        else "loop x2" if gated and K == 14336  # two such tiles: VMEM
+        else "words x1")
     experts = np.repeat(np.arange(E), groups).astype(np.int32)
     N = len(experts)
+    n_tiles = mq.moe_n_tiles(N, 1, E, bm)
     dest, src, te, n_used = mq.moe_layout(
-        jnp.asarray(experts)[:, None], E, bm, mq.moe_n_tiles(N, 1, E, bm))
+        jnp.asarray(experts)[:, None], E, bm, n_tiles)
+    assert int(n_used) < n_tiles  # the last tile is dead
     x = jax.random.normal(jax.random.PRNGKey(7), (N, K)).astype(jnp.bfloat16)
     y = mq.moe_qmatmul(x[src], ws if gated else ws[0], te, n_used, bm,
-                       act="silu" if gated else None, out_dtype=jnp.float32)
+                       act=act, out_dtype=jnp.float32)
     got = np.asarray(y[dest[:, 0]])
     per = [jnp.einsum("nk,nok->no", x, w.dequantize(jnp.bfloat16)[experts],
                       preferred_element_type=jnp.float32) for w in ws]
-    want = jax.nn.silu(per[0]) * per[1] if gated else per[0]
+    want = mq.FUSED_ACTS[act](per[0]) * per[1] if gated else per[0]
     np.testing.assert_allclose(got, np.asarray(want), rtol=0, atol=5e-5)
+    if paired:
+        assert mq.call_plan(ws[0]) == "loop x3"
+        g, u = (mq.moe_qmatmul(x[src], w, te, n_used, bm,
+                               out_dtype=jnp.float32)[dest[:, 0]] for w in ws)
+        np.testing.assert_allclose(
+            got, np.asarray(mq.FUSED_ACTS[act](g) * u), rtol=0, atol=5e-5)
+
+
+def test_grouped_tile_chooses_from_shapes_alone():
+    """`tiling.grouped_tile`: the paired tile for two stacks at a multiple
+    of 256 that is not one of 512, today's plan everywhere else (Mixtral's
+    and GLM's block shapes, every down projection), the stored-layout loop
+    for one stack at such a width or a row that is not whole lanes."""
+    row = lambda K: K // 2 + K // 16  # sym_int4 codes + float16 scales
+    # (a step holds the word tiles that divide the call within 3 MiB of
+    # codes: a whole 768-wide expert, one of 1280's five 1 MiB tiles)
+    for K, O, held in ((4096, 768, 3), (2560, 768, 3), (2048, 256, 1),
+                       (4096, 1280, 1), (2048, 1280, 5)):
+        assert grouped_tile(O, row(K), K // 2, 2) \
+            == ("words:paired", 256, held)
+        assert grouped_tile(O, row(K), K // 2, 1) == ("loop", 256, 1)
+    for K, O, stacks, held in (
+            (4096, 14336, 2, 1), (14336, 4096, 1, 1),  # Mixtral: as before
+            (2048, 1536, 2, 3), (1536, 2048, 1, 4),  # GLM: an expert a step
+            (768, 4096, 1, 8), (768, 2560, 1, 5), (4096, 4096, 1, 2),
+            (2048, 4096, 1, 4)):
+        assert grouped_tile(O, row(K), K // 2, stacks) \
+            == ("words", 512, held)
+    assert grouped_tile(768, row(192), 96, 2) == ("loop", 256, 1)
+    assert grouped_tile(640, row(4096), 2048, 2) == ("loop", 128, 1)
 
 
 def test_natural_columns_puts_pack_major_columns_back():

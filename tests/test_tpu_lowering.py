@@ -129,6 +129,133 @@ def test_moe_qmatmul_compiles_at_mixtrals_shapes(one_chip, K, O, gated):
     ).compile()
 
 
+# ---- 768-wide experts on the paired word tile (ISSUE 44) -------------------
+
+# (E, k, K, O, act): granite-4.0-h-small's and SmallThinker-21BA3B's expert
+# calls, the gated first half (768 = 3 x 256 rows a stack) and the down
+# projection; each at a decode step's rows and at a prefill's 256-row tiles
+_EXPERTS_768 = {
+    "granite-gate_up": (72, 10, 4096, 768, "silu"),
+    "granite-down": (72, 10, 768, 4096, None),
+    "smallthinker-gate_up": (64, 6, 2560, 768, "relu"),
+    "smallthinker-down": (64, 6, 768, 2560, None),
+}
+
+
+@pytest.mark.parametrize("rows", ("decode", "prefill"))
+@pytest.mark.parametrize("name", list(_EXPERTS_768))
+def test_moe_qmatmul_compiles_at_granites_and_smallthinkers_shapes(
+        one_chip, monkeypatch, name, rows):
+    """Mosaic takes the paired tile (two 256-row blocks' words stacked on
+    sublanes and turned as one, one product, the activation on the two
+    halves of its columns) at both cells' widths and row tiles, and NO call
+    there takes the stored-layout loop."""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.ops.pallas import qdecode
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    E, k, K, O, act = _EXPERTS_768[name]
+    N = {"granite": 32, "smallthinker": 16}[name.split("-")[0]] \
+        if rows == "decode" else 2048
+    bm = mq.moe_block_m(N, max(K, O))
+    assert bm == (N if rows == "decode" else 256)
+    n_tiles = mq.moe_n_tiles(N, k, E, bm)
+    calls = {"loop": 0, "staged": 0}
+    for fn, key in (("decode_chunk", "loop"), ("stage_words", "staged")):
+        real = getattr(qdecode, fn)
+        monkeypatch.setattr(
+            qdecode, fn, lambda *a, _r=real, _k=key, **kw: (
+                calls.__setitem__(_k, calls[_k] + 1), _r(*a, **kw))[1])
+
+    def f(x, te, n_used, layer, *fields):
+        ws = [QTensor(qtype="sym_int4", data=fields[2 * i],
+                      scales=fields[2 * i + 1]) for i in range(len(fields) // 2)]
+        assert mq.call_plan(ws) == (
+            "words:paired x1 of 3 tiles" if act
+            else f"words x1 of {O // 512} tiles")
+        # (as `_moe_dispatch_grouped` calls it: `down` leaves in float32)
+        return mq.moe_qmatmul(x, ws if act else ws[0], te, n_used, bm,
+                              act=act, layer=layer, interpret=False,
+                              out_dtype=jnp.bfloat16 if act else jnp.float32)
+
+    fields = []
+    for _ in range(2 if act else 1):
+        fields += [_sds((2, E, O, K // 2), jnp.uint8, one_chip),
+                   _sds((E, O, K // 32), jnp.float16, one_chip)]
+    c = jax.jit(f).lower(
+        _sds((n_tiles * bm, K), jnp.bfloat16, one_chip),
+        _sds((n_tiles,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        *fields,
+    ).compile()
+    assert "moe_qmatmul" in c.as_text()  # the name the roofline readers find
+    # (a whole expert a grid step: the walk over its word tiles is traced
+    # once and unrolled when it is lowered)
+    assert calls == {"loop": 0, "staged": 1}, calls
+
+
+def _mosaic_bodies(lowered_text):
+    """The Mosaic modules of a lowered program's kernels, printed without
+    source locations (the serialized bodies carry file lines)."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    out = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                           lowered_text):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            out.append(ir.Module.parse(base64.b64decode(body)).operation
+                       .get_asm(enable_debug_info=False))
+    return out
+
+
+# sha256 of the dense `qmatmul`'s Mosaic module on PR 41's tree (wqkv at 32
+# rows, w_down at a prefill's 256, Mistral's head on the stored-layout loop,
+# Qwen2's wqkv whose chunks are Python's loop)
+_DENSE_BODIES = {
+    (4096, 6144, 32):
+        "68f393d0756fa36b2f8712371d26345bc23f83cdc98ca09a99765afd3a7353f5",
+    (14336, 4096, 256):
+        "1a85d31ea15f3e4e065dd5a754811e1d682984e654d3f1ae6cc832e1adfeb5a8",
+    (4096, 32000, 32):
+        "48b359797facf56403dd5372088f6d9c0a56b9c6a174e51f1e3dfdd52f6b8f51",
+    (3584, 4608, 16):
+        "45b6c9cce86c3b881c5047a338ad68461da690bd5689940b3d7ae176c4eabad1",
+}
+
+
+@pytest.mark.parametrize("K,O,M", list(_DENSE_BODIES))
+def test_dense_qmatmul_lowers_to_the_parents_program(one_chip, K, O, M):
+    """ISSUE 44 gave `qdecode.stage_words` a second block for the grouped
+    kernel's paired tile: the dense kernels' own programs are what they
+    were, to the byte, and the grouped kernel's plan at Mixtral's shapes
+    is one 512-row word tile a grid step as it was (GLM's smaller tiles
+    are now a whole expert a step)."""
+    import hashlib
+
+    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_int4
+    from bigdl_tpu.ops.pallas.tiling import grouped_tile
+
+    text = jax.jit(
+        lambda x, d, s, layer: qmatmul_int4(x, d, s, interpret=False,
+                                            layer=layer)
+    ).lower(_sds((M, K), jnp.bfloat16, one_chip),
+            _sds((2, O, K // 2), jnp.uint8, one_chip),
+            _sds((O, K // 32), jnp.float16, one_chip),
+            _sds((), jnp.int32, one_chip)).as_text()
+    (body,) = _mosaic_bodies(text)
+    assert hashlib.sha256(body.encode()).hexdigest() == _DENSE_BODIES[K, O, M]
+    for k, o, stacks, held in ((4096, 14336, 2, 1), (14336, 4096, 1, 1),
+                               (2048, 1536, 2, 3), (1536, 2048, 1, 4)):
+        assert grouped_tile(o, k // 2 + k // 16, k // 2, stacks) \
+            == ("words", 512, held)
+
+
 # ---- paged decode attention over groups of live pages (ISSUE 35) -----------
 
 # (slots, KV heads, query heads a KV head, head size, layers, pages in the
