@@ -265,19 +265,20 @@ def flash_prefill_cost(T: int, S: int, Hq: int, Hkv: int, D: int,
     kernel picks (tiling.flash_blocks) and with the kernel's own causal
     block-skip predicate (tiling.flash_live_blocks).
 
-    Fetch pattern (flash_attention._flash BlockSpecs): the q block index
-    map ignores j, so a q tile is fetched once per (b, h, i); k/v tiles
-    are re-fetched per live (i, j) pair for every QUERY head (GQA
-    grouping shares the HBM page only within one h's sweep). fp8 KV
-    halves the k/v code bytes and adds f32 per-(slot, head) scales."""
-    block_q, block_k = flash_blocks(T, S)
+    Fetch pattern (flash_attention._flash BlockSpecs): a q block holds
+    the positions of all the query heads of ONE KV head and its index
+    map ignores j, so it is fetched once per (b, kv head, i); k/v tiles
+    are fetched once per live (i, j) pair and KV head (a dead step names
+    the block already resident). fp8 KV halves the k/v code bytes and
+    adds f32 per-(slot, head) scales."""
+    kv_bpe = 1 if quantize_kv else 2
+    block_q, block_k = flash_blocks(T, S, D, Hq // Hkv, kv_bpe)
     live = flash_live_blocks(T, S, block_q, block_k,
                              q_offset=q_offset, window=window)
     Tp = round_up(T, block_q)
-    kv_bpe = 1 if quantize_kv else 2
     q_bytes = B * Hq * Tp * D * _X_BPE
     kv_tile = block_k * D * kv_bpe + (block_k * 4 if quantize_kv else 0)
-    kv_bytes = B * Hq * live * 2 * kv_tile  # k AND v
+    kv_bytes = B * Hkv * live * 2 * kv_tile  # k AND v
     o_bytes = B * Hq * Tp * D * _OUT_BPE
     # qk^T + av over the live blocks (the skipped blocks cost nothing —
     # the kernel's pl.when elides the whole compute body)

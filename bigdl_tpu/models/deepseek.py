@@ -426,8 +426,7 @@ def _expanded_attention(q, k, v, q_slots, start, scale, compute_dtype,
             return jnp.pad(a, ((0, 0),) * 3 + ((0, D - a.shape[-1]),))
 
         out = flash_attention(widen(q), widen(k), widen(v), start=start,
-                              q_offset=flash_offset, scale=scale,
-                              block_q=256, block_k=512)
+                              q_offset=flash_offset, scale=scale)
         return out[..., :Dv]
     S = k.shape[1]
     bq = min(_PREFILL_BLOCK_Q, T)

@@ -14,11 +14,22 @@ entry). Query slot t attends kv slot j iff
     start[b] <= j <= q_offset + t          (causal)
     and j > q_offset + t - window          (if sliding window).
 
-Grid is (B, Hq, nQ, nK) with the K axis innermost ("arbitrary"
-semantics); m/l/acc accumulators live in VMEM scratch and the output
-block is written once on the last K step. K blocks entirely above the
-causal diagonal are skipped via `pl.when`, so causal costs ~half of
-full attention, matching a hand-scheduled kernel.
+Grid is (B, Hkv, nQ, nK) with the K axis innermost ("arbitrary"
+semantics). A q block is `block_q` POSITIONS of all `group = Hq / Hkv`
+query heads of one KV head, stacked on sublanes as `group * block_q`
+rows, so K and V are fetched once a KV head; the tiles are
+`tiling.flash_blocks`' (512 keys a step where S allows, from T, S, D,
+`group` and the cache's itemsize against `tiling.VMEM_BUDGET`). q, K and
+V reach the MXU in the type they are stored in (bfloat16: exact
+products), accumulated in float32; the scale, the softcap, the running
+max and sum, `alpha` and the accumulator are float32, and the
+probabilities are cast to V's type for the context dot alone. A step
+whose K block no row of its q block attends (above the causal diagonal,
+behind the window) is skipped via `pl.when`, and the K / V index maps
+clamp `j` into the q block's live range (`live_k_range`), so a dead step
+names the block already resident and fetches nothing. The output block
+is written once, on the last K step. docs/kernels.md#flash has the
+account; `scripts/flash_kernel_bench.py` times the kernel alone.
 """
 
 from __future__ import annotations
@@ -32,9 +43,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bigdl_tpu.ops import routes
 from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.tiling import (
-    FLASH_BLOCK_K, FLASH_BLOCK_Q, MOSAIC_LANES, flash_blocks,
+    MOSAIC_LANES, VMEM_LIMIT_BYTES, flash_blocks, flash_live_blocks,
 )
 from bigdl_tpu.utils import round_up
 
@@ -44,18 +56,56 @@ _NEG_INF = -1e30
 _LANES = MOSAIC_LANES
 
 
+def live_k_range(i, q_offset, block_q: int, block_k: int, n_k: int,
+                 window: Optional[int]):
+    """(first, last) K blocks that some row of q block `i` attends, both
+    inside 0 .. n_k - 1: the kernel's liveness test of a grid step, and
+    what its K / V index maps clamp `j` into. Works on Python ints (the
+    tests, `tiling.flash_live_blocks`' twin) and on traced scalars."""
+    last = (q_offset + (i + 1) * block_q - 1) // block_k
+    if isinstance(last, int):
+        lo, hi = max, min
+    else:
+        lo, hi = jnp.maximum, jnp.minimum
+    last = hi(last, n_k - 1)
+    if window is None:
+        return 0, last
+    # the first row's window opens at column row_min - window + 1
+    first = lo(q_offset + i * block_q - window + 1, 0) // block_k
+    return hi(first, last), last
+
+
+def clamped_k_block(j, i, q_offset, block_q: int, block_k: int, n_k: int,
+                    window: Optional[int]):
+    """The K block grid step (i, j) points its DMA at: `j` inside the live
+    range of q block `i`, the nearer end outside it, so that a dead step
+    names the block already resident and Mosaic issues no DMA for it."""
+    first, last = live_k_range(i, q_offset, block_q, block_k, n_k, window)
+    if isinstance(last, int) and isinstance(j, int):
+        return min(max(j, first), last)
+    return jnp.minimum(jnp.maximum(j, first), last)
+
+
+def _lanes(x, n: int):
+    """A lane-replicated [rows, 128] value at `n` lanes."""
+    if n % _LANES == 0:
+        return x if n == _LANES else pltpu.repeat(x, n // _LANES, axis=1)
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
 
 def _kernel(
-    start_ref,  # SMEM [B] int32: per-row pad offsets (indexed by program_id)
-    qoff_ref,  # SMEM [1] int32: global slot of q position 0
-    q_ref,  # VMEM [1, 1, BQ, D]
+    start_ref,  # SMEM [B] int32 (scalar prefetch): per-row pad offsets
+    qoff_ref,  # SMEM [1] int32 (scalar prefetch): global slot of q position 0
+    q_ref,  # VMEM [1, 1, group, BQ, D]
     k_ref,  # VMEM [1, 1, BK, D]
     v_ref,  # VMEM [1, 1, BK, D]
     *refs,  # (+ ks/vs VMEM [1, 1, BK, 1] f32 when quantized) o, scratch
     scale: float,
+    group: int,
     block_q: int,
     block_k: int,
-    causal: bool,
     window: Optional[int],
     softcap: Optional[float],
     quantized: bool,
@@ -68,6 +118,7 @@ def _kernel(
     b = pl.program_id(0)
     i, j = pl.program_id(2), pl.program_id(3)
     n_k = pl.num_programs(3)
+    rows = group * block_q
 
     @pl.when(j == 0)
     def _init():
@@ -76,85 +127,90 @@ def _kernel(
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     qoff = qoff_ref[0]
-    row_max = qoff + (i + 1) * block_q - 1  # largest global q slot in block
-    # K block is live unless entirely above the causal diagonal / outside
-    # the sliding window of every query row in this Q block.
-    live = jnp.bool_(True)
-    if causal:
-        live = live & (j * block_k <= row_max)
-    if window is not None:
-        row_min = qoff + i * block_q
-        live = live & ((j + 1) * block_k - 1 > row_min - window)
+    # a K block is live unless it lies wholly above the causal diagonal
+    # or outside the sliding window of every position of this q block;
+    # the index maps hold a dead step on a live block, so it costs no DMA
+    first, last = live_k_range(i, qoff, block_q, block_k, n_k, window)
 
-    @pl.when(live)
+    @pl.when((j >= first) & (j <= last))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [BQ, D]
-        # shared KV decode body (fp8 codes cross as uint8 bits and go
-        # through the same qdecode bit decoder as fp8 GEMM weights);
-        # the [BK, 1] scale broadcasts over D
-        k = qdecode.decode_kv(
-            k_ref[0, 0], ks_ref[0, 0] if quantized else None, kv_value
-        )  # [BK, D]
+        # the group's heads stacked on sublanes: row r is position
+        # r % block_q of head r // block_q
+        q = q_ref[0, 0].reshape(rows, q_ref.shape[-1])
+        if quantized:
+            # fp8 codes cross as uint8 bits and go through the same
+            # qdecode bit decoder as fp8 GEMM weights; the [BK, 1] scale
+            # broadcasts over D. Decoded tiles are float32, and so is
+            # their product
+            k = qdecode.decode_kv(k_ref[0, 0], ks_ref[0, 0], kv_value)
+            v = qdecode.decode_kv(v_ref[0, 0], vs_ref[0, 0], kv_value)
+        else:
+            k, v = k_ref[0, 0], v_ref[0, 0]
+        # operands as they are stored (bf16 q and cache: exact products
+        # on the MXU), float32 accumulation and float32 from there on
+        dt = jnp.promote_types(q.dtype, k.dtype)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [BQ, BK]
+            q.astype(dt), k.astype(dt), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [rows, BK]
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
 
-        rows = qoff + i * block_q + jax.lax.broadcasted_iota(
+        # one [BQ, BK] mask serves every head of the group
+        pos = qoff + i * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0
         )
         cols = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
-        valid = cols >= start_ref[b]
-        if causal:
-            valid = valid & (cols <= rows)
+        valid = (cols >= start_ref[b]) & (cols <= pos)
         if window is not None:
-            valid = valid & (cols > rows - window)
-        s = jnp.where(valid, s, _NEG_INF)
+            valid = valid & (cols > pos - window)
+        s = jnp.where(
+            valid[None], s.reshape(group, block_q, block_k), _NEG_INF
+        ).reshape(rows, block_k)
 
-        m_prev = m_scr[:, :1]  # [BQ, 1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        # m, l and alpha stay lane-replicated [rows, 128], as the scratch
+        # holds them: a [rows, 1] column fills as many vregs, and every
+        # use of one costs a lane broadcast a sublane tile
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        # exp(-1e30 - (-1e30)) = 1 on fully-masked rows; zero explicitly.
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # [BQ, BK]
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-
-        v = qdecode.decode_kv(
-            v_ref[0, 0], vs_ref[0, 0] if quantized else None, kv_value
-        )  # [BK, D]
+        # a row that has met no valid column yet reads exp(0) = 1 on its
+        # masked ones; its first valid block wipes that (alpha = 0), and
+        # a row that never meets one is zeroed in _finalize
+        p = jnp.exp(s - _lanes(m_new, block_k))  # [rows, BK]
+        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[:] = acc_scr[:] * _lanes(alpha, acc_scr.shape[1]) + pv
+        m_scr[:] = m_new
 
     @pl.when(j == n_k - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        out = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        seen = m_scr[:] > 0.5 * _NEG_INF
+        inv = jnp.where(seen, 1.0 / jnp.where(seen, l_scr[:], 1.0), 0.0)
+        out = acc_scr[:] * _lanes(inv, acc_scr.shape[1])
+        o_ref[0, 0] = out.reshape(o_ref.shape[2:]).astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "window", "softcap", "scale", "block_q", "block_k", "interpret"
+        "window", "softcap", "scale", "block_q", "block_k", "interpret"
     ),
 )
 def _flash(
     q, k, v, start, q_offset, k_scale, v_scale,
-    causal: bool, window: Optional[int], softcap: Optional[float],
+    window: Optional[int], softcap: Optional[float],
     scale: float, block_q: int, block_k: int, interpret: bool,
 ):
-    B, Hq, T, D = q.shape
-    _, Hkv, S, _ = k.shape
-    group = Hq // Hkv
+    B, Hkv, group, T, D = q.shape
+    S = k.shape[2]
     n_q, n_k = T // block_q, S // block_k
+    rows = group * block_q
     quantized = k_scale is not None
     kv_value = ("e4m3",) if k.dtype == jnp.float8_e4m3fn else ("e5m2",)
     if quantized:
@@ -164,53 +220,48 @@ def _flash(
         k = jax.lax.bitcast_convert_type(k, jnp.uint8)
         v = jax.lax.bitcast_convert_type(v, jnp.uint8)
 
-    grid = (B, Hq, n_q, n_k)
     kernel = functools.partial(
         _kernel,
-        scale=scale, block_q=block_q, block_k=block_k,
-        causal=causal, window=window, softcap=softcap, quantized=quantized,
+        scale=scale, group=group, block_q=block_q, block_k=block_k,
+        window=window, softcap=softcap, quantized=quantized,
         kv_value=kv_value,
     )
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, D), lambda b, h, i, j: (b, h // group, j, 0),
-        memory_space=pltpu.VMEM,
-    )
-    in_specs = [
-        pl.BlockSpec((B,), lambda b, h, i, j: (0,), memory_space=pltpu.SMEM),
-        pl.BlockSpec((1,), lambda b, h, i, j: (0,), memory_space=pltpu.SMEM),
-        pl.BlockSpec(
-            (1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        kv_spec, kv_spec,
-    ]
+
+    def q_index(b, h, i, j, start_ref, qoff_ref):
+        return (b, h, 0, i, 0)
+
+    def kv_index(b, h, i, j, start_ref, qoff_ref):
+        return (b, h, clamped_k_block(j, i, qoff_ref[0], block_q, block_k,
+                                      n_k, window), 0)
+
+    q_spec = pl.BlockSpec((1, 1, group, block_q, D), q_index)
+    in_specs = [q_spec,
+                pl.BlockSpec((1, 1, block_k, D), kv_index),
+                pl.BlockSpec((1, 1, block_k, D), kv_index)]
     args = [start, q_offset, q, k, v]
     if quantized:
         # [B, Hkv, S, 1] f32: a trailing singleton keeps the block rank-2
         # in (sublane, lane) with a full-dim lane (always legal)
-        sc_spec = pl.BlockSpec(
-            (1, 1, block_k, 1), lambda b, h, i, j: (b, h // group, j, 0),
-            memory_space=pltpu.VMEM,
-        )
-        in_specs += [sc_spec, sc_spec]
+        in_specs += [pl.BlockSpec((1, 1, block_k, 1), kv_index)] * 2
         args += [k_scale, v_scale]
     return pl.pallas_call(
         kernel,
         name="flash_attention",
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0),
-            memory_space=pltpu.VMEM,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, Hkv, n_q, n_k),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((rows, _LANES), jnp.float32),
+                pltpu.VMEM((rows, _LANES), jnp.float32),
+                pltpu.VMEM((rows, D), jnp.float32),
+            ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, T, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(*args)
@@ -228,13 +279,15 @@ def flash_attention(
     scale: Optional[float] = None,
     k_scale: Optional[jax.Array] = None,  # [B, S, Hkv] fp8 dequant scales
     v_scale: Optional[jax.Array] = None,
-    block_q: int = FLASH_BLOCK_Q,
-    block_k: int = FLASH_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Returns [B, T, Hq, D] in q.dtype. Pads T/S/D to tile multiples
     internally; padding key slots are excluded by the causal mask (they
-    lie beyond every query's global slot).
+    lie beyond every query's global slot). The tiles are
+    `tiling.flash_blocks`'; `block_q` / `block_k` override them (tests,
+    scripts/flash_kernel_bench.py).
 
     With k_scale/v_scale, k/v are fp8 codes from a quantized KV cache
     and dequantize per block IN-KERNEL (the paged kernel's fp8 story):
@@ -245,6 +298,7 @@ def flash_attention(
 
     B, T, Hq, D = q.shape
     _, S, Hkv, _ = k.shape
+    group = Hq // Hkv
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     if interpret is None:
@@ -255,13 +309,20 @@ def flash_attention(
         q_offset = jnp.zeros((), jnp.int32)
     assert causal, "non-causal path uses ops.attention (bidirectional encoders)"
 
-    block_q, block_k = flash_blocks(T, S, block_q, block_k)
+    block_q, block_k = flash_blocks(T, S, D, group, k.dtype.itemsize,
+                                    block_q, block_k)
     Tp, Sp, Dp = round_up(T, block_q), round_up(S, block_k), round_up(D, _LANES)
+    steps = B * Hkv * (Tp // block_q) * (Sp // block_k)
+    live = B * Hkv * flash_live_blocks(T, S, block_q, block_k, window=window)
+    routes.note("flash", f"q {group}x{block_q} x k {block_k}",
+                f"{live} of {steps} steps live at T={T} S={S}")
 
-    qt = jnp.transpose(q, (0, 2, 1, 3))  # [B, Hq, T, D]
-    kt = jnp.transpose(k, (0, 2, 1, 3))
+    # [B, Hkv, group, T, D]: query head h is head h % group of KV head
+    # h // group
+    qt = jnp.transpose(q.reshape(B, T, Hkv, group, D), (0, 2, 3, 1, 4))
+    kt = jnp.transpose(k, (0, 2, 1, 3))  # [B, Hkv, S, D]
     vt = jnp.transpose(v, (0, 2, 1, 3))
-    qt = jnp.pad(qt, ((0, 0), (0, 0), (0, Tp - T), (0, Dp - D)))
+    qt = jnp.pad(qt, ((0, 0),) * 3 + ((0, Tp - T), (0, Dp - D)))
     kt = jnp.pad(kt, ((0, 0), (0, 0), (0, Sp - S), (0, Dp - D)))
     vt = jnp.pad(vt, ((0, 0), (0, 0), (0, Sp - S), (0, Dp - D)))
 
@@ -276,6 +337,7 @@ def flash_attention(
         start.astype(jnp.int32),
         q_offset.astype(jnp.int32).reshape(1),
         prep_scale(k_scale), prep_scale(v_scale),
-        causal, window, softcap, scale, block_q, block_k, interpret,
+        window, softcap, scale, block_q, block_k, interpret,
     )
-    return jnp.transpose(out[:, :, :T, :D], (0, 2, 1, 3))
+    out = jnp.transpose(out[:, :, :, :T, :D], (0, 3, 1, 2, 4))
+    return out.reshape(B, T, Hq, D)

@@ -299,19 +299,77 @@ def lora_fused_ok(R: int, K: int) -> bool:
 #: operand tile goes below it in the lane dimension
 MOSAIC_LANES = 128
 
-#: flash attention default q/k block edge (clamped to the padded
-#: sequence extents by `flash_blocks`)
-FLASH_BLOCK_Q = 128
-FLASH_BLOCK_K = 128
+#: flash attention's tile caps. A grid step costs about 0.35 us on a v5e
+#: whatever it holds, so a step holds `FLASH_BLOCK_K` keys against up to
+#: `FLASH_ROWS` q rows (the `group` query heads of one KV head stacked on
+#: `block_q` positions). Positions stop at `FLASH_BLOCK_Q`: a tile on the
+#: causal diagonal computes about (block_q + block_k) / 2 dead columns a
+#: row, so rows come from heads before positions.
+FLASH_BLOCK_Q = 512
+FLASH_BLOCK_K = 512
+FLASH_ROWS = 1024
 
 
-def flash_blocks(T: int, S: int,
-                 block_q: int = FLASH_BLOCK_Q,
-                 block_k: int = FLASH_BLOCK_K) -> tuple:
-    """The (block_q, block_k) flash_attention actually runs at for a
-    [T] x [S] problem: the policy default clamped to the 16-padded
-    sequence extents (short prefills run one small block per axis)."""
-    return (min(block_q, round_up(T, 16)), min(block_k, round_up(S, 16)))
+def flash_tile_bytes(block_q: int, block_k: int, D: int, group: int = 1,
+                     itemsize: int = 2) -> int:
+    """VMEM one flash grid step is priced at: the q and output blocks
+    (2-byte, double-buffered), the K and V blocks at the cache's
+    `itemsize` (double-buffered; a 1-byte cache also holds its decoded
+    float32 tiles and its scales), the float32 m / l / acc scratch, and
+    the [rows, block_k] scores, probabilities (float32) and the
+    probabilities as the context dot's operand."""
+    rows, Dp = group * block_q, round_up(D, MOSAIC_LANES)
+    q_out = 2 * 2 * rows * Dp * 2
+    kv = 2 * 2 * block_k * Dp * itemsize
+    if itemsize == 1:
+        kv += 2 * block_k * Dp * 4 + 2 * 2 * block_k * MOSAIC_LANES * 4
+    scratch = rows * Dp * 4 + 2 * rows * MOSAIC_LANES * 4
+    return q_out + kv + scratch + rows * block_k * (4 + 4 + 2)
+
+
+def _flash_tile(n: int, cap: int) -> int:
+    """A tile edge for an extent of `n`: all of it, 16-padded, up to one
+    lane tile; beyond, the largest multiple of 128 up to `cap` that
+    divides the 128-padded extent, so that nothing is padded (and
+    copied) by more than a lane tile."""
+    if n <= MOSAIC_LANES:
+        return round_up(n, 16)
+    tiles = round_up(n, MOSAIC_LANES) // MOSAIC_LANES
+    return MOSAIC_LANES * max(
+        d for d in range(1, max(cap // MOSAIC_LANES, 1) + 1)
+        if tiles % d == 0)
+
+
+def flash_blocks(T: int, S: int, D: int = MOSAIC_LANES, group: int = 1,
+                 itemsize: int = 2, block_q=None, block_k=None) -> tuple:
+    """The (block_q, block_k) flash_attention runs a [T] x [S] problem
+    at, from what it can observe: `block_q` POSITIONS of the `group`
+    query heads that share a KV head (group * block_q q rows a step)
+    against `block_k` keys. The caps above, cut until
+    `flash_tile_bytes` fits `VMEM_BUDGET` (positions first, then keys:
+    wide heads, many heads a group, a 1-byte cache), and clamped to the
+    extents (short prefills run one small block an axis). A `block_q` /
+    `block_k` given is taken as it is, clamped to the 16-padded extent
+    (tests and the kernel bench)."""
+    cap_q = max(
+        MOSAIC_LANES,
+        min(FLASH_BLOCK_Q, FLASH_ROWS // group // MOSAIC_LANES * MOSAIC_LANES))
+    cap_k = FLASH_BLOCK_K
+
+    def fit(cap, given, n):
+        return (min(given, round_up(n, 16)) if given
+                else _flash_tile(n, cap))
+
+    while True:
+        bq, bk = fit(cap_q, block_q, T), fit(cap_k, block_k, S)
+        if flash_tile_bytes(bq, bk, D, group, itemsize) <= VMEM_BUDGET:
+            return bq, bk
+        if not block_q and cap_q > MOSAIC_LANES and bq > MOSAIC_LANES:
+            cap_q -= MOSAIC_LANES
+        elif not block_k and cap_k > MOSAIC_LANES and bk > MOSAIC_LANES:
+            cap_k -= MOSAIC_LANES
+        else:
+            return bq, bk
 
 
 def flash_live_blocks(T: int, S: int, block_q: int, block_k: int,

@@ -79,6 +79,141 @@ def test_flash_multiblock(rng):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
 
 
+# ---------------------------------------------------------------------------
+# the prefill kernel at the tiles `tiling.flash_blocks` picks (PR 45):
+# bf16 operands, the group's heads stacked in one q block, 512 keys a step,
+# dead K blocks neither computed nor named by the index maps
+# ---------------------------------------------------------------------------
+
+# id: (T, S, q_offset, start, group, D, window, softcap, fp8)
+_FLASH_CASES = {
+    "t16-d64": (16, 64, 0, 0, 1, 64, None, None, False),
+    "t48-offset37-g4": (48, 128, 37, 0, 4, 128, None, None, False),
+    "t250-start5-g7-d64": (250, 640, 37, 5, 7, 64, None, None, False),
+    "t1024-offset512-g4": (1024, 2048, 512, 0, 4, 128, None, None, False),
+    "t1792-g4": (1792, 2048, 0, 0, 4, 128, None, None, False),
+    "t1024-d256": (1024, 2048, 0, 0, 1, 256, None, None, False),
+    "t250-window100-start3": (250, 512, 0, 3, 4, 128, 100, None, False),
+    "t1024-offset512-window300-g7": (1024, 1664, 512, 0, 7, 128, 300, None,
+                                     False),
+    "t48-softcap": (48, 128, 37, 0, 4, 128, None, 30.0, False),
+    "t250-softcap-window-d256": (250, 384, 37, 0, 1, 256, 64, 30.0, False),
+    "t250-fp8-g4": (250, 640, 37, 5, 4, 128, None, None, True),
+    "t1024-fp8-window": (1024, 1152, 0, 0, 1, 128, 200, None, True),
+}
+
+
+@pytest.mark.parametrize("case", list(_FLASH_CASES))
+def test_flash_bf16_at_the_policys_tiles(rng, case):
+    """bfloat16 q and cache (or fp8 codes and scales) against the XLA
+    masked attention on the float32 values of the same inputs; slots past
+    q_offset + T exist in every case and hold noise."""
+    from bigdl_tpu.kvcache import _quantize_heads
+
+    T, S, q_offset, start, group, D, window, softcap, fp8 = \
+        _FLASH_CASES[case]
+    hkv = 1 if T > 512 else 2
+    q = jnp.asarray(rng.normal(size=(1, T, hkv * group, D)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(1, S, hkv, D)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(1, S, hkv, D)), jnp.bfloat16)
+    st = jnp.asarray([start], jnp.int32)
+    off = jnp.asarray(q_offset, jnp.int32)
+    assert q_offset + T < S
+    kw = dict(start=st, q_offset=off, window=window, softcap=softcap,
+              interpret=True)
+    f32 = jnp.float32
+    if fp8:
+        kq, ks = _quantize_heads(k.astype(f32))
+        vq, vs = _quantize_heads(v.astype(f32))
+        out = flash_attention(q, kq, vq, k_scale=ks, v_scale=vs, **kw)
+        k = kq.astype(f32) * ks.astype(f32)[..., None]
+        v = vq.astype(f32) * vs.astype(f32)[..., None]
+    else:
+        out = flash_attention(q, k, v, **kw)
+    assert out.dtype == jnp.bfloat16 and out.shape == q.shape
+    ref = _masked_reference(q.astype(f32), k.astype(f32), v.astype(f32),
+                            st, off, window=window, softcap=softcap)
+    pad = max(start - q_offset, 0)  # pad positions attend nothing: zeros
+    np.testing.assert_allclose(np.asarray(out, np.float32)[:, pad:],
+                               np.asarray(ref)[:, pad:], atol=2e-2)
+
+
+def test_flash_rows_before_start_read_zero(rng):
+    """A left-padded row's pad positions attend nothing: zeros, not the
+    mean of V a softmax over masked scores alone would give."""
+    T, D = 32, 16
+    q, k, v = (jnp.asarray(rng.normal(size=(1, T, 2, D)), jnp.bfloat16)
+               for _ in range(3))
+    out = flash_attention(q, k, v, start=jnp.asarray([5], jnp.int32),
+                          interpret=True)
+    assert np.all(np.asarray(out[:, :5], np.float32) == 0.0)
+    assert np.all(np.any(np.asarray(out[:, 5:], np.float32) != 0.0, -1))
+
+
+# (T, S, D, group, itemsize): the cells' prefill shapes and the odd ones
+_FLASH_SHAPES = [
+    (1792, 2048, 128, 4, 2), (1024, 2048, 128, 4, 2), (1024, 1152, 128, 4, 2),
+    (512, 2048, 128, 7, 2), (192, 2048, 128, 7, 2), (4096, 5120, 256, 1, 2),
+    (8192, 9216, 128, 7, 2), (16, 2048, 128, 4, 2), (250, 2048, 64, 1, 2),
+    (1792, 2048, 128, 4, 1), (1024, 1024, 256, 16, 2),
+]
+
+
+@pytest.mark.parametrize("shape", _FLASH_SHAPES, ids=str)
+def test_flash_blocks_fit_the_budget_they_state(shape):
+    from bigdl_tpu.ops.pallas import tiling
+
+    T, S, D, group, itemsize = shape
+    bq, bk = tiling.flash_blocks(T, S, D, group, itemsize)
+    assert bq % 16 == 0 and bk % 16 == 0
+    if T > 128:  # nothing padded by more than a lane tile
+        assert bq % 128 == 0 and tiling.round_up(T, 128) % bq == 0
+    if S > 128:
+        assert bk % 128 == 0 and tiling.round_up(S, 128) % bk == 0
+    assert bk <= tiling.FLASH_BLOCK_K and bq <= tiling.FLASH_BLOCK_Q
+    assert (tiling.flash_tile_bytes(bq, bk, D, group, itemsize)
+            <= tiling.VMEM_BUDGET) or (bq <= 128 and bk <= 128)
+    if min(T, S) >= 1024 and S % 512 == 0 and group * D <= 1024:
+        assert bk == 512 and group * bq >= 512  # a step's worth of work
+
+
+@pytest.mark.parametrize("shape", _FLASH_SHAPES, ids=str)
+@pytest.mark.parametrize("window", [None, 300])
+def test_flash_live_blocks_counts_the_steps_the_kernel_computes(
+        shape, window):
+    """`tiling.flash_live_blocks` (the roofline's and the route line's
+    count) against the kernel's own test of a grid step, `live_k_range`,
+    at the tiles the policy returns; and the K index map names a live
+    block at every step: never one past the causal frontier, never one
+    behind the window."""
+    from bigdl_tpu.ops.pallas import tiling
+    from bigdl_tpu.ops.pallas.flash_attention import (
+        clamped_k_block, live_k_range,
+    )
+
+    T, S, D, group, itemsize = shape
+    bq, bk = tiling.flash_blocks(T, S, D, group, itemsize)
+    n_q, n_k = -(-T // bq), -(-S // bk)
+    for q_offset in (0, 37, S - T):
+        computed = 0
+        for i in range(n_q):
+            first, last = live_k_range(i, q_offset, bq, bk, n_k, window)
+            assert 0 <= first <= last <= n_k - 1
+            computed += last - first + 1
+            row_min, row_max = q_offset + i * bq, q_offset + (i + 1) * bq - 1
+            for j in range(n_k):
+                jj = clamped_k_block(j, i, q_offset, bq, bk, n_k, window)
+                assert first <= jj <= last
+                assert jj == j or not first <= j <= last
+                # its first key is no later than the block's last row,
+                # or it is the row range's last block (T padded past S)
+                assert jj * bk <= row_max or jj == n_k - 1
+                if window is not None:
+                    assert (jj + 1) * bk - 1 > row_min - window
+        assert computed == tiling.flash_live_blocks(
+            T, S, bq, bk, q_offset=q_offset, window=window)
+
+
 @pytest.mark.parametrize("m", [1, 4])
 def test_qmatmul_int4_matches_dequant(rng, m):
     K, O = 128, 256

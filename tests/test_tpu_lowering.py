@@ -474,3 +474,45 @@ def test_paged_prefill_handles_no_whole_pool(one_chip, monkeypatch, name,
     m = c.memory_analysis()
     assert m.alias_size_in_bytes >= 2 * pool.k.size * pool.k.dtype.itemsize
     assert m.temp_size_in_bytes < math.prod(layer) * pool.k.dtype.itemsize
+
+
+# The prefill's attention at the cells' shapes (PR 45): name: (T, S, Hq, Hkv,
+# D, window, softcap, fp8 cache). The q block stacks the heads of a group on
+# sublanes (a reshape Mosaic takes only at whole sublane tiles), the index
+# maps read the scalar-prefetched offset, and a step's scores are float32
+# [group * block_q, block_k] in VMEM
+_FLASH = {
+    "mistral-7b longprompt": (1792, 2048, 32, 8, 128, 4096, None, False),
+    "mistral-7b generate": (1024, 1152, 32, 8, 128, 4096, None, False),
+    "mistral-7b chat, 16 tokens": (16, 2048, 32, 8, 128, 4096, None, False),
+    "qwen2-7b, group of 7": (768, 2048, 28, 4, 128, None, None, False),
+    "glm-4.7-flash expanded, D 256": (4096, 5120, 20, 20, 256, None, None,
+                                      False),
+    "smallthinker window layer": (8192, 9216, 28, 4, 128, 4096, None, False),
+    "gemma2 softcap, D 256": (1024, 1024, 16, 8, 256, None, 50.0, False),
+    "head of 64": (512, 1024, 8, 8, 64, None, None, False),
+    "fp8 cache": (1024, 2048, 32, 8, 128, None, None, True),
+}
+
+
+@pytest.mark.parametrize("name", list(_FLASH))
+def test_flash_attention_compiles_at_the_cells_shapes(one_chip, name):
+    from bigdl_tpu.ops.pallas.flash_attention import flash_attention
+
+    T, S, Hq, Hkv, D, window, softcap, fp8 = _FLASH[name]
+    kv = jnp.float8_e5m2 if fp8 else jnp.bfloat16
+    args = [_sds((1, T, Hq, D), jnp.bfloat16, one_chip),
+            _sds((1, S, Hkv, D), kv, one_chip),
+            _sds((1, S, Hkv, D), kv, one_chip),
+            _sds((1,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip)]
+    if fp8:
+        args += [_sds((1, S, Hkv), jnp.float16, one_chip)] * 2
+
+    def call(q, k, v, start, q_offset, k_scale=None, v_scale=None):
+        return flash_attention(q, k, v, start=start, q_offset=q_offset,
+                               window=window, softcap=softcap,
+                               k_scale=k_scale, v_scale=v_scale,
+                               interpret=False)
+
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert "flash_attention" in text
