@@ -130,7 +130,7 @@ def insert_row(cache: KVCache, pcache: KVCache, slot, pad) -> KVCache:
 def swap_out_row(cache: KVCache, slot: int, n: Optional[int] = None):
     """Copy one pool row's KV (every layer, first `n` slots — the row's
     live region; None = full row) to host RAM — the dense-engine half of
-    serving preemption (the paged twin is kvpaged.swap_out_pages).
+    serving preemption (the paged twin is kvpaged.CacheKind.swap_out).
     Returns (k, v, k_scale|None, v_scale|None) numpy arrays;
     byte-preserving, so swap-in + decode is bit-exact. Slots past pos
     are never read (attention masks them; decode overwrites at pos), so

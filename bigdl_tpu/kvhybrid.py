@@ -42,9 +42,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from bigdl_tpu import kvpaged
+from bigdl_tpu import kvpaged, kvstate
 
 KIND = "state_beside_pages"
 _HI = jax.lax.Precision.HIGHEST  # float32 operands stay float32 on the MXU
@@ -275,40 +274,55 @@ def prefill_chunks(n_tokens: int, chunk: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# a slot to host RAM and back (the engine's preemption)
+# the cache kind (kvpaged.CacheKind): pages, and the slot's own state row
 # ---------------------------------------------------------------------------
 
-@dataclasses.dataclass
-class HostHybrid:
-    """One slot parked in host RAM: its pages and its state row, numpy
-    copies, bit for bit."""
+class _StateBesidePages(kvpaged.CacheKind):
+    name = label = KIND
+    arrays = ("k", "v", "conv", "ssm")
+    page_arrays = ("k", "v")
+    needs_paged = (
+        "{kind} is served with paged=True: a slot holds KV pages for the "
+        "attention layers and a state row for the others")
+    refuses = kvpaged.not_wired("R4", "quantize_kv", "speculative",
+                                "adapters")
+    # a prefix hit would need the state at the prefix's end
+    share_prefixes = tp_sharded = False
+    make_pool = kvpaged.CacheKind._family_pool
+    metrics = staticmethod(kvstate.state_metrics)
 
-    k: np.ndarray  # [La, n, page, Hkv, D]
-    v: np.ndarray
-    conv: np.ndarray  # [Lm, d_conv - 1, C]
-    ssm: np.ndarray  # [Lm, heads * head size, d_state]
+    def row_view(self, leaves, tables, pos0, last_idx, slot, cfg, geo):
+        """The pool itself behind the slot's one-row table and its state
+        row `slot`: keys and values go into the row's pages, the state runs
+        from nothing when `pos0` is 0 and else from the row's own; the
+        positions past `last_idx` leave the state and the convolution's
+        tail as they were."""
+        cache = HybridCache(
+            **dict(zip(self.arrays, leaves)), block_tables=tables[0],
+            pos=pos0, start=jnp.zeros((1,), jnp.int32), rows=slot,
+            valid_len=last_idx[None] + 1)
+        return cache, cache
 
-    @property
-    def nbytes(self) -> int:
-        return (self.k.nbytes + self.v.nbytes + self.conv.nbytes
-                + self.ssm.nbytes)
+    def write_back(self, pool, row, n_tokens, last_idx, cfg):
+        return self.leaves(row)
+
+    axes = (1, 1, 2, 1)
+
+    def _spots(self, pages, slot, window_pages):
+        return pages, pages, slot, slot  # the pages, and the slot's own row
+
+    def state_row_nbytes(self, cache):
+        return row_nbytes(cache)
+
+    def note_chunk(self, st, cfg, geo, bucket, n):
+        st.state_chunks += prefill_chunks(bucket, cfg.mamba_chunk_size)
+
+    def prefill_args(self, st):
+        return {"state_chunks": st.state_chunks}
+
+    def decode_args(self, cfg, table, live, moved):
+        return {**kvstate.state_decode_args(live, moved),
+                **super().decode_args(cfg, table, live, moved)}
 
 
-def swap_out(cache: HybridCache, pages, row: int) -> HostHybrid:
-    """Copy the pages `pages` and state row `row` to the host."""
-    at = jnp.asarray(pages, jnp.int32)
-    return HostHybrid(k=np.asarray(cache.k[:, at]),
-                      v=np.asarray(cache.v[:, at]),
-                      conv=np.asarray(cache.conv[:, :, row]),
-                      ssm=np.asarray(cache.ssm[:, row]))
-
-
-def swap_in(cache: HybridCache, k, v, conv, ssm, row, pages) -> HybridCache:
-    """Write a parked slot into the pages `pages` and state row `row`; jit
-    with the cache donated, the write is in place."""
-    at = pages.astype(jnp.int32)
-    return dataclasses.replace(
-        cache, k=cache.k.at[:, at].set(k), v=cache.v.at[:, at].set(v),
-        conv=cache.conv.at[:, :, row].set(conv),
-        ssm=cache.ssm.at[:, row].set(ssm))
-
+CACHE_KIND = _StateBesidePages()

@@ -43,10 +43,12 @@ bigdl_tpu.kvcache), so llama.forward runs on either cache unchanged.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import types
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @jax.tree_util.register_dataclass
@@ -285,8 +287,6 @@ class AdapterPageStore:
     def write(self, pages, flat) -> None:
         """Scatter a flat bf16 host/device vector into physical pages
         `pages` (zero-padded to the page frame)."""
-        import numpy as np
-
         n = len(pages) * self.page_elems
         v = np.zeros((n,), np.float32)
         v[: flat.size] = np.asarray(flat, np.float32).ravel()
@@ -306,103 +306,21 @@ class AdapterPageStore:
 # Host-RAM page swap (serving preemption)
 # ---------------------------------------------------------------------------
 
-@dataclasses.dataclass
-class HostKVPages:
-    """A preempted request's KV pages parked in host RAM (all layers,
-    page-granular). The serving engine swaps a victim out here, releases
-    its device pages, and swaps back into freshly allocated (possibly
-    different) physical pages on resume — contents are byte-preserved, so
-    decode after swap-in is bit-exact with the uninterrupted run. On a
-    real TPU runtime `jax.device_get` stages through the runtime's host
-    transfer buffers; the arrays below are plain (pageable) numpy — a
-    pinned-allocation fast path is a perf follow-up, not a correctness
-    one."""
-
-    k: "object"  # np.ndarray [L, n, page, Hkv, D] in the pool dtype
-    v: "object"
-    k_scale: Optional[object] = None  # [L, n, page, Hkv] when quantized
-    v_scale: Optional[object] = None
-
-    @property
-    def n_pages(self) -> int:
-        return self.k.shape[1]
+class HostPages(types.SimpleNamespace):
+    """A preempted request's part of a paged pool parked in host RAM: for
+    each array of its cache kind (`CacheKind.arrays`, by name) the slot's
+    pages, or its state row, over every layer. The serving engine swaps a
+    victim out here (`CacheKind.swap_out`), releases its device pages, and
+    swaps back into freshly allocated (possibly different) physical pages
+    on resume (`swap_in`): contents are byte-preserved, so decode after
+    swap-in is bit-exact with the uninterrupted run. On a real TPU runtime
+    the transfer stages through the runtime's host buffers; the arrays are
+    plain (pageable) numpy: a pinned-allocation fast path is a perf
+    follow-up, not a correctness one."""
 
     @property
     def nbytes(self) -> int:
-        n = self.k.nbytes + self.v.nbytes
-        if self.k_scale is not None:
-            n += self.k_scale.nbytes + self.v_scale.nbytes
-        return n
-
-
-def swap_out_pages(cache: PagedKVCache, pages) -> HostKVPages:
-    """Copy the listed physical pages' KV (every layer) to host RAM.
-    `pages` is a host-side list/array of physical page ids; the gather +
-    device→host transfer is one fused program per distinct page count."""
-    import numpy as np
-
-    ids = jnp.asarray(list(pages), jnp.int32)
-    k = np.asarray(jax.device_get(cache.k[:, ids]))
-    v = np.asarray(jax.device_get(cache.v[:, ids]))
-    ks = vs = None
-    if cache.quantized:
-        ks = np.asarray(jax.device_get(cache.k_scale[:, ids]))
-        vs = np.asarray(jax.device_get(cache.v_scale[:, ids]))
-    return HostKVPages(k=k, v=v, k_scale=ks, v_scale=vs)
-
-
-def swap_in_pages(cache: PagedKVCache, k, v, k_scale, v_scale,
-                  pages: jax.Array) -> PagedKVCache:
-    """Write a host blob's pages back into physical pages `pages` (a [n]
-    int32 array; need not be the pages the blob came from). jit-friendly:
-    the engine wraps it with donated cache buffers so the scatter happens
-    in place; one compiled program per distinct page count."""
-    upd = {"k": cache.k.at[:, pages].set(jnp.asarray(k, cache.k.dtype)),
-           "v": cache.v.at[:, pages].set(jnp.asarray(v, cache.v.dtype))}
-    if cache.quantized:
-        upd["k_scale"] = cache.k_scale.at[:, pages].set(
-            jnp.asarray(k_scale, cache.k_scale.dtype))
-        upd["v_scale"] = cache.v_scale.at[:, pages].set(
-            jnp.asarray(v_scale, cache.v_scale.dtype))
-    return dataclasses.replace(cache, **upd)
-
-
-@dataclasses.dataclass
-class HostLatentPages:
-    """`HostKVPages` for a `PagedLatentCache`: the parked pages' latents,
-    every layer, byte for byte."""
-
-    lat: "object"  # np.ndarray [L, n, page, r + dr] in the pool dtype
-
-    @property
-    def n_pages(self) -> int:
-        return self.lat.shape[1]
-
-    @property
-    def nbytes(self) -> int:
-        return self.lat.nbytes
-
-
-def swap_out_latent(cache: PagedLatentCache, pages) -> HostLatentPages:
-    import numpy as np
-
-    ids = jnp.asarray(list(pages), jnp.int32)
-    return HostLatentPages(lat=np.asarray(jax.device_get(cache.lat[:, ids])))
-
-
-def swap_in_latent(cache: PagedLatentCache, lat,
-                   pages: jax.Array) -> PagedLatentCache:
-    """`swap_in_pages` for latent pages (jitted by the engine with the
-    cache donated; one program per distinct page count)."""
-    return dataclasses.replace(cache, lat=cache.lat.at[:, pages].set(
-        jnp.asarray(lat, cache.lat.dtype)))
-
-
-def copy_latent_page(cache: PagedLatentCache, src, dst) -> PagedLatentCache:
-    """One physical page's latents (all layers) into another: the sub-page
-    prefix-sharing copy."""
-    return dataclasses.replace(
-        cache, lat=cache.lat.at[:, dst].set(cache.lat[:, src]))
+        return sum(a.nbytes for a in vars(self).values() if a is not None)
 
 
 def gather_row(cache: PagedKVCache):
@@ -524,3 +442,230 @@ def read_layer(
     k = k.reshape(B, mp * page, *k.shape[3:])
     v = v.reshape(B, mp * page, *v.shape[3:])
     return k.astype(dtype), v.astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# the cache KIND: what `serving/engine.InferenceEngine` asks of a paged cache
+# ---------------------------------------------------------------------------
+
+class Geometry(NamedTuple):
+    """What an engine's constructor fixes of a paged pool's size."""
+
+    n_slots: int
+    max_len: int
+    page_size: int
+    n_pages: int
+    max_pages_per_row: int
+    quantize_kv: bool = False
+
+
+_ASKED = {"quantize_kv": "quantize_kv", "speculative": "speculative serving",
+          "adapters": "adapter serving",
+          "prefill_chunk_tokens": "prefill_chunk_tokens"}
+
+
+def not_wired(where: str, *features: str) -> dict:
+    """`CacheKind.refuses` for features a kind has not been given yet."""
+    return {f: f"{_ASKED[f]} is not wired for {{kind}} yet (ROADMAP {where})"
+            for f in features}
+
+
+class CacheKind:
+    """A paged cache kind as the serving engine sees it (docs/serving.md,
+    "Cache kinds"): one stateless object beside the cache it describes,
+    chosen once (`serving/engine._cache_kind`) and kept as `engine.kind`.
+    This class IS the kind of KV pages (`KV_PAGES`); `LATENT_PAGES` below,
+    `kvstate.CACHE_KIND`, `kvhybrid.CACHE_KIND` and `kvwindow.CACHE_KIND`
+    override what differs."""
+
+    name = label = "kv_pages"  # `label`: what a refusal calls the kind
+    arrays: tuple = ("k", "v", "k_scale", "v_scale")  # the pool's leaves
+    page_arrays: tuple = arrays  # those a page number of the table indexes
+    needs_paged: Optional[str] = None  # the sentence that refuses paged=False
+    refuses: dict = {}  # feature -> the sentence that refuses it
+    share_prefixes = True  # what it tells `PageTable`, with `window`
+    tp_sharded = True  # a mesh shards the pool's KV heads over `tp`
+
+    def check(self, model_type: str, paged: bool, **asked) -> None:
+        """Raise the sentence of the first thing asked that it cannot serve."""
+        kind = f"{self.label} ({model_type})"
+        if not paged:
+            if self.needs_paged:
+                raise NotImplementedError(self.needs_paged.format(kind=kind))
+            return
+        for what, sentence in self.refuses.items():
+            if asked.get(what):
+                raise NotImplementedError(sentence.format(kind=kind))
+
+    def window(self, cfg) -> Optional[int]:
+        return None
+
+    def page_geometry(self, n_slots, max_len, page_size, n_pages) -> tuple:
+        """(page_size, n_pages) of the table: the caller's, where a page
+        holds tokens."""
+        return page_size, n_pages
+
+    def make_pool(self, cfg, geo: Geometry):
+        return init_paged(
+            cfg.num_hidden_layers, geo.n_pages, geo.page_size,
+            cfg.num_key_value_heads, cfg.head_dim_, geo.n_slots,
+            geo.max_pages_per_row, quantize_kv=geo.quantize_kv)
+
+    def _family_pool(self, cfg, geo: Geometry):
+        """`make_pool` of a kind whose family builds its pool."""
+        from bigdl_tpu.models import get_family
+
+        return get_family(cfg.model_type).init_paged_cache(
+            cfg, geo.n_pages, geo.page_size, geo.n_slots,
+            geo.max_pages_per_row)
+
+    def leaves(self, cache) -> tuple:
+        """The pool's arrays without tables and positions, as ONE pytree:
+        what a program donates."""
+        return tuple(getattr(cache, f) for f in self.arrays)
+
+    def with_leaves(self, cache, leaves):
+        return dataclasses.replace(cache, **dict(zip(self.arrays, leaves)))
+
+    # ---- an admission's prefill (traced: engine_paged_prefill) -------------
+
+    def row_view(self, leaves, tables, pos0, last_idx, slot, cfg, geo):
+        """(the pool behind a one-row table, the one-row cache the prefill
+        runs on): here the row's own pages gathered into the dense form."""
+        pool = PagedKVCache(
+            **dict(zip(self.arrays, leaves)), block_tables=tables[0],
+            pos=pos0, start=jnp.zeros((1,), jnp.int32))
+        return pool, gather_row(pool)
+
+    def write_back(self, pool, row, n_tokens: int, last_idx, cfg) -> tuple:
+        """The pool's leaves after the prefill left `row`."""
+        return self.leaves(scatter_row_pages(pool, row, n_tokens))
+
+    def forward_kw(self, last_idx) -> dict:
+        """What the prefill's forward takes beside the cache."""
+        return {}
+
+    # ---- pages between pools and the host ----------------------------------
+
+    axes: tuple = (1, 1, 1, 1)  # of each array, the axis `_spots` index
+
+    def _spots(self, pages, slot, window_pages) -> tuple:
+        """Where each array keeps a slot's part, along its axis of `axes`:
+        here its pages."""
+        return (pages,) * len(self.arrays)
+
+    def copy_page(self, cache, src, dst):
+        """One physical page (all layers) into another: the sub-page
+        prefix-sharing copy (slots past the shared run are overwritten by
+        the tail prefill or masked by pos)."""
+        pairs = zip(self.arrays, self._spots(src, None, None),
+                    self._spots(dst, None, None))
+        return dataclasses.replace(cache, **{
+            f: getattr(cache, f).at[:, j].set(getattr(cache, f)[:, i])
+            for f, i, j in pairs
+            if f in self.page_arrays and getattr(cache, f) is not None})
+
+    def swap_out(self, cache, pages, slot: int, window_pages) -> HostPages:
+        """Copy the slot's part of every array to host RAM: its pages
+        `pages` (and `window_pages`, physical ids, host lists) and its
+        state row, where the kind has them."""
+        at = self._spots(jnp.asarray(list(pages), jnp.int32), slot,
+                         jnp.asarray(list(window_pages), jnp.int32))
+        return HostPages(**{
+            f: None if a is None else np.asarray(a[(slice(None),) * ax + (i,)])
+            for f, a, i, ax in zip(self.arrays, self.leaves(cache), at,
+                                   self.axes)})
+
+    def swap_in(self, cache, parked: tuple, into: tuple):
+        """Write `parked` (a `swap_out` blob's arrays, in `arrays` order)
+        into `into` = (pages, slot, window pages), which need not be where
+        the blob came from; jitted by the engine with the cache donated,
+        the scatter is in place, one program per distinct page count."""
+        return self.with_leaves(cache, tuple(
+            None if a is None else
+            a.at[(slice(None),) * ax + (i,)].set(jnp.asarray(p, a.dtype))
+            for a, p, i, ax in zip(self.leaves(cache), parked,
+                                   self._spots(*into), self.axes)))
+
+    # ---- accounting (host) --------------------------------------------------
+
+    def state_row_nbytes(self, cache) -> int:
+        """Bytes of one slot's state row over all layers, where it has one."""
+        return 0
+
+    def token_nbytes(self, cfg) -> int:
+        """Bytes of one token's latents over all layers, where it has them."""
+        return 0
+
+    def note_chunk(self, st, cfg, geo: Geometry, bucket: int, n: int):
+        """Add a prefill chunk of `n` tokens padded to `bucket`, about to
+        run from `st.written`, to the `_PrefillState`'s counts."""
+        st.row_pages += geo.max_pages_per_row
+        st.pages_written += pages_spanned(
+            st.written, bucket, geo.page_size, geo.max_pages_per_row)
+
+    def prefill_args(self, st) -> dict:
+        """The `prefill` span's arguments: with `page_nbytes`, the pool
+        bytes the admission touched."""
+        return {"row_pages": st.row_pages, "pages_written": st.pages_written}
+
+    def decode_args(self, cfg, table, live, moved: int) -> dict:
+        """The `decode_step` span's: the table's pos still holds the
+        step's own."""
+        n_live, grid = table.grid_pages(live)
+        return {"live_pages": n_live, "grid_pages": grid}
+
+    def metrics(self, engine) -> list:
+        """Its `/metrics` families: (name, type, help, value) each."""
+        return []
+
+
+class _LatentPages(CacheKind):
+    """Pages of latents (`PagedLatentCache`): an MLA family's, made by its
+    `init_paged_cache`; the prefill runs on the pool itself."""
+
+    name, label = "latent_pages", "latent pages"
+    arrays = page_arrays = ("lat",)
+    refuses = not_wired("R1", "quantize_kv", "speculative", "adapters")
+    tp_sharded = False
+    make_pool = CacheKind._family_pool
+
+    def row_view(self, leaves, tables, pos0, last_idx, slot, cfg, geo):
+        pool = PagedLatentCache(lat=leaves[0], block_tables=tables[0],
+                                pos=pos0, start=jnp.zeros((1,), jnp.int32))
+        return pool, pool
+
+    def write_back(self, pool, row, n_tokens, last_idx, cfg):
+        return self.leaves(row)
+
+    def token_nbytes(self, cfg) -> int:
+        from bigdl_tpu.models import get_family
+
+        return get_family(cfg.model_type).latent_token_nbytes(cfg)
+
+    def note_chunk(self, st, cfg, geo, bucket, n):
+        # the expanded form up-projects the row's whole capacity
+        st.upprojected += geo.max_pages_per_row * geo.page_size
+
+    def prefill_args(self, st):
+        return {"latent_tokens_upprojected": st.upprojected}
+
+    def decode_args(self, cfg, table, live, moved):
+        n = sum(table.pos[i] + 1 for i in np.nonzero(live)[0])  # slots 0..pos
+        return {**super().decode_args(cfg, table, live, moved),
+                "latent_live_tokens": int(n),
+                "latent_bytes_read": int(n * self.token_nbytes(cfg))}
+
+    def metrics(self, engine):
+        pool = engine.pages.pool
+        return [
+            ("bigdl_tpu_latent_pages_in_use", "gauge", "latent pages held "
+             "by slots or the prefix cache (of n_pages - 1)",
+             pool.n_pages - 1 - pool.n_free),
+            ("bigdl_tpu_latent_token_bytes", "gauge", "bytes of one "
+             "token's latents over all layers",
+             self.token_nbytes(engine.config))]
+
+
+KV_PAGES = CacheKind()
+LATENT_PAGES = _LatentPages()

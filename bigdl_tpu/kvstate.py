@@ -55,6 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bigdl_tpu import kvpaged
+
 KIND = "power_retention"
 PREFILL_CHUNK = 128  # tokens of one chunk of the prefill form
 _HI = jax.lax.Precision.HIGHEST  # float32 operands stay float32 on the MXU
@@ -324,31 +326,90 @@ def prefill_chunks(n_tokens: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# rows to host RAM and back (the engine's preemption)
+# the cache kind (kvpaged.CacheKind): a state row a slot, and no pages
 # ---------------------------------------------------------------------------
 
-@dataclasses.dataclass
-class HostState:
-    """State rows parked in host RAM: numpy copies, bit for bit."""
-
-    S: np.ndarray  # [L, n, Hkv, D, P]
-    z: np.ndarray  # [L, n, Hkv, 1, P]
-
-    @property
-    def nbytes(self) -> int:
-        return self.S.nbytes + self.z.nbytes
-
-
-def swap_out_rows(state: RetentionState, pages) -> HostState:
-    """Copy the state rows behind `pages` (row + 1 each) to the host."""
-    at = jnp.asarray(pages, jnp.int32) - 1
-    return HostState(S=np.asarray(state.S[:, at]),
-                     z=np.asarray(state.z[:, at]))
+def state_metrics(engine) -> list:
+    """`CacheKind.metrics` of a kind whose slots hold state rows (in every
+    layer, here, or beside KV pages, kvhybrid.py): what a decode step reads
+    and writes again is the live rows, whatever the contexts."""
+    return [
+        ("bigdl_tpu_state_rows_live", "gauge", "slots whose state row the "
+         "next decode step reads and writes", int(engine.active.sum())),
+        ("bigdl_tpu_state_pool_bytes", "gauge", "device bytes of the "
+         "recurrent-state pool (every slot's row, all layers)",
+         engine.state_row_bytes * engine.n_slots),
+        ("bigdl_tpu_state_bytes_moved_total", "counter", "bytes of state "
+         "rows that decode steps read and wrote again",
+         engine.state_bytes_moved)]
 
 
-def swap_in_rows(state: RetentionState, S, z, pages) -> RetentionState:
-    """Write parked rows into the rows behind `pages`; jit with the state
-    donated, the write is in place."""
-    at = pages.astype(jnp.int32) - 1
-    return dataclasses.replace(
-        state, S=state.S.at[:, at].set(S), z=state.z.at[:, at].set(z))
+def state_decode_args(live, moved: int) -> dict:
+    """`CacheKind.decode_args` of the same kinds: the live rows, and the
+    bytes of them the step read and wrote again."""
+    return {"state_rows_live": int(live.sum()), "state_bytes_moved": moved}
+
+
+class _StateRows(kvpaged.CacheKind):
+    name = label = KIND
+    arrays = page_arrays = ("S", "z")
+    needs_paged = (
+        "{kind} is served with paged=True: a slot's recurrent state is a "
+        "row the page table owns, and there is no dense pool of keys to "
+        "fall back on")
+    refuses = {
+        "quantize_kv": "quantize_kv is not available for {kind}: the cache "
+                       "is a float32 recurrent state, not keys and values",
+        "speculative": "speculative serving is not available for {kind}: a "
+                       "rejected draft cannot be taken back out of a "
+                       "recurrent state by moving `pos`"}
+    # a prefix hit would need the state at the prefix's end
+    share_prefixes = tp_sharded = False
+    metrics = staticmethod(state_metrics)
+
+    def page_geometry(self, n_slots, max_len, page_size, n_pages):
+        # what a page is here: the slot's state row, whole. It never grows,
+        # so one page spans `max_len` tokens and a row of the table has one
+        # entry; `page_size` and `n_pages` size nothing (a caller that
+        # builds every engine alike may pass them)
+        return max_len, n_slots + 1
+
+    def make_pool(self, cfg, geo):
+        cache = init_state(cfg.num_hidden_layers, geo.n_slots,
+                           cfg.num_key_value_heads, cfg.head_dim_,
+                           max_len=geo.max_len)
+        # per-row positions, and the table: no slot holds a row yet
+        return dataclasses.replace(
+            cache, pos=jnp.zeros((geo.n_slots,), jnp.int32),
+            block_tables=jnp.zeros((geo.n_slots, 1), jnp.int32))
+
+    def row_view(self, leaves, tables, pos0, last_idx, slot, cfg, geo):
+        """The pool itself behind the slot's one-row table: the chunked
+        form runs from nothing when `pos0` is 0 and else from the row's
+        state; the positions past `last_idx` leave the state as it was."""
+        cache = RetentionState(
+            S=leaves[0], z=leaves[1], block_tables=tables[0], pos=pos0,
+            start=jnp.zeros((1,), jnp.int32), valid_len=last_idx[None] + 1,
+            max_len=geo.max_len)
+        return cache, cache
+
+    def write_back(self, pool, row, n_tokens, last_idx, cfg):
+        return self.leaves(row)
+
+    def _spots(self, pages, slot, window_pages):
+        return (pages - 1,) * 2  # page p is state row p - 1
+
+    def state_row_nbytes(self, cache):
+        return row_nbytes(cache)
+
+    def note_chunk(self, st, cfg, geo, bucket, n):
+        st.state_chunks += prefill_chunks(bucket)
+
+    def prefill_args(self, st):
+        return {"state_chunks": st.state_chunks}
+
+    def decode_args(self, cfg, table, live, moved):
+        return state_decode_args(live, moved)
+
+
+CACHE_KIND = _StateRows()

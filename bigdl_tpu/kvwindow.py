@@ -209,39 +209,79 @@ def window_pages_spanned(pos: int, n_tokens: int, n_valid: int, window: int,
 
 
 # ---------------------------------------------------------------------------
-# a slot to host RAM and back (the engine's preemption)
+# the cache kind (kvpaged.CacheKind): two groups of pages, a table each
 # ---------------------------------------------------------------------------
 
-@dataclasses.dataclass
-class HostGroups:
-    """One slot parked in host RAM: its pages of both groups, numpy copies,
-    bit for bit."""
+class _PageGroups(kvpaged.CacheKind):
+    name = label = KIND
+    arrays = ("k", "v", "kw", "vw")
+    page_arrays = ("k", "v")
+    needs_paged = (
+        "{kind} is served with paged=True: a slot holds KV pages for the "
+        "attention layers in two groups, and frees the window group's "
+        "behind the window")
+    refuses = kvpaged.not_wired("R3", "quantize_kv", "speculative",
+                                "adapters", "prefill_chunk_tokens")
+    # a prefix hit would need the window pages of the prefix's last `window`
+    # tokens, which the request that wrote them has freed by then
+    share_prefixes = tp_sharded = False
+    make_pool = kvpaged.CacheKind._family_pool
 
-    k: np.ndarray  # [Lg, n, page, Hkv, D]
-    v: np.ndarray
-    kw: np.ndarray  # [Lw, m, page, Hkv, D]
-    vw: np.ndarray
+    def window(self, cfg):
+        return cfg.sliding_window
 
-    @property
-    def nbytes(self) -> int:
-        return self.k.nbytes + self.v.nbytes + self.kw.nbytes + self.vw.nbytes
+    def row_view(self, leaves, tables, pos0, last_idx, slot, cfg, geo):
+        """The row's own pages of both groups, gathered once into the dense
+        form at the row's scalar position."""
+        pool = PageGroups(
+            **dict(zip(self.arrays, leaves)), block_tables=tables[0],
+            window_tables=tables[1], pos=pos0,
+            start=jnp.zeros((1,), jnp.int32))
+        return pool, gather_rows(pool)
+
+    def write_back(self, pool, row, n_tokens, last_idx, cfg):
+        """A page at a time: the global group's pages whole, the window
+        group's only from the first page a query at the prompt's end
+        (`pos0 + last_idx + 1`) still reads."""
+        return self.leaves(scatter_rows(pool, row, n_tokens, last_idx + 1,
+                                        cfg.sliding_window))
+
+    def forward_kw(self, last_idx):
+        return {"logits_at": last_idx}  # the head on the last token alone
+
+    def _spots(self, pages, slot, window_pages):
+        return pages, pages, window_pages, window_pages
+
+    def note_chunk(self, st, cfg, geo, bucket, n):
+        page, mp = geo.page_size, geo.max_pages_per_row
+        st.row_pages += 2 * mp
+        st.window_pages_written += window_pages_spanned(
+            st.written, bucket, n, cfg.sliding_window, page, mp)
+        st.pages_written += kvpaged.pages_spanned(st.written, bucket, page, mp)
+
+    def prefill_args(self, st):  # the pages written back, by group
+        return {"row_pages": st.row_pages,
+                "pages_written_global": st.pages_written,
+                "pages_written_window": st.window_pages_written}
+
+    def decode_args(self, cfg, table, live, moved):
+        # by group, and no one-pool count: a window layer loads fewer pages
+        # than `pos` spans. freed = since the step before
+        return {**table.group_pages(live),
+                "window_pages_freed": table.window_pages_freed_since()}
+
+    def metrics(self, engine):
+        """The window group's pages go back to their pool behind the
+        window, while the request decodes."""
+        in_use = engine.pages.pages_in_use()
+        return [
+            ("bigdl_tpu_global_pages_in_use", "gauge", "pages of the global "
+             "group (full-attention layers) some slot holds", in_use[0]),
+            ("bigdl_tpu_window_pages_in_use", "gauge", "pages of the window "
+             "group (window layers) some slot holds", in_use[1]),
+            ("bigdl_tpu_window_pages_freed_total", "counter", "window pages "
+             "given back behind the window by requests still decoding",
+             engine.pages.window_pages_freed)]
 
 
-def swap_out(cache: PageGroups, pages, window_pages) -> HostGroups:
-    """Copy the global pages `pages` and the window pages `window_pages`
-    (physical page ids, host lists) to the host."""
-    g = jnp.asarray(list(pages), jnp.int32)
-    w = jnp.asarray(list(window_pages), jnp.int32)
-    return HostGroups(k=np.asarray(cache.k[:, g]), v=np.asarray(cache.v[:, g]),
-                      kw=np.asarray(cache.kw[:, w]),
-                      vw=np.asarray(cache.vw[:, w]))
-
-
-def swap_in(cache: PageGroups, k, v, kw, vw, pages,
-            window_pages) -> PageGroups:
-    """Write a parked slot into the pages `pages` / `window_pages`; jit
-    with the cache donated, the write is in place."""
-    g, w = pages.astype(jnp.int32), window_pages.astype(jnp.int32)
-    return dataclasses.replace(
-        cache, k=cache.k.at[:, g].set(k), v=cache.v.at[:, g].set(v),
-        kw=cache.kw.at[:, w].set(kw), vw=cache.vw.at[:, w].set(vw))
+CACHE_KIND = _PageGroups()

@@ -39,8 +39,8 @@ stay float32 / the init dtype.
 
 The cache is `kvhybrid.HybridCache`: pages for the attention layers and a
 state row for the Mamba layers in one slot. `InferenceEngine(paged=True)`
-gets it from `init_paged_cache` (the kind is chosen once, in its
-`__init__`); `TpuModel.generate` gets one from `init_cache` with every row's
+gets it from `init_paged_cache` (through its kind,
+`kvhybrid.CACHE_KIND`); `TpuModel.generate` gets one from `init_cache` with every row's
 pages laid out in order.
 """
 

@@ -29,8 +29,8 @@ the unsliced stacks (`linear(layer=)`, `_moe_dispatch(layer=)`), as in
 The cache is `kvwindow.PageGroups`: the full layers' keys and values in a
 GLOBAL group, the window layers' in a WINDOW group whose pages the serving
 engine frees behind the window. `InferenceEngine(paged=True)` gets the paged
-form from `init_paged_cache` (the kind is chosen once, in its `__init__`)
-and prefills on the dense form of ONE row; `TpuModel.generate` gets the
+form from `init_paged_cache` (through its kind, `kvwindow.CACHE_KIND`) and
+prefills on the dense form of ONE row; `TpuModel.generate` gets the
 dense form from `init_cache` (every position kept, the window a mask).
 """
 

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu import kvcache, kvhybrid, kvpaged, kvstate, kvwindow
+from bigdl_tpu import kvcache, kvpaged
 from bigdl_tpu.generate import GenerationConfig, sample_token_per_row
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.obs import retrace
@@ -126,6 +126,28 @@ def last_routed_request() -> Optional["Request"]:
     with a whole record of its expert choices (`Request.expert_ids`), if
     its caller still holds it; None otherwise."""
     return None if _last_routed is None else _last_routed()
+
+
+def _cache_kind(model) -> kvpaged.CacheKind:
+    """The paged cache kind of `model` (docs/serving.md, "Cache kinds"),
+    chosen here and nowhere else: a recurrent state in every layer by the
+    config's `attention_kind`; then the family's own, which it names
+    (`PAGED_CACHE_KIND`: a state row beside the pages, kvhybrid.py; a
+    window group of pages beside the global one, kvwindow.py) or, offering
+    an `init_paged_cache` and no name, has as latent pages (MLA); then KV
+    pages. The page table books, parks and restores a page of any kind
+    alike."""
+    from bigdl_tpu import kvhybrid, kvstate, kvwindow
+
+    if model.config.attention_kind == kvstate.KIND:
+        return kvstate.CACHE_KIND
+    own = getattr(model.family, "PAGED_CACHE_KIND", None)
+    for mod in (kvhybrid, kvwindow):
+        if own == mod.KIND:
+            return mod.CACHE_KIND
+    if hasattr(model.family, "init_paged_cache"):
+        return kvpaged.LATENT_PAGES
+    return kvpaged.KV_PAGES
 
 
 @dataclasses.dataclass
@@ -276,7 +298,7 @@ class _Preempted:
     dosample: bool
     penalty: float
     seen: Any  # [V] bool host row (repetition-penalty state)
-    blob: Any  # kvpaged.HostKVPages | dense (k, v, ks, vs) tuple
+    blob: Any  # kvpaged.HostPages | dense (k, v, ks, vs) tuple
     n_pages: int = 0  # paged: pages to reallocate on resume
 
 
@@ -416,26 +438,6 @@ class InferenceEngine:
         # fp8 KV storage for the shared pool (dense or paged): halves KV
         # HBM capacity + traffic, the reference's fp8 kv-cache lever
         self.quantize_kv = quantize_kv
-        # a model whose attention keeps a recurrent STATE and no keys
-        # (bigdl_tpu/kvstate.py): a slot then holds one state row where
-        # the others hold pages, and the page table owns the rows
-        self._state_rows = self.config.attention_kind == kvstate.KIND
-        if self._state_rows:
-            kind = f"{kvstate.KIND} ({model.config.model_type})"
-            if not paged:
-                raise NotImplementedError(
-                    f"{kind} is served with paged=True: a slot's recurrent "
-                    "state is a row the page table owns, and there is no "
-                    "dense pool of keys to fall back on")
-            if quantize_kv:
-                raise NotImplementedError(
-                    f"quantize_kv is not available for {kind}: the cache "
-                    "is a float32 recurrent state, not keys and values")
-            if speculative:
-                raise NotImplementedError(
-                    f"speculative serving is not available for {kind}: a "
-                    "rejected draft cannot be taken back out of a "
-                    "recurrent state by moving `pos`")
         # families with their own cache serve through (a) the generic
         # dataclass insert path when they declare SERVABLE_CACHE (MLA's
         # latent as a dense pool — flat [L, B, S, ...] fields with real
@@ -443,48 +445,15 @@ class InferenceEngine:
         # engine_insert adapter when the cache has nested pools or property
         # pos (rwkv recurrent state, yuan localized-filter hiddens, mllama
         # cross-attention; the generic path would silently corrupt them),
-        # or (c), paged, through a page pool of their own kind when they
-        # offer `init_paged_cache` (MLA's latent pages,
-        # kvpaged.PagedLatentCache; a hybrid's KV pages with a state row
-        # beside them in every slot, kvhybrid.HybridCache; a window group
-        # of pages beside the global one, kvwindow.PageGroups): the page
-        # table books, parks and restores such a page as it does a KV page,
-        # and the kind is chosen here, once, as `kvstate.KIND` is.
+        # or (c), paged, through a page pool of their own KIND
+        # (`_cache_kind`): what varies between paged engines is behind
+        # `self.kind`, None for a dense pool
         fam = model.family
-        self._paged_pool = (getattr(fam, "init_paged_cache", None)
-                            if paged else None)
-        own_kind = getattr(fam, "PAGED_CACHE_KIND", None)
-        self._hybrid = (self._paged_pool is not None
-                        and own_kind == kvhybrid.KIND)
-        self._groups = (self._paged_pool is not None
-                        and own_kind == kvwindow.KIND)
-        self._latent = (self._paged_pool is not None and not self._hybrid
-                        and not self._groups)
-        if own_kind in (kvhybrid.KIND, kvwindow.KIND) and not paged:
-            raise NotImplementedError(
-                f"{own_kind} ({model.config.model_type}) is served with "
-                "paged=True: a slot holds KV pages for the attention layers "
-                + ("and a state row for the others"
-                   if own_kind == kvhybrid.KIND else
-                   "in two groups, and frees the window group's behind the "
-                   "window"))
-        if self._paged_pool is not None:
-            kind, where = (
-                (f"{kvhybrid.KIND} ({model.config.model_type})", "R4")
-                if self._hybrid else
-                (f"{kvwindow.KIND} ({model.config.model_type})", "R3")
-                if self._groups else
-                (f"latent pages ({model.config.model_type})", "R1"))
-            for asked, what in ((quantize_kv, "quantize_kv"),
-                                (speculative, "speculative serving"),
-                                (adapters is not None, "adapter serving"),
-                                (self._groups
-                                 and prefill_chunk_tokens is not None,
-                                 "prefill_chunk_tokens")):
-                if asked:
-                    raise NotImplementedError(
-                        f"{what} is not wired for {kind} yet (ROADMAP "
-                        f"{where})")
+        kind = _cache_kind(model)
+        kind.check(model.config.model_type, paged, quantize_kv=quantize_kv,
+                   speculative=speculative, adapters=adapters is not None,
+                   prefill_chunk_tokens=prefill_chunk_tokens is not None)
+        self.kind = kind if paged else None
         self._family_cache = None
         self._family_pool = getattr(fam, "engine_pool", None)
         self._family_insert = getattr(fam, "engine_insert", None)
@@ -496,8 +465,8 @@ class InferenceEngine:
                 f"{model.config.model_type}: engine_pool and engine_insert "
                 "must be defined together"
             )
-        if (hasattr(fam, "init_cache") and not self._state_rows
-                and self._paged_pool is None):
+        if (hasattr(fam, "init_cache")
+                and self.kind in (None, kvpaged.KV_PAGES)):
             custom = (self._family_pool is not None
                       and self._family_insert is not None)
             if not custom and not getattr(fam, "SERVABLE_CACHE", False):
@@ -521,12 +490,9 @@ class InferenceEngine:
                 f"{model.config.model_type}'s family cache; use "
                 "quantize_kv=False"
             )
-        if self._state_rows:
-            # what a page is here: the slot's state row, whole. It never
-            # grows, so one page spans `max_len` tokens and a row of the
-            # table has one entry; `page_size` and `n_pages` size nothing
-            # (a caller that builds every engine alike may pass them)
-            page_size, n_pages = max_len, n_slots + 1
+        if paged:  # a kind whose page is a row sizes the table itself
+            page_size, n_pages = kind.page_geometry(
+                n_slots, max_len, page_size, n_pages)
         self.page_size = page_size
         # physical reserve past max_len: a speculative verify round writes
         # draft_k tokens at pos..pos+K-1 before rolling back; a request
@@ -578,12 +544,8 @@ class InferenceEngine:
         self.pages = PageTable(
             n_slots, self.n_pages, page_size, self.max_pages_per_row,
             max_len, faults=self._faults,
-            # a prefix hit would need the state at the prefix's end, or
-            # the window pages of the prefix's last `window` tokens, which
-            # the request that wrote them has freed by then
-            share_prefixes=not (self._state_rows or self._hybrid
-                                or self._groups),
-            window=self.config.sliding_window if self._groups else None,
+            share_prefixes=kind.share_prefixes,
+            window=kind.window(self.config),
         ) if paged else None
         self._rng = jax.random.PRNGKey(seed)
         # queue.Queue (not SimpleQueue): the queue-deadline sweep filters
@@ -603,12 +565,11 @@ class InferenceEngine:
         # bytes of one slot's recurrent state over all layers (0 for a
         # model that keeps keys): a decode step moves twice that a live row
         self.state_row_bytes = (
-            kvstate.row_nbytes(self.cache) if self._state_rows
-            else kvhybrid.row_nbytes(self.cache) if self._hybrid else 0)
+            kind.state_row_nbytes(self.cache) if paged else 0)
         # bytes of one token's latents over all layers (0 for a model that
         # keeps keys and values): what a decode step must read a live token
         self.latent_token_bytes = (
-            fam.latent_token_nbytes(self.config) if self._latent else 0)
+            kind.token_nbytes(self.config) if paged else 0)
         # bytes of state rows that decode steps read and wrote again
         self.state_bytes_moved = 0
         self.cur = jnp.zeros((n_slots,), jnp.int32)  # last token per slot
@@ -693,13 +654,13 @@ class InferenceEngine:
         # A family forward says that it reports its routing by taking
         # moe_routing= (models/llama.forward and deepseek.forward do).
         # Dense models, and forwards that do not report, pay nothing.
-        self._moe_routing = False
+        self.moe_routing = False
         if getattr(self.config, "is_moe", False):
             import inspect
 
             try:
-                self._moe_routing = ("moe_routing"
-                                     in inspect.signature(fwd).parameters)
+                self.moe_routing = ("moe_routing"
+                                    in inspect.signature(fwd).parameters)
             except (TypeError, ValueError):  # pragma: no cover - exotic
                 pass
         self._moe_last: Optional[tuple] = None  # newest decode step's
@@ -708,19 +669,9 @@ class InferenceEngine:
         # activated, and the prompt position its first chunk started at
         self._admit_moe: list = []
         self._admit_moe_start = 0
-        # a recurrent-state model: chunks of the prefill form that the
-        # admission being activated ran (its `prefill` span's argument)
-        self._admit_state_chunks = 0
-        # a latent-page model: tokens whose K and V the admission's
-        # prefill up-projected from latents (its `prefill` span's argument)
-        self._admit_upprojected = 0
-        # KV pages: pool pages the admission's prefill gathered and wrote
-        # back (its `prefill` span's `row_pages` and `pages_written`)
-        self._admit_row_pages = self._admit_pages_written = 0
-        # two groups of pages: the window group's share of those written,
-        # and the table's count of freed pages at the last decode span
-        self._admit_window_pages_written = 0
-        self._window_freed_noted = 0
+        # what the kind counted of that prefill (`CacheKind.prefill_args`):
+        # its `prefill` span's arguments
+        self._admit_args: dict = {}
         self._decode = self._with_mesh(jax.jit(
             _named("engine_decode", self._decode_impl, fwd),
             donate_argnames=("cache", "seen"),
@@ -738,31 +689,12 @@ class InferenceEngine:
         self._insert = self._with_mesh(jax.jit(
             self._insert_impl, donate_argnames=("cache",)
         ))
-        if self._state_rows:
-            paged_prefill = jax.jit(
-                _named("engine_paged_prefill", self._state_prefill_impl, fwd),
-                donate_argnames=("S", "z"))
-        elif self._latent:
-            paged_prefill = jax.jit(
-                _named("engine_paged_prefill", self._latent_prefill_impl, fwd),
-                donate_argnames=("lat",))
-        elif self._hybrid:
-            paged_prefill = jax.jit(
-                _named("engine_paged_prefill", self._hybrid_prefill_impl, fwd),
-                donate_argnames=("k", "v", "conv", "ssm"))
-        elif self._groups:
-            paged_prefill = jax.jit(
-                _named("engine_paged_prefill", self._groups_prefill_impl, fwd),
-                donate_argnames=("k", "v", "kw", "vw"))
-        else:
-            paged_prefill = jax.jit(
-                _named("engine_paged_prefill", self._paged_prefill_impl, fwd),
-                donate_argnames=("k", "v", "ks", "vs"))
-        self._paged_prefill = self._with_mesh(paged_prefill)
-        self._copy_page = self._with_mesh(jax.jit(
-            kvpaged.copy_latent_page if self._latent
-            else self._copy_page_impl, donate_argnames=("cache",)
-        ))
+        self._paged_prefill = self._with_mesh(jax.jit(
+            _named("engine_paged_prefill", self._paged_prefill_impl, fwd),
+            donate_argnames=("pool",)))
+        if paged:
+            self._copy_page = self._with_mesh(jax.jit(
+                kind.copy_page, donate_argnames=("cache",)))
         # --- in-engine speculative decoding (reference serves it through
         # ipex_llm_worker.py:72-99; SURVEY §7 names "continuous batching +
         # speculative interaction" a hard part). Slot-pool design: a
@@ -955,25 +887,9 @@ class InferenceEngine:
         # preemption is gated off for them.
         if self._family_cache is not None:
             self.preemption = False
-        elif self._state_rows:
-            self._swap_in = self._with_mesh(jax.jit(
-                kvstate.swap_in_rows, donate_argnames=("state",)
-            ))
-        elif self._latent:
-            self._swap_in = self._with_mesh(jax.jit(
-                kvpaged.swap_in_latent, donate_argnames=("cache",)
-            ))
-        elif self._hybrid:
-            self._swap_in = self._with_mesh(jax.jit(
-                kvhybrid.swap_in, donate_argnames=("cache",)
-            ))
-        elif self._groups:
-            self._swap_in = self._with_mesh(jax.jit(
-                kvwindow.swap_in, donate_argnames=("cache",)
-            ))
         elif paged:
             self._swap_in = self._with_mesh(jax.jit(
-                kvpaged.swap_in_pages, donate_argnames=("cache",)
+                kind.swap_in, donate_argnames=("cache",)
             ))
         else:
             self._dense_swap_in = self._with_mesh(jax.jit(
@@ -1019,6 +935,13 @@ class InferenceEngine:
             finally:
                 self.max_queue = bound
 
+    @property
+    def _geo(self) -> kvpaged.Geometry:
+        """The paged pool's size as a cache kind takes it."""
+        return kvpaged.Geometry(
+            self.n_slots, self.max_len, self.page_size, self.n_pages,
+            self.max_pages_per_row, self.quantize_kv)
+
     def _with_mesh(self, fn):
         if self._mesh is None:
             return fn
@@ -1043,23 +966,10 @@ class InferenceEngine:
             return dataclasses.replace(
                 cache, pos=jnp.zeros((self.n_slots,), jnp.int32)
             )
-        if self._paged_pool is not None and not force_dense:
-            return self._paged_pool(cfg, self.n_pages, self.page_size,
-                                    self.n_slots, self.max_pages_per_row)
-        if self._state_rows:
-            cache = kvstate.init_state(
-                cfg.num_hidden_layers, self.n_slots,
-                cfg.num_key_value_heads, cfg.head_dim_, max_len=self.max_len)
-            # per-row positions, and the table: no slot holds a row yet
-            return dataclasses.replace(
-                cache, pos=jnp.zeros((self.n_slots,), jnp.int32),
-                block_tables=jnp.zeros((self.n_slots, 1), jnp.int32))
         if self.paged and not force_dense:
-            cache = kvpaged.init_paged(
-                cfg.num_hidden_layers, self.n_pages, self.page_size,
-                cfg.num_key_value_heads, cfg.head_dim_, self.n_slots,
-                self.max_pages_per_row, quantize_kv=self.quantize_kv,
-            )
+            cache = self.kind.make_pool(cfg, self._geo)
+            if not self.kind.tp_sharded:
+                return cache
         else:
             cache = kvcache.init_cache(
                 cfg.num_hidden_layers, self.n_slots,
@@ -1140,116 +1050,44 @@ class InferenceEngine:
             return dataclasses.replace(cache, **upd)
         return kvcache.insert_row(cache, pcache, slot, pad)
 
-    @staticmethod
-    def _copy_page_impl(cache, src, dst):
-        """Duplicate one physical page's KV (all layers) into another —
-        the sub-page prefix-sharing copy (slots past the shared run are
-        overwritten by the tail prefill or masked by pos)."""
-        upd = {"k": cache.k.at[:, dst].set(cache.k[:, src]),
-               "v": cache.v.at[:, dst].set(cache.v[:, src])}
-        if cache.quantized:
-            upd["k_scale"] = cache.k_scale.at[:, dst].set(cache.k_scale[:, src])
-            upd["v_scale"] = cache.v_scale.at[:, dst].set(cache.v_scale[:, src])
-        return dataclasses.replace(cache, **upd)
-
-    def _paged_prefill_impl(self, forward, params, k, v, ks, vs, row_bt,
-                            pos0, tokens, last_idx, lora=None):
-        """Tail prefill for ONE slot on the row's OWN pages: gathered once
-        for every layer into a dense one-row cache at the row's scalar
+    def _paged_prefill_impl(self, forward, params, pool, tables, pos0,
+                            tokens, last_idx, slot, lora=None):
+        """Tail prefill for ONE slot, on what its cache kind makes of the
+        pool's arrays `pool` (donated) behind the row's own `tables`
+        (`CacheKind.row_view`): for KV pages the row's OWN pages, gathered
+        once for every layer into a dense one-row cache at the row's scalar
         position (what a radix hit shares and what earlier chunks wrote
         come with them), prefilled as `_prefill_impl` prefills a dense
         engine's row (a contiguous write and the flash kernel, where
         `forward` takes it), and only the pages this call wrote scattered
-        back into the donated pool. The pool is the operand of that gather
-        and of that scatter and of nothing else: never the layer loop's
-        carry, never sliced by layer.
+        back (`write_back`). The pool is the operand of that gather and of
+        that scatter and of nothing else: never the layer loop's carry,
+        never sliced by layer. A kind whose forward writes through the
+        table runs on the pool itself, into state row `slot` where it
+        keeps one.
         tokens are RIGHT-padded to a bucket; last_idx selects the real
         last token's logits (pad writes land at slots >= pos and are
-        overwritten by decode). `lora` = the request's rank-bucketed
-        adapter tree (every chunk of a chunked prefill carries it)."""
-        pool = kvpaged.PagedKVCache(
-            k=k, v=v, k_scale=ks, v_scale=vs, block_tables=row_bt, pos=pos0,
-            start=jnp.zeros((1,), jnp.int32),
-        )
-        kw = {} if lora is None else {"lora": lora}
+        overwritten by decode; a state leaves them out). `lora` = the
+        request's rank-bucketed adapter tree (every chunk of a chunked
+        prefill carries it)."""
+        kind, cfg = self.kind, self.config
+        pool, row = kind.row_view(pool, tables, pos0, last_idx, slot, cfg,
+                                  self._geo)
+        kw = dict(kind.forward_kw(last_idx))
+        at = 0 if "logits_at" in kw else last_idx
+        if lora is not None:
+            kw["lora"] = lora
         logits, row, experts = self._forward_routing(
-            forward, params, tokens, kvpaged.gather_row(pool), "prefill", kw)
-        pool = kvpaged.scatter_row_pages(pool, row, tokens.shape[1])
-        return (logits[0, last_idx], pool.k, pool.v, pool.k_scale,
-                pool.v_scale, None if experts is None else experts[:, 0])
-
-    def _state_prefill_impl(self, forward, params, S, z, row_bt, pos0,
-                            tokens, last_idx, lora=None):
-        """`_paged_prefill_impl` for a model that keeps a recurrent state:
-        ONE slot's prompt (or the next chunk of it) through the chunked
-        form, from nothing when `pos0` is 0 and else from the row's state,
-        written into the slot's row of the shared pool (donated S, z).
-        tokens are RIGHT-padded to a bucket; the positions past `last_idx`
-        leave the state as it was (kvstate: gate 1, no update)."""
-        cache = kvstate.RetentionState(
-            S=S, z=z, block_tables=row_bt, pos=pos0,
-            start=jnp.zeros((1,), jnp.int32), valid_len=last_idx[None] + 1,
-            max_len=self.max_len)
-        kw = {} if lora is None else {"lora": lora}
-        logits, cache = forward(self.config, params, tokens, cache,
-                                mode="prefill", **kw)
-        return logits[0, last_idx], cache.S, cache.z
-
-    def _latent_prefill_impl(self, forward, params, lat, row_bt, pos0,
-                             tokens, last_idx, lora=None):
-        """`_paged_prefill_impl` for a model that keeps latent pages: ONE
-        slot's tail prefill through the family forward's expanded form,
-        its latents written straight into the shared pool (donated)."""
-        cache = kvpaged.PagedLatentCache(
-            lat=lat, block_tables=row_bt, pos=pos0,
-            start=jnp.zeros((1,), jnp.int32))
-        logits, cache, experts = self._forward_routing(
-            forward, params, tokens, cache, "prefill", {})
-        return (logits[0, last_idx], cache.lat,
-                None if experts is None else experts[:, 0])
-
-    def _hybrid_prefill_impl(self, forward, params, k, v, conv, ssm, row_bt,
-                             pos0, tokens, last_idx, slot):
-        """`_paged_prefill_impl` for a model whose slot holds a state row
-        beside its pages: ONE slot's prompt (or the next chunk of it), its
-        keys and values written into the row's pages and its state, from
-        nothing when `pos0` is 0 and else from the row's own, into state row
-        `slot` (all four donated). The positions past `last_idx` leave the
-        state and the convolution's tail as they were."""
-        cache = kvhybrid.HybridCache(
-            k=k, v=v, conv=conv, ssm=ssm, block_tables=row_bt, pos=pos0,
-            start=jnp.zeros((1,), jnp.int32), rows=slot,
-            valid_len=last_idx[None] + 1)
-        logits, cache, experts = self._forward_routing(
-            forward, params, tokens, cache, "prefill", {})
-        return (logits[0, last_idx], cache.k, cache.v, cache.conv, cache.ssm,
-                None if experts is None else experts[:, 0])
-
-    def _groups_prefill_impl(self, forward, params, k, v, kw, vw, row_bt,
-                             row_wbt, pos0, tokens, last_idx):
-        """`_paged_prefill_impl` for a model whose slot holds two groups of
-        pages: ONE slot's prompt on the row's own pages of both groups,
-        gathered once into the dense form at the row's scalar position, and
-        written back a page at a time (all four pools donated): the global
-        group's pages whole, the window group's only from the first page a
-        query at the prompt's end (`pos0 + last_idx + 1`) still reads."""
-        pool = kvwindow.PageGroups(
-            k=k, v=v, kw=kw, vw=vw, block_tables=row_bt,
-            window_tables=row_wbt, pos=pos0,
-            start=jnp.zeros((1,), jnp.int32))
-        logits, row, experts = self._forward_routing(
-            forward, params, tokens, kvwindow.gather_rows(pool), "prefill",
-            {"logits_at": last_idx})  # the head on the last token alone
-        pool = kvwindow.scatter_rows(pool, row, tokens.shape[1],
-                                     last_idx + 1, self.config.sliding_window)
-        return (logits[0, 0], pool.k, pool.v, pool.kw, pool.vw,
+            forward, params, tokens, row, "prefill", kw)
+        pool = kind.write_back(pool, row, tokens.shape[1], last_idx, cfg)
+        return (logits[0, at], pool,
                 None if experts is None else experts[:, 0])
 
     def _forward_routing(self, forward, params, tokens, cache, mode, kw):
         """`forward`, and for a sparse-expert model every position's top-k
         expert ids [L, B, T, k] as small integers (None for a dense
         model: nothing is traced for it)."""
-        if not self._moe_routing:
+        if not self.moe_routing:
             return forward(self.config, params, tokens, cache, mode=mode,
                            **kw) + (None,)
         logits, cache, routing = forward(
@@ -1721,61 +1559,19 @@ class InferenceEngine:
         toks[0, :n] = prompt[st.written: st.written + n]  # RIGHT pad:
         # writes past pos get overwritten by decode, masked meanwhile
         self.prefill_chunks += 1
-        where = (jnp.asarray(st.row[None]),
-                 jnp.asarray([st.written], jnp.int32), jnp.asarray(toks),
-                 jnp.asarray(n - 1))
-        if self._state_rows:
-            logits_last, S, z = self._paged_prefill(
-                self.model.params, self.cache.S, self.cache.z, *where,
-                lora=self._prefill_lora(st.req))
-            self.cache = dataclasses.replace(self.cache, S=S, z=z)
-            st.state_chunks += kvstate.prefill_chunks(bucket)
-        elif self._latent:
-            logits_last, lat, moe = self._paged_prefill(
-                self.model.params, self.cache.lat, *where)
-            if moe is not None:
-                st.moe.append((moe, n))
-            self.cache = dataclasses.replace(self.cache, lat=lat)
-            # the expanded form up-projects the row's whole capacity
-            st.upprojected += self.cache.max_len
-        elif self._hybrid:
-            c = self.cache
-            logits_last, k, v, conv, ssm, moe = self._paged_prefill(
-                self.model.params, c.k, c.v, c.conv, c.ssm, *where,
-                jnp.asarray([st.slot], jnp.int32))
-            if moe is not None:
-                st.moe.append((moe, n))
-            self.cache = dataclasses.replace(c, k=k, v=v, conv=conv, ssm=ssm)
-            st.state_chunks += kvhybrid.prefill_chunks(
-                bucket, self.config.mamba_chunk_size)
-        elif self._groups:
-            c = self.cache
-            logits_last, k, v, kw, vw, moe = self._paged_prefill(
-                self.model.params, c.k, c.v, c.kw, c.vw, where[0],
-                jnp.asarray(st.wrow[None]), *where[1:])
-            if moe is not None:
-                st.moe.append((moe, n))
-            self.cache = dataclasses.replace(c, k=k, v=v, kw=kw, vw=vw)
-            st.row_pages += 2 * self.max_pages_per_row
-            st.window_pages_written += kvwindow.window_pages_spanned(
-                st.written, bucket, n, self.config.sliding_window,
-                self.page_size, self.max_pages_per_row)
-            st.pages_written += kvpaged.pages_spanned(
-                st.written, bucket, self.page_size, self.max_pages_per_row)
-        else:
-            logits_last, k, v, ks, vs, moe = self._paged_prefill(
-                self.model.params, self.cache.k, self.cache.v,
-                self.cache.k_scale, self.cache.v_scale, *where,
-                lora=self._prefill_lora(st.req),
-            )
-            if moe is not None:
-                st.moe.append((moe, n))
-            self.cache = dataclasses.replace(
-                self.cache, k=k, v=v, k_scale=ks, v_scale=vs,
-            )
-            st.row_pages += self.max_pages_per_row
-            st.pages_written += kvpaged.pages_spanned(
-                st.written, bucket, self.page_size, self.max_pages_per_row)
+        tables = (jnp.asarray(st.row[None]),
+                  None if st.wrow is None else jnp.asarray(st.wrow[None]))
+        kind = self.kind
+        logits_last, pool, moe = self._paged_prefill(
+            self.model.params, kind.leaves(self.cache), tables,
+            jnp.asarray([st.written], jnp.int32), jnp.asarray(toks),
+            jnp.asarray(n - 1), np.asarray([st.slot], np.int32),  # sent
+            # only where the kind's program reads it
+            lora=self._prefill_lora(st.req))
+        self.cache = kind.with_leaves(self.cache, pool)
+        if moe is not None:
+            st.moe.append((moe, n))
+        kind.note_chunk(st, self.config, self._geo, bucket, n)
         st.written += n
         if not last:
             self._chunk_retrace_s += self._retrace_mark("prefill.dispatch")
@@ -1791,11 +1587,7 @@ class InferenceEngine:
         self.pages.register_prefix(slot, prompt, st.path,
                                    ns=st.req.adapter)
         self._admit_moe, self._admit_moe_start = st.moe, st.start
-        self._admit_state_chunks = st.state_chunks
-        self._admit_upprojected = st.upprojected
-        self._admit_row_pages = st.row_pages
-        self._admit_pages_written = st.pages_written
-        self._admit_window_pages_written = st.window_pages_written
+        self._admit_args = kind.prefill_args(st)
         if self.speculative:
             # prefix-cache hits only save TARGET prefill; the draft
             # always prefills its full context into the dense draft pool
@@ -1918,15 +1710,10 @@ class InferenceEngine:
             pos = self.pages.pos[slot]
             keep = self.pages.kv_pages(slot)
             n_keep = len(keep)
-            if self._hybrid:  # the pages and the slot's state row
-                blob = kvhybrid.swap_out(self.cache, keep, slot)
-            elif self._groups:  # the pages of both groups
-                blob = kvwindow.swap_out(self.cache, keep,
-                                         self.pages.window_kv_pages(slot))
-            else:
-                blob = (kvstate.swap_out_rows if self._state_rows
-                        else kvpaged.swap_out_latent if self._latent
-                        else kvpaged.swap_out_pages)(self.cache, keep)
+            # the pages, with a state row or a second group's where the
+            # kind has them
+            blob = self.kind.swap_out(self.cache, keep, slot,
+                                      self.pages.window_kv_pages(slot))
             start = 0
         else:
             pos = int(np.asarray(self.cache.pos[slot]))
@@ -1972,17 +1759,10 @@ class InferenceEngine:
             fresh = self.pages.restore(slot, entry.n_pages, entry.pos)
             if fresh is None:  # retry when pages free up
                 return False
-            b = entry.blob
-            parked = ((b.S, b.z) if self._state_rows
-                      else (b.lat,) if self._latent
-                      else (b.k, b.v, b.conv, b.ssm, jnp.asarray(slot))
-                      if self._hybrid
-                      else (b.k, b.v, b.kw, b.vw) if self._groups
-                      else (b.k, b.v, b.k_scale, b.v_scale))
-            into = (jnp.asarray(fresh, jnp.int32),)
-            if self._groups:
-                into += (jnp.asarray(self.pages.win_pages[slot], jnp.int32),)
-            self.cache = self._swap_in(self.cache, *parked, *into)
+            into = (jnp.asarray(fresh, jnp.int32), jnp.asarray(slot),
+                    jnp.asarray(self.pages.win_pages[slot], jnp.int32))
+            self.cache = self._swap_in(
+                self.cache, self.kind.leaves(entry.blob), into)
             self.cache = dataclasses.replace(
                 self.cache,
                 pos=self.cache.pos.at[slot].set(entry.pos),
@@ -2524,18 +2304,7 @@ class InferenceEngine:
             if tr is not None and tr.enabled:
                 moe_args = _moe_load(chosen, self.config.num_experts)
             self._admit_moe = []
-        if self.state_row_bytes:
-            moe_args["state_chunks"] = self._admit_state_chunks
-        if self._latent:
-            moe_args["latent_tokens_upprojected"] = self._admit_upprojected
-        if self._groups:  # the pages written back, by group
-            moe_args["row_pages"] = self._admit_row_pages
-            moe_args["pages_written_global"] = self._admit_pages_written
-            moe_args["pages_written_window"] = \
-                self._admit_window_pages_written
-        elif self._admit_row_pages:  # with `page_nbytes`: pool bytes touched
-            moe_args["row_pages"] = self._admit_row_pages
-            moe_args["pages_written"] = self._admit_pages_written
+        moe_args.update(self._admit_args)
         if req.admit_ts is not None:
             self.prefill_seconds.observe(now - req.admit_ts)
             if tr is not None and tr.enabled:
@@ -3135,8 +2904,8 @@ class InferenceEngine:
         """Send the block table where the host's mirror has changed."""
         bt = self.pages.block_table() if self.paged else None
         if bt is not None:
-            both = ({"window_tables": jnp.asarray(self.pages.window_table)}
-                    if self._groups else {})
+            both = ({} if self.pages.window is None else
+                    {"window_tables": jnp.asarray(self.pages.window_table)})
             self.cache = dataclasses.replace(
                 self.cache, block_tables=jnp.asarray(bt), **both)
         return bt is not None
@@ -3249,7 +3018,7 @@ class InferenceEngine:
         if n_top:
             tops_h = (host[:, 2:2 + n_top], np.ascontiguousarray(
                 host[:, 2 + n_top:2 + 2 * n_top]).view(np.float32))
-        if self._moe_routing:  # [B, L * k] -> [L, B, k]
+        if self.moe_routing:  # [B, L * k] -> [L, B, k]
             experts_h = host[:, 2 + 2 * n_top:].reshape(
                 self.n_slots, -1, self.config.num_experts_per_tok
             ).transpose(1, 0, 2).astype(
@@ -3315,27 +3084,8 @@ class InferenceEngine:
         st = self._step_trace
         if st is not None:
             st.decoded = True
-        pages = {}
-        if self.state_row_bytes:  # in place of pages, or beside them
-            pages["state_rows_live"] = busy
-            pages["state_bytes_moved"] = moved
-        if self._groups:
-            # by group, and no one-pool count: a window layer loads fewer
-            # pages than `pos` spans. The table's pos still holds the
-            # step's own; freed = since the step before
-            pages.update(self.pages.group_pages(live))
-            freed = self.pages.window_pages_freed
-            pages["window_pages_freed"] = freed - self._window_freed_noted
-            self._window_freed_noted = freed
-        elif self.paged and not self._state_rows:
-            # the table's pos still holds the step's own
-            pages["live_pages"], pages["grid_pages"] = \
-                self.pages.grid_pages(live)
-            if self._latent:  # slots 0 .. pos of every live row
-                n = sum(self.pages.pos[i] + 1 for i in np.nonzero(live)[0])
-                pages["latent_live_tokens"] = int(n)
-                pages["latent_bytes_read"] = int(
-                    n * self.latent_token_bytes)
+        pages = (self.kind.decode_args(self.config, self.pages, live, moved)
+                 if self.paged else {})
         tr.complete(
             "decode_step", t_start, t1 - t_start, tid=DECODE_TID,
             cat="engine", seq=fl.seq, ahead=fl.ahead, occupancy=busy,

@@ -429,65 +429,12 @@ class Metrics:
                     "# TYPE bigdl_tpu_radix_nodes gauge",
                     f"bigdl_tpu_radix_nodes {pages.radix.n_nodes}",
                 ]
-            if getattr(self.engine, "state_row_bytes", 0):
-                # a model whose slots hold recurrent state rows (in every
-                # layer, bigdl_tpu/kvstate.py, or beside KV pages,
-                # bigdl_tpu/kvhybrid.py): what a decode step reads and
-                # writes again is the live rows, whatever the contexts
-                lines += [
-                    "# HELP bigdl_tpu_state_rows_live slots whose state "
-                    "row the next decode step reads and writes",
-                    "# TYPE bigdl_tpu_state_rows_live gauge",
-                    f"bigdl_tpu_state_rows_live "
-                    f"{int(self.engine.active.sum())}",
-                    "# HELP bigdl_tpu_state_pool_bytes device bytes of the "
-                    "recurrent-state pool (every slot's row, all layers)",
-                    "# TYPE bigdl_tpu_state_pool_bytes gauge",
-                    f"bigdl_tpu_state_pool_bytes "
-                    f"{self.engine.state_row_bytes * self.engine.n_slots}",
-                    "# HELP bigdl_tpu_state_bytes_moved_total bytes of "
-                    "state rows that decode steps read and wrote again",
-                    "# TYPE bigdl_tpu_state_bytes_moved_total counter",
-                    f"bigdl_tpu_state_bytes_moved_total "
-                    f"{self.engine.state_bytes_moved}",
-                ]
-            if getattr(self.engine, "_groups", False):
-                # a model whose slots hold two groups of pages
-                # (bigdl_tpu/kvwindow.py): the window group's go back to
-                # their pool behind the window, while the request decodes
-                in_use = self.engine.pages.pages_in_use()
-                lines += [
-                    "# HELP bigdl_tpu_global_pages_in_use pages of the "
-                    "global group (full-attention layers) some slot holds",
-                    "# TYPE bigdl_tpu_global_pages_in_use gauge",
-                    f"bigdl_tpu_global_pages_in_use {in_use[0]}",
-                    "# HELP bigdl_tpu_window_pages_in_use pages of the "
-                    "window group (window layers) some slot holds",
-                    "# TYPE bigdl_tpu_window_pages_in_use gauge",
-                    f"bigdl_tpu_window_pages_in_use {in_use[1]}",
-                    "# HELP bigdl_tpu_window_pages_freed_total window pages "
-                    "given back behind the window by requests still decoding",
-                    "# TYPE bigdl_tpu_window_pages_freed_total counter",
-                    f"bigdl_tpu_window_pages_freed_total "
-                    f"{self.engine.pages.window_pages_freed}",
-                ]
-            if getattr(self.engine, "_latent", False):
-                # a model whose pages hold latents (kvpaged.
-                # PagedLatentCache): pages in use, and what they hold
-                pool = self.engine.pages.pool
-                lines += [
-                    "# HELP bigdl_tpu_latent_pages_in_use latent pages "
-                    "held by slots or the prefix cache (of n_pages - 1)",
-                    "# TYPE bigdl_tpu_latent_pages_in_use gauge",
-                    f"bigdl_tpu_latent_pages_in_use "
-                    f"{pool.n_pages - 1 - pool.n_free}",
-                    "# HELP bigdl_tpu_latent_token_bytes bytes of one "
-                    "token's latents over all layers",
-                    "# TYPE bigdl_tpu_latent_token_bytes gauge",
-                    f"bigdl_tpu_latent_token_bytes "
-                    f"{self.engine.latent_token_bytes}",
-                ]
-            if getattr(self.engine, "_moe_routing", False):
+            # what the engine's cache kind exports of its own (state rows,
+            # a window group's pages, latents: `CacheKind.metrics`)
+            for name, typ, text, value in _kind_metrics(self.engine):
+                lines += [f"# HELP {name} {text}", f"# TYPE {name} {typ}",
+                          f"{name} {value}"]
+            if getattr(self.engine, "moe_routing", False):
                 # sparse-expert models: the newest decode step's expert
                 # load (the `moe_*` arguments of its `decode_step` span)
                 load = self.engine.moe_load()
@@ -536,7 +483,7 @@ class Metrics:
                 # whenever the adapter block does (0 when the engine has
                 # no pager — dense pool or family cache) so the drift
                 # gate stays structural, not configuration-dependent.
-                pager = getattr(self.engine, "_pager", None)
+                pager = getattr(self.engine.pages, "pager", None)
                 pi = pager.page_ins if pager is not None else 0
                 po = pager.page_outs if pager is not None else 0
                 pr = pager.pages_resident if pager is not None else 0
@@ -631,23 +578,6 @@ _PAGED_FAMILIES = (
     "bigdl_tpu_radix_nodes",
 )
 
-_STATE_FAMILIES = (
-    "bigdl_tpu_state_rows_live",
-    "bigdl_tpu_state_pool_bytes",
-    "bigdl_tpu_state_bytes_moved_total",
-)
-
-_WINDOW_FAMILIES = (
-    "bigdl_tpu_global_pages_in_use",
-    "bigdl_tpu_window_pages_in_use",
-    "bigdl_tpu_window_pages_freed_total",
-)
-
-_LATENT_FAMILIES = (
-    "bigdl_tpu_latent_pages_in_use",
-    "bigdl_tpu_latent_token_bytes",
-)
-
 _MOE_FAMILIES = (
     "bigdl_tpu_moe_expert_load_imbalance",
     "bigdl_tpu_moe_experts_hit_share",
@@ -670,6 +600,13 @@ _ADAPTER_FAMILIES = (
 )
 
 
+def _kind_metrics(engine) -> list:
+    """(name, type, help, value) of each family the engine's paged cache
+    kind adds (`kvpaged.CacheKind.metrics`); none for a dense pool."""
+    kind = getattr(engine, "kind", None)
+    return [] if kind is None else kind.metrics(engine)
+
+
 def expected_families(engine=None) -> list:
     """Every metric family a `Metrics(engine).render()` must expose."""
     names = list(_PROCESS_FAMILIES)
@@ -677,13 +614,8 @@ def expected_families(engine=None) -> list:
         names += _ENGINE_FAMILIES
         if getattr(engine, "paged", False):
             names += _PAGED_FAMILIES
-        if getattr(engine, "state_row_bytes", 0):
-            names += _STATE_FAMILIES
-        if getattr(engine, "_groups", False):
-            names += _WINDOW_FAMILIES
-        if getattr(engine, "_latent", False):
-            names += _LATENT_FAMILIES
-        if getattr(engine, "_moe_routing", False):
+        names += [m[0] for m in _kind_metrics(engine)]
+        if getattr(engine, "moe_routing", False):
             names += _MOE_FAMILIES
         if getattr(engine, "adapters", None) is not None:
             names += _ADAPTER_FAMILIES

@@ -122,13 +122,14 @@ class PageTable:
         # the window table's mirror, uploaded with the block table's
         self.window = window
         self.wpool = None
+        self.win_pages: list[list[int]] = [[] for _ in range(n_slots)]
+        self.win_first = [0] * n_slots
         if window is not None:
             self.wpool = PagePool(window_pool_pages(n_slots, window,
                                                     page_size))
-            self.win_pages: list[list[int]] = [[] for _ in range(n_slots)]
-            self.win_first = [0] * n_slots
             self.window_table = np.zeros_like(self._bt)
             self.window_pages_freed = 0  # behind the window, ever
+            self._freed_noted = 0  # ... and at `window_pages_freed_since`
 
     def rebuilt(self) -> "PageTable":
         """The table as the constructor makes it, for a device pool that
@@ -142,6 +143,7 @@ class PageTable:
                         self.share_prefixes, self.window)
         if self.window is not None:
             new.window_pages_freed = self.window_pages_freed
+            new._freed_noted = self._freed_noted
         for name in self.TOTALS:
             setattr(new, name, getattr(self, name))
         if self.pager is not None:
@@ -501,6 +503,13 @@ class PageTable:
             "window_pages_unfreed": sum(len(self.slot_pages[i])
                                         for i in rows),
         }
+
+    def window_pages_freed_since(self) -> int:
+        """Window pages freed since the call before: a decode step's span
+        argument."""
+        freed = self.window_pages_freed - self._freed_noted
+        self._freed_noted = self.window_pages_freed
+        return freed
 
     def grid_pages(self, active: np.ndarray) -> tuple[int, int]:
         """(live, grid) pages of the paged decode kernel's next step, from

@@ -325,8 +325,8 @@ class SimDriver:
 
         def paged_prefill(*a, **k):
             out = paged_prefill0(*a, **k)
-            chunk = int(a[7].shape[1])  # bucketed tail tokens
-            prior = int(np.asarray(a[6])[0])  # prefix-cache coverage
+            chunk = int(a[4].shape[1])  # bucketed tail tokens
+            prior = int(np.asarray(a[3])[0])  # prefix-cache coverage
             clock.advance(cost.prefill_s(
                 chunk, prior_tokens=prior,
                 adapter_rank=(eng._last_prefill_rank,
@@ -335,14 +335,15 @@ class SimDriver:
 
         eng._paged_prefill = paged_prefill
 
-        copy_page0 = eng._copy_page
+        if getattr(eng, "_copy_page", None) is not None:  # a paged engine
+            copy_page0 = eng._copy_page
 
-        def copy_page(*a, **k):
-            out = copy_page0(*a, **k)
-            clock.advance(cost.kv_copy_s(page))
-            return out
+            def copy_page(*a, **k):
+                out = copy_page0(*a, **k)
+                clock.advance(cost.kv_copy_s(page))
+                return out
 
-        eng._copy_page = copy_page
+            eng._copy_page = copy_page
 
         # speculative rounds: the engine's real draft+verify program
         # runs on the tiny model; the charge is K draft steps + one
@@ -368,7 +369,7 @@ class SimDriver:
 
             def swap_in(*a, **k):
                 out = swap_in0(*a, **k)
-                clock.advance(cost.swap_s(int(a[5].shape[0]) * page))
+                clock.advance(cost.swap_s(int(a[2][0].shape[0]) * page))
                 return out
 
             eng._swap_in = swap_in

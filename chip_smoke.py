@@ -603,13 +603,12 @@ def lowered_engine_programs(server, sz: Sizes, tp: int) -> None:
             jnp.asarray(eng._temp), jnp.asarray(eng._topk),
             jnp.asarray(eng._topp), jnp.asarray(eng._dosample), eng.seen,
             jnp.asarray(eng._penalty), lora=None)
-        c = eng.cache
         prefill = _jit_of(eng._paged_prefill).lower(
-            eng.model.params, c.k, c.v, c.k_scale, c.v_scale,
-            jnp.zeros((1, eng.max_pages_per_row), jnp.int32),
+            eng.model.params, eng.kind.leaves(eng.cache),
+            (jnp.zeros((1, eng.max_pages_per_row), jnp.int32), None),
             jnp.zeros((1,), jnp.int32),
             jnp.zeros((1, sz.buckets[1]), jnp.int32), jnp.asarray(0),
-            lora=None)
+            jnp.zeros((1,), jnp.int32), lora=None)
         d_found = kernels_in(decode.as_text())
         p_found = kernels_in(prefill.as_text())
         say(f"lowered engine decode step: Mosaic calls {dict(d_found)}")

@@ -64,9 +64,10 @@ def main() -> int:
                 z((cfg.vocab_size,), bool), z((), jnp.int32),
                 z((B,), jnp.int32), eng.seen)),
             "engine_paged_prefill": (eng._paged_prefill, (
-                params, c.k, c.v, c.k_scale, c.v_scale,
-                z((1, eng.max_pages_per_row), jnp.int32), z((1,), jnp.int32),
-                z((1, 64), jnp.int32), z((), jnp.int32))),
+                params, eng.kind.leaves(c),
+                (z((1, eng.max_pages_per_row), jnp.int32), None),
+                z((1,), jnp.int32), z((1, 64), jnp.int32), z((), jnp.int32),
+                z((1,), jnp.int32))),
         }
         for name, (fn, args) in programs.items():
             fn = getattr(fn, "__wrapped__", fn)

@@ -78,9 +78,10 @@ def programs() -> dict:
                 arr((), f32), arr((V,), jnp.bool_), arr((), i32),
                 cur=arr((B,), i32), seen=arr((B, V), jnp.bool_))),
             "engine_paged_prefill T=256": _sha(eng._paged_prefill.lower(
-                params, cache.k, cache.v, cache.k_scale, cache.v_scale,
-                arr((1, eng.max_pages_per_row), i32), arr((1,), i32),
-                arr((1, 256), i32), arr((), i32), lora=None)),
+                params, eng.kind.leaves(cache),
+                (arr((1, eng.max_pages_per_row), i32), None),
+                arr((1,), i32), arr((1, 256), i32), arr((), i32),
+                arr((1,), i32), lora=None)),
             "copy_page": _sha(eng._copy_page.lower(
                 cache, arr((), i32), arr((), i32))),
         }

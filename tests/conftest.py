@@ -51,4 +51,7 @@ def _clear_jax_caches_per_module():
     free — not OOM). Dropping compiled-computation caches between
     modules keeps the process young at a modest recompile cost."""
     yield
+    import engines  # tests/engines.py: the engines' programs, shared a module
+
+    engines.forget()
     jax.clear_caches()

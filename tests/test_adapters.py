@@ -29,7 +29,7 @@ from bigdl_tpu.models.config import PRESETS
 from bigdl_tpu.serving.adapters import (
     AdapterError, AdapterRegistry, load_adapter, rank_bucket, save_adapter,
 )
-from bigdl_tpu.serving.engine import InferenceEngine
+from engines import shared_engine
 from bigdl_tpu.serving.faults import FaultInjector
 from bigdl_tpu.train.qlora import init_lora, merge_lora
 
@@ -81,8 +81,8 @@ def adapter_dir(tmp_path_factory):
 
 def _run_engine(model, jobs, registry=None, n_new=8, **eng_kw):
     """jobs: list of (prompt, adapter_name|None) -> out_tokens list."""
-    eng = InferenceEngine(model, n_slots=4, max_len=128, paged=True,
-                          page_size=16, adapters=registry, **eng_kw)
+    eng = shared_engine(model, n_slots=4, max_len=128, paged=True,
+                        page_size=16, adapters=registry, **eng_kw)
     reqs = [eng.submit(p, max_new_tokens=n_new, adapter=a)
             for p, a in jobs]
     eng.run_until_idle(max_steps=2000)
@@ -269,8 +269,8 @@ def test_batched_epilogue_matches_per_request(model):
 
 def _merged_tokens(model, lora, prompt, n_new=8):
     merged = TpuModel(CFG, merge_lora(model.params, lora), "bf16")
-    eng = InferenceEngine(merged, n_slots=4, max_len=128, paged=True,
-                          page_size=16)
+    eng = shared_engine(merged, n_slots=4, max_len=128, paged=True,
+                        page_size=16)
     req = eng.submit(prompt, max_new_tokens=n_new)
     eng.run_until_idle(max_steps=500)
     return req.out_tokens
@@ -357,8 +357,8 @@ def test_shared_prefix_never_leaks_across_tenants(model, adapter_dir):
         "t-r3": _merged_tokens(model, loras["t-r3"], prompt),
     }
     reg = AdapterRegistry(dir=d)
-    eng = InferenceEngine(model, n_slots=4, max_len=128, paged=True,
-                          page_size=16, adapters=reg)
+    eng = shared_engine(model, n_slots=4, max_len=128, paged=True,
+                        page_size=16, adapters=reg)
     # tenant A primes the cache with its adapter-shifted pages
     first = eng.submit(prompt, max_new_tokens=8, adapter="t-r2")
     eng.run_until_idle(max_steps=500)
@@ -394,8 +394,8 @@ def test_parity_cancel_mid_decode(model, adapter_dir):
     (the registry can evict it again) and never disturbs neighbours."""
     d, _ = adapter_dir
     reg = AdapterRegistry(dir=d)
-    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                          page_size=16, adapters=reg)
+    eng = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                        page_size=16, adapters=reg)
     r1 = eng.submit(PROMPTS[0], max_new_tokens=30, adapter="t-r2")
     r2 = eng.submit(PROMPTS[1], max_new_tokens=6, adapter="t-r3")
     for _ in range(3):
@@ -452,7 +452,7 @@ def test_unknown_and_mismatched_adapter(model, adapter_dir, tmp_path):
     eng2, (r3,) = _run_engine(model, [(PROMPTS[0], "wrong")], reg2)
     assert r3.finish_reason == "error" and "rank_mismatch" in r3.error
     # adapter named but no registry configured -> invalid at submit
-    eng3 = InferenceEngine(model, n_slots=2, max_len=128)
+    eng3 = shared_engine(model, n_slots=2, max_len=128)
     r4 = eng3.submit(PROMPTS[0], max_new_tokens=4, adapter="t-r2")
     assert r4.done and r4.finish_reason == "invalid"
 
@@ -467,17 +467,17 @@ def test_replay_after_crash_with_adapter(model, adapter_dir, tmp_path,
     jpath = str(tmp_path / "journal.jsonl")
     inj = FaultInjector(seed=0).arm("crash_before_done", times=1)
     reg = AdapterRegistry(dir=d)
-    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                          page_size=16, adapters=reg, journal=jpath,
-                          faults=inj)
+    eng = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                        page_size=16, adapters=reg, journal=jpath,
+                        faults=inj)
     req = eng.submit(PROMPTS[1], max_new_tokens=8, adapter="t-r2")
     with pytest.raises(Exception):
         eng.run_until_idle(max_steps=500)  # injected crash in _finish
     assert req.done  # completed, but its tombstone never landed
     # successor process: replay must resubmit WITH the adapter
     reg2 = AdapterRegistry(dir=d)
-    eng2 = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                           page_size=16, adapters=reg2, journal=jpath)
+    eng2 = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                         page_size=16, adapters=reg2, journal=jpath)
     assert len(eng2.recovered_requests) == 1
     rec = eng2.recovered_requests[0]
     assert rec.adapter == "t-r2"
@@ -502,8 +502,8 @@ def test_quantized_base_all_targets(tmp_path):
                                     "w_up", "w_down"))
     save_adapter(str(tmp_path / "q.npz"), lora)
     reg = AdapterRegistry(dir=str(tmp_path))
-    eng = InferenceEngine(qmodel, n_slots=2, max_len=128, paged=True,
-                          page_size=16, adapters=reg)
+    eng = shared_engine(qmodel, n_slots=2, max_len=128, paged=True,
+                        page_size=16, adapters=reg)
     ra = eng.submit(PROMPTS[0], max_new_tokens=8, adapter="q")
     rb = eng.submit(PROMPTS[0], max_new_tokens=8)
     eng.run_until_idle(max_steps=300)
@@ -747,17 +747,17 @@ def test_speculative_adapter_replay_after_crash(model, adapter_dir,
     jpath = str(tmp_path / "journal.jsonl")
     inj = FaultInjector(seed=0).arm("crash_before_done", times=1)
     reg = AdapterRegistry(dir=d)
-    eng = InferenceEngine(model, n_slots=4, max_len=128, paged=True,
-                          page_size=16, adapters=reg, journal=jpath,
-                          faults=inj, speculative=True, draft_k=2)
+    eng = shared_engine(model, n_slots=4, max_len=128, paged=True,
+                        page_size=16, adapters=reg, journal=jpath,
+                        faults=inj, speculative=True, draft_k=2)
     req = eng.submit(PROMPTS[1], max_new_tokens=8, adapter="t-r2")
     with pytest.raises(Exception):
         eng.run_until_idle(max_steps=500)
     assert req.done
     reg2 = AdapterRegistry(dir=d)
-    eng2 = InferenceEngine(model, n_slots=4, max_len=128, paged=True,
-                           page_size=16, adapters=reg2, journal=jpath,
-                           speculative=True, draft_k=2)
+    eng2 = shared_engine(model, n_slots=4, max_len=128, paged=True,
+                         page_size=16, adapters=reg2, journal=jpath,
+                         speculative=True, draft_k=2)
     assert len(eng2.recovered_requests) == 1
     rec = eng2.recovered_requests[0]
     assert rec.adapter == "t-r2"

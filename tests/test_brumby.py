@@ -23,6 +23,7 @@ from bigdl_tpu.api import TpuModel, optimize_model
 from bigdl_tpu.models import get_family, llama
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.serving.engine import InferenceEngine
+from engines import shared_engine
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -291,11 +292,11 @@ def _check_request(params, req, atol=0.08):
 
 
 def test_engine_serves_from_state_rows(model, params):
-    """`InferenceEngine(paged=True, page_size=, n_pages=)` as the benchmark
+    """`shared_engine(paged=True, page_size=, n_pages=)` as the benchmark
     builds it: two requests of different lengths in flight, a third that
     reuses a slot, every logprob against the reference, nothing leaked."""
-    eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                          page_size=64, n_pages=9)
+    eng = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                        page_size=64, n_pages=9)
     assert eng.pages.page_size == 256 and eng.pages.max_pages_per_row == 1
     assert eng.cache.S.shape[1] == 2  # a row a slot, none for scratch
     a = eng.submit(_tokens(70, 11).tolist(), max_new_tokens=12)
@@ -315,8 +316,8 @@ def test_engine_serves_from_state_rows(model, params):
 
 
 def test_engine_chunked_prefill_continues_from_the_row(model, params):
-    eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                          prefill_chunk_tokens=48)
+    eng = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                        prefill_chunk_tokens=48)
     r = eng.submit(_tokens(130, 21).tolist(), max_new_tokens=6)
     eng.run_until_idle()
     assert eng.prefill_chunks == 3
@@ -327,11 +328,11 @@ def test_engine_max_len_bounds_a_request(model):
     """`max_len` bounds a request as it does where there are pages: an
     over-long prompt is refused, or cut to its tail where the engine is
     told to."""
-    eng = InferenceEngine(model, n_slots=1, max_len=48, paged=True)
+    eng = shared_engine(model, n_slots=1, max_len=48, paged=True)
     r = eng.submit(_tokens(60, 3).tolist(), max_new_tokens=8)
     assert r.finish_reason == "invalid" and "max_len 48" in r.error
-    eng = InferenceEngine(model, n_slots=1, max_len=48, paged=True,
-                          truncate_prompts=True)
+    eng = shared_engine(model, n_slots=1, max_len=48, paged=True,
+                        truncate_prompts=True)
     r = eng.submit(_tokens(60, 3).tolist(), max_new_tokens=8)
     eng.run_until_idle()
     assert len(r.prompt) == 40 and len(r.out_tokens) == 8
@@ -340,10 +341,10 @@ def test_engine_max_len_bounds_a_request(model):
 
 def test_park_and_resume_is_bit_equal(model):
     prompt = _tokens(50, 31).tolist()
-    plain = InferenceEngine(model, n_slots=2, max_len=128, paged=True)
+    plain = shared_engine(model, n_slots=2, max_len=128, paged=True)
     want = plain.submit(prompt, max_new_tokens=14)
     plain.run_until_idle()
-    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True)
+    eng = shared_engine(model, n_slots=2, max_len=128, paged=True)
     other = eng.submit(_tokens(20, 32).tolist(), max_new_tokens=14)
     r = eng.submit(prompt, max_new_tokens=14)
     for _ in range(4):
@@ -366,13 +367,13 @@ def test_park_and_resume_is_bit_equal(model):
 def test_the_three_refusals(model):
     kind = "power_retention"
     with pytest.raises(NotImplementedError, match=f"quantize_kv.*{kind}"):
-        InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                        quantize_kv=True)
+        shared_engine(model, n_slots=1, max_len=64, paged=True,
+                      quantize_kv=True)
     with pytest.raises(NotImplementedError, match=f"speculative.*{kind}"):
-        InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                        speculative=True)
+        shared_engine(model, n_slots=1, max_len=64, paged=True,
+                      speculative=True)
     with pytest.raises(NotImplementedError, match=f"{kind}.*paged=True"):
-        InferenceEngine(model, n_slots=1, max_len=64)
+        shared_engine(model, n_slots=1, max_len=64)
     with pytest.raises(NotImplementedError, match=f"quantize_kv.*{kind}"):
         model.generate([[1, 2, 3]], max_new_tokens=2, quantize_kv=True)
 

@@ -1,0 +1,354 @@
+"""The paged cache kinds, each clause of `kvpaged.CacheKind` at toy sizes.
+
+What `serving/engine.InferenceEngine` asks of a paged cache it asks one
+object (`engine.kind`, docs/serving.md "Cache kinds"): KV pages and latent
+pages (kvpaged.py), a state row a slot (kvstate.py), a state row beside
+pages (kvhybrid.py), two groups of pages (kvwindow.py). Every test here is
+one clause of that protocol over the five kinds, on pools of a few hundred
+KB, with no engine built and no engine program compiled. The names of span
+arguments and of `/metrics` families are written out HERE: the benchmark's
+readers (bench/reduce, bench/metrics) take them by name, and a kind that
+renames one must fail a test that does not import the name from it.
+"""
+
+import re
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bigdl_tpu import kvhybrid, kvpaged, kvstate, kvwindow
+from bigdl_tpu.models import get_family
+from bigdl_tpu.models.config import PRESETS, ModelConfig
+from bigdl_tpu.serving.engine import _cache_kind, _PrefillState
+from bigdl_tpu.serving.pages import PageTable
+
+pytestmark = pytest.mark.core
+
+_DENSE = dict(hidden_size=128, intermediate_size=256, num_hidden_layers=2,
+              num_attention_heads=4, num_key_value_heads=2, vocab_size=512,
+              rms_norm_eps=1e-5, rope_theta=1e4, max_position_embeddings=2048,
+              tie_word_embeddings=False)
+CONFIGS = {
+    "kv_pages": ModelConfig.from_hf_config(dict(_DENSE, model_type="mistral")),
+    "latent_pages": ModelConfig.from_hf_config(dict(
+        _DENSE, model_type="glm4_moe_lite", num_hidden_layers=3,
+        num_key_value_heads=4, moe_intermediate_size=128, n_routed_experts=8,
+        num_experts_per_tok=2, n_shared_experts=1, first_k_dense_replace=1,
+        n_group=1, topk_group=1, topk_method="noaux_tc", norm_topk_prob=True,
+        routed_scaling_factor=1.8, q_lora_rank=128, kv_lora_rank=96,
+        qk_nope_head_dim=32, qk_rope_head_dim=32, v_head_dim=64,
+        rope_scaling=None)),
+    "power_retention": ModelConfig.from_hf_config(
+        dict(_DENSE, model_type="brumby", head_dim=32)),
+    "state_beside_pages": PRESETS["tiny-granite-hybrid"],
+    "window_pages_beside_pages": PRESETS["tiny-smallthinker"],
+}
+KINDS = {
+    "kv_pages": kvpaged.KV_PAGES,
+    "latent_pages": kvpaged.LATENT_PAGES,
+    "power_retention": kvstate.CACHE_KIND,
+    "state_beside_pages": kvhybrid.CACHE_KIND,
+    "window_pages_beside_pages": kvwindow.CACHE_KIND,
+}
+NAMES = sorted(KINDS)
+N_SLOTS, MAX_LEN, PAGE = 4, 64, 8
+
+
+def _geometry(kind) -> kvpaged.Geometry:
+    page, n_pages = kind.page_geometry(N_SLOTS, MAX_LEN, PAGE, 17)
+    return kvpaged.Geometry(N_SLOTS, MAX_LEN, page, n_pages,
+                            -(-MAX_LEN // page))
+
+
+def _pool(name, fill: bool = True):
+    """The kind's pool; `fill`: every array random, so that a copy that
+    misses a layer or a field shows."""
+    kind = KINDS[name]
+    pool = kind.make_pool(CONFIGS[name], _geometry(kind))
+    if not fill:
+        return pool
+    rng = np.random.default_rng(0)
+    return kind.with_leaves(pool, tuple(
+        None if a is None else
+        jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+        for a in kind.leaves(pool)))
+
+
+def _table(name) -> PageTable:
+    kind, geo = KINDS[name], _geometry(KINDS[name])
+    return PageTable(N_SLOTS, geo.n_pages, geo.page_size,
+                     geo.max_pages_per_row, MAX_LEN,
+                     share_prefixes=kind.share_prefixes,
+                     window=kind.window(CONFIGS[name]))
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_the_kind_is_chosen_from_the_model_alone(name):
+    cfg = CONFIGS[name]
+    model = types.SimpleNamespace(config=cfg,
+                                  family=get_family(cfg.model_type))
+    kind = _cache_kind(model)
+    assert kind is KINDS[name] and kind.name == name
+    # what it tells the page table
+    assert kind.share_prefixes == (name in ("kv_pages", "latent_pages"))
+    assert kind.window(cfg) == (
+        cfg.sliding_window if name == "window_pages_beside_pages" else None)
+    page, n_pages = kind.page_geometry(N_SLOTS, MAX_LEN, PAGE, 17)
+    assert (page, n_pages) == ((MAX_LEN, N_SLOTS + 1)
+                               if name == "power_retention" else (PAGE, 17))
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_leaves_round_trip_to_the_same_pytree(name):
+    kind, pool = KINDS[name], _pool(name, fill=False)
+    leaves = kind.leaves(pool)
+    assert len(leaves) == len(kind.arrays)
+    # the pool's arrays and nothing else: no table, no position
+    big = {id(a) for a in jax.tree.leaves(pool) if a.ndim >= 4}
+    assert big == {id(a) for a in jax.tree.leaves(leaves)}
+    again = kind.with_leaves(pool, leaves)
+    assert jax.tree.structure(again) == jax.tree.structure(pool)
+    assert all(a is b for a, b in zip(jax.tree.leaves(again),
+                                      jax.tree.leaves(pool)))
+    swapped = kind.with_leaves(pool, jax.tree.map(lambda a: a + 1, leaves))
+    assert all(np.all(np.asarray(a, np.float32) == 1)
+               for a in jax.tree.leaves(kind.leaves(swapped)))
+    assert swapped.block_tables is pool.block_tables
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_swap_out_then_in_restores_every_array_bit_for_bit(name):
+    kind, pool = KINDS[name], _pool(name)
+    # a page is a row for a state (page p is row p - 1): stay inside 1..4
+    src, dst = ([1, 2], 0, [3]), ([4, 3], 2, [1])
+    blob = kind.swap_out(pool, *src)
+    parked = kind.leaves(blob)
+    assert all(a is None or (isinstance(a, np.ndarray) and a.any())
+               for a in parked)
+    assert blob.nbytes == sum(a.nbytes for a in parked if a is not None)
+    empty = kind.with_leaves(pool, jax.tree.map(jnp.zeros_like,
+                                                kind.leaves(pool)))
+    into = (jnp.asarray(dst[0], jnp.int32), jnp.asarray(dst[1]),
+            jnp.asarray(dst[2], jnp.int32))
+    back = kind.leaves(kind.swap_out(kind.swap_in(empty, parked, into), *dst))
+    for a, b in zip(parked, back):
+        assert (a is None) == (b is None)
+        if a is not None:
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_copy_page_copies_every_layer_and_nothing_else(name):
+    kind, pool = KINDS[name], _pool(name)
+    got = kind.copy_page(pool, jnp.asarray(1), jnp.asarray(3))
+    row = name == "power_retention"  # page p is state row p - 1
+    fields = kind.arrays if row else kind.page_arrays
+    assert fields
+    for f in fields:
+        a, b = getattr(pool, f), getattr(got, f)
+        if a is None:
+            assert b is None
+            continue
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        s, d = (0, 2) if row else (1, 3)
+        assert np.array_equal(b[:, d], a[:, s]) and a[:, s].any()
+        rest = [i for i in range(a.shape[1]) if i != d]
+        assert np.array_equal(b[:, rest], a[:, rest])
+    for f in set(kind.arrays) - set(fields):  # a state row, a second group
+        assert getattr(got, f) is getattr(pool, f)
+
+
+_NOT_WIRED = "{what} is not wired for {kind} \\({model}\\) yet \\(ROADMAP {r}\\)"
+_WHAT = {"quantize_kv": "quantize_kv", "speculative": "speculative serving",
+         "adapters": "adapter serving",
+         "prefill_chunk_tokens": "prefill_chunk_tokens"}
+# kind -> (its name in a refusal, model type, ROADMAP item, what it refuses)
+_REFUSES = {
+    "kv_pages": ("kv_pages", "mistral", None, ()),
+    "latent_pages": ("latent pages", "glm4_moe_lite", "R1",
+                     ("quantize_kv", "speculative", "adapters")),
+    "power_retention": ("power_retention", "brumby", None,
+                        ("quantize_kv", "speculative")),
+    "state_beside_pages": ("state_beside_pages", "granitemoehybrid", "R4",
+                           ("quantize_kv", "speculative", "adapters")),
+    "window_pages_beside_pages": (
+        "window_pages_beside_pages", "smallthinker", "R3",
+        ("quantize_kv", "speculative", "adapters", "prefill_chunk_tokens")),
+}
+_OWN = {  # today's sentences where they are not "not wired"
+    ("power_retention", "paged"):
+        r"power_retention \(brumby\) is served with paged=True: a slot's "
+        "recurrent state is a row the page table owns, and there is no "
+        "dense pool of keys to fall back on",
+    ("power_retention", "quantize_kv"):
+        r"quantize_kv is not available for power_retention \(brumby\): the "
+        "cache is a float32 recurrent state, not keys and values",
+    ("power_retention", "speculative"):
+        r"speculative serving is not available for power_retention "
+        r"\(brumby\): a rejected draft cannot be taken back out of a "
+        "recurrent state by moving `pos`",
+    ("state_beside_pages", "paged"):
+        r"state_beside_pages \(granitemoehybrid\) is served with paged=True: "
+        "a slot holds KV pages for the attention layers and a state row for "
+        "the others",
+    ("window_pages_beside_pages", "paged"):
+        r"window_pages_beside_pages \(smallthinker\) is served with "
+        "paged=True: a slot holds KV pages for the attention layers in two "
+        "groups, and frees the window group's behind the window",
+}
+
+
+@pytest.mark.parametrize("feature", ["paged", *_WHAT])
+@pytest.mark.parametrize("name", NAMES)
+def test_refusals_are_todays_sentences(name, feature):
+    kind = KINDS[name]
+    label, model, where, refused = _REFUSES[name]
+    assert CONFIGS[name].model_type == model
+    if feature == "paged":
+        ask, want = dict(paged=False), _OWN.get((name, "paged"))
+    else:
+        ask = dict(paged=True, **{feature: True})
+        want = _OWN.get((name, feature)) if feature in refused else None
+        if feature in refused and want is None:
+            want = _NOT_WIRED.format(what=_WHAT[feature], kind=label,
+                                     model=model, r=where)
+    if want is None:
+        kind.check(model, **ask)
+        return
+    with pytest.raises(NotImplementedError) as e:
+        kind.check(model, **ask)
+    assert re.fullmatch(want, str(e.value)), str(e.value)
+    # asked for nothing, it serves
+    kind.check(model, paged=True, quantize_kv=False, speculative=False,
+               adapters=False, prefill_chunk_tokens=False)
+
+
+# the names bench/reduce and bench/metrics read, by kind: the `prefill`
+# span's arguments, the `decode_step` span's, the `/metrics` families
+_SPANS = {
+    "kv_pages": (["pages_written", "row_pages"],
+                 ["grid_pages", "live_pages"], []),
+    "latent_pages": (["latent_tokens_upprojected"],
+                     ["grid_pages", "latent_bytes_read", "latent_live_tokens",
+                      "live_pages"],
+                     ["bigdl_tpu_latent_pages_in_use",
+                      "bigdl_tpu_latent_token_bytes"]),
+    "power_retention": (["state_chunks"],
+                        ["state_bytes_moved", "state_rows_live"],
+                        ["bigdl_tpu_state_rows_live",
+                         "bigdl_tpu_state_pool_bytes",
+                         "bigdl_tpu_state_bytes_moved_total"]),
+    "state_beside_pages": (["state_chunks"],
+                           ["grid_pages", "live_pages", "state_bytes_moved",
+                            "state_rows_live"],
+                           ["bigdl_tpu_state_rows_live",
+                            "bigdl_tpu_state_pool_bytes",
+                            "bigdl_tpu_state_bytes_moved_total"]),
+    "window_pages_beside_pages": (
+        ["pages_written_global", "pages_written_window", "row_pages"],
+        ["grid_pages_global", "grid_pages_window", "live_pages_global",
+         "live_pages_window", "window_pages_freed", "window_pages_held",
+         "window_pages_unfreed"],
+        ["bigdl_tpu_global_pages_in_use", "bigdl_tpu_window_pages_in_use",
+         "bigdl_tpu_window_pages_freed_total"]),
+}
+
+
+def _chunk(name, written: int, bucket: int, n: int) -> _PrefillState:
+    st = _PrefillState(req=None, slot=0, row=None, written=written, path=[],
+                       chunk=n)
+    kind = KINDS[name]
+    kind.note_chunk(st, CONFIGS[name], _geometry(kind), bucket, n)
+    return st
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_span_arguments_keep_the_names_the_benchmark_reads(name):
+    kind, cfg = KINDS[name], CONFIGS[name]
+    prefill, decode, _ = _SPANS[name]
+    st = _chunk(name, 0, 32, 30)
+    args = kind.prefill_args(st)
+    assert sorted(args) == prefill
+    assert all(isinstance(v, int) and v > 0 for v in args.values()), args
+    table = _table(name)
+    table.reserve(0, list(range(1, 20)))
+    live = np.array([True, False, False, False])
+    args = kind.decode_args(cfg, table, live, 2 * 4096)
+    assert sorted(args) == decode
+    assert all(isinstance(v, int) for v in args.values()), args
+    if "state_bytes_moved" in args:
+        assert (args["state_rows_live"], args["state_bytes_moved"]) \
+            == (1, 8192)
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_a_chunk_adds_what_the_kind_counts(name):
+    """Two chunks, 0..31 and 32..63 of a 64-token row of eight pages."""
+    kind, geo = KINDS[name], _geometry(KINDS[name])
+    st = _chunk(name, 0, 32, 32)
+    st.written = 32
+    kind.note_chunk(st, CONFIGS[name], geo, 32, 20)
+    got = (st.state_chunks, st.upprojected, st.row_pages, st.pages_written,
+           st.window_pages_written)
+    cfg = CONFIGS[name]
+    assert got == {
+        "kv_pages": lambda: (0, 0, 16, 8, 0),
+        "latent_pages": lambda: (0, 128, 0, 0, 0),
+        "power_retention": lambda: (
+            2 * kvstate.prefill_chunks(32), 0, 0, 0, 0),
+        "state_beside_pages": lambda: (
+            2 * kvhybrid.prefill_chunks(32, cfg.mamba_chunk_size), 0, 0, 0, 0),
+        "window_pages_beside_pages": lambda: (0, 0, 32, 8, sum(
+            kvwindow.window_pages_spanned(w, 32, n, cfg.sliding_window,
+                                          PAGE, 8)
+            for w, n in ((0, 32), (32, 20)))),
+    }[name]()
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_metrics_families_keep_their_names(name):
+    kind, pool = KINDS[name], _pool(name, fill=False)
+    engine = types.SimpleNamespace(
+        kind=kind, config=CONFIGS[name], pages=_table(name), n_slots=N_SLOTS,
+        active=np.array([True, True, False, False]), state_bytes_moved=12,
+        state_row_bytes=kind.state_row_nbytes(pool))
+    got = kind.metrics(engine)
+    assert [m[0] for m in got] == _SPANS[name][2]
+    for family, typ, text, value in got:
+        assert typ == ("counter" if family.endswith("_total") else "gauge")
+        assert text and "\n" not in text and isinstance(value, int)
+    from bigdl_tpu.serving.metrics import expected_families
+
+    assert set(_SPANS[name][2]) <= set(expected_families(engine))
+    assert bool(kind.state_row_nbytes(pool)) == ("state" in "".join(
+        _SPANS[name][0]))
+    assert bool(kind.token_nbytes(CONFIGS[name])) == (name == "latent_pages")
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_a_prefill_gives_back_the_pool_it_was_lent(name):
+    """`row_view` then `write_back`, traced and not run: the leaves come
+    back in their shapes (what `donate_argnames=("pool",)` aliases), and
+    only the gathering kinds prefill a row that is not the pool."""
+    kind, cfg, geo = KINDS[name], CONFIGS[name], _geometry(KINDS[name])
+    pool = _pool(name, fill=False)
+    table = jnp.zeros((1, geo.max_pages_per_row), jnp.int32)
+    seen = {}
+
+    def program(leaves, last_idx):
+        one, row = kind.row_view(leaves, (table, table), jnp.zeros(
+            (1,), jnp.int32), last_idx, jnp.zeros((1,), jnp.int32), cfg, geo)
+        seen["same"] = row is one
+        return kind.write_back(one, row, 16, last_idx, cfg)
+
+    leaves = kind.leaves(pool)
+    out = jax.eval_shape(program, leaves, jnp.asarray(11))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), out) \
+        == jax.tree.map(lambda a: (a.shape, a.dtype), leaves)
+    assert seen["same"] == (name not in ("kv_pages",
+                                         "window_pages_beside_pages"))
+    assert kind.forward_kw(3) == (
+        {"logits_at": 3} if name == "window_pages_beside_pages" else {})

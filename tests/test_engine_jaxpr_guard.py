@@ -102,22 +102,14 @@ def counts(name: str) -> tuple:
     eng = InferenceEngine(TpuModel(cfg, params, "sym_int4"), n_slots=4,
                           max_len=256, paged=True, page_size=16, n_pages=65)
     B, c, z = 4, eng.cache, jnp.zeros
-    if hasattr(c, "S"):  # a state row a slot
-        pool = (c.S, c.z)
-    elif hasattr(c, "lat"):  # latent pages
-        pool = (c.lat,)
-    elif hasattr(c, "kw"):  # two groups of pages, a table each
-        pool = (c.k, c.v, c.kw, c.vw,
-                z((1, eng.max_pages_per_row), jnp.int32))
-    else:
-        pool = (c.k, c.v, c.k_scale, c.v_scale)
+    table = z((1, eng.max_pages_per_row), jnp.int32)
     programs = (
         (eng._decode, (
             params, z((B,), jnp.int32), c, jax.random.PRNGKey(0), z((B,)),
             z((B,), jnp.int32), z((B,)), z((B,), bool), eng.seen, z((B,)))),
         (eng._paged_prefill, (
-            params, *pool, z((1, eng.max_pages_per_row), jnp.int32),
-            z((1,), jnp.int32), z((1, 64), jnp.int32), z((), jnp.int32))))
+            params, eng.kind.leaves(c), (table, table), z((1,), jnp.int32),
+            z((1, 64), jnp.int32), z((), jnp.int32), z((1,), jnp.int32))))
     return tuple(
         n_eqns(jax.make_jaxpr(getattr(fn, "__wrapped__", fn))(*args).jaxpr)
         for fn, args in programs)

@@ -19,6 +19,7 @@ from bigdl_tpu.generate import GenerationConfig
 from bigdl_tpu.models import deepseek, get_family
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.serving.engine import InferenceEngine
+from engines import shared_engine
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -58,11 +59,13 @@ def _tokens(n, seed, vocab=512):
     return np.random.default_rng(seed).integers(1, vocab, n)
 
 
-def _engine(cfg, params, qtype="bf16", **kw):
+def _engine(cfg, params, qtype="bf16", fresh=False, **kw):
+    """`fresh`: with programs of its own, for a test of what it traces."""
     args = dict(n_slots=3, max_len=256, paged=True, page_size=16, n_pages=60,
                 gen=GenerationConfig(eos_token_id=None))
     args.update(kw)
-    return InferenceEngine(TpuModel(cfg, params, qtype), **args)
+    make = InferenceEngine if fresh else shared_engine
+    return make(TpuModel(cfg, params, qtype), **args)
 
 
 @pytest.fixture
@@ -208,7 +211,8 @@ def test_engine_with_the_kernels_interpreted_matches_the_reference(interpret):
     ref = _reference()
     cfg, params = _params(TINY_KERNELS, "sym_int4")
     with record_routes() as routes:
-        eng = _engine(cfg, params, "sym_int4", n_slots=2, n_pages=30)
+        eng = _engine(cfg, params, "sym_int4", fresh=True, n_slots=2,
+                      n_pages=30)
         r = eng.submit(_tokens(37, 3).tolist(), max_new_tokens=4)
         eng.run_until_idle()
     _check(ref, TINY_KERNELS, params, r, 4, 0.02)
@@ -322,10 +326,10 @@ def test_the_refusals_name_latent_pages():
                      (dict(adapters=object()), "adapter")):
         with pytest.raises(NotImplementedError,
                            match=f"{what}.*not wired for latent pages"):
-            InferenceEngine(model, n_slots=1, max_len=64, paged=True, **kw)
+            shared_engine(model, n_slots=1, max_len=64, paged=True, **kw)
     # the dense pool of latents is still served, unpaged
-    eng = InferenceEngine(model, n_slots=1, max_len=64,
-                          gen=GenerationConfig(eos_token_id=None))
+    eng = shared_engine(model, n_slots=1, max_len=64,
+                        gen=GenerationConfig(eos_token_id=None))
     r = eng.submit(_tokens(20, 8).tolist(), max_new_tokens=3)
     eng.run_until_idle()
     assert r.finish_reason == "length" and len(r.out_tokens) == 3

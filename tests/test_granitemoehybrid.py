@@ -29,6 +29,7 @@ from bigdl_tpu.api import TpuModel, optimize_model  # noqa: E402
 from bigdl_tpu.models import get_family  # noqa: E402
 from bigdl_tpu.models.config import PRESETS, ModelConfig  # noqa: E402
 from bigdl_tpu.serving.engine import InferenceEngine  # noqa: E402
+from engines import shared_engine  # noqa: E402
 
 pytestmark = pytest.mark.core
 
@@ -249,9 +250,9 @@ def test_engine_serves_state_beside_pages(model, ref, params, monkeypatch,
     route and with the kernels through the interpreter: every logprob
     against the reference, a reused row starts from zero, nothing leaks."""
     monkeypatch.setenv("BIGDL_TPU_PALLAS", pallas)
-    eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                          page_size=8)
-    assert eng._hybrid and eng.cache.ssm.shape[1] == 2
+    eng = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                        page_size=8)
+    assert eng.kind is kvhybrid.CACHE_KIND and eng.cache.ssm.shape[1] == 2
     assert eng.cache.k.shape[0] == 1 and eng.cache.conv.shape[0] == 4
     reqs = [eng.submit(_tokens(n, 10 + n).tolist(), max_new_tokens=m)
             for n, m in ((19, 7), (6, 4), (30, 6))]
@@ -269,8 +270,8 @@ def test_engine_serves_state_beside_pages(model, ref, params, monkeypatch,
 
 
 def test_an_idle_slots_row_is_unchanged_and_rows_do_not_mix(model):
-    eng = InferenceEngine(model, n_slots=3, max_len=64, paged=True,
-                          page_size=8)
+    eng = shared_engine(model, n_slots=3, max_len=64, paged=True,
+                        page_size=8)
     eng.cache = dataclasses.replace(
         eng.cache, ssm=eng.cache.ssm.at[:, 2].set(7.0),
         conv=eng.cache.conv.at[:, :, 2].set(7.0))
@@ -279,8 +280,8 @@ def test_an_idle_slots_row_is_unchanged_and_rows_do_not_mix(model):
     eng.run_until_idle()
     assert np.all(np.asarray(eng.cache.ssm[:, 2]) == 7.0)  # never held
     assert np.all(np.asarray(eng.cache.conv[:, :, 2]) == 7.0)
-    alone = InferenceEngine(model, n_slots=3, max_len=64, paged=True,
-                            page_size=8)
+    alone = shared_engine(model, n_slots=3, max_len=64, paged=True,
+                          page_size=8)
     a2 = alone.submit(list(a.prompt), max_new_tokens=5)
     alone.run_until_idle()
     assert a2.out_tokens == a.out_tokens and a2.out_logprobs == a.out_logprobs
@@ -288,8 +289,8 @@ def test_an_idle_slots_row_is_unchanged_and_rows_do_not_mix(model):
 
 
 def test_engine_chunked_prefill_continues_from_the_row(model, ref, params):
-    eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                          page_size=8, prefill_chunk_tokens=12)
+    eng = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                        page_size=8, prefill_chunk_tokens=12)
     r = eng.submit(_tokens(30, 21).tolist(), max_new_tokens=5)
     eng.run_until_idle()
     assert eng.prefill_chunks == 3
@@ -298,12 +299,12 @@ def test_engine_chunked_prefill_continues_from_the_row(model, ref, params):
 
 def test_park_and_resume_carries_pages_and_row(model):
     prompt = _tokens(20, 31).tolist()
-    plain = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                            page_size=8)
+    plain = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                          page_size=8)
     want = plain.submit(prompt, max_new_tokens=10)
     plain.run_until_idle()
-    eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                          page_size=8)
+    eng = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                        page_size=8)
     other = eng.submit(_tokens(10, 32).tolist(), max_new_tokens=10)
     r = eng.submit(prompt, max_new_tokens=10)
     for _ in range(4):
@@ -326,13 +327,13 @@ def test_park_and_resume_carries_pages_and_row(model):
 def test_the_refusals_name_the_kind(model):
     kind = kvhybrid.KIND
     with pytest.raises(NotImplementedError, match=f"quantize_kv.*{kind}"):
-        InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                        quantize_kv=True)
+        shared_engine(model, n_slots=1, max_len=64, paged=True,
+                      quantize_kv=True)
     with pytest.raises(NotImplementedError, match=f"speculative.*{kind}"):
-        InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                        speculative=True)
+        shared_engine(model, n_slots=1, max_len=64, paged=True,
+                      speculative=True)
     with pytest.raises(NotImplementedError, match=f"{kind}.*paged=True"):
-        InferenceEngine(model, n_slots=1, max_len=64)
+        shared_engine(model, n_slots=1, max_len=64)
     with pytest.raises(NotImplementedError, match=f"quantize_kv.*{kind}"):
         model.generate([[1, 2, 3]], max_new_tokens=2, quantize_kv=True)
 

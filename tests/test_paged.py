@@ -14,6 +14,7 @@ from bigdl_tpu.api import TpuModel, optimize_model
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import PRESETS
 from bigdl_tpu.serving.engine import InferenceEngine
+from engines import shared_engine
 
 CFG = PRESETS["tiny-llama"]
 
@@ -44,8 +45,8 @@ def wide_model():
 
 
 def _wide_engine(wide_model, **kw):
-    eng = InferenceEngine(wide_model, n_slots=2, max_len=512, paged=True,
-                          page_size=64, **kw)
+    eng = shared_engine(wide_model, n_slots=2, max_len=512, paged=True,
+                        page_size=64, **kw)
     from bigdl_tpu.ops.pallas.paged_attention import (
         group_pages, pool_tiles_whole)
     Hkv, D = eng.cache.k.shape[3:]
@@ -97,9 +98,9 @@ def _run(engine, prompts, maxnt=10):
 @pytest.mark.core
 def test_paged_engine_matches_dense_engine(model):
     prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8], [11, 12, 13]]
-    ref = _run(InferenceEngine(model, n_slots=2, max_len=128), prompts)
-    out = _run(InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                               page_size=16), prompts)
+    ref = _run(shared_engine(model, n_slots=2, max_len=128), prompts)
+    out = _run(shared_engine(model, n_slots=2, max_len=128, paged=True,
+                             page_size=16), prompts)
     assert out == ref
 
 
@@ -111,15 +112,14 @@ def _watch_prefills(eng):
     after the call."""
     calls, inner = [], eng._paged_prefill
 
-    def spy(params, k, v, ks, vs, row_bt, pos0, tokens, last_idx, **kw):
-        pools = [a for a in (k, v, ks, vs) if a is not None]
+    def spy(params, pool, tables, pos0, tokens, *rest, **kw):
+        pools = [a for a in pool if a is not None]
         before = [np.asarray(a).view(np.uint8) for a in pools]  # donated next
-        out = inner(params, k, v, ks, vs, row_bt, pos0, tokens, last_idx,
-                    **kw)
-        after = [np.asarray(a).view(np.uint8) for a in out[1:5]
+        out = inner(params, pool, tables, pos0, tokens, *rest, **kw)
+        after = [np.asarray(a).view(np.uint8) for a in out[1]
                  if a is not None]
-        calls.append((np.asarray(row_bt)[0], int(pos0[0]), tokens.shape[1],
-                      before, after))
+        calls.append((np.asarray(tables[0])[0], int(pos0[0]),
+                      tokens.shape[1], before, after))
         return out
 
     eng._paged_prefill = spy
@@ -205,9 +205,9 @@ def test_admission_writes_only_its_rows_own_pages(model, case, route,
         assert [c[1] for c in calls] == [0, 16, 32]
         assert all((c[0] == first_row).all() for c in calls)
 
-    dense = InferenceEngine(model, n_slots=2, max_len=128,
-                            **{k: v for k, v in opts.items()
-                               if k == "quantize_kv"})
+    dense = shared_engine(model, n_slots=2, max_len=128,
+                          **{k: v for k, v in opts.items()
+                             if k == "quantize_kv"})
     ref = dense.submit(prompt, max_new_tokens=6)
     dense.run_until_idle()
     if case == "fp8":  # the dense pool rounds its scales to float16
@@ -222,8 +222,8 @@ def test_paged_pool_smaller_than_dense_worstcase(model):
     """The pool can be much smaller than slots*max_len and still serve
     (on-demand allocation): 4 slots x 256 logical but only 24 pages x 16
     = 384 slots of physical KV."""
-    eng = InferenceEngine(model, n_slots=4, max_len=256, paged=True,
-                          page_size=16, n_pages=24)
+    eng = shared_engine(model, n_slots=4, max_len=256, paged=True,
+                        page_size=16, n_pages=24)
     prompts = [[i, i + 1, i + 2, i + 3] for i in range(1, 9)]
     outs = _run(eng, prompts, maxnt=8)
     assert len(outs) == 8 and all(len(o) == 8 for o in outs)
@@ -235,8 +235,8 @@ def test_prefix_cache_hits_and_reuses_compute(model):
     """Identical page-aligned prompt prefixes are served from cached
     pages: the second request records a hit and produces identical
     output; storage is shared (same physical page in both tables)."""
-    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                          page_size=8)
+    eng = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                        page_size=8)
     prefix = [5, 6, 7, 8, 9, 10, 11, 12]  # exactly one page
     p1 = prefix + [20, 21]
     p2 = prefix + [30, 31, 32]
@@ -249,7 +249,7 @@ def test_prefix_cache_hits_and_reuses_compute(model):
     assert r1.done and r2.done
 
     # same prompts through a dense engine agree token for token
-    dense = InferenceEngine(model, n_slots=2, max_len=128)
+    dense = shared_engine(model, n_slots=2, max_len=128)
     d1 = dense.submit(p1, max_new_tokens=6)
     d2 = dense.submit(p2, max_new_tokens=6)
     dense.run_until_idle()
@@ -258,8 +258,8 @@ def test_prefix_cache_hits_and_reuses_compute(model):
 
 
 def test_pages_released_and_reused(model):
-    eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8, n_pages=6)
+    eng = shared_engine(model, n_slots=1, max_len=64, paged=True,
+                        page_size=8, n_pages=6)
     for round_i in range(5):  # far more logical traffic than 6 pages hold
         out = _run(eng, [[1 + round_i, 2, 3, 4, 5]], maxnt=6)
         assert len(out[0]) == 6
@@ -274,12 +274,12 @@ def test_long_decode_grows_pages_without_drift(model):
     """Decode far past the admission bucket: on-demand page growth must
     stay page-aligned (a 32-aligned start drifted the page index and
     crashed with an out-of-bounds block-table write)."""
-    eng = InferenceEngine(model, n_slots=1, max_len=256, paged=True,
-                          page_size=64)
+    eng = shared_engine(model, n_slots=1, max_len=256, paged=True,
+                        page_size=64)
     outs = _run(eng, [[3, 1, 4, 1, 5]], maxnt=200)
     assert len(outs[0]) == 200
     # matches the dense engine token for token over the whole run
-    dense = InferenceEngine(model, n_slots=1, max_len=256)
+    dense = shared_engine(model, n_slots=1, max_len=256)
     ref = _run(dense, [[3, 1, 4, 1, 5]], maxnt=200)
     assert outs == ref
 
@@ -287,8 +287,8 @@ def test_long_decode_grows_pages_without_drift(model):
 def test_impossible_request_fails_instead_of_blocking(model):
     """A prompt that can never fit the pool errors out immediately and
     does not head-of-line-block the queue."""
-    eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                          page_size=16, n_pages=4)  # 3 allocatable
+    eng = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                        page_size=16, n_pages=4)  # 3 allocatable
     big = eng.submit(list(range(1, 100)), max_new_tokens=4)
     small = eng.submit([1, 2, 3], max_new_tokens=4)
     eng.run_until_idle()
@@ -300,8 +300,8 @@ def test_impossible_request_fails_instead_of_blocking(model):
 def test_pool_exhaustion_requeues_and_recovers(model):
     """More concurrent demand than pages: admission defers (request waits)
     rather than failing, and completes once pages free up."""
-    eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                          page_size=8, n_pages=5)
+    eng = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                        page_size=8, n_pages=5)
     long_p = list(range(1, 25))  # 24 tokens -> 4 pages at admission
     reqs = [eng.submit(long_p, max_new_tokens=6),
             eng.submit(list(range(30, 54)), max_new_tokens=6)]
@@ -649,16 +649,16 @@ def test_paged_fp8_pages(model):
     """fp8 page storage: half the page bytes; decode stays coherent and
     close to the bf16-paged output (engine-level: quantize_kv=True)."""
     prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8]]
-    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                          page_size=16, quantize_kv=True)
+    eng = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                        page_size=16, quantize_kv=True)
     assert eng.cache.quantized
     assert eng.cache.k.dtype == jnp.float8_e5m2
     outs = _run(eng, prompts, maxnt=8)
     assert all(len(o) == 8 for o in outs)
     # fp8 is lossy, so tokens may eventually diverge from bf16 pages;
     # the first few greedy tokens of a confident model should agree
-    ref = _run(InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                               page_size=16), prompts, maxnt=8)
+    ref = _run(shared_engine(model, n_slots=2, max_len=128, paged=True,
+                             page_size=16), prompts, maxnt=8)
     agree = sum(a == b for o, r in zip(outs, ref) for a, b in zip(o[:4], r[:4]))
     assert agree >= 4, (outs, ref)
 
@@ -679,9 +679,9 @@ def test_speculative_over_paged_matches_plain(model):
     is byte-identical to plain (non-speculative, non-paged) serving, and
     verify rounds genuinely emit >1 token (draft == target here)."""
     prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8], [11, 12, 13]]
-    ref = _run(InferenceEngine(model, n_slots=2, max_len=128), prompts,
+    ref = _run(shared_engine(model, n_slots=2, max_len=128), prompts,
                maxnt=12)
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=2, max_len=128, paged=True, page_size=16,
         speculative=True, draft_params=model.params, draft_k=4,
     )
@@ -694,7 +694,7 @@ def test_speculative_over_paged_matches_plain(model):
 def test_speculative_paged_page_accounting(model):
     """Verify rounds write draft_k tokens ahead — pages must be allocated
     for the full window and refcounts must balance after release."""
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=1, max_len=64, paged=True, page_size=8, n_pages=8,
         speculative=True, draft_params=model.params, draft_k=4,
     )
@@ -709,7 +709,7 @@ def test_speculative_paged_page_accounting(model):
 def test_speculative_paged_prefix_cache_composes(model):
     """A shared page-aligned prefix still hits the prefix cache under
     speculative serving, and outputs stay byte-identical to dense."""
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=2, max_len=128, paged=True, page_size=8,
         speculative=True, draft_params=model.params, draft_k=3,
     )
@@ -720,7 +720,7 @@ def test_speculative_paged_prefix_cache_composes(model):
     r2 = eng.submit(p2, max_new_tokens=6)
     eng.run_until_idle()
     assert eng.pages.prefix_hits == 1
-    dense = InferenceEngine(model, n_slots=2, max_len=128)
+    dense = shared_engine(model, n_slots=2, max_len=128)
     d1 = dense.submit(p1, max_new_tokens=6)
     d2 = dense.submit(p2, max_new_tokens=6)
     dense.run_until_idle()
@@ -735,9 +735,9 @@ def test_speculative_budget_exhaustion_near_cache_end(model):
     inside the cache; output stays identical to plain serving."""
     prompt = list(range(1, 40))
     maxnt = 24
-    ref = _run(InferenceEngine(model, n_slots=1, max_len=64), [prompt],
+    ref = _run(shared_engine(model, n_slots=1, max_len=64), [prompt],
                maxnt=maxnt)
-    out = _run(InferenceEngine(
+    out = _run(shared_engine(
         model, n_slots=1, max_len=64, speculative=True,
         draft_params=model.params, draft_k=4,
     ), [prompt], maxnt=maxnt)
@@ -750,8 +750,8 @@ def test_subpage_prefix_sharing_skips_prefill(model):
     of re-prefilling them — WHEN that shrinks the prefill bucket (cost
     is bucket-quantized; a copy that saves nothing is skipped) — and
     output stays byte-identical to dense."""
-    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                          page_size=8)
+    eng = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                        page_size=8)
     p1 = list(range(10, 26))  # two fully-covered pages
     r1 = eng.submit(p1, max_new_tokens=6)
     eng.run_until_idle()
@@ -779,7 +779,7 @@ def test_subpage_prefix_sharing_skips_prefill(model):
     eng.run_until_idle()
     assert eng.pages.prefix_partial_hits == before
 
-    dense = InferenceEngine(model, n_slots=2, max_len=128)
+    dense = shared_engine(model, n_slots=2, max_len=128)
     outs = []
     for p in (p1, p2, p3, p4):
         outs.append(dense.submit(p, max_new_tokens=6))
@@ -796,8 +796,8 @@ def test_subpage_sharing_source_page_protected_from_eviction(model):
     admission's own prefix (shared run + copy source), admission must
     defer — not evict the source out from under the copy. Once pages
     free up, the request completes byte-identical to dense."""
-    eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8)
+    eng = shared_engine(model, n_slots=1, max_len=64, paged=True,
+                        page_size=8)
     p1 = [5, 6, 7, 8, 9, 10, 11, 12, 20, 21, 22, 23, 24, 25, 26, 27]
     eng.submit(p1, max_new_tokens=4)
     eng.run_until_idle()
@@ -814,7 +814,7 @@ def test_subpage_sharing_source_page_protected_from_eviction(model):
     eng.pages.pool.free.extend(saved)
     eng.run_until_idle()
     assert r2.done and not r2.error
-    dense = InferenceEngine(model, n_slots=1, max_len=64)
+    dense = shared_engine(model, n_slots=1, max_len=64)
     d2 = dense.submit(p2, max_new_tokens=4)
     dense.run_until_idle()
     assert r2.out_tokens == d2.out_tokens
@@ -826,10 +826,10 @@ def test_speculative_paged_fp8_composes(model):
     greedy rows (identical pool quantization, identical acceptance
     math), and speculation genuinely fires."""
     prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8]]
-    ref = _run(InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                               page_size=16, quantize_kv=True),
+    ref = _run(shared_engine(model, n_slots=2, max_len=128, paged=True,
+                             page_size=16, quantize_kv=True),
                prompts, maxnt=10)
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=2, max_len=128, paged=True, page_size=16,
         quantize_kv=True, speculative=True, draft_params=model.params,
         draft_k=4,
@@ -844,9 +844,9 @@ def test_adaptive_draft_over_paged_matches_plain(model):
     to plain serving, page reservation follows the CURRENT ladder K, and
     a forced downshift keeps serving correctly."""
     prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8], [11, 12, 13]]
-    ref = _run(InferenceEngine(model, n_slots=2, max_len=128), prompts,
+    ref = _run(shared_engine(model, n_slots=2, max_len=128), prompts,
                maxnt=12)
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=2, max_len=128, paged=True, page_size=16,
         speculative=True, draft_params=model.params, draft_k=4,
         adaptive_draft=True,
@@ -855,7 +855,7 @@ def test_adaptive_draft_over_paged_matches_plain(model):
     assert out == ref
 
     # force a downshift and serve again — still byte-identical
-    eng2 = InferenceEngine(
+    eng2 = shared_engine(
         model, n_slots=2, max_len=128, paged=True, page_size=16,
         speculative=True, draft_params=model.params, draft_k=4,
         adaptive_draft=True,
@@ -868,8 +868,8 @@ def test_adaptive_draft_over_paged_matches_plain(model):
 def test_no_page_leak_under_cancel_rounds(model):
     """Client cancels mid-decode across several rounds must return every
     non-cached page to the free list with no negative refcounts."""
-    eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                          page_size=8, n_pages=12)
+    eng = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                        page_size=8, n_pages=12)
     free0 = eng.pages.pool.n_free
     for round_i in range(3):
         rs = [eng.submit([round_i * 17 + j, 5, 6, 7, 8], max_new_tokens=40)

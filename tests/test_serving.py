@@ -24,6 +24,7 @@ from bigdl_tpu.generate import GenerationConfig
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import PRESETS
 from bigdl_tpu.serving.engine import InferenceEngine
+from engines import shared_engine
 
 CFG = PRESETS["tiny-llama"]
 
@@ -49,7 +50,7 @@ def test_engine_matches_generate(model):
         tuple(p): model.generate([p], max_new_tokens=10)[0].tolist()
         for p in PROMPTS
     }
-    eng = InferenceEngine(model, n_slots=2, max_len=128)
+    eng = shared_engine(model, n_slots=2, max_len=128)
     # staggered admission: 2 slots, 3 requests — the third joins only when
     # a slot frees, mid-flight of the others
     reqs = [eng.submit(p, max_new_tokens=10) for p in PROMPTS]
@@ -60,7 +61,7 @@ def test_engine_matches_generate(model):
 
 
 def test_engine_streaming_queue(model):
-    eng = InferenceEngine(model, n_slots=2, max_len=128)
+    eng = shared_engine(model, n_slots=2, max_len=128)
     q: queue.SimpleQueue = queue.SimpleQueue()
     req = eng.submit(PROMPTS[0], max_new_tokens=6, stream=q)
     eng.run_until_idle(max_steps=100)
@@ -82,7 +83,7 @@ def test_engine_eos_frees_slot(model):
     # were the two pre-existing seed failures noted in PR 10)
     ref = model.generate([PROMPTS[0]], max_new_tokens=8)[0].tolist()
     eos = ref[2]
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=1, max_len=128,
         gen=GenerationConfig(eos_token_id=eos),
     )
@@ -98,7 +99,7 @@ def test_engine_eos_frees_slot(model):
 def test_oversized_max_tokens_clamped(model):
     """max_new_tokens >= max_len must not crash the engine (regression:
     bucket went to zero and the worker thread died)."""
-    eng = InferenceEngine(model, n_slots=1, max_len=128)
+    eng = shared_engine(model, n_slots=1, max_len=128)
     r = eng.submit(PROMPTS[0], max_new_tokens=5000)
     eng.run_until_idle(max_steps=300)
     assert r.done and r.error is None
@@ -109,14 +110,14 @@ def test_oversized_max_tokens_clamped(model):
 @pytest.mark.core
 def test_finish_reason_stop_vs_length(model):
     ref = model.generate([PROMPTS[0]], max_new_tokens=8)[0].tolist()
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=1, max_len=128,
         gen=GenerationConfig(eos_token_id=ref[2]),
     )
     stopped = eng.submit(PROMPTS[0], max_new_tokens=8)
     eng.run_until_idle(max_steps=100)
     assert stopped.finish_reason == "stop"
-    eng2 = InferenceEngine(model, n_slots=1, max_len=128)
+    eng2 = shared_engine(model, n_slots=1, max_len=128)
     capped = eng2.submit(PROMPTS[0], max_new_tokens=4)
     eng2.run_until_idle(max_steps=100)
     assert capped.finish_reason == "length"
@@ -186,7 +187,7 @@ def test_per_request_sampling_independent_streams(model):
     ref0 = model.generate([PROMPTS[0]], max_new_tokens=10)[0].tolist()
     ref1 = model.generate([PROMPTS[1]], max_new_tokens=10)[0].tolist()
 
-    eng = InferenceEngine(model, n_slots=3, max_len=128)
+    eng = shared_engine(model, n_slots=3, max_len=128)
     greedy = eng.submit(PROMPTS[0], max_new_tokens=10)
     hot = eng.submit(PROMPTS[2], max_new_tokens=10,
                      do_sample=True, temperature=5.0)
@@ -201,7 +202,7 @@ def test_per_request_sampling_independent_streams(model):
 
 def test_per_request_eos(model):
     ref = model.generate([PROMPTS[0]], max_new_tokens=8)[0].tolist()
-    eng = InferenceEngine(model, n_slots=2, max_len=128)
+    eng = shared_engine(model, n_slots=2, max_len=128)
     # same prompt, two different per-request EOS ids. The stop oracle is
     # everything BEFORE the eos id's first occurrence (this seed's model
     # repeats its greedy token, so ref[2] can occur at index 0 — the old
@@ -253,7 +254,7 @@ def test_engine_repetition_penalty_matches_generate(model):
     prompt = [5, 6, 7, 8, 5, 6]
     ref = model.generate([prompt], max_new_tokens=8, repetition_penalty=1.5)
 
-    eng = InferenceEngine(model, n_slots=4, max_len=128)
+    eng = shared_engine(model, n_slots=4, max_len=128)
     r_pen = eng.submit(prompt, max_new_tokens=8, repetition_penalty=1.5)
     r_plain = eng.submit(prompt, max_new_tokens=8)
     eng.run_until_idle()
@@ -285,15 +286,15 @@ def test_engine_serves_mla_family():
     prompts = [[3, 1, 4, 1, 5], [9, 2, 6], [5, 3, 5, 8]]
     refs = [m.generate([p], max_new_tokens=6)[0].tolist() for p in prompts]
 
-    eng = InferenceEngine(m, n_slots=2, max_len=128)  # < len(prompts): requeue
+    eng = shared_engine(m, n_slots=2, max_len=128)  # < len(prompts): requeue
     reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
     eng.run_until_idle()
     for r, ref in zip(reqs, refs):
         assert r.out_tokens == ref, (r.out_tokens, ref)
 
     # paged, the family serves from latent pages (PR 34) and says the same
-    paged = InferenceEngine(m, n_slots=2, max_len=64, paged=True,
-                            page_size=16, n_pages=9)
+    paged = shared_engine(m, n_slots=2, max_len=64, paged=True,
+                          page_size=16, n_pages=9)
     reqs = [paged.submit(p, max_new_tokens=6) for p in prompts]
     paged.run_until_idle()
     for r, ref in zip(reqs, refs):
@@ -313,7 +314,7 @@ def test_paged_refuses_a_family_cache_that_is_not_a_pool():
         intermediate_size=128))
     m = TpuModel(cfg, rwkv.init_params(cfg, jax.random.PRNGKey(0)), "bf16")
     with pytest.raises(NotImplementedError, match="paged"):
-        InferenceEngine(m, n_slots=2, max_len=64, paged=True)
+        shared_engine(m, n_slots=2, max_len=64, paged=True)
 
 
 def test_engine_rejects_unsupported_family_caches():
@@ -336,10 +337,10 @@ def test_engine_rejects_unsupported_family_caches():
         config=cfg, family=fake_family, params={}, qtype="bf16",
     )
     with pytest.raises(NotImplementedError, match="cache layout"):
-        InferenceEngine(fake_model, n_slots=2, max_len=64)
+        shared_engine(fake_model, n_slots=2, max_len=64)
     fake_family.engine_pool = lambda *a, **k: None  # half an adapter
     with pytest.raises(TypeError, match="must be defined together"):
-        InferenceEngine(fake_model, n_slots=2, max_len=64)
+        shared_engine(fake_model, n_slots=2, max_len=64)
 
 
 def test_engine_speculative_matches_generate(model):
@@ -350,7 +351,7 @@ def test_engine_speculative_matches_generate(model):
         tuple(p): model.generate([p], max_new_tokens=12)[0].tolist()
         for p in PROMPTS
     }
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=2, max_len=128, speculative=True,
         draft_params=model.params, draft_k=4,
     )
@@ -369,7 +370,7 @@ def test_engine_speculative_matches_generate(model):
 def test_engine_speculative_sampled_rides_along(model):
     """A do_sample request in a speculative batch accepts 0 drafts but
     still completes with the requested token budget."""
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=2, max_len=128, speculative=True,
         draft_params=model.params, draft_k=4,
         gen=GenerationConfig(do_sample=False),
@@ -422,7 +423,7 @@ def test_engine_custom_cache_families(model_type):
         tuple(p): m.generate([p], max_new_tokens=8)[0].tolist()
         for p in prompts
     }
-    eng = InferenceEngine(m, n_slots=2, max_len=128)
+    eng = shared_engine(m, n_slots=2, max_len=128)
     reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
     eng.run_until_idle(max_steps=300)
     for p, r in zip(prompts, reqs):
@@ -483,7 +484,7 @@ def test_engine_speculative_sampling_accepts_drafts(model):
     """With draft == target, sampling rows now accept drafts with
     probability p(argmax) > 0 — rounds emit more than 1 token on
     average, and requests still complete with their full budget."""
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=2, max_len=128, speculative=True,
         draft_params=model.params, draft_k=4,
     )
@@ -522,12 +523,12 @@ def test_engine_speculative_mla_family():
     m = TpuModel(cfg, params, "sym_int4")
 
     prompts = [[3, 1, 4, 1, 5], [9, 2, 6]]
-    ref_eng = InferenceEngine(m, n_slots=2, max_len=128)
+    ref_eng = shared_engine(m, n_slots=2, max_len=128)
     refs = [ref_eng.submit(p, max_new_tokens=8) for p in prompts]
     ref_eng.run_until_idle()
 
-    eng = InferenceEngine(m, n_slots=2, max_len=128, speculative=True,
-                          draft_params=m.params, draft_k=3)
+    eng = shared_engine(m, n_slots=2, max_len=128, speculative=True,
+                        draft_params=m.params, draft_k=3)
     reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
     eng.run_until_idle(max_steps=300)
     for r, ref in zip(reqs, refs):
@@ -555,7 +556,7 @@ def test_journal_recovery_replays_unfinished(model, tmp_path):
         for p in PROMPTS
     }
 
-    eng1 = InferenceEngine(model, n_slots=2, max_len=128, journal=jpath)
+    eng1 = shared_engine(model, n_slots=2, max_len=128, journal=jpath)
     r_done = eng1.submit(PROMPTS[0], max_new_tokens=8)
     eng1.run_until_idle(max_steps=200)  # completes + tombstones request 0
     assert r_done.done
@@ -566,7 +567,7 @@ def test_journal_recovery_replays_unfinished(model, tmp_path):
     with open(jpath, "a") as f:
         f.write('{"op": "sub')
 
-    eng2 = InferenceEngine(model, n_slots=2, max_len=128, journal=jpath)
+    eng2 = shared_engine(model, n_slots=2, max_len=128, journal=jpath)
     replayed = eng2.recovered_requests  # auto-replayed at attach
     assert [r.prompt for r in replayed] == [PROMPTS[1], PROMPTS[2]]
     # rid counter seeded past every journaled rid: a fresh submit must
@@ -580,7 +581,7 @@ def test_journal_recovery_replays_unfinished(model, tmp_path):
 
     # the replayed generation re-journaled and tombstoned: a third
     # engine finds nothing to replay
-    eng3 = InferenceEngine(model, n_slots=2, max_len=128, journal=jpath)
+    eng3 = shared_engine(model, n_slots=2, max_len=128, journal=jpath)
     assert eng3.recovered_requests == []
 
 
@@ -593,7 +594,7 @@ def test_engine_adaptive_draft_identical_and_ladder(model):
         tuple(p): model.generate([p], max_new_tokens=12)[0].tolist()
         for p in PROMPTS
     }
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=2, max_len=128, speculative=True,
         draft_params=model.params, draft_k=4, adaptive_draft=True,
     )
@@ -620,7 +621,7 @@ def test_engine_adaptive_draft_identical_and_ladder(model):
 
 def test_adaptive_draft_requires_speculative(model):
     with pytest.raises(ValueError, match="adaptive_draft"):
-        InferenceEngine(model, n_slots=2, max_len=64, adaptive_draft=True)
+        shared_engine(model, n_slots=2, max_len=64, adaptive_draft=True)
 
 
 def test_logprobs_plain_and_speculative_agree(model):
@@ -628,14 +629,14 @@ def test_logprobs_plain_and_speculative_agree(model):
     engine reports the SAME logprobs as plain serving (the verify pass
     scores with the target model — exactness extends to logprobs)."""
     prompt = [3, 1, 4, 1, 5, 9]
-    eng = InferenceEngine(model, n_slots=2, max_len=128)
+    eng = shared_engine(model, n_slots=2, max_len=128)
     r = eng.submit(prompt, max_new_tokens=10)
     eng.run_until_idle()
     assert len(r.out_logprobs) == len(r.out_tokens) == 10
     assert all(lp <= 0.0 for lp in r.out_logprobs)
 
-    spec = InferenceEngine(model, n_slots=2, max_len=128, speculative=True,
-                           draft_params=model.params, draft_k=4)
+    spec = shared_engine(model, n_slots=2, max_len=128, speculative=True,
+                         draft_params=model.params, draft_k=4)
     rs = spec.submit(prompt, max_new_tokens=10)
     spec.run_until_idle()
     assert rs.out_tokens == r.out_tokens
@@ -673,11 +674,11 @@ def test_logprobs_penalty_rows_match_across_modes(model):
     penalty-adjusted distribution — both engine modes must report THAT
     logprob (review finding, round 5)."""
     prompt = [3, 1, 4, 1, 5, 9]
-    plain = InferenceEngine(model, n_slots=2, max_len=128)
+    plain = shared_engine(model, n_slots=2, max_len=128)
     rp = plain.submit(prompt, max_new_tokens=8, repetition_penalty=1.3)
     plain.run_until_idle()
-    spec = InferenceEngine(model, n_slots=2, max_len=128, speculative=True,
-                           draft_params=model.params, draft_k=4)
+    spec = shared_engine(model, n_slots=2, max_len=128, speculative=True,
+                         draft_params=model.params, draft_k=4)
     rs = spec.submit(prompt, max_new_tokens=8, repetition_penalty=1.3)
     spec.run_until_idle()
     assert rs.out_tokens == rp.out_tokens
@@ -689,7 +690,7 @@ def test_top_logprobs_opt_in(model):
     """logprobs_top_k=N returns the N most likely alternatives per token,
     consistent with the chosen-token logprob; engines without the option
     pay nothing and return none."""
-    eng = InferenceEngine(model, n_slots=2, max_len=64, logprobs_top_k=3)
+    eng = shared_engine(model, n_slots=2, max_len=64, logprobs_top_k=3)
     r = eng.submit([3, 1, 4], max_new_tokens=5)
     eng.run_until_idle()
     assert len(r.out_top_logprobs) == 5
@@ -701,15 +702,15 @@ def test_top_logprobs_opt_in(model):
         assert best == tok
         assert abs(alt[tok] - lp) < 1e-3
 
-    plain = InferenceEngine(model, n_slots=2, max_len=64)
+    plain = shared_engine(model, n_slots=2, max_len=64)
     rp = plain.submit([3, 1, 4], max_new_tokens=5)
     plain.run_until_idle()
     assert rp.out_top_logprobs == []
     assert rp.out_tokens == r.out_tokens  # option does not change output
 
     with pytest.raises(NotImplementedError, match="logprobs_top_k"):
-        InferenceEngine(model, n_slots=2, max_len=64, logprobs_top_k=3,
-                        speculative=True, draft_params=model.params)
+        shared_engine(model, n_slots=2, max_len=64, logprobs_top_k=3,
+                      speculative=True, draft_params=model.params)
 
 
 def test_completions_top_logprobs_honors_requested_count(model):
@@ -867,7 +868,7 @@ def test_first_token_program_agrees_with_eager(model, vocab, dosample,
     from bigdl_tpu.serving.engine import _read_first_token
 
     n_top, slots, slot = 4, 3, 1
-    eng = InferenceEngine(model, n_slots=2, max_len=64, logprobs_top_k=n_top)
+    eng = shared_engine(model, n_slots=2, max_len=64, logprobs_top_k=n_top)
     rs = np.random.default_rng(vocab + 2 * dosample)
     for trial in range(3):
         logits = jnp.asarray(

@@ -27,11 +27,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from bench import cells  # noqa: E402
-from bigdl_tpu import kvwindow  # noqa: E402
+from bigdl_tpu import kvpaged, kvwindow  # noqa: E402
 from bigdl_tpu.api import TpuModel, optimize_model  # noqa: E402
 from bigdl_tpu.models import get_family, llama  # noqa: E402
 from bigdl_tpu.models.config import PRESETS, ModelConfig  # noqa: E402
 from bigdl_tpu.serving.engine import InferenceEngine  # noqa: E402
+from engines import shared_engine  # noqa: E402
 
 pytestmark = pytest.mark.core
 
@@ -93,10 +94,11 @@ def _f32(fam, cfg, p, toks, cache=None, mode="prefill"):
                        compute_dtype=jnp.float32)
 
 
-def _engine(model, **kw):
+def _engine(model, fresh=False, **kw):
+    """`fresh`: with programs of its own, for a test of what it traces."""
     kw = {"n_slots": 3, "max_len": 128, "paged": True, "page_size": PAGE,
           **kw}
-    return InferenceEngine(model, **kw)
+    return (InferenceEngine if fresh else shared_engine)(model, **kw)
 
 
 def test_preset_is_the_hf_config(fam):
@@ -341,7 +343,7 @@ def test_park_and_resume_carries_both_groups(model):
     assert eng.preemptions == 1 and eng.pages.slot_pages[1] == []
     assert eng.pages.win_pages[1] == []
     parked = eng._preempted[0].blob
-    assert isinstance(parked, kvwindow.HostGroups)
+    assert isinstance(parked, kvpaged.HostPages)
     n_g, n_w, pos = parked.k.shape[1], parked.kw.shape[1], \
         eng._preempted[0].pos
     assert n_g == -(-pos // PAGE)
@@ -359,9 +361,9 @@ def test_the_refusals_name_the_kind(model):
                      ("speculative", {"speculative": True}),
                      ("prefill_chunk_tokens", {"prefill_chunk_tokens": 16})):
         with pytest.raises(NotImplementedError, match=f"{what}.*{kind}"):
-            InferenceEngine(model, n_slots=1, max_len=64, paged=True, **kw)
+            shared_engine(model, n_slots=1, max_len=64, paged=True, **kw)
     with pytest.raises(NotImplementedError, match=f"{kind}.*paged=True"):
-        InferenceEngine(model, n_slots=1, max_len=64)
+        shared_engine(model, n_slots=1, max_len=64)
     with pytest.raises(NotImplementedError, match=f"quantize_kv.*{kind}"):
         model.generate([[1, 2, 3]], max_new_tokens=2, quantize_kv=True)
 
@@ -383,7 +385,7 @@ def test_spans_counters_and_routes(model, monkeypatch):
     monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
     tr = TraceRecorder(capacity=4096)
     with record_routes() as routes:
-        eng = _engine(model, n_slots=2, tracer=tr)
+        eng = _engine(model, fresh=True, n_slots=2, tracer=tr)
         eng.submit(_tokens(50, 41).tolist(), max_new_tokens=12)
         eng.submit(_tokens(7, 42).tolist(), max_new_tokens=4)
         eng.run_until_idle()

@@ -17,9 +17,9 @@ from bigdl_tpu.generate import GenerationConfig
 from bigdl_tpu.models import deepseek, get_family, llama
 from bigdl_tpu.models.config import PRESETS, ModelConfig
 from bigdl_tpu.obs.tracing import DECODE_TID, TraceRecorder, validate_nesting
-from bigdl_tpu.serving.engine import InferenceEngine
 from bigdl_tpu.serving.faults import FaultInjector
 from bigdl_tpu.serving.metrics import Metrics
+from engines import shared_engine
 
 pytestmark = pytest.mark.core
 
@@ -72,7 +72,7 @@ def _engine(models, kind, ahead=True, **kw):
     args = dict(n_slots=2, max_len=64, gen=GenerationConfig(
         eos_token_id=None), **opts)
     args.update(kw)
-    eng = InferenceEngine(models[key], **args)
+    eng = shared_engine(models[key], **args)
     if not ahead:
         eng._book_ahead = lambda unread: None
     return eng

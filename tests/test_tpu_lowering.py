@@ -455,10 +455,11 @@ def test_paged_prefill_handles_no_whole_pool(one_chip, monkeypatch, name,
         2, e["n_pages"], e["page_size"], cfg.num_key_value_heads,
         cfg.head_dim_, 1, eng.max_pages_per_row)))
     c = eng._paged_prefill.lower(
-        on_chip(weights.param_shapes(cfg, qtype)), pool.k, pool.v, None,
-        None, _sds((1, eng.max_pages_per_row), jnp.int32, one_chip),
+        on_chip(weights.param_shapes(cfg, qtype)), eng.kind.leaves(pool),
+        (_sds((1, eng.max_pages_per_row), jnp.int32, one_chip), None),
         _sds((1,), jnp.int32, one_chip), _sds((1, bucket), jnp.int32, one_chip),
-        _sds((), jnp.int32, one_chip), lora=None).compile()
+        _sds((), jnp.int32, one_chip), _sds((1,), jnp.int32, one_chip),
+        lora=None).compile()
     text = c.as_text()
     assert "flash_attention" in text
 
