@@ -1284,6 +1284,59 @@ def _smallthinker_tree(config: ModelConfig, get: Get, quant
     return positions, top
 
 
+def _laguna_tree(config: ModelConfig, get: Get, quant
+                 ) -> tuple[list, list, dict]:
+    """Laguna (poolside). Returns (the first period's per-layer dicts, one
+    list of per-layer dicts for each POSITION of the later periods, top),
+    `quant` applied as tensors stream in: layer `l` of a later period is
+    entry `l // P - 1` of position `l % P` (models/laguna.py runs the first
+    period by itself and scans over the others). The checkpoint's names are
+    ASSUMED, as far as the config lets them be known (there is no network
+    to read the source's modeling file): HF's usual `self_attn.{q,k,v,o}_proj`
+    and layer norms, the per-head gate as `self_attn.g_proj` [Hq, H], a
+    dense layer's `mlp.{gate,up,down}_proj`, a sparse layer's router
+    `mlp.gate` [E, H] (kept unpacked), experts
+    `mlp.experts.<e>.{gate,up,down}_proj` stacked into the `w_gate_e` /
+    `w_up_e` / `w_down_e` the grouped kernel takes, and the shared expert
+    `mlp.shared_expert.{gate,up,down}_proj` (the config's
+    `shared_expert_intermediate_size` is qwen2_moe's key)."""
+    from bigdl_tpu.models.laguna import period
+
+    def one(i: int) -> dict:
+        p = f"model.layers.{i}."
+        a, m = p + "self_attn.", p + "mlp."
+        d = {"attn_norm": get(p + "input_layernorm.weight"),
+             "mlp_norm": get(p + "post_attention_layernorm.weight"),
+             "wq": get(a + "q_proj.weight"), "wk": get(a + "k_proj.weight"),
+             "wv": get(a + "v_proj.weight"), "wo": get(a + "o_proj.weight")}
+        if config.attn_gate:
+            d["attn_gate"] = get(a + "g_proj.weight")
+        names = (("gate", "gate_proj"), ("up", "up_proj"),
+                 ("down", "down_proj"))
+        if i < config.first_k_dense_replace:
+            for ours, theirs in names:
+                d[f"w_{ours}"] = get(f"{m}{theirs}.weight")
+            return {k: quant(k, v) for k, v in d.items()}
+        d["router"] = get(m + "gate.weight")
+        for ours, theirs in names:
+            d[f"w_{ours}_e"] = np.stack([
+                np.asarray(get(f"{m}experts.{x}.{theirs}.weight"))
+                for x in range(config.num_experts)])
+            if config.shared_expert_intermediate_size:
+                d[f"w_{ours}_s"] = get(f"{m}shared_expert.{theirs}.weight")
+        return {k: quant(k, v) for k, v in d.items()}
+
+    P = period(config)
+    first = [one(j) for j in range(P)]
+    positions = [[one(i) for i in range(P + j, config.num_hidden_layers, P)]
+                 for j in range(P)]
+    top = {"embed": get("model.embed_tokens.weight"),
+           "final_norm": get("model.norm.weight")}
+    if not config.tie_word_embeddings:
+        top["lm_head"] = get("lm_head.weight")
+    return first, positions, top
+
+
 def layer_tensors(config: ModelConfig, i: int, get: Get) -> dict[str, np.ndarray]:
     fn = _FAMILY_LAYER.get(config.model_type, _llama_layer)
     return fn(config, i, get)
@@ -1395,6 +1448,15 @@ def params_from_state_dict(
     if config.model_type == "smallthinker":
         positions, top = _smallthinker_tree(config, get_tensor, maybe_quant)
         params = {"period": {str(j): stack_dicts(layers)
+                             for j, layers in enumerate(positions)}}
+        for k, v in top.items():
+            params[k] = maybe_quant(k, v)
+        return params
+
+    if config.model_type == "laguna":
+        first, positions, top = _laguna_tree(config, get_tensor, maybe_quant)
+        params = {"first": {str(j): d for j, d in enumerate(first)},
+                  "period": {str(j): stack_dicts(layers)
                              for j, layers in enumerate(positions)}}
         for k, v in top.items():
             params[k] = maybe_quant(k, v)

@@ -169,6 +169,10 @@ _LAZY_FAMILIES = {
     # window and full attention layers mixed by index, a rope in the window
     # layers only, two groups of pages in one slot (bigdl_tpu/kvwindow.py)
     "smallthinker": "bigdl_tpu.models.smallthinker",
+    # full and window attention layers that differ in query heads, rope and
+    # projections, a sigmoid gate a head, a dense first layer before the
+    # sigmoid-routed experts; smallthinker's two groups of pages
+    "laguna": "bigdl_tpu.models.laguna",
 }
 
 

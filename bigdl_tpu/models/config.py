@@ -148,6 +148,16 @@ class ModelConfig:
     # index (beside `sliding_layers`, which layers take the window); a
     # layer with 0 has no position encoding at all
     rope_layers: Optional[tuple] = None
+    # laguna (models/laguna.py): attention layers that differ in SHAPE by
+    # kind. Query heads of every layer, by index (one count a kind:
+    # `num_attention_heads` is the full layers'); a sigmoid gate on every
+    # head's output, one scalar a head and token ("per_head"); the share of
+    # a head the WINDOW layers' rope turns (`rope_local_theta` is its base;
+    # `partial_rotary_factor`, `rope_theta` and `rope_scaling` are the full
+    # layers')
+    heads_per_layer: Optional[tuple] = None
+    attn_gate: Optional[str] = None
+    rope_local_partial_rotary_factor: Optional[float] = None
 
     def __post_init__(self):
         if self.attention_kind not in ("softmax", "power_retention"):
@@ -179,7 +189,8 @@ class ModelConfig:
         # (save_low_bit -> load_low_bit) and must re-become tuples or the
         # config stops hashing as a static jit argument
         for f in ("sliding_layers", "cross_attention_layers",
-                  "mrope_section", "layer_types", "rope_layers"):
+                  "mrope_section", "layer_types", "rope_layers",
+                  "heads_per_layer"):
             v = getattr(self, f)
             if isinstance(v, list):
                 object.__setattr__(self, f, tuple(v))
@@ -654,6 +665,88 @@ def _hf_smallthinker(hf, kw):
     kw.setdefault("tie_word_embeddings", False)
 
 
+def _hf_laguna(hf, kw):
+    """Laguna (poolside, e.g. Laguna-XS.2): full and window attention mixed
+    by `layer_types`, and the two kinds differ in SHAPE: their query heads
+    (`num_attention_heads_per_layer`), their rope (`rope_parameters` by
+    kind: YaRN over part of the head on the full layers, a plain rope on
+    the window layers) while every head's output passes a sigmoid gate
+    (`gating`); leading dense layers by `mlp_layer_types`, then
+    `num_experts` sigmoid-routed experts (top-k renormalised, times
+    `moe_routed_scaling_factor`, no selection bias, no groups) and one
+    ungated shared expert. What config.json has no key for (the gate's
+    form, the rope convention, no q/k norm) is the model type's, written
+    in models/laguna.py."""
+    L = hf["num_hidden_layers"]
+    kinds = tuple(hf.get("layer_types") or ("full_attention",) * L)
+    heads = tuple(int(h) for h in hf.get("num_attention_heads_per_layer")
+                  or (hf["num_attention_heads"],) * L)
+    mlp = tuple(hf.get("mlp_layer_types") or tuple(
+        "dense" if l in (hf.get("mlp_only_layers") or ()) else "sparse"
+        for l in range(L)))
+    if min(len(kinds), len(heads), len(mlp)) < L or set(kinds) - {
+            "full_attention", "sliding_attention"}:
+        raise ValueError(
+            f"layer_types, num_attention_heads_per_layer and mlp_layer_types "
+            f"must name {L} layers ('full_attention' | 'sliding_attention'); "
+            f"got {len(kinds)}, {len(heads)} and {len(mlp)} entries")
+    # a config cut in depth may keep its per-layer lists: the first L count
+    kinds, heads, mlp = kinds[:L], heads[:L], mlp[:L]
+    sliding = tuple(int(t == "sliding_attention") for t in kinds)
+    for s in (0, 1):
+        if len({h for h, t in zip(heads, sliding) if t == s}) > 1:
+            raise NotImplementedError(
+                "laguna with query heads that differ inside one kind of "
+                "layer: the weights are stacked by kind")
+    n_dense = next((l for l, t in enumerate(mlp) if t != "dense"), L)
+    if set(mlp[n_dense:]) - {"sparse"}:
+        raise NotImplementedError(
+            f"laguna with a dense feed-forward after a sparse one "
+            f"(mlp_layer_types {mlp}): dense layers lead")
+    if any(sliding) and not hf.get("sliding_window"):
+        raise ValueError("layer_types names window layers but "
+                         "sliding_window is not set")
+    gating = hf.get("gating")
+    if gating not in (None, False, True, "per-head", "per_head"):
+        raise NotImplementedError(
+            f"laguna with gating {gating!r}: the gate is written per head")
+    rp = hf.get("rope_parameters") or {}
+    full = dict(rp.get("full_attention") or {})
+    local = dict(rp.get("sliding_attention") or {})
+    if local.get("rope_type", "default") != "default":
+        raise NotImplementedError(
+            f"laguna with a scaled rope on the window layers ({local}): "
+            "theirs is written plain")
+    kw["rope_theta"] = float(full.pop("rope_theta", 10000.0))
+    kw["partial_rotary_factor"] = full.pop(
+        "partial_rotary_factor", hf.get("partial_rotary_factor", 1.0))
+    kw["rope_scaling"] = (
+        full if full.get("rope_type", "default") != "default" else None)
+    kw["rope_local_theta"] = float(local.get("rope_theta", 10000.0))
+    kw["rope_local_partial_rotary_factor"] = local.get(
+        "partial_rotary_factor", 1.0)
+    kw["sliding_window"] = hf.get("sliding_window") if any(sliding) else None
+    kw["sliding_layers"], kw["heads_per_layer"] = sliding, heads
+    kw["attn_gate"] = "per_head" if gating else None
+    kw["first_k_dense_replace"] = n_dense
+    kw["num_experts"] = hf.get("num_experts") or 0
+    kw["num_experts_per_tok"] = hf.get("num_experts_per_tok") or 2
+    kw["moe_intermediate_size"] = hf.get("moe_intermediate_size")
+    kw["shared_expert_intermediate_size"] = hf.get(
+        "shared_expert_intermediate_size")
+    # `deepseek._router`'s sigmoid branch: one group, no selection bias
+    kw["scoring_func"], kw["topk_method"] = "sigmoid", "noaux_tc"
+    kw["n_group"] = kw["topk_group"] = 1
+    kw["norm_topk_prob"] = bool(hf.get("norm_topk_prob", True))
+    kw["routed_scaling_factor"] = hf.get("moe_routed_scaling_factor", 1.0)
+    if hf.get("moe_router_logit_softcapping"):
+        raise NotImplementedError("laguna with a softcapped router")
+    if hf.get("moe_apply_router_weight_on_input"):
+        raise NotImplementedError(
+            "laguna with the router's weight on the experts' input")
+    kw.setdefault("tie_word_embeddings", False)
+
+
 def _hf_granitemoehybrid(hf, kw):
     """Granite 4.0-H (HF modeling_granitemoehybrid): Mamba-2 and attention
     layers by `layer_types`, every layer followed by top-k routed experts
@@ -1042,6 +1135,7 @@ _HF_BUILDERS = {
     "brumby": _hf_brumby,
     "granitemoehybrid": _hf_granitemoehybrid,
     "smallthinker": _hf_smallthinker,
+    "laguna": _hf_laguna,
     "qwen3_moe": _hf_qwen3_moe,
     "phi": _hf_phi,
     "cohere": _hf_cohere,
@@ -1137,5 +1231,30 @@ PRESETS: dict[str, ModelConfig] = {
         sliding_layers=(0, 1, 1, 1) * 2, rope_layers=(0, 1, 1, 1) * 2,
         num_experts=8, num_experts_per_tok=3, moe_intermediate_size=32,
         norm_topk_prob=True, hidden_act="relu",
+    ),
+    # Laguna's shape at toy sizes: two periods of one full layer (6 gated
+    # query heads, YaRN over half the head) and three window layers (8, a
+    # plain rope) over 2 KV heads, a dense first layer, then 16
+    # sigmoid-routed experts top-4 and a shared one, a window shorter than
+    # the tests' sequences (tests/test_laguna.py holds it to its
+    # config.json form; the dense combine, so that nothing is dropped)
+    "tiny-laguna": ModelConfig(
+        model_type="laguna", vocab_size=256, hidden_size=64,
+        intermediate_size=128, num_hidden_layers=8, num_attention_heads=6,
+        num_key_value_heads=2, head_dim=32, rms_norm_eps=1e-6,
+        max_position_embeddings=4096, sliding_window=32,
+        sliding_layers=(0, 1, 1, 1) * 2, heads_per_layer=(6, 8, 8, 8) * 2,
+        attn_gate="per_head", rope_theta=500000.0,
+        rope_scaling={"rope_type": "yarn", "factor": 16.0,
+                      "original_max_position_embeddings": 64,
+                      "beta_fast": 8.0, "beta_slow": 1.0,
+                      "attention_factor": 1.2772588722239782},
+        partial_rotary_factor=0.5, rope_local_theta=10000.0,
+        rope_local_partial_rotary_factor=1.0, first_k_dense_replace=1,
+        num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+        shared_expert_intermediate_size=32, scoring_func="sigmoid",
+        topk_method="noaux_tc", n_group=1, topk_group=1,
+        norm_topk_prob=True, routed_scaling_factor=2.5,
+        moe_dispatch="dense",
     ),
 }

@@ -310,8 +310,10 @@ def _router(config: ModelConfig, xc, p):
         topv, topi = jax.lax.top_k(scores, k)
     elif G == 1:
         # one group holds every expert: nothing is limited, and the bias
-        # (noaux_tc) still moves the choice and not the weights
-        choice = scores + p["e_bias"][None] if method == "noaux_tc" else scores
+        # (noaux_tc) still moves the choice and not the weights. A family
+        # without a selection bias (laguna) has no `e_bias` leaf
+        biased = method == "noaux_tc" and "e_bias" in p
+        choice = scores + p["e_bias"][None] if biased else scores
         _, topi = jax.lax.top_k(choice, k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)
     else:

@@ -100,12 +100,12 @@ def moe_block_m(n_tokens: int, k_max: int) -> int:
 
 
 def moe_n_tiles(n_tokens: int, k: int, n_experts: int, block_m: int) -> int:
-    """Static bound on the row tiles the sorted layout can need: every
-    expert pads its group to a multiple of `block_m`, and no expert gets
-    more than `n_tokens` rows (top-k picks distinct experts)."""
+    """Static bound on the row tiles the sorted layout can need: an expert
+    pads its group to a multiple of `block_m` and gets at most `n_tokens`
+    rows (top-k picks distinct experts); a tile holds an assignment."""
     per_expert = -(-n_tokens // block_m)
     padded = (n_tokens * k + n_experts * (block_m - 1)) // block_m
-    return max(1, min(n_experts * per_expert, padded))
+    return max(1, min(n_experts * per_expert, padded, n_tokens * k))
 
 
 def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int):

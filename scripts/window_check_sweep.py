@@ -33,6 +33,11 @@ put in its place), a seeded prompt, greedy, and then
 
     chiprun -- python3 scripts/window_check_sweep.py --first 2147485201 --n 3
 
+`--cell laguna-xs.2.mixedlen-closed` (PR 47) is the same walk for the other
+family on two groups of pages, whose window is 512: `--prompts 1024 4136 4136
+8192 --new 9 9 80 9` there, and `--prompts 250 --new 9` for the entry's own
+check length (bench/configs/laguna-xs.2-int4.json quotes both tables).
+
 Prints one line a seed and length and a summary; exit code 0 whatever the
 readings say. `--rehearse`: the files' rehearsal sizes on the CPU."""
 
@@ -82,7 +87,10 @@ def main() -> int:
     hf, qtype = cells.as_run(cell.config), cell.config["bench"]["qtype"]
     cfg = ModelConfig.from_hf_config(hf)
     ref = cell.reference()
-    L, k = hf["num_hidden_layers"], hf["moe_num_active_primary_experts"]
+    # the record's shape: a reference whose sparse layers are not all the
+    # layers says so (bench/reference/laguna.py), SmallThinker's is its keys'
+    L, k = (ref.choice_shape(hf) if hasattr(ref, "choice_shape") else
+            (hf["num_hidden_layers"], hf["moe_num_active_primary_experts"]))
     cases = list(zip(args.prompts, args.new))
 
     def fp8(x):

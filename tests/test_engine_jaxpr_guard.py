@@ -22,6 +22,10 @@ PR 41 adds a tiny SmallThinker with a pin of its own: a family of its own
 they counted; its own count is a scan over the periods of its layouts with
 the period's four layers as the body, so it grows with the PERIOD and not
 with the depth (two periods here count what six would).
+PR 47 adds a tiny Laguna the same way (models/laguna.py, loaded on first
+use): its first period is run by itself, because layer 0's feed-forward is
+dense, and the other periods are the scan's body, so it counts two periods'
+layers whatever the depth; the six above count what they counted.
 
     JAX_PLATFORMS=cpu python tests/test_engine_jaxpr_guard.py
 """
@@ -60,6 +64,25 @@ MODELS = {
         moe_num_primary_experts=8, moe_num_active_primary_experts=3,
         sliding_window_size=32, sliding_window_layout=[0, 1, 1, 1] * 2,
         rope_layout=[0, 1, 1, 1] * 2),
+    "laguna": dict(
+        model_type="laguna", hidden_size=128, intermediate_size=256,
+        head_dim=32, num_attention_heads=6, num_key_value_heads=2,
+        num_hidden_layers=8, vocab_size=512, rms_norm_eps=1e-6,
+        max_position_embeddings=2048, tie_word_embeddings=False,
+        num_experts=16, num_experts_per_tok=4, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128, gating=True, sliding_window=32,
+        moe_routed_scaling_factor=2.5, partial_rotary_factor=0.5,
+        layer_types=(["full_attention"] + ["sliding_attention"] * 3) * 2,
+        mlp_layer_types=["dense"] + ["sparse"] * 7,
+        num_attention_heads_per_layer=[6, 8, 8, 8] * 2,
+        rope_parameters={
+            "full_attention": {
+                "rope_theta": 500000, "rope_type": "yarn", "factor": 16,
+                "original_max_position_embeddings": 64, "beta_slow": 1,
+                "beta_fast": 8, "attention_factor": 1.2772588722239782,
+                "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                                  "partial_rotary_factor": 1}}),
 }
 
 # (engine_decode, engine_paged_prefill) on the parent of PR 38; the paged
@@ -71,6 +94,8 @@ PINNED = {
     "mixtral": (505, 399),
     "qwen2": (462, 359),
     "smallthinker": (1404, 1244),  # PR 41's own: a four-layer scan body
+    # PR 47's own: the first period's four layers, then a four-layer body
+    "laguna": (3316, 2904),
 }
 
 

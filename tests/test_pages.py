@@ -338,6 +338,35 @@ def test_a_window_page_is_freed_in_the_step_that_passes_it():
     assert t.page_leaks() == 0 and t.pages_in_use() == (0, 0)
 
 
+@pytest.mark.parametrize("n_prompt", [32, 45, 64, 96])
+def test_a_window_of_eight_pages_frees_a_page_every_page_tokens(n_prompt):
+    """A window of eight pages under prompts that are at least the window
+    (Laguna's cell: 512 over pages of 64, every prompt 512 or more): the
+    slot never holds more than W // P + 2 window pages, whatever the
+    prompt, and from its first decoded tokens on one goes back every PAGE
+    tokens, so the pool of `n_slots * (W // P + 2) + 1` never runs dry."""
+    W = 8 * PAGE
+    t = groups(n_pages=65, n_slots=1, rows=64, max_len=256, window=W)
+    assert t.wpool.n_pages == W // PAGE + 2 + 1
+    admit2(t, 0, seq(n_prompt))
+    assert t.win_first[0] == (n_prompt - W + 1) // PAGE
+    most, freed_at = len(t.win_pages[0]), []
+    for _ in range(60):
+        while t.short(0, 2):  # the engine books one step ahead
+            t.extend(0, t.alloc())
+        before = t.window_pages_freed
+        t.advance(0)
+        freed_at += [t.pos[0]] * (t.window_pages_freed - before)
+        most = max(most, len(t.win_pages[0]))
+        assert t.page_leaks() == 0
+    assert most <= W // PAGE + 2
+    # the first within PAGE tokens of the prompt's end, then one a page
+    assert n_prompt < freed_at[0] <= n_prompt + PAGE
+    assert set(np.diff(freed_at)) == {PAGE} and len(freed_at) == 60 // PAGE
+    t.release(0)
+    assert t.page_leaks() == 0 and t.pages_in_use() == (0, 0)
+
+
 def test_freed_pages_return_to_the_pool_and_serve_another_slot():
     t = groups(n_slots=2)
     cap = t.wpool.n_pages - 1

@@ -194,6 +194,47 @@ def test_moe_qmatmul_compiles_at_granites_and_smallthinkers_shapes(
     assert calls == {"loop": 0, "staged": 1}, calls
 
 
+@pytest.mark.parametrize("rows", ("decode", "prefill"))
+@pytest.mark.parametrize("name", ("gate_up", "down"))
+def test_moe_qmatmul_compiles_at_256_experts_of_width_512(one_chip, name,
+                                                          rows):
+    """Laguna-XS.2's expert calls (PR 47): E = 256, four times the largest
+    stack the kernel had taken, top-8, width 512. A decode step's 128
+    assignments leave most of the 256 tiles of the plan empty; both calls
+    take the word path."""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    E, k = 256, 8
+    K, O, act = (2048, 512, "silu") if name == "gate_up" else (512, 2048,
+                                                               None)
+    N = 16 if rows == "decode" else 8192
+    bm = mq.moe_block_m(N, 2048)
+    n_tiles = mq.moe_n_tiles(N, k, E, bm)
+    assert n_tiles == (128 if rows == "decode" else 256 + 255)
+
+    def f(x, te, n_used, layer, *fields):
+        ws = [QTensor(qtype="sym_int4", data=fields[2 * i],
+                      scales=fields[2 * i + 1]) for i in range(len(fields) // 2)]
+        assert mq.call_plan(ws) == ("words x1" if act
+                                    else "words x1 of 4 tiles")
+        return mq.moe_qmatmul(x, ws if act else ws[0], te, n_used, bm,
+                              act=act, layer=layer, interpret=False,
+                              out_dtype=jnp.bfloat16 if act else jnp.float32)
+
+    fields = []
+    for _ in range(2 if act else 1):
+        fields += [_sds((3, E, O, K // 2), jnp.uint8, one_chip),
+                   _sds((E, O, K // 32), jnp.float16, one_chip)]
+    c = jax.jit(f).lower(
+        _sds((n_tiles * bm, K), jnp.bfloat16, one_chip),
+        _sds((n_tiles,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        *fields,
+    ).compile()
+    assert "moe_qmatmul" in c.as_text()
+
+
 def _mosaic_bodies(lowered_text):
     """The Mosaic modules of a lowered program's kernels, printed without
     source locations (the serialized bodies carry file lines)."""
@@ -490,6 +531,12 @@ _FLASH = {
     "glm-4.7-flash expanded, D 256": (4096, 5120, 20, 20, 256, None, None,
                                       False),
     "smallthinker window layer": (8192, 9216, 28, 4, 128, 4096, None, False),
+    # PR 47: two head counts over the same 8 KV heads in one program, a
+    # group of 6 unbounded and a group of 8 under a window of one K block
+    "laguna full layer, group of 6": (8192, 9216, 48, 8, 128, None, None,
+                                      False),
+    "laguna window layer, group of 8": (8192, 9216, 64, 8, 128, 512, None,
+                                        False),
     "gemma2 softcap, D 256": (1024, 1024, 16, 8, 256, None, 50.0, False),
     "head of 64": (512, 1024, 8, 8, 64, None, None, False),
     "fp8 cache": (1024, 2048, 32, 8, 128, None, None, True),
