@@ -61,6 +61,17 @@ class TpuModel:
     # block-quantized ring for comm_qtype=...)
     comm: Optional[Any] = None
 
+    def __post_init__(self):
+        # a model is what programs serve from: every packed weight a
+        # kernel reads gets its scales as the operand that kernel reads in
+        # place, once (llama.prepare_kernel_scales; the tree as it is
+        # where no kernel runs). to_mesh() drops them again: shards keep
+        # the float16 fields.
+        if self.mesh is None:
+            from bigdl_tpu.models.llama import prepare_kernel_scales
+
+            self.params = prepare_kernel_scales(self.config, self.params)
+
     @property
     def family(self):
         return get_family(self.config.model_type)
@@ -164,7 +175,9 @@ class TpuModel:
         from bigdl_tpu.parallel import make_mesh, shard_params
         from bigdl_tpu.parallel.mesh import mesh_shape_for
         from bigdl_tpu.parallel.sharding import param_specs
+        from bigdl_tpu.quant.qtensor import without_scale_bits
 
+        self.params = without_scale_bits(self.params)
         if mesh is None:
             n = len(jax.devices())
             if pp > 1:

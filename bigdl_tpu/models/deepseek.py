@@ -64,6 +64,7 @@ from bigdl_tpu.kvcache import _scatter_rows
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.ops import linear, rms_norm
+from bigdl_tpu.ops.linear import stacks_in
 from bigdl_tpu.ops.rope import make_inv_freq_scaled, rope_cos_sin
 
 Params = dict[str, Any]
@@ -396,16 +397,13 @@ def _keep_codes_out(group: Params) -> tuple[Params, dict]:
     call is first copied whole: 354 MB of expert stacks a layer of
     GLM-4.7-Flash, hit or not. The rule is llama.forward's: weights the
     kernels' shape guard refuses and fp8 codes keep their slices."""
-    from bigdl_tpu.ops.linear import grouped_route
+    from bigdl_tpu.ops.linear import grouped_route, stacks_out
 
     names = [n for n in _LINEAR_STACKS
              if n in group and grouped_route(group[n]) is None]
     if "w_up_e" in group and llama.moe_grouped_why_not(group, False) is None:
         names += [n for n in llama._EXPERT_STACKS if n in group]
-    codes = {n: group[n].data for n in names
-             if not group[n].spec.storage.startswith("fp8")}
-    return ({n: dataclasses.replace(w, data=None) if n in codes else w
-             for n, w in group.items()}, codes)
+    return stacks_out(group, names)
 
 
 def _expanded_attention(q, k, v, q_slots, start, scale, compute_dtype,
@@ -624,8 +622,7 @@ def forward(
             p, dc = xs
             # the unsliced codes go back in, with the index that finds
             # this layer in them
-            p = {**p, **{n: dataclasses.replace(p[n], data=d)
-                         for n, d in codes.items()}}
+            p = stacks_in(p, codes)
 
             def proj(x, p, name):
                 return linear(x, p[name], None, compute_dtype,

@@ -56,6 +56,7 @@ from bigdl_tpu import kvhybrid, kvpaged
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.ops import linear, rms_norm
+from bigdl_tpu.ops.linear import stacks_in
 
 Params = dict[str, Any]
 
@@ -230,16 +231,13 @@ def _keep_codes_out(group: Params, kind: str) -> tuple[Params, dict]:
     """`deepseek._keep_codes_out` for this family's groups: the packed
     codes of every weight that goes to a kernel taken out of what a scan
     slices; the body hands the whole stack back with the layer's index."""
-    from bigdl_tpu.ops.linear import grouped_route
+    from bigdl_tpu.ops.linear import grouped_route, stacks_out
 
     names = [n for n in _MIXER_STACKS[kind] + _SHARED_STACKS
              if n in group and grouped_route(group[n]) is None]
     if "w_up_e" in group and llama.moe_grouped_why_not(group, False) is None:
         names += [n for n in llama._EXPERT_STACKS if n in group]
-    codes = {n: group[n].data for n in names
-             if not group[n].spec.storage.startswith("fp8")}
-    return ({n: dataclasses.replace(w, data=None) if n in codes else w
-             for n, w in group.items()}, codes)
+    return stacks_out(group, names)
 
 
 def forward(
@@ -333,8 +331,7 @@ def forward(
     def layer(kind, hidden, c, p, codes, idx, at):
         """One decoder layer: number `idx` of its run (which finds it in
         the unsliced codes) and number `at` of its kind (in the cache)."""
-        p = {**p, **{n: dataclasses.replace(p[n], data=d)
-                     for n, d in codes.items()}}
+        p = stacks_in(p, codes)
 
         def proj(x, p, name):
             return linear(x, p[name], None, compute_dtype,

@@ -52,7 +52,6 @@ form from `init_paged_cache` (its kind, `kvwindow.CACHE_KIND`);
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Any, Optional
 
 import jax
@@ -62,6 +61,7 @@ from bigdl_tpu import kvcache, kvpaged, kvwindow
 from bigdl_tpu.models import deepseek, llama
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.ops import linear, rms_norm
+from bigdl_tpu.ops.linear import stacks_in
 
 Params = dict[str, Any]
 
@@ -222,7 +222,7 @@ def _keep_codes_out(stack: Params) -> tuple[Params, dict]:
     """`smallthinker._keep_codes_out` for a position's stack: the packed
     codes of every weight that goes to a kernel taken out of what the scan
     slices; the body hands the whole stack back with the period's index."""
-    from bigdl_tpu.ops.linear import grouped_route
+    from bigdl_tpu.ops.linear import grouped_route, stacks_out
     from bigdl_tpu.quant import QTensor
 
     names = [n for n in _LINEAR_STACKS
@@ -231,10 +231,7 @@ def _keep_codes_out(stack: Params) -> tuple[Params, dict]:
     if isinstance(stack.get("w_up_e"), QTensor) \
             and llama.moe_grouped_why_not(stack, False) is None:
         names += list(llama._EXPERT_STACKS)
-    codes = {n: stack[n].data for n in names
-             if not stack[n].spec.storage.startswith("fp8")}
-    return ({n: dataclasses.replace(w, data=None) if n in codes else w
-             for n, w in stack.items()}, codes)
+    return stacks_out(stack, names)
 
 
 def _rope_tables(config: ModelConfig, cache, T: int):
@@ -331,8 +328,7 @@ def forward(
         """The layer at position `j` of a period: `i` the period's index in
         the stacks `codes` come from (None: `p` is the layer's own), `idx`
         the layer's index in its group of pages."""
-        p = {**p, **{n: dataclasses.replace(p[n], data=d)
-                     for n, d in codes.items()}}
+        p = stacks_in(p, codes)
         Hq, window = heads[j], W if sliding[j] else None
 
         def proj(x, name):

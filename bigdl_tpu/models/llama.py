@@ -38,7 +38,9 @@ from bigdl_tpu import kvcache
 from bigdl_tpu.kvcache import KVCache
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.ops import apply_rotary_emb, attention, linear, rms_norm, rope_cos_sin
-from bigdl_tpu.ops.linear import col_parallel_linear, row_parallel_linear
+from bigdl_tpu.ops.linear import (
+    col_parallel_linear, row_parallel_linear, stacks_in,
+)
 from bigdl_tpu.ops.norms import layer_norm
 from bigdl_tpu.ops.rope import alibi_slopes, make_inv_freq_scaled
 from bigdl_tpu.quant import QTensor, quantize
@@ -573,6 +575,72 @@ def _grouped_plan(config: ModelConfig, p: Params) -> str:
     return f"{first}, down {mq.call_plan(p['w_down_e'])}"
 
 
+def _gate_up_fused(config: ModelConfig) -> bool:
+    """Is a layer's gate / up ONE grouped call (the activation applied
+    in-kernel), as `_moe_dispatch_grouped` makes it?"""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+
+    return config.gated_mlp and config.hidden_act in mq.FUSED_ACTS
+
+
+def _grouped_calls(config: ModelConfig, p: Params) -> list:
+    """The stacks of each grouped call `_moe_dispatch_grouped` makes for
+    this layer: the fused gate / up pair, or each stack by itself."""
+    if _gate_up_fused(config):
+        return [(p["w_gate_e"], p["w_up_e"]), (p["w_down_e"],)]
+    return [(p[n],) for n in _EXPERT_STACKS if n in p]
+
+
+def _reads_bits(config: ModelConfig, p: Params) -> bool:
+    """Does every grouped call of this layer read prepared scale bits?"""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.ops.pallas.qmatmul import prepared_bits
+
+    return all(prepared_bits(w, mq.bits_layout(ws)) is not None
+               for ws in _grouped_calls(config, p) for w in ws)
+
+
+def prepare_kernel_scales(config: ModelConfig, params: Params) -> Params:
+    """`params` as a program that serves from it holds it: every packed
+    weight a kernel reads carries its scales a second time as the operand
+    that kernel reads in place (`linear.prepare_scale_bits`: uint16 bits,
+    the output rows on lanes), laid out here, ONCE, by one jitted program.
+    An expert stack (`_EXPERT_STACKS`, in whichever group of whichever
+    family's tree) is laid out for the grouped call it is part of, every
+    other packed weight for `linear`; the float16 fields stay for the XLA
+    routes, the references and whoever walks the tree, and a tree no
+    kernel reads (a CPU without the interpreter, `BIGDL_TPU_PALLAS=0`)
+    comes back as it is. The forwards find the bits by looking
+    (`linear.stacks_out`); a tree that was not prepared runs as before."""
+    from bigdl_tpu.ops.linear import prepare_scale_bits
+    from bigdl_tpu.ops.pallas import why_not_pallas
+    from bigdl_tpu.quant.qtensor import ARRAY_FIELDS
+
+    if why_not_pallas() is not None:
+        return params
+    is_q = lambda x: isinstance(x, QTensor)  # noqa: E731
+    paired = _gate_up_fused(config)
+    stacks = {"w_gate_e": 1 + paired, "w_up_e": 1 + paired, "w_down_e": 1}
+
+    def bits_of(tree):
+        """A QTensor of the new fields alone for each of the tree's: only
+        they leave the program, the tree's own arrays are not copied."""
+        def one(path, w):
+            if not is_q(w):
+                return None
+            w = prepare_scale_bits(
+                w, stacks.get(getattr(path[-1], "key", None)))
+            return dataclasses.replace(w, **dict.fromkeys(ARRAY_FIELDS))
+
+        return jax.tree_util.tree_map_with_path(one, tree, is_leaf=is_q)
+
+    return jax.tree.map(
+        lambda w, b: dataclasses.replace(
+            w, scale_bits=b.scale_bits, min_bits=b.min_bits,
+            bits_layout=b.bits_layout) if is_q(w) else w,
+        params, jax.jit(bits_of)(params), is_leaf=is_q)
+
+
 def moe_grouped_why_not(p: Params, differentiable: bool) -> Optional[str]:
     """None when a layer's experts take `_moe_dispatch_grouped`: packed
     stacks the kernels can tile, the kernels in use (a TPU, or the
@@ -606,7 +674,8 @@ def _moe_dispatch(
     if why is None:
         routes.note("moe", "pallas:grouped",
                     f"{p['w_up_e'].qtype} {detail} dropless: "
-                    f"{_grouped_plan(config, p)}")
+                    f"{_grouped_plan(config, p)} "
+                    f"scales:{'stack' if _reads_bits(config, p) else 'slice'}")
         return _moe_dispatch_grouped(config, xc, p, compute_dtype, topv,
                                      topi, layer)
     assert layer is None, "unsliced expert codes are for the grouped path"
@@ -901,34 +970,34 @@ def forward(
     lora_scale = lora["scale"] if lora is not None else None
     tp_sharded = comm is not None and comm.axis_size > 1
 
-    # The packed codes of every per-layer weight that goes to a kernel
-    # stay OUT of the scan's per-layer slices: the kernel reads its blocks
-    # from the whole stack by layer index ([L, O, C] through `linear`'s
-    # `layer`, the experts' [L, E, O, C] through the grouped kernel's),
-    # where a slice handed to a Mosaic call is first copied whole, every
-    # layer of every step (and every expert, hit or not). Who keeps today's
-    # slices, each by what `forward` sees in its inputs: adapters (the
-    # backward's dx kernel takes one layer's weight, and the XLA expert
-    # formulations differentiate), projections that run per shard under
-    # tensor parallelism, a weight the kernels' shape guard refuses (the
-    # XLA dequant fuses its own slice), and fp8 codes, which reach a kernel
-    # through a bitcast that would copy the whole stack instead. Scales
-    # stay sliced: a sixteenth of the bytes, and the uint16 view of a whole
-    # float16 stack is itself a copy XLA materialises.
+    # The whole-stack operands of every per-layer weight that goes to a
+    # kernel stay OUT of the scan's per-layer slices (`linear.stacks_out`):
+    # the packed codes and, where the tree was prepared for serving
+    # (`prepare_kernel_scales`), the scales' bits. The kernel reads its
+    # blocks from the whole stack by layer index ([L, O, C] through
+    # `linear`'s `layer`, the experts' [L, E, O, C] through the grouped
+    # kernel's), where a slice handed to a Mosaic call is first copied
+    # whole, every layer of every step (and every expert, hit or not). Who
+    # keeps today's slices, each by what `forward` sees in its inputs:
+    # adapters (the backward's dx kernel takes one layer's weight, and the
+    # XLA expert formulations differentiate), projections that run per
+    # shard under tensor parallelism, a weight the kernels' shape guard
+    # refuses (the XLA dequant fuses its own slice), and fp8 codes, which
+    # reach a kernel through a bitcast that would copy the whole stack
+    # instead. The float16 scales stay sliced for whoever else reads them
+    # (a tree nobody prepared, the XLA routes, a backward); a call that
+    # reads the prepared bits leaves that slice dead.
     layers = params["layers"]
     stack_codes = {}
     if lora is None:
-        from bigdl_tpu.ops.linear import grouped_route
+        from bigdl_tpu.ops.linear import grouped_route, stacks_out
 
         names = [n for n in _LINEAR_STACKS if n in layers
                  and not (tp_sharded and n in _TP_PARALLEL)
                  and grouped_route(layers[n]) is None]
         if config.is_moe and moe_grouped_why_not(layers, False) is None:
             names += [n for n in _EXPERT_STACKS if n in layers]
-        stack_codes = {n: layers[n].data for n in names
-                       if not layers[n].spec.storage.startswith("fp8")}
-        layers = {n: dataclasses.replace(w, data=None) if n in stack_codes
-                  else w for n, w in layers.items()}
+        layers, stack_codes = stacks_out(layers, names)
     moe_stacked = any(n in stack_codes for n in _EXPERT_STACKS)
 
     def layer_proj(x, p, lp, wname, bname=None, idx=None):
@@ -966,10 +1035,9 @@ def forward(
     def body(carry, xs):
         hidden, c, idx = carry
         p, lp = xs if lora is not None else (xs, None)
-        # the unsliced codes go back in, with the index that finds this
+        # the unsliced stacks go back in, with the index that finds this
         # layer in them
-        p = {**p, **{n: dataclasses.replace(p[n], data=d)
-                     for n, d in stack_codes.items()}}
+        p = stacks_in(p, stack_codes)
         proj = functools.partial(layer_proj, idx=idx)
 
         with jax.named_scope("norm_rope"):

@@ -36,7 +36,6 @@ dense form from `init_cache` (every position kept, the window a mask).
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Any, Optional
 
 import jax
@@ -46,6 +45,7 @@ from bigdl_tpu import kvcache, kvpaged, kvwindow
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.ops import linear, rms_norm
+from bigdl_tpu.ops.linear import stacks_in
 
 Params = dict[str, Any]
 
@@ -172,7 +172,7 @@ def _keep_codes_out(stack: Params) -> tuple[Params, dict]:
     """`granitemoehybrid._keep_codes_out` for a position's stack: the packed
     codes of every weight that goes to a kernel taken out of what the scan
     slices; the body hands the whole stack back with the period's index."""
-    from bigdl_tpu.ops.linear import grouped_route
+    from bigdl_tpu.ops.linear import grouped_route, stacks_out
     from bigdl_tpu.quant import QTensor
 
     names = [n for n in _ATTN_STACKS
@@ -181,10 +181,7 @@ def _keep_codes_out(stack: Params) -> tuple[Params, dict]:
     if isinstance(stack["w_up_e"], QTensor) \
             and llama.moe_grouped_why_not(stack, False) is None:
         names += list(llama._EXPERT_STACKS)
-    codes = {n: stack[n].data for n in names
-             if not stack[n].spec.storage.startswith("fp8")}
-    return ({n: dataclasses.replace(w, data=None) if n in codes else w
-             for n, w in stack.items()}, codes)
+    return stacks_out(stack, names)
 
 
 def forward(
@@ -263,8 +260,7 @@ def forward(
 
     def layer(j, hidden, c, p, codes, i):
         """Layer `j` of period `i`."""
-        p = {**p, **{n: dataclasses.replace(p[n], data=d)
-                     for n, d in codes.items()}}
+        p = stacks_in(p, codes)
         window = W if sliding[j] else None
         idx = i * per[sliding[j]] + rank[j]
 

@@ -38,6 +38,7 @@ import numpy as np
 
 from bigdl_tpu.ops import routes
 from bigdl_tpu.quant import QTensor
+from bigdl_tpu.quant.qtensor import KERNEL_FIELDS
 
 # Decode GEMV threshold, same role as the reference's `use_batch_forward`
 # heuristic (low_bit_linear.py:272-309): below this many rows the matmul
@@ -302,6 +303,89 @@ def grouped_route(*stacks) -> Optional[str]:
     return None
 
 
+def _row_bytes(w: QTensor) -> int:
+    return w.data.shape[-1] * w.data.dtype.itemsize
+
+
+def _reads_bits(w: QTensor) -> bool:
+    """Does a `linear` call on `w` read prepared scale bits in place?"""
+    from bigdl_tpu.ops.pallas.qdecode import spec_for
+    from bigdl_tpu.ops.pallas.qmatmul import bits_layout, prepared_bits
+
+    return prepared_bits(w, bits_layout(
+        spec_for(w.spec), w.data.shape[-2], _row_bytes(w))) is not None
+
+
+def prepare_scale_bits(w, stacks: Optional[int] = None):
+    """`w` with its float16 `scales` (and `mins`) a second time as the
+    operand its kernel reads in place (`QTensor.scale_bits`, `min_bits`,
+    `bits_layout`): uint16 bits, one block a word tile with the tile's rows
+    on lanes where the word path runs (`qdecode.pack_major_bits`), the
+    stored `[.., O, nb]` on the stored-layout loop. What the kernels'
+    wrappers otherwise derive from the float16 fields before EVERY call
+    (a view XLA materialises, padded to 128 lanes, and the tile's
+    transposes every grid step) is derived once, by whoever takes a tree
+    to serve from (`llama.prepare_kernel_scales`).
+
+    `stacks`: None for a weight `linear` multiplies by (`[O, C]`, or a
+    stack of layers `[L, O, C]` read by layer index), else the number of
+    expert stacks of the grouped call it is part of (2: a fused gate / up
+    pair), whose plan picks the layout. `w` comes back as it is where no
+    kernel would read the bits: not packed, refused by the shape guards,
+    fp8 codes, a two-level format."""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.ops.pallas import qdecode
+    from bigdl_tpu.ops.pallas.qmatmul import _f16_bits, bits_layout
+    from bigdl_tpu.ops.pallas.tiling import WORD_BLOCK_O
+
+    if not isinstance(w, QTensor) or w.spec.storage.startswith("fp8"):
+        return w
+    if (_shape_guard(w)[0] is None if w.data.ndim == 2
+            else grouped_route(w) is not None):
+        return w
+    if stacks is None:
+        layout = bits_layout(qdecode.spec_for(w.spec), w.data.shape[-2],
+                             _row_bytes(w))
+    else:
+        layout = mq.bits_layout((w,) * stacks)
+    if layout is None:
+        return w
+    rows = {"words": WORD_BLOCK_O, "words:paired": WORD_BLOCK_O // 2}
+    lay = (_f16_bits if layout == "stored" else
+           functools.partial(qdecode.pack_major_bits, rows=rows[layout]))
+    return dataclasses.replace(
+        w, scale_bits=lay(w.scales), bits_layout=layout,
+        min_bits=None if w.mins is None else lay(w.mins))
+
+
+def stacks_out(group: dict, names) -> tuple[dict, dict]:
+    """(`group` with the whole-stack operands of the weights `names` taken
+    out, those operands by name and field): what a layer scan must not
+    slice, because a slice handed to a Mosaic call is first copied whole.
+    The packed codes always; the prepared scale bits where the tree has
+    them (`prepare_scale_bits`). fp8 codes keep their slices (they reach a
+    kernel through a bitcast that would copy the whole stack instead).
+    The scan slices what is left, the float16 scales included (nobody reads
+    them where the bits are read), and its body puts the whole stacks back
+    with `stacks_in` and hands `linear` / `_moe_dispatch` the layer's
+    index."""
+    kept = {}
+    for n in names:
+        w = group[n]
+        if w.spec.storage.startswith("fp8"):
+            continue
+        kept[n] = {f: getattr(w, f) for f in ("data", *KERNEL_FIELDS)
+                   if getattr(w, f) is not None}
+    return ({n: dataclasses.replace(w, **dict.fromkeys(kept[n]))
+             if n in kept else w for n, w in group.items()}, kept)
+
+
+def stacks_in(p: dict, kept: dict) -> dict:
+    """One layer's weights `p` with `stacks_out`'s whole stacks back in."""
+    return {**p, **{n: dataclasses.replace(p[n], **fields)
+                    for n, fields in kept.items()}}
+
+
 def _fused_kernel(x: jax.Array, w: QTensor) -> Optional[Callable]:
     return _fused_route(x, w)[0]
 
@@ -371,7 +455,7 @@ def _zero_cotangent(w: QTensor) -> QTensor:
             return jnp.zeros(a.shape, a.dtype)
         return np.zeros(a.shape, jax.dtypes.float0)
 
-    return w.map_arrays(z)
+    return jax.tree.map(z, w)
 
 
 def _layer_of(w: QTensor, layer) -> QTensor:
@@ -385,6 +469,12 @@ def _layer_of(w: QTensor, layer) -> QTensor:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _fused_matmul(x: jax.Array, w: QTensor, layer, qtype: str, block_o: int):
+    if w.bits_layout is not None:
+        # prepared scale bits: the generic entry reads them (the same
+        # DecodeSpec, from the registry, as the per-format wrappers build)
+        from bigdl_tpu.ops.pallas import qmatmul
+
+        return qmatmul(x, w, out_dtype=x.dtype, block_o=block_o, layer=layer)
     entry = _QGEMV_QTYPES[qtype]
     run = entry.run if _rows(x.shape) <= _GEMV_MAX_ROWS else entry.gemm
     return run(x, w, block_o, layer=layer)
@@ -533,8 +623,9 @@ def linear(
 
     With ``layer`` (a traced index) the packed codes ``w.data`` are those
     of a whole stack of layers ``[L, O, C]`` and the kernel reads layer
-    ``layer`` of them in place; every other field of ``w`` is that
-    layer's own. A caller inside a layer scan hands the codes over this
+    ``layer`` of them in place, and so are the prepared scale bits where
+    ``w`` carries them (`prepare_scale_bits`); every other field of ``w``
+    is that layer's own. A caller inside a layer scan hands the codes over this
     way because a per-layer slice given to a Mosaic call is copied whole
     first (`models/llama.forward` says which weights it does this for).
 
@@ -562,7 +653,8 @@ def linear(
             "linear", f"pallas:{why}" if kernel is not None else "xla",
             f"{w.qtype} M{_rows(x.shape)} K{w.shape[-1]} "
             f"O{w.data.shape[-2]} " + ("stack" if stacked else "slice")
-            + ("" if kernel is not None else f" ({why})"))
+            + (f" ({why})" if kernel is None else " scales:stack"
+               if lora is None and _reads_bits(w) else " scales:slice"))
         if kernel is not None:
             from bigdl_tpu.ops.pallas.tiling import WORD_BLOCK_O
 

@@ -62,10 +62,13 @@ route note):
 The packed codes may keep their leading layer axis (`[L, E, O, C]` with
 a traced `layer`): the kernel then reads its blocks straight out of the
 scanned-over array, where a `[E, O, C]` slice handed to a Mosaic call
-would first be copied whole, every expert of it, hit or not. The float16
-scales (an eighth of the bytes) cannot: Mosaic takes no float16 argument,
-the uint16 view of them is a copy XLA materializes, and one layer's copy
-at a time is what fits.
+would first be copied whole, every expert of it, hit or not. So may the
+scales, once they are prepared (`ops/linear.prepare_scale_bits`: uint16 bits,
+`[L, E, O / rows, nb, rows]` with a word tile's rows on lanes in the order
+its decode leaves them): a grid step's side block is then the
+`[held, nb, rows]` of the tiles it holds and `qdecode.stage_words` a load.
+Float16 scales (a tree nobody prepared) are viewed as uint16 before the
+call, a copy XLA materializes over every expert of the layer.
 """
 
 from __future__ import annotations
@@ -79,7 +82,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
-from bigdl_tpu.ops.pallas.qmatmul import _side_arrays, _validate
+from bigdl_tpu.ops.pallas.qmatmul import (
+    _validate, prepared_bits, side_operands,
+)
 from bigdl_tpu.ops.pallas.tiling import (
     VMEM_LIMIT_BYTES, WORD_BLOCK_O, finest_split, forward_chunk, grouped_tile,
     pick_block_m,
@@ -141,7 +146,8 @@ def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int):
 
 
 def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
-            spec: DecodeSpec, n_w: int, act, form: str, rows: int):
+            spec: DecodeSpec, n_w: int, act, form: str, rows: int,
+            prepared: bool = False):
     """One [block_m, block_o] tile of one expert: `qmatmul._kernel`'s
     chunk loop (`qdecode.tile_product`) over each of the `n_w` weight
     stacks, skipped whole when the tile holds no assignment. On the word
@@ -149,7 +155,9 @@ def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
     form (`tiling.grouped_tile`) has one tile for both stacks. A step
     that holds several tiles of `rows` rows walks them, one product and
     one store each, through the same scratch (a `fori_loop` unrolled at
-    lowering: see the module docstring)."""
+    lowering: see the module docstring). With ``prepared`` a side ref
+    holds the `qdecode.pack_major_bits` blocks of the step's tiles,
+    `[held, nb, rows]`."""
     del te_ref  # read by the index maps
     per = 1 + spec.n_side
     o_ref = refs[n_w * per]
@@ -159,7 +167,8 @@ def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
     def product(blocks, sides):
         """float32 [block_m, rows]: one word tile (or the loop's tile)."""
         if form == "words:paired":
-            qdecode.stage_words(spec, blocks, sides, scratch)
+            qdecode.stage_words(spec, blocks, sides, scratch,
+                                prepared=prepared)
             y = qdecode.natural_columns(qdecode.staged_product(
                 spec, K, ck, x_ref, scratch,
                 jnp.issubdtype(blocks[0].dtype, jnp.signedinteger)))
@@ -168,7 +177,7 @@ def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
         accs = [
             qdecode.tile_product(
                 spec, K, ck, x_ref, blocks[i], sides[i],
-                scratch[3 * i:3 * i + 3] if words else None)
+                scratch[3 * i:3 * i + 3] if words else None, prepared)
             for i in range(n_w)
         ]
         y = accs[0] if n_w == 1 else FUSED_ACTS[act](accs[0]) * accs[1]
@@ -179,6 +188,8 @@ def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
         blocks = [refs[i * per] for i in range(n_w)]
         sides = [refs[i * per + 1:(i + 1) * per] for i in range(n_w)]
         if held == 1:
+            if prepared:
+                sides = [[r[0] for r in side] for side in sides]
             o_ref[:] = product(blocks, sides).astype(o_ref.dtype)
             return
 
@@ -187,8 +198,8 @@ def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
             y = product([b.at[at, :] for b in blocks],
                         # (loaded here: a ref view narrower than 128
                         # lanes does not lower)
-                        [[r[at, :] for r in side] for side in sides]
-                        ).astype(o_ref.dtype)
+                        [[r[j] if prepared else r[at, :] for r in side]
+                         for side in sides]).astype(o_ref.dtype)
             # (a store takes no dynamic lane offset; unrolled, `j` is a
             # constant and the branches fold away)
             for t in range(held):
@@ -203,13 +214,17 @@ def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
 @functools.partial(
     jax.jit, static_argnames=("spec", "out_dtype", "block_m", "block_o",
                               "ck", "n_w", "act", "form", "rows",
-                              "layered", "interpret"))
+                              "layered", "prepared", "interpret"))
 def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
-             act, form: str, rows: int, layered: tuple, interpret: bool,
-             tile_expert, meta, x, *arrays):
+             act, form: str, rows: int, layered: tuple, prepared: bool,
+             interpret: bool, tile_expert, meta, x, *arrays):
+    """``prepared``: the side arrays are `qdecode.pack_major_bits` of the
+    call's word tiles, `[L, E, O / rows, nb, rows]`, and a grid step's
+    block is the `[held, nb, rows]` of the tiles it holds."""
     Mp, K = x.shape
     O = arrays[0].shape[-2]
     n_o = O // block_o
+    per = 1 + spec.n_side
 
     def x_map(m, o, te, meta):
         return (jnp.minimum(m, meta[0] - 1), 0)
@@ -219,19 +234,26 @@ def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
             meta[1] if has_layer else 0, te[m],
             jnp.where(m < meta[0], o, n_o - 1), 0)
 
+    def bits_map(has_layer):  # the same block of tiles, one axis up
+        w = w_map(has_layer)
+        return lambda *a: (*w(*a), 0)
+
     in_specs = [pl.BlockSpec((block_m, K), x_map)] + [
-        pl.BlockSpec((None, None, block_o, a.shape[-1]), w_map(has_layer))
-        for a, has_layer in zip(arrays, layered)
+        pl.BlockSpec((None, None, block_o // rows, *a.shape[-2:]),
+                     bits_map(has_layer)) if prepared and i % per
+        else pl.BlockSpec((None, None, block_o, a.shape[-1]),
+                          w_map(has_layer))
+        for i, (a, has_layer) in enumerate(zip(arrays, layered))
     ]
-    per = 1 + spec.n_side
     scratch = []
     if form != "loop":  # a word tile of each stack, or the pair's one
         scratch = qdecode.word_scratch(
-            spec, WORD_BLOCK_O, arrays[0].shape[-1], arrays[per - 1].shape[-1]
+            spec, WORD_BLOCK_O, arrays[0].shape[-1],
+            K // spec.block if prepared else arrays[per - 1].shape[-1]
         ) * (1 if form == "words:paired" else n_w)
     return pl.pallas_call(
         functools.partial(_kernel, K=K, ck=ck, spec=spec, n_w=n_w, act=act,
-                          form=form, rows=rows),
+                          form=form, rows=rows, prepared=prepared),
         name="moe_qmatmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -277,18 +299,21 @@ def moe_qmatmul(
     K = x.shape[-1]
     assert x.shape[0] == tile_expert.shape[0] * block_m, (x.shape, block_m)
 
+    n_w = len(ws)
+    form, rows, held, persist_row = _plan(ws)
+    bits = bits_layout(ws)
+    bits = bits if all(prepared_bits(w, bits) for w in ws) else None
     arrays, layered = [], []
     for w in ws:
         data = w.data
         if w.spec.storage.startswith("fp8"):
             data = jax.lax.bitcast_convert_type(data, jnp.uint8)
         _validate(spec, K, data)
-        for a in (data, *_side_arrays(spec, w.scales, w.mins, w.sub_scales,
-                                      w.sub_mins)):
-            layered.append(a.ndim == 4)
-            arrays.append(a if a.ndim == 4 else a[None])
-    n_w = len(ws)
-    form, rows, held, persist_row = _plan(ws)
+        for i, a in enumerate((data, *side_operands(spec, w, bits))):
+            # (a block of `pack_major_bits` has one axis more)
+            rank = 4 if i and bits and bits != "stored" else 3
+            layered.append(a.ndim == rank + 1)
+            arrays.append(a if layered[-1] else a[None])
     block_o = rows * held
     persist = (n_w * block_o * persist_row + block_m * K * 2
                + n_w * block_m * block_o * 4)
@@ -297,9 +322,23 @@ def moe_qmatmul(
     meta = jnp.stack([jnp.asarray(n_used, jnp.int32),
                       jnp.asarray(0 if layer is None else layer, jnp.int32)])
     return _moe_qmm(spec, jnp.dtype(out_dtype), block_m, block_o, ck, n_w,
-                    act, form, rows, tuple(layered), bool(interpret),
+                    act, form, rows, tuple(layered),
+                    bits is not None and bits != "stored", bool(interpret),
                     tile_expert.astype(jnp.int32),
                     meta, x.astype(jnp.bfloat16), *arrays)
+
+
+def bits_layout(ws):
+    """How a call on these stacks reads prepared scale bits
+    (`qmatmul.bits_layout` for the grouped kernel): its plan's word form
+    (``"words"``: `qdecode.pack_major_bits` of 512-row tiles,
+    ``"words:paired"``: of each stack's 256-row blocks), ``"stored"`` for
+    the loop, None for the two-level formats."""
+    ws = tuple(ws) if isinstance(ws, (tuple, list)) else (ws,)
+    if ws[0].spec.superblock:
+        return None
+    form = _plan(ws)[0]
+    return "stored" if form == "loop" else form
 
 
 def _plan(ws) -> tuple:

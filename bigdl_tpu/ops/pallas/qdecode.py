@@ -433,15 +433,49 @@ def _pad_lanes(a, mult: int = 128):
         [a, jnp.zeros((a.shape[0], mult - n % mult), a.dtype)], axis=1)
 
 
+def pack_major_bits(a, rows: int):
+    """Float16 `[..., O, nb]` scales (or mins) -> uint16 bits
+    `[..., O / rows, nb, rows]`, the operand a word tile of `rows` rows
+    reads in place (`stage_words(prepared=True)`): one block a tile, the
+    K/32 blocks on sublanes and the tile's rows on lanes in the order the
+    word decode leaves them, column `p * rows / 4 + i` row `4 i + p`. Run
+    once, when a program takes its weights (`ops/linear.prepare_scale_bits`),
+    where `stage_words` otherwise builds the same array every grid step."""
+    *lead, O, nb = a.shape
+    bits = jax.lax.bitcast_convert_type(a.astype(jnp.float16), jnp.uint16)
+    bits = bits.reshape(*lead, O // rows, rows // WORD_ROWS, WORD_ROWS, nb)
+    n = len(lead)
+    return bits.transpose(*range(n + 1), n + 3, n + 2, n + 1).reshape(
+        *lead, O // rows, nb, rows)
+
+
+def _pair_columns(g, u):
+    """The `[nb, 256]` blocks of a paired tile's two stacks (each
+    pack-major over its own 256 rows: 64 columns a pack) -> the `[nb, 512]`
+    of the one word tile they decode as: pack p's 128 lanes are gate's 64
+    beside up's 64. A lane roll and a select for every 128 lanes."""
+    lanes = 128
+    first = jax.lax.broadcasted_iota(
+        jnp.int32, (g.shape[0], lanes), 1) < lanes // 2
+    out = []
+    for j in range(g.shape[1] // lanes):
+        ga, ua = slc(g, j * lanes, lanes), slc(u, j * lanes, lanes)
+        out += [jnp.where(first, ga, pltpu.roll(ua, lanes // 2, 1)),
+                jnp.where(first, pltpu.roll(ga, lanes // 2, 1), ua)]
+    return jnp.concatenate(out, axis=1)
+
+
 def stage_words(spec: DecodeSpec, w_refs, side_refs, scratch,
-                piece: int = 2048):
+                piece: int = 2048, prepared: bool = False):
     """Once per grid step: the tile's words and its effective scales,
     transposed into `scratch` (see `word_scratch`). `w_refs` holds the
     tile's code blocks and `side_refs` each block's side refs: one block of
     512 rows, or the 256-row gate and up blocks of a gated expert call
     (`tiling.grouped_tile`), whose 64 + 64 word rows are stacked on
     sublanes and turned as one, so that the tile's rows 0..255 are gate
-    and 256..511 up."""
+    and 256..511 up. With ``prepared`` the side blocks are
+    `pack_major_bits`'s `[nb, rows]`, already turned: a load and
+    `f16_bits_to_f32` (single-level formats; no `effective_side`)."""
     wT_ref, s32_ref, sT_ref = scratch
     row_bytes = w_refs[0].shape[1]
     q = sum(r.shape[0] for r in w_refs) // WORD_ROWS
@@ -450,6 +484,12 @@ def stage_words(spec: DecodeSpec, w_refs, side_refs, scratch,
         words = [pltpu.bitcast(r[:, j0:j0 + cw], jnp.int32) for r in w_refs]
         wT_ref[j0:j0 + cw, :] = (
             words[0] if len(words) == 1 else jnp.concatenate(words, axis=0)).T
+    if prepared:
+        for i in range(spec.n_side):
+            a = [f16_bits_to_f32(refs[i][...]) for refs in side_refs]
+            sT_ref[i, :a[0].shape[0], :] = (
+                a[0] if len(a) == 1 else _pair_columns(*a))
+        return
     effs = [effective_side(spec, load_side(spec, refs)) for refs in side_refs]
     for i, a in enumerate(zip(*effs)):
         a = _pad_lanes(a[0] if len(a) == 1 else jnp.concatenate(a, axis=0))
@@ -524,7 +564,7 @@ def decode_chunk_words(spec: DecodeSpec, K: int, wT_ref, sT_ref, signed: bool,
 
 
 def tile_product(spec: DecodeSpec, K: int, ck: int, x_ref, w_ref, side_refs,
-                 scratch=None):
+                 scratch=None, prepared: bool = False):
     """float32 [block_m, block_o] = x @ dq(W tile)^T, the chunk loop both
     forward kernels run: chunks of the logical contraction axis, each
     decoded to bf16 and fed to the MXU, so live dequant temporaries stay
@@ -555,7 +595,7 @@ def tile_product(spec: DecodeSpec, K: int, ck: int, x_ref, w_ref, side_refs,
                 slc(x, e0, c), wd, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
         return acc
-    stage_words(spec, (w_ref,), (side_refs,), scratch)
+    stage_words(spec, (w_ref,), (side_refs,), scratch, prepared=prepared)
     return staged_product(
         spec, K, ck, x_ref, scratch,
         jnp.issubdtype(w_ref.dtype, jnp.signedinteger))

@@ -95,7 +95,8 @@ def _f16_bits(a: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
-            spec: DecodeSpec, lora: bool = False, words: bool = False):
+            spec: DecodeSpec, lora: bool = False, words: bool = False,
+            prepared: bool = False):
     """One [block_m, block_o] output tile: `qdecode.tile_product`'s chunk
     loop, acc += x_chunk @ dq(W_chunk)^T over chunks of the logical
     contraction axis. `layer_ref` is read by the weight's
@@ -122,7 +123,8 @@ def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
         side_refs = rest[:-4]
     else:
         side_refs = rest[:-1]
-    acc = qdecode.tile_product(spec, K, ck, x_ref, w_ref, side_refs, scratch)
+    acc = qdecode.tile_product(spec, K, ck, x_ref, w_ref, side_refs, scratch,
+                               prepared)
     if words:
         acc = qdecode.natural_columns(acc)
     if lora:
@@ -140,16 +142,18 @@ def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
 
 @functools.partial(
     jax.jit, static_argnames=("spec", "out_dtype", "block_m", "block_o",
-                              "ck", "interpret", "lora")
+                              "ck", "interpret", "lora", "bits")
 )
 def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
-         interpret: bool, lora: bool, layer, x2, w, *rest):
+         interpret: bool, lora: bool, bits, layer, x2, w, *rest):
     """`w` is the packed codes of a STACK of weights `[L, O, row_bytes]`
     and `layer [1]` int32 the one to multiply by, scalar-prefetched so
     the index map can name it: the tile's DMA reads layer `layer[0]` out
     of the whole array. A slice `w[layer]` handed to a Mosaic call would
     first be copied whole, every call. Everything else (`rest`: scales,
-    LoRA operands) is one layer's own rank-2 array."""
+    LoRA operands) is one layer's own rank-2 array, unless ``bits`` names
+    the layout of prepared scale bits (`bits_layout`): those keep their
+    layer axis too and are read by the same index."""
     Mp, K = x2.shape
     O = w.shape[1]
     if lora:
@@ -162,10 +166,22 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((None, block_o, w.shape[2]),
                      lambda m, o, l: (l[0], o, 0), memory_space=pltpu.VMEM),
-    ] + [
-        pl.BlockSpec((block_o, a.shape[1]), row, memory_space=pltpu.VMEM)
-        for a in side
     ]
+    if bits == "words":  # [L, O / 512, nb, 512]: the tile's own block
+        in_specs += [
+            pl.BlockSpec((None, None, *a.shape[2:]),
+                         lambda m, o, l: (l[0], o, 0, 0),
+                         memory_space=pltpu.VMEM) for a in side]
+    elif bits == "stored":  # [L, O, nb] uint16
+        in_specs += [
+            pl.BlockSpec((None, block_o, a.shape[2]),
+                         lambda m, o, l: (l[0], o, 0),
+                         memory_space=pltpu.VMEM) for a in side]
+    else:
+        in_specs += [
+            pl.BlockSpec((block_o, a.shape[1]), row, memory_space=pltpu.VMEM)
+            for a in side
+        ]
     if lora:
         # LoRA epilogue operands: A_cat rides as a FULL block (resident
         # across the whole o sweep, like the x tile), B_cat tiles follow
@@ -183,15 +199,17 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
     # the word path: not with LoRA operands (their VMEM beside the
     # transposed tile was never compiled for the chip)
     words = not lora and words_ok(block_o, w.shape[2])
+    assert (bits == "words") <= words, (bits, block_o, w.shape)
     scratch = qdecode.word_scratch(
-        spec, block_o, w.shape[2], side[-1].shape[1]) if words else []
+        spec, block_o, w.shape[2], K // spec.block if bits
+        else side[-1].shape[1]) if words else []
     # grid order (m, o): o innermost, so the x tile stays resident across
     # a full sweep of weight tiles and packed weights are re-fetched only
     # once per M tile (the roofline model in benchmark/roofline.py
     # assumes exactly this fetch pattern)
     return pl.pallas_call(
         functools.partial(_kernel, K=K, ck=ck, spec=spec, lora=lora,
-                          words=words),
+                          words=words, prepared=bits == "words"),
         name="qmatmul_lora" if lora else "qmatmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -237,8 +255,24 @@ def _side_arrays(spec: DecodeSpec, scales, mins, sub_scales, sub_mins):
     return (_f16_bits(scales),)
 
 
+def bits_layout(spec: DecodeSpec, O: int, row_bytes: int,
+                cap: int = WORD_BLOCK_O):
+    """How `_fused` reads prepared scale bits for a weight `[O, row_bytes]`
+    of codes, from the static shapes its tile plan is picked from:
+    ``"words"`` where the word path runs (`qdecode.pack_major_bits` of
+    512-row tiles), ``"stored"`` on the stored-layout loop (the uint16 view,
+    made once), None for the two-level formats, whose effective scales are
+    products the kernel forms in the stored layout."""
+    if spec.super_block:
+        return None
+    nb = row_bytes * 8 // (sum(spec.planes) or 8) // spec.block
+    block_o = pick_block_o(O, row_bytes + spec.n_side * nb * 2, cap=cap,
+                           row_bytes=row_bytes)
+    return "words" if words_ok(block_o, row_bytes) else "stored"
+
+
 def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
-           lora=None, layer=None):
+           lora=None, layer=None, bits=None):
     """Shared wrapper: flatten/pad rows, pick tiles, run the kernel.
 
     With ``layer`` (a traced index) ``data`` is the codes of a stack of
@@ -247,6 +281,11 @@ def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
     its own (``[O, row_bytes]``: the LM head, anything outside a layer
     scan) is the stack of one read at 0, a reshape and no copy. Tiles are
     picked from one layer's ``[O, row_bytes]`` either way.
+
+    With ``bits`` (`bits_layout`'s name) ``side`` holds the weight's
+    prepared scale bits, uint16 in that layout and with the leading axes
+    ``data`` has: the kernel reads them in place by the same index, where
+    a float16 ``side`` is viewed as uint16 (a copy) before every call.
 
     ``lora`` (optional) is the fused-epilogue operand triple
     ``(a_cat [R, K], b_cat [O, R], gate [M, R])`` — see _kernel; the
@@ -258,6 +297,8 @@ def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
     *lead, K = x.shape
     if layer is None:
         data, layer = data[None], 0
+        if bits:
+            side = tuple(a[None] for a in side)
     assert data.ndim == 3, data.shape
     O = data.shape[1]
     _validate(spec, K, data)
@@ -276,6 +317,7 @@ def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
     extra = ()
     lora_bytes = 0
     if lora is not None:
+        assert not bits, "prepared bits are not read beside LoRA operands"
         a_cat, b_cat, gate = lora
         R = a_cat.shape[0]
         assert a_cat.shape == (R, K), (a_cat.shape, K)
@@ -288,8 +330,10 @@ def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
                  gate2)
         lora_bytes = lora_operand_bytes(R, K, 256, block_m)
 
-    persist_row = data.shape[2] * data.dtype.itemsize + sum(
-        a.shape[1] * a.dtype.itemsize for a in side)
+    # (prepared bits: K / block uint16 a row and side array, as stored)
+    persist_row = data.shape[2] * data.dtype.itemsize + (
+        len(side) * (K // spec.block) * 2 if bits else sum(
+            a.shape[1] * a.dtype.itemsize for a in side))
     block_o = pick_block_o(O, persist_row, cap=block_o,
                            row_bytes=0 if lora is not None else data.shape[2])
     persist = (block_o * persist_row + block_m * K * 2
@@ -298,7 +342,7 @@ def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,
                        block_o, persist, finest_split(K, spec.planes),
                        spec.block, spec.mins)
     y = _qmm(spec, jnp.dtype(out_dtype), block_m, block_o, ck,
-             bool(interpret), lora is not None,
+             bool(interpret), lora is not None, bits,
              jnp.asarray(layer, jnp.int32).reshape(1), x2, data, *side, *extra)
     return y[:M].reshape(*lead, O)
 
@@ -325,9 +369,26 @@ def qmatmul(
         # fp8 bytes cross as stored; the kernel decodes the 256-entry
         # byte codebook arithmetically from the bit fields
         data = jax.lax.bitcast_convert_type(data, jnp.uint8)
-    side = _side_arrays(spec, w.scales, w.mins, w.sub_scales, w.sub_mins)
-    return _fused(x, data, spec, side, out_dtype, block_o, interpret,
-                  layer=layer)
+    bits = prepared_bits(w, bits_layout(
+        spec, data.shape[-2], data.shape[-1] * data.dtype.itemsize, block_o))
+    return _fused(x, data, spec, side_operands(spec, w, bits), out_dtype,
+                  block_o, interpret, layer=layer, bits=bits)
+
+
+def prepared_bits(w, layout):
+    """`layout` when `w` carries scale bits prepared for it (the layout a
+    call's own tile plan reads), else None: the call views the float16
+    fields, as it does for every weight nobody prepared."""
+    return layout if layout is not None and w.bits_layout == layout else None
+
+
+def side_operands(spec: DecodeSpec, w, bits):
+    """The scale-side operands of a call on `w`, in kernel argument order:
+    its prepared bits where `bits` (`prepared_bits`' answer) says the call
+    reads them, else the float16 fields viewed as uint16."""
+    if bits:
+        return (w.scale_bits, w.min_bits)[:spec.n_side]
+    return _side_arrays(spec, w.scales, w.mins, w.sub_scales, w.sub_mins)
 
 
 def qmatmul_lora(

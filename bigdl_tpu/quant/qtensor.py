@@ -30,6 +30,13 @@ from bigdl_tpu.quant.qtypes import QTypeSpec, resolve_qtype
 # carry the integer sub-block scales of two-level (k-quant) formats
 ARRAY_FIELDS = ("data", "scales", "mins", "sub_scales", "sub_mins")
 
+# what a serving program derives ONCE from `scales` / `mins` for the fused
+# kernels (`ops/linear.prepare_scale_bits`): the same float16 values as
+# uint16 bits in the order the kernel's tile plan reads them, `bits_layout`
+# naming that order. Not ARRAY_FIELDS: a QTensor rebuilt field-wise (sliced,
+# stacked, sharded, saved) drops them and its calls read the float16 fields.
+KERNEL_FIELDS = ("scale_bits", "min_bits")
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -40,6 +47,10 @@ class QTensor:
     qtype: str = dataclasses.field(metadata=dict(static=True), kw_only=True)
     sub_scales: Optional[jax.Array] = None
     sub_mins: Optional[jax.Array] = None
+    scale_bits: Optional[jax.Array] = None
+    min_bits: Optional[jax.Array] = None
+    bits_layout: Optional[str] = dataclasses.field(
+        default=None, metadata=dict(static=True), kw_only=True)
 
     @property
     def spec(self) -> QTypeSpec:
@@ -82,6 +93,17 @@ class QTensor:
             if v is not None:
                 n += v.size * v.dtype.itemsize
         return n
+
+
+def without_scale_bits(tree):
+    """`tree` with every QTensor's KERNEL_FIELDS dropped: the tree as
+    `optimize_model` returns it, for whoever shards, saves or compares
+    it."""
+    is_q = lambda x: isinstance(x, QTensor)  # noqa: E731
+    return jax.tree.map(
+        lambda w: dataclasses.replace(
+            w, bits_layout=None, **dict.fromkeys(KERNEL_FIELDS))
+        if is_q(w) else w, tree, is_leaf=is_q)
 
 
 def map_arrays_multi(ws: list["QTensor"], fn) -> "QTensor":

@@ -146,7 +146,108 @@ def test_the_other_models_programs_count_what_they_counted(name):
     assert counts(name) == PINNED[name]
 
 
+# ---- the scales' per-layer work cannot come back unseen (ISSUE 48) --------
+#
+# The pins above are of the XLA route (a CPU without the interpreter runs no
+# kernel, so nothing there views a scale), and PR 48 left all seven where they
+# were. With the kernels on, the decode step of a tree prepared for serving
+# (`llama.prepare_kernel_scales`, which `TpuModel` runs) must neither view a
+# float16 scale as uint16 nor slice a scales-shaped operand inside its layer
+# scan: on the chip each of those was a copy of the WHOLE stack, padded to 128
+# lanes, every layer of every step (12.7 ms of Laguna's 28 ms step, PR 47).
+
+_WIDE = dict(hidden_size=256, intermediate_size=512)  # a 512-row word tile
+GUARDED = {
+    "mistral": dict(MODELS["mistral"], **_WIDE),
+    "mixtral": dict(MODELS["mixtral"], **_WIDE),
+}
+# equations of `engine_decode` with the kernels on (the interpreter's route):
+# (the tree as `optimize_model` makes it, the tree prepared)
+KERNEL_ROUTE = {
+    "mistral": (431, 427),
+    "mixtral": (531, 523),
+}
+
+
+def _eqns(jaxpr, inside_scan=False):
+    """(equation, is it inside a scan's body) over `jaxpr` and what it nests;
+    a kernel's own body (`pallas_call`) is the kernel's business."""
+    for e in jaxpr.eqns:
+        yield e, inside_scan
+        if e.primitive.name == "pallas_call":
+            continue
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(
+                        sub, inside_scan or e.primitive.name == "scan")
+
+
+def scale_work(name: str, prepare: bool) -> tuple:
+    """(equations of `engine_decode` outside the kernels' bodies, the
+    equations inside its layer scan that view 16-bit floats as integers or
+    slice a 16-bit operand, the layer stacks the scan is handed whole)."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.api import TpuModel, optimize_model
+    from bigdl_tpu.models import get_family
+    from bigdl_tpu.models.config import ModelConfig
+    from bigdl_tpu.quant.qtensor import without_scale_bits
+    from bigdl_tpu.serving.engine import InferenceEngine
+
+    cfg = ModelConfig.from_hf_config(GUARDED[name])
+    model = TpuModel(cfg, optimize_model(
+        get_family(cfg.model_type).init_params(cfg, jax.random.PRNGKey(0)),
+        cfg, "sym_int4"), "sym_int4")
+    params = model.params if prepare else without_scale_bits(model.params)
+    eng = InferenceEngine(model, n_slots=4, max_len=256, paged=True,
+                          page_size=16, n_pages=65)
+    B, z = 4, jnp.zeros
+    jaxpr = jax.make_jaxpr(eng._decode.__wrapped__)(
+        params, z((B,), jnp.int32), eng.cache, jax.random.PRNGKey(0),
+        z((B,)), z((B,), jnp.int32), z((B,)), z((B,), bool), eng.seen,
+        z((B,))).jaxpr
+    sixteen = (jnp.float16, jnp.uint16)
+    work, n, whole = [], 0, 0
+    for e, in_scan in _eqns(jaxpr):
+        n += 1
+        prim = e.primitive.name
+        if prim == "scan":  # its constants: what the body indexes by layer
+            whole += sum(v.aval.dtype == jnp.uint16 and v.aval.ndim >= 3
+                         for v in e.invars[:e.params["num_consts"]])
+        if in_scan and e.invars and hasattr(e.invars[0], "aval") and (
+                prim == "bitcast_convert_type"
+                and e.invars[0].aval.dtype == jnp.float16
+                or prim in ("dynamic_slice", "slice", "gather")
+                and e.invars[0].aval.dtype in sixteen
+                and e.invars[0].aval.ndim >= 2):
+            work.append(f"{prim} {e.invars[0].aval.str_short()}")
+    return n, work, whole
+
+
+@pytest.mark.core
+@pytest.mark.parametrize("name", sorted(GUARDED))
+def test_a_prepared_trees_decode_scan_neither_views_nor_slices_a_scale(
+        monkeypatch, name):
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+    n_stored, stored, whole_stored = scale_work(name, prepare=False)
+    n_prepared, prepared, whole = scale_work(name, prepare=True)
+    # the guard can see what it guards against: a view a projection (and an
+    # expert stack) a layer on the tree nobody prepared
+    assert whole_stored == 0 and len(stored) >= 4, stored
+    assert all(w.startswith("bitcast_convert_type") for w in stored), stored
+    assert prepared == [], prepared
+    assert whole == len(stored), (whole, stored)  # each view became a stack
+    assert (n_stored, n_prepared) == KERNEL_ROUTE[name]
+
+
 if __name__ == "__main__":
     sys.path.insert(0, os.getcwd())
     for name in sorted(MODELS):
         print(f"    {name!r}: {counts(name)},", flush=True)
+    os.environ["BIGDL_TPU_PALLAS"] = "interpret"
+    for name in sorted(GUARDED):
+        print(f"    {name!r}: ({scale_work(name, False)[0]}, "
+              f"{scale_work(name, True)[0]}),  # KERNEL_ROUTE", flush=True)

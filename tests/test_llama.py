@@ -442,7 +442,10 @@ def test_bench_configurations_read_every_projection_from_the_stack(
     note `stack` for all four projections of a layer (Mixtral: the two of
     its attention; its experts go through the grouped kernel by the same
     index), `slice` for the LM head alone, and `stack` for none under an
-    adapter tree."""
+    adapter tree. The scales follow (ISSUE 48): `scales:slice` on every call
+    of the tree `optimize_model` makes, `scales:stack` on every layer call
+    AND the head once the tree is prepared for serving, and `scales:slice`
+    again for whatever an adapter keeps sliced."""
     import os
 
     from bench import cells, weights
@@ -455,9 +458,13 @@ def test_bench_configurations_read_every_projection_from_the_stack(
     hf["num_hidden_layers"] = 2
     cfg = ModelConfig.from_hf_config(hf)
     qtype, slots = config["bench"]["qtype"], config["bench"]["engine"]["n_slots"]
-    params = weights.param_shapes(cfg, qtype)
+    stored = weights.param_shapes(cfg, qtype)
+    prepared = jax.eval_shape(
+        lambda p: llama.prepare_kernel_scales(cfg, p), stored)
+    assert jax.tree.structure(stored) == jax.tree.structure(
+        weights.param_shapes(cfg, qtype))  # preparing touches no tree
 
-    def notes(B, T, mode, lora=None):
+    def notes(B, T, mode, lora=None, params=stored):
         cache = jax.eval_shape(lambda: kvcache.init_cache(
             2, B, 2048, cfg.num_key_value_heads, cfg.head_dim_))
         with record_routes() as routes:
@@ -468,15 +475,28 @@ def test_bench_configurations_read_every_projection_from_the_stack(
                 params, cache, lora)
         lin = {k: n for k, n in routes.items() if k[0] == "linear"}
         assert all(k[1].startswith("pallas:") for k in lin), lin
-        return (sum(n for k, n in lin.items() if k[2].endswith(" stack")),
-                sum(n for k, n in lin.items() if k[2].endswith(" slice")),
-                routes)
+        # a kernel call's detail: qtype M K O, the codes, ..., the scales
+        scales = {k[2].split()[-1] for k in routes
+                  if k[0] == "linear" or k[:2] == ("moe", "pallas:grouped")}
+        return (sum(n for k, n in lin.items() if k[2].split()[4] == "stack"),
+                sum(n for k, n in lin.items() if k[2].split()[4] == "slice"),
+                scales, routes)
 
     for B, T, mode in ((slots, 1, "decode"), (1, 1024, "prefill")):
-        n_stack, n_slice, routes = notes(B, T, mode)
-        assert (n_stack, n_slice) == (stacked, 1), routes
-        if cfg.is_moe:
-            assert any(k[:2] == ("moe", "pallas:grouped") for k in routes)
+        for params, want in ((stored, "scales:slice"),
+                             (prepared, "scales:stack")):
+            n_stack, n_slice, scales, routes = notes(B, T, mode,
+                                                     params=params)
+            assert (n_stack, n_slice) == (stacked, 1), routes
+            assert scales == {want}, routes
+            if cfg.is_moe:
+                assert any(k[:2] == ("moe", "pallas:grouped")
+                           for k in routes)
     lora = jax.eval_shape(lambda: init_lora(cfg, jax.random.PRNGKey(0), 4))
-    n_stack, n_slice, routes = notes(1, 64, "prefill", lora)
-    assert n_stack == 0 and n_slice == stacked + 1, routes
+    # under an adapter only the head, which no adapter touches, reads bits
+    for params, want in ((stored, {"scales:slice"}),
+                         (prepared, {"scales:slice", "scales:stack"})):
+        n_stack, n_slice, scales, routes = notes(1, 64, "prefill", lora,
+                                                 params)
+        assert n_stack == 0 and n_slice == stacked + 1, routes
+        assert scales == want, routes

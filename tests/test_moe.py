@@ -263,6 +263,76 @@ def test_grouped_kernel_gated_pair_and_layer_axis(interpret):
                                rtol=1e-6, atol=1e-6)
 
 
+# (K, O, gated, qtype): the plans the MoE cells run. `words:paired` at
+# granite's and SmallThinker's 768-wide experts (nb = 128 cut to 24 here, and
+# 80), their down projections (several word tiles a grid step, nb = 24),
+# Laguna's 512-wide experts (nb = 64 into one tile, nb = 16 into four),
+# Mixtral's `words` pair, a format with mins, and the stored-layout loop
+_PREPARED_EXPERTS = {
+    "paired-nb24": (768, 768, True, "sym_int4"),
+    "paired-nb80": (2560, 768, True, "sym_int4"),
+    "paired-mins": (768, 768, True, "asym_int4"),
+    "down-768-nb24-5-tiles": (768, 2560, False, "sym_int4"),
+    "laguna-gate_up-nb64": (2048, 512, True, "sym_int4"),
+    "laguna-down-nb16-4-tiles": (512, 2048, False, "sym_int4"),
+    "words-pair": (1024, 1024, True, "sym_int4"),
+    "loop-ungated-768": (1024, 768, False, "sym_int4"),
+}
+
+
+@pytest.mark.parametrize("layered", (True, False), ids=("stack", "own"))
+@pytest.mark.parametrize("name", list(_PREPARED_EXPERTS))
+def test_grouped_kernel_on_prepared_scale_bits_is_bit_equal(interpret, name,
+                                                            layered):
+    """The grouped call that reads `prepare_scale_bits`'s uint16 stacks in
+    place (by layer and expert, or a layer's own outside a scan) gives, bit
+    for bit, what the call on the float16 slices gives (ISSUE 48)."""
+    from bigdl_tpu.ops.linear import prepare_scale_bits
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.quant import quantize
+
+    K, O, gated, qtype = _PREPARED_EXPERTS[name]
+    L, E, N, k = 2, 4, 13, 2
+    n_w = 2 if gated else 1
+    ws = [quantize(jax.random.normal(jax.random.PRNGKey(i), (L, E, O, K))
+                   * 0.05, qtype) for i in range(n_w)]
+    form = mq._plan(ws)[0]
+    assert form == ("words:paired" if name.startswith("paired") else
+                    "loop" if name.startswith("loop") else "words")
+    prep = [prepare_scale_bits(w, n_w) for w in ws]
+    rows = {"words": 512, "words:paired": 256}.get(form)
+    for w in prep:
+        assert w.bits_layout == ("stored" if form == "loop" else form)
+        assert w.scale_bits.dtype == jnp.uint16
+        assert w.scale_bits.shape == (
+            (L, E, O, K // 32) if rows is None
+            else (L, E, O // rows, K // 32, rows))
+    # what `forward` hands the kernel: codes (and bits) with the layer
+    # axis and the float16 fields sliced, or everything the layer's own
+    own = lambda w: jax.tree.map(lambda a: a[1], w)  # noqa: E731
+    if layered:
+        layer = jnp.asarray(1)
+        sliced = [dataclasses.replace(
+            w, scales=w.scales[1],
+            mins=None if w.mins is None else w.mins[1]) for w in ws]
+    else:
+        layer, sliced, prep = None, [own(w) for w in ws], [own(w) for w in prep]
+    bm = mq.moe_block_m(N, max(K, O))
+    topi = jax.random.randint(jax.random.PRNGKey(2), (N, k), 0, E - 1)
+    dest, src, te, n_used = mq.moe_layout(
+        topi, E, bm, mq.moe_n_tiles(N, k, E, bm))
+    x = jax.random.normal(jax.random.PRNGKey(3), (N, K)).astype(jnp.bfloat16)
+    call = functools.partial(
+        mq.moe_qmatmul, x[src], tile_expert=te, n_used=n_used, block_m=bm,
+        layer=layer, out_dtype=jnp.float32,
+        **(dict(act="silu") if gated else {}))
+    got = call(ws=prep if gated else prep[0])
+    want = call(ws=sliced if gated else sliced[0])
+    live = np.asarray(dest).reshape(-1)
+    np.testing.assert_array_equal(np.asarray(got)[live],
+                                  np.asarray(want)[live])
+
+
 # (b) droplessness. TOLERANCE 1e-3 on block outputs of 0.02 rms (0.09 at the
 # largest): bf16 operands into a float32 accumulator and a bf16 result, 2^-9
 # each; measured 3e-4. An assignment that is dropped is off by the whole

@@ -703,8 +703,10 @@ def test_linear_on_a_stack_is_bit_equal_to_the_slice(rng, monkeypatch, qtype,
                 for l in range(L)]
     kind = "gemv" if m <= 32 else "gemm"
     assert routes == {
-        ("linear", f"pallas:{kind}", f"{qtype} M{m} K{K} O{O} stack"): 1,
-        ("linear", f"pallas:{kind}", f"{qtype} M{m} K{K} O{O} slice"): 1,
+        ("linear", f"pallas:{kind}",
+         f"{qtype} M{m} K{K} O{O} stack scales:slice"): 1,
+        ("linear", f"pallas:{kind}",
+         f"{qtype} M{m} K{K} O{O} slice scales:slice"): 1,
     }
     for l in range(L):
         assert np.isfinite(want[l]).all()

@@ -297,6 +297,115 @@ def test_dense_qmatmul_lowers_to_the_parents_program(one_chip, K, O, M):
             == ("words", 512, held)
 
 
+# ---- prepared scale bits, read in place (ISSUE 48) --------------------------
+
+def _no_scale_is_moved(compiled):
+    """The optimized program neither copies nor views a 16-bit array: the
+    prepared bits reach the Mosaic call as the argument they are."""
+    text = compiled.as_text()
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if (" copy(" in line or "bitcast-convert" in line)
+             and (" u16[" in line or " f16[" in line)]
+    assert not moved, moved
+    return text
+
+
+def _prepared(w, stacks, chip):
+    from bigdl_tpu.ops.linear import prepare_scale_bits
+
+    w = jax.eval_shape(lambda w: prepare_scale_bits(w, stacks), w)
+    return jax.tree.map(lambda a: _sds(a.shape, a.dtype, chip), w)
+
+
+# (K, O, M, qtype, in a layer stack?): a wqkv, a prefill's w_down (nb = 448),
+# Qwen2's w_down (nb = 592) and its head outside the scan (297 tiles of
+# nb = 112), Mistral's head on the stored-layout loop, a format with mins
+_PREPARED_DENSE = {
+    "wqkv": (4096, 6144, 32, "sym_int4", True),
+    "w_down-prefill": (14336, 4096, 256, "sym_int4", True),
+    "qwen2-w_down": (18944, 3584, 16, "sym_int4", True),
+    "qwen2-head": (3584, 152064, 16, "sym_int4", False),
+    "mistral-head-stored": (4096, 32000, 32, "sym_int4", False),
+    "stored-in-a-stack": (4096, 768, 32, "sym_int4", True),
+    "mins": (2048, 1024, 8, "asym_int4", True),
+}
+
+
+@pytest.mark.parametrize("name", list(_PREPARED_DENSE))
+def test_qmatmul_compiles_on_prepared_scale_bits(one_chip, monkeypatch, name):
+    """Mosaic takes the operand blocks of a call that reads its scales in
+    place: `[nb, 512]` uint16 of `[L, O / 512, nb, 512]` by layer and tile
+    (whole (16, 128) tiles at every cell's nb), the stored `[O, nb]` on the
+    loop; and XLA hands the stack over as it is."""
+    from bigdl_tpu.ops.pallas.qmatmul import qmatmul
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")  # the guards' switch
+    K, O, M, qtype, stacked = _PREPARED_DENSE[name]
+    lead = (2,) if stacked else ()
+    side = _sds((*lead, O, K // 32), jnp.float16, one_chip)
+    w = _prepared(QTensor(
+        qtype=qtype, data=_sds((*lead, O, K // 2), jnp.uint8, one_chip),
+        scales=side, mins=side if qtype == "asym_int4" else None),
+        None, one_chip)
+    assert w.bits_layout == ("stored" if "stored" in name else "words")
+    x = _sds((M, K), jnp.bfloat16, one_chip)
+    if stacked:
+        c = jax.jit(lambda x, w, l: qmatmul(x, w, interpret=False, layer=l)
+                    ).lower(x, w, _sds((), jnp.int32, one_chip)).compile()
+    else:
+        c = jax.jit(lambda x, w: qmatmul(x, w, interpret=False)
+                    ).lower(x, w).compile()
+    assert "qmatmul" in _no_scale_is_moved(c)
+
+
+# (E, k, K, O, act, rows of a step): the paired tile's two `[nb, 256]` blocks
+# (a lane roll and a select a pack), several word tiles a step (nb = 24: a
+# tile and a half of sublanes), Laguna's and Mixtral's
+_PREPARED_EXPERTS = {
+    "granite-gate_up": (72, 10, 4096, 768, "silu", 32),
+    "granite-down": (72, 10, 768, 4096, None, 32),
+    "smallthinker-gate_up-prefill": (64, 6, 2560, 768, "relu", 2048),
+    "smallthinker-down": (64, 6, 768, 2560, None, 16),
+    "laguna-gate_up": (256, 8, 2048, 512, "silu", 16),
+    "laguna-down": (256, 8, 512, 2048, None, 16),
+    "laguna-down-prefill": (256, 8, 512, 2048, None, 8192),
+    "mixtral-gate_up": (8, 2, 4096, 14336, "silu", 16),
+    "glm-gate_up": (64, 4, 2048, 1536, "silu", 32),
+}
+
+
+@pytest.mark.parametrize("name", list(_PREPARED_EXPERTS))
+def test_moe_qmatmul_compiles_on_prepared_scale_bits(one_chip, monkeypatch,
+                                                     name):
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+    E, k, K, O, act, N = _PREPARED_EXPERTS[name]
+    n_w = 2 if act else 1
+    ws = [_prepared(QTensor(
+        qtype="sym_int4", data=_sds((2, E, O, K // 2), jnp.uint8, one_chip),
+        scales=_sds((2, E, O, K // 32), jnp.float16, one_chip)), n_w,
+        one_chip) for _ in range(n_w)]
+    form = mq._plan(ws)[0]
+    assert form != "loop" and all(w.bits_layout == form for w in ws)
+    bm = mq.moe_block_m(N, max(K, O, 2048))
+    n_tiles = mq.moe_n_tiles(N, k, E, bm)
+
+    def f(x, te, n_used, layer, *ws):
+        return mq.moe_qmatmul(x, list(ws) if act else ws[0], te, n_used, bm,
+                              act=act, layer=layer, interpret=False,
+                              out_dtype=jnp.bfloat16 if act else jnp.float32)
+
+    c = jax.jit(f).lower(
+        _sds((n_tiles * bm, K), jnp.bfloat16, one_chip),
+        _sds((n_tiles,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        *ws).compile()
+    assert "moe_qmatmul" in _no_scale_is_moved(c)
+
+
 # ---- paged decode attention over groups of live pages (ISSUE 35) -----------
 
 # (slots, KV heads, query heads a KV head, head size, layers, pages in the
