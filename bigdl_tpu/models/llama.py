@@ -562,8 +562,8 @@ def _moe_dispatch_grouped(
 def _grouped_plan(config: ModelConfig, p: Params) -> str:
     """The tile plan of each call `_moe_dispatch_grouped` makes for this
     layer (`moe_qmatmul.call_plan`: the loop, and the grid steps an
-    expert), for the route note: `gate_up words:paired x1 of 3 tiles, down
-    words x1 of 8 tiles`."""
+    expert), for the route note: `gate_up words:inplace:paired x1 of 3
+    tiles, down words:inplace x1 of 8 tiles`."""
     from bigdl_tpu.ops.pallas import moe_qmatmul as mq
 
     if not config.gated_mlp:

@@ -316,6 +316,18 @@ def _reads_bits(w: QTensor) -> bool:
         spec_for(w.spec), w.data.shape[-2], _row_bytes(w))) is not None
 
 
+def _in_place(w: QTensor) -> bool:
+    """Does a `linear` call on `w` (no adapter) decode its tiles on the
+    word path with the codes cut out where they lie
+    (`qdecode.signed_field`)? From the tile plan's own static shapes."""
+    from bigdl_tpu.ops.pallas.qdecode import signed_field, spec_for
+    from bigdl_tpu.ops.pallas.qmatmul import tile_form
+
+    spec = spec_for(w.spec)
+    return bool(signed_field(spec)) and tile_form(
+        spec, w.data.shape[-2], _row_bytes(w)) == "words"
+
+
 def prepare_scale_bits(w, stacks: Optional[int] = None):
     """`w` with its float16 `scales` (and `mins`) a second time as the
     operand its kernel reads in place (`QTensor.scale_bits`, `min_bits`,
@@ -653,6 +665,8 @@ def linear(
             "linear", f"pallas:{why}" if kernel is not None else "xla",
             f"{w.qtype} M{_rows(x.shape)} K{w.shape[-1]} "
             f"O{w.data.shape[-2]} " + ("stack" if stacked else "slice")
+            + (" words:inplace" if kernel is not None and lora is None
+               and _in_place(w) else "")
             + (f" ({why})" if kernel is None else " scales:stack"
                if lora is None and _reads_bits(w) else " scales:slice"))
         if kernel is not None:

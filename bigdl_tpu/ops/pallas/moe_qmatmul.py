@@ -170,8 +170,7 @@ def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
             qdecode.stage_words(spec, blocks, sides, scratch,
                                 prepared=prepared)
             y = qdecode.natural_columns(qdecode.staged_product(
-                spec, K, ck, x_ref, scratch,
-                jnp.issubdtype(blocks[0].dtype, jnp.signedinteger)))
+                spec, K, ck, x_ref, scratch))
             return FUSED_ACTS[act](y[:, :rows]) * y[:, rows:]
         words = form == "words"
         accs = [
@@ -357,8 +356,12 @@ def _plan(ws) -> tuple:
 def call_plan(ws) -> str:
     """What `moe_qmatmul` will run for these stacks, for a route note: the
     loop, the grid steps an expert and the word tiles a step where it
-    holds several, `words:paired x1 of 3 tiles`."""
+    holds several, `words:inplace:paired x1 of 3 tiles`; `:inplace` where
+    the word path cuts the codes out signed where they lie
+    (`qdecode.signed_field`)."""
     ws = tuple(ws) if isinstance(ws, (tuple, list)) else (ws,)
     form, rows, held, _ = _plan(ws)
+    if form != "loop" and qdecode.signed_field(qdecode.spec_for(ws[0].spec)):
+        form = form.replace("words", "words:inplace")
     note = f"{form} x{ws[0].data.shape[-2] // (rows * held)}"
     return note if held == 1 else f"{note} of {held} tiles"

@@ -394,6 +394,14 @@ def decode_chunk(spec: DecodeSpec, K: int, w, side, e0: int, c: int):
 # tile with no uint8 -> int32 widening at all, so the result's columns come
 # out pack-major: `tile_product` returns them so, and the kernels put them
 # back once per tile before the store (`natural_columns`).
+#
+# What bounds the chunk loop now is VALU issue (the same script, PR 49: the
+# kernel's time falls by about 7 us for every operation a decoded vreg
+# sheds, at K x O = 14336 x 4096). So a field whose value is signed is cut
+# out of its word signed (`signed_field`): shift left, arithmetic shift
+# right, convert, multiply, cast, five operations where shift, mask,
+# subtract made six. ONE shift does not do it: a shift left clears what
+# lies above a field and leaves the word's lower fields below it.
 
 def word_scratch(spec: DecodeSpec, block_o: int, row_bytes: int, nb: int):
     """Scratch shapes of one weight stack's word path: the transposed
@@ -465,10 +473,43 @@ def _pair_columns(g, u):
     return jnp.concatenate(out, axis=1)
 
 
+def signed_field(spec: DecodeSpec) -> int:
+    """The width b of the codes the word path cuts out of the word SIGNED,
+    where they lie (`_packs`: a shift left and an arithmetic shift right),
+    or 0. Static, from the spec alone:
+
+    * byte codes that are integers (sym_int8, asym_int5, q3_k, q6_k: stored
+      int8): b = 8, the byte sign-extended;
+    * one plane of b-bit fields whose value is `code - 2^(b-1)` (sym_int4,
+      PR 49): `stage_words` flips every field's top bit once a tile, which
+      makes the field `code - 2^(b-1)` in two's complement, where the
+      unsigned field costs a shift, a mask and `decode_values`' subtract.
+      The same int32 either way, so the same float32 and the same bf16.
+
+    An asymmetric plane (its minimum would have to be re-centred, and
+    would round differently), two planes, a codebook and the float formats
+    keep the unsigned field."""
+    if not spec.planes:
+        return 8 if spec.value == ("offset", 0) else 0
+    if (len(spec.planes) == 1
+            and spec.value == ("offset", 1 << (spec.planes[0] - 1))):
+        return spec.planes[0]
+    return 0
+
+
+def _top_bits(bits: int):
+    """int32 with the top bit of every `bits`-wide field set."""
+    mask = sum(1 << j for j in range(bits - 1, 32, bits))
+    return jnp.int32(mask - (1 << 32) if mask >> 31 else mask)
+
+
 def stage_words(spec: DecodeSpec, w_refs, side_refs, scratch,
                 piece: int = 2048, prepared: bool = False):
     """Once per grid step: the tile's words and its effective scales,
-    transposed into `scratch` (see `word_scratch`). `w_refs` holds the
+    transposed into `scratch` (see `word_scratch`), the words with their
+    fields' top bits flipped where a plane's fields are cut out signed
+    (`signed_field`: one operation a WORD vreg, an eighth of one a decoded
+    vreg, before the transpose). `w_refs` holds the
     tile's code blocks and `side_refs` each block's side refs: one block of
     512 rows, or the 256-row gate and up blocks of a gated expert call
     (`tiling.grouped_tile`), whose 64 + 64 word rows are stacked on
@@ -479,11 +520,13 @@ def stage_words(spec: DecodeSpec, w_refs, side_refs, scratch,
     wT_ref, s32_ref, sT_ref = scratch
     row_bytes = w_refs[0].shape[1]
     q = sum(r.shape[0] for r in w_refs) // WORD_ROWS
+    # (a byte code is signed as stored: only a plane's fields are flipped)
+    flip = signed_field(spec) if spec.planes else 0
     for j0 in range(0, row_bytes, piece):
         cw = min(piece, row_bytes - j0)
         words = [pltpu.bitcast(r[:, j0:j0 + cw], jnp.int32) for r in w_refs]
-        wT_ref[j0:j0 + cw, :] = (
-            words[0] if len(words) == 1 else jnp.concatenate(words, axis=0)).T
+        words = words[0] if len(words) == 1 else jnp.concatenate(words, axis=0)
+        wT_ref[j0:j0 + cw, :] = (words ^ _top_bits(flip) if flip else words).T
     if prepared:
         for i in range(spec.n_side):
             a = [f16_bits_to_f32(refs[i][...]) for refs in side_refs]
@@ -507,21 +550,23 @@ def _packs(words, shift: int, bits: int, signed: bool = False):
     """[c, bo/4] words -> [c, bo] int32: the `bits`-wide field at
     `shift` of each of the four bytes, pack p on lanes p*bo/4 .. (a
     128-aligned lane concatenation places vregs, it moves nothing)."""
-    if signed:  # int8 codes: sign-extend the byte
+    if signed:  # `signed_field`: sign-extend the field where it lies
+        up = [32 - bits - shift - 8 * p for p in range(WORD_ROWS)]
         return jnp.concatenate(
-            [(words << (24 - 8 * p)) >> 24 for p in range(WORD_ROWS)], axis=1)
+            [(words << u if u else words) >> (32 - bits) for u in up], axis=1)
     return jnp.concatenate(
         [(words >> (8 * p + shift)) & ((1 << bits) - 1)
          for p in range(WORD_ROWS)], axis=1)
 
 
-def word_codes(spec: DecodeSpec, K: int, wT_ref, signed: bool, seg: int,
-               off, c: int):
+def word_codes(spec: DecodeSpec, K: int, wT_ref, seg: int, off, c: int):
     """int32 codes [c, bo] (pack-major lanes) of the `c` logical elements
     at `off` (a Python int or a traced index, a multiple of 8) within
     segment `seg` of the finest plane split: `plane_chunk_code` with the
     byte axis on sublanes. Within a segment every plane's split index is
-    static, so the shifts are."""
+    static, so the shifts are. A signed field (`signed_field`) comes out
+    as the value itself, `code - 2^(b-1)` or the byte."""
+    signed = bool(signed_field(spec))
     if not spec.planes:
         return _packs(wT_ref[pl.ds(off, c), :], 0, 8, signed)
     e_seg = seg * finest_split(K, spec.planes)
@@ -530,7 +575,7 @@ def word_codes(spec: DecodeSpec, K: int, wT_ref, signed: bool, seg: int,
     for r_plane, bits, _s, qel in plane_layout(K, spec.planes):
         mp = e_seg // qel
         words = wT_ref[pl.ds(r_plane + e_seg - mp * qel + off, c), :]
-        piece = _packs(words, bits * mp, bits)
+        piece = _packs(words, bits * mp, bits, signed)
         code = piece if code is None else code | (piece << shift)
         shift += bits
     return code
@@ -543,13 +588,13 @@ def _rows_repeat(a, block: int):
     return jnp.broadcast_to(a[:, None, :], (n, block, q)).reshape(n * block, q)
 
 
-def decode_chunk_words(spec: DecodeSpec, K: int, wT_ref, sT_ref, signed: bool,
-                       seg: int, off, c: int):
+def decode_chunk_words(spec: DecodeSpec, K: int, wT_ref, sT_ref, seg: int,
+                       off, c: int):
     """bf16 weights [c, bo] (k on sublanes, the tile's rows on lanes,
     pack-major) of the `c` elements at `off` within segment `seg`: the
     values `decode_chunk` gives, bit for bit."""
-    vals = decode_values(word_codes(spec, K, wT_ref, signed, seg, off, c),
-                         spec.value)
+    vals = decode_values(word_codes(spec, K, wT_ref, seg, off, c),
+                         ("offset", 0) if signed_field(spec) else spec.value)
     e_seg = seg * finest_split(K, spec.planes)
     nsc = c // spec.block
     if isinstance(off, int):
@@ -596,20 +641,17 @@ def tile_product(spec: DecodeSpec, K: int, ck: int, x_ref, w_ref, side_refs,
                 preferred_element_type=jnp.float32)
         return acc
     stage_words(spec, (w_ref,), (side_refs,), scratch, prepared=prepared)
-    return staged_product(
-        spec, K, ck, x_ref, scratch,
-        jnp.issubdtype(w_ref.dtype, jnp.signedinteger))
+    return staged_product(spec, K, ck, x_ref, scratch)
 
 
-def staged_product(spec: DecodeSpec, K: int, ck: int, x_ref, scratch,
-                   signed: bool):
+def staged_product(spec: DecodeSpec, K: int, ck: int, x_ref, scratch):
     """`tile_product`'s chunk loop over the word tile `stage_words` left
     in `scratch`: float32 [block_m, 512], columns pack-major."""
     wT_ref, _, sT_ref = scratch
     qmin = finest_split(K, spec.planes)
 
     def chunk(acc, seg, off, c):
-        wd = decode_chunk_words(spec, K, wT_ref, sT_ref, signed, seg, off, c)
+        wd = decode_chunk_words(spec, K, wT_ref, sT_ref, seg, off, c)
         xs = x_ref[:, pl.ds(pl.multiple_of(seg * qmin + off, 128), c)]
         return acc + jax.lax.dot_general(
             xs.astype(jnp.bfloat16), wd, (((1,), (0,)), ((), ())),

@@ -255,20 +255,27 @@ def _side_arrays(spec: DecodeSpec, scales, mins, sub_scales, sub_mins):
     return (_f16_bits(scales),)
 
 
-def bits_layout(spec: DecodeSpec, O: int, row_bytes: int,
-                cap: int = WORD_BLOCK_O):
-    """How `_fused` reads prepared scale bits for a weight `[O, row_bytes]`
-    of codes, from the static shapes its tile plan is picked from:
-    ``"words"`` where the word path runs (`qdecode.pack_major_bits` of
-    512-row tiles), ``"stored"`` on the stored-layout loop (the uint16 view,
-    made once), None for the two-level formats, whose effective scales are
-    products the kernel forms in the stored layout."""
-    if spec.super_block:
-        return None
+def tile_form(spec: DecodeSpec, O: int, row_bytes: int,
+              cap: int = WORD_BLOCK_O) -> str:
+    """Which loop decodes `_fused`'s tiles of a weight `[O, row_bytes]` of
+    codes (no adapter), from the static shapes its tile plan is picked
+    from: ``"words"`` the word path, ``"stored"`` the stored-layout loop.
+    (A row's side bytes are priced as float16 a block and side array: what
+    a single-level format holds, and more than a two-level one does.)"""
     nb = row_bytes * 8 // (sum(spec.planes) or 8) // spec.block
     block_o = pick_block_o(O, row_bytes + spec.n_side * nb * 2, cap=cap,
                            row_bytes=row_bytes)
     return "words" if words_ok(block_o, row_bytes) else "stored"
+
+
+def bits_layout(spec: DecodeSpec, O: int, row_bytes: int,
+                cap: int = WORD_BLOCK_O):
+    """How `_fused` reads prepared scale bits for a weight `[O, row_bytes]`
+    of codes: `tile_form`'s name (``"words"``: `qdecode.pack_major_bits` of
+    512-row tiles; ``"stored"``: the uint16 view, made once), None for the
+    two-level formats, whose effective scales are products the kernel forms
+    in the stored layout."""
+    return None if spec.super_block else tile_form(spec, O, row_bytes, cap)
 
 
 def _fused(x, data, spec: DecodeSpec, side, out_dtype, block_o, interpret,

@@ -16,16 +16,30 @@ bytes that must move (packed codes, scales, x, y) over 819 GB/s is
 
 Bodies:
 
-* `tree`: `bigdl_tpu.ops.pallas.qmatmul._qmm` as it stands (variant d);
+* `tree`: `bigdl_tpu.ops.pallas.qmatmul._qmm` as it stands, the float16
+  scales viewed as uint16 and staged every grid step; `prep`: the same on
+  prepared scale bits (`qdecode.pack_major_bits`), which is what a cell
+  runs since PR 48;
 * `words`: this script's copy of the word path (`qdecode.tile_product`
   with scratch): the tile read as 32-bit words, transposed once, decoded
-  with k on sublanes. Variants: d whole, a scales not spread (one
-  broadcast row), b codes not decoded (the raw byte of the word), c
-  nothing computed (tiles fetched, output zero);
+  with k on sublanes. A variant is letters joined by `-`. The decode of a
+  weight: `d` the chain the tree ran until PR 49 (shift, mask, subtract,
+  convert, multiply, cast); `s` the nibbles' top bits flipped once a tile
+  and the field cut out signed (shift left, arithmetic shift right: what
+  the tree runs since PR 49); `i` the flipped field masked where it lies
+  and the scale carrying 2^-28 (shift left, mask); `i-1` ISSUE 49's one
+  shift alone, which is NOT the dequantizer's weights (the word's lower
+  nibbles stay below the field) and is kept as a time only. What is taken
+  out: `a` scales not spread (one broadcast row); `b` the SUBTRACT alone
+  (the raw byte of the word: it still shifts, masks and converts, so it
+  prices one operation of the chain and not "the code decode"); `t` the
+  tile staged on a call's first grid step alone (no transpose of words or
+  scales on any other); `g` no `natural_columns` (the columns stored
+  pack-major); `c` nothing computed (tiles fetched, output zero);
 * `rows`: the copy of the loop as it was before PR 32 (and still is where
   no 512-row tile fits, and in `qbackward`): stored [o, k] layout, scales
-  spread over lanes by a float32 one-hot matmul per chunk. Same variants,
-  and `ab`: both taken out (widen, convert, cast and the product alone).
+  spread over lanes by a float32 one-hot matmul per chunk. Variants `d`,
+  `a`, `b` and `a-b` (widen, convert, cast and the product alone).
 
 `--plan experts` (PR 44) does the same for the grouped expert kernel
 `moe_qmatmul` at the four MoE cells' expert shapes, one line a (shape,
@@ -36,7 +50,9 @@ step costs over its bytes' time (`step_over_us`). Variants, in
 stored-layout loop at 256-row tiles (what a 768-wide gated call ran before
 PR 44); `fetch` nothing computed; `paired` the gated call's two 256-row
 blocks decoded as ONE 512-row word tile; `mb` / `one` several word tiles a
-grid step (about 1 MB of codes; a whole expert).
+grid step (about 1 MB of codes; a whole expert); `chain`, `signed`,
+`inplace`: the tree's own plan (`tiling.grouped_tile`) on this script's
+copies of the decode, `d`, `s` and `i` above.
 
 It also checks, on the device it runs on, that what each body feeds the
 MXU is the dequantizer's weights bit for bit. The CPU interpreter cannot
@@ -78,7 +94,7 @@ HBM_BYTES_PER_S = 819e9  # TPU v5e, Google Cloud documentation
 
 # ----------------------------------------------------------- the two bodies
 
-def rows_body(x, w_ref, s_ref, *, K, ck, variant):
+def rows_body(x, w_ref, s_ref, *, K, ck, flags):
     """The stored-layout loop (`tile_product` without scratch), sym_int4."""
     bo, kh = w_ref.shape[0], K // 2
     s = qdecode.f16_bits_to_f32(s_ref[:])
@@ -87,11 +103,11 @@ def rows_body(x, w_ref, s_ref, *, K, ck, variant):
     for e0, c in qdecode.walk(K, SPEC.planes, ck):
         mp = e0 // kh
         wb = qdecode.slc(w, e0 - mp * kh, c).astype(jnp.int32)
-        if "b" in variant:
+        if "b" in flags:
             v = wb.astype(jnp.float32)
         else:
             v = (((wb >> (4 * mp)) & 15) - 8).astype(jnp.float32)
-        if "a" in variant:
+        if "a" in flags:
             sx = jnp.broadcast_to(qdecode.slc(s, e0 // 32, 1), (bo, c))
         else:
             sx = qdecode.expand_scales(qdecode.slc(s, e0 // 32, c // 32), c, 32,
@@ -102,66 +118,140 @@ def rows_body(x, w_ref, s_ref, *, K, ck, variant):
     return acc
 
 
-def words_body(x_ref, w_ref, s_ref, scratch, *, K, ck, variant):
-    """The word path (`tile_product` with scratch), sym_int4: the chunks of
-    each nibble half one loop body, unrolled when it is lowered; the four
-    packs side by side on lanes. (`split` in the variant: a chain and a
-    dot per pack instead; `roll`: the loop left rolled.)"""
-    qdecode.stage_words(SPEC, (w_ref,), ((s_ref,),), scratch)
-    wT_ref, _, sT_ref = scratch
-    bo, kh = w_ref.shape[0], K // 2
-    q = bo // 4
-    split = "split" in variant
+FLIP = np.int32(-0x77777778)  # 0x88888888: the top bit of every nibble
+TOP = np.int32(-0x10000000)  # 0xF0000000: a word's last nibble
 
-    def weights(words, sx, h, lanes):
-        if "b" in variant:
-            v = ((words >> (8 * lanes)) & 0xFF if split
-                 else qdecode._packs(words, 0, 8)).astype(jnp.float32)
-        else:
-            code = ((words >> (8 * lanes + 4 * h)) & 15 if split
-                    else qdecode._packs(words, 4 * h, 4))
-            v = (code - 8).astype(jnp.float32)
+
+# this script's copies of the word path, by name: the chain of six, and the
+# two forms that convert a flipped nibble (`pack_values`)
+COPIES = {"chain": "d", "signed": "s", "inplace": "i"}
+
+
+def flags_of(variant):
+    """`a-b`, `i-t`: a variant's letters, joined by `-`."""
+    return frozenset(variant.split("-"))
+
+
+def stage_copy(w_refs, s_refs, scratch, flags):
+    """`qdecode.stage_words` for sym_int4's stored scales (one 512-row block,
+    or a gated pair's two 256-row blocks), as it was before PR 49. `i` and
+    `s` flip the nibbles' top bits on the way (the field is then `code - 8`
+    in two's complement), and `i` has the scales carry 2^-28."""
+    wT_ref, s32_ref, sT_ref = scratch
+    row_bytes = w_refs[0].shape[1]
+    q = sum(r.shape[0] for r in w_refs) // 4
+    for j0 in range(0, row_bytes, 2048):
+        cw = min(2048, row_bytes - j0)
+        words = [pltpu.bitcast(r[:, j0:j0 + cw], jnp.int32) for r in w_refs]
+        words = words[0] if len(words) == 1 else jnp.concatenate(words, axis=0)
+        wT_ref[j0:j0 + cw, :] = (words ^ FLIP if flags & {"i", "s"}
+                                 else words).T
+    a = [qdecode.f16_bits_to_f32(r[...]) for r in s_refs]
+    a = qdecode._pad_lanes(a[0] if len(a) == 1 else jnp.concatenate(a, axis=0))
+    if "i" in flags:
+        a = a * jnp.float32(2.0 ** -28)
+    for g in range(a.shape[-1] // 128):
+        s32_ref[g] = qdecode.slc(a, g * 128, 128)
+        for p in range(4):
+            sT_ref[0, g * 128:(g + 1) * 128, p * q:(p + 1) * q] = s32_ref[
+                g, pl.ds(p, q, stride=4), :].T
+
+
+def pack_values(words, p, h, flags):
+    """float32 values of pack p, nibble half h of a chunk's words. `d`: the
+    chain of shift, mask, subtract and convert; `b`: without the subtract
+    and on the whole byte; on flipped nibbles `s`: shift left and
+    arithmetic shift right, `code - 8` sign-extended, and `i`: shift left
+    and mask where the field lies, `(code - 8) * 2^28` (the word's first
+    nibble needs no mask, its last no shift)."""
+    up = 28 - 8 * p - 4 * h
+    if "i" in flags:
+        u = words << up if up else words
+        if "1" in flags:  # ISSUE 49's one shift alone: NOT the values (the
+            return u.astype(jnp.float32)  # nibbles below stay), a time only
+        return (u & TOP if up < 28 else u).astype(jnp.float32)
+    if "s" in flags:
+        return ((words << up if up else words) >> 28).astype(jnp.float32)
+    if "b" in flags:
+        return ((words >> (8 * p)) & 0xFF).astype(jnp.float32)
+    return (((words >> (8 * p + 4 * h)) & 15) - 8).astype(jnp.float32)
+
+
+def words_product(x_ref, scratch, *, K, ck, flags):
+    """The word path's chunk loop (`qdecode.staged_product`), sym_int4: the
+    chunks of each nibble half one loop body, unrolled when it is lowered;
+    the four packs side by side on lanes. (`split`: a chain and a dot per
+    pack instead; `roll`: the loop left rolled.)"""
+    wT_ref, _, sT_ref = scratch
+    kh = K // 2
+    q = wT_ref.shape[1]
+    split = "split" in flags
+
+    def weights(words, sx, h, packs):
+        v = [pack_values(words, p, h, flags) for p in packs]
+        v = v[0] if len(v) == 1 else jnp.concatenate(v, axis=1)
         return (v * sx).astype(jnp.bfloat16)
 
     acc = tuple(jnp.zeros((x_ref.shape[0], q), jnp.float32) for _ in range(4)
-                ) if split else jnp.zeros((x_ref.shape[0], bo), jnp.float32)
+                ) if split else jnp.zeros((x_ref.shape[0], 4 * q), jnp.float32)
     for h in range(2):
         def chunk(i, acc, h=h):
-            off = pl.multiple_of(i * ck, ck)
+            if kh == ck:  # one chunk a half (K = 768): static offsets
+                off, sb0, x0 = 0, (h * kh) // 32, h * kh
+            else:
+                off = pl.multiple_of(i * ck, ck)
+                sb0 = pl.multiple_of((h * kh) // 32 + off // 32, 8)
+                x0 = pl.multiple_of(h * kh + off, 128)
             words = wT_ref[pl.ds(off, ck), :]
-            sb0 = pl.multiple_of((h * kh) // 32 + off // 32, 8)
-            rows = pl.ds(0, 1) if "a" in variant else pl.ds(sb0, ck // 32)
-            xs = x_ref[:, pl.ds(pl.multiple_of(h * kh + off, 128), ck)
-                       ].astype(jnp.bfloat16)
+            rows = pl.ds(0, 1) if "a" in flags else pl.ds(sb0, ck // 32)
+            xs = x_ref[:, pl.ds(x0, ck)].astype(jnp.bfloat16)
             dot = lambda wd: jax.lax.dot_general(
                 xs, wd, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             spread = lambda s: (jnp.broadcast_to(s, (ck, s.shape[1]))
-                                if "a" in variant
+                                if "a" in flags
                                 else qdecode._rows_repeat(s, 32))
             if split:
                 return tuple(
                     acc[p] + dot(weights(
                         words, spread(sT_ref[0, rows, p * q:(p + 1) * q]),
-                        h, p)) for p in range(4))
-            return acc + dot(weights(words, spread(sT_ref[0, rows, :]), h, 0))
-        acc = jax.lax.fori_loop(0, kh // ck, chunk, acc,
-                                unroll="roll" not in variant)
+                        h, (p,))) for p in range(4))
+            return acc + dot(weights(words, spread(sT_ref[0, rows, :]), h,
+                                     range(4)))
+        acc = chunk(0, acc) if kh == ck else jax.lax.fori_loop(
+            0, kh // ck, chunk, acc, unroll="roll" not in flags)
     return jnp.concatenate(acc, axis=1) if split else acc
+
+
+def words_body(x_ref, w_ref, s_ref, scratch, *, K, ck, flags):
+    """The word path (`tile_product` with scratch). `t`: the tile is staged
+    on the call's first grid step alone, so every other step runs the chunk
+    loop on what is there and transposes nothing."""
+    def stage():
+        stage_copy((w_ref,), (s_ref,), scratch, flags)
+
+    if "t" in flags:
+        pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))(stage)
+    else:
+        stage()
+    return words_product(x_ref, scratch, K=K, ck=ck, flags=flags)
 
 
 def _kernel(layer_ref, x_ref, w_ref, s_ref, o_ref, *scratch, K, ck, body,
             variant):
     del layer_ref
-    if variant == "c":
+    flags = flags_of(variant)
+    if "c" in flags:
         o_ref[:] = jnp.zeros(o_ref.shape, o_ref.dtype)
         return
     if body == "words":
-        acc = qdecode.natural_columns(words_body(
-            x_ref, w_ref, s_ref, scratch, K=K, ck=ck, variant=variant))
+        acc = words_body(x_ref, w_ref, s_ref, scratch, K=K, ck=ck,
+                         flags=flags)
+        if "g" not in flags:  # `g`: the columns left pack-major
+            acc = qdecode.natural_columns(acc)
     else:
         acc = rows_body(x_ref[:].astype(jnp.bfloat16), w_ref, s_ref, K=K,
-                        ck=ck, variant=variant)
+                        ck=ck, flags=flags)
     o_ref[:] = acc.astype(o_ref.dtype)
 
 
@@ -176,7 +266,7 @@ def qmm_copy(layer, x2, w, s, *, block_m, block_o, ck, body, variant):
                if body == "words" else [])
     return pl.pallas_call(
         functools.partial(_kernel, K=K, ck=ck, body=body, variant=variant),
-        name=f"qmatmul_{body}_{variant}",
+        name=f"qmatmul_{body}_{variant}".replace("-", "_"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(Mp // block_m, O // block_o),
@@ -191,14 +281,16 @@ def qmm_copy(layer, x2, w, s, *, block_m, block_o, ck, body, variant):
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, O), jnp.bfloat16),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            # `t` stages on the first step alone: the steps run in order
+            dimension_semantics=("arbitrary",) * 2 if "t" in flags_of(variant)
+            else ("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(layer, x2, w, s)
 
 
 def tiles(body, M, K, O):
-    """The policy's tiles: the tree's for `tree` and `words`, the 256-row
-    ones the stored-layout loop had for `rows`."""
+    """The policy's tiles: the tree's for `tree`, `prep` and `words`, the
+    256-row ones the stored-layout loop had for `rows`."""
     block_m = pick_block_m(M, K)
     persist_row = K // 2 + (K // 32) * 2
     block_o = pick_block_o(O, persist_row, cap=256 if body == "rows" else 512,
@@ -212,14 +304,15 @@ def tiles(body, M, K, O):
 def build(body, variant, M, K, O):
     """-> (run(n, x, w, s): n dependent calls, call(layer, x, w, s), tiles)."""
     block_m, block_o, ck = tiles(body, M, K, O)
-    if body == "words" and not words_ok(block_o, K // 2):
+    if body in ("words", "prep") and not words_ok(block_o, K // 2):
         return None
-    if body == "tree":
+    if body in ("tree", "prep"):
         qm = importlib.import_module("bigdl_tpu.ops.pallas.qmatmul")
 
         def call(layer, x, w, s):
             return qm._qmm(SPEC, jnp.dtype(jnp.bfloat16), block_m, block_o,
-                           ck, False, False, layer, x, w, s)
+                           ck, False, False, "words" if body == "prep"
+                           else None, layer, x, w, s)
     else:
         def call(layer, x, w, s):
             return qmm_copy(layer, x, w, s, block_m=block_m, block_o=block_o,
@@ -239,18 +332,23 @@ def build(body, variant, M, K, O):
     return run, call, (block_m, block_o, ck)
 
 
-def operands(M, K, O, block_m, key, sharding=None):
+def operands(M, K, O, block_m, key, sharding=None, prepared=False):
+    """x, the codes of two layers and one layer's scale bits as stored
+    `[O, nb]`, or ``prepared`` (`qdecode.pack_major_bits`, both layers')."""
     Mp = round_up(M, block_m)
     if sharding is not None:  # shapes alone, for a described device
         sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=sharding)
         return (sds((Mp, K), jnp.bfloat16), sds((2, O, K // 2), jnp.uint8),
-                sds((O, K // 32), jnp.uint16))
+                sds((2, O // 512, K // 32, 512) if prepared
+                    else (O, K // 32), jnp.uint16))
     k1, k2, k3 = jax.random.split(key, 3)
     x = jax.random.normal(k1, (Mp, K), jnp.float32).astype(jnp.bfloat16)
     w = jax.random.randint(k2, (2, O, K // 2), 0, 256, jnp.int32
                            ).astype(jnp.uint8)
     s = (jax.random.uniform(k3, (O, K // 32)) * 0.01 + 0.001
          ).astype(jnp.float16)
+    if prepared:
+        return x, w, qdecode.pack_major_bits(jnp.stack([s, s]), 512)
     return x, w, jax.lax.bitcast_convert_type(s, jnp.uint16)
 
 
@@ -259,7 +357,7 @@ def measure(body, variant, M, K, O, key, ns=(16, 32, 64), reps=3):
     if built is None:
         return None
     run, _, (block_m, block_o, ck) = built
-    x, w, s = operands(M, K, O, block_m, key)
+    x, w, s = operands(M, K, O, block_m, key, prepared=body == "prep")
     jax.block_until_ready(run(2, x, w, s))
     ts = []
     for n in ns:
@@ -290,26 +388,45 @@ def fed_weights_check():
         for e0, c in qdecode.walk(K, SPEC.planes, 2048):
             o_ref[:, e0:e0 + c] = qdecode.decode_chunk(SPEC, K, w, side, e0, c)
 
-    def words_kern(w_ref, s_ref, o_ref, *scratch):
-        qdecode.stage_words(SPEC, (w_ref,), ((s_ref,),), scratch)
+    def words_kern(w_ref, s_ref, o_ref, *scratch, form):
+        """`words`: the tree's word path; a name of `COPIES`: this
+        script's copy of it (`stage_copy`, `pack_values`)."""
+        flags = flags_of(COPIES.get(form, ""))
+        if form == "words":
+            qdecode.stage_words(SPEC, (w_ref,), ((s_ref,),), scratch)
+        else:
+            stage_copy((w_ref,), (s_ref,), scratch, flags)
         for seg in range(2):
             for c0 in range(0, K // 2, 512):
-                o_ref[seg * (K // 2) + c0:seg * (K // 2) + c0 + 512, :] = \
-                    qdecode.decode_chunk_words(
-                        SPEC, K, scratch[0], scratch[2], False, seg, c0, 512)
+                if form == "words":
+                    wd = qdecode.decode_chunk_words(
+                        SPEC, K, scratch[0], scratch[2], seg, c0, 512)
+                else:
+                    v = jnp.concatenate(
+                        [pack_values(scratch[0][c0:c0 + 512, :], p, seg, flags)
+                         for p in range(4)], axis=1)
+                    sb0 = (seg * (K // 2) + c0) // 32
+                    wd = (v * qdecode._rows_repeat(
+                        scratch[2][0, sb0:sb0 + 16, :], 32)
+                          ).astype(jnp.bfloat16)
+                o_ref[seg * (K // 2) + c0:seg * (K // 2) + c0 + 512, :] = wd
 
     k2, k3 = jax.random.split(jax.random.key(1))
     w = jax.random.randint(k2, (bo, K // 2), 0, 256, jnp.int32
                            ).astype(jnp.uint8)
     sc = (jax.random.uniform(k3, (bo, K // 32)) * 0.01 + 0.001
           ).astype(jnp.float16)
+    # float16's corners too: `i` carries 2^-28 on the scale
+    sc = sc.at[:8, :8].set(jnp.asarray(
+        [2.0 ** -24, 65504.0, -65504.0, 0.0, -0.0, -2.0 ** -24, 2.0 ** -14,
+         -0.005], jnp.float16)[:, None])
     bits = jax.lax.bitcast_convert_type(sc, jnp.uint16)
     params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
     rows = pl.pallas_call(
         rows_kern, out_shape=jax.ShapeDtypeStruct((bo, K), jnp.bfloat16),
         compiler_params=params)(w, bits)
-    words = pl.pallas_call(
-        words_kern,
+    by_words = lambda form: pl.pallas_call(
+        functools.partial(words_kern, form=form),
         out_shape=jax.ShapeDtypeStruct((K, bo), jnp.bfloat16),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0, grid=(1,),
@@ -319,14 +436,16 @@ def fed_weights_check():
             scratch_shapes=qdecode.word_scratch(SPEC, bo, K // 2, K // 32)),
         compiler_params=params)(w, bits)
     # lane p * bo/4 + i of the tile is its row 4i + p
-    words = jnp.transpose(words.reshape(K, 4, bo // 4), (2, 1, 0)
-                          ).reshape(bo, K)
+    natural = lambda t: jnp.transpose(t.reshape(K, 4, bo // 4), (2, 1, 0)
+                                      ).reshape(bo, K)
     wn = np.asarray(w).astype(np.int32)
     codes = np.concatenate([wn & 15, wn >> 4], axis=1) - 8
     sf = np.repeat(np.asarray(sc).astype(np.float32), 32, axis=1)
     want = np.asarray(jnp.asarray(codes.astype(np.float32) * sf
                                   ).astype(jnp.bfloat16).astype(jnp.float32))
-    for name, got in (("rows", rows), ("words", words)):
+    for name, got in (("rows", rows), *(
+            (form, natural(by_words(form)))
+            for form in ("words", *COPIES))):
         g = np.asarray(got.astype(jnp.float32))
         bad = g != want
         rel = (np.abs(g - want)[bad] / np.abs(want)[bad]).max() if bad.any() \
@@ -394,6 +513,16 @@ def experts_kernel(te_ref, meta_ref, x_ref, *refs, K, ck, n_w, variant, tiles,
             o_ref[:] = y.astype(o_ref.dtype)
             return
         rows = 256 if paired else 512  # of a stack, a word tile
+        if variant in COPIES:  # this script's copies
+            flags = flags_of(COPIES[variant])
+            stage = lambda ws, ss, sc: stage_copy(
+                ws, [s[0] for s in ss], sc, flags)
+            product = lambda sc: words_product(x_ref, sc, K=K, ck=ck,
+                                               flags=flags)
+        else:
+            stage = lambda ws, ss, sc: qdecode.stage_words(SPEC, ws, ss, sc)
+            product = lambda sc: qdecode.staged_product(SPEC, K, ck, x_ref,
+                                                        sc)
         for j in range(tiles):
             def cut(r):
                 return r if tiles == 1 else r.at[pl.ds(j * rows, rows), :]
@@ -402,17 +531,15 @@ def experts_kernel(te_ref, meta_ref, x_ref, *refs, K, ck, n_w, variant, tiles,
             ss = [(refs[2 * i + 1][:][j * rows:(j + 1) * rows],)
                   for i in range(n_w)]
             if paired:
-                qdecode.stage_words(SPEC, ws, ss, scratch[:3])
-                y = qdecode.natural_columns(qdecode.staged_product(
-                    SPEC, K, ck, x_ref, scratch[:3], False))
+                stage(ws, ss, scratch[:3])
+                y = qdecode.natural_columns(product(scratch[:3]))
                 y = silu(y[:, :256]) * y[:, 256:]
             else:
                 accs = []
                 for i in range(n_w):
                     sc = scratch[3 * i:3 * i + 3]
-                    qdecode.stage_words(SPEC, ws[i:i + 1], ss[i:i + 1], sc)
-                    accs.append(qdecode.staged_product(
-                        SPEC, K, ck, x_ref, sc, False))
+                    stage(ws[i:i + 1], ss[i:i + 1], sc)
+                    accs.append(product(sc))
                 y = qdecode.natural_columns(
                     accs[0] if n_w == 1 else silu(accs[0]) * accs[1])
             o_ref[:, j * rows:(j + 1) * rows] = y.astype(o_ref.dtype)
@@ -476,6 +603,13 @@ def experts_tiles(variant, K, O, n_w):
             bo = 256
         return bo, 1, forward_chunk(False, bo * n_w, 0, qmin, SPEC.block,
                                     False)
+    if variant in COPIES:  # the tree's own plan
+        from bigdl_tpu.ops.pallas.tiling import grouped_tile
+
+        form, rows, held = grouped_tile(O, row, K // 2, n_w)
+        if form == "loop":
+            return None
+        return rows * held, held, words_chunk(qmin, SPEC.block)
     paired = n_w == 2 and O % 512 == 256
     if variant == "paired" and not paired:
         return None
@@ -614,7 +748,7 @@ def experts_check():
         block_m = args[2].shape[0] // args[0].shape[0]
         want = experts_build("tree", "granite", K, O, gated)[1](*args)
         want = np.asarray(want[:hit * block_m].astype(jnp.float32))
-        for v in ("loop", "paired", "mb", "one"):
+        for v in ("loop", "paired", "mb", "one", *COPIES):
             built = experts_build(v, "granite", K, O, gated)
             if built is None:
                 continue
@@ -630,7 +764,8 @@ def experts_plan():
     for name, (_, _, _, _, shapes) in EXPERT_SHAPES.items():
         for K, O, gated in shapes:
             plan += [(v, name, K, O, gated)
-                     for v in ("tree", "loop", "fetch", "paired", "mb", "one")]
+                     for v in ("tree", *COPIES, "loop", "fetch", "paired",
+                               "mb", "one")]
     return plan
 
 
@@ -665,12 +800,17 @@ def plan_of(name):
                          for v in ("d", "d-split", "d-roll", "d-split-roll")]
         return plan
     shapes = cell_shapes()
-    if name == "mistral":  # every variant, every M
+    if name == "mistral":  # every variant, at the cells' M
         for K, O in shapes["mistral-7b-int4"][0]:
             for M in (1, 8, 16, 32):
-                plan += [("tree", "d", M, K, O)]
-                plan += [("words", v, M, K, O) for v in "abc"]
-                plan += [("rows", v, M, K, O) for v in ("d", "a", "b", "ab")]
+                plan += [("tree", "d", M, K, O), ("prep", "d", M, K, O),
+                         ("rows", "d", M, K, O)]
+                plan += [("words", v, M, K, O) for v in (
+                    "d", "s", "i", "i-1", "a", "b", "c")]
+                if M in (1, 32):  # what is left beside the chain
+                    plan += [("words", v, M, K, O) for v in (
+                        "i-a", "t", "i-t", "g", "i-g")]
+                    plan += [("rows", v, M, K, O) for v in ("a", "b", "a-b")]
         return plan
     for cfg, (kos, M) in shapes.items():  # the before / after table
         for K, O in kos:
@@ -715,7 +855,8 @@ def main() -> int:
                 continue
             run, _, (block_m, _, _) = built
             run.lower(jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
-                      *operands(M, K, O, block_m, None, one)).compile()
+                      *operands(M, K, O, block_m, None, one, body == "prep")
+                      ).compile()
             print(f"ok {body} {v} M={M} K={K} O={O}", flush=True)
         return 0
 

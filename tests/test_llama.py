@@ -489,6 +489,15 @@ def test_bench_configurations_read_every_projection_from_the_stack(
                                                      params=params)
             assert (n_stack, n_slice) == (stacked, 1), routes
             assert scales == {want}, routes
+            # ISSUE 49: every packed call but a head with no 512-row tile
+            # (Mistral's and Mixtral's 32000 rows) decodes in place
+            for (op, _, detail), _n in routes.items():
+                if op == "linear":
+                    assert ("words:inplace" in detail) == (
+                        "stack" in detail.split()
+                        or int(detail.split()[3][1:]) % 512 == 0), detail
+                elif op == "moe":
+                    assert detail.count("words:inplace") == 2, detail
             if cfg.is_moe:
                 assert any(k[:2] == ("moe", "pallas:grouped")
                            for k in routes)

@@ -369,14 +369,14 @@ def test_grouped_dispatch_drops_nothing(interpret, tiny_mixtral, routing,
 
 
 @pytest.mark.parametrize("act,gated,want", [
-    ("silu", True, "gate_up words:paired x1 of 3 tiles, "
-                   "down words x1 of 8 tiles"),
-    ("relu", True, "gate_up words:paired x1 of 3 tiles, "
-                   "down words x1 of 8 tiles"),
+    ("silu", True, "gate_up words:inplace:paired x1 of 3 tiles, "
+                   "down words:inplace x1 of 8 tiles"),
+    ("relu", True, "gate_up words:inplace:paired x1 of 3 tiles, "
+                   "down words:inplace x1 of 8 tiles"),
     # not fused: two plain 768-wide calls, which stay on the stored-layout
     # loop (ISSUE 44: no third form), as phixtral's fc1 does
-    ("gelu_pytorch_tanh", True, "gate, up loop x3, down words x1 of 8 tiles"),
-    ("gelu_new", False, "up loop x3, down words x1 of 8 tiles"),
+    ("gelu_pytorch_tanh", True, "gate, up loop x3, down words:inplace x1 of 8 tiles"),
+    ("gelu_new", False, "up loop x3, down words:inplace x1 of 8 tiles"),
 ])
 def test_grouped_route_note_names_each_calls_tile_plan(act, gated, want):
     """The route note of a grouped MoE layer says which loop each of its

@@ -64,10 +64,11 @@ def _kernel_data(qt):
                                     qt.sub_mins)
 
 
-def _decoded_by_words(qts, K, ck=None):
+def _decoded_by_words(qts, K, ck=None, prepared=False):
     """[O, K] bf16: what the word path feeds the MXU for one tile, its
     chunks rolled or unrolled as the kernel would have them. `qts`: the
-    tile's one block of 512 rows, or the gated pair's two of 256."""
+    tile's one block of 512 rows, or the gated pair's two of 256.
+    ``prepared``: the scales as `pack_major_bits` lays them out once."""
     qts = qts if isinstance(qts, (tuple, list)) else (qts,)
     blocks = [_kernel_data(qt) for qt in qts]
     spec, data, side = blocks[0]
@@ -77,24 +78,28 @@ def _decoded_by_words(qts, K, ck=None):
     qmin = finest_split(K, spec.planes)
     ck = ck or words_chunk(qmin, spec.block)
     per = 1 + spec.n_side
+    nb = side[-1].shape[1]
+    if prepared:
+        blocks = [(sp, d, tuple(
+            qdecode.pack_major_bits(a, d.shape[0])[0]
+            for a in (qt.scales, qt.mins) if a is not None))
+            for (sp, d, _), qt in zip(blocks, qts)]
 
     def kern(*refs):
         o_ref, scratch = refs[len(blocks) * per], refs[len(blocks) * per + 1:]
         qdecode.stage_words(
             spec, [refs[i * per] for i in range(len(blocks))],
             [refs[i * per + 1:(i + 1) * per] for i in range(len(blocks))],
-            scratch)
-        signed = jnp.issubdtype(refs[0].dtype, jnp.signedinteger)
+            scratch, prepared=prepared)
         for seg in range(K // qmin):
             for c0, c in chunk_spans(qmin, ck):
                 o_ref[seg * qmin + c0:seg * qmin + c0 + c, :] = \
                     qdecode.decode_chunk_words(
-                        spec, K, scratch[0], scratch[2], signed, seg, c0, c)
+                        spec, K, scratch[0], scratch[2], seg, c0, c)
 
     out = pl.pallas_call(
         kern, out_shape=jax.ShapeDtypeStruct((K, O), jnp.bfloat16),
-        scratch_shapes=qdecode.word_scratch(spec, O, data.shape[1],
-                                            side[-1].shape[1]),
+        scratch_shapes=qdecode.word_scratch(spec, O, data.shape[1], nb),
         interpret=True,
     )(*(a for _, d, sd in blocks for a in (d, *sd)))
     # lane p * q + i of the tile is its row 4i + p
@@ -129,6 +134,108 @@ def test_decoded_weights_are_the_dequantizers_bit_for_bit(qtype):
         got = np.asarray(got.astype(jnp.float32))
         bad = np.argwhere(got != want)
         assert bad.size == 0, (qtype, name, len(bad), bad[:4])
+
+
+def _corner_block(qtype, rows, K, seed):
+    """A block whose codes are the format's ends in whole stretches (0 and
+    15: a flipped nibble of -8 is the lane's sign bit alone; int8's -128
+    and 127) and random elsewhere, under scales at float16's corners; and
+    its weights worked out here, `round_bf16(float32(code - offset) *
+    float32(scale))`, not by the dequantizer."""
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    rng = np.random.default_rng(seed)
+    sc = (rng.uniform(0.001, 0.011, (rows, K // 32))
+          * rng.choice([-1.0, 1.0], (rows, K // 32))).astype(np.float16)
+    corners = np.asarray([2.0 ** -24, -2.0 ** -24, 65504.0, -65504.0, 0.0,
+                          -0.0, 2.0 ** -14, 1.0], np.float16)
+    sc[:, :8] = corners  # both nibble halves: blocks 0.. and K/64..
+    sc[:, K // 64:K // 64 + 8] = corners[::-1]
+    if qtype == "sym_int4":
+        data = rng.integers(0, 256, (rows, K // 2), dtype=np.uint8)
+        for j, byte in enumerate((0x00, 0xFF, 0xF0, 0x0F, 0x88, 0x77)):
+            data[j::16, :] = byte
+            data[:, 32 * j + 7] = byte
+        codes = np.concatenate([data & 15, data >> 4], axis=1).astype(
+            np.int32) - 8
+    else:
+        data = rng.integers(-128, 128, (rows, K), dtype=np.int8)
+        for j, byte in enumerate((-128, 127, 0, -1)):
+            data[j::16, :] = byte
+            data[:, 32 * j + 7] = byte
+        codes = data.astype(np.int32)
+    want = (codes.astype(np.float32)
+            * np.repeat(sc.astype(np.float32), 32, axis=1))
+    want = np.asarray(jnp.asarray(want).astype(jnp.bfloat16)
+                      .astype(jnp.float32))
+    return QTensor(qtype=qtype, data=jnp.asarray(data),
+                   scales=jnp.asarray(sc)), want
+
+
+@pytest.mark.parametrize("prepared", (False, True),
+                         ids=("staged", "prepared"))
+@pytest.mark.parametrize("qtype,blocks", [("sym_int4", 1), ("sym_int4", 2),
+                                          ("sym_int8", 1)],
+                         ids=("sym_int4", "sym_int4-paired", "sym_int8"))
+def test_signed_fields_at_their_corners_bit_for_bit(qtype, blocks, prepared):
+    """ISSUE 49: the word path cuts a sym_int4 nibble out of its word
+    signed, as it does an int8 byte (`qdecode.signed_field`). The ends of
+    the code range under the ends of float16, one 512-row tile and the
+    paired tile of a 768-wide gated call, scales staged and prepared."""
+    K = 1024
+    made = [_corner_block(qtype, WORD_BLOCK_O // blocks, K, seed=i)
+            for i in range(blocks)]
+    want = np.concatenate([w for _, w in made])
+    assert np.isfinite(want).all() and (want == 0).any()
+    got = np.asarray(_decoded_by_words(
+        [qt for qt, _ in made], K, prepared=prepared).astype(jnp.float32))
+    bad = np.argwhere((got != want) | (np.signbit(got) != np.signbit(want)))
+    assert bad.size == 0, (qtype, len(bad), bad[:4])
+
+
+def _chunk_body_ops(spec, seg):
+    """Primitive counts of one chunk's decode on the word path."""
+    import collections
+
+    K, O = 1024, WORD_BLOCK_O
+    row_bytes = K * (sum(spec.planes) or 8) // 8
+
+    def kern(wT_ref, sT_ref, o_ref):
+        o_ref[...] = qdecode.decode_chunk_words(
+            spec, K, wT_ref, sT_ref, seg, 0, 256)
+
+    jaxpr = jax.make_jaxpr(lambda wT, sT: pl.pallas_call(
+        kern, out_shape=jax.ShapeDtypeStruct((256, O), jnp.bfloat16),
+        interpret=True)(wT, sT))(
+        jax.ShapeDtypeStruct((row_bytes, O // WORD_ROWS), jnp.int32),
+        jax.ShapeDtypeStruct((1, 128, O), jnp.float32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    return collections.Counter(
+        e.primitive.name for e in call.params["jaxpr"].eqns)
+
+
+@pytest.mark.parametrize("name,seg", [("sym_int4", 0), ("sym_int4", 1),
+                                      ("sym_int8", 0)])
+def test_chunk_body_cuts_a_signed_field_in_two_operations(name, seg):
+    """The chunk body's equations for the formats that decode where the
+    field lies: a shift left and an arithmetic shift right a pack (no
+    shift left for the word's last field), then convert, multiply, cast.
+    No mask and no subtract: the chain of six ISSUE 49 found is five.
+    (The issue's ONE shift is not the dequantizer's weights: the word's
+    lower fields stay below the one cut out.)"""
+    from bigdl_tpu.quant.qtypes import resolve_qtype as get_qtype
+
+    spec = qdecode.spec_for(get_qtype(name))
+    ops = _chunk_body_ops(spec, seg)
+    last_field = name == "sym_int8" or seg == 1
+    assert ops["shift_right_arithmetic"] == WORD_ROWS
+    assert ops["shift_left"] == WORD_ROWS - last_field
+    assert ops["mul"] == 1 and ops["convert_element_type"] == 2
+    assert not {"and", "sub", "or", "add"} & set(ops), ops
+    # what the chain was, and still is for a format with a minimum
+    old = _chunk_body_ops(qdecode.spec_for(get_qtype("asym_int4")), seg)
+    assert old["and"] == WORD_ROWS and "shift_left" not in old
 
 
 def _reference(x, qt):
@@ -210,9 +317,9 @@ def test_grouped_kernel_on_the_word_path(interpret, K, O, act, bm):
                    * K ** -0.5, "sym_int4") for i in range(2 if gated else 1)]
     paired = O == 768
     assert mq.call_plan(ws if gated else ws[0]) == (
-        "words:paired x1 of 3 tiles" if paired
+        "words:inplace:paired x1 of 3 tiles" if paired
         else "loop x2" if gated and K == 14336  # two such tiles: VMEM
-        else "words x1")
+        else "words:inplace x1")
     experts = np.repeat(np.arange(E), groups).astype(np.int32)
     N = len(experts)
     n_tiles = mq.moe_n_tiles(N, 1, E, bm)

@@ -171,8 +171,8 @@ def test_moe_qmatmul_compiles_at_granites_and_smallthinkers_shapes(
         ws = [QTensor(qtype="sym_int4", data=fields[2 * i],
                       scales=fields[2 * i + 1]) for i in range(len(fields) // 2)]
         assert mq.call_plan(ws) == (
-            "words:paired x1 of 3 tiles" if act
-            else f"words x1 of {O // 512} tiles")
+            "words:inplace:paired x1 of 3 tiles" if act
+            else f"words:inplace x1 of {O // 512} tiles")
         # (as `_moe_dispatch_grouped` calls it: `down` leaves in float32)
         return mq.moe_qmatmul(x, ws if act else ws[0], te, n_used, bm,
                               act=act, layer=layer, interpret=False,
@@ -216,8 +216,8 @@ def test_moe_qmatmul_compiles_at_256_experts_of_width_512(one_chip, name,
     def f(x, te, n_used, layer, *fields):
         ws = [QTensor(qtype="sym_int4", data=fields[2 * i],
                       scales=fields[2 * i + 1]) for i in range(len(fields) // 2)]
-        assert mq.call_plan(ws) == ("words x1" if act
-                                    else "words x1 of 4 tiles")
+        assert mq.call_plan(ws) == ("words:inplace x1" if act
+                                    else "words:inplace x1 of 4 tiles")
         return mq.moe_qmatmul(x, ws if act else ws[0], te, n_used, bm,
                               act=act, layer=layer, interpret=False,
                               out_dtype=jnp.bfloat16 if act else jnp.float32)
@@ -255,26 +255,31 @@ def _mosaic_bodies(lowered_text):
     return out
 
 
-# sha256 of the dense `qmatmul`'s Mosaic module on PR 41's tree (wqkv at 32
-# rows, w_down at a prefill's 256, Mistral's head on the stored-layout loop,
-# Qwen2's wqkv whose chunks are Python's loop)
+# sha256 of the dense `qmatmul`'s Mosaic module (wqkv at 32 rows, w_down at
+# a prefill's 256, Mistral's head on the stored-layout loop, Qwen2's wqkv
+# whose chunks are Python's loop): the word path's three on PR 49's tree
+# (a sym_int4 nibble cut out signed: the chunk loop's equations changed, on
+# purpose), the stored-layout loop's as it has been since PR 41
 _DENSE_BODIES = {
     (4096, 6144, 32):
-        "68f393d0756fa36b2f8712371d26345bc23f83cdc98ca09a99765afd3a7353f5",
+        "2fc2dc3cb2ef5b2b5e9808c79310efe8895849f0dfed9379ffd5cdd72c5921f4",
     (14336, 4096, 256):
-        "1a85d31ea15f3e4e065dd5a754811e1d682984e654d3f1ae6cc832e1adfeb5a8",
+        "b24b8181ff570e2dc4f492c2fd26355abaf6f151da5be58ccf21870e8eb4f63f",
     (4096, 32000, 32):
         "48b359797facf56403dd5372088f6d9c0a56b9c6a174e51f1e3dfdd52f6b8f51",
     (3584, 4608, 16):
-        "45b6c9cce86c3b881c5047a338ad68461da690bd5689940b3d7ae176c4eabad1",
+        "94a9a360d80d336bdfd50d1a02bc73e74d57d514b0c65450039b2705eee60395",
 }
 
 
 @pytest.mark.parametrize("K,O,M", list(_DENSE_BODIES))
 def test_dense_qmatmul_lowers_to_the_parents_program(one_chip, K, O, M):
-    """ISSUE 44 gave `qdecode.stage_words` a second block for the grouped
-    kernel's paired tile: the dense kernels' own programs are what they
-    were, to the byte, and the grouped kernel's plan at Mixtral's shapes
+    """A pin against changes that were not meant: ISSUE 44 gave
+    `qdecode.stage_words` a second block for the grouped kernel's paired
+    tile and ISSUE 48 another way in for the scales, and the dense kernels'
+    own programs stayed what they were, to the byte (ISSUE 49 changed the
+    word path's chunk loop and re-pinned it; the stored-layout loop, which
+    it left alone, kept its hash), and the grouped kernel's plan at Mixtral's shapes
     is one 512-row word tile a grid step as it was (GLM's smaller tiles
     are now a whole expert a step)."""
     import hashlib
@@ -404,6 +409,62 @@ def test_moe_qmatmul_compiles_on_prepared_scale_bits(one_chip, monkeypatch,
         _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
         *ws).compile()
     assert "moe_qmatmul" in _no_scale_is_moved(c)
+
+
+# ---- a sym_int4 nibble cut out of its word signed (ISSUE 49) ----------------
+
+@pytest.mark.parametrize("prepared", (False, True),
+                         ids=("staged", "prepared"))
+@pytest.mark.parametrize("kind", ("dense", "grouped", "paired", "mins"))
+def test_word_path_is_lowered_with_signed_nibbles(one_chip, monkeypatch, kind,
+                                                  prepared):
+    """What Mosaic is handed for a cell's packed calls (a wqkv, Mixtral's
+    down projection, granite's paired gate / up; scales staged and
+    prepared) flips the nibbles' top bits once a tile (`arith.xori`: the
+    kernels have no other) and masks nothing in the chunk loop; a format
+    with a minimum keeps the unsigned field. (That all of these COMPILE is
+    the tests' above.)"""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.ops.pallas.qmatmul import qmatmul
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")  # the guards' switch
+    qtype = "asym_int4" if kind == "mins" else "sym_int4"
+    E, K, O, act = {"dense": (None, 4096, 6144, None),
+                    "mins": (None, 2048, 1024, None),
+                    "grouped": (8, 14336, 4096, None),
+                    "paired": (72, 4096, 768, "silu")}[kind]
+    lead = (2,) if E is None else (2, E)
+
+    def weight():
+        # (a dense layer's stored scales are its own slice of the scan's)
+        side = _sds((*(() if E is None and not prepared else lead), O,
+                     K // 32), jnp.float16, one_chip)
+        w = QTensor(qtype=qtype, data=_sds((*lead, O, K // 2), jnp.uint8,
+                                           one_chip),
+                    scales=side, mins=side if kind == "mins" else None)
+        return _prepared(w, 2 if act else None if E is None else 1,
+                         one_chip) if prepared else w
+
+    layer = _sds((), jnp.int32, one_chip)
+    if E is None:
+        lowered = jax.jit(
+            lambda x, w, l: qmatmul(x, w, interpret=False, layer=l)
+        ).lower(_sds((32, K), jnp.bfloat16, one_chip), weight(), layer)
+    else:
+        ws = [weight() for _ in range(2 if act else 1)]
+        assert ("words:inplace" in mq.call_plan(ws)) and (
+            ":paired" in mq.call_plan(ws)) == (kind == "paired")
+        bm = mq.moe_block_m(32, max(K, O))
+        n_tiles = mq.moe_n_tiles(32, 2, E, bm)
+        lowered = jax.jit(lambda x, te, n, l, *ws: mq.moe_qmatmul(
+            x, list(ws) if act else ws[0], te, n, bm, act=act, layer=l,
+            interpret=False)).lower(
+            _sds((n_tiles * bm, K), jnp.bfloat16, one_chip),
+            _sds((n_tiles,), jnp.int32, one_chip), layer, layer, *ws)
+    (body,) = _mosaic_bodies(lowered.as_text())
+    assert ("arith.xori" in body) == (kind != "mins")
+    assert "arith.shrsi" in body and "arith.shli" in body
 
 
 # ---- paged decode attention over groups of live pages (ISSUE 35) -----------
