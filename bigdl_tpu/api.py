@@ -293,6 +293,16 @@ class TpuModel:
         save_low_bit(path, self.config, self.params, self.qtype,
                      faults=faults)
 
+    def _refuse_block_diffusion(self, what: str) -> None:
+        """A diffusion checkpoint decoded one token a step from shifted
+        logits would be another model, in silence: refuse by name."""
+        if self.config.block_length:
+            raise NotImplementedError(
+                f"{self.config.model_type} generates by diffusion over "
+                f"blocks of {self.config.block_length}: serve it through "
+                "InferenceEngine(paged=True) (serving/blocks.py); "
+                f"{what} decodes autoregressively and does not run it")
+
     def generate(
         self,
         prompts: Union[Sequence[Sequence[int]], np.ndarray],
@@ -324,6 +334,7 @@ class TpuModel:
         exceed the cache and generation runs in constant memory."""
         from bigdl_tpu.utils import flags
 
+        self._refuse_block_diffusion("generate()")
         if isinstance(prompts, np.ndarray):
             prompts = [list(row) for row in prompts]
         if not prompts:
@@ -486,6 +497,8 @@ class TpuModel:
         IPEX_LLM_PERFORMANCE_MODE): n-gram candidates, one verify forward."""
         from bigdl_tpu.decode import lookup_generate
 
+        self._refuse_block_diffusion("generate_lookup()")
+
         # under a pp mesh the verify forward is the pipeline step
         # (forward_fn keeps the family-forward call shape, so the lookup
         # while_loop runs unchanged with per-stage KV caches)
@@ -536,6 +549,8 @@ class TpuModel:
         only meaningful when this model holds higher-precision weights.
         The self-draft is built once and cached on the model."""
         from bigdl_tpu.decode import speculative_generate
+
+        self._refuse_block_diffusion("generate_speculative()")
 
         if self.pp_size > 1:
             raise NotImplementedError(

@@ -1024,6 +1024,7 @@ _FAMILY_LAYER = {
     "falcon": _falcon_layer,
     "qwen3": _qwen3_layer,
     "qwen3_moe": _qwen3_moe_layer,
+    "sdar_moe": _qwen3_moe_layer,  # Qwen3-MoE's tensor names
     "phi": _phi_layer,
     "cohere": _cohere_layer,
     "yuan": _yuan_layer,

@@ -173,6 +173,9 @@ _LAZY_FAMILIES = {
     # projections, a sigmoid gate a head, a dense first layer before the
     # sigmoid-routed experts; smallthinker's two groups of pages
     "laguna": "bigdl_tpu.models.laguna",
+    # Qwen3-MoE's network generated by diffusion over blocks: a step is a
+    # pass over a block of positions (serving/blocks.py)
+    "sdar_moe": "bigdl_tpu.models.sdar",
 }
 
 

@@ -158,6 +158,20 @@ class ModelConfig:
     heads_per_layer: Optional[tuple] = None
     attn_gate: Optional[str] = None
     rope_local_partial_rotary_factor: Optional[float] = None
+    # sdar_moe (models/sdar.py): generation by DIFFUSION OVER BLOCKS. A
+    # `block_length` of b > 0 makes the attention mask causal BY BLOCK (key
+    # j is visible to query i iff j // b <= i // b) and the logits at
+    # position i score the token AT i; blocks of b positions are produced
+    # left to right, every position of one starting as `mask_token_id` and
+    # revealed over `denoising_steps` passes by `remasking_strategy`
+    # ("sequential" | "low_confidence_static" | "low_confidence_dynamic",
+    # the last revealing what passes `confidence_threshold`). 0 = an
+    # autoregressive model (serving/blocks.py has the loop)
+    block_length: int = 0
+    denoising_steps: int = 4
+    remasking_strategy: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    mask_token_id: int = 0
 
     def __post_init__(self):
         if self.attention_kind not in ("softmax", "power_retention"):
@@ -812,6 +826,37 @@ def _hf_qwen3_moe(hf, kw):
         )
 
 
+BLOCK_STRATEGIES = ("sequential", "low_confidence_static",
+                    "low_confidence_dynamic")
+
+
+def _hf_sdar_moe(hf, kw):
+    """SDAR-MoE (JetLM SDAR-30B-A3B-Chat): Qwen3-MoE's network, generated
+    by diffusion over blocks. The source's config.json gives none of the
+    five keys below; the defaults are the family's released chat
+    checkpoints' (block of 4, the dynamic low-confidence reveal at 0.9,
+    `<|MASK|>` = 151669), and a config may say otherwise: they are
+    constants of an engine (serving/blocks.py)."""
+    _hf_qwen3_moe(hf, kw)
+    kw["block_length"] = int(hf.get("block_length", 4))
+    kw["denoising_steps"] = int(hf.get("denoising_steps",
+                                       kw["block_length"]))
+    kw["remasking_strategy"] = hf.get("remasking_strategy",
+                                      "low_confidence_dynamic")
+    kw["confidence_threshold"] = float(hf.get("confidence_threshold", 0.9))
+    kw["mask_token_id"] = int(hf.get("mask_token_id", 151669))
+    if kw["block_length"] < 1:
+        raise ValueError("sdar_moe needs a block_length of at least 1")
+    if kw["remasking_strategy"] not in BLOCK_STRATEGIES:
+        raise ValueError(
+            f"remasking_strategy must be one of {BLOCK_STRATEGIES}; got "
+            f"{kw['remasking_strategy']!r}")
+    if not 0 <= kw["mask_token_id"] < hf["vocab_size"]:
+        raise ValueError(
+            f"mask_token_id {kw['mask_token_id']} outside the vocabulary "
+            f"of {hf['vocab_size']}")
+
+
 def _hf_phi(hf, kw):
     """Phi-1/1.5/2 (HF modeling_phi): parallel attn+mlp sharing ONE
     input layernorm (the translator duplicates it, like falcon-7b),
@@ -1137,6 +1182,7 @@ _HF_BUILDERS = {
     "smallthinker": _hf_smallthinker,
     "laguna": _hf_laguna,
     "qwen3_moe": _hf_qwen3_moe,
+    "sdar_moe": _hf_sdar_moe,
     "phi": _hf_phi,
     "cohere": _hf_cohere,
     "qwen": _hf_qwen,

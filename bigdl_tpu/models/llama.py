@@ -726,7 +726,10 @@ def forward(
     params: Params,
     tokens: jax.Array,  # [B, T] int32
     cache: Optional[KVCache],
-    mode: str = "prefill",  # static: "prefill" | "decode"
+    mode: str = "prefill",  # static: "prefill" | "decode" | "block" (a
+    # model generated by diffusion over blocks, config.block_length = b:
+    # tokens [B, b] are each row's block at the row's own offset, written to
+    # its pages and attended in both directions inside the block)
     compute_dtype=jnp.bfloat16,
     lora: Optional[Params] = None,  # LoRA adapter tree (see bigdl_tpu.train)
     start: Optional[jax.Array] = None,  # [B] pad offsets when cache is None
@@ -772,8 +775,11 @@ def forward(
     of the layer stack (parallel/pipeline.py): embedding happens before
     the first stage, final norm + lm head after the last.
     """
-    assert mode in ("prefill", "decode")
+    assert mode in ("prefill", "decode", "block")
     B, T = tokens.shape[:2]
+    # 0 for every autoregressive model: then nothing below differs
+    block_causal = config.block_length
+    assert (mode == "block") <= (block_causal == T and cache is not None)
     Hq, Hkv, D = config.num_attention_heads, config.num_key_value_heads, config.head_dim_
     eps = config.rms_norm_eps
 
@@ -878,15 +884,23 @@ def forward(
         and uniform_window and not config.alibi
         and attention_override is None
         and config.attn_logit_softcap is None
+        and not block_causal  # that kernel's mask is causal by position
     )
 
     # Attention masks (shared by all layers, computed once outside the scan).
     # With sliding-window alternation (gemma2) both the global and the
     # sliding mask are built; the scan body selects per layer index.
+    def last_seen(q_slot):
+        """The last slot a query at `q_slot` sees: itself, or under a mask
+        causal by block the end of its block."""
+        if not block_causal:
+            return q_slot
+        return q_slot - q_slot % block_causal + (block_causal - 1)
+
     def build_masks():
         if cache is None:
             tj = jnp.arange(T)
-            base = (tj[None, :] <= tj[:, None])[None] & (
+            base = (tj[None, :] <= last_seen(tj[:, None]))[None] & (
                 tj[None, None, :] >= row_start[:, None, None]
             )  # [B, T, T]
             k_slot = tj[None, None, :]
@@ -894,7 +908,7 @@ def forward(
         else:
             S = cache.max_len
             sj = jnp.arange(S)
-            base = (sj[None, None, :] <= slots[..., None]) & (
+            base = (sj[None, None, :] <= last_seen(slots[..., None])) & (
                 sj[None, None, :] >= row_start[:, None, None]
             )  # [B, T, S]
             k_slot = sj[None, None, :]
@@ -911,7 +925,8 @@ def forward(
     from bigdl_tpu.kvpaged import PagedKVCache, live_rows
 
     use_paged_kernel = (
-        isinstance(cache, PagedKVCache) and mode == "decode" and T == 1
+        isinstance(cache, PagedKVCache)
+        and (mode == "decode" and T == 1 or mode == "block")
         and use_pallas() and not config.alibi
         and attention_override is None
     )
@@ -933,7 +948,9 @@ def forward(
                     "pallas:retention" if why is None else "xla:retention",
                     att_detail + (f" ({why})" if why else ""))
     elif use_paged_kernel:
-        routes.note("attention", "pallas:paged", att_detail)
+        routes.note("attention", "pallas:paged", att_detail + (
+            f" block of {T}: {T * Hq // Hkv} rows a KV head"
+            if mode == "block" else ""))
         row_live = live_rows(cache)  # the table does not change in here
     elif use_flash_train:
         routes.note("attention", "pallas:flash_train", att_detail)
@@ -1107,6 +1124,14 @@ def forward(
 
             if retention:
                 pass  # done above, on the state
+            elif use_paged_kernel and mode == "block":
+                from bigdl_tpu.ops.pallas import paged_block_attention
+
+                attn = paged_block_attention(
+                    q, c.k, c.v, c.block_tables, idx, c.pos, c.start,
+                    k_scale=c.k_scale, v_scale=c.v_scale,
+                    scale=config.attn_scale,
+                    softcap=config.attn_logit_softcap, live=row_live)
             elif use_paged_kernel:
                 from bigdl_tpu.ops.pallas import paged_decode_attention
 
@@ -1141,6 +1166,7 @@ def forward(
                     window=config.sliding_window, softcap=config.attn_logit_softcap,
                     scale=config.attn_scale,
                     k_scale=k_scale_att, v_scale=v_scale_att,
+                    block_causal=block_causal or None,
                 )
             else:
                 is_sliding = sliding_flags[layer_offset + idx]

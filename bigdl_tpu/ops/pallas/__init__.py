@@ -67,7 +67,8 @@ from bigdl_tpu.ops.pallas.flash_backward import (  # noqa: E402
     flash_attention_trainable,
 )
 from bigdl_tpu.ops.pallas.paged_attention import (  # noqa: E402
-    paged_decode_attention, paged_latent_decode_attention,
+    paged_block_attention, paged_decode_attention,
+    paged_latent_decode_attention,
 )
 from bigdl_tpu.ops.pallas.qbackward import (  # noqa: E402
     dw_matmul, qmatmul_dx,
@@ -80,6 +81,7 @@ from bigdl_tpu.ops.pallas.qmatmul import (  # noqa: E402
 
 __all__ = ["use_pallas", "why_not_pallas", "interpret_mode", "flash_attention",
            "flash_attention_trainable",
+           "paged_block_attention",
            "paged_decode_attention", "paged_latent_decode_attention", "qmatmul",
            "qmatmul_int4",
            "qmatmul_codebook",

@@ -13,6 +13,11 @@ Layout: q [B, T, Hq, D]; k, v [B, S, Hkv, D] (the KV-cache layout).
 entry). Query slot t attends kv slot j iff
     start[b] <= j <= q_offset + t          (causal)
     and j > q_offset + t - window          (if sliding window).
+With `block_causal=b` (a model generated by diffusion over blocks,
+models/sdar.py) the first line's bound is the END of the query's block of
+`b` slots, `(q_offset + t) // b * b + b - 1`: both directions inside a
+block, causal between blocks. The liveness test below is by tile and takes
+the same bound, so only the in-tile mask differs from the causal kernel.
 
 Grid is (B, Hkv, nQ, nK) with the K axis innermost ("arbitrary"
 semantics). A q block is `block_q` POSITIONS of all `group = Hq / Hkv`
@@ -56,13 +61,24 @@ _NEG_INF = -1e30
 _LANES = MOSAIC_LANES
 
 
+def block_end(pos, b: Optional[int]):
+    """The last slot a query at slot `pos` sees: `pos`, or under a mask
+    causal by blocks of `b` the end of its block."""
+    if not b or b == 1:
+        return pos
+    if isinstance(pos, int):
+        return pos - pos % b + b - 1
+    return pos - jax.lax.rem(pos, jnp.asarray(b, pos.dtype)) + (b - 1)
+
+
 def live_k_range(i, q_offset, block_q: int, block_k: int, n_k: int,
-                 window: Optional[int]):
+                 window: Optional[int], block_causal: Optional[int] = None):
     """(first, last) K blocks that some row of q block `i` attends, both
     inside 0 .. n_k - 1: the kernel's liveness test of a grid step, and
     what its K / V index maps clamp `j` into. Works on Python ints (the
     tests, `tiling.flash_live_blocks`' twin) and on traced scalars."""
-    last = (q_offset + (i + 1) * block_q - 1) // block_k
+    last = block_end(q_offset + (i + 1) * block_q - 1,
+                     block_causal) // block_k
     if isinstance(last, int):
         lo, hi = max, min
     else:
@@ -76,11 +92,13 @@ def live_k_range(i, q_offset, block_q: int, block_k: int, n_k: int,
 
 
 def clamped_k_block(j, i, q_offset, block_q: int, block_k: int, n_k: int,
-                    window: Optional[int]):
+                    window: Optional[int],
+                    block_causal: Optional[int] = None):
     """The K block grid step (i, j) points its DMA at: `j` inside the live
     range of q block `i`, the nearer end outside it, so that a dead step
     names the block already resident and Mosaic issues no DMA for it."""
-    first, last = live_k_range(i, q_offset, block_q, block_k, n_k, window)
+    first, last = live_k_range(i, q_offset, block_q, block_k, n_k, window,
+                               block_causal)
     if isinstance(last, int) and isinstance(j, int):
         return min(max(j, first), last)
     return jnp.minimum(jnp.maximum(j, first), last)
@@ -110,6 +128,7 @@ def _kernel(
     softcap: Optional[float],
     quantized: bool,
     kv_value: tuple,
+    block_causal: Optional[int] = None,
 ):
     if quantized:  # fp8 KV: per-(slot, head) f32 scales ride alongside
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
@@ -130,7 +149,8 @@ def _kernel(
     # a K block is live unless it lies wholly above the causal diagonal
     # or outside the sliding window of every position of this q block;
     # the index maps hold a dead step on a live block, so it costs no DMA
-    first, last = live_k_range(i, qoff, block_q, block_k, n_k, window)
+    first, last = live_k_range(i, qoff, block_q, block_k, n_k, window,
+                               block_causal)
 
     @pl.when((j >= first) & (j <= last))
     def _compute():
@@ -163,7 +183,8 @@ def _kernel(
         cols = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
-        valid = (cols >= start_ref[b]) & (cols <= pos)
+        valid = (cols >= start_ref[b]) & (cols <= block_end(pos,
+                                                            block_causal))
         if window is not None:
             valid = valid & (cols > pos - window)
         s = jnp.where(
@@ -199,13 +220,15 @@ def _kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "window", "softcap", "scale", "block_q", "block_k", "interpret"
+        "window", "softcap", "scale", "block_q", "block_k", "interpret",
+        "block_causal",
     ),
 )
 def _flash(
     q, k, v, start, q_offset, k_scale, v_scale,
     window: Optional[int], softcap: Optional[float],
     scale: float, block_q: int, block_k: int, interpret: bool,
+    block_causal: Optional[int] = None,
 ):
     B, Hkv, group, T, D = q.shape
     S = k.shape[2]
@@ -224,7 +247,7 @@ def _flash(
         _kernel,
         scale=scale, group=group, block_q=block_q, block_k=block_k,
         window=window, softcap=softcap, quantized=quantized,
-        kv_value=kv_value,
+        kv_value=kv_value, block_causal=block_causal,
     )
 
     def q_index(b, h, i, j, start_ref, qoff_ref):
@@ -232,7 +255,7 @@ def _flash(
 
     def kv_index(b, h, i, j, start_ref, qoff_ref):
         return (b, h, clamped_k_block(j, i, qoff_ref[0], block_q, block_k,
-                                      n_k, window), 0)
+                                      n_k, window, block_causal), 0)
 
     q_spec = pl.BlockSpec((1, 1, group, block_q, D), q_index)
     in_specs = [q_spec,
@@ -282,6 +305,8 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    block_causal: Optional[int] = None,  # static: the mask is causal by
+    # blocks of this many slots (both directions inside one); None = causal
 ) -> jax.Array:
     """Returns [B, T, Hq, D] in q.dtype. Pads T/S/D to tile multiples
     internally; padding key slots are excluded by the causal mask (they
@@ -308,6 +333,8 @@ def flash_attention(
     if q_offset is None:
         q_offset = jnp.zeros((), jnp.int32)
     assert causal, "non-causal path uses ops.attention (bidirectional encoders)"
+    assert not (block_causal and window), "a window under a block mask"
+    block_causal = block_causal if block_causal and block_causal > 1 else None
 
     block_q, block_k = flash_blocks(T, S, D, group, k.dtype.itemsize,
                                     block_q, block_k)
@@ -315,7 +342,9 @@ def flash_attention(
     steps = B * Hkv * (Tp // block_q) * (Sp // block_k)
     live = B * Hkv * flash_live_blocks(T, S, block_q, block_k, window=window)
     routes.note("flash", f"q {group}x{block_q} x k {block_k}",
-                f"{live} of {steps} steps live at T={T} S={S}")
+                f"{live} of {steps} steps live at T={T} S={S}"
+                + (f", causal by blocks of {block_causal}"
+                   if block_causal else ""))
 
     # [B, Hkv, group, T, D]: query head h is head h % group of KV head
     # h // group
@@ -338,6 +367,7 @@ def flash_attention(
         q_offset.astype(jnp.int32).reshape(1),
         prep_scale(k_scale), prep_scale(v_scale),
         window, softcap, scale, block_q, block_k, interpret,
+        block_causal=block_causal,
     )
     out = jnp.transpose(out[:, :, :, :T, :D], (0, 3, 1, 2, 4))
     return out.reshape(B, T, Hq, D)

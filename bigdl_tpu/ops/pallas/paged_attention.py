@@ -423,6 +423,36 @@ def paged_decode_attention(
     )(*args)
 
 
+def paged_block_attention(
+    q: jax.Array,  # [B, b, Hq, D]: the queries of each row's block
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    block_tables: jax.Array,
+    layer: jax.Array,
+    pos: jax.Array,  # [B] the block's FIRST slot
+    start: jax.Array,
+    **kw,  # paged_decode_attention's (scales, scale, softcap, live, ...)
+) -> jax.Array:
+    """A block's `b` query positions a row in one call of
+    `paged_decode_attention` (models/sdar.py: a pass of the diffusion over
+    blocks). Every query of a block sees slots `start .. pos + b - 1`, both
+    directions inside the block, so the block's queries are `b x G` rows of
+    each KV head's dot at ONE position, the block's last slot: the kernel's
+    body as it stands, with `b` times the rows a KV head (`group` is read
+    off q's shape) and the same one DMA a live page. Returns
+    [B, b, Hq, D]."""
+    B, b, Hq, D = q.shape
+    Hkv = k_pages.shape[3]
+    G = Hq // Hkv
+    # row (h, t, g) of the kernel's q: KV head h owns rows h*b*G .. +b*G
+    rows = jnp.transpose(q.reshape(B, b, Hkv, G, D), (0, 2, 1, 3, 4))
+    out = paged_decode_attention(
+        rows.reshape(B, Hkv * b * G, D), k_pages, v_pages, block_tables,
+        layer, pos + (b - 1), start, **kw)
+    out = jnp.transpose(out.reshape(B, Hkv, b, G, D), (0, 2, 1, 3, 4))
+    return out.reshape(B, b, Hq, D)
+
+
 # ---------------------------------------------------------------------------
 # Latent pages (MLA, models/deepseek.py): the absorbed decode form
 # ---------------------------------------------------------------------------

@@ -45,6 +45,8 @@ from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.obs import retrace
 from bigdl_tpu.obs.tracing import DECODE_TID
 from bigdl_tpu.serving.faults import NULL_INJECTOR, FaultError
+from bigdl_tpu.serving.hostrow import bits as _bits
+from bigdl_tpu.serving.hostrow import expert_id_dtype as _expert_id_dtype
 from bigdl_tpu.serving.metrics import Histogram
 from bigdl_tpu.serving.pages import NeverFits, PageTable, prefill_bucket
 from bigdl_tpu.utils import round_up
@@ -69,17 +71,6 @@ def _named(name: str, fn, *bound):
     p = functools.partial(fn, *bound)
     p.__name__ = name
     return p
-
-
-def _bits(x):
-    """A float32 array's bits as int32: how a logprob rides in the one
-    int32 vector a program packs for the host."""
-    return jax.lax.bitcast_convert_type(x, jnp.int32)
-
-
-def _expert_id_dtype(n_experts: int):
-    """The small integer type a step's expert ids are kept in."""
-    return np.int8 if n_experts <= 127 else np.int16
 
 
 def _read_first_token(out, n_top: int) -> tuple:
@@ -135,12 +126,17 @@ def _cache_kind(model) -> kvpaged.CacheKind:
     (`PAGED_CACHE_KIND`: a state row beside the pages, kvhybrid.py; a
     window group of pages beside the global one, kvwindow.py) or, offering
     an `init_paged_cache` and no name, has as latent pages (MLA); then KV
-    pages. The page table books, parks and restores a page of any kind
-    alike."""
+    pages (those of a model generated by diffusion over blocks as their own
+    kind, for what it refuses: serving/blocks.py). The page table books,
+    parks and restores a page of any kind alike."""
     from bigdl_tpu import kvhybrid, kvstate, kvwindow
 
     if model.config.attention_kind == kvstate.KIND:
         return kvstate.CACHE_KIND
+    if model.config.block_length:  # KV pages, generated by blocks
+        from bigdl_tpu.serving import blocks
+
+        return blocks.CACHE_KIND
     own = getattr(model.family, "PAGED_CACHE_KIND", None)
     for mod in (kvhybrid, kvwindow):
         if own == mod.KIND:
@@ -183,6 +179,13 @@ class Request:
     # input position ([L, k] per step); read them with expert_ids()
     prompt_experts: Optional["np.ndarray"] = None
     out_experts: list = dataclasses.field(default_factory=list)
+    # generation by blocks: one record a pass of this request's row, in
+    # order: {"block", "pass", "base" (the block's first position), "ids"
+    # [b] the pass ran on, "masked" and "revealed" [b] bool, "stored",
+    # "experts" [L, b, k] or None}. A token's logprob is the log-confidence of the
+    # pass that revealed it; `prompt_experts` then covers the prompt's
+    # whole blocks and `out_experts` every position of each STORED block
+    passes: list = dataclasses.field(default_factory=list)
     done: bool = False
     finish_reason: str = ""  # "stop" (EOS) | "length" (budget) |
     # "invalid" (rejected at submit — over-long prompt) | "error" |
@@ -214,9 +217,10 @@ class Request:
         model, a prompt served partly from the prefix cache, or positions
         not decoded yet. The last emitted token was never an input, so
         there are len(prompt) + len(out_tokens) - 1 positions at most."""
-        n_out = n_positions - len(self.prompt)
-        if (self.prompt_experts is None
-                or not 0 <= n_out <= len(self.out_experts)):
+        if self.prompt_experts is None:
+            return None
+        n_out = n_positions - self.prompt_experts.shape[1]
+        if not 0 <= n_out <= len(self.out_experts):
             return None
         steps = [e[:, None] for e in self.out_experts[:n_out]]
         return np.concatenate([self.prompt_experts] + steps, axis=1)
@@ -695,6 +699,25 @@ class InferenceEngine:
         if paged:
             self._copy_page = self._with_mesh(jax.jit(
                 kind.copy_page, donate_argnames=("cache",)))
+        # THE place that chooses the step: a model generated by diffusion
+        # over blocks is stepped by a pass over every row's block. Its
+        # scheduler (serving/blocks.py) holds the block state and the host
+        # half; the engine builds the pass as its decode program and the
+        # program that opens a slot's first block beside it (no
+        # `engine_first_token` runs for this kind), and every site below
+        # that differs asks `self.blocks`
+        self.blocks = None
+        self.logprobs_top_k = logprobs_top_k
+        if self.config.block_length:
+            from bigdl_tpu.serving.blocks import BlockScheduler
+
+            self.blocks = BlockScheduler(self)
+            self._decode = self._with_mesh(jax.jit(
+                _named("engine_decode", self.blocks.pass_impl, fwd),
+                donate_argnames=("state", "cache")))
+            self._arm_block = self._with_mesh(jax.jit(
+                _named("engine_block_arm", self.blocks.arm_impl),
+                donate_argnames=("state",)))
         # --- in-engine speculative decoding (reference serves it through
         # ipex_llm_worker.py:72-99; SURVEY §7 names "continuous batching +
         # speculative interaction" a hard part). Slot-pool design: a
@@ -782,7 +805,6 @@ class InferenceEngine:
             )
         self.adaptive_draft = adaptive_draft
         self.truncate_prompts = truncate_prompts
-        self.logprobs_top_k = logprobs_top_k
         self._waiting: Optional[Request] = None  # paged OOM retry slot
         # rid -> Request whose client went away (stop-string hit,
         # disconnect, server timeout): handler threads add, the engine
@@ -884,8 +906,11 @@ class InferenceEngine:
         # swap-in programs (swap-OUT is a plain device_get, no jit). The
         # donated cache makes the restore an in-place scatter. Family
         # caches (nested pools / property pos) have no row-swap story:
-        # preemption is gated off for them.
-        if self._family_cache is not None:
+        # preemption is gated off for them. So it is for a model generated
+        # by blocks (parking would have to keep a block half revealed):
+        # the option is on by default all the way up from the server, so it
+        # is switched off here and not refused; `preempt()` raises by name
+        if self._family_cache is not None or self.blocks is not None:
             self.preemption = False
         elif paged:
             self._swap_in = self._with_mesh(jax.jit(
@@ -1396,6 +1421,15 @@ class InferenceEngine:
             if stream is not None:
                 stream.put(None)
             return req
+        refused = None if self.blocks is None else self.blocks.refuses(req)
+        if refused is not None:
+            req.error = refused
+            req.finish_reason = "invalid"
+            req.done = True
+            self._note_finish(req, req.submit_ts)
+            if stream is not None:
+                stream.put(None)
+            return req
         limit = self.max_len - max_new_tokens
         if len(req.prompt) > limit and not self.truncate_prompts:
             # FAIL FAST: admission used to tail-truncate silently, which
@@ -1508,7 +1542,7 @@ class InferenceEngine:
                 self.cache, jnp.asarray(plan.copy[0]),
                 jnp.asarray(plan.copy[1]),
             )
-        rest = len(prompt) - plan.covered
+        rest = self._prefill_len(prompt) - plan.covered
         chunk = self.prefill_chunk_tokens
         chunked = chunk is not None and rest > chunk
         st = _PrefillState(
@@ -1528,6 +1562,14 @@ class InferenceEngine:
         else:
             self._prefill_chunk(st)  # monolithic: the rest is one chunk
         return True
+
+    def _prefill_len(self, prompt: list) -> int:
+        """Tokens of `prompt` an admission's prefill stores: all of them,
+        or the whole blocks of a model generated by blocks (the rest open
+        its first block)."""
+        if self.blocks is None:
+            return len(prompt)
+        return self.blocks.prefill_len(len(prompt))
 
     def _advance_prefill(self) -> None:
         """Run AT MOST ONE chunk of the at-most-one in-flight chunked
@@ -1551,7 +1593,8 @@ class InferenceEngine:
         """The chunk's host work up to its activation; returns the last
         chunk's logits, None after an earlier one."""
         prompt = st.req.prompt
-        rem = len(prompt) - st.written
+        stored = self._prefill_len(prompt)
+        rem = stored - st.written
         n = min(st.chunk, rem)
         last = n == rem
         bucket = prefill_bucket(n, self.max_len - st.written)
@@ -1565,7 +1608,7 @@ class InferenceEngine:
         logits_last, pool, moe = self._paged_prefill(
             self.model.params, kind.leaves(self.cache), tables,
             jnp.asarray([st.written], jnp.int32), jnp.asarray(toks),
-            jnp.asarray(n - 1), np.asarray([st.slot], np.int32),  # sent
+            jnp.asarray(max(n - 1, 0)), np.asarray([st.slot], np.int32),  # sent
             # only where the kind's program reads it
             lora=self._prefill_lora(st.req))
         self.cache = kind.with_leaves(self.cache, pool)
@@ -1578,10 +1621,10 @@ class InferenceEngine:
             return None
         slot = st.slot
         self._prefilling = None
-        self.pages.install(slot, st.row, len(prompt), st.wrow)
+        self.pages.install(slot, st.row, stored, st.wrow)
         self.cache = dataclasses.replace(
             self.cache,
-            pos=self.cache.pos.at[slot].set(len(prompt)),
+            pos=self.cache.pos.at[slot].set(stored),
             start=self.cache.start.at[slot].set(0),
         )
         self.pages.register_prefix(slot, prompt, st.path,
@@ -1830,6 +1873,10 @@ class InferenceEngine:
                 f"preemption is not wired for "
                 f"{self.config.model_type}'s family cache"
             )
+        if self.blocks is not None:
+            raise NotImplementedError(
+                f"preemption inside a block is not wired for "
+                f"{self.config.model_type} yet (ROADMAP R9)")
         self._preempt_requested.add(req.rid)
 
     def _reap_preempt_requests(self) -> None:
@@ -2265,23 +2312,37 @@ class InferenceEngine:
             row = np.zeros((self.config.vocab_size,), bool)
             ids = np.asarray(req.prompt, np.int64)
             row[ids[(ids >= 0) & (ids < row.size)]] = True
-        with self._phase("first_token.sample", req.rid):
-            self.cur, self.seen, self._rng, out = self._first_token(
-                logits_last, self._rng, np.float32(temp), np.int32(topk),
-                np.float32(topp), np.bool_(dosample), np.float32(penalty),
-                row, np.int32(slot), cur=self.cur, seen=self.seen,
-            )
-            # the admission's one host sync: the prefill program has run
-            # by now
-            first, first_lp, first_top = _read_first_token(
-                out, self.logprobs_top_k)
+        by_blocks = self.blocks is not None
+        with self._phase("prefill.wait" if by_blocks
+                         else "first_token.sample", req.rid):
+            if by_blocks:
+                # no token comes of a prefill here: open the slot's first
+                # block. Nothing is fetched, so the next pass is enqueued
+                # behind the prefill; only a traced admission waits for it,
+                # so that its span is the work's and not the enqueueing's
+                self.blocks.state = self._arm_block(
+                    self.blocks.state, np.int32(slot),
+                    *self.blocks.arm(slot, req))
+                if tr is not None and tr.enabled:
+                    jax.block_until_ready(logits_last)
+            else:
+                self.cur, self.seen, self._rng, out = self._first_token(
+                    logits_last, self._rng, np.float32(temp),
+                    np.int32(topk), np.float32(topp), np.bool_(dosample),
+                    np.float32(penalty), row, np.int32(slot), cur=self.cur,
+                    seen=self.seen,
+                )
+                # the admission's one host sync: the prefill program has
+                # run by now
+                first, first_lp, first_top = _read_first_token(
+                    out, self.logprobs_top_k)
         rt_sample = self._retrace_mark("first_token.sample")
         if t_enter is not None:
             t_sampled = self._clock()
         eos = (req.eos_token_id if req.eos_token_id is not None
                else self.gen.eos_token_id)
         self._slots[slot] = _Slot(
-            req=req, remaining=req.max_new_tokens - 1, eos=eos,
+            req=req, remaining=req.max_new_tokens - (not by_blocks), eos=eos,
             seq=next(self._seq),
         )
         self._temp[slot], self._topk[slot] = temp, topk
@@ -2305,6 +2366,10 @@ class InferenceEngine:
                 moe_args = _moe_load(chosen, self.config.num_experts)
             self._admit_moe = []
         moe_args.update(self._admit_args)
+        if by_blocks:
+            stored = self._prefill_len(req.prompt)
+            moe_args.update(blocks_stored=stored // self.blocks.b,
+                            tail_tokens=len(req.prompt) - stored)
         if req.admit_ts is not None:
             self.prefill_seconds.observe(now - req.admit_ts)
             if tr is not None and tr.enabled:
@@ -2321,12 +2386,14 @@ class InferenceEngine:
                         (("prefill.dispatch",
                           {"rid": req.rid, "prompt_tokens": len(req.prompt),
                            "retrace_s": rt_dispatch}),
-                         ("first_token.sample",
+                         ("prefill.wait" if by_blocks
+                          else "first_token.sample",
                           {"rid": req.rid, "retrace_s": rt_sample}),
                          ("first_token.arm",
                           {"rid": req.rid, "retrace_s": rt_arm})),
                         tid=req.rid, cat="request")
-        self._emit(slot, first, first_lp, first_top)
+        if not by_blocks:
+            self._emit(slot, first, first_lp, first_top)
 
     def _admit_dense(self, req: Request, slot: int) -> None:
         self._mark_admitted(req)
@@ -2551,6 +2618,8 @@ class InferenceEngine:
         self.active[:] = False
         self._preempted.clear()  # blobs reference the old pool's layout
         self._prefilling = None  # a half-run chunk plan died with the pool
+        if self.blocks is not None:
+            self.blocks.reset()
         self._slot_adapter = [None] * self.n_slots
         self._blora, self._blora_dirty = None, True
         if self.paged:
@@ -2854,7 +2923,8 @@ class InferenceEngine:
         unread = None if self._flight is None else self._flight.reqs
         if unread is None and self.active.any():
             if self.paged:
-                self._ensure_decode_pages()
+                self._ensure_decode_pages(
+                    1 if self.blocks is None else self.blocks.b)
             if self.active.any():
                 unread = [s.req if a else None
                           for s, a in zip(self._slots, self.active)]
@@ -2884,11 +2954,21 @@ class InferenceEngine:
         for i in np.nonzero(self.active)[0]:
             s = self._slots[int(i)]
             behind = unread[i] is s.req
+            if self.blocks is not None:
+                # a pass yields 0 to b tokens: no count says which is a
+                # row's last, so every row gets one (dropped at its read if
+                # the request ended in between) and the pages of the block
+                # it will write
+                rows[i] = s.req
+                n = self.blocks.need_tokens(int(i), behind)
+                if n > 0:
+                    need.append((int(i), n))
+                continue
             if behind and s.remaining <= 1:
                 continue
             rows[i] = s.req
             need.append((int(i), 1 + behind))
-        if not need:
+        if not any(r is not None for r in rows):
             return None
         if self.paged:
             for slot, n in need:
@@ -2961,10 +3041,15 @@ class InferenceEngine:
             if st is not None:
                 t_args = self._clock()
             with self._phase("decode.call", seq=seq):
-                self.cur, out, self.cache, self.seen = self._decode(
-                    self.model.params, self.cur, self.cache, key, *sampling,
-                    self.seen, penalty, lora=lora,
-                )
+                if self.blocks is not None:
+                    self.blocks.state, out, self.cache = self._decode(
+                        self.model.params, self.blocks.state, self.cache,
+                        key, *sampling)
+                else:
+                    self.cur, out, self.cache, self.seen = self._decode(
+                        self.model.params, self.cur, self.cache, key,
+                        *sampling, self.seen, penalty, lora=lora,
+                    )
         except Exception:
             # the donated cache buffer is gone — rebuild before re-raising
             # (the server's guard fails the in-flight requests)
@@ -3010,6 +3095,17 @@ class InferenceEngine:
         self.decode_rows_discarded += (
             sum(r is not None for r in fl.reqs) - int(live.sum()))
         self.decode_steps[fl.ahead] += 1
+        if self.blocks is not None:
+            self.blocks.read(fl, host, live, t_wait)
+        else:
+            self._read_rows(fl, host, live, t_wait)
+        if st is not None:
+            st.seq = fl.seq
+            st.close(self._clock(), "step.emit", {"seq": fl.seq})
+
+    def _read_rows(self, fl: _Flight, host: "np.ndarray",
+                   live: "np.ndarray", t_wait: Optional[float]) -> None:
+        """A plain decode step's rows as fetched: one token a live row."""
         n_top = self.logprobs_top_k
         toks = host[:, 0]
         lps = self._inject_nan(
@@ -3054,18 +3150,17 @@ class InferenceEngine:
                     alt = {int(t): float(l)
                            for t, l in zip(tops_h[0][i], tops_h[1][i])}
                 self._emit(i, int(toks[i]), float(lps[i]), alt)
-        if st is not None:
-            st.seq = fl.seq
-            st.close(self._clock(), "step.emit", {"seq": fl.seq})
 
     def _note_decode_step(self, fl: _Flight, live: "np.ndarray",
                           t_wait: Optional[float] = None,
-                          arrays: int = 1) -> None:
+                          arrays: int = 1,
+                          extra: Optional[dict] = None) -> None:
         """Per-step accounting where step `fl` has been fetched: the
         duration histogram and, on the decode track, its `decode_step`
         span, from the later of its dispatch and the fetch before it to
         this fetch, so that consecutive spans never overlap and, with a
-        step in flight, its length is what the step cost the device. The
+        step in flight, its length is what the step cost the device.
+        `extra`: what a pass over blocks adds to the span's arguments. The
         span's arguments describe `fl`: they are taken before the host's
         mirrors advance. `t_wait`: where `decode.wait` ended, for a read
         whose phases are parts of `engine.step`."""
@@ -3090,7 +3185,7 @@ class InferenceEngine:
             "decode_step", t_start, t1 - t_start, tid=DECODE_TID,
             cat="engine", seq=fl.seq, ahead=fl.ahead, occupancy=busy,
             slots=self.n_slots, queue_depth=self._queue.qsize(), **pages,
-            **self.moe_load())
+            **self.moe_load(), **(extra or {}))
         tr.counter("batch", ts=t1, occupancy=busy,
                    queued=self._queue.qsize(),
                    preempted=len(self._preempted))

@@ -183,11 +183,22 @@ def main() -> int:
                      for s in jax.tree.leaves(eng.kind.leaves(pool)))
 
     rows = []
-    dec = eng._decode.lower(
-        params, arr((B,), jnp.int32), pool, arr((2,), jnp.uint32),
-        arr((B,), jnp.float32), arr((B,), jnp.int32), arr((B,), jnp.float32),
-        arr((B,), jnp.bool_), arr((B, cfg.vocab_size), jnp.bool_),
-        arr((B,), jnp.float32), lora=None).compile()
+    sampling = (arr((B,), jnp.float32), arr((B,), jnp.int32),
+                arr((B,), jnp.float32), arr((B,), jnp.bool_))
+    if eng.blocks is not None:  # a pass over every row's block
+        from bigdl_tpu.serving.blocks import BlockState
+
+        b = cfg.block_length
+        state = BlockState(ids=arr((B, b), jnp.int32),
+                           revealed=arr((B, b), jnp.bool_),
+                           n_pass=arr((B,), jnp.int32))
+        dec = eng._decode.lower(
+            params, state, pool, arr((2,), jnp.uint32), *sampling).compile()
+    else:
+        dec = eng._decode.lower(
+            params, arr((B,), jnp.int32), pool, arr((2,), jnp.uint32),
+            *sampling, arr((B, cfg.vocab_size), jnp.bool_),
+            arr((B,), jnp.float32), lora=None).compile()
     rows.append((f"engine_decode B={B}", dec.memory_analysis()))
     table = arr((1, eng.max_pages_per_row), jnp.int32)
     for T in args.prefill:

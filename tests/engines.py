@@ -33,9 +33,9 @@ from bigdl_tpu.serving.engine import InferenceEngine
 _SHAPES = ("n_slots", "max_len", "paged", "page_size", "n_pages",
            "speculative", "draft_k", "logprobs_top_k", "quantize_kv")
 #: the attributes that hold an engine's programs
-_PROGRAMS = ("_decode", "_first_token", "_prefill", "_insert",
-             "_paged_prefill", "_copy_page", "_swap_in", "_dense_swap_in",
-             "_spec_decode")
+_PROGRAMS = ("_decode", "_first_token", "_arm_block", "_prefill",
+             "_insert", "_paged_prefill", "_copy_page", "_swap_in",
+             "_dense_swap_in", "_spec_decode")
 _SIGNATURE = inspect.signature(InferenceEngine.__init__)
 _donors: dict = {}
 

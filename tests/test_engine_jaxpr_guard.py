@@ -26,6 +26,13 @@ PR 47 adds a tiny Laguna the same way (models/laguna.py, loaded on first
 use): its first period is run by itself, because layer 0's feed-forward is
 dense, and the other periods are the scan's body, so it counts two periods'
 layers whatever the depth; the six above count what they counted.
+PR 50 adds a tiny SDAR-MoE (models/sdar.py, loaded on first use: Qwen3-MoE's
+block through `llama.forward`, generated by diffusion over blocks): its
+`engine_decode` is a PASS over a block of 4 positions a row with the reveal
+after it (serving/blocks.py), its prefill the llama prefill under a mask
+causal by block. What it added to `llama.forward` and `flash_attention` is
+behind `config.block_length`, 0 for everyone else: the seven above count
+what they counted.
 
     JAX_PLATFORMS=cpu python tests/test_engine_jaxpr_guard.py
 """
@@ -83,6 +90,10 @@ MODELS = {
                 "partial_rotary_factor": 0.5},
             "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
                                   "partial_rotary_factor": 1}}),
+    "sdar_moe": dict(
+        _DENSE, model_type="sdar_moe", head_dim=32, rms_norm_eps=1e-6,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=128,
+        norm_topk_prob=True, mask_token_id=511),
 }
 
 # (engine_decode, engine_paged_prefill) on the parent of PR 38; the paged
@@ -96,6 +107,8 @@ PINNED = {
     "smallthinker": (1404, 1244),  # PR 41's own: a four-layer scan body
     # PR 47's own: the first period's four layers, then a four-layer body
     "laguna": (3316, 2904),
+    # PR 50's own: a pass over blocks of 4 and its reveal; the llama prefill
+    "sdar_moe": (598, 438),
 }
 
 
@@ -128,10 +141,15 @@ def counts(name: str) -> tuple:
                           max_len=256, paged=True, page_size=16, n_pages=65)
     B, c, z = 4, eng.cache, jnp.zeros
     table = z((1, eng.max_pages_per_row), jnp.int32)
+    sampling = (z((B,)), z((B,), jnp.int32), z((B,)), z((B,), bool))
+    if eng.blocks is not None:  # a pass over every row's block
+        decode = (params, eng.blocks.state, c, jax.random.PRNGKey(0),
+                  *sampling)
+    else:
+        decode = (params, z((B,), jnp.int32), c, jax.random.PRNGKey(0),
+                  *sampling, eng.seen, z((B,)))
     programs = (
-        (eng._decode, (
-            params, z((B,), jnp.int32), c, jax.random.PRNGKey(0), z((B,)),
-            z((B,), jnp.int32), z((B,)), z((B,), bool), eng.seen, z((B,)))),
+        (eng._decode, decode),
         (eng._paged_prefill, (
             params, eng.kind.leaves(c), (table, table), z((1,), jnp.int32),
             z((1, 64), jnp.int32), z((), jnp.int32), z((1,), jnp.int32))))

@@ -734,3 +734,52 @@ def test_flash_attention_compiles_at_the_cells_shapes(one_chip, name):
 
     text = jax.jit(call).lower(*args).compile().as_text()
     assert "flash_attention" in text
+
+
+# ---- SDAR-30B-A3B: generation by diffusion over blocks (ISSUE 50) ----------
+
+# Hkv 4, D 128, 8 query heads a KV head, pages of 64, b 4, 24 layers, 16 slots
+
+
+def test_a_blocks_queries_compile_as_rows_of_the_paged_kernel(one_chip):
+    """A block's 4 query positions are 4 x 8 = 32 rows of each KV head's dot
+    at the block's last slot: `paged_decode_attention` itself, 128 query rows
+    a slot where a one-token step has 32, the pool handed over as it lies."""
+    from bigdl_tpu.ops.pallas import paged_attention as pa
+
+    B, b, Hq, Hkv, D, L, NP, page, mp = 16, 4, 32, 4, 128, 24, 513, 64, 32
+    kv = _sds((L, NP, page, Hkv, D), jnp.bfloat16, one_chip)
+
+    def f(q, k, v, bt, layer, pos, start, live):
+        return pa.paged_block_attention(q, k, v, bt, layer, pos, start,
+                                        live=live, interpret=False)
+
+    c = jax.jit(f).lower(
+        _sds((B, b, Hq, D), jnp.bfloat16, one_chip), kv, kv,
+        _sds((B, mp), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip)).compile()
+    assert "paged_decode_attention" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 21  # q and out
+    # re-ordered by KV head: 2 x 16 x 128 x 128 bf16, never the pool
+
+
+@pytest.mark.parametrize("T", [64, 1024])
+def test_block_causal_flash_attention_compiles(one_chip, T):
+    """The prefill's mask causal by blocks of 4: T 1024 over 2048 slots (the
+    cell's longest prompt) and its shortest."""
+    from bigdl_tpu.ops.pallas.flash_attention import flash_attention
+
+    S, Hq, Hkv, D = 2048, 32, 4, 128
+
+    def call(q, k, v, start, q_offset):
+        return flash_attention(q, k, v, start=start, q_offset=q_offset,
+                               block_causal=4, interpret=False)
+
+    text = jax.jit(call).lower(
+        _sds((1, T, Hq, D), jnp.bfloat16, one_chip),
+        _sds((1, S, Hkv, D), jnp.bfloat16, one_chip),
+        _sds((1, S, Hkv, D), jnp.bfloat16, one_chip),
+        _sds((1,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile().as_text()
+    assert "flash_attention" in text
