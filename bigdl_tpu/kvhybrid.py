@@ -320,9 +320,9 @@ class _StateBesidePages(kvpaged.CacheKind):
     def prefill_args(self, st):
         return {"state_chunks": st.state_chunks}
 
-    def decode_args(self, cfg, table, live, moved):
+    def decode_args(self, cfg, table, live, moved, pool):
         return {**kvstate.state_decode_args(live, moved),
-                **super().decode_args(cfg, table, live, moved)}
+                **super().decode_args(cfg, table, live, moved, pool)}
 
 
 CACHE_KIND = _StateBesidePages()

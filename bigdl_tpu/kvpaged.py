@@ -609,9 +609,9 @@ class CacheKind:
         bytes the admission touched."""
         return {"row_pages": st.row_pages, "pages_written": st.pages_written}
 
-    def decode_args(self, cfg, table, live, moved: int) -> dict:
+    def decode_args(self, cfg, table, live, moved: int, pool) -> dict:
         """The `decode_step` span's: the table's pos still holds the
-        step's own."""
+        step's own; `pool` is the engine's, read for its shapes alone."""
         n_live, grid = table.grid_pages(live)
         return {"live_pages": n_live, "grid_pages": grid}
 
@@ -650,11 +650,23 @@ class _LatentPages(CacheKind):
     def prefill_args(self, st):
         return {"latent_tokens_upprojected": st.upprojected}
 
-    def decode_args(self, cfg, table, live, moved):
-        n = sum(table.pos[i] + 1 for i in np.nonzero(live)[0])  # slots 0..pos
-        return {**super().decode_args(cfg, table, live, moved),
+    def decode_args(self, cfg, table, live, moved, pool):
+        from bigdl_tpu.ops.pallas.paged_attention import latent_group_pages
+
+        rows = [int(i) for i in np.nonzero(live)[0]]
+        n = sum(table.pos[i] + 1 for i in rows)  # slots 0..pos
+        mp = table.max_pages_per_row
+        group = latent_group_pages(pool.lat, cfg.num_attention_heads, mp)
+        return {**super().decode_args(cfg, table, live, moved, pool),
                 "latent_live_tokens": int(n),
-                "latent_bytes_read": int(n * self.token_nbytes(cfg))}
+                "latent_bytes_read": int(n * self.token_nbytes(cfg)),
+                # one call of the decode kernel (a layer): a grid step a
+                # slot, a loop trip a group of pages from an engine row's
+                # slot 0 (its `start`) up to its pos, as `grid_pages` counts
+                "attn_grid_steps": table.n_slots,
+                "attn_live_groups": sum(
+                    min(table.pos[i] // table.page_size, mp - 1) // group + 1
+                    for i in rows)}
 
     def metrics(self, engine):
         pool = engine.pages.pool

@@ -408,7 +408,7 @@ class _StateRows(kvpaged.CacheKind):
     def prefill_args(self, st):
         return {"state_chunks": st.state_chunks}
 
-    def decode_args(self, cfg, table, live, moved):
+    def decode_args(self, cfg, table, live, moved, pool):
         return state_decode_args(live, moved)
 
 

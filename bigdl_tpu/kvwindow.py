@@ -264,7 +264,7 @@ class _PageGroups(kvpaged.CacheKind):
                 "pages_written_global": st.pages_written,
                 "pages_written_window": st.window_pages_written}
 
-    def decode_args(self, cfg, table, live, moved):
+    def decode_args(self, cfg, table, live, moved, pool):
         # by group, and no one-pool count: a window layer loads fewer pages
         # than `pos` spans. freed = since the step before
         return {**table.group_pages(live),

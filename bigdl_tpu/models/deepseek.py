@@ -510,7 +510,12 @@ def forward(
     expand = paged and T > 1
     use_flash = expand and B == 1 and use_pallas()
     if use_kernel:
-        routes.note("attention", "pallas:paged_latent", detail)
+        from bigdl_tpu.ops.pallas.paged_attention import latent_group_pages
+
+        routes.note(
+            "attention", "pallas:paged_latent", detail + " grid of %d rows, "
+            "groups of %d pages" % (B, latent_group_pages(
+                cache.lat, H, cache.block_tables.shape[1])))
         row_live = live_rows(cache)  # the table does not change in here
     elif use_flash:
         routes.note("attention", "pallas:flash",

@@ -44,6 +44,12 @@ weight of exactly 0: a row's result depends on its own live pages alone,
 so the rows are independent ("parallel"). A row with no live page writes
 zeros.
 
+`paged_latent_decode_attention` (latent pages: one array, no heads)
+runs the same row loop around its own body: `_row_of_live_groups` is the
+skeleton of both, the flash recurrence (`_softmax_*`) and the scalar
+operand (`_scalars`) are shared, and what a group IS comes from the cache
+kind's static shapes.
+
 The kernel's own DMA can take a page out of the pool only where XLA
 leaves the page's [Hkv, D] tiles unpadded in HBM (`pool_tiles_whole`:
 every cell's shape; not 1, 3 or 6 KV heads, not a head of 64 or 96).
@@ -63,7 +69,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.ops.pallas import qdecode
+from bigdl_tpu.ops.pallas import qdecode, tiling
 
 _NEG_INF = -1e30
 _NO_WINDOW = 2 ** 30
@@ -141,6 +147,182 @@ def _div(x, n: int):
     return jax.lax.div(x, n)
 
 
+def _row_range(meta_ref, b, n_batch: int):
+    """(first, last) live pages of row `b` out of `_scalars`' operand."""
+    return meta_ref[2 + 2 * n_batch + b], meta_ref[2 + 3 * n_batch + b]
+
+
+def _row_meta(meta_ref, b, n_batch: int):
+    """(layer, window, pos, start, first, last) of row `b` out of it."""
+    return (meta_ref[0], meta_ref[1], meta_ref[2 + b],
+            meta_ref[2 + n_batch + b], *_row_range(meta_ref, b, n_batch))
+
+
+def _softmax_init(acc_ref, m_ref, l_ref):
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+
+def _softmax_update(s, valid, v, acc_ref, m_ref, l_ref, v_scale=None):
+    """The flash recurrence over one more block of columns: `s` the masked
+    float32 scores [rows, columns], `v` [columns, D] the context dot's
+    operand; `v_scale()` the columns' float32 scales (fp8 pages)."""
+    m_prev = m_ref[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # exp-weights of masked columns are exactly 0 (a fully-masked
+    # group must contribute nothing, even while m is still -inf)
+    pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[:] = l_ref[:] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+    if v_scale is not None:  # a dead page's scale is anything: 0 * NaN
+        pexp = jnp.where(valid, pexp * v_scale(), 0.0)
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[:] = m_new
+
+
+def _softmax_finish(o_ref, acc_ref, l_ref):  # no valid slot: zeros
+    l = l_ref[:]
+    o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _row_of_live_groups(bt_ref, b, layer, first_b, last_b, pages: int,
+                        arrays, sem, zeroed, o_ref, state, row_body,
+                        next_row=None):
+    """One grid step of a decode kernel whose pool stays in HBM: the loop
+    over the groups of `pages` logical pages that row b's live range
+    first_b .. last_b reaches into, BOTH kernels' skeleton.
+
+    `arrays` is [(the pool [L, n_pages, ...] in HBM, its VMEM buffer
+    [2, pages, ...])]: one DMA a live page and array, straight through the
+    block table, the next group's in flight while this one is computed.
+    A page the row does not own is never loaded: zeros stand in its place
+    in `zeroed` (the buffer the context dot reads: weight exactly 0,
+    whatever earlier groups and rows left there), and its scores are
+    masked by slot. `row_body()` is traced once a live row, after its
+    first group's copies have started, and returns `attend(g, slot)`:
+    group g's dots and softmax update out of buffer `slot`. `state` is the
+    (acc, m, l) scratch. A row with no live page runs nothing and writes
+    zeros.
+
+    `next_row` is (row -> its (first, last), the number of rows, an SMEM
+    scratch of two int32): the row's LAST trip then starts the first group
+    of the row after it, if that one is live, into the buffer the trip
+    leaves free, and says so in the scratch (was it started, into which
+    buffer), so that no row but the first after an idle one waits for a
+    DMA with nothing to compute. The grid must then run its rows in order
+    ("arbitrary": on a chip of two TensorCores they all run on one).
+    TEMPORARY: `paged_decode_attention` gives none while its Mosaic
+    modules are held to PR 50's (`tests/test_tpu_lowering._PAGED_BODIES`),
+    and a row of its then opens with its own first group; the change that
+    makes it pass one and re-pins deletes that arm, the ONE `if next_row
+    is None` below, and the default (PERF.md 'Left by PR 51' (2))."""
+    max_pages = bt_ref.shape[1]
+
+    def groups_of(first, last):
+        g_first = first // pages
+        return g_first, jnp.where(first <= last,
+                                  last // pages - g_first + 1, 0)
+
+    g_first, n_groups = groups_of(first_b, last_b)
+
+    def transfers(row, first, last, g, slot):
+        """[(is the page live, its copies)] of a row's group g into
+        `slot`."""
+        out = []
+        for j in range(pages):
+            pg = g * pages + j
+            phys = bt_ref[row, jnp.minimum(pg, max_pages - 1)]
+            out.append(((pg >= first) & (pg <= last), [
+                pltpu.make_async_copy(src.at[layer, phys], dst.at[slot, j],
+                                      sem.at[i, slot])
+                for i, (src, dst) in enumerate(arrays)]))
+        return out
+
+    def start(g, slot, row=b, first=first_b, last=last_b):
+        for j, (is_live, copies) in enumerate(
+                transfers(row, first, last, g, slot)):
+            @pl.when(is_live)
+            def _():
+                for c in copies:
+                    c.start()
+
+            @pl.when(jnp.logical_not(is_live))
+            def _():
+                zeroed[slot, j] = jnp.zeros(zeroed.shape[2:], zeroed.dtype)
+
+    def wait(g, slot):
+        for is_live, copies in transfers(b, first_b, last_b, g, slot):
+            @pl.when(is_live)
+            def _():
+                for c in copies:
+                    c.wait()
+
+    if next_row is None:  # TEMPORARY, see above: never ahead of its row
+        def open_row():
+            start(g_first, 0)
+            return (lambda i: i & 1), lambda i, slot: pl.when(
+                i + 1 < n_groups)(functools.partial(
+                    start, g_first + i + 1, 1 - slot))
+    else:
+        range_of, n_rows, ahead_ref = next_row
+
+        @pl.when(b == 0)
+        def _nothing_in_flight():
+            ahead_ref[0] = 0
+
+        def open_row():
+            started = ahead_ref[0] == 1  # by the row before, in its slot
+            slot0 = jnp.where(started, ahead_ref[1], 0)
+            pl.when(jnp.logical_not(started))(
+                functools.partial(start, g_first, 0))
+            ahead_ref[0] = 0
+            after = jnp.minimum(b + 1, n_rows - 1)
+            first_a, last_a = range_of(after)
+            g_first_a, n_groups_a = groups_of(first_a, last_a)
+            after_is_live = (b + 1 < n_rows) & (n_groups_a > 0)
+
+            def start_next(i, slot):  # this row's group, or the next row's
+                mine = i + 1 < n_groups
+
+                @pl.when(mine | after_is_live)
+                def _start_what_comes_next():
+                    start(jnp.where(mine, g_first + i + 1, g_first_a),
+                          1 - slot, jnp.where(mine, b, after),
+                          jnp.where(mine, first_b, first_a),
+                          jnp.where(mine, last_b, last_a))
+
+                @pl.when(jnp.logical_not(mine) & after_is_live)
+                def _tell_the_row_after():
+                    ahead_ref[0] = 1
+                    ahead_ref[1] = 1 - slot
+
+            return (lambda i: (slot0 + i) & 1), start_next
+
+    @pl.when(n_groups == 0)
+    def _idle_row():  # the grid step and this store are all it costs
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(n_groups > 0)
+    def _live_row():
+        _softmax_init(*state)
+        # the row's first group is in flight; trip i -> its buffer, and
+        # what trip i starts for the trip after it
+        slot_of, start_next = open_row()
+        attend = row_body()  # once a row, not once a group
+
+        def one_group(i, _):
+            slot = slot_of(i)
+            start_next(i, slot)
+            wait(g_first + i, slot)
+            attend(g_first + i, slot)
+
+        jax.lax.fori_loop(0, n_groups, one_group, None)
+        _softmax_finish(o_ref, state[0], state[2])
+
+
 def _kernel(bt_ref, meta_ref, q_ref, k_in, v_in, *refs,
             n_kv: int, group: int, page: int, pages: int, n_batch: int,
             scale: float, softcap: float | None, quantized: bool,
@@ -149,27 +331,15 @@ def _kernel(bt_ref, meta_ref, q_ref, k_in, v_in, *refs,
     if quantized:  # fp8 pages: the row's per-vector f32 scales, by column
         ks_ref, vs_ref, *refs = refs
     o_ref, *buffers, acc_ref, m_ref, l_ref = refs
+    state = (acc_ref, m_ref, l_ref)
     b = pl.program_id(0)
     n_q, head_dim = q_ref.shape[1:]
     columns = pages * page * n_kv
 
-    layer = meta_ref[0]
-    win = meta_ref[1]  # traced per-layer sliding window (2**30 = none)
-    pos_b = meta_ref[2 + b]
-    start_b = meta_ref[2 + n_batch + b]
-    first_b = meta_ref[2 + 2 * n_batch + b]
-    last_b = meta_ref[2 + 3 * n_batch + b]
+    # win: the traced per-layer sliding window (2**30 = none)
+    layer, win, pos_b, start_b, first_b, last_b = _row_meta(
+        meta_ref, b, n_batch)
     lo_b = jnp.maximum(start_b, pos_b - win + 1)
-
-    def init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    def finish():  # a row with no valid slot writes zeros
-        l = l_ref[:]
-        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
-                    ).astype(o_ref.dtype)
 
     def columns_of_row():
         """(the columns of a query row's own KV head, a column's slot in
@@ -213,20 +383,8 @@ def _kernel(bt_ref, meta_ref, q_ref, k_in, v_in, *refs,
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
         s = jnp.where(valid, s, _NEG_INF)
-
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # exp-weights of masked columns are exactly 0 (a fully-masked
-        # group must contribute nothing, even while m is still -inf)
-        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
-        if quantized:  # a dead page's scale is anything: 0 * NaN
-            pexp = jnp.where(valid, pexp * vs_ref[0, at], 0.0)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        _softmax_update(s, valid, v, *state,
+                        v_scale=(lambda: vs_ref[0, at]) if quantized else None)
 
     if piped:
         # a pool whose tiles XLA pads (`pool_tiles_whole`): grid
@@ -234,75 +392,40 @@ def _kernel(bt_ref, meta_ref, q_ref, k_in, v_in, *refs,
         # step outside first_b .. last_b the index maps name the block
         # already held (`clamped_page`: no DMA) and the body is skipped.
         p = pl.program_id(1)
-        pl.when(p == 0)(init)
+        pl.when(p == 0)(functools.partial(_softmax_init, *state))
 
         @pl.when((p >= first_b) & (p <= last_b))
         def _live_page():
             attend(p, k_in[0], v_in[0], *columns_of_row())
 
-        pl.when(p == pl.num_programs(1) - 1)(finish)
+        pl.when(p == pl.num_programs(1) - 1)(
+            functools.partial(_softmax_finish, o_ref, acc_ref, l_ref))
         return
 
     k_buf, v_buf, sem = buffers
-    max_pages = bt_ref.shape[1]
 
-    g_first = first_b // pages
-    n_groups = jnp.where(first_b <= last_b, last_b // pages - g_first + 1, 0)
+    def row_body():
+        row_columns = columns_of_row()
+        return lambda g, slot: attend(g, k_buf[slot], v_buf[slot],
+                                      *row_columns)
 
-    def transfers(g, slot):
-        """[(is the page live, its copies)] of group g into buffer `slot`:
-        one DMA a live page and array, straight through the block table."""
-        out = []
-        for j in range(pages):
-            pg = g * pages + j
-            phys = bt_ref[b, jnp.minimum(pg, max_pages - 1)]
-            out.append(((pg >= first_b) & (pg <= last_b), [
-                pltpu.make_async_copy(src.at[layer, phys], dst.at[slot, j],
-                                      sem.at[i, slot])
-                for i, (src, dst) in enumerate([(k_in, k_buf),
-                                                (v_in, v_buf)])]))
-        return out
+    _row_of_live_groups(bt_ref, b, layer, first_b, last_b, pages,
+                        [(k_in, k_buf), (v_in, v_buf)], sem, v_buf, o_ref,
+                        state, row_body)
 
-    def start(g, slot):
-        for j, (is_live, copies) in enumerate(transfers(g, slot)):
-            @pl.when(is_live)
-            def _():
-                for c in copies:
-                    c.start()
 
-            # a page the row does not own is never loaded: zeros stand in
-            # its place in V (weight exactly 0), whatever earlier groups
-            # and rows left there; K's scores are masked by slot
-            @pl.when(jnp.logical_not(is_live))
-            def _():
-                v_buf[slot, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
-
-    def wait(g, slot):
-        for is_live, copies in transfers(g, slot):
-            @pl.when(is_live)
-            def _():
-                for c in copies:
-                    c.wait()
-
-    @pl.when(n_groups == 0)
-    def _idle_row():  # the grid step and this store are all it costs
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-
-    @pl.when(n_groups > 0)
-    def _live_row():
-        init()
-        start(g_first, 0)
-        row_columns = columns_of_row()  # once a row, not once a group
-
-        def one_group(i, _):
-            slot = i & 1
-            pl.when(i + 1 < n_groups)(
-                functools.partial(start, g_first + i + 1, 1 - slot))
-            wait(g_first + i, slot)
-            attend(g_first + i, k_buf[slot], v_buf[slot], *row_columns)
-
-        jax.lax.fori_loop(0, n_groups, one_group, None)
-        finish()
+def _scalars(layer, window, pos, start, page: int, max_pages: int, live):
+    """The kernels' second scalar-prefetch operand (the block table is
+    the first): [layer, window, pos x B, start x B, first x B, last x B],
+    first .. last each row's `live_page_range`."""
+    win = jnp.asarray(_NO_WINDOW if window is None else window, jnp.int32)
+    pos = pos.astype(jnp.int32)
+    start = start.astype(jnp.int32)
+    first, last = live_page_range(pos, start, win, page, max_pages, live)
+    return jnp.concatenate([
+        jnp.reshape(layer, (1,)).astype(jnp.int32), win[None],
+        pos, start, first, last,
+    ])
 
 
 @functools.partial(
@@ -342,14 +465,7 @@ def paged_decode_attention(
     operand = jnp.bfloat16 if quantized else k_pages.dtype
     P = group_pages(page, Hkv, D, k_pages.dtype.itemsize, mp)
 
-    win = jnp.asarray(_NO_WINDOW if window is None else window, jnp.int32)
-    pos = pos.astype(jnp.int32)
-    start = start.astype(jnp.int32)
-    first, last = live_page_range(pos, start, win, page, mp, live)
-    meta = jnp.concatenate([
-        jnp.reshape(layer, (1,)).astype(jnp.int32), win[None],
-        pos, start, first, last,
-    ])
+    meta = _scalars(layer, window, pos, start, page, mp, live)
 
     piped = not pool_tiles_whole(Hkv, D, k_pages.dtype.itemsize)
     if piped:  # a page a grid step, through the block table's clamped map
@@ -457,66 +573,55 @@ def paged_block_attention(
 # Latent pages (MLA, models/deepseek.py): the absorbed decode form
 # ---------------------------------------------------------------------------
 
-#: logical pages one grid step of the latent kernel reads. Each is its own
-#: block (its own DMA through the block table), and the step joins them in
-#: VMEM and makes ONE score dot and ONE context dot over all of them: a
-#: 64-token page alone is a dot of 64 columns, and the grid step's fixed
-#: cost would be paid per page.
-LATENT_PAGES_PER_STEP = 8
-
-
-def _latent_kernel(bt_ref, meta_ref, q_ref, *refs, rank: int, page: int,
-                   group: int, n_batch: int, scale: float):
-    lat_refs, (o_ref, acc_ref, m_ref, l_ref) = refs[:group], refs[group:]
+def _latent_kernel(bt_ref, meta_ref, q_ref, lat_in, o_ref, lat_buf, sem,
+                   ahead_ref, acc_ref, m_ref, l_ref, *, rank: int, page: int,
+                   pages: int, n_batch: int, scale: float):
+    """The row loop's other body: a group is `pages` pages of ONE array,
+    rows [slots, width] that are key (all of a row) and value (its first
+    `rank` lanes) at once, and every head is a row of the two dots."""
     b = pl.program_id(0)
-    p = pl.program_id(1)
+    layer, _, pos_b, start_b, first_b, last_b = _row_meta(meta_ref, b, n_batch)
+    slots = pages * page
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def row_body():
+        slot_in_group = jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[1], slots), 1)
 
-    first_b = meta_ref[1 + 2 * n_batch + b]
-    last_b = meta_ref[1 + 3 * n_batch + b]
+        def attend(g, slot):
+            lat = lat_buf[slot].reshape(slots, lat_buf.shape[-1])
+            # one read of the group serves every head
+            s = jax.lax.dot_general(
+                q_ref[0], lat, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [Hp, slots]
+            t = g * slots + slot_in_group
+            valid = (t >= start_b) & (t <= pos_b)
+            s = jnp.where(valid, s, _NEG_INF)
+            _softmax_update(s, valid, lat[:, :rank], acc_ref, m_ref, l_ref)
 
-    # a step with a live page among its `group`: the others' blocks are
-    # clamped onto live pages of the same row (finite, and masked by slot)
-    @pl.when((first_b <= last_b) & (p * group <= last_b)
-             & (p * group + group - 1 >= first_b))
-    def _live_step():
-        pos_b = meta_ref[1 + b]
-        start_b = meta_ref[1 + n_batch + b]
-        lat = jnp.concatenate([r[0, 0] for r in lat_refs], axis=0) \
-            if group > 1 else lat_refs[0][0, 0]  # [group * page, r + dr]
-        # every head is a row of the dot: one read of the page serves all
-        s = jax.lax.dot_general(
-            q_ref[0], lat, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [Hp, G * page]
-        slot = p * (group * page) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        valid = (slot >= start_b) & (slot <= pos_b)
-        s = jnp.where(valid, s, _NEG_INF)
+        return attend
 
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pexp.astype(lat.dtype), lat[:, :rank], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+    _row_of_live_groups(
+        bt_ref, b, layer, first_b, last_b, pages, [(lat_in, lat_buf)], sem,
+        lat_buf, o_ref, (acc_ref, m_ref, l_ref), row_body,
+        next_row=(functools.partial(_row_range, meta_ref, n_batch=n_batch),
+                  n_batch, ahead_ref))
 
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _finish():
-        l = l_ref[:]
-        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
-                    ).astype(o_ref.dtype)
+
+def latent_group_pages(lat_pages, heads: int, max_pages: int) -> int:
+    """Logical pages one trip of `paged_latent_decode_attention`'s row loop
+    joins over the pool `lat_pages` [L, n_pages, page, width] (its shape
+    and dtype are all that is read) for `heads` heads and rows of
+    `max_pages` pages: `tiling.latent_group_pages` on the pool as it is.
+    The kernel's own call, and what a record that names its group (the
+    route line, the `decode_step` span's `attn_live_groups`) calls on the
+    pool it holds."""
+    _, _, page, width = lat_pages.shape
+    return tiling.latent_group_pages(
+        page, width, jnp.dtype(lat_pages.dtype).itemsize, heads, max_pages)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
-                                             "pages_per_step"))
+                                             "pages_per_group"))
 def paged_latent_decode_attention(
     q_eff: jax.Array,  # [B, H, r]: W_uk^T q_nope, the absorbed query
     q_pe: jax.Array,  # [B, H, dr] rotated
@@ -529,13 +634,15 @@ def paged_latent_decode_attention(
     scale: float,
     live: jax.Array | None = None,  # [B] bool; None = every row is live
     interpret: bool | None = None,
-    pages_per_step: int = LATENT_PAGES_PER_STEP,
+    pages_per_group: int | None = None,  # tests and the kernel bench
 ) -> jax.Array:
     """Absorbed MLA decode over latent pages, in place: returns the
     context [B, H, r] (softmax-weighted sum of the compressed kv), to be
-    up-projected by W_uv outside. bf16 dots accumulated in float32,
-    float32 softmax state; pages outside `live_page_range` cost neither
-    DMA nor compute, and a row `live` marks idle comes back as zeros."""
+    up-projected by W_uv outside. `paged_decode_attention`'s form (grid
+    (B,), the pool in HBM, a loop over the row's live groups, a DMA a live
+    page) around its own body. bf16 dots accumulated in float32, float32
+    softmax state; pages outside `live_page_range` cost neither DMA nor
+    compute, and a row `live` marks idle comes back as zeros."""
     from bigdl_tpu.ops.pallas import interpret_mode
 
     if interpret is None:
@@ -543,46 +650,45 @@ def paged_latent_decode_attention(
     B, H, r = q_eff.shape
     L, NP, page, width = lat_pages.shape
     mp = block_tables.shape[1]
-    G = min(pages_per_step, mp)
-    steps = -(-mp // G)
     Hp = -(-H // 16) * 16  # whole bf16 sublane tiles for the dots' rows
+    P = min(mp, pages_per_group or latent_group_pages(lat_pages, H, mp))
 
     q = jnp.concatenate([q_eff, q_pe], axis=-1).astype(lat_pages.dtype)
     q = jnp.pad(q, ((0, 0), (0, Hp - H), (0, width - q.shape[-1])))
-    pos = pos.astype(jnp.int32)
-    start = start.astype(jnp.int32)
-    first, last = live_page_range(pos, start, _NO_WINDOW, page, mp, live)
-    meta = jnp.concatenate([
-        jnp.reshape(layer, (1,)).astype(jnp.int32), pos, start, first, last])
-
-    def lat_spec(j):
-        def index(b, p, bt, meta):
-            pg = clamped_page(p * G + j, meta[1 + 2 * B + b],
-                              meta[1 + 3 * B + b])
-            return meta[0], bt[b, pg], 0, 0
-        return pl.BlockSpec((1, 1, page, width), index)
+    meta = _scalars(layer, None, pos, start, page, mp, live)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, steps),
-        in_specs=[pl.BlockSpec((1, Hp, width), lambda b, p, bt, meta: (b, 0, 0))]
-        + [lat_spec(j) for j in range(G)],
-        out_specs=pl.BlockSpec((1, Hp, r), lambda b, p, bt, meta: (b, 0, 0)),
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, Hp, width), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, Hp, r), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
+            pltpu.VMEM((2, P, page, width), lat_pages.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((2,), jnp.int32),
             pltpu.VMEM((Hp, r), jnp.float32),
             pltpu.VMEM((Hp, 1), jnp.float32),
             pltpu.VMEM((Hp, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_latent_kernel, rank=r, page=page, group=G,
+        functools.partial(_latent_kernel, rank=r, page=page, pages=P,
                           n_batch=B, scale=scale),
         name="paged_latent_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hp, r), jnp.bfloat16),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # a row starts the next one's first copies, so rows run in
+            # order: "arbitrary" where the old grid's rows were "parallel".
+            # A v5e has one TensorCore and loses nothing; on a chip of two
+            # the rows would all run on one of them: such a target splits
+            # the rows by core first and reads ahead within a core's share
+            # (docs/kernels.md#paged-latent)
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=tiling.latent_group_bytes(
+                P, page, width, lat_pages.dtype.itemsize, Hp) + 2 ** 23,
         ),
         interpret=interpret,
-    )(block_tables, meta, q, *([lat_pages] * G))
+    )(block_tables, meta, q, lat_pages)
     return out[:, :H]

@@ -396,6 +396,41 @@ def flash_live_blocks(T: int, S: int, block_q: int, block_k: int,
     return live
 
 
+#: what one trip of the latent decode kernel's row loop may hold of VMEM
+#: (`latent_group_bytes`). A trip's fixed cost (a copy to start and to wait
+#: for a page, the softmax state's update) is paid a group and the dots run
+#: over a whole group whatever it holds, so a row's last group wastes half
+#: a group on average: at GLM-4.7-Flash's rows (640 lanes of bf16, 20
+#: heads, pages of 64) 16 pages, 5.3 MiB, beat 4, 8 and 32 on the cell's
+#: mix of rows and on full ones (4.00 ms a decode step against 6.15, 4.55
+#: and 4.10; PERF.md section 6, PR 51, the kernel alone on a v5e)
+LATENT_GROUP_BYTES = 6 * 1024 * 1024
+
+
+def latent_group_bytes(pages: int, page: int, width: int, itemsize: int,
+                       rows: int) -> int:
+    """VMEM one group of `pages` latent pages is priced at: both buffers
+    of the group's rows [slots, width] and the copy the dots read, and the
+    dozen [rows, slots] float32 temporaries of the softmax between them."""
+    slots = pages * page
+    return 3 * slots * width * itemsize + 12 * max(rows, 8) * slots * 4
+
+
+def latent_group_pages(page: int, width: int, itemsize: int, heads: int,
+                       max_pages: int) -> int:
+    """Logical pages of one unit of the latent decode kernel's work (one
+    score dot and one context dot over all heads, the heads padded to
+    whole tiles of 16 rows), from the static shapes alone: the largest
+    power of two whose `latent_group_bytes` stays within
+    `LATENT_GROUP_BYTES`. Never more than a row has, never fewer than
+    one."""
+    rows, pages = round_up(heads, 16), 1
+    while 2 * pages <= max_pages and latent_group_bytes(
+            2 * pages, page, width, itemsize, rows) <= LATENT_GROUP_BYTES:
+        pages *= 2
+    return pages
+
+
 #: the word path's chunk: [512, block_o] of codes, values, repeated
 #: scales, products (float32) and the bf16 result are 6 MiB of
 #: temporaries (8 with mins); smaller chunks only add loop turns, larger

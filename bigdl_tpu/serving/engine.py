@@ -3179,8 +3179,8 @@ class InferenceEngine:
         st = self._step_trace
         if st is not None:
             st.decoded = True
-        pages = (self.kind.decode_args(self.config, self.pages, live, moved)
-                 if self.paged else {})
+        pages = (self.kind.decode_args(self.config, self.pages, live, moved,
+                                       self.cache) if self.paged else {})
         tr.complete(
             "decode_step", t_start, t1 - t_start, tid=DECODE_TID,
             cat="engine", seq=fl.seq, ahead=fl.ahead, occupancy=busy,

@@ -232,7 +232,8 @@ _SPANS = {
     "kv_pages": (["pages_written", "row_pages"],
                  ["grid_pages", "live_pages"], []),
     "latent_pages": (["latent_tokens_upprojected"],
-                     ["grid_pages", "latent_bytes_read", "latent_live_tokens",
+                     ["attn_grid_steps", "attn_live_groups", "grid_pages",
+                      "latent_bytes_read", "latent_live_tokens",
                       "live_pages"],
                      ["bigdl_tpu_latent_pages_in_use",
                       "bigdl_tpu_latent_token_bytes"]),
@@ -276,7 +277,8 @@ def test_span_arguments_keep_the_names_the_benchmark_reads(name):
     table = _table(name)
     table.reserve(0, list(range(1, 20)))
     live = np.array([True, False, False, False])
-    args = kind.decode_args(cfg, table, live, 2 * 4096)
+    args = kind.decode_args(cfg, table, live, 2 * 4096,
+                            _pool(name, fill=False))
     assert sorted(args) == decode
     assert all(isinstance(v, int) for v in args.values()), args
     if "state_bytes_moved" in args:

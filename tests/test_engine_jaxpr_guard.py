@@ -202,10 +202,10 @@ def _eqns(jaxpr, inside_scan=False):
                         sub, inside_scan or e.primitive.name == "scan")
 
 
-def scale_work(name: str, prepare: bool) -> tuple:
-    """(equations of `engine_decode` outside the kernels' bodies, the
-    equations inside its layer scan that view 16-bit floats as integers or
-    slice a 16-bit operand, the layer stacks the scan is handed whole)."""
+def kernel_route_jaxpr(hf: dict, prepare: bool = True):
+    """`engine_decode` of a four-slot paged engine on `hf`, traced with
+    whatever route the environment gives the kernels: the tree prepared
+    for serving, or as `optimize_model` makes it."""
     import jax
     import jax.numpy as jnp
 
@@ -215,7 +215,7 @@ def scale_work(name: str, prepare: bool) -> tuple:
     from bigdl_tpu.quant.qtensor import without_scale_bits
     from bigdl_tpu.serving.engine import InferenceEngine
 
-    cfg = ModelConfig.from_hf_config(GUARDED[name])
+    cfg = ModelConfig.from_hf_config(hf)
     model = TpuModel(cfg, optimize_model(
         get_family(cfg.model_type).init_params(cfg, jax.random.PRNGKey(0)),
         cfg, "sym_int4"), "sym_int4")
@@ -223,10 +223,19 @@ def scale_work(name: str, prepare: bool) -> tuple:
     eng = InferenceEngine(model, n_slots=4, max_len=256, paged=True,
                           page_size=16, n_pages=65)
     B, z = 4, jnp.zeros
-    jaxpr = jax.make_jaxpr(eng._decode.__wrapped__)(
+    return jax.make_jaxpr(eng._decode.__wrapped__)(
         params, z((B,), jnp.int32), eng.cache, jax.random.PRNGKey(0),
         z((B,)), z((B,), jnp.int32), z((B,)), z((B,), bool), eng.seen,
         z((B,))).jaxpr
+
+
+def scale_work(name: str, prepare: bool) -> tuple:
+    """(equations of `engine_decode` outside the kernels' bodies, the
+    equations inside its layer scan that view 16-bit floats as integers or
+    slice a 16-bit operand, the layer stacks the scan is handed whole)."""
+    import jax.numpy as jnp
+
+    jaxpr = kernel_route_jaxpr(GUARDED[name], prepare)
     sixteen = (jnp.float16, jnp.uint16)
     work, n, whole = [], 0, 0
     for e, in_scan in _eqns(jaxpr):
@@ -261,6 +270,33 @@ def test_a_prepared_trees_decode_scan_neither_views_nor_slices_a_scale(
     assert (n_stored, n_prepared) == KERNEL_ROUTE[name]
 
 
+# GLM's decode step with the kernels on, outside the kernels' bodies: 928 on
+# PR 50's tree, and 930 since PR 51 gave the latent decode kernel its
+# sibling's scalar operand (`paged_attention._scalars`: the window's entry, one
+# equation a call, two calls a step). The XLA-route pin above did not move.
+GLM_KERNEL_ROUTE = 930
+
+
+def glm_kernel_route() -> tuple:
+    """(equations of GLM's `engine_decode` outside the kernels' bodies, the
+    grid of each `paged_latent_decode_attention` call in it)."""
+    jaxpr = kernel_route_jaxpr(MODELS["glm4_moe_lite"])
+    eqns = [e for e, _ in _eqns(jaxpr)]
+    return len(eqns), [
+        e.params["grid_mapping"].grid for e in eqns
+        if e.primitive.name == "pallas_call" and "paged_latent_decode"
+        in e.params["name"]]
+
+
+@pytest.mark.core
+def test_glms_decode_step_calls_the_latent_kernel_a_grid_step_a_row(
+        monkeypatch):
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+    n, grids = glm_kernel_route()
+    assert grids == [(4,), (4,)]  # the dense layer's scan, the experts'
+    assert n == GLM_KERNEL_ROUTE
+
+
 if __name__ == "__main__":
     sys.path.insert(0, os.getcwd())
     for name in sorted(MODELS):
@@ -269,3 +305,4 @@ if __name__ == "__main__":
     for name in sorted(GUARDED):
         print(f"    {name!r}: ({scale_work(name, False)[0]}, "
               f"{scale_work(name, True)[0]}),  # KERNEL_ROUTE", flush=True)
+    print(f"GLM_KERNEL_ROUTE = {glm_kernel_route()[0]}", flush=True)

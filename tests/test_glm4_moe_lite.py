@@ -18,6 +18,7 @@ from bigdl_tpu.api import TpuModel, optimize_model
 from bigdl_tpu.generate import GenerationConfig
 from bigdl_tpu.models import deepseek, get_family
 from bigdl_tpu.models.config import ModelConfig
+from bigdl_tpu.ops.pallas.paged_attention import latent_group_pages
 from bigdl_tpu.serving.engine import InferenceEngine
 from engines import shared_engine
 
@@ -218,6 +219,11 @@ def test_engine_with_the_kernels_interpreted_matches_the_reference(interpret):
     _check(ref, TINY_KERNELS, params, r, 4, 0.02)
     kinds = {k[:2] for k in routes}
     assert ("attention", "pallas:paged_latent") in kinds
+    (form,) = {k[2].split(" T1 ")[1] for k in routes
+               if k[1] == "pallas:paged_latent"}
+    assert form == "grid of 2 rows, groups of %d pages" % (
+        latent_group_pages(eng.cache.lat, cfg.num_attention_heads,
+                           eng.max_pages_per_row))
     assert ("attention", "pallas:flash") in kinds
     assert ("moe", "pallas:grouped") in kinds
     assert eng.page_leaks() == 0
@@ -354,6 +360,14 @@ def test_spans_and_gauges_of_a_latent_engine():
     # both rows live in the second of the two decode steps: slots 0 .. pos
     # of each, the token the step itself wrote included
     assert max(a["latent_live_tokens"] for a in steps) == 42 + 22
+    # the decode kernel's form, a call: a grid step a slot, and the trips of
+    # its loop, one a group of pages up to a live row's pos
+    group = latent_group_pages(eng.cache.lat, cfg.num_attention_heads,
+                               eng.max_pages_per_row)
+    assert all(a["attn_grid_steps"] == eng.n_slots and a["occupancy"]
+               <= a["attn_live_groups"] <= a["live_pages"] for a in steps)
+    assert max(a["attn_live_groups"] for a in steps) == (
+        41 // eng.page_size // group + 1 + 21 // eng.page_size // group + 1)
     assert all(a["moe_experts"] == 16 and a["moe_assignments"] > 0
                for a in steps)
     pre = {e["args"]["prompt_tokens"]: e["args"] for e in ev
@@ -412,13 +426,196 @@ def test_latent_kernel_interpreted_equals_jnp(group):
             jnp.asarray(start))
     got = paged_latent_decode_attention(
         q_eff, q_pe, lat, *args, scale=0.1, live=jnp.asarray(live),
-        interpret=True, pages_per_step=group)
+        interpret=True, pages_per_group=group)
     want = _jnp_absorbed(q_eff, q_pe, lat, args[0], 1, args[2], args[3], 0.1,
                          jnp.asarray(live))
     assert got.shape == (B, H, r) and got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
                                rtol=0, atol=0.01)
     assert not np.any(np.asarray(got[2], np.float32))  # the idle row: zeros
+
+
+def _latent_case(lengths, page=16, mp=12, r=128, dr=64, H=5, seed=0,
+                 shuffle=True, poison=True):
+    """(q_eff, q_pe, lat, (bt, layer, pos, start), live) for rows holding
+    `lengths` tokens (0: an idle row, mapped to no page): a pool whose
+    pages are dealt out of order (or in order), NaN in every page no live
+    row maps."""
+    rng = np.random.default_rng(seed)
+    B, L = len(lengths), 2
+    n_pages = 1 + sum(-(-n // page) for n in lengths) + 3
+    lat = jnp.asarray(rng.normal(size=(L, n_pages, page, 256)), jnp.bfloat16)
+    lat = lat.at[..., r + dr:].set(0)
+    ids = np.arange(1, n_pages)
+    ids, k = (rng.permutation(ids) if shuffle else ids), 0
+    bt = np.zeros((B, mp), np.int32)
+    for b, n in enumerate(lengths):
+        held = -(-n // page)
+        bt[b, :held] = ids[k:k + held]
+        k += held
+    if poison:
+        dead = np.setdiff1d(np.arange(n_pages), bt.ravel()[bt.ravel() > 0])
+        lat = lat.at[:, jnp.asarray(dead)].set(jnp.nan)
+    lengths = np.asarray(lengths)
+    q_eff = jnp.asarray(rng.normal(size=(B, H, r)), jnp.bfloat16)
+    q_pe = jnp.asarray(rng.normal(size=(B, H, dr)), jnp.bfloat16)
+    args = (jnp.asarray(bt), jnp.asarray(1),
+            jnp.asarray(np.maximum(lengths - 1, 0)), jnp.zeros(B, jnp.int32))
+    return q_eff, q_pe, lat, args, jnp.asarray(lengths > 0)
+
+
+def _holds_to_jnp(case, group):
+    from bigdl_tpu.ops.pallas.paged_attention import (
+        paged_latent_decode_attention)
+
+    q_eff, q_pe, lat, args, live = case
+    got = paged_latent_decode_attention(
+        q_eff, q_pe, lat, *args, scale=0.1, live=live, interpret=True,
+        pages_per_group=group)
+    want = _jnp_absorbed(q_eff, q_pe, lat, args[0], 1, args[2], args[3], 0.1,
+                         live)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               rtol=0, atol=0.01)
+    idle = ~np.asarray(live)
+    assert not np.any(np.asarray(got, np.float32)[idle])  # zeros, not NaN
+    return got
+
+
+# pages of 16 tokens, 12 a row: the rule gives such rows groups of 8 pages
+# (128 tokens, the largest power of two a row holds); the other is 4 (64)
+_LENGTHS = {
+    "idle": 0, "one_token": 1, "a_token_short_of_a_page": 15,
+    "a_page": 16, "a_token_into_the_next_page": 17,
+    "a_group_of_4": 64, "a_last_group_of_4_with_dead_pages": 64 + 17,
+    "a_group_of_8": 128, "a_last_group_of_8_with_dead_pages": 128 + 17,
+    "the_whole_row": 12 * 16,
+}
+
+
+@pytest.mark.parametrize("group", [None, 4], ids=["ruled", "of_4"])
+@pytest.mark.parametrize("case", list(_LENGTHS))
+def test_latent_kernel_at_a_rows_length(case, group):
+    """One row of the length under test between an idle row and a full
+    one: every page it does not own holds NaN, the dead pages of its last
+    group among them, and none reaches the output."""
+    _holds_to_jnp(_latent_case([0, _LENGTHS[case], 12 * 16]), group)
+
+
+@pytest.mark.parametrize("group", [None, 4], ids=["ruled", "of_4"])
+def test_latent_kernel_over_32_rows_of_mixed_lengths(group):
+    lengths = np.random.default_rng(7).integers(0, 12 * 16 + 1, 32)
+    lengths[[3, 11]] = 0  # idle rows among them
+    _holds_to_jnp(_latent_case(list(lengths), H=3, seed=3), group)
+
+
+@pytest.mark.parametrize("group", [None, 4], ids=["ruled", "of_4"])
+def test_latent_kernel_reads_through_the_block_table(group):
+    """The same rows from a pool dealt in order and from one dealt out of
+    order: the same pages' contents under other page numbers give the same
+    bits."""
+    lengths, outs = [70, 33, 0, 150], []
+    for shuffle in (False, True):
+        q_eff, q_pe, lat, args, live = _latent_case(
+            lengths, shuffle=shuffle, poison=False)
+        if shuffle:  # carry the in-order pool's pages to their new numbers
+            lat = jnp.zeros_like(lat).at[:, args[0].ravel()].set(
+                straight[2][:, straight[3][0].ravel()])
+            case = (straight[0], straight[1], lat, args, live)
+        else:
+            case = straight = (q_eff, q_pe, lat, args, live)
+        outs.append(np.asarray(_holds_to_jnp(case, group), np.float32))
+    np.testing.assert_array_equal(*outs)
+
+
+def _script():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "latent_kernel_bench",
+        os.path.join(ROOT, "scripts", "latent_kernel_bench.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("group", [1, 4, 8])
+def test_latent_kernel_equals_the_old_grid_bit_for_bit(group):
+    """PR 51 changed how pages REACH the dots, not what the dots are fed:
+    at the same pages a group the row loop gives the bits of the (row,
+    group) grid it replaced, which `scripts/latent_kernel_bench.py` keeps."""
+    from bigdl_tpu.ops.pallas.paged_attention import (
+        paged_latent_decode_attention)
+
+    q_eff, q_pe, lat, args, live = _latent_case([5, 0, 100, 192, 64],
+                                                poison=False)
+    got = paged_latent_decode_attention(
+        q_eff, q_pe, lat, *args, scale=0.1, live=live, interpret=True,
+        pages_per_group=group)
+    old = _script().grid_form(q_eff, q_pe, lat, *args, 0.1, live, group,
+                              interpret=True)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(old, np.float32))
+
+
+def _pallas_calls(jaxpr):
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("rows,pages_a_row", [(3, 12), (32, 80)])
+def test_latent_kernels_grid_is_a_step_a_row(rows, pages_a_row):
+    """Grid (B,), whatever a row may hold, and the pool handed over where
+    it lies (no block of it: the body fetches what is live)."""
+    from bigdl_tpu.ops.pallas.paged_attention import (
+        paged_latent_decode_attention)
+
+    B, H, r, dr, page, L, NP = rows, 5, 128, 64, 16, 2, 9
+    z = jnp.zeros
+    jaxpr = jax.make_jaxpr(lambda *a: paged_latent_decode_attention(
+        *a, scale=0.1, interpret=True))(
+        z((B, H, r), jnp.bfloat16), z((B, H, dr), jnp.bfloat16),
+        z((L, NP, page, 256), jnp.bfloat16), z((B, pages_a_row), jnp.int32),
+        z((), jnp.int32), z((B,), jnp.int32), z((B,), jnp.int32))
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    gm = call.params["grid_mapping"]
+    assert gm.grid == (B,)
+    q_block, pool, out_block = (str(bm.block_aval)
+                                for bm in gm.block_mappings)
+    assert "any" in pool and "any" not in q_block + out_block
+
+
+def test_latent_group_comes_from_the_rows_bytes():
+    """`tiling.latent_group_pages`: from the page's bytes and the heads'
+    rows against `LATENT_GROUP_BYTES`: a power of two, within the budget
+    and the row, the next one beyond one of them."""
+    from bigdl_tpu.ops.pallas import tiling
+
+    cell = tiling.latent_group_pages(64, 640, 2, 20, 80)  # GLM-4.7-Flash's
+    assert cell == _CELL_GROUP
+    assert tiling.latent_group_pages(16, 256, 2, 5, 12) == 8  # these tests'
+    for page, width, itemsize, heads, mp in (
+            (64, 640, 2, 20, 80), (16, 256, 2, 5, 12), (128, 640, 2, 128, 64),
+            (64, 640, 4, 32, 80), (4096, 640, 2, 20, 4), (64, 640, 2, 20, 3)):
+        p = tiling.latent_group_pages(page, width, itemsize, heads, mp)
+        rows = -(-heads // 16) * 16
+        assert 1 <= p <= mp and p & (p - 1) == 0
+        assert p == 1 or tiling.latent_group_bytes(
+            p, page, width, itemsize, rows) <= tiling.LATENT_GROUP_BYTES
+        assert 2 * p > mp or tiling.latent_group_bytes(
+            2 * p, page, width, itemsize, rows) > tiling.LATENT_GROUP_BYTES
+    # wider rows, more heads or bigger pages: never more pages a group
+    assert tiling.latent_group_pages(64, 1280, 2, 20, 80) <= cell
+    assert tiling.latent_group_pages(64, 640, 2, 128, 80) <= cell
+    assert tiling.latent_group_pages(128, 640, 2, 20, 80) <= cell
+
+
+_CELL_GROUP = 16
 
 
 # ---- weights stay out of the scan's slices ------------------------------------

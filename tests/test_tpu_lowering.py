@@ -470,10 +470,13 @@ def test_word_path_is_lowered_with_signed_nibbles(one_chip, monkeypatch, kind,
 # ---- paged decode attention over groups of live pages (ISSUE 35) -----------
 
 # (slots, KV heads, query heads a KV head, head size, layers, pages in the
-# pool): the three configurations that serve from KV pages (pages of 64
-# tokens, 32 a row), Mistral's with an fp8 pool, and shapes whose [Hkv, D]
-# tiles XLA pads in HBM, which no DMA of the kernel's own can slice: those
-# pages come through Pallas's pipeline, one a grid step, to the same body
+# pool[, pages a row: 32]): every configuration that serves from KV pages
+# (pages of 64 tokens) at its cell's pool, Mistral's with an fp8 pool too,
+# and shapes whose [Hkv, D] tiles XLA pads in HBM, which no DMA of the
+# kernel's own can slice: those pages come through Pallas's pipeline, one a
+# grid step, to the same body. The two kinds with two groups of pages call
+# the kernel once a group; SDAR's block of 4 positions is 4 x 8 query rows
+# a KV head through `paged_block_attention`
 _PAGED = {
     "mistral-7b": (32, 8, 4, 128, 32, 1025),
     "qwen2-7b": (16, 4, 7, 128, 28, 1025),
@@ -483,7 +486,44 @@ _PAGED = {
     "piped-one-kv-head": (8, 1, 8, 256, 18, 257),  # Gemma-2B's (MQA)
     "piped-six-kv-heads": (8, 6, 4, 128, 2, 257),
     "piped-head-64-fp8": (8, 2, 7, 64, 24, 257),  # Qwen2-0.5B's, fp8 pool
+    "granite-4.0-h-small": (32, 8, 4, 128, 2, 1537, 48),
+    "smallthinker-21ba3b-global": (16, 4, 7, 128, 6, 2305, 144),
+    "smallthinker-21ba3b-window": (16, 4, 7, 128, 18, 1057, 144),
+    "laguna-xs.2-full": (16, 8, 6, 128, 4, 2305, 144),
+    "laguna-xs.2-window": (16, 8, 8, 128, 12, 161, 144),
+    "sdar-30b-a3b-block": (16, 4, 8, 128, 24, 513),
 }
+
+
+def _lowered_paged(name, one_chip):
+    from bigdl_tpu.ops.pallas import paged_attention as pa
+
+    B, Hkv, G, D, L, NP, mp = (*_PAGED[name], 32)[:7]
+    page = 64
+    fp8 = name.endswith("fp8")
+    assert pa.pool_tiles_whole(Hkv, D, 1 if fp8 else 2) \
+        != name.startswith("piped")
+    kv = _sds((L, NP, page, Hkv, D),
+              jnp.float8_e5m2 if fp8 else jnp.bfloat16, one_chip)
+    scales = [_sds((L, NP, page, Hkv), jnp.float32, one_chip)] * 2 if fp8 \
+        else []
+    q = _sds((B, Hkv * G, D), jnp.bfloat16, one_chip)
+    attention = pa.paged_decode_attention
+    if name.endswith("block"):  # b = 4 positions a row
+        q = _sds((B, 4, Hkv * G, D), jnp.bfloat16, one_chip)
+        attention = pa.paged_block_attention
+
+    def f(q, k, v, bt, layer, pos, start, win, live, *scales):
+        return attention(
+            q, k, v, bt, layer, pos, start, *scales, window=win, live=live,
+            softcap=30.0 if fp8 else None, interpret=False)
+
+    return jax.jit(f).lower(
+        q, kv, kv,
+        _sds((B, mp), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((B,), jnp.bool_, one_chip),
+        *scales)
 
 
 @pytest.mark.parametrize("name", list(_PAGED))
@@ -495,61 +535,102 @@ def test_paged_decode_kernel_compiles_at_the_cells_shapes(one_chip, name):
     XLA hands the pool over as it lies (no copy in front of the call). The
     fp8 pool brings its scales by group and column. The `piped` shapes
     compile the other way pages reach the same body."""
-    from bigdl_tpu.ops.pallas import paged_attention as pa
-
-    B, Hkv, G, D, L, NP = _PAGED[name]
-    page, mp = 64, 32
-    fp8 = name.endswith("fp8")
-    assert pa.pool_tiles_whole(Hkv, D, 1 if fp8 else 2) \
-        != name.startswith("piped")
-    kv = _sds((L, NP, page, Hkv, D),
-              jnp.float8_e5m2 if fp8 else jnp.bfloat16, one_chip)
-    scales = [_sds((L, NP, page, Hkv), jnp.float32, one_chip)] * 2 if fp8 \
-        else []
-
-    def f(q, k, v, bt, layer, pos, start, win, live, *scales):
-        return pa.paged_decode_attention(
-            q, k, v, bt, layer, pos, start, *scales, window=win, live=live,
-            softcap=30.0 if fp8 else None, interpret=False)
-
-    c = jax.jit(f).lower(
-        _sds((B, Hkv * G, D), jnp.bfloat16, one_chip), kv, kv,
-        _sds((B, mp), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
-        _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
-        _sds((), jnp.int32, one_chip), _sds((B,), jnp.bool_, one_chip),
-        *scales).compile()
+    c = _lowered_paged(name, one_chip).compile()
     assert "paged_decode_attention" in c.as_text()
     if not name.startswith("piped"):  # a pool of padded tiles is re-laid in
-        # front of a call that stands alone, on the parent's kernel as here
-        assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+        # front of a call that stands alone, on the parent's kernel as here;
+        # a block's q and out are re-ordered by KV head (1 MiB), never a pool
+        assert c.memory_analysis().temp_size_in_bytes < 2 ** (
+            21 if name.endswith("block") else 20)
+
+
+# sha256 of `paged_decode_attention`'s Mosaic module at those shapes, on PR
+# 50's tree (`git archive 16740e8`, this file's `_lowered_paged` run there):
+# PR 51 made the row loop (`_row_of_live_groups`), the softmax
+# recurrence and the scalar operand the latent kernel's too, and this
+# kernel's own programs stayed what they were, to the byte
+_PAGED_BODIES = {
+    "mistral-7b":
+        "08a55bc782e096b59537fa82f635f89597caa4202a6cd128b1638f38b6998d3a",
+    "qwen2-7b":
+        "bb7e78d1dba394d2e7a7b63b0ea73d5e102e3b8a9b28e5910c51c5515c37b4b4",
+    "mixtral-8x7b":
+        "e6666e973d406c4c29387029863c3d2df8fec099f0c54485fdb923650ce05dd1",
+    "mistral-7b-fp8":
+        "e015a7c4557c427c65d16efe4b212c0d19d386c60855e60eb2cbc4686c424c28",
+    "piped-head-64":
+        "9730dd96c20962a607dbcb4f43af716a61e75672d590cca8e256bf82b6f7af44",
+    "piped-one-kv-head":
+        "f6391bafdb6592764df27b2d3d034d737ed0e99e635f75cc8c908ed4795a8b5f",
+    "piped-six-kv-heads":
+        "2ca14ff71de83b8304db9b4fe6753640575998c432a08b732d6ad4c75dddcbf3",
+    "piped-head-64-fp8":
+        "68d46919bff1b3fca0a61b6422a73dfb1b64ea5f89aa37cb7a8cc9e92953b7e5",
+    "granite-4.0-h-small":
+        "0b672d2402078d9f2ff0225aff818cb0bd9cc7a242cccd4e54c7e6391528508d",
+    "smallthinker-21ba3b-global":
+        "4aee7701aa0330e80fdac1d486f2f0d7301739d85f6e7745ca0e5064f2896c2d",
+    "smallthinker-21ba3b-window":
+        "d9a5a8c857388b3630b8439bddb274bbbc5299d407eab025c2edd8a3d5d41391",
+    "laguna-xs.2-full":
+        "d64c6b040b5bbeb784534b7b47f3aec309fb79bd4c17ecfbbeff87ee9a16d53a",
+    "laguna-xs.2-window":
+        "90bb412f9cdc2cb1557a4cec3898c7a89059f48b78ab7486cc6d4dffc66d24d0",
+    "sdar-30b-a3b-block":
+        "e701829cdaece8e699875dff399e96cf9ef10c830b9f262f5b78f20c17774886",
+}
+
+
+@pytest.mark.parametrize("name", list(_PAGED))
+def test_paged_decode_kernel_lowers_to_the_parents_program(one_chip, name):
+    import hashlib
+
+    (body,) = _mosaic_bodies(_lowered_paged(name, one_chip).as_text())
+    assert hashlib.sha256(body.encode()).hexdigest() == _PAGED_BODIES[name]
 
 
 # ---- GLM-4.7-Flash: latent pages, 64 experts (ISSUE 34) ---------------------
 
+# sha256 of `paged_latent_decode_attention`'s Mosaic module at the cell's
+# shapes since PR 51: grid (32,), the pool in HBM, a loop over a row's live
+# groups of 16 pages (`tiling.latent_group_pages`), a DMA a live page
+_LATENT_BODY = (
+    "7f16238c86047e796bfc74fdeee991b3d221860acfe7aaef177bec67a5b2033a")
+
+
 def test_latent_decode_kernel_compiles_at_the_cells_shapes(one_chip):
     """32 slots x 80 pages of 64 tokens, 20 heads on rows of 640 lanes, a
-    pool of 2561 pages over 20 layers: Mosaic takes the eight page blocks of
-    a grid step and the two dots over them, and XLA hands the pool over as
-    it lies (no copy of 4.2 GB in front of the call)."""
+    pool of 2561 pages over 20 layers: Mosaic takes the loop over a row's
+    live groups, the DMA a live page [64, 640] out of the pool in HBM and
+    the two dots over a group's pages joined [slots, 640] without a
+    relayout, and XLA hands the pool over as it lies (no copy of 4.2 GB in
+    front of the call)."""
+    import hashlib
+
+    from bigdl_tpu.ops.pallas import tiling
     from bigdl_tpu.ops.pallas.paged_attention import (
         paged_latent_decode_attention)
 
     B, H, r, dr, page, mp, L, NP = 32, 20, 512, 64, 64, 80, 20, 2561
+    assert tiling.latent_group_pages(page, 640, 2, H, mp) == 16
 
     def f(qe, qp, lat, bt, layer, pos, start, live):
         return paged_latent_decode_attention(
             qe, qp, lat, bt, layer, pos, start, scale=0.0625, live=live,
             interpret=False)
 
-    c = jax.jit(f).lower(
+    lowered = jax.jit(f).lower(
         _sds((B, H, r), jnp.bfloat16, one_chip),
         _sds((B, H, dr), jnp.bfloat16, one_chip),
         _sds((L, NP, page, 640), jnp.bfloat16, one_chip),
         _sds((B, mp), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
         _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
-        _sds((B,), jnp.bool_, one_chip)).compile()
+        _sds((B,), jnp.bool_, one_chip))
+    c = lowered.compile()
     assert "paged_latent_decode_attention" in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
+    (body,) = _mosaic_bodies(lowered.as_text())
+    assert hashlib.sha256(body.encode()).hexdigest() == _LATENT_BODY
 
 
 @pytest.mark.parametrize("K,O,gated", [(2048, 1536, True),
