@@ -23,6 +23,7 @@ import numpy as np
 
 from bigdl_tpu import kvcache
 from bigdl_tpu.models.config import ModelConfig
+from bigdl_tpu.obs.scopes import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -230,15 +231,17 @@ def generate_tokens(
         shift = make_sink_shift(config, window, sink, chunk)
     else:
         assert cache_len >= T + gen.max_new_tokens
-    if cache_init is not None:
-        cache = cache_init(config, B, cache_len, quantize_kv)
-        assert compress_budget == 0, "SnapKV needs a KV cache"
-    else:
-        cache = kvcache.init_cache(
-            config.num_hidden_layers, B, cache_len, config.num_key_value_heads,
-            config.head_dim_, quantize_kv=quantize_kv,
-        )
-    cache = dataclasses.replace(cache, start=start)
+    with scope("engine"):
+        if cache_init is not None:
+            cache = cache_init(config, B, cache_len, quantize_kv)
+            assert compress_budget == 0, "SnapKV needs a KV cache"
+        else:
+            cache = kvcache.init_cache(
+                config.num_hidden_layers, B, cache_len,
+                config.num_key_value_heads, config.head_dim_,
+                quantize_kv=quantize_kv,
+            )
+        cache = dataclasses.replace(cache, start=start)
 
     if compress_budget:
         assert compress_budget > compress_window
@@ -247,66 +250,74 @@ def generate_tokens(
             collect_obs=compress_window, last_logits_only=last_logits,
         )
         out_len = cache_len_for(compress_budget, gen.max_new_tokens)
-        cache = kvcache.compress(
-            cache, obs, compress_budget, out_len,
-            window=compress_window, kernel=compress_kernel,
-        )
+        with scope("engine"):
+            cache = kvcache.compress(
+                cache, obs, compress_budget, out_len,
+                window=compress_window, kernel=compress_kernel,
+            )
     else:
         logits, cache = model_forward(
             config, params, tokens, cache, mode="prefill",
             last_logits_only=last_logits,
         )
     use_rep = gen.repetition_penalty != 1.0  # static: compiles away
-    seen = (
-        seen_from_prompt(tokens, start, config.vocab_size)
-        if use_rep else jnp.zeros((B, 1), jnp.bool_)
-    )
-
-    key, k0 = jax.random.split(key)
-    first_logits = logits[:, -1]
-    with jax.named_scope("sample"):
+    with scope("engine"):
+        seen = (
+            seen_from_prompt(tokens, start, config.vocab_size)
+            if use_rep else jnp.zeros((B, 1), jnp.bool_)
+        )
+        key, k0 = jax.random.split(key)
+    with scope("sample"):
+        first_logits = logits[:, -1]
         if use_rep:
             first_logits = apply_repetition_penalty(
                 first_logits, seen, gen.repetition_penalty
             )
         first = sample_token(first_logits, k0, gen)
-    if use_rep:
-        seen = seen.at[jnp.arange(B), first].set(True)
-
-    out = jnp.full((B, gen.max_new_tokens), gen.pad_token_id, jnp.int32)
-    out = out.at[:, 0].set(first)
     eos = gen.eos_token_id
-    done = (
-        first == eos if eos is not None else jnp.zeros((B,), jnp.bool_)
-    )
+    with scope("engine"):
+        if use_rep:
+            seen = seen.at[jnp.arange(B), first].set(True)
+
+        out = jnp.full((B, gen.max_new_tokens), gen.pad_token_id, jnp.int32)
+        out = out.at[:, 0].set(first)
+        done = (
+            first == eos if eos is not None else jnp.zeros((B,), jnp.bool_)
+        )
 
     def cond(state):
         i, _, _, done, _, _, _ = state
-        return (i < gen.max_new_tokens) & ~jnp.all(done)
+        with scope("engine"):
+            return (i < gen.max_new_tokens) & ~jnp.all(done)
 
     def step(state):
         i, cur, cache, done, out, key, seen = state
-        if shift is not None:
-            cache = shift(cache)  # evict the oldest non-sink slot if full
+        with scope("engine"):
+            if shift is not None:
+                cache = shift(cache)  # evict the oldest non-sink slot if full
+            cur = cur[:, None]
         logits, cache = model_forward(
-            config, params, cur[:, None], cache, mode="decode"
+            config, params, cur, cache, mode="decode"
         )
-        key, k = jax.random.split(key)
-        step_logits = logits[:, -1]
-        with jax.named_scope("sample"):
+        with scope("engine"):
+            key, k = jax.random.split(key)
+        with scope("sample"):
+            step_logits = logits[:, -1]
             if use_rep:
                 step_logits = apply_repetition_penalty(
                     step_logits, seen, gen.repetition_penalty
                 )
             nxt = sample_token(step_logits, k, gen)
-        if eos is not None:
-            nxt = jnp.where(done, gen.pad_token_id, nxt)
-            done = done | (nxt == eos)
-        if use_rep:
-            seen = seen.at[jnp.arange(B), nxt].set(True)
-        out = jax.lax.dynamic_update_slice(out, nxt[:, None], (0, i))
-        return (i + 1, nxt, cache, done, out, key, seen)
+        with scope("engine"):
+            if eos is not None:
+                nxt = jnp.where(done, gen.pad_token_id, nxt)
+                done = done | (nxt == eos)
+            if use_rep:
+                seen = seen.at[jnp.arange(B), nxt].set(True)
+            out = jax.lax.dynamic_update_slice(out, nxt[:, None], (0, i))
+            return (i + 1, nxt, cache, done, out, key, seen)
 
-    state = (jnp.ones((), jnp.int32), first, cache, done, out, key, seen)
+    with scope("engine"):
+        state = (jnp.ones((), jnp.int32), first, cache, done, out, key, seen)
     _, _, _, _, out, _, _ = jax.lax.while_loop(cond, step, state)
     return out

@@ -27,8 +27,8 @@ A decode step runs it once, through the Pallas kernel `mamba2_decode`
 (ops/pallas/mamba2.py: the live rows' state read and written in place) where
 the kernels are in use, else in `jnp`. A prefill runs the CHUNKED form
 (`ssd_chunked`: inside a chunk the sum over s <= t of exp(sum a) C_t . B_s
-dt_s x_s, across chunks the state) on the XLA route under
-`jax.named_scope("mamba2_prefill")`. Decay sums and the state are float32.
+dt_s x_s, across chunks the state) on the XLA route under the scope
+`mamba2_prefill`. Decay sums and the state are float32.
 A position that is no token (left padding before `start`, the right padding
 of a bucket past `valid_len`) carries dt = 0 and a zero convolution input:
 no decay, no update, and the convolution's tail is taken at the last real
@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from bigdl_tpu import kvpaged, kvstate
+from bigdl_tpu.obs.scopes import scope
 
 KIND = "state_beside_pages"
 _HI = jax.lax.Precision.HIGHEST  # float32 operands stay float32 on the MXU
@@ -260,7 +261,7 @@ def mix(cache: HybridCache, layer, xbc, dt, A, D, conv_w, conv_b, *,
             y, h = ssm_step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], h)
             y = y[:, None]
         else:
-            with jax.named_scope("mamba2_prefill"):
+            with scope("mamba2_prefill"):
                 y, h = ssd_chunked(x, dt, A, Bm, Cm, h, chunk)
         ssm = cache.ssm.at[layer, to].set(h.reshape(B, inner, N),
                                           mode="drop")

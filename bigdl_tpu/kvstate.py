@@ -39,8 +39,8 @@ holds none, the convention of `kvpaged` (page 0 is nobody's). Without a
 table batch row b holds state row b (`TpuModel.generate`).
 
 Prefill runs the chunked form (`_chunked`: the a[t, s] form inside a chunk,
-the state across chunks) on the XLA route under
-`jax.named_scope("power_retention_prefill")`; decode runs the Pallas kernel
+the state across chunks) on the XLA route under the scope
+`power_retention_prefill`; decode runs the Pallas kernel
 `power_retention_decode` (ops/pallas/power_retention.py) where the kernels
 are in use, else the same update in `jnp`. Gate sums and the state are
 float32; q, k and v arrive in the compute dtype.
@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bigdl_tpu import kvpaged
+from bigdl_tpu.obs.scopes import scope
 
 KIND = "power_retention"
 PREFILL_CHUNK = 128  # tokens of one chunk of the prefill form
@@ -285,7 +286,7 @@ def attend(state: Optional[RetentionState], layer, q, k, v, g, valid,
     q = q.reshape(B, T, Hkv, G, D)
     if state is None:
         P = phi_dim(D)
-        with jax.named_scope("power_retention_prefill"):
+        with scope("power_retention_prefill"):
             y, _, _ = _chunked(q, k, v, g, jnp.zeros((B, Hkv, D, P)),
                                jnp.zeros((B, Hkv, P)), eps)
         return y.reshape(B, T, Hq, D), None
@@ -310,7 +311,7 @@ def attend(state: Optional[RetentionState], layer, q, k, v, g, valid,
         y, S1, z1 = _step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], S0, z0, eps)
         y = y[:, None]
     else:
-        with jax.named_scope("power_retention_prefill"):
+        with scope("power_retention_prefill"):
             y, S1, z1 = _chunked(q, k, v, g, S0, z0, eps)
     to = jnp.where(live, at, state.n_rows)  # an idle row writes nowhere
     state = dataclasses.replace(

@@ -63,6 +63,7 @@ import jax.numpy as jnp
 from bigdl_tpu.kvcache import _scatter_rows
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import ModelConfig
+from bigdl_tpu.obs.scopes import scope
 from bigdl_tpu.ops import linear, rms_norm
 from bigdl_tpu.ops.linear import stacks_in
 from bigdl_tpu.ops.rope import make_inv_freq_scaled, rope_cos_sin
@@ -350,10 +351,10 @@ def _moe_mlp(config: ModelConfig, x, p, compute_dtype, proj, layer=None):
     (out [B,T,hid], topi [B,T,k])."""
     B, T, hid = x.shape
     xc = x.astype(compute_dtype)
-    with jax.named_scope("moe.router"):
+    with scope("moe.router"):
         topv, topi = _router(config, xc.reshape(-1, hid), p)
-    topv = topv.reshape(B, T, -1)
-    topi = topi.reshape(B, T, -1)
+        topv = topv.reshape(B, T, -1)
+        topi = topi.reshape(B, T, -1)
 
     rcfg = config
     if (config.topk_method or "greedy") != "greedy" and config.n_group:
@@ -373,9 +374,10 @@ def _moe_mlp(config: ModelConfig, x, p, compute_dtype, proj, layer=None):
                               ragged_config=rcfg, layer=layer)
 
     if config.n_shared_experts:
-        g = proj(xc, p, "w_gate_s")
-        u = proj(xc, p, "w_up_s")
-        out = out + proj(jax.nn.silu(g) * u, p, "w_down_s")
+        with scope("moe.shared"):
+            g = proj(xc, p, "w_gate_s")
+            u = proj(xc, p, "w_up_s")
+            out = out + proj(jax.nn.silu(g) * u, p, "w_down_s")
     return out, topi
 
 
@@ -489,21 +491,24 @@ def forward(
 
     fresh = cache is None
     if fresh:
-        cache = init_cache(config, B, T, dtype=jnp.float32)
+        with scope("engine"):
+            cache = init_cache(config, B, T, dtype=jnp.float32)
     paged = isinstance(cache, PagedLatentCache)
 
-    pos_col = cache.pos[:, None] if cache.pos.ndim == 1 else cache.pos
-    slots = pos_col + jnp.arange(T)[None, :]
-    positions = cache.next_positions(T)
+    with scope("engine"):  # positions, and the embedding
+        pos_col = cache.pos[:, None] if cache.pos.ndim == 1 else cache.pos
+        slots = pos_col + jnp.arange(T)[None, :]
+        positions = cache.next_positions(T)
 
-    h = llama.embed_tokens(config, params, tokens, compute_dtype)
+        h = llama.embed_tokens(config, params, tokens, compute_dtype)
 
-    inv_freq, att_scale = make_inv_freq_scaled(
-        dr, config.rope_theta, config.rope_scaling_dict,
-        seq_len=cache.max_len,
-    )
-    cos, sin = rope_cos_sin(positions, inv_freq, interleaved=True,
-                            scale=att_scale)
+    with scope("attn.rope"):  # the tables, once for every layer
+        inv_freq, att_scale = make_inv_freq_scaled(
+            dr, config.rope_theta, config.rope_scaling_dict,
+            seq_len=cache.max_len,
+        )
+        cos, sin = rope_cos_sin(positions, inv_freq, interleaved=True,
+                                scale=att_scale)
 
     detail = f"mode={mode} B{B} T{T}"
     use_kernel = paged and mode == "decode" and T == 1 and use_pallas()
@@ -516,7 +521,8 @@ def forward(
             "attention", "pallas:paged_latent", detail + " grid of %d rows, "
             "groups of %d pages" % (B, latent_group_pages(
                 cache.lat, H, cache.block_tables.shape[1])))
-        row_live = live_rows(cache)  # the table does not change in here
+        with scope("attn"):
+            row_live = live_rows(cache)  # the table does not change in here
     elif use_flash:
         routes.note("attention", "pallas:flash",
                     detail + " expanded from latents")
@@ -526,11 +532,12 @@ def forward(
             if expand else " absorbed over the latents"))
     if not (use_kernel or expand):
         S = cache.max_len
-        sj = jnp.arange(S)
-        mask = (sj[None, None, :] <= slots[..., None]) & (
-            sj[None, None, :] >= cache.start[:, None, None]
-        )  # [B, T, S]
-        mask = mask[:, None]  # [B, 1, T, S]
+        with scope("attn"):  # the mask, once for every layer
+            sj = jnp.arange(S)
+            mask = (sj[None, None, :] <= slots[..., None]) & (
+                sj[None, None, :] >= cache.start[:, None, None]
+            )  # [B, T, S]
+            mask = mask[:, None]  # [B, 1, T, S]
 
     per_row = cache.pos.ndim == 1
 
@@ -551,22 +558,25 @@ def forward(
         layer's (ckv_l, kpe_l) of a dense one), layer `gidx` of the model.
         Returns (attn_out [B,T,hid], the cache with the layer's latents
         written)."""
-        if "w_dq" in p:
-            qa = proj(x, p, "w_dq")
-            q = proj(rms_norm(qa, p["q_norm"], eps), p, "w_uq")
-        else:
-            q = proj(x, p, "wq")
-        q = q.reshape(B, T, H, dn + dr)
-        q_nope, q_pe = q[..., :dn], q[..., dn:]
+        with scope("attn.proj"):  # down and up, the norm between them
+            if "w_dq" in p:
+                qa = proj(x, p, "w_dq")
+                q = proj(rms_norm(qa, p["q_norm"], eps), p, "w_uq")
+            else:
+                q = proj(x, p, "wq")
+            q = q.reshape(B, T, H, dn + dr)
+            q_nope, q_pe = q[..., :dn], q[..., dn:]
 
-        ckv_pe = proj(x, p, "w_dkv")  # [B,T,r+dr]
-        ckv = rms_norm(ckv_pe[..., :r], p["kv_norm"], eps)
-        kpe = ckv_pe[..., None, r:]  # [B,T,1,dr] single shared rope head
+            ckv_pe = proj(x, p, "w_dkv")  # [B,T,r+dr]
+        with scope("attn.rope"):
+            ckv = rms_norm(ckv_pe[..., :r], p["kv_norm"], eps)
+            kpe = ckv_pe[..., None, r:]  # [B,T,1,dr] single shared rope head
 
-        q_pe, kpe = apply_rotary_emb(q_pe, kpe, cos, sin, True)
-        kpe = kpe[..., 0, :]  # [B,T,dr]
-        w_uk = p["w_uk"].astype(compute_dtype)
-        w_uv = p["w_uv"].astype(compute_dtype)
+            q_pe, kpe = apply_rotary_emb(q_pe, kpe, cos, sin, True)
+            kpe = kpe[..., 0, :]  # [B,T,dr]
+        with scope("attn.proj"):
+            w_uk = p["w_uk"].astype(compute_dtype)
+            w_uv = p["w_uv"].astype(compute_dtype)
 
         if paged:
             c = update_latent_layer(
@@ -587,18 +597,21 @@ def forward(
             # is kept
             lat = read_latent_layer(c, gidx).astype(compute_dtype)
             CKV, KPE = lat[..., :r], lat[..., r:r + dr]
-            k_full = jnp.concatenate([
-                jnp.einsum("bsr,hdr->bshd", CKV, w_uk),
-                jnp.broadcast_to(KPE[:, :, None], KPE.shape[:2] + (H, dr)),
-            ], axis=-1)
-            v_full = jnp.einsum("bsr,hdr->bshd", CKV, w_uv)
+            with scope("attn.proj"):  # the up-projection of every key
+                k_full = jnp.concatenate([
+                    jnp.einsum("bsr,hdr->bshd", CKV, w_uk),
+                    jnp.broadcast_to(KPE[:, :, None],
+                                     KPE.shape[:2] + (H, dr)),
+                ], axis=-1)
+                v_full = jnp.einsum("bsr,hdr->bshd", CKV, w_uv)
             out = _expanded_attention(
                 jnp.concatenate([q_nope, q_pe], axis=-1), k_full, v_full,
                 slots, cache.start, scale, compute_dtype,
                 flash_offset=cache.pos[0] if use_flash else None)
         else:
             # absorbed scores: q_eff = W_uk^T q_nope, dotted with the latent
-            q_eff = jnp.einsum("bthd,hdr->bthr", q_nope, w_uk)
+            with scope("attn.proj"):
+                q_eff = jnp.einsum("bthd,hdr->bthr", q_nope, w_uk)
             if use_kernel:
                 from bigdl_tpu.ops.pallas import paged_latent_decode_attention
 
@@ -611,9 +624,11 @@ def forward(
             else:
                 ctx = absorbed(q_eff, q_pe, c[0].astype(compute_dtype),
                                c[1].astype(compute_dtype))
-            out = jnp.einsum("bthr,hdr->bthd", ctx, w_uv)
-        return proj(out.reshape(B, T, H * dv).astype(compute_dtype), p,
-                    "wo"), c
+            with scope("attn.proj"):
+                out = jnp.einsum("bthr,hdr->bthd", ctx, w_uv)
+        with scope("attn.proj"):
+            return proj(out.reshape(B, T, H * dv).astype(compute_dtype), p,
+                        "wo"), c
 
     rs = config.residual_scale
 
@@ -633,14 +648,16 @@ def forward(
                 return linear(x, p[name], None, compute_dtype,
                               layer=idx if name in codes else None)
 
-            x = rms_norm(hidden, p["attn_norm"], eps)
-            with jax.named_scope("attn"):
+            with scope("norm"):
+                x = rms_norm(hidden, p["attn_norm"], eps)
+            with scope("attn"):
                 out, new_c = attn(x, p, pc if paged else dc, offset + idx,
                                   proj)
-            hidden = hidden + (out * rs if rs else out)
-            x = rms_norm(hidden, p["mlp_norm"], eps)
+            with scope("norm"):
+                hidden = hidden + (out * rs if rs else out)
+                x = rms_norm(hidden, p["mlp_norm"], eps)
             routed = None
-            with jax.named_scope("ffn"):
+            with scope("ffn"):
                 if moe:
                     d, routed = _moe_mlp(
                         config, x, p, compute_dtype, proj,
@@ -649,11 +666,14 @@ def forward(
                     g = proj(x, p, "w_gate")
                     u = proj(x, p, "w_up")
                     d = proj(jax.nn.silu(g) * u, p, "w_down")
-            hidden = hidden + (d * rs if rs else d)
+            with scope("norm"):  # the add fuses with the next norm
+                hidden = hidden + (d * rs if rs else d)
+            with scope("engine"):  # the loop's own count
+                nxt = idx + 1
             if paged:
-                return (hidden, new_c, idx + 1), (
+                return (hidden, new_c, nxt), (
                     None, routed if moe_routing else None)
-            return (hidden, pc, idx + 1), (
+            return (hidden, pc, nxt), (
                 new_c, routed if moe_routing else None)
 
         (hidden, pc, _), (dc, routing) = jax.lax.scan(
@@ -665,39 +685,41 @@ def forward(
     dense_out, routing = [], None
     c = cache
     if K:
-        h, c0, _ = segment(h, c if paged else (cache.ckv[:K], cache.kpe[:K]),
-                           params["layers"], False, 0)
+        with scope("attn"):  # a dense cache: the segment's layers of it
+            c0 = c if paged else (cache.ckv[:K], cache.kpe[:K])
+        h, c0, _ = segment(h, c0, params["layers"], False, 0)
         if paged:
             c = c0
         else:
             dense_out.append(c0)
     if config.num_hidden_layers - K:
-        h, c1, routing = segment(
-            h, c if paged else (cache.ckv[K:], cache.kpe[K:]),
-            params["moe_layers"], True, K)
+        with scope("attn"):
+            c1 = c if paged else (cache.ckv[K:], cache.kpe[K:])
+        h, c1, routing = segment(h, c1, params["moe_layers"], True, K)
         if paged:
             c = c1
         else:
             dense_out.append(c1)
 
-    if last_logits_only:
-        h = h[:, -1:]
-    with jax.named_scope("lm_head"):
+    with scope("lm_head"):
+        if last_logits_only:
+            h = h[:, -1:]
         logits = llama.lm_head_logits(config, params, h, compute_dtype)
 
-    extra = ()
-    if moe_routing:
-        extra = (routing if routing is not None else jnp.zeros(
-            (0, B, T, max(config.num_experts_per_tok, 1)), jnp.int32),)
-    if fresh:
-        return (logits, None) + extra
-    if paged:
-        cache = dataclasses.replace(c, pos=cache.pos + T)
-    else:
-        cache = dataclasses.replace(
-            cache,
-            ckv=jnp.concatenate([d[0] for d in dense_out], axis=0),
-            kpe=jnp.concatenate([d[1] for d in dense_out], axis=0),
-            pos=cache.pos + T,
-        )
-    return (logits, cache) + extra
+    with scope("engine"):
+        extra = ()
+        if moe_routing:
+            extra = (routing if routing is not None else jnp.zeros(
+                (0, B, T, max(config.num_experts_per_tok, 1)), jnp.int32),)
+        if fresh:
+            return (logits, None) + extra
+        if paged:
+            cache = dataclasses.replace(c, pos=cache.pos + T)
+        else:
+            cache = dataclasses.replace(
+                cache,
+                ckv=jnp.concatenate([d[0] for d in dense_out], axis=0),
+                kpe=jnp.concatenate([d[1] for d in dense_out], axis=0),
+                pos=cache.pos + T,
+            )
+        return (logits, cache) + extra

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 from bigdl_tpu import kvhybrid, kvpaged
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import ModelConfig
+from bigdl_tpu.obs.scopes import scope
 from bigdl_tpu.ops import linear, rms_norm
 from bigdl_tpu.ops.linear import stacks_in
 
@@ -269,32 +270,38 @@ def forward(
 
     fresh = cache is None
     if fresh:
-        cache = init_cache(config, B, T)
+        with scope("engine"):
+            cache = init_cache(config, B, T)
 
-    slots = cache.pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    with scope("engine"):
+        slots = cache.pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     detail = f"mode={mode} B{B} T{T}"
     use_kernel = decode and use_pallas()
     use_flash = T > 1 and B == 1 and use_pallas()
     if use_kernel:
         routes.note("attention", "pallas:paged", detail + " nope")
-        row_live = kvpaged.live_rows(cache)
+        with scope("attn"):
+            row_live = kvpaged.live_rows(cache)
     elif use_flash:
         routes.note("attention", "pallas:flash", detail + " nope")
     else:
         routes.note("attention", "xla",
                     f"{detail} nope ({why_not_pallas() or 'B > 1'})")
-        sj = jnp.arange(cache.max_len)
-        mask = ((sj[None, None, :] <= slots[..., None])
-                & (sj[None, None, :] >= cache.start[:, None, None]))
-        mask = mask[:, None, None]  # [B, 1, 1, T, S]
+        with scope("attn"):  # the mask, once for every layer
+            sj = jnp.arange(cache.max_len)
+            mask = ((sj[None, None, :] <= slots[..., None])
+                    & (sj[None, None, :] >= cache.start[:, None, None]))
+            mask = mask[:, None, None]  # [B, 1, 1, T, S]
 
-    h = llama.embed_tokens(config, params, tokens, compute_dtype)
+    with scope("engine"):
+        h = llama.embed_tokens(config, params, tokens, compute_dtype)
 
     def attn_mixer(x, p, c, idx, proj):
         """GQA without positions over layer `idx`'s pages."""
-        q = proj(x, p, "wq").reshape(B, T, Hq, D)
-        k = proj(x, p, "wk").reshape(B, T, Hkv, D)
-        v = proj(x, p, "wv").reshape(B, T, Hkv, D)
+        with scope("attn.proj"):
+            q = proj(x, p, "wq").reshape(B, T, Hq, D)
+            k = proj(x, p, "wk").reshape(B, T, Hkv, D)
+            v = proj(x, p, "wv").reshape(B, T, Hkv, D)
         kv = kvpaged.update_layer(c.kv, idx, k, v)
         c = dataclasses.replace(c, k=kv.k, v=kv.v)
         if use_kernel:
@@ -312,8 +319,9 @@ def forward(
                                       q_offset=c.pos[0], scale=scale)
             else:
                 out = attention(q, kf, vf, mask=mask, scale=scale)
-        return proj(out.reshape(B, T, Hq * D).astype(compute_dtype), p,
-                    "wo"), c
+        with scope("attn.proj"):
+            return proj(out.reshape(B, T, Hq * D).astype(compute_dtype), p,
+                        "wo"), c
 
     def mamba_mixer(x, p, c, idx, proj):
         zxd = proj(x, p, "w_in")  # [B, T, inner + C + H]
@@ -337,54 +345,63 @@ def forward(
             return linear(x, p[name], None, compute_dtype,
                           layer=idx if name in codes else None)
 
-        x = rms_norm(hidden, p["attn_norm"], eps)
-        with jax.named_scope("attn" if kind == "attention" else "mamba2"):
+        with scope("norm"):
+            x = rms_norm(hidden, p["attn_norm"], eps)
+        with scope("attn" if kind == "attention" else "mamba2"):
             out, c = (attn_mixer if kind == "attention" else mamba_mixer)(
                 x, p, c, at, proj)
-        hidden = hidden + out * rs
-        x = rms_norm(hidden, p["mlp_norm"], eps).astype(compute_dtype)
-        topi = jnp.zeros((B, T, max(config.num_experts_per_tok, 1)),
-                         jnp.int32)
-        with jax.named_scope("ffn"):
+        with scope("norm"):
+            hidden = hidden + out * rs
+            x = rms_norm(hidden, p["mlp_norm"], eps).astype(compute_dtype)
+        with scope("ffn"):
+            topi = jnp.zeros((B, T, max(config.num_experts_per_tok, 1)),
+                             jnp.int32)
             d = 0.0
             if config.is_moe:
-                with jax.named_scope("moe.router"):
+                with scope("moe.router"):
                     topv, topi = _router(config, x, p)
                 d = llama._moe_dispatch(
                     config, x, p, compute_dtype, topv, topi,
                     layer=idx if "w_up_e" in codes else None)
             if "w_up_s" in p:
-                g, u = proj(x, p, "w_gate_s"), proj(x, p, "w_up_s")
-                d = d + proj(jax.nn.silu(g) * u, p, "w_down_s")
-        return hidden + d * rs, c, topi
+                with scope("moe.shared"):  # the always-on MLP
+                    g, u = proj(x, p, "w_gate_s"), proj(x, p, "w_up_s")
+                    d = d + proj(jax.nn.silu(g) * u, p, "w_down_s")
+        with scope("norm"):  # the add fuses with the next norm
+            return hidden + d * rs, c, topi
 
     routing = []
     c = cache
-    zero = jnp.zeros((), jnp.int32)
+    with scope("engine"):
+        zero = jnp.zeros((), jnp.int32)
     for (kind, first, n), r in zip(layer_runs(config),
                                    sorted(params["runs"])):
         sliced, codes = _keep_codes_out(params["runs"][r], kind)
         if n == 1:
-            h, c, topi = layer(kind, h, c, jax.tree.map(lambda a: a[0],
-                                                        sliced),
-                               codes, zero, zero + first)
-            routing.append(topi[None])
+            with scope("engine"):  # the one layer out of its stack
+                p1, at = jax.tree.map(lambda a: a[0], sliced), zero + first
+            h, c, topi = layer(kind, h, c, p1, codes, zero, at)
+            with scope("engine"):
+                routing.append(topi[None])
             continue
 
         def body(carry, p, kind=kind, codes=codes, first=first):
             hidden, c, idx = carry
-            hidden, c, topi = layer(kind, hidden, c, p, codes, idx,
-                                    idx + first)
-            return (hidden, c, idx + 1), topi if moe_routing else None
+            with scope("engine"):  # the loop's own counts
+                at = idx + first
+            hidden, c, topi = layer(kind, hidden, c, p, codes, idx, at)
+            with scope("engine"):
+                return (hidden, c, idx + 1), topi if moe_routing else None
 
         (h, c, _), topi = jax.lax.scan(body, (h, c, zero), sliced)
         routing.append(topi)
 
-    if last_logits_only:
-        h = h[:, -1:]
-    with jax.named_scope("lm_head"):
+    with scope("lm_head"):
+        if last_logits_only:
+            h = h[:, -1:]
         logits = llama.lm_head_logits(config, params, h, compute_dtype)
-    extra = (jnp.concatenate(routing, axis=0),) if moe_routing else ()
-    if fresh:
-        return (logits, None) + extra
-    return (logits, kvhybrid.advance(c, T)) + extra
+    with scope("engine"):
+        extra = (jnp.concatenate(routing, axis=0),) if moe_routing else ()
+        if fresh:
+            return (logits, None) + extra
+        return (logits, kvhybrid.advance(c, T)) + extra

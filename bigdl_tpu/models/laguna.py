@@ -60,6 +60,7 @@ import jax.numpy as jnp
 from bigdl_tpu import kvcache, kvpaged, kvwindow
 from bigdl_tpu.models import deepseek, llama
 from bigdl_tpu.models.config import ModelConfig
+from bigdl_tpu.obs.scopes import scope
 from bigdl_tpu.ops import linear, rms_norm
 from bigdl_tpu.ops.linear import stacks_in
 
@@ -286,11 +287,13 @@ def forward(
 
     fresh = cache is None
     if fresh:
-        cache = init_cache(config, B, T, dtype=compute_dtype)
+        with scope("engine"):
+            cache = init_cache(config, B, T, dtype=compute_dtype)
     paged = cache.paged
     scalar_pos = cache.pos.ndim == 0
-    pos_col = cache.pos if scalar_pos else cache.pos[:, None]
-    slots = pos_col + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B|1, T]
+    with scope("engine"):
+        pos_col = cache.pos if scalar_pos else cache.pos[:, None]
+        slots = pos_col + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B|1, T]
 
     detail = f"mode={mode} B{B} T{T}"
     use_kernel = paged and mode == "decode" and T == 1 and use_pallas()
@@ -298,7 +301,8 @@ def forward(
                  and use_pallas())
     if use_kernel:
         route, why = "pallas:paged", ""
-        row_live = kvpaged.live_rows(cache)
+        with scope("attn"):
+            row_live = kvpaged.live_rows(cache)
     elif use_flash:
         route, why = "pallas:flash", ""
     else:
@@ -306,11 +310,13 @@ def forward(
         why = " (" + (why_not_pallas() or (
             "a paged or per-row cache at T > 1: flash takes one dense row"
             if T > 1 else "dense-cache decode: fused XLA attention")) + ")"
-        sj = jnp.arange(cache.max_len)[None, None, :]
-        full = (sj <= slots[..., None]) & (sj >= cache.start[:, None, None])
-        masks = (full[:, None, None],  # [B, 1, 1, T, S]
-                 (full & (sj > slots[..., None] - W))[:, None, None]
-                 if W else None)
+        with scope("attn"):  # the masks, once for every layer
+            sj = jnp.arange(cache.max_len)[None, None, :]
+            full = (sj <= slots[..., None]) & (
+                sj >= cache.start[:, None, None])
+            masks = (full[:, None, None],  # [B, 1, 1, T, S]
+                     (full & (sj > slots[..., None] - W))[:, None, None]
+                     if W else None)
     for s in sorted(set(sliding[:P])):  # a line each kind of layer
         j = sliding.index(s)
         kind = (f"window {W} x{per[1]} rope {config.rope_local_theta:g}"
@@ -321,8 +327,10 @@ def forward(
                     f"{', gated' if config.attn_gate else ''}, "
                     f"{n_scanned + 1} periods{why}")
 
-    tables = _rope_tables(config, cache, T)
-    h = llama.embed_tokens(config, params, tokens, compute_dtype)
+    with scope("attn.rope"):
+        tables = _rope_tables(config, cache, T)
+    with scope("engine"):
+        h = llama.embed_tokens(config, params, tokens, compute_dtype)
 
     def layer(j, hidden, c, p, codes, i, idx):
         """The layer at position `j` of a period: `i` the period's index in
@@ -335,12 +343,15 @@ def forward(
             return linear(x, p[name], None, compute_dtype,
                           layer=i if name in codes else None)
 
-        x = rms_norm(hidden, p["attn_norm"], eps)
-        with jax.named_scope("attn"):
+        with scope("norm"):
+            x = rms_norm(hidden, p["attn_norm"], eps)
+        with scope("attn.proj"):
             q = proj(x, "wq").reshape(B, T, Hq, D)
             k = proj(x, "wk").reshape(B, T, Hkv, D)
             v = proj(x, "wv").reshape(B, T, Hkv, D)
+        with scope("attn.rope"):
             q, k = apply_rotary_emb(q, k, *tables[sliding[j]])
+        with scope("attn"):
             g = kvcache.update_layer(c.group(sliding[j]), idx, k, v)
             c = c.with_group(sliding[j], g)
             if use_kernel:
@@ -359,32 +370,36 @@ def forward(
                 else:
                     out = attention(q, kf, vf, masks[sliding[j]])
             if config.attn_gate:
-                with jax.named_scope("attn.gate"):  # a scalar a head
+                with scope("attn.gate"):  # a scalar a head
                     gate = jax.nn.sigmoid(jnp.einsum(
                         "bth,oh->bto", x.astype(compute_dtype),
                         p["attn_gate"].astype(compute_dtype),
                         preferred_element_type=jnp.float32))
                     out = out * gate[..., None].astype(out.dtype)
-            hidden = hidden + proj(
-                out.reshape(B, T, Hq * D).astype(compute_dtype), "wo")
-        y = rms_norm(hidden, p["mlp_norm"], eps).astype(compute_dtype)
+        with scope("attn.proj"):
+            out = proj(out.reshape(B, T, Hq * D).astype(compute_dtype), "wo")
+        with scope("norm"):
+            hidden = hidden + out
+            y = rms_norm(hidden, p["mlp_norm"], eps).astype(compute_dtype)
         if "router" not in p:
-            with jax.named_scope("ffn.dense"):
+            with scope("ffn.dense"):
                 d = proj(jax.nn.silu(proj(y, "w_gate")) * proj(y, "w_up"),
                          "w_down")
-            return hidden + d, c, None
-        with jax.named_scope("moe.router"):
+            with scope("norm"):  # the add fuses with the next norm
+                return hidden + d, c, None
+        with scope("moe.router"):
             topv, topi = deepseek._router(config, y.reshape(B * T, -1), p)
             topv, topi = topv.reshape(B, T, -1), topi.reshape(B, T, -1)
-        with jax.named_scope("ffn"):
+        with scope("ffn"):
             d = llama._moe_dispatch(
                 config, y, p, compute_dtype, topv, topi,
                 layer=i if "w_up_e" in codes else None)
         if "w_up_s" in p:
-            with jax.named_scope("moe.shared"):  # ungated, at weight 1
+            with scope("moe.shared"):  # ungated, at weight 1
                 d = d + proj(jax.nn.silu(proj(y, "w_gate_s"))
                              * proj(y, "w_up_s"), "w_down_s")
-        return hidden + d, c, topi
+        with scope("norm"):
+            return hidden + d, c, topi
 
     first_routing = []
     for j in range(P):  # the first period: every layer its own weights
@@ -399,27 +414,30 @@ def forward(
         hidden, c, i = carry
         chosen = []
         for j, p in enumerate(xs):
-            hidden, c, topi = layer(j, hidden, c, p, stacks[j][1], i,
-                                    (i + 1) * per[sliding[j]] + rank[j])
+            with scope("engine"):  # the loop's own counts
+                idx = (i + 1) * per[sliding[j]] + rank[j]
+            hidden, c, topi = layer(j, hidden, c, p, stacks[j][1], i, idx)
             chosen.append(topi)
-        return (hidden, c, i + 1), (jnp.stack(chosen) if moe_routing
-                                    else None)
+        with scope("engine"):  # and the ids for the host
+            return (hidden, c, i + 1), (jnp.stack(chosen) if moe_routing
+                                        else None)
 
     (h, cache, _), routing = jax.lax.scan(
         body, (h, cache, jnp.zeros((), jnp.int32)),
         tuple(sliced for sliced, _ in stacks))
 
-    if logits_at is not None:
-        h = jax.lax.dynamic_slice_in_dim(h, logits_at, 1, axis=1)
-    elif last_logits_only:
-        h = h[:, -1:]
-    with jax.named_scope("lm_head"):
+    with scope("lm_head"):
+        if logits_at is not None:
+            h = jax.lax.dynamic_slice_in_dim(h, logits_at, 1, axis=1)
+        elif last_logits_only:
+            h = h[:, -1:]
         logits = llama.lm_head_logits(config, params, h, compute_dtype)
-    extra = ()
-    if moe_routing:  # [n, P, B, T, k] -> the model's layer order
-        extra = (jnp.concatenate(
-            [t[None] for t in first_routing]
-            + [routing.reshape((-1,) + routing.shape[2:])]),)
-    if fresh:
-        return (logits, None) + extra
-    return (logits, kvwindow.advance(cache, T)) + extra
+    with scope("engine"):
+        extra = ()
+        if moe_routing:  # [n, P, B, T, k] -> the model's layer order
+            extra = (jnp.concatenate(
+                [t[None] for t in first_routing]
+                + [routing.reshape((-1,) + routing.shape[2:])]),)
+        if fresh:
+            return (logits, None) + extra
+        return (logits, kvwindow.advance(cache, T)) + extra

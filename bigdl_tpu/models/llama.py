@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from bigdl_tpu import kvcache
 from bigdl_tpu.kvcache import KVCache
 from bigdl_tpu.models.config import ModelConfig
+from bigdl_tpu.obs.scopes import scope
 from bigdl_tpu.ops import apply_rotary_emb, attention, linear, rms_norm, rope_cos_sin
 from bigdl_tpu.ops.linear import (
     col_parallel_linear, row_parallel_linear, stacks_in,
@@ -337,19 +338,21 @@ def lm_head_logits(config: ModelConfig, params: Params, h: jax.Array,
                    compute_dtype=jnp.bfloat16) -> jax.Array:
     """Final norm + lm head + logit scaling/softcap — shared by forward()
     and the pipeline stage program."""
-    if config.norm_type == "layernorm":
-        h = layer_norm(h, params["final_norm"], params.get("final_norm_b"),
-                       config.rms_norm_eps)
-    else:
-        h = rms_norm(h, params["final_norm"], config.rms_norm_eps,
-                     offset=config.rms_norm_offset)
-    lm_head = params.get("lm_head", params["embed"])
-    logits = linear(
-        h, lm_head, params.get("lm_head_b"), compute_dtype
-    ).astype(jnp.float32)
-    if config.logit_scale:
-        logits = logits * config.logit_scale
-    return _softcap(logits, config.final_logit_softcap)
+    with scope("norm"):
+        if config.norm_type == "layernorm":
+            h = layer_norm(h, params["final_norm"],
+                           params.get("final_norm_b"), config.rms_norm_eps)
+        else:
+            h = rms_norm(h, params["final_norm"], config.rms_norm_eps,
+                         offset=config.rms_norm_offset)
+    with scope("lm_head"):
+        lm_head = params.get("lm_head", params["embed"])
+        logits = linear(
+            h, lm_head, params.get("lm_head_b"), compute_dtype
+        ).astype(jnp.float32)
+        if config.logit_scale:
+            logits = logits * config.logit_scale
+        return _softcap(logits, config.final_logit_softcap)
 
 
 def _lora_delta(x, pair, scale, compute_dtype):
@@ -532,12 +535,12 @@ def _moe_dispatch_grouped(
     n_tiles = mq.moe_n_tiles(N, k, E, block_m)
     call = functools.partial(mq.moe_qmatmul, block_m=block_m, layer=layer)
 
-    with jax.named_scope("moe.dispatch"):
+    with scope("moe.dispatch"):
         dest, src, tile_expert, n_used = mq.moe_layout(
             topi.reshape(N, k), E, block_m, n_tiles)
         xs = xc.reshape(N, H)[src]  # [n_tiles * block_m, H]
         row_expert = jnp.repeat(tile_expert, block_m)
-    with jax.named_scope("moe.experts"):
+    with scope("moe.experts"):
         if not config.gated_mlp:  # phixtral: biased fc1 -> act -> fc2
             u = call(xs, wu, tile_expert, n_used, out_dtype=jnp.float32)
             if "b_up_e" in p:
@@ -554,7 +557,7 @@ def _moe_dispatch_grouped(
                  out_dtype=jnp.float32)
         if not config.gated_mlp and "b_down_e" in p:
             y = y + p["b_down_e"].astype(jnp.float32)[row_expert]
-    with jax.named_scope("moe.combine"):
+    with scope("moe.combine"):
         out = jnp.sum(y[dest] * topv.reshape(N, k, 1), axis=1)
     return out.astype(compute_dtype).reshape(B, T, H)
 
@@ -701,23 +704,25 @@ def _moe_block(config: ModelConfig, x: jax.Array, p: Params, compute_dtype,
     says which path runs when). Returns (out [B,T,H], topi [B,T,k])."""
     B, T, H = x.shape
     xc = x.astype(compute_dtype)
-    with jax.named_scope("moe.router"):
+    with scope("moe.router"):
         topv, topi = _moe_router(config, xc, p)
     out = _moe_dispatch(config, xc, p, compute_dtype, topv, topi,
                         differentiable=differentiable, layer=layer)
 
     if config.shared_expert_intermediate_size:
         # qwen2_moe shared expert, sigmoid-gated (models/qwen2_moe.py)
-        sg = jnp.einsum("bth,ih->bti", xc, _deq(p["w_gate_s"], compute_dtype))
-        su = jnp.einsum("bth,ih->bti", xc, _deq(p["w_up_s"], compute_dtype))
-        sd = jnp.einsum(
-            "bti,hi->bth", _act(config.hidden_act, sg) * su,
-            _deq(p["w_down_s"], compute_dtype),
-        )
-        gate = jax.nn.sigmoid(
-            jnp.einsum("bth,oh->bto", xc, p["shared_gate"].astype(compute_dtype))
-        )
-        out = out + sd * gate
+        with scope("moe.shared"):
+            sg = jnp.einsum("bth,ih->bti", xc,
+                            _deq(p["w_gate_s"], compute_dtype))
+            su = jnp.einsum("bth,ih->bti", xc,
+                            _deq(p["w_up_s"], compute_dtype))
+            sd = jnp.einsum(
+                "bti,hi->bth", _act(config.hidden_act, sg) * su,
+                _deq(p["w_down_s"], compute_dtype),
+            )
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bth,oh->bto", xc, p["shared_gate"].astype(compute_dtype)))
+            out = out + sd * gate
     return out, topi
 
 
@@ -788,79 +793,81 @@ def forward(
             return layer_norm(x, w, b, eps)
         return rms_norm(x, w, eps, offset=config.rms_norm_offset)
 
-    if cache is None:
-        pos0 = jnp.zeros((), jnp.int32)
-        row_start = jnp.zeros((B,), jnp.int32) if start is None else start
-    else:
-        pos0 = cache.pos
-        row_start = cache.start
-
-    # Positions are relative to each row's start (left pad); after SnapKV
-    # compression slots ≠ positions and the cache carries the true next
-    # position in rope_base. pos may be per-row (serving engine).
-    pos_col = pos0[:, None] if pos0.ndim == 1 else pos0
-    slots = pos_col + jnp.arange(T)[None, :]  # [B|1, T] global cache slots
-    if positions is not None:
-        positions = positions.astype(jnp.int32)  # caller-supplied override
-    elif cache is not None:
-        positions = cache.next_positions(T)  # [B, T]
-    else:
-        positions = jnp.maximum(slots - row_start[:, None], 0)  # [B, T]
-
-    if input_is_hidden:
-        h = tokens.astype(compute_dtype)
-    else:
-        h = embed_tokens(config, params, tokens, compute_dtype)
-        if config.learned_positions:  # gpt2 wpe table
-            h = h + params["wpe"].astype(compute_dtype)[positions]
-        if config.embed_layernorm:  # bloom word_embeddings_layernorm
-            h = layer_norm(
-                h, params["embed_norm"], params.get("embed_norm_b"),
-                config.rms_norm_eps,
-            )
-
-    use_rope = not (config.alibi or config.learned_positions)
-    cos_local = sin_local = None
-    if use_rope:
-        inv_freq, att_scale = make_inv_freq_scaled(
-            config.rotary_dim, config.rope_theta, config.rope_scaling_dict,
-            seq_len=(cache.max_len if cache is not None else T),
-        )
-        if position_grid is not None and config.mrope_section:
-            from bigdl_tpu.ops.rope import mrope_cos_sin
-
-            cos, sin = mrope_cos_sin(
-                position_grid, inv_freq, config.mrope_section,
-                scale=att_scale,
-            )
+    with scope("engine"):  # positions, and the embedding
+        if cache is None:
+            pos0 = jnp.zeros((), jnp.int32)
+            row_start = jnp.zeros((B,), jnp.int32) if start is None else start
         else:
-            cos, sin = rope_cos_sin(
-                positions, inv_freq, interleaved=config.rope_interleaved,
-                scale=att_scale,
-            )
-        if config.rope_local_theta is not None:
-            # gemma3 dual rope: sliding layers use the local base,
-            # UNscaled (HF applies rope_scaling to global layers only)
-            inv_local, _ = make_inv_freq_scaled(
-                config.rotary_dim, config.rope_local_theta, None
-            )
-            cos_local, sin_local = rope_cos_sin(
-                positions, inv_local, interleaved=config.rope_interleaved
-            )
-    else:
-        cos = sin = None
+            pos0 = cache.pos
+            row_start = cache.start
 
-    # qwen v1 logn attention (HF modeling_qwen logn_tensor; reference
-    # models/qwen.py): queries beyond the training length scale by
-    # log_train_len(pos+1) so attention entropy stays flat as the
-    # context grows. max(1, .) keeps in-distribution positions exact.
-    logn_col = None
-    if config.logn_attn and config.logn_train_len:
-        i = positions.astype(jnp.float32) + 1.0
-        logn = jnp.maximum(
-            jnp.log(i) / jnp.log(jnp.float32(config.logn_train_len)), 1.0
-        )
-        logn_col = logn[:, :, None, None].astype(compute_dtype)
+        # Positions are relative to each row's start (left pad); after SnapKV
+        # compression slots ≠ positions and the cache carries the true next
+        # position in rope_base. pos may be per-row (serving engine).
+        pos_col = pos0[:, None] if pos0.ndim == 1 else pos0
+        slots = pos_col + jnp.arange(T)[None, :]  # [B|1, T] global cache slots
+        if positions is not None:
+            positions = positions.astype(jnp.int32)  # caller-supplied override
+        elif cache is not None:
+            positions = cache.next_positions(T)  # [B, T]
+        else:
+            positions = jnp.maximum(slots - row_start[:, None], 0)  # [B, T]
+
+        if input_is_hidden:
+            h = tokens.astype(compute_dtype)
+        else:
+            h = embed_tokens(config, params, tokens, compute_dtype)
+            if config.learned_positions:  # gpt2 wpe table
+                h = h + params["wpe"].astype(compute_dtype)[positions]
+            if config.embed_layernorm:  # bloom word_embeddings_layernorm
+                h = layer_norm(
+                    h, params["embed_norm"], params.get("embed_norm_b"),
+                    config.rms_norm_eps,
+                )
+
+    with scope("attn.rope"):  # the tables, once for every layer
+        use_rope = not (config.alibi or config.learned_positions)
+        cos_local = sin_local = None
+        if use_rope:
+            inv_freq, att_scale = make_inv_freq_scaled(
+                config.rotary_dim, config.rope_theta, config.rope_scaling_dict,
+                seq_len=(cache.max_len if cache is not None else T),
+            )
+            if position_grid is not None and config.mrope_section:
+                from bigdl_tpu.ops.rope import mrope_cos_sin
+
+                cos, sin = mrope_cos_sin(
+                    position_grid, inv_freq, config.mrope_section,
+                    scale=att_scale,
+                )
+            else:
+                cos, sin = rope_cos_sin(
+                    positions, inv_freq, interleaved=config.rope_interleaved,
+                    scale=att_scale,
+                )
+            if config.rope_local_theta is not None:
+                # gemma3 dual rope: sliding layers use the local base,
+                # UNscaled (HF applies rope_scaling to global layers only)
+                inv_local, _ = make_inv_freq_scaled(
+                    config.rotary_dim, config.rope_local_theta, None
+                )
+                cos_local, sin_local = rope_cos_sin(
+                    positions, inv_local, interleaved=config.rope_interleaved
+                )
+        else:
+            cos = sin = None
+
+        # qwen v1 logn attention (HF modeling_qwen logn_tensor; reference
+        # models/qwen.py): queries beyond the training length scale by
+        # log_train_len(pos+1) so attention entropy stays flat as the
+        # context grows. max(1, .) keeps in-distribution positions exact.
+        logn_col = None
+        if config.logn_attn and config.logn_train_len:
+            i = positions.astype(jnp.float32) + 1.0
+            logn = jnp.maximum(
+                jnp.log(i) / jnp.log(jnp.float32(config.logn_train_len)), 1.0
+            )
+            logn_col = logn[:, :, None, None].astype(compute_dtype)
 
     # Prefill goes through the Pallas flash-attention kernel (no [T,S]
     # score matrix in HBM); decode and the differentiable cache-free
@@ -941,7 +948,8 @@ def forward(
         from bigdl_tpu import kvstate
 
         use_flash = use_flash_train = use_paged_kernel = False
-        ret_valid = kvstate.valid_positions(cache, slots, row_start, T)
+        with scope("attn"):
+            ret_valid = kvstate.valid_positions(cache, slots, row_start, T)
         why = (kvstate.why_not_kernel(D) if mode == "decode" and T == 1
                and cache is not None else "the chunked form is XLA's")
         routes.note("attention",
@@ -951,7 +959,8 @@ def forward(
         routes.note("attention", "pallas:paged", att_detail + (
             f" block of {T}: {T * Hq // Hkv} rows a KV head"
             if mode == "block" else ""))
-        row_live = live_rows(cache)  # the table does not change in here
+        with scope("attn"):
+            row_live = live_rows(cache)  # the table does not change in here
     elif use_flash_train:
         routes.note("attention", "pallas:flash_train", att_detail)
     elif use_flash:
@@ -968,21 +977,22 @@ def forward(
         mask_global = mask_sliding = None
         alibi_bias = None
     else:
-        mask_global, mask_sliding, k_slot, q_slot = build_masks()
-        if config.alibi:
-            # additive float bias: slope_h * (k_pos - q_pos), 0 on diagonal
-            # (start offsets cancel in the difference)
-            slopes = alibi_slopes(Hq).reshape(Hkv, Hq // Hkv)
-            if config.alibi_scale:  # falcon-rw: bias shares the score scale
-                slopes = slopes * config.alibi_scale
-            dist = (k_slot - q_slot).astype(jnp.float32)  # [B, T, S]
-            alibi_bias = (
-                slopes[None, :, :, None, None] * dist[:, None, None]
-            )  # [B, Hkv, G, T, S]
-        else:
-            alibi_bias = None
-        mask_global = mask_global[:, None, None]  # [B,1,1,T,S]
-        mask_sliding = mask_sliding[:, None, None]
+        with scope("attn"):  # the masks, once for every layer
+            mask_global, mask_sliding, k_slot, q_slot = build_masks()
+            if config.alibi:
+                # additive float bias: slope_h * (k_pos - q_pos), 0 on
+                # diagonal (start offsets cancel in the difference)
+                slopes = alibi_slopes(Hq).reshape(Hkv, Hq // Hkv)
+                if config.alibi_scale:  # falcon-rw: bias shares the scale
+                    slopes = slopes * config.alibi_scale
+                dist = (k_slot - q_slot).astype(jnp.float32)  # [B, T, S]
+                alibi_bias = (
+                    slopes[None, :, :, None, None] * dist[:, None, None]
+                )  # [B, Hkv, G, T, S]
+            else:
+                alibi_bias = None
+            mask_global = mask_global[:, None, None]  # [B,1,1,T,S]
+            mask_sliding = mask_sliding[:, None, None]
 
     lora_scale = lora["scale"] if lora is not None else None
     tp_sharded = comm is not None and comm.axis_size > 1
@@ -1044,10 +1054,10 @@ def forward(
         return y
 
     # per-layer static sliding flags, as a traced vector for the scan body
-    sliding_flags = jnp.asarray(
-        [config.layer_is_sliding(l) for l in range(config.num_hidden_layers)],
-        jnp.bool_,
-    )
+    with scope("attn"):
+        sliding_flags = jnp.asarray(
+            [config.layer_is_sliding(l)
+             for l in range(config.num_hidden_layers)], jnp.bool_)
 
     def body(carry, xs):
         hidden, c, idx = carry
@@ -1057,9 +1067,9 @@ def forward(
         p = stacks_in(p, stack_codes)
         proj = functools.partial(layer_proj, idx=idx)
 
-        with jax.named_scope("norm_rope"):
+        with scope("norm"):
             x = norm(hidden, p["attn_norm"], p.get("attn_norm_b"))
-        with jax.named_scope("attn"):
+        with scope("attn.proj"):
             if "wqkv" in p:  # merged layout (merge_fused_params)
                 QD, KD = Hq * D, Hkv * D
                 qkv = proj(x, p, None, "wqkv", "bqkv")
@@ -1079,7 +1089,7 @@ def forward(
                 q = proj(x, p, lp, "wq", "bq").reshape(B, T, Hq, D)
                 k = proj(x, p, lp, "wk", "bk").reshape(B, T, Hkv, D)
                 v = proj(x, p, lp, "wv", "bv").reshape(B, T, Hkv, D)
-        with jax.named_scope("norm_rope"):
+        with scope("attn.rope"):
             if config.qk_norm:
                 q = rms_norm(q, p["q_norm"], eps, offset=config.rms_norm_offset)
                 k = rms_norm(k, p["k_norm"], eps, offset=config.rms_norm_offset)
@@ -1094,7 +1104,7 @@ def forward(
             if logn_col is not None:
                 q = q * logn_col
 
-        with jax.named_scope("attn"):
+        with scope("attn"):
             k_scale_att = v_scale_att = None
             if retention:
                 # log-gates in float32 at full precision, like a router's
@@ -1177,8 +1187,9 @@ def forward(
                     q, k_att, v_att, mask,
                     scale=config.attn_scale, softcap=config.attn_logit_softcap,
                 )
+        with scope("attn.proj"):
             out = proj(attn.reshape(B, T, Hq * D), p, lp, "wo", "bo")
-        with jax.named_scope("norm_rope"):
+        with scope("norm"):
             if config.post_attn_norm:
                 out = norm(out, p["post_attn_norm"])
             rs = config.residual_scale
@@ -1190,7 +1201,7 @@ def forward(
                 hidden = hidden + (out * rs if rs else out)
                 mlp_in = norm(hidden, p["mlp_norm"], p.get("mlp_norm_b"))
 
-        with jax.named_scope("ffn"):
+        with scope("ffn"):
             x = mlp_in
             routed = None
             if config.is_moe:
@@ -1217,16 +1228,21 @@ def forward(
             else:
                 up = proj(x, p, lp, "w_up", "b_up")
                 down = proj(_act(config.hidden_act, up), p, lp, "w_down", "b_down")
+        with scope("norm"):  # the residual add fuses with the next norm
             if config.post_attn_norm:
                 down = norm(down, p["post_mlp_norm"])
-        if config.parallel_residual:
-            hidden = hidden + out + down
-        else:
-            hidden = hidden + (down * rs if rs else down)
+            if config.parallel_residual:
+                hidden = hidden + out + down
+            else:
+                hidden = hidden + (down * rs if rs else down)
 
-        ys = (q[:, T - collect_obs:] if collect_obs else None,
-              routed if moe_routing else None)
-        return (hidden, c, idx + 1), ys
+        obs_q = None
+        if collect_obs:  # the window's queries, for SnapKV
+            with scope("attn"):
+                obs_q = q[:, T - collect_obs:]
+        ys = (obs_q, routed if moe_routing else None)
+        with scope("engine"):  # the loop's own count
+            return (hidden, c, idx + 1), ys
 
     xs = (layers, lora["layers"]) if lora is not None else layers
     scan_body = body
@@ -1242,13 +1258,14 @@ def forward(
     if return_hidden:
         logits = h
     else:
-        if last_logits_only:
-            h = h[:, -1:]
-        with jax.named_scope("lm_head"):
+        with scope("lm_head"):
+            if last_logits_only:
+                h = h[:, -1:]
             logits = lm_head_logits(config, params, h, compute_dtype)
-    if retention and cache is not None:
-        cache = kvstate.advance(cache, T)
-    elif cache is not None:
-        cache = kvcache.advance(cache, T)
+    with scope("engine"):
+        if retention and cache is not None:
+            cache = kvstate.advance(cache, T)
+        elif cache is not None:
+            cache = kvcache.advance(cache, T)
     out = (logits, cache) + ((obs,) if collect_obs else ())
     return out + ((routing,) if moe_routing else ())

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from bigdl_tpu import kvcache, kvpaged, kvwindow
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import ModelConfig
+from bigdl_tpu.obs.scopes import scope
 from bigdl_tpu.ops import linear, rms_norm
 from bigdl_tpu.ops.linear import stacks_in
 
@@ -222,11 +223,13 @@ def forward(
 
     fresh = cache is None
     if fresh:
-        cache = init_cache(config, B, T, dtype=compute_dtype)
+        with scope("engine"):
+            cache = init_cache(config, B, T, dtype=compute_dtype)
     paged = cache.paged
     scalar_pos = cache.pos.ndim == 0
-    pos_col = cache.pos if scalar_pos else cache.pos[:, None]
-    slots = pos_col + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B|1, T]
+    with scope("engine"):
+        pos_col = cache.pos if scalar_pos else cache.pos[:, None]
+        slots = pos_col + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B|1, T]
 
     detail = f"mode={mode} B{B} T{T}"
     note = (f"full x{per[0]} nope, window {W} x{per[1]} rope, "
@@ -236,7 +239,8 @@ def forward(
                  and use_pallas())
     if use_kernel:
         routes.note("attention", "pallas:paged", f"{detail} {note}")
-        row_live = kvpaged.live_rows(cache)
+        with scope("attn"):
+            row_live = kvpaged.live_rows(cache)
     elif use_flash:
         routes.note("attention", "pallas:flash", f"{detail} {note}")
     else:
@@ -244,39 +248,47 @@ def forward(
             "a paged or per-row cache at T > 1: flash takes one dense row"
             if T > 1 else "dense-cache decode: fused XLA attention")
         routes.note("attention", "xla", f"{detail} {note} ({why})")
-        sj = jnp.arange(cache.max_len)[None, None, :]
-        full = (sj <= slots[..., None]) & (sj >= cache.start[:, None, None])
-        masks = (full[:, None, None],  # [B, 1, 1, T, S]
-                 (full & (sj > slots[..., None] - W))[:, None, None]
-                 if W else None)
+        with scope("attn"):  # the masks, once for every layer
+            sj = jnp.arange(cache.max_len)[None, None, :]
+            full = (sj <= slots[..., None]) & (
+                sj >= cache.start[:, None, None])
+            masks = (full[:, None, None],  # [B, 1, 1, T, S]
+                     (full & (sj > slots[..., None] - W))[:, None, None]
+                     if W else None)
 
-    inv_freq, att_scale = make_inv_freq_scaled(
-        config.rotary_dim, config.rope_theta, config.rope_scaling_dict,
-        seq_len=cache.max_len)
-    cos, sin = rope_cos_sin(cache.group(False).next_positions(T), inv_freq,
-                            scale=att_scale)
+    with scope("attn.rope"):  # the tables, once for every layer
+        inv_freq, att_scale = make_inv_freq_scaled(
+            config.rotary_dim, config.rope_theta, config.rope_scaling_dict,
+            seq_len=cache.max_len)
+        cos, sin = rope_cos_sin(cache.group(False).next_positions(T),
+                                inv_freq, scale=att_scale)
 
-    h = llama.embed_tokens(config, params, tokens, compute_dtype)
+    with scope("engine"):
+        h = llama.embed_tokens(config, params, tokens, compute_dtype)
 
     def layer(j, hidden, c, p, codes, i):
         """Layer `j` of period `i`."""
         p = stacks_in(p, codes)
         window = W if sliding[j] else None
-        idx = i * per[sliding[j]] + rank[j]
+        with scope("engine"):  # the loop's own counts
+            idx = i * per[sliding[j]] + rank[j]
 
         def proj(x, name):
             return linear(x, p[name], None, compute_dtype,
                           layer=i if name in codes else None)
 
-        with jax.named_scope("moe.router"):  # from the layer's INPUT
+        with scope("moe.router"):  # from the layer's INPUT
             topv, topi = llama._moe_router(config, hidden, p)
-        x = rms_norm(hidden, p["attn_norm"], eps)
-        with jax.named_scope("attn"):
+        with scope("norm"):
+            x = rms_norm(hidden, p["attn_norm"], eps)
+        with scope("attn.proj"):
             q = proj(x, "wq").reshape(B, T, Hq, D)
             k = proj(x, "wk").reshape(B, T, Hkv, D)
             v = proj(x, "wv").reshape(B, T, Hkv, D)
-            if rope[j]:  # a NoPE layer makes no rope call at all
+        if rope[j]:  # a NoPE layer makes no rope call at all
+            with scope("attn.rope"):
                 q, k = apply_rotary_emb(q, k, cos, sin)
+        with scope("attn"):
             g = kvcache.update_layer(c.group(sliding[j]), idx, k, v)
             c = c.with_group(sliding[j], g)
             if use_kernel:
@@ -294,14 +306,17 @@ def forward(
                                           q_offset=c.pos, window=window)
                 else:
                     out = attention(q, kf, vf, masks[sliding[j]])
-            hidden = hidden + proj(
-                out.reshape(B, T, Hq * D).astype(compute_dtype), "wo")
-        x = rms_norm(hidden, p["mlp_norm"], eps).astype(compute_dtype)
-        with jax.named_scope("ffn"):
+        with scope("attn.proj"):
+            out = proj(out.reshape(B, T, Hq * D).astype(compute_dtype), "wo")
+        with scope("norm"):
+            hidden = hidden + out
+            x = rms_norm(hidden, p["mlp_norm"], eps).astype(compute_dtype)
+        with scope("ffn"):
             d = llama._moe_dispatch(
                 config, x, p, compute_dtype, topv, topi,
                 layer=i if "w_up_e" in codes else None)
-        return hidden + d, c, topi
+        with scope("norm"):  # the add fuses with the next norm
+            return hidden + d, c, topi
 
     stacks = [_keep_codes_out(params["period"][str(j)]) for j in range(P)]
 
@@ -311,22 +326,24 @@ def forward(
         for j, p in enumerate(xs):
             hidden, c, topi = layer(j, hidden, c, p, stacks[j][1], i)
             chosen.append(topi)
-        return (hidden, c, i + 1), (jnp.stack(chosen) if moe_routing
-                                    else None)
+        with scope("engine"):  # the loop's own count, the ids for the host
+            return (hidden, c, i + 1), (jnp.stack(chosen) if moe_routing
+                                        else None)
 
     (h, cache, _), routing = jax.lax.scan(
         body, (h, cache, jnp.zeros((), jnp.int32)),
         tuple(sliced for sliced, _ in stacks))
 
-    if logits_at is not None:
-        h = jax.lax.dynamic_slice_in_dim(h, logits_at, 1, axis=1)
-    elif last_logits_only:
-        h = h[:, -1:]
-    with jax.named_scope("lm_head"):
+    with scope("lm_head"):
+        if logits_at is not None:
+            h = jax.lax.dynamic_slice_in_dim(h, logits_at, 1, axis=1)
+        elif last_logits_only:
+            h = h[:, -1:]
         logits = llama.lm_head_logits(config, params, h, compute_dtype)
-    extra = ()
-    if moe_routing:  # [n_periods, P, B, T, k] -> the model's layer order
-        extra = (routing.reshape((-1,) + routing.shape[2:]),)
-    if fresh:
-        return (logits, None) + extra
-    return (logits, kvwindow.advance(cache, T)) + extra
+    with scope("engine"):
+        extra = ()
+        if moe_routing:  # [n_periods, P, B, T, k] -> the model's layer order
+            extra = (routing.reshape((-1,) + routing.shape[2:]),)
+        if fresh:
+            return (logits, None) + extra
+        return (logits, kvwindow.advance(cache, T)) + extra

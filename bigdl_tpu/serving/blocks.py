@@ -71,6 +71,7 @@ import numpy as np
 from bigdl_tpu import kvpaged
 from bigdl_tpu.generate import sample_token_per_row
 from bigdl_tpu.models.config import BLOCK_STRATEGIES
+from bigdl_tpu.obs.scopes import scope
 from bigdl_tpu.ops import routes
 from bigdl_tpu.serving.hostrow import bits, expert_id_dtype
 
@@ -222,10 +223,11 @@ class BlockScheduler:
     # ---- the device half ---------------------------------------------------
 
     def arm_impl(self, state: BlockState, slot, ids, revealed):
-        return BlockState(
-            ids=state.ids.at[slot].set(ids),
-            revealed=state.revealed.at[slot].set(revealed),
-            n_pass=state.n_pass.at[slot].set(0))
+        with scope("engine"):
+            return BlockState(
+                ids=state.ids.at[slot].set(ids),
+                revealed=state.revealed.at[slot].set(revealed),
+                n_pass=state.n_pass.at[slot].set(0))
 
     def pass_impl(self, forward, params, state: BlockState, cache, key,
                   temp, topk, topp, dosample):
@@ -235,16 +237,17 @@ class BlockScheduler:
         block. Packs what the host reads into one int32 row a slot."""
         eng, b = self.engine, self.b
         cfg = eng.config
-        live = kvpaged.live_rows(cache)
+        with scope("engine"):
+            live = kvpaged.live_rows(cache)
         pos0 = cache.pos
         logits, cache, experts = eng._forward_routing(
             forward, params, state.ids, cache, "block", {})
-        with jax.named_scope("block.reveal"):
+        with scope("block.reveal"):
             x0, lp, shown, store = reveal(
                 logits.astype(jnp.float32), state, key,
                 (temp, topk, topp, dosample), self.steps,
                 cfg.remasking_strategy, cfg.confidence_threshold)
-        with jax.named_scope("block.store"):
+        with scope("block.store"):
             # the forward advanced every row by b; only a row whose block is
             # stored moves on (an idle row, whose table maps nothing, never)
             move = store & live
@@ -256,13 +259,14 @@ class BlockScheduler:
                 revealed=(state.revealed | shown) & ~move[:, None]
                 & live[:, None],
                 n_pass=jnp.where(move | ~live, 0, state.n_pass + 1))
-        out = [state.ids, x0, bits(lp), shown.astype(jnp.int32),
-               (~state.revealed).astype(jnp.int32),
-               store.astype(jnp.int32)[:, None], state.n_pass[:, None]]
-        if experts is not None:  # [L, B, b, k] -> [B, L * b * k]
-            out.append(jnp.swapaxes(experts, 0, 1).reshape(
-                x0.shape[0], -1).astype(jnp.int32))
-        return nxt, jnp.concatenate(out, axis=1), cache
+        with scope("engine"):  # what the host reads
+            out = [state.ids, x0, bits(lp), shown.astype(jnp.int32),
+                   (~state.revealed).astype(jnp.int32),
+                   store.astype(jnp.int32)[:, None], state.n_pass[:, None]]
+            if experts is not None:  # [L, B, b, k] -> [B, L * b * k]
+                out.append(jnp.swapaxes(experts, 0, 1).reshape(
+                    x0.shape[0], -1).astype(jnp.int32))
+            return nxt, jnp.concatenate(out, axis=1), cache
 
     # ---- an admission ------------------------------------------------------
 

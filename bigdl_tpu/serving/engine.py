@@ -43,6 +43,7 @@ from bigdl_tpu import kvcache, kvpaged
 from bigdl_tpu.generate import GenerationConfig, sample_token_per_row
 from bigdl_tpu.models.config import ModelConfig
 from bigdl_tpu.obs import retrace
+from bigdl_tpu.obs.scopes import scope
 from bigdl_tpu.obs.tracing import DECODE_TID
 from bigdl_tpu.serving.faults import NULL_INJECTOR, FaultError
 from bigdl_tpu.serving.hostrow import bits as _bits
@@ -1032,20 +1033,23 @@ class InferenceEngine:
         decode parity with the offline-merged weights breaks at token
         one."""
         cfg = self.config
-        if self._family_cache is not None:
-            cache = self._family_cache(cfg, 1, bucket)
-        else:
-            cache = kvcache.init_cache(
-                cfg.num_hidden_layers, 1, bucket, cfg.num_key_value_heads,
-                cfg.head_dim_, quantize_kv=self.quantize_kv,
-            )
-        cache = dataclasses.replace(cache, start=start)
+        with scope("engine"):
+            if self._family_cache is not None:
+                cache = self._family_cache(cfg, 1, bucket)
+            else:
+                cache = kvcache.init_cache(
+                    cfg.num_hidden_layers, 1, bucket,
+                    cfg.num_key_value_heads, cfg.head_dim_,
+                    quantize_kv=self.quantize_kv,
+                )
+            cache = dataclasses.replace(cache, start=start)
         kw = {} if lora is None else {"lora": lora}
         logits, cache = forward(
             cfg, params, tokens, cache, mode="prefill",
             last_logits_only=True, **kw
         )
-        return logits[:, -1], cache
+        with scope("engine"):
+            return logits[:, -1], cache
 
     def _insert_impl(self, cache, pcache, slot, pad):
         """Copy a prefilled request's KV (length `bucket`) into slot row at
@@ -1096,17 +1100,19 @@ class InferenceEngine:
         request's rank-bucketed adapter tree (every chunk of a chunked
         prefill carries it)."""
         kind, cfg = self.kind, self.config
-        pool, row = kind.row_view(pool, tables, pos0, last_idx, slot, cfg,
-                                  self._geo)
+        with scope("engine"):
+            pool, row = kind.row_view(pool, tables, pos0, last_idx, slot,
+                                      cfg, self._geo)
         kw = dict(kind.forward_kw(last_idx))
         at = 0 if "logits_at" in kw else last_idx
         if lora is not None:
             kw["lora"] = lora
         logits, row, experts = self._forward_routing(
             forward, params, tokens, row, "prefill", kw)
-        pool = kind.write_back(pool, row, tokens.shape[1], last_idx, cfg)
-        return (logits[0, at], pool,
-                None if experts is None else experts[:, 0])
+        with scope("engine"):
+            pool = kind.write_back(pool, row, tokens.shape[1], last_idx, cfg)
+            return (logits[0, at], pool,
+                    None if experts is None else experts[:, 0])
 
     def _forward_routing(self, forward, params, tokens, cache, mode, kw):
         """`forward`, and for a sparse-expert model every position's top-k
@@ -1118,8 +1124,9 @@ class InferenceEngine:
         logits, cache, routing = forward(
             self.config, params, tokens, cache, mode=mode, moe_routing=True,
             **kw)
-        return logits, cache, routing.astype(
-            _expert_id_dtype(self.config.num_experts))
+        with scope("engine"):
+            return logits, cache, routing.astype(
+                _expert_id_dtype(self.config.num_experts))
 
     def _first_token_impl(self, logits, rng, temp, topk, topp, dosample,
                           penalty, row, slot, cur, seen):
@@ -1135,23 +1142,26 @@ class InferenceEngine:
         engine seeded alike and fed alike samples alike."""
         from bigdl_tpu.generate import apply_repetition_penalty
 
-        logits = logits.reshape(1, -1)
-        rng, key = jax.random.split(rng)
-        # no penalty arrives as 1.0 with an all-False row: the identity
-        logits = apply_repetition_penalty(logits, row[None], penalty)
-        with jax.named_scope("sample"):
+        with scope("engine"):
+            logits = logits.reshape(1, -1)
+            rng, key = jax.random.split(rng)
+        with scope("sample"):
+            # no penalty arrives as 1.0 with an all-False row: the identity
+            logits = apply_repetition_penalty(logits, row[None], penalty)
             first = sample_token_per_row(
                 logits, key, temp[None], topk[None], topp[None],
                 dosample[None])[0]
-        row_lp = jax.nn.log_softmax(logits.astype(jnp.float32).reshape(-1))
+        with scope("engine"):
+            row_lp = jax.nn.log_softmax(
+                logits.astype(jnp.float32).reshape(-1))
 
-        out = [first[None], _bits(row_lp[first])[None]]
-        if self.logprobs_top_k:  # static: an engine constant
-            tv, ti = jax.lax.top_k(row_lp, self.logprobs_top_k)
-            out += [ti.astype(jnp.int32), _bits(tv)]
-        cur = cur.at[slot].set(first)
-        seen = seen.at[slot].set(row).at[slot, first].set(True)
-        return cur, seen, rng, jnp.concatenate(out)
+            out = [first[None], _bits(row_lp[first])[None]]
+            if self.logprobs_top_k:  # static: an engine constant
+                tv, ti = jax.lax.top_k(row_lp, self.logprobs_top_k)
+                out += [ti.astype(jnp.int32), _bits(tv)]
+            cur = cur.at[slot].set(first)
+            seen = seen.at[slot].set(row).at[slot, first].set(True)
+            return cur, seen, rng, jnp.concatenate(out)
 
     def _decode_impl(self, forward, params, cur, cache, key,
                      temp, topk, topp, dosample, seen, penalty,
@@ -1164,12 +1174,14 @@ class InferenceEngine:
         # output (ops/linear.lora_epilogue) — adapter-less slots carry
         # zero-padded rows and a 0 scale, contributing exactly nothing
         kw = {} if lora is None else {"lora": lora}
+        with scope("engine"):
+            tokens = cur[:, None]
         logits, cache, experts = self._forward_routing(
-            forward, params, cur[:, None], cache, "decode", kw)
-        last = logits[:, -1]
+            forward, params, tokens, cache, "decode", kw)
         # all-default batches (every penalty 1.0) skip the O(slots x V)
         # rewrite, mirroring sample_token_per_row's all-greedy guard
-        with jax.named_scope("sample"):
+        with scope("sample"):
+            last = logits[:, -1]
             step = jax.lax.cond(
                 jnp.any(penalty != 1.0),
                 lambda: apply_repetition_penalty(last, seen, penalty),
@@ -1177,24 +1189,25 @@ class InferenceEngine:
             )
             nxt = sample_token_per_row(step, key, temp, topk, topp,
                                        dosample)
-        # chosen-token logprob without materializing [B, V] log-softmax:
-        # gather the logit, subtract the row's logsumexp
-        step32 = step.astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(step32, axis=-1)
-        lp = (jnp.take_along_axis(step32, nxt[:, None], axis=-1)[:, 0]
-              - lse)
-        seen = seen.at[jnp.arange(seen.shape[0]), nxt].set(True)
+        with scope("engine"):
+            # chosen-token logprob without materializing [B, V]
+            # log-softmax: gather the logit, subtract the row's logsumexp
+            step32 = step.astype(jnp.float32)
+            lse = jax.scipy.special.logsumexp(step32, axis=-1)
+            lp = (jnp.take_along_axis(step32, nxt[:, None], axis=-1)[:, 0]
+                  - lse)
+            seen = seen.at[jnp.arange(seen.shape[0]), nxt].set(True)
 
-        # what the host reads, one int32 row a slot (_read_step): `nxt` is
-        # the next step's input and never leaves the device
-        out = [nxt[:, None], _bits(lp)[:, None]]
-        if self.logprobs_top_k:  # static: compiles only when opted in
-            tv, ti = jax.lax.top_k(step32, self.logprobs_top_k)
-            out += [ti.astype(jnp.int32), _bits(tv - lse[:, None])]
-        if experts is not None:  # [L, B, 1, k] -> [B, L * k]
-            out.append(jnp.swapaxes(experts[:, :, 0], 0, 1).reshape(
-                nxt.shape[0], -1).astype(jnp.int32))
-        return nxt, jnp.concatenate(out, axis=1), cache, seen
+            # what the host reads, one int32 row a slot (_read_step): `nxt`
+            # is the next step's input and never leaves the device
+            out = [nxt[:, None], _bits(lp)[:, None]]
+            if self.logprobs_top_k:  # static: compiles only when opted in
+                tv, ti = jax.lax.top_k(step32, self.logprobs_top_k)
+                out += [ti.astype(jnp.int32), _bits(tv - lse[:, None])]
+            if experts is not None:  # [L, B, 1, k] -> [B, L * k]
+                out.append(jnp.swapaxes(experts[:, :, 0], 0, 1).reshape(
+                    nxt.shape[0], -1).astype(jnp.int32))
+            return nxt, jnp.concatenate(out, axis=1), cache, seen
 
     def _spec_decode_impl(self, forward, k_draft, params, dparams, cur, cache,
                           dcache, key, temp, topk, topp, dosample, seen,
@@ -1223,16 +1236,20 @@ class InferenceEngine:
 
         def draft_step(carry, _):
             tok, dc = carry
-            lg, dc = forward(cfg, dparams, tok[:, None], dc, mode="decode")
-            nxt = jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32)
+            with scope("engine"):
+                tok = tok[:, None]
+            lg, dc = forward(cfg, dparams, tok, dc, mode="decode")
+            with scope("sample"):
+                nxt = jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32)
             return (nxt, dc), nxt
 
         (_, dcache), drafts = jax.lax.scan(
             draft_step, (cur, dcache), None, length=K
         )
-        drafts = jnp.swapaxes(drafts, 0, 1)  # [B, K]
-
-        verify_in = jnp.concatenate([cur[:, None], drafts[:, :K - 1]], axis=1)
+        with scope("engine"):
+            drafts = jnp.swapaxes(drafts, 0, 1)  # [B, K]
+            verify_in = jnp.concatenate(
+                [cur[:, None], drafts[:, :K - 1]], axis=1)
         # adapter-aware verification: the TARGET forward applies the
         # batched per-slot adapter tree (the same one plain decode
         # uses), so accepted tokens follow the adapter-shifted target
@@ -1244,92 +1261,96 @@ class InferenceEngine:
         tlogits, cache = forward(
             cfg, params, verify_in, cache, mode="prefill", **kw
         )
-        tlogits = tlogits.astype(jnp.float32)
-        greedy = jnp.argmax(tlogits, axis=-1).astype(jnp.int32)  # [B, K]
+        with scope("sample"):  # what each row accepts
+            tlogits = tlogits.astype(jnp.float32)
+            greedy = jnp.argmax(tlogits, axis=-1).astype(jnp.int32)  # [B, K]
 
-        # acceptance per decode mode: greedy rows match the target's
-        # argmax (byte-identical to plain serving); sampling rows run
-        # rejection acceptance against the full per-position sampling
-        # distribution (exact output law — decode/speculative.py);
-        # repetition-penalty rows accept 0 and take the penalty-adjusted
-        # sampler token at position 0 (their distribution depends on
-        # tokens emitted earlier in the same round)
-        from bigdl_tpu.decode.speculative import rejection_accept
-        from bigdl_tpu.generate import filter_logits_per_row
+            # acceptance per decode mode: greedy rows match the target's
+            # argmax (byte-identical to plain serving); sampling rows run
+            # rejection acceptance against the full per-position sampling
+            # distribution (exact output law — decode/speculative.py);
+            # repetition-penalty rows accept 0 and take the penalty-adjusted
+            # sampler token at position 0 (their distribution depends on
+            # tokens emitted earlier in the same round)
+            from bigdl_tpu.decode.speculative import rejection_accept
+            from bigdl_tpu.generate import filter_logits_per_row
 
-        pen1 = penalty == 1.0
-        row_greedy = ~dosample & pen1
-        row_sampled = dosample & pen1
-        k_acc, k_pen = jax.random.split(key)
+            pen1 = penalty == 1.0
+            row_greedy = ~dosample & pen1
+            row_sampled = dosample & pen1
+            k_acc, k_pen = jax.random.split(key)
 
-        def accept_mixed():
-            probs = jax.nn.softmax(
-                filter_logits_per_row(tlogits, temp, topk, topp), axis=-1
-            )
-            return rejection_accept(
-                k_acc, probs, drafts, greedy, row_greedy, row_sampled
-            )
+            def accept_mixed():
+                probs = jax.nn.softmax(
+                    filter_logits_per_row(tlogits, temp, topk, topp), axis=-1
+                )
+                return rejection_accept(
+                    k_acc, probs, drafts, greedy, row_greedy, row_sampled
+                )
 
-        def accept_greedy_only():
-            # all-greedy pools (the common serving case) skip the two
-            # full [B, K, V] sorts + softmax of the filtered-probs path
-            acc = (drafts[:, : K - 1] == greedy[:, : K - 1]) \
-                & row_greedy[:, None]
-            n = jnp.sum(jnp.cumprod(acc.astype(jnp.int32), axis=1), axis=1)
-            return n, jnp.take_along_axis(greedy, n[:, None], axis=1)[:, 0]
+            def accept_greedy_only():
+                # all-greedy pools (the common serving case) skip the two
+                # full [B, K, V] sorts + softmax of the filtered-probs path
+                acc = (drafts[:, : K - 1] == greedy[:, : K - 1]) \
+                    & row_greedy[:, None]
+                n = jnp.sum(jnp.cumprod(acc.astype(jnp.int32), axis=1), axis=1)
+                return n, jnp.take_along_axis(greedy, n[:, None], axis=1)[:, 0]
 
-        n_acc, extra = jax.lax.cond(
-            jnp.any(row_sampled), accept_mixed, accept_greedy_only
-        )
-
-        def penalty_sample():
-            step0 = apply_repetition_penalty(tlogits[:, 0], seen, penalty)
-            return sample_token_per_row(
-                step0, k_pen, temp, topk, topp, dosample
+            n_acc, extra = jax.lax.cond(
+                jnp.any(row_sampled), accept_mixed, accept_greedy_only
             )
 
-        # penalty rows accept 0 and take the penalty-adjusted sampler
-        # token at position 0; all-pen1 batches skip the extra sampler
-        samp0 = jax.lax.cond(
-            jnp.any(~pen1), penalty_sample, lambda: extra
-        )
-        extra = jnp.where(pen1, extra, samp0)
+            def penalty_sample():
+                step0 = apply_repetition_penalty(tlogits[:, 0], seen, penalty)
+                return sample_token_per_row(
+                    step0, k_pen, temp, topk, topp, dosample
+                )
 
-        pos = jnp.arange(K, dtype=jnp.int32)[None, :]
-        choice = jnp.where(
-            pos < n_acc[:, None], drafts,
-            jnp.where(pos == n_acc[:, None], extra[:, None], greedy),
-        )
-        cur2 = extra
-        # [B, K] target logprob of each emitted token (gather - logsumexp,
-        # no [B, K, V] log-softmax materialization)
-        lp_all = (
-            jnp.take_along_axis(tlogits, choice[..., None], axis=-1)[..., 0]
-            - jax.scipy.special.logsumexp(tlogits, axis=-1)
-        )
+            # penalty rows accept 0 and take the penalty-adjusted sampler
+            # token at position 0; all-pen1 batches skip the extra sampler
+            samp0 = jax.lax.cond(
+                jnp.any(~pen1), penalty_sample, lambda: extra
+            )
+            extra = jnp.where(pen1, extra, samp0)
 
-        def lp0_penalized():
-            # penalty rows sampled position 0 from the penalty-adjusted
-            # distribution — report the logprob they were drawn from,
-            # matching the plain path (review finding, round 5)
-            step0 = apply_repetition_penalty(tlogits[:, 0], seen, penalty)
-            return (jnp.take_along_axis(
-                step0, choice[:, 0][:, None], axis=-1)[:, 0]
-                - jax.scipy.special.logsumexp(step0, axis=-1))
+        with scope("engine"):
+            pos = jnp.arange(K, dtype=jnp.int32)[None, :]
+            choice = jnp.where(
+                pos < n_acc[:, None], drafts,
+                jnp.where(pos == n_acc[:, None], extra[:, None], greedy),
+            )
+            cur2 = extra
+            # [B, K] target logprob of each emitted token (gather - logsumexp,
+            # no [B, K, V] log-softmax materialization)
+            lp_all = (
+                jnp.take_along_axis(
+                    tlogits, choice[..., None], axis=-1)[..., 0]
+                - jax.scipy.special.logsumexp(tlogits, axis=-1)
+            )
 
-        lp0 = jax.lax.cond(
-            jnp.any(penalty != 1.0), lp0_penalized, lambda: lp_all[:, 0]
-        )
-        lp_all = lp_all.at[:, 0].set(
-            jnp.where(penalty != 1.0, lp0, lp_all[:, 0])
-        )
+            def lp0_penalized():
+                # penalty rows sampled position 0 from the penalty-adjusted
+                # distribution — report the logprob they were drawn from,
+                # matching the plain path (review finding, round 5)
+                step0 = apply_repetition_penalty(tlogits[:, 0], seen, penalty)
+                return (jnp.take_along_axis(
+                    step0, choice[:, 0][:, None], axis=-1)[:, 0]
+                    - jax.scipy.special.logsumexp(step0, axis=-1))
 
-        cache = dataclasses.replace(cache, pos=cache.pos - K + n_acc + 1)
-        dcache = dataclasses.replace(dcache, pos=dcache.pos - K + n_acc + 1)
-        rows = jnp.arange(seen.shape[0])
-        # penalty rows emit exactly cur2; spec rows don't read `seen`
-        seen = seen.at[rows, cur2].set(True)
-        return choice, lp_all, n_acc, cur2, cache, dcache, seen
+            lp0 = jax.lax.cond(
+                jnp.any(penalty != 1.0), lp0_penalized, lambda: lp_all[:, 0]
+            )
+            lp_all = lp_all.at[:, 0].set(
+                jnp.where(penalty != 1.0, lp0, lp_all[:, 0])
+            )
+
+            cache = dataclasses.replace(cache, pos=cache.pos - K + n_acc + 1)
+            dcache = dataclasses.replace(
+                dcache, pos=dcache.pos - K + n_acc + 1)
+            rows = jnp.arange(seen.shape[0])
+            # penalty rows emit exactly cur2; spec rows don't read `seen`
+            seen = seen.at[rows, cur2].set(True)
+            return choice, lp_all, n_acc, cur2, cache, dcache, seen
 
     # ---- host API ---------------------------------------------------------
 

@@ -1,21 +1,32 @@
 #!/usr/bin/env python3
-"""Which program do a trace's device operations of a given kind sit in?
+"""Which program, and which scope of it, do a trace's device operations of a
+given kind sit in?
 
-    python scripts/trace_ops_by_program.py <logdir or .xplane.pb> [kind ...]
+    python scripts/trace_ops_by_program.py <logdir or .xplane.pb> [kind ...] [--by scope]
 
 `kind` is a row of the benchmark's `breakdown.device_ops` (`copy`,
 `fusion`, ...; default `copy`). Prints each device's programs (`XLA
-Modules`) by time, then per (kind, program) the events' count, seconds
-and longest, with one event's full name: its shapes and layouts say what
-is being moved. An event belongs to the program execution it starts in.
-After a traced run of a cell the trace is in `.bench_trace/` (PERF.md
-section 5 keeps what this found in `qwen2-7b.chat-closed`).
+Modules`) by time, then per (kind, program), or with `--by scope` per (kind,
+program, scope), the operations' count, self seconds and longest, with one
+operation's name stack (`tf_op`: the scopes it was traced under, down to the
+primitive) and whole instruction: its shapes and layouts say what is being
+moved. `bench/reduce/scopes.py` places every operation (`place`: an
+operation belongs to the execution it starts in, and to the innermost scope of
+`bigdl_tpu/obs/scopes.py` on its name stack); this script only groups what it
+is handed. With no kind and `--by scope` it prints the reducer's own table
+(device time by scope, an execution of `engine_decode`,
+`engine_paged_prefill`, `generate_tokens`).
+
+After a traced run of a cell the trace is in `.bench_trace/`; a server's is
+in the logdir its operator gave `/debug/profiler` (docs/observability.md
+section 4).
 """
 
 from __future__ import annotations
 
-import bisect
+import argparse
 import collections
+import dataclasses
 import os
 import sys
 
@@ -24,48 +35,52 @@ sys.path.insert(0, ROOT)
 
 
 def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("logdir")
+    ap.add_argument("kinds", nargs="*")
+    ap.add_argument("--by", choices=("program", "scope"), default="program")
+    args = ap.parse_args()
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from jax.profiler import ProfileData
 
-    from bench.reduce import xplane
+    from bench.reduce import scopes, xplane
 
-    path = sys.argv[1] if sys.argv[1].endswith(".pb") else \
-        xplane.find_trace(sys.argv[1])
-    kinds = sys.argv[2:] or ["copy"]
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith(xplane.DEVICE_PREFIX):
+    path = args.logdir if args.logdir.endswith(".pb") else \
+        xplane.find_trace(args.logdir)
+    loaded = dataclasses.replace(xplane.load(path, ""), sync=None)
+    metadata = scopes.read_metadata(path)
+    by_mod = collections.defaultdict(lambda: [0, 0.0])
+    for _, name, a, b, _ in scopes.executions(loaded):
+        by_mod[name][0] += 1
+        by_mod[name][1] += b - a
+    print("programs by device time")
+    for name, (n, secs) in sorted(by_mod.items(),
+                                  key=lambda kv: -kv[1][1])[:12]:
+        print(f"  {secs:9.4f} s x{n:<6d} {name}")
+    if not args.kinds and args.by == "scope":
+        lo = min((e.start for evs in loaded.ops.values() for e in evs),
+                 default=0.0)
+        hi = max((e.start + e.dur for evs in loaded.ops.values()
+                  for e in evs), default=0.0)
+        dev = xplane.Reduced(loaded, 0.0, lo, hi)  # the whole trace
+        print("\n".join(scopes.build(dev, metadata).lines()))
+        return 0
+    kinds = args.kinds or ["copy"]
+    acc = collections.defaultdict(lambda: [0, 0.0, 0.0, ""])
+    for op in scopes.place(loaded, metadata):
+        if op.kind not in kinds:
             continue
-        lines = {line.name: list(line.events) for line in plane.lines}
-        mods = sorted((e.start_ns, e.start_ns + e.duration_ns,
-                       e.name.split("(")[0])
-                      for e in lines.get(xplane.MODULES_LINE, []))
-        if not mods:
-            continue
-        by_mod = collections.defaultdict(lambda: [0, 0.0])
-        for lo, hi, name in mods:
-            by_mod[name][0] += 1
-            by_mod[name][1] += (hi - lo) / 1e9
-        print(f"{plane.name}: programs by device time")
-        for name, (n, secs) in sorted(by_mod.items(),
-                                      key=lambda kv: -kv[1][1])[:12]:
-            print(f"  {secs:9.4f} s x{n:<6d} {name}")
-        starts = [m[0] for m in mods]
-        acc = collections.defaultdict(lambda: [0, 0.0, 0.0, ""])
-        for e in lines.get(xplane.OPS_LINE, []):
-            kind = xplane._base(xplane.own_name(e.name))
-            if kind not in kinds:
-                continue
-            i = bisect.bisect_right(starts, e.start_ns) - 1
-            inside = i >= 0 and e.start_ns < mods[i][1]
-            a = acc[kind, mods[i][2] if inside else "<no program>"]
-            a[0] += 1
-            a[1] += e.duration_ns / 1e9
-            if e.duration_ns / 1e6 > a[2]:
-                a[2], a[3] = e.duration_ns / 1e6, e.name[:300]
-        for (kind, mod), (n, secs, longest, name) in sorted(
-                acc.items(), key=lambda kv: -kv[1][1]):
-            print(f"  {secs:9.4f} s x{n:<6d} longest {longest:8.3f} ms  "
-                  f"{kind} in {mod}\n      {name}")
+        key = (op.kind, op.program or "<no program>") + (
+            (op.scope,) if args.by == "scope" else ())
+        a = acc[key]
+        a[0] += 1
+        a[1] += op.self_s
+        if op.dur * 1e3 > a[2]:
+            a[2] = op.dur * 1e3
+            a[3] = f"{op.tf_op}\n      {op.hlo[:300] or op.name}"
+    for key, (n, secs, longest, name) in sorted(
+            acc.items(), key=lambda kv: -kv[1][1]):
+        print(f"  {secs:9.4f} s x{n:<6d} longest {longest:8.3f} ms  "
+              f"{key[0]} in {' / '.join(key[1:])}\n      {name}")
     return 0
 
 
