@@ -519,7 +519,9 @@ def _moe_dispatch_grouped(
     """Dropless dispatch on packed weights: the assignments are sorted
     by expert, each expert's rows padded to a whole row tile, and
     gate/up/down run through the grouped fused dequant kernel
-    (`ops/pallas/moe_qmatmul.py`) with float32 accumulation. Every
+    (`ops/pallas/moe_qmatmul.py`) with float32 accumulation. Where one
+    tile holds the whole call (every decode step) nothing is sorted or
+    copied: each hit expert's tile is the call's rows as they stand. Every
     assignment is computed (the layout has room for all N*k, however
     they fall), an expert nobody chose is never read, and no expert is
     ever dequantized into HBM. Rows are independent from the gather to
@@ -531,14 +533,21 @@ def _moe_dispatch_grouped(
     B, T, H = xc.shape
     E, k, N = config.num_experts, config.num_experts_per_tok, B * T
     wu, wd = p["w_up_e"], p["w_down_e"]
-    block_m = mq.moe_block_m(N, max(H, wu.data.shape[-2]))
-    n_tiles = mq.moe_n_tiles(N, k, E, block_m)
+    block_m = _moe_block_m(xc, p)
     call = functools.partial(mq.moe_qmatmul, block_m=block_m, layer=layer)
 
     with scope("moe.dispatch"):
-        dest, src, tile_expert, n_used = mq.moe_layout(
-            topi.reshape(N, k), E, block_m, n_tiles)
-        xs = xc.reshape(N, H)[src]  # [n_tiles * block_m, H]
+        xs = xc.reshape(N, H)
+        if N <= block_m:  # one tile holds the call: the rows as they stand
+            dest, tile_expert, n_used = mq.moe_layout_shared(
+                topi.reshape(N, k), E, block_m)
+            if N < block_m:  # (a few rows, to the sublane tile)
+                xs = jnp.pad(xs, ((0, block_m - N), (0, 0)))
+        else:
+            dest, src, tile_expert, n_used = mq.moe_layout(
+                topi.reshape(N, k), E, block_m,
+                mq.moe_n_tiles(N, k, E, block_m))
+            xs = xs[src]  # [n_tiles * block_m, H]
         row_expert = jnp.repeat(tile_expert, block_m)
     with scope("moe.experts"):
         if not config.gated_mlp:  # phixtral: biased fc1 -> act -> fc2
@@ -560,6 +569,14 @@ def _moe_dispatch_grouped(
     with scope("moe.combine"):
         out = jnp.sum(y[dest] * topv.reshape(N, k, 1), axis=1)
     return out.astype(compute_dtype).reshape(B, T, H)
+
+
+def _moe_block_m(xc: jax.Array, p: Params) -> int:
+    """The row tile of a layer's grouped calls on `xc [B, T, H]`."""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+
+    B, T, H = xc.shape
+    return mq.moe_block_m(B * T, max(H, p["w_up_e"].data.shape[-2]))
 
 
 def _grouped_plan(config: ModelConfig, p: Params) -> str:
@@ -675,9 +692,10 @@ def _moe_dispatch(
               f"E{config.num_experts} H{H}")
     why = moe_grouped_why_not(p, differentiable)
     if why is None:
+        rows = "shared" if B * T <= _moe_block_m(xc, p) else "sorted"
         routes.note("moe", "pallas:grouped",
                     f"{p['w_up_e'].qtype} {detail} dropless: "
-                    f"{_grouped_plan(config, p)} "
+                    f"{_grouped_plan(config, p)} rows:{rows} "
                     f"scales:{'stack' if _reads_bits(config, p) else 'slice'}")
         return _moe_dispatch_grouped(config, xc, p, compute_dtype, topv,
                                      topi, layer)

@@ -13,6 +13,17 @@ scalar-prefetched table. Nothing here depends on how many rows an expert
 got: group sizes live on the device, 0 is allowed, and no assignment is
 ever dropped (the layout has room for all of them).
 
+Where ONE tile holds the whole call (`N <= block_m`: every decode step,
+`block_m` being the step's rows rounded up to 8) nothing is sorted: a
+tile's `block_m` rows go through the MXU whatever they hold, so every hit
+expert's tile is the call's rows as they stand, in token order
+(`moe_layout_shared`: assignment (n, j) is row n of the tile of expert
+`topi[n, j]`), and `x` is `[block_m, K]`, ONE block whose index is (0, 0)
+at every grid step, fetched once a call. The sorted form would first write
+`[n_tiles * block_m, K]` (8192 rows from a pass's 64) and read it back a
+live tile at a time for the same products, bit for bit. `moe_qmatmul`
+takes the form from `x`'s shape; the output is in tile layout in both.
+
 What a step pays for:
 
 * the tiles in use come first (`n_used` of the static `n_tiles`); a tile
@@ -132,17 +143,41 @@ def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int):
     seen = jnp.cumsum(onehot, axis=0)
     rank = jnp.sum((seen - onehot) * onehot, axis=-1)  # earlier same-expert
     counts = seen[-1]
-    tiles = (counts + block_m - 1) // block_m
-    tile_end = jnp.cumsum(tiles)
-    n_used = tile_end[-1]
-    dest = ((tile_end - tiles)[e_flat] * block_m + rank).astype(jnp.int32)
+    first, tile_expert, n_used = _tiles((counts + block_m - 1) // block_m,
+                                        n_tiles)
+    dest = (first[e_flat] * block_m + rank).astype(jnp.int32)
     tok = jnp.arange(N * k, dtype=jnp.int32) // k
     src = jnp.zeros((n_tiles * block_m,), jnp.int32).at[dest].set(
         tok, unique_indices=True)
+    return dest.reshape(N, k), src, tile_expert, n_used
+
+
+def moe_layout_shared(topi: jax.Array, n_experts: int, block_m: int):
+    """`moe_layout` where ONE tile holds the whole call (`N <= block_m`)
+    and every hit expert's tile is the call's rows as they stand, in token
+    order: `dest[n, j]` is row n of the tile of expert `topi[n, j]`, the
+    tiles numbered over the hit experts in expert order. No rows move, so
+    there is no `src` and nothing to rank: -> (`dest`, `tile_expert`,
+    `n_used`), the last two as `moe_layout` gives them."""
+    N, k = topi.shape
+    assert N <= block_m, (N, block_m)
+    hit = jnp.any(topi.reshape(N * k, 1).astype(jnp.int32) == jnp.arange(
+        n_experts, dtype=jnp.int32)[None], axis=0).astype(jnp.int32)
+    first, tile_expert, n_used = _tiles(
+        hit, moe_n_tiles(N, k, n_experts, block_m))
+    dest = first[topi] * block_m + jnp.arange(N, dtype=jnp.int32)[:, None]
+    return dest, tile_expert, n_used
+
+
+def _tiles(tiles: jax.Array, n_tiles: int):
+    """From the tiles each expert needs `[E]`: the tile each expert's first
+    is, `tile_expert [n_tiles]` and `n_used` (`moe_layout`)."""
+    tile_end = jnp.cumsum(tiles)
+    n_used = tile_end[-1]
     m = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), n_used - 1)
     tile_expert = jnp.sum(
         (tile_end[None, :] <= m[:, None]).astype(jnp.int32), axis=-1)
-    return dest.reshape(N, k), src, tile_expert, n_used
+    return tile_end - tiles, tile_expert, n_used
 
 
 def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
@@ -220,13 +255,14 @@ def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
     """``prepared``: the side arrays are `qdecode.pack_major_bits` of the
     call's word tiles, `[L, E, O / rows, nb, rows]`, and a grid step's
     block is the `[held, nb, rows]` of the tiles it holds."""
-    Mp, K = x.shape
+    K = x.shape[-1]
+    Mp = tile_expert.shape[0] * block_m
     O = arrays[0].shape[-2]
     n_o = O // block_o
     per = 1 + spec.n_side
 
-    def x_map(m, o, te, meta):
-        return (jnp.minimum(m, meta[0] - 1), 0)
+    def x_map(m, o, te, meta):  # shared rows: the one block, fetched once
+        return (jnp.minimum(m, meta[0] - 1) if x.shape[0] == Mp else 0, 0)
 
     def w_map(has_layer):  # a dead tile names the block already held
         return lambda m, o, te, meta: (
@@ -271,7 +307,8 @@ def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
 
 
 def moe_qmatmul(
-    x: jax.Array,  # [n_tiles * block_m, K] rows sorted by expert
+    x: jax.Array,  # [n_tiles * block_m, K] rows sorted by expert, or
+    # [block_m, K]: the call's rows in token order, every tile's alike
     ws,  # one QTensor stack [E, O, K], or the (gate, up) pair of a gated
     # FFN; with `layer`, any field may be rank 4 and is indexed by it
     tile_expert: jax.Array,  # [n_tiles] int32 (moe_layout)
@@ -296,7 +333,8 @@ def moe_qmatmul(
                for w in ws)
     spec = qdecode.spec_for(w0.spec)
     K = x.shape[-1]
-    assert x.shape[0] == tile_expert.shape[0] * block_m, (x.shape, block_m)
+    assert x.shape[0] in (block_m, tile_expert.shape[0] * block_m), (
+        x.shape, block_m)
 
     n_w = len(ws)
     form, rows, held, persist_row = _plan(ws)

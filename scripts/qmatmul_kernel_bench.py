@@ -6,6 +6,7 @@ through the chip tool:
 
     python scripts/qmatmul_kernel_bench.py [--plan cells|mistral|quick]
     python scripts/qmatmul_kernel_bench.py --plan experts   # the grouped kernel
+    python scripts/qmatmul_kernel_bench.py --plan experts --variants tree shared
     python scripts/qmatmul_kernel_bench.py --lower   # compile only, no chip
 
 Each line is one (body, variant, K, O, M): 64 dependent calls inside one
@@ -46,7 +47,10 @@ Bodies:
 variant): `hit` experts of E each with one row tile, as a decode step has
 them. It prints us a hit expert, the share of HBM time, and what a grid
 step costs over its bytes' time (`step_over_us`). Variants, in
-`experts_kernel` below: `tree` the kernel as it stands; `loop` the
+`experts_kernel` below: `tree` the kernel as it stands, rows sorted by
+expert (an x tile a live expert); `shared` the same on the step's rows as
+they stand, one `[block_m, K]` block every tile reads (what a decode step's
+gate / up call runs since PR 53: the difference is the x re-fetch); `loop` the
 stored-layout loop at 256-row tiles (what a 768-wide gated call ran before
 PR 44); `fetch` nothing computed; `paired` the gated call's two 256-row
 blocks decoded as ONE 512-row word tile; `mb` / `one` several word tiles a
@@ -488,6 +492,8 @@ EXPERT_SHAPES = {
     "smallthinker": (64, 6, 16, 50, ((2560, 768, True), (768, 2560, False))),
     "glm": (64, 4, 32, 55, ((2048, 1536, True), (1536, 2048, False))),
     "mixtral": (8, 2, 16, 8, ((4096, 14336, True), (14336, 4096, False))),
+    # SDAR's pass: 64 rows (16 blocks of 4), 85 live tiles a layer (PR 53)
+    "sdar": (128, 8, 64, 85, ((2048, 768, True), (768, 2048, False))),
 }
 STEP_BYTES = 1 << 20  # `mb`: codes a grid step may hold, all stacks
 
@@ -634,7 +640,8 @@ def experts_tiles(variant, K, O, n_w):
     return rows * t, t, words_chunk(qmin, SPEC.block)
 
 
-def experts_operands(name, K, O, gated, key, sharding=None):
+def experts_operands(name, K, O, gated, key, sharding=None, shared=False):
+    """``shared``: x is the step's rows as they stand, `[block_m, K]`."""
     from bigdl_tpu.ops.pallas import moe_qmatmul as mq
 
     E, k, N, hit, shapes = EXPERT_SHAPES[name]
@@ -643,7 +650,7 @@ def experts_operands(name, K, O, gated, key, sharding=None):
     n_w = 2 if gated else 1
     L = 2
     shapes_ = ([((n_tiles,), jnp.int32), ((2,), jnp.int32),
-                ((n_tiles * block_m, K), jnp.bfloat16)]
+                (((1 if shared else n_tiles) * block_m, K), jnp.bfloat16)]
                + [((L, E, O, K // 2), jnp.uint8),
                   ((E, O, K // 32), jnp.uint16)] * n_w)
     if sharding is not None:
@@ -672,7 +679,9 @@ def experts_build(variant, name, K, O, gated):
     n_w = 2 if gated else 1
     E, k, N, hit, shapes = EXPERT_SHAPES[name]
     block_m = mq.moe_block_m(N, max(max(s[0], s[1]) for s in shapes))
-    if variant == "tree":
+    if variant == "shared" and not gated:
+        return None  # the down call's rows are the gate / up call's tiles
+    if variant in ("tree", "shared"):  # (the form is x's shape)
         plan = None
 
         def call(te, meta, x, *arrays):
@@ -712,7 +721,8 @@ def experts_measure(variant, name, K, O, gated, key, ns=(16, 32, 64), reps=3):
     if built is None:
         return None
     run, _, plan = built
-    block_m, hit, args = experts_operands(name, K, O, gated, key)
+    block_m, hit, args = experts_operands(name, K, O, gated, key,
+                                          shared=variant == "shared")
     jax.block_until_ready(run(2, *args))
     ts = []
     for n in ns:
@@ -764,8 +774,8 @@ def experts_plan():
     for name, (_, _, _, _, shapes) in EXPERT_SHAPES.items():
         for K, O, gated in shapes:
             plan += [(v, name, K, O, gated)
-                     for v in ("tree", *COPIES, "loop", "fetch", "paired",
-                               "mb", "one")]
+                     for v in ("tree", "shared", *COPIES, "loop", "fetch",
+                               "paired", "mb", "one")]
     return plan
 
 
@@ -828,10 +838,14 @@ def main() -> int:
                     choices=("cells", "mistral", "quick", "forms", "experts"))
     ap.add_argument("--lower", "--fit", action="store_true",
                     help="compile the plan for a described v5e; no chip")
+    ap.add_argument("--variants", nargs="+",
+                    help="of --plan experts: these variants alone")
     ap.add_argument("--out", default="chiprun_out/qmatmul_kernel_bench.jsonl")
     args = ap.parse_args()
     experts = args.plan == "experts"
     plan = experts_plan() if experts else plan_of(args.plan)
+    if experts and args.variants:
+        plan = [case for case in plan if case[0] in args.variants]
 
     if args.lower:
         from jax.experimental import topologies
@@ -844,7 +858,8 @@ def main() -> int:
             if built is None:
                 print(f"skip {v} {name} K={K} O={O}: no such form")
                 continue
-            _, _, args_ = experts_operands(name, K, O, gated, None, one)
+            _, _, args_ = experts_operands(name, K, O, gated, None, one,
+                                           shared=v == "shared")
             built[0].lower(jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
                            *args_).compile()
             print(f"ok {v} {name} K={K} O={O} {built[2]}", flush=True)

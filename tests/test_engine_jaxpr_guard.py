@@ -37,6 +37,7 @@ what they counted.
     JAX_PLATFORMS=cpu python tests/test_engine_jaxpr_guard.py
 """
 
+import functools
 import os
 import sys
 
@@ -124,6 +125,20 @@ def n_eqns(jaxpr) -> int:
     return n
 
 
+def decode_args(eng, params) -> tuple:
+    """`engine_decode`'s arguments for a four-slot engine."""
+    import jax
+    import jax.numpy as jnp
+
+    B, z = 4, jnp.zeros
+    sampling = (z((B,)), z((B,), jnp.int32), z((B,)), z((B,), bool))
+    if eng.blocks is not None:  # a pass over every row's block
+        return (params, eng.blocks.state, eng.cache, jax.random.PRNGKey(0),
+                *sampling)
+    return (params, z((B,), jnp.int32), eng.cache, jax.random.PRNGKey(0),
+            *sampling, eng.seen, z((B,)))
+
+
 def counts(name: str) -> tuple:
     import jax
     import jax.numpy as jnp
@@ -139,17 +154,10 @@ def counts(name: str) -> tuple:
         cfg, "sym_int4")
     eng = InferenceEngine(TpuModel(cfg, params, "sym_int4"), n_slots=4,
                           max_len=256, paged=True, page_size=16, n_pages=65)
-    B, c, z = 4, eng.cache, jnp.zeros
+    c, z = eng.cache, jnp.zeros
     table = z((1, eng.max_pages_per_row), jnp.int32)
-    sampling = (z((B,)), z((B,), jnp.int32), z((B,)), z((B,), bool))
-    if eng.blocks is not None:  # a pass over every row's block
-        decode = (params, eng.blocks.state, c, jax.random.PRNGKey(0),
-                  *sampling)
-    else:
-        decode = (params, z((B,), jnp.int32), c, jax.random.PRNGKey(0),
-                  *sampling, eng.seen, z((B,)))
     programs = (
-        (eng._decode, decode),
+        (eng._decode, decode_args(eng, params)),
         (eng._paged_prefill, (
             params, eng.kind.leaves(c), (table, table), z((1,), jnp.int32),
             z((1, 64), jnp.int32), z((), jnp.int32), z((1,), jnp.int32))))
@@ -179,11 +187,29 @@ GUARDED = {
     "mistral": dict(MODELS["mistral"], **_WIDE),
     "mixtral": dict(MODELS["mixtral"], **_WIDE),
 }
+# what `kernel_route_jaxpr` traces: the tiny models above, the two guarded
+# ones at their wider sizes, and a granite-4.0-h wide enough for the grouped
+# kernel (the preset's 64 is not)
+KERNEL_MODELS = dict(
+    MODELS, **GUARDED,
+    granitemoehybrid=dict(
+        model_type="granitemoehybrid", vocab_size=256, hidden_size=128,
+        intermediate_size=128, shared_intermediate_size=128,
+        num_hidden_layers=5,
+        layer_types=["mamba", "mamba", "attention", "mamba", "mamba"],
+        num_attention_heads=4, num_key_value_heads=2, num_local_experts=8,
+        num_experts_per_tok=3, mamba_n_heads=4, mamba_d_head=32,
+        mamba_d_state=16, mamba_d_conv=4, mamba_expand=1, mamba_n_groups=1,
+        mamba_chunk_size=8, embedding_multiplier=12,
+        residual_multiplier=0.22, attention_multiplier=0.0625,
+        logits_scaling=4, position_embedding_type="nope",
+        tie_word_embeddings=True, rms_norm_eps=1e-5))
 # equations of `engine_decode` with the kernels on (the interpreter's route):
-# (the tree as `optimize_model` makes it, the tree prepared)
+# (the tree as `optimize_model` makes it, the tree prepared). Mixtral's were
+# (531, 523) until ISSUE 53 took the sort out of a decode step's dispatch
 KERNEL_ROUTE = {
     "mistral": (431, 427),
-    "mixtral": (531, 523),
+    "mixtral": (487, 479),
 }
 
 
@@ -202,12 +228,13 @@ def _eqns(jaxpr, inside_scan=False):
                         sub, inside_scan or e.primitive.name == "scan")
 
 
-def kernel_route_jaxpr(hf: dict, prepare: bool = True):
-    """`engine_decode` of a four-slot paged engine on `hf`, traced with
-    whatever route the environment gives the kernels: the tree prepared
-    for serving, or as `optimize_model` makes it."""
+@functools.lru_cache(maxsize=None)
+def kernel_route_jaxpr(name: str, prepare: bool = True):
+    """`engine_decode` of a four-slot paged engine on `KERNEL_MODELS[name]`,
+    traced with whatever route the environment gives the kernels (every
+    caller sets the interpreter's): the tree prepared for serving, or as
+    `optimize_model` makes it. Traced once a process."""
     import jax
-    import jax.numpy as jnp
 
     from bigdl_tpu.api import TpuModel, optimize_model
     from bigdl_tpu.models import get_family
@@ -215,18 +242,15 @@ def kernel_route_jaxpr(hf: dict, prepare: bool = True):
     from bigdl_tpu.quant.qtensor import without_scale_bits
     from bigdl_tpu.serving.engine import InferenceEngine
 
-    cfg = ModelConfig.from_hf_config(hf)
+    cfg = ModelConfig.from_hf_config(KERNEL_MODELS[name])
     model = TpuModel(cfg, optimize_model(
         get_family(cfg.model_type).init_params(cfg, jax.random.PRNGKey(0)),
         cfg, "sym_int4"), "sym_int4")
     params = model.params if prepare else without_scale_bits(model.params)
     eng = InferenceEngine(model, n_slots=4, max_len=256, paged=True,
                           page_size=16, n_pages=65)
-    B, z = 4, jnp.zeros
-    return jax.make_jaxpr(eng._decode.__wrapped__)(
-        params, z((B,), jnp.int32), eng.cache, jax.random.PRNGKey(0),
-        z((B,)), z((B,), jnp.int32), z((B,)), z((B,), bool), eng.seen,
-        z((B,))).jaxpr
+    return jax.make_jaxpr(getattr(eng._decode, "__wrapped__", eng._decode))(
+        *decode_args(eng, params)).jaxpr
 
 
 def scale_work(name: str, prepare: bool) -> tuple:
@@ -235,7 +259,7 @@ def scale_work(name: str, prepare: bool) -> tuple:
     slice a 16-bit operand, the layer stacks the scan is handed whole)."""
     import jax.numpy as jnp
 
-    jaxpr = kernel_route_jaxpr(GUARDED[name], prepare)
+    jaxpr = kernel_route_jaxpr(name, prepare)
     sixteen = (jnp.float16, jnp.uint16)
     work, n, whole = [], 0, 0
     for e, in_scan in _eqns(jaxpr):
@@ -274,13 +298,13 @@ def test_a_prepared_trees_decode_scan_neither_views_nor_slices_a_scale(
 # PR 50's tree, and 930 since PR 51 gave the latent decode kernel its
 # sibling's scalar operand (`paged_attention._scalars`: the window's entry, one
 # equation a call, two calls a step). The XLA-route pin above did not move.
-GLM_KERNEL_ROUTE = 930
+# 886 since ISSUE 53: `MOE_KERNEL_ROUTE` below has every MoE family's.
 
 
 def glm_kernel_route() -> tuple:
     """(equations of GLM's `engine_decode` outside the kernels' bodies, the
     grid of each `paged_latent_decode_attention` call in it)."""
-    jaxpr = kernel_route_jaxpr(MODELS["glm4_moe_lite"])
+    jaxpr = kernel_route_jaxpr("glm4_moe_lite")
     eqns = [e for e, _ in _eqns(jaxpr)]
     return len(eqns), [
         e.params["grid_mapping"].grid for e in eqns
@@ -294,7 +318,90 @@ def test_glms_decode_step_calls_the_latent_kernel_a_grid_step_a_row(
     monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
     n, grids = glm_kernel_route()
     assert grids == [(4,), (4,)]  # the dense layer's scan, the experts'
-    assert n == GLM_KERNEL_ROUTE
+    assert n == MOE_KERNEL_ROUTE["glm4_moe_lite"]
+
+
+# ---- a decode step's experts read the step's rows where they lie (ISSUE 53)
+#
+# Until PR 53 every MoE layer of every decode step gathered the step's N rows
+# into the grouped kernel's sorted layout, `[n_tiles * block_m, H]` for N * k
+# assignments (SDAR: 8192 rows from 64, 33.5 MB written a layer; `moe.dispatch`
+# 2.77 ms of a 24 ms pass, PERF.md section 5). One row tile holds a whole
+# decode step, so each hit expert's tile is the step's rows as they stand and
+# nothing is sorted or copied. The guard: with the kernels on, no gather of
+# the decode program has a result of more than N * k rows of width H (the
+# combine's `y[dest]` has exactly that many), and the programs are SHORTER
+# than the parent's (equations outside the kernels' bodies; the parent's in
+# the comment), none longer.
+MOE_KERNEL_ROUTE = {
+    "mixtral": 479,  # 523
+    "glm4_moe_lite": 886,  # 930
+    "smallthinker": 1373,  # 1549
+    "laguna": 2916,  # 3224
+    "sdar_moe": 546,  # 593
+    "granitemoehybrid": 1248,  # 1380
+}
+
+
+def wide_row_gathers(jaxpr, H: int, most: int) -> list:
+    """The gathers of `jaxpr` (kernels' bodies apart) whose result has more
+    than `most` rows of width `H`."""
+    import math
+
+    return [e.outvars[0].aval.str_short() for e, _ in _eqns(jaxpr)
+            if e.primitive.name == "gather"
+            and e.outvars[0].aval.shape[-1:] == (H,)
+            and math.prod(e.outvars[0].aval.shape[:-1]) > most]
+
+
+@pytest.mark.core
+@pytest.mark.parametrize("name", sorted(MOE_KERNEL_ROUTE))
+def test_a_moe_decode_step_gathers_no_padded_copy_of_its_rows(monkeypatch,
+                                                              name):
+    from bigdl_tpu.models.config import ModelConfig
+
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+    cfg = ModelConfig.from_hf_config(KERNEL_MODELS[name])
+    jaxpr = kernel_route_jaxpr(name)
+    N = 4 * max(1, cfg.block_length)  # the slots, or SDAR's blocks of 4
+    assert wide_row_gathers(
+        jaxpr, cfg.hidden_size, N * cfg.num_experts_per_tok) == []
+    assert sum(1 for _ in _eqns(jaxpr)) == MOE_KERNEL_ROUTE[name]
+
+
+@pytest.mark.core
+def test_the_gather_guard_sees_the_sorted_forms_copy(monkeypatch):
+    """What the guard guards against, in the one place that keeps it: a
+    call of more rows than a tile holds (a prefill above 256 tokens) sorts
+    its rows into `[n_tiles * block_m, H]`."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.models import llama
+    from bigdl_tpu.models.config import ModelConfig
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+    cfg = ModelConfig.from_hf_config(KERNEL_MODELS["mixtral"])
+    E, k, H, I = cfg.num_experts, cfg.num_experts_per_tok, 256, 512
+
+    def stack(O, K):
+        return QTensor(qtype="sym_int4", data=jnp.zeros((E, O, K // 2),
+                                                        jnp.uint8),
+                       scales=jnp.zeros((E, O, K // 32), jnp.float16))
+
+    p = {"w_gate_e": stack(I, H), "w_up_e": stack(I, H),
+         "w_down_e": stack(H, I)}
+
+    def gathers(N):
+        jaxpr = jax.make_jaxpr(lambda x, v, i: llama._moe_dispatch_grouped(
+            cfg, x, p, jnp.bfloat16, v, i))(
+            jnp.zeros((1, N, H), jnp.bfloat16), jnp.zeros((1, N, k)),
+            jnp.zeros((1, N, k), jnp.int32)).jaxpr
+        return wide_row_gathers(jaxpr, H, N * k)
+
+    assert gathers(256) == []
+    assert gathers(264) == ["bfloat16[1536,256]"]  # 6 tiles of 256 rows
 
 
 if __name__ == "__main__":
@@ -305,4 +412,6 @@ if __name__ == "__main__":
     for name in sorted(GUARDED):
         print(f"    {name!r}: ({scale_work(name, False)[0]}, "
               f"{scale_work(name, True)[0]}),  # KERNEL_ROUTE", flush=True)
-    print(f"GLM_KERNEL_ROUTE = {glm_kernel_route()[0]}", flush=True)
+    for name in sorted(MOE_KERNEL_ROUTE):
+        n = sum(1 for _ in _eqns(kernel_route_jaxpr(name)))
+        print(f"    {name!r}: {n},  # MOE_KERNEL_ROUTE", flush=True)

@@ -346,16 +346,18 @@ ROUTINGS = {
 }
 
 
-@pytest.mark.parametrize("routing,act", [
-    *((r, "silu") for r in ROUTINGS),
-    ("uniform", "gelu_pytorch_tanh"),  # not fused: two calls and XLA
+@pytest.mark.parametrize("routing,act,N", [
+    *((r, "silu", 48) for r in ROUTINGS),
+    ("uniform", "gelu_pytorch_tanh", 48),  # not fused: two calls and XLA
+    # more rows than a tile holds: sorted by expert (48 rows are one tile,
+    # which every hit expert reads as it stands: ISSUE 53)
+    ("three-experts-idle", "silu", 264),
 ])
 def test_grouped_dispatch_drops_nothing(interpret, tiny_mixtral, routing,
-                                        act):
+                                        act, N):
     cfg, params = tiny_mixtral
     cfg = dataclasses.replace(cfg, hidden_act=act)
     p = _layer0(params)
-    N = 48
     topi = jnp.asarray(ROUTINGS[routing](N, cfg.num_experts), jnp.int32)
     topv = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(5), (N, 2)))
     x = jax.random.normal(jax.random.PRNGKey(4), (N, cfg.hidden_size)
@@ -368,6 +370,114 @@ def test_grouped_dispatch_drops_nothing(interpret, tiny_mixtral, routing,
                                rtol=0, atol=1e-3)
 
 
+# (e) a call that one row tile holds (`N <= block_m`: every decode step)
+# hands the kernel its rows as they stand, one `[block_m, K]` block every hit
+# expert's tile reads, and sorts nothing (ISSUE 53). Every assignment's row
+# is the same bf16 row through the same decode, product and accumulation as
+# in its expert's sorted tile, so the two forms agree BIT FOR BIT.
+_SHARED_SHAPES = [(64, 8, 128), (32, 4, 64), (16, 8, 256), (5, 2, 8)]
+# (O, K, stacks, layered): the single stack, the fused `words` pair, the
+# `words:paired` 768-wide pair, and a pair that keeps its layer axis and
+# reads prepared scale bits by (layer, expert)
+_SHARED_STACKS = {
+    "stack": (512, 256, 1, False),
+    "pair": (512, 256, 2, False),
+    "paired-768": (768, 256, 2, False),
+    "layered-pair": (512, 256, 2, True),
+}
+
+
+def _random_stack(key, shape, K):
+    """A sym_int4 stack of random codes and scales (no float32 original:
+    256 experts of it are 25 MB)."""
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    kd, ks = jax.random.split(key)
+    return QTensor(
+        qtype="sym_int4",
+        data=jax.random.bits(kd, (*shape, K // 2), jnp.uint8),
+        scales=jax.random.uniform(ks, (*shape, K // 32), jnp.float32,
+                                  0.002, 0.01).astype(jnp.float16))
+
+
+def _shared_case(N, k, E, routing, H):
+    """(x [N, H] bf16, topi [N, k], the rows whose outputs count). Expert 1
+    is chosen by nobody; row 1 is an idle slot that holds NaN and is routed
+    like any other; `one-expert` sends every assignment to expert 2."""
+    rng = np.random.default_rng(N)
+    if routing == "one-expert":
+        topi = np.full((N, k), 2)
+    else:
+        others = np.delete(np.arange(E), 1)
+        topi = np.stack([rng.permutation(others)[:k] for _ in range(N)])
+    x = jax.random.normal(jax.random.PRNGKey(N), (N, H)).astype(jnp.bfloat16)
+    live = np.arange(N) != 1
+    return (jnp.where(live[:, None], x, jnp.nan),
+            jnp.asarray(topi, jnp.int32), live)
+
+
+@pytest.mark.parametrize("routing", ["idle-expert", "one-expert"])
+@pytest.mark.parametrize("stacks", [*_SHARED_STACKS, "dispatch"])
+@pytest.mark.parametrize("N,k,E", _SHARED_SHAPES)
+def test_shared_rows_equal_sorted_rows_bit_for_bit(interpret, N, k, E,
+                                                   stacks, routing):
+    from bigdl_tpu.ops.linear import prepare_scale_bits
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+
+    if stacks == "dispatch":  # the whole layer against float32
+        H, I = 256, 512
+        cfg = dataclasses.replace(
+            ModelConfig.from_hf_config(TINY_MIXTRAL), num_experts=E,
+            num_experts_per_tok=k)
+        keys = jax.random.split(jax.random.PRNGKey(E), 3)
+        p = {"w_gate_e": _random_stack(keys[0], (E, I), H),
+             "w_up_e": _random_stack(keys[1], (E, I), H),
+             "w_down_e": _random_stack(keys[2], (E, H), I)}
+        x, topi, live = _shared_case(N, k, E, routing, H)
+        topv = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(5),
+                                                (N, k)))
+        assert N <= llama._moe_block_m(x[None], p)
+        want = np.asarray(_expected_block(p, x, topv, topi))[live]
+        got = np.asarray(llama._moe_dispatch_grouped(
+            cfg, x[None], p, jnp.bfloat16, topv[None], topi[None])[0],
+            np.float32)[live]
+        # TOLERANCE 1% of the largest output (0.2 to 0.35): `z` and the
+        # result are bf16, 2^-9 each; measured 0.35 to 0.55%. An assignment
+        # dropped or sent to another expert's tile is off by its whole term,
+        # a k-th of the output: 0.03 to 0.1 at its largest element.
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=0.01 * np.abs(want).max())
+        return
+    O, K, n_w, layered = _SHARED_STACKS[stacks]
+    ws = [_random_stack(jax.random.PRNGKey(i), (2, E, O) if layered
+                        else (E, O), K) for i in range(n_w)]
+    if layered:
+        ws = [prepare_scale_bits(w, n_w) for w in ws]
+    bm = mq.moe_block_m(N, max(K, O))
+    assert bm == -(-N // 8) * 8
+    x, topi, live = _shared_case(N, k, E, routing, K)
+    call = functools.partial(
+        mq.moe_qmatmul, ws=ws if n_w == 2 else ws[0], block_m=bm,
+        layer=jnp.asarray(1) if layered else None, out_dtype=jnp.float32,
+        **(dict(act="silu") if n_w == 2 else {}))
+    dest, src, te, n_used = mq.moe_layout(
+        topi, E, bm, mq.moe_n_tiles(N, k, E, bm))
+    sorted_y = call(x[src], tile_expert=te, n_used=n_used)[dest]
+    dest2, te2, n_used2 = mq.moe_layout_shared(topi, E, bm)
+    if routing == "idle-expert":  # one tile an expert in either form
+        assert int(n_used2) == int(n_used) == len(np.unique(topi))
+        np.testing.assert_array_equal(np.asarray(te2), np.asarray(te))
+        assert 1 not in np.asarray(te2)
+    else:
+        assert int(n_used2) == 1 and int(n_used) == -(-N * k // bm)
+    shared_y = call(jnp.pad(x, ((0, bm - N), (0, 0))), tile_expert=te2,
+                    n_used=n_used2)[dest2]
+    assert shared_y.shape == (N, k, O)
+    assert np.isfinite(np.asarray(shared_y)[live]).all()
+    assert np.isnan(np.asarray(shared_y)[1]).all()
+    np.testing.assert_array_equal(np.asarray(shared_y), np.asarray(sorted_y))
+
+
 @pytest.mark.parametrize("act,gated,want", [
     ("silu", True, "gate_up words:inplace:paired x1 of 3 tiles, "
                    "down words:inplace x1 of 8 tiles"),
@@ -378,10 +488,18 @@ def test_grouped_dispatch_drops_nothing(interpret, tiny_mixtral, routing,
     ("gelu_pytorch_tanh", True, "gate, up loop x3, down words:inplace x1 of 8 tiles"),
     ("gelu_new", False, "up loop x3, down words:inplace x1 of 8 tiles"),
 ])
-def test_grouped_route_note_names_each_calls_tile_plan(act, gated, want):
+# a step's rows, a prefill bucket one tile still holds, and the first row
+# past it: the sorted form begins where a second tile does
+@pytest.mark.parametrize("N,rows", [(32, "shared"), (5, "shared"),
+                                    (256, "shared"), (257, "sorted"),
+                                    (2048, "sorted")])
+def test_grouped_route_note_names_each_calls_tile_plan(interpret, act, gated,
+                                                       want, N, rows):
     """The route note of a grouped MoE layer says which loop each of its
-    calls takes and the grid steps an expert (granite's 4096 x 768 experts:
-    nothing computed, the plan is static)."""
+    calls takes, the grid steps an expert, and whether the calls read the
+    layer's rows as they stand or sorted by expert (granite's 4096 x 768
+    experts: nothing computed, the plan is static)."""
+    from bigdl_tpu.ops.routes import record_routes
     from bigdl_tpu.quant.qtensor import QTensor
 
     def stack(O, K):
@@ -390,11 +508,23 @@ def test_grouped_route_note_names_each_calls_tile_plan(act, gated, want):
                        scales=jax.ShapeDtypeStruct((2, O, K // 32),
                                                    jnp.float16))
 
-    cfg = dataclasses.replace(ModelConfig.from_hf_config(TINY_MIXTRAL),
-                              hidden_act=act, gated_mlp=gated)
+    cfg = dataclasses.replace(
+        ModelConfig.from_hf_config(TINY_MIXTRAL), hidden_act=act,
+        gated_mlp=gated, num_experts=2, num_experts_per_tok=1)
     p = {"w_gate_e": stack(768, 4096), "w_up_e": stack(768, 4096),
          "w_down_e": stack(4096, 768)}
     assert llama._grouped_plan(cfg, p) == want
+    with record_routes() as routes:
+        out = jax.eval_shape(
+            lambda x, p, v, i: llama._moe_dispatch(
+                cfg, x, p, jnp.bfloat16, v, i),
+            jax.ShapeDtypeStruct((1, N, 4096), jnp.bfloat16), p,
+            jax.ShapeDtypeStruct((1, N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, N, 1), jnp.int32))
+    assert out.shape == (1, N, 4096)
+    assert list(routes) == [(
+        "moe", "pallas:grouped", f"sym_int4 N{N} k1 E2 H4096 dropless: "
+        f"{want} rows:{rows} scales:slice")], routes
 
 
 def test_ragged_dispatch_drops_where_grouped_does_not(tiny_mixtral):

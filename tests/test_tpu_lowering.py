@@ -377,12 +377,34 @@ _PREPARED_EXPERTS = {
     "laguna-down-prefill": (256, 8, 512, 2048, None, 8192),
     "mixtral-gate_up": (8, 2, 4096, 14336, "silu", 16),
     "glm-gate_up": (64, 4, 2048, 1536, "silu", 32),
+    # SDAR's pass: 64 rows, 512 assignments over 128 experts (ISSUE 53)
+    "sdar-gate_up": (128, 8, 2048, 768, "silu", 64),
+    "sdar-down": (128, 8, 768, 2048, None, 64),
 }
+# the gate / up call of a step one row tile holds: since ISSUE 53 it is handed
+# the step's rows as they stand, `[block_m, K]`, beside the sorted form the
+# prefill (and whoever calls the kernel with sorted rows) keeps
+_SHARED_ROWS = [name for name, (*_, act, N) in _PREPARED_EXPERTS.items()
+                if act and N <= 256]
 
 
-@pytest.mark.parametrize("name", list(_PREPARED_EXPERTS))
+def _x_index_map(mosaic_body: str) -> str:
+    """The function Mosaic is handed for the `x` operand's block index."""
+    funcs = mosaic_body.split('"stable_mosaic.func.func"')
+    (fn,) = [f for f in funcs if 'sym_name = "transform_0"' in f]
+    return fn
+
+
+@pytest.mark.parametrize("name,rows", [
+    *((name, "sorted") for name in _PREPARED_EXPERTS),
+    *((name, "shared") for name in _SHARED_ROWS)])
 def test_moe_qmatmul_compiles_on_prepared_scale_bits(one_chip, monkeypatch,
-                                                     name):
+                                                     name, rows):
+    """Mosaic takes the grouped call on prepared bits in either form of
+    `x`: rows sorted by expert, a tile each, its block index read from the
+    live-tile count; or the call's rows as they stand, ONE block whose index
+    is a constant, so the pipeline fetches it once a call and not once a
+    live tile."""
     from bigdl_tpu.ops.pallas import moe_qmatmul as mq
     from bigdl_tpu.quant.qtensor import QTensor
 
@@ -403,12 +425,18 @@ def test_moe_qmatmul_compiles_on_prepared_scale_bits(one_chip, monkeypatch,
                               act=act, layer=layer, interpret=False,
                               out_dtype=jnp.bfloat16 if act else jnp.float32)
 
-    c = jax.jit(f).lower(
-        _sds((n_tiles * bm, K), jnp.bfloat16, one_chip),
+    assert len(_SHARED_ROWS) == 5 and (rows == "sorted" or N == bm)
+    lowered = jax.jit(f).lower(
+        _sds(((n_tiles if rows == "sorted" else 1) * bm, K), jnp.bfloat16,
+             one_chip),
         _sds((n_tiles,), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
-        *ws).compile()
+        *ws)
+    (body,) = _mosaic_bodies(lowered.as_text())
+    assert ("memref.load" in _x_index_map(body)) == (rows == "sorted")
+    c = lowered.compile()
     assert "moe_qmatmul" in _no_scale_is_moved(c)
+    assert c.out_info.shape == (n_tiles * bm, O)
 
 
 # ---- a sym_int4 nibble cut out of its word signed (ISSUE 49) ----------------
