@@ -302,6 +302,10 @@ class TpuModel:
                 f"blocks of {self.config.block_length}: serve it through "
                 "InferenceEngine(paged=True) (serving/blocks.py); "
                 f"{what} decodes autoregressively and does not run it")
+        why = getattr(self.family, "GENERATE_REFUSAL", None)
+        if why:  # a family served by the paged engine alone says why
+            raise NotImplementedError(
+                f"{self.config.model_type}: {why}; {what} does not run it")
 
     def generate(
         self,

@@ -1249,6 +1249,48 @@ def _granitemoehybrid_tree(config: ModelConfig, get: Get, quant
     return runs, top
 
 
+def _minicpm_sala_tree(config: ModelConfig, get: Get, quant
+                       ) -> tuple[list, dict]:
+    """MiniCPM-SALA. Returns (one list of per-layer dicts for each RUN of
+    layers of one kind, top), `quant` applied as tensors stream in. The
+    tensor names are WRITTEN FROM MEMORY of the source's
+    modeling_minicpm_sala.py, which is not in the repository (a checkpoint
+    that names them otherwise fails here by the missing name, not in
+    silence): both mixers under `self_attn.` with `q_proj`, `k_proj`,
+    `v_proj`, `o_proj`, the output gate `o_gate`, the per-head norms
+    `q_norm` / `k_norm` and, on a lightning layer, `o_norm`. The head's rows
+    are padded with zeros to whole lane tiles (the logits are sliced back
+    to the vocabulary)."""
+    from bigdl_tpu.models.minicpm_sala import LIGHTNING, layer_runs
+
+    def one(i: int, kind: str) -> dict:
+        p, a = f"model.layers.{i}.", f"model.layers.{i}.self_attn."
+        d = {"attn_norm": get(p + "input_layernorm.weight"),
+             "mlp_norm": get(p + "post_attention_layernorm.weight"),
+             "q_norm": get(a + "q_norm.weight"),
+             "k_norm": get(a + "k_norm.weight"),
+             "wq": get(a + "q_proj.weight"), "wk": get(a + "k_proj.weight"),
+             "wv": get(a + "v_proj.weight"), "wg": get(a + "o_gate.weight"),
+             "wo": get(a + "o_proj.weight"),
+             "w_gate": get(p + "mlp.gate_proj.weight"),
+             "w_up": get(p + "mlp.up_proj.weight"),
+             "w_down": get(p + "mlp.down_proj.weight")}
+        if kind == LIGHTNING:
+            d["o_norm"] = get(a + "o_norm.weight")
+        return {k: quant(k, v) for k, v in d.items()}
+
+    runs, i = [], 0
+    for kind, _, n in layer_runs(config):
+        runs.append([one(i + j, kind) for j in range(n)])
+        i += n
+    top = {"embed": get("model.embed_tokens.weight"),
+           "final_norm": get("model.norm.weight")}
+    head = np.asarray(top["embed"] if config.tie_word_embeddings
+                      else get("lm_head.weight"))
+    top["lm_head"] = np.pad(head, ((0, -head.shape[0] % 128), (0, 0)))
+    return runs, top
+
+
 def _smallthinker_tree(config: ModelConfig, get: Get, quant
                        ) -> tuple[list, dict]:
     """SmallThinker (PowerInfer's modeling_smallthinker). Returns (one list
@@ -1438,8 +1480,11 @@ def params_from_state_dict(
             params[k] = maybe_quant(k, v)
         return params
 
-    if config.model_type == "granitemoehybrid":
-        runs, top = _granitemoehybrid_tree(config, get_tensor, maybe_quant)
+    if config.model_type in ("granitemoehybrid", "minicpm_sala"):
+        tree = (_granitemoehybrid_tree
+                if config.model_type == "granitemoehybrid"
+                else _minicpm_sala_tree)
+        runs, top = tree(config, get_tensor, maybe_quant)
         params = {"runs": {f"{r:02d}": stack_dicts(run)
                            for r, run in enumerate(runs)}}
         for k, v in top.items():

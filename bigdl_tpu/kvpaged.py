@@ -619,6 +619,12 @@ class CacheKind:
         """Its `/metrics` families: (name, type, help, value) each."""
         return []
 
+    def report(self, cache):
+        """[B, W] int32 that the kind's forward left in `cache` for the
+        host (a block-sparse layer's counts, kvsparse.py), or None: the
+        decode step appends it to its one fetch, a prefill returns it."""
+        return None
+
 
 class _LatentPages(CacheKind):
     """Pages of latents (`PagedLatentCache`): an MLA family's, made by its

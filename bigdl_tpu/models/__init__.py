@@ -176,6 +176,10 @@ _LAZY_FAMILIES = {
     # Qwen3-MoE's network generated by diffusion over blocks: a step is a
     # pass over a block of positions (serving/blocks.py)
     "sdar_moe": "bigdl_tpu.models.sdar",
+    # block-sparse attention layers (a selection of pages a row and KV
+    # head) between lightning attention layers (a state row a slot)
+    # (bigdl_tpu/kvsparse.py)
+    "minicpm_sala": "bigdl_tpu.models.minicpm_sala",
 }
 
 

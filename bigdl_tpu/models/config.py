@@ -172,6 +172,17 @@ class ModelConfig:
     remasking_strategy: str = "low_confidence_dynamic"
     confidence_threshold: float = 0.9
     mask_token_id: int = 0
+    # minicpm_sala (models/minicpm_sala.py): each layer's mixer by index,
+    # "minicpm4" (softmax attention without rope whose long rows read a
+    # SELECTION of key blocks, kvsparse.py) | "lightning-attn" (decayed
+    # linear attention: `lightning_heads` heads with their own keys and
+    # values and a [head size, head size] state each). `sparse_config` is
+    # the selection's sizes as sorted (key, value) pairs: kernel_size,
+    # kernel_stride, block_size, topk, init_blocks, window_size, dense_len
+    mixer_types: Optional[tuple] = None
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    sparse_config: Optional[tuple] = None
 
     def __post_init__(self):
         if self.attention_kind not in ("softmax", "power_retention"):
@@ -193,6 +204,12 @@ class ModelConfig:
         # ModelConfig is a static jit argument and must hash; rope_scaling
         # arrives as a dict from HF config.json (or a list-of-pairs after a
         # JSON round-trip through save_low_bit) — normalize to a tuple.
+        sc = self.sparse_config
+        if isinstance(sc, dict):
+            sc = sorted(sc.items())
+        if sc is not None:  # (lists of pairs after a JSON round trip)
+            object.__setattr__(self, "sparse_config",
+                               tuple((k, int(v)) for k, v in sc))
         rs = self.rope_scaling
         if isinstance(rs, dict):
             rs = tuple(sorted((k, _hashable(v)) for k, v in rs.items()))
@@ -204,7 +221,7 @@ class ModelConfig:
         # config stops hashing as a static jit argument
         for f in ("sliding_layers", "cross_attention_layers",
                   "mrope_section", "layer_types", "rope_layers",
-                  "heads_per_layer"):
+                  "heads_per_layer", "mixer_types"):
             v = getattr(self, f)
             if isinstance(v, list):
                 object.__setattr__(self, f, tuple(v))
@@ -423,6 +440,67 @@ def _hf_minicpm(hf, kw):
     kw["embedding_scale"] = hf.get("scale_emb", 1.0)
     if "dim_model_base" in hf and hf.get("hidden_size"):
         kw["logit_scale"] = 1.0 / (hf["hidden_size"] / hf["dim_model_base"])
+
+
+#: the `sparse_config` of the `minicpm4` mixer where a checkpoint gives none:
+#: MiniCPM4.1's published sizes (InfLLM-v2)
+SPARSE_DEFAULTS = {"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
+                   "topk": 64, "init_blocks": 1, "window_size": 2048,
+                   "dense_len": 8192}
+
+
+def _hf_minicpm_sala(hf, kw):
+    """MiniCPM-SALA: MiniCPM's three scalings over layers that are, by
+    `mixer_types`, block-sparse softmax attention (`minicpm4`) or lightning
+    attention (`lightning-attn`). What cannot be served is refused by
+    name; a checkpoint's own `sparse_config` wins over the family's."""
+    _hf_minicpm(hf, kw)
+    L = hf["num_hidden_layers"]
+    kinds = tuple(hf.get("mixer_types") or ("minicpm4",) * L)
+    if len(kinds) != L or set(kinds) - {"minicpm4", "lightning-attn"}:
+        raise ValueError(
+            f"mixer_types must name {L} layers as 'minicpm4' or "
+            f"'lightning-attn'; got {len(kinds)}: {sorted(set(kinds))}")
+    kw["mixer_types"] = kinds
+    kw["lightning_heads"] = hf.get("lightning_nh",
+                                   hf["num_attention_heads"])
+    kw["lightning_head_dim"] = hf.get("lightning_head_dim",
+                                      hf.get("head_dim") or 128)
+    if hf.get("lightning_nkv", kw["lightning_heads"]) != kw[
+            "lightning_heads"]:
+        raise NotImplementedError(
+            f"minicpm_sala with lightning_nkv {hf['lightning_nkv']} != "
+            f"lightning_nh {kw['lightning_heads']}: the lightning state is "
+            "written for a key and a value a head")
+    if hf.get("attn_use_rope", False):
+        raise NotImplementedError(
+            "minicpm_sala with attn_use_rope: the sparse layers are "
+            "written without a position encoding")
+    for flag in ("lightning_use_rope", "qk_norm", "use_output_gate",
+                 "use_output_norm", "attn_use_output_gate"):
+        if not hf.get(flag, True):
+            raise NotImplementedError(
+                f"minicpm_sala with {flag} false: the mixers are written "
+                "with it")
+    if hf.get("lightning_scale", "1/sqrt(d)") != "1/sqrt(d)":
+        raise NotImplementedError(
+            f"minicpm_sala with lightning_scale {hf['lightning_scale']!r}")
+    kw["qk_norm"] = True
+    sparse = {**SPARSE_DEFAULTS, **(hf.get("sparse_config") or {})}
+    unknown = set(sparse) - set(SPARSE_DEFAULTS)
+    if unknown:
+        raise ValueError(f"sparse_config keys {sorted(unknown)} are not "
+                         f"among {sorted(SPARSE_DEFAULTS)}")
+    if (sparse["block_size"] % sparse["kernel_stride"]
+            or sparse["kernel_size"] != 2 * sparse["kernel_stride"]
+            or sparse["window_size"] % sparse["block_size"]
+            or sparse["dense_len"] % sparse["block_size"]):
+        raise NotImplementedError(
+            f"minicpm_sala with sparse_config {sparse}: windows of two "
+            "strides, and a block, a local window and a dense length in "
+            "whole blocks, are what the selection is written for")
+    kw["sparse_config"] = sparse
+    kw.setdefault("tie_word_embeddings", False)
 
 
 def _hf_glm(hf, kw):
@@ -1153,6 +1231,7 @@ _HF_BUILDERS = {
     "internlmxcomposer2": _hf_internlm2,
     "internlm": _hf_internlm,
     "minicpm": _hf_minicpm,
+    "minicpm_sala": _hf_minicpm_sala,
     "glm": _hf_glm,
     "gpt2": _hf_gpt2,
     "bloom": _hf_bloom,

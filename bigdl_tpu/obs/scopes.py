@@ -56,14 +56,22 @@ VOCABULARY = (
     "block.store",
 )
 
-_NAMES = frozenset(VOCABULARY)
+#: names that stand INSIDE a scope of the vocabulary and split it further
+#: by hand (`scripts/trace_ops_by_program.py`): the reducer and the metrics
+#: know the vocabulary alone, and an operation under one of these belongs to
+#: the vocabulary's scope around it (`bench/reduce/scopes.scope_of` takes the
+#: innermost name it knows). A block-sparse layer's selection, and the two
+#: forms of a lightning (decayed linear attention) layer (kvsparse.py).
+DETAIL = ("sparse_select", "lightning_prefill", "lightning_decode")
+
+_NAMES = frozenset(VOCABULARY + DETAIL)
 
 
 def scope(name: str):
-    """`jax.named_scope(name)` for a name of `VOCABULARY`; any other name
-    raises where the program is traced."""
+    """`jax.named_scope(name)` for a name of `VOCABULARY` (or of `DETAIL`,
+    inside one); any other name raises where the program is traced."""
     if name not in _NAMES:
         raise ValueError(
             f"{name!r} is not a scope of bigdl_tpu.obs.scopes.VOCABULARY; "
-            f"have {sorted(_NAMES)}")
+            f"have {sorted(VOCABULARY)} and, inside them, {sorted(DETAIL)}")
     return jax.named_scope(name)

@@ -129,7 +129,12 @@ def _kernel(
     quantized: bool,
     kv_value: tuple,
     block_causal: Optional[int] = None,
+    masked: bool = False,
 ):
+    mk_ref = None
+    if masked:  # + VMEM [1, 1, BQ, BK] int8: a key a query may read, beside
+        # the causal bound (a block-sparse layer's selection, kvsparse.py)
+        mk_ref, *refs = refs
     if quantized:  # fp8 KV: per-(slot, head) f32 scales ride alongside
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -187,6 +192,8 @@ def _kernel(
                                                             block_causal))
         if window is not None:
             valid = valid & (cols > pos - window)
+        if masked:
+            valid = valid & (mk_ref[0, 0].astype(jnp.int32) != 0)
         s = jnp.where(
             valid[None], s.reshape(group, block_q, block_k), _NEG_INF
         ).reshape(rows, block_k)
@@ -228,7 +235,7 @@ def _flash(
     q, k, v, start, q_offset, k_scale, v_scale,
     window: Optional[int], softcap: Optional[float],
     scale: float, block_q: int, block_k: int, interpret: bool,
-    block_causal: Optional[int] = None,
+    block_causal: Optional[int] = None, mask=None,
 ):
     B, Hkv, group, T, D = q.shape
     S = k.shape[2]
@@ -248,6 +255,7 @@ def _flash(
         scale=scale, group=group, block_q=block_q, block_k=block_k,
         window=window, softcap=softcap, quantized=quantized,
         kv_value=kv_value, block_causal=block_causal,
+        masked=mask is not None,
     )
 
     def q_index(b, h, i, j, start_ref, qoff_ref):
@@ -262,6 +270,15 @@ def _flash(
                 pl.BlockSpec((1, 1, block_k, D), kv_index),
                 pl.BlockSpec((1, 1, block_k, D), kv_index)]
     args = [start, q_offset, q, k, v]
+    if mask is not None:  # [B, Hkv, T, S] int8, a tile a grid step
+
+        def mask_index(b, h, i, j, start_ref, qoff_ref):
+            return (b, h, i, clamped_k_block(j, i, qoff_ref[0], block_q,
+                                             block_k, n_k, window,
+                                             block_causal))
+
+        in_specs.append(pl.BlockSpec((1, 1, block_q, block_k), mask_index))
+        args.append(mask)
     if quantized:
         # [B, Hkv, S, 1] f32: a trailing singleton keeps the block rank-2
         # in (sublane, lane) with a full-dim lane (always legal)
@@ -307,6 +324,9 @@ def flash_attention(
     interpret: Optional[bool] = None,
     block_causal: Optional[int] = None,  # static: the mask is causal by
     # blocks of this many slots (both directions inside one); None = causal
+    mask: Optional[jax.Array] = None,  # [B, Hkv, T, S] int8: beside the
+    # causal bound, the keys a query position of a KV head's group may read
+    # (nonzero); a tile of it a grid step
 ) -> jax.Array:
     """Returns [B, T, Hq, D] in q.dtype. Pads T/S/D to tile multiples
     internally; padding key slots are excluded by the causal mask (they
@@ -368,6 +388,9 @@ def flash_attention(
         prep_scale(k_scale), prep_scale(v_scale),
         window, softcap, scale, block_q, block_k, interpret,
         block_causal=block_causal,
+        mask=None if mask is None else jnp.pad(
+            mask.astype(jnp.int8),
+            ((0, 0), (0, 0), (0, Tp - T), (0, Sp - S))),
     )
     out = jnp.transpose(out[:, :, :, :T, :D], (0, 3, 1, 2, 4))
     return out.reshape(B, T, Hq, D)

@@ -61,16 +61,21 @@ def _split(x):
 
 
 def _kernel(meta_ref, d_ref, u_ref, b_ref, c_ref, s_ref, s_out, y_ref, *,
-            n_chunks: int):
+            n_chunks: int, per_chunk: bool = False):
     i, j = pl.program_id(0), pl.program_id(1)
     live = i < meta_ref[1]
 
     @pl.when(live)
     def _update():
         brow = b_ref[:1, :]  # [1, N]
+        # per_chunk (`lightning_decode`): B and C are a chunk's own, row c of
+        # [chunks padded to 8, N]; y's row c is then row c of its own dot
         c_both = jnp.concatenate(_split(c_ref[...]), axis=0)  # [2 * 8, N]
+        pad = c_ref.shape[0]
         for c in range(n_chunks):
             at = pl.ds(c * CHUNK, CHUNK)
+            if per_chunk:
+                brow = b_ref[c:c + 1, :]
             h = (d_ref[:, c:c + 1] * s_ref[at, :]
                  + u_ref[:, c:c + 1] * brow)  # [128, N]
             s_out[at, :] = h
@@ -81,7 +86,8 @@ def _kernel(meta_ref, d_ref, u_ref, b_ref, c_ref, s_ref, s_out, y_ref, *,
                     preferred_element_type=jnp.float32)
 
             both = sum(map(dot, _split(h)))
-            y_ref[c:c + 1, :] = both[:1] + both[_PAD:_PAD + 1]
+            r = c if per_chunk else 0
+            y_ref[c:c + 1, :] = both[r:r + 1] + both[pad + r:pad + r + 1]
 
     @pl.when(jnp.logical_not(live))
     def _idle():
@@ -174,3 +180,83 @@ def mamba2_decode(
         interpret=interpret,
     )(meta, dec, u, padded(Bm), padded(Cm), ssm)
     return y.reshape(B, H, P), ssm
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def lightning_decode(
+    state: jax.Array,  # [Ll, R, heads * head size, N] float32, the whole pool
+    layer: jax.Array,  # scalar int32
+    rows: jax.Array,  # [B] int32 state row of each batch row
+    live: jax.Array,  # [B] bool: rows that hold one
+    v: jax.Array,  # [B, H, P] float32: the token's values
+    decay: jax.Array,  # [H] float32: a head's decay a token, in (0, 1)
+    k: jax.Array,  # [B, H, N] float32: the token's keys, a head's own
+    q: jax.Array,  # [B, H, N] float32: its queries (scaled), a head's own
+    interpret: bool | None = None,
+):
+    """`mamba2_decode`'s sibling for lightning attention (kvsparse.py): the
+    same pass over the live rows' state in place, with `dt` = 1, the decay
+    a constant of the head, and B and C (k and q) A HEAD: a head is one
+    chunk of `CHUNK` = P rows, so chunk c of a block reads row c of the
+    block's keys and queries. Returns (y [B, H, P] float32, state)."""
+    from bigdl_tpu.ops.pallas import interpret_mode
+
+    if interpret is None:
+        interpret = interpret_mode()
+    B, H, P = v.shape
+    inner, N = state.shape[-2:]
+    if P != CHUNK:
+        raise ValueError(f"a head of {P} values is not a chunk of {CHUNK}")
+    blk = block_rows(inner)
+    n_blocks, n_chunks = inner // blk, blk // CHUNK
+    pad = -(-n_chunks // _PAD) * _PAD
+
+    def columns(a):  # [B, H * P] a row -> [B, blocks, 128, chunks]
+        return jnp.swapaxes(a.astype(jnp.float32).reshape(
+            B, n_blocks, n_chunks, CHUNK), 2, 3)
+
+    dec = columns(jnp.broadcast_to(jnp.repeat(decay, P)[None], (B, inner)))
+    u = columns(v.reshape(B, inner))
+
+    def by_block(a):  # [B, H, N] -> [B, blocks, chunks padded, N]
+        a = a.astype(jnp.float32).reshape(B, n_blocks, n_chunks, N)
+        return jnp.pad(a, ((0, 0), (0, 0), (0, pad - n_chunks), (0, 0)))
+
+    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    step = jnp.minimum(jnp.arange(B, dtype=jnp.int32),
+                       jnp.maximum(n_live - 1, 0))
+    meta = jnp.concatenate([
+        jnp.reshape(layer, (1,)).astype(jnp.int32), n_live[None], order,
+        jnp.maximum(rows.astype(jnp.int32)[order[step]], 0)])
+
+    def state_block(i, j, m):
+        return (m[0], m[2 + B + i], jnp.where(i < m[1], j, n_blocks - 1), 0)
+
+    def per_row(i, j, m):  # an idle row asks for one block, once
+        return (m[2 + i], jnp.where(i < m[1], j, 0), 0, 0)
+
+    s_spec = pl.BlockSpec((None, None, blk, N), state_block)
+    col = pl.BlockSpec((None, None, CHUNK, n_chunks), per_row)
+    vec = pl.BlockSpec((None, None, pad, N), per_row)
+    state, y = pl.pallas_call(
+        functools.partial(_kernel, n_chunks=n_chunks, per_chunk=True),
+        name="lightning_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, n_blocks),
+            in_specs=[col, col, vec, vec, s_spec],
+            out_specs=[
+                s_spec,
+                pl.BlockSpec((None, None, n_chunks, CHUNK),
+                             lambda i, j, m: (m[2 + i], j, 0, 0)),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct(state.shape, state.dtype),
+            jax.ShapeDtypeStruct((B, n_blocks, n_chunks, CHUNK), jnp.float32),
+        ],
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(meta, dec, u, by_block(k), by_block(q), state)
+    return y.reshape(B, H, P), state

@@ -326,10 +326,13 @@ def _row_of_live_groups(bt_ref, b, layer, first_b, last_b, pages: int,
 def _kernel(bt_ref, meta_ref, q_ref, k_in, v_in, *refs,
             n_kv: int, group: int, page: int, pages: int, n_batch: int,
             scale: float, softcap: float | None, quantized: bool,
-            piped: bool):
-    ks_ref = vs_ref = None
+            piped: bool, listed: bool = False):
+    ks_ref = vs_ref = mask_ref = None
     if quantized:  # fp8 pages: the row's per-vector f32 scales, by column
         ks_ref, vs_ref, *refs = refs
+    if listed:  # a page LIST (`paged_sparse_decode_attention`): which
+        # columns of each group a KV head reads, 1.0 or 0.0
+        mask_ref, *refs = refs
     o_ref, *buffers, acc_ref, m_ref, l_ref = refs
     state = (acc_ref, m_ref, l_ref)
     b = pl.program_id(0)
@@ -360,6 +363,8 @@ def _kernel(bt_ref, meta_ref, q_ref, k_in, v_in, *refs,
         # current token was just written to), inside the window
         t = g * (pages * page) + slot_in_group
         valid = own_head & (t >= lo_b) & (t <= pos_b)
+        if listed:
+            valid = valid & (mask_ref[0, g] > 0.0)
 
         q = q_ref[0]
         if quantized:
@@ -537,6 +542,91 @@ def paged_decode_attention(
         ),
         interpret=interpret,
     )(*args)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_sparse_decode_attention(
+    q: jax.Array,  # [B, Hq, D] current-token queries
+    k_pages: jax.Array,  # [L, n_pages, page, Hkv, D] the FULL pool, bf16
+    v_pages: jax.Array,
+    page_list: jax.Array,  # [B, U] int32 PHYSICAL pages, a row's in order
+    n_listed: jax.Array,  # [B] int32 how many of them the row reads
+    reads: jax.Array,  # [B, Hkv, U] bool: KV head h reads listed page u
+    layer: jax.Array,  # scalar int32
+    last_fill: jax.Array,  # [B] int32 slots of the row's LAST listed page
+    scale: float | None = None,
+    live: jax.Array | None = None,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """`paged_decode_attention` over a LIST of pages a row in place of a
+    range (block-sparse attention, kvsparse.py): row b attends to the pages
+    `page_list[b, :n_listed[b]]`, each whole but the last, which holds
+    `last_fill[b]` valid slots (the page of the current token: a selection
+    always takes it). The list is the UNION of what the row's KV heads
+    chose, a page's DMA bringing every head's half of it, and `reads` says
+    which head's columns count: the others are masked like dead slots. The
+    body, the row loop and the DMA a listed page are the range kernel's
+    (`_kernel`, `_row_of_live_groups`): the list stands where the block
+    table stood, at positions of its own (no rope: where a key lay is not
+    read). Returns [B, Hq, D]; an idle or empty row comes back as zeros."""
+    from bigdl_tpu.ops.pallas import interpret_mode
+
+    if interpret is None:
+        interpret = interpret_mode()
+    B, Hq, D = q.shape
+    _, _, page, Hkv, _ = k_pages.shape
+    U = page_list.shape[1]
+    if not (interpret
+            or pool_tiles_whole(Hkv, D, k_pages.dtype.itemsize)):
+        raise NotImplementedError(
+            f"a pool of {Hkv} KV heads of {D} is not laid out in whole "
+            "tiles: the sparse decode kernel takes its pages by its own DMA")
+    P = group_pages(page, Hkv, D, k_pages.dtype.itemsize, U)
+    if not pool_tiles_whole(Hkv, D, k_pages.dtype.itemsize):
+        # (the interpreter alone: `group_pages` says 1 for such a pool)
+        P = max(1, min(U, _GROUP_TOKENS // page))
+    n_groups = -(-U // P)
+    n = n_listed.astype(jnp.int32)
+    has = n > 0 if live is None else (n > 0) & live
+    pos = jnp.maximum(n - 1, 0) * page + last_fill.astype(jnp.int32) - 1
+    meta = _scalars(layer, None, pos, jnp.zeros_like(pos), page, U, has)
+    # which columns of each group a head reads: column c of a group is
+    # slot c // Hkv of KV head c % Hkv of page c // (page * Hkv)
+    cols = jnp.pad(reads, ((0, 0), (0, 0), (0, n_groups * P - U)))
+    cols = jnp.moveaxis(cols.reshape(B, Hkv, n_groups, P), 1, 3)
+    cols = jnp.broadcast_to(cols[:, :, :, None, :],
+                            (B, n_groups, P, page, Hkv))
+    cols = cols.reshape(B, n_groups, 1, P * page * Hkv).astype(jnp.float32)
+    row = pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(
+            _kernel, n_kv=Hkv, group=Hq // Hkv, page=page, pages=P,
+            n_batch=B, scale=scale if scale is not None else D ** -0.5,
+            softcap=None, quantized=False, piped=False, listed=True),
+        name="paged_sparse_decode_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[row, pool, pool,
+                      pl.BlockSpec((1, n_groups, 1, P * page * Hkv),
+                                   lambda b, *_: (b, 0, 0, 0))],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((2, P, page, Hkv, D), k_pages.dtype),
+                pltpu.VMEM((2, P, page, Hkv, D), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((Hq, D), jnp.float32),
+                pltpu.VMEM((Hq, 1), jnp.float32),
+                pltpu.VMEM((Hq, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), jnp.bfloat16),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=int(
+                4 * P * page * Hkv * D * k_pages.dtype.itemsize
+                + 12 * max(Hq, 8) * P * page * Hkv * 4 + 2 ** 23)),
+        interpret=interpret,
+    )(page_list.astype(jnp.int32), meta, q.astype(k_pages.dtype), k_pages,
+      v_pages, cols)
 
 
 def paged_block_attention(

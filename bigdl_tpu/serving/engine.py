@@ -60,6 +60,15 @@ RETRACE_PHASES = ("prefill.dispatch", "first_token.sample",
 #: decode tokens coalesced into one `decode` span of a request's track
 TRACE_DECODE_EVERY = 8
 
+#: prompt tokens one `step()` admits before it decodes the rows it has: a
+#: further prompt is admitted only while the prompts of this call, with it,
+#: stay within the budget (the first always is). A queue of long prompts
+#: (16 of 8k to 16k tokens prefill for 40 s) gets a decode step between
+#: two of them, where the admit-all loop let the rows already admitted
+#: stand still until the last free slot was filled; prompts that sum to
+#: less are admitted together as before (vLLM: `max_num_batched_tokens`)
+ADMIT_TOKENS_PER_STEP = 16384
+
 #: what a phase runs under while tracing is off: one shared context that
 #: does nothing, so an untraced step builds no annotation
 _NO_PHASE = contextlib.nullcontext()
@@ -125,12 +134,13 @@ def _cache_kind(model) -> kvpaged.CacheKind:
     chosen here and nowhere else: a recurrent state in every layer by the
     config's `attention_kind`; then the family's own, which it names
     (`PAGED_CACHE_KIND`: a state row beside the pages, kvhybrid.py; a
-    window group of pages beside the global one, kvwindow.py) or, offering
+    window group of pages beside the global one, kvwindow.py; pages read
+    by selection beside a state row, kvsparse.py) or, offering
     an `init_paged_cache` and no name, has as latent pages (MLA); then KV
     pages (those of a model generated by diffusion over blocks as their own
     kind, for what it refuses: serving/blocks.py). The page table books,
     parks and restores a page of any kind alike."""
-    from bigdl_tpu import kvhybrid, kvstate, kvwindow
+    from bigdl_tpu import kvhybrid, kvsparse, kvstate, kvwindow
 
     if model.config.attention_kind == kvstate.KIND:
         return kvstate.CACHE_KIND
@@ -139,7 +149,7 @@ def _cache_kind(model) -> kvpaged.CacheKind:
 
         return blocks.CACHE_KIND
     own = getattr(model.family, "PAGED_CACHE_KIND", None)
-    for mod in (kvhybrid, kvwindow):
+    for mod in (kvhybrid, kvwindow, kvsparse):
         if own == mod.KIND:
             return mod.CACHE_KIND
     if hasattr(model.family, "init_paged_cache"):
@@ -187,6 +197,13 @@ class Request:
     # pass that revealed it; `prompt_experts` then covers the prompt's
     # whole blocks and `out_experts` every position of each STORED block
     passes: list = dataclasses.field(default_factory=list)
+    # block-sparse attention (kvsparse.py), only where the kind was asked
+    # (`CACHE_KIND.report_ids`): the key blocks each sparse layer and KV
+    # head chose, flat [Ls * Hkv * topk] layer-major (-1: fewer, or a row
+    # read whole), at the prompt's last position and at each decode step's
+    # input position
+    prompt_selection: Optional["np.ndarray"] = None
+    out_selection: list = dataclasses.field(default_factory=list)
     done: bool = False
     finish_reason: str = ""  # "stop" (EOS) | "length" (budget) |
     # "invalid" (rejected at submit — over-long prompt) | "error" |
@@ -668,6 +685,13 @@ class InferenceEngine:
                                     in inspect.signature(fwd).parameters)
             except (TypeError, ValueError):  # pragma: no cover - exotic
                 pass
+        # a kind whose forward reports what it did (`CacheKind.report`: a
+        # block-sparse layer's counts of pages, and the chosen ids where it
+        # was asked) appends that many int32 columns to a step's one fetch;
+        # the counts, summed, are `report_totals`
+        chose = kind.report(self.cache) if paged else None
+        self._report_width = 0 if chose is None else chose.shape[1]
+        self.report_totals: collections.Counter = collections.Counter()
         self._moe_last: Optional[tuple] = None  # newest decode step's
         # expert ids [L, B, k] and its live rows; see moe_load
         # (choices on the device, tokens) per chunk of the prefill being
@@ -1111,8 +1135,9 @@ class InferenceEngine:
             forward, params, tokens, row, "prefill", kw)
         with scope("engine"):
             pool = kind.write_back(pool, row, tokens.shape[1], last_idx, cfg)
-            return (logits[0, at], pool,
-                    None if experts is None else experts[:, 0])
+            if experts is None:  # or what the kind's forward chose
+                return logits[0, at], pool, kind.report(row)
+            return logits[0, at], pool, experts[:, 0]
 
     def _forward_routing(self, forward, params, tokens, cache, mode, kw):
         """`forward`, and for a sparse-expert model every position's top-k
@@ -1207,6 +1232,9 @@ class InferenceEngine:
             if experts is not None:  # [L, B, 1, k] -> [B, L * k]
                 out.append(jnp.swapaxes(experts[:, :, 0], 0, 1).reshape(
                     nxt.shape[0], -1).astype(jnp.int32))
+            chose = None if self.kind is None else self.kind.report(cache)
+            if chose is not None:  # a kind whose forward reports (static)
+                out.append(chose)
             return nxt, jnp.concatenate(out, axis=1), cache, seen
 
     def _spec_decode_impl(self, forward, k_draft, params, dparams, cur, cache,
@@ -2184,7 +2212,7 @@ class InferenceEngine:
         reason = req.finish_reason or "?"
         with self._stat_lock:
             self.finish_reasons[reason] += 1
-        if req.prompt_experts is not None:
+        if req.prompt_experts is not None or req.prompt_selection is not None:
             global _last_routed
             _last_routed = weakref.ref(req)
         entry = self._adapter_refs.pop(req.rid, None)
@@ -2378,6 +2406,14 @@ class InferenceEngine:
         now = self._clock()
         rt_arm = self._retrace_mark("first_token.arm")
         moe_args = {}
+        if self._admit_moe and self._report_width:
+            # what the kind's prefill chose at the prompt's last position
+            chose, moe_args = self.kind.read_report(
+                np.asarray(self._admit_moe[-1][0]), prefill=True)
+            if chose is not None:  # the kind was asked for them
+                req.prompt_selection = chose[0]
+            self.report_totals.update(moe_args)
+            self._admit_moe = []
         if self._admit_moe:  # the prefill has run (first-token sync above)
             chosen = np.concatenate(
                 [np.asarray(a)[:, :n] for a, n in self._admit_moe], axis=1)
@@ -2442,6 +2478,7 @@ class InferenceEngine:
         self._activate(slot, req, logits_last)
 
     def _admit(self) -> None:
+        spent = 0  # prompt tokens admitted by this call
         while True:
             slot = self._free_slot()
             if slot is None:
@@ -2492,6 +2529,9 @@ class InferenceEngine:
             if which is not None:
                 self._expire_queued(req, which, now)
                 continue
+            if spent and spent + len(req.prompt) > ADMIT_TOKENS_PER_STEP:
+                self._waiting = req  # first in line after a decode step
+                return
             if req.adapter is not None and not self._resolve_adapter(req):
                 continue  # structured failure: ONE request errors, the
                 # batch keeps serving (never fail_all for a bad adapter)
@@ -2501,6 +2541,7 @@ class InferenceEngine:
                     return
             else:
                 self._admit_dense(req, slot)
+            spent += len(req.prompt)
 
     def _emit(self, slot: int, token: int,
               logprob: Optional[float] = None,
@@ -3142,9 +3183,14 @@ class InferenceEngine:
                 _expert_id_dtype(self.config.num_experts))
             # counted only when a span or a gauge reads it (moe_load)
             self._moe_last = (experts_h, live)
+        chose, extra = None, None
+        if self._report_width:  # what the kind's forward chose, a row
+            chose, extra = self.kind.read_report(
+                host[:, host.shape[1] - self._report_width:], live)
+            self.report_totals.update(extra)
         # the fetch above is the host sync: the step's device work is
         # really done here, so the duration is honest
-        self._note_decode_step(fl, live, t_wait)
+        self._note_decode_step(fl, live, t_wait, extra=extra)
         with self._phase("step.emit", seq=fl.seq):
             for i in np.nonzero(live)[0]:
                 i = int(i)
@@ -3166,6 +3212,8 @@ class InferenceEngine:
                     self.pages.advance(i)
                 if experts_h is not None:
                     s.req.out_experts.append(experts_h[:, i])
+                if chose is not None:
+                    s.req.out_selection.append(chose[i])
                 alt = None
                 if tops_h is not None:
                     alt = {int(t): float(l)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bigdl_tpu import kvhybrid, kvpaged, kvstate, kvwindow
+from bigdl_tpu import kvhybrid, kvpaged, kvsparse, kvstate, kvwindow
 from bigdl_tpu.models import get_family
 from bigdl_tpu.models.config import PRESETS, ModelConfig
 from bigdl_tpu.serving.engine import _cache_kind, _PrefillState
@@ -45,6 +45,14 @@ CONFIGS = {
         dict(_DENSE, model_type="brumby", head_dim=32)),
     "state_beside_pages": PRESETS["tiny-granite-hybrid"],
     "window_pages_beside_pages": PRESETS["tiny-smallthinker"],
+    "selected_pages_beside_state": ModelConfig.from_hf_config(dict(
+        _DENSE, model_type="minicpm_sala", num_hidden_layers=3,
+        mixer_types=["minicpm4", "lightning-attn", "minicpm4"], head_dim=32,
+        lightning_nh=4, lightning_nkv=4, lightning_head_dim=32,
+        rms_norm_eps=1e-6, scale_emb=12, scale_depth=1.4, dim_model_base=32,
+        sparse_config=dict(kernel_size=8, kernel_stride=4, block_size=8,
+                           topk=4, init_blocks=1, window_size=8,
+                           dense_len=16))),
 }
 KINDS = {
     "kv_pages": kvpaged.KV_PAGES,
@@ -52,6 +60,7 @@ KINDS = {
     "power_retention": kvstate.CACHE_KIND,
     "state_beside_pages": kvhybrid.CACHE_KIND,
     "window_pages_beside_pages": kvwindow.CACHE_KIND,
+    "selected_pages_beside_state": kvsparse.CACHE_KIND,
 }
 NAMES = sorted(KINDS)
 N_SLOTS, MAX_LEN, PAGE = 4, 64, 8
@@ -177,6 +186,9 @@ _REFUSES = {
     "window_pages_beside_pages": (
         "window_pages_beside_pages", "smallthinker", "R3",
         ("quantize_kv", "speculative", "adapters", "prefill_chunk_tokens")),
+    "selected_pages_beside_state": (
+        "selected_pages_beside_state", "minicpm_sala", "R11",
+        ("quantize_kv", "speculative", "adapters", "prefill_chunk_tokens")),
 }
 _OWN = {  # today's sentences where they are not "not wired"
     ("power_retention", "paged"):
@@ -194,6 +206,14 @@ _OWN = {  # today's sentences where they are not "not wired"
         r"state_beside_pages \(granitemoehybrid\) is served with paged=True: "
         "a slot holds KV pages for the attention layers and a state row for "
         "the others",
+    ("selected_pages_beside_state", "paged"):
+        r"selected_pages_beside_state \(minicpm_sala\) is served with "
+        "paged=True: a slot holds KV pages and pooled keys for the sparse "
+        "layers and a state row for the others",
+    ("selected_pages_beside_state", "prefill_chunk_tokens"):
+        r"prefill_chunk_tokens is not wired for selected_pages_beside_state "
+        r"\(minicpm_sala\) yet \(ROADMAP R11\): a prefill runs a whole "
+        "prompt from an empty row",
     ("window_pages_beside_pages", "paged"):
         r"window_pages_beside_pages \(smallthinker\) is served with "
         "paged=True: a slot holds KV pages for the attention layers in two "
@@ -255,6 +275,18 @@ _SPANS = {
          "window_pages_unfreed"],
         ["bigdl_tpu_global_pages_in_use", "bigdl_tpu_window_pages_in_use",
          "bigdl_tpu_window_pages_freed_total"]),
+    # (the report's counts, `sparse_pages_selected` and the rest, join the
+    # spans where the engine has fetched them: tests/test_minicpm_sala.py)
+    "selected_pages_beside_state": (
+        ["state_chunks"],
+        ["grid_pages", "live_pages", "state_bytes_moved",
+         "state_rows_live"],
+        ["bigdl_tpu_state_rows_live", "bigdl_tpu_state_pool_bytes",
+         "bigdl_tpu_state_bytes_moved_total",
+         "bigdl_tpu_sparse_pages_selected_total",
+         "bigdl_tpu_sparse_pages_read_total",
+         "bigdl_tpu_pooled_keys_written_total",
+         "bigdl_tpu_sparse_selected_page_share"]),
 }
 
 
@@ -303,6 +335,8 @@ def test_a_chunk_adds_what_the_kind_counts(name):
             2 * kvstate.prefill_chunks(32), 0, 0, 0, 0),
         "state_beside_pages": lambda: (
             2 * kvhybrid.prefill_chunks(32, cfg.mamba_chunk_size), 0, 0, 0, 0),
+        "selected_pages_beside_state": lambda: (
+            2 * kvsparse.prefill_chunks(32, cfg.mamba_chunk_size), 0, 0, 0, 0),
         "window_pages_beside_pages": lambda: (0, 0, 32, 8, sum(
             kvwindow.window_pages_spanned(w, 32, n, cfg.sliding_window,
                                           PAGE, 8)
@@ -316,12 +350,14 @@ def test_metrics_families_keep_their_names(name):
     engine = types.SimpleNamespace(
         kind=kind, config=CONFIGS[name], pages=_table(name), n_slots=N_SLOTS,
         active=np.array([True, True, False, False]), state_bytes_moved=12,
-        state_row_bytes=kind.state_row_nbytes(pool))
+        state_row_bytes=kind.state_row_nbytes(pool),
+        report_totals={"sparse_pages_read": 3, "sparse_pages_live": 4})
     got = kind.metrics(engine)
     assert [m[0] for m in got] == _SPANS[name][2]
     for family, typ, text, value in got:
         assert typ == ("counter" if family.endswith("_total") else "gauge")
-        assert text and "\n" not in text and isinstance(value, int)
+        assert text and "\n" not in text and isinstance(
+            value, float if family.endswith("_share") else int)
     from bigdl_tpu.serving.metrics import expected_families
 
     assert set(_SPANS[name][2]) <= set(expected_families(engine))
@@ -353,4 +389,5 @@ def test_a_prefill_gives_back_the_pool_it_was_lent(name):
     assert seen["same"] == (name not in ("kv_pages",
                                          "window_pages_beside_pages"))
     assert kind.forward_kw(3) == (
-        {"logits_at": 3} if name == "window_pages_beside_pages" else {})
+        {"logits_at": 3} if name in ("window_pages_beside_pages",
+                                     "selected_pages_beside_state") else {})

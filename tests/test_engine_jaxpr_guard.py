@@ -95,6 +95,15 @@ MODELS = {
         _DENSE, model_type="sdar_moe", head_dim=32, rms_norm_eps=1e-6,
         num_experts=8, num_experts_per_tok=2, moe_intermediate_size=128,
         norm_topk_prob=True, mask_token_id=511),
+    "minicpm_sala": dict(
+        _DENSE, model_type="minicpm_sala", num_hidden_layers=4,
+        mixer_types=["minicpm4", "lightning-attn", "lightning-attn",
+                     "minicpm4"],
+        head_dim=32, lightning_nh=4, lightning_nkv=4, lightning_head_dim=32,
+        rms_norm_eps=1e-6, scale_emb=12, scale_depth=1.4, dim_model_base=32,
+        sparse_config=dict(kernel_size=8, kernel_stride=4, block_size=16,
+                           topk=6, init_blocks=1, window_size=32,
+                           dense_len=64)),
 }
 
 # (engine_decode, engine_paged_prefill) on the parent of PR 38; the paged
@@ -110,6 +119,9 @@ PINNED = {
     "laguna": (3316, 2904),
     # PR 50's own: a pass over blocks of 4 and its reveal; the llama prefill
     "sdar_moe": (598, 438),
+    # PR 54's own: two sparse layers one by one around a two-layer scan of
+    # lightning layers; the selection, the page list and the report
+    "minicpm_sala": (1924, 1476),
 }
 
 

@@ -83,9 +83,18 @@ def _tokens(n, seed):
     return np.random.default_rng(seed).integers(1, 256, n)
 
 
+_JITTED = {}  # the reference, compiled once a (config, n_last, options)
+
+
 def _ref_logits(ref, p, seq, n_last, hf=HF, **kw):
-    return np.asarray(ref.logits(hf, p, jnp.asarray(seq, jnp.int32), n_last,
-                                 **kw))
+    """The reference under `jax.jit`, as tests/test_laguna.py runs its own:
+    run eagerly it compiles every scan and map of its own again at every
+    call (ROADMAP D13)."""
+    key = (repr(sorted(hf.items())), n_last, repr(sorted(kw.items())))
+    if key not in _JITTED:
+        _JITTED[key] = jax.jit(
+            lambda p, t: ref.logits(hf, p, t, n_last, **kw))
+    return np.asarray(_JITTED[key](p, jnp.asarray(seq, jnp.int32)))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 5))

@@ -892,3 +892,78 @@ def test_block_causal_flash_attention_compiles(one_chip, T):
         _sds((1,), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip)).compile().as_text()
     assert "flash_attention" in text
+
+
+def test_sparse_decode_kernel_compiles_at_the_cells_shapes(one_chip):
+    """MiniCPM-SALA's sparse layers (PR 54): 16 slots, 2 KV heads of 128
+    with 16 query heads each, pages of 64, 272 pages a row of which a list of
+    at most 128 is read (64 chosen a KV head, their union; a row below
+    `dense_len` its 128), a pool of 4353 pages over 8 layers. The range
+    kernel's body and row loop with the list where the block table stood and
+    a mask a head and column: Mosaic takes it, and XLA hands the pool over as
+    it lies (the column mask is the one temporary, 1 MiB)."""
+    from bigdl_tpu import kvsparse
+    from bigdl_tpu.ops.pallas import paged_attention as pa
+
+    B, Hkv, G, D, page, L, NP, mp = 16, 2, 16, 128, 64, 8, 4353, 272
+    sz = kvsparse.Sizes(16, 64, 64, 1, 32, 8192)
+    U = kvsparse.list_width(sz, Hkv, mp)
+    assert U == 128 and pa.pool_tiles_whole(Hkv, D, 2)
+    kv = _sds((L, NP, page, Hkv, D), jnp.bfloat16, one_chip)
+
+    def f(q, k, v, plist, n, reads, layer, fill, live):
+        return pa.paged_sparse_decode_attention(
+            q, k, v, plist, n, reads, layer, fill, scale=D ** -0.5,
+            live=live, interpret=False)
+
+    c = jax.jit(f).lower(
+        _sds((B, Hkv * G, D), jnp.bfloat16, one_chip), kv, kv,
+        _sds((B, U), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((B, Hkv, U), jnp.bool_, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip)).compile()
+    assert "paged_sparse_decode_attention" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 22
+
+
+def test_lightning_decode_kernel_compiles_at_the_cells_shapes(one_chip):
+    """MiniCPM-SALA's lightning layers (PR 54): 16 slots' state rows `[32
+    heads x 128, 128]` float32 over 24 layers, B and C (k and q) A HEAD: a
+    chunk of a block its own row of keys and queries. The pool goes in and
+    comes out as one buffer."""
+    from bigdl_tpu.ops.pallas.mamba2 import lightning_decode
+
+    B, H, D, L, R = 16, 32, 128, 24, 16
+    vec = _sds((B, H, D), jnp.float32, one_chip)
+
+    def f(state, layer, rows, live, v, decay, k, q):
+        return lightning_decode(state, layer, rows, live, v, decay, k, q,
+                                interpret=False)
+
+    c = jax.jit(f, donate_argnums=0).lower(
+        _sds((L, R, H * D, D), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip), vec,
+        _sds((H,), jnp.float32, one_chip), vec, vec).compile()
+    assert "lightning_decode" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 22
+
+
+def test_flash_attention_compiles_under_a_selections_mask(one_chip):
+    """MiniCPM-SALA's prefill (PR 54): 16384 positions of 16 query heads a
+    KV head, 2 KV heads of 128, and beside the causal bound an int8 mask a
+    query, KV head and key (what the selection lets a query read), a tile a
+    grid step through the kernel's own clamped index map."""
+    from bigdl_tpu.ops.pallas import flash_attention
+
+    T, Hkv, G, D = 16384, 2, 16, 128
+    kv = _sds((1, T, Hkv, D), jnp.bfloat16, one_chip)
+
+    def f(q, k, v, mask):
+        return flash_attention(q, k, v, scale=D ** -0.5, mask=mask,
+                               interpret=False)
+
+    c = jax.jit(f).lower(
+        _sds((1, T, Hkv * G, D), jnp.bfloat16, one_chip), kv, kv,
+        _sds((1, Hkv, T, T), jnp.int8, one_chip)).compile()
+    assert "flash_attention" in c.as_text()
