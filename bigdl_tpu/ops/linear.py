@@ -316,24 +316,33 @@ def _reads_bits(w: QTensor) -> bool:
         spec_for(w.spec), w.data.shape[-2], _row_bytes(w))) is not None
 
 
-def _in_place(w: QTensor) -> bool:
-    """Does a `linear` call on `w` (no adapter) decode its tiles on the
-    word path with the codes cut out where they lie
-    (`qdecode.signed_field`)? From the tile plan's own static shapes."""
+def _words_note(w: QTensor) -> str:
+    """What a `linear` call on `w` (no adapter) says of its decode loop in
+    its route note, from the tile plan's own static shapes: nothing on the
+    stored-layout loop; on the word path ``" words"``, with ``:inplace``
+    where the codes are cut out where they lie (`qdecode.signed_field`)
+    and ``:ragged:<tiles>`` where the last of that many word tiles is
+    ragged (`tiling.ragged_word_tiles`). A whole-tile call of a format
+    with no signed field says nothing, as it always has."""
     from bigdl_tpu.ops.pallas.qdecode import signed_field, spec_for
     from bigdl_tpu.ops.pallas.qmatmul import tile_form
+    from bigdl_tpu.ops.pallas.tiling import WORD_BLOCK_O, word_tiles
 
-    spec = spec_for(w.spec)
-    return bool(signed_field(spec)) and tile_form(
-        spec, w.data.shape[-2], _row_bytes(w)) == "words"
+    spec, O = spec_for(w.spec), w.data.shape[-2]
+    if tile_form(spec, O, _row_bytes(w)) != "words":
+        return ""
+    note = (":inplace" if signed_field(spec) else "") + (
+        f":ragged:{word_tiles(O)}" if O % WORD_BLOCK_O else "")
+    return " words" + note if note else ""
 
 
 def prepare_scale_bits(w, stacks: Optional[int] = None):
     """`w` with its float16 `scales` (and `mins`) a second time as the
     operand its kernel reads in place (`QTensor.scale_bits`, `min_bits`,
     `bits_layout`): uint16 bits, one block a word tile with the tile's rows
-    on lanes where the word path runs (`qdecode.pack_major_bits`), the
-    stored `[.., O, nb]` on the stored-layout loop. What the kernels'
+    on lanes where the word path runs (`qdecode.pack_major_bits`; a ragged
+    last tile's block is whole, zeros past O), the stored `[.., O, nb]` on
+    the stored-layout loop. What the kernels'
     wrappers otherwise derive from the float16 fields before EVERY call
     (a view XLA materialises, padded to 128 lanes, and the tile's
     transposes every grid step) is derived once, by whoever takes a tree
@@ -665,8 +674,7 @@ def linear(
             "linear", f"pallas:{why}" if kernel is not None else "xla",
             f"{w.qtype} M{_rows(x.shape)} K{w.shape[-1]} "
             f"O{w.data.shape[-2]} " + ("stack" if stacked else "slice")
-            + (" words:inplace" if kernel is not None and lora is None
-               and _in_place(w) else "")
+            + (_words_note(w) if kernel is not None and lora is None else "")
             + (f" ({why})" if kernel is None else " scales:stack"
                if lora is None and _reads_bits(w) else " scales:slice"))
         if kernel is not None:

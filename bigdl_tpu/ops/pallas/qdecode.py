@@ -443,18 +443,24 @@ def _pad_lanes(a, mult: int = 128):
 
 def pack_major_bits(a, rows: int):
     """Float16 `[..., O, nb]` scales (or mins) -> uint16 bits
-    `[..., O / rows, nb, rows]`, the operand a word tile of `rows` rows
-    reads in place (`stage_words(prepared=True)`): one block a tile, the
-    K/32 blocks on sublanes and the tile's rows on lanes in the order the
-    word decode leaves them, column `p * rows / 4 + i` row `4 i + p`. Run
-    once, when a program takes its weights (`ops/linear.prepare_scale_bits`),
-    where `stage_words` otherwise builds the same array every grid step."""
+    `[..., ceil(O / rows), nb, rows]`, the operand a word tile of `rows`
+    rows reads in place (`stage_words(prepared=True)`): one block a tile,
+    the K/32 blocks on sublanes and the tile's rows on lanes in the order
+    the word decode leaves them, column `p * rows / 4 + i` row `4 i + p`.
+    A ragged last tile's rows past O are zeros, so that its block is whole
+    and they decode to 0. Run once, when a program takes its weights
+    (`ops/linear.prepare_scale_bits`), where `stage_words` otherwise builds
+    the same array every grid step."""
     *lead, O, nb = a.shape
     bits = jax.lax.bitcast_convert_type(a.astype(jnp.float16), jnp.uint16)
-    bits = bits.reshape(*lead, O // rows, rows // WORD_ROWS, WORD_ROWS, nb)
+    tiles = round_up(O, rows) // rows
+    if tiles * rows != O:
+        bits = jnp.pad(
+            bits, [(0, 0)] * len(lead) + [(0, tiles * rows - O), (0, 0)])
+    bits = bits.reshape(*lead, tiles, rows // WORD_ROWS, WORD_ROWS, nb)
     n = len(lead)
     return bits.transpose(*range(n + 1), n + 3, n + 2, n + 1).reshape(
-        *lead, O // rows, nb, rows)
+        *lead, tiles, nb, rows)
 
 
 def _pair_columns(g, u):

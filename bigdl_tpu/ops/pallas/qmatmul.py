@@ -27,8 +27,9 @@ model). This module is tiling + epilogue: grid over (M tiles, O tiles),
 and `qdecode.tile_product`'s chunk loop over K, which bounds live
 dequant temporaries to O(block_o * chunk) regardless of K. Where a 512-row tile fits, that loop runs on the tile read as
 32-bit words and transposed once (k on sublanes, a block's scale a
-sublane broadcast, docs/kernels.md#word-path); elsewhere in the stored
-layout.
+sublane broadcast, docs/kernels.md#word-path), the last tile ragged where
+O is no multiple of 512 (`tiling.ragged_word_tiles`: an LM head's
+vocabulary); elsewhere in the stored layout.
 
 Layout contract (quant/numerics.py pack_nibbles / pack_planes): the
 m-th split of a b-bit plane is a *contiguous* byte range unpacked with
@@ -153,7 +154,17 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
     first be copied whole, every call. Everything else (`rest`: scales,
     LoRA operands) is one layer's own rank-2 array, unless ``bits`` names
     the layout of prepared scale bits (`bits_layout`): those keep their
-    layer axis too and are read by the same index."""
+    layer axis too and are read by the same index.
+
+    The O grid is `cdiv(O, block_o)`: where the plan is the word path over
+    an O that is no multiple of 512, the last step's code block (and the
+    float16 view of the scales, where nobody prepared bits) is partial:
+    the DMA brings its valid rows, the rest of the buffer is whatever it
+    held. A row of the tile is a column of the product from the decode to
+    the store (`natural_columns` permutes columns within the tile), so
+    nothing past O reaches a column below it, a NaN included, and the
+    output block's columns past O are dropped at the store: `out_shape`
+    stays `(Mp, O)`, and nobody slices or masks."""
     Mp, K = x2.shape
     O = w.shape[1]
     if lora:
@@ -167,7 +178,7 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
         pl.BlockSpec((None, block_o, w.shape[2]),
                      lambda m, o, l: (l[0], o, 0), memory_space=pltpu.VMEM),
     ]
-    if bits == "words":  # [L, O / 512, nb, 512]: the tile's own block
+    if bits == "words":  # [L, word_tiles(O), nb, 512]: the tile's own block
         in_specs += [
             pl.BlockSpec((None, None, *a.shape[2:]),
                          lambda m, o, l: (l[0], o, 0, 0),
@@ -213,7 +224,10 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
         name="qmatmul_lora" if lora else "qmatmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(Mp // block_m, O // block_o),
+            # a ragged last word tile (`tiling.ragged_word_tiles`): its
+            # code block's rows past O are whatever the buffer held, and
+            # the columns they decode to are not stored
+            grid=(Mp // block_m, pl.cdiv(O, block_o)),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
                 (block_m, block_o), lambda m, o, l: (m, o),
@@ -259,7 +273,9 @@ def tile_form(spec: DecodeSpec, O: int, row_bytes: int,
               cap: int = WORD_BLOCK_O) -> str:
     """Which loop decodes `_fused`'s tiles of a weight `[O, row_bytes]` of
     codes (no adapter), from the static shapes its tile plan is picked
-    from: ``"words"`` the word path, ``"stored"`` the stored-layout loop.
+    from: ``"words"`` the word path (over `tiling.word_tiles(O)` tiles, the
+    last one ragged where O is no multiple of 512), ``"stored"`` the
+    stored-layout loop.
     (A row's side bytes are priced as float16 a block and side array: what
     a single-level format holds, and more than a two-level one does.)"""
     nb = row_bytes * 8 // (sum(spec.planes) or 8) // spec.block
@@ -272,7 +288,8 @@ def bits_layout(spec: DecodeSpec, O: int, row_bytes: int,
                 cap: int = WORD_BLOCK_O):
     """How `_fused` reads prepared scale bits for a weight `[O, row_bytes]`
     of codes: `tile_form`'s name (``"words"``: `qdecode.pack_major_bits` of
-    512-row tiles; ``"stored"``: the uint16 view, made once), None for the
+    512-row tiles, a ragged last one filled with zeros; ``"stored"``: the
+    uint16 view, made once), None for the
     two-level formats, whose effective scales are products the kernel forms
     in the stored layout."""
     return None if spec.super_block else tile_form(spec, O, row_bytes, cap)

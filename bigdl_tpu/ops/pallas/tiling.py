@@ -94,16 +94,39 @@ def words_ok(block_o: int, row_bytes: int) -> bool:
     return block_o == WORD_BLOCK_O and row_bytes % 128 == 0
 
 
+def word_tiles(O: int) -> int:
+    """Word tiles that cover O rows, the last one ragged where
+    `WORD_BLOCK_O` does not divide O."""
+    return -(-O // WORD_BLOCK_O)
+
+
+def ragged_word_tiles(O: int) -> bool:
+    """May an O that is no multiple of `WORD_BLOCK_O` run the word path
+    over `word_tiles(O)` tiles, the last one ragged? Its rows must be whole
+    128-lane groups (the output block's valid columns, the code block's
+    valid rows), and at least one whole tile must come before the ragged
+    one, so that under half of what is decoded is padding. The threshold
+    is where `scripts/qmatmul_kernel_bench.py --plan ragged` put it (a
+    v5e, PR 55, the stored-layout loop's time over the ragged form's at
+    K = 2048 / 4096): O = 128 0.72 / 0.79, 256 0.88 / 0.93, 384 1.03 /
+    1.09 (one tile, mostly padding: the loop keeps them), 640 1.23 / 1.06,
+    768 1.10 / 1.15, 1152 1.27 / 1.22, and 1.55 to 2.67 at the LM heads'
+    32000 to 154880 rows, which waste 128 to 384 of them."""
+    return O % 128 == 0 and O > WORD_BLOCK_O
+
+
 def pick_block_o(O: int, persist_per_row: int, cap: int = WORD_BLOCK_O,
                  row_bytes: int = 0) -> int:
-    """The O tile. `WORD_BLOCK_O` rows where the word path can run (O a
-    multiple of it, `row_bytes` given and lane-aligned, the tile and its
-    transposed copy within `WORDS_VMEM_BYTES`); else the largest
-    lane-legal tile for the stored-layout loop: a multiple of 128
-    dividing O (256 preferred, 128 if the per-row persistent footprint is
-    large or the caller caps it), else the full dim (always legal —
-    Mosaic pads)."""
-    if (cap >= WORD_BLOCK_O and O % WORD_BLOCK_O == 0
+    """The O tile. `WORD_BLOCK_O` rows where the word path can run
+    (`row_bytes` given and lane-aligned, the tile and its transposed copy
+    within `WORDS_VMEM_BYTES`, and O a multiple of the tile or
+    `ragged_word_tiles`: the grid is then `word_tiles(O)` and the last
+    tile's rows past O are never stored); else the largest lane-legal tile
+    for the stored-layout loop: a multiple of 128 dividing O (256
+    preferred, 128 if the per-row persistent footprint is large or the
+    caller caps it), else the full dim (always legal: Mosaic pads)."""
+    if (cap >= WORD_BLOCK_O
+            and (O % WORD_BLOCK_O == 0 or ragged_word_tiles(O))
             and row_bytes and words_ok(WORD_BLOCK_O, row_bytes)
             and words_tile_bytes(row_bytes, persist_per_row)
             <= WORDS_VMEM_BYTES):
@@ -151,8 +174,10 @@ def grouped_tile(O: int, persist_per_row: int, row_bytes: int,
             and words_ok(WORD_BLOCK_O, row_bytes)
             and words_tile_bytes(row_bytes, persist_per_row)
             <= WORDS_VMEM_BYTES):
-        rows = pick_block_o(O, persist_per_row * stacks,
-                            row_bytes=row_bytes * stacks)
+        # (whole tiles only: the grouped kernel has no ragged one)
+        rows = pick_block_o(
+            O, persist_per_row * stacks,
+            row_bytes=row_bytes * stacks if O % WORD_BLOCK_O == 0 else 0)
         if not words_ok(rows, row_bytes):
             return "loop", rows, 1
         form = "words"

@@ -7,6 +7,7 @@ through the chip tool:
     python scripts/qmatmul_kernel_bench.py [--plan cells|mistral|quick]
     python scripts/qmatmul_kernel_bench.py --plan experts   # the grouped kernel
     python scripts/qmatmul_kernel_bench.py --plan experts --variants tree shared
+    python scripts/qmatmul_kernel_bench.py --plan ragged    # O no multiple of 512
     python scripts/qmatmul_kernel_bench.py --lower   # compile only, no chip
 
 Each line is one (body, variant, K, O, M): 64 dependent calls inside one
@@ -21,6 +22,15 @@ Bodies:
   scales viewed as uint16 and staged every grid step; `prep`: the same on
   prepared scale bits (`qdecode.pack_major_bits`), which is what a cell
   runs since PR 48;
+* `loop` and `ragged` (`--plan ragged`, PR 55), the tree's `_qmm` on
+  prepared bits in the two forms an O that is no multiple of 512 can take:
+  the stored-layout loop at `pick_block_o`'s 256- or 128-row tile (what the
+  cells' heads ran until PR 55), and the word path over `word_tiles(O)`
+  tiles, the last one ragged, WHATEVER `tiling.ragged_word_tiles` says of
+  that O: the plan's small O (under one tile, then one to four whole tiles
+  and a remainder) are where that rule's threshold was read from. These
+  calls take microseconds, so the plan times 64, 128 and 256 calls, best of
+  five, and still repeats to 10% only: read a ratio from several runs;
 * `words`: this script's copy of the word path (`qdecode.tile_product`
   with scratch): the tile read as 32-bit words, transposed once, decoded
   with k on sublanes. A variant is letters joined by `-`. The decode of a
@@ -89,7 +99,7 @@ from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
 from bigdl_tpu.ops.pallas.tiling import (
     VMEM_LIMIT_BYTES, finest_split, forward_chunk, pick_block_m, pick_block_o,
-    round_up, words_ok,
+    round_up, word_tiles, words_ok,
 )
 
 SPEC = DecodeSpec(planes=(4,), value=("offset", 8), block=32)
@@ -292,13 +302,20 @@ def qmm_copy(layer, x2, w, s, *, block_m, block_o, ck, body, variant):
     )(layer, x2, w, s)
 
 
+# the bodies that are the tree's `_qmm` on prepared scale bits, and the
+# layout each reads (`qmatmul.bits_layout`)
+BITS = {"prep": "words", "ragged": "words", "loop": "stored"}
+
+
 def tiles(body, M, K, O):
     """The policy's tiles: the tree's for `tree`, `prep` and `words`, the
-    256-row ones the stored-layout loop had for `rows`."""
+    stored-layout loop's (256 rows at most) for `rows` and `loop`, the word
+    tile whatever the policy says of that O for `ragged`."""
     block_m = pick_block_m(M, K)
     persist_row = K // 2 + (K // 32) * 2
-    block_o = pick_block_o(O, persist_row, cap=256 if body == "rows" else 512,
-                           row_bytes=K // 2)
+    block_o = 512 if body == "ragged" else pick_block_o(
+        O, persist_row, cap=256 if body in ("rows", "loop") else 512,
+        row_bytes=K // 2)
     persist = block_o * persist_row + block_m * K * 2 + block_m * block_o * 4
     ck = forward_chunk(words_ok(block_o, K // 2), block_o, persist,
                        finest_split(K, SPEC.planes), SPEC.block, False)
@@ -310,13 +327,12 @@ def build(body, variant, M, K, O):
     block_m, block_o, ck = tiles(body, M, K, O)
     if body in ("words", "prep") and not words_ok(block_o, K // 2):
         return None
-    if body in ("tree", "prep"):
+    if body in ("tree", *BITS):
         qm = importlib.import_module("bigdl_tpu.ops.pallas.qmatmul")
 
         def call(layer, x, w, s):
             return qm._qmm(SPEC, jnp.dtype(jnp.bfloat16), block_m, block_o,
-                           ck, False, False, "words" if body == "prep"
-                           else None, layer, x, w, s)
+                           ck, False, False, BITS.get(body), layer, x, w, s)
     else:
         def call(layer, x, w, s):
             return qmm_copy(layer, x, w, s, block_m=block_m, block_o=block_o,
@@ -336,24 +352,27 @@ def build(body, variant, M, K, O):
     return run, call, (block_m, block_o, ck)
 
 
-def operands(M, K, O, block_m, key, sharding=None, prepared=False):
+def operands(M, K, O, block_m, key, sharding=None, prepared=None):
     """x, the codes of two layers and one layer's scale bits as stored
-    `[O, nb]`, or ``prepared`` (`qdecode.pack_major_bits`, both layers')."""
+    `[O, nb]`, or both layers' ``prepared`` in a layout of `BITS`
+    (`qdecode.pack_major_bits`; the stored `[2, O, nb]`)."""
     Mp = round_up(M, block_m)
     if sharding is not None:  # shapes alone, for a described device
         sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=sharding)
         return (sds((Mp, K), jnp.bfloat16), sds((2, O, K // 2), jnp.uint8),
-                sds((2, O // 512, K // 32, 512) if prepared
-                    else (O, K // 32), jnp.uint16))
+                sds({"words": (2, word_tiles(O), K // 32, 512),
+                     "stored": (2, O, K // 32), None: (O, K // 32)}[prepared],
+                    jnp.uint16))
     k1, k2, k3 = jax.random.split(key, 3)
     x = jax.random.normal(k1, (Mp, K), jnp.float32).astype(jnp.bfloat16)
     w = jax.random.randint(k2, (2, O, K // 2), 0, 256, jnp.int32
                            ).astype(jnp.uint8)
     s = (jax.random.uniform(k3, (O, K // 32)) * 0.01 + 0.001
          ).astype(jnp.float16)
-    if prepared:
+    if prepared == "words":
         return x, w, qdecode.pack_major_bits(jnp.stack([s, s]), 512)
-    return x, w, jax.lax.bitcast_convert_type(s, jnp.uint16)
+    bits = jax.lax.bitcast_convert_type(s, jnp.uint16)
+    return x, w, jnp.stack([bits, bits]) if prepared else bits
 
 
 def measure(body, variant, M, K, O, key, ns=(16, 32, 64), reps=3):
@@ -361,7 +380,7 @@ def measure(body, variant, M, K, O, key, ns=(16, 32, 64), reps=3):
     if built is None:
         return None
     run, _, (block_m, block_o, ck) = built
-    x, w, s = operands(M, K, O, block_m, key, prepared=body == "prep")
+    x, w, s = operands(M, K, O, block_m, key, prepared=BITS.get(body))
     jax.block_until_ready(run(2, x, w, s))
     ts = []
     for n in ns:
@@ -480,6 +499,26 @@ def product_check():
         yield dict(check="product_vs_xla", M=M, K=K, O=O,
                    worst=float(jnp.abs(y - want).max()),
                    of=float(jnp.abs(want).max()))
+
+
+def ragged_check():
+    """The ragged word tile against the stored-layout loop on the same
+    operands, both the tree's `_qmm` on prepared bits: bf16 outputs of the
+    same float32 sums in another order, a bf16 step apart at most, and
+    every column finite (nothing of the ragged tile's buffer past O shows)."""
+    for K, O, M in ((4096, 32000, 32), (4096, 16768, 32), (2048, 768, 32),
+                    (5120, 151936, 8), (4096, 32000, 256)):
+        ys = []
+        for body in ("loop", "ragged"):
+            _, call, (block_m, _, _) = build(body, "d", M, K, O)
+            x, w, s = operands(M, K, O, block_m, jax.random.key(O + M),
+                               prepared=BITS[body])
+            ys.append(np.asarray(call(jnp.ones((1,), jnp.int32), x, w, s
+                                      ).astype(jnp.float32)))
+        yield dict(check="ragged_vs_loop", M=M, K=K, O=O,
+                   worst=float(np.abs(ys[1] - ys[0]).max()),
+                   of=float(np.abs(ys[0]).max()),
+                   finite=bool(np.isfinite(ys[1]).all()))
 
 
 # ------------------------------------------------- the grouped expert kernel
@@ -781,6 +820,21 @@ def experts_plan():
 
 # ----------------------------------------------------------------- the plans
 
+# (K, O, M) of every `linear` of a cell whose O is no multiple of 512 (the
+# route tables of the twelve cells' set-up logs, PR 54), at the rows the
+# cell's decode step has: the seven heads (Mistral's at chat-steady's 32
+# rows, `generate`'s one and Mixtral's 16), granite's Mamba `in_proj`, GLM's
+# O = 768 and MiniCPM-SALA's O = 256; then, for the threshold, under one
+# tile and one to four whole tiles and a remainder, at two K
+RAGGED_SHAPES = (
+    (4096, 32000, 32), (4096, 32000, 1), (4096, 32000, 16),
+    (5120, 151936, 8), (2560, 151936, 16), (2048, 151936, 64),
+    (2048, 154880, 32), (4096, 73472, 16), (4096, 16768, 32),
+    (2048, 768, 32), (4096, 256, 16),
+    *((K, O, M) for K, M in ((2048, 32), (4096, 16))
+      for O in (128, 256, 384, 640, 896, 1152, 1408, 1664, 2176)),
+)
+
 def cell_shapes():
     """{configuration: ((K, O) of its decode step's distinct projections,
     the rows its cell's decode step has)}."""
@@ -809,6 +863,9 @@ def plan_of(name):
                 plan += [("words", v, M, K, O)
                          for v in ("d", "d-split", "d-roll", "d-split-roll")]
         return plan
+    if name == "ragged":
+        return [(b, "d", M, K, O) for K, O, M in RAGGED_SHAPES
+                for b in ("loop", "ragged")]
     shapes = cell_shapes()
     if name == "mistral":  # every variant, at the cells' M
         for K, O in shapes["mistral-7b-int4"][0]:
@@ -835,7 +892,8 @@ def plan_of(name):
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--plan", default="cells",
-                    choices=("cells", "mistral", "quick", "forms", "experts"))
+                    choices=("cells", "mistral", "quick", "forms", "experts",
+                             "ragged"))
     ap.add_argument("--lower", "--fit", action="store_true",
                     help="compile the plan for a described v5e; no chip")
     ap.add_argument("--variants", nargs="+",
@@ -870,7 +928,7 @@ def main() -> int:
                 continue
             run, _, (block_m, _, _) = built
             run.lower(jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
-                      *operands(M, K, O, block_m, None, one, body == "prep")
+                      *operands(M, K, O, block_m, None, one, BITS.get(body))
                       ).compile()
             print(f"ok {body} {v} M={M} K={K} O={O}", flush=True)
         return 0
@@ -887,8 +945,11 @@ def main() -> int:
             checks = experts_check()
             results = (experts_measure(*case, key) for case in plan)
         else:
-            checks = (*fed_weights_check(), *product_check())
-            results = (measure(*case, key) for case in plan)
+            checks = (ragged_check() if args.plan == "ragged"
+                      else (*fed_weights_check(), *product_check()))
+            times = (dict(ns=(64, 128, 256), reps=5)
+                     if args.plan == "ragged" else {})
+            results = (measure(*case, key, **times) for case in plan)
         for r in (*checks, *results):
             if r is not None:
                 print(json.dumps(r), flush=True)

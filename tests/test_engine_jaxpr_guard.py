@@ -306,6 +306,48 @@ def test_a_prepared_trees_decode_scan_neither_views_nor_slices_a_scale(
     assert (n_stored, n_prepared) == KERNEL_ROUTE[name]
 
 
+# ---- the head's route note names the loop it ran (ISSUE 55) ----------------
+#
+# A vocabulary of whole lanes that is no multiple of 512 (32000, 151936,
+# 154880, 73472: seven of the ten configurations) ran its head on the
+# stored-layout loop until PR 55; it runs the word path over a ragged last
+# tile, and the `linear` route line every bench run prints says which.
+
+@pytest.mark.core
+@pytest.mark.parametrize("vocab,note", [
+    (1024, "words:inplace"), (1024 + 128, "words:inplace:ragged:3")])
+def test_the_heads_route_note_names_its_word_form(monkeypatch, vocab, note):
+    import jax
+
+    from bigdl_tpu.api import TpuModel, optimize_model
+    from bigdl_tpu.models import get_family
+    from bigdl_tpu.models.config import ModelConfig
+    from bigdl_tpu.ops.routes import record_routes
+    from bigdl_tpu.serving.engine import InferenceEngine
+
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+    cfg = ModelConfig.from_hf_config(
+        dict(GUARDED["mistral"], vocab_size=vocab))
+    model = TpuModel(cfg, optimize_model(
+        get_family(cfg.model_type).init_params(cfg, jax.random.PRNGKey(0)),
+        cfg, "sym_int4"), "sym_int4")
+    eng = InferenceEngine(model, n_slots=4, max_len=256, paged=True,
+                          page_size=16, n_pages=65)
+    with record_routes() as routes:
+        jax.make_jaxpr(getattr(eng._decode, "__wrapped__", eng._decode))(
+            *decode_args(eng, model.params))
+    # qtype M K O, codes sliced (outside the scan), the loop, the scales
+    heads = [d.split() for op, route, d in routes
+             if op == "linear" and {f"O{vocab}", "slice"} <= set(d.split())]
+    assert heads and all(
+        route.startswith("pallas:") for op, route, d in routes
+        if op == "linear")
+    assert all(d[4:] == ["slice", note, "scales:stack"] for d in heads), heads
+    # the projections in the scan are whole tiles: their notes did not move
+    assert all("ragged" not in d for op, _, d in routes
+               if op == "linear" and "stack" in d.split())
+
+
 # GLM's decode step with the kernels on, outside the kernels' bodies: 928 on
 # PR 50's tree, and 930 since PR 51 gave the latent decode kernel its
 # sibling's scalar operand (`paged_attention._scalars`: the window's entry, one

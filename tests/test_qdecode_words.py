@@ -34,7 +34,7 @@ from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qmatmul import _side_arrays, qmatmul
 from bigdl_tpu.ops.pallas.tiling import (
     WORD_BLOCK_O, WORD_ROWS, chunk_spans, finest_split, grouped_tile,
-    words_chunk, words_ok,
+    word_tiles, words_chunk, words_ok,
 )
 from bigdl_tpu.quant import quantize
 
@@ -291,6 +291,99 @@ def test_every_format_takes_the_word_path(interpret, qtype):
         old = qmatmul(x, qt, out_dtype=jnp.float32, block_o=256)
         np.testing.assert_allclose(np.asarray(y), np.asarray(old), rtol=0,
                                    atol=5e-5, err_msg=f"{qtype} M={M}")
+
+
+# ---- a ragged last word tile (ISSUE 55) ------------------------------------
+
+def _poisoned_from(qt, row):
+    """`qt` with every field's rows from `row` on set to all-ones bits:
+    codes 0xFF, NaN scales and mins, the largest sub-scales."""
+    import dataclasses
+
+    def ones(a):
+        if a is None:
+            return None
+        bits = jnp.full(a.shape[1:], -1, jnp.int8 if a.dtype.itemsize == 1
+                        else jnp.int16)
+        return a.at[row:].set(jax.lax.bitcast_convert_type(bits, a.dtype))
+    return dataclasses.replace(qt, **{
+        f: ones(getattr(qt, f))
+        for f in ("data", "scales", "mins", "sub_scales", "sub_mins")})
+
+
+def _staged_and_prepared():
+    """Every format with its scales staged; the single-level ones prepared
+    too (a two-level format's effective scales are products the kernel
+    forms: nothing is prepared for it)."""
+    from bigdl_tpu.quant.qtypes import resolve_qtype
+
+    names = sorted(_QGEMV_QTYPES)
+    return [(q, False) for q in names] + [
+        (q, True) for q in names if not resolve_qtype(q).superblock]
+
+
+@pytest.mark.parametrize(
+    "qtype,prepared", _staged_and_prepared(),
+    ids=lambda v: v if isinstance(v, str) else ("staged", "prepared")[v])
+def test_rows_past_a_ragged_tiles_end_reach_no_row_before_it(qtype,
+                                                             prepared):
+    """The last word tile of an O that is no multiple of 512 holds, past O,
+    whatever its buffer held. Rows 128.. of a tile POISONED (all-ones bits
+    in every field): rows 0..127 decode to the dequantizer's weights bit
+    for bit, for every format, scales staged and (single-level formats)
+    prepared. The words' transpose, the strided scale reads and the packs
+    keep a row of the tile in its own lanes."""
+    K, valid = 1024, 128
+    qt = _weights(qtype, WORD_BLOCK_O, K, seed=11)
+    want = np.asarray(qt.dequantize(jnp.bfloat16).astype(jnp.float32))
+    got = np.asarray(_decoded_by_words(
+        _poisoned_from(qt, valid), K, prepared=prepared
+    ).astype(jnp.float32))
+    np.testing.assert_array_equal(got[:valid], want[:valid])
+    assert (got[valid:] != want[valid:]).any()  # the poison was decoded
+
+
+@pytest.mark.parametrize("rows,O", [(512, 640), (512, 1408), (256, 768),
+                                    (512, 1024)])
+def test_pack_major_bits_fills_a_ragged_tile_with_zeros(rows, O):
+    """`[.., O, nb]` -> `[.., ceil(O / rows), nb, rows]`: the whole tiles as
+    they always were, the ragged one the pack of its rows over zeros (a
+    float16 +0.0: the padded rows decode to 0, whatever their codes)."""
+    nb = 24
+    a = (jax.random.uniform(jax.random.PRNGKey(O), (3, O, nb)) + 0.5
+         ).astype(jnp.float16)
+    got = qdecode.pack_major_bits(a, rows)
+    tiles = -(-O // rows)
+    assert got.shape == (3, tiles, nb, rows) and got.dtype == jnp.uint16
+    padded = jnp.pad(a, ((0, 0), (0, tiles * rows - O), (0, 0)))
+    for t in range(tiles):
+        np.testing.assert_array_equal(
+            np.asarray(got[:, t]), np.asarray(qdecode.pack_major_bits(
+                padded[:, t * rows:(t + 1) * rows], rows)[:, 0]))
+    if rows == WORD_BLOCK_O:
+        assert tiles == word_tiles(O)
+    # column p * rows / 4 + i of a tile is its row 4 i + p
+    last = np.asarray(got[:, -1]).reshape(3, nb, WORD_ROWS, rows // WORD_ROWS)
+    n = (O - (tiles - 1) * rows) // WORD_ROWS
+    assert last[..., :n].all() and not last[..., n:].any()
+
+
+@pytest.mark.parametrize("qtype", sorted(_QGEMV_QTYPES))
+def test_every_format_takes_the_ragged_tile(interpret, qtype):
+    """GEMV rows through the whole kernel at O = 512 + 128, every format
+    (the two-level ones bring FOUR partial side blocks): the word path over
+    two tiles against the reference and against the stored-layout loop
+    (the same weights, capped to 128-row tiles)."""
+    K, O = 1024, WORD_BLOCK_O + 128
+    qt = _weights(qtype, O, K, seed=4)
+    x = jax.random.normal(jax.random.PRNGKey(8), (8, K)).astype(jnp.bfloat16)
+    y = qmatmul(x, qt, out_dtype=jnp.float32)
+    assert y.shape == (8, O)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(_reference(x, qt)),
+                               rtol=0, atol=5e-5, err_msg=qtype)
+    old = qmatmul(x, qt, out_dtype=jnp.float32, block_o=256)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(old), rtol=0,
+                               atol=5e-5, err_msg=qtype)
 
 
 # (K, O, act, block_m): Mixtral's two contractions on 512-row word tiles,

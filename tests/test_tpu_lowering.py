@@ -42,7 +42,8 @@ def _sds(shape, dtype, sharding):
 # configurations) with the rows the cell's decode step has, and a prefill's
 # row tile at each configuration's widest contraction
 _DENSE = [
-    # Mistral-7B: wqkv, wo, w_gateup, w_down, head (32000: no 512 tile)
+    # Mistral-7B: wqkv, wo, w_gateup, w_down, head (32000: 62 word tiles
+    # and a ragged one of 256 rows since ISSUE 55, as Brumby's 151936 below)
     (4096, 6144, 1), (4096, 6144, 32), (4096, 4096, 32), (4096, 28672, 32),
     (14336, 4096, 32), (4096, 32000, 32), (14336, 4096, 256),
     # Qwen2-7B
@@ -51,6 +52,12 @@ _DENSE = [
     # Brumby-14B
     (5120, 7168, 8), (5120, 5120, 8), (5120, 34816, 8), (17408, 5120, 8),
     (5120, 151936, 8), (17408, 5120, 256),
+    # ISSUE 55: the other ragged heads (SmallThinker, SDAR's 64 rows a pass,
+    # GLM, MiniCPM-SALA), a prefill's head over longprompt's rows, granite's
+    # Mamba `in_proj` and GLM's O = 768; MiniCPM-SALA's O = 256 keeps the loop
+    (2560, 151936, 16), (2048, 151936, 64), (2048, 154880, 32),
+    (4096, 73472, 16), (4096, 32000, 1792), (4096, 16768, 32),
+    (2048, 768, 32), (4096, 256, 16),
 ]
 
 
@@ -76,14 +83,18 @@ def _format_names():
     return sorted(_QGEMV_QTYPES)
 
 
-@pytest.mark.parametrize("O", (1024, 768), ids=("words", "rows"))
+@pytest.mark.parametrize("O", (1024, 768, 384),
+                         ids=("words", "ragged", "rows"))
 @pytest.mark.parametrize("qtype", _format_names())
 def test_every_format_compiles_on_both_loops(one_chip, qtype, O):
     """GEMV rows and a GEMM row tile, K = 2048, on the word path (two
-    512-row tiles) and on the stored-layout loop (O = 768 has no 512-row
-    tile): each format's planes, value decode and scale levels through
-    Mosaic. (The k-quants encode on the host, so the fields' shapes come
-    from a real, small quantization and not from `eval_shape`.)"""
+    512-row tiles; since ISSUE 55 a whole tile and a ragged one of 256
+    rows too, every operand block of the last grid step partial: codes,
+    each format's one to four side arrays, the output) and on the
+    stored-layout loop (O = 384 has no whole tile): each format's planes,
+    value decode and scale levels through Mosaic. (The k-quants encode on
+    the host, so the fields' shapes come from a real, small quantization
+    and not from `eval_shape`.)"""
     from bigdl_tpu.ops.pallas.qmatmul import qmatmul
     from bigdl_tpu.ops.pallas.tiling import WORD_BLOCK_O, pick_block_o
     from bigdl_tpu.quant import quantize
@@ -92,7 +103,7 @@ def test_every_format_compiles_on_both_loops(one_chip, qtype, O):
     qt = quantize(jnp.zeros((O, K), jnp.float32), qtype)
     rb = qt.data.shape[1] * qt.data.dtype.itemsize
     assert (pick_block_o(O, 2 * rb, row_bytes=rb) == WORD_BLOCK_O) \
-        == (O == 1024)
+        == (O != 384)
     assert qt.qtype == qtype
     qt = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), qt)
     for M in (8, 256):
@@ -256,19 +267,26 @@ def _mosaic_bodies(lowered_text):
 
 
 # sha256 of the dense `qmatmul`'s Mosaic module (wqkv at 32 rows, w_down at
-# a prefill's 256, Mistral's head on the stored-layout loop, Qwen2's wqkv
-# whose chunks are Python's loop): the word path's three on PR 49's tree
-# (a sym_int4 nibble cut out signed: the chunk loop's equations changed, on
-# purpose), the stored-layout loop's as it has been since PR 41
+# a prefill's 256, Mistral's head, Qwen2's wqkv whose chunks are Python's
+# loop, MiniCPM-SALA's O = 256 on the stored-layout loop): the word path's
+# three on PR 49's tree (a sym_int4 nibble cut out signed: the chunk loop's
+# equations changed, on purpose); Mistral's head re-pinned by ISSUE 55, on
+# purpose (48b35979... on the stored-layout loop at 256-row tiles until
+# then; the word path over 63 tiles now, its grid and block shapes alone
+# differ from wqkv's module); the stored-layout loop's own program, which
+# ISSUE 55 left alone, pinned at a shape its rule leaves there (PR 54's
+# tree gives the same hash)
 _DENSE_BODIES = {
     (4096, 6144, 32):
         "2fc2dc3cb2ef5b2b5e9808c79310efe8895849f0dfed9379ffd5cdd72c5921f4",
     (14336, 4096, 256):
         "b24b8181ff570e2dc4f492c2fd26355abaf6f151da5be58ccf21870e8eb4f63f",
     (4096, 32000, 32):
-        "48b359797facf56403dd5372088f6d9c0a56b9c6a174e51f1e3dfdd52f6b8f51",
+        "5d88cbbccc37d52b837c78c623a1c617da6f8de23cd8815f5917cd07b75a1c1f",
     (3584, 4608, 16):
         "94a9a360d80d336bdfd50d1a02bc73e74d57d514b0c65450039b2705eee60395",
+    (4096, 256, 16):
+        "c2eefe715c2f667fe3ec3adbd3d76447902b6090cddd43dd6585c190b581b851",
 }
 
 
@@ -324,24 +342,32 @@ def _prepared(w, stacks, chip):
 
 # (K, O, M, qtype, in a layer stack?): a wqkv, a prefill's w_down (nb = 448),
 # Qwen2's w_down (nb = 592) and its head outside the scan (297 tiles of
-# nb = 112), Mistral's head on the stored-layout loop, a format with mins
+# nb = 112), a format with mins; since ISSUE 55 the ragged last word tile on
+# bits padded to whole tiles (Mistral's head, Brumby's, granite's `in_proj`
+# and GLM's O = 768 in their stacks, mins), and the stored-layout loop at
+# what it keeps (MiniCPM-SALA's O = 256)
 _PREPARED_DENSE = {
     "wqkv": (4096, 6144, 32, "sym_int4", True),
     "w_down-prefill": (14336, 4096, 256, "sym_int4", True),
     "qwen2-w_down": (18944, 3584, 16, "sym_int4", True),
     "qwen2-head": (3584, 152064, 16, "sym_int4", False),
-    "mistral-head-stored": (4096, 32000, 32, "sym_int4", False),
-    "stored-in-a-stack": (4096, 768, 32, "sym_int4", True),
+    "mistral-head-ragged": (4096, 32000, 32, "sym_int4", False),
+    "ragged-in-a-stack": (4096, 768, 32, "sym_int4", True),
     "mins": (2048, 1024, 8, "asym_int4", True),
+    "brumby-head-ragged": (5120, 151936, 8, "sym_int4", False),
+    "granite-in_proj-ragged": (4096, 16768, 32, "sym_int4", True),
+    "mins-ragged": (2048, 1280, 8, "asym_int4", True),
+    "stored-in-a-stack": (4096, 256, 16, "sym_int4", True),
 }
 
 
 @pytest.mark.parametrize("name", list(_PREPARED_DENSE))
 def test_qmatmul_compiles_on_prepared_scale_bits(one_chip, monkeypatch, name):
     """Mosaic takes the operand blocks of a call that reads its scales in
-    place: `[nb, 512]` uint16 of `[L, O / 512, nb, 512]` by layer and tile
-    (whole (16, 128) tiles at every cell's nb), the stored `[O, nb]` on the
-    loop; and XLA hands the stack over as it is."""
+    place: `[nb, 512]` uint16 of `[L, ceil(O / 512), nb, 512]` by layer and
+    tile (whole (16, 128) tiles at every cell's nb; a ragged last tile's
+    block is whole too, beside its partial code and output blocks), the
+    stored `[O, nb]` on the loop; and XLA hands the stack over as it is."""
     from bigdl_tpu.ops.pallas.qmatmul import qmatmul
     from bigdl_tpu.quant.qtensor import QTensor
 
@@ -354,6 +380,9 @@ def test_qmatmul_compiles_on_prepared_scale_bits(one_chip, monkeypatch, name):
         scales=side, mins=side if qtype == "asym_int4" else None),
         None, one_chip)
     assert w.bits_layout == ("stored" if "stored" in name else "words")
+    assert ("ragged" in name) == (w.bits_layout == "words" and O % 512 > 0)
+    if w.bits_layout == "words":
+        assert w.scale_bits.shape == (*lead, -(-O // 512), K // 32, 512)
     x = _sds((M, K), jnp.bfloat16, one_chip)
     if stacked:
         c = jax.jit(lambda x, w, l: qmatmul(x, w, interpret=False, layer=l)
