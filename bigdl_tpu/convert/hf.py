@@ -1249,6 +1249,60 @@ def _granitemoehybrid_tree(config: ModelConfig, get: Get, quant
     return runs, top
 
 
+def _jamba_tree(config: ModelConfig, get: Get, quant) -> tuple[list, dict]:
+    """Jamba (HF modeling_jamba, `num_experts` 1). Returns (one list of
+    per-layer dicts for each RUN of layers of one kind, top), `quant` applied
+    as tensors stream in. `mamba.in_proj` is [u | z] as it stands;
+    `conv1d.weight [E, 1, K]` becomes `conv_w [K, E]`; `A_log [E, N]` becomes
+    the decay rate `a = exp(A_log)` laid `[N, E]` (the state's layout) in
+    float16; `x_proj`, `dt_proj` and the three inner norms keep the load
+    dtype, `dt_proj.bias`, `D` and the convolution float32. With tied
+    embeddings the head is a packed copy of the table."""
+    from bigdl_tpu.models.jamba import layer_runs
+
+    def f32(x):
+        return jnp.asarray(np.asarray(x, np.float32))
+
+    def one(i: int, kind: str) -> dict:
+        p = f"model.layers.{i}."
+        d = {"attn_norm": get(p + "input_layernorm.weight"),
+             "mlp_norm": get(p + "pre_ff_layernorm.weight"),
+             "w_gate": get(p + "feed_forward.gate_proj.weight"),
+             "w_up": get(p + "feed_forward.up_proj.weight"),
+             "w_down": get(p + "feed_forward.down_proj.weight")}
+        if kind == "mamba":
+            m = p + "mamba."
+            d.update(w_in=get(m + "in_proj.weight"),
+                     w_out=get(m + "out_proj.weight"),
+                     w_x=get(m + "x_proj.weight"),
+                     w_dt=get(m + "dt_proj.weight"),
+                     dt_norm=get(m + "dt_layernorm.weight"),
+                     b_norm=get(m + "b_layernorm.weight"),
+                     c_norm=get(m + "c_layernorm.weight"))
+            exact = {
+                "conv_w": f32(np.asarray(get(m + "conv1d.weight"))[:, 0].T),
+                "conv_b": f32(get(m + "conv1d.bias")),
+                "dt_bias": f32(get(m + "dt_proj.bias")),
+                "D": f32(get(m + "D")),
+                "a": jnp.exp(f32(get(m + "A_log"))).T.astype(jnp.float16)}
+        else:
+            a = p + "self_attn."
+            d.update(wq=get(a + "q_proj.weight"), wk=get(a + "k_proj.weight"),
+                     wv=get(a + "v_proj.weight"), wo=get(a + "o_proj.weight"))
+            exact = {}
+        return {**{k: quant(k, v) for k, v in d.items()}, **exact}
+
+    runs, i = [], 0
+    for kind, _, n in layer_runs(config):
+        runs.append([one(i + j, kind) for j in range(n)])
+        i += n
+    top = {"embed": get("model.embed_tokens.weight"),
+           "final_norm": get("model.final_layernorm.weight")}
+    top["lm_head"] = (top["embed"] if config.tie_word_embeddings
+                      else get("lm_head.weight"))
+    return runs, top
+
+
 def _minicpm_sala_tree(config: ModelConfig, get: Get, quant
                        ) -> tuple[list, dict]:
     """MiniCPM-SALA. Returns (one list of per-layer dicts for each RUN of
@@ -1480,11 +1534,11 @@ def params_from_state_dict(
             params[k] = maybe_quant(k, v)
         return params
 
-    if config.model_type in ("granitemoehybrid", "minicpm_sala"):
-        tree = (_granitemoehybrid_tree
-                if config.model_type == "granitemoehybrid"
-                else _minicpm_sala_tree)
-        runs, top = tree(config, get_tensor, maybe_quant)
+    by_runs = {"granitemoehybrid": _granitemoehybrid_tree,
+               "minicpm_sala": _minicpm_sala_tree, "jamba": _jamba_tree}
+    if config.model_type in by_runs:
+        runs, top = by_runs[config.model_type](config, get_tensor,
+                                               maybe_quant)
         params = {"runs": {f"{r:02d}": stack_dicts(run)
                            for r, run in enumerate(runs)}}
         for k, v in top.items():

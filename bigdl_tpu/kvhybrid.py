@@ -19,6 +19,23 @@ nor written by a step, and an admission's prefill starts its row from zero
     ssm  [Lm, R, heads * head size, d_state]   float32, d_state on lanes
     k, v [La, n_pages, page, Hkv, D]           as kvpaged.PagedKVCache's
 
+The state's shape is the FAMILY's (`init_hybrid(state=, conv_rows=)`): what
+follows the row axis of `ssm`, the convolution's channels, and which axis of
+`conv` is the row. A Mamba-1 layer (Jamba, `models/jamba.py`; `mix1` below)
+convolves its E inner channels alone and keeps
+
+    conv [Lm, R, (d_conv - 1) * E]   a row's tail in one piece, its inputs
+                                     side by side on lanes (`conv_rows` 1)
+    ssm  [Lm, R, d_state, E]         the channels on lanes, the state index
+                                     on sublanes
+
+(its d_state of 16 would fill 16 of 128 lanes the other way round; and with
+256 rows between a tail's d_conv - 1 inputs XLA re-laid the whole `conv`
+array, 0.4 GB, into and out of every program that gathers or scatters rows of
+it, wherever an axis of 3 stood: scripts/engine_fit.py shows such a copy). Everything that books, parks,
+restores or counts a row (`row_nbytes`, `_spots`, `axes_of`, `row_view`, the
+spans) reads sizes and the row axis.
+
 The Mamba-2 recurrence of one head, with a_t = dt_t * A <= 0:
 
     h_t = exp(a_t) h_{t-1} + dt_t x_t (x) B_t        y_t = h_t C_t + D x_t
@@ -33,10 +50,21 @@ A position that is no token (left padding before `start`, the right padding
 of a bucket past `valid_len`) carries dt = 0 and a zero convolution input:
 no decay, no update, and the convolution's tail is taken at the last real
 token.
+
+The Mamba-1 recurrence of one channel d and state index n, with A[n, d] < 0
+and dt a CHANNEL's:
+
+    h_t[n, d] = exp(dt_t[d] A[n, d]) h_{t-1}[n, d] + dt_t[d] B_t[n] x_t[d]
+    y_t[d]    = sum_n C_t[n] h_t[n, d] + D[d] x_t[d]
+
+has no head and no matrix-product form: both a decode step and a one-row
+prefill run it through the kernels of ops/pallas/selective_scan.py (the
+state in place), else token by token in `jnp` (`scan1`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional
 
@@ -56,7 +84,7 @@ class HybridCache:
     k: jax.Array  # [La, n_pages, page, Hkv, D]
     v: jax.Array
     conv: jax.Array  # [Lm, d_conv - 1, R, C] float32
-    ssm: jax.Array  # [Lm, R, heads * head size, d_state] float32
+    ssm: jax.Array  # [Lm, R, *the family's state] float32
     block_tables: jax.Array  # [B, max_pages] int32, 0 = nobody's page
     pos: jax.Array  # [B] int32 next slot per row
     start: jax.Array  # [B] int32 first valid slot (left padding)
@@ -65,6 +93,10 @@ class HybridCache:
     # [B] int32: how many of the NEXT forward's T positions are tokens
     # (the engine's prefill pads a bucket on the right); None = all
     valid_len: Optional[jax.Array] = None
+    # the axis of `conv` that is the state row: 2 (`[Lm, K - 1, R, C]`) or
+    # 1 (`[Lm, R, (K - 1) * C]`); static
+    conv_rows: int = dataclasses.field(default=2,
+                                       metadata=dict(static=True))
 
     @property
     def page_size(self) -> int:
@@ -98,14 +130,20 @@ def init_hybrid(n_attn: int, n_mamba: int, n_pages: int, page_size: int,
                 n_kv_heads: int, head_dim: int, rows: int,
                 max_pages_per_row: int, conv_dim: int, d_conv: int,
                 inner: int, d_state: int, batch: Optional[int] = None,
-                dtype=jnp.bfloat16) -> HybridCache:
-    """Zeros: pages nobody holds and `rows` state rows."""
+                dtype=jnp.bfloat16, state: Optional[tuple] = None,
+                conv_rows: int = 2) -> HybridCache:
+    """Zeros: pages nobody holds and `rows` state rows, each layer's of
+    shape `state` (Mamba-2's `(inner, d_state)` where the family names
+    none), the convolution's tails with the rows on axis `conv_rows`."""
     b = rows if batch is None else batch
     kv = (n_attn, n_pages, page_size, n_kv_heads, head_dim)
+    tails = ((d_conv - 1, rows, conv_dim) if conv_rows == 2
+             else (rows, (d_conv - 1) * conv_dim))
     return HybridCache(
-        k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
-        conv=jnp.zeros((n_mamba, d_conv - 1, rows, conv_dim), jnp.float32),
-        ssm=jnp.zeros((n_mamba, rows, inner, d_state), jnp.float32),
+        k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype), conv_rows=conv_rows,
+        conv=jnp.zeros((n_mamba,) + tails, jnp.float32),
+        ssm=jnp.zeros((n_mamba, rows) + (state or (inner, d_state)),
+                      jnp.float32),
         block_tables=jnp.zeros((b, max_pages_per_row), jnp.int32),
         pos=jnp.zeros((b,), jnp.int32), start=jnp.zeros((b,), jnp.int32))
 
@@ -269,6 +307,143 @@ def mix(cache: HybridCache, layer, xbc, dt, A, D, conv_w, conv_b, *,
     return y, dataclasses.replace(cache, conv=conv, ssm=ssm)
 
 
+# ---------------------------------------------------------------------------
+# the Mamba-1 mixer's state arithmetic
+# ---------------------------------------------------------------------------
+
+def scan1(x, dt, A, Bm, Cm, h):
+    """The selective scan in `jnp`, token by token. x, dt [B, T, E] (dt 0
+    where the position is no token), A [N, E], Bm, Cm [B, T, N], h [B, N,
+    E], all float32. Returns (y [B, T, E] without the D term, h after the T
+    tokens)."""
+    def one(h, t):
+        xt, dtt, bt, ct = t
+        h = (jnp.exp(dtt[:, None] * A) * h
+             + (dtt * xt)[:, None] * bt[..., None])
+        return h, jnp.sum(h * ct[..., None], axis=1)
+
+    h, y = jax.lax.scan(one, h, tuple(
+        jnp.moveaxis(a, 1, 0) for a in (x, dt, Bm, Cm)))
+    return jnp.moveaxis(y, 0, 1), h
+
+
+def conv_step(tail, u, w, b):
+    """`causal_conv` for ONE token on tails laid side by side: `tail [B,
+    (K - 1) * C]` (the K - 1 inputs before this one, oldest first), `u [B,
+    C]`, float32. Returns (out [B, C], the tail after this token). Every
+    piece is whole lane tiles where C is: no input changes its layout."""
+    K, C = w.shape[0], u.shape[-1]
+    window = jnp.concatenate([tail, u], axis=1)  # [B, K * C]
+    out = b + sum(window[:, k * C:(k + 1) * C] * w[k] for k in range(K))
+    return out, window[:, C:]
+
+
+def why_not_scan_kernel(d_state: int, inner: int) -> Optional[str]:
+    """None when a Mamba-1 layer takes the kernels of `selective_scan`."""
+    from bigdl_tpu.ops.pallas import interpret_mode, why_not_pallas
+
+    why = why_not_pallas()
+    if why is None and inner % 128:
+        why = f"inner width {inner} is not whole lane tiles"
+    if why is None and d_state % 8 and not interpret_mode():
+        why = f"d_state {d_state} is not whole sublanes"
+    return why
+
+
+def mix1(cache: HybridCache, layer, u, p, *, dt_rank: int, d_state: int,
+         eps: float, decode: bool):
+    """The state part of Mamba-1 layer `layer` (index among the Mamba
+    layers) over this forward's T positions: the causal convolution and silu
+    over the inner channels `u [B, T, E]`, the second projection `w_x` to
+    [r | B | C], each through its own RMSNorm, dt = softplus(r w_dt + dt_bias)
+    a CHANNEL, then the scan. `p` holds the layer's small weights: `conv_w
+    [K, E]`, `conv_b`, `w_x [R + 2 N, E]`, `dt_norm`, `b_norm`, `c_norm`,
+    `w_dt [E, R]`, `dt_bias`, `a [N, E]` (A = -a) and `D [E]`. Returns
+    (y [B, T, E] float32 with the D term, the cache with the layer's rows
+    updated)."""
+    from bigdl_tpu.ops import rms_norm, routes
+
+    B, T, E = u.shape
+    R, N = dt_rank, d_state
+    f32 = jnp.float32
+    valid = valid_positions(cache, T)
+    u = jnp.where(valid[..., None], u.astype(f32), 0.0)
+    end = (jnp.full((B,), T, jnp.int32) if cache.valid_len is None
+           else cache.valid_len.astype(jnp.int32))
+    rows, live = cache.state_rows()
+    at = jnp.clip(rows, 0, cache.n_rows - 1)
+    to = jnp.where(live, at, cache.n_rows)  # an idle row writes nowhere
+    # a row at position 0 starts from nothing, whatever its last holder left
+    fresh = cache.pos == 0
+    assert cache.conv_rows == 1, "a Mamba-1 row's tail lies in one piece"
+    # batch row b holds state row b (a decode step, `generate`): the layer's
+    # tails are a slice of the pool, not a gather
+    whole = cache.rows is None and B == cache.n_rows
+    old = cache.conv[layer] if whole else cache.conv[layer, at]
+    tail = jnp.where(fresh[:, None], 0.0, old)  # [B, (K - 1) * E]
+    if decode and T == 1:
+        x, tail = conv_step(tail, u[:, 0], p["conv_w"], p["conv_b"])
+        x = x[:, None]
+    else:
+        x, tail = causal_conv(tail.reshape(B, -1, E), u, p["conv_w"],
+                              p["conv_b"], end)
+        tail = tail.reshape(B, -1)
+    x = jax.nn.silu(x)
+    if whole:
+        conv = jax.lax.dynamic_update_slice(
+            cache.conv, jnp.where(live[:, None], tail, old)[None],
+            (layer, 0, 0))
+    else:
+        conv = cache.conv.at[layer, to].set(tail, mode="drop")
+
+    def proj(a, w):  # bf16 operands as `linear`'s, float32 sums
+        return jnp.einsum("bti,oi->bto", a.astype(w.dtype), w,
+                          preferred_element_type=f32)
+
+    rbc = proj(x, p["w_x"])
+    r = rms_norm(rbc[..., :R], p["dt_norm"], eps)
+    Bm = rms_norm(rbc[..., R:R + N], p["b_norm"], eps)
+    Cm = rms_norm(rbc[..., R + N:], p["c_norm"], eps)
+    dt = jax.nn.softplus(proj(r, p["w_dt"]) + p["dt_bias"])
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    A = -p["a"].astype(f32)
+    detail = f"B{B} T{T} E{E} N{N}"
+    why = why_not_scan_kernel(N, E)
+    one_token = decode and T == 1
+    with contextlib.ExitStack() as under:
+        if not one_token:
+            under.enter_context(scope("mamba2_prefill"))
+        under.enter_context(
+            scope("mamba1_decode" if one_token else "mamba1_prefill"))
+        if why is None and one_token:
+            from bigdl_tpu.ops.pallas.selective_scan import mamba1_decode
+
+            routes.note("mamba1", "pallas", detail + " decode")
+            y, ssm = mamba1_decode(cache.ssm, layer, rows, live, x[:, 0],
+                                   dt[:, 0], A, Bm[:, 0], Cm[:, 0])
+            y = y[:, None]
+        elif why is None and B == 1:
+            from bigdl_tpu.ops.pallas.selective_scan import mamba1_prefill
+
+            routes.note("mamba1", "pallas", detail + " prefill")
+            y, ssm = mamba1_prefill(cache.ssm, layer, at[0], fresh[0],
+                                    end[0], x[0], dt[0], A, Bm[0], Cm[0])
+            y = y[None]
+        else:
+            routes.note("mamba1", "xla", f"{detail} ({why or 'B > 1'})")
+            h = jnp.where(fresh[:, None, None], 0.0, cache.ssm[layer, at])
+            y, h = scan1(x, dt, A, Bm, Cm, h)
+            ssm = cache.ssm.at[layer, to].set(h, mode="drop")
+    y = y + p["D"] * x
+    return y, dataclasses.replace(cache, conv=conv, ssm=ssm)
+
+
+def conv_rows_of(cfg) -> int:
+    """`HybridCache.conv_rows` of a model: a selective scan's family lays a
+    row's tail in one piece."""
+    return 1 if cfg.mamba_dt_rank else 2
+
+
 def prefill_chunks(n_tokens: int, chunk: int) -> int:
     """Chunks of the prefill form over `n_tokens` (a span's argument)."""
     return -(-n_tokens // min(chunk, max(n_tokens, 1)))
@@ -301,13 +476,16 @@ class _StateBesidePages(kvpaged.CacheKind):
         cache = HybridCache(
             **dict(zip(self.arrays, leaves)), block_tables=tables[0],
             pos=pos0, start=jnp.zeros((1,), jnp.int32), rows=slot,
-            valid_len=last_idx[None] + 1)
+            valid_len=last_idx[None] + 1, conv_rows=conv_rows_of(cfg))
         return cache, cache
 
     def write_back(self, pool, row, n_tokens, last_idx, cfg):
         return self.leaves(row)
 
     axes = (1, 1, 2, 1)
+
+    def axes_of(self, cache):
+        return (1, 1, cache.conv_rows, 1)
 
     def _spots(self, pages, slot, window_pages):
         return pages, pages, slot, slot  # the pages, and the slot's own row
@@ -316,9 +494,14 @@ class _StateBesidePages(kvpaged.CacheKind):
         return row_nbytes(cache)
 
     def note_chunk(self, st, cfg, geo, bucket, n):
-        st.state_chunks += prefill_chunks(bucket, cfg.mamba_chunk_size)
+        if cfg.mamba_dt_rank:  # a selective scan runs token by token
+            st.scan_tokens += n
+        else:
+            st.state_chunks += prefill_chunks(bucket, cfg.mamba_chunk_size)
 
     def prefill_args(self, st):
+        if st.scan_tokens:
+            return {"scan_tokens": st.scan_tokens}
         return {"state_chunks": st.state_chunks}
 
     def decode_args(self, cfg, table, live, moved, pool):

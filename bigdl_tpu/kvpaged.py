@@ -549,6 +549,11 @@ class CacheKind:
 
     axes: tuple = (1, 1, 1, 1)  # of each array, the axis `_spots` index
 
+    def axes_of(self, cache) -> tuple:
+        """`axes` of this cache (a kind whose families lay a leaf out
+        differently reads it off the cache)."""
+        return self.axes
+
     def _spots(self, pages, slot, window_pages) -> tuple:
         """Where each array keeps a slot's part, along its axis of `axes`:
         here its pages."""
@@ -574,7 +579,7 @@ class CacheKind:
         return HostPages(**{
             f: None if a is None else np.asarray(a[(slice(None),) * ax + (i,)])
             for f, a, i, ax in zip(self.arrays, self.leaves(cache), at,
-                                   self.axes)})
+                                   self.axes_of(cache))})
 
     def swap_in(self, cache, parked: tuple, into: tuple):
         """Write `parked` (a `swap_out` blob's arrays, in `arrays` order)
@@ -585,7 +590,8 @@ class CacheKind:
             None if a is None else
             a.at[(slice(None),) * ax + (i,)].set(jnp.asarray(p, a.dtype))
             for a, p, i, ax in zip(self.leaves(cache), parked,
-                                   self._spots(*into), self.axes)))
+                                   self._spots(*into),
+                                   self.axes_of(cache))))
 
     # ---- accounting (host) --------------------------------------------------
 

@@ -633,6 +633,7 @@ class _SelectedPagesBesideState(kvhybrid._StateBesidePages):
             "a prefill runs a whole prompt from an empty row"),
     }
     axes = (1, 1, 1, 1)
+    axes_of = kvpaged.CacheKind.axes_of  # its arrays lie one way only
     # True asks for the chosen block ids beside the counts (4 KB a token at
     # 8 layers x 2 heads x 64): `Request.prompt_selection` / `out_selection`
     # for a reference that takes the program's selection. It fixes the

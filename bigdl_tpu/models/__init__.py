@@ -180,6 +180,11 @@ _LAZY_FAMILIES = {
     # head) between lightning attention layers (a state row a slot)
     # (bigdl_tpu/kvsparse.py)
     "minicpm_sala": "bigdl_tpu.models.minicpm_sala",
+    # Mamba-1 layers (a selective scan: the decay a channel's and a state
+    # index's) with a multi-query NoPE attention layer every few, granite's
+    # state row beside KV pages with the state the other way round
+    # (bigdl_tpu/kvhybrid.py)
+    "jamba": "bigdl_tpu.models.jamba",
 }
 
 

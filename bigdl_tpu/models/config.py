@@ -183,6 +183,12 @@ class ModelConfig:
     lightning_heads: int = 0
     lightning_head_dim: int = 0
     sparse_config: Optional[tuple] = None
+    # jamba (models/jamba.py): `layer_types` as granite's, but the "mamba"
+    # layers are MAMBA-1: the inner width is `mamba_expand` x hidden, a
+    # channel's step dt comes from a projection of rank `mamba_dt_rank`
+    # (0 = no such mixer), and the decay differs by channel AND state index
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
 
     def __post_init__(self):
         if self.attention_kind not in ("softmax", "power_retention"):
@@ -890,6 +896,42 @@ def _hf_granitemoehybrid(hf, kw):
     kw.setdefault("tie_word_embeddings", True)
 
 
+def _hf_jamba(hf, kw):
+    """Jamba (HF modeling_jamba): Mamba-1 layers with an attention layer
+    every `attn_layer_period` (HF's `layers_block_type`: attention where
+    `i % period == offset`), a SwiGLU MLP after every mixer, no position
+    encoding anywhere. `num_logits_to_keep`, `use_mamba_kernels` and a null
+    `sliding_window` are read by nothing. Refused by name: the sparse
+    layers of the larger siblings, a projection bias, a window."""
+    if hf.get("num_experts", 1) > 1:
+        raise NotImplementedError(
+            f"jamba with num_experts {hf['num_experts']}: the layers are "
+            "written with the dense MLP (HF's JambaMLP, what num_experts 1 "
+            "takes)")
+    if hf.get("mamba_proj_bias"):
+        raise NotImplementedError("jamba with mamba_proj_bias")
+    if hf.get("sliding_window") is not None:
+        raise NotImplementedError(
+            f"jamba with sliding_window {hf['sliding_window']}: the "
+            "attention layers are written over the whole context")
+    L = hf["num_hidden_layers"]
+    period = hf.get("attn_layer_period", 8)
+    offset = hf.get("attn_layer_offset", 4)
+    kw["layer_types"] = tuple(
+        "attention" if i % period == offset else "mamba" for i in range(L))
+    kw["position_embedding_type"] = "nope"
+    kw["mamba_expand"] = hf.get("mamba_expand", 2)
+    kw["mamba_d_state"] = hf.get("mamba_d_state", 16)
+    kw["mamba_d_conv"] = hf.get("mamba_d_conv", 4)
+    rank = hf.get("mamba_dt_rank", 256)
+    kw["mamba_dt_rank"] = (-(-hf["hidden_size"] // 16) if rank == "auto"
+                           else rank)
+    if not hf.get("mamba_conv_bias", True):
+        raise NotImplementedError("jamba without mamba_conv_bias")
+    kw.setdefault("rms_norm_eps", 1e-6)
+    kw.setdefault("tie_word_embeddings", False)
+
+
 def _hf_qwen3_moe(hf, kw):
     _hf_qwen3(hf, kw)
     kw["num_experts"] = hf.get("num_experts", 128)
@@ -1258,6 +1300,7 @@ _HF_BUILDERS = {
     "qwen3": _hf_qwen3,
     "brumby": _hf_brumby,
     "granitemoehybrid": _hf_granitemoehybrid,
+    "jamba": _hf_jamba,
     "smallthinker": _hf_smallthinker,
     "laguna": _hf_laguna,
     "qwen3_moe": _hf_qwen3_moe,
@@ -1343,6 +1386,18 @@ PRESETS: dict[str, ModelConfig] = {
         num_experts_per_tok=3, moe_intermediate_size=32,
         shared_intermediate_size=64, embedding_scale=12,
         residual_scale=0.22, attn_scale=0.0625, logit_scale=0.25,
+    ),
+    # Jamba's shape at toy sizes: Mamba-1 layers (inner 256, a [16, 256]
+    # state, dt of rank 8) with one multi-query NoPE attention layer among
+    # them (period 4, offset 2), a SwiGLU MLP after every mixer
+    # (tests/test_jamba.py holds it to its config.json form)
+    "tiny-jamba": ModelConfig(
+        model_type="jamba", vocab_size=256, hidden_size=128,
+        intermediate_size=256, num_hidden_layers=4, num_attention_heads=4,
+        num_key_value_heads=1, tie_word_embeddings=True, rms_norm_eps=1e-6,
+        layer_types=("mamba", "mamba", "attention", "mamba"),
+        mamba_expand=2, mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=8,
+        position_embedding_type="nope",
     ),
     # SmallThinker's shape at toy sizes: two periods of one full NoPE layer
     # and three window layers with a rope, 8 ReLU-gated experts top-3

@@ -150,12 +150,14 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
 
 
 def quantize_params(params: Params, qtype: str,
-                    lm_head_qtype: Optional[str] = None) -> Params:
-    """Pack the projections, the experts and the shared MLP; the router,
-    the convolution, `dt_bias`, `a`, `D` and the norms stay as they are.
-    With tied embeddings the head becomes a PACKED COPY of the table
-    (`lm_head`): the lookup keeps its bf16 rows, a decode step reads the
-    head at 4 bits."""
+                    lm_head_qtype: Optional[str] = None,
+                    targets: tuple = _QUANT_TARGETS) -> Params:
+    """Pack the projections, the experts and the shared MLP (`targets`: a
+    family that stacks its layers by run as this one does names its own);
+    the router, the convolution, `dt_bias`, `a`, `D` and the norms stay as
+    they are. With tied embeddings the head becomes a PACKED COPY of the
+    table (`lm_head`): the lookup keeps its bf16 rows, a decode step reads
+    the head at 4 bits."""
     from bigdl_tpu.quant import QTensor, quantize_or_dense
     from bigdl_tpu.quant.qtypes import resolve_qtype, split_mixed_qtype
 
@@ -167,7 +169,7 @@ def quantize_params(params: Params, qtype: str,
     out = dict(params)
     out["runs"] = {
         r: {name: quantize_or_dense(w, spec.name, name)
-            if name in _QUANT_TARGETS and not isinstance(w, QTensor) else w
+            if name in targets and not isinstance(w, QTensor) else w
             for name, w in run.items()} for r, run in params["runs"].items()}
     head = params.get("lm_head", params["embed"])
     lm_spec = resolve_qtype(lm_head_qtype) if lm_head_qtype else spec
@@ -197,16 +199,18 @@ PAGED_CACHE_KIND = kvhybrid.KIND
 
 
 def init_cache(config: ModelConfig, batch: int, cache_len: int = 0,
-               quantize_kv: bool = False) -> kvhybrid.HybridCache:
-    """`generate_tokens`' family hook: every row's pages in order."""
+               quantize_kv: bool = False, paged=None
+               ) -> kvhybrid.HybridCache:
+    """`generate_tokens`' family hook: every row's pages in order (`paged`:
+    another family's `init_paged_cache`)."""
     if quantize_kv:
         raise NotImplementedError(
             f"quantize_kv is not available for {kvhybrid.KIND} "
             f"({config.model_type}): fp8 pages beside a float32 state are "
             "not wired")
     per_row = max(-(-cache_len // GENERATE_PAGE), 1)
-    cache = init_paged_cache(config, batch * per_row + 1, GENERATE_PAGE,
-                             batch, per_row)
+    cache = (paged or init_paged_cache)(
+        config, batch * per_row + 1, GENERATE_PAGE, batch, per_row)
     table = 1 + jnp.arange(batch * per_row, dtype=jnp.int32)
     return dataclasses.replace(
         cache, block_tables=table.reshape(batch, per_row))

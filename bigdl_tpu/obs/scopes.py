@@ -61,8 +61,10 @@ VOCABULARY = (
 #: know the vocabulary alone, and an operation under one of these belongs to
 #: the vocabulary's scope around it (`bench/reduce/scopes.scope_of` takes the
 #: innermost name it knows). A block-sparse layer's selection, and the two
-#: forms of a lightning (decayed linear attention) layer (kvsparse.py).
-DETAIL = ("sparse_select", "lightning_prefill", "lightning_decode")
+#: forms of a lightning (decayed linear attention) layer (kvsparse.py), and
+#: of a Mamba-1 layer's selective scan (kvhybrid.mix1).
+DETAIL = ("sparse_select", "lightning_prefill", "lightning_decode",
+          "mamba1_prefill", "mamba1_decode")
 
 _NAMES = frozenset(VOCABULARY + DETAIL)
 

@@ -349,6 +349,8 @@ class _PrefillState:
     start: int = 0  # `written` at the first chunk (0: the whole prompt)
     state_chunks: int = 0  # a recurrent-state model: chunks of the
     # prefill form run so far (the `prefill` span's `state_chunks`)
+    scan_tokens: int = 0  # ... or, where the form is a selective scan,
+    # tokens that went through it (the span's `scan_tokens`)
     upprojected: int = 0  # a latent-page model: tokens whose K and V the
     # chunks so far expanded from latents (`latent_tokens_upprojected`)
     row_pages: int = 0  # KV pages: pool pages the chunks so far gathered

@@ -121,6 +121,33 @@ def loop_body_moves(hlo: str) -> list:
     return sorted(moves, reverse=True)
 
 
+def pool_leaf_as_compiled(hlo: str, shape: tuple) -> tuple:
+    """(padded bytes, layout, [opcode x count]) of the pool leaf of `shape`
+    in the optimized module `hlo`: the layout its `parameter` has, and every
+    OTHER instruction whose result has the leaf's dimensions and is no view
+    of it (a `copy` of a pool is a pool's bytes moved every step; the
+    kernels' aliased results and tuple plumbing are the pool itself)."""
+    import collections
+    import re
+
+    dims = ",".join(map(str, shape))
+    pat = re.compile(
+        r"\s*(?:ROOT )?%?[\w.\-]+ = \(?(\w+\[" + re.escape(dims)
+        + r"\](?:\{[^}]*\})?)[^=]*? ([\w\-]+)\(")
+    n, layout, others = 0, "", collections.Counter()
+    for line in hlo.splitlines():
+        m = pat.match(line)
+        if not m:
+            continue
+        if m.group(2) == "parameter":
+            n, layout = padded_bytes(m.group(1)), m.group(1).split("]")[1]
+        elif m.group(2) not in ("get-tuple-element", "bitcast", "tuple",
+                                "custom-call", "while", "conditional",
+                                "dynamic-update-slice"):
+            others[m.group(2)] += 1
+    return n, layout, [f"{op} x{k}" for op, k in sorted(others.items())]
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", required=True)
@@ -128,6 +155,8 @@ def main() -> int:
     ap.add_argument("--prefill", type=int, nargs="*", default=[1024])
     ap.add_argument("--unprepared", action="store_true",
                     help="the tree without the kernels' scale bits")
+    ap.add_argument("--hlo", metavar="DIR",
+                    help="write each program's optimized HLO there")
     args = ap.parse_args()
 
     import jax
@@ -201,12 +230,14 @@ def main() -> int:
             arr((B,), jnp.float32), lora=None).compile()
     rows.append((f"engine_decode B={B}", dec.memory_analysis()))
     table = arr((1, eng.max_pages_per_row), jnp.int32)
+    pres = []
     for T in args.prefill:
         pre = eng._paged_prefill.lower(
             params, eng.kind.leaves(pool), (table, table),
             arr((1,), jnp.int32), arr((1, T), jnp.int32), arr((), jnp.int32),
             arr((1,), jnp.int32), lora=None).compile()
         rows.append((f"engine_paged_prefill T={T}", pre.memory_analysis()))
+        pres.append((f"engine_paged_prefill_T{T}", pre))
 
     print(f"{args.config} ({eng.kind.name}): {cfg.num_hidden_layers} layers, "
           f"weights {w_bytes / GIB:.2f} GiB ({w_bytes / 1e9:.2f} GB), pool "
@@ -224,7 +255,23 @@ def main() -> int:
     print(f"  prepared scale bits among the arguments: {len(bits)} arrays, "
           f"{bits_bytes / GIB:.2f} GiB ({bits_bytes / 1e9:.2f} GB) in "
           "(16, 128) tiles")
-    moves = loop_body_moves(dec.as_text())
+    hlo = dec.as_text()
+    if args.hlo:
+        os.makedirs(args.hlo, exist_ok=True)
+        for name, program in [("engine_decode", dec)] + pres:
+            with open(os.path.join(args.hlo, name + ".hlo.txt"), "w") as f:
+                f.write(program.as_text())
+    print("  the pool as engine_decode's compiled arguments hold it (tiles "
+          "padded), and what else of a leaf's size the program makes:")
+    for leaf, s in zip(eng.kind.arrays, eng.kind.leaves(pool)):
+        if s is None:
+            continue
+        n, as_arg, others = pool_leaf_as_compiled(hlo, s.shape)
+        print(f"    {leaf:8s} {str(tuple(s.shape)):28s} "
+              f"{s.size * s.dtype.itemsize / GIB:5.2f} GiB as shaped, "
+              f"{n / GIB:5.2f} as compiled {as_arg}; other results of its "
+              f"shape: {others or 'none'}")
+    moves = loop_body_moves(hlo)
     print(f"  engine_decode's loop bodies copy, view or slice "
           f"{sum(m[0] for m in moves) / MIB:.1f} MiB a turn in "
           f"{len(moves)} operations (padded bytes of each result):")

@@ -391,3 +391,116 @@ def test_a_prefill_gives_back_the_pool_it_was_lent(name):
     assert kind.forward_kw(3) == (
         {"logits_at": 3} if name in ("window_pages_beside_pages",
                                      "selected_pages_beside_state") else {})
+
+
+# ---------------------------------------------------------------------------
+# `state_beside_pages` holds TWO state layouts (ISSUE 56): Mamba-2's
+# (granite: `ssm [Lm, R, inner, d_state]`, `conv [Lm, K - 1, R, C]`) and
+# Mamba-1's (jamba: `ssm [Lm, R, d_state, E]`, `conv [Lm, R, (K - 1) * E]`)
+# ---------------------------------------------------------------------------
+
+_LAYOUTS = {
+    # preset: (ssm after the row axis, conv's shape with R its rows, the
+    # axis of conv that is the row, the prefill span's argument)
+    "tiny-granite-hybrid": (
+        lambda c: (c.mamba_n_heads * c.mamba_d_head, c.mamba_d_state),
+        lambda c, R: (3, R, c.mamba_n_heads * c.mamba_d_head
+                      + 2 * c.mamba_d_state), 2, "state_chunks"),
+    "tiny-jamba": (
+        lambda c: (c.mamba_d_state, 2 * c.hidden_size),
+        lambda c, R: (R, 3 * 2 * c.hidden_size), 1, "scan_tokens"),
+}
+
+
+def _layout_pool(preset):
+    cfg, kind = PRESETS[preset], kvhybrid.CACHE_KIND
+    pool = kind.make_pool(cfg, _geometry(kind))
+    rng = np.random.default_rng(1)
+    return cfg, kind.with_leaves(pool, tuple(
+        jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+        for a in kind.leaves(pool)))
+
+
+@pytest.mark.parametrize("preset", sorted(_LAYOUTS))
+def test_state_beside_pages_is_both_families_kind(preset):
+    cfg = PRESETS[preset]
+    model = types.SimpleNamespace(config=cfg,
+                                  family=get_family(cfg.model_type))
+    assert _cache_kind(model) is kvhybrid.CACHE_KIND
+    state, conv, rows_axis, _ = _LAYOUTS[preset]
+    cfg, pool = _layout_pool(preset)
+    Lm = sum(k == "mamba" for k in cfg.layer_types)
+    assert pool.ssm.shape == (Lm, N_SLOTS) + state(cfg)
+    assert pool.conv.shape == (Lm,) + conv(cfg, N_SLOTS)
+    assert pool.n_rows == N_SLOTS and pool.conv_rows == rows_axis
+    assert pool.k.shape[0] == len(cfg.layer_types) - Lm
+    # a row's bytes: the state and the K - 1 = 3 inputs of the convolution
+    # over its channels, float32, every Mamba layer
+    kind = kvhybrid.CACHE_KIND
+    channels = int(np.prod(conv(cfg, 1))) // 3
+    assert kind.state_row_nbytes(pool) == kvhybrid.row_nbytes(pool) == (
+        Lm * (int(np.prod(state(cfg))) + 3 * channels) * 4)
+    assert kind.axes_of(pool) == (1, 1, rows_axis, 1)
+    assert kind._spots("pages", "slot", None) == (
+        "pages", "pages", "slot", "slot")
+
+
+@pytest.mark.parametrize("preset", sorted(_LAYOUTS))
+def test_both_layouts_park_and_restore_a_row_bit_for_bit(preset):
+    kind = kvhybrid.CACHE_KIND
+    cfg, pool = _layout_pool(preset)
+    Lm = pool.ssm.shape[0]
+    blob = kind.swap_out(pool, [1, 2], 1, [])
+    assert blob.ssm.shape == (Lm,) + pool.ssm.shape[2:]
+    assert blob.conv.size == pool.conv.size // N_SLOTS
+    assert blob.ssm.tobytes() == np.asarray(pool.ssm[:, 1]).tobytes()
+    assert blob.ssm.nbytes + blob.conv.nbytes == kind.state_row_nbytes(pool)
+    empty = kind.with_leaves(pool, jax.tree.map(jnp.zeros_like,
+                                                kind.leaves(pool)))
+    into = (jnp.asarray([4, 3], jnp.int32), jnp.asarray(2),
+            jnp.asarray([], jnp.int32))
+    there = kind.swap_in(empty, kind.leaves(blob), into)
+    assert there.conv_rows == pool.conv_rows
+    back = kind.leaves(kind.swap_out(there, [4, 3], 2, []))
+    for a, b in zip(kind.leaves(blob), back):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+    # the other rows of the pool it was written into stay zeros
+    assert not np.asarray(there.ssm[:, [0, 1, 3]]).any()
+
+
+@pytest.mark.parametrize("preset", sorted(_LAYOUTS))
+def test_both_layouts_count_a_prefill_their_own_way(preset):
+    kind = kvhybrid.CACHE_KIND
+    cfg, want = PRESETS[preset], _LAYOUTS[preset][3]
+    st = _PrefillState(req=None, slot=0, row=None, written=0, path=[],
+                       chunk=30)
+    kind.note_chunk(st, cfg, _geometry(kind), 32, 30)
+    st.written = 30
+    kind.note_chunk(st, cfg, _geometry(kind), 16, 9)
+    args = kind.prefill_args(st)
+    assert list(args) == [want]
+    assert args[want] == (39 if want == "scan_tokens" else sum(
+        kvhybrid.prefill_chunks(b, cfg.mamba_chunk_size) for b in (32, 16)))
+
+
+@pytest.mark.parametrize("preset", sorted(_LAYOUTS))
+def test_both_layouts_prefill_on_the_pool_itself(preset):
+    """`row_view`: the row IS the pool behind a one-row table, with the
+    family's own tail layout, and comes back in the pool's shapes."""
+    kind = kvhybrid.CACHE_KIND
+    cfg, pool = _layout_pool(preset)
+    geo = _geometry(kind)
+    table = jnp.zeros((1, geo.max_pages_per_row), jnp.int32)
+    seen = {}
+
+    def program(leaves, last_idx):
+        one, row = kind.row_view(leaves, (table, table), jnp.zeros(
+            (1,), jnp.int32), last_idx, jnp.asarray([2], jnp.int32), cfg, geo)
+        seen.update(same=row is one, conv_rows=row.conv_rows)
+        return kind.write_back(one, row, 16, last_idx, cfg)
+
+    leaves = kind.leaves(pool)
+    out = jax.eval_shape(program, leaves, jnp.asarray(11))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), out) \
+        == jax.tree.map(lambda a: (a.shape, a.dtype), leaves)
+    assert seen == dict(same=True, conv_rows=pool.conv_rows)

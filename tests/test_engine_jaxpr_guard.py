@@ -33,6 +33,10 @@ after it (serving/blocks.py), its prefill the llama prefill under a mask
 causal by block. What it added to `llama.forward` and `flash_attention` is
 behind `config.block_length`, 0 for everyone else: the seven above count
 what they counted.
+PR 56 adds a tiny Jamba (models/jamba.py, loaded on first use: Mamba-1 layers
+on `state_beside_pages`' second state layout, `kvhybrid.mix1`): a family and
+a mixer of its own beside granite's, whose programs, and everyone else's,
+`scripts/engine_parity.py` found the parent's to the byte.
 
     JAX_PLATFORMS=cpu python tests/test_engine_jaxpr_guard.py
 """
@@ -104,6 +108,11 @@ MODELS = {
         sparse_config=dict(kernel_size=8, kernel_stride=4, block_size=16,
                            topk=6, init_blocks=1, window_size=32,
                            dense_len=64)),
+    "jamba": dict(
+        _DENSE, model_type="jamba", num_hidden_layers=4,
+        num_key_value_heads=1, attn_layer_period=4, attn_layer_offset=2,
+        num_experts=1, mamba_expand=2, mamba_d_state=16, mamba_d_conv=4,
+        mamba_dt_rank=8, rms_norm_eps=1e-6, tie_word_embeddings=True),
 }
 
 # (engine_decode, engine_paged_prefill) on the parent of PR 38; the paged
@@ -122,6 +131,9 @@ PINNED = {
     # PR 54's own: two sparse layers one by one around a two-layer scan of
     # lightning layers; the selection, the page list and the report
     "minicpm_sala": (1924, 1476),
+    # PR 56's own: a two-layer scan of Mamba-1 layers, the attention layer,
+    # one more Mamba-1 layer; the `jnp` scan on this route
+    "jamba": (1135, 1031),
 }
 
 

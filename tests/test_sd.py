@@ -12,6 +12,10 @@ import pytest
 
 from bigdl_tpu.models import sd
 
+# outside tier-1 since ISSUE 56 (ROADMAP D13 (1)): 8 cases, 179 test-seconds,
+# and `models/sd.py` stands outside every benchmark cell's program (D7)
+pytestmark = pytest.mark.slow
+
 CFG = sd.SDConfig(
     in_channels=4, out_channels=4,
     block_out_channels=(32, 64, 96, 96), layers_per_block=2,

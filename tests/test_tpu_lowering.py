@@ -996,3 +996,188 @@ def test_flash_attention_compiles_under_a_selections_mask(one_chip):
         _sds((1, T, Hkv * G, D), jnp.bfloat16, one_chip), kv, kv,
         _sds((1, Hkv, T, T), jnp.int8, one_chip)).compile()
     assert "flash_attention" in c.as_text()
+
+
+# ---- Jamba2-3B: Mamba-1 layers, one KV head (ISSUE 56) ----------------------
+
+# 26 Mamba layers, 256 state rows `[16, 5120]` float32 (channels on lanes), a
+# decode step's 256 tokens and a prefill's 64 / 1024 of one row
+
+
+def _scan_pool(one_chip, L=26, R=256, N=16, E=5120):
+    return _sds((L, R, N, E), jnp.float32, one_chip)
+
+
+def test_mamba1_decode_kernel_compiles_at_the_cells_shapes(one_chip):
+    """The selective scan of one token a live row, the pool in and out as one
+    buffer: the only temporaries are the small operands as the kernel takes
+    them (x, dt and y a row a block, B and C a column), never a `[B, 16,
+    5120]` array."""
+    from bigdl_tpu.ops.pallas.selective_scan import mamba1_decode
+
+    B, N, E = 256, 16, 5120
+    row = _sds((B, E), jnp.float32, one_chip)
+    vec = _sds((B, N), jnp.float32, one_chip)
+
+    def f(ssm, layer, rows, live, x, dt, A, Bm, Cm):
+        return mamba1_decode(ssm, layer, rows, live, x, dt, A, Bm, Cm,
+                             interpret=False)
+
+    c = jax.jit(f, donate_argnums=0).lower(
+        _scan_pool(one_chip), _sds((), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.bool_, one_chip),
+        row, row, _sds((N, E), jnp.float32, one_chip), vec, vec).compile()
+    assert "mamba1_decode" in c.as_text()
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < B * N * E * 4 // 2
+    assert m.alias_size_in_bytes >= 26 * B * N * E * 4
+
+
+@pytest.mark.parametrize("T", [64, 1024])
+def test_mamba1_prefill_kernel_compiles_at_the_cells_shapes(one_chip, T):
+    """One row's T tokens with the state in VMEM across them: x, dt, B, C in
+    blocks of tokens, y out, no `[T, 16, 5120]` array."""
+    from bigdl_tpu.ops.pallas.selective_scan import mamba1_prefill
+
+    N, E = 16, 5120
+    tok = _sds((T, E), jnp.float32, one_chip)
+    vec = _sds((T, N), jnp.float32, one_chip)
+    scalar = _sds((), jnp.int32, one_chip)
+
+    def f(ssm, layer, row, fresh, n_valid, x, dt, A, Bm, Cm):
+        return mamba1_prefill(ssm, layer, row, fresh, n_valid, x, dt, A, Bm,
+                              Cm, interpret=False)
+
+    c = jax.jit(f, donate_argnums=0).lower(
+        _scan_pool(one_chip), scalar, scalar,
+        _sds((), jnp.bool_, one_chip), scalar, tok, tok,
+        _sds((N, E), jnp.float32, one_chip), vec, vec).compile()
+    assert "mamba1_prefill" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < T * N * E * 4 // 2
+
+
+@pytest.mark.parametrize("program", ["engine_decode", "engine_paged_prefill"])
+def test_jambas_engine_programs_keep_the_scan_in_the_pool(one_chip,
+                                                        monkeypatch, program):
+    """`engine_decode` at 256 rows and `engine_paged_prefill` at T = 1024 of
+    the cell's own pool, the first two runs of layers (7 Mamba, 1 attention),
+    compiled for the chip: both call the scan kernel; no float32 array has a
+    `[.., 16, 5120]` piece a token or a row outside the pool (the decay
+    `exp(dt A)` is formed in the kernel); the state pool is aliased in and
+    out with no other result of its shape; and no whole `conv` array is
+    re-laid (3 inputs side by side a row, not an axis of 3)."""
+    import math
+    import os
+    import re
+
+    from bench import cells, weights
+    from bigdl_tpu.api import TpuModel
+    from bigdl_tpu.models.config import ModelConfig
+    from bigdl_tpu.models.llama import prepare_kernel_scales
+    from bigdl_tpu.serving.engine import InferenceEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = cells.load_json(root, "bench", "configs", "jamba2-3b-int4.json")
+    cfg = ModelConfig.from_hf_config(
+        dict(cells.as_run(config), num_hidden_layers=8))
+    e, qtype = config["bench"]["engine"], config["bench"]["qtype"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the target
+    B, T = e["n_slots"], 1024
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda p: prepare_kernel_scales(cfg, p),
+        weights.param_shapes(cfg, qtype)))
+    eng = InferenceEngine(TpuModel(cfg, None, qtype), n_slots=1,
+                          max_len=e["max_len"], paged=True,
+                          page_size=e["page_size"], n_pages=2)
+    eng.n_slots, eng.n_pages = B, e["n_pages"]
+    pool = on_chip(jax.eval_shape(eng._make_pool))
+    assert pool.ssm.shape == (7, B, 16, 5120)
+    if program == "engine_decode":
+        c = eng._decode.lower(
+            params, _sds((B,), jnp.int32, one_chip), pool,
+            _sds((2,), jnp.uint32, one_chip),
+            _sds((B,), jnp.float32, one_chip), _sds((B,), jnp.int32, one_chip),
+            _sds((B,), jnp.float32, one_chip), _sds((B,), jnp.bool_, one_chip),
+            _sds((B, cfg.vocab_size), jnp.bool_, one_chip),
+            _sds((B,), jnp.float32, one_chip), lora=None).compile()
+        kernel, lead = "mamba1_decode", B
+    else:
+        table = _sds((1, eng.max_pages_per_row), jnp.int32, one_chip)
+        c = eng._paged_prefill.lower(
+            params, eng.kind.leaves(pool), (table, table),
+            _sds((1,), jnp.int32, one_chip), _sds((1, T), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip), _sds((1,), jnp.int32, one_chip),
+            lora=None).compile()
+        kernel, lead = "mamba1_prefill", T
+    text = c.as_text()
+    assert f'kernel_name = "{kernel}"' in text or kernel in text
+    shapes = {tuple(int(d) for d in dims.split(",") if d)
+              for dims in re.findall(r"f32\[([\d,]+)\]", text)}
+    wide = {s for s in shapes if s[-2:] == (16, 5120)
+            and math.prod(s[:-2]) > 1}  # (one layer's A is `[16, 5120]`)
+    assert wide == {pool.ssm.shape}, wide
+    assert not {s for s in shapes - {pool.ssm.shape}
+                if lead in s[:-1] and 5120 in s and 16 in s}
+    # the pool itself: a parameter, the kernel's aliased result, the loop's
+    # and the output's plumbing, and no copy
+    state = ",".join(map(str, pool.ssm.shape))
+    made = set(re.findall(
+        r"= \(?f32\[" + state + r"\]\S* ([\w\-]+)\(", text))
+    assert made <= {"parameter", "get-tuple-element", "custom-call", "while",
+                    "tuple", "bitcast", "conditional"}, made
+    conv = ",".join(map(str, pool.conv.shape))
+    assert "copy" not in set(re.findall(
+        r"= \(?f32\[" + conv + r"\]\S* ([\w\-]+)\(", text))
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes >= pool.ssm.size * 4 + pool.conv.size * 4
+
+
+def test_paged_decode_kernel_compiles_at_jambas_one_kv_head(one_chip):
+    """256 slots x 10 pages of 256 tokens, 20 query heads on ONE KV head of
+    128, a pool of 2561 pages over 2 layers: `pool_tiles_whole` is false (XLA
+    pads the `[1, 128]` tiles), so the pages come through Pallas's pipeline,
+    a page a grid step of a (256, 10) grid; a group of 20 query rows is no
+    multiple of 8 and Mosaic takes it. In front of a call that stands alone
+    the pool is re-laid, as in front of every `piped` call; so it is in
+    `engine_decode` (scripts/engine_fit.py counts four copies of a pool a
+    step: ROADMAP R10's price at this shape, PERF.md 'Left by PR 56')."""
+    from bigdl_tpu.ops.pallas import paged_attention as pa
+
+    B, Hkv, G, D, L, NP, page, mp = 256, 1, 20, 128, 2, 2561, 256, 10
+    assert not pa.pool_tiles_whole(Hkv, D, 2)
+    kv = _sds((L, NP, page, Hkv, D), jnp.bfloat16, one_chip)
+
+    def f(q, k, v, bt, layer, pos, start, live):
+        return pa.paged_decode_attention(q, k, v, bt, layer, pos, start,
+                                         scale=D ** -0.5, live=live,
+                                         interpret=False)
+
+    c = jax.jit(f).lower(
+        _sds((B, Hkv * G, D), jnp.bfloat16, one_chip), kv, kv,
+        _sds((B, mp), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip)).compile()
+    assert "paged_decode_attention" in c.as_text()
+
+
+@pytest.mark.parametrize("T", [64, 1024])
+def test_flash_attention_compiles_at_jambas_group_of_20(one_chip, T):
+    from bigdl_tpu.ops.pallas.flash_attention import flash_attention
+
+    S, Hq, D = 2560, 20, 128
+
+    def call(q, k, v, start, q_offset):
+        return flash_attention(q, k, v, start=start, q_offset=q_offset,
+                               scale=D ** -0.5, interpret=False)
+
+    text = jax.jit(call).lower(
+        _sds((1, T, Hq, D), jnp.bfloat16, one_chip),
+        _sds((1, S, 1, D), jnp.bfloat16, one_chip),
+        _sds((1, S, 1, D), jnp.bfloat16, one_chip),
+        _sds((1,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile().as_text()
+    assert "flash_attention" in text
