@@ -50,14 +50,20 @@ skeleton of both, the flash recurrence (`_softmax_*`) and the scalar
 operand (`_scalars`) are shared, and what a group IS comes from the cache
 kind's static shapes.
 
-The kernel's own DMA can take a page out of the pool only where XLA
-leaves the page's [Hkv, D] tiles unpadded in HBM (`pool_tiles_whole`:
-every cell's shape; not 1, 3 or 6 KV heads, not a head of 64 or 96).
-Elsewhere Mosaic refuses the slice, and the pages reach the SAME body
-through Pallas's pipeline, one page a grid step of a (B, max_pages)
-grid, a dead step naming the block already held (`clamped_page`) and
-skipping the body: the price of such a shape is a grid step a dead page
-again.
+The kernel's own DMA can take a page out of the pool only where the
+page lies in HBM in whole, unpadded tiles. XLA leaves the page's
+[Hkv, D] tiles so at every cell's shape with 2, 4 or 8 KV heads of 128
+(`pool_tiles_whole`). A pool of ONE KV head it keeps with a page's SLOTS
+on the sublanes, where the head axis stood (`{4,2,3,1,0}`): read as
+[L, n_pages, page, D] (a bitcast, `one_head_view`) its [page, D] face is
+whole tiles, and the same row loop and body take it with `n_kv = 1`.
+Elsewhere (3 or 6 KV heads, a head of 64 or 96) Mosaic refuses the
+slice, and the pages reach the SAME body through Pallas's pipeline, one
+page a grid step of a (B, max_pages) grid, a dead step naming the block
+already held (`clamped_page`) and skipping the body: the price of such a
+shape is a grid step a dead page again, and a copy of the pool wherever
+XLA's layout of it is not the one Mosaic asks. Which of the two a pool
+took is noted while tracing (`routes.note("paged", "rows" | "piped")`).
 """
 
 from __future__ import annotations
@@ -69,6 +75,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bigdl_tpu.ops import routes
 from bigdl_tpu.ops.pallas import qdecode, tiling
 
 _NEG_INF = -1e30
@@ -108,15 +115,37 @@ def clamped_page(p, first, last):
 
 def pool_tiles_whole(n_kv: int, head_dim: int, itemsize: int) -> bool:
     """Whether a page's [Hkv, D] tiles lie in HBM unpadded, so that a DMA
-    of ours can take one page out of the pool: D in whole lane tiles, and
-    the KV heads a sublane tile of their own (2 or 4 heads of 2-byte
-    values, 8 of any) or whole tiles of 8. Where XLA pads them (6 heads to
-    8, 1 to 2, a head of 64 to 128 lanes) Mosaic refuses the slice as not
-    aligned to the tiling, and the pages come through Pallas's own
-    pipeline instead, one a grid step (`_kernel`, `piped`): the one arm
-    a pool laid out in whole tiles would delete (PERF.md section 7)."""
+    of ours can take one page out of the pool as it is stored: D in whole
+    lane tiles, and the KV heads a sublane tile of their own (2 or 4 heads
+    of 2-byte values, 8 of any) or whole tiles of 8. Where XLA pads them
+    (6 heads to 8, a head of 64 to 128 lanes) Mosaic refuses the slice as
+    not aligned to the tiling."""
     return head_dim % 128 == 0 and (
         n_kv % 8 == 0 or (n_kv in (2, 4) and itemsize == 2))
+
+
+def one_head_view(page: int, n_kv: int, head_dim: int, itemsize: int) -> bool:
+    """Whether a pool of ONE KV head read as [L, n_pages, page, D] has its
+    [page, D] face in whole unpadded tiles: XLA keeps such a pool with the
+    page's slots on the sublanes (`{4,2,3,1,0}`: there is no head axis to
+    tile), so the view is a bitcast of what lies in HBM, and a page of
+    whole sublane tiles (16 rows of bf16, 32 of fp8 codes) at D in whole
+    lane tiles is a slice Mosaic takes. Compiled for a described v5e at
+    both widths (tests/test_tpu_lowering.py); a 4-byte pool is no cell's
+    and stays where it was."""
+    return (n_kv == 1 and head_dim % 128 == 0 and itemsize in (1, 2)
+            and page % (32 // itemsize) == 0)
+
+
+def pages_by_dma(page: int, n_kv: int, head_dim: int, itemsize: int) -> bool:
+    """Whether `paged_decode_attention` fetches a row's live pages itself
+    (the row loop, grid (B,)): out of the pool as it is stored
+    (`pool_tiles_whole`) or through its `one_head_view`. Elsewhere the
+    pages come through Pallas's own pipeline, one a grid step (`_kernel`,
+    `piped`): the one arm a pool laid out in whole tiles would delete
+    (PERF.md section 7)."""
+    return (pool_tiles_whole(n_kv, head_dim, itemsize)
+            or one_head_view(page, n_kv, head_dim, itemsize))
 
 
 def group_pages(page: int, n_kv: int, head_dim: int, itemsize: int,
@@ -129,8 +158,9 @@ def group_pages(page: int, n_kv: int, head_dim: int, itemsize: int,
     V, double-buffered, and the [Hq, columns] float32 scores in a few MiB
     of VMEM. Never more than a row has, never fewer than one: a page too
     large to join is a group of its own, and so is every page of a pool
-    whose tiles are not whole (`pool_tiles_whole`), in the same kernel."""
-    if not pool_tiles_whole(n_kv, head_dim, itemsize):
+    whose pages no DMA of ours can take (`pages_by_dma`), in the same
+    kernel."""
+    if not pages_by_dma(page, n_kv, head_dim, itemsize):
         return 1
     columns = _GROUP_COLUMNS * 2 // max(2, itemsize) * 128 // max(128, head_dim)
     tokens = min(_GROUP_TOKENS, columns // n_kv)
@@ -392,7 +422,7 @@ def _kernel(bt_ref, meta_ref, q_ref, k_in, v_in, *refs,
                         v_scale=(lambda: vs_ref[0, at]) if quantized else None)
 
     if piped:
-        # a pool whose tiles XLA pads (`pool_tiles_whole`): grid
+        # a pool whose tiles XLA pads (`pages_by_dma` false): grid
         # (B, max_pages), a page a step through Pallas's pipeline. On a
         # step outside first_b .. last_b the index maps name the block
         # already held (`clamped_page`: no DMA) and the body is skipped.
@@ -472,7 +502,15 @@ def paged_decode_attention(
 
     meta = _scalars(layer, window, pos, start, page, mp, live)
 
-    piped = not pool_tiles_whole(Hkv, D, k_pages.dtype.itemsize)
+    piped = not pages_by_dma(page, Hkv, D, k_pages.dtype.itemsize)
+    routes.note("paged", "piped" if piped else "rows",
+                f"{P} pages a group of {page} x {Hkv} at D={D}")
+    face = (page, Hkv, D)  # one page as the kernel's DMA and buffers see it
+    if one_head_view(page, Hkv, D, k_pages.dtype.itemsize):
+        # the pool as XLA keeps it: a bitcast, no copy in front of the call
+        face = (page, D)
+        k_pages = k_pages.reshape(L, NP, *face)
+        v_pages = v_pages.reshape(L, NP, *face)
     if piped:  # a page a grid step, through the block table's clamped map
         grid = (B, mp)
 
@@ -485,8 +523,8 @@ def paged_decode_attention(
     else:  # the pool stays in HBM; the kernel fetches groups of live pages
         grid = (B,)
         pool = pl.BlockSpec(memory_space=pl.ANY)
-        scratch = [pltpu.VMEM((2, P, page, Hkv, D), k_pages.dtype),
-                   pltpu.VMEM((2, P, page, Hkv, D), v_pages.dtype),
+        scratch = [pltpu.VMEM((2, P, *face), k_pages.dtype),
+                   pltpu.VMEM((2, P, *face), v_pages.dtype),
                    pltpu.SemaphoreType.DMA((2, 2))]
     row = pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0))
     in_specs = [row, pool, pool]

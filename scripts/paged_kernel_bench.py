@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """`paged_decode_attention` alone on the chip, at the shapes of the three
 configurations that serve from KV pages (pages of 64 tokens, 32 a row, the
-cells' own pools): seconds per decode step's worth of calls (one per layer,
+cells' own pools; `--shape jamba2-3b`: ONE KV head, 256 rows of 10 pages of
+256, PR 57): seconds per decode step's worth of calls (one per layer,
 each fed the last one's output so that none overlaps the next, inside one jit
 with the pool carried and donated as the engine's is; host clock over several
 such steps), the bytes the LIVE pages hold, and the kernel's output against
@@ -40,16 +41,23 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # name: (rows, KV heads, query heads a KV head, layers, pages in the pool,
-# head size). The last is no cell's: a head of 64 (Llama-3.2-1B's attention),
-# whose pool XLA pads, so that its pages reach the kernel's body through
-# Pallas's pipeline and not the kernel's own DMA (asked for by name only)
+# head size[, tokens a page: 64, pages a row: 32]). `head-64` is no cell's: a
+# head of 64 (Llama-3.2-1B's attention), whose pool XLA pads, so that its
+# pages reach the kernel's body through Pallas's pipeline and not the kernel's
+# own DMA. `jamba2-3b` is its cell's pool of ONE KV head (the row loop through
+# `one_head_view` since PR 57, that pipeline before). Both by name only
 SHAPES = {
     "mistral-7b": (32, 8, 4, 32, 1025, 128),
     "qwen2-7b": (16, 4, 7, 28, 1025, 128),
     "mixtral-8x7b": (16, 8, 4, 10, 1025, 128),
     "head-64": (16, 8, 4, 16, 513, 64),
+    "jamba2-3b": (256, 1, 20, 2, 2561, 128, 256, 10),
 }
-PAGE, MAX_PAGES = 64, 32
+
+
+def shape_of(name):
+    return (*SHAPES[name], 64, 32)[:8]
+
 
 # live pages a row (0 = an idle row), as the cells' traced steps show them:
 # chat-steady 22 of 1024, longprompt 182 of 1024 in 8 rows, qwen2 127 of 512
@@ -70,8 +78,16 @@ CELL_MIXES = {
     "head-64": {
         "half idle": [3, 0, 6, 0, 7, 0, 8, 0, 8, 0, 8, 0, 9, 0, 10, 0],
     },
+    # 770 of 2560 pages live (`kernel.paged_live_page_share--closed` 29.8%,
+    # ledger, PR 56): contexts of a log-normal prompt plus half an output
+    "jamba2-3b": {
+        "manychat-closed": [
+            sorted(n for n, rows in ((1, 40), (2, 70), (3, 65), (4, 40),
+                                     (5, 22), (6, 11), (7, 5), (8, 3))
+                   for _ in range(rows))[i * 37 % 256] for i in range(256)],
+    },
 }
-UNIFORM = (0, 1, 3, 8, 20, 28)
+UNIFORM = (0, 1, 3, 8, 20, 28)  # (those a row of the shape can hold)
 
 
 def main() -> int:
@@ -110,8 +126,8 @@ def main() -> int:
     def attention(q, k, v, bt, layer, pos, start, live, group, scales=()):
         if group:  # `group_pages` reads these while tracing: the jit that
             # calls this is made anew for each group
-            pa._GROUP_TOKENS = group * PAGE
-            pa._GROUP_COLUMNS = max(rule[1], group * PAGE * k.shape[3])
+            pa._GROUP_TOKENS = group * k.shape[2]
+            pa._GROUP_COLUMNS = max(rule[1], pa._GROUP_TOKENS * k.shape[3])
             pa.paged_decode_attention.clear_cache()
         try:
             return pa.paged_decode_attention(q, k, v, bt, layer, pos, start,
@@ -143,7 +159,7 @@ def main() -> int:
             return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
         for name in args.shape:
-            B, Hkv, G, L, NP, HEAD_DIM = SHAPES[name]
+            B, Hkv, G, L, NP, HEAD_DIM, PAGE, MAX_PAGES = shape_of(name)
             kv = sds((L, NP, PAGE, Hkv, HEAD_DIM),
                      jnp.float8_e5m2 if args.fp8 else jnp.bfloat16)
             scales = (sds((L, NP, PAGE, Hkv), jnp.float32),) * 2 \
@@ -182,7 +198,7 @@ def main() -> int:
         # the pool is an ARGUMENT everywhere: a jit that captured it would
         # copy gigabytes into the program
         B, mp = bt.shape
-        Hkv, D = k.shape[3:]
+        PAGE, Hkv, D = k.shape[2:]
 
         def rows(pool, scale):
             r = pool[layer][bt].astype(jnp.float32)
@@ -202,7 +218,7 @@ def main() -> int:
         return out.reshape(q.shape)
 
     for name in args.shape:
-        B, Hkv, G, L, NP, HEAD_DIM = SHAPES[name]
+        B, Hkv, G, L, NP, HEAD_DIM, PAGE, MAX_PAGES = shape_of(name)
         if args.rehearse:
             L, args.steps = 2, 1
         keys = jax.random.split(jax.random.key(0), 3)
@@ -226,12 +242,13 @@ def main() -> int:
 
         def rows(mix):
             n = np.asarray(mix)
-            # the last live page holds 47 of its 64 slots
+            # the last live page holds all but 17 of its slots
             return (jnp.asarray(np.maximum(n * PAGE - 18, 0), jnp.int32),
                     jnp.asarray(n > 0))
 
         mixes = dict(CELL_MIXES[name])
-        mixes.update({f"every row {n}": [n] * B for n in UNIFORM})
+        mixes.update({f"every row {n}": [n] * B for n in UNIFORM
+                      if n <= MAX_PAGES})
         if args.rehearse:
             mixes = dict(list(mixes.items())[:2])
         for group in groups:

@@ -68,6 +68,9 @@ def test_serve_manifest_probe_hits_real_route():
         assert f'"{path}"' in server_src, f"probe path {path} not served"
 
 
+# 44 s through the CLI in child processes, no cell's program: tier-1's wall
+# time is its limit (ROADMAP D13 step (1), PR 57)
+@pytest.mark.slow
 def test_multihost_qlora_runs_and_resumes(tmp_path):
     """The finetune entrypoint trains on the virtual CPU mesh, writes
     the atomic train state, and a rerun resumes from it (the JobSet's

@@ -67,6 +67,9 @@ COMMON = dict(
 )
 
 
+# 45 s against a family the `model-configs` guide bars from the benchmark: no
+# cell's program rests on it (ROADMAP D13 step (1), PR 57)
+@pytest.mark.slow
 def test_gemma2_equivalence():
     cfg, model = hf_tiny(
         "Gemma2ForCausalLM", "Gemma2Config",

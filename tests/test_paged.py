@@ -427,6 +427,27 @@ KERNEL_CASES.update({
         pos=[420, 250, 767, 150], start=[130, 70, 330, 20]),
 })
 
+# ISSUE 57: a pool of ONE KV head of 128 (bf16) reaches the same row loop
+# through its `one_head_view`: 20 query heads on the one head, pages of 64
+# in groups of 4 (the last hanging over the row's end) and a page of 256 as
+# a group of its own; `start > 0`, an idle row between live ones, a window;
+# fp8 codes by the same rule at 32 rows a tile. Dead pages poisoned as above.
+_ONE_HEAD = dict(_WIDE, Hkv=1, G=20)
+KERNEL_CASES.update({
+    "groups_one_head_idle_between_live": dict(
+        _ONE_HEAD, pos=[420, 250, 639, 150], start=[130, 70, 330, 20],
+        live=[True, False, True, True]),
+    "groups_one_head_window": dict(
+        _ONE_HEAD, pos=[600, 333, 100, 470], start=[0, 20, 0, 300],
+        window=80),
+    "groups_one_head_page_256": dict(
+        _ONE_HEAD, page=256, mp=3, bt=KERNEL_CASES["groups_of_one_page"]["bt"],
+        pos=[420, 250, 767, 150], start=[130, 70, 330, 20]),
+    "groups_one_head_fp8": dict(
+        _ONE_HEAD, pos=[420, 250, 639, 150], start=[130, 70, 330, 20],
+        fp8=True),
+})
+
 
 def _kernel_case(name, poison):
     """(kernel output, gather reference on the clean pool, live) for one
@@ -435,7 +456,7 @@ def _kernel_case(name, poison):
     first .. last, scratch page included."""
     from bigdl_tpu.ops.pallas import paged_decode_attention
     from bigdl_tpu.ops.pallas.paged_attention import (
-        group_pages, live_page_range, pool_tiles_whole)
+        group_pages, live_page_range, pages_by_dma)
 
     c = dict(G=3, start=None, window=None, softcap=None, fp8=False,
              live=None, page=_KP, mp=_KMP, bt=_KBT, Hkv=2, D=16,
@@ -460,7 +481,7 @@ def _kernel_case(name, poison):
     # the `groups_*` shapes take the kernel's own DMA and several groups a
     # row by the kernel's own rules, the others a page a grid step
     itemsize = cache.k.dtype.itemsize
-    assert pool_tiles_whole(Hkv, D, itemsize) == name.startswith("groups_")
+    assert pages_by_dma(page, Hkv, D, itemsize) == name.startswith("groups_")
     if name.startswith("groups_"):
         assert group_pages(page, Hkv, D, itemsize, mp) < mp
     for layer in range(L):  # fill every row's pages, slots past pos too
@@ -549,18 +570,30 @@ def test_pages_a_group_follow_from_the_static_shapes():
     assert group_pages(64, 8, 128, 2, 3) == 3
     assert group_pages(16, 8, 128, 2, 128) == 16
     assert group_pages(4096, 8, 128, 2, 32) == 1  # a page too large to join
+    # ONE KV head: the columns are the slots (jamba's page of 256 is a group)
+    assert group_pages(64, 1, 128, 2, 32) == group_pages(64, 1, 256, 2, 32) == 4
+    assert group_pages(256, 1, 128, 2, 10) == 1
+    assert group_pages(64, 1, 128, 1, 32) == 4  # fp8 codes
     # a pool of padded tiles: a page a step (the unit tests' own shapes too)
-    assert group_pages(64, 8, 64, 2, 32) == group_pages(64, 1, 128, 2, 32) == 1
-    assert group_pages(8, 2, 16, 4, 4) == 1
+    assert group_pages(64, 8, 64, 2, 32) == group_pages(64, 6, 128, 2, 32) == 1
+    assert group_pages(8, 2, 16, 4, 4) == group_pages(8, 1, 128, 2, 32) == 1
 
 
 def test_pages_come_by_dma_only_where_the_pools_tiles_are_whole():
-    """The kernel fetches groups of pages itself only out of a pool whose
-    [Hkv, D] tiles XLA leaves unpadded in HBM (tests/test_tpu_lowering.py
-    compiles both sides of the rule); elsewhere Pallas's pipeline brings a
-    page a grid step to the same body. The unit tests' own shapes (heads
-    of 16 and 32) take that second form, the `groups_*` cases the first."""
-    from bigdl_tpu.ops.pallas.paged_attention import pool_tiles_whole
+    """The kernel fetches groups of pages itself only where a page lies in
+    HBM in whole unpadded tiles (tests/test_tpu_lowering.py compiles both
+    sides of the rule): the [Hkv, D] tiles of 2, 4 or 8 KV heads of 128 as
+    the pool is stored, or ONE KV head of 128 read as [.., page, D] (XLA
+    keeps its slots on the sublanes: a bitcast) where a page is whole
+    sublane tiles, 16 rows of bf16 and 32 of fp8 codes. Elsewhere (3 and 6
+    heads, heads of 64 and 96, a float32 one-head pool) Pallas's pipeline
+    brings a page a grid step to the same body, and the route says which.
+    The unit tests' own shapes (heads of 16 and 32) take that second form,
+    the `groups_*` cases the first."""
+    from bigdl_tpu.ops.pallas import paged_decode_attention
+    from bigdl_tpu.ops.pallas.paged_attention import (
+        one_head_view, pages_by_dma, pool_tiles_whole)
+    from bigdl_tpu.ops.routes import record_routes
 
     assert pool_tiles_whole(8, 128, 2) and pool_tiles_whole(4, 128, 2)
     assert pool_tiles_whole(2, 128, 2) and pool_tiles_whole(16, 256, 2)
@@ -569,6 +602,31 @@ def test_pages_come_by_dma_only_where_the_pools_tiles_are_whole():
                                      (8, 64, 2), (8, 96, 2), (2, 16, 4),
                                      (4, 128, 1), (4, 128, 4)):
         assert not pool_tiles_whole(n_kv, head_dim, itemsize)
+        # as stored or not, no view of more than one head helps
+        assert pages_by_dma(64, n_kv, head_dim, itemsize) == (n_kv == 1)
+    assert pages_by_dma(64, 8, 128, 2) and not one_head_view(64, 8, 128, 2)
+    for page, head_dim, itemsize in ((256, 128, 2), (16, 128, 2),
+                                     (64, 256, 2), (32, 128, 1)):
+        assert one_head_view(page, 1, head_dim, itemsize)
+    for page, head_dim, itemsize in ((8, 128, 2), (16, 128, 1), (64, 64, 2),
+                                     (64, 96, 2), (64, 128, 4)):
+        assert not pages_by_dma(page, 1, head_dim, itemsize)
+
+    def route(page, n_kv, head_dim):
+        pool = jnp.zeros((1, 3, page, n_kv, head_dim), jnp.bfloat16)
+        with record_routes() as routes:
+            paged_decode_attention(
+                jnp.zeros((1, 2 * n_kv, head_dim), jnp.bfloat16), pool, pool,
+                jnp.asarray([[1, 2]], jnp.int32), jnp.asarray(0),
+                jnp.asarray([17], jnp.int32), jnp.asarray([0], jnp.int32),
+                interpret=True)
+        ((op, arm, detail),) = routes
+        assert op == "paged"
+        return arm, detail
+
+    assert route(16, 1, 128) == ("rows", "2 pages a group of 16 x 1 at D=128")
+    assert route(16, 6, 128) == ("piped", "1 pages a group of 16 x 6 at D=128")
+    assert route(16, 2, 64) == ("piped", "1 pages a group of 16 x 2 at D=64")
 
 
 @pytest.mark.parametrize("G,page,D", [(4, 8, 32), (7, 8, 32), (4, 64, 128),

@@ -529,9 +529,11 @@ def test_word_path_is_lowered_with_signed_nibbles(one_chip, monkeypatch, kind,
 # (slots, KV heads, query heads a KV head, head size, layers, pages in the
 # pool[, pages a row: 32]): every configuration that serves from KV pages
 # (pages of 64 tokens) at its cell's pool, Mistral's with an fp8 pool too,
-# and shapes whose [Hkv, D] tiles XLA pads in HBM, which no DMA of the
-# kernel's own can slice: those pages come through Pallas's pipeline, one a
-# grid step, to the same body. The two kinds with two groups of pages call
+# a pool of ONE KV head, which the row loop takes through a view of what XLA
+# keeps (`one_head_view`, ISSUE 57), and shapes whose [Hkv, D] tiles XLA pads
+# in HBM, which no DMA of the kernel's own can slice: those pages come
+# through Pallas's pipeline, one a grid step, to the same body (`piped`). The
+# two kinds with two groups of pages call
 # the kernel once a group; SDAR's block of 4 positions is 4 x 8 query rows
 # a KV head through `paged_block_attention`
 _PAGED = {
@@ -540,7 +542,7 @@ _PAGED = {
     "mixtral-8x7b": (16, 8, 4, 128, 10, 1025),
     "mistral-7b-fp8": (32, 8, 4, 128, 32, 1025),
     "piped-head-64": (8, 8, 4, 64, 16, 257),  # Llama-3.2-1B's attention
-    "piped-one-kv-head": (8, 1, 8, 256, 18, 257),  # Gemma-2B's (MQA)
+    "rows-one-kv-head": (8, 1, 8, 256, 18, 257),  # Gemma-2B's (MQA)
     "piped-six-kv-heads": (8, 6, 4, 128, 2, 257),
     "piped-head-64-fp8": (8, 2, 7, 64, 24, 257),  # Qwen2-0.5B's, fp8 pool
     "granite-4.0-h-small": (32, 8, 4, 128, 2, 1537, 48),
@@ -558,8 +560,9 @@ def _lowered_paged(name, one_chip):
     B, Hkv, G, D, L, NP, mp = (*_PAGED[name], 32)[:7]
     page = 64
     fp8 = name.endswith("fp8")
-    assert pa.pool_tiles_whole(Hkv, D, 1 if fp8 else 2) \
+    assert pa.pages_by_dma(page, Hkv, D, 1 if fp8 else 2) \
         != name.startswith("piped")
+    assert pa.one_head_view(page, Hkv, D, 2) == (name == "rows-one-kv-head")
     kv = _sds((L, NP, page, Hkv, D),
               jnp.float8_e5m2 if fp8 else jnp.bfloat16, one_chip)
     scales = [_sds((L, NP, page, Hkv), jnp.float32, one_chip)] * 2 if fp8 \
@@ -589,9 +592,10 @@ def test_paged_decode_kernel_compiles_at_the_cells_shapes(one_chip, name):
     live page out of the pool in HBM, the pages joined into one operand
     without a relayout ([pages, page, Hkv, D] read as [columns, D]), the two
     dots over all KV heads at once and the masked softmax between them; and
-    XLA hands the pool over as it lies (no copy in front of the call). The
-    fp8 pool brings its scales by group and column. The `piped` shapes
-    compile the other way pages reach the same body."""
+    XLA hands the pool over as it lies (no copy in front of the call), a
+    pool of one KV head through the view that is a bitcast of it. The fp8
+    pool brings its scales by group and column. The `piped` shapes compile
+    the other way pages reach the same body."""
     c = _lowered_paged(name, one_chip).compile()
     assert "paged_decode_attention" in c.as_text()
     if not name.startswith("piped"):  # a pool of padded tiles is re-laid in
@@ -617,8 +621,8 @@ _PAGED_BODIES = {
         "e015a7c4557c427c65d16efe4b212c0d19d386c60855e60eb2cbc4686c424c28",
     "piped-head-64":
         "9730dd96c20962a607dbcb4f43af716a61e75672d590cca8e256bf82b6f7af44",
-    "piped-one-kv-head":
-        "f6391bafdb6592764df27b2d3d034d737ed0e99e635f75cc8c908ed4795a8b5f",
+    "rows-one-kv-head":  # since PR 57: grid (8,), groups of 4 pages [64, 256]
+        "071cac7a3c2312fdb9d4e8cef0b634a2b9ed97a44339c9eb2a23f5b86dad9165",
     "piped-six-kv-heads":
         "2ca14ff71de83b8304db9b4fe6753640575998c432a08b732d6ad4c75dddcbf3",
     "piped-head-64-fp8":
@@ -1056,6 +1060,15 @@ def test_mamba1_prefill_kernel_compiles_at_the_cells_shapes(one_chip, T):
     assert c.memory_analysis().temp_size_in_bytes < T * N * E * 4 // 2
 
 
+def _made_by_copy(text, *shapes):
+    """The `copy` results of optimized HLO `text` that have one of
+    `shapes`, whatever their type and layout."""
+    import re
+
+    dims = "|".join(",".join(map(str, s)) for s in shapes)
+    return re.findall(r"= \(?\w+\[(?:" + dims + r")\]\S* copy\(", text)
+
+
 @pytest.mark.parametrize("program", ["engine_decode", "engine_paged_prefill"])
 def test_jambas_engine_programs_keep_the_scan_in_the_pool(one_chip,
                                                         monkeypatch, program):
@@ -1064,8 +1077,9 @@ def test_jambas_engine_programs_keep_the_scan_in_the_pool(one_chip,
     compiled for the chip: both call the scan kernel; no float32 array has a
     `[.., 16, 5120]` piece a token or a row outside the pool (the decay
     `exp(dt A)` is formed in the kernel); the state pool is aliased in and
-    out with no other result of its shape; and no whole `conv` array is
-    re-laid (3 inputs side by side a row, not an axis of 3)."""
+    out with no other result of its shape; no whole `conv` array is
+    re-laid (3 inputs side by side a row, not an axis of 3); and no K or V
+    pool is (ONE KV head, which the paged kernel reads as XLA keeps it)."""
     import math
     import os
     import re
@@ -1129,39 +1143,53 @@ def test_jambas_engine_programs_keep_the_scan_in_the_pool(one_chip,
         r"= \(?f32\[" + state + r"\]\S* ([\w\-]+)\(", text))
     assert made <= {"parameter", "get-tuple-element", "custom-call", "while",
                     "tuple", "bitcast", "conditional"}, made
-    conv = ",".join(map(str, pool.conv.shape))
-    assert "copy" not in set(re.findall(
-        r"= \(?f32\[" + conv + r"\]\S* ([\w\-]+)\(", text))
+    assert not _made_by_copy(text, pool.conv.shape)
+    # nor is a K or V pool of ONE KV head: the paged kernel reads it as XLA
+    # keeps it (`paged_attention.one_head_view`; four copies a step till PR 57)
+    assert not _made_by_copy(text, pool.k.shape,
+                             pool.k.shape[:3] + pool.k.shape[4:])
     m = c.memory_analysis()
     assert m.alias_size_in_bytes >= pool.ssm.size * 4 + pool.conv.size * 4
 
 
-def test_paged_decode_kernel_compiles_at_jambas_one_kv_head(one_chip):
+@pytest.mark.parametrize("fp8", [False, True], ids=["bf16", "fp8"])
+def test_paged_decode_kernel_compiles_at_jambas_one_kv_head(one_chip, fp8):
     """256 slots x 10 pages of 256 tokens, 20 query heads on ONE KV head of
     128, a pool of 2561 pages over 2 layers: `pool_tiles_whole` is false (XLA
-    pads the `[1, 128]` tiles), so the pages come through Pallas's pipeline,
-    a page a grid step of a (256, 10) grid; a group of 20 query rows is no
-    multiple of 8 and Mosaic takes it. In front of a call that stands alone
-    the pool is re-laid, as in front of every `piped` call; so it is in
-    `engine_decode` (scripts/engine_fit.py counts four copies of a pool a
-    step: ROADMAP R10's price at this shape, PERF.md 'Left by PR 56')."""
+    would pad `[1, 128]` tiles, so it keeps the page's 256 slots on the
+    sublanes instead, `{4,2,3,1,0}`), and the kernel reads the pool as
+    `[L, pages, 256, 128]`, a bitcast of that (`one_head_view`): the row
+    loop's own DMA takes a live page, a group is one page, grid (256,); a
+    group of 20 query rows is no multiple of 8 and Mosaic takes it. The pool
+    is handed over as it lies: no copy of 0.31 GiB in front of the call
+    (PERF.md section 6, PR 57; the four a step that ROADMAP R10 counted).
+    fp8 codes by the same rule at their 32 rows a tile."""
     from bigdl_tpu.ops.pallas import paged_attention as pa
 
     B, Hkv, G, D, L, NP, page, mp = 256, 1, 20, 128, 2, 2561, 256, 10
-    assert not pa.pool_tiles_whole(Hkv, D, 2)
-    kv = _sds((L, NP, page, Hkv, D), jnp.bfloat16, one_chip)
+    itemsize = 1 if fp8 else 2
+    assert not pa.pool_tiles_whole(Hkv, D, itemsize)
+    assert pa.one_head_view(page, Hkv, D, itemsize)
+    assert pa.group_pages(page, Hkv, D, itemsize, mp) == 1
+    kv = _sds((L, NP, page, Hkv, D),
+              jnp.float8_e5m2 if fp8 else jnp.bfloat16, one_chip)
+    scales = [_sds((L, NP, page, Hkv), jnp.float32, one_chip)] * 2 * fp8
 
-    def f(q, k, v, bt, layer, pos, start, live):
+    def f(q, k, v, bt, layer, pos, start, live, *scales):
         return pa.paged_decode_attention(q, k, v, bt, layer, pos, start,
-                                         scale=D ** -0.5, live=live,
+                                         *scales, scale=D ** -0.5, live=live,
                                          interpret=False)
 
     c = jax.jit(f).lower(
         _sds((B, Hkv * G, D), jnp.bfloat16, one_chip), kv, kv,
         _sds((B, mp), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
         _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
-        _sds((B,), jnp.bool_, one_chip)).compile()
-    assert "paged_decode_attention" in c.as_text()
+        _sds((B,), jnp.bool_, one_chip), *scales).compile()
+    text = c.as_text()
+    assert "paged_decode_attention" in text
+    assert not _made_by_copy(text, (L, NP, page, Hkv, D), (L, NP, page, D))
+    if not fp8:  # (the scales of a layer are re-laid and gathered: 10 MiB)
+        assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 @pytest.mark.parametrize("T", [64, 1024])
