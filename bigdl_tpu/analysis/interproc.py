@@ -16,13 +16,11 @@ Rule map (details + examples in docs/static-analysis.md):
 - LCK102   blocking call (fsync/flush/sleep/host transfer) under a hot
            lock (``_stat_lock`` / ``_admission_lock``)
 - DSP001   registered qtype missing from the GEMV dispatch table (or a
-           dispatch key naming an unregistered qtype); table entry with
-           neither a fused backward kernel nor a stated bwd_exempt
+           dispatch key naming an unregistered qtype)
 - DSP002   ``from bigdl_tpu.ops.pallas import X`` where X is not
            exported by the kernel package
-- DSP003   dispatch k_multiple (forward or bwd_k_multiple) incompatible
-           with the qtype's block/superblock geometry; DecodeSpec
-           storage not covered
+- DSP003   dispatch k_multiple incompatible with the qtype's
+           block/superblock geometry; DecodeSpec storage not covered
 - DSP004   VMEM-budget magic number drifted from tiling.py's constants
 - DSP005   tiling.py budget invariants (caps, lane alignment) violated
 - DSP006   attention epilogue decodes K/V tiles inline instead of
@@ -240,111 +238,20 @@ def _gemv_table(tree: ast.Module) -> Tuple[Optional[int],
                 if not (isinstance(k, ast.Constant)
                         and isinstance(k.value, str)):
                     continue
-                k_multiple = -1
-                if isinstance(v, ast.Call) and v.args:
-                    try:
-                        k_multiple = int(flow.eval_const(v.args[0]))
-                    except (ValueError, TypeError):
-                        k_multiple = -1
+                try:
+                    k_multiple = int(flow.eval_const(v))
+                except (ValueError, TypeError):
+                    k_multiple = -1
                 table[k.value] = (k_multiple, k.lineno)
             return node.lineno, table
     return None, {}
-
-
-#: _GemvEntry field order (positional-arg mapping for the resolvers
-#: below); kept in sync by test_dsp001_field_order_matches_linear.
-_GEMV_FIELDS = ("k_multiple", "run", "gemm", "gemm_exempt",
-                "bwd", "bwd_exempt", "bwd_k_multiple")
-
-
-def _gemv_entries(tree: ast.Module):
-    """(qtype, key lineno, value ast.Call) per _QGEMV_QTYPES entry."""
-    for node in ast.walk(tree):
-        if (isinstance(node, ast.Assign) and len(node.targets) == 1
-                and isinstance(node.targets[0], ast.Name)
-                and node.targets[0].id == "_QGEMV_QTYPES"
-                and isinstance(node.value, ast.Dict)):
-            for k, v in zip(node.value.keys, node.value.values):
-                if (isinstance(k, ast.Constant) and isinstance(k.value, str)
-                        and isinstance(v, ast.Call)):
-                    yield k.value, k.lineno, v
-
-
-def _entry_factories(tree: ast.Module) -> Dict[str, tuple]:
-    """name -> (param names, {param: default expr}, return-call field
-    exprs) for every module-level helper whose body returns a
-    ``_GemvEntry(...)`` — linear.py's ``_entry`` and any sibling a new
-    format family adds."""
-    out: Dict[str, tuple] = {}
-    for node in tree.body:
-        if not isinstance(node, ast.FunctionDef):
-            continue
-        ret = None
-        for stmt in ast.walk(node):
-            if (isinstance(stmt, ast.Return)
-                    and isinstance(stmt.value, ast.Call)
-                    and isinstance(stmt.value.func, ast.Name)
-                    and stmt.value.func.id == "_GemvEntry"):
-                ret = stmt.value
-        if ret is None:
-            continue
-        a = node.args
-        params = [p.arg for p in a.args]
-        defaults = dict(zip(params[len(params) - len(a.defaults):],
-                            a.defaults))
-        for p, d in zip(a.kwonlyargs, a.kw_defaults):
-            params.append(p.arg)
-            if d is not None:
-                defaults[p.arg] = d
-        fields = dict(zip(_GEMV_FIELDS, ret.args))
-        for kw in ret.keywords:
-            if kw.arg:
-                fields[kw.arg] = kw.value
-        out[node.name] = (params, defaults, fields)
-    return out
-
-
-def _entry_fields(call: ast.Call,
-                  factories: Dict[str, tuple]) -> Optional[Dict[str, object]]:
-    """Resolve one table entry's _GemvEntry field exprs, following one
-    level of factory indirection (``_entry(64, f)`` substitutes the
-    caller's arguments into the factory's ``_GemvEntry(...)`` return).
-    None when the callee cannot be analyzed statically."""
-    fname = call.func.id if isinstance(call.func, ast.Name) else None
-    if fname == "_GemvEntry":
-        fields: Dict[str, object] = dict(zip(_GEMV_FIELDS, call.args))
-        for kw in call.keywords:
-            if kw.arg:
-                fields[kw.arg] = kw.value
-        return fields
-    fac = factories.get(fname or "")
-    if fac is None:
-        return None
-    params, defaults, ret_fields = fac
-    bind: Dict[str, object] = dict(zip(params, call.args))
-    for kw in call.keywords:
-        if kw.arg:
-            bind[kw.arg] = kw.value
-    fields = {}
-    for field, expr in ret_fields.items():
-        if isinstance(expr, ast.Name) and expr.id in params:
-            expr = bind.get(expr.id, defaults.get(expr.id))
-        fields[field] = expr
-    return fields
-
-
-def _expr_is_none(expr: object) -> bool:
-    """Absent (NamedTuple default None) or a literal ``None``."""
-    return expr is None or (isinstance(expr, ast.Constant)
-                            and expr.value is None)
 
 
 class DispatchCoverage(Check):
     rule = "DSP001"
     description = (
         "every non-dense registered qtype needs a _QGEMV_QTYPES entry "
-        "(or the table names a qtype that is not registered); every "
-        "entry needs a fused backward kernel or an explicit bwd_exempt"
+        "(or the table names a qtype that is not registered)"
     )
 
     def run(self, ctx: FileContext) -> Iterable[Finding]:
@@ -367,8 +274,8 @@ class DispatchCoverage(Check):
                             "_QGEMV_QTYPES entry — it would silently fall "
                             "back to dequant-matmul on the decode path"
                             % (name, spec.get("line")),
-                    hint="add a _QGEMV_QTYPES entry (kernel or explicit "
-                         "gemm-path _entry with gemm_exempt)",
+                    hint="add a _QGEMV_QTYPES entry (its k_multiple) and "
+                         "a qdecode.spec_for branch",
                 )
         for name, (_, line) in sorted(table.items()):
             if name not in specs:
@@ -377,29 +284,6 @@ class DispatchCoverage(Check):
                     message="_QGEMV_QTYPES entry '%s' names a qtype that "
                             "is not registered in quant/qtypes.py" % name,
                     hint="remove the stale entry or register the qtype",
-                )
-        # the backward column: the import-time assert catches this at
-        # runtime, but only on a path that imports linear.py — the lint
-        # catches it on the diff. A silent bwd=None entry falls back to
-        # XLA-remat dx, which writes a full bf16 dequant of W to HBM
-        # every train step (the backward twin of the forward cliff).
-        factories = _entry_factories(ctx.tree)
-        for name, line, call in _gemv_entries(ctx.tree):
-            fields = _entry_fields(call, factories)
-            if fields is None:
-                continue  # opaque callee: runtime assert still guards
-            if _expr_is_none(fields.get("bwd")) \
-                    and _expr_is_none(fields.get("bwd_exempt")):
-                yield Finding(
-                    rule=self.rule, path=ctx.rel, line=line,
-                    message="'%s' declares neither a fused backward "
-                            "kernel (bwd=) nor a bwd_exempt reason — dx "
-                            "would silently fall back to XLA-remat "
-                            "dequant every train step" % name,
-                    hint="route bwd through ops/pallas/qbackward.py's "
-                         "table-driven dx kernel, or state why the "
-                         "format cannot decode in the transposed access "
-                         "pattern",
                 )
 
 
@@ -439,7 +323,7 @@ def _pallas_exports(project: "flow.Project") -> Set[str]:
     if mod is None:
         return set()
     names: Set[str] = set(mod.functions) | set(mod.classes)
-    names |= set(mod.imports)  # from .qmatmul import qmatmul_int4, ...
+    names |= set(mod.imports)  # from .qmatmul import qmatmul, ...
     for node in ast.walk(mod.tree):
         if isinstance(node, ast.Assign):
             for tgt in node.targets:
@@ -464,11 +348,9 @@ def _pallas_exports(project: "flow.Project") -> Set[str]:
 class DispatchGeometry(Check):
     rule = "DSP003"
     description = (
-        "dispatch k_multiple (forward or backward) must be divisible by "
-        "the qtype's block (and superblock) size — and bwd_k_multiple "
-        "may only coarsen the forward alignment; DecodeSpec storage "
-        "dispatch must cover every registered storage or have an "
-        "explicit default"
+        "dispatch k_multiple must be divisible by the qtype's block "
+        "(and superblock) size; DecodeSpec storage dispatch must cover "
+        "every registered storage or have an explicit default"
     )
 
     def run(self, ctx: FileContext) -> Iterable[Finding]:
@@ -513,49 +395,6 @@ class DispatchGeometry(Check):
                     message="'%s' uses packed_planes storage but declares "
                             "no planes tuple" % name,
                     hint="declare the per-plane bit widths in QTypeSpec",
-                )
-        # backward tile geometry: a declared bwd_k_multiple must satisfy
-        # the same block/superblock divisibility as the forward's, and
-        # may only COARSEN it (the dx kernel's chunk walk has the same
-        # plane-split period as the forward's — a finer backward
-        # alignment would admit shapes the decode loop cannot tile)
-        factories = _entry_factories(ctx.tree)
-        for name, line, call in _gemv_entries(ctx.tree):
-            fields = _entry_fields(call, factories)
-            if fields is None:
-                continue
-            expr = fields.get("bwd_k_multiple")
-            if _expr_is_none(expr):
-                continue  # inherits k_multiple, already checked above
-            try:
-                bkm = int(flow.eval_const(expr))
-            except (ValueError, TypeError):
-                continue
-            spec = specs.get(name)
-            if spec is None or bkm <= 0:
-                continue
-            for field in ("block_size", "superblock"):
-                unit = spec.get(field)
-                if isinstance(unit, int) and unit > 0 and bkm % unit != 0:
-                    yield Finding(
-                        rule=self.rule, path=ctx.rel, line=line,
-                        message="'%s' bwd_k_multiple %d is not a multiple "
-                                "of its %s %d — the dx kernel's K walk "
-                                "would straddle quant groups"
-                                % (name, bkm, field, unit),
-                        hint="backward alignment must keep whole quant "
-                             "blocks per decoded chunk",
-                    )
-            fwd = table.get(name, (-1, 0))[0]
-            if fwd > 0 and bkm % fwd != 0:
-                yield Finding(
-                    rule=self.rule, path=ctx.rel, line=line,
-                    message="'%s' bwd_k_multiple %d is not a multiple of "
-                            "its forward k_multiple %d — it may only "
-                            "coarsen the contraction alignment, never "
-                            "refine it" % (name, bkm, fwd),
-                    hint="use a multiple of k_multiple (or None to "
-                         "inherit it)",
                 )
 
     def _check_storage_coverage(self, ctx: FileContext) -> Iterable[Finding]:

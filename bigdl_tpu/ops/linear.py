@@ -4,13 +4,13 @@ Equivalent of `LowBitLinear.forward` in the reference
 (low_bit_linear.py:606-716): one entry point that dispatches on weight
 type and shape. The prefill/decode split the reference implements with
 two SYCL kernels (`xe_linear.forward_new` vs `xe_batch.batch_forward`)
-maps to: decode-shaped (few rows) matmuls go to the fused Pallas
-dequant-GEMV (packed weights cross HBM as stored), larger shapes —
-prefill, continuous batches, speculative verify, QLoRA training — go to
-the fused tiled dequant-GEMM (weight tiles decode once in VMEM and feed
-the MXU; the dequantized copy never round-trips HBM). Only ineligible
-shapes (odd O/K, exempt formats) take the in-graph XLA dequant that XLA
-fuses into the matmul.
+is ONE kernel here, `ops/pallas/qmatmul.qmatmul`, whose row tile follows
+the row count: at decode's few rows packed weights cross HBM as stored,
+at larger shapes (prefill, continuous batches, speculative verify, QLoRA
+training) weight tiles decode once in VMEM and feed the MXU, and the
+dequantized copy never round-trips HBM. Only what `fused_why_not` refuses
+(odd O/K, a format with no decoder, the kernels switched off) takes the
+in-graph XLA dequant that XLA fuses into the matmul.
 
 The fused paths are wrapped in a custom_vjp so training (QLoRA's frozen
 low-bit base) can differentiate through them. The backward is fused
@@ -18,11 +18,9 @@ too: dx = g @ dequant(W) routes to the Pallas dx kernel
 (ops/pallas/qbackward.py), which dequantizes weight tiles per-chunk in
 VMEM straight into the MXU — the bf16 rematerialized copy of W the XLA
 remat path writes to HBM every train step never exists ("Training
-Transformers with 4-bit Integers", arxiv 2306.11987). The registry's
-`bwd` column drives it through the same shared decoder as the forward,
-with an import-time assert that no qtype silently falls back; the XLA
-remat stays available under `fused_backward_scope(False)` as the parity
-oracle.
+Transformers with 4-bit Integers", arxiv 2306.11987). It reads every
+format through the forward's shared decoder; the XLA remat stays
+available under `fused_backward_scope(False)` as the parity oracle.
 """
 
 from __future__ import annotations
@@ -30,7 +28,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-from typing import Callable, NamedTuple, Optional, Union
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -40,11 +38,12 @@ from bigdl_tpu.ops import routes
 from bigdl_tpu.quant import QTensor
 from bigdl_tpu.quant.qtensor import KERNEL_FIELDS
 
-# Decode GEMV threshold, same role as the reference's `use_batch_forward`
-# heuristic (low_bit_linear.py:272-309): below this many rows the matmul
-# is weight-bandwidth-bound and the whole-M-block GEMV contract wins;
-# above it the tiled GEMM amortizes each decoded weight tile over a
-# [block_m, K] row tile.
+# A route note says `gemv` up to this many rows and `gemm` above, the
+# reference's `use_batch_forward` split (low_bit_linear.py:272-309): at
+# few rows the matmul is bound by the weight's bandwidth, above them each
+# decoded weight tile is amortized over a [block_m, K] row tile. ONE
+# kernel serves both (`ops/pallas/qmatmul.qmatmul`, whose row tile
+# `tiling.pick_block_m` picks): the threshold picks the word, no kernel.
 _GEMV_MAX_ROWS = 32
 
 
@@ -55,198 +54,53 @@ def _rows(shape) -> int:
     return n
 
 
-def _run_sym_int4(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_int4
-
-    return qmatmul_int4(x, w.data, w.scales, out_dtype=x.dtype, block_o=bo,
-                        layer=layer)
-
-
-def _run_asym_int4(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_asym_int4
-
-    return qmatmul_asym_int4(x, w.data, w.scales, w.mins, out_dtype=x.dtype,
-                             block_o=bo, layer=layer)
-
-
-def _run_codebook(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_codebook
-
-    return qmatmul_codebook(x, w.data, w.scales, codebook=w.spec.codebook,
-                            block=w.spec.block_size, out_dtype=x.dtype,
-                            block_o=bo, layer=layer)
-
-
-def _run_int8(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_int8
-
-    return qmatmul_int8(x, w.data, w.scales, out_dtype=x.dtype, block_o=bo,
-                        layer=layer)
-
-
-def _run_asym_int5(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_bytes
-
-    return qmatmul_bytes(x, w.data, w.scales, w.mins, decode="i8",
-                         block=w.spec.block_size, out_dtype=x.dtype,
-                         block_o=bo, layer=layer)
-
-
-def _run_fp8(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_fp8
-
-    return qmatmul_fp8(x, w.data, w.scales, block=w.spec.block_size,
-                       out_dtype=x.dtype, block_o=bo, layer=layer)
-
-
-def _run_planes(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_planes
-
-    spec = w.spec
-    if spec.name == "fp6":  # exact arithmetic e2m3 decode
-        decode = ("e2m3",)
-    elif spec.codebook is not None:  # nf3: 8-entry select tree
-        decode = ("lut", tuple(float(c) for c in spec.codebook))
-    else:  # sym_int5: v - 16
-        decode = ("offset", 16)
-    return qmatmul_planes(x, w.data, w.scales, spec.planes, decode,
-                          spec.block_size, out_dtype=x.dtype, block_o=bo,
-                          layer=layer)
-
-
-def _run_q4k(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_q4k
-
-    return qmatmul_q4k(x, w.data, w.scales, w.mins, w.sub_scales,
-                       w.sub_mins, out_dtype=x.dtype, block_o=bo,
-                       layer=layer)
-
-
-def _run_q5k(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_q5k
-
-    return qmatmul_q5k(x, w.data, w.scales, w.mins, w.sub_scales,
-                       w.sub_mins, out_dtype=x.dtype, block_o=bo,
-                       layer=layer)
-
-
-def _run_q2k(x, w, bo, layer=None):
-    from bigdl_tpu.ops.pallas import qmatmul_q2k
-
-    return qmatmul_q2k(x, w.data, w.scales, w.mins, w.sub_scales,
-                       w.sub_mins, out_dtype=x.dtype, block_o=bo,
-                       layer=layer)
-
-
-def _run_dx(g, w, bo):
-    # shared fused backward: dx = g @ dequant(W), table-driven through
-    # qdecode.spec_for — one kernel body serves every registered format
-    from bigdl_tpu.ops.pallas import qmatmul_dx
-
-    return qmatmul_dx(g, w, out_dtype=g.dtype, block_o=bo)
-
-
-def _run_q6k(x, w, bo, layer=None):
-    # planar q3_k is structurally identical to q6_k (int8 centered
-    # codes, int8 sub-scales per 16, f16 d per 256) and shares its kernel
-    from bigdl_tpu.ops.pallas import qmatmul_q6k
-
-    return qmatmul_q6k(x, w.data, w.scales, w.sub_scales, out_dtype=x.dtype,
-                       block_o=bo, layer=layer)
-
-
-class _GemvEntry(NamedTuple):
-    """Eligibility + kernels for one qtype, registered in one place.
-
-    k_multiple folds every per-format shape rule into one divisibility
-    check on the LOGICAL contraction dim: whole quant blocks per packed
-    plane (sym/asym_int4 64, nf4/fp4 128), whole super-blocks (k-quants
-    256), and 128-lane alignment of the finest plane split for the
-    multi-plane kernels (fp6/q2_k 512; sym_int5/nf3/q5_k 1024 — the
-    eighth-split 1-bit plane slices at K/8-byte offsets).
-
-    `run` serves decode shapes (rows <= _GEMV_MAX_ROWS, whole-M block),
-    `gemm` serves everything above (M-tiled; both resolve to the unified
-    kernel in ops/pallas/qmatmul.py, which reads the format through the
-    shared decoder in ops/pallas/qdecode.py). A format without a fused
-    GEMM path MUST say why in `gemm_exempt` — the dispatch-coverage test
-    fails any entry that silently leaves prefill shapes on the XLA
-    dequant path.
-
-    `bwd` is the fused backward dx kernel (ops/pallas/qbackward.py,
-    same table-driven decoder); a format without one MUST say why in
-    `bwd_exempt` — a silent XLA-remat fallback rewrites a full bf16
-    dequant of W to HBM every train step, the backward twin of the
-    forward cliff. `bwd_k_multiple` optionally coarsens the contraction
-    alignment the backward needs (None inherits k_multiple; the dx
-    kernel's chunk walk has the same plane-split period as the
-    forward's, so every current format inherits)."""
-    k_multiple: int
-    run: Callable  # (x [M, K] compute dtype, w, block_o) -> y [M, O]
-    gemm: Optional[Callable] = None  # rows > _GEMV_MAX_ROWS kernel
-    gemm_exempt: Optional[str] = None  # stated reason when gemm is None
-    bwd: Optional[Callable] = None  # (g [M, O], w, block_o) -> dx [M, K]
-    bwd_exempt: Optional[str] = None  # stated reason when bwd is None
-    bwd_k_multiple: Optional[int] = None  # None = inherit k_multiple
-
-
-def _entry(k_multiple: int, run: Callable) -> _GemvEntry:
-    # every current format's kernel is M-tiled, so the same callable
-    # serves both shape classes, and the table-driven dx kernel serves
-    # every format's backward; a future format that can only GEMV (or
-    # cannot decode in the transposed access pattern) must pass an
-    # explicit gemm_exempt / bwd_exempt reason instead
-    return _GemvEntry(k_multiple, run, gemm=run, bwd=_run_dx)
-
-
-# every qtype with a decode path dispatches to a fused Pallas kernel —
-# the in-kernel decode mirrors QTensor.dequantize exactly
+# Every qtype with a decode path, and the multiple its LOGICAL contraction
+# dim must be of: every per-format shape rule as one divisibility check.
+# Whole quant blocks per packed plane (sym/asym_int4 64, nf4/fp4 128), whole
+# super-blocks (k-quants 256), and 128-lane alignment of the finest plane
+# split for the multi-plane formats (fp6/q2_k 512; sym_int5/nf3/q5_k 1024:
+# the eighth-split 1-bit plane slices at K/8-byte offsets). Forward
+# (`qmatmul`, GEMV and GEMM shapes alike) and backward (`qmatmul_dx`, whose
+# chunk walk has the forward's plane-split period) read every one of them
+# through the shared decoder, `qdecode.spec_for`; graftlint DSP001 / DSP003
+# hold the table to the qtype registry.
 _QGEMV_QTYPES = {
-    "sym_int4": _entry(64, _run_sym_int4),
-    "asym_int4": _entry(64, _run_asym_int4),
-    "nf4": _entry(128, _run_codebook),
-    "fp4": _entry(128, _run_codebook),
-    "sym_int8": _entry(32, _run_int8),
-    "asym_int5": _entry(32, _run_asym_int5),
-    "fp8_e4m3": _entry(128, _run_fp8),
-    "fp8_e5m2": _entry(128, _run_fp8),
-    "sym_int5": _entry(1024, _run_planes),
-    "fp6": _entry(512, _run_planes),
-    "nf3": _entry(1024, _run_planes),
-    "q2_k": _entry(512, _run_q2k),
-    "q3_k": _entry(256, _run_q6k),
-    "q4_k": _entry(256, _run_q4k),
-    "q5_k": _entry(1024, _run_q5k),
-    "q6_k": _entry(256, _run_q6k),
+    "sym_int4": 64,
+    "asym_int4": 64,
+    "nf4": 128,
+    "fp4": 128,
+    "sym_int8": 32,
+    "asym_int5": 32,
+    "fp8_e4m3": 128,
+    "fp8_e5m2": 128,
+    "sym_int5": 1024,
+    "fp6": 512,
+    "nf3": 1024,
+    "q2_k": 512,
+    "q3_k": 256,
+    "q4_k": 256,
+    "q5_k": 1024,
+    "q6_k": 256,
 }
 
-for _name, _e in _QGEMV_QTYPES.items():
-    assert _e.gemm is not None or _e.gemm_exempt, (
-        f"{_name}: declare a fused GEMM kernel or an explicit gemm_exempt "
-        "reason (silent XLA-dequant fallback above _GEMV_MAX_ROWS is the "
-        "2.7x cliff class this registry exists to prevent)"
-    )
-    assert _e.bwd is not None or _e.bwd_exempt, (
-        f"{_name}: declare a fused backward kernel or an explicit "
-        "bwd_exempt reason — a silent XLA-remat dx writes a full bf16 "
-        "dequant of W to HBM every train step, the backward twin of the "
-        "forward cliff"
-    )
 
-
-def _shape_guard(w: QTensor) -> tuple[Optional[_GemvEntry], str]:
-    """(entry, "") when the kernels can tile this weight's last two dims
-    [O, K] (a rank-2 weight or each expert of a stack), else (None, the
-    guard that refuses it)."""
+def fused_why_not(w: QTensor, lead: Optional[int] = None) -> Optional[str]:
+    """None when the packed-matmul kernels take `w`, whatever the row
+    count, else the guard that refuses it, in the words a route note
+    prints. The kernels tile a weight's last two dims `[O, K]`; `lead` is
+    how many leading axes the caller's kernel indexes (`linear`: none, or
+    the layer axis), None where any stack will do (`grouped_route`)."""
     from bigdl_tpu.ops.pallas import why_not_pallas
     from bigdl_tpu.ops.pallas.tiling import VMEM_BUDGET
 
-    entry = _QGEMV_QTYPES.get(w.qtype)
-    if entry is None:
-        return None, "no fused kernel registered for this qtype"
+    if lead is not None and w.data.ndim - lead != 2:
+        return f"weight is rank {w.data.ndim - lead}, kernels take rank 2"
+    k_multiple = _QGEMV_QTYPES.get(w.qtype)
+    if k_multiple is None:
+        return "no fused kernel registered for this qtype"
     out, kw_ = w.data.shape[-2:]
     if out % 128 != 0:
-        return None, "O not a multiple of 128 lanes"
+        return "O not a multiple of 128 lanes"
     # the kernels tile O at >= 128 rows (Mosaic lane rule forbids
     # smaller output tiles); if even a 128-row tile's persistent weight
     # block cannot fit half the scoped-VMEM budget (the other half is
@@ -254,32 +108,10 @@ def _shape_guard(w: QTensor) -> tuple[Optional[_GemvEntry], str]:
     # compile a kernel that overflows vmem
     row_bytes = kw_ * w.data.dtype.itemsize
     if 128 * row_bytes > VMEM_BUDGET // 2:
-        return None, "a 128-row weight tile exceeds half the VMEM budget"
-    if w.shape[-1] % entry.k_multiple != 0:
-        return None, f"K not a multiple of {entry.k_multiple}"
-    off = why_not_pallas()
-    if off is not None:
-        return None, off
-    return entry, ""
-
-
-def _fused_route(x: jax.Array, w: QTensor, stacked: bool = False
-                 ) -> tuple[Optional[Callable], str]:
-    """(kernel, why): the fused kernel this (x, w) pair dispatches to,
-    or None with the guard that sent it to the XLA dequant route. Shape
-    guards are shared by both shape classes. `stacked`: the codes carry
-    a leading layer axis the caller indexes (`linear`'s `layer`)."""
-    rank = w.data.ndim - int(stacked)
-    if rank != 2:
-        return None, f"weight is rank {rank}, kernels take rank 2"
-    entry, why = _shape_guard(w)
-    if entry is None:
-        return None, why
-    if _rows(x.shape) <= _GEMV_MAX_ROWS:
-        return entry.run, "gemv"
-    if entry.gemm is None:
-        return None, f"gemm_exempt: {entry.gemm_exempt}"
-    return entry.gemm, "gemm"
+        return "a 128-row weight tile exceeds half the VMEM budget"
+    if w.shape[-1] % k_multiple != 0:
+        return f"K not a multiple of {k_multiple}"
+    return why_not_pallas()
 
 
 def grouped_route(*stacks) -> Optional[str]:
@@ -295,11 +127,9 @@ def grouped_route(*stacks) -> Optional[str]:
             return "weights are dense, not packed"
         if w.data.ndim < 3:
             return f"weight is rank {w.data.ndim}, not a stack"
-        entry, why = _shape_guard(w)
-        if entry is None:
+        why = fused_why_not(w)
+        if why is not None:
             return why
-        if entry.gemm is None:
-            return f"gemm_exempt: {entry.gemm_exempt}"
     return None
 
 
@@ -361,8 +191,7 @@ def prepare_scale_bits(w, stacks: Optional[int] = None):
 
     if not isinstance(w, QTensor) or w.spec.storage.startswith("fp8"):
         return w
-    if (_shape_guard(w)[0] is None if w.data.ndim == 2
-            else grouped_route(w) is not None):
+    if fused_why_not(w) is not None:
         return w
     if stacks is None:
         layout = bits_layout(qdecode.spec_for(w.spec), w.data.shape[-2],
@@ -407,29 +236,12 @@ def stacks_in(p: dict, kept: dict) -> dict:
                     for n, fields in kept.items()}}
 
 
-def _fused_kernel(x: jax.Array, w: QTensor) -> Optional[Callable]:
-    return _fused_route(x, w)[0]
-
-
-def _use_qgemv(x: jax.Array, w: QTensor) -> bool:
-    """Decode-shaped dispatch to the fused GEMV contract."""
-    return (_rows(x.shape) <= _GEMV_MAX_ROWS
-            and _fused_kernel(x, w) is not None)
-
-
-def _use_qgemm(x: jax.Array, w: QTensor) -> bool:
-    """Prefill/batch/training dispatch to the fused tiled GEMM."""
-    return (_rows(x.shape) > _GEMV_MAX_ROWS
-            and _fused_kernel(x, w) is not None)
-
-
 # Backward-path selector, read at TRACE time inside the custom_vjp bwd
-# rules: True routes dx through the fused Pallas kernel whenever the
-# entry has one, False keeps the XLA rematerialized dequant (the parity
-# oracle, and the pre-PR behavior). Trace-time means the flag is baked
-# into the jaxpr — flipping it under an already-jitted train step does
-# nothing until retrace, which is exactly the semantics a per-run knob
-# (train/qlora.make_train_step(fused_backward=...)) needs.
+# rules: True routes dx through the fused Pallas kernel, False keeps the
+# XLA rematerialized dequant (the parity oracle). Trace-time means the
+# flag is baked into the jaxpr — flipping it under an already-jitted
+# train step does nothing until retrace, which is exactly the semantics
+# a per-run knob (train/qlora.make_train_step(fused_backward=...)) needs.
 _FUSED_BACKWARD = True
 
 
@@ -451,18 +263,17 @@ def fused_backward_scope(enabled: bool = True):
         _FUSED_BACKWARD = prev
 
 
-def _fused_dx(g: jax.Array, w: QTensor, qtype: str, block_o: int):
-    """dx = g @ dequant(W) for the custom_vjp bwd rules: the fused
-    Pallas kernel when the registry + selector allow it, else the XLA
-    rematerialized dequant. Forward eligibility (O % 128, weight-tile
-    VMEM fit, K % k_multiple, use_pallas) already held — the vjp only
-    wraps fused forwards — so the only fresh check is the backward's own
-    alignment column."""
-    entry = _QGEMV_QTYPES[qtype]
-    km = entry.bwd_k_multiple or entry.k_multiple
-    if (_FUSED_BACKWARD and entry.bwd is not None
-            and w.shape[-1] % km == 0):
-        return entry.bwd(g, w, block_o)
+def _fused_dx(g: jax.Array, w: QTensor):
+    """dx = g @ dequant(W) for the custom_vjp bwd rules: the fused Pallas
+    kernel (`qmatmul_dx`, the forward's decoder and so its formats and
+    its K alignment: the vjp only wraps fused forwards, whose guards
+    already held) unless the selector asks for the XLA rematerialized
+    dequant."""
+    if _FUSED_BACKWARD:
+        from bigdl_tpu.ops.pallas import qmatmul_dx
+        from bigdl_tpu.ops.pallas.tiling import WORD_BLOCK_O
+
+        return qmatmul_dx(g, w, out_dtype=g.dtype, block_o=WORD_BLOCK_O)
     wd = w.dequantize(g.dtype)
     return jnp.einsum("...o,ok->...k", g, wd, preferred_element_type=g.dtype)
 
@@ -488,31 +299,24 @@ def _layer_of(w: QTensor, layer) -> QTensor:
         w.data, layer, keepdims=False))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _fused_matmul(x: jax.Array, w: QTensor, layer, qtype: str, block_o: int):
-    if w.bits_layout is not None:
-        # prepared scale bits: the generic entry reads them (the same
-        # DecodeSpec, from the registry, as the per-format wrappers build)
-        from bigdl_tpu.ops.pallas import qmatmul
+@jax.custom_vjp
+def _fused_matmul(x: jax.Array, w: QTensor, layer):
+    from bigdl_tpu.ops.pallas import qmatmul
 
-        return qmatmul(x, w, out_dtype=x.dtype, block_o=block_o, layer=layer)
-    entry = _QGEMV_QTYPES[qtype]
-    run = entry.run if _rows(x.shape) <= _GEMV_MAX_ROWS else entry.gemm
-    return run(x, w, block_o, layer=layer)
+    return qmatmul(x, w, out_dtype=x.dtype, layer=layer)
 
 
-def _fused_fwd(x, w, layer, qtype, block_o):
-    return _fused_matmul(x, w, layer, qtype, block_o), (w, layer)
+def _fused_fwd(x, w, layer):
+    return _fused_matmul(x, w, layer), (w, layer)
 
 
-def _fused_bwd(qtype, block_o, res, g):
+def _fused_bwd(res, g):
     # dx = g @ dequant(W) through the fused Pallas kernel (or the XLA
     # remat oracle under fused_backward_scope(False)), which takes one
     # layer's weight; W itself is frozen, so its cotangent is a symbolic
     # zero
     w, layer = res
-    dx = _fused_dx(g, _layer_of(w, layer), qtype, block_o)
-    return dx, _zero_cotangent(w), None
+    return _fused_dx(g, _layer_of(w, layer)), _zero_cotangent(w), None
 
 
 _fused_matmul.defvjp(_fused_fwd, _fused_bwd)
@@ -556,21 +360,19 @@ def _lora_cat_operands(x: jax.Array, lora, compute_dtype):
     return a, b, gate
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _fused_lora_matmul(x: jax.Array, w: QTensor, a_cat, b_cat, gate,
-                       qtype: str, block_o: int):
+@jax.custom_vjp
+def _fused_lora_matmul(x: jax.Array, w: QTensor, a_cat, b_cat, gate):
     from bigdl_tpu.ops.pallas import qmatmul_lora
 
-    return qmatmul_lora(x, w, a_cat, b_cat, gate, out_dtype=x.dtype,
-                        block_o=block_o)
+    return qmatmul_lora(x, w, a_cat, b_cat, gate, out_dtype=x.dtype)
 
 
-def _fused_lora_fwd(x, w, a_cat, b_cat, gate, qtype, block_o):
-    y = _fused_lora_matmul(x, w, a_cat, b_cat, gate, qtype, block_o)
+def _fused_lora_fwd(x, w, a_cat, b_cat, gate):
+    y = _fused_lora_matmul(x, w, a_cat, b_cat, gate)
     return y, (x, w, a_cat, b_cat, gate)
 
 
-def _fused_lora_bwd(qtype, block_o, res, g):
+def _fused_lora_bwd(res, g):
     # the base-weight dx term routes through the fused kernel exactly
     # like _fused_bwd; the epilogue's product-rule terms stay on XLA
     # (rank-R operands are far below 128-lane tile economics). For
@@ -585,7 +387,7 @@ def _fused_lora_bwd(qtype, block_o, res, g):
     u = xf @ ac.T  # [M, R]
     dv = gf @ bc  # [M, R]
     du = dv * gtc
-    dxw = _fused_dx(gf, w, qtype, block_o).astype(cd)
+    dxw = _fused_dx(gf, w).astype(cd)
     dx = (dxw + du @ ac).reshape(x.shape).astype(x.dtype)
     da = (du.T @ xf).astype(a.dtype)
     db = (gf.T @ (u * gtc)).astype(b.dtype)
@@ -650,11 +452,10 @@ def linear(
     way because a per-layer slice given to a Mosaic call is copied whole
     first (`models/llama.forward` says which weights it does this for).
 
-    QTensor weights route to the fused Pallas dequant kernels whenever
-    the shape is eligible (GEMV below `_GEMV_MAX_ROWS` rows, tiled GEMM
-    above); otherwise the dequantization is expressed in-graph so XLA
-    fuses unpack+scale into the matmul's operand read. Weights stay
-    packed in HBM either way.
+    QTensor weights route to the fused Pallas dequant kernel whenever
+    `fused_why_not` has no objection; otherwise the dequantization is
+    expressed in-graph so XLA fuses unpack+scale into the matmul's
+    operand read. Weights stay packed in HBM either way.
 
     ``lora`` is an optional (a, b, scale) triple in either
     `lora_epilogue` shape. On the fused path it folds into the kernel's
@@ -669,27 +470,25 @@ def linear(
         assert not (stacked and lora is not None), (
             "an adapter's base weight comes sliced: the backward's dx "
             "kernel takes one layer")
-        kernel, why = _fused_route(x, w, stacked)
+        why = fused_why_not(w, lead=int(stacked))
+        shape_class = "gemv" if _rows(x.shape) <= _GEMV_MAX_ROWS else "gemm"
         routes.note(
-            "linear", f"pallas:{why}" if kernel is not None else "xla",
+            "linear", f"pallas:{shape_class}" if why is None else "xla",
             f"{w.qtype} M{_rows(x.shape)} K{w.shape[-1]} "
             f"O{w.data.shape[-2]} " + ("stack" if stacked else "slice")
-            + (_words_note(w) if kernel is not None and lora is None else "")
-            + (f" ({why})" if kernel is None else " scales:stack"
+            + (_words_note(w) if why is None and lora is None else "")
+            + (f" ({why})" if why is not None else " scales:stack"
                if lora is None and _reads_bits(w) else " scales:slice"))
-        if kernel is not None:
-            from bigdl_tpu.ops.pallas.tiling import WORD_BLOCK_O
-
-            block_o = WORD_BLOCK_O  # a cap: `tiling.pick_block_o` picks
+        if why is None:
             xc = x.astype(compute_dtype)
             if lora is not None:
                 ops = _lora_cat_operands(x, lora, compute_dtype)
                 if ops is not None:
-                    y = _fused_lora_matmul(xc, w, *ops, w.qtype, block_o)
+                    y = _fused_lora_matmul(xc, w, *ops)
                     if bias is not None:
                         y = y + bias.astype(compute_dtype)
                     return y
-            y = _fused_matmul(xc, w, layer, w.qtype, block_o)
+            y = _fused_matmul(xc, w, layer)
             if lora is not None:
                 y = y + lora_epilogue(x, *lora, compute_dtype)
             if bias is not None:

@@ -73,19 +73,10 @@ from bigdl_tpu.ops.pallas.paged_attention import (  # noqa: E402
 from bigdl_tpu.ops.pallas.qbackward import (  # noqa: E402
     dw_matmul, qmatmul_dx,
 )
-from bigdl_tpu.ops.pallas.qmatmul import (  # noqa: E402
-    qmatmul, qmatmul_asym_int4, qmatmul_bytes, qmatmul_codebook,
-    qmatmul_fp8, qmatmul_int4, qmatmul_int8, qmatmul_lora, qmatmul_planes,
-    qmatmul_q2k, qmatmul_q4k, qmatmul_q5k, qmatmul_q6k,
-)
+from bigdl_tpu.ops.pallas.qmatmul import qmatmul, qmatmul_lora  # noqa: E402
 
 __all__ = ["use_pallas", "why_not_pallas", "interpret_mode", "flash_attention",
            "flash_attention_trainable",
            "paged_block_attention",
            "paged_decode_attention", "paged_latent_decode_attention", "qmatmul",
-           "qmatmul_int4",
-           "qmatmul_codebook",
-           "qmatmul_int8", "qmatmul_asym_int4", "qmatmul_q4k",
-           "qmatmul_q6k", "qmatmul_bytes", "qmatmul_fp8",
-           "qmatmul_planes", "qmatmul_q2k", "qmatmul_q5k",
            "qmatmul_lora", "qmatmul_dx", "dw_matmul"]

@@ -26,10 +26,7 @@ closes the loop:
 
 Both kernels are driven by the same table-driven decoder
 (`qdecode.DecodeSpec` / `spec_for`) as the forward, so every registered
-format gets a fused backward with ZERO per-format kernel code — the
-registry in ops/linear.py asserts at import time that no qtype silently
-falls back to the XLA remat path (the `bwd_exempt` column is the only
-sanctioned exit).
+format gets a fused backward with ZERO per-format kernel code.
 
 Decode chunks accumulate into the [block_m, K] scratch through static
 lane slices; chunk boundaries come from `qdecode.walk`, which aligns

@@ -75,9 +75,6 @@ from bigdl_tpu.ops.pallas.tiling import (
     lora_operand_bytes, pick_block_m, pick_block_o, round_up, words_ok,
 )
 
-BLOCK = 32  # quant block (elements per scale) for sym_int4; nf4/fp4 use 64
-
-
 def _params_parallel():
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel"),
@@ -442,237 +439,3 @@ def qmatmul_lora(
     side = _side_arrays(spec, w.scales, w.mins, w.sub_scales, w.sub_mins)
     return _fused(x, data, spec, side, out_dtype, block_o, interpret,
                   lora=(a_cat, b_cat, gate))
-
-
-# ---------------------------------------------------------------------------
-# per-format wrappers (stable public API; all delegate to the unified
-# kernel with an explicit DecodeSpec)
-# ---------------------------------------------------------------------------
-
-def qmatmul_int4(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K // 2] packed uint8 (sym_int4, half-split)
-    scales: jax.Array,  # [O, K // 32] f16 (or bf16)
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """y[..., O] = x @ dequant(W)^T for a sym_int4 QTensor's fields."""
-    spec = DecodeSpec(planes=(4,), value=("offset", 8), block=BLOCK)
-    return _fused(x, data, spec, (_f16_bits(scales),), out_dtype, block_o,
-                  interpret, layer=layer)
-
-
-def qmatmul_codebook(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K // 2] packed uint8 (half-split nibbles)
-    scales: jax.Array,  # [O, K // block] f16
-    codebook,  # 16 static floats: value = codebook[code] * scale
-    block: int = 64,
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """Fused dequant matmul for LUT nibble formats (nf4 / fp4).
-
-    Same HBM story as qmatmul_int4 (weights cross as packed nibbles,
-    ~4x less traffic than bf16); the in-kernel decode is a 16-way
-    compare/select tree over the static codebook instead of (v - 8):
-    Mosaic has no vector gather of a table. What the selects cost on the
-    chip: not measured (the cells are sym_int4)."""
-    spec = DecodeSpec(
-        planes=(4,), value=("lut", tuple(float(c) for c in codebook)),
-        block=block,
-    )
-    return _fused(x, data, spec, (_f16_bits(scales),), out_dtype, block_o,
-                  interpret, layer=layer)
-
-
-def qmatmul_int8(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K] int8 (sym_int8 / imported q8_0)
-    scales: jax.Array,  # [O, K // 32] f16 (or bf16)
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """y[..., O] = x @ dequant(W)^T for a sym_int8 QTensor's fields:
-    weights cross HBM as int8 — half the traffic of bf16."""
-    return qmatmul_bytes(x, data, scales, None, "i8", BLOCK, out_dtype,
-                         block_o, interpret, layer)
-
-
-def qmatmul_asym_int4(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K // 2] packed uint8 (half-split)
-    scales: jax.Array,  # [O, K // 32] f16
-    mins: jax.Array,  # [O, K // 32] f16 (raw block minimum; w = q*d + m)
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """Fused dequant matmul for asym_int4: the per-block min adds one
-    rank-1-per-block term, folded into the bf16 weight expansion before
-    the dot (same HBM story as sym_int4 + 0.5 bit/weight for mins)."""
-    spec = DecodeSpec(planes=(4,), value=("offset", 0), block=BLOCK,
-                      mins=True)
-    return _fused(x, data, spec, (_f16_bits(scales), _f16_bits(mins)),
-                  out_dtype, block_o, interpret, layer=layer)
-
-
-def qmatmul_q4k(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K // 2] packed uint8 (half-split)
-    scales: jax.Array,  # [O, K // 256] f16 super-scale d
-    mins: jax.Array,  # [O, K // 256] f16 super-scale dmin
-    sub_scales: jax.Array,  # [O, K // 32] uint8 6-bit sc
-    sub_mins: jax.Array,  # [O, K // 32] uint8 6-bit mn
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """Fused dequant matmul for planar q4_k (quant/kq_planar.py):
-    w = (d*sc)*q - (dmin*mn). Weights cross HBM at 4.625 bits/weight —
-    the reference's recommended quality format (README ppl table) served
-    at sym_int4-class bandwidth instead of the 2.7x dequant fallback."""
-    spec = DecodeSpec(planes=(4,), value=("offset", 0), block=32,
-                      mins=True, super_block=256)
-    return _fused(
-        x, data, spec,
-        (_f16_bits(scales), _f16_bits(mins), sub_scales, sub_mins),
-        out_dtype, block_o, interpret, layer=layer)
-
-
-def qmatmul_q6k(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K] int8 centered codes
-    scales: jax.Array,  # [O, K // 256] f16 super-scale d
-    sub_scales: jax.Array,  # [O, K // 16] int8 sc
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """Fused matmul for planar q6_k: w = (d*sc)*q per 16-element
-    sub-block. Planar q3_k is structurally identical (int8 centered
-    codes, int8 sc per 16, f16 d per 256) and shares this wrapper."""
-    spec = DecodeSpec(planes=(), value=("offset", 0), block=16,
-                      super_block=256)
-    return _fused(x, data, spec, (_f16_bits(scales), sub_scales),
-                  out_dtype, block_o, interpret, layer=layer)
-
-
-_BYTE_VALUES = {"i8": ("offset", 0), "e4m3": ("e4m3",), "e5m2": ("e5m2",)}
-
-
-def qmatmul_bytes(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K] one code byte per element
-    scales: jax.Array,  # [O, K // block] f16
-    mins: jax.Array | None = None,  # [O, K // block] f16 (w = dec(q)*d + m)
-    decode: str = "i8",  # i8 | e4m3 | e5m2
-    block: int = BLOCK,
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """Fused dequant matmul for byte-per-element formats: sym_int8,
-    asym_int5 (decode="i8" + mins) and fp8_e4m3/fp8_e5m2 (pass data
-    bitcast to uint8; the 256-entry byte codebook is realized
-    arithmetically from the fp8 bit fields)."""
-    assert scales.shape[-1] * block == x.shape[-1], (scales.shape, block)
-    spec = DecodeSpec(planes=(), value=_BYTE_VALUES[decode], block=block,
-                      mins=mins is not None)
-    side = ((_f16_bits(scales), _f16_bits(mins)) if mins is not None
-            else (_f16_bits(scales),))
-    return _fused(x, data, spec, side, out_dtype, block_o, interpret,
-                  layer=layer)
-
-
-def qmatmul_fp8(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K] float8_e4m3fn / float8_e5m2
-    scales: jax.Array,  # [O, K // block] f16
-    block: int = 128,
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """Fused dequant matmul for fp8 weights: bytes cross HBM as stored
-    (half the traffic of the bf16 dequant fallback) and decode in-kernel
-    from the bit fields."""
-    decode = "e4m3" if data.dtype == jnp.float8_e4m3fn else "e5m2"
-    bits = jax.lax.bitcast_convert_type(data, jnp.uint8)
-    return qmatmul_bytes(x, bits, scales, None, decode, block, out_dtype,
-                         block_o, interpret, layer)
-
-
-def qmatmul_planes(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K*bits/8] concatenated packed planes
-    scales: jax.Array,  # [O, K // block] f16
-    planes: tuple,  # per-plane bit widths, low bits first
-    decode: tuple,  # ("offset", o) | ("lut", codebook) | ("e2m3",)
-    block: int,
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """Fused dequant matmul for packed multi-plane formats (fp6 at 6,
-    sym_int5 at 5, nf3 at 3 bits/weight of HBM traffic vs 16 for the
-    dequant fallback). `decode` is the qdecode value tag as-is."""
-    spec = DecodeSpec(planes=tuple(planes), value=tuple(decode), block=block)
-    return _fused(x, data, spec, (_f16_bits(scales),), out_dtype, block_o,
-                  interpret, layer=layer)
-
-
-def qmatmul_q2k(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, K // 4] quarter-split packed 2-bit codes
-    scales: jax.Array,  # [O, K // 256] f16 super-scale d
-    mins: jax.Array,  # [O, K // 256] f16 super-scale dmin
-    sub_scales: jax.Array,  # [O, K // 16] uint8 4-bit sc
-    sub_mins: jax.Array,  # [O, K // 16] uint8 4-bit mn
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """Fused matmul for planar q2_k: w = (d*sc)*q - (dmin*mn) per
-    16-element sub-block, 2.625 bits/weight of HBM traffic."""
-    spec = DecodeSpec(planes=(2,), value=("offset", 0), block=16,
-                      mins=True, super_block=256)
-    return _fused(
-        x, data, spec,
-        (_f16_bits(scales), _f16_bits(mins), sub_scales, sub_mins),
-        out_dtype, block_o, interpret, layer=layer)
-
-
-def qmatmul_q5k(
-    x: jax.Array,  # [..., K]
-    data: jax.Array,  # [O, 5K/8] half-split nibbles ++ 1-bit plane
-    scales: jax.Array,  # [O, K // 256] f16 super-scale d
-    mins: jax.Array,  # [O, K // 256] f16 super-scale dmin
-    sub_scales: jax.Array,  # [O, K // 32] uint8 6-bit sc
-    sub_mins: jax.Array,  # [O, K // 32] uint8 6-bit mn
-    out_dtype=jnp.bfloat16,
-    block_o: int = WORD_BLOCK_O,
-    interpret: bool | None = None,
-    layer=None,  # traced index: `data` is then a stack [L, O, *]
-) -> jax.Array:
-    """Fused matmul for planar q5_k: q4_k's two-level math with the 5th
-    code bit read from an extra packed plane (5.625 bits/weight)."""
-    spec = DecodeSpec(planes=(4, 1), value=("offset", 0), block=32,
-                      mins=True, super_block=256)
-    return _fused(
-        x, data, spec,
-        (_f16_bits(scales), _f16_bits(mins), sub_scales, sub_mins),
-        out_dtype, block_o, interpret, layer=layer)

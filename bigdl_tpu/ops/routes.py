@@ -9,7 +9,8 @@ the counter afterwards:
 
     with record_routes() as routes:
         model.generate(...)
-    # routes[("linear", "pallas:gemv", "sym_int4 M2 K4096 O6144")] == 1
+    # routes[("linear", "pallas:gemv", "sym_int4 M2 K4096 O6144 stack "
+    #         "words:inplace scales:stack")] == 1
 
 Outside a scope `note` costs one global read. The sink is process-wide
 on purpose: the serving engine traces on its own thread.

@@ -12,8 +12,8 @@ needed). One jitted train step covers forward, backward, and the optax
 update, sharded over the same (dp, sp, tp) mesh as inference.
 
 The frozen-base matmul runs fused in BOTH directions (ops/linear.py
-routes training shapes — rows > `_GEMV_MAX_ROWS` — to the Pallas kernel
-under a custom_vjp): the forward's y = x @ dq(W)^T and the backward's
+routes training shapes to the Pallas kernel like any other, under a
+custom_vjp): the forward's y = x @ dq(W)^T and the backward's
 dx = g @ dq(W) both dequantize base-weight tiles in VMEM
 (ops/pallas/qmatmul.py forward, ops/pallas/qbackward.py dx) instead of
 materializing a bf16 copy of W in HBM per step. The old XLA

@@ -905,7 +905,7 @@ def run_kernels(rehearse: bool) -> None:
 
     from bigdl_tpu.ops import pallas
     from bigdl_tpu.ops.attention import attention
-    from bigdl_tpu.ops.linear import _QGEMV_QTYPES, _fused_route, linear
+    from bigdl_tpu.ops.linear import _QGEMV_QTYPES, fused_why_not, linear
     from bigdl_tpu.quant.synth import synth_qtensor
 
     ks, ms, O = ((4096, 14336), (1, 8, 512), 4096)
@@ -943,8 +943,8 @@ def run_kernels(rehearse: bool) -> None:
                 x = jnp.asarray(rng.normal(size=(M, K)), jnp.bfloat16)
 
                 def fwd():
-                    kernel, why = _fused_route(x, w)
-                    if kernel is None:
+                    why = fused_why_not(w, lead=0)
+                    if why is not None:
                         raise RuntimeError(f"XLA route: {why}")
                     y = jax.jit(linear)(x, w)
                     with jax.default_matmul_precision("highest"):

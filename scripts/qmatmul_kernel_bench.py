@@ -482,14 +482,16 @@ def product_check():
     """The tree's kernel against XLA on the same device: y in float32 from
     the dequantizer's bf16 weights at HIGHEST precision. What may differ is
     float32 summation order: a few 1e-6 of |y|."""
+    from bigdl_tpu.quant.qtensor import QTensor
+
     qm = importlib.import_module("bigdl_tpu.ops.pallas.qmatmul")
     for M, K, O in ((1, 4096, 6144), (32, 14336, 4096), (256, 4096, 6144),
                     (32, 4096, 32000)):
         x, w, s = operands(M, K, O, pick_block_m(M, K), jax.random.key(M))
         x = x[:M]
         scales = jax.lax.bitcast_convert_type(s, jnp.float16)
-        y = qm.qmatmul_int4(x, w, scales, out_dtype=jnp.float32,
-                            layer=jnp.int32(1))
+        y = qm.qmatmul(x, QTensor(data=w, scales=scales, qtype="sym_int4"),
+                       out_dtype=jnp.float32, layer=jnp.int32(1))
         codes = jnp.concatenate([w[1] & 15, w[1] >> 4], axis=1
                                 ).astype(jnp.float32) - 8
         wd = (codes * jnp.repeat(scales.astype(jnp.float32), 32, axis=1)

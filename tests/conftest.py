@@ -27,11 +27,24 @@ if "host_platform_device_count" not in xla_flags:
 # docs/ci.md). Fresh compiles cost ~1 extra minute per full run; a
 # segfaulted suite costs everything.
 os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+# Sixteen test files import `transformers`, which imports TensorFlow unless
+# told not to: 46 s for the first test of a worker to do so, 12 s for every
+# later worker, 7 s with this (ISSUE 58). No test uses a TensorFlow model.
+os.environ.setdefault("USE_TF", "0")
 
 import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
 jax.config.update("jax_compilation_cache_dir", None)
+# XLA compiles with most of its optimization passes off: the suite's CPU
+# time is mostly compiling programs that then run once, on tiny shapes
+# (ISSUE 58: about 600 of tier-1's 6,100 test-seconds between two runs on
+# one machine; files that mostly INTERPRET kernels run a little slower). What it trades is the
+# quality of the CPU's code, which no test measures. The pinned Mosaic
+# modules are hashes of LOWERED text and do not see it; the compiles for a
+# described TPU would (every backend is handed the option), so
+# `test_tpu_lowering.py` turns it off for its module.
+jax.config.update("jax_disable_most_optimizations", True)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

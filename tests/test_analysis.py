@@ -859,8 +859,8 @@ def test_dsp001_missing_and_unknown_gemv_entries():
     # many non-dense qtypes; this table covers one and invents one
     fs = lint("""
         _QGEMV_QTYPES = {
-            "sym_int4": _entry(64, None),
-            "bogus_q9": _entry(64, None),
+            "sym_int4": 64,
+            "bogus_q9": 64,
         }
     """, "bigdl_tpu/ops/linear.py", "DSP001")
     missing = [f for f in fs if "has no _QGEMV_QTYPES entry" in f.message]
@@ -869,10 +869,13 @@ def test_dsp001_missing_and_unknown_gemv_entries():
     assert len(unknown) == 1 and "not registered" in unknown[0].message
 
 
-def test_dsp001_real_linear_table_is_complete():
+@pytest.mark.parametrize("rule", ["DSP001", "DSP003"])
+def test_dsp001_real_linear_table_is_complete(rule):
+    """Every registered format has its k_multiple (DSP001), and each is a
+    multiple of the format's block and superblock (DSP003)."""
     fs = [f for f in lc.lint_paths(
         [os.path.join(REPO, "bigdl_tpu/ops/linear.py")])
-        if f.rule == "DSP001"]
+        if f.rule == rule]
     assert fs == [], "\n".join(f.format() for f in fs)
 
 
@@ -890,14 +893,14 @@ def test_dsp003_k_multiple_must_respect_block_size():
     # sym_int4's block_size is 32; a k_multiple of 48 splits blocks
     fs = lint("""
         _QGEMV_QTYPES = {
-            "sym_int4": _entry(48, None),
+            "sym_int4": 48,
         }
     """, "bigdl_tpu/ops/linear.py", "DSP003")
     assert len(fs) == 1 and "48" in fs[0].message \
         and "block" in fs[0].message
     assert lint("""
         _QGEMV_QTYPES = {
-            "sym_int4": _entry(64, None),
+            "sym_int4": 64,
         }
     """, "bigdl_tpu/ops/linear.py", "DSP003") == []
 
@@ -915,61 +918,6 @@ def test_dsp003_spec_for_must_cover_every_storage_or_default():
                 return 1
             raise ValueError(spec.storage)
     """, "bigdl_tpu/ops/pallas/qdecode.py", "DSP003") == []
-
-
-def test_dsp001_backward_column_requires_kernel_or_exemption():
-    # the factory's bwd default is None: an entry that neither passes a
-    # kernel nor states bwd_exempt is the silent XLA-remat fallback
-    fs = lint("""
-        def _entry(k_multiple, run, bwd=None, bwd_exempt=None):
-            return _GemvEntry(k_multiple, run, gemm=run, bwd=bwd,
-                              bwd_exempt=bwd_exempt)
-
-        _QGEMV_QTYPES = {
-            "sym_int4": _entry(64, None),
-            "nf4": _entry(128, None, bwd_exempt="codebook gather only"),
-            "sym_int8": _entry(32, None, bwd=_run_dx),
-        }
-    """, "bigdl_tpu/ops/linear.py", "DSP001")
-    bwd = [f for f in fs if "neither a fused backward" in f.message]
-    assert len(bwd) == 1 and "sym_int4" in bwd[0].message
-
-
-def test_dsp001_backward_column_direct_gemventry_literal():
-    fs = lint("""
-        _QGEMV_QTYPES = {
-            "sym_int4": _GemvEntry(64, _run_sym_int4),
-        }
-    """, "bigdl_tpu/ops/linear.py", "DSP001")
-    bwd = [f for f in fs if "neither a fused backward" in f.message]
-    assert len(bwd) == 1  # NamedTuple default bwd=None, no exemption
-
-
-def test_dsp003_bwd_k_multiple_must_respect_block_and_forward():
-    # sym_int4's block_size is 32: bwd_k_multiple=48 splits quant blocks
-    # AND refines the forward alignment (48 % 64 != 0) — two findings
-    fs = lint("""
-        _QGEMV_QTYPES = {
-            "sym_int4": _GemvEntry(64, None, bwd=None,
-                                   bwd_exempt="x", bwd_k_multiple=48),
-        }
-    """, "bigdl_tpu/ops/linear.py", "DSP003")
-    assert any("block_size" in f.message for f in fs)
-    assert any("forward k_multiple" in f.message for f in fs)
-    # a coarsening multiple of both is fine
-    assert lint("""
-        _QGEMV_QTYPES = {
-            "sym_int4": _GemvEntry(64, None, bwd=None,
-                                   bwd_exempt="x", bwd_k_multiple=128),
-        }
-    """, "bigdl_tpu/ops/linear.py", "DSP003") == []
-
-
-def test_dsp003_real_linear_backward_geometry_clean():
-    fs = [f for f in lc.lint_paths(
-        [os.path.join(REPO, "bigdl_tpu/ops/linear.py")])
-        if f.rule == "DSP003"]
-    assert fs == [], "\n".join(f.format() for f in fs)
 
 
 def test_dsp006_inline_kv_astype_fires():
@@ -1015,8 +963,8 @@ def test_dsp006_real_attention_files_clean():
 
 
 def test_dsp004_restated_budget_literal_in_ops_fires():
-    # 5 MiB == VMEM_BUDGET // 2 (tiling.py) — the exact drift this PR
-    # fixed in linear._fused_kernel
+    # 5 MiB == VMEM_BUDGET // 2 (tiling.py): the drift this rule was
+    # written for, in `ops/linear`'s VMEM guard
     fs = lint("""
         CAP = 5 * 1024 * 1024
     """, "bigdl_tpu/ops/foo.py", "DSP004")

@@ -21,7 +21,7 @@ import pytest
 from bigdl_tpu.api import TpuModel, optimize_model
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import PRESETS
-from bigdl_tpu.serving.engine import InferenceEngine
+from engines import shared_engine
 
 CFG = PRESETS["tiny-llama"]
 
@@ -52,10 +52,10 @@ def test_chunked_prefill_token_and_logprob_parity(model, chunk):
     chunk; 512: >= any prompt (degenerates to monolithic). Ids must be
     identical and per-token logprobs must agree to float tolerance."""
     prompts = [list(range(1, 40)), list(range(60, 85)), [7, 8, 9]]
-    ref = _run(InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                               page_size=16), prompts, maxnt=10)
-    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                          page_size=16, prefill_chunk_tokens=chunk)
+    ref = _run(shared_engine(model, n_slots=2, max_len=128, paged=True,
+                             page_size=16), prompts, maxnt=10)
+    eng = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                        page_size=16, prefill_chunk_tokens=chunk)
     out = _run(eng, prompts, maxnt=10)
     for r, o in zip(ref, out):
         assert o.out_tokens == r.out_tokens
@@ -73,15 +73,15 @@ def test_chunked_prefill_composes_with_radix_hits(model):
     """A cached prefix shrinks the chunked remainder too: the second
     request hits the radix cache AND chunk-prefills only its tail,
     output byte-identical to dense."""
-    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                          page_size=8, prefill_chunk_tokens=8)
+    eng = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                        page_size=8, prefill_chunk_tokens=8)
     p1 = list(range(10, 34))  # 3 full pages
     p2 = list(range(10, 26)) + [90, 91, 92, 93, 94, 95, 96, 97]
     r1 = _run(eng, [p1], maxnt=6)[0]
     hits0 = eng.pages.prefix_hits
     r2 = _run(eng, [p2], maxnt=6)[0]
     assert eng.pages.prefix_hits == hits0 + 1
-    dense = InferenceEngine(model, n_slots=2, max_len=128)
+    dense = shared_engine(model, n_slots=2, max_len=128)
     d1, d2 = _run(dense, [p1, p2], maxnt=6)
     assert r1.out_tokens == d1.out_tokens
     assert r2.out_tokens == d2.out_tokens
@@ -91,8 +91,8 @@ def test_chunked_prefill_interleaves_decode(model):
     """A running request keeps emitting while another's prompt
     chunk-prefills: the running slot's token count advances during the
     prefilling stretch (the no-stall property, host-observable)."""
-    eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                          page_size=16, prefill_chunk_tokens=16)
+    eng = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                        page_size=16, prefill_chunk_tokens=16)
     a = eng.submit([1, 2, 3], max_new_tokens=40)
     eng.step()  # admit + first token
     got0 = len(a.out_tokens)
@@ -132,8 +132,8 @@ def _start_chunked(eng, prompt, **kw):
 
 @pytest.mark.core
 def test_cancel_between_chunks_frees_pages(model):
-    eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                          page_size=16, prefill_chunk_tokens=16)
+    eng = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                        page_size=16, prefill_chunk_tokens=16)
     free0 = eng.pages.pool.n_free
     req = _start_chunked(eng, list(range(1, 129)), max_new_tokens=4)
     eng.cancel(req)
@@ -149,9 +149,9 @@ def test_cancel_between_chunks_frees_pages(model):
 
 def test_deadline_between_chunks_times_out_cleanly(model):
     fake = [0.0]
-    eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                          page_size=16, prefill_chunk_tokens=16,
-                          clock=lambda: fake[0])
+    eng = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                        page_size=16, prefill_chunk_tokens=16,
+                        clock=lambda: fake[0])
     free0 = eng.pages.pool.n_free
     req = _start_chunked(eng, list(range(1, 129)), max_new_tokens=4,
                          deadline_s=5.0)
@@ -168,15 +168,15 @@ def test_preempt_request_between_chunks_is_noop(model):
     """engine.preempt() on a still-prefilling request has no decode
     state to park: the marker drops, prefill completes, output is
     unaffected."""
-    eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                          page_size=16, prefill_chunk_tokens=16)
+    eng = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                        page_size=16, prefill_chunk_tokens=16)
     prompt = list(range(1, 129))
     req = _start_chunked(eng, prompt, max_new_tokens=4)
     eng.preempt(req)
     eng.run_until_idle()
     assert req.done and not req.error and req.preemptions == 0
-    ref = _run(InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                               page_size=16), [prompt], maxnt=4)[0]
+    ref = _run(shared_engine(model, n_slots=2, max_len=256, paged=True,
+                             page_size=16), [prompt], maxnt=4)[0]
     assert req.out_tokens == ref.out_tokens
     assert eng.page_leaks() == 0
 
@@ -185,15 +185,15 @@ def test_journal_replay_after_death_mid_chunk(model, tmp_path):
     """Kill the engine between chunks: the journaled request has no
     tombstone, so a successor engine replays and completes it."""
     jpath = str(tmp_path / "journal.jsonl")
-    eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                          page_size=16, prefill_chunk_tokens=16,
-                          journal=jpath)
+    eng = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                        page_size=16, prefill_chunk_tokens=16,
+                        journal=jpath)
     prompt = list(range(1, 129))
     _start_chunked(eng, prompt, max_new_tokens=4)
     del eng  # process death: no tombstone, no cleanup
-    eng2 = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                           page_size=16, prefill_chunk_tokens=16,
-                           journal=jpath)
+    eng2 = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                         page_size=16, prefill_chunk_tokens=16,
+                         journal=jpath)
     assert len(eng2.recovered_requests) == 1
     rec = eng2.recovered_requests[0]
     assert rec.prompt == prompt
@@ -203,8 +203,8 @@ def test_journal_replay_after_death_mid_chunk(model, tmp_path):
 
 
 def test_fail_all_mid_chunk_releases_everything(model):
-    eng = InferenceEngine(model, n_slots=2, max_len=256, paged=True,
-                          page_size=16, prefill_chunk_tokens=16)
+    eng = shared_engine(model, n_slots=2, max_len=256, paged=True,
+                        page_size=16, prefill_chunk_tokens=16)
     free0 = eng.pages.pool.n_free
     req = _start_chunked(eng, list(range(1, 129)), max_new_tokens=4)
     eng.fail_all("injected crash")
@@ -220,9 +220,9 @@ def test_chunk_plan_yields_pages_to_decoding_slot(model):
     chunk plan holds most of the pool must NOT be length-truncated or
     self-preempt-failed: the plan yields (slot released, request back
     at the queue front) and both requests complete in full."""
-    eng = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                          page_size=8, n_pages=15,  # 14 allocatable
-                          prefill_chunk_tokens=8)
+    eng = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                        page_size=8, n_pages=15,  # 14 allocatable
+                        prefill_chunk_tokens=8)
     a = eng.submit([1, 2, 3, 4, 5], max_new_tokens=40)
     eng.step()  # A admitted (2 pages), decoding
     # B's 12-page / 12-chunk plan takes every remaining page; A hits
@@ -238,8 +238,8 @@ def test_chunk_plan_yields_pages_to_decoding_slot(model):
     # before restarting (1 for A + 12 for B's full second pass < total)
     assert eng.prefill_chunks >= 14, eng.prefill_chunks
     # output parity with an unpressured engine (same prompts)
-    eng2 = InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                           page_size=8)
+    eng2 = shared_engine(model, n_slots=2, max_len=128, paged=True,
+                         page_size=8)
     a2 = eng2.submit([1, 2, 3, 4, 5], max_new_tokens=40)
     eng2.step()
     b2 = eng2.submit(list(range(10, 106)), max_new_tokens=8)
@@ -252,9 +252,9 @@ def test_speculative_rejects_chunked_prefill(model):
     """The draft admission prefill is monolithic: the combo would
     silently break the one-chunk stall bound, so the ctor refuses."""
     with pytest.raises(NotImplementedError, match="draft admission"):
-        InferenceEngine(model, n_slots=2, max_len=128, paged=True,
-                        page_size=16, prefill_chunk_tokens=16,
-                        speculative=True, draft_params=model.params)
+        shared_engine(model, n_slots=2, max_len=128, paged=True,
+                      page_size=16, prefill_chunk_tokens=16,
+                      speculative=True, draft_params=model.params)
 
 
 # ---------------------------------------------------------------------------
@@ -268,8 +268,8 @@ def test_evict_then_readmit_leaves_zero_dead_nodes(model):
     re-registers it. After every round the tree must hold ONLY
     reachable nodes (the flat cache accumulated stale child keys whose
     pages were evicted and scanned them forever)."""
-    eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8, n_pages=7)  # 6 allocatable
+    eng = shared_engine(model, n_slots=1, max_len=64, paged=True,
+                        page_size=8, n_pages=7)  # 6 allocatable
     shared = list(range(10, 26))  # 2 full pages when tailed
     for round_i in range(4):
         # disjoint filler churns the pool and forces eviction of the
@@ -291,8 +291,8 @@ def test_eviction_composes_with_preemption(model):
     held by slots), allocation escalates to host-RAM preemption and the
     victim resumes bit-exactly — the radix cache must not break PR 6's
     swap path."""
-    eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                          page_size=8, n_pages=7)
+    eng = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                        page_size=8, n_pages=7)
     a = eng.submit(list(range(1, 17)), max_new_tokens=24)
     b = eng.submit(list(range(30, 46)), max_new_tokens=24)
     eng.run_until_idle()
@@ -301,8 +301,8 @@ def test_eviction_composes_with_preemption(model):
     assert eng.preemptions > 0  # the pool genuinely could not hold both
     assert eng.page_leaks() == 0
     # parity with an unpressured engine
-    eng2 = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                           page_size=8)
+    eng2 = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                         page_size=8)
     a2 = eng2.submit(list(range(1, 17)), max_new_tokens=24)
     b2 = eng2.submit(list(range(30, 46)), max_new_tokens=24)
     eng2.run_until_idle()

@@ -46,14 +46,32 @@ def _reference():
     return cells.load_module(ROOT, "reference", "glm4_moe_lite")
 
 
+_MADE = {}  # a tiny model's (config, tree), once a (config, qtype, seed)
+_JITTED = {}  # the reference, compiled once a (config, n_last)
+
+
 def _params(hf, qtype, seed=0):
-    cfg = ModelConfig.from_hf_config(hf)
-    params = deepseek.init_params(cfg, jax.random.PRNGKey(seed))
-    if "moe_layers" in params and "e_bias" in params["moe_layers"]:
-        params["moe_layers"]["e_bias"] = 0.01 * jax.random.normal(
-            jax.random.PRNGKey(seed + 5),
-            params["moe_layers"]["e_bias"].shape)
-    return cfg, optimize_model(params, cfg, qtype)
+    """The same objects to every test that asks alike (none changes a
+    tree): `shared_engine` knows a model by them."""
+    key = (json.dumps(hf, sort_keys=True), qtype, seed)
+    if key not in _MADE:
+        cfg = ModelConfig.from_hf_config(hf)
+        params = deepseek.init_params(cfg, jax.random.PRNGKey(seed))
+        if "moe_layers" in params and "e_bias" in params["moe_layers"]:
+            params["moe_layers"]["e_bias"] = 0.01 * jax.random.normal(
+                jax.random.PRNGKey(seed + 5),
+                params["moe_layers"]["e_bias"].shape)
+        _MADE[key] = cfg, optimize_model(params, cfg, qtype)
+    return _MADE[key]
+
+
+def _ref_logits(ref, hf, params, seq, n_last):
+    """The reference under `jax.jit`: run eagerly it compiles every scan of
+    its own again at every call."""
+    key = (json.dumps(hf, sort_keys=True), n_last)
+    if key not in _JITTED:
+        _JITTED[key] = jax.jit(lambda p, t: ref.logits(hf, p, t, n_last))
+    return _JITTED[key](params, jnp.asarray(seq, jnp.int32))
 
 
 def _tokens(n, seed, vocab=512):
@@ -121,7 +139,7 @@ def test_forward_matches_the_reference_whole_sequence(qtype):
     ref = _reference()
     cfg, params = _params(TINY, qtype)
     toks = _tokens(40, 1)
-    want = ref.logits(TINY, params, jnp.asarray(toks, jnp.int32), 40)
+    want = _ref_logits(ref, TINY, params, toks, 40)
     got, _, routing = deepseek.forward(
         cfg, params, jnp.asarray(toks[None], jnp.int32), None,
         compute_dtype=jnp.float32, moe_routing=True)
@@ -171,8 +189,8 @@ def test_moe_routing_ids_are_the_reference_routers_within_its_margin():
 # ---- prefill then decode through latent pages --------------------------------
 
 def _check(ref, hf, params, r, n_new, atol):
-    seq = jnp.asarray(r.prompt + r.out_tokens[:-1], jnp.int32)
-    want = jax.nn.log_softmax(ref.logits(hf, params, seq, n_new))
+    want = jax.nn.log_softmax(
+        _ref_logits(ref, hf, params, r.prompt + r.out_tokens[:-1], n_new))
     want = np.asarray(want)[np.arange(n_new), r.out_tokens]
     np.testing.assert_allclose(np.asarray(r.out_logprobs), want, rtol=0,
                                atol=atol)

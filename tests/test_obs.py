@@ -39,6 +39,7 @@ from bigdl_tpu.obs.tracing import (
 )
 from bigdl_tpu.serving import engine as engine_mod
 from bigdl_tpu.serving.engine import InferenceEngine
+from engines import shared_engine
 from bigdl_tpu.serving.faults import FaultInjector
 from bigdl_tpu.serving.metrics import Metrics, metric_drift
 
@@ -83,8 +84,8 @@ def test_trace_export_golden(model, tmp_path, monkeypatch):
     monkeypatch.setattr(engine_mod, "TRACE_DECODE_EVERY", 3)
     tr = TraceRecorder(enabled=True)
     log_path = str(tmp_path / "requests.jsonl")
-    eng = InferenceEngine(model, n_slots=2, max_len=128, tracer=tr,
-                          request_log=log_path)
+    eng = shared_engine(model, n_slots=2, max_len=128, tracer=tr,
+                        request_log=log_path)
     reqs = [eng.submit([3, 1, 4, 1, 5], max_new_tokens=8)
             for _ in range(3)]
     eng.run_until_idle()
@@ -170,8 +171,8 @@ def test_preempted_request_trace_and_metric_consistency(model, monkeypatch):
     monkeypatch.setattr(engine_mod, "TRACE_DECODE_EVERY", 4)
     tr = TraceRecorder(enabled=True)
     inj = FaultInjector(seed=0)
-    eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8, faults=inj, tracer=tr)
+    eng = shared_engine(model, n_slots=1, max_len=64, paged=True,
+                        page_size=8, faults=inj, tracer=tr)
     r = eng.submit([3, 1, 4, 1, 5], max_new_tokens=40)
     eng.step()  # admit; next page allocation is the decode extension
     # twice: the page a step AHEAD of the one in flight (dry there, the
@@ -237,8 +238,8 @@ def test_request_dying_while_parked_closes_preempted_span(model):
     with a dangling swap_out instant."""
     tr = TraceRecorder(enabled=True)
     inj = FaultInjector(seed=0)
-    eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8, faults=inj, tracer=tr)
+    eng = shared_engine(model, n_slots=1, max_len=64, paged=True,
+                        page_size=8, faults=inj, tracer=tr)
     r = eng.submit([3, 1, 4, 1, 5], max_new_tokens=20)
     eng.step()  # admit + first token
     eng.preempt(r)  # operator-initiated park
@@ -272,7 +273,7 @@ def test_ttft_itl_under_injected_slow_step(model):
     stall = 0.03
     inj = FaultInjector(seed=0)
     inj.arm("slow_step", times=-1, seconds=stall)
-    eng = InferenceEngine(model, n_slots=1, max_len=128, faults=inj)
+    eng = shared_engine(model, n_slots=1, max_len=128, faults=inj)
     r = eng.submit([2, 7, 1, 8], max_new_tokens=5)
     eng.run_until_idle()
     assert r.done and len(r.out_tokens) == 5
@@ -319,11 +320,12 @@ ENGINES = {
 }
 
 
-def _engine(model, kind, **kw):
+def _engine(model, kind, build=shared_engine, **kw):
+    """`build=InferenceEngine` where the test reads what the engine traced."""
     opts = dict(ENGINES[kind])
     if opts.get("speculative"):
         opts["draft_params"] = model.params
-    return InferenceEngine(model, n_slots=2, max_len=128, **opts, **kw)
+    return build(model, n_slots=2, max_len=128, **opts, **kw)
 
 
 def _serve(eng, n=3):
@@ -365,7 +367,7 @@ def test_phase_spans_partition_admission_and_step(model, kind):
     another without overlap, and `seq` ties a step's span to its phases.
     The parents keep what they had (bench/ reads them)."""
     tr = TraceRecorder(enabled=True)
-    eng = _engine(model, kind, tracer=tr)
+    eng = _engine(model, kind, build=InferenceEngine, tracer=tr)
     reqs = _serve(eng)
     eng.close()
     events = tr.events()
@@ -787,9 +789,9 @@ def test_metrics_render_drift_full_engine(model):
     """A paged + speculative engine renders EVERY registered family and
     nothing unregistered — a new metric can neither silently vanish
     from /metrics nor ship without being added to the registry."""
-    eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                          page_size=8, speculative=True,
-                          draft_params=model.params, draft_k=3)
+    eng = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                        page_size=8, speculative=True,
+                        draft_params=model.params, draft_k=3)
     eng.submit([1, 2, 3, 4, 5], max_new_tokens=4)
     eng.run_until_idle()
     text = Metrics(eng).render()
@@ -1015,8 +1017,8 @@ def test_engine_injectable_clock(model):
         return sim["t"]
 
     tr = TraceRecorder(enabled=True, clock=fake_clock)
-    eng = InferenceEngine(model, n_slots=1, max_len=128, tracer=tr,
-                          clock=fake_clock)
+    eng = shared_engine(model, n_slots=1, max_len=128, tracer=tr,
+                        clock=fake_clock)
     r = eng.submit([9, 9, 8, 2], max_new_tokens=3)
     eng.run_until_idle()
     assert r.done

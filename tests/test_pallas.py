@@ -16,7 +16,7 @@ import pytest
 
 from bigdl_tpu.ops.attention import attention
 from bigdl_tpu.ops.pallas.flash_attention import flash_attention
-from bigdl_tpu.ops.pallas.qmatmul import qmatmul_int4
+from bigdl_tpu.ops.pallas.qmatmul import qmatmul
 from bigdl_tpu.quant import QTensor, quantize
 
 
@@ -214,22 +214,55 @@ def test_flash_live_blocks_counts_the_steps_the_kernel_computes(
             T, S, bq, bk, q_offset=q_offset, window=window)
 
 
-@pytest.mark.parametrize("m", [1, 4])
-def test_qmatmul_int4_matches_dequant(rng, m):
-    K, O = 128, 256
-    x = jnp.asarray(rng.normal(size=(m, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
-    qt = quantize(w, "sym_int4")
-
-    y = qmatmul_int4(x, qt.data, qt.scales, block_o=128, interpret=True)
-    ref = jnp.einsum(
+def _gemv_oracle(x, qt):
+    return jnp.einsum(
         "mk,ok->mo", x.astype(jnp.bfloat16), qt.dequantize(jnp.bfloat16),
         preferred_element_type=jnp.bfloat16,
     )
+
+
+def _core(*case):
+    return pytest.param(*case, marks=pytest.mark.core)
+
+
+# (qtype, K, O, rows, a shift that gives the asymmetric formats a minimum to
+# carry, atol): every format's planes, value decode and scale levels at
+# GEMV rows. K = 768 is an odd count of super-blocks (a chunk starts mid
+# super-block: llama2's K = 11008 has 43); the k-quants of 768 and q3_k,
+# which `spec_for` decodes as q6_k (int8 centered codes, int8 sub-scales
+# per 16), come back as the format that was asked for.
+_FORMAT_CASES = [
+    ("sym_int4", 128, 256, 1, 0.0, 0.15), ("sym_int4", 128, 256, 4, 0.0, 0.15),
+    ("nf4", 256, 256, 2, 0.0, 0.15), ("fp4", 256, 256, 2, 0.0, 0.15),
+    ("sym_int8", 128, 256, 1, 0.0, 0.1), ("sym_int8", 128, 256, 4, 0.0, 0.1),
+    *[("q4_k", K, 128, m, 0.0, 0.15) for K in (256, 768) for m in (1, 4)],
+    *[("q6_k", K, 128, m, 0.0, 0.1) for K in (256, 768) for m in (1, 4)],
+    ("asym_int4", 128, 256, 1, 0.05, 0.15),
+    ("asym_int4", 128, 256, 4, 0.05, 0.15),
+    *[_core(q, 256, 128, m, 0.0, 0.1)
+      for q in ("fp8_e4m3", "fp8_e5m2") for m in (1, 4)],
+    _core("asym_int5", 128, 128, 2, 0.05, 0.15),
+    _core("sym_int5", 1024, 128, 1, 0.0, 0.15),
+    _core("fp6", 512, 128, 1, 0.0, 0.15),
+    _core("nf3", 1024, 128, 1, 0.0, 0.15),
+    *[_core(q, K, 128, 2, 0.0, 0.15)
+      for q, K in (("q2_k", 512), ("q2_k", 768), ("q5_k", 1024), ("q5_k", 768))],
+    _core("q3_k", 256, 128, 1, 0.0, 0.1),
+]
+
+
+@pytest.mark.parametrize("qtype,K,O,m,shift,atol", _FORMAT_CASES)
+def test_qmatmul_matches_dequant(rng, qtype, K, O, m, shift, atol):
+    """The fused matmul == dequantize-then-matmul, format by format (the
+    kernel's only rounding is the shared bf16 weight cast)."""
+    x = jnp.asarray(rng.normal(size=(m, K)), jnp.float32).astype(jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1 + shift, jnp.float32)
+    qt = quantize(w, qtype)
+    assert qt.qtype == qtype
+    y = qmatmul(x, qt, block_o=128, interpret=True)
     np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(ref, jnp.float32),
-        atol=0.15, rtol=0.05,
-    )
+        np.asarray(y, jnp.float32),
+        np.asarray(_gemv_oracle(x, qt), jnp.float32), atol=atol, rtol=0.05)
 
 
 def test_qmatmul_leading_dims(rng):
@@ -239,7 +272,7 @@ def test_qmatmul_leading_dims(rng):
     w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
     qt = quantize(w, "sym_int4")
 
-    y = qmatmul_int4(x, qt.data, qt.scales, block_o=128, interpret=True)
+    y = qmatmul(x, qt, block_o=128, interpret=True)
     assert y.shape == (2, 3, O)
     ref = jnp.einsum("btk,ok->bto", x.astype(jnp.float32), qt.dequantize(jnp.float32))
     np.testing.assert_allclose(np.asarray(y, jnp.float32), np.asarray(ref), atol=0.2)
@@ -257,7 +290,7 @@ def test_linear_dispatch_uses_kernel(rng, monkeypatch):
     x = jnp.asarray(rng.normal(size=(1, 1, K)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
     qt = quantize(w, "sym_int4")
-    assert linear_mod._use_qgemv(x, qt)
+    assert linear_mod.fused_why_not(qt, lead=0) is None
     y = linear_mod.linear(x, qt)
     dq = jnp.einsum("btk,ok->bto", x, qt.dequantize(jnp.float32))
     np.testing.assert_allclose(np.asarray(y, jnp.float32), np.asarray(dq), atol=0.2)
@@ -288,145 +321,33 @@ def test_flash_prefill_in_model(rng, monkeypatch):
     np.testing.assert_allclose(flash_logits, ref_logits, atol=5e-2)
 
 
-@pytest.mark.parametrize("qtype", ["nf4", "fp4"])
-def test_qmatmul_codebook_matches_dequant(rng, qtype):
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_codebook
-    from bigdl_tpu.quant.qtypes import resolve_qtype
-
-    K, O = 256, 256  # nf4/fp4 block 64 needs K % 128 == 0
-    x = jnp.asarray(rng.normal(size=(2, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
-    qt = quantize(w, qtype)
-    spec = resolve_qtype(qtype)
-
-    y = qmatmul_codebook(
-        x, qt.data, qt.scales, codebook=spec.codebook,
-        block=spec.block_size, block_o=128, interpret=True,
-    )
-    ref = jnp.einsum(
-        "mk,ok->mo", x.astype(jnp.bfloat16), qt.dequantize(jnp.bfloat16),
-        preferred_element_type=jnp.bfloat16,
-    )
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(ref, jnp.float32),
-        atol=0.15, rtol=0.05,
-    )
-
-
 def test_linear_dispatch_nf4_uses_codebook_kernel(rng, monkeypatch):
     """linear() routes decode-shaped nf4 matmuls to the codebook kernel."""
     monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
-    from bigdl_tpu.ops.linear import linear, _use_qgemv
+    from bigdl_tpu.ops.linear import fused_why_not, linear
 
     K, O = 128, 128
     x = jnp.asarray(rng.normal(size=(1, 1, K)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
     qt = quantize(w, "nf4")
-    assert _use_qgemv(x, qt)
+    assert fused_why_not(qt, lead=0) is None
     y = linear(x, qt, None, jnp.float32)
     ref = jnp.einsum("btk,ok->bto", x, qt.dequantize(jnp.float32))
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=0.05)
 
 
-@pytest.mark.parametrize("m", [1, 4])
-def test_qmatmul_int8_matches_dequant(rng, m):
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_int8
-
-    K, O = 128, 256
-    x = jnp.asarray(rng.normal(size=(m, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
-    qt = quantize(w, "sym_int8")
-    y = qmatmul_int8(x, qt.data, qt.scales, block_o=128, interpret=True)
-    ref = jnp.einsum(
-        "mk,ok->mo", x.astype(jnp.bfloat16), qt.dequantize(jnp.bfloat16),
-        preferred_element_type=jnp.bfloat16,
-    )
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(ref, jnp.float32),
-        atol=0.1, rtol=0.05,
-    )
-
-
 def test_linear_dispatch_int8_uses_kernel(rng, monkeypatch):
     monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
-    from bigdl_tpu.ops.linear import _use_qgemv, linear
+    from bigdl_tpu.ops.linear import fused_why_not, linear
 
     K, O = 64, 128
     x = jnp.asarray(rng.normal(size=(1, 1, K)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
     qt = quantize(w, "sym_int8")
-    assert _use_qgemv(x, qt)
+    assert fused_why_not(qt, lead=0) is None
     y = linear(x, qt, None, jnp.float32)
     ref = jnp.einsum("btk,ok->bto", x, qt.dequantize(jnp.float32))
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=0.05)
-
-
-@pytest.mark.parametrize("m", [1, 4])
-@pytest.mark.parametrize("K", [256, 768])  # 768 = odd super-block count
-def test_qmatmul_q4k_matches_dequant(rng, m, K):
-    """Fused two-level q4_k GEMV == dequant-then-matmul (the kernel's
-    only rounding is the shared bf16 weight cast). 768 exercises the
-    odd-super-block offset expansion (llama2's K=11008 -> 43 blocks)."""
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_q4k
-
-    O = 128
-    x = jnp.asarray(rng.normal(size=(m, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
-    qt = quantize(w, "q4_k")
-    assert qt.qtype == "q4_k"
-    y = qmatmul_q4k(x, qt.data, qt.scales, qt.mins, qt.sub_scales,
-                    qt.sub_mins, block_o=128, interpret=True)
-    ref = jnp.einsum(
-        "mk,ok->mo", x.astype(jnp.bfloat16), qt.dequantize(jnp.bfloat16),
-        preferred_element_type=jnp.bfloat16,
-    )
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(ref, jnp.float32),
-        atol=0.15, rtol=0.05,
-    )
-
-
-@pytest.mark.parametrize("m", [1, 4])
-@pytest.mark.parametrize("K", [256, 768])
-def test_qmatmul_q6k_matches_dequant(rng, m, K):
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_q6k
-
-    O = 128
-    x = jnp.asarray(rng.normal(size=(m, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
-    qt = quantize(w, "q6_k")
-    y = qmatmul_q6k(x, qt.data, qt.scales, qt.sub_scales, block_o=128,
-                    interpret=True)
-    ref = jnp.einsum(
-        "mk,ok->mo", x.astype(jnp.bfloat16), qt.dequantize(jnp.bfloat16),
-        preferred_element_type=jnp.bfloat16,
-    )
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(ref, jnp.float32),
-        atol=0.1, rtol=0.05,
-    )
-
-
-@pytest.mark.parametrize("m", [1, 4])
-def test_qmatmul_asym_int4_matches_dequant(rng, m):
-    """asym_int4's per-block min folds into the weight expansion; the
-    kernel must match w = q*d + m dequant (numerics' `+ m` convention)."""
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_asym_int4
-
-    K, O = 128, 256
-    x = jnp.asarray(rng.normal(size=(m, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1 + 0.05, jnp.float32)
-    qt = quantize(w, "asym_int4")
-    y = qmatmul_asym_int4(x, qt.data, qt.scales, qt.mins, block_o=128,
-                          interpret=True)
-    ref = jnp.einsum(
-        "mk,ok->mo", x.astype(jnp.bfloat16), qt.dequantize(jnp.bfloat16),
-        preferred_element_type=jnp.bfloat16,
-    )
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(ref, jnp.float32),
-        atol=0.15, rtol=0.05,
-    )
 
 
 @pytest.mark.parametrize("qtype", ["q4_k", "q6_k", "asym_int4"])
@@ -435,168 +356,95 @@ def test_linear_dispatch_kquant_uses_kernel(rng, monkeypatch, qtype):
     kernels (these formats used to take the XLA dequant fallback on
     the decode hot path; its cost on the chip: not measured)."""
     monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
-    from bigdl_tpu.ops.linear import _use_qgemv, linear
+    from bigdl_tpu.ops.linear import fused_why_not, linear
 
     K, O = 256, 128
     x = jnp.asarray(rng.normal(size=(1, 1, K)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
     qt = quantize(w, qtype)
     assert qt.qtype == qtype
-    assert _use_qgemv(x, qt)
+    assert fused_why_not(qt, lead=0) is None
     y = linear(x, qt, None, jnp.float32)
     ref = jnp.einsum("btk,ok->bto", x, qt.dequantize(jnp.float32))
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=0.05)
-    # prefill shapes stay on the XLA dequant path
-    xp = jnp.asarray(rng.normal(size=(1, 64, K)), jnp.float32)
-    assert not _use_qgemv(xp, qt)
-
-
-# ---------------------------------------------------------------------------
-# round 6: universal fused dequant-GEMV — every decodable qtype
-# ---------------------------------------------------------------------------
-
-def _gemv_oracle(x, qt):
-    return jnp.einsum(
-        "mk,ok->mo", x.astype(jnp.bfloat16), qt.dequantize(jnp.bfloat16),
-        preferred_element_type=jnp.bfloat16,
-    )
-
-
-@pytest.mark.core
-@pytest.mark.parametrize("m", [1, 4])
-@pytest.mark.parametrize("qtype", ["fp8_e4m3", "fp8_e5m2"])
-def test_qmatmul_fp8_matches_dequant(rng, m, qtype):
-    """fp8 byte-codebook GEMV: the in-kernel arithmetic bit decode must
-    match XLA's fp8->f32 cast for every encodable pattern (tight-tol:
-    the only rounding is the shared bf16 weight cast)."""
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_fp8
-
-    K, O = 256, 128
-    x = jnp.asarray(rng.normal(size=(m, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
-    qt = quantize(w, qtype)
-    y = qmatmul_fp8(x, qt.data, qt.scales, block_o=128, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(_gemv_oracle(x, qt), jnp.float32),
-        atol=0.1, rtol=0.05,
-    )
-
-
-@pytest.mark.core
-def test_qmatmul_bytes_asym_int5_matches_dequant(rng):
-    """asym_int5 through the byte-code kernel: w = q*d + m, the per-block
-    min folded in exactly like the asym_int4 nibble kernel."""
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_bytes
-
-    K, O = 128, 128
-    x = jnp.asarray(rng.normal(size=(2, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1 + 0.05, jnp.float32)
-    qt = quantize(w, "asym_int5")
-    y = qmatmul_bytes(x, qt.data, qt.scales, qt.mins, decode="i8",
-                      block=32, block_o=128, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(_gemv_oracle(x, qt), jnp.float32),
-        atol=0.15, rtol=0.05,
-    )
-
-
-@pytest.mark.core
-@pytest.mark.parametrize("qtype,K", [("sym_int5", 1024), ("fp6", 512),
-                                     ("nf3", 1024)])
-def test_qmatmul_planes_matches_dequant(rng, qtype, K):
-    """Packed multi-plane GEMV (4+1 / 4+2 / 2+1 bit planes): in-kernel
-    plane reassembly + decode vs the unpack_planes dequant oracle.
-    Exact for sym_int5 (integer decode); tight-tol for fp6 (arithmetic
-    e2m3 == FP6_CODEBOOK) and nf3 (8-entry LUT tree)."""
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_planes
-    from bigdl_tpu.quant.qtypes import resolve_qtype
-
-    O = 128
-    spec = resolve_qtype(qtype)
-    x = jnp.asarray(rng.normal(size=(1, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
-    qt = quantize(w, qtype)
-    if qtype == "fp6":
-        decode = ("e2m3",)
-    elif spec.codebook is not None:
-        decode = ("lut", tuple(float(c) for c in spec.codebook))
-    else:
-        decode = ("offset", 16)
-    y = qmatmul_planes(x, qt.data, qt.scales, spec.planes, decode,
-                       spec.block_size, block_o=128, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(_gemv_oracle(x, qt), jnp.float32),
-        atol=0.15, rtol=0.05,
-    )
-
-
-@pytest.mark.core
-@pytest.mark.parametrize("qtype,K", [("q2_k", 512), ("q2_k", 768),
-                                     ("q5_k", 1024), ("q5_k", 768)])
-def test_qmatmul_kq_planes_matches_dequant(rng, qtype, K):
-    """q2_k / q5_k two-level multi-plane GEMV vs the planar dequant
-    oracle. 768 = odd super-block count (mid-super chunk starts through
-    the offset one-hot expansion, like the q4_k test)."""
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_q2k, qmatmul_q5k
-
-    O = 128
-    x = jnp.asarray(rng.normal(size=(2, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
-    qt = quantize(w, qtype)
-    assert qt.qtype == qtype
-    fn = qmatmul_q2k if qtype == "q2_k" else qmatmul_q5k
-    y = fn(x, qt.data, qt.scales, qt.mins, qt.sub_scales, qt.sub_mins,
-           block_o=128, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(_gemv_oracle(x, qt), jnp.float32),
-        atol=0.15, rtol=0.05,
-    )
-
-
-@pytest.mark.core
-def test_qmatmul_q3k_shares_q6k_kernel(rng):
-    """Planar q3_k is structurally q6_k (int8 centered codes, int8
-    sub-scales per 16) and must run through the q6_k kernel unchanged."""
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_q6k
-
-    K, O = 256, 128
-    x = jnp.asarray(rng.normal(size=(1, K)), jnp.float32).astype(jnp.bfloat16)
-    w = jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32)
-    qt = quantize(w, "q3_k")
-    assert qt.qtype == "q3_k"
-    y = qmatmul_q6k(x, qt.data, qt.scales, qt.sub_scales, block_o=128,
-                    interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(y, jnp.float32), np.asarray(_gemv_oracle(x, qt), jnp.float32),
-        atol=0.1, rtol=0.05,
-    )
 
 
 @pytest.mark.core
 def test_gemv_dispatch_coverage(rng, monkeypatch):
     """EVERY qtype in the registry with a decode path must be registered
-    in _QGEMV_QTYPES and dispatch to a fused kernel at an eligible
-    decode shape — the acceptance gate against XLA-fallback cliffs
-    (their cost on the chip: not measured). Also checks the shared
-    shape guards."""
+    in _QGEMV_QTYPES and dispatch to the fused kernel at an eligible
+    shape — the acceptance gate against XLA-fallback cliffs
+    (their cost on the chip: not measured)."""
     monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
-    from bigdl_tpu.ops.linear import _GEMV_MAX_ROWS, _QGEMV_QTYPES, _use_qgemv
+    from bigdl_tpu.ops.linear import _QGEMV_QTYPES, fused_why_not
     from bigdl_tpu.quant import qtype_registry
 
     decodable = {n for n, s in qtype_registry().items() if not s.is_dense}
     assert decodable == set(_QGEMV_QTYPES), (
         "fused-GEMV registry out of sync with quant/qtypes.py"
     )
-    for name, entry in _QGEMV_QTYPES.items():
-        K = entry.k_multiple if entry.k_multiple >= 256 else 256
-        x = jnp.zeros((1, 1, K), jnp.float32)
+    for name, k_multiple in _QGEMV_QTYPES.items():
+        K = max(k_multiple, 256)
         w = jnp.asarray(rng.normal(size=(128, K)) * 0.1, jnp.float32)
         qt = quantize(w, name)
         assert qt.qtype == name, name
-        assert _use_qgemv(x, qt), f"{name}: eligible decode shape missed"
-        # prefill rows and odd-O shapes stay on the XLA dequant path
-        assert not _use_qgemv(
-            jnp.zeros((1, _GEMV_MAX_ROWS + 1, K), jnp.float32), qt), name
+        assert fused_why_not(qt, lead=0) is None, (
+            f"{name}: eligible shape missed")
+
+
+def _refused(monkeypatch, reason):
+    """A weight `fused_why_not` refuses for `reason` alone: every other
+    guard would take it."""
+    import importlib
+
+    linear_mod = importlib.import_module("bigdl_tpu.ops.linear")
+    zeros = lambda qtype, *shape: quantize(jnp.zeros(shape, jnp.float32), qtype)
+    if reason == "O not a multiple of 128 lanes":
+        return zeros("sym_int4", 120, 256)
+    if reason == "a 128-row weight tile exceeds half the VMEM budget":
+        return zeros("sym_int8", 128, 40960 + 32)  # 128 rows: over 5 MiB
+    if reason == "K not a multiple of 64":
+        return zeros("sym_int4", 128, 96)
+    if reason == "weight is rank 3, kernels take rank 2":
+        return zeros("sym_int4", 2, 128, 256)  # a stack, and no `layer`
+    if reason == "no fused kernel registered for this qtype":
+        monkeypatch.delitem(linear_mod._QGEMV_QTYPES, "nf4")
+        return zeros("nf4", 128, 256)
+    assert reason == "BIGDL_TPU_PALLAS=0"
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "0")
+    return zeros("sym_int4", 128, 256)
+
+
+@pytest.mark.core
+@pytest.mark.parametrize("reason", [
+    "O not a multiple of 128 lanes",
+    "a 128-row weight tile exceeds half the VMEM budget",
+    "K not a multiple of 64",
+    "weight is rank 3, kernels take rank 2",
+    "no fused kernel registered for this qtype",
+    "BIGDL_TPU_PALLAS=0",
+])
+def test_fused_why_not_names_the_guard_that_refuses(monkeypatch, reason):
+    """Each guard of the one predicate, by the words an operator reads in
+    a route note (`linear ... xla ... (<reason>)`, printed by `bench/run.py`
+    in set-up): the same weight with the guard's cause taken away is
+    taken, so the reason is the one that refused."""
+    from bigdl_tpu.ops.linear import fused_why_not, linear
+    from bigdl_tpu.ops.routes import record_routes
+
+    monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
+    assert fused_why_not(
+        quantize(jnp.zeros((128, 256), jnp.float32), "sym_int4"),
+        lead=0) is None
+    qt = _refused(monkeypatch, reason)
+    assert fused_why_not(qt, lead=0) == reason
+    if qt.data.ndim == 2:  # and `linear` computes it on the XLA route
+        x = jnp.zeros((4, qt.shape[-1]), jnp.bfloat16)
+        with record_routes() as routes:
+            jax.eval_shape(linear, x, qt)
+        ((op, route, detail),) = routes
+        assert (op, route) == ("linear", "xla")
+        assert detail.endswith(f" slice ({reason})"), detail
 
 
 @pytest.mark.core

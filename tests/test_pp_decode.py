@@ -16,6 +16,7 @@ import pytest
 from bigdl_tpu.api import TpuModel, optimize_model
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import ModelConfig
+from engines import shared_engine
 
 CFG = ModelConfig(
     vocab_size=128, hidden_size=64, intermediate_size=128,
@@ -25,25 +26,35 @@ CFG = ModelConfig(
 PROMPTS = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8]]
 
 
-def build(pp=1, tp=1):
-    params = optimize_model(
-        llama.init_params(CFG, jax.random.PRNGKey(0)), CFG, "sym_int4"
-    )
-    model = TpuModel(CFG, params, "sym_int4")
-    if pp > 1 or tp > 1:
+@pytest.fixture(scope="module")
+def build():
+    """`build(pp, tp)`: the model on that mesh, made once a module (no test
+    changes a model: one `TpuModel` keeps the programs its `generate`
+    compiled, and `shared_engine` those of its first engine)."""
+    made = {}
+
+    def build(pp=1, tp=1):
         if pp * tp > len(jax.devices()):
             pytest.skip(f"needs {pp * tp} devices")
-        model = model.to_mesh(pp=pp, tp=tp, dp=1)
-    return model
+        if (pp, tp) not in made:
+            model = TpuModel(CFG, optimize_model(
+                llama.init_params(CFG, jax.random.PRNGKey(0)), CFG,
+                "sym_int4"), "sym_int4")
+            if pp > 1 or tp > 1:
+                model = model.to_mesh(pp=pp, tp=tp, dp=1)
+            made[pp, tp] = model
+        return made[pp, tp]
+
+    return build
 
 
-def test_pp_generate_matches_single_device():
+def test_pp_generate_matches_single_device(build):
     ref = build().generate(PROMPTS, max_new_tokens=12)
     out = build(pp=4).generate(PROMPTS, max_new_tokens=12)
     np.testing.assert_array_equal(out, ref)
 
 
-def test_pp_plus_tp_generate_matches_single_device():
+def test_pp_plus_tp_generate_matches_single_device(build):
     ref = build().generate(PROMPTS, max_new_tokens=10)
     out = build(pp=2, tp=2).generate(PROMPTS, max_new_tokens=10)
     np.testing.assert_array_equal(out, ref)
@@ -57,14 +68,12 @@ def test_pp_layers_divisibility_error():
         model.to_mesh(pp=3, tp=1, dp=1)
 
 
-def test_engine_over_pp_tp_mesh():
+def test_engine_over_pp_tp_mesh(build):
     """Continuous-batching engine with the KV pool's layer axis over pp
     and kv heads over tp — greedy outputs must match the single-device
     engine token for token."""
-    from bigdl_tpu.serving.engine import InferenceEngine
-
     def run(model):
-        eng = InferenceEngine(model, n_slots=2, max_len=128)
+        eng = shared_engine(model, n_slots=2, max_len=128)
         reqs = [eng.submit(p, max_new_tokens=8) for p in PROMPTS]
         eng.run_until_idle()
         assert all(r.done for r in reqs)
@@ -75,13 +84,11 @@ def test_engine_over_pp_tp_mesh():
     assert out == ref
 
 
-def test_engine_pp_mid_flight_admission():
+def test_engine_pp_mid_flight_admission(build):
     """A request admitted while another decodes (slot insert into the
     pp-sharded pool) still completes correctly."""
-    from bigdl_tpu.serving.engine import InferenceEngine
-
     model = build(pp=2, tp=2)
-    eng = InferenceEngine(model, n_slots=2, max_len=128)
+    eng = shared_engine(model, n_slots=2, max_len=128)
     r1 = eng.submit(PROMPTS[0], max_new_tokens=12)
     for _ in range(4):
         eng.step()
@@ -90,7 +97,7 @@ def test_engine_pp_mid_flight_admission():
     assert r1.done and r2.done
     assert len(r1.out_tokens) > 0 and len(r2.out_tokens) > 0
     # same prompts through a fresh single-device engine agree (greedy)
-    ref_eng = InferenceEngine(build(), n_slots=2, max_len=128)
+    ref_eng = shared_engine(build(), n_slots=2, max_len=128)
     ref1 = ref_eng.submit(PROMPTS[0], max_new_tokens=12)
     ref2 = ref_eng.submit(PROMPTS[1], max_new_tokens=6)
     ref_eng.run_until_idle()
@@ -98,7 +105,7 @@ def test_engine_pp_mid_flight_admission():
     assert r2.out_tokens == ref2.out_tokens
 
 
-def test_pp_lookup_matches_single_device():
+def test_pp_lookup_matches_single_device(build):
     """VERDICT r04 missing/weak #6: prompt-lookup decoding runs through
     the pipeline step (forward_fn) — greedy output matches plain
     generate on a single device."""
@@ -111,7 +118,7 @@ def test_pp_lookup_matches_single_device():
     np.testing.assert_array_equal(got, np.asarray(want))
 
 
-def test_pp_snapkv_matches_single_device():
+def test_pp_snapkv_matches_single_device(build):
     """SnapKV compression under pp: the pipeline step now threads
     collect_obs (per-stage observation queries committed on the active
     tick), so compress_kv no longer downgrades to full-cache decode."""
@@ -125,19 +132,17 @@ def test_pp_snapkv_matches_single_device():
     np.testing.assert_array_equal(got, np.asarray(want))
 
 
-def test_engine_pp_speculative_matches_plain():
+def test_engine_pp_speculative_matches_plain(build):
     """In-engine speculative decoding over a (pp=2, tp=2) mesh: greedy
     output byte-identical to plain single-device serving."""
-    from bigdl_tpu.serving.engine import InferenceEngine
-
     plain = build()
-    ref_eng = InferenceEngine(plain, n_slots=2, max_len=64)
+    ref_eng = shared_engine(plain, n_slots=2, max_len=64)
     refs = [ref_eng.submit(p, max_new_tokens=8) for p in PROMPTS]
     ref_eng.run_until_idle()
 
     model = build(pp=2, tp=2)
-    eng = InferenceEngine(model, n_slots=2, max_len=64, speculative=True,
-                          draft_params=model.params, draft_k=3)
+    eng = shared_engine(model, n_slots=2, max_len=64, speculative=True,
+                        draft_params=model.params, draft_k=3)
     reqs = [eng.submit(p, max_new_tokens=8) for p in PROMPTS]
     eng.run_until_idle(max_steps=200)
     for r, ref in zip(reqs, refs):

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.ops.linear import (
-    _QGEMV_QTYPES, _use_qgemm, fused_backward_scope, linear,
+    _QGEMV_QTYPES, fused_backward_scope, fused_why_not, linear,
 )
 from bigdl_tpu.ops.pallas.qbackward import dw_matmul, qmatmul_dx
 from bigdl_tpu.quant import quantize
@@ -33,17 +33,12 @@ _O = 384  # ragged N: three 128-lane tiles, not a 256 multiple
 
 @pytest.mark.core
 def test_backward_dispatch_coverage():
-    """Every registered qtype declares a fused backward kernel or an
-    explicit bwd_exempt reason (the import-time assert enforces this;
-    graftlint DSP001 catches it on the diff), and a declared
-    bwd_k_multiple may only coarsen the forward alignment."""
+    """The dx kernel reads every registered qtype through the forward's
+    decoder, at the forward's K alignment: the parity matrix below walks
+    all of them, at a K each that the forward's guard takes."""
     assert set(_K_FOR) == set(_QGEMV_QTYPES), "K table out of sync"
-    for name, entry in _QGEMV_QTYPES.items():
-        assert entry.bwd is not None or entry.bwd_exempt, (
-            f"{name}: no fused backward kernel and no bwd_exempt reason"
-        )
-        km = entry.bwd_k_multiple or entry.k_multiple
-        assert km > 0 and km % entry.k_multiple == 0, (name, km)
+    for name, k_multiple in _QGEMV_QTYPES.items():
+        assert k_multiple > 0 and _K_FOR[name] % k_multiple == 0, name
 
 
 @pytest.mark.core
@@ -163,7 +158,7 @@ def test_lora_fused_forward_grad_through_fused_dx(rng, monkeypatch):
     b = jnp.asarray(rng.normal(size=(O, r)) * 0.1, jnp.float32)
     scale = jnp.asarray(2.0, jnp.float32)
     x = jnp.asarray(rng.normal(size=(1, 40, K)), jnp.float32)
-    assert _use_qgemm(x, qt)
+    assert fused_why_not(qt, lead=0) is None
     g = jnp.asarray(rng.normal(size=(1, 40, O)), jnp.float32)
 
     def loss(x, a, b):

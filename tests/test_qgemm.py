@@ -1,10 +1,11 @@
 """Tiled dequant-GEMM: dispatch coverage + parity matrix (ISSUE 9).
 
-The fused kernels run through the Pallas interpreter on CPU and are
-diffed against the XLA dequant reference, straddling `_GEMV_MAX_ROWS`
-(the old cliff: shapes above it fell back to materializing the
-dequantized weights in-graph; its cost on the chip: not measured). All
-core-marked: scripts/ci.sh --core runs them.
+The fused kernel runs through the Pallas interpreter on CPU and is
+diffed against the XLA dequant reference on either side of its own
+boundary, the row tile (`tiling.pick_block_m`), and of `_GEMV_MAX_ROWS`,
+where the route note's word changes (the old cliff: shapes above it fell
+back to materializing the dequantized weights in-graph; its cost on the
+chip: not measured). All core-marked: scripts/ci.sh --core runs them.
 """
 
 import jax
@@ -13,8 +14,10 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.ops.linear import (
-    _GEMV_MAX_ROWS, _QGEMV_QTYPES, _use_qgemm, _use_qgemv, linear,
+    _GEMV_MAX_ROWS, _QGEMV_QTYPES, fused_why_not, linear,
 )
+from bigdl_tpu.ops.pallas.tiling import pick_block_m
+from bigdl_tpu.ops.routes import record_routes
 from bigdl_tpu.quant import quantize
 
 # per-qtype contraction dims: the smallest k_multiple-eligible K that
@@ -28,58 +31,70 @@ _K_FOR = {
     "q2_k": 512, "q3_k": 768, "q4_k": 768, "q5_k": 1024, "q6_k": 768,
 }
 _O = 384  # ragged N: three 128-lane tiles, not a 256 multiple
+# rows within ONE row tile of every K here, and rows across two
+_M_TILE, _M_ACROSS = 128, 264
+
+
+def _route_of(x, qt) -> str:
+    """The route `linear` notes for this call (it is noted while tracing,
+    so nothing runs)."""
+    with record_routes() as routes:
+        jax.eval_shape(linear, x, qt)
+    ((_, route, _),) = routes
+    return route
 
 
 @pytest.mark.core
 def test_gemm_dispatch_coverage(monkeypatch):
-    """Every qtype in _QGEMV_QTYPES either has a registered fused GEMM
-    kernel or carries an explicit exemption reason — new formats cannot
-    silently regress prefill/batch/QLoRA shapes onto the XLA dequant
-    path. For registered formats, shapes straddling _GEMV_MAX_ROWS
-    route to the right kernel class."""
+    """Every qtype in _QGEMV_QTYPES takes the fused kernel whatever the
+    row count — new formats cannot silently regress prefill/batch/QLoRA
+    shapes onto the XLA dequant path — and the route note says `gemv` up
+    to _GEMV_MAX_ROWS rows and `gemm` above."""
     monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
     assert set(_K_FOR) == set(_QGEMV_QTYPES), "K table out of sync"
     rng = np.random.default_rng(0)
-    for name, entry in _QGEMV_QTYPES.items():
-        assert entry.gemm is not None or entry.gemm_exempt, (
-            f"{name}: no fused GEMM kernel and no gemm_exempt reason"
-        )
+    for name in _QGEMV_QTYPES:
         K = _K_FOR[name]
+        assert pick_block_m(_M_TILE, K) == _M_TILE
+        assert pick_block_m(_M_ACROSS, K) < _M_ACROSS
         w = jnp.asarray(rng.normal(size=(_O, K)) * 0.1, jnp.float32)
         qt = quantize(w, name)
         assert qt.qtype == name, name
+        assert fused_why_not(qt, lead=0) is None, name
         for m in (1, _GEMV_MAX_ROWS):
             x = jnp.zeros((1, m, K), jnp.float32)
-            assert _use_qgemv(x, qt) and not _use_qgemm(x, qt), (name, m)
-        for m in (_GEMV_MAX_ROWS + 1, 128):
+            assert _route_of(x, qt) == "pallas:gemv", (name, m)
+        for m in (_GEMV_MAX_ROWS + 1, _M_ACROSS):
             x = jnp.zeros((1, m, K), jnp.float32)
-            want = entry.gemm is not None
-            assert _use_qgemm(x, qt) == want, (name, m)
-            assert not _use_qgemv(x, qt), (name, m)
+            assert _route_of(x, qt) == "pallas:gemm", (name, m)
         # odd O (not a 128-lane multiple) stays on the XLA path
         x = jnp.zeros((1, 64, K), jnp.float32)
-        assert not _use_qgemm(x, quantize(w[:120], name)), name
+        assert _route_of(x, quantize(w[:120], name)) == "xla", name
 
 
 @pytest.mark.core
 @pytest.mark.parametrize("qtype", sorted(_QGEMV_QTYPES))
 def test_gemm_parity_matrix(rng, monkeypatch, qtype):
-    """GEMM vs GEMV vs XLA-dequant for every registered qtype at shapes
-    straddling _GEMV_MAX_ROWS (M = 1, 32, 33, 128). The fused outputs'
-    only rounding vs the oracle is the shared bf16 weight cast; rows of
-    a batched GEMM agree with the decode GEMV on the same activation
-    (no numeric cliff at the dispatch boundary)."""
+    """GEMM vs GEMV vs XLA-dequant for every registered qtype at one row,
+    at rows within one row tile and at rows across two (the kernel's own
+    boundary), and for sym_int4 on either side of _GEMV_MAX_ROWS too
+    (the route note's). The fused outputs' only rounding vs the oracle is
+    the shared bf16 weight cast; rows of a batched GEMM agree with the
+    decode GEMV on the same activation (no numeric cliff at either
+    boundary)."""
     monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
     K = _K_FOR[qtype]
     w = jnp.asarray(rng.normal(size=(_O, K)) * 0.1, jnp.float32)
     qt = quantize(w, qtype)
     assert qt.qtype == qtype
     wd = qt.dequantize(jnp.bfloat16)
-    x_all = jnp.asarray(rng.normal(size=(128, K)), jnp.float32
+    x_all = jnp.asarray(rng.normal(size=(_M_ACROSS, K)), jnp.float32
                         ).astype(jnp.bfloat16)
 
     y_gemv1 = None
-    for m in (1, 32, 33, 128):
+    label = ((_GEMV_MAX_ROWS, _GEMV_MAX_ROWS + 1) if qtype == "sym_int4"
+             else ())
+    for m in (1, *label, _M_TILE, _M_ACROSS):
         x = x_all[:m]
         y = linear(x, qt, None, jnp.bfloat16)
         ref = jnp.einsum("mk,ok->mo", x, wd,
@@ -107,7 +122,7 @@ def test_gemm_grad_matches_xla_path(rng, monkeypatch):
     x = jnp.asarray(rng.normal(size=(2, 33, K)), jnp.float32)
     qt = quantize(jnp.asarray(rng.normal(size=(O, K)) * 0.1, jnp.float32),
                   "sym_int4")
-    assert _use_qgemm(x, qt)
+    assert _route_of(x, qt) == "pallas:gemm"
     g = jnp.asarray(rng.normal(size=(2, 33, O)), jnp.float32)
 
     def loss(x):
@@ -216,7 +231,7 @@ def test_qlora_train_step_fused_matches_xla(monkeypatch):
     monkeypatch.setenv("BIGDL_TPU_PALLAS", "interpret")
     w_up = params["layers"]["w_up"].map_arrays(lambda a: a[0])  # layer 0
     probe = jnp.zeros((1, 40, cfg.hidden_size), jnp.float32)
-    assert _use_qgemm(probe, w_up)
+    assert _route_of(probe, w_up) == "pallas:gemm"
 
     _, _, loss_fused = step(params, lora, opt_state, tokens, mask)
     l_fused, _, _ = step(params, lora, opt_state, tokens, mask)

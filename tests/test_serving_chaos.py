@@ -21,10 +21,10 @@ import pytest
 from bigdl_tpu.api import TpuModel, optimize_model
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import PRESETS
-from bigdl_tpu.serving.engine import InferenceEngine
 from bigdl_tpu.serving.faults import (
     NULL_INJECTOR, FaultError, FaultInjector,
 )
+from engines import shared_engine
 
 CFG = PRESETS["tiny-llama"]
 
@@ -73,7 +73,7 @@ def test_injector_deterministic_counting():
 def test_nan_logits_quarantines_only_the_poisoned_slot(model):
     want = model.generate([[2, 7, 1, 8]], max_new_tokens=10)[0].tolist()
     inj = FaultInjector(seed=0)
-    eng = InferenceEngine(model, n_slots=2, max_len=64, faults=inj)
+    eng = shared_engine(model, n_slots=2, max_len=64, faults=inj)
     ra = eng.submit([3, 1, 4], max_new_tokens=10)
     rb = eng.submit([2, 7, 1, 8], max_new_tokens=10)
     eng.step()
@@ -97,9 +97,9 @@ def test_nan_logits_quarantines_speculative_slot(model):
     the poisoned row is quarantined, the clean row decodes bit-exactly."""
     want = model.generate([[2, 7, 1, 8]], max_new_tokens=10)[0].tolist()
     inj = FaultInjector(seed=0)
-    eng = InferenceEngine(model, n_slots=2, max_len=64, speculative=True,
-                          draft_params=model.params, draft_k=4,
-                          faults=inj)
+    eng = shared_engine(model, n_slots=2, max_len=64, speculative=True,
+                        draft_params=model.params, draft_k=4,
+                        faults=inj)
     ra = eng.submit([3, 1, 4], max_new_tokens=10)
     rb = eng.submit([2, 7, 1, 8], max_new_tokens=10)
     eng.step()
@@ -115,8 +115,8 @@ def test_nan_logits_quarantines_speculative_slot(model):
 @pytest.mark.chaos
 def test_nan_logits_paged_releases_pages(model):
     inj = FaultInjector(seed=0)
-    eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                          page_size=8, faults=inj)
+    eng = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                        page_size=8, faults=inj)
     free0 = eng.pages.pool.n_free
     r = eng.submit([3, 1, 4, 1, 5], max_new_tokens=20)
     eng.step()
@@ -213,7 +213,7 @@ def test_stream_stall_cancels_request(model):
     """A stalled stream consumer's timeout cancels the request in the
     engine rather than letting it decode to nowhere forever."""
     inj = FaultInjector(seed=0)
-    eng = InferenceEngine(model, n_slots=1, max_len=128, faults=inj)
+    eng = shared_engine(model, n_slots=1, max_len=128, faults=inj)
     # engine-level equivalent of _stream_iter's cancel-on-stall
     q: _q.SimpleQueue = _q.SimpleQueue()
     r = eng.submit([3, 1, 4], max_new_tokens=100, stream=q)
@@ -233,8 +233,8 @@ def test_stream_stall_cancels_request(model):
 def test_crash_before_done_is_replayed(model, tmp_path):
     jpath = str(tmp_path / "journal.jsonl")
     inj = FaultInjector(seed=0).arm("crash_before_done", times=1)
-    eng = InferenceEngine(model, n_slots=1, max_len=64, journal=jpath,
-                          faults=inj)
+    eng = shared_engine(model, n_slots=1, max_len=64, journal=jpath,
+                        faults=inj)
     r = eng.submit([3, 1, 4], max_new_tokens=5)
     crashed = False
     for _ in range(100):
@@ -246,14 +246,14 @@ def test_crash_before_done_is_replayed(model, tmp_path):
             break
     assert crashed and r.done  # completed, but tombstone never written
     # successor process: the request replays (at-least-once, never lost)
-    eng2 = InferenceEngine(model, n_slots=1, max_len=64, journal=jpath)
+    eng2 = shared_engine(model, n_slots=1, max_len=64, journal=jpath)
     assert len(eng2.recovered_requests) == 1
     assert eng2.recovered_requests[0].prompt == [3, 1, 4]
     eng2.run_until_idle()
     rec = eng2.recovered_requests[0]
     assert rec.done and not rec.error and len(rec.out_tokens) == 5
     # fully tombstoned now: a third engine replays nothing
-    eng3 = InferenceEngine(model, n_slots=1, max_len=64, journal=jpath)
+    eng3 = shared_engine(model, n_slots=1, max_len=64, journal=jpath)
     assert eng3.recovered_requests == []
 
 
@@ -266,8 +266,8 @@ def test_crash_cleanup_survives_multi_charge_arm(model, tmp_path):
     kill the thread and hang every client."""
     jpath = str(tmp_path / "journal.jsonl")
     inj = FaultInjector(seed=0).arm("crash_before_done", times=2)
-    eng = InferenceEngine(model, n_slots=1, max_len=64, journal=jpath,
-                          faults=inj)
+    eng = shared_engine(model, n_slots=1, max_len=64, journal=jpath,
+                        faults=inj)
     r = eng.submit([3, 1, 4], max_new_tokens=5)
     with pytest.raises(FaultError):
         eng.run_until_idle()
@@ -283,7 +283,7 @@ def test_crash_cleanup_survives_multi_charge_arm(model, tmp_path):
     assert r2.done and not r2.error and len(r2.out_tokens) == 4
     # the at-least-once window survived the live-server cleanup path: a
     # successor engine still replays the un-tombstoned request
-    eng2 = InferenceEngine(model, n_slots=1, max_len=64, journal=jpath)
+    eng2 = shared_engine(model, n_slots=1, max_len=64, journal=jpath)
     assert [e.prompt for e in eng2.recovered_requests] == [[3, 1, 4]]
 
 
@@ -294,12 +294,12 @@ def test_journal_replay_bypasses_admission_bound(model, tmp_path):
     replay would erase its only journal record (replay tombstones the
     old rid as soon as the replacement submit lands) — permanent loss."""
     jpath = str(tmp_path / "backlog.jsonl")
-    eng = InferenceEngine(model, n_slots=1, max_len=64, journal=jpath)
+    eng = shared_engine(model, n_slots=1, max_len=64, journal=jpath)
     reqs = [eng.submit([2 + i, 7], max_new_tokens=3, deadline_s=120.0)
             for i in range(5)]
     # crash before any step: all 5 remain journaled, none tombstoned
-    eng2 = InferenceEngine(model, n_slots=1, max_len=64, journal=jpath,
-                           max_queue=2)
+    eng2 = shared_engine(model, n_slots=1, max_len=64, journal=jpath,
+                         max_queue=2)
     assert len(eng2.recovered_requests) == 5
     # per-request deadlines survive the crash (fresh clock from replay)
     assert all(r.deadline_s == 120.0 for r in eng2.recovered_requests)
@@ -358,9 +358,9 @@ def test_chaos_sweep_survives_every_fault_class(model, tmp_path):
     at most the faulted request fails, the engine never hangs, and the
     free-page count returns to its initial value."""
     inj = FaultInjector(seed=3)
-    eng = InferenceEngine(model, n_slots=2, max_len=64, paged=True,
-                          page_size=8, n_pages=10, faults=inj,
-                          journal=str(tmp_path / "sweep.jsonl"))
+    eng = shared_engine(model, n_slots=2, max_len=64, paged=True,
+                        page_size=8, n_pages=10, faults=inj,
+                        journal=str(tmp_path / "sweep.jsonl"))
     free0 = eng.pages.pool.n_free
     reqs = [eng.submit([2 + i, 7, 9, 11], max_new_tokens=30)
             for i in range(4)]
@@ -399,7 +399,7 @@ def test_graceful_drain_finishes_inflight_sheds_new_compacts_journal(
     work runs to completion; close() then flushes + compacts the
     journal so a clean shutdown leaves NOTHING to replay."""
     jpath = str(tmp_path / "drain.jsonl")
-    eng = InferenceEngine(model, n_slots=2, max_len=64, journal=jpath)
+    eng = shared_engine(model, n_slots=2, max_len=64, journal=jpath)
     inflight = [eng.submit([2 + i, 7], max_new_tokens=5)
                 for i in range(3)]
     eng.step()  # some admitted, one still queued
@@ -415,7 +415,7 @@ def test_graceful_drain_finishes_inflight_sheds_new_compacts_journal(
     from bigdl_tpu.serving.journal import RequestJournal
 
     assert RequestJournal.pending(jpath) == []
-    eng2 = InferenceEngine(model, n_slots=2, max_len=64, journal=jpath)
+    eng2 = shared_engine(model, n_slots=2, max_len=64, journal=jpath)
     assert eng2.recovered_requests == []
 
 
@@ -450,11 +450,11 @@ def test_drain_timeout_leaves_unfinished_tail_for_replay(model, tmp_path):
     and replay at the next start (the crash path as fallback)."""
     jpath = str(tmp_path / "stuck.jsonl")
     inj = FaultInjector(seed=0).arm("slow_step", times=-1, seconds=0.2)
-    eng = InferenceEngine(model, n_slots=1, max_len=64, journal=jpath,
-                          faults=inj)
+    eng = shared_engine(model, n_slots=1, max_len=64, journal=jpath,
+                        faults=inj)
     req = eng.submit([3, 1, 4], max_new_tokens=50)
     assert eng.drain(timeout_s=0.3) is False
     assert not req.done  # not cut short, just not finished
     eng.close()
-    eng2 = InferenceEngine(model, n_slots=1, max_len=64, journal=jpath)
+    eng2 = shared_engine(model, n_slots=1, max_len=64, journal=jpath)
     assert [e.prompt for e in eng2.recovered_requests] == [[3, 1, 4]]

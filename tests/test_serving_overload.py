@@ -20,8 +20,8 @@ import pytest
 from bigdl_tpu.api import TpuModel, optimize_model
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import PRESETS
-from bigdl_tpu.serving.engine import InferenceEngine
 from bigdl_tpu.serving.faults import FaultInjector
+from engines import shared_engine
 
 CFG = PRESETS["tiny-llama"]
 
@@ -55,8 +55,8 @@ def test_preemption_parity_paged_under_injected_exhaustion(model):
     prompt = [3, 1, 4, 1, 5]
     want = model.generate([prompt], max_new_tokens=40)[0].tolist()
     inj = FaultInjector(seed=0)
-    eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8, faults=inj)
+    eng = shared_engine(model, n_slots=1, max_len=64, paged=True,
+                        page_size=8, faults=inj)
     r = eng.submit(prompt, max_new_tokens=40)
     eng.step()  # admit; the next page allocation is the decode extension
     # twice: dry for the page a step AHEAD of the one in flight (the engine
@@ -79,7 +79,7 @@ def test_preemption_parity_dense_via_preempt_api(model):
     want = model.generate([prompt], max_new_tokens=20)[0].tolist()
     # max_len 128 > the 64-slot swap bucket: the blob really is a SLICE
     # of the row (the idle tail stays behind), not a full-row copy
-    eng = InferenceEngine(model, n_slots=1, max_len=128)
+    eng = shared_engine(model, n_slots=1, max_len=128)
     r = eng.submit(prompt, max_new_tokens=20)
     for _ in range(4):
         eng.step()
@@ -94,8 +94,8 @@ def test_preemption_parity_dense_via_preempt_api(model):
 def test_preemption_parity_paged_via_preempt_api(model):
     prompt = [9, 9, 8, 2, 4]
     want = model.generate([prompt], max_new_tokens=16)[0].tolist()
-    eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8)
+    eng = shared_engine(model, n_slots=1, max_len=64, paged=True,
+                        page_size=8)
     r = eng.submit(prompt, max_new_tokens=16)
     for _ in range(3):
         eng.step()
@@ -110,10 +110,10 @@ def test_preemption_preserves_repetition_penalty_state(model):
     """The seen-token mask rides the swap blob: a penalized request
     resumed after preemption matches its uninterrupted run."""
     prompt = [3, 1, 4, 1, 5]
-    ref_eng = InferenceEngine(model, n_slots=1, max_len=64)
+    ref_eng = shared_engine(model, n_slots=1, max_len=64)
     ref = ref_eng.submit(prompt, max_new_tokens=16, repetition_penalty=1.5)
     ref_eng.run_until_idle()
-    eng = InferenceEngine(model, n_slots=1, max_len=64)
+    eng = shared_engine(model, n_slots=1, max_len=64)
     r = eng.submit(prompt, max_new_tokens=16, repetition_penalty=1.5)
     for _ in range(5):
         eng.step()
@@ -138,8 +138,8 @@ def test_pool_exhaustion_storm_no_early_length(model):
     want = {tuple(p): model.generate([p], max_new_tokens=maxnt)[0].tolist()
             for p in prompts}
     # 3 slots x (up to 6 pages each at the end) >> 9 allocatable pages
-    eng = InferenceEngine(model, n_slots=3, max_len=64, paged=True,
-                          page_size=8, n_pages=10)
+    eng = shared_engine(model, n_slots=3, max_len=64, paged=True,
+                        page_size=8, n_pages=10)
     reqs = [eng.submit(p, max_new_tokens=maxnt) for p in prompts]
     eng.run_until_idle(max_steps=5000)
     for p, r in zip(prompts, reqs):
@@ -160,8 +160,8 @@ def test_pool_exhaustion_storm_large(model):
     excluded from the tier-1 budget via the slow marker."""
     prompts = [[i + 2, 5, 6, 7, 8] for i in range(8)]
     maxnt = 40
-    eng = InferenceEngine(model, n_slots=3, max_len=64, paged=True,
-                          page_size=8, n_pages=10)
+    eng = shared_engine(model, n_slots=3, max_len=64, paged=True,
+                        page_size=8, n_pages=10)
     reqs = [eng.submit(p, max_new_tokens=maxnt) for p in prompts]
     eng.run_until_idle(max_steps=20000)
     for r in reqs:
@@ -175,8 +175,8 @@ def test_preemption_disabled_restores_length_finish(model):
     """preemption=False keeps the old overload behavior (finish "length"
     on pool exhaustion) for operators who prefer truncation to swapping."""
     inj = FaultInjector(seed=0)
-    eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8, faults=inj, preemption=False)
+    eng = shared_engine(model, n_slots=1, max_len=64, paged=True,
+                        page_size=8, faults=inj, preemption=False)
     r = eng.submit([3, 1, 4, 1, 5], max_new_tokens=40)
     eng.step()
     # twice: dry for the page a step AHEAD of the one in flight (the engine
@@ -195,7 +195,7 @@ def test_preemption_disabled_restores_length_finish(model):
 @pytest.mark.core
 @pytest.mark.chaos
 def test_queue_bound_sheds_fast(model):
-    eng = InferenceEngine(model, n_slots=1, max_len=64, max_queue=1)
+    eng = shared_engine(model, n_slots=1, max_len=64, max_queue=1)
     a = eng.submit([3, 1, 4], max_new_tokens=30)
     eng.step()  # a occupies the slot
     b = eng.submit([2, 7], max_new_tokens=4)  # queued: 1 == bound
@@ -210,7 +210,7 @@ def test_queue_bound_sheds_fast(model):
 
 @pytest.mark.chaos
 def test_queue_deadline_sheds_instead_of_serving_late(model):
-    eng = InferenceEngine(model, n_slots=1, max_len=64)
+    eng = shared_engine(model, n_slots=1, max_len=64)
     a = eng.submit([3, 1, 4], max_new_tokens=20)
     b = eng.submit([2, 7], max_new_tokens=4, queue_deadline_s=0.0)
     eng.run_until_idle()
@@ -228,7 +228,7 @@ def test_queue_deadline_sheds_while_saturated(model):
     """Expired queued requests are shed by the per-step sweep even when
     no slot frees: a saturated engine must not 429 new clients over a
     queue of already-dead work."""
-    eng = InferenceEngine(model, n_slots=1, max_len=64, max_queue=1)
+    eng = shared_engine(model, n_slots=1, max_len=64, max_queue=1)
     a = eng.submit([3, 1, 4], max_new_tokens=30)
     eng.step()  # a occupies the only slot for many steps
     b = eng.submit([2, 7], max_new_tokens=4, queue_deadline_s=0.01)
@@ -249,7 +249,7 @@ def test_queued_cancel_frees_queue_capacity(model):
     """A cancelled request is dropped from the queue by the per-step
     sweep even when no slot frees — it must stop counting against
     max_queue the moment the engine notices, not when a slot opens."""
-    eng = InferenceEngine(model, n_slots=1, max_len=64, max_queue=1)
+    eng = shared_engine(model, n_slots=1, max_len=64, max_queue=1)
     a = eng.submit([3, 1, 4], max_new_tokens=30)
     eng.step()  # a occupies the only slot
     b = eng.submit([2, 7], max_new_tokens=4)  # queued: at the bound
@@ -271,8 +271,8 @@ def test_cancel_reaches_parked_request(model):
     lingering behind other parked work until its resume turn."""
     import queue as _q
 
-    eng = InferenceEngine(model, n_slots=1, max_len=64, paged=True,
-                          page_size=8)
+    eng = shared_engine(model, n_slots=1, max_len=64, paged=True,
+                        page_size=8)
     q: _q.SimpleQueue = _q.SimpleQueue()
     r = eng.submit([3, 1, 4], max_new_tokens=30, stream=q)
     for _ in range(3):
@@ -292,7 +292,7 @@ def test_cancel_reaches_parked_request(model):
 def test_shed_stream_gets_sentinel(model):
     import queue as _q
 
-    eng = InferenceEngine(model, n_slots=1, max_len=64, max_queue=1)
+    eng = shared_engine(model, n_slots=1, max_len=64, max_queue=1)
     eng.submit([3, 1, 4], max_new_tokens=30)
     eng.step()
     eng.submit([2, 7], max_new_tokens=4)
@@ -302,9 +302,15 @@ def test_shed_stream_gets_sentinel(model):
     assert q.get_nowait() is None  # client unblocks immediately
 
 
+def _slow_steps():
+    """Every step stalled 20 ms: 100 tokens then take two seconds whatever
+    was compiled before, and a deadline of 0.3 s falls mid-decode."""
+    return FaultInjector(seed=0).arm("slow_step", times=-1, seconds=0.02)
+
+
 @pytest.mark.chaos
 def test_deadline_mid_decode_finishes_timeout_with_partial_output(model):
-    eng = InferenceEngine(model, n_slots=1, max_len=128)
+    eng = shared_engine(model, n_slots=1, max_len=128, faults=_slow_steps())
     r = eng.submit([3, 1, 4], max_new_tokens=100, deadline_s=0.3)
     eng.run_until_idle(max_steps=100000)
     assert r.done and r.finish_reason == "timeout"
@@ -315,7 +321,8 @@ def test_deadline_mid_decode_finishes_timeout_with_partial_output(model):
 
 @pytest.mark.chaos
 def test_engine_default_deadlines_apply(model):
-    eng = InferenceEngine(model, n_slots=1, max_len=128, deadline_s=0.3)
+    eng = shared_engine(model, n_slots=1, max_len=128, deadline_s=0.3,
+                        faults=_slow_steps())
     r = eng.submit([3, 1, 4], max_new_tokens=100)
     assert r.deadline_s == 0.3  # engine default resolved at submit
     eng.run_until_idle(max_steps=100000)

@@ -16,6 +16,7 @@ import pytest
 from bigdl_tpu.api import TpuModel, optimize_model
 from bigdl_tpu.models import llama
 from bigdl_tpu.models.config import PRESETS
+from engines import shared_engine
 
 CFG = PRESETS["tiny-llama"]
 
@@ -119,8 +120,6 @@ def test_overlong_prompt_rejected_not_truncated(server):
     explicit engine opt-in."""
     import jax
 
-    from bigdl_tpu.serving.engine import InferenceEngine
-
     port = server.httpd.server_address[1]
     long_prompt = [(i % 250) + 2 for i in range(298)]  # in-vocab, 298 toks
     with pytest.raises(urllib.error.HTTPError) as e:
@@ -131,14 +130,14 @@ def test_overlong_prompt_rejected_not_truncated(server):
 
     # engine-level: rejected request is done+invalid without queueing
     model = server.engine.model
-    eng = InferenceEngine(model, n_slots=1, max_len=64)
+    eng = shared_engine(model, n_slots=1, max_len=64)
     r = eng.submit(list(range(2, 200)), max_new_tokens=4)
     assert r.done and r.finish_reason == "invalid" and "exceeds" in r.error
 
     # opt-in truncation restores the old behavior: generates from the
     # kept tail, byte-identical to generate() on that tail
-    eng_t = InferenceEngine(model, n_slots=1, max_len=64,
-                            truncate_prompts=True)
+    eng_t = shared_engine(model, n_slots=1, max_len=64,
+                          truncate_prompts=True)
     long_p = list(range(2, 200))
     r = eng_t.submit(long_p, max_new_tokens=4)
     eng_t.run_until_idle()
@@ -172,9 +171,7 @@ def test_generate_input_validation(server):
     assert out.shape == (1, 2)
 
     # engine submit: out-of-vocab / empty prompts fail as "invalid"
-    from bigdl_tpu.serving.engine import InferenceEngine
-
-    eng = InferenceEngine(model, n_slots=1, max_len=64)
+    eng = shared_engine(model, n_slots=1, max_len=64)
     req = eng.submit([V + 7], max_new_tokens=2)
     assert req.done and req.finish_reason == "invalid"
     req = eng.submit([], max_new_tokens=2)
@@ -190,11 +187,9 @@ def test_full_feature_composition_torture(server, tmp_path):
     adaptive draft + journal + mixed sampling + a mid-flight cancel —
     must complete all requests, leak no pages, and tombstone the journal
     so a successor engine replays nothing."""
-    from bigdl_tpu.serving.engine import InferenceEngine
-
     model = server.engine.model
     jpath = str(tmp_path / "journal.jsonl")
-    eng = InferenceEngine(
+    eng = shared_engine(
         model, n_slots=2, max_len=96, paged=True, page_size=8,
         speculative=True, draft_params=model.params, draft_k=4,
         adaptive_draft=True, quantize_kv=True, journal=jpath,
@@ -211,6 +206,6 @@ def test_full_feature_composition_torture(server, tmp_path):
     assert not [r.error for r in reqs if r.error]
     assert eng.pages.pool.n_free + eng.pages.radix.n_nodes == free0
     assert eng.page_leaks() == 0
-    eng2 = InferenceEngine(model, n_slots=2, max_len=96, paged=True,
-                           page_size=8, journal=jpath)
+    eng2 = shared_engine(model, n_slots=2, max_len=96, paged=True,
+                         page_size=8, journal=jpath)
     assert len(eng2.recovered_requests) == 0  # all tombstoned

@@ -20,6 +20,17 @@ from jax.sharding import SingleDeviceSharding
 pytestmark = pytest.mark.core
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _compile_as_the_chip_does():
+    """`conftest.py` compiles with most optimizations off, an option every
+    backend is handed: here the TPU's compiler works as it does on the chip
+    (what it refuses, what it copies, what it keeps in memory)."""
+    was = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
+    yield
+    jax.config.update("jax_disable_most_optimizations", was)
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     import os
@@ -41,6 +52,21 @@ def _sds(shape, dtype, sharding):
 # (K, O) of the cells' projections (bench/costs.decode_linears of the four
 # configurations) with the rows the cell's decode step has, and a prefill's
 # row tile at each configuration's widest contraction
+def _lower_on_a_stack(chip, K, O, M, L=2):
+    """`qmatmul` lowered on a sym_int4 weight nobody prepared, as a layer
+    scan hands it over: the codes of a stack of L layers, the scales of
+    the layer's own."""
+    from bigdl_tpu.ops.pallas.qmatmul import qmatmul
+    from bigdl_tpu.quant import QTensor
+
+    w = QTensor(data=_sds((L, O, K // 2), jnp.uint8, chip),
+                scales=_sds((O, K // 32), jnp.float16, chip),
+                qtype="sym_int4")
+    return jax.jit(
+        lambda x, w, layer: qmatmul(x, w, interpret=False, layer=layer)
+    ).lower(_sds((M, K), jnp.bfloat16, chip), w, _sds((), jnp.int32, chip))
+
+
 _DENSE = [
     # Mistral-7B: wqkv, wo, w_gateup, w_down, head (32000: 62 word tiles
     # and a ragged one of 256 rows since ISSUE 55, as Brumby's 151936 below)
@@ -63,19 +89,7 @@ _DENSE = [
 
 @pytest.mark.parametrize("K,O,M", _DENSE)
 def test_qmatmul_compiles_at_the_cells_shapes(one_chip, K, O, M):
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_int4
-
-    L = 2
-
-    def f(x, data, scales, layer):
-        return qmatmul_int4(x, data, scales, interpret=False, layer=layer)
-
-    jax.jit(f).lower(
-        _sds((M, K), jnp.bfloat16, one_chip),
-        _sds((L, O, K // 2), jnp.uint8, one_chip),
-        _sds((O, K // 32), jnp.float16, one_chip),
-        _sds((), jnp.int32, one_chip),
-    ).compile()
+    _lower_on_a_stack(one_chip, K, O, M).compile()
 
 
 def _format_names():
@@ -302,17 +316,9 @@ def test_dense_qmatmul_lowers_to_the_parents_program(one_chip, K, O, M):
     are now a whole expert a step)."""
     import hashlib
 
-    from bigdl_tpu.ops.pallas.qmatmul import qmatmul_int4
     from bigdl_tpu.ops.pallas.tiling import grouped_tile
 
-    text = jax.jit(
-        lambda x, d, s, layer: qmatmul_int4(x, d, s, interpret=False,
-                                            layer=layer)
-    ).lower(_sds((M, K), jnp.bfloat16, one_chip),
-            _sds((2, O, K // 2), jnp.uint8, one_chip),
-            _sds((O, K // 32), jnp.float16, one_chip),
-            _sds((), jnp.int32, one_chip)).as_text()
-    (body,) = _mosaic_bodies(text)
+    (body,) = _mosaic_bodies(_lower_on_a_stack(one_chip, K, O, M).as_text())
     assert hashlib.sha256(body.encode()).hexdigest() == _DENSE_BODIES[K, O, M]
     for k, o, stacks, held in ((4096, 14336, 2, 1), (14336, 4096, 1, 1),
                                (2048, 1536, 2, 3), (1536, 2048, 1, 4)):
