@@ -526,7 +526,10 @@ def _moe_dispatch_grouped(
     they fall), an expert nobody chose is never read, and no expert is
     ever dequantized into HBM. Rows are independent from the gather to
     the combine, so a padded or idle row (NaN included) cannot reach a
-    live one. `layer` says the stacks' packed codes still carry the layer
+    live one. The combine gathers k-major, `y[dest.T]` as `[k, N, H]`, and
+    sums over the major axis: as `[N, k, H]`, k lies on the sublane axis,
+    is padded to a multiple of 8 and every float32 row re-laid once more a
+    layer. `layer` says the stacks' packed codes still carry the layer
     axis (forward keeps them out of the scan's slices)."""
     from bigdl_tpu.ops.pallas import moe_qmatmul as mq
 
@@ -567,7 +570,7 @@ def _moe_dispatch_grouped(
         if not config.gated_mlp and "b_down_e" in p:
             y = y + p["b_down_e"].astype(jnp.float32)[row_expert]
     with scope("moe.combine"):
-        out = jnp.sum(y[dest] * topv.reshape(N, k, 1), axis=1)
+        out = jnp.sum(y[dest.T] * topv.reshape(N, k).T[:, :, None], axis=0)
     return out.astype(compute_dtype).reshape(B, T, H)
 
 

@@ -230,10 +230,11 @@ KERNEL_MODELS = dict(
         tie_word_embeddings=True, rms_norm_eps=1e-5))
 # equations of `engine_decode` with the kernels on (the interpreter's route):
 # (the tree as `optimize_model` makes it, the tree prepared). Mixtral's were
-# (531, 523) until ISSUE 53 took the sort out of a decode step's dispatch
+# (531, 523) until ISSUE 53 took the sort out of a decode step's dispatch,
+# and (487, 479) until ISSUE 60's k-major combine (`MOE_KERNEL_ROUTE` below)
 KERNEL_ROUTE = {
     "mistral": (431, 427),
-    "mixtral": (487, 479),
+    "mixtral": (490, 482),
 }
 
 
@@ -396,16 +397,19 @@ def test_glms_decode_step_calls_the_latent_kernel_a_grid_step_a_row(
 # decode step, so each hit expert's tile is the step's rows as they stand and
 # nothing is sorted or copied. The guard: with the kernels on, no gather of
 # the decode program has a result of more than N * k rows of width H (the
-# combine's `y[dest]` has exactly that many), and the programs are SHORTER
+# combine's `y[dest.T]` has exactly that many), and the programs are SHORTER
 # than the parent's (equations outside the kernels' bodies; the parent's in
-# the comment), none longer.
+# the comment), none longer. ISSUE 60's k-major combine adds three equations
+# a traced layer body (`dest` and the weights transposed, N * k words each,
+# so that no `[N, k, H]` float32 is re-laid: tests/test_moe.py (f)); the
+# counts before it are the comment's second number.
 MOE_KERNEL_ROUTE = {
-    "mixtral": 479,  # 523
-    "glm4_moe_lite": 886,  # 930
-    "smallthinker": 1373,  # 1549
-    "laguna": 2916,  # 3224
-    "sdar_moe": 546,  # 593
-    "granitemoehybrid": 1248,  # 1380
+    "mixtral": 482,  # 523, 479
+    "glm4_moe_lite": 889,  # 930, 886
+    "smallthinker": 1385,  # 1549, 1373
+    "laguna": 2937,  # 3224, 2916
+    "sdar_moe": 549,  # 593, 546
+    "granitemoehybrid": 1257,  # 1380, 1248
 }
 
 

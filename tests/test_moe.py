@@ -478,6 +478,66 @@ def test_shared_rows_equal_sorted_rows_bit_for_bit(interpret, N, k, E,
     np.testing.assert_array_equal(np.asarray(shared_y), np.asarray(sorted_y))
 
 
+# (f) the combine gathers the assignments k-major (ISSUE 60): `y[dest.T]` is
+# `[k, N, H]`, whole `(N, H)` tiles summed over the major axis. Token-major,
+# `y[dest]` is `[N, k, H]` with k on the sublane axis of a tile of 8: padded
+# to the next multiple of 8 and re-laid, a copy of every assignment's row a
+# layer (`f32[49152,2560] -> [8192,6,2560]`, 1.8 ms a layer of SmallThinker's
+# prefill). Same addends, same float32, same weights: only the association
+# of a sum of k terms may differ.
+@pytest.mark.parametrize("N,rows", [(264, "sorted"), (16, "shared")])
+@pytest.mark.parametrize("k", [2, 4, 6, 8, 10])
+def test_combine_sums_k_major_what_token_major_summed(interpret, monkeypatch,
+                                                      k, N, rows):
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from tests.test_engine_jaxpr_guard import _eqns
+
+    E, H, I = 12, 256, 512
+    cfg = dataclasses.replace(
+        ModelConfig.from_hf_config(TINY_MIXTRAL), num_experts=E,
+        num_experts_per_tok=k)
+    keys = jax.random.split(jax.random.PRNGKey(10 * k + N), 6)
+    p = {"w_gate_e": _random_stack(keys[3], (E, I), H),
+         "w_up_e": _random_stack(keys[4], (E, I), H),
+         "w_down_e": _random_stack(keys[5], (E, H), I)}
+    topi = jnp.argsort(jax.random.uniform(keys[0], (N, E)))[:, :k].astype(
+        jnp.int32)  # k distinct experts a token
+    topv = jax.nn.softmax(jax.random.normal(keys[1], (N, k)))
+    x = jnp.zeros((1, N, H), jnp.bfloat16)
+    bm = llama._moe_block_m(x, p)
+    assert (N > bm) == (rows == "sorted")
+    n_tiles = mq.moe_n_tiles(N, k, E, bm)
+    dest = (mq.moe_layout(topi, E, bm, n_tiles)[0] if rows == "sorted"
+            else mq.moe_layout_shared(topi, E, bm)[0])
+    # (a) the experts' result given: every row no assignment reads is NaN
+    live = np.zeros(n_tiles * bm, bool)
+    live[np.asarray(dest)] = True
+    assert live.sum() == N * k < live.size
+    y = jnp.where(live[:, None], jax.random.normal(keys[2], (live.size, H)),
+                  jnp.nan)
+
+    def given(xs, ws, tile_expert, n_used, **kw):
+        if ws is p["w_down_e"]:
+            return y
+        return jnp.zeros((tile_expert.shape[0] * bm, I), jnp.bfloat16)
+
+    with monkeypatch.context() as m:
+        m.setattr(mq, "moe_qmatmul", given)
+        got = np.asarray(llama._moe_dispatch_grouped(
+            cfg, x, p, jnp.float32, topv[None], topi[None])[0])
+    want = np.asarray(jnp.sum(y[dest] * topv.reshape(N, k, 1), axis=1))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    # (b) the program as it is served: no float32 [N, k, H] anywhere in it,
+    # and the [k, N, H] gather this walk would have seen it beside
+    jaxpr = jax.make_jaxpr(lambda x, v, i: llama._moe_dispatch_grouped(
+        cfg, x, p, jnp.bfloat16, v, i))(x, topv[None], topi[None]).jaxpr
+    avals = {(v.aval.shape, str(v.aval.dtype))
+             for e, _ in _eqns(jaxpr) for v in e.outvars}
+    assert ((k, N, H), "float32") in avals
+    assert ((N, k, H), "float32") not in avals
+
+
 @pytest.mark.parametrize("act,gated,want", [
     ("silu", True, "gate_up words:inplace:paired x1 of 3 tiles, "
                    "down words:inplace x1 of 8 tiles"),
