@@ -1303,6 +1303,71 @@ def _jamba_tree(config: ModelConfig, get: Get, quant) -> tuple[list, dict]:
     return runs, top
 
 
+def _lfm2_moe_tree(config: ModelConfig, get: Get, quant
+                   ) -> tuple[list, dict]:
+    """LFM2-MoE (HF modeling_lfm2_moe). Returns (one list of per-layer dicts
+    for each RUN of layers of one operator and one feed-forward, top),
+    `quant` applied as tensors stream in. `operator_norm` / `ffn_norm` are
+    the two pre-norms and `embedding_norm` the OUTPUT norm; `conv.in_proj`
+    is [B | C | x] as it stands; `conv.conv.weight [H, 1, K]` becomes
+    `conv_w [K, H]` (w[K - 1] the current input), float32; `self_attn.
+    q_layernorm` / `k_layernorm` are the per-head norms and `out_proj` is
+    `wo`; a dense layer's `feed_forward.w1 / w3 / w2` are gate / up / down,
+    a sparse layer's `experts.<e>.w1 / w3 / w2` stack to `[E, ..]`;
+    `feed_forward.gate` is the router and `expert_bias` the selection bias,
+    both float32 (a checkpoint without one, `use_expert_bias` false, chooses
+    by a bias of zeros). With tied embeddings the head is a packed copy of
+    the table."""
+    from bigdl_tpu.models.lfm2_moe import layer_runs
+
+    def f32(x):
+        return jnp.asarray(np.asarray(x, np.float32))
+
+    def one(i: int, kind: str, dense: bool) -> dict:
+        p = f"model.layers.{i}."
+        d = {"attn_norm": get(p + "operator_norm.weight"),
+             "mlp_norm": get(p + "ffn_norm.weight")}
+        exact = {}
+        if kind == "conv":
+            c = p + "conv."
+            d.update(w_in=get(c + "in_proj.weight"),
+                     w_out=get(c + "out_proj.weight"))
+            exact["conv_w"] = f32(np.asarray(get(c + "conv.weight"))[:, 0].T)
+        else:
+            a = p + "self_attn."
+            d.update(wq=get(a + "q_proj.weight"), wk=get(a + "k_proj.weight"),
+                     wv=get(a + "v_proj.weight"),
+                     wo=get(a + "out_proj.weight"),
+                     q_norm=get(a + "q_layernorm.weight"),
+                     k_norm=get(a + "k_layernorm.weight"))
+        f = p + "feed_forward."
+        if dense:
+            d.update(w_gate=get(f + "w1.weight"), w_up=get(f + "w3.weight"),
+                     w_down=get(f + "w2.weight"))
+        else:
+            E = config.num_experts
+            for ours, theirs in (("w_gate_e", "w1"), ("w_up_e", "w3"),
+                                 ("w_down_e", "w2")):
+                d[ours] = np.stack([np.asarray(
+                    get(f"{f}experts.{e}.{theirs}.weight")) for e in range(E)])
+            exact["router"] = f32(get(f + "gate.weight"))
+            try:
+                exact["e_bias"] = f32(get(f + "expert_bias"))
+            except KeyError:
+                exact["e_bias"] = jnp.zeros((E,), jnp.float32)
+        return {**{k: quant(k, v) for k, v in d.items()}, **exact}
+
+    runs, i = [], 0
+    for kind, _, n, dense in layer_runs(config):
+        runs.append([one(i + j, kind, dense) for j in range(n)])
+        i += n
+    top = {"embed": get("model.embed_tokens.weight"),
+           "final_norm": get("model.embedding_norm.weight")}
+    top["lm_head"] = (top["embed"] if config.tie_word_embeddings
+                      else get("lm_head.weight"))
+    return runs, top
+
+
 def _minicpm_sala_tree(config: ModelConfig, get: Get, quant
                        ) -> tuple[list, dict]:
     """MiniCPM-SALA. Returns (one list of per-layer dicts for each RUN of
@@ -1535,7 +1600,8 @@ def params_from_state_dict(
         return params
 
     by_runs = {"granitemoehybrid": _granitemoehybrid_tree,
-               "minicpm_sala": _minicpm_sala_tree, "jamba": _jamba_tree}
+               "minicpm_sala": _minicpm_sala_tree, "jamba": _jamba_tree,
+               "lfm2_moe": _lfm2_moe_tree}
     if config.model_type in by_runs:
         runs, top = by_runs[config.model_type](config, get_tensor,
                                                maybe_quant)

@@ -32,9 +32,18 @@ convolves its E inner channels alone and keeps
 (its d_state of 16 would fill 16 of 128 lanes the other way round; and with
 256 rows between a tail's d_conv - 1 inputs XLA re-laid the whole `conv`
 array, 0.4 GB, into and out of every program that gathers or scatters rows of
-it, wherever an axis of 3 stood: scripts/engine_fit.py shows such a copy). Everything that books, parks,
-restores or counts a row (`row_nbytes`, `_spots`, `axes_of`, `row_view`, the
-spans) reads sizes and the row axis.
+it, wherever an axis of 3 stood: scripts/engine_fit.py shows such a copy). A gated
+short-convolution layer (LFM2, `models/lfm2_moe.py`; `tail_conv` below) has NO
+recurrence at all: its state is the tail alone,
+
+    conv [Lc, R, (L_cache - 1) * H]  laid as Mamba-1's, `conv_rows` 1
+    ssm  None
+
+Everything that books, parks, restores or counts a row (`row_nbytes`,
+`n_rows`, `_spots`, `axes_of`, `row_view`, the spans) reads what is there:
+sizes, the row axis, and no array where the family has none. What a prefill
+counts for its span is the family's too, handed over with the state's shape
+(`init_hybrid(counts=)`).
 
 The Mamba-2 recurrence of one head, with a_t = dt_t * A <= 0:
 
@@ -66,7 +75,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -84,7 +93,8 @@ class HybridCache:
     k: jax.Array  # [La, n_pages, page, Hkv, D]
     v: jax.Array
     conv: jax.Array  # [Lm, d_conv - 1, R, C] float32
-    ssm: jax.Array  # [Lm, R, *the family's state] float32
+    # [Lm, R, *the family's state] float32; None: the tail is all the state
+    ssm: Optional[jax.Array]
     block_tables: jax.Array  # [B, max_pages] int32, 0 = nobody's page
     pos: jax.Array  # [B] int32 next slot per row
     start: jax.Array  # [B] int32 first valid slot (left padding)
@@ -97,6 +107,11 @@ class HybridCache:
     # 1 (`[Lm, R, (K - 1) * C]`); static
     conv_rows: int = dataclasses.field(default=2,
                                        metadata=dict(static=True))
+    # what a prefill chunk adds to its span, by the family's prefill form:
+    # ("state_chunks", the chunk length of a chunked form), ("scan_tokens",)
+    # where the form walks the tokens, () where a tail is all the state
+    # (read from the engine's pool alone, `note_chunk`); static
+    counts: tuple = dataclasses.field(default=(), metadata=dict(static=True))
 
     @property
     def page_size(self) -> int:
@@ -108,7 +123,7 @@ class HybridCache:
 
     @property
     def n_rows(self) -> int:
-        return self.ssm.shape[1]
+        return self.conv.shape[self.conv_rows]
 
     @property
     def kv(self) -> kvpaged.PagedKVCache:
@@ -129,29 +144,32 @@ class HybridCache:
 def init_hybrid(n_attn: int, n_mamba: int, n_pages: int, page_size: int,
                 n_kv_heads: int, head_dim: int, rows: int,
                 max_pages_per_row: int, conv_dim: int, d_conv: int,
-                inner: int, d_state: int, batch: Optional[int] = None,
-                dtype=jnp.bfloat16, state: Optional[tuple] = None,
+                state: Optional[tuple], counts: tuple = (),
+                batch: Optional[int] = None, dtype=jnp.bfloat16,
                 conv_rows: int = 2) -> HybridCache:
-    """Zeros: pages nobody holds and `rows` state rows, each layer's of
-    shape `state` (Mamba-2's `(inner, d_state)` where the family names
-    none), the convolution's tails with the rows on axis `conv_rows`."""
+    """Zeros: pages nobody holds and `rows` state rows, each layer's of the
+    family's shape `state` (None: a layer keeps its convolution's tail and
+    nothing else), the tails with the rows on axis `conv_rows`; `counts`:
+    `HybridCache.counts`."""
     b = rows if batch is None else batch
     kv = (n_attn, n_pages, page_size, n_kv_heads, head_dim)
     tails = ((d_conv - 1, rows, conv_dim) if conv_rows == 2
              else (rows, (d_conv - 1) * conv_dim))
     return HybridCache(
         k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype), conv_rows=conv_rows,
+        counts=tuple(counts),
         conv=jnp.zeros((n_mamba,) + tails, jnp.float32),
-        ssm=jnp.zeros((n_mamba, rows) + (state or (inner, d_state)),
-                      jnp.float32),
+        ssm=(None if state is None else
+             jnp.zeros((n_mamba, rows) + tuple(state), jnp.float32)),
         block_tables=jnp.zeros((b, max_pages_per_row), jnp.int32),
         pos=jnp.zeros((b,), jnp.int32), start=jnp.zeros((b,), jnp.int32))
 
 
 def row_nbytes(cache: HybridCache) -> int:
-    """Bytes of ONE state row over all Mamba layers: what a decode step
+    """Bytes of ONE state row over all state layers: what a decode step
     reads, and writes again, for each live slot."""
-    return (cache.conv.size + cache.ssm.size) // cache.n_rows * 4
+    return sum(a.size for a in (cache.conv, cache.ssm)
+               if a is not None) // cache.n_rows * 4
 
 
 def valid_positions(cache: HybridCache, T: int) -> jax.Array:
@@ -338,6 +356,76 @@ def conv_step(tail, u, w, b):
     return out, window[:, C:]
 
 
+class _RowTails(NamedTuple):
+    """Where each batch row's state lies, and a layer's tails laid flat."""
+
+    rows: jax.Array  # [B] state row of each batch row
+    live: jax.Array  # [B] bool
+    at: jax.Array  # [B] the row to read, inside the pool
+    to: jax.Array  # [B] the row to write; past the pool for an idle row
+    fresh: jax.Array  # [B] bool: at position 0, starting from nothing
+    whole: bool  # batch row b IS state row b: slices, not gathers
+    old: jax.Array  # [B, (K - 1) * C] what the pool holds
+    tail: jax.Array  # ... and zeros where the row is fresh
+
+
+def _flat_tails(cache: HybridCache, layer, B: int) -> _RowTails:
+    """`_RowTails` of layer `layer`, whose tails lie in one piece a row."""
+    rows, live = cache.state_rows()
+    at = jnp.clip(rows, 0, cache.n_rows - 1)
+    to = jnp.where(live, at, cache.n_rows)  # an idle row writes nowhere
+    # a row at position 0 starts from nothing, whatever its last holder left
+    fresh = cache.pos == 0
+    assert cache.conv_rows == 1, "the row's tail lies in one piece"
+    # batch row b holds state row b (a decode step, `generate`): the layer's
+    # tails are a slice of the pool, not a gather
+    whole = cache.rows is None and B == cache.n_rows
+    old = cache.conv[layer] if whole else cache.conv[layer, at]
+    tail = jnp.where(fresh[:, None], 0.0, old)  # [B, (K - 1) * C]
+    return _RowTails(rows, live, at, to, fresh, whole, old, tail)
+
+
+def _conv_from_tail(tail, u, w, b, end, one_token: bool):
+    """The convolution of `u [B, T, C]` behind the flat `tail`: `conv_step`
+    for a decode step's one token, else `causal_conv`. Returns (out [B, T,
+    C], the flat tail after position `end` - 1)."""
+    B, _, C = u.shape
+    if one_token:
+        x, tail = conv_step(tail, u[:, 0], w, b)
+        return x[:, None], tail
+    x, tail = causal_conv(tail.reshape(B, -1, C), u, w, b, end)
+    return x, tail.reshape(B, -1)
+
+
+def _put_tails(cache: HybridCache, layer, tail, r: _RowTails):
+    """`cache.conv` with the live rows' new flat tails of layer `layer`."""
+    if r.whole:
+        return jax.lax.dynamic_update_slice(
+            cache.conv, jnp.where(r.live[:, None], tail, r.old)[None],
+            (layer, 0, 0))
+    return cache.conv.at[layer, r.to].set(tail, mode="drop")
+
+
+def tail_conv(cache: HybridCache, layer, g, w, *, decode: bool):
+    """The state part of gated short-convolution layer `layer` (LFM2; index
+    among the convolution layers) over this forward's T positions: the
+    depthwise causal convolution of the gated input `g [B, T, C]` with `w
+    [K, C]` (w[K - 1] weighs the current input), no bias, no activation,
+    behind the row's tail, which is ALL the state such a layer has. A
+    position that is no token contributes a zero and the tail is taken at
+    the last real one. Returns (c [B, T, C] float32, the cache with the
+    layer's tails updated)."""
+    B, T, _ = g.shape
+    valid = valid_positions(cache, T)
+    g = jnp.where(valid[..., None], g.astype(jnp.float32), 0.0)
+    end = (jnp.full((B,), T, jnp.int32) if cache.valid_len is None
+           else cache.valid_len.astype(jnp.int32))
+    r = _flat_tails(cache, layer, B)
+    c, tail = _conv_from_tail(r.tail, g, w, 0.0, end, decode and T == 1)
+    return c, dataclasses.replace(cache,
+                                  conv=_put_tails(cache, layer, tail, r))
+
+
 def why_not_scan_kernel(d_state: int, inner: int) -> Optional[str]:
     """None when a Mamba-1 layer takes the kernels of `selective_scan`."""
     from bigdl_tpu.ops.pallas import interpret_mode, why_not_pallas
@@ -370,31 +458,12 @@ def mix1(cache: HybridCache, layer, u, p, *, dt_rank: int, d_state: int,
     u = jnp.where(valid[..., None], u.astype(f32), 0.0)
     end = (jnp.full((B,), T, jnp.int32) if cache.valid_len is None
            else cache.valid_len.astype(jnp.int32))
-    rows, live = cache.state_rows()
-    at = jnp.clip(rows, 0, cache.n_rows - 1)
-    to = jnp.where(live, at, cache.n_rows)  # an idle row writes nowhere
-    # a row at position 0 starts from nothing, whatever its last holder left
-    fresh = cache.pos == 0
-    assert cache.conv_rows == 1, "a Mamba-1 row's tail lies in one piece"
-    # batch row b holds state row b (a decode step, `generate`): the layer's
-    # tails are a slice of the pool, not a gather
-    whole = cache.rows is None and B == cache.n_rows
-    old = cache.conv[layer] if whole else cache.conv[layer, at]
-    tail = jnp.where(fresh[:, None], 0.0, old)  # [B, (K - 1) * E]
-    if decode and T == 1:
-        x, tail = conv_step(tail, u[:, 0], p["conv_w"], p["conv_b"])
-        x = x[:, None]
-    else:
-        x, tail = causal_conv(tail.reshape(B, -1, E), u, p["conv_w"],
-                              p["conv_b"], end)
-        tail = tail.reshape(B, -1)
+    r = _flat_tails(cache, layer, B)
+    rows, live, at, to, fresh = r[:5]
+    x, tail = _conv_from_tail(r.tail, u, p["conv_w"], p["conv_b"], end,
+                              decode and T == 1)
     x = jax.nn.silu(x)
-    if whole:
-        conv = jax.lax.dynamic_update_slice(
-            cache.conv, jnp.where(live[:, None], tail, old)[None],
-            (layer, 0, 0))
-    else:
-        conv = cache.conv.at[layer, to].set(tail, mode="drop")
+    conv = _put_tails(cache, layer, tail, r)
 
     def proj(a, w):  # bf16 operands as `linear`'s, float32 sums
         return jnp.einsum("bti,oi->bto", a.astype(w.dtype), w,
@@ -438,10 +507,11 @@ def mix1(cache: HybridCache, layer, u, p, *, dt_rank: int, d_state: int,
     return y, dataclasses.replace(cache, conv=conv, ssm=ssm)
 
 
-def conv_rows_of(cfg) -> int:
-    """`HybridCache.conv_rows` of a model: a selective scan's family lays a
-    row's tail in one piece."""
-    return 1 if cfg.mamba_dt_rank else 2
+def _rows_axis(conv) -> int:
+    """`HybridCache.conv_rows` of a pool's `conv` leaf: `[Ls, K - 1, R, C]`
+    keeps the rows on axis 2, a tail in one piece `[Ls, R, (K - 1) * C]` on
+    axis 1."""
+    return 2 if conv.ndim == 4 else 1
 
 
 def prefill_chunks(n_tokens: int, chunk: int) -> int:
@@ -476,7 +546,7 @@ class _StateBesidePages(kvpaged.CacheKind):
         cache = HybridCache(
             **dict(zip(self.arrays, leaves)), block_tables=tables[0],
             pos=pos0, start=jnp.zeros((1,), jnp.int32), rows=slot,
-            valid_len=last_idx[None] + 1, conv_rows=conv_rows_of(cfg))
+            valid_len=last_idx[None] + 1, conv_rows=_rows_axis(leaves[2]))
         return cache, cache
 
     def write_back(self, pool, row, n_tokens, last_idx, cfg):
@@ -493,16 +563,19 @@ class _StateBesidePages(kvpaged.CacheKind):
     def state_row_nbytes(self, cache):
         return row_nbytes(cache)
 
-    def note_chunk(self, st, cfg, geo, bucket, n):
-        if cfg.mamba_dt_rank:  # a selective scan runs token by token
-            st.scan_tokens += n
-        else:
-            st.state_chunks += prefill_chunks(bucket, cfg.mamba_chunk_size)
+    def note_chunk(self, st, cfg, geo, bucket, n, pool):
+        """What the family's prefill form counts of a chunk
+        (`HybridCache.counts`): the chunks of a chunked form over the
+        bucket, the tokens a scan walked, nothing for a tail alone."""
+        if pool.counts:
+            name, *chunk = pool.counts
+            by = prefill_chunks(bucket, *chunk) if chunk else n
+            setattr(st, name, getattr(st, name) + by)
 
     def prefill_args(self, st):
-        if st.scan_tokens:
-            return {"scan_tokens": st.scan_tokens}
-        return {"state_chunks": st.state_chunks}
+        return {name: getattr(st, name)
+                for name in ("state_chunks", "scan_tokens")
+                if getattr(st, name)}
 
     def decode_args(self, cfg, table, live, moved, pool):
         return {**kvstate.state_decode_args(live, moved),

@@ -603,9 +603,10 @@ class CacheKind:
         """Bytes of one token's latents over all layers, where it has them."""
         return 0
 
-    def note_chunk(self, st, cfg, geo: Geometry, bucket: int, n: int):
+    def note_chunk(self, st, cfg, geo: Geometry, bucket: int, n: int, pool):
         """Add a prefill chunk of `n` tokens padded to `bucket`, about to
-        run from `st.written`, to the `_PrefillState`'s counts."""
+        run from `st.written`, to the `_PrefillState`'s counts; `pool` is
+        the engine's, read for what is static of it alone."""
         st.row_pages += geo.max_pages_per_row
         st.pages_written += pages_spanned(
             st.written, bucket, geo.page_size, geo.max_pages_per_row)
@@ -655,7 +656,7 @@ class _LatentPages(CacheKind):
 
         return get_family(cfg.model_type).latent_token_nbytes(cfg)
 
-    def note_chunk(self, st, cfg, geo, bucket, n):
+    def note_chunk(self, st, cfg, geo, bucket, n, pool):
         # the expanded form up-projects the row's whole capacity
         st.upprojected += geo.max_pages_per_row * geo.page_size
 

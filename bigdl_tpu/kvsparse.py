@@ -661,6 +661,10 @@ class _SelectedPagesBesideState(kvhybrid._StateBesidePages):
     def state_row_nbytes(self, cache):
         return row_nbytes(cache)
 
+    def note_chunk(self, st, cfg, geo, bucket, n, pool):
+        # the lightning layers' chunked form over the bucket
+        st.state_chunks += prefill_chunks(bucket, cfg.mamba_chunk_size)
+
     # ---- what a forward chose (the engine's one fetch a step) --------------
 
     def report(self, cache):

@@ -403,7 +403,7 @@ class _StateRows(kvpaged.CacheKind):
     def state_row_nbytes(self, cache):
         return row_nbytes(cache)
 
-    def note_chunk(self, st, cfg, geo, bucket, n):
+    def note_chunk(self, st, cfg, geo, bucket, n, pool):
         st.state_chunks += prefill_chunks(bucket)
 
     def prefill_args(self, st):

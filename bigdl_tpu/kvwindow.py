@@ -252,7 +252,7 @@ class _PageGroups(kvpaged.CacheKind):
     def _spots(self, pages, slot, window_pages):
         return pages, pages, window_pages, window_pages
 
-    def note_chunk(self, st, cfg, geo, bucket, n):
+    def note_chunk(self, st, cfg, geo, bucket, n, pool):
         page, mp = geo.page_size, geo.max_pages_per_row
         st.row_pages += 2 * mp
         st.window_pages_written += window_pages_spanned(

@@ -185,6 +185,12 @@ _LAZY_FAMILIES = {
     # state row beside KV pages with the state the other way round
     # (bigdl_tpu/kvhybrid.py)
     "jamba": "bigdl_tpu.models.jamba",
+    # gated short-convolution layers (their only state the convolution's
+    # tail) with a GQA layer every few on heads of 64, kept two to a row of
+    # lanes; dense layers lead, then sigmoid-routed experts with a
+    # selection bias; granite's state row beside KV pages with no
+    # recurrence state at all (bigdl_tpu/kvhybrid.py)
+    "lfm2_moe": "bigdl_tpu.models.lfm2_moe",
 }
 
 

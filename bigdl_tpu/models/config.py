@@ -189,6 +189,11 @@ class ModelConfig:
     # (0 = no such mixer), and the decay differs by channel AND state index
     mamba_expand: int = 2
     mamba_dt_rank: int = 0
+    # lfm2_moe (models/lfm2_moe.py): `layer_types` names each layer's
+    # operator, "conv" | "attention"; a "conv" layer is LFM2's gated short
+    # convolution, depthwise and causal over `conv_l_cache` inputs (0 = no
+    # such operator), whose only state is its last `conv_l_cache - 1` inputs
+    conv_l_cache: int = 0
 
     def __post_init__(self):
         if self.attention_kind not in ("softmax", "power_retention"):
@@ -932,6 +937,46 @@ def _hf_jamba(hf, kw):
     kw.setdefault("tie_word_embeddings", False)
 
 
+def _hf_lfm2_moe(hf, kw):
+    """LFM2-MoE (HF modeling_lfm2_moe): gated short-convolution layers with
+    a GQA layer every few (`layer_types`: "conv" | "full_attention"), q/k
+    RMSNorm a head before a plain rope, `num_dense_layers` leading layers
+    with a dense SwiGLU and then sigmoid-routed experts with a selection
+    bias (`deepseek._router`'s sigmoid branch, one group). Refused by name:
+    a convolution bias, a scaled rope."""
+    L = hf["num_hidden_layers"]
+    kinds = tuple("attention" if t == "full_attention" else t
+                  for t in hf.get("layer_types") or ())
+    if len(kinds) != L or set(kinds) - {"conv", "attention"}:
+        raise ValueError(
+            f"layer_types must name {L} layers as 'conv' or "
+            f"'full_attention'; got {len(kinds)}: {sorted(set(kinds))}")
+    if hf.get("conv_bias"):
+        raise NotImplementedError("lfm2_moe with conv_bias")
+    rp = hf.get("rope_parameters") or {}
+    if rp.get("rope_type", "default") != "default" or hf.get("rope_scaling"):
+        raise NotImplementedError(
+            f"lfm2_moe with a scaled rope ({rp or hf['rope_scaling']}): the "
+            "attention layers' is written plain")
+    kw["rope_theta"] = float(rp.get("rope_theta",
+                                    hf.get("rope_theta", 1000000.0)))
+    kw["layer_types"] = kinds
+    kw["conv_l_cache"] = hf.get("conv_L_cache", 3)
+    kw["rms_norm_eps"] = hf.get("norm_eps", 1e-5)
+    kw["qk_norm"] = True
+    kw["first_k_dense_replace"] = hf.get("num_dense_layers", 2)
+    kw["num_experts"] = hf.get("num_experts") or 0
+    kw["num_experts_per_tok"] = hf.get("num_experts_per_tok") or 2
+    kw["moe_intermediate_size"] = hf.get("moe_intermediate_size")
+    # the sigmoid branch with one group; `use_expert_bias` false is a bias
+    # of zeros (convert/hf.py), which chooses as the scores do
+    kw["scoring_func"], kw["topk_method"] = "sigmoid", "noaux_tc"
+    kw["n_group"] = kw["topk_group"] = 1
+    kw["norm_topk_prob"] = bool(hf.get("norm_topk_prob", True))
+    kw["routed_scaling_factor"] = hf.get("routed_scaling_factor", 1.0)
+    kw.setdefault("tie_word_embeddings", True)
+
+
 def _hf_qwen3_moe(hf, kw):
     _hf_qwen3(hf, kw)
     kw["num_experts"] = hf.get("num_experts", 128)
@@ -1301,6 +1346,7 @@ _HF_BUILDERS = {
     "brumby": _hf_brumby,
     "granitemoehybrid": _hf_granitemoehybrid,
     "jamba": _hf_jamba,
+    "lfm2_moe": _hf_lfm2_moe,
     "smallthinker": _hf_smallthinker,
     "laguna": _hf_laguna,
     "qwen3_moe": _hf_qwen3_moe,
@@ -1398,6 +1444,25 @@ PRESETS: dict[str, ModelConfig] = {
         layer_types=("mamba", "mamba", "attention", "mamba"),
         mamba_expand=2, mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=8,
         position_embedding_type="nope",
+    ),
+    # LFM2-MoE's shape at toy sizes: a dense convolution layer, then
+    # sparse ones (8 sigmoid-routed experts top-2 with a selection bias)
+    # with two attention layers of 4 KV heads of 64 among them, so that the
+    # pool keeps two lane pairs a row (tests/test_lfm2_moe.py holds it to
+    # its config.json form; the dense combine, so that nothing is dropped)
+    "tiny-lfm2-moe": ModelConfig(
+        model_type="lfm2_moe", vocab_size=256, hidden_size=512,
+        intermediate_size=128, num_hidden_layers=6, num_attention_heads=8,
+        num_key_value_heads=4, tie_word_embeddings=True,
+        rms_norm_eps=1e-5, rope_theta=1000000.0,
+        max_position_embeddings=4096, qk_norm=True, conv_l_cache=3,
+        layer_types=("conv", "attention", "conv", "conv", "attention",
+                     "conv"),
+        first_k_dense_replace=1, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=64, scoring_func="sigmoid",
+        topk_method="noaux_tc", n_group=1, topk_group=1,
+        norm_topk_prob=True, routed_scaling_factor=1.0,
+        moe_dispatch="dense",
     ),
     # SmallThinker's shape at toy sizes: two periods of one full NoPE layer
     # and three window layers with a rope, 8 ReLU-gated experts top-3

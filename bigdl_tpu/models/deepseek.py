@@ -287,9 +287,11 @@ def quantize_params(params: Params, qtype: str, lm_head_qtype: Optional[str] = N
     return out
 
 
-def _router(config: ModelConfig, xc, p):
+def _router(config: ModelConfig, xc, p, norm_eps: float = 1e-20):
     """DeepSeek routing: (topv [N,k] f32, topi [N,k] i32) over flattened
-    tokens. Mirrors DeepseekV2MoEGate / DeepseekV3TopkRouter (and
+    tokens (`norm_eps`: what a family's reference adds to the chosen
+    scores' sum before dividing; LFM2-MoE's has 1e-6). Mirrors
+    DeepseekV2MoEGate / DeepseekV3TopkRouter (and
     Glm4MoeTopkRouter, which is V3's) exactly. The logits are a float32
     product at full precision, as llama's router's are: E x H weights cost
     nothing, and sigmoid scores of 64 experts tie far more often than a
@@ -340,7 +342,7 @@ def _router(config: ModelConfig, xc, p):
     # ignores the flag entirely — our oracle; the official v2 remote code
     # normalizes INSTEAD of scaling, a known upstream divergence)
     if config.norm_topk_prob and method == "noaux_tc":
-        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + norm_eps)
     return topv * config.routed_scaling_factor, topi
 
 

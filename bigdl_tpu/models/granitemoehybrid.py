@@ -192,7 +192,8 @@ def init_paged_cache(config: ModelConfig, n_pages: int, page_size: int,
     return kvhybrid.init_hybrid(
         n_layers(config, "attention"), n_layers(config, "mamba"), n_pages,
         page_size, config.num_key_value_heads, config.head_dim_, batch,
-        max_pages_per_row, C, config.mamba_d_conv, inner, N)
+        max_pages_per_row, C, config.mamba_d_conv, (inner, N),
+        counts=("state_chunks", config.mamba_chunk_size))  # the SSD form's
 
 
 PAGED_CACHE_KIND = kvhybrid.KIND

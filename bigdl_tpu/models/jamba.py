@@ -142,8 +142,9 @@ def init_paged_cache(config: ModelConfig, n_pages: int, page_size: int,
     return kvhybrid.init_hybrid(
         n_layers(config, "attention"), n_layers(config, "mamba"), n_pages,
         page_size, config.num_key_value_heads, config.head_dim_, batch,
-        max_pages_per_row, E, config.mamba_d_conv, E, N, state=(N, E),
-        conv_rows=kvhybrid.conv_rows_of(config))
+        max_pages_per_row, E, config.mamba_d_conv, (N, E),
+        counts=("scan_tokens",),  # a selective scan runs token by token
+        conv_rows=1)
 
 
 PAGED_CACHE_KIND = kvhybrid.KIND

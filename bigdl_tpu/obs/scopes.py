@@ -62,9 +62,12 @@ VOCABULARY = (
 #: the vocabulary's scope around it (`bench/reduce/scopes.scope_of` takes the
 #: innermost name it knows). A block-sparse layer's selection, and the two
 #: forms of a lightning (decayed linear attention) layer (kvsparse.py), and
-#: of a Mamba-1 layer's selective scan (kvhybrid.mix1).
+#: of a Mamba-1 layer's selective scan (kvhybrid.mix1); of a gated
+#: short-convolution layer everything but its two projections (the gate, the
+#: convolution, the tail's update), and the padding and slicing around
+#: attention over lane pairs (models/lfm2_moe.py).
 DETAIL = ("sparse_select", "lightning_prefill", "lightning_decode",
-          "mamba1_prefill", "mamba1_decode")
+          "mamba1_prefill", "mamba1_decode", "short_conv", "pair_attn")
 
 _NAMES = frozenset(VOCABULARY + DETAIL)
 

@@ -1665,7 +1665,7 @@ class InferenceEngine:
         self.cache = kind.with_leaves(self.cache, pool)
         if moe is not None:
             st.moe.append((moe, n))
-        kind.note_chunk(st, self.config, self._geo, bucket, n)
+        kind.note_chunk(st, self.config, self._geo, bucket, n, self.cache)
         st.written += n
         if not last:
             self._chunk_retrace_s += self._retrace_mark("prefill.dispatch")

@@ -19,6 +19,19 @@ eviction and preemption, chunked prefill): per request its tokens, finish
 reason and preemptions, and per step the physical pages of every slot.
 The module is imported from the working directory, so the same file reads
 either tree.
+
+`--v5e NAME ...` instead: has a change to a MODEL's or a cache kind's code
+left another configuration's device programs alone? sha256 of `engine_decode`
+and `engine_paged_prefill` (T = 1024) of each named file of bench/configs/,
+at its own depth, slots and pool, lowered for a DESCRIBED v5e (no chip; one
+to three minutes a configuration), so the kernels take their Pallas route and
+the text holds their names, grids and operands' shapes. A kernel's BODY is
+left out of the hash: the serialized Mosaic module carries source locations,
+which move with any line added above a call; `git diff` on `ops/pallas/` says
+whether a body changed. `--dump DIR` keeps the texts, for `diff`.
+
+    python scripts/engine_parity.py --v5e granite-4.0-h-small-int4 \\
+        jamba2-3b-int4 qwen2-7b-int4 glm-4.7-flash-int4 > /tmp/change.json
 """
 
 from __future__ import annotations
@@ -88,6 +101,76 @@ def programs() -> dict:
     return out
 
 
+def described(names, dump=None) -> dict:
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from bench import cells, weights
+    from bigdl_tpu.api import TpuModel
+    from bigdl_tpu.models.config import ModelConfig
+    from bigdl_tpu.models.llama import prepare_kernel_scales
+    from bigdl_tpu.serving.engine import InferenceEngine
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    jax.default_backend = lambda: "tpu"  # the target, not where this runs
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: arr(s.shape, s.dtype), tree)
+
+    def sha(name, lowered):
+        text = lowered.as_text()
+        if dump:
+            with open(os.path.join(dump, name + ".txt"), "w") as f:
+                f.write(text)
+        text = re.sub(r'body\\22: \\22[^\\]*\\22', "body", text)
+        return hashlib.sha256(text.encode()).hexdigest()
+
+    f32, i32, out = jnp.float32, jnp.int32, {}
+    for name in names:
+        config = cells.load_json(os.getcwd(), "bench", "configs",
+                                 name + ".json")
+        cfg = ModelConfig.from_hf_config(cells.as_run(config))
+        e, qtype = config["bench"]["engine"], config["bench"]["qtype"]
+        B, V = e["n_slots"], cfg.vocab_size
+        params = on_chip(jax.eval_shape(
+            lambda p: prepare_kernel_scales(cfg, p),
+            weights.param_shapes(cfg, qtype)))
+        # built small (nothing is allocated at the cell's size), then told
+        # the cell's slots and pool
+        eng = InferenceEngine(TpuModel(cfg, None, qtype), n_slots=1,
+                              max_len=e["max_len"], paged=True,
+                              page_size=e["page_size"], n_pages=2)
+        eng.n_slots = B
+        eng.page_size, eng.n_pages = eng.kind.page_geometry(
+            B, e["max_len"], e["page_size"], e["n_pages"])
+        pool = on_chip(jax.eval_shape(eng._make_pool))
+        table = arr((1, eng.max_pages_per_row), i32)
+        out[name] = {
+            "engine_decode": sha(name + ".engine_decode", eng._decode.lower(
+                params, arr((B,), i32), pool, arr((2,), jnp.uint32),
+                arr((B,), f32), arr((B,), i32), arr((B,), f32),
+                arr((B,), jnp.bool_), arr((B, V), jnp.bool_),
+                arr((B,), f32), lora=None)),
+            "engine_paged_prefill T=1024": sha(
+                name + ".engine_paged_prefill", eng._paged_prefill.lower(
+                    params, eng.kind.leaves(pool), (table, table),
+                    arr((1,), i32), arr((1, 1024), i32), arr((), i32),
+                    arr((1,), i32), lora=None)),
+        }
+        print(name, "lowered", file=sys.stderr, flush=True)
+    return out
+
+
 def _slot_pages(eng) -> list:
     # a tree from before PR 29 keeps the lists on the engine itself
     table = getattr(eng, "pages", None)
@@ -144,6 +227,13 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
-    json.dump({"programs": programs(), "run": run()}, sys.stdout,
+    import argparse
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--v5e", nargs="+", metavar="NAME")
+    ap.add_argument("--dump", metavar="DIR")
+    args = ap.parse_args()
+    json.dump(described(args.v5e, args.dump) if args.v5e
+              else {"programs": programs(), "run": run()}, sys.stdout,
               indent=1, sort_keys=True)
     print()

@@ -2,7 +2,9 @@
 """`paged_decode_attention` alone on the chip, at the shapes of the three
 configurations that serve from KV pages (pages of 64 tokens, 32 a row, the
 cells' own pools; `--shape jamba2-3b`: ONE KV head, 256 rows of 10 pages of
-256, PR 57): seconds per decode step's worth of calls (one per layer,
+256, PR 57; `--shape lfm2-24b-a2b`: 8 KV heads of 64 as 4 LANE PAIRS of 128,
+64 rows of 80 pages of 64 over 5 layers, PR 61): seconds per decode step's
+worth of calls (one per layer,
 each fed the last one's output so that none overlaps the next, inside one jit
 with the pool carried and donated as the engine's is; host clock over several
 such steps), the bytes the LIVE pages hold, and the kernel's output against
@@ -45,13 +47,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # head of 64 (Llama-3.2-1B's attention), whose pool XLA pads, so that its
 # pages reach the kernel's body through Pallas's pipeline and not the kernel's
 # own DMA. `jamba2-3b` is its cell's pool of ONE KV head (the row loop through
-# `one_head_view` since PR 57, that pipeline before). Both by name only
+# `one_head_view` since PR 57, that pipeline before). `lfm2-24b-a2b` is its
+# cell's pool of 8 KV heads of 64 AS THE KERNEL SEES IT: 4 rows of 128 lanes,
+# two heads side by side (`ops/attention.lane_pairs`), 8 query rows a wide
+# head, the row loop; stored `[.., 8, 64]` it would be `head-64`'s arm. All
+# three by name only
 SHAPES = {
     "mistral-7b": (32, 8, 4, 32, 1025, 128),
     "qwen2-7b": (16, 4, 7, 28, 1025, 128),
     "mixtral-8x7b": (16, 8, 4, 10, 1025, 128),
     "head-64": (16, 8, 4, 16, 513, 64),
     "jamba2-3b": (256, 1, 20, 2, 2561, 128, 256, 10),
+    "lfm2-24b-a2b": (64, 4, 8, 5, 5121, 128, 64, 80),
 }
 
 
@@ -87,6 +94,15 @@ CELL_MIXES = {
                    for _ in range(rows))[i * 37 % 256] for i in range(256)],
     },
 }
+# contexts of a log-normal prompt (median 2048, 1024..4096) plus half an
+# output, in pages of 64: 2493 of 5120 live
+_LFM2_PAGES = (
+    18, 18, 18, 18, 18, 18, 19, 20, 21, 21, 22, 23, 23, 24, 25, 25,
+    26, 26, 27, 28, 28, 29, 29, 30, 31, 31, 32, 33, 33, 34, 34, 35,
+    36, 37, 37, 38, 39, 40, 40, 41, 42, 43, 44, 45, 46, 47, 48, 49,
+    50, 51, 53, 54, 56, 58, 59, 62, 64, 67, 70, 72, 72, 72, 72, 72)
+CELL_MIXES["lfm2-24b-a2b"] = {
+    "manydocs-closed": [_LFM2_PAGES[i * 37 % 64] for i in range(64)]}
 UNIFORM = (0, 1, 3, 8, 20, 28)  # (those a row of the shape can hold)
 
 

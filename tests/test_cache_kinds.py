@@ -294,7 +294,8 @@ def _chunk(name, written: int, bucket: int, n: int) -> _PrefillState:
     st = _PrefillState(req=None, slot=0, row=None, written=written, path=[],
                        chunk=n)
     kind = KINDS[name]
-    kind.note_chunk(st, CONFIGS[name], _geometry(kind), bucket, n)
+    kind.note_chunk(st, CONFIGS[name], _geometry(kind), bucket, n,
+                    _pool(name, fill=False))
     return st
 
 
@@ -324,7 +325,7 @@ def test_a_chunk_adds_what_the_kind_counts(name):
     kind, geo = KINDS[name], _geometry(KINDS[name])
     st = _chunk(name, 0, 32, 32)
     st.written = 32
-    kind.note_chunk(st, CONFIGS[name], geo, 32, 20)
+    kind.note_chunk(st, CONFIGS[name], geo, 32, 20, _pool(name, fill=False))
     got = (st.state_chunks, st.upprojected, st.row_pages, st.pages_written,
            st.window_pages_written)
     cfg = CONFIGS[name]
@@ -394,21 +395,29 @@ def test_a_prefill_gives_back_the_pool_it_was_lent(name):
 
 
 # ---------------------------------------------------------------------------
-# `state_beside_pages` holds TWO state layouts (ISSUE 56): Mamba-2's
-# (granite: `ssm [Lm, R, inner, d_state]`, `conv [Lm, K - 1, R, C]`) and
+# `state_beside_pages` holds THREE state layouts (ISSUE 56, 61): Mamba-2's
+# (granite: `ssm [Lm, R, inner, d_state]`, `conv [Lm, K - 1, R, C]`),
 # Mamba-1's (jamba: `ssm [Lm, R, d_state, E]`, `conv [Lm, R, (K - 1) * E]`)
+# and a short convolution's (lfm2_moe: NO `ssm`, `conv [Lc, R, (K - 1) * H]`:
+# the tail is all the state), with nothing in the kind that asks which
 # ---------------------------------------------------------------------------
 
 _LAYOUTS = {
-    # preset: (ssm after the row axis, conv's shape with R its rows, the
-    # axis of conv that is the row, the prefill span's argument)
+    # preset: (ssm after the row axis or None, conv's shape with R its
+    # rows, the axis of conv that is the row, the prefill span's argument,
+    # the name of the state layers in `layer_types`, the K - 1 inputs a tail
+    # holds)
     "tiny-granite-hybrid": (
         lambda c: (c.mamba_n_heads * c.mamba_d_head, c.mamba_d_state),
         lambda c, R: (3, R, c.mamba_n_heads * c.mamba_d_head
-                      + 2 * c.mamba_d_state), 2, "state_chunks"),
+                      + 2 * c.mamba_d_state), 2, "state_chunks", "mamba", 3),
     "tiny-jamba": (
         lambda c: (c.mamba_d_state, 2 * c.hidden_size),
-        lambda c, R: (R, 3 * 2 * c.hidden_size), 1, "scan_tokens"),
+        lambda c, R: (R, 3 * 2 * c.hidden_size), 1, "scan_tokens", "mamba",
+        3),
+    "tiny-lfm2-moe": (
+        lambda c: None,
+        lambda c, R: (R, 2 * c.hidden_size), 1, None, "conv", 2),
 }
 
 
@@ -417,6 +426,7 @@ def _layout_pool(preset):
     pool = kind.make_pool(cfg, _geometry(kind))
     rng = np.random.default_rng(1)
     return cfg, kind.with_leaves(pool, tuple(
+        None if a is None else
         jnp.asarray(rng.standard_normal(a.shape), a.dtype)
         for a in kind.leaves(pool)))
 
@@ -427,19 +437,22 @@ def test_state_beside_pages_is_both_families_kind(preset):
     model = types.SimpleNamespace(config=cfg,
                                   family=get_family(cfg.model_type))
     assert _cache_kind(model) is kvhybrid.CACHE_KIND
-    state, conv, rows_axis, _ = _LAYOUTS[preset]
+    state, conv, rows_axis, _, layers, taps = _LAYOUTS[preset]
     cfg, pool = _layout_pool(preset)
-    Lm = sum(k == "mamba" for k in cfg.layer_types)
-    assert pool.ssm.shape == (Lm, N_SLOTS) + state(cfg)
+    Lm = sum(k == layers for k in cfg.layer_types)
+    if state(cfg) is None:  # the tail is all the state
+        assert pool.ssm is None
+    else:
+        assert pool.ssm.shape == (Lm, N_SLOTS) + state(cfg)
     assert pool.conv.shape == (Lm,) + conv(cfg, N_SLOTS)
     assert pool.n_rows == N_SLOTS and pool.conv_rows == rows_axis
     assert pool.k.shape[0] == len(cfg.layer_types) - Lm
-    # a row's bytes: the state and the K - 1 = 3 inputs of the convolution
-    # over its channels, float32, every Mamba layer
+    # a row's bytes: the state (where there is one) and the K - 1 inputs of
+    # the convolution over its channels, float32, every state layer
     kind = kvhybrid.CACHE_KIND
-    channels = int(np.prod(conv(cfg, 1))) // 3
+    channels = int(np.prod(conv(cfg, 1))) // taps
     assert kind.state_row_nbytes(pool) == kvhybrid.row_nbytes(pool) == (
-        Lm * (int(np.prod(state(cfg))) + 3 * channels) * 4)
+        Lm * (int(np.prod(state(cfg) or (0,))) + taps * channels) * 4)
     assert kind.axes_of(pool) == (1, 1, rows_axis, 1)
     assert kind._spots("pages", "slot", None) == (
         "pages", "pages", "slot", "slot")
@@ -449,12 +462,20 @@ def test_state_beside_pages_is_both_families_kind(preset):
 def test_both_layouts_park_and_restore_a_row_bit_for_bit(preset):
     kind = kvhybrid.CACHE_KIND
     cfg, pool = _layout_pool(preset)
-    Lm = pool.ssm.shape[0]
+    Lm = pool.conv.shape[0]
     blob = kind.swap_out(pool, [1, 2], 1, [])
-    assert blob.ssm.shape == (Lm,) + pool.ssm.shape[2:]
     assert blob.conv.size == pool.conv.size // N_SLOTS
-    assert blob.ssm.tobytes() == np.asarray(pool.ssm[:, 1]).tobytes()
-    assert blob.ssm.nbytes + blob.conv.nbytes == kind.state_row_nbytes(pool)
+    assert blob.conv.tobytes() == np.asarray(
+        pool.conv[:, 1] if pool.conv_rows == 1
+        else pool.conv[:, :, 1]).tobytes()
+    if pool.ssm is None:  # nothing is parked where nothing is kept
+        assert blob.ssm is None
+        assert blob.conv.nbytes == kind.state_row_nbytes(pool)
+    else:
+        assert blob.ssm.shape == (Lm,) + pool.ssm.shape[2:]
+        assert blob.ssm.tobytes() == np.asarray(pool.ssm[:, 1]).tobytes()
+        assert blob.ssm.nbytes + blob.conv.nbytes \
+            == kind.state_row_nbytes(pool)
     empty = kind.with_leaves(pool, jax.tree.map(jnp.zeros_like,
                                                 kind.leaves(pool)))
     into = (jnp.asarray([4, 3], jnp.int32), jnp.asarray(2),
@@ -463,9 +484,13 @@ def test_both_layouts_park_and_restore_a_row_bit_for_bit(preset):
     assert there.conv_rows == pool.conv_rows
     back = kind.leaves(kind.swap_out(there, [4, 3], 2, []))
     for a, b in zip(kind.leaves(blob), back):
-        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+        assert (a is None and b is None) or (
+            a.dtype == b.dtype and a.tobytes() == b.tobytes())
     # the other rows of the pool it was written into stay zeros
-    assert not np.asarray(there.ssm[:, [0, 1, 3]]).any()
+    rows = np.moveaxis(np.asarray(there.conv), there.conv_rows, 1)
+    assert not rows[:, [0, 1, 3]].any() and rows[:, 2].any()
+    if there.ssm is not None:
+        assert not np.asarray(there.ssm[:, [0, 1, 3]]).any()
 
 
 @pytest.mark.parametrize("preset", sorted(_LAYOUTS))
@@ -474,12 +499,16 @@ def test_both_layouts_count_a_prefill_their_own_way(preset):
     cfg, want = PRESETS[preset], _LAYOUTS[preset][3]
     st = _PrefillState(req=None, slot=0, row=None, written=0, path=[],
                        chunk=30)
-    kind.note_chunk(st, cfg, _geometry(kind), 32, 30)
+    pool = kind.make_pool(cfg, _geometry(kind))  # the family's `counts`
+    kind.note_chunk(st, cfg, _geometry(kind), 32, 30, pool)
     st.written = 30
-    kind.note_chunk(st, cfg, _geometry(kind), 16, 9)
+    kind.note_chunk(st, cfg, _geometry(kind), 16, 9, pool)
     args = kind.prefill_args(st)
+    if want is None:  # a tail is no prefill form: nothing to count
+        assert args == {}
+        return
     assert list(args) == [want]
-    assert args[want] == (39 if want == "scan_tokens" else sum(
+    assert args[want] == (39 if want.endswith("_tokens") else sum(
         kvhybrid.prefill_chunks(b, cfg.mamba_chunk_size) for b in (32, 16)))
 
 

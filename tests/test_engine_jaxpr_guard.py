@@ -37,6 +37,12 @@ PR 56 adds a tiny Jamba (models/jamba.py, loaded on first use: Mamba-1 layers
 on `state_beside_pages`' second state layout, `kvhybrid.mix1`): a family and
 a mixer of its own beside granite's, whose programs, and everyone else's,
 `scripts/engine_parity.py` found the parent's to the byte.
+PR 61 adds a tiny LFM2-MoE (models/lfm2_moe.py, loaded on first use: gated
+short-convolution layers on `state_beside_pages` with NO recurrence state,
+`kvhybrid.tail_conv`; KV heads of 64 as lane pairs): a third family on the
+kind. Granite's, jamba's, qwen2's and GLM's programs lowered for a described
+v5e are the parent's to the byte, the kernels' own bodies aside (they carry
+source locations; `scripts/engine_parity.py --v5e`): PERF.md section 6, PR 61.
 
     JAX_PLATFORMS=cpu python tests/test_engine_jaxpr_guard.py
 """
@@ -113,6 +119,17 @@ MODELS = {
         num_key_value_heads=1, attn_layer_period=4, attn_layer_offset=2,
         num_experts=1, mamba_expand=2, mamba_d_state=16, mamba_d_conv=4,
         mamba_dt_rank=8, rms_norm_eps=1e-6, tie_word_embeddings=True),
+    "lfm2_moe": dict(
+        model_type="lfm2_moe", vocab_size=512, hidden_size=512,
+        intermediate_size=128, num_hidden_layers=6, num_attention_heads=8,
+        num_key_value_heads=4, conv_L_cache=3, conv_bias=False,
+        norm_eps=1e-5, num_dense_layers=1, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=128,
+        norm_topk_prob=True, routed_scaling_factor=1.0,
+        use_expert_bias=True, max_position_embeddings=2048,
+        layer_types=["conv", "full_attention", "conv", "conv",
+                     "full_attention", "conv"],
+        rope_parameters={"rope_theta": 1000000.0, "rope_type": "default"}),
 }
 
 # (engine_decode, engine_paged_prefill) on the parent of PR 38; the paged
@@ -134,6 +151,9 @@ PINNED = {
     # PR 56's own: a two-layer scan of Mamba-1 layers, the attention layer,
     # one more Mamba-1 layer; the `jnp` scan on this route
     "jamba": (1135, 1031),
+    # PR 61's own: a dense convolution layer, an attention layer on lane
+    # pairs, a two-layer scan of sparse convolution layers, one more of each
+    "lfm2_moe": (1621, 1534),
 }
 
 
@@ -410,6 +430,7 @@ MOE_KERNEL_ROUTE = {
     "laguna": 2937,  # 3224, 2916
     "sdar_moe": 549,  # 593, 546
     "granitemoehybrid": 1257,  # 1380, 1248
+    "lfm2_moe": 1624,  # PR 61's own
 }
 
 

@@ -1215,3 +1215,171 @@ def test_flash_attention_compiles_at_jambas_group_of_20(one_chip, T):
         _sds((1,), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip)).compile().as_text()
     assert "flash_attention" in text
+
+
+# ---------------------------------------------------------------------------
+# LFM2-24B-A2B (ISSUE 61): KV heads of 64 as lane pairs, a tail-only state
+# ---------------------------------------------------------------------------
+
+def test_paged_decode_kernel_compiles_at_lfm2s_lane_pairs(one_chip):
+    """64 slots x 80 pages of 64 tokens, 32 query heads on 8 KV heads of 64
+    kept two to a row of 128 lanes, a pool of 5121 pages over 5 layers. As
+    published, `[.., 8, 64]`, the tiles are padded and the kernel takes its
+    `piped` arm; as pairs, `[.., 4, 128]`, they are whole (Qwen2's shape),
+    the row loop's own DMA takes a live page, grid (64,), and the queries
+    are padded instead of the pool: no copy of a 1.56 GiB pool, and next to
+    nothing kept beside the arguments."""
+    from bigdl_tpu.ops import routes
+    from bigdl_tpu.ops.attention import (lane_pairs, pair_queries,
+                                         unpair_context)
+    from bigdl_tpu.ops.pallas import paged_attention as pa
+
+    B, Hq, Hkv, D, L, NP, page, mp = 64, 32, 8, 64, 5, 5121, 64, 80
+    assert not pa.pool_tiles_whole(Hkv, D, 2) and lane_pairs(Hkv, D)
+    assert pa.pool_tiles_whole(Hkv // 2, 2 * D, 2)
+    assert pa.group_pages(page, Hkv // 2, 2 * D, 2, mp) == 4
+    kv = _sds((L, NP, page, Hkv // 2, 2 * D), jnp.bfloat16, one_chip)
+
+    def f(q, k, v, bt, layer, pos, start, live):
+        out = pa.paged_decode_attention(
+            pair_queries(q, Hkv), k, v, bt, layer, pos, start,
+            scale=D ** -0.5, live=live, interpret=False)
+        return unpair_context(out, Hkv)
+
+    with routes.record_routes() as seen:
+        c = jax.jit(f).lower(
+            _sds((B, Hq, D), jnp.bfloat16, one_chip), kv, kv,
+            _sds((B, mp), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+            _sds((B,), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+            _sds((B,), jnp.bool_, one_chip)).compile()
+    assert {(op, route) for op, route, _ in seen} == {("paged", "rows")}
+    text = c.as_text()
+    assert "paged_decode_attention" in text
+    assert not _made_by_copy(text, kv.shape)
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 21
+
+
+@pytest.mark.parametrize("T", [1024, 4096])
+def test_flash_attention_on_lane_pairs_reads_k_and_v_unpadded(
+        one_chip, T):
+    """A prefill's attention from a row's gathered pages `[1, S, 4, 128]`
+    (the pool's layout): with the queries padded to the pairs (4 KV heads of
+    128, a group of 8) the kernel reads K and V as they lie; as 8 heads of 64
+    it pads every head to 128 lanes first (`flash_attention` pads D to whole
+    lane tiles), a re-laid copy of K and of V at twice their bytes. Both
+    compile; the pairs make no padded copy, and are what
+    `models/lfm2_moe.py` runs."""
+    from bigdl_tpu.ops.attention import pair_queries, unpair_context
+    from bigdl_tpu.ops.pallas.flash_attention import flash_attention
+
+    S, Hq, Hkv, D = 5120, 32, 8, 64
+
+    def as_pairs(q, k, v, start, q_offset):
+        return unpair_context(flash_attention(
+            pair_queries(q, Hkv), k, v, start=start, q_offset=q_offset,
+            scale=D ** -0.5, interpret=False), Hkv)
+
+    def as_heads(q, k, v, start, q_offset):
+        return flash_attention(
+            q, k.reshape(1, S, Hkv, D), v.reshape(1, S, Hkv, D), start=start,
+            q_offset=q_offset, scale=D ** -0.5, interpret=False)
+
+    args = (_sds((1, T, Hq, D), jnp.bfloat16, one_chip),
+            _sds((1, S, Hkv // 2, 2 * D), jnp.bfloat16, one_chip),
+            _sds((1, S, Hkv // 2, 2 * D), jnp.bfloat16, one_chip),
+            _sds((1,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip))
+    made = {}
+    for form in (as_pairs, as_heads):
+        text = jax.jit(form).lower(*args).compile().as_text()
+        assert "flash_attention" in text
+        made[form.__name__] = text
+    # what the kernel is handed as K and V: the row's pages transposed,
+    # `[1, 4, S, 128]`, or every head of 64 padded to a tile, twice that
+    assert "bf16[1,4,5120,128]" in made["as_pairs"]
+    assert "bf16[1,8,5120,128]" not in made["as_pairs"]
+    assert "bf16[1,8,5120,128]" in made["as_heads"]
+
+
+@pytest.mark.parametrize("program,T", [
+    ("engine_decode", 1), ("engine_paged_prefill", 1024),
+    ("engine_paged_prefill", 4096)])
+def test_lfm2s_engine_programs_keep_the_pool_whole_and_unpadded(
+        one_chip, monkeypatch, program, T):
+    """`engine_decode` at 64 rows and `engine_paged_prefill` at T = 1024 and
+    4096 of the cell's own pool, the first three runs of layers (2 dense
+    convolution layers, 1 attention, 3 sparse convolution layers), compiled
+    for the chip. The three holds of ISSUE 61: the paged route is `rows`; no
+    copy of a whole K or V pool (nor of the tails); and the pool's bytes as
+    compiled are its shape's (a pool of `[.., 8, 64]` would be padded to
+    twice that: the arguments would outgrow their shapes by a pool)."""
+    import os
+
+    from bench import cells, weights
+    from bigdl_tpu.api import TpuModel
+    from bigdl_tpu.models.config import ModelConfig
+    from bigdl_tpu.models.llama import prepare_kernel_scales
+    from bigdl_tpu.ops import routes
+    from bigdl_tpu.serving.engine import InferenceEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = cells.load_json(root, "bench", "configs",
+                             "lfm2-24b-a2b-int4.json")
+    hf = cells.as_run(config)
+    cfg = ModelConfig.from_hf_config(dict(
+        hf, num_hidden_layers=6, layer_types=hf["layer_types"][:6]))
+    e, qtype = config["bench"]["engine"], config["bench"]["qtype"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the target
+    B = e["n_slots"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda p: prepare_kernel_scales(cfg, p),
+        weights.param_shapes(cfg, qtype)))
+    eng = InferenceEngine(TpuModel(cfg, None, qtype), n_slots=1,
+                          max_len=e["max_len"], paged=True,
+                          page_size=e["page_size"], n_pages=2)
+    eng.n_slots, eng.n_pages = B, e["n_pages"]
+    pool = on_chip(jax.eval_shape(eng._make_pool))
+    assert pool.ssm is None and pool.conv.shape == (5, B, 2 * 2048)
+    assert pool.k.shape == (1, e["n_pages"], 64, 4, 128)  # lane pairs
+    with routes.record_routes() as seen:
+        if program == "engine_decode":
+            args = (params, _sds((B,), jnp.int32, one_chip), pool,
+                    _sds((2,), jnp.uint32, one_chip),
+                    _sds((B,), jnp.float32, one_chip),
+                    _sds((B,), jnp.int32, one_chip),
+                    _sds((B,), jnp.float32, one_chip),
+                    _sds((B,), jnp.bool_, one_chip),
+                    _sds((B, cfg.vocab_size), jnp.bool_, one_chip),
+                    _sds((B,), jnp.float32, one_chip))
+            c = eng._decode.lower(*args, lora=None).compile()
+        else:
+            table = _sds((1, eng.max_pages_per_row), jnp.int32, one_chip)
+            args = (params, eng.kind.leaves(pool), (table, table),
+                    _sds((1,), jnp.int32, one_chip),
+                    _sds((1, T), jnp.int32, one_chip),
+                    _sds((), jnp.int32, one_chip),
+                    _sds((1,), jnp.int32, one_chip))
+            c = eng._paged_prefill.lower(*args, lora=None).compile()
+    took = {(op, route) for op, route, _ in seen}
+    if program == "engine_decode":
+        assert ("paged", "rows") in took and ("paged", "piped") not in took
+        assert ("attention", "pallas:paged") in took
+    else:
+        assert ("attention", "pallas:flash") in took
+    assert any(op == "moe" and route == "pallas:grouped"
+               for op, route in took)
+    text = c.as_text()
+    assert ("paged_decode_attention" if program == "engine_decode"
+            else "flash_attention") in text
+    assert not _made_by_copy(text, pool.k.shape, pool.conv.shape)
+    m = c.memory_analysis()
+    pool_bytes = 2 * pool.k.size * 2 + pool.conv.size * 4
+    assert m.alias_size_in_bytes >= pool_bytes
+    shaped = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(args))
+    # (arguments nobody reads, the float16 scales beside their prepared
+    # bits, are pruned: the compiled arguments can be fewer, never a pool
+    # more)
+    assert m.argument_size_in_bytes < shaped + pool_bytes // 8
