@@ -59,11 +59,14 @@ route note):
   small (`tiling.GROUPED_STEP_BYTES` of codes: a whole 768-wide expert,
   GLM's too; Mixtral's 2 and 3.5 MiB tiles stay one a step): a grid step
   costs 0.8 to 1.1 us beside the bytes it brings (chip, PR 44), so the
-  body walks the tiles it holds, one product and one store each, in a
-  loop that is traced ONCE and unrolled when it is lowered: straight-line
-  code lets one tile's stores overlap the next one's staging (left
-  rolled it gains nothing over a tile a step), and a body written out in
-  Python cost granite's cell 45 s of warm set-up in tracing;
+  body walks the tiles it holds, one staging, one product and one store
+  each, over two `jit`s of the kernel's refs (`_stage_tile`,
+  `_tile_product`) that are traced ONCE and lowered a tile: straight-line
+  code lets one tile's stores overlap the next one's staging (a rolled
+  loop gains nothing over a tile a step), and a body TRACED a tile in
+  Python cost granite's cell 45 s of warm set-up. (Until PR 63 a
+  `fori_loop` unrolled at lowering, traced once a kernel instance; the
+  `jit`s are traced once a PROCESS for blocks of one shape.)
 * `loop`: the stored-layout loop at 256- or 128-row tiles. Left to an
   UNGATED call at such a width (phixtral's `fc1`, or the two plain calls
   of a gated FFN whose activation is not in `FUSED_ACTS`) and to rows of
@@ -180,69 +183,91 @@ def _tiles(tiles: jax.Array, n_tiles: int):
     return tile_end - tiles, tile_expert, n_used
 
 
+# The kernel's two halves, `jit`s of its refs as `qdecode.stage_tile` and
+# `qdecode.product_of_tile` are: traced once for each set of block shapes,
+# whatever instance calls them (a cell's prefill buckets, its layers' two
+# calls at other O), and lowered in line at every call.
+
+@functools.partial(jax.jit, static_argnames=("spec", "prepared", "rows",
+                                             "paired"))
+def _stage_tile(blocks, sides, scratch, j, *, spec: DecodeSpec,
+                prepared: bool, rows: int, paired: bool):
+    """Word tile `j` of the tiles a step holds (rows `j * rows ..` of each
+    stack's code block, or block `j` of its prepared bits) into the
+    scratch: one staging a stack, or the paired tile's one of both."""
+    if blocks[0].shape[0] == rows:  # the step holds one tile
+        blk, sd = blocks, (
+            [[r[0] for r in side] for side in sides] if prepared else sides)
+    else:
+        at = pl.ds(pl.multiple_of(j * rows, rows), rows)
+        blk = [b.at[at, :] for b in blocks]
+        # (loaded here: a ref view narrower than 128 lanes does not lower)
+        sd = [[r[j] if prepared else r[at, :] for r in side]
+              for side in sides]
+    if paired:
+        qdecode.stage_words(spec, blk, sd, scratch, prepared=prepared)
+        return
+    for i in range(len(blocks)):
+        qdecode.stage_words(spec, (blk[i],), (sd[i],),
+                            scratch[3 * i:3 * i + 3], prepared=prepared)
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "K", "ck", "act", "rows",
+                                             "paired", "dtype"))
+def _tile_product(x_ref, scratch, *, spec: DecodeSpec, K: int, ck: int,
+                  act, rows: int, paired: bool, dtype):
+    """[block_m, rows] of `dtype`: the product of the staged tile (of each
+    stack's, then `act(gate) * up` in float32), its columns put back."""
+    if paired:
+        y = qdecode.natural_columns(qdecode.staged_product(
+            spec, K, ck, x_ref, scratch))
+        y = FUSED_ACTS[act](y[:, :rows]) * y[:, rows:]
+    else:
+        accs = [qdecode.staged_product(spec, K, ck, x_ref,
+                                       scratch[3 * i:3 * i + 3])
+                for i in range(len(scratch) // 3)]
+        y = qdecode.natural_columns(
+            accs[0] if act is None else FUSED_ACTS[act](accs[0]) * accs[1])
+    return y.astype(dtype)
+
+
 def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
             spec: DecodeSpec, n_w: int, act, form: str, rows: int,
             prepared: bool = False):
     """One [block_m, block_o] tile of one expert: `qmatmul._kernel`'s
-    chunk loop (`qdecode.tile_product`) over each of the `n_w` weight
-    stacks, skipped whole when the tile holds no assignment. On the word
-    path three scratch refs per word tile follow the output; the paired
-    form (`tiling.grouped_tile`) has one tile for both stacks. A step
-    that holds several tiles of `rows` rows walks them, one product and
-    one store each, through the same scratch (a `fori_loop` unrolled at
-    lowering: see the module docstring). With ``prepared`` a side ref
-    holds the `qdecode.pack_major_bits` blocks of the step's tiles,
-    `[held, nb, rows]`."""
+    chunk loop over each of the `n_w` weight stacks, skipped whole when
+    the tile holds no assignment. On the word path three scratch refs per
+    word tile follow the output; the paired form (`tiling.grouped_tile`)
+    has one tile for both stacks. A step that holds several tiles of
+    `rows` rows walks them, one staging, one product and one store each,
+    through the same scratch, written out here over `_stage_tile` and
+    `_tile_product`: each is traced once and lowered a tile, so Mosaic
+    sees straight-line code (see the module docstring). With ``prepared``
+    a side ref holds the `qdecode.pack_major_bits` blocks of the step's
+    tiles, `[held, nb, rows]`."""
     del te_ref  # read by the index maps
     per = 1 + spec.n_side
     o_ref = refs[n_w * per]
     scratch = refs[n_w * per + 1:]
     held = o_ref.shape[1] // rows  # tiles this step holds
-
-    def product(blocks, sides):
-        """float32 [block_m, rows]: one word tile (or the loop's tile)."""
-        if form == "words:paired":
-            qdecode.stage_words(spec, blocks, sides, scratch,
-                                prepared=prepared)
-            y = qdecode.natural_columns(qdecode.staged_product(
-                spec, K, ck, x_ref, scratch))
-            return FUSED_ACTS[act](y[:, :rows]) * y[:, rows:]
-        words = form == "words"
-        accs = [
-            qdecode.tile_product(
-                spec, K, ck, x_ref, blocks[i], sides[i],
-                scratch[3 * i:3 * i + 3] if words else None, prepared)
-            for i in range(n_w)
-        ]
-        y = accs[0] if n_w == 1 else FUSED_ACTS[act](accs[0]) * accs[1]
-        return qdecode.natural_columns(y) if words else y
+    blocks = [refs[i * per] for i in range(n_w)]
+    sides = [refs[i * per + 1:(i + 1) * per] for i in range(n_w)]
 
     @pl.when(pl.program_id(0) < meta_ref[0])
     def _live_tile():
-        blocks = [refs[i * per] for i in range(n_w)]
-        sides = [refs[i * per + 1:(i + 1) * per] for i in range(n_w)]
-        if held == 1:
-            if prepared:
-                sides = [[r[0] for r in side] for side in sides]
-            o_ref[:] = product(blocks, sides).astype(o_ref.dtype)
+        if form == "loop":
+            accs = [qdecode.tile_product(spec, K, ck, x_ref, blocks[i],
+                                         sides[i]) for i in range(n_w)]
+            o_ref[:] = (accs[0] if n_w == 1 else FUSED_ACTS[act](accs[0])
+                        * accs[1]).astype(o_ref.dtype)
             return
-
-        def tile(j, carry):
-            at = pl.ds(pl.multiple_of(j * rows, rows), rows)
-            y = product([b.at[at, :] for b in blocks],
-                        # (loaded here: a ref view narrower than 128
-                        # lanes does not lower)
-                        [[r[j] if prepared else r[at, :] for r in side]
-                         for side in sides]).astype(o_ref.dtype)
-            # (a store takes no dynamic lane offset; unrolled, `j` is a
-            # constant and the branches fold away)
-            for t in range(held):
-                @pl.when(j == t)
-                def _store():
-                    o_ref[:, t * rows:(t + 1) * rows] = y
-            return carry
-
-        jax.lax.fori_loop(0, held, tile, 0, unroll=held)
+        paired = form == "words:paired"
+        for j in range(held):
+            _stage_tile(blocks, sides, scratch, j, spec=spec,
+                        prepared=prepared, rows=rows, paired=paired)
+            o_ref[:, j * rows:(j + 1) * rows] = _tile_product(
+                x_ref, scratch, spec=spec, K=K, ck=ck, act=act, rows=rows,
+                paired=paired, dtype=o_ref.dtype)
 
 
 @functools.partial(

@@ -4,11 +4,11 @@ One implementation of the per-format bit decode, consumed by BOTH the
 fused dequant-GEMV and the tiled dequant-GEMM kernels in
 `ops/pallas/qmatmul.py` (and, later, by flash-attention epilogues) — the
 format decode lives here exactly once, the matmul kernels are tiling +
-epilogue. `tile_product` is the forward kernels' chunk loop; it decodes
-a tile either in the stored [o, k] layout (`decode_chunk`, which
+epilogue. The forward kernels' chunk loop decodes a tile either in the
+stored [o, k] layout (`tile_product` over `decode_chunk`, which
 `qbackward` also calls) or, where the tile's shape allows, as 32-bit
-words transposed once per grid step (`decode_chunk_words`, bottom of
-this file), the same values bit for bit.
+words transposed once a tile (`stage_words`, then `staged_product` over
+`decode_chunk_words`, bottom of this file), the same values bit for bit.
 
 A format is described by a static, hashable `DecodeSpec`:
 
@@ -44,6 +44,7 @@ measurement history):
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -110,17 +111,27 @@ def f16_bits_to_f32(bits):
     f16 vectors). Subnormal f16 decodes exactly as sign * mant * 2^-24 —
     NOT flushed: k-quant super-scales d = max|sub_scale|/127 routinely
     land below 6.1e-5 for real checkpoint magnitudes (caught by the q6_k
-    kernel equivalence test: flushing zeroed whole super-blocks)."""
+    kernel equivalence test: flushing zeroed whole super-blocks).
+
+    14 operations a vreg (20 until PR 63; a word tile's scales are a
+    fifth to a third of what staging it costs, and staging is 17% of the
+    kernel: PERF.md section 6, PR 63): the magnitude's 15 bits shifted into
+    place with the exponent's rebias ADDED to them (`+ (112 << 23)`: no
+    field is cut out), the sign masked and shifted once for both arms, and
+    a subnormal's mantissa converted and scaled with that sign set in its
+    bits. Every finite pattern is exact (`tests/test_qdecode_words.py`
+    walks all 65536; the chip's own arithmetic, which flushes float32
+    subnormals, read 0 wrong of them too: the subnormal arm never holds
+    one, mant * 2^-24 >= 2^-24)."""
     b = bits.astype(jnp.int32)
-    sign = (b >> 15) & 1
-    exp = (b >> 10) & 0x1F
-    mant = b & 0x3FF
-    f32_bits = (sign << 31) | ((exp + 127 - 15) << 23) | (mant << 13)
-    val = jax.lax.bitcast_convert_type(f32_bits, jnp.float32)
-    sub = (1.0 - 2.0 * sign.astype(jnp.float32)) * (
-        mant.astype(jnp.float32) * jnp.float32(2.0 ** -24)
-    )
-    return jnp.where(exp == 0, sub, val)
+    mag = b & 0x7FFF
+    sign = (b & 0x8000) << 16
+    val = jax.lax.bitcast_convert_type(
+        sign | ((mag << 13) + ((127 - 15) << 23)), jnp.float32)
+    sub = (b & 0x3FF).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    sub = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(sub, jnp.int32) | sign, jnp.float32)
+    return jnp.where(mag < 0x400, sub, val)  # (exponent field 0)
 
 
 def fp8_bits_to_f32(b, exp_bits: int, mant_bits: int, bias: int):
@@ -392,7 +403,7 @@ def decode_chunk(spec: DecodeSpec, K: int, w, side, e0: int, c: int):
 # row_bytes] words, an eighth of the vregs the decoded float32 tile has.
 # Pack p (bits 8p .. 8p+7 of a word) then decodes to rows 4i + p of the
 # tile with no uint8 -> int32 widening at all, so the result's columns come
-# out pack-major: `tile_product` returns them so, and the kernels put them
+# out pack-major: `staged_product` returns them so, and the kernels put them
 # back once per tile before the store (`natural_columns`).
 #
 # What bounds the chunk loop now is VALU issue (the same script, PR 49: the
@@ -511,8 +522,8 @@ def _top_bits(bits: int):
 
 def stage_words(spec: DecodeSpec, w_refs, side_refs, scratch,
                 piece: int = 2048, prepared: bool = False):
-    """Once per grid step: the tile's words and its effective scales,
-    transposed into `scratch` (see `word_scratch`), the words with their
+    """Once a word tile: its words and its effective scales, transposed
+    into `scratch` (see `word_scratch`), the words with their
     fields' top bits flipped where a plane's fields are cut out signed
     (`signed_field`: one operation a WORD vreg, an eighth of one a decoded
     vreg, before the transpose). `w_refs` holds the
@@ -614,45 +625,40 @@ def decode_chunk_words(spec: DecodeSpec, K: int, wT_ref, sT_ref, seg: int,
     return w.astype(jnp.bfloat16)
 
 
-def tile_product(spec: DecodeSpec, K: int, ck: int, x_ref, w_ref, side_refs,
-                 scratch=None, prepared: bool = False):
-    """float32 [block_m, block_o] = x @ dq(W tile)^T, the chunk loop both
-    forward kernels run: chunks of the logical contraction axis, each
-    decoded to bf16 and fed to the MXU, so live dequant temporaries stay
-    O(block_o * ck) whatever K is.
-
-    Without `scratch` the loop is statically unrolled in the stored
-    layout. With it (the word path, `words_ok`) the columns come out
-    pack-major (see `natural_columns`), and the chunks of one segment of
-    the finest plane split are ONE `fori_loop` body wherever `ck` divides
-    the segment into chunks whose scale rows start on a sublane tile
-    (`tiling.words_chunk`), unrolled when it is LOWERED
-    (`unroll=True`): Python traces the body once per segment whatever K
-    is, which is what keeps a program's set-up (an end-to-end metric:
-    every program is traced anew in every process) near the old loop's,
-    and Mosaic still sees straight-line code. Left rolled, the loop read
-    33% slower on the chip (38.4 -> 51.5 us at 4096 -> 6144, PR 32): one
-    chunk's decode does not overlap the next one's product across a
-    loop's back edge."""
-    if scratch is None:
-        bm, bo = x_ref.shape[0], w_ref.shape[0]
-        x = x_ref[:].astype(jnp.bfloat16)
-        side = load_side(spec, side_refs)
-        w = w_ref[:]  # packed codes [block_o, row_bytes]
-        acc = jnp.zeros((bm, bo), jnp.float32)
-        for e0, c in walk(K, spec.planes, ck):
-            wd = decode_chunk(spec, K, w, side, e0, c)  # bf16 [bo, c]
-            acc += jax.lax.dot_general(
-                slc(x, e0, c), wd, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        return acc
-    stage_words(spec, (w_ref,), (side_refs,), scratch, prepared=prepared)
-    return staged_product(spec, K, ck, x_ref, scratch)
+def tile_product(spec: DecodeSpec, K: int, ck: int, x_ref, w_ref, side_refs):
+    """float32 [block_m, block_o] = x @ dq(W tile)^T in the stored layout,
+    the chunk loop of every forward tile the word path does not take
+    (`staged_product` is the word path's): chunks of the logical
+    contraction axis, statically unrolled, each decoded to bf16 and fed to
+    the MXU, so live dequant temporaries stay O(block_o * ck) whatever K
+    is."""
+    bm, bo = x_ref.shape[0], w_ref.shape[0]
+    x = x_ref[:].astype(jnp.bfloat16)
+    side = load_side(spec, side_refs)
+    w = w_ref[:]  # packed codes [block_o, row_bytes]
+    acc = jnp.zeros((bm, bo), jnp.float32)
+    for e0, c in walk(K, spec.planes, ck):
+        wd = decode_chunk(spec, K, w, side, e0, c)  # bf16 [bo, c]
+        acc += jax.lax.dot_general(
+            slc(x, e0, c), wd, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    return acc
 
 
 def staged_product(spec: DecodeSpec, K: int, ck: int, x_ref, scratch):
-    """`tile_product`'s chunk loop over the word tile `stage_words` left
-    in `scratch`: float32 [block_m, 512], columns pack-major."""
+    """float32 [block_m, 512] = x @ dq(W tile)^T over the word tile
+    `stage_words` left in `scratch`, columns pack-major
+    (see `natural_columns`): the word path's chunk loop. The chunks of one
+    segment of the finest plane split are ONE `fori_loop` body wherever
+    `ck` divides the segment into chunks whose scale rows start on a
+    sublane tile (`tiling.words_chunk`), unrolled when it is LOWERED
+    (`unroll=True`): Python traces the body once per segment whatever K
+    is, which is what keeps a program's set-up (an end-to-end metric:
+    every program is traced anew in every process) near the stored-layout
+    loop's, and Mosaic still sees straight-line code. Left rolled, the
+    loop read 33% slower on the chip (38.4 -> 51.5 us at 4096 -> 6144, PR
+    32): one chunk's decode does not overlap the next one's product across
+    a loop's back edge."""
     wT_ref, _, sT_ref = scratch
     qmin = finest_split(K, spec.planes)
 
@@ -679,6 +685,29 @@ def staged_product(spec: DecodeSpec, K: int, ck: int, x_ref, scratch):
     return acc
 
 
+# The two halves as `jit`s of the kernel's refs. A kernel's body is traced
+# anew for every `pallas_call` instance, and a process traces dozens whose
+# blocks have the same shapes: a cell's prefill buckets share a row tile,
+# and the four K = 4096 projections of a layer differ in O alone, which no
+# block shows. A module-level `jit` is traced ONCE for each set of block
+# shapes (and `spec`, K, chunk), whatever kernel instance calls it, and is
+# lowered in line where it is called: chat-steady's 45 `_qmm` instances
+# trace the chunk loop 8 times and the staging twice (PERF.md section 6,
+# PR 63). `natural_columns` is one for the same reason.
+
+@functools.partial(jax.jit, static_argnames=("spec", "prepared"))
+def stage_tile(w_refs, side_refs, scratch, *, spec: DecodeSpec,
+               prepared: bool = False):
+    """`stage_words`, traced once for blocks of these shapes."""
+    stage_words(spec, w_refs, side_refs, scratch, prepared=prepared)
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "K", "ck"))
+def product_of_tile(x_ref, scratch, *, spec: DecodeSpec, K: int, ck: int):
+    """`staged_product`, traced once for blocks of these shapes."""
+    return staged_product(spec, K, ck, x_ref, scratch)
+
+
 # out[r, l] = x[r, idx[r, l, 0]] on one 128-lane group: the gather
 # `jnp.take_along_axis(x, idx, axis=1)` traces to (Mosaic's `dynamic_gather`)
 # without the dozen equations of index bookkeeping around it
@@ -687,6 +716,7 @@ _LANE_GATHER = jax.lax.GatherDimensionNumbers(
     operand_batching_dims=(0,), start_indices_batching_dims=(0,))
 
 
+@jax.jit
 def natural_columns(y):
     """[block_m, 512] with pack-major columns (column 128 p + i holds row
     4i + p of the tile) -> natural order, once per tile before the store:

@@ -24,7 +24,7 @@ decoder for GEMV, GEMM and, later, flash epilogues — a format is a
 static `DecodeSpec`); tile/chunk policy lives in `ops/pallas/tiling.py`
 (pure Python, shared with `benchmark/roofline.py`'s analytic cost
 model). This module is tiling + epilogue: grid over (M tiles, O tiles),
-and `qdecode.tile_product`'s chunk loop over K, which bounds live
+and `qdecode`'s chunk loop over K, which bounds live
 dequant temporaries to O(block_o * chunk) regardless of K. Where a 512-row tile fits, that loop runs on the tile read as
 32-bit words and transposed once (k on sublanes, a block's scale a
 sublane broadcast, docs/kernels.md#word-path), the last tile ragged where
@@ -95,9 +95,12 @@ def _f16_bits(a: jax.Array) -> jax.Array:
 def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
             spec: DecodeSpec, lora: bool = False, words: bool = False,
             prepared: bool = False):
-    """One [block_m, block_o] output tile: `qdecode.tile_product`'s chunk
-    loop, acc += x_chunk @ dq(W_chunk)^T over chunks of the logical
-    contraction axis. `layer_ref` is read by the weight's
+    """One [block_m, block_o] output tile: acc += x_chunk @ dq(W_chunk)^T
+    over chunks of the logical contraction axis (`qdecode.tile_product` in
+    the stored layout; on the word path `qdecode.stage_tile`, then
+    `qdecode.product_of_tile`: each a `jit` of the kernel's refs, traced
+    once for blocks of these shapes whatever instance calls it).
+    `layer_ref` is read by the weight's
     index map alone: the tile arrives as `[block_o, row_bytes]` whichever
     layer of the stack it came from. With ``words`` the last three refs
     are the word path's scratch.
@@ -112,19 +115,21 @@ def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
     adapter group's rank-bucket columns and 0 elsewhere, which is how
     one dot pair serves a heterogeneous multi-tenant batch."""
     del layer_ref
-    scratch = None
     if words:
-        rest, scratch = rest[:-3], rest[-3:]
+        *side_refs, o_ref = rest[:-3]
+        scratch = tuple(rest[-3:])
+        qdecode.stage_tile((w_ref,), (tuple(side_refs),), scratch, spec=spec,
+                           prepared=prepared)
+        o_ref[:] = qdecode.natural_columns(qdecode.product_of_tile(
+            x_ref, scratch, spec=spec, K=K, ck=ck)).astype(o_ref.dtype)
+        return
     o_ref = rest[-1]
     if lora:
         a_ref, b_ref, g_ref = rest[-4:-1]
         side_refs = rest[:-4]
     else:
         side_refs = rest[:-1]
-    acc = qdecode.tile_product(spec, K, ck, x_ref, w_ref, side_refs, scratch,
-                               prepared)
-    if words:
-        acc = qdecode.natural_columns(acc)
+    acc = qdecode.tile_product(spec, K, ck, x_ref, w_ref, side_refs)
     if lora:
         xa = jax.lax.dot_general(  # [block_m, R]
             x_ref[:].astype(jnp.bfloat16), a_ref[:], (((1,), (1,)), ((), ())),

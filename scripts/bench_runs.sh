@@ -12,12 +12,14 @@
 # change, parent, each pair of sides at the same seeds. Never stops at a
 # failing run: the exit code of each is printed. BENCH_RUNNER names another
 # script with bench/run.py's arguments (scripts/bench_engine_counters.py,
-# which adds the engine's step-in-flight counters to the log).
+# which adds the engine's step-in-flight counters to the log). BENCH_SECONDS
+# shortens the window where only `setup_s` is read (a cold run that fills
+# the compile cache, the warm runs after it: PERF.md section 6, PR 63).
 set -u
 tag=$1 dir=$2 trace=$3
 shift 3
 root=$(cd "$(dirname "$0")/.." && pwd)
-seconds=$(python3 -c "import json; print(json.load(open('$root/BENCHMARK.json'))['run_seconds'])")
+seconds=${BENCH_SECONDS:-$(python3 -c "import json; print(json.load(open('$root/BENCHMARK.json'))['run_seconds'])")}
 mkdir -p "$root/chiprun_out"
 while [ $# -ge 2 ]; do
     cell=$1 seed=$2
@@ -27,5 +29,6 @@ while [ $# -ge 2 ]; do
         --seconds "$seconds" --trace "$trace") >"$log" 2>&1
     echo "$tag $cell $seed trace$trace exit $?"
     grep -a "reference check\|^engine counters" "$log" | cut -c1-400
+    grep -a "^set-up \|^ *[0-9.]* s  " "$log" | cut -c1-300
     tail -n 1 "$log" | cut -c1-3000
 done

@@ -8,6 +8,7 @@ through the chip tool:
     python scripts/qmatmul_kernel_bench.py --plan experts   # the grouped kernel
     python scripts/qmatmul_kernel_bench.py --plan experts --variants tree shared
     python scripts/qmatmul_kernel_bench.py --plan ragged    # O no multiple of 512
+    python scripts/qmatmul_kernel_bench.py --plan ahead     # one set / ahead / unstaged
     python scripts/qmatmul_kernel_bench.py --lower   # compile only, no chip
 
 Each line is one (body, variant, K, O, M): 64 dependent calls inside one
@@ -21,7 +22,16 @@ Bodies:
 * `tree`: `bigdl_tpu.ops.pallas.qmatmul._qmm` as it stands, the float16
   scales viewed as uint16 and staged every grid step; `prep`: the same on
   prepared scale bits (`qdecode.pack_major_bits`), which is what a cell
-  runs since PR 48;
+  runs since PR 48. A word tile is staged and THEN multiplied, out of one
+  scratch set. Three variants price the staging, in `--plan mistral`,
+  `--plan experts` and, alone, `--plan ahead`: **one set** (`tree` /
+  `prep`; this script's copy `words` variant `s`; experts `tree` /
+  `signed`), **ahead** (`words` variant `s-h`, experts `ahead`: each tile
+  staged beside the product of the one before it out of a SECOND scratch
+  set; PR 63 read this form and nine siblings on the chip, all 0 to 20%
+  SLOWER than one set: PERF.md section 6) and **nothing staged**, the
+  bound (`words` variant `s-t`, experts `unstaged`: a call's first tile
+  staged, every other multiplied out of what is there);
 * `loop` and `ragged` (`--plan ragged`, PR 55), the tree's `_qmm` on
   prepared bits in the two forms an O that is no multiple of 512 can take:
   the stored-layout loop at `pick_block_o`'s 256- or 128-row tile (what the
@@ -45,7 +55,11 @@ Bodies:
   (the raw byte of the word: it still shifts, masks and converts, so it
   prices one operation of the chain and not "the code decode"); `t` the
   tile staged on a call's first grid step alone (no transpose of words or
-  scales on any other); `g` no `natural_columns` (the columns stored
+  scales on any other); `h` each tile staged AHEAD: the O grid one step
+  longer, step o stages tile o into one of two scratch sets and multiplies
+  tile o - 1 out of the other (the sets static, by the step's parity, both
+  ends peeled: the best of the forms PR 63 measured, and slower than one
+  set); `g` no `natural_columns` (the columns stored
   pack-major); `c` nothing computed (tiles fetched, output zero);
 * `rows`: the copy of the loop as it was before PR 32 (and still is where
   no 512-row tile fits, and in `qbackward`): stored [o, k] layout, scales
@@ -66,7 +80,10 @@ PR 44); `fetch` nothing computed; `paired` the gated call's two 256-row
 blocks decoded as ONE 512-row word tile; `mb` / `one` several word tiles a
 grid step (about 1 MB of codes; a whole expert); `chain`, `signed`,
 `inplace`: the tree's own plan (`tiling.grouped_tile`) on this script's
-copies of the decode, `d`, `s` and `i` above.
+copies of the decode, `d`, `s` and `i` above; `ahead`: `signed` with the
+tiles a step holds staged each beside the product of the one before it,
+out of two scratch sets; `unstaged`: `signed` with the call's first tile
+alone staged.
 
 It also checks, on the device it runs on, that what each body feeds the
 MXU is the dequantizer's weights bit for bit. The CPU interpreter cannot
@@ -109,7 +126,7 @@ HBM_BYTES_PER_S = 819e9  # TPU v5e, Google Cloud documentation
 # ----------------------------------------------------------- the two bodies
 
 def rows_body(x, w_ref, s_ref, *, K, ck, flags):
-    """The stored-layout loop (`tile_product` without scratch), sym_int4."""
+    """The stored-layout loop (`qdecode.tile_product`), sym_int4."""
     bo, kh = w_ref.shape[0], K // 2
     s = qdecode.f16_bits_to_f32(s_ref[:])
     w = w_ref[:]
@@ -138,7 +155,8 @@ TOP = np.int32(-0x10000000)  # 0xF0000000: a word's last nibble
 
 # this script's copies of the word path, by name: the chain of six, and the
 # two forms that convert a flipped nibble (`pack_values`)
-COPIES = {"chain": "d", "signed": "s", "inplace": "i"}
+COPIES = {"chain": "d", "signed": "s", "inplace": "i", "ahead": "s",
+          "unstaged": "s"}
 
 
 def flags_of(variant):
@@ -238,7 +256,7 @@ def words_product(x_ref, scratch, *, K, ck, flags):
 
 
 def words_body(x_ref, w_ref, s_ref, scratch, *, K, ck, flags):
-    """The word path (`tile_product` with scratch). `t`: the tile is staged
+    """The word path (`stage_words`, then `staged_product`). `t`: the tile is staged
     on the call's first grid step alone, so every other step runs the chunk
     loop on what is there and transposes nothing."""
     def stage():
@@ -251,12 +269,44 @@ def words_body(x_ref, w_ref, s_ref, scratch, *, K, ck, flags):
     return words_product(x_ref, scratch, K=K, ck=ck, flags=flags)
 
 
+def ahead_body(x_ref, w_ref, s_ref, o_ref, scratch, *, K, ck, flags, n):
+    """`h`: step o of n + 1 stages tile o and multiplies tile o - 1, the two
+    scratch sets picked by the step's parity (static), both ends peeled."""
+    A, B = scratch[:3], scratch[3:]
+    o = pl.program_id(1)
+    even = (o & 1) == 0
+
+    def stage(sc):
+        stage_copy((w_ref,), (s_ref,), sc, flags)
+
+    def product(sc):
+        acc = words_product(x_ref, sc, K=K, ck=ck, flags=flags)
+        o_ref[:] = qdecode.natural_columns(acc).astype(o_ref.dtype)
+
+    pl.when(o == 0)(lambda: stage(A))
+    pl.when(o == n)(lambda: product(A if (n - 1) % 2 == 0 else B))
+
+    @pl.when((o > 0) & (o < n) & even)
+    def _even():
+        stage(A)
+        product(B)
+
+    @pl.when((o < n) & jnp.logical_not(even))
+    def _odd():
+        stage(B)
+        product(A)
+
+
 def _kernel(layer_ref, x_ref, w_ref, s_ref, o_ref, *scratch, K, ck, body,
-            variant):
+            variant, n=0):
     del layer_ref
     flags = flags_of(variant)
     if "c" in flags:
         o_ref[:] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        return
+    if "h" in flags:
+        ahead_body(x_ref, w_ref, s_ref, o_ref, scratch, K=K, ck=ck,
+                   flags=flags, n=n)
         return
     if body == "words":
         acc = words_body(x_ref, w_ref, s_ref, scratch, K=K, ck=ck,
@@ -276,28 +326,40 @@ def qmm_copy(layer, x2, w, s, *, block_m, block_o, ck, body, variant):
     columns back in order as the tree's does, `natural_columns`)."""
     Mp, K = x2.shape
     O = w.shape[1]
+    n = O // block_o
+    # `h`: one step more; the weight-side blocks held at the last tile for
+    # it (a repeated block index is not fetched again), the output block
+    # one step behind (not written back in between), two scratch sets
+    ahead = "h" in flags_of(variant)
+    tile = (lambda o: jnp.minimum(o, n - 1)) if ahead else (lambda o: o)
     scratch = (qdecode.word_scratch(SPEC, block_o, w.shape[2], s.shape[1])
-               if body == "words" else [])
+               * (2 if ahead else 1) if body == "words" else [])
     return pl.pallas_call(
-        functools.partial(_kernel, K=K, ck=ck, body=body, variant=variant),
+        functools.partial(_kernel, K=K, ck=ck, body=body, variant=variant,
+                          n=n),
         name=f"qmatmul_{body}_{variant}".replace("-", "_"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(Mp // block_m, O // block_o),
+            grid=(Mp // block_m, n + ahead),
             in_specs=[
                 pl.BlockSpec((block_m, K), lambda m, o, l: (m, 0)),
                 pl.BlockSpec((None, block_o, w.shape[2]),
-                             lambda m, o, l: (l[0], o, 0)),
-                pl.BlockSpec((block_o, s.shape[1]), lambda m, o, l: (o, 0)),
+                             lambda m, o, l: (l[0], tile(o), 0)),
+                pl.BlockSpec((block_o, s.shape[1]),
+                             lambda m, o, l: (tile(o), 0)),
             ],
-            out_specs=pl.BlockSpec((block_m, block_o), lambda m, o, l: (m, o)),
+            out_specs=pl.BlockSpec(
+                (block_m, block_o),
+                (lambda m, o, l: (m, jnp.maximum(o - 1, 0))) if ahead
+                else (lambda m, o, l: (m, o))),
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, O), jnp.bfloat16),
         compiler_params=pltpu.CompilerParams(
-            # `t` stages on the first step alone: the steps run in order
-            dimension_semantics=("arbitrary",) * 2 if "t" in flags_of(variant)
-            else ("parallel", "parallel"),
+            # `t` stages on the first step alone, `h` carries a staged
+            # tile to the next step: the steps run in order
+            dimension_semantics=("arbitrary",) * 2
+            if flags_of(variant) & {"t", "h"} else ("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(layer, x2, w, s)
 
@@ -546,6 +608,7 @@ def experts_kernel(te_ref, meta_ref, x_ref, *refs, K, ck, n_w, variant, tiles,
     del te_ref
     o_ref, scratch = refs[2 * n_w], refs[2 * n_w + 1:]
     silu = jax.nn.silu
+    first_step = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
 
     @pl.when(pl.program_id(0) < meta_ref[0])
     def _live_tile():
@@ -566,27 +629,44 @@ def experts_kernel(te_ref, meta_ref, x_ref, *refs, K, ck, n_w, variant, tiles,
                 ws, [s[0] for s in ss], sc, flags)
             product = lambda sc: words_product(x_ref, sc, K=K, ck=ck,
                                                flags=flags)
+            if variant == "unstaged":  # the call's first tile alone
+                every = stage
+                stage = lambda *a: pl.when(first_step)(lambda: every(*a))
         else:
             stage = lambda ws, ss, sc: qdecode.stage_words(SPEC, ws, ss, sc)
             product = lambda sc: qdecode.staged_product(SPEC, K, ck, x_ref,
                                                         sc)
-        for j in range(tiles):
+        per = 3 if paired else 3 * n_w  # scratch refs a set
+        sets = len(scratch) // per  # two for `ahead`
+
+        def stage_tile(j):
+            """Tile j of the step's into scratch set j % sets."""
             def cut(r):
                 return r if tiles == 1 else r.at[pl.ds(j * rows, rows), :]
             ws = [cut(refs[2 * i]) for i in range(n_w)]
             # (a row slice of a scale REF 24 lanes wide does not lower)
             ss = [(refs[2 * i + 1][:][j * rows:(j + 1) * rows],)
                   for i in range(n_w)]
+            sc = scratch[j % sets * per:(j % sets + 1) * per]
             if paired:
-                stage(ws, ss, scratch[:3])
-                y = qdecode.natural_columns(product(scratch[:3]))
+                stage(ws, ss, sc)
+            else:
+                for i in range(n_w):
+                    stage(ws[i:i + 1], ss[i:i + 1], sc[3 * i:3 * i + 3])
+
+        if sets == 2:  # `ahead`: the first tile in the open
+            stage_tile(0)
+        for j in range(tiles):
+            if sets == 2 and j + 1 < tiles:
+                stage_tile(j + 1)
+            elif sets == 1 and not (variant == "unstaged" and j):
+                stage_tile(j)
+            sc = scratch[j % sets * per:(j % sets + 1) * per]
+            if paired:
+                y = qdecode.natural_columns(product(sc))
                 y = silu(y[:, :256]) * y[:, 256:]
             else:
-                accs = []
-                for i in range(n_w):
-                    sc = scratch[3 * i:3 * i + 3]
-                    stage(ws[i:i + 1], ss[i:i + 1], sc)
-                    accs.append(product(sc))
+                accs = [product(sc[3 * i:3 * i + 3]) for i in range(n_w)]
                 y = qdecode.natural_columns(
                     accs[0] if n_w == 1 else silu(accs[0]) * accs[1])
             o_ref[:, j * rows:(j + 1) * rows] = y.astype(o_ref.dtype)
@@ -618,6 +698,8 @@ def experts_copy(te, meta, x, *arrays, block_m, block_o, ck, variant, tiles):
         ]
     paired = n_w == 2 and O % 512 == 256
     sets = 0 if variant in ("loop", "fetch") else 1 if paired else n_w
+    if variant == "ahead" and tiles > 1:  # a second set to stage into
+        sets *= 2
     scratch = qdecode.word_scratch(SPEC, 512, K // 2, K // 32) * sets
     return pl.pallas_call(
         functools.partial(experts_kernel, K=K, ck=ck, n_w=n_w,
@@ -799,7 +881,8 @@ def experts_check():
         block_m = args[2].shape[0] // args[0].shape[0]
         want = experts_build("tree", "granite", K, O, gated)[1](*args)
         want = np.asarray(want[:hit * block_m].astype(jnp.float32))
-        for v in ("loop", "paired", "mb", "one", *COPIES):
+        for v in ("loop", "paired", "mb", "one",
+                  *(c for c in COPIES if c != "unstaged")):  # (a time only)
             built = experts_build(v, "granite", K, O, gated)
             if built is None:
                 continue
@@ -869,6 +952,11 @@ def plan_of(name):
         return [(b, "d", M, K, O) for K, O, M in RAGGED_SHAPES
                 for b in ("loop", "ragged")]
     shapes = cell_shapes()
+    if name == "ahead":  # one set (the tree, the copy), ahead, nothing staged
+        return [(b, v, M, K, O) for K, O in shapes["mistral-7b-int4"][0]
+                for M in (1, 32, 256)
+                for b, v in (("prep", "d"), ("words", "s"), ("words", "s-h"),
+                             ("words", "s-t"))]
     if name == "mistral":  # every variant, at the cells' M
         for K, O in shapes["mistral-7b-int4"][0]:
             for M in (1, 8, 16, 32):
@@ -878,7 +966,7 @@ def plan_of(name):
                     "d", "s", "i", "i-1", "a", "b", "c")]
                 if M in (1, 32):  # what is left beside the chain
                     plan += [("words", v, M, K, O) for v in (
-                        "i-a", "t", "i-t", "g", "i-g")]
+                        "i-a", "t", "s-t", "s-h", "i-t", "g", "i-g")]
                     plan += [("rows", v, M, K, O) for v in ("a", "b", "a-b")]
         return plan
     for cfg, (kos, M) in shapes.items():  # the before / after table
@@ -895,7 +983,7 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--plan", default="cells",
                     choices=("cells", "mistral", "quick", "forms", "experts",
-                             "ragged"))
+                             "ragged", "ahead"))
     ap.add_argument("--lower", "--fit", action="store_true",
                     help="compile the plan for a described v5e; no chip")
     ap.add_argument("--variants", nargs="+",

@@ -14,7 +14,14 @@ Three things are held:
 * the grouped expert kernel does the same with an empty group, one stack
   and the gated pair, on 512-row word tiles and on the PAIRED tile of a
   768-wide gated call (ISSUE 44: 256 rows of `w_gate` beside 256 of `w_up`
-  decoded as one 512-row word tile).
+  decoded as one 512-row word tile);
+* the kernels call the two halves of a word tile as `jit`s of their refs,
+  traced once a process for blocks of one shape (ISSUE 63:
+  `qdecode.stage_tile`, `qdecode.product_of_tile`, the grouped kernel's
+  `_stage_tile` and `_tile_product`), and the product is, bit for bit, what
+  `stage_words`, `staged_product` and `natural_columns` give written out in
+  a kernel of the test's own (`_one_set` below), whichever instance traced
+  them first.
 
 TOLERANCE of the products: both sides multiply the same bf16 operands into
 float32, so they differ by float32 summation order alone. Weights are drawn
@@ -238,6 +245,42 @@ def test_chunk_body_cuts_a_signed_field_in_two_operations(name, seg):
     assert old["and"] == WORD_ROWS and "shift_left" not in old
 
 
+@pytest.mark.parametrize("which", ("zeros", "subnormals", "normals",
+                                   "top-exponent"))
+def test_f16_bits_decode_every_pattern_exactly(which):
+    """`f16_bits_to_f32` (ISSUE 63: 14 operations where it had 20) over all
+    65536 bit patterns, by exponent class: both zeros keep their sign, a
+    subnormal is mant * 2^-24 and NOT flushed, a normal is float16's own
+    value; the top exponent's patterns (inf / nan, which no encoder here
+    stores) come out as the finite values their fields spell, as they
+    always did. Plain and through a kernel."""
+    bits = np.arange(65536, dtype=np.uint16)
+    exp, mant = (bits >> 10) & 31, bits & 1023
+    pick = {"zeros": (exp == 0) & (mant == 0),
+            "subnormals": (exp == 0) & (mant > 0),
+            "normals": (exp > 0) & (exp < 31), "top-exponent": exp == 31}[which]
+    bits = bits[pick]
+    want = bits.view(np.float16).astype(np.float32)
+    if which == "top-exponent":  # (1 + mant / 1024) * 2^16, signed
+        want = ((1 + (bits & 1023) / 1024.0) * 65536.0 * np.where(
+            bits >> 15, -1.0, 1.0)).astype(np.float32)
+    n = -(-len(bits) // 128) * 128
+    padded = np.zeros(n, np.uint16)
+    padded[:len(bits)] = bits
+    tile = jnp.asarray(padded.reshape(-1, 128))
+
+    def kern(b_ref, o_ref):
+        o_ref[...] = qdecode.f16_bits_to_f32(b_ref[...])
+
+    through = pl.pallas_call(
+        kern, out_shape=jax.ShapeDtypeStruct(tile.shape, jnp.float32),
+        interpret=True)(tile)
+    for got in (qdecode.f16_bits_to_f32(tile), through):
+        got = np.asarray(got).reshape(-1)[:len(bits)]
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32))
+
+
 def _reference(x, qt):
     return jnp.dot(x.astype(jnp.bfloat16), qt.dequantize(jnp.bfloat16).T,
                    preferred_element_type=jnp.float32)
@@ -291,6 +334,83 @@ def test_every_format_takes_the_word_path(interpret, qtype):
         old = qmatmul(x, qt, out_dtype=jnp.float32, block_o=256)
         np.testing.assert_allclose(np.asarray(y), np.asarray(old), rtol=0,
                                    atol=5e-5, err_msg=f"{qtype} M={M}")
+
+
+# ---- the halves as `jit`s traced once a process (ISSUE 63) -----------------
+
+def _one_set(x, qt, block_m=None):
+    """float32 [M, O]: the word path written out in a kernel of the test's
+    own, grid (M tiles, O tiles), every tile staged and then multiplied;
+    the tree's own `stage_words`, `staged_product` and `natural_columns`
+    called as plain functions on the tree's tiles and chunk, so the same
+    float32 sums in the same order."""
+    from bigdl_tpu.ops.pallas.tiling import pick_block_m, round_up
+
+    spec, data, side = _kernel_data(qt)
+    (M, K), (O, rb) = x.shape, data.shape
+    bm = block_m or pick_block_m(M, K)
+    Mp = round_up(M, bm)
+    ck = words_chunk(finest_split(K, spec.planes), spec.block)
+
+    def kern(x_ref, w_ref, *rest):
+        side_refs, o_ref = rest[:len(side)], rest[len(side)]
+        scratch = rest[len(side) + 1:]
+        qdecode.stage_words(spec, (w_ref,), (side_refs,), scratch)
+        o_ref[:] = qdecode.natural_columns(
+            qdecode.staged_product(spec, K, ck, x_ref, scratch))
+
+    rows = lambda m, o: (o, 0)
+    y = pl.pallas_call(
+        kern, grid=(Mp // bm, word_tiles(O)),
+        in_specs=[pl.BlockSpec((bm, K), lambda m, o: (m, 0)),
+                  pl.BlockSpec((WORD_BLOCK_O, rb), rows),
+                  *(pl.BlockSpec((WORD_BLOCK_O, a.shape[1]), rows)
+                    for a in side)],
+        out_specs=pl.BlockSpec((bm, WORD_BLOCK_O), lambda m, o: (m, o)),
+        out_shape=jax.ShapeDtypeStruct((Mp, O), jnp.float32),
+        scratch_shapes=qdecode.word_scratch(
+            spec, WORD_BLOCK_O, rb, side[-1].shape[1]),
+        interpret=True,
+    )(jnp.pad(x.astype(jnp.bfloat16), ((0, Mp - M), (0, 0))), data, *side)
+    return y[:M]
+
+
+def _same_bits(got, want, what):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert got.shape == want.shape, (what, got.shape, want.shape)
+    bad = np.argwhere(got.view(np.uint32) != want.view(np.uint32))
+    assert bad.size == 0, (what, len(bad), bad[:4])
+
+
+@pytest.mark.parametrize("qtype", sorted(_QGEMV_QTYPES))
+def test_every_format_through_the_cached_halves_bit_for_bit(interpret, qtype):
+    """Three word tiles through `_qmm`'s cached halves: every packed
+    format's product is the written-out kernel's, to the last bit (same
+    codes, same scales, same bf16 weights, the same float32 accumulation
+    within a tile)."""
+    K, O = 1024, 3 * WORD_BLOCK_O
+    qt = _weights(qtype, O, K, seed=6)
+    x = jax.random.normal(jax.random.PRNGKey(2), (8, K)).astype(jnp.bfloat16)
+    _same_bits(qmatmul(x, qt, out_dtype=jnp.float32), _one_set(x, qt), qtype)
+
+
+@pytest.mark.parametrize("M", (8, 520), ids=("one-row-tile", "three"))
+@pytest.mark.parametrize("O", (512, 1024, 1536, 32000),
+                         ids=("one-tile", "two", "odd", "ragged-63"))
+def test_tiles_through_the_cached_halves_bit_for_bit(interpret, O, M):
+    """sym_int4 at an O of one word tile, of two, of an odd count and of
+    Mistral's head (62 tiles and a ragged one of 256 rows: the last step's
+    partial blocks), under one row tile and under three: the same traces
+    serve every O (no block shows it) and each row tile its own."""
+    from bigdl_tpu.ops.pallas.tiling import pick_block_m
+
+    K = 512
+    qt = _weights("sym_int4", O, K, seed=O)
+    x = jax.random.normal(jax.random.PRNGKey(M), (M, K)).astype(jnp.bfloat16)
+    assert -(-M // pick_block_m(M, K)) == (1 if M == 8 else 3)
+    got = qmatmul(x, qt, out_dtype=jnp.float32)
+    assert got.shape == (M, O)
+    _same_bits(got, _one_set(x, qt), (O, M))
 
 
 # ---- a ragged last word tile (ISSUE 55) ------------------------------------
@@ -388,12 +508,47 @@ def test_every_format_takes_the_ragged_tile(interpret, qtype):
 
 # (K, O, act, block_m): Mixtral's two contractions on 512-row word tiles,
 # then the PAIRED tile of granite's (K 4096) and SmallThinker's (K 2560)
-# 768-wide gated calls at a decode step's row tiles and a prefill's
+# 768-wide gated calls at a decode step's row tiles and a prefill's; then
+# (ISSUE 63) experts of several word tiles: two tiles a step and three of a
+# gated pair (the walk inside a step over the cached halves), a tile a step
+# over two steps, one stack and the gated pair
 _GROUPED = [(K, WORD_BLOCK_O, act, 8) for K in (4096, 14336)
             for act in (None, "silu")] + [
     (4096, 768, "silu", 32), (4096, 768, "relu", 16), (4096, 768, "silu", 256),
     (2560, 768, "relu", 16), (2560, 768, "silu", 32), (2560, 768, "relu", 256),
+    (1024, 1024, None, 8), (1024, 1536, "silu", 16),
+    (8192, 1024, None, 8), (4096, 1024, "silu", 8),
 ]
+_GROUPED_PLANS = {
+    (1024, 1024, None): "words:inplace x1 of 2 tiles",
+    (1024, 1536, "silu"): "words:inplace x1 of 3 tiles",
+    (8192, 1024, None): "words:inplace x2",
+    (4096, 1024, "silu"): "words:inplace x2",
+}
+
+
+def _one_set_expert_tile(x_tile, ws, e, act, paired):
+    """What the grouped kernel stores for one live row tile of expert `e`,
+    from the written-out dense kernel (`_one_set`) on the same rows: each
+    stack's product, or the paired tile's (256 rows of gate beside 256 of
+    up, a word tile each pair), then the activation in float32."""
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+    from bigdl_tpu.quant.qtensor import QTensor
+
+    bm = x_tile.shape[0]
+    one = [QTensor(qtype=w.qtype, data=w.data[e], scales=w.scales[e])
+           for w in ws]
+    if paired:
+        half = WORD_BLOCK_O // 2
+        cut = lambda a: a.reshape(-1, half, a.shape[-1])
+        both = QTensor(qtype=one[0].qtype, **{
+            f: jnp.stack([cut(getattr(one[0], f)), cut(getattr(one[1], f))],
+                         axis=1).reshape(-1, getattr(one[0], f).shape[-1])
+            for f in ("data", "scales")})
+        y = _one_set(x_tile, both, block_m=bm).reshape(bm, -1, 2, half)
+        return (mq.FUSED_ACTS[act](y[:, :, 0]) * y[:, :, 1]).reshape(bm, -1)
+    ys = [_one_set(x_tile, w, block_m=bm) for w in one]
+    return ys[0] if act is None else mq.FUSED_ACTS[act](ys[0]) * ys[1]
 
 
 @pytest.mark.parametrize("K,O,act,bm", _GROUPED)
@@ -401,7 +556,10 @@ def test_grouped_kernel_on_the_word_path(interpret, K, O, act, bm):
     """A group of size 0 (an expert with no rows), a dead tile past the
     tiles in use, one stack and the (gate, up) pair: rows of expert e are
     x @ dq(W[e])^T. A gated 768-wide call takes the paired tile, and
-    agrees with two ungated calls (the stored-layout loop) + XLA."""
+    agrees with two ungated calls (the stored-layout loop) + XLA. Every
+    live row tile is the written-out product of its expert, bit for bit
+    (ISSUE 63: the step's tiles are walked over `_stage_tile` and
+    `_tile_product`, traced once)."""
     from bigdl_tpu.ops.pallas import moe_qmatmul as mq
 
     E, gated = 4, act is not None
@@ -409,7 +567,8 @@ def test_grouped_kernel_on_the_word_path(interpret, K, O, act, bm):
     ws = [quantize(jax.random.normal(jax.random.PRNGKey(i), (E, O, K))
                    * K ** -0.5, "sym_int4") for i in range(2 if gated else 1)]
     paired = O == 768
-    assert mq.call_plan(ws if gated else ws[0]) == (
+    assert mq.call_plan(ws if gated else ws[0]) == _GROUPED_PLANS.get(
+        (K, O, act),
         "words:inplace:paired x1 of 3 tiles" if paired
         else "loop x2" if gated and K == 14336  # two such tiles: VMEM
         else "words:inplace x1")
@@ -427,6 +586,11 @@ def test_grouped_kernel_on_the_word_path(interpret, K, O, act, bm):
                       preferred_element_type=jnp.float32) for w in ws]
     want = mq.FUSED_ACTS[act](per[0]) * per[1] if gated else per[0]
     np.testing.assert_allclose(got, np.asarray(want), rtol=0, atol=5e-5)
+    if not (gated and K == 14336):  # (the loop has no scratch to compare)
+        for m in range(int(n_used)):
+            rows = slice(m * bm, (m + 1) * bm)
+            _same_bits(y[rows], _one_set_expert_tile(
+                x[src][rows], ws, int(te[m]), act, paired), (m, int(te[m])))
     if paired:
         assert mq.call_plan(ws[0]) == "loop x3"
         g, u = (mq.moe_qmatmul(x[src], w, te, n_used, bm,
@@ -471,12 +635,12 @@ def test_natural_columns_puts_pack_major_columns_back():
 def test_linear_reaches_the_word_path(interpret, monkeypatch):
     """`ops.linear.linear`, the call the models make, hands the kernel the
     policy's tile and not one of its own: a 512-row weight is decoded by
-    `stage_words`, through GEMV and GEMM rows alike."""
+    `stage_words` (through `stage_tile`), GEMV and GEMM rows alike."""
     from bigdl_tpu.ops.linear import linear
 
     staged = []
-    real = qdecode.stage_words
-    monkeypatch.setattr(qdecode, "stage_words",
+    real = qdecode.stage_tile  # (called a kernel instance; traced once)
+    monkeypatch.setattr(qdecode, "stage_tile",
                         lambda *a, **k: staged.append(1) or real(*a, **k))
     qt = _weights("sym_int4", WORD_BLOCK_O, 1280, seed=5)  # 1280: no other
     for M in (3, 40):                                      # test's shape

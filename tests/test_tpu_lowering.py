@@ -214,9 +214,10 @@ def test_moe_qmatmul_compiles_at_granites_and_smallthinkers_shapes(
         *fields,
     ).compile()
     assert "moe_qmatmul" in c.as_text()  # the name the roofline readers find
-    # (a whole expert a grid step: the walk over its word tiles is traced
-    # once and unrolled when it is lowered)
-    assert calls == {"loop": 0, "staged": 1}, calls
+    # (a whole expert a grid step: the walk over its word tiles calls a
+    # `jit` of the kernel's refs a tile, traced ONCE a process for blocks of
+    # one shape: not again by a later case of this test on the same blocks)
+    assert calls["loop"] == 0 and calls["staged"] <= 1, calls
 
 
 @pytest.mark.parametrize("rows", ("decode", "prefill"))
@@ -282,25 +283,25 @@ def _mosaic_bodies(lowered_text):
 
 # sha256 of the dense `qmatmul`'s Mosaic module (wqkv at 32 rows, w_down at
 # a prefill's 256, Mistral's head, Qwen2's wqkv whose chunks are Python's
-# loop, MiniCPM-SALA's O = 256 on the stored-layout loop): the word path's
-# three on PR 49's tree (a sym_int4 nibble cut out signed: the chunk loop's
-# equations changed, on purpose); Mistral's head re-pinned by ISSUE 55, on
-# purpose (48b35979... on the stored-layout loop at 256-row tiles until
-# then; the word path over 63 tiles now, its grid and block shapes alone
-# differ from wqkv's module); the stored-layout loop's own program, which
-# ISSUE 55 left alone, pinned at a shape its rule leaves there (PR 54's
-# tree gives the same hash)
+# loop, MiniCPM-SALA's O = 256 on the stored-layout loop). All five
+# re-pinned by ISSUE 63, on purpose: `qdecode.f16_bits_to_f32`, which every
+# packed kernel stages its scales with, is 14 operations where it was 20
+# (the same float32 for every finite pattern), and nothing else of the
+# module moved (the halves became `jit`s of the kernel's refs, which Mosaic
+# is handed in line). Before: PR 49's chunk loop (a sym_int4 nibble cut out
+# signed) and PR 55's ragged head (the word path over 63 tiles, its grid
+# and block shapes alone differing from wqkv's module).
 _DENSE_BODIES = {
     (4096, 6144, 32):
-        "2fc2dc3cb2ef5b2b5e9808c79310efe8895849f0dfed9379ffd5cdd72c5921f4",
+        "83edf03a2458a9ba3369abdf981ad23a81507dd234a5f92bac0f24ed8d158f9a",
     (14336, 4096, 256):
-        "b24b8181ff570e2dc4f492c2fd26355abaf6f151da5be58ccf21870e8eb4f63f",
+        "24b1b3b7bb070e5d045c0770d2f5105868a8beae3d04737149f305a97d22708e",
     (4096, 32000, 32):
-        "5d88cbbccc37d52b837c78c623a1c617da6f8de23cd8815f5917cd07b75a1c1f",
+        "c79b7bcab3e191281b2392a1c3fb4125bf84241fb9939d0c5192f35a54ed12f3",
     (3584, 4608, 16):
-        "94a9a360d80d336bdfd50d1a02bc73e74d57d514b0c65450039b2705eee60395",
+        "0736bce93734b801d11c5fe6b605f3d6305a3de4383786012ae40d920d092ac9",
     (4096, 256, 16):
-        "c2eefe715c2f667fe3ec3adbd3d76447902b6090cddd43dd6585c190b581b851",
+        "e92c10ef3bbded5c23c42b4d7dc98c9ba15bc7578ebcc2c550a684aff7fba0ef",
 }
 
 
