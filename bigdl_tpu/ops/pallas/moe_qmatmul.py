@@ -29,7 +29,11 @@ What a step pays for:
 * the tiles in use come first (`n_used` of the static `n_tiles`); a tile
   past them computes nothing and its block indices name the blocks the
   last live step already holds, so it costs no DMA either (the rule
-  `paged_attention.py` uses for dead pages);
+  `paged_attention.py` uses for dead pages). On the word forms the codes
+  are no blocks at all: the stacks stay in HBM and every LIVE step copies
+  its expert's tiles in as words, one live step ahead
+  (`qdecode.copy_tiles_ahead`; docs/kernels.md#word-path), so a dead tile
+  starts and waits for nothing;
 * an expert with no rows has no tile, so its weights are never read; an
   expert whose rows fit one tile (every decode batch: `block_m` covers
   the whole batch) is read once, packed;
@@ -100,8 +104,8 @@ from bigdl_tpu.ops.pallas.qmatmul import (
     _validate, prepared_bits, side_operands,
 )
 from bigdl_tpu.ops.pallas.tiling import (
-    VMEM_LIMIT_BYTES, WORD_BLOCK_O, finest_split, forward_chunk, grouped_tile,
-    pick_block_m,
+    VMEM_LIMIT_BYTES, WORD_BLOCK_O, WORD_ROWS, finest_split, forward_chunk,
+    grouped_tile, pick_block_m,
 )
 
 #: activations the gated call applies in-kernel (float32, before the
@@ -190,24 +194,28 @@ def _tiles(tiles: jax.Array, n_tiles: int):
 
 @functools.partial(jax.jit, static_argnames=("spec", "prepared", "rows",
                                              "paired"))
-def _stage_tile(blocks, sides, scratch, j, *, spec: DecodeSpec,
+def _stage_tile(bufs, slot, sides, scratch, j, *, spec: DecodeSpec,
                 prepared: bool, rows: int, paired: bool):
-    """Word tile `j` of the tiles a step holds (rows `j * rows ..` of each
-    stack's code block, or block `j` of its prepared bits) into the
-    scratch: one staging a stack, or the paired tile's one of both."""
-    if blocks[0].shape[0] == rows:  # the step holds one tile
-        blk, sd = blocks, (
+    """Word tile `j` of the tiles a step holds (word rows `j * rows / 4 ..`
+    of buffer `slot` of each stack's `bufs`, where
+    `qdecode.copy_tiles_ahead` left the step's words; rows `j * rows ..` of
+    its side blocks, or block `j` of its prepared bits) into the scratch:
+    one staging a stack, or the paired tile's one of both."""
+    rows_in = rows // WORD_ROWS
+    if bufs[0].shape[1] == rows_in:  # the step holds one tile
+        blk, sd = [b.at[slot] for b in bufs], (
             [[r[0] for r in side] for side in sides] if prepared else sides)
     else:
         at = pl.ds(pl.multiple_of(j * rows, rows), rows)
-        blk = [b.at[at, :] for b in blocks]
+        blk = [b.at[slot, pl.ds(pl.multiple_of(j * rows_in, rows_in),
+                                rows_in), :] for b in bufs]
         # (loaded here: a ref view narrower than 128 lanes does not lower)
         sd = [[r[j] if prepared else r[at, :] for r in side]
               for side in sides]
     if paired:
         qdecode.stage_words(spec, blk, sd, scratch, prepared=prepared)
         return
-    for i in range(len(blocks)):
+    for i in range(len(bufs)):
         qdecode.stage_words(spec, (blk[i],), (sd[i],),
                             scratch[3 * i:3 * i + 3], prepared=prepared)
 
@@ -233,27 +241,33 @@ def _tile_product(x_ref, scratch, *, spec: DecodeSpec, K: int, ck: int,
 
 def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
             spec: DecodeSpec, n_w: int, act, form: str, rows: int,
-            prepared: bool = False):
+            prepared: bool = False, layered: bool = False, n_o: int = 1):
     """One [block_m, block_o] tile of one expert: `qmatmul._kernel`'s
     chunk loop over each of the `n_w` weight stacks, skipped whole when
     the tile holds no assignment. On the word path three scratch refs per
     word tile follow the output; the paired form (`tiling.grouped_tile`)
-    has one tile for both stacks. A step that holds several tiles of
+    has one tile for both stacks. Then come each stack's two word buffers
+    and their semaphores: the word forms' code refs are the whole stacks in
+    HBM (``layered``: read at layer `meta[1]`, else at 0) and a live step's
+    tiles of expert `te[m]` are copied in by `qdecode.copy_tiles_ahead`,
+    the next LIVE step's asked for first, `meta[0] * n_o` steps in the
+    chain (``n_o``: grid steps a row tile). A step that holds several tiles of
     `rows` rows walks them, one staging, one product and one store each,
     through the same scratch, written out here over `_stage_tile` and
     `_tile_product`: each is traced once and lowered a tile, so Mosaic
     sees straight-line code (see the module docstring). With ``prepared``
     a side ref holds the `qdecode.pack_major_bits` blocks of the step's
     tiles, `[held, nb, rows]`."""
-    del te_ref  # read by the index maps
     per = 1 + spec.n_side
     o_ref = refs[n_w * per]
     scratch = refs[n_w * per + 1:]
     held = o_ref.shape[1] // rows  # tiles this step holds
     blocks = [refs[i * per] for i in range(n_w)]
     sides = [refs[i * per + 1:(i + 1) * per] for i in range(n_w)]
+    # (read out here: the interpreter resolves no grid index in a branch)
+    m, o = pl.program_id(0), pl.program_id(1)
 
-    @pl.when(pl.program_id(0) < meta_ref[0])
+    @pl.when(m < meta_ref[0])
     def _live_tile():
         if form == "loop":
             accs = [qdecode.tile_product(spec, K, ck, x_ref, blocks[i],
@@ -262,11 +276,22 @@ def _kernel(te_ref, meta_ref, x_ref, *refs, K: int, ck: int,
                         * accs[1]).astype(o_ref.dtype)
             return
         paired = form == "words:paired"
+        stage, bufs, sem = (scratch[:-n_w - 1], scratch[-n_w - 1:-1],
+                            scratch[-1])
+        layer = meta_ref[1] if layered else 0
+        # the row tile of the step after this one (the chain's last step
+        # asks for nothing: any tile of the table does)
+        m_next = jax.lax.min(
+            m + 1 if n_o == 1 else m + jax.lax.div(o + 1, n_o),
+            te_ref.shape[0] - 1)
+        slot = qdecode.copy_tiles_ahead(
+            blocks, bufs, sem, m, o, meta_ref[0], (layer, te_ref[m]),
+            (layer, te_ref[m_next]), n_o=n_o, last_rows=bufs[0].shape[1])
         for j in range(held):
-            _stage_tile(blocks, sides, scratch, j, spec=spec,
+            _stage_tile(bufs, slot, sides, stage, j, spec=spec,
                         prepared=prepared, rows=rows, paired=paired)
             o_ref[:, j * rows:(j + 1) * rows] = _tile_product(
-                x_ref, scratch, spec=spec, K=K, ck=ck, act=act, rows=rows,
+                x_ref, stage, spec=spec, K=K, ck=ck, act=act, rows=rows,
                 paired=paired, dtype=o_ref.dtype)
 
 
@@ -298,22 +323,31 @@ def _moe_qmm(spec, out_dtype, block_m: int, block_o: int, ck: int, n_w: int,
         w = w_map(has_layer)
         return lambda *a: (*w(*a), 0)
 
+    words = form != "loop"
     in_specs = [pl.BlockSpec((block_m, K), x_map)] + [
         pl.BlockSpec((None, None, block_o // rows, *a.shape[-2:]),
                      bits_map(has_layer)) if prepared and i % per
+        # the word forms' codes stay in HBM: the kernel brings its tiles
+        else pl.BlockSpec(memory_space=pl.ANY) if words and not i % per
         else pl.BlockSpec((None, None, block_o, a.shape[-1]),
                           w_map(has_layer))
         for i, (a, has_layer) in enumerate(zip(arrays, layered))
     ]
     scratch = []
-    if form != "loop":  # a word tile of each stack, or the pair's one
+    if words:  # a word tile of each stack, or the pair's one; then two
+        # buffers of a step's words a stack and their semaphores
         scratch = qdecode.word_scratch(
             spec, WORD_BLOCK_O, arrays[0].shape[-1],
             K // spec.block if prepared else arrays[per - 1].shape[-1]
-        ) * (1 if form == "words:paired" else n_w)
+        ) * (1 if form == "words:paired" else n_w) + qdecode.word_buffers(
+            n_w, block_o, arrays[0].shape[-1])
+        if interpret:  # (`qmatmul._qmm`: a constant stack's word view)
+            arrays = [jax.lax.optimization_barrier(a) if not i % per else a
+                      for i, a in enumerate(arrays)]
     return pl.pallas_call(
         functools.partial(_kernel, K=K, ck=ck, spec=spec, n_w=n_w, act=act,
-                          form=form, rows=rows, prepared=prepared),
+                          form=form, rows=rows, prepared=prepared,
+                          layered=layered[0], n_o=n_o),
         name="moe_qmatmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

@@ -399,8 +399,17 @@ def decode_chunk(spec: DecodeSpec, K: int, w, side, e0: int, c: int):
 #
 # So the tile is turned once per grid step, as 32-bit words: a byte tile
 # [bo, row_bytes] viewed as int32 holds WORD_ROWS = 4 consecutive O rows in
-# one lane (`pltpu.bitcast`, free), and the XLU transposes [bo / 4,
-# row_bytes] words, an eighth of the vregs the decoded float32 tile has.
+# one lane, and the XLU transposes [bo / 4, row_bytes] words, an eighth of
+# the vregs the decoded float32 tile has. The view is free only where the
+# tile ARRIVES as words: a byte block out of Pallas's pipeline lies in VMEM
+# in XLA's `(8, 128)(4, 1)` byte tiling, and `pltpu.bitcast(..., int32)` of
+# it makes Mosaic re-lay every vreg to `(32, 128)` with four unpacks and
+# three packs, 2,048 VALU operations a tile at K = 4096 beside the chunk
+# chain's 9,984 in a loop VALU issue bounds (PR 62's bundle dump,
+# `scripts/kernel_bundles.py`). The forward kernels therefore leave the
+# stack in HBM and copy each tile in themselves as int32 words
+# (`copy_tiles_ahead`): those byte tiles ARE `(2, 128)` word tiles, and a
+# DMA into an `(8, 128)`-tiled int32 buffer re-tiles in the DMA engine.
 # Pack p (bits 8p .. 8p+7 of a word) then decodes to rows 4i + p of the
 # tile with no uint8 -> int32 widening at all, so the result's columns come
 # out pack-major: `staged_product` returns them so, and the kernels put them
@@ -527,7 +536,10 @@ def stage_words(spec: DecodeSpec, w_refs, side_refs, scratch,
     fields' top bits flipped where a plane's fields are cut out signed
     (`signed_field`: one operation a WORD vreg, an eighth of one a decoded
     vreg, before the transpose). `w_refs` holds the
-    tile's code blocks and `side_refs` each block's side refs: one block of
+    tile's code blocks (the int32 words `copy_tiles_ahead` brought, loaded
+    as they are; a uint8 block out of a pipeline is viewed as words, which
+    is NOT free: see the note above `word_scratch`) and `side_refs` each
+    block's side refs: one block of
     512 rows, or the 256-row gate and up blocks of a gated expert call
     (`tiling.grouped_tile`), whose 64 + 64 word rows are stacked on
     sublanes and turned as one, so that the tile's rows 0..255 are gate
@@ -536,12 +548,14 @@ def stage_words(spec: DecodeSpec, w_refs, side_refs, scratch,
     `f16_bits_to_f32` (single-level formats; no `effective_side`)."""
     wT_ref, s32_ref, sT_ref = scratch
     row_bytes = w_refs[0].shape[1]
-    q = sum(r.shape[0] for r in w_refs) // WORD_ROWS
+    q = wT_ref.shape[1]
     # (a byte code is signed as stored: only a plane's fields are flipped)
     flip = signed_field(spec) if spec.planes else 0
     for j0 in range(0, row_bytes, piece):
         cw = min(piece, row_bytes - j0)
-        words = [pltpu.bitcast(r[:, j0:j0 + cw], jnp.int32) for r in w_refs]
+        words = [r[:, j0:j0 + cw] if r.dtype == jnp.int32
+                 else pltpu.bitcast(r[:, j0:j0 + cw], jnp.int32)
+                 for r in w_refs]
         words = words[0] if len(words) == 1 else jnp.concatenate(words, axis=0)
         wT_ref[j0:j0 + cw, :] = (words ^ _top_bits(flip) if flip else words).T
     if prepared:
@@ -685,27 +699,116 @@ def staged_product(spec: DecodeSpec, K: int, ck: int, x_ref, scratch):
     return acc
 
 
-# The two halves as `jit`s of the kernel's refs. A kernel's body is traced
-# anew for every `pallas_call` instance, and a process traces dozens whose
-# blocks have the same shapes: a cell's prefill buckets share a row tile,
-# and the four K = 4096 projections of a layer differ in O alone, which no
-# block shows. A module-level `jit` is traced ONCE for each set of block
-# shapes (and `spec`, K, chunk), whatever kernel instance calls it, and is
-# lowered in line where it is called: chat-steady's 45 `_qmm` instances
-# trace the chunk loop 8 times and the staging twice (PERF.md section 6,
-# PR 63). `natural_columns` is one for the same reason.
+# The two halves and the copy chain as `jit`s of the kernel's refs. A
+# kernel's body is traced anew for every `pallas_call` instance, and a
+# process traces dozens whose blocks have the same shapes: a cell's prefill
+# buckets share a row tile, and the four K = 4096 projections of a layer
+# differ in O alone, which no block shows. A module-level `jit` is traced
+# ONCE for each set of block shapes (and `spec`, K, chunk), whatever kernel
+# instance calls it, and is lowered in line where it is called:
+# chat-steady's 45 `_qmm` instances trace the chunk loop 8 times and the
+# staging twice (PERF.md section 6, PR 63). `natural_columns` is one for
+# the same reason, and so is the copy chain (`copy_tiles_ahead`, PR 64),
+# whose HBM ref does show O: once a weight stack's shape, five times for
+# those 45.
 
 @functools.partial(jax.jit, static_argnames=("spec", "prepared"))
-def stage_tile(w_refs, side_refs, scratch, *, spec: DecodeSpec,
+def stage_tile(w_bufs, slot, side_refs, scratch, *, spec: DecodeSpec,
                prepared: bool = False):
-    """`stage_words`, traced once for blocks of these shapes."""
-    stage_words(spec, w_refs, side_refs, scratch, prepared=prepared)
+    """`stage_words` of the tile in buffer `slot` of each of `w_bufs`
+    (`copy_tiles_ahead`'s), traced once for blocks of these shapes."""
+    stage_words(spec, [b.at[slot] for b in w_bufs], side_refs, scratch,
+                prepared=prepared)
+
+
+def word_buffers(n_w: int, block_o: int, row_bytes: int):
+    """Scratch shapes of `copy_tiles_ahead`: two buffers of a grid step's
+    words for each of the `n_w` stacks (what the pipeline's two byte blocks
+    held: `tiling.words_tile_bytes` stands), then a DMA semaphore a buffer
+    and stack."""
+    return [pltpu.VMEM((2, block_o // WORD_ROWS, row_bytes), jnp.int32)
+            ] * n_w + [pltpu.SemaphoreType.DMA((2, n_w))]
+
+
+def copy_tile(words, bufs, sem, at, lead, start: bool, *, n_o: int,
+              last_rows: int):
+    """Start (or wait for) the copies of grid step `at` of
+    `copy_tiles_ahead`'s chain: word rows `(at % n_o) * rows ..` of
+    `words[lead]` into buffer `at % 2`, for each stack's word view; the
+    last tile's `last_rows` alone where it is ragged (two static copy
+    shapes, picked by the tile)."""
+    rows = bufs[0].shape[1]
+    tile, slot = jax.lax.rem(at, n_o), jax.lax.rem(at, 2)
+
+    def run(n):
+        for i, (w, b) in enumerate(zip(words, bufs)):
+            dma = pltpu.make_async_copy(
+                w.at[(*lead, pl.ds(tile * rows, n), slice(None))],
+                b.at[slot, pl.ds(0, n), :], sem.at[slot, i])
+            dma.start() if start else dma.wait()
+
+    if last_rows == rows:
+        run(rows)
+    else:
+        pl.when(tile < n_o - 1)(lambda: run(rows))
+        pl.when(tile == n_o - 1)(lambda: run(last_rows))
+
+
+@functools.partial(jax.jit, static_argnames=("n_o", "last_rows"))
+def copy_tiles_ahead(stacks, bufs, sem, m, o, n_m, lead, lead_next, *,
+                     n_o: int, last_rows: int):
+    """The forward kernels' code tiles, brought by the kernel's own DMA AS
+    WORDS, one grid step ahead (PR 62's `m`). Each of `stacks` stays in HBM
+    (`pl.ANY`), `[..., O, row_bytes]` bytes viewed as int32 `[..., O / 4,
+    row_bytes]`. Grid step `(m, o)` is step `m * n_o + o` of the `n_m *
+    n_o` that copy (run in order, `n_o` a row tile; `n_m` the row tiles
+    that do: all of a dense call's, the live ones of a grouped call's),
+    and brings a tile of `stack[lead]` (the leading indices: a layer, or a
+    layer and the row tile's expert; `lead_next` those of step + 1) into
+    buffer `step % 2` of that stack's `bufs` (`[2, rows, row_bytes]`
+    int32). A step starts step + 1's copies (where there is one) BEFORE it
+    waits for its own; the first starts both, so nothing but the call's
+    first tile is waited for in full. A ragged last tile (`last_rows` <
+    rows) brings its valid rows alone: the rest of its buffer is whatever
+    it held (`qmatmul._qmm`). Traced once for stacks of one shape, whatever
+    kernel instance calls it (a prefill's buckets, a decode step), the
+    step's arithmetic with it.
+    -> the buffer that holds this step's words, `bufs[i].at[slot]`."""
+    step, n_steps = m * n_o + o, n_m * n_o
+    copy = functools.partial(
+        copy_tile, [w.bitcast(jnp.int32) for w in stacks], bufs, sem, n_o=n_o)
+    # (a call's first tile is whole: a ragged one has a whole one before it)
+    pl.when(step == 0)(
+        lambda: copy(step, lead, True, last_rows=bufs[0].shape[1]))
+    pl.when(step + 1 < n_steps)(
+        lambda: copy(step + 1, lead_next, True, last_rows=last_rows))
+    copy(step, lead, False, last_rows=last_rows)
+    return jax.lax.rem(step, 2)
+
+
+def _interpreter_follows_jits():
+    """The CPU interpreter gives a kernel's semaphores a type XLA has
+    (int16) and carries that into the bodies of `cond`, `scan` and `while`,
+    not into a `jit`'s: `copy_tiles_ahead` takes its semaphores as
+    arguments, so the interpreter is told to follow a `jit` the same way.
+    A compiled kernel never comes here."""
+    from jax._src import pjit
+    from jax._src.pallas import hlo_interpreter as interpreter
+
+    interpreter._eval_jaxpr_hop_rules.setdefault(
+        pjit.jit_p, interpreter.make_hop_rule(pjit.jit_p, "jaxpr"))
+
+
+_interpreter_follows_jits()
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "K", "ck"))
 def product_of_tile(x_ref, scratch, *, spec: DecodeSpec, K: int, ck: int):
-    """`staged_product`, traced once for blocks of these shapes."""
-    return staged_product(spec, K, ck, x_ref, scratch)
+    """`staged_product` with its columns put back (`natural_columns`),
+    traced once for blocks of these shapes: one `jit` an instance calls,
+    not two (a call of a `jit` costs a kernel's trace 2 to 3 ms, as much as
+    `copy_tiles_ahead`'s does: PERF.md section 6, PR 64)."""
+    return natural_columns(staged_product(spec, K, ck, x_ref, scratch))
 
 
 # out[r, l] = x[r, idx[r, l, 0]] on one 128-lane group: the gather

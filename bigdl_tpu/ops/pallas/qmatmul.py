@@ -29,7 +29,10 @@ dequant temporaries to O(block_o * chunk) regardless of K. Where a 512-row tile 
 32-bit words and transposed once (k on sublanes, a block's scale a
 sublane broadcast, docs/kernels.md#word-path), the last tile ragged where
 O is no multiple of 512 (`tiling.ragged_word_tiles`: an LM head's
-vocabulary); elsewhere in the stored layout.
+vocabulary); elsewhere in the stored layout. On the word path the packed
+stack stays in HBM and the kernel copies each tile in itself, as words,
+one grid step ahead (`qdecode.copy_tiles_ahead`): the DMA engine re-tiles
+what the VALU otherwise would.
 
 Layout contract (quant/numerics.py pack_nibbles / pack_planes): the
 m-th split of a b-bit plane is a *contiguous* byte range unpacked with
@@ -71,13 +74,15 @@ from jax.experimental.pallas import tpu as pltpu
 from bigdl_tpu.ops.pallas import qdecode
 from bigdl_tpu.ops.pallas.qdecode import DecodeSpec
 from bigdl_tpu.ops.pallas.tiling import (
-    VMEM_LIMIT_BYTES, WORD_BLOCK_O, finest_split, forward_chunk,
-    lora_operand_bytes, pick_block_m, pick_block_o, round_up, words_ok,
+    VMEM_LIMIT_BYTES, WORD_BLOCK_O, WORD_ROWS, finest_split, forward_chunk,
+    lora_operand_bytes, pick_block_m, pick_block_o, round_up, word_tiles,
+    words_ok,
 )
 
-def _params_parallel():
+def _params(in_order: bool):
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel"),
+        dimension_semantics=("arbitrary",) * 2 if in_order
+        else ("parallel", "parallel"),
         vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
@@ -94,16 +99,18 @@ def _f16_bits(a: jax.Array) -> jax.Array:
 
 def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
             spec: DecodeSpec, lora: bool = False, words: bool = False,
-            prepared: bool = False):
+            prepared: bool = False, O: int = 0):
     """One [block_m, block_o] output tile: acc += x_chunk @ dq(W_chunk)^T
     over chunks of the logical contraction axis (`qdecode.tile_product` in
-    the stored layout; on the word path `qdecode.stage_tile`, then
-    `qdecode.product_of_tile`: each a `jit` of the kernel's refs, traced
-    once for blocks of these shapes whatever instance calls it).
-    `layer_ref` is read by the weight's
-    index map alone: the tile arrives as `[block_o, row_bytes]` whichever
-    layer of the stack it came from. With ``words`` the last three refs
-    are the word path's scratch.
+    the stored layout; on the word path `qdecode.copy_tiles_ahead`,
+    `qdecode.stage_tile`, then `qdecode.product_of_tile`: each a `jit` of
+    the kernel's refs, traced once for refs of these shapes whatever
+    instance calls it). In the stored layout `layer_ref` is read by the
+    weight's index map alone: the tile arrives as `[block_o, row_bytes]`
+    whichever layer of the stack it came from. With ``words`` `w_ref` is
+    the whole stack in HBM and the last five refs are the word path's
+    scratch, the two buffers a tile's words are copied into and their
+    semaphores among them (`O` says where a ragged last tile ends).
 
     With ``lora`` the multi-tenant LoRA epilogue folds into the same
     tile before writeback (the S-LoRA/Punica batched-adapter GEMM,
@@ -114,14 +121,20 @@ def _kernel(layer_ref, x_ref, w_ref, *rest, K: int, ck: int,
     per-row adapter selection AND scale: row m holds scale_m in its own
     adapter group's rank-bucket columns and 0 elsewhere, which is how
     one dot pair serves a heterogeneous multi-tenant batch."""
-    del layer_ref
     if words:
-        *side_refs, o_ref = rest[:-3]
-        scratch = tuple(rest[-3:])
-        qdecode.stage_tile((w_ref,), (tuple(side_refs),), scratch, spec=spec,
-                           prepared=prepared)
-        o_ref[:] = qdecode.natural_columns(qdecode.product_of_tile(
-            x_ref, scratch, spec=spec, K=K, ck=ck)).astype(o_ref.dtype)
+        *side_refs, o_ref = rest[:-5]
+        scratch, wbuf, sem = tuple(rest[-5:-2]), rest[-2], rest[-1]
+        n_o = word_tiles(O)
+        # the grid steps in order, o innermost; every step copies
+        lead = (layer_ref[0],)
+        slot = qdecode.copy_tiles_ahead(
+            (w_ref,), (wbuf,), sem, pl.program_id(0), pl.program_id(1),
+            pl.num_programs(0), lead, lead, n_o=n_o,
+            last_rows=(O - (n_o - 1) * WORD_BLOCK_O) // WORD_ROWS)
+        qdecode.stage_tile((wbuf,), slot, (tuple(side_refs),), scratch,
+                           spec=spec, prepared=prepared)
+        o_ref[:] = qdecode.product_of_tile(
+            x_ref, scratch, spec=spec, K=K, ck=ck).astype(o_ref.dtype)
         return
     o_ref = rest[-1]
     if lora:
@@ -151,15 +164,17 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
          interpret: bool, lora: bool, bits, layer, x2, w, *rest):
     """`w` is the packed codes of a STACK of weights `[L, O, row_bytes]`
     and `layer [1]` int32 the one to multiply by, scalar-prefetched so
-    the index map can name it: the tile's DMA reads layer `layer[0]` out
-    of the whole array. A slice `w[layer]` handed to a Mosaic call would
-    first be copied whole, every call. Everything else (`rest`: scales,
+    the index map (the stored-layout loop's) or the kernel's own copy (the
+    word path's, `qdecode.copy_tiles_ahead`) can name it: the tile's DMA
+    reads layer `layer[0]` out of the whole array. A slice `w[layer]`
+    handed to a Mosaic call would first be copied whole, every call.
+    Everything else (`rest`: scales,
     LoRA operands) is one layer's own rank-2 array, unless ``bits`` names
     the layout of prepared scale bits (`bits_layout`): those keep their
     layer axis too and are read by the same index.
 
     The O grid is `cdiv(O, block_o)`: where the plan is the word path over
-    an O that is no multiple of 512, the last step's code block (and the
+    an O that is no multiple of 512, the last step's code tile (and the
     float16 view of the scales, where nobody prepared bits) is partial:
     the DMA brings its valid rows, the rest of the buffer is whatever it
     held. A row of the tile is a column of the product from the decode to
@@ -213,16 +228,27 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
     # transposed tile was never compiled for the chip)
     words = not lora and words_ok(block_o, w.shape[2])
     assert (bits == "words") <= words, (bits, block_o, w.shape)
-    scratch = qdecode.word_scratch(
-        spec, block_o, w.shape[2], K // spec.block if bits
-        else side[-1].shape[1]) if words else []
+    scratch = []
+    if words:
+        # the codes stay in HBM: the kernel brings a tile as words into one
+        # of two buffers (what the pipeline's two byte blocks held)
+        in_specs[1] = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = qdecode.word_scratch(
+            spec, block_o, w.shape[2], K // spec.block if bits
+            else side[-1].shape[1]) + qdecode.word_buffers(
+                1, block_o, w.shape[2])
+        if interpret:
+            # XLA:CPU folds the interpreter's word view of a stack that is
+            # a CONSTANT of the jit around the call to wrong words,
+            # silently (PR 62); a compiled kernel never sees it
+            w = jax.lax.optimization_barrier(w)
     # grid order (m, o): o innermost, so the x tile stays resident across
     # a full sweep of weight tiles and packed weights are re-fetched only
     # once per M tile (the roofline model in benchmark/roofline.py
-    # assumes exactly this fetch pattern)
+    # assumes exactly this fetch pattern; the word path's copies keep it)
     return pl.pallas_call(
         functools.partial(_kernel, K=K, ck=ck, spec=spec, lora=lora,
-                          words=words, prepared=bits == "words"),
+                          words=words, prepared=bits == "words", O=O),
         name="qmatmul_lora" if lora else "qmatmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -238,7 +264,8 @@ def _qmm(spec, out_dtype, block_m: int, block_o: int, ck: int,
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, O), out_dtype),
-        compiler_params=_params_parallel(),
+        # (the word path's copies name the NEXT step's tile: in order)
+        compiler_params=_params(words),
         interpret=interpret,
     )(layer, x2, w, *rest)
 

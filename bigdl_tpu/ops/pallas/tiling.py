@@ -79,7 +79,9 @@ WORD_BLOCK_O = 512
 
 def words_tile_bytes(row_bytes: int, persist_per_row: int) -> int:
     """VMEM a word-path tile holds across its chunk loop: the packed
-    blocks as the pipeline keeps them (twice), the transposed words (the
+    codes twice (the two buffers `qdecode.copy_tiles_ahead` copies tiles
+    into, what the pipeline's two byte blocks held) and the side blocks as
+    the pipeline keeps them (twice), the transposed words (the
     code bytes again) and the scales staged and transposed in float32
     (8 bytes for every stored side byte covers the f16 and the 8-bit
     sub-scale formats, lane padding included at real widths)."""

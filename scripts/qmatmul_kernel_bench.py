@@ -9,6 +9,7 @@ through the chip tool:
     python scripts/qmatmul_kernel_bench.py --plan experts --variants tree shared
     python scripts/qmatmul_kernel_bench.py --plan ragged    # O no multiple of 512
     python scripts/qmatmul_kernel_bench.py --plan ahead     # one set / ahead / unstaged
+    python scripts/qmatmul_kernel_bench.py --plan dma       # the tile copied in as words
     python scripts/qmatmul_kernel_bench.py --lower   # compile only, no chip
 
 Each line is one (body, variant, K, O, M): 64 dependent calls inside one
@@ -60,7 +61,16 @@ Bodies:
   tile o - 1 out of the other (the sets static, by the step's parity, both
   ends peeled: the best of the forms PR 63 measured, and slower than one
   set); `g` no `natural_columns` (the columns stored
-  pack-major); `c` nothing computed (tiles fetched, output zero);
+  pack-major); `c` nothing computed (tiles fetched, output zero). `--plan
+  dma` (PR 62's `m`, in the tree since PR 64): `p` the scales as prepared
+  bits `[nb, 512]` (what a cell's tree reads since PR 48; the copy's default
+  is the stored `[512, nb]`, staged and turned), so that `s-p` is the word
+  path as the tree ran it until PR 64, the code block a pipelined uint8
+  block that `pltpu.bitcast` re-lays on the VALU; `m` the stack left in HBM
+  and each tile copied in AS WORDS by the kernel's own DMA, one grid step
+  ahead, into two `(8, 128)` int32 buffers (`words_by_dma`, the chain
+  written out in the kernel; the tree's `prep` holds the same chain in
+  `qdecode.copy_tiles_ahead`); `m-t` prices what staging is left;
 * `rows`: the copy of the loop as it was before PR 32 (and still is where
   no 512-row tile fits, and in `qbackward`): stored [o, k] layout, scales
   spread over lanes by a float32 one-hot matmul per chunk. Variants `d`,
@@ -168,17 +178,27 @@ def stage_copy(w_refs, s_refs, scratch, flags):
     """`qdecode.stage_words` for sym_int4's stored scales (one 512-row block,
     or a gated pair's two 256-row blocks), as it was before PR 49. `i` and
     `s` flip the nibbles' top bits on the way (the field is then `code - 8`
-    in two's complement), and `i` has the scales carry 2^-28."""
+    in two's complement), and `i` has the scales carry 2^-28. `p`: the
+    scale block is `qdecode.pack_major_bits`'s `[nb, 512]` (a load and the
+    float16 decode, no transpose). `m`: the code refs hold words already
+    (`words_by_dma`), where a byte block is viewed as words on the VALU."""
     wT_ref, s32_ref, sT_ref = scratch
     row_bytes = w_refs[0].shape[1]
-    q = sum(r.shape[0] for r in w_refs) // 4
+    q = wT_ref.shape[1]
     for j0 in range(0, row_bytes, 2048):
         cw = min(2048, row_bytes - j0)
-        words = [pltpu.bitcast(r[:, j0:j0 + cw], jnp.int32) for r in w_refs]
+        words = [r[:, j0:j0 + cw] if "m" in flags
+                 else pltpu.bitcast(r[:, j0:j0 + cw], jnp.int32)
+                 for r in w_refs]
         words = words[0] if len(words) == 1 else jnp.concatenate(words, axis=0)
         wT_ref[j0:j0 + cw, :] = (words ^ FLIP if flags & {"i", "s"}
                                  else words).T
     a = [qdecode.f16_bits_to_f32(r[...]) for r in s_refs]
+    if "p" in flags:
+        (a,) = a
+        sT_ref[0, :a.shape[0], :] = (a * jnp.float32(2.0 ** -28)
+                                     if "i" in flags else a)
+        return
     a = qdecode._pad_lanes(a[0] if len(a) == 1 else jnp.concatenate(a, axis=0))
     if "i" in flags:
         a = a * jnp.float32(2.0 ** -28)
@@ -269,6 +289,31 @@ def words_body(x_ref, w_ref, s_ref, scratch, *, K, ck, flags):
     return words_product(x_ref, scratch, K=K, ck=ck, flags=flags)
 
 
+def words_by_dma(layer_ref, w_hbm, wbuf, sem, n_o):
+    """`m`: the code tile brought by the kernel's own DMA as WORDS. The stack
+    stays in HBM (`pl.ANY`), its REF viewed as int32 `[L, O / 4, row_bytes]`
+    (XLA's `(8, 128)(4, 1)` byte tiles are `(2, 128)` word tiles), and a
+    tile's `[128, row_bytes]` words are copied into one of two `(8, 128)`
+    tiled buffers: the DMA engine does the re-tiling that costs the VALU
+    seven operations a vreg. Tile s + 1 is asked for before tile s is waited
+    for; the call's first step asks for both. The tree's chain
+    (`qdecode.copy_tiles_ahead`) written out in the kernel.
+    -> the buffer that holds this step's words."""
+    step = pl.program_id(0) * n_o + pl.program_id(1)
+    words = w_hbm.bitcast(jnp.int32)
+
+    def copy(at):
+        return pltpu.make_async_copy(
+            words.at[layer_ref[0], pl.ds((at % n_o) * 128, 128), :],
+            wbuf.at[at % 2], sem.at[at % 2])
+
+    pl.when(step == 0)(lambda: copy(step).start())
+    pl.when(step + 1 < pl.num_programs(0) * n_o)(
+        lambda: copy(step + 1).start())
+    copy(step).wait()
+    return wbuf.at[step % 2]
+
+
 def ahead_body(x_ref, w_ref, s_ref, o_ref, scratch, *, K, ck, flags, n):
     """`h`: step o of n + 1 stages tile o and multiplies tile o - 1, the two
     scratch sets picked by the step's parity (static), both ends peeled."""
@@ -299,8 +344,10 @@ def ahead_body(x_ref, w_ref, s_ref, o_ref, scratch, *, K, ck, flags, n):
 
 def _kernel(layer_ref, x_ref, w_ref, s_ref, o_ref, *scratch, K, ck, body,
             variant, n=0):
-    del layer_ref
     flags = flags_of(variant)
+    if "m" in flags:
+        *scratch, wbuf, sem = scratch
+        w_ref = words_by_dma(layer_ref, w_ref, wbuf, sem, n)
     if "c" in flags:
         o_ref[:] = jnp.zeros(o_ref.shape, o_ref.dtype)
         return
@@ -330,10 +377,15 @@ def qmm_copy(layer, x2, w, s, *, block_m, block_o, ck, body, variant):
     # `h`: one step more; the weight-side blocks held at the last tile for
     # it (a repeated block index is not fetched again), the output block
     # one step behind (not written back in between), two scratch sets
-    ahead = "h" in flags_of(variant)
+    flags = flags_of(variant)
+    ahead = "h" in flags
     tile = (lambda o: jnp.minimum(o, n - 1)) if ahead else (lambda o: o)
-    scratch = (qdecode.word_scratch(SPEC, block_o, w.shape[2], s.shape[1])
+    scratch = (qdecode.word_scratch(SPEC, block_o, w.shape[2], K // 32)
                * (2 if ahead else 1) if body == "words" else [])
+    if "m" in flags:  # two buffers of a tile's words, a semaphore each
+        scratch = [*scratch,
+                   pltpu.VMEM((2, block_o // 4, w.shape[2]), jnp.int32),
+                   pltpu.SemaphoreType.DMA((2,))]
     return pl.pallas_call(
         functools.partial(_kernel, K=K, ck=ck, body=body, variant=variant,
                           n=n),
@@ -343,8 +395,13 @@ def qmm_copy(layer, x2, w, s, *, block_m, block_o, ck, body, variant):
             grid=(Mp // block_m, n + ahead),
             in_specs=[
                 pl.BlockSpec((block_m, K), lambda m, o, l: (m, 0)),
+                pl.BlockSpec(memory_space=pl.ANY) if "m" in flags else
                 pl.BlockSpec((None, block_o, w.shape[2]),
                              lambda m, o, l: (l[0], tile(o), 0)),
+                # `p`: prepared bits [L, tiles, nb, 512], the tile's block
+                pl.BlockSpec((None, None, *s.shape[2:]),
+                             lambda m, o, l: (l[0], tile(o), 0, 0))
+                if "p" in flags else
                 pl.BlockSpec((block_o, s.shape[1]),
                              lambda m, o, l: (tile(o), 0)),
             ],
@@ -357,9 +414,10 @@ def qmm_copy(layer, x2, w, s, *, block_m, block_o, ck, body, variant):
         out_shape=jax.ShapeDtypeStruct((Mp, O), jnp.bfloat16),
         compiler_params=pltpu.CompilerParams(
             # `t` stages on the first step alone, `h` carries a staged
-            # tile to the next step: the steps run in order
+            # tile to the next step, `m` asks for the next step's tile: the
+            # steps run in order
             dimension_semantics=("arbitrary",) * 2
-            if flags_of(variant) & {"t", "h"} else ("parallel", "parallel"),
+            if flags & {"t", "h", "m"} else ("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(layer, x2, w, s)
 
@@ -367,6 +425,13 @@ def qmm_copy(layer, x2, w, s, *, block_m, block_o, ck, body, variant):
 # the bodies that are the tree's `_qmm` on prepared scale bits, and the
 # layout each reads (`qmatmul.bits_layout`)
 BITS = {"prep": "words", "ragged": "words", "loop": "stored"}
+
+
+def bits_of(body, variant):
+    """The layout of the scale bits a case reads (`operands`), or None for
+    one layer's stored `[O, nb]`: a tree body's own, `p` of the copy."""
+    return "words" if body == "words" and "p" in flags_of(variant) \
+        else BITS.get(body)
 
 
 def tiles(body, M, K, O):
@@ -442,7 +507,7 @@ def measure(body, variant, M, K, O, key, ns=(16, 32, 64), reps=3):
     if built is None:
         return None
     run, _, (block_m, block_o, ck) = built
-    x, w, s = operands(M, K, O, block_m, key, prepared=BITS.get(body))
+    x, w, s = operands(M, K, O, block_m, key, prepared=bits_of(body, variant))
     jax.block_until_ready(run(2, x, w, s))
     ts = []
     for n in ns:
@@ -563,6 +628,30 @@ def product_check():
         yield dict(check="product_vs_xla", M=M, K=K, O=O,
                    worst=float(jnp.abs(y - want).max()),
                    of=float(jnp.abs(want).max()))
+
+
+def dma_check():
+    """The tree's `_qmm` on prepared bits (its tiles copied in as words,
+    PR 64) and this script's `s-p-m` against `s-p`, the same word path on a
+    pipelined byte block (the tree until PR 64), on the same operands, on
+    the device: bit for bit. One M tile and two, float16's corners among
+    the scales."""
+    cases = (("words", "s-p"), ("prep", "d"), ("words", "s-p-m"))
+    for M, K, O in ((32, 4096, 1536), (8, 14336, 1024), (512, 4096, 2048)):
+        ys = []
+        for body, v in cases:
+            _, call, (block_m, _, _) = build(body, v, M, K, O)
+            x, w, s = operands(M, K, O, block_m, jax.random.key(O + M),
+                               prepared="words")
+            s = s.at[:, :, :2, :8].set(jnp.asarray(
+                [1, 0x03FF, 0x8001, 0, 0x8000, 0x0400, 0x7BFF, 0xFBFF],
+                jnp.uint16))
+            ys.append(np.asarray(call(jnp.ones((1,), jnp.int32), x, w, s
+                                      ).astype(jnp.float32)))
+        yield dict(check="dma_vs_s_p", M=M, K=K, O=O,
+                   variants=["-".join(c) for c in cases[1:]],
+                   mismatched=[int((y != ys[0]).sum()) for y in ys[1:]],
+                   of=int(ys[0].size))
 
 
 def ragged_check():
@@ -875,22 +964,30 @@ def experts_check():
     """Each variant against the tree's kernel on the same operands, on the
     device (granite's two shapes, rows of live tiles): the same bf16
     weights into the same float32 sums."""
-    for K, O, gated in EXPERT_SHAPES["granite"][4]:
-        _, hit, args = experts_operands("granite", K, O, gated,
-                                        jax.random.key(3))
-        block_m = args[2].shape[0] // args[0].shape[0]
-        want = experts_build("tree", "granite", K, O, gated)[1](*args)
-        want = np.asarray(want[:hit * block_m].astype(jnp.float32))
-        for v in ("loop", "paired", "mb", "one",
-                  *(c for c in COPIES if c != "unstaged")):  # (a time only)
-            built = experts_build(v, "granite", K, O, gated)
-            if built is None:
-                continue
-            got = np.asarray(built[1](*args)[:hit * block_m
-                                             ].astype(jnp.float32))
-            yield dict(check="experts_vs_tree", variant=v, K=K, O=O,
-                       worst=float(np.abs(got - want).max()),
-                       of=float(np.abs(want).max()))
+    # (`signed` is the tree's own arithmetic on PIPELINED byte blocks, where
+    # the tree copies its tiles in as words since PR 64: 0 mismatched is the
+    # grouped kernel's bit check; Mixtral's is a tile a grid step over many
+    # steps, the chain of copies)
+    variants = {"granite": ("loop", "paired", "mb", "one", *(
+        c for c in COPIES if c != "unstaged")),  # (a time only)
+                "mixtral": ("signed",)}
+    for name, vs in variants.items():
+        for K, O, gated in EXPERT_SHAPES[name][4]:
+            _, hit, args = experts_operands(name, K, O, gated,
+                                            jax.random.key(3))
+            block_m = args[2].shape[0] // args[0].shape[0]
+            want = experts_build("tree", name, K, O, gated)[1](*args)
+            want = np.asarray(want[:hit * block_m].astype(jnp.float32))
+            for v in vs:
+                built = experts_build(v, name, K, O, gated)
+                if built is None:
+                    continue
+                got = np.asarray(built[1](*args)[:hit * block_m
+                                                 ].astype(jnp.float32))
+                yield dict(check="experts_vs_tree", cell=name, variant=v,
+                           K=K, O=O, worst=float(np.abs(got - want).max()),
+                           mismatched=int((got != want).sum()),
+                           of=float(np.abs(want).max()))
 
 
 def experts_plan():
@@ -957,6 +1054,15 @@ def plan_of(name):
                 for M in (1, 32, 256)
                 for b, v in (("prep", "d"), ("words", "s"), ("words", "s-h"),
                              ("words", "s-t"))]
+    if name == "dma":  # the tree (tiles copied in as words), the copy on a
+        # pipelined byte block, on its own copies, and what staging is left
+        for K, O, Ms in ((14336, 4096, (32, 1, 256, 1024)),
+                         (4096, 28672, (32, 256, 1024)), (4096, 6144, (32,)),
+                         (4096, 4096, (32,)), (18944, 3584, (16,))):
+            for M in Ms:
+                plan += [("prep", "d", M, K, O)] + [
+                    ("words", "s-p" + v, M, K, O) for v in ("", "-m", "-m-t")]
+        return plan
     if name == "mistral":  # every variant, at the cells' M
         for K, O in shapes["mistral-7b-int4"][0]:
             for M in (1, 8, 16, 32):
@@ -983,7 +1089,7 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--plan", default="cells",
                     choices=("cells", "mistral", "quick", "forms", "experts",
-                             "ragged", "ahead"))
+                             "ragged", "ahead", "dma"))
     ap.add_argument("--lower", "--fit", action="store_true",
                     help="compile the plan for a described v5e; no chip")
     ap.add_argument("--variants", nargs="+",
@@ -1018,8 +1124,8 @@ def main() -> int:
                 continue
             run, _, (block_m, _, _) = built
             run.lower(jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
-                      *operands(M, K, O, block_m, None, one, BITS.get(body))
-                      ).compile()
+                      *operands(M, K, O, block_m, None, one,
+                                bits_of(body, v))).compile()
             print(f"ok {body} {v} M={M} K={K} O={O}", flush=True)
         return 0
 
@@ -1036,6 +1142,7 @@ def main() -> int:
             results = (experts_measure(*case, key) for case in plan)
         else:
             checks = (ragged_check() if args.plan == "ragged"
+                      else dma_check() if args.plan == "dma"
                       else (*fed_weights_check(), *product_check()))
             times = (dict(ns=(64, 128, 256), reps=5)
                      if args.plan == "ragged" else {})

@@ -251,10 +251,24 @@ KERNEL_MODELS = dict(
 # equations of `engine_decode` with the kernels on (the interpreter's route):
 # (the tree as `optimize_model` makes it, the tree prepared). Mixtral's were
 # (531, 523) until ISSUE 53 took the sort out of a decode step's dispatch,
-# and (487, 479) until ISSUE 60's k-major combine (`MOE_KERNEL_ROUTE` below)
+# and (487, 479) until ISSUE 60's k-major combine (`MOE_KERNEL_ROUTE` below).
+# Re-pinned by ISSUE 64, on purpose, from (431, 427) and (490, 482): the word
+# path copies its code tiles in as words by the kernel's own DMA, and through
+# the INTERPRETER (this route) the packed stack passes an
+# `optimization_barrier` in front of every such call (XLA:CPU folds the
+# interpreter's word view of a constant stack to wrong words): one equation a
+# word-path call, three in the tiny Mistral's program and four in the tiny
+# Mixtral's, and NONE in a program traced for the chip (`interpret` unset),
+# whose equations outside the kernels' bodies are the parent's. Warm
+# `setup_s`, as this file asks, parent -> change, alternating pairs
+# (my chip runs, PR 64): `mistral-7b.chat-steady` 32.07 / 31.04 / 32.47 /
+# 31.84 -> 32.40 / 32.50 / 31.54 / 32.40 s, `mistral-7b.generate-1024-128`
+# 23.76 -> 24.13 over six pairs, `sdar-30b-a3b.blockgen-closed` 52.64 ->
+# 53.27 over four: the kernels' own traces, not these equations (PERF.md
+# section 6, PR 64).
 KERNEL_ROUTE = {
-    "mistral": (431, 427),
-    "mixtral": (490, 482),
+    "mistral": (434, 430),
+    "mixtral": (494, 486),
 }
 
 
@@ -424,13 +438,13 @@ def test_glms_decode_step_calls_the_latent_kernel_a_grid_step_a_row(
 # so that no `[N, k, H]` float32 is re-laid: tests/test_moe.py (f)); the
 # counts before it are the comment's second number.
 MOE_KERNEL_ROUTE = {
-    "mixtral": 482,  # 523, 479
+    "mixtral": 486,  # 523, 479; 482 until ISSUE 64 (`KERNEL_ROUTE` above)
     "glm4_moe_lite": 889,  # 930, 886
     "smallthinker": 1385,  # 1549, 1373
     "laguna": 2937,  # 3224, 2916
     "sdar_moe": 549,  # 593, 546
     "granitemoehybrid": 1257,  # 1380, 1248
-    "lfm2_moe": 1624,  # PR 61's own
+    "lfm2_moe": 1635,  # PR 61's own 1624, and ISSUE 64's barriers
 }
 
 

@@ -599,6 +599,71 @@ def test_grouped_kernel_on_the_word_path(interpret, K, O, act, bm):
             got, np.asarray(mq.FUSED_ACTS[act](g) * u), rtol=0, atol=5e-5)
 
 
+# (K, O, act, layers): the walks of SEVERAL word tiles a grid step whose
+# code tiles the kernel brings itself as words (ISSUE 64): two tiles, GLM's
+# gated three of two products each, the five and the eight of the 768-wide
+# experts' down projections, the paired three out of a layered stack
+_WALKS = [(2048, 1024, None, 0), (2048, 1536, "silu", 0), (768, 2560, None, 0),
+          (768, 4096, None, 3), (2560, 768, "silu", 3)]
+
+
+@pytest.mark.parametrize("prepared", (False, True),
+                         ids=("staged", "prepared"))
+@pytest.mark.parametrize("K,O,act,layers", _WALKS)
+def test_grouped_walk_brings_its_tiles_as_words(interpret, K, O, act, layers,
+                                                prepared):
+    """ISSUE 64: the word forms' codes stay in HBM and every live grid step
+    copies its expert's tiles, as int32 words, one live step ahead
+    (`qdecode.copy_tiles_ahead`). Live tiles of three experts in a row (the
+    chain of copies crosses experts), an expert with no rows, a dead tile
+    past the tiles in use (it copies nothing and nothing waits for it), a
+    traced layer of a stack that is a constant of the jit around the call:
+    every row is x @ dq(W[layer, expert])^T, and the first and the last
+    live row tile (the chain's first copy, and the one after the empty
+    expert) are, BIT FOR BIT, the written-out one-set kernel's product of
+    their expert on pipelined byte blocks, on prepared scale bits and on
+    the float16 fields alike."""
+    from bigdl_tpu.ops.linear import prepare_scale_bits
+    from bigdl_tpu.ops.pallas import moe_qmatmul as mq
+
+    E, bm, gated = 4, 8, act is not None
+    groups = [5, 0, 9, 2]
+    lead = (layers,) if layers else ()
+    bare = [quantize(jax.random.normal(jax.random.PRNGKey(i),
+                                       (*lead, E, O, K)) * K ** -0.5,
+                     "sym_int4") for i in range(2 if gated else 1)]
+    held = {1024: 2, 1536: 3, 2560: 5, 4096: 8, 768: 3}[O]
+    plan = mq.call_plan(bare if gated else bare[0])
+    assert plan.endswith(f"of {held} tiles"), plan
+    ws = [prepare_scale_bits(w, len(bare)) for w in bare] if prepared else bare
+    assert all((w.scale_bits is not None) == prepared for w in ws)
+    experts = np.repeat(np.arange(E), groups).astype(np.int32)
+    N = len(experts)
+    n_tiles = mq.moe_n_tiles(N, 1, E, bm)
+    dest, src, te, n_used = mq.moe_layout(
+        jnp.asarray(experts)[:, None], E, bm, n_tiles)
+    assert 3 < int(n_used) < n_tiles
+    x = jax.random.normal(jax.random.PRNGKey(7), (N, K)).astype(jnp.bfloat16)
+    layer = layers - 1 if layers else None
+    call = jax.jit(lambda x, l: mq.moe_qmatmul(
+        x, ws if gated else ws[0], te, n_used, bm, act=act, layer=l,
+        out_dtype=jnp.float32))
+    y = call(x[src], layer)
+    pick = (lambda a: a[layer]) if layers else (lambda a: a)
+    per = [jnp.einsum("nk,nok->no", x,
+                      pick(w.dequantize(jnp.bfloat16))[experts],
+                      preferred_element_type=jnp.float32) for w in bare]
+    want = mq.FUSED_ACTS[act](per[0]) * per[1] if gated else per[0]
+    np.testing.assert_allclose(np.asarray(y[dest[:, 0]]), np.asarray(want),
+                               rtol=0, atol=5e-5)
+    flat = [jax.tree.map(pick, w) for w in bare]
+    for m in (0, int(n_used) - 1):
+        rows = slice(m * bm, (m + 1) * bm)
+        _same_bits(y[rows], _one_set_expert_tile(
+            x[src][rows], flat, int(te[m]), act, ":paired" in plan),
+            (m, int(te[m])))
+
+
 def test_grouped_tile_chooses_from_shapes_alone():
     """`tiling.grouped_tile`: the paired tile for two stacks at a multiple
     of 256 that is not one of 512, today's plan everywhere else (Mixtral's

@@ -79,6 +79,58 @@ def _poisoned_past(qt, O):
                        constant_values=jnp.nan))
 
 
+# ---- the code tiles brought as words, by the kernel's own DMA (ISSUE 64) ----
+
+@pytest.mark.parametrize("prepared", (False, True),
+                         ids=("staged", "prepared"))
+@pytest.mark.parametrize("stacked", (False, True), ids=("plain", "stacked"))
+@pytest.mark.parametrize("M", (8, 512), ids=("one-M-tile", "two-M-tiles"))
+@pytest.mark.parametrize("O", (512, 1024, 1536, 1152),
+                         ids=("one-tile", "two", "three", "ragged"))
+def test_word_tiles_brought_as_words(interpret, O, M, stacked, prepared):
+    """The word path's codes stay in HBM and `qdecode.copy_tiles_ahead`
+    copies a tile's words into one of two buffers one grid step ahead. One
+    tile (no step to run ahead of), two, an odd number (the buffers' parity
+    turns over at an M tile's end), a ragged last tile (its valid rows
+    alone are copied); two M tiles (the sweep starts again while the last
+    tile's buffer is still read); a traced layer of a stack, the weights
+    constants of the jit around it (the interpreter's barrier); scales
+    staged and prepared. The reference's product at the file's tolerance,
+    and BIT FOR BIT the written-out one-set kernel on pipelined byte blocks
+    that `tests/test_qdecode_words.py` holds, one M tile at a time."""
+    import dataclasses
+
+    from test_qdecode_words import _one_set
+
+    from bigdl_tpu.ops.linear import prepare_scale_bits
+    from bigdl_tpu.quant.qtensor import without_scale_bits
+
+    K, L = 512, 2
+    qt = quantize(jax.random.normal(jax.random.PRNGKey(O + M), (L, O, K))
+                  * K ** -0.5, "sym_int4")
+    if prepared:
+        qt = prepare_scale_bits(qt)
+        assert qt.bits_layout == "words"
+    one = jax.tree.map(lambda a: a[L - 1], qt)
+    x = jax.random.normal(jax.random.PRNGKey(M), (M, K)).astype(jnp.bfloat16)
+    if stacked:
+        # (the codes of the whole stack; float16 scales are the layer's own)
+        w = qt if prepared else dataclasses.replace(qt, scales=one.scales)
+        call = jax.jit(lambda x, l: qmatmul(x, w, out_dtype=jnp.float32,
+                                            layer=l))
+        y = np.asarray(call(x, L - 1))
+    else:
+        y = np.asarray(qmatmul(x, one, out_dtype=jnp.float32))
+    assert y.shape == (M, O)
+    bare = without_scale_bits(one)
+    want = jnp.dot(x, bare.dequantize(jnp.bfloat16).T,
+                   preferred_element_type=jnp.float32)
+    np.testing.assert_allclose(y, np.asarray(want), rtol=0, atol=5e-5)
+    halves = [x] if M == 8 else [x[:256], x[256:]]
+    np.testing.assert_array_equal(
+        y, np.concatenate([np.asarray(_one_set(h, bare)) for h in halves]))
+
+
 @pytest.mark.parametrize("K,O,M", RAGGED)
 def test_ragged_word_tile_at_the_heads_remainders(interpret, K, O, M):
     """An O of whole lanes that is no multiple of 512 runs the word path
