@@ -177,6 +177,13 @@ class TpuModel:
         from bigdl_tpu.parallel.sharding import param_specs
         from bigdl_tpu.quant.qtensor import without_scale_bits
 
+        if getattr(self.config, "expert_share", None) is not None:
+            first, held, width = self.config.expert_share
+            raise NotImplementedError(
+                f"{self.config.model_type} holds one rank's share of its "
+                f"experts ({held} of {width} from {first}): under a mesh "
+                "the share is shard_map's to make, from a tree that holds "
+                "every expert (ROADMAP B2)")
         self.params = without_scale_bits(self.params)
         if mesh is None:
             n = len(jax.devices())

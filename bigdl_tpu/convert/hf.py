@@ -46,6 +46,8 @@ _QUANT_TARGETS = {
     "w_dq", "w_uq", "w_dkv",
     # the Mamba-2 mixer's projections (models/granitemoehybrid.py)
     "w_in", "w_out",
+    # a mixer's output gate (models/minicpm_sala.py, models/solar_open2.py)
+    "wg",
 }
 
 Get = Callable[[str], np.ndarray]
@@ -1368,6 +1370,73 @@ def _lfm2_moe_tree(config: ModelConfig, get: Get, quant
     return runs, top
 
 
+def _solar_open2_tree(config: ModelConfig, get: Get, quant
+                      ) -> tuple[list, dict]:
+    """Solar-Open2. Returns (one list of per-layer dicts for each RUN of
+    layers of one kind, top), `quant` applied as tensors stream in. The
+    tensor names are WRITTEN FROM MEMORY of Kimi Linear's
+    `KimiDeltaAttention` and of the DeepSeek-V3 family's expert block, whose
+    config keys the source carries; its modeling file is not in the
+    repository (a checkpoint that names them otherwise fails here by the
+    missing name, not in silence). Both mixers under `self_attn.`: `q_proj`,
+    `k_proj`, `v_proj`, `o_proj`; a GQA layer's gate `g_proj`; a KDA layer's
+    three `{q,k,v}_conv1d.weight [H * D, 1, K]` become ONE `conv_w [K, 3 * H
+    * D]` (w[K - 1] the current input; q | k | v side by side), float32, as
+    `A_log`, `dt_bias` and `g_b_proj.bias` (`g_bias`) are; `f_a_proj` /
+    `f_b_proj`, `g_a_proj` / `g_b_proj` and `b_proj` (`w_beta`) stay dense.
+    `mlp.gate.weight` is the router and `mlp.gate.e_score_correction_bias`
+    the selection bias, both float32 and over the router's WHOLE width;
+    `mlp.experts.<e>.*` are read for the experts HELD here alone
+    (`ModelConfig.first_expert` on), `mlp.shared_experts.*` whole."""
+    from bigdl_tpu.models.solar_open2 import ATTENTION, layer_runs
+
+    def f32(x):
+        return jnp.asarray(np.asarray(x, np.float32))
+
+    def one(i: int, kind: str) -> dict:
+        p, a, m = (f"model.layers.{i}.", f"model.layers.{i}.self_attn.",
+                   f"model.layers.{i}.mlp.")
+        d = {"attn_norm": get(p + "input_layernorm.weight"),
+             "mlp_norm": get(p + "post_attention_layernorm.weight"),
+             "wq": get(a + "q_proj.weight"), "wk": get(a + "k_proj.weight"),
+             "wv": get(a + "v_proj.weight"), "wo": get(a + "o_proj.weight")}
+        exact = {"router": f32(get(m + "gate.weight")),
+                 "e_bias": f32(get(m + "gate.e_score_correction_bias"))}
+        if kind == ATTENTION:
+            d["wg"] = get(a + "g_proj.weight")
+        else:
+            d.update(f_a=get(a + "f_a_proj.weight"),
+                     f_b=get(a + "f_b_proj.weight"),
+                     g_a=get(a + "g_a_proj.weight"),
+                     g_b=get(a + "g_b_proj.weight"),
+                     w_beta=get(a + "b_proj.weight"),
+                     o_norm=get(a + "o_norm.weight"))
+            exact.update(
+                conv_w=f32(np.concatenate([
+                    np.asarray(get(f"{a}{n}_conv1d.weight"))[:, 0].T
+                    for n in "qkv"], axis=1)),
+                A_log=f32(get(a + "A_log")), dt_bias=f32(get(a + "dt_bias")),
+                g_bias=f32(get(a + "g_b_proj.bias")))
+        held = range(config.first_expert,
+                     config.first_expert + config.num_experts)
+        for ours, theirs in (("w_gate_e", "gate_proj"), ("w_up_e", "up_proj"),
+                             ("w_down_e", "down_proj")):
+            d[ours] = np.stack([np.asarray(
+                get(f"{m}experts.{e}.{theirs}.weight")) for e in held])
+            if config.n_shared_experts:
+                d[ours[:-1] + "s"] = get(
+                    f"{m}shared_experts.{theirs}.weight")
+        return {**{k: quant(k, v) for k, v in d.items()}, **exact}
+
+    runs, i = [], 0
+    for kind, _, n in layer_runs(config):
+        runs.append([one(i + j, kind) for j in range(n)])
+        i += n
+    return runs, {"embed": get("model.embed_tokens.weight"),
+                  "final_norm": get("model.norm.weight"),
+                  "lm_head": get("lm_head.weight")}
+
+
 def _minicpm_sala_tree(config: ModelConfig, get: Get, quant
                        ) -> tuple[list, dict]:
     """MiniCPM-SALA. Returns (one list of per-layer dicts for each RUN of
@@ -1601,7 +1670,8 @@ def params_from_state_dict(
 
     by_runs = {"granitemoehybrid": _granitemoehybrid_tree,
                "minicpm_sala": _minicpm_sala_tree, "jamba": _jamba_tree,
-               "lfm2_moe": _lfm2_moe_tree}
+               "lfm2_moe": _lfm2_moe_tree,
+               "solar_open2": _solar_open2_tree}
     if config.model_type in by_runs:
         runs, top = by_runs[config.model_type](config, get_tensor,
                                                maybe_quant)

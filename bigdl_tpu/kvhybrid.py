@@ -39,6 +39,16 @@ recurrence at all: its state is the tail alone,
     conv [Lc, R, (L_cache - 1) * H]  laid as Mamba-1's, `conv_rows` 1
     ssm  None
 
+A Kimi delta attention layer (Solar-Open2, `models/solar_open2.py`;
+`kda_mix` below) keeps the tails of THREE convolutions (q, k, v) and a matrix
+state a head, laid as lightning's is (kvsparse.py):
+
+    conv [Lk, R, (K - 1) * 3 * H * D]  laid as Mamba-1's, `conv_rows` 1
+    ssm  [Lk, R, H * D, D]             `ssm[.., h * D + p, n]` = S_h[n, p]:
+                                       the value index on sublanes, the key
+                                       index on lanes, so a key channel's
+                                       decay is a row broadcast down
+
 Everything that books, parks, restores or counts a row (`row_nbytes`,
 `n_rows`, `_spots`, `axes_of`, `row_view`, the spans) reads what is there:
 sizes, the row axis, and no array where the family has none. What a prefill
@@ -69,6 +79,18 @@ and dt a CHANNEL's:
 has no head and no matrix-product form: both a decode step and a one-row
 prefill run it through the kernels of ops/pallas/selective_scan.py (the
 state in place), else token by token in `jnp` (`scan1`).
+
+The gated delta rule of one KDA head, S in R^{N x P} (key index n, value
+index p), g_t <= 0 a log-decay a KEY CHANNEL and beta_t in (0, 2):
+
+    S' = diag(exp(g_t)) S_{t-1}
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T          o_t = S_t^T q_t
+
+reads the state before it writes it, so its transition is not diagonal. A
+decode step runs it through `kda_decode` (ops/pallas/mamba2.py: two passes
+over a head's block while it sits in VMEM, one read and one write of it in
+HBM), else `kda_step`; a prefill runs the chunked form `kda_chunked` on the
+XLA route under the scope `kda_prefill`.
 """
 
 from __future__ import annotations
@@ -505,6 +527,192 @@ def mix1(cache: HybridCache, layer, u, p, *, dt_rank: int, d_state: int,
             ssm = cache.ssm.at[layer, to].set(h, mode="drop")
     y = y + p["D"] * x
     return y, dataclasses.replace(cache, conv=conv, ssm=ssm)
+
+
+# ---------------------------------------------------------------------------
+# Kimi delta attention (KDA): a gated delta rule's state arithmetic
+# ---------------------------------------------------------------------------
+
+KDA_CHUNK = 64  # tokens of one chunk of the prefill's form
+KDA_SUB = 16  # ... and of one sub-block of its decay products
+
+
+def kda_step(q, k, v, g, beta, S):
+    """One token in `jnp`. q, k [B, H, N] (q scaled, both L2-normalised), v
+    [B, H, P], g [B, H, N] <= 0 the log-decay a KEY CHANNEL, beta [B, H], S
+    [B, H, P, N] (the value index before the key index, as stored), all
+    float32. The state decays, is READ at k, takes the corrected rank-one
+    update and is read again at q. Returns (o [B, H, P], S)."""
+    S = S * jnp.exp(g)[:, :, None, :]
+    r = jnp.einsum("bhpn,bhn->bhp", S, k, precision=_HI)
+    S = S + (beta[..., None] * (v - r))[..., None] * k[:, :, None, :]
+    return jnp.einsum("bhpn,bhn->bhp", S, q, precision=_HI), S
+
+
+def _kda_pairs(a, k, G, strict: bool):
+    """[H, C, C] float32: sum_n a_i[n] k_j[n] exp(G_i[n] - G_j[n]) for j < i
+    (`strict`) or j <= i, else 0, over one chunk. a, k, G [C, H, N], G the
+    running sum of the log-decays, so every exponent taken is of a
+    difference with i >= j and is <= 0: inside a sub-block of `KDA_SUB`
+    positions the differences are formed pair by pair; a key before the
+    query's sub-block is reached in two factors through that sub-block's
+    FIRST row r, exp(G_i - G_r) exp(G_r - G_j) with j < r <= i, neither of
+    which can grow."""
+    C, H, N = k.shape
+    s = min(KDA_SUB, C)
+    nb = C // s
+    blk = lambda x: x.reshape(nb, s, H, N)  # noqa: E731
+    ab, kb, Gb = blk(a), blk(k), blk(G)
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    keep = (j < i) if strict else (j <= i)
+    diff = Gb[:, :, None] - Gb[:, None, :]  # [nb, s(i), s(j), H, N]
+    diag = jnp.sum(
+        ab[:, :, None] * kb[:, None, :]
+        * jnp.exp(jnp.where(keep[None, :, :, None, None], diff, -jnp.inf)),
+        axis=-1)  # [nb, s, s, H]
+    G0 = Gb[:, 0]  # [nb, H, N]: each sub-block's first row
+    before = (jnp.arange(C)[None, :] < (jnp.arange(nb) * s)[:, None])
+    far = k[None] * jnp.exp(jnp.where(
+        before[:, :, None, None], G0[:, None] - G[None], -jnp.inf))
+    off = jnp.einsum("bihn,bjhn->hbij", ab * jnp.exp(Gb - G0[:, None]), far,
+                     precision=_HI)  # [H, nb, s, C]
+    out = off.reshape(H, C, C)
+    eye = jnp.eye(nb, dtype=diag.dtype)
+    # the diagonal sub-blocks, each at its own place
+    return out + jnp.einsum("bijh,bc->hbicj", diag, eye).reshape(H, C, C)
+
+
+def kda_chunked(q, k, v, g, beta, S, chunk: int = KDA_CHUNK):
+    """The chunked form of the delta rule over T tokens of ONE sequence from
+    the state `S`. q, k, g [T, H, N], v [T, H, P], beta [T, H] (g = 0 and
+    beta = 0 where the position is no token), S [H, P, N], all float32.
+    With G the running sum of g inside a chunk and S_0 the state at its
+    start (written S[n, p] here; stored the other way round):
+
+        A_ij = sum_n k_i[n] k_j[n] exp(G_i[n] - G_j[n])         i > j
+        (I + diag(beta) A) U = diag(beta) (V - (K * exp(G)) S_0)
+        o_i = (q_i * exp(G_i)) S_0 + sum_{j <= i} QK_ij u_j
+        S_C = diag(exp(G_C)) S_0 + sum_j (k_j * exp(G_C - G_j)) u_j^T
+
+    What does not read the state (A, QK, the solve against V and against
+    K * exp(G)) is made for every chunk first; the walk over the chunks is
+    then four products a chunk. Returns (o [T, H, P], S after the T
+    tokens)."""
+    from jax.scipy.linalg import solve_triangular
+
+    T, H, N = k.shape
+    C = min(chunk, -(-T // KDA_SUB) * KDA_SUB)
+    pad = -T % C
+    if pad:  # g = 0, beta = 0: a padded position neither decays nor updates
+        q, k, v, g, beta = (jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                            for a in (q, k, v, g, beta))
+    n = (T + pad) // C
+    eye = jnp.eye(C, dtype=jnp.float32)
+
+    def prepare(xs):  # one chunk, nothing of it reads the state
+        qc, kc, vc, gc, bc = xs
+        G = jnp.cumsum(gc, axis=0)  # [C, H, N], inclusive
+        bh = bc.T[:, :, None]  # [H, C, 1]
+        lower = eye + bh * _kda_pairs(kc, kc, G, strict=True)
+        kg = kc * jnp.exp(G)
+        rhs = jnp.concatenate([jnp.moveaxis(vc, 1, 0),
+                               jnp.moveaxis(kg, 1, 0)], axis=-1) * bh
+        w = solve_triangular(lower, rhs, lower=True, unit_diagonal=True)
+        return (w[..., :vc.shape[-1]], w[..., vc.shape[-1]:],
+                _kda_pairs(qc, kc, G, strict=False),
+                jnp.moveaxis(qc * jnp.exp(G), 1, 0),
+                jnp.moveaxis(kc * jnp.exp(G[-1:] - G), 1, 0),
+                jnp.exp(G[-1]))
+
+    def chunks(a):  # [n * C, ...] -> [n, C, ...]
+        return a.reshape(n, C, *a.shape[1:])
+
+    parts = jax.lax.map(prepare, tuple(map(chunks, (q, k, v, g, beta))),
+                        batch_size=min(n, 8))
+
+    def one(S, xs):
+        wv, wk, qk, qg, kend, dec = xs  # [H, C, P] [H, C, N] [H, C, C] ...
+        u = wv - jnp.einsum("hcn,hpn->hcp", wk, S, precision=_HI)
+        o = (jnp.einsum("hcn,hpn->hcp", qg, S, precision=_HI)
+             + jnp.einsum("hij,hjp->hip", qk, u, precision=_HI))
+        S = dec[:, None, :] * S + jnp.einsum("hcp,hcn->hpn", u, kend,
+                                             precision=_HI)
+        return S, o
+
+    S, o = jax.lax.scan(one, S, parts)  # o [n, H, C, P]
+    return jnp.moveaxis(o, 1, 2).reshape(n * C, H, -1)[:T], S
+
+
+def why_not_kda_kernel(d_head: int, inner: int) -> Optional[str]:
+    """None when a decode step takes `kda_decode`."""
+    from bigdl_tpu.ops.pallas import interpret_mode, why_not_pallas
+    from bigdl_tpu.ops.pallas.mamba2 import CHUNK
+
+    why = why_not_pallas()
+    if why is None and d_head != CHUNK:
+        why = f"a head of {d_head} is not a chunk of {CHUNK} rows"
+    if why is None and inner % CHUNK and not interpret_mode():
+        why = f"inner width {inner} is not whole chunks of {CHUNK} rows"
+    return why
+
+
+def kda_mix(cache: HybridCache, layer, qkv, g, beta, conv_w, *,
+            n_heads: int, d_head: int, decode: bool):
+    """The state part of KDA layer `layer` (index among the KDA layers) over
+    this forward's T positions: the three depthwise causal convolutions (one
+    over `qkv [B, T, 3 * H * D]`, the projections q | k | v side by side,
+    `conv_w [K, 3 * H * D]`, no bias) and silu, the L2 norm of a head's q
+    and k and q's 1 / sqrt(D), then the delta rule. `g [B, T, H, D]` <= 0
+    the log-decay a key channel, `beta [B, T, H]`, both float32; a position
+    that is no token gets g = 0 and beta = 0 here and neither decays nor
+    updates. Returns (o [B, T, H, D] float32, the cache with the layer's
+    rows updated)."""
+    from bigdl_tpu.ops import routes
+
+    B, T, _ = qkv.shape
+    H, D = n_heads, d_head
+    inner = H * D
+    valid = valid_positions(cache, T)
+    qkv = jnp.where(valid[..., None], qkv.astype(jnp.float32), 0.0)
+    g = jnp.where(valid[..., None, None], g, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+    end = (jnp.full((B,), T, jnp.int32) if cache.valid_len is None
+           else cache.valid_len.astype(jnp.int32))
+    one_token = decode and T == 1
+    r = _flat_tails(cache, layer, B)
+    with scope("short_conv"):
+        x, tail = _conv_from_tail(r.tail, qkv, conv_w, 0.0, end, one_token)
+        x = jax.nn.silu(x).reshape(B, T, 3, H, D)
+        conv = _put_tails(cache, layer, tail, r)
+
+        def unit(a):  # a / max(|a|, 1e-6) over a head's lanes
+            return a * jax.lax.rsqrt(jnp.maximum(
+                jnp.sum(a * a, axis=-1, keepdims=True), 1e-12))
+
+        q, k, v = unit(x[:, :, 0]) * D ** -0.5, unit(x[:, :, 1]), x[:, :, 2]
+    detail = f"B{B} T{T} H{H} D{D} state float32"
+    why = why_not_kda_kernel(D, inner)
+    if one_token and why is None:
+        from bigdl_tpu.ops.pallas.mamba2 import kda_decode
+
+        routes.note("kda", "pallas:kda_decode", detail)
+        with scope("kda_decode"):
+            o, ssm = kda_decode(cache.ssm, layer, r.rows, r.live, q[:, 0],
+                                k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+        return o[:, None], dataclasses.replace(cache, conv=conv, ssm=ssm)
+    routes.note("kda", "xla", detail + (
+        f" step ({why})" if one_token else f" chunked prefill C{KDA_CHUNK}"))
+    S = jnp.where(r.fresh[:, None, None, None], 0.0,
+                  cache.ssm[layer, r.at].reshape(B, H, D, D))
+    if one_token:
+        with scope("kda_decode"):
+            o, S = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], S)
+            o = o[:, None]
+    else:
+        with scope("kda_prefill"):
+            o, S = jax.vmap(kda_chunked)(q, k, v, g, beta, S)
+    ssm = cache.ssm.at[layer, r.to].set(S.reshape(B, inner, D), mode="drop")
+    return o, dataclasses.replace(cache, conv=conv, ssm=ssm)
 
 
 def _rows_axis(conv) -> int:

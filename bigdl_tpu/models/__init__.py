@@ -191,6 +191,13 @@ _LAZY_FAMILIES = {
     # selection bias; granite's state row beside KV pages with no
     # recurrence state at all (bigdl_tpu/kvhybrid.py)
     "lfm2_moe": "bigdl_tpu.models.lfm2_moe",
+    # Kimi delta attention layers (a gated delta rule: the decay a key
+    # channel's, a matrix state a head that a step reads before it writes)
+    # with a gated NoPE GQA layer every few, sigmoid-routed experts of which
+    # this program may hold one rank's share; granite's state row beside KV
+    # pages with three convolutions' tails and lightning's state layout
+    # (bigdl_tpu/kvhybrid.py)
+    "solar_open2": "bigdl_tpu.models.solar_open2",
 }
 
 

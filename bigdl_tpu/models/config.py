@@ -194,6 +194,20 @@ class ModelConfig:
     # convolution, depthwise and causal over `conv_l_cache` inputs (0 = no
     # such operator), whose only state is its last `conv_l_cache - 1` inputs
     conv_l_cache: int = 0
+    # one rank's share of an expert-parallel layer (models/llama.py
+    # `_moe_dispatch`): the router scores `router_experts` experts (0 = the
+    # `num_experts` held here: every expert, no share) and this program
+    # holds `num_experts` of them from id `first_expert` on. An assignment
+    # to an expert held elsewhere is dropped; its weight stays in the
+    # normalisation
+    router_experts: int = 0
+    first_expert: int = 0
+    # solar_open2 (models/solar_open2.py): `layer_types` "attention" | "kda";
+    # a "kda" layer is Kimi delta attention, `kda_heads` heads of
+    # `kda_head_dim` keys and values behind three short convolutions of
+    # `conv_l_cache` taps (kvhybrid.kda_mix)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
 
     def __post_init__(self):
         if self.attention_kind not in ("softmax", "power_retention"):
@@ -207,6 +221,13 @@ class ModelConfig:
                 f"power retention of degree {self.retention_degree}: the "
                 "state's feature map is written for degree 2"
             )
+        if self.router_experts and not (
+                0 <= self.first_expert
+                <= self.router_experts - self.num_experts):
+            raise ValueError(
+                f"a share of {self.num_experts} experts from id "
+                f"{self.first_expert} does not lie in a router's "
+                f"{self.router_experts}")
         if self.moe_dispatch not in (None, "dense", "ragged"):
             raise ValueError(
                 f"moe_dispatch must be None, 'dense' or 'ragged'; "
@@ -262,6 +283,20 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores: more than `num_experts` where this
+        program holds one rank's share of them."""
+        return self.router_experts or self.num_experts
+
+    @property
+    def expert_share(self) -> Optional[tuple]:
+        """None where every routed expert is held here, else (id of the
+        first expert held, experts held, the router's width)."""
+        if self.router_width == self.num_experts:
+            return None
+        return self.first_expert, self.num_experts, self.router_width
 
     def layer_is_sliding(self, layer_idx: int) -> bool:
         """Static per-layer attention kind (gemma2 alternation / gemma3
@@ -977,6 +1012,70 @@ def _hf_lfm2_moe(hf, kw):
     kw.setdefault("tie_word_embeddings", True)
 
 
+#: the key a configuration of one expert-parallel rank carries beside the
+#: source's own (no public config.json has it): the router's width and the
+#: id of the first expert held, `n_routed_experts` then counting the held
+EXPERT_SHARE_KEY = "expert_parallel_share"
+
+
+def _hf_solar_open2(hf, kw):
+    """Solar-Open2 (upstage/Solar-Open2-250B): Kimi delta attention layers
+    with a gated NoPE GQA layer at each index of `gqa_layers`, every layer
+    followed by sigmoid-routed experts with a selection bias and one
+    ungated shared expert (`deepseek._router`'s `noaux_tc` branch with one
+    group). Refused by name: a rope, leading dense layers, a full-rank
+    decay projection, an ungated GQA layer. `EXPERT_SHARE_KEY`
+    (`{"router_experts": 320, "first_expert": 0}`) makes `n_routed_experts`
+    the count HELD here of a router that wide."""
+    L = hf["num_hidden_layers"]
+    if hf.get("use_rope"):
+        raise NotImplementedError(
+            "solar_open2 with use_rope: the GQA layers are written without "
+            "positions")
+    if hf.get("first_k_dense_replace", 0):
+        raise NotImplementedError(
+            f"solar_open2 with first_k_dense_replace "
+            f"{hf['first_k_dense_replace']}: every layer is written sparse")
+    if hf.get("kda_use_full_proj"):
+        raise NotImplementedError(
+            "solar_open2 with kda_use_full_proj: the decay and the output "
+            "gate are written as low-rank pairs")
+    if not hf.get("use_gqa_gate", True):
+        raise NotImplementedError("solar_open2 without use_gqa_gate")
+    if not hf.get("kda_allow_neg_eigval", True):
+        raise NotImplementedError(
+            "solar_open2 without kda_allow_neg_eigval: beta is written "
+            "doubled")
+    gqa = set(hf.get("gqa_layers") or ())
+    if not gqa <= set(range(L)):
+        raise ValueError(f"gqa_layers {sorted(gqa)} name layers past {L}")
+    kw["layer_types"] = tuple(
+        "attention" if i in gqa else "kda" for i in range(L))
+    lin = hf.get("linear_attn_config") or {}
+    kw["kda_heads"] = lin.get("num_heads", hf["num_attention_heads"])
+    kw["kda_head_dim"] = lin.get("head_dim", 128)
+    if lin.get("num_kv_heads") not in (None, kw["kda_heads"]):
+        raise NotImplementedError(
+            f"solar_open2 with {lin['num_kv_heads']} KDA key heads for "
+            f"{kw['kda_heads']}: a head's state is written square")
+    kw["conv_l_cache"] = lin.get("short_conv_kernel_size", 4)
+    kw["position_embedding_type"] = "nope"
+    kw["rms_norm_eps"] = hf.get("rms_norm_eps", 1e-5)
+    kw["num_experts"] = hf.get("n_routed_experts") or 0
+    kw["num_experts_per_tok"] = hf.get("num_experts_per_tok") or 8
+    kw["moe_intermediate_size"] = hf.get("moe_intermediate_size")
+    kw["n_shared_experts"] = hf.get("n_shared_experts")
+    kw["scoring_func"], kw["topk_method"] = "sigmoid", "noaux_tc"
+    kw["n_group"] = kw["topk_group"] = 1
+    kw["norm_topk_prob"] = bool(hf.get("norm_topk_prob", True))
+    kw["routed_scaling_factor"] = hf.get("routed_scaling_factor", 1.0)
+    share = hf.get(EXPERT_SHARE_KEY)
+    if share:
+        kw["router_experts"] = int(share["router_experts"])
+        kw["first_expert"] = int(share.get("first_expert", 0))
+    kw.setdefault("tie_word_embeddings", False)
+
+
 def _hf_qwen3_moe(hf, kw):
     _hf_qwen3(hf, kw)
     kw["num_experts"] = hf.get("num_experts", 128)
@@ -1347,6 +1446,7 @@ _HF_BUILDERS = {
     "granitemoehybrid": _hf_granitemoehybrid,
     "jamba": _hf_jamba,
     "lfm2_moe": _hf_lfm2_moe,
+    "solar_open2": _hf_solar_open2,
     "smallthinker": _hf_smallthinker,
     "laguna": _hf_laguna,
     "qwen3_moe": _hf_qwen3_moe,
@@ -1462,6 +1562,24 @@ PRESETS: dict[str, ModelConfig] = {
         moe_intermediate_size=64, scoring_func="sigmoid",
         topk_method="noaux_tc", n_group=1, topk_group=1,
         norm_topk_prob=True, routed_scaling_factor=1.0,
+        moe_dispatch="dense",
+    ),
+    # Solar-Open2's shape at toy sizes: a gated NoPE GQA layer, two Kimi
+    # delta attention layers (2 heads of 128 behind three convolutions of 4
+    # taps), a GQA layer again; 8 sigmoid-routed experts with a selection
+    # bias, top-2, and one shared expert (tests/test_solar_open2.py holds it
+    # to an HF config dict, and serves a rank's share of it)
+    "tiny-solar-open2": ModelConfig(
+        model_type="solar_open2", vocab_size=256, hidden_size=256,
+        intermediate_size=512, num_hidden_layers=4, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=128, rms_norm_eps=1e-5,
+        max_position_embeddings=4096, tie_word_embeddings=False,
+        position_embedding_type="nope",
+        layer_types=("attention", "kda", "kda", "attention"),
+        kda_heads=2, kda_head_dim=128, conv_l_cache=4, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=64, n_shared_experts=1,
+        scoring_func="sigmoid", topk_method="noaux_tc", n_group=1,
+        topk_group=1, norm_topk_prob=True, routed_scaling_factor=1.0,
         moe_dispatch="dense",
     ),
     # SmallThinker's shape at toy sizes: two periods of one full NoPE layer

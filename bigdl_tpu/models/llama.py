@@ -514,7 +514,7 @@ _LINEAR_STACKS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
 
 def _moe_dispatch_grouped(
     config: ModelConfig, xc: jax.Array, p: Params, compute_dtype,
-    topv: jax.Array, topi: jax.Array, layer=None,
+    topv: jax.Array, topi: jax.Array, layer=None, held=None,
 ) -> jax.Array:
     """Dropless dispatch on packed weights: the assignments are sorted
     by expert, each expert's rows padded to a whole row tile, and
@@ -530,7 +530,10 @@ def _moe_dispatch_grouped(
     sums over the major axis: as `[N, k, H]`, k lies on the sublane axis,
     is padded to a multiple of 8 and every float32 row re-laid once more a
     layer. `layer` says the stacks' packed codes still carry the layer
-    axis (forward keeps them out of the scan's slices)."""
+    axis (forward keeps them out of the scan's slices). `held [B, T, k]`
+    (`_held_share`: one rank's share of the experts): an assignment that is
+    not held gets no row and no tile, and nothing of the buffer is read for
+    it (a tile nobody filled holds no meaning)."""
     from bigdl_tpu.ops.pallas import moe_qmatmul as mq
 
     B, T, H = xc.shape
@@ -539,17 +542,19 @@ def _moe_dispatch_grouped(
     block_m = _moe_block_m(xc, p)
     call = functools.partial(mq.moe_qmatmul, block_m=block_m, layer=layer)
 
+    if held is not None:
+        held = held.reshape(N, k)
     with scope("moe.dispatch"):
         xs = xc.reshape(N, H)
         if N <= block_m:  # one tile holds the call: the rows as they stand
             dest, tile_expert, n_used = mq.moe_layout_shared(
-                topi.reshape(N, k), E, block_m)
+                topi.reshape(N, k), E, block_m, held)
             if N < block_m:  # (a few rows, to the sublane tile)
                 xs = jnp.pad(xs, ((0, block_m - N), (0, 0)))
         else:
             dest, src, tile_expert, n_used = mq.moe_layout(
                 topi.reshape(N, k), E, block_m,
-                mq.moe_n_tiles(N, k, E, block_m))
+                mq.moe_n_tiles(N, k, E, block_m), held)
             xs = xs[src]  # [n_tiles * block_m, H]
         row_expert = jnp.repeat(tile_expert, block_m)
     with scope("moe.experts"):
@@ -570,7 +575,10 @@ def _moe_dispatch_grouped(
         if not config.gated_mlp and "b_down_e" in p:
             y = y + p["b_down_e"].astype(jnp.float32)[row_expert]
     with scope("moe.combine"):
-        out = jnp.sum(y[dest.T] * topv.reshape(N, k).T[:, :, None], axis=0)
+        rows = y[dest.T]
+        if held is not None:
+            rows = jnp.where(held.T[:, :, None], rows, 0.0)
+        out = jnp.sum(rows * topv.reshape(N, k).T[:, :, None], axis=0)
     return out.astype(compute_dtype).reshape(B, T, H)
 
 
@@ -676,6 +684,21 @@ def moe_grouped_why_not(p: Params, differentiable: bool) -> Optional[str]:
     return grouped_route(*(p[n] for n in _EXPERT_STACKS if n in p))
 
 
+def _held_share(config: ModelConfig, topv: jax.Array, topi: jax.Array):
+    """One rank's share of an expert-parallel layer (`ModelConfig.
+    expert_share`): of the router's choices `topi` (ids over the router's
+    whole width) those that fall on the experts held here, as (weights,
+    zero where the expert is held elsewhere; LOCAL ids, `num_experts`, one
+    past the last, where it is; which assignments are held). The weights
+    are the whole routing's: what normalised them summed over every chosen
+    expert, held here or not."""
+    first, n_held, _ = config.expert_share
+    local = topi.astype(jnp.int32) - first
+    held = (local >= 0) & (local < n_held)
+    return (jnp.where(held, topv, 0.0), jnp.where(held, local, n_held),
+            held)
+
+
 def _moe_dispatch(
     config: ModelConfig, xc: jax.Array, p: Params, compute_dtype,
     topv: jax.Array, topi: jax.Array, ragged_config=None,
@@ -687,12 +710,23 @@ def _moe_dispatch(
     axis XLA partitions, an ineligible shape, the CPU without the
     interpreter) takes the XLA formulation `resolve_moe_dispatch` names.
     `ragged_config` carries a family's capacity adjustment for the
-    ragged formulation only (DeepSeek's group-limited routing)."""
+    ragged formulation only (DeepSeek's group-limited routing). Where the
+    configuration holds one rank's share of the experts (`_held_share`),
+    every formulation computes the held experts' part and nothing stands
+    in for the others': the dense one-hot has no column for them, the
+    ragged form sends them to its overflow bin, the grouped layout gives
+    them no row."""
     from bigdl_tpu.ops import routes
 
     B, T, H = xc.shape
     detail = (f"N{B * T} k{config.num_experts_per_tok} "
               f"E{config.num_experts} H{H}")
+    held = None
+    if config.expert_share is not None:
+        first, n_held, width = config.expert_share
+        detail += f" held {n_held}/{width} first {first}"
+        with scope("moe.dispatch"):
+            topv, topi, held = _held_share(config, topv, topi)
     why = moe_grouped_why_not(p, differentiable)
     if why is None:
         rows = "shared" if B * T <= _moe_block_m(xc, p) else "sorted"
@@ -701,7 +735,7 @@ def _moe_dispatch(
                     f"{_grouped_plan(config, p)} rows:{rows} "
                     f"scales:{'stack' if _reads_bits(config, p) else 'slice'}")
         return _moe_dispatch_grouped(config, xc, p, compute_dtype, topv,
-                                     topi, layer)
+                                     topi, layer, held)
     assert layer is None, "unsliced expert codes are for the grouped path"
     kind = resolve_moe_dispatch(config)
     routes.note("moe", f"xla:{kind}", f"{detail} ({why})")

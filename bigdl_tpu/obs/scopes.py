@@ -65,9 +65,12 @@ VOCABULARY = (
 #: of a Mamba-1 layer's selective scan (kvhybrid.mix1); of a gated
 #: short-convolution layer everything but its two projections (the gate, the
 #: convolution, the tail's update), and the padding and slicing around
-#: attention over lane pairs (models/lfm2_moe.py).
+#: attention over lane pairs (models/lfm2_moe.py); the two forms of a Kimi
+#: delta attention layer's delta rule (kvhybrid.kda_mix), whose three
+#: convolutions stand under `short_conv`.
 DETAIL = ("sparse_select", "lightning_prefill", "lightning_decode",
-          "mamba1_prefill", "mamba1_decode", "short_conv", "pair_attn")
+          "mamba1_prefill", "mamba1_decode", "short_conv", "pair_attn",
+          "kda_prefill", "kda_decode")
 
 _NAMES = frozenset(VOCABULARY + DETAIL)
 

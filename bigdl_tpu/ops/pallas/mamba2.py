@@ -100,6 +100,30 @@ def _kernel(meta_ref, d_ref, u_ref, b_ref, c_ref, s_ref, s_out, y_ref, *,
         s_out[...] = s_ref[...]
 
 
+def _live_rows_first(layer, rows, live, n_blocks: int):
+    """The grid's walk over the batch rows, LIVE ROWS FIRST: (the
+    scalar-prefetched `meta` = [layer, live rows, the batch rows in that
+    order, the state row of each step], the state's index map, a per-row
+    operand's). A step past the last live one stays on that one's state
+    row and asks for one block of a per-row operand, once."""
+    B = rows.shape[0]
+    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    step = jnp.minimum(jnp.arange(B, dtype=jnp.int32),
+                       jnp.maximum(n_live - 1, 0))
+    meta = jnp.concatenate([
+        jnp.reshape(layer, (1,)).astype(jnp.int32), n_live[None], order,
+        jnp.maximum(rows.astype(jnp.int32)[order[step]], 0)])
+
+    def state_block(i, j, m):
+        return (m[0], m[2 + B + i], jnp.where(i < m[1], j, n_blocks - 1), 0)
+
+    def per_row(i, j, m):
+        return (m[2 + i], jnp.where(i < m[1], j, 0), 0, 0)
+
+    return meta, state_block, per_row
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def mamba2_decode(
     ssm: jax.Array,  # [Lm, R, heads * head size, N] float32, the whole pool
@@ -136,21 +160,8 @@ def mamba2_decode(
     def padded(a):  # [B, N] -> [B, 8, N], the vector in every row
         return jnp.broadcast_to(a.astype(jnp.float32)[:, None], (B, _PAD, N))
 
-    # batch rows, live ones first; a step past the last live one stays on
-    # that one's state row
-    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
-    n_live = jnp.sum(live, dtype=jnp.int32)
-    step = jnp.minimum(jnp.arange(B, dtype=jnp.int32),
-                       jnp.maximum(n_live - 1, 0))
-    meta = jnp.concatenate([
-        jnp.reshape(layer, (1,)).astype(jnp.int32), n_live[None], order,
-        jnp.maximum(rows.astype(jnp.int32)[order[step]], 0)])
-
-    def state_block(i, j, m):
-        return (m[0], m[2 + B + i], jnp.where(i < m[1], j, n_blocks - 1), 0)
-
-    def per_row(i, j, m):  # an idle row asks for one block, once
-        return (m[2 + i], jnp.where(i < m[1], j, 0), 0, 0)
+    meta, state_block, per_row = _live_rows_first(layer, rows, live,
+                                                  n_blocks)
 
     def vector(i, j, m):
         return (m[2 + i], 0, 0)
@@ -222,20 +233,8 @@ def lightning_decode(
         a = a.astype(jnp.float32).reshape(B, n_blocks, n_chunks, N)
         return jnp.pad(a, ((0, 0), (0, 0), (0, pad - n_chunks), (0, 0)))
 
-    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
-    n_live = jnp.sum(live, dtype=jnp.int32)
-    step = jnp.minimum(jnp.arange(B, dtype=jnp.int32),
-                       jnp.maximum(n_live - 1, 0))
-    meta = jnp.concatenate([
-        jnp.reshape(layer, (1,)).astype(jnp.int32), n_live[None], order,
-        jnp.maximum(rows.astype(jnp.int32)[order[step]], 0)])
-
-    def state_block(i, j, m):
-        return (m[0], m[2 + B + i], jnp.where(i < m[1], j, n_blocks - 1), 0)
-
-    def per_row(i, j, m):  # an idle row asks for one block, once
-        return (m[2 + i], jnp.where(i < m[1], j, 0), 0, 0)
-
+    meta, state_block, per_row = _live_rows_first(layer, rows, live,
+                                                  n_blocks)
     s_spec = pl.BlockSpec((None, None, blk, N), state_block)
     col = pl.BlockSpec((None, None, CHUNK, n_chunks), per_row)
     vec = pl.BlockSpec((None, None, pad, N), per_row)
@@ -260,3 +259,105 @@ def lightning_decode(
         interpret=interpret,
     )(meta, dec, u, by_block(k), by_block(q), state)
     return y.reshape(B, H, P), state
+
+
+def _kda_kernel(meta_ref, e_ref, k_ref, q_ref, bv_ref, b_ref, s_ref, s_out,
+                y_ref, *, n_chunks: int):
+    """One block of heads of one live row: a head is one chunk of `CHUNK`
+    value rows by N key lanes. Decay by the head's row of factors, read at
+    k (a lane reduction: a column down the value rows), add the corrected
+    rank-one update (that column times k's row), read at q. float32 on the
+    VPU throughout; the block is read once and written once."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    live = i < meta_ref[1]
+
+    @pl.when(live)
+    def _update():
+        for c in range(n_chunks):
+            at = pl.ds(c * CHUNK, CHUNK)
+            krow = k_ref[c:c + 1, :]  # [1, N]
+            s1 = s_ref[at, :] * e_ref[c:c + 1, :]  # [128, N]
+            r = jnp.sum(s1 * krow, axis=1, keepdims=True)  # S'^T k: [128, 1]
+            h = s1 + (bv_ref[:, c:c + 1] - b_ref[:, c:c + 1] * r) * krow
+            s_out[at, :] = h
+            y_ref[:, c:c + 1] = jnp.sum(h * q_ref[c:c + 1, :], axis=1,
+                                        keepdims=True)
+
+    @pl.when(jnp.logical_not(live))
+    def _idle():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when((meta_ref[1] == 0) & (i == 0) & (j == 0))
+    def _untouched():
+        s_out[...] = s_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_decode(
+    state: jax.Array,  # [Lk, R, heads * head size, N] float32, the whole pool
+    layer: jax.Array,  # scalar int32
+    rows: jax.Array,  # [B] int32 state row of each batch row
+    live: jax.Array,  # [B] bool: rows that hold one
+    q: jax.Array,  # [B, H, N] float32: the token's queries (normed, scaled)
+    k: jax.Array,  # [B, H, N] float32: its keys (normed)
+    v: jax.Array,  # [B, H, P] float32: its values
+    g: jax.Array,  # [B, H, N] float32 <= 0: the log-decay a key channel
+    beta: jax.Array,  # [B, H] float32: the update's strength
+    interpret: bool | None = None,
+):
+    """`lightning_decode`'s sibling for Kimi delta attention (kvhybrid.py):
+    the same pass over the live rows' state in place, with the decay a
+    VECTOR over a head's key lanes and the state READ (S'^T k) before the
+    rank-one update it corrects is written: S' = S * exp(g) row by row,
+    S = S' + beta (v - S'^T k) k^T, y = S^T q. Returns (y [B, H, P]
+    float32, state). An idle row's y is zeros and its state is not
+    touched."""
+    from bigdl_tpu.ops.pallas import interpret_mode
+
+    if interpret is None:
+        interpret = interpret_mode()
+    B, H, P = v.shape
+    inner, N = state.shape[-2:]
+    if P != CHUNK:
+        raise ValueError(f"a head of {P} values is not a chunk of {CHUNK}")
+    blk = block_rows(inner)
+    n_blocks, n_chunks = inner // blk, blk // CHUNK
+    pad = -(-n_chunks // _PAD) * _PAD
+
+    def columns(a):  # [B, H, P] -> [B, blocks, 128, chunks]
+        return jnp.swapaxes(a.astype(jnp.float32).reshape(
+            B, n_blocks, n_chunks, CHUNK), 2, 3)
+
+    def by_block(a):  # [B, H, N] -> [B, blocks, chunks padded, N]
+        a = a.astype(jnp.float32).reshape(B, n_blocks, n_chunks, N)
+        return jnp.pad(a, ((0, 0), (0, 0), (0, pad - n_chunks), (0, 0)))
+
+    meta, state_block, per_row = _live_rows_first(layer, rows, live,
+                                                  n_blocks)
+    s_spec = pl.BlockSpec((None, None, blk, N), state_block)
+    col = pl.BlockSpec((None, None, CHUNK, n_chunks), per_row)
+    vec = pl.BlockSpec((None, None, pad, N), per_row)
+    b_col = jnp.broadcast_to(beta[..., None], v.shape)
+    state, y = pl.pallas_call(
+        functools.partial(_kda_kernel, n_chunks=n_chunks),
+        name="kda_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, n_blocks),
+            in_specs=[vec, vec, vec, col, col, s_spec],
+            out_specs=[
+                s_spec,
+                pl.BlockSpec((None, None, CHUNK, n_chunks),
+                             lambda i, j, m: (m[2 + i], j, 0, 0)),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct(state.shape, state.dtype),
+            jax.ShapeDtypeStruct((B, n_blocks, CHUNK, n_chunks), jnp.float32),
+        ],
+        # operands count from the scalar-prefetched one: the state is 6
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(meta, by_block(jnp.exp(g)), by_block(k), by_block(q),
+      columns(b_col * v), columns(b_col), state)
+    return jnp.swapaxes(y, 2, 3).reshape(B, H, P), state

@@ -131,7 +131,8 @@ def moe_n_tiles(n_tokens: int, k: int, n_experts: int, block_m: int) -> int:
     return max(1, min(n_experts * per_expert, padded, n_tokens * k))
 
 
-def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int):
+def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int,
+               held=None):
     """Where each assignment goes. `topi [N, k]` int32 expert ids ->
 
     * `dest [N, k]`: row of the sorted buffer that holds assignment
@@ -142,11 +143,17 @@ def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int):
     * `tile_expert [n_tiles]`: the expert whose weights tile m uses (a
       tile past `n_used` repeats the last live tile's);
     * `n_used`: scalar, tiles that hold at least one assignment.
+
+    `held [N, k]` bool (one rank's share of the experts, `topi` its LOCAL
+    ids): an assignment that is not held gets no row, counts toward no
+    tile, and its `dest` lies past the buffer.
     """
     N, k = topi.shape
     e_flat = topi.reshape(N * k).astype(jnp.int32)
-    onehot = (e_flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None]
-              ).astype(jnp.int32)  # [N*k, E]
+    onehot = e_flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None]
+    if held is not None:
+        onehot = onehot & held.reshape(N * k, 1)
+    onehot = onehot.astype(jnp.int32)  # [N*k, E]
     seen = jnp.cumsum(onehot, axis=0)
     rank = jnp.sum((seen - onehot) * onehot, axis=-1)  # earlier same-expert
     counts = seen[-1]
@@ -154,26 +161,46 @@ def moe_layout(topi: jax.Array, n_experts: int, block_m: int, n_tiles: int):
                                         n_tiles)
     dest = (first[e_flat] * block_m + rank).astype(jnp.int32)
     tok = jnp.arange(N * k, dtype=jnp.int32) // k
+    if held is not None:  # dropped: a row past the buffer, written nowhere
+        dest = jnp.where(held.reshape(N * k), dest, n_tiles * block_m)
+        n_used = _one_tile_at_least(n_used)
     src = jnp.zeros((n_tiles * block_m,), jnp.int32).at[dest].set(
-        tok, unique_indices=True)
+        tok, unique_indices=True, mode="drop")
     return dest.reshape(N, k), src, tile_expert, n_used
 
 
-def moe_layout_shared(topi: jax.Array, n_experts: int, block_m: int):
+def moe_layout_shared(topi: jax.Array, n_experts: int, block_m: int,
+                      held=None):
     """`moe_layout` where ONE tile holds the whole call (`N <= block_m`)
     and every hit expert's tile is the call's rows as they stand, in token
     order: `dest[n, j]` is row n of the tile of expert `topi[n, j]`, the
     tiles numbered over the hit experts in expert order. No rows move, so
     there is no `src` and nothing to rank: -> (`dest`, `tile_expert`,
-    `n_used`), the last two as `moe_layout` gives them."""
+    `n_used`), the last two as `moe_layout` gives them. `held`: as
+    `moe_layout`'s; an assignment that is not held hits no expert."""
     N, k = topi.shape
     assert N <= block_m, (N, block_m)
-    hit = jnp.any(topi.reshape(N * k, 1).astype(jnp.int32) == jnp.arange(
-        n_experts, dtype=jnp.int32)[None], axis=0).astype(jnp.int32)
+    hit = topi.reshape(N * k, 1).astype(jnp.int32) == jnp.arange(
+        n_experts, dtype=jnp.int32)[None]
+    if held is not None:
+        hit = hit & held.reshape(N * k, 1)
+    hit = jnp.any(hit, axis=0).astype(jnp.int32)
     first, tile_expert, n_used = _tiles(
         hit, moe_n_tiles(N, k, n_experts, block_m))
     dest = first[topi] * block_m + jnp.arange(N, dtype=jnp.int32)[:, None]
+    if held is not None:
+        n_used = _one_tile_at_least(n_used)
     return dest, tile_expert, n_used
+
+
+def _one_tile_at_least(n_used):
+    """A share's call in which NO assignment is held (one live row whose
+    eight choices all fall on other ranks' experts) keeps tile 0 live, on
+    expert 0 and rows nobody reads: with no live tile at all the row block
+    the kernel asks for, `min(m, n_used - 1)`, lies before the buffer (on
+    the chip the device halts on that DMA; the interpreter clamps it). A
+    layer that holds every expert always has a live tile."""
+    return jnp.maximum(n_used, 1)
 
 
 def _tiles(tiles: jax.Array, n_tiles: int):

@@ -96,15 +96,17 @@ def _read_first_token(out, n_top: int) -> tuple:
     return int(out[0]), float(out[1:2].view(np.float32)[0]), top
 
 
-def _moe_load(choices: "np.ndarray", n_experts: int) -> dict:
+def _moe_load(choices: "np.ndarray", n_experts: int, first: int = 0) -> dict:
     """Span arguments from the expert choices [L, n, k] of a step's live
     rows (or an admission's prompt tokens): how many assignments there
     were, how many (layer, expert) pairs got any (each is one expert's
     packed weights read), the busiest pair's, and how many pairs there
-    are."""
+    are. Of a router wider than the `n_experts` held here from id `first`
+    on, only what is HELD and computed here counts."""
     L = choices.shape[0]
-    flat = (np.arange(L)[:, None] * n_experts
-            + choices.reshape(L, -1).astype(np.int64)).ravel()
+    local = choices.reshape(L, -1).astype(np.int64) - first
+    flat = (np.arange(L)[:, None] * n_experts + local)[
+        (local >= 0) & (local < n_experts)]
     c = np.bincount(flat, minlength=L * n_experts)
     return {"moe_assignments": int(c.sum()),
             "moe_experts_hit": int(np.count_nonzero(c)),
@@ -679,14 +681,23 @@ class InferenceEngine:
         # moe_routing= (models/llama.forward and deepseek.forward do).
         # Dense models, and forwards that do not report, pay nothing.
         self.moe_routing = False
-        if getattr(self.config, "is_moe", False):
-            import inspect
+        import inspect
 
-            try:
-                self.moe_routing = ("moe_routing"
-                                    in inspect.signature(fwd).parameters)
-            except (TypeError, ValueError):  # pragma: no cover - exotic
-                pass
+        try:
+            takes = inspect.signature(fwd).parameters
+        except (TypeError, ValueError):  # pragma: no cover - exotic
+            takes = ()
+        if getattr(self.config, "is_moe", False):
+            self.moe_routing = "moe_routing" in takes
+        # a forward that takes `logits_at=` runs the head on that one
+        # position of a paged prefill, whatever its cache kind ([T, V]
+        # logits of a 196608-row head are 3.2 GB at T = 4096)
+        self._head_at_last = paged and "logits_at" in takes
+        # one rank's share of an expert-parallel layer: the ids a forward
+        # reports are the router's (over its whole width), and a step's
+        # load counts the experts HELD here (`_moe_load`)
+        self._expert_ids = getattr(self.config, "router_width", 0)
+        self._first_expert = getattr(self.config, "first_expert", 0)
         # a kind whose forward reports what it did (`CacheKind.report`: a
         # block-sparse layer's counts of pages, and the chosen ids where it
         # was asked) appends that many int32 columns to a step's one fetch;
@@ -1130,6 +1141,8 @@ class InferenceEngine:
             pool, row = kind.row_view(pool, tables, pos0, last_idx, slot,
                                       cfg, self._geo)
         kw = dict(kind.forward_kw(last_idx))
+        if self._head_at_last:
+            kw.setdefault("logits_at", last_idx)
         at = 0 if "logits_at" in kw else last_idx
         if lora is not None:
             kw["lora"] = lora
@@ -1153,7 +1166,7 @@ class InferenceEngine:
             **kw)
         with scope("engine"):
             return logits, cache, routing.astype(
-                _expert_id_dtype(self.config.num_experts))
+                _expert_id_dtype(self._expert_ids))
 
     def _first_token_impl(self, logits, rng, temp, topk, topp, dosample,
                           penalty, row, slot, cur, seen):
@@ -2422,7 +2435,8 @@ class InferenceEngine:
             if self._admit_moe_start == 0:  # nothing came from the cache
                 req.prompt_experts = chosen
             if tr is not None and tr.enabled:
-                moe_args = _moe_load(chosen, self.config.num_experts)
+                moe_args = _moe_load(chosen, self.config.num_experts,
+                                     self._first_expert)
             self._admit_moe = []
         moe_args.update(self._admit_args)
         if by_blocks:
@@ -3182,7 +3196,7 @@ class InferenceEngine:
             experts_h = host[:, 2 + 2 * n_top:].reshape(
                 self.n_slots, -1, self.config.num_experts_per_tok
             ).transpose(1, 0, 2).astype(
-                _expert_id_dtype(self.config.num_experts))
+                _expert_id_dtype(self._expert_ids))
             # counted only when a span or a gauge reads it (moe_load)
             self._moe_last = (experts_h, live)
         chose, extra = None, None
@@ -3508,7 +3522,8 @@ class InferenceEngine:
         if self._moe_last is None:
             return {}
         experts, live = self._moe_last
-        return _moe_load(experts[:, live], self.config.num_experts)
+        return _moe_load(experts[:, live], self.config.num_experts,
+                         self._first_expert)
 
     def kv_utilization(self) -> float:
         """Fraction of the KV pool holding live state: allocated pages

@@ -96,10 +96,15 @@ def planted(name: str):
         setattr(module, attr, whole)
 
 
-def main() -> int:
+def main(cell: str = "lfm2-24b-a2b.manydocs-closed", first: int = 2147485301,
+         faults: tuple = FAULTS, plant=planted,
+         sparse_layers=lambda hf: (hf["num_hidden_layers"]
+                                   - hf["num_dense_layers"])) -> int:
+    """`scripts/delta_check_sweep.py` is this sweep with another cell, its
+    own faults and every layer sparse."""
     ap = argparse.ArgumentParser()
-    ap.add_argument("--cell", default="lfm2-24b-a2b.manydocs-closed")
-    ap.add_argument("--first", type=int, default=2147485301)
+    ap.add_argument("--cell", default=cell)
+    ap.add_argument("--first", type=int, default=first)
     ap.add_argument("--n", type=int, default=6)
     ap.add_argument("--prompts", type=int, nargs="*", default=[250, 1000])
     ap.add_argument("--faults", type=int, default=2,
@@ -134,8 +139,7 @@ def main() -> int:
     cfg = ModelConfig.from_hf_config(hf)
     ref = cell.reference()
     n_new = 9
-    L_moe, k = hf["num_hidden_layers"] - hf["num_dense_layers"], \
-        hf["num_experts_per_tok"]
+    L_moe, k = sparse_layers(hf), hf["num_experts_per_tok"]
 
     def fp8(x):
         return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
@@ -166,13 +170,17 @@ def main() -> int:
         page_size=geo["page_size"])
     small["n_pages"] = 4 * small["max_len"] // geo["page_size"] + 1
     broken = {}  # fault -> its engine, traced with the fault in
+    kept = []  # the newest request: the reference finds its expert choices
+    # through a WEAK reference (`serving.engine.last_routed_request`), and
+    # a request nobody holds is compared free
 
     def serve_small(eng, prompt):
         r = eng.submit(prompt, max_new_tokens=n_new)
         eng.run_until_idle()
+        kept[:] = [r]
         return list(r.out_tokens), np.asarray(r.out_logprobs, np.float64)
 
-    driver, rows, seen, bad = None, [], {f: [] for f in FAULTS}, 0
+    driver, rows, seen, bad = None, [], {f: [] for f in faults}, 0
     for i, seed in enumerate(range(args.first, args.first + args.n)):
         if driver is not None:  # keep one set of weights on the chip
             driver.engine.model.params = None
@@ -220,9 +228,9 @@ def main() -> int:
             n_prompt = args.prompts[0]
             prompt = np.random.default_rng(seed + n_prompt).integers(
                 1, hf["vocab_size"], n_prompt).tolist()
-            for fault in FAULTS:
+            for fault in faults:
                 if fault not in broken:
-                    with planted(fault):
+                    with plant(fault):
                         eng = InferenceEngine(TpuModel(cfg, served, qtype),
                                               **small)
                         toks, got = serve_small(eng, prompt)  # traced here

@@ -6,8 +6,14 @@ place), the bytes that must move over them, and the kernel's output against
 
     python scripts/retention_kernel_bench.py [--layers 20] [--slots 8]
 
+`--shape solar-open2` is its sibling `kda_decode` (Kimi delta attention,
+ops/pallas/mamba2.py) at that cell's sizes instead: 9 layers, 32 slots, 64
+heads of 128, a `[8192, 128]` float32 state a layer and slot, against
+`kvhybrid.kda_step`, at 32, 16 and 1 live rows and with every row idle.
+
 Prints one line per number of live slots. Not part of the benchmark: the
-cell `brumby-14b.reason-closed` measures the kernel inside `engine_decode`.
+cells `brumby-14b.reason-closed` and `solar-open2-250b.longctx-closed`
+measure the kernels inside `engine_decode`.
 """
 
 from __future__ import annotations
@@ -21,14 +27,81 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def _timed(run, state, live, steps: int):
+    """Seconds a call of `run(*state, live) -> (*state, ys)`, warmed."""
+    import jax
+
+    *state, ys = run(*state, live)  # compile, warm
+    jax.block_until_ready(ys)
+    t = time.perf_counter()
+    for _ in range(steps):
+        *state, ys = run(*state, live)
+    jax.block_until_ready(ys)
+    return (time.perf_counter() - t) / steps, state
+
+
+def solar_open2(steps: int, layers: int = 9, B: int = 32, H: int = 64,
+                D: int = 128) -> int:
+    """`kda_decode` alone at the Solar-Open2 cell's sizes."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu import kvhybrid
+    from bigdl_tpu.ops.pallas.mamba2 import kda_decode
+
+    ks = jax.random.split(jax.random.key(0), 7)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    q = unit(jax.random.normal(ks[0], (B, H, D))) * D ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, H, D)))
+    v = jax.random.normal(ks[2], (B, H, D))
+    g = -jax.random.uniform(ks[3], (B, H, D), minval=0.01, maxval=1.0)
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (B, H)))
+    rows = jnp.arange(B, dtype=jnp.int32)
+    S1 = 0.1 * jax.random.normal(ks[5], (1, B, H * D, D), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        yr, Sr = jax.jit(kvhybrid.kda_step)(q, k, v, g, beta,
+                                            S1[0].reshape(B, H, D, D))
+    y, S2 = kda_decode(S1, jnp.asarray(0), rows, rows >= 0, q, k, v, g, beta)
+    print(f"kernel vs float32 step: y worst {float(jnp.abs(y - yr).max()):.3e}"
+          f" of {float(jnp.abs(yr).max()):.3e}, S worst "
+          f"{float(jnp.abs(S2[0].reshape(Sr.shape) - Sr).max()):.3e}",
+          flush=True)
+    del S1, S2, Sr
+
+    def step(S, live):
+        def one(S, layer):
+            y, S = kda_decode(S, layer, rows, live, q, k, v, g, beta)
+            return S, y[0, 0, 0]
+        return jax.lax.scan(one, S, jnp.arange(layers))
+
+    run = jax.jit(step, donate_argnums=0)
+    state = [jnp.zeros((layers, B, H * D, D), jnp.float32)]
+    row_bytes = layers * H * D * D * 4
+    for n_live in sorted({B, B // 2, 1, 0}, reverse=True):
+        dt, state = _timed(run, state, jnp.arange(B) < n_live, steps)
+        moved = 2 * n_live * row_bytes
+        print(f"kda_decode live {n_live} of {B}: {dt * 1e3:.2f} ms a step "
+              f"(host clock, {steps} steps, {layers} layers), "
+              f"{moved / 1e9:.2f} GB of state to move, "
+              f"{100 * moved / 819e9 / dt:.1f}% of 819 GB/s", flush=True)
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--shape", choices=("brumby", "solar-open2"),
+                    default="brumby")
     ap.add_argument("--layers", type=int, default=20)
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
 
     import jax
+    if args.shape == "solar-open2":
+        dev = jax.devices()[0]
+        print(f"device: {dev.platform} {dev.device_kind}", flush=True)
+        return solar_open2(args.steps)
+
     import jax.numpy as jnp
     import numpy as np
 

@@ -418,6 +418,12 @@ _LAYOUTS = {
     "tiny-lfm2-moe": (
         lambda c: None,
         lambda c, R: (R, 2 * c.hidden_size), 1, None, "conv", 2),
+    # a delta rule's (solar_open2, ISSUE 65): lightning's state layout
+    # behind the tails of three convolutions in one piece
+    "tiny-solar-open2": (
+        lambda c: (c.kda_heads * c.kda_head_dim, c.kda_head_dim),
+        lambda c, R: (R, 3 * 3 * c.kda_heads * c.kda_head_dim), 1,
+        "state_chunks", "kda", 3),
 }
 
 

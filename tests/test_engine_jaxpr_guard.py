@@ -130,6 +130,20 @@ MODELS = {
         layer_types=["conv", "full_attention", "conv", "conv",
                      "full_attention", "conv"],
         rope_parameters={"rope_theta": 1000000.0, "rope_type": "default"}),
+    # rank 1 of 2 of a tiny Solar-Open2 (PR 65): a GQA layer, a two-layer
+    # scan of Kimi delta attention layers, a GQA layer; 4 of a router's 8
+    # experts held
+    "solar_open2": dict(
+        model_type="solar_open2", vocab_size=512, hidden_size=256,
+        intermediate_size=512, num_hidden_layers=4, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=128, rms_norm_eps=1e-5,
+        max_position_embeddings=2048, use_rope=False, gqa_layers=[0, 3],
+        use_gqa_gate=True, n_routed_experts=4, n_shared_experts=1,
+        num_experts_per_tok=2, moe_intermediate_size=128,
+        norm_topk_prob=True, routed_scaling_factor=1.0,
+        linear_attn_config={"short_conv_kernel_size": 4, "head_dim": 128,
+                            "num_heads": 2, "num_kv_heads": None},
+        expert_parallel_share={"router_experts": 8, "first_expert": 4}),
 }
 
 # (engine_decode, engine_paged_prefill) on the parent of PR 38; the paged
@@ -154,6 +168,9 @@ PINNED = {
     # PR 61's own: a dense convolution layer, an attention layer on lane
     # pairs, a two-layer scan of sparse convolution layers, one more of each
     "lfm2_moe": (1621, 1534),
+    # PR 65's own: the share's mask and local ids in every layer, the
+    # chunked delta rule (its `lax.map` and its walk) in the prefill
+    "solar_open2": (1476, 1547),
 }
 
 
@@ -445,6 +462,7 @@ MOE_KERNEL_ROUTE = {
     "sdar_moe": 549,  # 593, 546
     "granitemoehybrid": 1257,  # 1380, 1248
     "lfm2_moe": 1635,  # PR 61's own 1624, and ISSUE 64's barriers
+    "solar_open2": 1367,  # PR 65's own
 }
 
 

@@ -1016,6 +1016,30 @@ def test_lightning_decode_kernel_compiles_at_the_cells_shapes(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 2 ** 22
 
 
+def test_kda_decode_kernel_compiles_at_the_cells_shapes(one_chip):
+    """Solar-Open2's Kimi delta attention layers (PR 65): 32 slots' state
+    rows `[64 heads x 128, 128]` float32 over 9 layers, the decay a VECTOR a
+    head, the state read (a lane reduction) before the rank-one update, the
+    output stored as columns. The pool goes in and comes out as one
+    buffer."""
+    from bigdl_tpu.ops.pallas.mamba2 import kda_decode
+
+    B, H, D, L, R = 32, 64, 128, 9, 32
+    vec = _sds((B, H, D), jnp.float32, one_chip)
+
+    def f(state, layer, rows, live, q, k, v, g, beta):
+        return kda_decode(state, layer, rows, live, q, k, v, g, beta,
+                          interpret=False)
+
+    c = jax.jit(f, donate_argnums=0).lower(
+        _sds((L, R, H * D, D), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip), vec, vec, vec, vec,
+        _sds((B, H), jnp.float32, one_chip)).compile()
+    assert "kda_decode" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 22
+
+
 def test_flash_attention_compiles_under_a_selections_mask(one_chip):
     """MiniCPM-SALA's prefill (PR 54): 16384 positions of 16 query heads a
     KV head, 2 KV heads of 128, and beside the causal bound an int8 mask a
